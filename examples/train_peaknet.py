@@ -92,15 +92,7 @@ def main():
 
     enable_large_alloc_reuse()
 
-    import os
-
     import jax
-
-    # some TPU plugins ignore the JAX_PLATFORMS env var; honor it via the
-    # config knob so `JAX_PLATFORMS=cpu python examples/train_peaknet.py`
-    # really runs on CPU (same mirroring as bench.py)
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import jax.numpy as jnp
     import optax
 
@@ -114,6 +106,9 @@ def main():
     from psana_ray_tpu.producer import ProducerRuntime
     from psana_ray_tpu.sources import SyntheticSource
     from psana_ray_tpu.transport.addressing import open_queue
+    from psana_ray_tpu.utils.jaxenv import startup_line
+
+    print(startup_line())  # once, at start: a fall to the CPU is visible
 
     # DP over every device; 'model' axis present (width 1) because the
     # models' logical-axis annotations name it — widen it on pod slices
@@ -149,8 +144,11 @@ def main():
     state = create_train_state(model, opt, jax.random.key(0), sample, mesh)
     step = make_train_step(model, opt, loss_fn)
 
+    # the calibration constants are ARGUMENTS, not closed over: a jit bakes
+    # closed-over arrays into the program as literals (19 MB here at
+    # epix10k2M — and as much again in every compile-cache entry)
     @jax.jit
-    def prepare(frames, valid):
+    def prepare(frames, valid, pedestal, gain, mask):
         c = calibrate(frames, pedestal, gain, mask, cm_algorithm="mean")
         x = panels_to_nhwc(c, mode="batch")  # [B*P, H, W, 1]
         targets = labels_of(x)
@@ -188,7 +186,8 @@ def main():
             # batches (GroupNorm training has no such constraint)
             return None
         x, targets, row_valid = prepare(
-            jnp.asarray(batch.frames), jnp.asarray(batch.valid)
+            jnp.asarray(batch.frames), jnp.asarray(batch.valid),
+            pedestal, gain, mask,
         )
         train_on.state, loss = step(train_on.state, x, (targets, row_valid))
         losses.append(float(loss))

@@ -47,8 +47,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from psana_ray_tpu.parallel.compat import shard_map
+from jax import lax, shard_map
 from jax.experimental import pallas as pl
 from jax.sharding import Mesh, PartitionSpec as P
 

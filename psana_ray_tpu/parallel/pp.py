@@ -32,8 +32,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from psana_ray_tpu.parallel.compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

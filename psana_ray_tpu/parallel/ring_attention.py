@@ -26,9 +26,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from psana_ray_tpu.parallel.compat import shard_map
 
 NEG_INF = -1e30
 

@@ -174,6 +174,10 @@ def create_train_state(
     # count) must be explicitly replicated across the mesh — left on a
     # single device, the first train step after a checkpoint restore fails
     # with "incompatible devices" (restore preserves committed shardings).
+    # Judged by the sharding's KIND, not its device count: on a one-device
+    # mesh a leaf without a mesh sharding covers "every device" yet still
+    # differs in type from what the step returns, and the second step
+    # would retrace and compile the whole train program again.
     # Optimizer state covers the 'params' collection only (make_train_step
     # updates {'params': ...}); non-param collections like 'batch_stats'
     # are carried by the train step, not the optimizer.
@@ -181,7 +185,7 @@ def create_train_state(
     replicated = NamedSharding(mesh, P())
     opt_state = jax.tree.map(
         lambda x: jax.device_put(x, replicated)
-        if hasattr(x, "sharding") and len(x.sharding.device_set) < mesh.size
+        if hasattr(x, "sharding") and not isinstance(x.sharding, NamedSharding)
         else x,
         opt_state,
     )

@@ -182,19 +182,18 @@ class SfxPipeline:
                 f"(trained with {self.features}); the widths are a property "
                 f"of the tree — drop the explicit features/--features"
             )
-        self._variables = {"params": params}
         self._model = PeakNetUNetTPU(
             features=self.features, norm="frozen", s2d=self.s2d
         )
-        self._calib = None
-        if calib is not None:
-            import jax.numpy as jnp
-
-            ped, gain, mask = calib
-            self._calib = (
-                jnp.asarray(ped), jnp.asarray(gain), jnp.asarray(mask)
-            )
-        self._step = jax.jit(self._device_step)
+        # Weights and calibration constants are device-resident ARGUMENTS
+        # of the compiled step, placed once here. Values a jit closes
+        # over are baked into the program as literals: the compiled step
+        # (and its persistent-cache entry) would carry ~56 MB of them and
+        # be keyed by the checkpoint's values, a cold compile per
+        # checkpoint.
+        self._variables = jax.device_put({"params": params})
+        self._calib = None if calib is None else jax.device_put(tuple(calib))
+        self._jit_step = jax.jit(self._device_step)
         self.n_events = 0
         self.n_peaks = 0
         # events/s, bytes/s, per-batch device-wait latency; a registry
@@ -204,30 +203,36 @@ class SfxPipeline:
         self.metrics = PipelineMetrics()
 
     # -- the one compiled program ----------------------------------------
-    def _device_step(self, frames):
+    def _device_step(self, variables, calib, frames):
         """``[B, P, H, W]`` raw-or-calibrated frames -> panel-row peak
-        tuples ``(yx [B*P, K, 2], score [B*P, K], n [B*P])``."""
+        tuples ``(yx [B*P, K, 2], score [B*P, K], n [B*P])``. Pure in its
+        arguments (``calib`` is the ``(pedestal, gain, mask)`` triple or
+        None), so it also runs per shard under ``shard_map``."""
         import jax.numpy as jnp
 
         from psana_ray_tpu.models import panels_to_nhwc
         from psana_ray_tpu.models.peaks import find_peaks
 
         x = frames
-        if self._calib is not None:
+        if calib is not None:
             from psana_ray_tpu.ops import fused_calibrate
 
-            ped, gain, mask = self._calib
+            ped, gain, mask = calib
             x = fused_calibrate(
                 x, ped, gain, mask,
                 threshold=self.cfg.calib_threshold, out_dtype=jnp.bfloat16,
             )
-        logits = self._model.apply(self._variables, panels_to_nhwc(x, mode="batch"))
+        logits = self._model.apply(variables, panels_to_nhwc(x, mode="batch"))
         return find_peaks(
             logits,
             max_peaks=self.cfg.max_peaks,
             threshold=self.cfg.peak_threshold,
             min_distance=self.cfg.min_distance,
         )
+
+    def _step(self, frames):
+        """The compiled step on this pipeline's own weights and constants."""
+        return self._jit_step(self._variables, self._calib, frames)
 
     # -- host side: panel rows -> per-event raw-coordinate peak sets ------
     def dispatch(self, batch):
@@ -462,12 +467,9 @@ def main(argv=None):
 
     import os
 
-    import jax
+    from psana_ray_tpu.utils.jaxenv import startup_line
 
-    if os.environ.get("JAX_PLATFORMS"):
-        # some TPU plugins ignore the env var; mirror it into the config
-        # knob (same pattern as bench.py / train_peaknet.py)
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    log.info(startup_line())  # once, before any work
 
     import dataclasses as dc
 

@@ -18,6 +18,7 @@ The C library builds on demand with ``make`` (g++); see
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import pickle
@@ -39,22 +40,35 @@ logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libshmring.so")
+_STAMP_PATH = _LIB_PATH + ".stamp"  # source digest the .so was built from
 
 _lib = None
 _lib_lock = threading.Lock()
 
 
+def _source_digest() -> str:
+    """Content hash of everything ``make`` builds the library from."""
+    h = hashlib.sha256()
+    for fname in sorted(os.listdir(_NATIVE_DIR)):
+        if fname.endswith((".cpp", ".h", ".hpp")) or fname == "Makefile":
+            h.update(fname.encode())
+            with open(os.path.join(_NATIVE_DIR, fname), "rb") as f:
+                h.update(f.read())
+    return h.hexdigest()
+
+
 def _lib_is_stale() -> bool:
-    """True when the .so is missing or older than any native source —
-    a stale binary must never shadow an edited shmring.cpp."""
+    """True unless the .so was built from the native sources as they are
+    now — a stale binary must never shadow an edited shmring.cpp. Judged
+    by the source digest the build stamps beside it, not by mtimes: a
+    copied tree (rsync, tar, a container layer) need not keep them."""
     if not os.path.exists(_LIB_PATH):
         return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    for fname in os.listdir(_NATIVE_DIR):
-        if fname.endswith((".cpp", ".h", ".hpp")) or fname == "Makefile":
-            if os.path.getmtime(os.path.join(_NATIVE_DIR, fname)) > lib_mtime:
-                return True
-    return False
+    try:
+        with open(_STAMP_PATH) as f:
+            return f.read().strip() != _source_digest()
+    except FileNotFoundError:
+        return True
 
 
 def _load_lib() -> ctypes.CDLL:
@@ -78,12 +92,15 @@ def _load_lib() -> ctypes.CDLL:
             try:
                 if _lib_is_stale():  # re-check under the lock: a sibling
                     try:             # process may have just built it
+                        digest = _source_digest()  # of what make reads
                         subprocess.run(
                             ["make", "-C", _NATIVE_DIR, "-s", "-B"],
                             check=True,
                             capture_output=True,
                             timeout=120,
                         )
+                        with open(_STAMP_PATH, "w") as stamp_f:
+                            stamp_f.write(digest)
                     except (
                         subprocess.CalledProcessError,
                         FileNotFoundError,

@@ -13,8 +13,8 @@ Two surfaces:
 - :func:`annotate` — named region that shows up on the trace timeline
   (wrap one pipeline stage: batch assembly, device put, step dispatch).
 
-Both degrade to no-ops when profiling is unavailable (e.g. a stripped
-CPU wheel) so production paths can leave the calls in place.
+A trace that cannot be started or written raises: a caller that asked
+for a device timeline must not get a run without one.
 """
 
 from __future__ import annotations
@@ -28,45 +28,15 @@ from typing import Iterator, Optional
 logger = logging.getLogger(__name__)
 
 
-def profiler_trace_kwargs(jax) -> dict:
-    """kwargs for ``jax.profiler.start_trace`` with the python tracer OFF.
+def start_trace_python_tracer_off(jax, path: str) -> None:
+    """``jax.profiler.start_trace`` with the python tracer OFF.
 
     On long captures the python tracer's host events flood the trace
     (observed hitting the xprof converter's 1M-event cap with ZERO device
-    events surviving) — the device timeline is what these traces are for.
-    Returns ``{}`` (tracer stays on, with a warning) when this jax build
-    has no ProfileOptions."""
-    try:
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        return {"profiler_options": opts}
-    except Exception as e:
-        logger.warning(
-            "jax.profiler.ProfileOptions unavailable (%r): python tracer "
-            "stays ON — long captures may flood the trace and lose the "
-            "device timeline", e,
-        )
-        return {}
-
-
-def start_trace_python_tracer_off(jax, path: str) -> None:
-    """``jax.profiler.start_trace`` with the python tracer disabled when
-    possible. Guards the VERSION-SKEW case ProfileOptions construction
-    alone cannot: a jax whose ProfileOptions exists but whose start_trace
-    lacks the ``profiler_options`` kwarg raises TypeError — retry without
-    the kwarg instead of letting it escape into callers' finally-blocks
-    (where a stop_trace on a never-started trace masks the real error)."""
-    kwargs = profiler_trace_kwargs(jax)
-    try:
-        jax.profiler.start_trace(path, **kwargs)
-    except TypeError:
-        if not kwargs:
-            raise
-        logger.warning(
-            "start_trace rejected profiler_options (version skew): python "
-            "tracer stays ON for this capture"
-        )
-        jax.profiler.start_trace(path)
+    events surviving) — the device timeline is what these traces are for."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(path, profiler_options=opts)
 
 
 @contextlib.contextmanager
@@ -84,20 +54,12 @@ def trace(logdir: Optional[str]) -> Iterator[None]:
     import jax
 
     path = os.path.join(logdir, time.strftime("%Y%m%d-%H%M%S"))
-    try:
-        start_trace_python_tracer_off(jax, path)
-    except Exception as e:  # pragma: no cover - backend without profiler
-        logger.warning("device tracing unavailable: %r", e)
-        yield
-        return
+    start_trace_python_tracer_off(jax, path)
     try:
         yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-            logger.info("device trace written to %s", path)
-        except Exception as e:  # pragma: no cover
-            logger.warning("stopping device trace failed: %r", e)
+        jax.profiler.stop_trace()
+        logger.info("device trace written to %s", path)
 
 
 def annotate(name: str):
@@ -108,10 +70,7 @@ def annotate(name: str):
     wrapper around a TraceMe)."""
     import jax
 
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - backend without profiler
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 def annotate_stage(stage: str):

@@ -1,0 +1,265 @@
+"""Ask the chip's compiler before the chip: the main path's device
+programs, at real widths, compiled for a DESCRIBED v5e:2x2 topology (no
+chip attached — jax.experimental.topologies). Interpret-mode tests cannot
+see what Mosaic refuses (unaligned slices, VMEM overflow) nor what does
+not fit HBM; these do, at no chip time. Nothing runs, so they say nothing
+about results or speed — ``chip_smoke.py`` on the chip does that.
+
+Also here: the compile-cache placement rule, the smoke's refusal to pass
+off the chip, and the producer staying JAX-free (the one-process-per-chip
+rule's cheap guards).
+"""
+
+import os
+import subprocess
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PANELS, H, W = 16, 352, 384  # epix10k2M
+BF16, F32 = jnp.bfloat16, jnp.float32
+S = jax.ShapeDtypeStruct  # case arguments are shapes; the test adds the device
+
+
+@pytest.fixture
+def cache_setting():
+    """Snapshot/restore the process-wide persistent-cache settings."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_enable_compilation_cache",
+        "jax_include_full_tracebacks_in_locations",
+    )
+    saved = {n: getattr(jax.config, n) for n in names}
+    yield
+    for n, v in saved.items():
+        jax.config.update(n, v)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def one_chip(cache_setting):
+    """Sharding on one described v5e chip; persistent cache OFF around the
+    compile (an entry written for a described device cannot be read back
+    without one — the next run would warn and recompile)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _calib(dtype):
+    from psana_ray_tpu.ops import fused_calibrate
+
+    def fn(raw, ped, gain, mask):
+        return fused_calibrate(
+            raw, ped, gain, mask, threshold=10.0, interpret=False, out_dtype=BF16
+        )
+
+    panel = (PANELS, H, W)
+    return fn, [S((8, *panel), dtype), S(panel, F32), S(panel, F32), S(panel, jnp.uint8)], 1
+
+
+def _flash_fwd():
+    from psana_ray_tpu.parallel import flash
+
+    q = S((2, 4, 8448, 128), BF16)
+    return (lambda q_, k, v: flash._pallas_attention_with_stats(q_, k, v, False)), [q, q, q], 1
+
+
+def _flash_bwd():
+    from psana_ray_tpu.parallel import flash
+
+    q = S((2, 4, 8448, 128), BF16)
+    lse = S((2, 4, 8448), F32)
+
+    def fn(q_, k, v, o, lse_, do):
+        return flash._pallas_attention_bwd(q_, k, v, o, lse_, do, False)
+
+    return fn, [q, q, q, q, lse, q], 2  # the dkv kernel and the dq kernel
+
+
+def _resnet_stage4():
+    """First stage-4 bottleneck of ResNet-50 on epix10k2M at batch 32:
+    22x24x1024 in, stride 2, projection — the VMEM-tight block."""
+    from psana_ray_tpu.models.pallas_resnet import fused_bottleneck
+
+    cin, f = 1024, 512
+
+    def fn(x, w1, w2, w3, wp, *affines):
+        return fused_bottleneck(
+            x, w1, w2, w3, affines, wp=wp, stride=2, w_true=24, interpret=False
+        )
+
+    affines = [S((1, c), F32) for c in (f, f, f, f, 4 * f, 4 * f, 4 * f, 4 * f)]
+    return fn, [
+        S((32, 22, 24, cin), BF16), S((cin, f), BF16), S((9, f, f), BF16),
+        S((f, 4 * f), BF16), S((cin, 4 * f), BF16), *affines,
+    ], 1
+
+
+def _unet_level1():
+    """PeakNet-TPU encoder level 1 at s2d=2, two frames: 88x96, 64 -> 128
+    channels, with its stride-2 downsample."""
+    from psana_ray_tpu.models.pallas_unet import fused_conv_block
+
+    def fn(x, w1, s1, b1, w2, s2, b2, wd):
+        return fused_conv_block(x, w1, (s1, b1), w2, (s2, b2), wd=wd, interpret=False)
+
+    vec = S((128,), F32)
+    k = S((3, 3, 128, 128), F32)
+    return fn, [S((32, 88, 96, 64), BF16), S((3, 3, 64, 128), F32), vec, vec, k, vec, vec, k], 1
+
+
+def _sfx_serve_step():
+    """The program ``python -m psana_ray_tpu.sfx`` compiles at its
+    defaults: u16 [8,16,352,384] -> fused calibration -> PeakNetUNetTPU
+    (64,128,256,512; s2d=2; frozen) -> find_peaks(128, 0.5, 2), built by
+    the pipeline's own constructor; its weights and calibration constants
+    are arguments of the step."""
+    from flax.core import meta
+
+    from psana_ray_tpu.models import PeakNetUNetTPU
+    from psana_ray_tpu.models.init import eval_shape_init
+    from psana_ray_tpu.sfx import SfxConfig, SfxPipeline
+
+    variables = meta.unbox(eval_shape_init(
+        PeakNetUNetTPU(features=(64, 128, 256, 512), norm="frozen", s2d=2),
+        (1, 64, 64, 1),
+    ))
+    panel = (PANELS, H, W)
+    calib = (np.zeros(panel, np.float32), np.ones(panel, np.float32), np.ones(panel, np.uint8))
+    pipe = SfxPipeline(variables, writer=None, calib=calib)
+    resident = jax.tree.map(lambda a: S(a.shape, a.dtype), (pipe._variables, pipe._calib))
+    return pipe._device_step, [*resident, S((SfxConfig.batch_size, *panel), jnp.uint16)], 1
+
+
+CASES = {
+    "calib_epix10k2M_u16": lambda: _calib(jnp.uint16),
+    "calib_epix10k2M_f32": lambda: _calib(F32),
+    "sfx_serve_step_cli_defaults": _sfx_serve_step,
+    "resnet50_stage4_bottleneck": _resnet_stage4,
+    "unet_level1_conv_block": _unet_level1,
+    "flash_fwd_2x4x8448x128": _flash_fwd,
+    "flash_bwd_2x4x8448x128": _flash_bwd,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
+    fn, arg_shapes, min_mosaic = CASES[case]()
+    # code that asks default_backend() would take its CPU (interpret)
+    # branch under a described topology; steer it here, not in the program
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), arg_shapes)
+    compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
+    assert compiled.as_text().count("tpu_custom_call") >= min_mosaic
+    mem = compiled.memory_analysis()
+    # one v5e chip: 16 GB of HBM for arguments, outputs and temporaries
+    assert (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
+    ) < 16e9
+
+
+# -- the compile cache can be placed from outside ---------------------------
+
+def test_compile_cache_honours_the_environment(cache_setting, monkeypatch, tmp_path):
+    from psana_ray_tpu.utils.jaxenv import configure_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert configure_compile_cache() == str(tmp_path)
+    # JAX reads the variable itself; code set no directory over it
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_into_the_checkout(cache_setting, monkeypatch):
+    from psana_ray_tpu.utils.jaxenv import configure_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(REPO, ".jax_cache")
+    assert configure_compile_cache() == want  # fixed: no pid, time or tmp name
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_kernel_program_is_the_same_from_any_call_stack(one_chip, monkeypatch):
+    """A Pallas kernel's serialized module must not carry its callers'
+    Python stack, or one step compiled from two entry points gets two
+    persistent-cache keys (seen on the v5e: CLI child vs. script)."""
+    from psana_ray_tpu.utils.jaxenv import configure_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "unused")  # set no dir here
+    configure_compile_cache()
+    step, arg_shapes, _ = _calib(jnp.uint16)
+    args = [S(a.shape, a.dtype, sharding=one_chip) for a in arg_shapes]
+
+    def through_another_caller(*a):
+        return step(*a)
+
+    texts = []
+    for fn in (step, through_another_caller):
+        jax.clear_caches()  # else the second lowering reuses the first trace
+        fn.__name__ = "step"  # the module is named after the function
+        texts.append(jax.jit(fn).lower(*args).as_text())
+    assert "tpu_custom_call" in texts[0]
+    assert texts[0] == texts[1]
+
+
+# -- chip_smoke.py refuses to pass off the chip -----------------------------
+
+def _run(argv, **env_extra):
+    env = dict(os.environ, **env_extra)
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    return subprocess.run(
+        argv, capture_output=True, text=True, timeout=300, env=env, cwd=REPO
+    )
+
+
+def test_chip_smoke_fails_at_the_device_check_on_cpu():
+    out = _run([sys.executable, "chip_smoke.py"], JAX_PLATFORMS="cpu")
+    assert out.returncode != 0
+    assert '"ok"' not in out.stdout
+    assert "JAX found no accelerator" in out.stderr  # there, not before
+
+
+def test_chip_smoke_failed_child_fails_the_run(tmp_path):
+    """A phase's child that exits non-zero ends the run non-zero — no
+    try/except lets a failed phase reach the result line."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    with pytest.raises(SystemExit) as e:
+        chip_smoke.run_child(
+            "serve", [sys.executable, "-c", "raise SystemExit(3)"],
+            str(tmp_path / "serve.log"),
+        )
+    assert e.value.code == 1
+
+
+def test_producer_cli_never_imports_jax():
+    """A producer must not be able to hold the chip: the CLI runs to its
+    EOS with ``jax`` absent from ``sys.modules``."""
+    code = (
+        "import sys; from psana_ray_tpu.producer import main; "
+        "main(['--detector_name', 'smoke_a', '--num_events', '4', '--calib']); "
+        "assert 'jax' not in sys.modules, 'producer imported jax'; print('JAXFREE')"
+    )
+    out = _run([sys.executable, "-c", code])
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "JAXFREE" in out.stdout

@@ -86,7 +86,7 @@ class TestStageTags:
     def test_tag_names_pin_the_canonical_stage_vocabulary(self):
         """TAG_NAMES[1:] IS obs.stages.STAGES — the profiler bills to
         the exact vocabulary the latency histograms speak; drift here
-        would silently fork the stage taxonomy."""
+        would silently fork the stage vocabulary."""
         assert tuple(TAG_NAMES[1:]) == tuple(STAGES)
         assert TAG_NAMES[TAG_UNTAGGED] == "untagged"
         assert N_TAGS == len(STAGES) + 1

@@ -1,0 +1,19 @@
+"""A quantile, in ms, of due-to-result latency over every frame DUE
+inside the window: from the instant the frame was due at the generator
+(open loop: a stall is charged to the frames behind it) to its result
+appended at the sink. A frame with no result has no latency to give; it
+is counted in ``failed`` and makes the run incorrect."""
+
+import numpy as np
+
+
+def read(ctx, q: float):
+    _, idx, done_t = ctx.results
+    due = ctx.generated["due"]
+    t0, t1 = ctx.window
+    ok = (idx >= 0) & (idx < len(due))
+    idx, done_t = idx[ok], done_t[ok]
+    in_window = (due[idx] >= t0) & (due[idx] < t1)
+    if not in_window.any():
+        return None
+    return float(np.quantile(done_t[in_window] - due[idx][in_window], float(q))) * 1e3

@@ -12,24 +12,33 @@ survives into the pjit'd world (the reference's `(rank, idx)` stamp,
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
 
 from psana_ray_tpu.obs.flight import FLIGHT
-from psana_ray_tpu.obs.profiling.stagetag import (
-    TAG_BATCH,
-    TAG_DEQUEUE,
-    TAG_UNTAGGED,
-    set_stage,
+from psana_ray_tpu.obs.stages import (
+    HOP_BATCH,
+    HOP_DEQ,
+    HOP_ENQ,
+    HOP_PUSH,
+    PHASE_BATCH,
+    PHASE_DEQUEUE,
+    PHASE_QUEUE_WAIT,
 )
-from psana_ray_tpu.obs.stages import HOP_BATCH, HOP_DEQ, HOP_PUSH
 from psana_ray_tpu.obs.tracing import TRACE_KEY, TRACER
 from psana_ray_tpu.records import EndOfStream, EosTally, FrameRecord, mark_hop
 from psana_ray_tpu.transport.recovery import return_to_queue
 from psana_ray_tpu.transport.registry import TransportClosed, TransportWedged
 from psana_ray_tpu.utils.bufpool import WIRE
+from psana_ray_tpu.utils.trace import phase
+
+# Batch ids, unique in the process whatever the number of batchers: the id
+# the phases' spans carry in the trace spool, and the one a traced frame's
+# own spans name as the batch it joined.
+_BATCH_IDS = itertools.count(1)
 
 
 class DrainControl:
@@ -77,6 +86,16 @@ class Batch:
     # Deliberately NOT part of map_arrays — device placement and global
     # assembly must never touch it (dataclasses.replace carries it along).
     hops: Optional[List[dict]] = None
+    # More host-only scalars, carried along the same way. ``batch_id``
+    # (0 = not from a batcher) names the batch in the trace spool.
+    # ``t_enq`` is the OLDEST enqueue stamp among its frames (monotonic
+    # seconds, from a transport that stamps its slots; 0.0 = unknown):
+    # an enqueue -> result ``e2e`` once per batch on untimed streams,
+    # with no per-frame object. ``t_staged`` is the instant the
+    # prefetcher had it on the device (0.0 = never staged by one).
+    batch_id: int = 0
+    t_enq: float = 0.0
+    t_staged: float = 0.0
 
     def __post_init__(self):
         if self.num_valid < 0:
@@ -152,6 +171,7 @@ class FrameBatcher:
         self._cur: Optional[tuple] = None
         self._fill = 0
         self._hops: Optional[List[dict]] = None  # stamps of the current batch
+        self._t_enq = 0.0  # oldest enqueue stamp of the current batch
 
     def _alloc(self) -> tuple:
         b = self.batch_size
@@ -192,6 +212,9 @@ class FrameBatcher:
         rank[i] = rec.shard_rank
         idx[i] = rec.event_idx
         energy[i] = rec.photon_energy
+        t_enq = rec.t_enq
+        if t_enq and (t_enq < self._t_enq or not self._t_enq):
+            self._t_enq = t_enq
         hops = rec.hops
         if hops is not None:  # timed stream: stamp copy-into-batch done
             hops[HOP_PUSH] = time.monotonic()
@@ -241,11 +264,15 @@ class FrameBatcher:
         self._cur = None
         self._fill = 0
         hops, self._hops = self._hops, None
+        t_enq, self._t_enq = self._t_enq, 0.0
         if hops is not None:  # one emit stamp for every record in the batch
             t = time.monotonic()
             for h in hops:
                 h[HOP_BATCH] = t
-        return Batch(frames, valid, rank, idx, energy, num_valid=n, hops=hops)
+        return Batch(
+            frames, valid, rank, idx, energy, num_valid=n, hops=hops,
+            batch_id=next(_BATCH_IDS), t_enq=t_enq,
+        )
 
 
 def batches_from_queue(
@@ -258,6 +285,7 @@ def batches_from_queue(
     raise_on_stall: bool = False,
     prefer_stream: bool = True,
     control: Optional[DrainControl] = None,
+    metrics=None,
 ) -> Iterator[Batch]:
     """Drain a transport queue into fixed-shape batches until EOS.
 
@@ -295,10 +323,24 @@ def batches_from_queue(
     the poll interval LIVE dials the autotune controller adjusts while
     this loop runs (ISSUE 15); the batch SHAPE stays fixed regardless —
     pjit compiles per shape, so only the drain granularity moves.
+
+    Every turn of the loop is three consecutive phases (``utils.trace.
+    phase``): ``queue_wait`` (the pop: it blocks up to the poll interval
+    for a first record, and on the view path copies nothing), ``dequeue``
+    (EOS tally, stamps) and ``batch`` (the copy into the arena); time
+    suspended at a ``yield`` is the consumer's. ``metrics`` (the serving
+    loop's ``PipelineMetrics``, optional) gets one ``queue_wait``
+    observation per turn that popped something, covering the whole wait
+    since the previous such turn — empty polls report nothing of their own.
     """
     batcher: Optional[FrameBatcher] = None
     starved_since: Optional[float] = None
     tally = EosTally()
+    wait_t0: Optional[float] = None  # start of the first of a run of empty polls
+    # the loop's three phases, built once: it can turn a thousand times a second
+    in_queue_wait = phase(PHASE_QUEUE_WAIT, metrics)
+    in_dequeue = phase(PHASE_DEQUEUE)
+    in_batch = phase(PHASE_BATCH)
     # drain preference: server-push stream (TCP streaming mode — no pull
     # RTT, no empty-queue polls) > zero-copy view drain (shm ring slots)
     # > plain get_batch. Every TCP variant returns lease-backed records
@@ -320,15 +362,26 @@ def batches_from_queue(
                     chunk = max(1, int(control.chunk))
                 if control.poll_s:
                     poll_s = float(control.poll_s)
-            set_stage(TAG_DEQUEUE)  # profiler: bill the pop to "dequeue"
-            try:
-                items = pop(chunk, timeout=poll_s)
-            except TransportWedged:
-                # a peer crashed mid-claim and frames are stuck behind the
-                # wedge: this is data loss, NOT a clean end of stream —
-                # propagate instead of flushing-and-returning like close
-                raise
-            except TransportClosed:
+            closed = False
+            with in_queue_wait as ph:
+                try:
+                    items = pop(chunk, timeout=poll_s)
+                except TransportWedged:
+                    # a peer crashed mid-claim and frames are stuck behind
+                    # the wedge: this is data loss, NOT a clean end of
+                    # stream — propagate instead of flushing-and-returning
+                    raise
+                except TransportClosed:
+                    closed, items = True, ()
+                if items:
+                    ph.frames = len(items)
+                    if wait_t0 is not None:  # the wait began polls ago
+                        ph.t0, wait_t0 = wait_t0, None
+                else:
+                    ph.record = False
+                    if wait_t0 is None:
+                        wait_t0 = ph.t0
+            if closed:
                 # transport died mid-stream: deliver what we already hold
                 # (reference dead-queue parity = clean exit, producer.py:112-114)
                 if batcher is not None and (tail := batcher.flush()) is not None:
@@ -358,8 +411,7 @@ def batches_from_queue(
                     return
                 continue
             starved_since = None
-            t_deq = time.monotonic()
-            tally.flush_duplicates(queue)  # gets just freed slots
+            t_deq = ph.t1  # the pop returned
             # Every record from this pop is copied-and-released BEFORE any
             # yield: a generator suspended at yield (slow consumer, full
             # prefetch queue) must not sit on transport leases — over the
@@ -369,55 +421,67 @@ def batches_from_queue(
             # contract (see FrameBatcher docstring; InfeedPipeline budgets
             # prefetch_depth + 4 for it).
             ready: List[Batch] = []
+            frames: List[FrameRecord] = []
             stream_done = False
-            set_stage(TAG_BATCH)  # profiler: the arena-copy section
-            for pos, item in enumerate(items):
-                if isinstance(item, EndOfStream):
-                    if tally.process(item):
-                        # items after the completing marker were already
-                        # popped; hand them to the tally (sibling EOS
-                        # copies) or back to the queue so nothing this
-                        # consumer holds is silently dropped
-                        leftover_frames = []
-                        for rest in items[pos + 1:]:
-                            if isinstance(rest, EndOfStream):
-                                tally.process(rest)
-                            else:
-                                # materialize BEFORE re-enqueueing: a view-
-                                # backed leftover still occupies the very
-                                # transport slot/buffer a put may need
-                                # (self-deadlock against a full ring)
-                                leftover_frames.append(
-                                    rest.materialize() if hasattr(rest, "materialize") else rest
-                                )
-                        if leftover_frames:
-                            return_to_queue(queue, leftover_frames, what="re-popped record")
-                        if batcher is not None and (tail := batcher.flush()) is not None:
-                            ready.append(tail)
-                        FLIGHT.record("eos_complete", source="batches_from_queue")
-                        stream_done = True
-                        break
-                    continue
-                if batcher is None:
-                    batcher = FrameBatcher(batch_size, n_buffers=n_buffers)
-                trace = item.trace
-                if trace is not None and trace.sampled and TRACER.enabled:
-                    # traced frame from the wire: seed the hops dict so
-                    # the batcher/prefetcher stamps become spans at step
-                    # completion (obs.tracing.emit_batch_spans). TRACE_KEY
-                    # carries the id; stage observation ignores it
-                    mark_hop(item, HOP_DEQ, t_deq)
-                    item.hops[TRACE_KEY] = trace.trace_id
-                elif item.hops is not None:  # timed stream: stamp the pop
-                    item.hops[HOP_DEQ] = t_deq
-                out = batcher.push_view(item)  # copy into arena, release lease
-                if out is not None:
-                    ready.append(out)
-            del items  # drop any lingering record refs with the pop
-            set_stage(TAG_UNTAGGED)  # suspended-at-yield time is the consumer's
-            yield from ready
+            in_dequeue.frames = len(items)
+            with in_dequeue:
+                tally.flush_duplicates(queue)  # gets just freed slots
+                tracing = TRACER.enabled
+                for pos, item in enumerate(items):
+                    if isinstance(item, EndOfStream):
+                        if tally.process(item):
+                            # items after the completing marker were already
+                            # popped; hand them to the tally (sibling EOS
+                            # copies) or back to the queue so nothing this
+                            # consumer holds is silently dropped
+                            leftover_frames = []
+                            for rest in items[pos + 1:]:
+                                if isinstance(rest, EndOfStream):
+                                    tally.process(rest)
+                                else:
+                                    # materialize BEFORE re-enqueueing: a view-
+                                    # backed leftover still occupies the very
+                                    # transport slot/buffer a put may need
+                                    # (self-deadlock against a full ring)
+                                    leftover_frames.append(
+                                        rest.materialize() if hasattr(rest, "materialize") else rest
+                                    )
+                            if leftover_frames:
+                                return_to_queue(queue, leftover_frames, what="re-popped record")
+                            FLIGHT.record("eos_complete", source="batches_from_queue")
+                            stream_done = True
+                            break
+                        continue
+                    trace = item.trace
+                    if trace is not None and trace.sampled and tracing:
+                        # traced frame from the wire: seed the hops dict so
+                        # the batcher's stamps become its spans once its
+                        # batch is launched (obs.stages.observe_frame_stages).
+                        # TRACE_KEY carries the id; stage observation ignores it
+                        mark_hop(item, HOP_DEQ, t_deq)
+                        item.hops[TRACE_KEY] = trace.trace_id
+                    elif item.hops is not None:  # timed stream: stamp the pop
+                        item.hops[HOP_DEQ] = t_deq
+                    hops = item.hops
+                    if hops is not None and item.t_enq and HOP_ENQ not in hops:
+                        # the transport's own enqueue stamp crossed the
+                        # process hop with the slot: queue_dwell and a
+                        # per-frame e2e exist on this side of it
+                        hops[HOP_ENQ] = item.t_enq
+                    frames.append(item)
+            in_batch.frames = len(frames)
+            with in_batch:
+                for item in frames:
+                    if batcher is None:
+                        batcher = FrameBatcher(batch_size, n_buffers=n_buffers)
+                    out = batcher.push_view(item)  # copy into arena, release lease
+                    if out is not None:
+                        ready.append(out)
+                if stream_done and batcher is not None and (tail := batcher.flush()) is not None:
+                    ready.append(tail)
+                del items, frames, item  # drop any lingering record refs with the pop
+            yield from ready  # suspended-at-yield time is the consumer's
             if stream_done:
                 return
     finally:
-        set_stage(TAG_UNTAGGED)
         tally.flush_duplicates(queue, final=True)

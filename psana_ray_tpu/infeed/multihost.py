@@ -35,7 +35,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from psana_ray_tpu.infeed.batcher import Batch, batches_from_queue
-from psana_ray_tpu.obs.stages import HOP_DEVICE_PUT
 from psana_ray_tpu.utils.metrics import PipelineMetrics
 
 try:  # Python 3.11+ builtin
@@ -191,10 +190,7 @@ def make_global_Batch(local: Batch, mesh: Mesh, data_axis: str = "data") -> Batc
     g = local.map_arrays(
         lambda a: make_global_batch(np.asarray(a), mesh, data_axis)
     )
-    if g.hops:  # timed stream: global assembly IS this path's device_put
-        t = time.monotonic()
-        for h in g.hops:
-            h[HOP_DEVICE_PUT] = t
+    g.t_staged = time.monotonic()  # global assembly IS this path's device_put
     return g
 
 

@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import queue as _queue
 import threading
-import time
 from typing import Any, Callable, Iterator, Optional
 
 import jax
@@ -29,14 +28,16 @@ import numpy as np
 
 from psana_ray_tpu.infeed.batcher import Batch, batches_from_queue
 from psana_ray_tpu.obs.stages import (
-    HOP_DEVICE_PUT,
-    STAGE_DEVICE_PUT,
-    STAGE_DISPATCH,
-    observe_batch_stages,
+    PHASE_DEVICE_PUT,
+    PHASE_DEVICE_WAIT,
+    PHASE_INFEED_WAIT,
+    PHASE_LAUNCH,
+    PHASE_PREFETCH_FULL,
+    observe_batch_done,
+    observe_frame_stages,
 )
-from psana_ray_tpu.obs.tracing import emit_batch_spans
 from psana_ray_tpu.utils.metrics import PipelineMetrics
-from psana_ray_tpu.utils.trace import annotate_stage
+from psana_ray_tpu.utils.trace import phase
 
 
 class StopStream(Exception):
@@ -52,7 +53,12 @@ class DevicePrefetcher:
     ``sharding`` may be a Sharding (placed on a mesh) or None (default
     device). Transfers run on a background thread ``prefetch_depth`` ahead
     of consumption; ``jax.device_put`` is async, so the thread's role is to
-    keep the H2D copy stream busy, not to block compute.
+    keep the H2D copy stream busy, not to block compute. The thread's
+    loop is two phases per batch (``utils.trace.phase``): ``device_put``
+    (the placement) and ``prefetch_full`` (the staged batch waits for
+    room in the buffer); the rest of its time is the source's own
+    (``batches_from_queue``'s phases). ``metrics`` (optional) gets one
+    observation of each per batch.
 
     Always ``close()`` (or use as a context manager, or exhaust the
     iterator) — an abandoned prefetcher would otherwise pin
@@ -65,6 +71,7 @@ class DevicePrefetcher:
         prefetch_depth: int = 2,
         to_device: Optional[Callable[[Batch], Any]] = None,
         stop_event: Optional[threading.Event] = None,
+        metrics: Optional[PipelineMetrics] = None,
     ):
         if prefetch_depth < 1:
             raise ValueError("prefetch_depth must be >= 1")
@@ -72,7 +79,8 @@ class DevicePrefetcher:
         self._sharding = sharding
         self.prefetch_depth = prefetch_depth
         self._buf: _queue.Queue = _queue.Queue(maxsize=prefetch_depth)
-        self._to_device = to_device or self._default_to_device
+        self._to_device = to_device or self._place
+        self._metrics = metrics
         self._err: Optional[BaseException] = None
         # sharing the event with the source generator (batches_from_queue's
         # ``stop``) lets close() cancel a poll loop the iterator protocol
@@ -81,17 +89,6 @@ class DevicePrefetcher:
         self._done = False
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
-
-    def _default_to_device(self, batch: Batch):
-        # annotate_stage: same stage vocabulary on the device timeline as
-        # on the metrics endpoint (obs.stages)
-        with annotate_stage(STAGE_DEVICE_PUT):
-            out = self._place(batch)
-        if batch.hops:  # timed stream: stamp device staging done
-            t = time.monotonic()
-            for h in batch.hops:
-                h[HOP_DEVICE_PUT] = t
-        return out
 
     def _place(self, batch: Batch):
         # num_valid stays the host int — counting on-device would sync
@@ -110,7 +107,14 @@ class DevicePrefetcher:
     def _run(self):
         try:
             for batch in self._src:
-                if not self._put(self._to_device(batch)):
+                mark = (self._metrics, batch.batch_id, batch.num_valid)
+                with phase(PHASE_DEVICE_PUT, *mark) as ph:
+                    staged = self._to_device(batch)
+                if isinstance(staged, Batch):
+                    staged.t_staged = ph.t1
+                with phase(PHASE_PREFETCH_FULL, *mark):
+                    ok = self._put(staged)
+                if not ok:
                     return  # closed — drop remaining stream
         except BaseException as e:  # surface in consumer thread
             self._err = e
@@ -185,24 +189,28 @@ def drive_step(
     :meth:`InfeedPipeline.run`, ``FanInPipeline.run``, and the multi-host
     loop — the latter passes ``nbytes`` explicitly (this HOST's ingest
     bytes; the global sharded array's nbytes would overcount by the
-    process count)."""
-    t0 = time.monotonic()
-    with annotate_stage(STAGE_DISPATCH):
+    process count).
+
+    Two phases: ``launch`` (the step call returns) and, when blocking,
+    ``device_wait``; ``metrics.step_latency`` spans both. A timed batch's
+    per-frame stamps are folded between the two, where the host would
+    only wait; what is the same for the whole batch is observed once,
+    after the step."""
+    mark = (metrics, batch.batch_id, batch.num_valid)
+    with phase(PHASE_LAUNCH, *mark) as ph:
         out = step(batch)
-        if block_until_ready:
+    t0 = ph.t0
+    observe_frame_stages(metrics.stages, batch)
+    if block_until_ready:
+        with phase(PHASE_DEVICE_WAIT, *mark) as ph:
             out = jax.block_until_ready(out)
-    t1 = time.monotonic()
+    t1 = ph.t1
     metrics.observe_batch(
         batch.num_valid,
         t1 - t0,
         nbytes=int(getattr(batch.frames, "nbytes", 0)) if nbytes is None else nbytes,
     )
-    if batch.hops:  # timed stream: fold hop stamps into stage histograms
-        observe_batch_stages(metrics.stages, batch, t1)
-        # traced records (TRACE_KEY in their hops) become per-stage spans
-        # on this process's trace track — same boundaries as the
-        # histograms, so timeline and quantiles agree by construction
-        emit_batch_spans(batch, t1)
+    observe_batch_done(metrics.stages, batch, t1)
     return out
 
 
@@ -263,6 +271,7 @@ class InfeedPipeline:
             max_wait_s=max_wait_s,
             stop=stop,
             n_buffers=batcher_buffers,
+            metrics=self.metrics,
         )
         self._prefetcher = DevicePrefetcher(
             self._batches,
@@ -270,6 +279,7 @@ class InfeedPipeline:
             prefetch_depth=prefetch_depth,
             stop_event=stop,
             to_device=None if place_on_device else (lambda b: b),
+            metrics=self.metrics,
         )
 
     def __iter__(self) -> Iterator[Batch]:
@@ -318,10 +328,21 @@ class InfeedPipeline:
         ``block_until_ready`` is set (which makes ``metrics.step_latency``
         a true per-batch device latency instead of dispatch time — the
         honest number for the <5 ms p50 target, BASELINE.md). The
-        prefetcher is closed on exit, normal or not."""
+        prefetcher is closed on exit, normal or not.
+
+        The serving thread's phases per batch: ``infeed_wait`` (blocked
+        on the prefetcher), ``launch`` and ``device_wait``
+        (:func:`drive_step`), then ``on_result``, the caller's own."""
         n = 0
+        batches = iter(self)
         try:
-            for batch in self:
+            while True:
+                with phase(PHASE_INFEED_WAIT, self.metrics) as ph:
+                    batch = next(batches, None)
+                    if batch is None:
+                        ph.record = False  # the stream's end, not a batch
+                        break
+                    ph.batch_id, ph.frames = batch.batch_id, batch.num_valid
                 out = drive_step(self.metrics, step, batch, block_until_ready)
                 n += batch.num_valid
                 if on_result is not None:

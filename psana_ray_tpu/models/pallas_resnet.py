@@ -411,6 +411,7 @@ def fused_bottleneck(
             out_shape=jax.ShapeDtypeStruct((bsz, ho, wo_buf, f), _BF16),
             scratch_shapes=front_scratch,
             interpret=interpret,
+            name="bottleneck_front",
         )(x, w1, w2, s1, b1, s2, b2)
 
         back_fixed = (
@@ -448,6 +449,7 @@ def fused_bottleneck(
             out_shape=jax.ShapeDtypeStruct((bsz, ho, wo_buf, cout), _BF16),
             scratch_shapes=back_scratch,
             interpret=interpret,
+            name="bottleneck_back",
         )(*back_ops)
 
     kernel = functools.partial(
@@ -484,6 +486,7 @@ def fused_bottleneck(
         out_shape=jax.ShapeDtypeStruct((bsz, ho, wo_buf, cout), _BF16),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="bottleneck",
     )(*operands)
 
 
@@ -553,35 +556,41 @@ def resnet_fused_infer(
     p = meta.unbox(variables)["params"]
     x = x.astype(_BF16)
 
+    # Named scopes (stem, stage1.., head) are metadata on the compiled
+    # ops: a device trace finds a stage again by name.
     # stem: conv7x7/2 + affine + silu + maxpool3x3/2 (XLA; ~4 ops)
-    y = jax.lax.conv_general_dilated(
-        x, p["stem"]["kernel"].astype(_BF16), (2, 2), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )
-    y = y * p["stem_norm"]["scale"].astype(_BF16) + p["stem_norm"]["bias"].astype(_BF16)
-    y = jax.nn.silu(y)
-    y = jax.lax.reduce_window(
-        y, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1), "SAME"
-    )
+    with jax.named_scope("stem"):
+        y = jax.lax.conv_general_dilated(
+            x, p["stem"]["kernel"].astype(_BF16), (2, 2), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )
+        y = y * p["stem_norm"]["scale"].astype(_BF16) + p["stem_norm"]["bias"].astype(_BF16)
+        y = jax.nn.silu(y)
+        y = jax.lax.reduce_window(
+            y, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1), "SAME"
+        )
 
-    # width alignment for the kernels' DMA constraints: pad W to a multiple
-    # of 8 once here; blocks carry (and re-zero) the padding thereafter
-    w_true = y.shape[2]
-    y = _pad_to(y, 2, _up(w_true, 8))
+        # width alignment for the kernels' DMA constraints: pad W to a
+        # multiple of 8 once here; blocks carry (and re-zero) the padding
+        # thereafter
+        w_true = y.shape[2]
+        y = _pad_to(y, 2, _up(w_true, 8))
 
     idx = 0
     for i, n_blocks in enumerate(stage_sizes):
-        for j in range(n_blocks):
-            stride = 2 if (i > 0 and j == 0) else 1
-            w1, w2, w3, aff, wp = _block_params(p[f"BottleneckBlock_{idx}"])
-            y = fused_bottleneck(
-                y, w1, w2, w3, aff, wp=wp, stride=stride, w_true=w_true,
-                interpret=interpret,
-            )
-            w_true //= stride
-            idx += 1
+        with jax.named_scope(f"stage{i + 1}"):
+            for j in range(n_blocks):
+                stride = 2 if (i > 0 and j == 0) else 1
+                w1, w2, w3, aff, wp = _block_params(p[f"BottleneckBlock_{idx}"])
+                y = fused_bottleneck(
+                    y, w1, w2, w3, aff, wp=wp, stride=stride, w_true=w_true,
+                    interpret=interpret,
+                )
+                w_true //= stride
+                idx += 1
 
     # GAP over TRUE extent: padded columns are exactly zero, so a sum over
     # the buffer divided by h*w_true equals the unpadded mean
-    feat = jnp.sum(y.astype(jnp.float32), axis=(1, 2)) / (y.shape[1] * w_true)
-    return feat @ p["head"]["kernel"] + p["head"]["bias"]
+    with jax.named_scope("head"):
+        feat = jnp.sum(y.astype(jnp.float32), axis=(1, 2)) / (y.shape[1] * w_true)
+        return feat @ p["head"]["kernel"] + p["head"]["bias"]

@@ -46,6 +46,23 @@ def find_peaks(
     if logits.ndim == 4:
         logits = logits[..., 0]
     n_, h, w = logits.shape
+    # two named scopes, metadata only: ``nms`` (the local-max test) and
+    # ``topk`` (the K best per row) are found again by name in a device
+    # trace, whatever XLA numbers its fusions
+    with jax.named_scope("nms"):
+        is_peak, prob = _local_maxima(logits, h, w, threshold, min_distance)
+    with jax.named_scope("topk"):
+        flat_score = jnp.where(is_peak, prob, 0.0).reshape(n_, h * w)
+        score, idx = jax.lax.top_k(flat_score, max_peaks)
+        valid = score > 0.0
+        yy = jnp.where(valid, idx // w, -1).astype(jnp.int32)
+        xx = jnp.where(valid, idx % w, -1).astype(jnp.int32)
+        yx = jnp.stack([yy, xx], axis=-1)
+        return yx, jnp.where(valid, score, 0.0), valid.sum(axis=1).astype(jnp.int32)
+
+
+def _local_maxima(logits, h: int, w: int, threshold: float, min_distance: int):
+    """``(is_peak [N,H,W] bool, prob [N,H,W] f32)`` of ``[N,H,W]`` logits."""
     prob = jax.nn.sigmoid(logits.astype(jnp.float32))
     # Local-max test with exact raster-order tie-break: a pixel survives
     # unless some window neighbor beats it on (prob, earlier raster index).
@@ -64,15 +81,7 @@ def find_peaks(
             sp = pprob[:, d + dy : d + dy + h, d + dx : d + dx + w]
             si = pidx[:, d + dy : d + dy + h, d + dx : d + dx + w]
             beaten |= (sp > prob) | ((sp == prob) & (si < idx))
-    is_peak = (prob >= threshold) & ~beaten
-
-    flat_score = jnp.where(is_peak, prob, 0.0).reshape(n_, h * w)
-    score, idx = jax.lax.top_k(flat_score, max_peaks)
-    valid = score > 0.0
-    yy = jnp.where(valid, idx // w, -1).astype(jnp.int32)
-    xx = jnp.where(valid, idx % w, -1).astype(jnp.int32)
-    yx = jnp.stack([yy, xx], axis=-1)
-    return yx, jnp.where(valid, score, 0.0), valid.sum(axis=1).astype(jnp.int32)
+    return (prob >= threshold) & ~beaten, prob
 
 
 def peak_metrics(

@@ -91,30 +91,38 @@ class PeakNetUNetTPU(nn.Module):
                 f"(s2d={self.s2d} x {len(self.features) - 1} stride-2 levels); "
                 f"got {h}x{w} — pad the panels or reduce depth"
             )
+        # One named scope per level (enc0.., bottleneck, dec<level>, head):
+        # metadata on the compiled ops, so a device trace finds a level
+        # again whatever the fusions are numbered. Parameter names and
+        # values do not depend on it.
         x = space_to_depth(x, self.s2d).astype(self.dtype)
         skips = []
         # encoder
-        for f in self.features[:-1]:
-            x = ConvBlock(f, dtype=self.dtype, norm=self.norm)(x)
-            skips.append(x)
-            x = _conv(f, (3, 3), (2, 2), self.dtype)(x)  # strided downsample
-        # bottleneck
-        x = ConvBlock(self.features[-1], dtype=self.dtype, norm=self.norm)(x)
+        for i, f in enumerate(self.features[:-1]):
+            with jax.named_scope(f"enc{i}"):
+                x = ConvBlock(f, dtype=self.dtype, norm=self.norm)(x)
+                skips.append(x)
+                x = _conv(f, (3, 3), (2, 2), self.dtype)(x)  # strided downsample
+        with jax.named_scope("bottleneck"):
+            x = ConvBlock(self.features[-1], dtype=self.dtype, norm=self.norm)(x)
         # decoder
-        for f, skip in zip(reversed(self.features[:-1]), reversed(skips)):
-            x = _upsample2x(x)
-            x = _conv(f, (3, 3), (1, 1), self.dtype)(x)
-            x = MergeBlock(f, dtype=self.dtype, norm=self.norm)(x, skip)
+        levels = range(len(skips) - 1, -1, -1)
+        for i, f, skip in zip(levels, reversed(self.features[:-1]), reversed(skips)):
+            with jax.named_scope(f"dec{i}"):
+                x = _upsample2x(x)
+                x = _conv(f, (3, 3), (1, 1), self.dtype)(x)
+                x = MergeBlock(f, dtype=self.dtype, norm=self.norm)(x, skip)
         # logits for every ORIGINAL pixel: s2d²·classes channels at packed
         # resolution, unshuffled back out — f32 like the classic head
-        y = nn.Conv(
-            self.num_classes * self.s2d * self.s2d,
-            (1, 1),
-            dtype=jnp.float32,
-            param_dtype=jnp.float32,
-            kernel_init=nn.initializers.variance_scaling(
-                1.0, "fan_in", "truncated_normal"
-            ),
-            name="logits",
-        )(x)
-        return depth_to_space(y, self.s2d)
+        with jax.named_scope("head"):
+            y = nn.Conv(
+                self.num_classes * self.s2d * self.s2d,
+                (1, 1),
+                dtype=jnp.float32,
+                param_dtype=jnp.float32,
+                kernel_init=nn.initializers.variance_scaling(
+                    1.0, "fan_in", "truncated_normal"
+                ),
+                name="logits",
+            )(x)
+            return depth_to_space(y, self.s2d)

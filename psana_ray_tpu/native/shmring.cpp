@@ -16,7 +16,14 @@
 // (the mapping is MAP_SHARED).
 //
 // Layout:  [Header][Slot 0][Slot 1]...[Slot N-1],
-//          slot = [atomic seq][u32 len][payload bytes]
+//          slot = [atomic seq][u32 len][u64 enqueue ns][payload bytes]
+//
+// Every slot is stamped with its enqueue instant (CLOCK_MONOTONIC, one
+// clock for all processes of a host) at put/commit and hands the stamp
+// back at get/acquire, where the ring also accumulates the dwell
+// (enqueue -> pop) of every item: an operator's queue_dwell with no
+// tracing on, and the stamp a frame's host timeline starts from on the
+// consumer's side of the process hop.
 //
 // Build: make -C psana_ray_tpu/native   (g++ -O2 -shared -fPIC)
 
@@ -32,7 +39,7 @@
 
 namespace {
 
-constexpr uint64_t kMagic = 0x50525452494E4732ULL;  // "PRTRING2"
+constexpr uint64_t kMagic = 0x50525452494E4733ULL;  // "PRTRING3"
 
 struct Header {
   uint64_t magic;
@@ -49,11 +56,16 @@ struct Header {
   std::atomic<uint64_t> n_put;
   std::atomic<uint64_t> n_get;
   std::atomic<uint64_t> n_put_rejected;
+  // queue residency of every item popped so far (see Slot::enq_ns)
+  std::atomic<uint64_t> dwell_ns_sum;
+  std::atomic<uint64_t> dwell_ns_max;
+  std::atomic<uint64_t> dwell_count;
 };
 
 struct Slot {
   std::atomic<uint64_t> seq;
   uint32_t len;
+  uint64_t enq_ns;  // CLOCK_MONOTONIC at put/commit
   // payload follows
 };
 
@@ -83,10 +95,27 @@ struct Ring {
   StallWatch put_watch;   // producer side: acquired-but-unreleased slot
 };
 
-inline uint64_t now_ms() {
+inline uint64_t now_ns() {
   struct timespec ts;
   clock_gettime(CLOCK_MONOTONIC, &ts);
-  return (uint64_t)ts.tv_sec * 1000u + (uint64_t)(ts.tv_nsec / 1000000);
+  return (uint64_t)ts.tv_sec * 1000000000u + (uint64_t)ts.tv_nsec;
+}
+
+inline uint64_t now_ms() { return now_ns() / 1000000u; }
+
+// The pop half of the stamp: fold one item's dwell into the header and
+// return its enqueue instant.
+inline uint64_t note_dwell(Header* h, const Slot* s) {
+  uint64_t enq = s->enq_ns;
+  uint64_t now = now_ns();
+  uint64_t dwell = now > enq ? now - enq : 0;
+  h->dwell_ns_sum.fetch_add(dwell, std::memory_order_relaxed);
+  h->dwell_count.fetch_add(1, std::memory_order_relaxed);
+  uint64_t seen = h->dwell_ns_max.load(std::memory_order_relaxed);
+  while (dwell > seen &&
+         !h->dwell_ns_max.compare_exchange_weak(seen, dwell, std::memory_order_relaxed)) {
+  }
+  return enq;
 }
 
 // Returns true when the same blocking (pos, seq) has persisted beyond the
@@ -163,6 +192,9 @@ void* shmring_create(const char* name, uint64_t capacity, uint64_t slot_bytes) {
   r->hdr->n_put.store(0);
   r->hdr->n_get.store(0);
   r->hdr->n_put_rejected.store(0);
+  r->hdr->dwell_ns_sum.store(0);
+  r->hdr->dwell_ns_max.store(0);
+  r->hdr->dwell_count.store(0);
   for (uint64_t i = 0; i < capacity; i++) slot_at(r, i)->seq.store(i);
   // publish magic last: attachers spin until it appears
   reinterpret_cast<std::atomic<uint64_t>*>(&r->hdr->magic)
@@ -251,6 +283,7 @@ int shmring_put(void* handle, const uint8_t* data, uint64_t len) {
       if (h->head.compare_exchange_weak(pos, pos + 1, std::memory_order_relaxed)) {
         s->len = (uint32_t)len;
         std::memcpy(reinterpret_cast<uint8_t*>(s) + sizeof(Slot), data, len);
+        s->enq_ns = now_ns();
         s->seq.store(pos + 1, std::memory_order_release);
         h->n_put.fetch_add(1, std::memory_order_relaxed);
         r->put_watch.armed = false;
@@ -284,6 +317,7 @@ int64_t shmring_get(void* handle, uint8_t* out, uint64_t out_cap) {
       if (h->tail.compare_exchange_weak(pos, pos + 1, std::memory_order_relaxed)) {
         uint64_t len = s->len;
         std::memcpy(out, reinterpret_cast<uint8_t*>(s) + sizeof(Slot), len);
+        note_dwell(h, s);
         s->seq.store(pos + h->capacity, std::memory_order_release);
         h->n_get.fetch_add(1, std::memory_order_relaxed);
         r->get_watch.armed = false;
@@ -342,13 +376,16 @@ void shmring_commit(void* handle, uint64_t ticket, uint64_t len) {
   Ring* r = static_cast<Ring*>(handle);
   Slot* s = slot_at(r, ticket);
   s->len = (uint32_t)len;
+  s->enq_ns = now_ns();
   s->seq.store(ticket + 1, std::memory_order_release);
   r->hdr->n_put.fetch_add(1, std::memory_order_relaxed);
 }
 
 // rc: payload length >= 0 (out_ptr -> slot payload, ticket -> pass to
-// release), -1 = empty, -2 = closed, -4 = wedged (see empty_or_wedged).
-int64_t shmring_acquire(void* handle, const uint8_t** out_ptr, uint64_t* ticket) {
+// release, enq_ns -> the slot's enqueue stamp), -1 = empty, -2 = closed,
+// -4 = wedged (see empty_or_wedged).
+int64_t shmring_acquire(void* handle, const uint8_t** out_ptr, uint64_t* ticket,
+                        uint64_t* enq_ns) {
   Ring* r = static_cast<Ring*>(handle);
   Header* h = r->hdr;
   if (h->closed.load(std::memory_order_acquire)) return -2;
@@ -361,6 +398,7 @@ int64_t shmring_acquire(void* handle, const uint8_t** out_ptr, uint64_t* ticket)
       if (h->tail.compare_exchange_weak(pos, pos + 1, std::memory_order_relaxed)) {
         *out_ptr = reinterpret_cast<uint8_t*>(s) + sizeof(Slot);
         *ticket = pos;
+        *enq_ns = note_dwell(h, s);
         r->get_watch.armed = false;
         return (int64_t)s->len;
       }
@@ -414,12 +452,15 @@ void shmring_begin_drain(void* handle) {
   static_cast<Ring*>(handle)->hdr->draining.store(1, std::memory_order_release);
 }
 
-void shmring_stats(void* handle, uint64_t* out4) {
+void shmring_stats(void* handle, uint64_t* out7) {
   Header* h = static_cast<Ring*>(handle)->hdr;
-  out4[0] = shmring_size(handle);
-  out4[1] = h->n_put.load(std::memory_order_relaxed);
-  out4[2] = h->n_get.load(std::memory_order_relaxed);
-  out4[3] = h->n_put_rejected.load(std::memory_order_relaxed);
+  out7[0] = shmring_size(handle);
+  out7[1] = h->n_put.load(std::memory_order_relaxed);
+  out7[2] = h->n_get.load(std::memory_order_relaxed);
+  out7[3] = h->n_put_rejected.load(std::memory_order_relaxed);
+  out7[4] = h->dwell_ns_sum.load(std::memory_order_relaxed);
+  out7[5] = h->dwell_ns_max.load(std::memory_order_relaxed);
+  out7[6] = h->dwell_count.load(std::memory_order_relaxed);
 }
 
 // Detach the mapping; destroy=1 also unlinks the shm object.

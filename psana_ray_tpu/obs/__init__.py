@@ -69,7 +69,6 @@ from psana_ray_tpu.obs.stages import (  # noqa: F401
     STAGE_QUEUE_DWELL,
     STAGES,
     StageTimes,
-    observe_batch_stages,
     observe_record_stages,
 )
 from psana_ray_tpu.obs.stall import (  # noqa: F401
@@ -107,7 +106,6 @@ from psana_ray_tpu.obs.tracing import (  # noqa: F401
     Tracer,
     add_trace_args,
     configure_from_args as configure_tracing_from_args,
-    emit_batch_spans,
     exchange_anchors,
     obs_status_suffix,
 )

@@ -325,8 +325,16 @@ class FlightRecorder:
                     dump_dir,
                     f"flight-{proc or 'proc'}-{os.getpid()}-{stamp}-{seq}.json",
                 )
-            with open(path, "w", encoding="utf-8") as f:
+            # whole or not at all: a reader polling the directory (an
+            # operator's tail, the tests) never sees half a document
+            with open(path + ".tmp", "w", encoding="utf-8") as f:
                 json.dump(doc, f, indent=1)
+            os.replace(path + ".tmp", path)
+            # the tracer holds its spans in memory until a flush: a dump
+            # rides failure paths, so what led here goes to the spool now
+            from psana_ray_tpu.obs.tracing import TRACER
+
+            TRACER.flush()
             logger.warning("flight recorder dump (%s) -> %s", reason, path)
             return path
         except Exception:  # noqa: BLE001 — the black box must not crash the plane

@@ -6,7 +6,7 @@ histograms already speak (:data:`psana_ray_tpu.obs.stages.STAGES`):
 each worker thread publishes "which stage am I executing right now" as
 one small-int tag in a plain dict keyed by thread ident, written at the
 EXISTING instrumentation points (the producer put path, the consumer
-drain loop, ``annotate_stage`` device regions, the event-loop dispatch
+drain loop, ``utils.trace.phase`` regions, the event-loop dispatch
 pass). The sampler reads the dict from its own thread — a
 ``threading.local`` would hide the value from the reader, so the tag
 table is deliberately a shared dict: CPython dict stores are atomic
@@ -48,8 +48,9 @@ __all__ = [
     "stage_region",
 ]
 
-# Tag ids: 0 = no declared stage; 1.. mirror obs.stages.STAGES order
-# (pinned by tests/test_profiling.py so the vocabularies cannot drift).
+# Tag ids: 0 = no declared stage; 1.. mirror obs.stages.STAGES order,
+# then the loop phases that are not also stage names, in obs.stages.PHASES
+# order (pinned by tests/test_profiling.py so the vocabularies cannot drift).
 TAG_UNTAGGED = 0
 TAG_ENQUEUE = 1
 TAG_QUEUE_DWELL = 2
@@ -58,6 +59,9 @@ TAG_BATCH = 4
 TAG_DEVICE_PUT = 5
 TAG_DISPATCH = 6
 
+# The names from "queue_wait" on are loop phases beyond the hop-stage
+# names (obs.stages.PHASES; the phases ``dequeue``/``batch``/
+# ``device_put`` share the stages' tags): looked up by name, TAG_OF_STAGE.
 TAG_NAMES = (
     "untagged",
     "enqueue",
@@ -66,10 +70,18 @@ TAG_NAMES = (
     "batch",
     "device_put",
     "dispatch",
+    "queue_wait",
+    "prefetch_full",
+    "infeed_wait",
+    "launch",
+    "device_wait",
+    "fold",
+    "append",
+    "gc",
 )
 N_TAGS = len(TAG_NAMES)
 
-#: stage name -> tag id (the ``annotate_stage`` bridge; unknown names
+#: stage name -> tag id (the ``utils.trace.phase`` bridge; unknown names
 #: map to untagged rather than raising — a new stage name must never
 #: break the data path it instruments).
 TAG_OF_STAGE = {name: i for i, name in enumerate(TAG_NAMES)}
@@ -109,10 +121,10 @@ def clear_thread(ident: Optional[int] = None) -> None:
 
 class stage_region:
     """Context manager: tag the calling thread with a stage FOR THE
-    SCOPE, optionally wrapping an inner context manager (the device
-    profiler's ``TraceAnnotation`` in ``utils.trace.annotate_stage``) so
-    one ``with`` statement feeds both the device timeline and the
-    continuous profiler. Restores the previous tag on exit — nested
+    SCOPE, optionally wrapping an inner context manager (a device
+    profiler ``TraceAnnotation``) so one ``with`` statement feeds both
+    the device timeline and the continuous profiler (the serving loops'
+    ``utils.trace.phase`` does the same and records the duration too). Restores the previous tag on exit — nested
     regions (dispatch > device_put) unwind to the enclosing stage."""
 
     __slots__ = ("_tag", "_inner", "_prev")

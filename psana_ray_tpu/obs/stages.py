@@ -5,7 +5,7 @@ device step; this module names them ONCE so the record envelope
 (:func:`psana_ray_tpu.records.mark_hop`), the latency histograms
 (:class:`psana_ray_tpu.utils.metrics.StageTimes`), the Prometheus export,
 and the device-timeline annotations (:func:`psana_ray_tpu.utils.trace.
-annotate_stage`) all agree.
+phase`) all agree.
 
 Hop boundaries (monotonic timestamps stamped on the record)::
 
@@ -25,6 +25,27 @@ Stage semantics:
 - ``dispatch``     staged → step returned (prefetch-buffer dwell + device
   step; with ``block_until_ready`` a true device latency).
 
+Loop phases (what a serving THREAD is doing, marked once per loop turn or
+per batch by :func:`psana_ray_tpu.utils.trace.phase`; consecutive, never
+nested, covering the whole loop body)::
+
+    batches_from_queue   queue_wait -> dequeue -> batch
+    DevicePrefetcher     device_put -> prefetch_full
+    InfeedPipeline.run   infeed_wait -> launch -> device_wait -> (on_result)
+    SfxPipeline.run      (the batcher's three) -> launch -> device_wait
+                         -> fold -> append
+
+A phase is a ``stage.<name>`` region on the profiler's timeline, a tag
+for the flame sampler, one span in the trace spool (named ``stage.<name>``
+there too, its id the batch's) and, where the loop owns a
+``PipelineMetrics``, one observation per batch in its stage histograms.
+Hop stages are per FRAME and phases per THREAD; three names serve both
+(``dequeue``, ``batch``, ``device_put``). In the histograms a name never
+means two things: ``device_put`` is the prefetcher's phase (one value a
+batch, the same for all its frames), and the batcher's ``dequeue`` and
+``batch`` phases stay out of the histograms, where those names are a
+frame's hop stages.
+
 Because stages are CONSECUTIVE differences of one record's timeline, the
 per-stage means over a set of records sum EXACTLY to the mean of the
 ``e2e`` pseudo-stage (src → step done) over the same records — that is
@@ -36,10 +57,9 @@ the telescoping: the next present boundary's stage absorbs the gap.
 
 from __future__ import annotations
 
-import time
 from typing import Optional
 
-from psana_ray_tpu.obs.tracing import TRACE_KEY
+from psana_ray_tpu.obs.tracing import TRACE_KEY, TRACER
 from psana_ray_tpu.utils.metrics import StageTimes  # noqa: F401  (re-export)
 
 # Hop (boundary) names, in pipeline order.
@@ -73,6 +93,53 @@ STAGES = (
 )
 
 
+# Loop phases (see the module docstring). The first three run once per
+# turn of ``batches_from_queue``; the rest once per batch.
+PHASE_QUEUE_WAIT = "queue_wait"  # blocked in the transport's pop
+PHASE_DEQUEUE = STAGE_DEQUEUE  # EOS tally, decode, stamps
+PHASE_BATCH = STAGE_BATCH  # the copy into the batch arena
+PHASE_DEVICE_PUT = STAGE_DEVICE_PUT  # host -> device placement
+PHASE_PREFETCH_FULL = "prefetch_full"  # staged batch waits for room
+PHASE_INFEED_WAIT = "infeed_wait"  # serving thread waits for a staged batch
+PHASE_LAUNCH = "launch"  # the step call returns (async dispatch)
+PHASE_DEVICE_WAIT = "device_wait"  # host blocks on the step's result
+PHASE_FOLD = "fold"  # device rows -> per-event results
+PHASE_APPEND = "append"  # sink append + cursor
+PHASE_GC = "gc"  # a generation-2 collection, inside whatever phase was open
+
+PHASES = (
+    PHASE_QUEUE_WAIT,
+    PHASE_DEQUEUE,
+    PHASE_BATCH,
+    PHASE_DEVICE_PUT,
+    PHASE_PREFETCH_FULL,
+    PHASE_INFEED_WAIT,
+    PHASE_LAUNCH,
+    PHASE_DEVICE_WAIT,
+    PHASE_FOLD,
+    PHASE_APPEND,
+    PHASE_GC,
+)
+
+# The hops a frame has crossed by the time its batch is emitted: what the
+# per-frame fold walks (everything later is the same for a whole batch).
+_FRAME_HOPS = (HOP_SRC, HOP_ENQ, HOP_DEQ, HOP_PUSH, HOP_BATCH)
+
+
+def _legs(hops: dict, boundaries=HOPS):
+    """``(stage, start, end)`` for each pair of consecutive PRESENT
+    boundaries of one record: THE telescoping walk. A missing boundary is
+    skipped, and the stage ending at the next present one absorbs the gap."""
+    prev: Optional[float] = None
+    for i, hop in enumerate(boundaries):
+        t = hops.get(hop)
+        if t is None:
+            continue
+        if prev is not None:
+            yield STAGES[i - 1], prev, t
+        prev = t
+
+
 def observe_record_stages(
     stages: StageTimes, hops: dict, t_end: float
 ) -> None:
@@ -86,31 +153,64 @@ def observe_record_stages(
     exemplar — the retained "which frame is in the bad bucket" link that
     ``trace_merge --exemplar`` resolves (ISSUE 13)."""
     exemplar = hops.get(TRACE_KEY)  # the sampled trace id, when traced
-    prev: Optional[float] = None
-    for i, hop in enumerate(HOPS):
-        t = hops.get(hop)
-        if t is None:
-            continue
-        if prev is not None:
-            # STAGES[i-1] is the stage ENDING at this boundary; when an
-            # earlier boundary was missing it absorbs the gap (telescoping)
-            stages.observe(STAGES[i - 1], t - prev, exemplar=exemplar)
-        prev = t
-    if prev is not None:
-        stages.observe(STAGE_DISPATCH, t_end - prev, exemplar=exemplar)
+    for stage, start, end in _legs(hops):
+        stages.observe(stage, end - start, exemplar=exemplar)
+    last = next((hops[h] for h in reversed(HOPS) if hops.get(h) is not None), None)
+    if last is not None:
+        stages.observe(STAGE_DISPATCH, t_end - last, exemplar=exemplar)
         t0 = hops.get(HOP_SRC)
         if t0 is not None:
             stages.observe(STAGE_E2E, t_end - t0, exemplar=exemplar)
 
 
-def observe_batch_stages(stages: StageTimes, batch, t_end: Optional[float] = None) -> None:
-    """Per-record stage decomposition for a whole batch (its ``hops``
-    list carries one stamp dict per timed real record). Near-zero cost on
-    untimed streams: ``batch.hops`` is None unless a producer stamped the
-    records."""
-    hops_list = getattr(batch, "hops", None)
+def observe_frame_stages(stages: StageTimes, batch, tracer=None) -> None:
+    """The per-FRAME half of a batch's stage timing, from stamps that
+    exist once the batch is emitted (src .. batch): ``enqueue``,
+    ``queue_dwell``, ``dequeue`` and ``batch`` of every timed record, the
+    same telescoping walk as :func:`observe_record_stages`. A traced
+    record (``TRACE_KEY`` in its hops) also gets one span per stage in
+    the trace spool, carrying the id of the batch it joined — all of a
+    batch's spans under one lock. The serving loops call this AFTER
+    launching the batch's step and BEFORE blocking on it, where the host
+    would only wait. Untimed streams: ``batch.hops`` is None, no work."""
+    hops_list = batch.hops
     if not hops_list:
         return
-    t_end = time.monotonic() if t_end is None else t_end
+    tr = TRACER if tracer is None else tracer
+    rows = [] if tr.enabled else None
+    batch_id = batch.batch_id
     for hops in hops_list:
-        observe_record_stages(stages, hops, t_end)
+        tid = hops.get(TRACE_KEY)
+        for stage, start, end in _legs(hops, _FRAME_HOPS):
+            stages.observe(stage, end - start, exemplar=tid)
+            # the enqueue leg is the PRODUCER's span (its sender emitted
+            # it; in-process transports share the hops dict)
+            if rows is not None and tid is not None and stage != STAGE_ENQUEUE:
+                rows.append((tid, stage, start, end, batch_id, 0))
+    if rows:
+        tr.extend(rows)
+
+
+def observe_batch_done(stages: StageTimes, batch, t_end: float) -> None:
+    """The per-BATCH half, once the batch's result is out (step done for
+    ``InfeedPipeline``, append done for ``SfxPipeline``): ``dispatch``
+    (staged on the device, ``batch.t_staged``, or else emitted ->
+    ``t_end``: one value for all its frames, observed once) and ``e2e`` —
+    per frame for a batch whose frames carry stamps (from ``src``, or
+    from ``enq`` behind a process hop), else
+    ONCE, for the batch's oldest frame (``batch.t_enq``, the transport's
+    own enqueue stamp: the worst case of the batch), never both."""
+    hops_list = batch.hops
+    if not hops_list:
+        if batch.t_enq:
+            stages.observe(STAGE_E2E, t_end - batch.t_enq)
+        return
+    last = batch.t_staged or hops_list[0].get(HOP_BATCH)
+    if last is not None:
+        stages.observe(STAGE_DISPATCH, t_end - last)
+    for hops in hops_list:
+        t0 = hops.get(HOP_SRC)
+        if t0 is None:
+            t0 = hops.get(HOP_ENQ)
+        if t0 is not None:
+            stages.observe(STAGE_E2E, t_end - t0, exemplar=hops.get(TRACE_KEY))

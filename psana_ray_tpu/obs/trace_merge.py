@@ -128,15 +128,24 @@ def merge(paths: List[str], only_trace: Optional[int] = None) -> dict:
 
     ``only_trace`` filters to ONE trace id — the ``--exemplar`` lookup
     (ISSUE 13): a latency-histogram bucket's retained exemplar resolves
-    to just that frame's cross-host timeline."""
+    to just that frame's cross-host timeline, down to the phases of the
+    batch it joined."""
     files = _expand(paths)
     if not files:
         raise FileNotFoundError(f"no trace spools found under {paths!r}")
     spools = [load_spool(p) for p in files]
     if only_trace is not None:
         for spool in spools:
+            # the frame's own spans, and the loop-phase spans (launch,
+            # device_wait, ...) of the batch it joined: its spans name
+            # the batch ("j") and the batch's spans carry that id
+            joined = {
+                s["j"] for s in spool["spans"]
+                if s.get("id") == only_trace and "j" in s
+            }
             spool["spans"] = [
-                s for s in spool["spans"] if s.get("id") == only_trace
+                s for s in spool["spans"]
+                if s.get("id") == only_trace or ("k" in s and s.get("id") in joined)
             ]
             spool["instants"] = [
                 i for i in spool["instants"] if i.get("id") == only_trace

@@ -25,27 +25,31 @@ Three pieces:
   three processes on one timeline.
 - ``python -m psana_ray_tpu.obs.trace_merge`` reads the spools and emits
   Chrome trace-event JSON loadable in Perfetto / TensorBoard, one track
-  per process, frame spans linked by trace id. The device-side
-  ``stage.*`` annotations (:func:`psana_ray_tpu.utils.trace.
-  annotate_stage`) use the same stage vocabulary, so a jax.profiler
-  capture of the same run lines up against the host spans.
+  per process, frame spans linked by trace id. The serving loops'
+  phases (:func:`psana_ray_tpu.utils.trace.phase`) land in the same
+  spool as ``stage.<name>`` spans, one per batch, under the batch's id;
+  a frame's own spans name the batch it joined (``j``).
 
 Everything here is pure stdlib (no numpy, no jax) so every process —
-including the queue server — can afford the import. Span recording for
-sampled frames is one lock + one small dict append; the spool is flushed
-in the background of normal operation (every ``FLUSH_EVERY`` spans and at
-process exit), never per span.
+including the queue server — can afford the import. Recording a span is
+one lock + one tuple appended to a bounded in-memory buffer: nothing is
+serialized or written on the emitting thread until ``flush()`` or
+``close()`` (process exit, a flight-recorder dump), so the instrument
+does not cause the idle it bills (choosing-metrics guide, section 4:
+"keep spans in memory and write them out when the benchmark ends").
 """
 
 from __future__ import annotations
 
 import atexit
 import dataclasses
+import gc
 import itertools
 import json
 import os
 import socket
 import struct
+import sys
 import threading
 import time
 from typing import Any, Dict, Optional
@@ -59,9 +63,9 @@ __all__ = [
     "SPAN_RELAY",
     "add_trace_args",
     "configure_from_args",
-    "emit_batch_spans",
     "exchange_anchors",
     "obs_status_suffix",
+    "profiler_annotation",
 ]
 
 # Reserved key in a record's ``hops`` dict carrying the trace id through
@@ -74,7 +78,19 @@ TRACE_KEY = "trace_id"
 SPAN_PRODUCE = "produce"  # instant: source read done (frame is born)
 SPAN_RELAY = "relay"  # queue server: response serialization + send
 
+GC_SPAN = "stage.gc"  # a generation-2 collection (obs.stages.PHASE_GC)
+
 _FLAG_SAMPLED = 0x01
+
+
+def profiler_annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` — a host region
+    on the profiler's own timeline — or None in a process that never
+    imported jax (no profile can be running there, and this module stays
+    importable by the JAX-free producers and queue servers)."""
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.profiler.TraceAnnotation(name)
+
 
 # trace_id:u64, origin_pid:u32, flags:u8, origin_host:12s (utf-8, padded)
 _CTX_WIRE = struct.Struct("<QIB12s")
@@ -117,7 +133,9 @@ class TraceContext:
 # Spool record tags (one JSON object per line):
 #   m = meta (process identity, sample config)   a = clock anchor
 #   p = peer anchor (tcp opcode 'A' exchange)    s = span   i = instant
-FLUSH_EVERY = 128
+# A span line is {"t":"s","id","n","a","b"} plus, on a frame's span, "j"
+# (the id of the batch it joined) or, on a loop phase's span ("n" is
+# "stage.<phase>", "id" the batch's), "k" (frames in the batch or turn).
 
 
 class Tracer:
@@ -130,7 +148,9 @@ class Tracer:
 
     def __init__(self):
         self.enabled = False
-        self._lock = threading.Lock()
+        # reentrant: an allocation under the lock can start a full
+        # collection, whose hook (_on_gc) records its span from inside
+        self._lock = threading.RLock()
         self._every = 0  # sample 1 frame in N; 0 = off
         # frame ticker: itertools.count.__next__ is atomic in CPython, so
         # concurrent producer shard threads get UNIQUE frame numbers (and
@@ -144,12 +164,18 @@ class Tracer:
         self._process = ""
         self._path: Optional[str] = None
         self._f = None
-        self._buf: list = []
+        # spans and instants as tuples, serialized at flush()/close()
+        self._buf: list = []  # guarded-by: _lock
         self._spans = 0
         self._drops = 0
         self._max_spans = 0
-        self._by_name: Dict[str, int] = {}
         self._atexit_registered = False
+        # full (generation-2) collections while tracing is on: a stop-
+        # the-world pause inside whatever phase a serving thread had open
+        self._gc_seconds = 0.0
+        self._gc_count = 0
+        self._gc_t0 = 0.0
+        self._gc_ann = None
 
     # -- configuration ----------------------------------------------------
     def configure(
@@ -161,9 +187,10 @@ class Tracer:
     ) -> "Tracer":
         """Enable tracing: sample 1 frame in ``sample_every`` (1 = every
         frame) and spool spans to ``spool_dir``. Reconfiguring closes the
-        previous spool first. ``max_spans`` bounds the spool — beyond it
-        spans are dropped and counted (``spans_dropped``), never blocking
-        the pipeline."""
+        previous spool first. ``max_spans`` bounds the spool, and with it
+        the spans held in memory between flushes — beyond it spans are
+        dropped and counted (``spans_dropped``), never blocking the
+        pipeline."""
         if sample_every <= 0:
             raise ValueError("sample_every must be >= 1 (frames per sample)")
         with self._lock:
@@ -176,7 +203,6 @@ class Tracer:
             self._count = 0
             self._spans = 0
             self._drops = 0
-            self._by_name = {}
             self._max_spans = max_spans
             # unique-across-processes id space: pid in the top bits, a
             # wall-clock sub-second salt so quick restarts don't collide
@@ -186,15 +212,16 @@ class Tracer:
                 spool_dir, f"{process}-{self._host}-{self._pid}.trace.jsonl"
             )
             self._f = open(self._path, "w", encoding="utf-8")
-            self._buf = [
-                self._line(
-                    t="m", process=process, host=self._host, pid=self._pid,
-                    every=self._every, start_wall=time.time(),
-                    start_mono=time.monotonic(),
-                )
-            ]
-            self._anchor_locked()
-            self._flush_locked()
+            self._buf = []
+            self._f.write(self._line(
+                t="m", process=process, host=self._host, pid=self._pid,
+                every=self._every, start_wall=time.time(),
+                start_mono=time.monotonic(),
+            ) + "\n")
+            self._flush_locked()  # the first clock anchor
+            self._gc_seconds, self._gc_count = 0.0, 0
+            if self._on_gc not in gc.callbacks:
+                gc.callbacks.append(self._on_gc)
             self.enabled = True
             if not self._atexit_registered:
                 self._atexit_registered = True
@@ -236,33 +263,69 @@ class Tracer:
             origin_pid=self._pid,
         )
 
-    # -- span sinks (sampled frames only) ---------------------------------
-    def span(self, trace_id: int, name: str, t0: float, t1: float) -> None:
+    # -- span sinks (sampled frames and loop phases) ----------------------
+    def span(self, trace_id: int, name: str, t0: float, t1: float,
+             frames: int = 0) -> None:
         """One completed span ``[t0, t1]`` in THIS process's monotonic
-        domain (the merge tool aligns domains via the spooled anchors)."""
-        if not self.enabled:
-            return
-        self._emit(name, self._line(t="s", id=trace_id, n=name, a=t0, b=t1))
+        domain (the merge tool aligns domains via the spooled anchors).
+        ``frames`` marks a loop phase's span (``trace_id`` is then the
+        batch's id): how many frames the batch or loop turn held."""
+        if self.enabled:
+            self._keep((trace_id, name, t0, t1, None, frames))
 
-    def instant(self, trace_id: int, name: str, t: float) -> None:
-        """A zero-duration marker (e.g. ``produce`` at source-read done)."""
-        if not self.enabled:
-            return
-        self._emit(name, self._line(t="i", id=trace_id, n=name, a=t))
-
-    def _emit(self, name: str, line: str) -> None:
-        """THE bounded-spool sink: cap accounting, per-name counts, and
-        the every-``FLUSH_EVERY`` anchor+flush policy live here once."""
+    def _keep(self, row: tuple) -> None:
+        """THE bounded sink of single rows: kept in memory, or dropped
+        and counted beyond ``max_spans``."""
         with self._lock:
             if self._spans >= self._max_spans:
                 self._drops += 1
                 return
             self._spans += 1
-            self._by_name[name] = self._by_name.get(name, 0) + 1
-            self._buf.append(line)
-            if len(self._buf) >= FLUSH_EVERY:
-                self._anchor_locked()
-                self._flush_locked()
+            self._buf.append(row)
+
+    def extend(self, rows) -> None:
+        """A batch's worth of frame spans, ``(trace_id, name, t0, t1,
+        batch_id, 0)`` each, under ONE lock acquisition; what does not
+        fit the bound is dropped and counted."""
+        if not self.enabled:
+            return
+        with self._lock:
+            room = self._max_spans - self._spans
+            if room < len(rows):
+                self._drops += len(rows) - max(room, 0)
+                rows = rows[: max(room, 0)]
+            self._spans += len(rows)
+            self._buf.extend(rows)
+
+    def instant(self, trace_id: int, name: str, t: float) -> None:
+        """A zero-duration marker (e.g. ``produce`` at source-read done)."""
+        if self.enabled:
+            self._keep((trace_id, name, t))
+
+    def _on_gc(self, when: str, info: dict) -> None:
+        """``gc.callbacks`` hook, installed while tracing is on: a full
+        collection becomes a ``stage.gc`` region on the profiler's
+        timeline and a span in the spool, and counts into
+        ``gc_seconds_total``. Younger generations (sub-millisecond, many
+        per second) pass with one comparison."""
+        if info.get("generation") != 2:
+            return
+        if when == "start":
+            self._gc_t0 = time.monotonic()
+            ann = self._gc_ann = profiler_annotation(GC_SPAN)
+            if ann is not None:
+                ann.__enter__()
+            return
+        t1 = time.monotonic()
+        ann, self._gc_ann = self._gc_ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        t0, self._gc_t0 = self._gc_t0, 0.0
+        if t0:
+            with self._lock:
+                self._gc_seconds += t1 - t0
+                self._gc_count += 1
+            self.span(0, GC_SPAN, t0, t1)
 
     # -- clock alignment --------------------------------------------------
     def write_anchor(self) -> None:
@@ -271,7 +334,7 @@ class Tracer:
         if not self.enabled:
             return
         with self._lock:
-            self._anchor_locked()
+            self._write_locked(self._anchor_line())
 
     def record_peer_anchor(self, exchange: dict) -> None:
         """Record one ping/anchor exchange with the queue server (tcp
@@ -281,11 +344,16 @@ class Tracer:
         if not self.enabled:
             return
         with self._lock:
-            self._buf.append(self._line(t="p", **exchange))
+            self._write_locked(self._line(t="p", **exchange))
 
-    def _anchor_locked(self) -> None:
-        # guarded-by-caller: _lock
-        self._buf.append(self._line(t="a", wall=time.time(), mono=time.monotonic()))
+    def _anchor_line(self) -> str:
+        return self._line(t="a", wall=time.time(), mono=time.monotonic())
+
+    def _write_locked(self, line: str) -> None:
+        # guarded-by-caller: _lock. Control-plane lines (anchors, peer
+        # exchanges: a handful per run) go straight to the file.
+        if self._f is not None:
+            self._f.write(line + "\n")
 
     @staticmethod
     def _line(**kw) -> str:
@@ -293,17 +361,32 @@ class Tracer:
 
     # -- lifecycle --------------------------------------------------------
     def flush(self) -> None:
+        """Serialize what the buffer holds to the spool, with a clock
+        anchor. Off the emitting threads: ``close()``, process exit, a
+        flight-recorder dump."""
         with self._lock:
             self._flush_locked()
 
     def _flush_locked(self) -> None:
         # guarded-by-caller: _lock
-        if self._f is None or not self._buf:
-            self._buf = self._buf if self._f is not None else []
+        if self._f is None:
+            self._buf = []
             return
-        self._f.write("\n".join(self._buf) + "\n")
+        rows, self._buf = self._buf, []
+        out = [self._anchor_line()]
+        for row in rows:
+            if len(row) == 3:
+                out.append(self._line(t="i", id=row[0], n=row[1], a=row[2]))
+                continue
+            tid, name, t0, t1, joined, frames = row
+            rec = {"t": "s", "id": tid, "n": name, "a": t0, "b": t1}
+            if joined is not None:
+                rec["j"] = joined
+            if frames:
+                rec["k"] = frames
+            out.append(self._line(**rec))
+        self._f.write("\n".join(out) + "\n")
         self._f.flush()
-        self._buf = []
 
     def close(self) -> None:
         """Flush + close the spool and disable. Safe to call repeatedly
@@ -314,7 +397,6 @@ class Tracer:
     def _close_locked(self) -> None:
         # guarded-by-caller: _lock
         if self._f is not None:
-            self._anchor_locked()
             self._flush_locked()
             try:
                 self._f.close()
@@ -323,6 +405,8 @@ class Tracer:
         self._f = None
         self.enabled = False
         self._every = 0
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
 
     # -- observability of the observer ------------------------------------
     def snapshot(self) -> dict:
@@ -334,9 +418,9 @@ class Tracer:
                 "frames_seen_total": self._count,
                 "spans_total": self._spans,
                 "spans_dropped_total": self._drops,
+                "gc_collections_total": self._gc_count,
+                "gc_seconds_total": round(self._gc_seconds, 6),
             }
-            if self._by_name:
-                out["spans_by_name"] = dict(self._by_name)
         return out
 
     def status_suffix(self, flight=None) -> str:
@@ -358,40 +442,6 @@ class Tracer:
 
 #: The process-global tracer every CLI configures (tests build their own).
 TRACER = Tracer()
-
-
-def emit_batch_spans(batch, t_end: float, tracer: Optional[Tracer] = None) -> None:
-    """Consumer-side spans for one batch: each traced record's hop stamps
-    (``TRACE_KEY`` marks the traced ones) become per-stage spans ending at
-    ``t_end`` (step completion) — the same telescoping walk as
-    :func:`psana_ray_tpu.obs.stages.observe_record_stages`, so span
-    boundaries and histogram boundaries agree by construction. Near-zero
-    cost on untraced streams (``batch.hops`` is None)."""
-    tr = TRACER if tracer is None else tracer
-    if not tr.enabled:
-        return
-    hops_list = getattr(batch, "hops", None)
-    if not hops_list:
-        return
-    from psana_ray_tpu.obs.stages import HOPS, STAGE_DISPATCH, STAGE_ENQUEUE, STAGES
-
-    for hops in hops_list:
-        tid = hops.get(TRACE_KEY)
-        if tid is None:
-            continue
-        prev = None
-        for i, hop in enumerate(HOPS):
-            t = hops.get(hop)
-            if t is None:
-                continue
-            # skip the enqueue leg: the PRODUCER's _Sender.flush already
-            # emitted it (in-process transports share the hops dict, so
-            # replaying src->enq here would double the span)
-            if prev is not None and STAGES[i - 1] != STAGE_ENQUEUE:
-                tr.span(tid, STAGES[i - 1], prev, t)
-            prev = t
-        if prev is not None:
-            tr.span(tid, STAGE_DISPATCH, prev, t_end)
 
 
 def exchange_anchors(queue, n: int = 3, tracer: Optional[Tracer] = None) -> int:

@@ -144,6 +144,7 @@ def fused_calibrate(
         out_shape=jax.ShapeDtypeStruct((b * p, h, w), out_dtype or raw.dtype),
         scratch_shapes=[pltpu.SMEM((2,), raw.dtype)],
         interpret=interpret,
+        name="fused_calibrate",  # the kernel's name in a device trace
     )(flat_raw, pedestal, gain, mask)
     out = out.reshape(b, p, h, w)
     return out[0] if squeeze else out

@@ -80,6 +80,13 @@ class FrameRecord:
     # zero cost for streams nobody is timing. Cross-process, the wall-clock
     # ``timestamp`` field is the enqueue-side stamp consumers fall back to.
     hops: Optional[dict] = dataclasses.field(default=None, repr=False)
+    # The instant the transport accepted this record, on the HOST's
+    # monotonic clock (never on the wire; 0.0 = unknown). A transport
+    # whose queue lives in memory shared by the host's processes stamps
+    # each slot and hands the stamp back with the pop (shm ring), so
+    # ``queue_dwell`` and an enqueue -> result ``e2e`` exist on the far
+    # side of a process hop, where ``hops`` cannot travel.
+    t_enq: float = dataclasses.field(default=0.0, repr=False)
     # Host-local buffer ownership (never on the wire): when ``panels`` is
     # a zero-copy view into pooled/transport memory, ``lease`` keeps that
     # memory checked out (utils.bufpool.Lease or a transport slot lease).
@@ -337,7 +344,7 @@ def mark_hop(rec, hop: str, t: Optional[float] = None) -> None:
     The observability layer's envelope hook: producers stamp source-read
     and enqueue, the batcher stamps dequeue/assembly, the prefetcher
     stamps device placement, and :func:`psana_ray_tpu.obs.stages.
-    observe_batch_stages` turns consecutive stamps into per-stage latency
+    observe_frame_stages` turns consecutive stamps into per-stage latency
     histograms. No-op on non-frame items (EOS markers are not timed);
     safe on the frozen dataclass (the dict is attached once via
     ``object.__setattr__``, then mutated in place)."""

@@ -17,8 +17,17 @@ final ``(yx, score, n)`` tuples come back to the host, where panel-local
 coordinates fold into the CrystFEL-style unassembled layout and append to
 the CXI file. The serving loop keeps ONE batch in flight: batch N runs
 on device while batch N-1's host fold + HDF5 append proceed (JAX's async
-dispatch — blocking only happens at the ``np.asarray`` drain), so host
+dispatch — blocking only happens at the ``device_get`` drain), so host
 write time hides under device compute instead of serializing with it.
+
+The loop's thread is always inside one phase (``utils.trace.phase``; the
+vocabulary is ``obs.stages.PHASES``): the batcher's ``queue_wait`` /
+``dequeue`` / ``batch``, then ``launch`` (the jit call, with its implicit
+host-to-device copy of the frames), and for the batch before it
+``device_wait`` (the ``device_get`` drain), ``fold`` (panel rows -> per-
+event peak sets) and ``append`` (``writer.append`` + cursor). Each is a
+``stage.<name>`` region on the profiler's timeline, one observation per
+batch in ``metrics.stages``, and one span in the trace spool.
 
 Coordinate convention (``peakYPosRaw``/``peakXPosRaw``): the cheetah-style
 vertically stacked panel layout — ``y_raw = panel * H + y_panel``,
@@ -38,9 +47,19 @@ from __future__ import annotations
 import dataclasses
 import math
 import time
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import numpy as np
+
+from psana_ray_tpu.obs.stages import (
+    PHASE_APPEND,
+    PHASE_DEVICE_WAIT,
+    PHASE_FOLD,
+    PHASE_LAUNCH,
+    observe_batch_done,
+    observe_frame_stages,
+)
+from psana_ray_tpu.utils.trace import phase
 
 
 @dataclasses.dataclass
@@ -207,28 +226,46 @@ class SfxPipeline:
         """``[B, P, H, W]`` raw-or-calibrated frames -> panel-row peak
         tuples ``(yx [B*P, K, 2], score [B*P, K], n [B*P])``. Pure in its
         arguments (``calib`` is the ``(pedestal, gain, mask)`` triple or
-        None), so it also runs per shard under ``shard_map``."""
+        None), so it also runs per shard under ``shard_map``.
+
+        The three parts run under named scopes (``calib``, ``peaknet``,
+        ``find_peaks``), by which a device trace finds their ops again
+        whatever the fusions are numbered. Each part is a NESTED jit,
+        which XLA inlines — the compiled program is the same — because
+        of what its exporter keeps: the name stack of a call site reaches
+        every op inlined from the call, but the stack on the enclosing
+        function's own ops is lost unless locations carry full tracebacks,
+        which ``configure_compile_cache`` turns off so that Pallas kernels
+        key alike from every entry point (looked at on the v5e, PR 24:
+        the profile's own HLO said ``conv_general_dilated``, no more)."""
+        import jax
         import jax.numpy as jnp
 
         from psana_ray_tpu.models import panels_to_nhwc
         from psana_ray_tpu.models.peaks import find_peaks
 
+        cfg = self.cfg
         x = frames
         if calib is not None:
-            from psana_ray_tpu.ops import fused_calibrate
+            from psana_ray_tpu.ops import fused_calibrate  # itself a jit
 
             ped, gain, mask = calib
-            x = fused_calibrate(
-                x, ped, gain, mask,
-                threshold=self.cfg.calib_threshold, out_dtype=jnp.bfloat16,
-            )
-        logits = self._model.apply(variables, panels_to_nhwc(x, mode="batch"))
-        return find_peaks(
-            logits,
-            max_peaks=self.cfg.max_peaks,
-            threshold=self.cfg.peak_threshold,
-            min_distance=self.cfg.min_distance,
-        )
+            with jax.named_scope("calib"):
+                x = fused_calibrate(
+                    x, ped, gain, mask,
+                    threshold=cfg.calib_threshold, out_dtype=jnp.bfloat16,
+                )
+        with jax.named_scope("peaknet"):
+            logits = jax.jit(self._model.apply)(variables, panels_to_nhwc(x, mode="batch"))
+        with jax.named_scope("find_peaks"):
+            return jax.jit(
+                lambda lg: find_peaks(
+                    lg,
+                    max_peaks=cfg.max_peaks,
+                    threshold=cfg.peak_threshold,
+                    min_distance=cfg.min_distance,
+                )
+            )(logits)
 
     def _step(self, frames):
         """The compiled step on this pipeline's own weights and constants."""
@@ -243,28 +280,66 @@ class SfxPipeline:
         the device program for batch N with the host-side peak fold and
         HDF5 append for batch N-1 (the serial loop leaves the chip idle
         for the whole host phase). :meth:`run` uses exactly this one-deep
-        schedule; results are bit-identical to the serial path."""
-        return self._step(batch.frames), batch
+        schedule; results are bit-identical to the serial path.
 
-    def drain(self, pending, cursor=None) -> int:
+        The ``launch`` phase; a timed batch's per-frame stamps are folded
+        right after it, while the device works (``obs.stages.
+        observe_frame_stages``)."""
+        with phase(PHASE_LAUNCH, self.metrics, batch.batch_id, batch.num_valid):
+            out = self._step(batch.frames)
+        observe_frame_stages(self.metrics.stages, batch)
+        return out, batch
+
+    def drain(self, pending, cursor=None,
+              on_appended: Optional[Callable[[int], None]] = None) -> int:
         """Block on a :meth:`dispatch` handle and append its REAL events
         to the CXI file; returns the number of events appended. Padding
         rows never reach the file; the cursor (if given) advances only
-        after an event is written."""
-        from psana_ray_tpu.cxi import PeakSet
+        after an event is written. ``on_appended(n)`` (optional) runs
+        last inside the ``append`` phase: :meth:`run` saves the cursor
+        there.
+
+        Three phases: ``device_wait``, ``fold``, ``append``. The batch's
+        ``e2e`` ends here, at append-done (``obs.stages.
+        observe_batch_done``)."""
+        import jax
 
         out, batch = pending
-        b, p, h, _ = batch.frames.shape
-        t0 = time.monotonic()
-        yx, score, n = (np.asarray(a) for a in out)
+        _, p, h, _ = batch.frames.shape
+        metrics = self.metrics
+        mark = (metrics, batch.batch_id, batch.num_valid)
+        with phase(PHASE_DEVICE_WAIT, *mark) as ph:
+            # ONE readback of the three results (``device_get`` starts
+            # every copy, then waits): each blocking round trip to the
+            # device pays the runtime's wake-up latency, which differs by
+            # half a millisecond with the host's state, and three in a row
+            # made a paced frame's latency follow that state three times
+            yx, score, n = jax.device_get(out)
         # device-wait latency: with one batch in flight this is the step
         # time NOT hidden behind the host fold/append of the previous batch
-        self.metrics.observe_batch(
-            int(np.sum(batch.valid)), time.monotonic() - t0,
+        metrics.observe_batch(
+            int(np.sum(batch.valid)), ph.t1 - ph.t0,
             nbytes=int(getattr(batch.frames, "nbytes", 0)),
         )
+        with phase(PHASE_FOLD, *mark):
+            sets = self._fold(batch, yx, score, n, p, h)
+        with phase(PHASE_APPEND, *mark) as ph:
+            self.writer.append(sets)
+            if cursor is not None:
+                for s in sets:  # after the append: watermark never runs ahead
+                    cursor.advance(s.shard_rank, s.event_idx)
+            self.n_events += len(sets)
+            if on_appended is not None:
+                on_appended(len(sets))
+        observe_batch_done(metrics.stages, batch, ph.t1)
+        return len(sets)
+
+    def _fold(self, batch, yx, score, n, p: int, h: int) -> list:
+        """Panel rows -> one raw-coordinate :class:`PeakSet` per REAL event."""
+        from psana_ray_tpu.cxi import PeakSet
+
         sets = []
-        for i in range(b):
+        for i in range(len(batch.frames)):
             if not batch.valid[i]:
                 continue
             ys, xs, ss = [], [], []
@@ -287,12 +362,7 @@ class SfxPipeline:
                 )
             )
             self.n_peaks += len(ss)
-        self.writer.append(sets)
-        if cursor is not None:
-            for s in sets:  # after the append: watermark never runs ahead
-                cursor.advance(s.shard_rank, s.event_idx)
-        self.n_events += len(sets)
-        return len(sets)
+        return sets
 
     def process_batch(self, batch, cursor=None) -> int:
         """Serial convenience: :meth:`dispatch` + :meth:`drain` in one
@@ -326,27 +396,27 @@ class SfxPipeline:
 
         start = self.n_events
 
+        def _save_cursor(wrote: int) -> None:
+            if (self.n_events // cursor_save_every) != (
+                (self.n_events - wrote) // cursor_save_every
+            ):
+                cursor.save(cursor_path)
+
+        saving = cursor is not None and cursor_path and cursor_save_every > 0
+        on_appended = _save_cursor if saving else None
+
         def _drain_one(pending) -> bool:
             """Drain + cursor bookkeeping; True = hit the max_events bound."""
-            wrote = self.drain(pending, cursor=cursor)
-            if cursor is not None and cursor_path and cursor_save_every > 0:
-                if (self.n_events // cursor_save_every) != (
-                    (self.n_events - wrote) // cursor_save_every
-                ):
-                    cursor.save(cursor_path)
+            self.drain(pending, cursor=cursor, on_appended=on_appended)
             return max_events is not None and self.n_events - start >= max_events
 
         pending = None
         try:
             for batch in batches_from_queue(
                 queue, self.cfg.batch_size, poll_interval_s=poll_interval_s,
-                stop=stop, control=drain_control,
+                stop=stop, control=drain_control, metrics=self.metrics,
             ):
                 nxt = self.dispatch(batch)
-                if batch.hops:  # traced records -> per-stage spans
-                    from psana_ray_tpu.obs.tracing import emit_batch_spans
-
-                    emit_batch_spans(batch, time.monotonic())
                 # clear ``pending`` BEFORE draining it: if drain raises
                 # after its writer.append, the finally below must not
                 # drain the same handle again (duplicate CXI rows)

@@ -146,6 +146,7 @@ def _load_lib() -> ctypes.CDLL:
             ctypes.c_void_p,
             ctypes.POINTER(ctypes.c_void_p),
             ctypes.POINTER(ctypes.c_uint64),
+            ctypes.POINTER(ctypes.c_uint64),
         ]
         lib.shmring_release.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
         lib.shmring_is_closed.restype = ctypes.c_int
@@ -153,7 +154,7 @@ def _load_lib() -> ctypes.CDLL:
         lib.shmring_set_stall_timeout.argtypes = [ctypes.c_void_p, ctypes.c_uint64]
         lib.shmring_begin_drain.argtypes = [ctypes.c_void_p]
         lib.shmring_close.argtypes = [ctypes.c_void_p]
-        lib.shmring_stats.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64 * 4)]
+        lib.shmring_stats.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_uint64 * 7)]
         lib.shmring_free.argtypes = [ctypes.c_void_p, ctypes.c_int]
         _lib = lib
         return _lib
@@ -165,6 +166,16 @@ def native_available() -> bool:
         return True
     except RuntimeError:
         return False
+
+
+def _stamped(item: Any, enq_ns: int) -> Any:
+    """Hand the slot's enqueue stamp (CLOCK_MONOTONIC ns: the clock of
+    ``time.monotonic()``, one for every process of the host) to a frame
+    record as ``t_enq`` seconds — where the consumer's side of the hop
+    starts the frame's timeline (``batches_from_queue``)."""
+    if isinstance(item, FrameRecord):
+        object.__setattr__(item, "t_enq", enq_ns * 1e-9)
+    return item
 
 
 class _SlotLease:
@@ -356,6 +367,7 @@ class ShmRingBuffer:
         while True:
             ptr = ctypes.c_void_p()
             ticket = ctypes.c_uint64()
+            enq_ns = ctypes.c_uint64()
             # held across acquire -> decode -> release: teardown must not
             # munmap the slot while the decode copy (or the zero-copy view
             # hand-off) reads it — the same UAF class as the PR 1 scrape
@@ -363,7 +375,9 @@ class ShmRingBuffer:
             # via GC inside decode's allocations) re-enters safely.
             with self._handle_lock:
                 h = self._live_handle()
-                n = self._lib.shmring_acquire(h, ctypes.byref(ptr), ctypes.byref(ticket))
+                n = self._lib.shmring_acquire(
+                    h, ctypes.byref(ptr), ctypes.byref(ticket), ctypes.byref(enq_ns)
+                )
                 if n == -1:
                     return EMPTY
                 if n == -2:
@@ -377,13 +391,14 @@ class ShmRingBuffer:
                     continue
                 if not view:
                     try:
-                        return self._decode(mv)  # copies panels out of the slot
+                        # copies panels out of the slot
+                        return _stamped(self._decode(mv), enq_ns.value)
                     finally:
                         self._lib.shmring_release(h, ticket)
                 self._slot_leases += 1
                 lease = _SlotLease(self, int(ticket.value))
                 try:
-                    return decode_payload(mv, lease=lease)
+                    return _stamped(decode_payload(mv, lease=lease), enq_ns.value)
                 except BaseException:
                     lease.release()
                     raise
@@ -480,7 +495,11 @@ class ShmRingBuffer:
                 self._lib.shmring_begin_drain(self._h)
 
     def stats(self) -> dict:
-        buf = (ctypes.c_uint64 * 4)()
+        """Depth and counters, plus the queue residency (enqueue stamp ->
+        pop) of every item popped so far, by any handle of the ring:
+        ``dwell_ms_mean``/``dwell_ms_max`` over ``dwell_count`` items —
+        ``queue_dwell`` for every frame, with no tracing on."""
+        buf = (ctypes.c_uint64 * 7)()
         with self._handle_lock:
             h = self._live_handle()
             self._lib.shmring_stats(h, ctypes.byref(buf))
@@ -493,6 +512,9 @@ class ShmRingBuffer:
             "gets": int(buf[2]),
             "puts_rejected": int(buf[3]),
             "voids_skipped": voids,
+            "dwell_count": int(buf[6]),
+            "dwell_ms_mean": buf[4] / buf[6] / 1e6 if buf[6] else 0.0,
+            "dwell_ms_max": buf[5] / 1e6,
         }
 
     def disconnect(self):

@@ -6,12 +6,14 @@ and latency quantiles live in :mod:`psana_ray_tpu.utils.metrics`; this
 module adds the device timeline half: XLA/TPU traces viewable in
 TensorBoard or Perfetto (``tensorboard --logdir <dir>`` -> Profile tab).
 
-Two surfaces:
+Three surfaces:
 
 - :func:`trace` — context manager capturing a device trace of the
   enclosed block (producer/consumer loops, a bench section);
-- :func:`annotate` — named region that shows up on the trace timeline
-  (wrap one pipeline stage: batch assembly, device put, step dispatch).
+- :func:`annotate` — named region that shows up on the trace timeline;
+- :class:`phase` — THE way a serving loop marks what its thread is doing:
+  one mark feeds the profiler timeline, the flame sampler's stage tags,
+  the stage histograms and the span spool.
 
 A trace that cannot be started or written raises: a caller that asked
 for a device timeline must not get a run without one.
@@ -24,6 +26,14 @@ import logging
 import os
 import time
 from typing import Iterator, Optional
+
+from psana_ray_tpu.obs.profiling.stagetag import (
+    TAG_OF_STAGE,
+    TAG_UNTAGGED,
+    set_stage,
+    swap_stage,
+)
+from psana_ray_tpu.obs.tracing import TRACER, profiler_annotation
 
 logger = logging.getLogger(__name__)
 
@@ -73,19 +83,66 @@ def annotate(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
-def annotate_stage(stage: str):
-    """Timeline region for one CANONICAL pipeline stage
-    (:data:`psana_ray_tpu.obs.stages.STAGES`), named ``stage.<name>`` —
-    the device-trace half of the stage-timing story: the same stage names
-    that label the latency histograms on the metrics endpoint label the
-    regions on the TensorBoard/Perfetto timeline, so a p99 outlier in
-    ``queue_dwell`` vs ``device_put`` points at the same vocabulary in
-    both tools.
+class phase:
+    """One phase of a serving thread's loop (:data:`psana_ray_tpu.obs.
+    stages.PHASES`), marked ONCE::
 
-    Also tags the calling thread for the continuous profiler
-    (ISSUE 16): flame samples taken inside the region bill to this
-    stage, so ``device_put``/``dispatch`` CPU shows up in the same
-    vocabulary on the CPU flame as on the device timeline."""
-    from psana_ray_tpu.obs.profiling.stagetag import stage_region
+        with phase(PHASE_LAUNCH, metrics, batch.batch_id, batch.num_valid):
+            out = step(batch)
 
-    return stage_region(stage, annotate(f"stage.{stage}"))
+    - a ``stage.<name>`` region on the profiler's timeline, beside the
+      device ops and on their clock (``jax.profiler.TraceAnnotation``;
+      skipped in a process that never imported jax, where no profile can
+      be running);
+    - the calling thread's tag for the flame sampler, restored on exit;
+    - on exit ONE observation of the duration in ``metrics.stages``
+      (``metrics``: a ``PipelineMetrics`` or None) and, while the tracer
+      is on, ONE ``stage.<name>`` span in its spool under ``batch_id``
+      with the ``frames`` the batch or loop turn held.
+
+    Phases of one thread are consecutive and never nested, and together
+    cover the loop body: the benchmark bills each idle gap of the device
+    to the phase the host was in. ``t0``/``t1`` (monotonic seconds) stay
+    readable after exit; a loop may set ``batch_id``/``frames`` inside
+    the region once it knows them, widen ``t0`` to the start of a wait
+    that spanned several polls, or clear ``record`` for a turn that has
+    nothing to report (an empty poll): the timeline region and the tag
+    are kept, the histogram and the spool skipped. An object may be
+    entered again and again (a loop that turns a thousand times a second
+    builds its phases once); each entry starts with ``record`` set."""
+
+    __slots__ = ("name", "batch_id", "frames", "record", "t0", "t1",
+                 "_metrics", "_tag", "_region", "_ann", "_prev")
+
+    def __init__(self, name: str, metrics=None, batch_id: int = 0, frames: int = 0):
+        self.name = name
+        self.batch_id = batch_id
+        self.frames = frames
+        self.record = True
+        self.t0 = self.t1 = 0.0
+        self._metrics = metrics
+        self._tag = TAG_OF_STAGE.get(name, TAG_UNTAGGED)
+        self._region = "stage." + name
+        self._ann = None
+        self._prev = TAG_UNTAGGED
+
+    def __enter__(self) -> "phase":
+        self.record = True
+        self._prev = swap_stage(self._tag)
+        ann = self._ann = profiler_annotation(self._region)
+        if ann is not None:
+            ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = self.t1 = time.monotonic()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        set_stage(self._prev)
+        if self.record:
+            if self._metrics is not None:
+                self._metrics.stages.observe(self.name, t1 - self.t0)
+            if TRACER.enabled:
+                TRACER.span(self.batch_id, self._region, self.t0, t1, self.frames)
+        return False

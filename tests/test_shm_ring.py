@@ -178,8 +178,11 @@ class TestWedgeDetection:
             assert ring.put(b"a") and ring.put(b"b")  # full
             # claim the tail slot like a consumer, then "crash" (no release)
             lib = _load_lib()
-            ptr, ticket = ctypes.c_void_p(), ctypes.c_uint64()
-            assert lib.shmring_acquire(ring._h, ctypes.byref(ptr), ctypes.byref(ticket)) >= 0
+            ptr, ticket, enq_ns = ctypes.c_void_p(), ctypes.c_uint64(), ctypes.c_uint64()
+            assert lib.shmring_acquire(
+                ring._h, ctypes.byref(ptr), ctypes.byref(ticket), ctypes.byref(enq_ns)
+            ) >= 0
+            assert enq_ns.value > 0  # the slot handed its enqueue stamp back
 
             with pytest.raises(TransportWedged, match="consumer.*crashed"):
                 deadline = time.monotonic() + 10

@@ -22,7 +22,6 @@ from psana_ray_tpu.obs.tracing import (
     TRACER,
     TraceContext,
     Tracer,
-    emit_batch_spans,
     exchange_anchors,
 )
 from psana_ray_tpu.records import FrameRecord, decode, encode_into, encoded_size
@@ -276,22 +275,28 @@ class TestBatchPathSpans:
         assert hops is not None and len(hops) == 1  # only the traced record
         assert hops[0][TRACE_KEY] == ctx.trace_id
 
-    def test_emit_batch_spans_telescopes_hops(self, tracer):
-        from psana_ray_tpu.obs.stages import HOP_BATCH, HOP_DEQ, HOP_PUSH
+    def _spans(self, tracer):
+        tracer.close()
+        rows = [json.loads(s) for s in open(tracer.spool_path) if s.strip()]
+        return [r for r in rows if r["t"] == "s"]
+
+    def test_frame_spans_telescope_hops_and_name_their_batch(self, tracer):
+        from psana_ray_tpu.obs.stages import (
+            HOP_BATCH, HOP_DEQ, HOP_PUSH, StageTimes, observe_frame_stages,
+        )
 
         class B:
+            batch_id = 41
             hops = [{TRACE_KEY: 99, HOP_DEQ: 1.0, HOP_PUSH: 2.0, HOP_BATCH: 3.0}]
 
-        emit_batch_spans(B(), 4.0, tracer=tracer)
-        tracer.close()
-        spans = [
-            json.loads(s) for s in open(tracer.spool_path) if s.strip()
+        st = StageTimes()
+        observe_frame_stages(st, B(), tracer=tracer)
+        # deq->push = dequeue, push->batch = batch; what comes after the
+        # batch is emitted is the batch's, not the frame's (phase spans)
+        assert [(r["n"], r["a"], r["b"], r["id"], r["j"]) for r in self._spans(tracer)] == [
+            ("dequeue", 1.0, 2.0, 99, 41), ("batch", 2.0, 3.0, 99, 41),
         ]
-        spans = [(r["n"], r["a"], r["b"]) for r in spans if r["t"] == "s"]
-        # deq->push = dequeue, push->batch = batch, batch->t_end = dispatch
-        assert spans == [
-            ("dequeue", 1.0, 2.0), ("batch", 2.0, 3.0), ("dispatch", 3.0, 4.0),
-        ]
+        assert st.stages() == ["batch", "dequeue"]
 
     def test_no_duplicate_enqueue_span_in_process(self, tracer):
         # in-process transports share the hops dict with the producer,
@@ -299,26 +304,32 @@ class TestBatchPathSpans:
         # batch walk must not replay the src->enq leg (but keeps the
         # enq->deq queue_dwell no server exists to emit)
         from psana_ray_tpu.obs.stages import (
-            HOP_BATCH, HOP_DEQ, HOP_ENQ, HOP_PUSH, HOP_SRC,
+            HOP_BATCH, HOP_DEQ, HOP_ENQ, HOP_PUSH, HOP_SRC, StageTimes,
+            observe_frame_stages,
         )
 
         class B:
+            batch_id = 1
             hops = [{
                 TRACE_KEY: 7, HOP_SRC: 1.0, HOP_ENQ: 2.0, HOP_DEQ: 3.0,
                 HOP_PUSH: 4.0, HOP_BATCH: 5.0,
             }]
 
-        emit_batch_spans(B(), 6.0, tracer=tracer)
-        names = tracer.snapshot()["spans_by_name"]
-        assert "enqueue" not in names, names
-        assert names == {"queue_dwell": 1, "dequeue": 1, "batch": 1, "dispatch": 1}
+        st = StageTimes()
+        observe_frame_stages(st, B(), tracer=tracer)
+        assert [r["n"] for r in self._spans(tracer)] == ["queue_dwell", "dequeue", "batch"]
+        assert "enqueue" in st.stages()  # the histogram still has the leg
 
     def test_untraced_batch_is_free(self, tracer):
+        from psana_ray_tpu.obs.stages import StageTimes, observe_frame_stages
+
         class B:
+            batch_id = 1
             hops = None
 
-        emit_batch_spans(B(), 1.0, tracer=tracer)
-        assert tracer.snapshot()["spans_total"] == 0
+        st = StageTimes()
+        observe_frame_stages(st, B(), tracer=tracer)
+        assert tracer.snapshot()["spans_total"] == 0 and st.stages() == []
 
 
 def _write_spool(path, process, host, pid, mono_offset, spans, peers=()):
@@ -397,6 +408,25 @@ class TestTraceMergeGolden:
             "s", "t", "t", "f",
         ]
         assert {f["pid"] for f in flows} == {1, 2, 3}
+
+    def test_exemplar_follows_the_frame_into_its_batch(self, tmp_path):
+        """A frame's spans name the batch it joined; the batch's phase
+        spans carry that id: --exemplar draws both, and no other batch's."""
+        from psana_ray_tpu.obs.trace_merge import merge
+
+        path = str(tmp_path / "c.trace.jsonl")
+        _write_spool(path, "consumer", "h", 1, 0.0, [])
+        with open(path, "a") as f:
+            for rec in (
+                {"t": "s", "id": 7, "n": "queue_dwell", "a": 1.0, "b": 2.0, "j": 3},
+                {"t": "s", "id": 8, "n": "queue_dwell", "a": 1.1, "b": 2.0, "j": 4},
+                {"t": "s", "id": 3, "n": "stage.launch", "a": 2.0, "b": 2.1, "k": 16},
+                {"t": "s", "id": 4, "n": "stage.launch", "a": 3.0, "b": 3.1, "k": 16},
+            ):
+                f.write(json.dumps(rec) + "\n")
+        doc = merge([path], only_trace=7)
+        names = [(e["name"], e["args"]["trace_id"]) for e in doc["traceEvents"] if e["ph"] == "X"]
+        assert names == [("queue_dwell", "0x7"), ("stage.launch", "0x3")]
 
     def test_peer_anchor_skew_correction(self, tmp_path):
         from psana_ray_tpu.obs.trace_merge import merge

@@ -517,8 +517,12 @@ class TestWorkersFleet:
                 assert prod.put(_rec(i))
             prod.disconnect()
 
+            # (twelve dials, not the default four: with full jitter four
+            # can be over in half a second, and a respawned pid is not
+            # yet a listening worker on a loaded machine)
             cons = TcpQueueClient(
                 "127.0.0.1", port, namespace="ns", queue_name="q3",
+                reconnect_tries=12,
             )
             first = cons.get_batch(6, timeout=10.0)
             assert len(first) == 6
@@ -544,7 +548,7 @@ class TestWorkersFleet:
             seen = {r.event_idx for r in first}
             deadline = time.monotonic() + 30
             while seen != set(range(20)) and time.monotonic() < deadline:
-                for r in cons.get_batch(64, timeout=2.0):
+                for r in cons.get_batch(64, timeout=10.0):
                     seen.add(r.event_idx)
             assert seen == set(range(20)), (
                 f"lost={sorted(set(range(20)) - seen)}"

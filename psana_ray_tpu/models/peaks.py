@@ -5,16 +5,29 @@ Closes the loop the reference's own packaging names as its mission —
 keyword at ``setup.py:15``) — but which exists nowhere in its code.
 
 Pipeline: PeakNet U-Net logits ``[N, H, W, 1]`` -> :func:`find_peaks`
-(device-side, jittable: sigmoid threshold + 3x3 local-maximum test +
+(device-side, jittable: sigmoid threshold + local-maximum test +
 top-K by score, fixed shapes so pjit never recompiles) -> host-side
 :class:`CxiWriter` appending the peak lists per event in the CXI layout
 (``/entry_1/result_1/peakXPosRaw`` et al.) that downstream SFX indexing
 tools (CrystFEL and friends) consume.
 
-TPU notes: the peak test is pad + unrolled shifted comparisons (integer-
-exact tie-breaks), all elementwise — XLA fuses the unrolled window into
-one kernel; ``top_k`` gives a FIXED peak-count output (padded, with a
-validity count) so a streaming consumer never sees a shape change.
+What ``top_k`` runs over, and why that is exact. A pixel survives the
+local-maximum test only if no neighbour within Chebyshev distance
+``min_distance`` = d beats it on (probability, earlier raster index), a
+strict total order: of two pixels within d of each other one beats the
+other, so two survivors are never within d, so every ``(d+1) x (d+1)``
+block of the map holds AT MOST ONE. ``top_k`` therefore runs over one
+candidate per block (15,104 a panel row for epix10k2M at d = 2, not
+135,168 pixels) and loses nothing; equal scores are then put back in
+raster order, so the peaks, their scores and their ORDER are those of
+``top_k`` over the whole raster-flat map (``tests/dense_peaks.py``).
+
+TPU notes: the test is unrolled shifted comparisons with static
+tie-breaks, all elementwise on per-block planes — XLA fuses the window
+and the block reduction into one kernel, batch on the lanes, which is
+also the operand layout its TopK runs fastest on; ``top_k`` gives a FIXED
+peak-count output (padded, with a validity count) so a streaming consumer
+never sees a shape change.
 """
 
 from __future__ import annotations
@@ -35,9 +48,13 @@ def find_peaks(
     """Extract up to ``max_peaks`` peak centers from ``[N, H, W, 1]`` (or
     ``[N, H, W]``) segmentation logits.
 
-    A pixel is a peak when its probability exceeds ``threshold`` AND it is
+    A pixel is a peak when its probability reaches ``threshold`` AND it is
     the maximum of its ``(2*min_distance+1)^2`` neighborhood (ties broken
     toward the first in raster order, matching the classic local-max rule).
+    The ``max_peaks`` best come out by descending score, equal scores lower
+    raster index first. ``top_k`` sees one candidate per
+    ``(min_distance+1)^2`` block — a block holds at most one peak (module
+    docstring), so the result is that of ``top_k`` over every pixel.
 
     Returns ``(yx, score, n)``: ``yx [N, max_peaks, 2]`` int32 row/col
     (padded entries are (-1,-1)), ``score [N, max_peaks]`` f32 probability
@@ -46,42 +63,88 @@ def find_peaks(
     if logits.ndim == 4:
         logits = logits[..., 0]
     n_, h, w = logits.shape
-    # two named scopes, metadata only: ``nms`` (the local-max test) and
-    # ``topk`` (the K best per row) are found again by name in a device
-    # trace, whatever XLA numbers its fusions
+    b = min_distance + 1
+    hb, wb = -(-h // b), -(-w // b)
+    # two named scopes, metadata only: ``nms`` (the local-max test, down to
+    # one candidate per block) and ``topk`` (the K best per row) are found
+    # again by name in a device trace, whatever XLA numbers its fusions
     with jax.named_scope("nms"):
-        is_peak, prob = _local_maxima(logits, h, w, threshold, min_distance)
+        cand, where = _local_maxima(logits, threshold, min_distance, b)
     with jax.named_scope("topk"):
-        flat_score = jnp.where(is_peak, prob, 0.0).reshape(n_, h * w)
-        score, idx = jax.lax.top_k(flat_score, max_peaks)
+        k = min(max_peaks, hb * wb)
+        top, cidx = jax.lax.top_k(cand.reshape(n_, hb * wb), k)
+        top_where = jnp.take_along_axis(where.reshape(n_, hb * wb), cidx, axis=1)
+        # ``top_k`` orders equal scores by block-grid index, the dense form
+        # by raster index; the two disagree only inside one row of blocks
+        # (raster order there is by in-block row first). Every equal score
+        # left out lies at or after the last kept candidate, so the kept set
+        # can be wrong only in THAT candidate's block row: take the row
+        # whole in place of its kept members and sort by (score, raster).
+        top_by = cidx // wb
+        cut_by = top_by[:, -1:]
+        in_row = (jnp.arange(hb, dtype=jnp.int32)[None] == cut_by)[:, :, None]
+        row_score = jnp.where(in_row, cand, 0.0).max(axis=1)
+        row_where = jnp.where(in_row, where, 0).max(axis=1)
+        score = jnp.concatenate([jnp.where(top_by == cut_by, 0.0, top), row_score], axis=1)
+        raster = jnp.concatenate([top_where, row_where], axis=1)
+        neg, raster = jax.lax.sort((-score, raster), dimension=1, num_keys=2)
+        short = max(max_peaks - (k + wb), 0)
+        score = jnp.pad(-neg[:, :max_peaks], ((0, 0), (0, short)))
+        raster = jnp.pad(raster[:, :max_peaks], ((0, 0), (0, short)))
         valid = score > 0.0
-        yy = jnp.where(valid, idx // w, -1).astype(jnp.int32)
-        xx = jnp.where(valid, idx % w, -1).astype(jnp.int32)
+        yy = jnp.where(valid, raster // w, -1).astype(jnp.int32)
+        xx = jnp.where(valid, raster % w, -1).astype(jnp.int32)
         yx = jnp.stack([yy, xx], axis=-1)
         return yx, jnp.where(valid, score, 0.0), valid.sum(axis=1).astype(jnp.int32)
 
 
-def _local_maxima(logits, h: int, w: int, threshold: float, min_distance: int):
-    """``(is_peak [N,H,W] bool, prob [N,H,W] f32)`` of ``[N,H,W]`` logits."""
-    prob = jax.nn.sigmoid(logits.astype(jnp.float32))
-    # Local-max test with exact raster-order tie-break: a pixel survives
-    # unless some window neighbor beats it on (prob, earlier raster index).
-    # Unrolled shifted comparisons (static (2d+1)^2-1 slices, XLA fuses the
-    # whole stack into one elementwise kernel) — exact where a float
-    # "prob - idx*eps" key would lose the tie-break to f32 rounding near 1.
-    d = min_distance
-    idx = jnp.arange(h * w, dtype=jnp.int32).reshape(1, h, w)
-    pprob = jnp.pad(prob, ((0, 0), (d, d), (d, d)), constant_values=-jnp.inf)
-    pidx = jnp.pad(idx, ((0, 0), (d, d), (d, d)), constant_values=h * w)
-    beaten = jnp.zeros(prob.shape, dtype=bool)
-    for dy in range(-d, d + 1):
-        for dx in range(-d, d + 1):
-            if dy == 0 and dx == 0:
-                continue
-            sp = pprob[:, d + dy : d + dy + h, d + dx : d + dx + w]
-            si = pidx[:, d + dy : d + dy + h, d + dx : d + dx + w]
-            beaten |= (sp > prob) | ((sp == prob) & (si < idx))
-    return (prob >= threshold) & ~beaten, prob
+def _local_maxima(logits, threshold: float, d: int, b: int):
+    """``(score [N,Hb,Wb] f32, where [N,Hb,Wb] int32)`` of ``[N,H,W]`` logits:
+    each ``b x b`` block's surviving pixel — its probability and its raster
+    index ``y*W + x`` (both 0 where the block has none). ``b = 1`` is the
+    full-resolution map; ``b = d + 1`` loses nothing (module docstring).
+
+    A pixel survives when its probability reaches ``threshold`` and no
+    neighbour in its ``(2d+1)^2`` window beats it on (probability, earlier
+    raster index): an earlier neighbour wins a tie, a later one does not —
+    static per offset, exact where a float "prob - idx*eps" key would lose
+    the tie-break to f32 rounding near 1."""
+    n_, h, w = logits.shape
+    hb, wb = -(-h // b), -(-w // b)
+    # Batch last, so that a strided slice moves whole rows of N values.
+    # phase[ry, rx] holds the padded map's pixels (ry + b*i, rx + b*j): cut
+    # ONCE, after which every window neighbour of every in-block place is a
+    # contiguous slice of one phase and the test is elementwise on
+    # [Hb, Wb, N] — XLA fuses it whole, and the full-resolution score map is
+    # never written. Out-of-frame pixels are -inf: they beat nothing and,
+    # where H or W is padded up to whole blocks, never survive.
+    prob = jax.nn.sigmoid(logits.transpose(1, 2, 0).astype(jnp.float32))
+    hq, wq = hb + -(-2 * d // b), wb + -(-2 * d // b)
+    pprob = jnp.pad(
+        prob, ((d, hq * b - h - d), (d, wq * b - w - d), (0, 0)), constant_values=-jnp.inf
+    )
+    phase = {(ry, rx): pprob[ry::b, rx::b] for ry in range(b) for rx in range(b)}
+
+    def at(oy, ox):  # padded pixel (oy + b*i, ox + b*j) of every block (i, j)
+        return phase[oy % b, ox % b][oy // b : oy // b + hb, ox // b : ox // b + wb]
+
+    corner = (jnp.arange(hb, dtype=jnp.int32)[:, None] * w + jnp.arange(wb, dtype=jnp.int32)) * b
+    score = jnp.zeros((hb, wb, n_), jnp.float32)
+    where = jnp.zeros((hb, wb, n_), jnp.int32)
+    for iy in range(b):
+        for ix in range(b):
+            c = at(d + iy, d + ix)
+            beaten = jnp.zeros(c.shape, dtype=bool)
+            for dy in range(-d, d + 1):
+                for dx in range(-d, d + 1):
+                    if (dy, dx) != (0, 0):
+                        sp = at(d + iy + dy, d + ix + dx)
+                        beaten |= (sp >= c) if (dy, dx) < (0, 0) else (sp > c)
+            s = jnp.where((c >= threshold) & ~beaten, c, 0.0)
+            # at most one place of a block survives: max IS that one's score
+            score = jnp.maximum(score, s)
+            where = jnp.where(s > 0.0, corner[:, :, None] + (iy * w + ix), where)
+    return score.transpose(2, 0, 1), where.transpose(2, 0, 1)
 
 
 def peak_metrics(

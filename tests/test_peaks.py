@@ -9,8 +9,10 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from dense_peaks import dense_find_peaks, dense_local_maxima
 from psana_ray_tpu.models.peaks import (
     CxiWriter,
+    _local_maxima,
     find_peaks,
     read_cxi_peaks,
     unpad_peaks,
@@ -55,6 +57,175 @@ class TestFindPeaks:
         z[0, 4:6, 4:6] = 6.0  # 2x2 plateau — tie-broken to ONE peak
         _, _, n = find_peaks(z, max_peaks=8)
         assert int(n[0]) == 1
+
+
+_JIT_FIND = jax.jit(find_peaks, static_argnums=(1, 2, 3))
+_JIT_DENSE = jax.jit(dense_find_peaks, static_argnums=(1, 2, 3))
+
+
+def _assert_same_as_dense(z, max_peaks, threshold=0.5, min_distance=1):
+    """``find_peaks`` against the dense oracle: yx, score and n equal element
+    for element, so the ORDER of equal scores is held too."""
+    z = jnp.asarray(z, jnp.float32)
+    got = _JIT_FIND(z, max_peaks, threshold, min_distance)
+    want = _JIT_DENSE(z, max_peaks, threshold, min_distance)
+    for name, g, w_ in zip(("yx", "score", "n"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w_), err_msg=name)
+    return tuple(np.asarray(g) for g in got)
+
+
+def _flat(h, w, centers, hot=40.0, cold=-40.0):
+    """One row: ``hot`` single pixels on ``cold``. sigmoid(40) is exactly 1.0
+    in f32, so every center ties with every other."""
+    z = np.full((1, h, w), cold, np.float32)
+    for cy, cx in centers:
+        z[0, cy, cx] = hot
+    return z
+
+
+# a row of blocks at min_distance 2 (3x3 blocks): A sits in the EARLIER
+# block but later in raster order than B (a lower in-block row wins)
+_A, _B, _C = (2, 1), (0, 5), (1, 10)
+
+
+class TestBlockCandidates:
+    """TopK runs over one candidate per (min_distance+1)^2 block; the result
+    is the dense form's, ties and their order included (ISSUE 26)."""
+
+    @pytest.mark.parametrize("hw", [(352, 384), (32, 128), (33, 47), (16, 16), (7, 5)])
+    @pytest.mark.parametrize("d", [0, 1, 2, 3])
+    def test_random_logits_agree_with_dense(self, d, hw):
+        rng = np.random.default_rng(1000 * d + hw[0])
+        z = rng.normal(size=(2, *hw)).astype(np.float32) * 3.0
+        cap = 128 if hw[0] > 30 else 8
+        _, _, n = _assert_same_as_dense(z, cap, 0.5, d)
+        assert n.max() > 0
+
+    @pytest.mark.parametrize("hw", [(32, 128), (33, 47), (20, 22)])
+    @pytest.mark.parametrize("d", [0, 1, 2, 3])
+    @pytest.mark.parametrize("levels", ["integers", "saturated"])
+    def test_tied_logits_agree_with_dense(self, levels, d, hw):
+        """Few distinct values: plateaus everywhere, equal peaks in every row
+        of blocks, and with the cap at 8 the cut falls among equals."""
+        rng = np.random.default_rng(7 * d + hw[1])
+        z = rng.normal(size=(3, *hw)).astype(np.float32) * 2.0
+        z = np.round(z) if levels == "integers" else np.where(z > 1.0, 40.0, -40.0)
+        for cap in (8, 128):
+            _assert_same_as_dense(z.astype(np.float32), cap, 0.5, d)
+
+    def test_plateaus_of_equal_logits(self):
+        z = np.full((1, 30, 40), -8.0, np.float32)
+        z[0, 4:9, 4:7] = 6.0  # each plateau elects its first pixel alone
+        z[0, 4:6, 20:31] = 6.0
+        z[0, 20:23, 10:12] = 6.0
+        yx, score, n = _assert_same_as_dense(z, 16, 0.5, 2)
+        assert [tuple(p) for p in yx[0, : n[0]]] == [(4, 4), (4, 20), (20, 10)]  # raster order
+        assert len(set(score[0, :3])) == 1
+
+    def test_probability_exactly_one_everywhere(self):
+        """A saturated map is ONE plateau: each pixel but the first is beaten
+        by an earlier equal neighbour."""
+        z = np.full((2, 32, 128), 40.0, np.float32)
+        yx, score, n = _assert_same_as_dense(z, 8, 0.5, 2)
+        assert (n == 1).all() and (score[:, 0] == 1.0).all()
+        assert (yx[:, 0] == 0).all()
+
+    def test_later_raster_peak_in_the_earlier_block_comes_second(self):
+        yx, _, n = _assert_same_as_dense(_flat(12, 18, [_A, _B]), 8, 0.5, 2)
+        assert n[0] == 2
+        assert [tuple(p) for p in yx[0, :2]] == [_B, _A]
+
+    @pytest.mark.parametrize("cap,kept", [(1, [_B]), (2, [_B, _C]), (3, [_B, _C, _A])])
+    def test_equal_scores_straddling_the_cap(self, cap, kept):
+        """Row over its cap, the K-th place among equals: the cut keeps the
+        lowest raster indices, not the lowest block indices."""
+        yx, score, n = _assert_same_as_dense(_flat(12, 18, [_A, _B, _C]), cap, 0.5, 2)
+        assert n[0] == cap and (score[0] == 1.0).all()
+        assert [tuple(p) for p in yx[0]] == kept
+
+    def test_cut_among_equals_below_a_brighter_peak_and_across_block_rows(self):
+        z = _flat(12, 18, [_A, _B, _C, (7, 2), (6, 16)], hot=3.0, cold=-8.0)
+        z[0, 10, 9] = 5.0  # the one brighter peak, in the LAST row of blocks
+        for cap in range(1, 8):
+            yx, _, n = _assert_same_as_dense(z, cap, 0.5, 2)
+            want = [(10, 9), _B, _C, _A, (6, 16), (7, 2)][:cap]
+            assert [tuple(p) for p in yx[0, : n[0]]] == want
+
+    @pytest.mark.parametrize("hw", [(12, 18), (13, 17), (14, 19)])
+    @pytest.mark.parametrize("d", [1, 2, 3])
+    def test_peaks_on_every_border_and_corner(self, d, hw):
+        h, w = hw
+        centers = [(0, 0), (0, w - 1), (h - 1, 0), (h - 1, w - 1),
+                   (0, w // 2), (h - 1, w // 2), (h // 2, 0), (h // 2, w - 1)]
+        z = np.full((1, h, w), -8.0, np.float32)
+        for i, (cy, cx) in enumerate(centers):
+            z[0, cy, cx] = 8.0 - 0.25 * i  # distinct: the order is by score
+        yx, _, n = _assert_same_as_dense(z, 16, 0.5, d)
+        assert [tuple(p) for p in yx[0, : n[0]]] == centers
+
+    def test_row_below_threshold_is_all_padding(self):
+        rng = np.random.default_rng(3)
+        z = rng.normal(size=(3, 32, 128)).astype(np.float32)
+        z[1] = -6.0 - np.abs(z[1])  # nothing in row 1 reaches 0.5
+        yx, score, n = _assert_same_as_dense(z, 8, 0.5, 2)
+        assert n[1] == 0 and n[0] == 8 and n[2] == 8
+        assert (yx[1] == -1).all() and (score[1] == 0.0).all()
+
+    @pytest.mark.parametrize("hw,d", [((3, 3), 2), ((7, 5), 2), ((7, 5), 1), ((1, 1), 3), ((2, 9), 0)])
+    def test_max_peaks_above_the_candidate_count(self, hw, d):
+        rng = np.random.default_rng(11)
+        z = rng.normal(size=(2, *hw)).astype(np.float32) * 3.0
+        yx, score, n = _assert_same_as_dense(z, 32, 0.5, d)
+        assert yx.shape == (2, 32, 2) and score.shape == (2, 32)
+        assert (n <= -(-hw[0] // (d + 1)) * -(-hw[1] // (d + 1))).all()
+
+    @pytest.mark.parametrize("d", [1, 2, 3])
+    def test_no_block_holds_two_survivors(self, d):
+        """What lets TopK run on one candidate per block: the full-resolution
+        mask (block 1) never has two survivors within Chebyshev distance d,
+        so none of the (d+1)^2 blocks of any grid alignment holds two."""
+        rng = np.random.default_rng(d)
+        z = np.round(rng.normal(size=(4, 31, 46)) * 2.0).astype(np.float32)
+        score, where = _local_maxima(jnp.asarray(z), 0.5, d, 1)
+        mask = np.asarray(score) > 0.0
+        is_peak, _ = dense_local_maxima(jnp.asarray(z), 0.5, d)
+        np.testing.assert_array_equal(mask, np.asarray(is_peak))
+        raster = np.broadcast_to(np.arange(31 * 46).reshape(31, 46), mask.shape)
+        np.testing.assert_array_equal(np.asarray(where)[mask], raster[mask])
+        assert mask.sum() > 40
+        b = d + 1
+        for oy in range(b):
+            for ox in range(b):
+                m = np.pad(mask, ((0, 0), (oy, 2 * b), (ox, 2 * b)))
+                m = m[:, : m.shape[1] // b * b, : m.shape[2] // b * b]
+                per_block = m.reshape(4, m.shape[1] // b, b, m.shape[2] // b, b).sum(axis=(2, 4))
+                assert per_block.max() == 1
+
+    def test_top_k_operand_is_one_candidate_per_block(self):
+        """The cell's own step (benchmark/configs/peaknet_sfx_epix10k2m.json:
+        epix10k2M panels, min_distance 2) hands ``top_k`` 118 x 128 = 15,104
+        scores a row — a refactor cannot put the whole 135,168-pixel map back."""
+        import json
+        import os
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(repo, "benchmark", "configs", "peaknet_sfx_epix10k2m.json")) as f:
+            cfg = json.load(f)
+        det = cfg["detector"]
+        step = jax.jit(lambda lg: find_peaks(
+            lg, max_peaks=int(cfg["panel_max_peaks"]),
+            threshold=float(cfg["peak_threshold"]), min_distance=int(cfg["min_distance"])))
+        logits = jax.ShapeDtypeStruct(
+            (int(det["panels"]), int(det["height"]), int(det["width"]), 1), jnp.float32)
+
+        def top_k_widths(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "top_k":
+                    yield eqn.invars[0].aval.shape[-1]
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from top_k_widths(sub)
+
+        assert list(top_k_widths(jax.make_jaxpr(step)(logits).jaxpr)) == [15104]
 
 
 class TestCxiRoundtrip:

@@ -186,6 +186,61 @@ def test_e2e_stream_to_cxi_recovers_planted_peaks(serving_ckpt, tmp_path):
     assert resumed.resume_point(0) == N_EVENTS
 
 
+def _stream_to_cxi_datasets(serving_ckpt, path: str) -> dict:
+    """A short stream through ``SfxPipeline`` with every panel row over a
+    small cap (threshold 0.02, 4 peaks a panel: the cut falls where the
+    scores crowd); every dataset of the CXI file as bytes."""
+    import h5py
+
+    from psana_ray_tpu.checkpoint import load_params
+    from psana_ray_tpu.config import PipelineConfig, SourceConfig
+    from psana_ray_tpu.models.peaks import CxiWriter
+    from psana_ray_tpu.producer import ProducerRuntime
+    from psana_ray_tpu.sfx import SfxConfig, SfxPipeline
+    from psana_ray_tpu.transport.addressing import open_queue
+
+    cfg = PipelineConfig(
+        source=SourceConfig(
+            exp="synthetic", run=EVAL_RUN, num_events=8, detector_name=DET, seed=SEED,
+        )
+    )
+    ProducerRuntime(cfg).run(block=False)
+    with CxiWriter(path, max_peaks=64) as writer:
+        pipe = SfxPipeline(
+            load_params(serving_ckpt), writer, features=FEATURES,
+            config=SfxConfig(batch_size=4, max_peaks=4, peak_threshold=0.02, min_distance=2),
+        )
+        assert pipe.run(open_queue(cfg.transport)) == 8
+    rows = {}
+    with h5py.File(path, "r") as f:
+        f.visititems(
+            lambda name, obj: rows.update({name: obj[()].tobytes()})
+            if isinstance(obj, h5py.Dataset) else None
+        )
+    return rows
+
+
+def test_cxi_rows_equal_the_dense_find_peaks(serving_ckpt, tmp_path, monkeypatch):
+    """The ORDER of peaks is part of the file: ``find_peaks`` runs TopK over
+    one candidate per block, and what lands in the CXI datasets is byte for
+    byte what the dense form (tests/dense_peaks.py) patched in writes."""
+    from dense_peaks import dense_find_peaks
+
+    import psana_ray_tpu.models.peaks as peaks
+    from psana_ray_tpu.cxi import read_cxi_peaks
+    from psana_ray_tpu.sources.base import DETECTORS
+
+    blocks = str(tmp_path / "blocks.cxi")
+    got = _stream_to_cxi_datasets(serving_ckpt, blocks)
+    monkeypatch.setattr(peaks, "find_peaks", dense_find_peaks)  # looked up per trace
+    want = _stream_to_cxi_datasets(serving_ckpt, str(tmp_path / "dense.cxi"))
+    assert got.keys() == want.keys() and len(got) >= 7
+    for name in want:
+        assert got[name] == want[name], name
+    n, *_ = read_cxi_peaks(blocks)
+    assert (n == 4 * DETECTORS[DET].panels).all()  # every panel row at its cap
+
+
 def test_competing_sfx_consumers_partition_and_merge(serving_ckpt, tmp_path):
     """The pod deployment shape: TWO SfxPipeline consumers compete on ONE
     queue (the reference's consumer-side DP, SURVEY §2 row 22), each

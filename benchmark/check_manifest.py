@@ -22,7 +22,21 @@ the wider of the two sets — and derives what a bound may be:
   absolute amount, and at most 0.1.
 
 ``setup_s`` is exempt from the spread rule (the driver judges its median
-only): its bound is 0.1. A bound outside its limits, a metric or cell
+only): its bound is 0.1. Since PR 33 it is process start -> window start
+LESS the seconds in between during which a sleeping child beside the run
+(``stops.py``) saw the sandbox stand still, and LESS what is left of the
+call that opens the TPU: the freeze while the chip opens was 2-9 s of
+18-28, the rest of that call a spin as long as the freeze was short, and
+between them they made the medians wander by more than the bound with
+nothing changed; what is left out is the per-layer ``setup_stopped_s``
+and ``device_open_s``. The saturated cells' rates have a name and a bound
+each, ``fps`` (``sfx_epix_saturated``, the device's cell) and ``fps.hit``
+(``hit_epix_saturated``, the host path's, several times as noisy), each
+from its own cell's spread by the rule above. ``latency_p50_ms`` is the
+mean of the latencies between the 45th and 55th percentile
+(``readers/latency_midmean.py``): the plain median of 16 clusters of
+latencies stood on the edge between two of them, and one batch landing
+late moved it by more than its bound (PERF.md, PR 24 finding 4). A bound outside its limits, a metric or cell
 without proof runs, a proof line that is not a correct TPU run, or a
 second set whose median is worse than the first's by more than the bound
 fails the check; so does a proof run whose ``memory_peak_bytes`` is under

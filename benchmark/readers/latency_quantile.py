@@ -7,13 +7,20 @@ is counted in ``failed`` and makes the run incorrect."""
 import numpy as np
 
 
-def read(ctx, q: float):
+def window_latencies(ctx) -> np.ndarray:
+    """Seconds from due to result, of every frame due inside the window
+    whose result reached the sink."""
     _, idx, done_t = ctx.results
     due = ctx.generated["due"]
     t0, t1 = ctx.window
     ok = (idx >= 0) & (idx < len(due))
     idx, done_t = idx[ok], done_t[ok]
     in_window = (due[idx] >= t0) & (due[idx] < t1)
-    if not in_window.any():
+    return done_t[in_window] - due[idx][in_window]
+
+
+def read(ctx, q: float):
+    lat = window_latencies(ctx)
+    if len(lat) == 0:
         return None
-    return float(np.quantile(done_t[in_window] - due[idx][in_window], float(q))) * 1e3
+    return float(np.quantile(lat, float(q))) * 1e3

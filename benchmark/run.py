@@ -12,6 +12,16 @@ then stream for ``--seconds`` and print, last, one JSON object: the
 cell's end-to-end metrics (``--trace 0``) or its per-layer metrics from a
 profiler trace and the program's own spans and counters (``--trace 1``).
 
+Beside every run sleeps a child (``stops.py``), started before anything
+else, that notes when the sandbox stood still. ``setup_s`` is process
+start -> window start LESS the stopped seconds in between (printed
+beside it as ``setup_stopped_s``) and less what is left of the call that
+opens the TPU (``device_open_s``): ``metrics/setup_s.json`` says which
+phases go. The stopped milliseconds inside the window are the per-layer
+``stopped_ms``, and every final line carries the stops and set-up's
+phases under ``"stops"``, so that a reading can be told from the
+machine's state.
+
 Nothing here names a cell, a configuration or a metric: a cell names its
 configuration file (``configs/``), its traffic file (``traffic/``) and,
 through the manifest, its metrics, each a data file (``metrics/``) naming
@@ -27,7 +37,7 @@ from __future__ import annotations
 
 import time
 
-T_PROCESS = time.monotonic()  # setup_s counts from here
+T_PROCESS = time.monotonic()  # setup_s counts from here, less the sandbox's stops
 
 import argparse
 import dataclasses
@@ -72,6 +82,8 @@ class Plan:
     traced: bool
     wanted: list  # the manifest entries of the metrics this run reports
     work: str  # scratch directory inside the checkout, removed at exit
+    stopwatch: subprocess.Popen  # the sleeping child (stops.py)
+    marks: list  # (phase, instant it ended, CPU seconds so far): set-up, cut end to end
 
 
 @dataclasses.dataclass
@@ -79,7 +91,6 @@ class Window:
     t_start: float
     t_end: float
     stop_at: float  # the generator's last offer
-    setup_s: float
 
 
 class Context:
@@ -91,7 +102,9 @@ class Context:
         self.peaks = None
         self.window = (0.0, 0.0)
         self.window_s = 0.0
-        self.setup_s = None
+        self.t_process = None  # the instant setup_s counts from
+        self.stops = None  # the sleeping child's gaps, (start, seconds) each
+        self.phases = {}  # set-up phase -> (start, end), end to end from t_process
         self.series = {}
         self.metrics = None
         self.spool_path = None
@@ -116,7 +129,57 @@ def read_metric(ctx: Context, entry: dict):
     return reader.read(ctx, **spec.get("args", {}))
 
 
+def start_stopwatch() -> subprocess.Popen:
+    """The sleeping child, off the program's path: no site packages, no
+    JAX, nothing of the repo but ``stops.py``."""
+    return subprocess.Popen(
+        [sys.executable, "-S", os.path.join(HERE, "stops.py")],
+        stdin=subprocess.DEVNULL, stdout=subprocess.PIPE, text=True, cwd=ROOT,
+    )
+
+
+def end_stopwatch(child: subprocess.Popen) -> list:
+    """Tell the child to end and take its gaps; a run without its record
+    has no ``setup_s`` to give and dies."""
+    child.terminate()
+    try:
+        out, _ = child.communicate(timeout=30.0)
+        record = json.loads(out.strip().splitlines()[-1])
+    except (subprocess.TimeoutExpired, IndexError, ValueError) as e:
+        die(f"the sleeping child gave no record of the sandbox's stops "
+            f"(exit code {child.poll()}): {e!r}", 3)
+    if record["first"] - T_PROCESS > 2.0:
+        die(f"the sleeping child took {record['first'] - T_PROCESS:.1f} s to its first "
+            f"tick: the stops before it are unseen", 3)
+    return [(float(a), float(b)) for a, b in record["gaps"]]
+
+
+def mark(marks: list, phase: str, at: float = None) -> None:
+    """One more phase of set-up has ended (now, or at the instant given)."""
+    marks.append((phase, time.monotonic() if at is None else at, time.process_time()))
+
+
+def phase_spans(marks: list) -> dict:
+    """``{phase: (start, end, CPU seconds of this process)}``: the marks
+    cut set-up end to end, the first phase starting with the process."""
+    spans, t_prev, cpu_prev = {}, T_PROCESS, 0.0
+    for name, t, cpu in marks:
+        spans[name] = (t_prev, t, cpu - cpu_prev)
+        t_prev, cpu_prev = t, cpu
+    return spans
+
+
 def main(argv=None) -> int:
+    stopwatch = start_stopwatch()
+    try:
+        return run(argv, stopwatch)
+    finally:
+        if stopwatch.poll() is None:
+            stopwatch.kill()
+        stopwatch.wait()
+
+
+def run(argv, stopwatch: subprocess.Popen) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
@@ -152,6 +215,7 @@ def main(argv=None) -> int:
     os.makedirs(work)
     plan = Plan(
         args=args, cell=cell, cfg=cfg, chips=chips, platform=platform, traced=traced, work=work,
+        stopwatch=stopwatch, marks=[],
         traffic=load_json(HERE, "traffic", cell["traffic"] + ".json"),
         wanted=cell_metrics(manifest, cell["name"], "per_layer" if traced else "end_to_end"),
     )
@@ -171,10 +235,10 @@ def run_cell(plan: Plan) -> int:
     transport = plan.cfg["transport"]
     if transport["scheme"] != "shm":
         die(f"transport scheme {transport['scheme']!r} has no opener here yet")
-    t0 = time.monotonic()
+    mark(plan.marks, "imports")
     ring_name = f"bench_{os.getpid()}"
     ring = ShmRingBuffer.create(ring_name, maxsize=int(transport["slots"]))
-    native_s = time.monotonic() - t0
+    mark(plan.marks, "native_ring")
     monitor = ShmRingBuffer.attach(ring_name, retries=0) if plan.traced else None
     report_path = os.path.join(plan.work, "generator.npz")
     spec = {
@@ -191,7 +255,7 @@ def run_cell(plan: Plan) -> int:
     )
     aux_stop = threading.Event()
     try:
-        return measure(plan, ring, monitor, child, report_path, native_s, aux_stop)
+        return measure(plan, ring, monitor, child, report_path, aux_stop)
     finally:
         aux_stop.set()
         if child.poll() is None:
@@ -222,16 +286,18 @@ def child_line(child, what: str, timeout_s: float) -> dict:
     return msg
 
 
-def measure(plan: Plan, ring, monitor, child, report_path, native_s, aux_stop) -> int:
+def measure(plan: Plan, ring, monitor, child, report_path, aux_stop) -> int:
     import numpy as np
 
     args, cfg, traffic, traced = plan.args, plan.cfg, plan.traffic, plan.traced
-    t_import = time.monotonic()
+    marks = plan.marks
+    mark(marks, "generator_spawn")
     import jax
 
     from benchmark import harness
     from psana_ray_tpu.utils.jaxenv import configure_compile_cache, device_summary
 
+    mark(marks, "jax_import")
     cache_dir = configure_compile_cache()
     # every program of a run, however quick to compile, comes from the
     # cache after the cell's first run in a checkout (JAX's default keeps
@@ -249,7 +315,7 @@ def measure(plan: Plan, ring, monitor, child, report_path, native_s, aux_stop) -
         die(f"device kind {dev['kind']!r} is not in benchmark/peaks.json: no peak to "
             f"hold a roofline share against, and no default")
     devices = jax.devices()
-    jax_s = time.monotonic() - t_import
+    mark(marks, "device_open")  # the cache directory, then jax.devices(): the TPU opens
 
     compiles = []  # (monotonic instant, name, seconds) of every backend compile
     jax.monitoring.register_event_duration_secs_listener(
@@ -257,15 +323,13 @@ def measure(plan: Plan, ring, monitor, child, report_path, native_s, aux_stop) -
         if "backend_compile" in name else None
     )
 
-    t0 = time.monotonic()
     program = importlib.import_module(f"benchmark.programs.{cfg['program']}").Program(
         cfg, args.seed, plan.work, devices
     )
-    build_s = time.monotonic() - t0
-    t0 = time.monotonic()
+    mark(marks, "build")  # weights made on the device, constants
     frames = harness.make_check_frames(cfg["detector"], min(8, program.frames_per_batch), args.seed)
     program.warm(frames)
-    warm_s = time.monotonic() - t0
+    mark(marks, "warm_up")  # compile or cache load, two batches
     spool_path = None
     if traced:
         from psana_ray_tpu.obs.tracing import TRACER
@@ -278,19 +342,18 @@ def measure(plan: Plan, ring, monitor, child, report_path, native_s, aux_stop) -
     # whose loop needs longer to reach its steady state (batch arenas that
     # fault in on their first fill) states its own, and the longer holds
     lead_s = max(float(traffic["lead_s"]), float(cfg.get("stream_lead_s", 0.0)))
-    t_go = time.monotonic() + 0.25
+    mark(marks, "generator_ready")
+    t_go = marks[-1][1] + 0.25
     t_start = t_go + lead_s
+    mark(marks, "lead", at=t_start)
     t_end = t_start + args.seconds
-    win = Window(t_start, t_end, t_end + float(traffic["tail_s"]), t_start - T_PROCESS)
+    win = Window(t_start, t_end, t_end + float(traffic["tail_s"]))
     child.stdin.write(json.dumps({"t_go": t_go, "stop_at": win.stop_at}) + "\n")
     child.stdin.flush()
     say(f"device {dev}; compile cache {cache_dir}")
-    say(f"setup_s {win.setup_s:.2f} = native ring {native_s:.2f} + jax import/devices {jax_s:.2f} "
-        f"+ build (weights on device, constants) {build_s:.2f} + warm-up (compile or cache "
-        f"load, 2 batches) {warm_s:.2f} + lead {lead_s + 0.25:.2f} + rest "
-        f"{win.setup_s - native_s - jax_s - build_s - warm_s - lead_s - 0.25:.2f}; generator "
-        f"pool {ready['pool_s']:.2f} s + ring pre-fault {ready['prefault_s']:.2f} s (in the "
-        f"child, overlapped)")
+    say(f"set-up by the wall clock {t_start - T_PROCESS:.2f} s (its phases, less the sandbox's "
+        f"stops, follow the window); generator pool {ready['pool_s']:.2f} s + ring pre-fault "
+        f"{ready['prefault_s']:.2f} s (in the child, overlapped)")
 
     depth = []
     trace_dir = os.path.join(plan.work, "trace")
@@ -308,6 +371,7 @@ def measure(plan: Plan, ring, monitor, child, report_path, native_s, aux_stop) -
 
     seen = program.run(ring)  # blocks until the generator's end of stream
     t_drained = time.monotonic()
+    gaps = end_stopwatch(plan.stopwatch)
     aux_stop.set()
     for t in threads:
         t.join(timeout=120.0)
@@ -338,6 +402,8 @@ def measure(plan: Plan, ring, monitor, child, report_path, native_s, aux_stop) -
         f"{t_drained - win.stop_at:.2f} s after the last offer; compiles inside the window: "
         f"{len(in_window_compiles)}")
     say_stalls(np, program.sink.log, gen, win)
+    spans = phase_spans(marks)
+    stops_record = say_stops(gaps, spans, win)
     if not closed:
         say_backlog(np, traffic, gen, idx, done_t, win)
 
@@ -352,7 +418,9 @@ def measure(plan: Plan, ring, monitor, child, report_path, native_s, aux_stop) -
     ctx = Context()
     ctx.cfg = cfg
     ctx.peaks = peaks_table.get(dev["kind"])
-    ctx.window, ctx.window_s, ctx.setup_s = (t_start, t_end), args.seconds, win.setup_s
+    ctx.window, ctx.window_s = (t_start, t_end), args.seconds
+    ctx.t_process, ctx.stops = T_PROCESS, gaps
+    ctx.phases = {name: (a, b) for name, (a, b, _) in spans.items()}
     ctx.metrics, ctx.spool_path = program.metrics, spool_path
     ctx.results, ctx.generated = (rank, idx, done_t), gen
     log = program.sink.log
@@ -393,7 +461,14 @@ def measure(plan: Plan, ring, monitor, child, report_path, native_s, aux_stop) -
     result["device"] = device
     if breakdown is not None and not args.rehearse:
         result["breakdown"] = breakdown
-    result.update(cell=plan.cell["name"], seed=args.seed, seconds=args.seconds, trace=int(traced))
+    result.update(cell=plan.cell["name"], seed=args.seed, seconds=args.seconds, trace=int(traced),
+                  stops=stops_record)
+    # each number compared, beside its limit, ends the standard error too:
+    # where a run is not correct, that is what the driver's record keeps
+    print(f"[bench] correct={correct}: failed {failed} (limit 0), compiles inside the window "
+          f"{len(in_window_compiles)} (limit 0), sink rows {file_rows} (must be "
+          f"{program.warm_rows + len(idx)}), pipeline saw {seen} of {sent_n} sent; against the "
+          f"reference: {json.dumps(check)}", file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 
@@ -417,6 +492,30 @@ def say_stalls(np, log, gen, win: Window) -> None:
         f"{(gen['sent'] - gen['due']).max() * 1e3:.1f} ms after due")
 
 
+def say_stops(gaps, spans: dict, win: Window) -> dict:
+    """What the sleeping child saw: set-up phase by phase, by the wall
+    clock and stopped, and every stop inside the window. The record rides
+    on the final line under ``"stops"`` (no metric: the readers take
+    theirs from the same gaps)."""
+    from benchmark import stops
+
+    phases = {name: [b - a, stops.overlap_s(gaps, a, b), cpu]
+              for name, (a, b, cpu) in spans.items()}
+    wall = win.t_start - T_PROCESS
+    in_setup = stops.inside(gaps, T_PROCESS, win.t_start)
+    stopped = sum(g[1] for g in in_setup)
+    say(f"set-up: wall {wall:.2f} s, stopped {stopped:.2f} s; phases, wall (stopped; "
+        f"CPU seconds of this process): " + ", ".join(
+            f"{name} {wall:.2f} ({stop:.2f}; {cpu:.2f})"
+            for name, (wall, stop, cpu) in phases.items()))
+    in_window = stops.inside(gaps, win.t_start, win.t_end)
+    say(f"stops: {len(in_setup)} in set-up; in the window "
+        f"{len(in_window)}, {sum(g[1] for g in in_window) * 1e3:.1f} ms: " + (", ".join(
+            f"{g[1] * 1e3:.1f} ms at window second {g[0]:.2f}" for g in in_window) or "none"))
+    return {"setup_wall_s": wall, "setup_stopped_s": stopped, "setup_phases": phases,
+            "window": in_window, "setup": in_setup}
+
+
 def say_backlog(np, traffic, gen, idx, done_t, win: Window) -> None:
     """Open loop: is the backlog growing? The same latency in both halves
     of the window says no."""
@@ -430,7 +529,8 @@ def say_backlog(np, traffic, gen, idx, done_t, win: Window) -> None:
         say(f"open loop at {traffic['rate_fps']} frames/s: median latency "
             f"{np.median(first) * 1e3:.1f} ms in the first half of the window, "
             f"{np.median(second) * 1e3:.1f} ms in the second; generator late p95 "
-            f"{np.quantile(gen['sent'] - gen['due'], 0.95) * 1e3:.2f} ms")
+            f"{np.quantile(gen['sent'] - gen['due'], 0.95) * 1e3:.2f} ms; the plain median over "
+            f"the window {np.median(np.concatenate([first, second])) * 1e3:.2f} ms")
 
 
 def reduce_trace(ctx: Context, trace_dir: str, device: dict, rehearse: bool) -> dict:

@@ -14,7 +14,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import time
-from typing import Iterator, List, Optional, Sequence
+from typing import Callable, Iterator, List, Optional, Sequence
 
 import numpy as np
 
@@ -286,6 +286,7 @@ def batches_from_queue(
     prefer_stream: bool = True,
     control: Optional[DrainControl] = None,
     metrics=None,
+    between_turns: Optional[Callable[[], Optional[bool]]] = None,
 ) -> Iterator[Batch]:
     """Drain a transport queue into fixed-shape batches until EOS.
 
@@ -332,6 +333,19 @@ def batches_from_queue(
     loop's ``PipelineMetrics``, optional) gets one ``queue_wait``
     observation per turn that popped something, covering the whole wait
     since the previous such turn — empty polls report nothing of their own.
+
+    ``between_turns`` (optional) is called on this loop's own thread at
+    the end of every turn that emitted no batch — a starved poll, or
+    frames that did not fill the arena — outside the three phases: the
+    consumer's chance to do work of its own while the stream has nothing
+    for it (``SfxPipeline.run`` drains a finished result there). It must
+    not block: frames wait in the transport meanwhile. It returns None
+    when it found nothing to do, False after work, and a truthy value to
+    end iteration as ``stop`` does. The time of its work is the
+    consumer's, like time suspended at a ``yield``: a ``queue_wait`` that
+    spans several empty polls starts anew after it. A turn ends with
+    every frame that arrives, and on a silent stream with every poll
+    interval.
     """
     batcher: Optional[FrameBatcher] = None
     starved_since: Optional[float] = None
@@ -409,6 +423,12 @@ def batches_from_queue(
                             f"no EOS (producer stalled or unreachable)"
                         )
                     return
+                if between_turns is not None:
+                    done = between_turns()
+                    if done:
+                        return
+                    if done is not None:  # it worked: the wait is counted anew
+                        wait_t0 = None
                 continue
             starved_since = None
             t_deq = ph.t1  # the pop returned
@@ -482,6 +502,8 @@ def batches_from_queue(
                 del items, frames, item  # drop any lingering record refs with the pop
             yield from ready  # suspended-at-yield time is the consumer's
             if stream_done:
+                return
+            if not ready and between_turns is not None and between_turns():
                 return
     finally:
         tally.flush_duplicates(queue, final=True)

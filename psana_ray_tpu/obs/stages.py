@@ -35,6 +35,11 @@ nested, covering the whole loop body)::
     SfxPipeline.run      (the batcher's three) -> launch -> device_wait
                          -> fold -> append
 
+``SfxPipeline.run`` drains a batch (``device_wait -> fold -> append``)
+after the next batch's ``launch`` or, when its result is ready sooner,
+between two turns of the batcher: after one turn's ``batch``, before the
+next turn's ``queue_wait``, never inside a turn.
+
 A phase is a ``stage.<name>`` region on the profiler's timeline, a tag
 for the flame sampler, one span in the trace spool (named ``stage.<name>``
 there too, its id the batch's) and, where the loop owns a

@@ -15,17 +15,23 @@ jitted device program per batch shape (fixed shapes from the batcher; the
 peak list is top-K padded, so streaming never recompiles); only the
 final ``(yx, score, n)`` tuples come back to the host, where panel-local
 coordinates fold into the CrystFEL-style unassembled layout and append to
-the CXI file. The serving loop keeps ONE batch in flight: batch N runs
-on device while batch N-1's host fold + HDF5 append proceed (JAX's async
-dispatch — blocking only happens at the ``device_get`` drain), so host
-write time hides under device compute instead of serializing with it.
+the CXI file. The serving loop is one thread with at most two batches
+dispatched and undrained: batch N runs on device while batch N-1's host
+fold + HDF5 append proceed (JAX's async dispatch — blocking only happens
+at the ``device_get`` drain), so host write time hides under device
+compute instead of serializing with it. A batch is drained right after
+the next one is launched, or — when its step ends before the next batch
+has filled — between two turns of the batcher, as soon as its result is
+seen ready (:meth:`SfxPipeline.run`): a result never waits on the device
+for the stream.
 
 The loop's thread is always inside one phase (``utils.trace.phase``; the
 vocabulary is ``obs.stages.PHASES``): the batcher's ``queue_wait`` /
 ``dequeue`` / ``batch``, then ``launch`` (the jit call, with its implicit
 host-to-device copy of the frames), and for the batch before it
 ``device_wait`` (the ``device_get`` drain), ``fold`` (panel rows -> per-
-event peak sets) and ``append`` (``writer.append`` + cursor). Each is a
+event peak sets) and ``append`` (``writer.append`` + cursor); an early
+drain's three lie between two turns of the batcher. Each is a
 ``stage.<name>`` region on the profiler's timeline, one observation per
 batch in ``metrics.stages``, and one span in the trace spool.
 
@@ -279,8 +285,9 @@ class SfxPipeline:
         enqueued; pairing it with :meth:`drain` one batch later overlaps
         the device program for batch N with the host-side peak fold and
         HDF5 append for batch N-1 (the serial loop leaves the chip idle
-        for the whole host phase). :meth:`run` uses exactly this one-deep
-        schedule; results are bit-identical to the serial path.
+        for the whole host phase). :meth:`run` drains a handle after the
+        next launch at the latest, and sooner once its outputs answer
+        ``is_ready()``; results are bit-identical to the serial path.
 
         The ``launch`` phase; a timed batch's per-frame stamps are folded
         right after it, while the device works (``obs.stages.
@@ -315,8 +322,9 @@ class SfxPipeline:
             # half a millisecond with the host's state, and three in a row
             # made a paced frame's latency follow that state three times
             yx, score, n = jax.device_get(out)
-        # device-wait latency: with one batch in flight this is the step
-        # time NOT hidden behind the host fold/append of the previous batch
+        # device-wait latency: the step time NOT hidden behind the host
+        # fold/append of the previous batch, or behind the stream (an
+        # early drain's is the readback alone: the step had ended)
         metrics.observe_batch(
             int(np.sum(batch.valid)), ph.t1 - ph.t0,
             nbytes=int(getattr(batch.frames, "nbytes", 0)),
@@ -383,18 +391,47 @@ class SfxPipeline:
         """Drain ``queue`` to EOS (or ``stop``/``max_events``) through the
         pipeline; returns events written this run.
 
-        One-deep device/host pipelining: batch N's device step executes
-        while batch N-1's peaks fold into raw coordinates and append to
-        the HDF5 file on the host (see :meth:`dispatch`) — the serial
-        loop pays host-write time as chip idle time. The in-flight batch
-        is always drained before returning (it was dispatched, and the
-        producer will not re-send it), so ``stop`` and ``max_events`` may
-        overshoot the serial loop's stopping point by one extra batch:
-        up to ``2*batch_size - 1`` events past the bound, vs the serial
-        loop's ``batch_size - 1``."""
-        from psana_ray_tpu.infeed.batcher import batches_from_queue
+        One thread, at most two batches dispatched and undrained. Batch
+        N's device step executes while batch N-1's peaks fold into raw
+        coordinates and append to the HDF5 file on the host (see
+        :meth:`dispatch`) — the serial loop pays host-write time as chip
+        idle time. WHEN a batch is drained follows what the loop sees:
+
+        - right after the next batch is launched, blocking on the result
+          (the one-deep schedule) — where frames outrun the device, the
+          batcher emits a batch every turn and this is the only drain;
+        - or earlier, between two turns of the batcher that emitted
+          nothing, as soon as every output of its step answers
+          ``is_ready()`` — where the device outruns the frames, a result
+          reaches the file when its step ends (seen at the next frame's
+          arrival, or the next empty poll), not when the next batch
+          fills. That drain never waits for the device, so the thread is
+          back at the transport after one readback, ``fold`` and
+          ``append``; ``metrics.drained_ahead`` counts such batches.
+          While a dispatched batch is undrained the pop's wait ends
+          every millisecond (the live ``poll_s`` dial; a caller's
+          ``drain_control`` keeps its own), so the step's end is seen
+          within one: at frame arrivals alone it is seen up to a frame
+          period late, and how late follows where the step time happens
+          to fall between two frames.
+
+        The in-flight batch is always drained before returning (it was
+        dispatched, and the producer will not re-send it), so ``stop``
+        and ``max_events`` may overshoot the serial loop's stopping point
+        by one extra batch: up to ``2*batch_size - 1`` events past the
+        bound, vs the serial loop's ``batch_size - 1``. A drain that
+        raises, early or not, surfaces from here: its handle is not
+        drained again, and the cursor is saved over what was written."""
+        from psana_ray_tpu.infeed.batcher import DrainControl, batches_from_queue
 
         start = self.n_events
+        # the pop's live dials: the caller's (autotune), or the loop's own
+        dials = drain_control if drain_control is not None else DrainControl()
+        ask_every_s = min(poll_interval_s, 0.001)  # after a running step
+
+        def _watch_the_step(running: bool) -> None:
+            if drain_control is None:
+                dials.poll_s = ask_every_s if running else None
 
         def _save_cursor(wrote: int) -> None:
             if (self.n_events // cursor_save_every) != (
@@ -411,12 +448,28 @@ class SfxPipeline:
             return max_events is not None and self.n_events - start >= max_events
 
         pending = None
+
+        def _drain_if_ready() -> Optional[bool]:
+            """The batcher's ``between_turns``: drain the pending batch if
+            its step has ended (asked, never waited for). None = nothing
+            was drained; True = hit the max_events bound."""
+            nonlocal pending
+            if pending is None or not all(a.is_ready() for a in pending[0]):
+                return None
+            prev, pending = pending, None  # as below: never drained twice
+            _watch_the_step(False)
+            hit = _drain_one(prev)
+            self.metrics.drained_ahead.add(1)
+            return hit
+
         try:
             for batch in batches_from_queue(
                 queue, self.cfg.batch_size, poll_interval_s=poll_interval_s,
-                stop=stop, control=drain_control, metrics=self.metrics,
+                stop=stop, control=dials, metrics=self.metrics,
+                between_turns=_drain_if_ready,
             ):
                 nxt = self.dispatch(batch)
+                _watch_the_step(True)
                 # clear ``pending`` BEFORE draining it: if drain raises
                 # after its writer.append, the finally below must not
                 # drain the same handle again (duplicate CXI rows)

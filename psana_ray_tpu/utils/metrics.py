@@ -247,6 +247,11 @@ class PipelineMetrics:
         self.frames = Meter("frames")
         self.bytes = Meter("bytes")
         self.batches = Meter("batches")
+        # batches appended before the NEXT batch's launch began, or the
+        # stream's end was seen (SfxPipeline.run found the result ready
+        # between two turns of the batcher): over ``batches`` near 1 when
+        # the device outruns the frames, 0 at saturation
+        self.drained_ahead = Meter("drained_ahead")
         self.step_latency = LatencyStats()
         self.stages = StageTimes()
         self._queue = queue
@@ -291,6 +296,7 @@ class PipelineMetrics:
             "bytes_per_second": round(self.bytes.rate(), 3),
             "batches_total": self.batches.count,
             "batches_per_second": round(self.batches.rate(), 3),
+            "drained_ahead_total": self.drained_ahead.count,
             "step_latency": self.step_latency.snapshot(),
         }
         stages = self.stages.snapshot()

@@ -2,6 +2,7 @@
 queue->mesh flow on the 8-device CPU mesh."""
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -96,6 +97,112 @@ class TestBatchesFromQueue:
         batches = list(batches_from_queue(q, batch_size=8, poll_interval_s=0.001))
         t.join()
         assert sum(b.num_valid for b in batches) == 20
+
+
+class _Pops:
+    """A transport whose every pop is scripted: an int is that many fresh
+    frames, 0 a starved poll; past the script, the end of the stream."""
+
+    def __init__(self, script):
+        self.script = list(script)
+        self.sent = 0
+
+    def get_batch(self, n, timeout=None):
+        if not self.script:
+            return [EndOfStream(total_events=self.sent)]
+        k = self.script.pop(0)
+        assert k <= n
+        out = [_rec(self.sent + j) for j in range(k)]
+        self.sent += k
+        return out
+
+
+class TestBetweenTurns:
+    """``between_turns``: the consumer's own work on the batcher's thread,
+    at the end of every turn that emitted no batch (ISSUE 35)."""
+
+    @pytest.mark.parametrize(
+        "script,want_calls,want_batches",
+        [
+            ([4, 4, 4], 0, 3),  # a batch every turn: never asked
+            ([0, 0, 4, 0], 3, 1),  # every starved poll
+            ([2, 2, 1, 3], 2, 2),  # frames that did not fill the arena
+            ([3, 4, 1], 1, 2),  # a pop that completes a batch AND starts one emits: not asked
+            ([4, 2], 1, 2),  # the turn that ends the stream flushes the tail: not asked again
+        ],
+        ids=["full-pops", "starved", "partial", "straddling", "eos-tail"],
+    )
+    def test_called_once_per_turn_without_a_batch(self, script, want_calls, want_batches):
+        calls = []
+        got = list(batches_from_queue(
+            _Pops(script), 4, poll_interval_s=0.001, between_turns=lambda: calls.append(1),
+        ))
+        assert len(calls) == want_calls
+        assert len(got) == want_batches
+        idx = np.concatenate([b.event_idx[b.valid.astype(bool)] for b in got])
+        assert idx.tolist() == list(range(sum(script)))  # and the stream is what it was
+
+    def test_it_runs_on_the_loops_thread_outside_every_phase(self):
+        from psana_ray_tpu.obs.profiling.stagetag import TAG_UNTAGGED, current_tag
+
+        seen = []
+        list(batches_from_queue(
+            _Pops([0, 2, 2]), 4, poll_interval_s=0.001,
+            between_turns=lambda: seen.append((threading.get_ident(), current_tag())),
+        ))
+        assert seen == [(threading.get_ident(), TAG_UNTAGGED)] * 2
+
+    @pytest.mark.parametrize("script", [[0, 0, 4], [2, 2, 4]], ids=["starved", "partial"])
+    def test_a_truthy_return_ends_iteration_as_stop_does(self, script):
+        q = _Pops(script)
+        got = list(batches_from_queue(q, 4, poll_interval_s=0.001, between_turns=lambda: True))
+        assert got == []  # frames held are abandoned, not flushed: a cancellation
+        assert len(q.script) == 2  # and nothing more was popped
+
+    @pytest.mark.parametrize("answer", [None, False], ids=["found-nothing", "worked"])
+    def test_a_wait_over_several_polls_starts_anew_after_its_work(self, answer):
+        # its time is the consumer's, like time suspended at a yield: the
+        # ``queue_wait`` that spans the empty polls must not cover it
+        from psana_ray_tpu.utils.metrics import PipelineMetrics
+
+        def hook():
+            time.sleep(0.03)
+            return answer
+
+        m = PipelineMetrics()
+        list(batches_from_queue(_Pops([0, 0, 4]), 4, poll_interval_s=0.001, metrics=m,
+                                between_turns=hook))
+        waits = m.stages.stat("queue_wait")._samples  # the frames' pop, then the end's
+        assert len(waits) == 2
+        assert (waits[0] < 0.03) == (answer is False), waits
+
+    def test_what_it_raises_surfaces_from_the_iterator(self):
+        def boom():
+            raise OSError("disk full")
+
+        it = batches_from_queue(_Pops([4, 1]), 4, poll_interval_s=0.001, between_turns=boom)
+        assert next(it).num_valid == 4
+        with pytest.raises(OSError, match="disk full"):
+            next(it)
+
+    def test_other_loops_pass_no_hook(self, monkeypatch):
+        # InfeedPipeline runs what it ran: its call names no between_turns
+        import psana_ray_tpu.infeed.pipeline as pipeline_mod
+
+        seen = {}
+
+        def spy(*a, **kw):
+            seen.update(kw)
+            return batches_from_queue(*a, **kw)
+
+        monkeypatch.setattr(pipeline_mod, "batches_from_queue", spy)
+        q = RingBuffer(maxsize=8)
+        for i in range(4):
+            q.put(_rec(i))
+        q.put(EndOfStream(total_events=4))
+        pipe = InfeedPipeline(q, batch_size=4, poll_interval_s=0.001)
+        assert pipe.run(lambda b: b.frames) == 4
+        assert seen and "between_turns" not in seen
 
 
 class TestDevicePrefetch:

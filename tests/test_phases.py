@@ -514,6 +514,68 @@ class TestSfxLoop:
 
 
 @needs_ring
+class TestSfxEarlyDrain:
+    """``SfxPipeline.run`` drains a batch whose step has ended between
+    two turns of the batcher (ISSUE 35): its ``device_wait`` / ``fold`` /
+    ``append`` lie after one turn's ``batch`` (or the ``launch``, over
+    empty polls, which leave no span) and before the next turn's
+    ``queue_wait`` — never inside a turn."""
+
+    N = 4 * BATCH
+
+    @pytest.mark.parametrize(
+        "poll_s", [0.001, 0.5], ids=["seen-at-an-empty-poll", "seen-at-a-frame"]
+    )
+    def test_an_early_drains_phases_lie_between_two_turns(
+        self, ring, sfx_variables, tmp_path, poll_s
+    ):
+        pipe = _sfx(sfx_variables)
+        z = np.zeros(BATCH)
+        pipe.process_batch(Batch(  # compile before the stream: its steps then end within a gap
+            np.zeros((BATCH,) + SHAPE, np.uint16), np.ones(BATCH, np.uint8), z.astype(np.int32),
+            np.arange(BATCH, dtype=np.int64), z.astype(np.float32),
+        ))
+        pipe.writer, pipe.metrics, pipe.n_events = _Writer(), PipelineMetrics(), 0
+        TRACER.configure(str(tmp_path), sample_every=1, process="t")
+        feeder, _ = _feed(ring, self.N, gap_s=0.05)  # a batch every 200 ms
+        assert pipe.run(ring, poll_interval_s=poll_s) == self.N
+        feeder.join(timeout=30)
+        rows = _spool(TRACER)
+        n_batches = self.N // BATCH
+        names = TURN_PHASES + BATCH_PHASES_SFX
+        for name in BATCH_PHASES_SFX:  # still once a batch, under the batch's id
+            spans = _phase_spans(rows, name)
+            assert len(spans) == n_batches, name
+            assert len({r["id"] for r in spans}) == n_batches
+        _assert_consecutive(rows, names)
+        spans = sorted(
+            (r for r in rows if r["t"] == "s" and r["n"] in {"stage." + n for n in names}),
+            key=lambda r: r["a"],
+        )
+        order = [r["n"][len("stage."):] for r in spans]
+        early = 0
+        for i, name in enumerate(order):
+            if name == "device_wait":
+                # a drain begins where a turn has ended or a launch has,
+                # and its three phases follow one another
+                assert order[i - 1] in ("batch", "launch"), order[max(0, i - 3): i + 4]
+                assert order[i + 1: i + 3] == ["fold", "append"]
+                assert spans[i]["id"] == spans[i + 1]["id"] == spans[i + 2]["id"]
+                if i + 3 < len(order):
+                    assert order[i + 3] == "queue_wait"
+                launches = [r for r in spans if r["n"] == "stage.launch"]
+                own = next(r for r in launches if r["id"] == spans[i]["id"])
+                later = [r for r in launches if r["a"] > own["a"]]
+                if later and spans[i + 2]["b"] <= later[0]["a"]:
+                    early += 1  # appended before the next launch began
+        assert early == n_batches - 1
+        # the last batch has no next launch: it counts too when the stream
+        # fell silent long enough before its end for the hook to see it done
+        assert pipe.metrics.drained_ahead.count in (n_batches - 1, n_batches)
+        assert pipe.metrics.step_latency.count == n_batches
+
+
+@needs_ring
 class TestInfeedLoop:
     N = 3 * BATCH
 

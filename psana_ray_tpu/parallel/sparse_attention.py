@@ -1,0 +1,331 @@
+"""Learned sparse attention: index scores, a per-query top-k key selection,
+and grouped-query attention restricted to the selected keys.
+
+The mechanism (DeepSeek-V3.2-Exp's description of DSA, with the sizes of
+Keye-VL-2.0's ``sa_config``): a light INDEXER of ``H_I`` query heads
+against ONE key head scores every earlier key for every query,
+
+    I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]) / sqrt(d_I)     s <= t
+
+and each query attends, in every attention head alike, to
+``Sel(t)``: the ``min(t + 1, topk)`` keys of largest ``I[t, .]``, equal
+scores resolved towards the EARLIER key.
+
+What is here, and what it is:
+
+- :func:`select_keys` — one Pallas kernel per tile of queries: the tile's
+  whole row of index scores is computed into VMEM (causal key tiles
+  only), each query's ``topk``-th largest score is found EXACTLY by a
+  32-step bisection over the scores' bit patterns (no sort, no
+  ``lax.top_k``: a k of 2048 over 34,304 is a sort on the TPU), ties at
+  that score are cut at the key index that ``Sel`` would cut them at, and
+  the selection leaves as an int8 mask ``[S/bq, S/bk, bq, bk]`` (tile
+  major, so that the attention kernel's mask tile is one block).
+- :func:`masked_gqa_attention` — a flash kernel over ALL causal tiles
+  that applies that mask: the MASKED-DENSE form. It does the work of
+  dense causal attention (``S^2/2`` pairs a head) whatever the selection;
+  a form that visits only live tiles, or gathers the selected keys, is
+  the optimisation this form is the yardstick for. The ``G`` key-value
+  heads each serve ``H/G`` query heads, whose query tiles are stacked
+  into one ``[H/G * bq, d]`` operand so that a key tile is loaded once
+  for all of them.
+- :func:`live_tiles` — how many ``stat_tile`` x ``stat_tile`` tiles at
+  or below the diagonal hold a selected pair (from the flags the selection
+  kernel writes beside its mask), and how many there are: what a
+  tile-skipping kernel could save.
+
+Off the TPU the kernels run in Pallas interpret mode (tests, rehearsals).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+NEG_INF = -1e30
+INT_MIN = -(2**31)
+_VMEM_LIMIT = 100 * 1024 * 1024  # of the v5e's 128 MiB; the default scope is 16
+
+
+def pick_tile(s: int, want: int) -> int:
+    """The largest multiple of 128 that divides ``s`` and is at most
+    ``want``; ``want`` itself where it divides ``s`` (small test sizes);
+    else the whole of ``s`` (one tile)."""
+    if s % want == 0:
+        return want
+    for b in range(min(want, s) // 128 * 128, 0, -128):
+        if s % b == 0:
+            return b
+    return s
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return jax.default_backend() != "tpu" if interpret is None else interpret
+
+
+def sortable_key(x: jax.Array) -> jax.Array:
+    """float32 -> int32 whose signed order is the floats' order."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+# ---------------------------------------------------------------------------
+# index scores + selection
+# ---------------------------------------------------------------------------
+
+def _select_kernel(q_ref, k_ref, w_ref, mask_ref, live_ref, keys_ref, *, topk, scale, block_q,
+                   block_k, n_kb):
+    qi = pl.program_id(0)
+    n_heads = q_ref.shape[0]
+    row0 = qi * block_q
+    n_live = (row0 + block_q + block_k - 1) // block_k  # key tiles at or below the diagonal
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    cols0 = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+
+    def score_tile(kb, carry):
+        kt = k_ref[pl.ds(pl.multiple_of(kb * block_k, block_k), block_k), :]
+        acc = jnp.zeros((block_q, block_k), jnp.float32)
+        for j in range(n_heads):
+            s = jax.lax.dot_general(q_ref[j], kt, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            acc = acc + w_ref[j] * jnp.maximum(s, 0.0)
+        key = sortable_key(acc * scale)
+        keys_ref[kb] = jnp.where(cols0 + kb * block_k > rows, INT_MIN, key)
+        return carry
+
+    jax.lax.fori_loop(0, n_live, score_tile, 0)
+
+    fold = 128 if block_k % 128 == 0 else block_k  # lanes of the running count
+
+    def count(pred):
+        """Per query, over its causal keys: how many satisfy ``pred(keys, kb)``.
+        The running count is one lane block wide, so that it stays in registers."""
+        def body(kb, acc):
+            hit = pred(keys_ref[kb], kb).astype(jnp.int32)
+            for c in range(0, block_k, fold):
+                acc = acc + hit[:, c:c + fold]
+            return acc
+
+        acc = jax.lax.fori_loop(0, n_live, body, jnp.zeros((block_q, fold), jnp.int32))
+        return jnp.sum(acc, axis=1, keepdims=True)
+
+    t = row0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    want = jnp.minimum(t + 1, topk)  # |Sel(t)|
+
+    # the want-th largest key, bit by bit from the top: the largest T with
+    # count(key >= T) >= want. Dead entries hold INT_MIN and T never does.
+    n0 = count(lambda k, kb: k >= 0)
+    ok = n0 >= want
+    lo = jnp.where(ok, 0, INT_MIN).astype(jnp.int32)
+    n_lo = jnp.where(ok, n0, t + 1)
+
+    def bit_step(i, carry):
+        lo, n_lo = carry
+        cand = lo + jnp.left_shift(jnp.int32(1), 30 - i)
+        n = count(lambda k, kb: k >= cand)
+        ok = n >= want
+        return jnp.where(ok, cand, lo), jnp.where(ok, n, n_lo)
+
+    thr, n_ge = jax.lax.fori_loop(0, 31, bit_step, (lo, n_lo))
+
+    # n_ge - want keys too many share the score thr: Sel keeps the EARLIER
+    # ones, so the cut is the largest bound c with
+    # count(key > thr) + count(key == thr, index < c) <= want. Float ties
+    # are rare: the search runs only in a tile that has one.
+    excess = n_ge - want
+    bits = (n_kb * block_k).bit_length()
+
+    def find_cut(_):
+        n_gt = count(lambda k, kb: k > thr)
+
+        def step(i, c):
+            cand = c + jnp.left_shift(jnp.int32(1), bits - 1 - i)
+            n = n_gt + count(lambda k, kb: (k == thr) & (cols0 + kb * block_k < cand))
+            return jnp.where(n <= want, cand, c)
+
+        return jax.lax.fori_loop(0, bits, step, jnp.zeros((block_q, 1), jnp.int32))
+
+    def keep_all(_):
+        return jnp.full((block_q, 1), 2**bits, jnp.int32)
+
+    cut = jax.lax.cond(jnp.max(excess) > 0, find_cut, keep_all, 0)
+
+    live_ref[...] = jnp.zeros(live_ref.shape, jnp.int32)
+    for kb in range(n_kb):
+        @pl.when(kb < n_live)
+        def _live(kb=kb):
+            k = keys_ref[kb]
+            sel = ((k > thr) | ((k == thr) & (cols0 + kb * block_k < cut))).astype(jnp.int32)
+            mask_ref[0, kb] = sel.astype(jnp.int8)
+            live_ref[0, :, kb:kb + 1] = jnp.max(sel, axis=(0, 1), keepdims=True)
+
+        @pl.when(kb >= n_live)
+        def _dead(kb=kb):
+            mask_ref[0, kb] = jnp.zeros((block_q, block_k), jnp.int8)
+
+
+def select_keys(q_idx, k_idx, w_idx, *, topk: int, block_q: int = 128, block_k: int = 512,
+                interpret: Optional[bool] = None) -> Tuple[jax.Array, jax.Array]:
+    """``q_idx [H_I, S, d_I]`` and ``k_idx [S, d_I]`` (after their rotary
+    and norm), ``w_idx [S, H_I]`` float32 -> the selection as an int8 mask
+    ``[S/bq, S/bk, bq, bk]``: entry ``[a, b, i, j]`` is 1 iff key
+    ``b*bk + j`` is in ``Sel(a*bq + i)``; and which of its tiles hold a
+    selected pair, int32 ``[S/bq, S/bk]`` (reducing the mask for that
+    afterwards took 38 ms a layer on the v5e: my chip run, PR 36)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_heads, s, d = q_idx.shape
+    bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
+    n_qb, n_kb = s // bq, s // bk
+    w = jnp.transpose(w_idx.astype(jnp.float32))[:, :, None]  # [H_I, S, 1]
+    kernel = functools.partial(_select_kernel, topk=int(topk), scale=float(d) ** -0.5,
+                               block_q=bq, block_k=bk, n_kb=n_kb)
+    lanes = -(-n_kb // 128) * 128
+    mask, live = pl.pallas_call(
+        kernel,
+        grid=(n_qb,),
+        in_specs=[
+            pl.BlockSpec((n_heads, bq, d), lambda i: (0, i, 0)),
+            pl.BlockSpec((s, d), lambda i: (0, 0)),
+            pl.BlockSpec((n_heads, bq, 1), lambda i: (0, i, 0)),
+        ],
+        out_specs=[pl.BlockSpec((1, n_kb, bq, bk), lambda i: (i, 0, 0, 0)),
+                   pl.BlockSpec((1, 1, lanes), lambda i: (i, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n_qb, n_kb, bq, bk), jnp.int8),
+                   jax.ShapeDtypeStruct((n_qb, 1, lanes), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((n_kb, bq, bk), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(interpret),
+        name="select_keys",
+    )(q_idx, k_idx, w)
+    return mask, live[:, 0, :n_kb]
+
+
+def mask_to_dense(mask: jax.Array) -> jax.Array:
+    """The tile-major selection mask as a plain ``[S, S]`` boolean."""
+    n_qb, n_kb, bq, bk = mask.shape
+    return jnp.transpose(mask, (0, 2, 1, 3)).reshape(n_qb * bq, n_kb * bk) != 0
+
+
+def causal_tiles(s: int, block_q: int = 128, block_k: int = 512) -> Tuple[jax.Array, jax.Array]:
+    """The mask and tile flags of plain causal attention in
+    :func:`select_keys`' form: what a layer without an indexer hands the
+    attention kernel."""
+    bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
+    dense = jnp.tril(jnp.ones((s, s), jnp.int8))
+    mask = jnp.transpose(dense.reshape(s // bq, bq, s // bk, bk), (0, 2, 1, 3))
+    return mask, jnp.any(mask != 0, axis=(2, 3)).astype(jnp.int32)
+
+
+def live_tiles(live: jax.Array, block_q: int, block_k: int,
+               stat_tile: int = 512) -> Tuple[jax.Array, int]:
+    """``(live, causal)``: of the ``stat_tile``-square tiles at or below
+    the diagonal, how many hold a selected pair (int32 scalar, on the
+    device) and how many there are (static), from the ``[S/bq, S/bk]``
+    flags of the mask's own tiles."""
+    n_qb, n_kb = live.shape
+    tile = pick_tile(n_qb * block_q, stat_tile)
+    if tile % block_q or tile % block_k:
+        raise ValueError(f"the statistics' tile {tile} is no multiple of the mask's "
+                         f"{block_q} x {block_k}")
+    n = n_qb * block_q // tile
+    big = jnp.any(live.reshape(n, tile // block_q, n, tile // block_k) != 0, axis=(1, 3))
+    return jnp.sum(big.astype(jnp.int32)), n * (n + 1) // 2
+
+
+# ---------------------------------------------------------------------------
+# masked grouped-query flash attention
+# ---------------------------------------------------------------------------
+
+def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
+                 *, rep, d, block_q, block_k, n_kb):
+    qi = pl.program_id(1)
+    kb = pl.program_id(2)
+
+    @pl.when(kb == 0)
+    def _reset():
+        m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when((qi + 1) * block_q > kb * block_k)  # the tile touches the causal part
+    def _tile():
+        # 0 where the query selected the key, NEG_INF where not (causal by
+        # construction); one tile for all the group's query heads
+        sel = mask_ref[:, 0].astype(jnp.float32).reshape(block_q, block_k)
+        bias = (sel - 1.0) * -NEG_INF
+        k, v = k_ref[...], v_ref[...]
+        for h in range(rep):  # the group's query heads share the key tile
+            rows = slice(h * block_q, (h + 1) * block_q)
+            s = jax.lax.dot_general(q_ref[:, h * d:(h + 1) * d], k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) + bias
+            m = m_ref[rows]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            # a row with nothing selected yet has m_new == NEG_INF and p == 1
+            # on its masked entries: the first selected key's alpha == 0 wipes
+            # that, and every row selects a key at or before its diagonal tile
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[rows] = m_new
+
+    @pl.when(kb == n_kb - 1)
+    def _finalize():
+        out = acc_ref[:] / l_ref[:]
+        for h in range(rep):
+            o_ref[:, h * d:(h + 1) * d] = out[h * block_q:(h + 1) * block_q].astype(o_ref.dtype)
+
+
+def masked_gqa_attention(q, k, v, mask, *, num_kv_heads: int, block_q: Optional[int] = None,
+                         interpret: Optional[bool] = None) -> jax.Array:
+    """``q [S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [S, G*d]``,
+    ``mask`` from :func:`select_keys` -> ``o [S, H*d]``: softmax attention
+    of every query head over the keys its query selected, query head ``h``
+    reading key-value head ``h // (H/G)``. Masked-dense: every causal tile
+    is computed. ``block_q`` (a multiple of the mask's query tile, which is
+    the default) is this kernel's own query tile."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_qb, n_kb, mq, bk = mask.shape
+    s = q.shape[0]
+    g = int(num_kv_heads)
+    d = k.shape[1] // g
+    rep = q.shape[1] // (g * d)
+    bq = mq if block_q is None else pick_tile(s, block_q)
+    if bq % mq:
+        raise ValueError(f"the attention's query tile {bq} is no multiple of the mask's {mq}")
+    r = bq // mq
+
+    def last_live(i, j):  # a tile above the diagonal re-reads the last one below: no new copy
+        return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
+
+    kernel = functools.partial(_attn_kernel, rep=rep, d=d, block_q=bq, block_k=bk, n_kb=n_kb)
+    return pl.pallas_call(
+        kernel,
+        grid=(g, s // bq, n_kb),
+        in_specs=[
+            pl.BlockSpec((bq, rep * d), lambda gi, i, j: (i, gi)),
+            pl.BlockSpec((bk, d), lambda gi, i, j: (last_live(i, j), gi)),
+            pl.BlockSpec((bk, d), lambda gi, i, j: (last_live(i, j), gi)),
+            pl.BlockSpec((r, 1, mq, bk), lambda gi, i, j: (i, last_live(i, j), 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((bq, rep * d), lambda gi, i, j: (i, gi)),
+        out_shape=jax.ShapeDtypeStruct((s, rep * g * d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((rep * bq, 1), jnp.float32),
+            pltpu.VMEM((rep * bq, 1), jnp.float32),
+            pltpu.VMEM((rep * bq, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(interpret),
+        name="masked_gqa_attention",
+    )(q, k, v, mask)

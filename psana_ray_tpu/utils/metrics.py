@@ -254,6 +254,11 @@ class PipelineMetrics:
         self.drained_ahead = Meter("drained_ahead")
         self.step_latency = LatencyStats()
         self.stages = StageTimes()
+        # counters a step's own statistics feed, by name: a model whose
+        # work per token depends on the data says how (expert load, live
+        # attention tiles: ``models.decoder.fold_step_stats``)
+        self.counters: Dict[str, float] = {}  # guarded-by: _counters_lock
+        self._counters_lock = threading.Lock()
         self._queue = queue
 
     def attach_queue(self, queue):
@@ -277,6 +282,12 @@ class PipelineMetrics:
             self.bytes.add(nbytes)
         self.step_latency.observe(latency_s)
 
+    def add_counter(self, name: str, amount: float = 1.0):
+        """Add to the named counter (created at 0 on first use); it shows
+        in :meth:`snapshot` under its name."""
+        with self._counters_lock:
+            self.counters[name] = self.counters.get(name, 0.0) + amount
+
     def _queue_stats(self) -> Optional[dict]:
         q = self._queue
         if q is None:
@@ -299,6 +310,8 @@ class PipelineMetrics:
             "drained_ahead_total": self.drained_ahead.count,
             "step_latency": self.step_latency.snapshot(),
         }
+        with self._counters_lock:
+            out.update(self.counters)
         stages = self.stages.snapshot()
         if stages:
             out["stages"] = stages

@@ -148,7 +148,52 @@ def _sfx_serve_step():
     return pipe._device_step, [*resident, S((SfxConfig.batch_size, *panel), jnp.uint16)], 1
 
 
+KEYE_S = 34304  # 33,792 patches of an epix10k2M frame + 512 prompt tokens
+
+
+def _keye_select():
+    """Index scores + exact top-2048 selection at the published indexer
+    sizes (16 heads of 64, one key head): a 128-query tile's whole score
+    row, 67 x 128 x 512 int32, sits in VMEM."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    def fn(q, k, w):
+        return sa.select_keys(q, k, w, topk=2048, block_q=128, block_k=512, interpret=False)[0]
+
+    return fn, [S((16, KEYE_S, 64), BF16), S((KEYE_S, 64), BF16), S((KEYE_S, 16), F32)], 1
+
+
+def _keye_attention():
+    """Masked grouped-query attention, 32 query heads on 4 key-value
+    heads of 128, query tile 256 over the selection's 128-row mask tiles."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    def fn(q, k, v, mask):
+        return sa.masked_gqa_attention(q, k, v, mask, num_kv_heads=4, block_q=256,
+                                       interpret=False)
+
+    kv = S((KEYE_S, 512), BF16)
+    return fn, [S((KEYE_S, 4096), BF16), kv, kv, S((268, 67, 128, 512), jnp.int8)], 1
+
+
+def _keye_experts():
+    """The dropless expert layer at 128 experts of 2048 x 768, top 8:
+    three megablox grouped products over 274,432 sorted rows."""
+    from psana_ray_tpu.parallel.moe import dropless_moe
+
+    def fn(x, router, w_gate, w_up, w_down):
+        return dropless_moe(x, router, w_gate, w_up, w_down, k=8, num_experts=128,
+                            interpret=False)
+
+    up = S((128, 2048, 768), BF16)
+    return fn, [S((KEYE_S, 2048), BF16), S((2048, 128), BF16), up, up,
+                S((128, 768, 2048), BF16)], 3
+
+
 CASES = {
+    "keye_select_keys_34304": _keye_select,
+    "keye_masked_gqa_attention_34304": _keye_attention,
+    "keye_dropless_experts_34304x8": _keye_experts,
     "calib_epix10k2M_u16": lambda: _calib(jnp.uint16),
     "calib_epix10k2M_f32": lambda: _calib(F32),
     "sfx_serve_step_cli_defaults": _sfx_serve_step,

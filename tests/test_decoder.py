@@ -1,0 +1,503 @@
+"""The config-driven decoder (``models/decoder.py``), its sparse attention
+and dropless experts, against the benchmark's plain reference
+(``benchmark/reference/keye_decoder.py``) at small sizes on the CPU, and
+the benchmark's reader for the counters it feeds."""
+
+import dataclasses
+import importlib
+import json
+import os
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import harness
+from benchmark.reference import keye_decoder as ref
+from psana_ray_tpu.models import decoder
+from psana_ray_tpu.parallel import sparse_attention as sa
+from psana_ray_tpu.parallel.moe import dropless_moe
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PANELS, ROWS, COLS, PROMPT = 2, 2, 14, 8  # 56 patches + 8 prompt ids = 64 tokens
+
+
+def mapping(**over):
+    """The Hugging Face keys of a small decoder with every mechanism on."""
+    m = dict(
+        hidden_size=64, num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=32, vocab_size=256, rms_norm_eps=1e-6, rope_theta=1e7,
+        rope_scaling={"mrope_section": [4, 6, 6]},
+        sa_config=dict(indexer_num_heads=4, indexer_head_dim=16, indexer_num_kv_heads=1,
+                       topk=16, q_chunk_size=16, kv_chunk_size=32),
+        num_experts=8, num_experts_per_tok=2, moe_intermediate_size=32, norm_topk_prob=True,
+        intermediate_size=96,
+    )
+    m.update(over)
+    return m
+
+
+def small(m):
+    """The configuration of ``m`` with tiles that cut 64 tokens into several."""
+    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), q_tile=16, attn_q_tile=32)
+
+
+def inputs(seed):
+    rng = np.random.default_rng(seed)
+    patches = jnp.asarray(rng.standard_normal((PANELS * ROWS * COLS, 64)), jnp.float32)
+    ids = jnp.asarray(rng.integers(0, 256, PROMPT))
+    return patches, ids, decoder.frame_positions(PANELS, ROWS, COLS, PROMPT)
+
+
+def all_logits(cfg, params, patches, ids, pos):
+    x, stats = decoder.trunk(params, decoder.embed(params, patches, ids), pos, cfg)
+    return decoder.logits_of(params, x, cfg), stats
+
+
+# ---------------------------------------------------------------------------
+# (a) the package's trunk against the reference, float32, all positions
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("variant", ["indexer_experts", "dense_attention", "dense_mlp"])
+def test_trunk_matches_reference_at_all_positions(variant):
+    m = mapping()
+    if variant == "dense_attention":
+        m.pop("sa_config")
+    if variant == "dense_mlp":
+        m.update(num_experts=0, num_experts_per_tok=0)
+    cfg = small(m)
+    params = decoder.init_params(cfg, jax.random.key(3), jnp.float32)
+    patches, ids, pos = inputs(3)
+    with jax.default_matmul_precision("highest"):
+        got, stats = jax.jit(lambda p: all_logits(cfg, p, patches, ids, pos))(params)
+        want = ref.forward(params, patches, ids, pos, m, block=16)
+    assert got.shape == (64, 256)
+    scale = float(jnp.sqrt(jnp.mean(want ** 2)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4 * scale, rtol=0)
+    live, causal = float(stats[2]), float(stats[3])
+    assert causal == 2 * 1 and 0 < live <= causal  # one 64 x 64 statistics tile a layer
+
+
+# ---------------------------------------------------------------------------
+# (b) Sel
+# ---------------------------------------------------------------------------
+
+def _selection(q_idx, k_idx, w_idx, topk, block_q=16, block_k=32):
+    mask, live = sa.select_keys(jnp.transpose(q_idx, (1, 0, 2)), k_idx, w_idx, topk=topk,
+                                block_q=block_q, block_k=block_k)
+    np.testing.assert_array_equal(np.asarray(live) != 0, np.asarray(mask).any(axis=(2, 3)))
+    s, d = k_idx.shape
+    dots = jnp.einsum("thd,sd->ths", q_idx, k_idx, precision="highest")
+    scores = jnp.sum(w_idx[:, :, None] * jax.nn.relu(dots), axis=1) / np.sqrt(d)
+    return np.asarray(sa.mask_to_dense(mask)), np.asarray(ref.select(scores, jnp.arange(s), topk))
+
+
+@pytest.mark.parametrize("case", ["longer_than_topk", "within_topk_is_dense", "tied_scores"])
+def test_selection_is_sel(case):
+    rng = np.random.default_rng(11)
+    s, heads, d = 96, 4, 16
+    q = jnp.asarray(rng.standard_normal((s, heads, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((s, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((s, heads)), jnp.float32)
+    topk = {"longer_than_topk": 16, "within_topk_is_dense": 128, "tied_scores": 8}[case]
+    if case == "tied_scores":
+        # every key one of three vectors: each query's scores tie in runs
+        # of 32, and the 8 selected must be the EARLIEST of the best run
+        k = k[jnp.arange(s) % 3]
+        w = jnp.abs(w)
+    got, want = _selection(q, k, w, topk)
+    np.testing.assert_array_equal(got, want)
+    counts = got.sum(axis=1)
+    np.testing.assert_array_equal(counts, np.minimum(np.arange(s) + 1, topk))
+    if case == "within_topk_is_dense":
+        np.testing.assert_array_equal(got, np.tril(np.ones((s, s), bool)))
+    if case == "tied_scores":
+        t = s - 1
+        best = np.flatnonzero(got[t])
+        assert len(set(best % 3)) == 1 and (best == best[0] + 3 * np.arange(8)).all()
+
+
+# ---------------------------------------------------------------------------
+# (c) multimodal rotary
+# ---------------------------------------------------------------------------
+
+def test_mrope_is_three_section_rotation():
+    rng = np.random.default_rng(5)
+    sections, theta, s = (4, 6, 6), 1e7, 12
+    pos = rng.integers(0, 50, (s, 3))
+    x = rng.standard_normal((s, 2, 32))
+    got = decoder.rotate(jnp.asarray(x, jnp.float32),
+                         decoder.rotary_angles(pos, theta, 16, sections))
+    want = np.empty_like(x)
+    for t in range(s):
+        for i in range(16):
+            comp = 0 if i < 4 else (1 if i < 10 else 2)  # t, h, w
+            ang = pos[t, comp] * theta ** (-i / 16)
+            c, sn = np.cos(ang), np.sin(ang)
+            want[t, :, i] = x[t, :, i] * c - x[t, :, i + 16] * sn
+            want[t, :, i + 16] = x[t, :, i + 16] * c + x[t, :, i] * sn
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
+    # equal components (a text token): plain rotary over that one position
+    same = np.repeat(pos[:, :1], 3, axis=1)
+    np.testing.assert_allclose(
+        np.asarray(decoder.rotary_angles(same, theta, 16, sections)),
+        np.asarray(decoder.rotary_angles(same[:, 0], theta, 16)), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# (d), (e) dropless experts, and the shares of a divided layer
+# ---------------------------------------------------------------------------
+
+def _expert_layer(seed, t=64, d=32, width=16, experts=128, k=8, hot=None):
+    rng = np.random.default_rng(seed)
+    p = {
+        "router": jnp.asarray(rng.standard_normal((d, experts)) * 0.5, jnp.float32),
+        "w_gate": jnp.asarray(rng.standard_normal((experts, d, width)) * 0.2, jnp.float32),
+        "w_up": jnp.asarray(rng.standard_normal((experts, d, width)) * 0.2, jnp.float32),
+        "w_down": jnp.asarray(rng.standard_normal((experts, width, d)) * 0.2, jnp.float32),
+    }
+    b = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
+    if hot is not None:  # an expert every token chooses
+        b = b.at[:, 0].set(4.0)
+        p["router"] = p["router"].at[0, hot].set(5.0)
+    m = {"E": experts, "k_e": k, "experts_held": (0, experts), "norm_topk_prob": True}
+    return p, b, m
+
+
+def _held(p, first, count):
+    return {k: (v if k == "router" else v[first:first + count]) for k, v in p.items()}
+
+
+def test_dropless_routing_loses_no_token_at_four_times_the_mean_load():
+    p, b, m = _expert_layer(7, experts=16, k=4, hot=5)
+    with jax.default_matmul_precision("highest"):
+        y, tokens = dropless_moe(b, p["router"], p["w_gate"], p["w_up"], p["w_down"],
+                                 k=4, num_experts=16)
+        want, chosen = ref.experts(p, b, m, jnp.float32)
+    tokens = np.asarray(tokens)
+    assert tokens.sum() == 64 * 4  # every slot of every token served
+    assert tokens[5] == 64 and tokens[5] >= 4 * tokens.mean()  # 4x the even share of 16
+    np.testing.assert_array_equal(tokens, np.asarray(chosen).sum(axis=0))
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
+
+
+def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_reference():
+    p, b, m = _expert_layer(9)
+    with jax.default_matmul_precision("highest"):
+        parts, served = [], 0
+        for first in (0, 32, 64, 96):
+            h = _held(p, first, 32)
+            y, tokens = dropless_moe(b, h["router"], h["w_gate"], h["w_up"], h["w_down"],
+                                     k=8, num_experts=128, experts_held=(first, 32))
+            parts.append(np.asarray(y, np.float64))
+            served += int(np.asarray(tokens).sum())
+        want, _ = ref.experts(p, b, m, jnp.float32)
+    assert served == 64 * 8
+    assert max(np.abs(part).max() for part in parts) > 0
+    np.testing.assert_allclose(sum(parts), np.asarray(want), atol=1e-5)
+    # and the reference, given one share, gives that share
+    one, _ = ref.experts(_held(p, 32, 32), b, {**m, "experts_held": (32, 32)}, jnp.float32)
+    np.testing.assert_allclose(parts[1], np.asarray(one), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# (f) the precision the configuration states, and one below it
+# ---------------------------------------------------------------------------
+
+def _reference_rows(params, patches, ids, pos, m, compute):
+    sizes = ref.sizes(m)
+    x = ref.embed(params, patches, ids, compute)
+    for p in params["layers"]:
+        x = ref.layer(p, x, pos, sizes, compute, 16, False)[0]
+    return x
+
+
+def test_bf16_passes_the_rows_verdict_and_float8_fails_it_at_every_seed():
+    from benchmark.programs.prefill import rows_verdict
+
+    m = mapping()
+    cfg = small(m)
+    init = jax.jit(lambda key: decoder.init_params(cfg, key, jnp.bfloat16))
+    _, ids, pos = inputs(0)
+    served = jax.jit(lambda p, f: decoder.trunk(p, decoder.embed(p, f, ids), pos, cfg)[0])
+    plain = {c: jax.jit(lambda p, f, c=c: _reference_rows(p, f, ids, pos, m, c))
+             for c in (jnp.float32, jnp.bfloat16, jnp.float8_e4m3fn)}
+    for seed in range(8):
+        params = init(harness.make_key(seed))
+        frame = inputs(seed)[0]
+        with jax.default_matmul_precision("highest"):
+            want, stated, below = (np.asarray(plain[c](params, frame)) for c in plain)
+        got = np.asarray(served(params, frame.astype(jnp.bfloat16)), np.float32)
+        verdict = rows_verdict(got, want, stated)
+        assert verdict["ok"] and verdict["yardsticks"] < 3.0, (seed, verdict)
+        lower = rows_verdict(below, want, stated)
+        assert not lower["ok"] and lower["yardsticks"] > 10.0, (seed, lower)
+
+
+def test_rows_verdict_ignores_a_minority_of_tossed_rows():
+    from benchmark.programs.prefill import rows_verdict
+
+    rng = np.random.default_rng(0)
+    want = rng.standard_normal((64, 32))
+    stated = want * (1 + 1e-3 * rng.standard_normal(want.shape))
+    got = want * (1 + 2e-3 * rng.standard_normal(want.shape))
+    got[::7] += 0.2 * rng.standard_normal((10, 32))  # one row in seven off by a fifth
+    few = rows_verdict(got, want, stated)
+    assert few["ok"] and 0.1 < few["rows_over_limit"] < 0.2
+    got[1::3] += 0.2 * rng.standard_normal((21, 32))  # and a further third: the median still holds
+    many = rows_verdict(got, want, stated)
+    assert not many["ok"] and many["rows_relative_rms_median"] <= many["limit"]
+    assert not rows_verdict(want * (1 + 2e-2 * rng.standard_normal(want.shape)), want, stated)["ok"]
+    got[0, 0] = np.nan
+    assert not rows_verdict(got, want, stated)["ok"]
+
+
+def _rehearsal_program(seed):
+    from benchmark.programs import prefill
+
+    with open(os.path.join(REPO, "benchmark", "configs", "keye_vl2_prefill_epix10k2m.json")) as f:
+        cfg = json.load(f)
+    cfg.update(cfg["rehearse"])
+    det = cfg["detector"]
+    frames = np.random.default_rng(seed).integers(
+        90, 140, (1, det["panels"], det["height"], det["width"])).astype(np.uint16)
+    return prefill.Program(cfg, seed, "", None), frames
+
+
+def test_the_check_holds_the_served_logits_and_both_parts_of_the_sequence(monkeypatch):
+    """What the chip's ``correct`` reads, at the rehearsal's size: the
+    patches' rows, the prompt's rows, the head and the served logits. A
+    head computed from float8 operands, served logits that are not the
+    checked program's, or a prompt one position out of place, is not
+    correct, each by the comparison that is there for it."""
+    program, frames = _rehearsal_program(1)
+    plain, memo = program.reference_hidden, {}
+    program.reference_hidden = lambda batch, c: (  # the faults below leave the reference alone
+        memo[c] if c in memo else memo.setdefault(c, plain(batch, c)))
+    verdict = program.check(frames)
+    assert verdict["ok"], verdict
+    assert verdict["patch_rows"]["rows"] == 64 and verdict["prompt_rows"]["rows"] == 8
+    parts = ("patch_rows", "prompt_rows", "head", "served")
+    assert all(verdict[k]["ok"] for k in parts)
+
+    def failing():
+        program._step = jax.jit(program._step.__wrapped__)  # the package is traced in: trace anew
+        verdict = program.check(frames)
+        assert not verdict["ok"], verdict
+        return {k for k in parts if not verdict[k]["ok"]}
+
+    with monkeypatch.context() as patch:
+        exact = decoder.logits_of
+
+        def float8_head(params, x, cfg):
+            rounded = dict(params, head=params["head"].astype(jnp.float8_e4m3fn).astype(x.dtype))
+            return exact(rounded, x, cfg)
+
+        patch.setattr(decoder, "logits_of", float8_head)
+        assert failing() == {"head"}
+
+    with monkeypatch.context() as patch:
+        serve = program._serve
+        patch.setattr(program, "_serve", lambda batch: (jnp.roll(serve(batch)[0], 1, axis=1), None))
+        program._step = jax.jit(program._step.__wrapped__)
+        assert failing() == {"served"}
+
+    with monkeypatch.context() as patch:
+        pos = decoder.frame_positions
+
+        def prompt_one_late(panels, rows, cols, prompt_len):
+            out = pos(panels, rows, cols, prompt_len).copy()
+            out[panels * rows * cols:] += 1
+            return out
+
+        patch.setattr(decoder, "frame_positions", prompt_one_late)
+        assert "prompt_rows" in failing()
+
+
+def _verdicts(m, seeds=range(8)):
+    """Per seed, ``precision_verdict`` of the LAST token's logits (what
+    the chip's check compares) for the package's bf16 trunk and for the
+    reference with float8-rounded operands."""
+    cfg = small(m)
+    init = jax.jit(lambda key: decoder.init_params(cfg, key, jnp.bfloat16))
+    _, ids, pos = inputs(0)
+
+    def served(p, f):
+        x, _ = decoder.trunk(p, decoder.embed(p, f, ids), pos, cfg)
+        return decoder.logits_of(p, x[-1:], cfg)
+
+    served = jax.jit(served)
+    plain = {c: jax.jit(lambda p, f, c=c: ref.forward(p, f, ids, pos, m, c, 16)[-1:])
+             for c in (jnp.float32, jnp.bfloat16, jnp.float8_e4m3fn)}
+    out = []
+    for seed in seeds:
+        params = init(harness.make_key(seed))
+        frame = inputs(seed)[0]
+        with jax.default_matmul_precision("highest"):
+            want, stated, below = (np.asarray(plain[c](params, frame)) for c in plain)
+        got = np.asarray(served(params, frame.astype(jnp.bfloat16)))
+        out.append((harness.precision_verdict(got, want, stated),
+                    harness.precision_verdict(below, want, stated)))
+    return out
+
+
+def test_bf16_passes_precision_verdict_and_float8_fails_it_at_every_seed():
+    """Where nothing is DECIDED (plain causal attention, a dense gated
+    MLP) the verdict reads the precision alone, at every seed."""
+    m = mapping(num_experts=0, num_experts_per_tok=0)
+    m.pop("sa_config")
+    for seed, (program, lower) in enumerate(_verdicts(m)):
+        assert program["ok"], (seed, program)
+        assert not lower["ok"], (seed, lower)
+        assert lower["logits_relative_rms"] > 10 * lower["yardstick_relative_rms"], (seed, lower)
+
+
+def test_with_selection_and_routing_the_verdict_holds_but_for_a_tossed_yardstick():
+    """Top-2 of 8 experts and top-16 keys are decisions: one that lies
+    inside the rounding noise goes either way, in the program and in the
+    yardstick independently, and at this size moves a token's logits by
+    tens of yardsticks. The program passes at every seed all the same;
+    float8 operands fail wherever the yardstick itself was not tossed
+    (there it is tens of times its usual self, and so is the limit)."""
+    verdicts = _verdicts(mapping())
+    usual = float(np.median([lower["yardstick_relative_rms"] for _, lower in verdicts]))
+    passed = 0
+    for seed, (program, lower) in enumerate(verdicts):
+        assert program["ok"], (seed, program)
+        if lower["ok"]:
+            passed += 1
+            assert lower["yardstick_relative_rms"] > 10 * usual, (seed, lower, usual)
+    assert passed <= 1
+
+
+@pytest.mark.parametrize("panels,rows,cols,prompt", [(2, 2, 14, 8), (16, 44, 48, 512)])
+def test_the_reference_places_the_tokens_where_the_package_does(panels, rows, cols, prompt):
+    want = ref.positions(panels, rows, cols, prompt)  # plain loops, the check's own
+    np.testing.assert_array_equal(decoder.frame_positions(panels, rows, cols, prompt), want)
+    assert want.shape == (panels * rows * cols + prompt, 3)
+    assert tuple(want[0]) == (0, 0, 0) and tuple(want[cols]) == (0, 1, 0)
+    assert tuple(want[panels * rows * cols - 1]) == (panels - 1, rows - 1, cols - 1)
+    first = max(panels, rows, cols)  # 48 at the published size: the configuration's `assumed`
+    np.testing.assert_array_equal(want[-prompt:], np.repeat(first + np.arange(prompt), 3).reshape(-1, 3))
+
+
+# ---------------------------------------------------------------------------
+# (g) the counters of a stream
+# ---------------------------------------------------------------------------
+
+def test_counters_of_a_two_frame_stream_through_infeed_pipeline():
+    from psana_ray_tpu.infeed import InfeedPipeline
+    from psana_ray_tpu.records import EndOfStream, FrameRecord
+    from psana_ray_tpu.transport import RingBuffer
+
+    m = mapping()
+    cfg = small(m)
+    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
+    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
+                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
+    calib = harness.make_calibration(detector, 1)
+    ids = jnp.arange(PROMPT, dtype=jnp.int32)
+    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
+    rng = np.random.default_rng(2)
+    q = RingBuffer(maxsize=4)
+    for i in range(2):
+        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
+    q.put(EndOfStream(total_events=2))
+    pipe = InfeedPipeline(q, batch_size=1, poll_interval_s=0.001)
+    logits = []
+
+    def on_result(out, batch):
+        logits.append(np.asarray(out[0]))
+        assert len(out) == 2  # logits and statistics: nothing for a check rides on a served frame
+        decoder.fold_step_stats(pipe.metrics, out[1])
+
+    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 2
+    assert all(x.shape == (1, 256) and np.isfinite(x).all() for x in logits)
+    snap = pipe.metrics.snapshot()
+    frames, layers, tokens = 2, 2, 64
+    assert snap["expert_tokens_mean_total"] == frames * layers * tokens * 2 / 8
+    assert snap["attn_tiles_causal_total"] == frames * layers * 1
+    assert snap["expert_tokens_mean_total"] <= snap["expert_tokens_max_total"] <= frames * layers * tokens
+    assert 0 < snap["attn_tiles_live_total"] <= snap["attn_tiles_causal_total"]
+    assert set(decoder.STEP_STATS) <= set(snap)
+
+
+def test_the_package_does_not_import_the_decoder():
+    import subprocess
+    import sys
+
+    code = ("import sys, psana_ray_tpu, psana_ray_tpu.models; "
+            "assert 'psana_ray_tpu.models.decoder' not in sys.modules; "
+            "assert 'psana_ray_tpu.parallel.sparse_attention' not in sys.modules")
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=REPO,
+                   env=dict(os.environ, JAX_PLATFORMS="cpu"), timeout=120)
+
+
+# ---------------------------------------------------------------------------
+# the benchmark's reader of two counters, and the manifest's new files
+# ---------------------------------------------------------------------------
+
+def _ctx(snapshot):
+    metrics = None if snapshot is None else types.SimpleNamespace(snapshot=lambda: snapshot)
+    return types.SimpleNamespace(metrics=metrics)
+
+
+@pytest.mark.parametrize("snapshot,args,want", [
+    ({"a_total": 6.0, "b_total": 4.0}, {}, 1.5),
+    ({"a_total": 6.0, "b_total": 4.0}, {"scale": 100.0}, 150.0),
+    ({"a_total": 6.0}, {}, None),                    # the parent: no such counter
+    ({"b_total": 4.0}, {}, None),
+    ({"a_total": 6.0, "b_total": 0.0}, {}, None),    # nothing counted yet
+    (None, {}, None),                                # a program without metrics
+])
+def test_program_counter_ratio(snapshot, args, want):
+    from benchmark.readers import program_counter_ratio
+
+    got = program_counter_ratio.read(_ctx(snapshot), "a_total", "b_total", **args)
+    assert got == want
+
+
+def test_every_metric_file_of_the_new_cell_names_a_reader_that_exists():
+    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+        manifest = json.load(f)
+    mine = [e for e in manifest["per_layer"] if e.get("workloads") == ["keye_epix_saturated"]]
+    assert len(mine) == 10
+    for entry in mine:
+        with open(os.path.join(REPO, "benchmark", "metrics", entry["name"] + ".json")) as f:
+            spec = json.load(f)
+        reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
+        assert callable(reader.read), entry["name"]
+        if spec["reader"] == "roofline_share":
+            module, fn = spec["args"]["function"].rsplit(".", 1)
+            need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
+            assert set(spec["args"]["shape_from"]) <= set(need.__code__.co_varnames)
+
+
+@pytest.mark.parametrize("name", [
+    "producer_blocked_share.hit", "ring_depth.hit", "queue_dwell_ms.hit", "device_put_ms",
+    "infeed_wait_ms", "launch_ms.hit", "device_wait_ms.hit", "step_ms.hit",
+    "device_idle_share.hit", "stopped_ms.hit", "fps.hit",
+])
+def test_the_new_cell_reports_the_host_path_under_the_names_the_hit_cell_has(name):
+    """The layers the cell shares with the hit cell (ring, batcher,
+    prefetcher, serving loop, device) are read by the same files under
+    the same names: one entry, both cells in its ``workloads``."""
+    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+        manifest = json.load(f)
+    entry, = [e for e in manifest["per_layer"] + manifest["end_to_end"] if e["name"] == name]
+    assert entry["workloads"] == ["hit_epix_saturated", "keye_epix_saturated"]
+    assert entry.get("moves", "fps.hit") == "fps.hit"
+    assert not os.path.exists(os.path.join(
+        REPO, "benchmark", "metrics", name.replace(".hit", "") + ".keye.json"))
+
+
+def test_roofline_counts_at_the_published_sizes():
+    from benchmark.roofline import decoder as need
+
+    s = 34304
+    assert need.causal_pairs(s) == 588_399_360
+    assert need.selected_pairs(s, 2048) == 2048 * 2049 // 2 + (s - 2048) * 2048
+    assert need.selected_attention(s, 32, 4, 128, 2048)["flops"] == need.selected_pairs(s, 2048) * 16384
+    assert need.grouped_product(s, 8, 2048, 768, 128)["flops"] == 2 * s * 8 * 2048 * 768

@@ -19,9 +19,11 @@ What is fused and what stays XLA — and why:
   this.
 - **enc level 0 and the decoder**: XLA. Level 0's 176x192x64 activations
   need three+ whole-panel buffers whose 64->128 lane padding doubles
-  them past VMEM, and the decoder's upsample+merge structure would force
-  every conv into phase-separated form. XLA runs these at good MXU
-  shapes already (N=64 -> 50%); the fusion win there is marginal against
+  them past VMEM, and a decoder level's output is twice the extent of
+  its input: a whole-panel kernel would hold both. XLA runs the decoder
+  within a quarter of the MXU's peak already (measured on the v5e, PR
+  37), its up-convolution on the low-resolution map (``unet.upconv2x``,
+  shared with the flax model): the fusion win there is marginal against
   the Mosaic-complexity risk.
 
 ``peaknet_tpu_fused_infer`` is the drop-in equivalent of
@@ -47,6 +49,7 @@ from psana_ray_tpu.models.pallas_resnet import (
     _up,
     _ypad_dims,
 )
+from psana_ray_tpu.models.unet import upconv2x
 from psana_ray_tpu.models.unet_tpu import depth_to_space, space_to_depth
 
 _BF16 = jnp.bfloat16
@@ -376,11 +379,7 @@ def peaknet_tpu_fused_infer(
         lvl = n_enc - 1 - i
         if lvl in f_pads:
             skip = skip[..., : features[lvl]]
-        n, hh, ww, c = y.shape
-        up = jnp.broadcast_to(
-            y[:, :, None, :, None, :], (n, hh, 2, ww, 2, c)
-        ).reshape(n, 2 * hh, 2 * ww, c)
-        u = _xla_conv3x3(up, p[f"Conv_{n_enc + i}"]["kernel"])
+        u = upconv2x(y, p[f"Conv_{n_enc + i}"]["kernel"])
         mb = p[f"MergeBlock_{i}"]
         z = _xla_conv3x3(u, mb["merge_up"]["kernel"]) + _xla_conv3x3(
             skip, mb["merge_skip"]["kernel"]

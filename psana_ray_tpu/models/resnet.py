@@ -32,6 +32,11 @@ Dtype = Any
 conv_axes = ("height", "width", "channels_in", "channels_out")
 
 
+_conv_kernel_init = nn.with_logical_partitioning(
+    nn.initializers.variance_scaling(2.0, "fan_out", "normal"), conv_axes
+)
+
+
 def _conv(features, kernel, strides, dtype, name=None):
     return nn.Conv(
         features,
@@ -41,9 +46,7 @@ def _conv(features, kernel, strides, dtype, name=None):
         use_bias=False,
         dtype=dtype,
         param_dtype=jnp.float32,
-        kernel_init=nn.with_logical_partitioning(
-            nn.initializers.variance_scaling(2.0, "fan_out", "normal"), conv_axes
-        ),
+        kernel_init=_conv_kernel_init,
         name=name,
     )
 

@@ -28,8 +28,9 @@ the MXU wants it:
   possible without halo-streaming, where the classic model's full-res
   352x384 levels could never fit VMEM.
 
-Same conventions as the classic model: strided-conv downsampling,
-broadcast 2x upsample + split-weight skip merge, GroupNorm + SiLU for
+Same conventions as the classic model: strided-conv downsampling, 2x
+nearest upsample + 3x3 convolution computed on the low-resolution map
+(``unet.upconv2x``), split-weight skip merge, GroupNorm + SiLU for
 training (``norm='group'``), folded :class:`FrozenAffine` statistics for
 streaming inference (``norm='frozen'``), bf16 compute / f32 params.
 """
@@ -43,7 +44,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from psana_ray_tpu.models.resnet import _conv
-from psana_ray_tpu.models.unet import ConvBlock, MergeBlock, _upsample2x
+from psana_ray_tpu.models.unet import ConvBlock, MergeBlock, UpConv2x
 
 Dtype = Any
 
@@ -109,8 +110,8 @@ class PeakNetUNetTPU(nn.Module):
         levels = range(len(skips) - 1, -1, -1)
         for i, f, skip in zip(levels, reversed(self.features[:-1]), reversed(skips)):
             with jax.named_scope(f"dec{i}"):
-                x = _upsample2x(x)
-                x = _conv(f, (3, 3), (1, 1), self.dtype)(x)
+                # Conv_<n>: the name the nn.Conv it replaces gave itself
+                x = UpConv2x(f, dtype=self.dtype, name=f"Conv_{2 * len(skips) - 1 - i}")(x)
                 x = MergeBlock(f, dtype=self.dtype, norm=self.norm)(x, skip)
         # logits for every ORIGINAL pixel: s2d²·classes channels at packed
         # resolution, unshuffled back out — f32 like the classic head

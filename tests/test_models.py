@@ -159,16 +159,163 @@ class TestMergeBlockEquivalence:
         assert out.shape == (2, 16, 16, 1)
         assert np.isfinite(np.asarray(out)).all()
 
-    def test_upsample2x_matches_resize_nearest(self, rng):
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
 
-        from psana_ray_tpu.models.unet import _upsample2x
+def _upsample_then_conv(x, k):
+    """The decoder's line as it was: nearest 2x upsample, 3x3 SAME."""
+    up = jnp.repeat(jnp.repeat(x, 2, axis=1), 2, axis=2)
+    return jax.lax.conv_general_dilated(
+        up, k, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
+    )
 
-        x = jnp.asarray(rng.normal(size=(2, 5, 6, 3)).astype(np.float32))
-        ref = jax.image.resize(x, (2, 10, 12, 3), "nearest")
-        np.testing.assert_array_equal(np.asarray(_upsample2x(x)), np.asarray(ref))
+
+_UPCONV_EXTENTS = [(2, 2), (3, 5), (22, 24)]
+_UPCONV_CHANNELS = [(1, 1), (8, 4), (16, 16)]
+
+# every leaf of PeakNetUNetTPU(features=(8, 16, 32), s2d=2, norm='frozen'),
+# written out: checkpoints, benchmark/reference/peaknet.py, sfx.infer_s2d
+# and infer_features, pallas_unet.peaknet_tpu_fused_infer go by these paths
+_TPU_FROZEN_TREE = {
+    "ConvBlock_0/Conv_0/kernel": (3, 3, 4, 8), "ConvBlock_0/Conv_1/kernel": (3, 3, 8, 8),
+    "ConvBlock_0/FrozenAffine_0/bias": (8,), "ConvBlock_0/FrozenAffine_0/scale": (8,),
+    "ConvBlock_0/FrozenAffine_1/bias": (8,), "ConvBlock_0/FrozenAffine_1/scale": (8,),
+    "ConvBlock_1/Conv_0/kernel": (3, 3, 8, 16), "ConvBlock_1/Conv_1/kernel": (3, 3, 16, 16),
+    "ConvBlock_1/FrozenAffine_0/bias": (16,), "ConvBlock_1/FrozenAffine_0/scale": (16,),
+    "ConvBlock_1/FrozenAffine_1/bias": (16,), "ConvBlock_1/FrozenAffine_1/scale": (16,),
+    "ConvBlock_2/Conv_0/kernel": (3, 3, 16, 32), "ConvBlock_2/Conv_1/kernel": (3, 3, 32, 32),
+    "ConvBlock_2/FrozenAffine_0/bias": (32,), "ConvBlock_2/FrozenAffine_0/scale": (32,),
+    "ConvBlock_2/FrozenAffine_1/bias": (32,), "ConvBlock_2/FrozenAffine_1/scale": (32,),
+    "Conv_0/kernel": (3, 3, 8, 8), "Conv_1/kernel": (3, 3, 16, 16),
+    "Conv_2/kernel": (3, 3, 32, 16), "Conv_3/kernel": (3, 3, 16, 8),
+    "MergeBlock_0/Conv_0/kernel": (3, 3, 16, 16),
+    "MergeBlock_0/FrozenAffine_0/bias": (16,), "MergeBlock_0/FrozenAffine_0/scale": (16,),
+    "MergeBlock_0/FrozenAffine_1/bias": (16,), "MergeBlock_0/FrozenAffine_1/scale": (16,),
+    "MergeBlock_0/merge_skip/kernel": (3, 3, 16, 16), "MergeBlock_0/merge_up/kernel": (3, 3, 16, 16),
+    "MergeBlock_1/Conv_0/kernel": (3, 3, 8, 8),
+    "MergeBlock_1/FrozenAffine_0/bias": (8,), "MergeBlock_1/FrozenAffine_0/scale": (8,),
+    "MergeBlock_1/FrozenAffine_1/bias": (8,), "MergeBlock_1/FrozenAffine_1/scale": (8,),
+    "MergeBlock_1/merge_skip/kernel": (3, 3, 8, 8), "MergeBlock_1/merge_up/kernel": (3, 3, 8, 8),
+    "logits/bias": (4,), "logits/kernel": (1, 1, 8, 4),
+}
+
+
+def _group_tree(frozen_tree, stem_in, head_out):
+    """The same model with ``norm='group'``: GroupNorm_<n> where the
+    frozen tree has FrozenAffine_<n>; the classic model has no s2d, so
+    its stem reads ``stem_in`` channels and its head emits ``head_out``."""
+    tree = {k.replace("FrozenAffine", "GroupNorm"): v for k, v in frozen_tree.items()}
+    tree["ConvBlock_0/Conv_0/kernel"] = (3, 3, stem_in, 8)
+    tree["logits/bias"], tree["logits/kernel"] = (head_out,), (1, 1, 8, head_out)
+    return tree
+
+
+def _leaf_shapes(variables):
+    from flax.core import meta
+
+    flat = jax.tree_util.tree_leaves_with_path(meta.unbox(variables)["params"])
+    return {"/".join(k.key for k in path): tuple(leaf.shape) for path, leaf in flat}
+
+
+class TestUpConv2x:
+    """models/unet.upconv2x: the decoder's upsample + 3x3 convolution on
+    the low-resolution map, from the same [3,3,Cin,Cout] kernel."""
+
+    @staticmethod
+    def _operands(rng, extent, channels):
+        (h, w), (cin, cout) = extent, channels
+        x = jnp.asarray(rng.normal(size=(2, h, w, cin)).astype(np.float32))
+        k = jnp.asarray(rng.normal(size=(3, 3, cin, cout)).astype(np.float32))
+        return x, k
+
+    @pytest.mark.parametrize("channels", _UPCONV_CHANNELS)
+    @pytest.mark.parametrize("extent", _UPCONV_EXTENTS)
+    def test_equals_upsample_then_conv(self, rng, extent, channels):
+        from psana_ray_tpu.models.unet import upconv2x
+
+        x, k = self._operands(rng, extent, channels)
+        want = np.asarray(_upsample_then_conv(x, k))
+        got = np.asarray(upconv2x(x, k))
+        assert got.shape == want.shape == (2, 2 * extent[0], 2 * extent[1], channels[1])
+        # every output pixel, the border among them: float32 summation
+        # order and nothing else (a wrong border tap reads ~1 RMS)
+        assert np.abs(got - want).max() <= 1e-5 * np.sqrt((want ** 2).mean())
+
+    @pytest.mark.parametrize("channels", _UPCONV_CHANNELS)
+    @pytest.mark.parametrize("extent", _UPCONV_EXTENTS)
+    def test_gradients_equal_upsample_then_conv(self, rng, extent, channels):
+        from psana_ray_tpu.models.unet import upconv2x
+
+        x, k = self._operands(rng, extent, channels)
+        ct = jnp.asarray(
+            rng.normal(size=(2, 2 * extent[0], 2 * extent[1], channels[1])).astype(np.float32)
+        )
+        want = jax.grad(lambda x, k: jnp.sum(_upsample_then_conv(x, k) * ct), (0, 1))(x, k)
+        got = jax.grad(lambda x, k: jnp.sum(upconv2x(x, k) * ct), (0, 1))(x, k)
+        for g, w_ in zip(got, want):
+            w_ = np.asarray(w_)
+            assert np.abs(np.asarray(g) - w_).max() <= 1e-5 * np.sqrt((w_ ** 2).mean())
+
+    def test_bf16_taps_are_summed_in_float32(self, rng):
+        """Compute dtype bf16: the summed taps are rounded ONCE, from
+        their float32 sum (not a sum of rounded taps)."""
+        from psana_ray_tpu.models.unet import _fold_taps
+
+        k = jnp.asarray(rng.normal(size=(3, 3, 2, 2)).astype(np.float32))
+        k4 = np.asarray(_fold_taps(_fold_taps(k, 0), 1))
+        assert k4.shape == (4, 4, 2, 2) and k4.dtype == np.float32
+        kn = np.asarray(k)
+        np.testing.assert_array_equal(k4[0, 0], kn[0, 0])
+        np.testing.assert_array_equal(k4[3, 3], kn[2, 2])
+        np.testing.assert_allclose(
+            k4[1, 2], kn[0, 1] + kn[0, 2] + kn[1, 1] + kn[1, 2], rtol=1e-6
+        )
+
+    @pytest.mark.parametrize("model_name", ["tpu_frozen", "tpu_group", "classic_group"])
+    def test_parameter_tree_is_written_out(self, model_name):
+        """The tree UpConv2x leaves behind is the one nn.Conv left:
+        every path and shape, Conv_2 and Conv_3 (the up-convolutions,
+        [3,3,Cin,Cout]) among them."""
+        from psana_ray_tpu.models import PeakNetUNetTPU
+
+        model, shape, want = {
+            "tpu_frozen": (PeakNetUNetTPU(features=(8, 16, 32), norm="frozen"),
+                           (1, 16, 16, 1), _TPU_FROZEN_TREE),
+            "tpu_group": (PeakNetUNetTPU(features=(8, 16, 32), norm="group"),
+                          (1, 16, 16, 1), _group_tree(_TPU_FROZEN_TREE, 4, 4)),
+            "classic_group": (PeakNetUNet(features=(8, 16, 32), norm="group"),
+                              (1, 8, 8, 1), _group_tree(_TPU_FROZEN_TREE, 1, 1)),
+        }[model_name]
+        assert _leaf_shapes(model.init(jax.random.key(0), jnp.zeros(shape))) == want
+
+    def test_reference_forward_agrees_on_the_tree(self, rng):
+        """benchmark/reference/peaknet.py reads the tree by path
+        (params['Conv_<levels-1+j>']) and upsamples then convolves: it
+        agrees with the model in float32."""
+        from flax.core import meta
+
+        from benchmark.reference import peaknet as reference
+        from psana_ray_tpu.models import PeakNetUNetTPU
+
+        model = PeakNetUNetTPU(features=(8, 16, 32), norm="frozen", dtype=jnp.float32)
+        x = jnp.asarray(rng.normal(size=(2, 32, 48, 1)).astype(np.float32))
+        variables = meta.unbox(model.init(jax.random.key(1), x))
+        got = np.asarray(model.apply(variables, x))
+        want = np.asarray(reference.forward(variables["params"], x, 2))
+        assert np.abs(got - want).max() <= 1e-4 * np.sqrt((want ** 2).mean())
+
+    @pytest.mark.parametrize("model_name", ["tpu", "classic"])
+    def test_no_upsampled_tensor_in_the_program(self, model_name):
+        """The old form's mark: a 6-D broadcast [N,H,2,W,2,C]."""
+        import re
+
+        from psana_ray_tpu.models import PeakNetUNetTPU
+
+        model = {"tpu": PeakNetUNetTPU(features=(8, 16, 32)),
+                 "classic": PeakNetUNet(features=(8, 16, 32))}[model_name]
+        x = jnp.zeros((1, 16, 16, 1))
+        text = jax.jit(model.apply).lower(model.init(jax.random.key(0), x), x).as_text()
+        six_d = [ln for ln in text.splitlines() if "broadcast_in_dim" in ln
+                 and re.search(r"-> tensor<(\d+x){6}", ln)]
+        assert "conv" in text and not six_d
 
 
 class TestUNetTPU:

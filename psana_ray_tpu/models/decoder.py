@@ -3,24 +3,31 @@
 Nothing else in ``models/`` has an RMS norm, a rotary embedding, a gated
 MLP or a block assembled from a configuration (``vit.py`` hard-codes
 LayerNorm, GELU and a position table). This module builds the text
-decoder of a vision-language model from the keys of its published
-``config.json``:
+decoder of a language model from the keys of its published
+``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B):
 
-    x  -> x + Wo . attention(rms(x))          pre-norm, per-head q/k RMS norm,
-    x  -> x + mlp(rms(x))                     multimodal rotary on q and k
+    x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
+    x  -> x + ff(rms(x))                      two kinds, its ff one of two
 
-with grouped-query heads; attention either plain causal or restricted, per
-query, to the ``topk`` keys a learned indexer ranks highest
-(``parallel/sparse_attention.py``); the MLP either a dense gated-SiLU one
-or top-k of ``num_experts`` experts without dropped tokens
-(``parallel/moe.dropless_moe``), of which this holder may hold a share
-(``experts_held``).
+A layer's OPERATOR (``layer_types``) is grouped-query attention (a
+per-head RMS norm on q and k, then the rotary, multimodal or on the
+sequence index), plain causal or restricted, per query, to the ``topk``
+keys a learned indexer ranks highest (``parallel/sparse_attention.py``);
+or a gated short convolution (:func:`gated_short_conv`). Its FEED-FORWARD
+is a dense gated-SiLU MLP (the first ``num_dense_layers``, or all where
+there are no experts) or top-k of ``num_experts`` experts without dropped
+tokens (``parallel/moe.dropless_moe``; a softmax router, or sigmoid
+affinities under a selection bias), of which this holder may hold a
+share (``experts_held``). The trunk runs that schedule over ``B``
+sequences of ``S`` tokens held as ``[B*S, D]`` rows: what mixes tokens
+(attention, the convolution, the rotary) is told ``B`` and stays inside
+a sequence; the expert layer sorts all ``B*S`` rows at once.
 
-:func:`frame_step` is the serving step of a FRAME READER: one detector
-frame, calibrated on the device, cut into patches, embedded by a linear
-patch embedding (standing in for the model's vision tower), followed by a
-text prompt, read through the trunk; the logits of the next token come
-back with a small statistics vector (:data:`STEP_STATS`) that
+:func:`frame_step` is the serving step of a FRAME READER: ``B`` detector
+frames, calibrated on the device, cut into patches, embedded by a linear
+patch embedding (standing in for a vision tower), each followed by the
+text prompt, read through the trunk; the logits of each frame's next token
+come back with a small statistics vector (:data:`STEP_STATS`) that
 :func:`fold_step_stats` adds to a pipeline's counters. Weights are an
 ARGUMENT of the step: one step keys alike in the compile cache from every
 entry point.
@@ -38,16 +45,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from psana_ray_tpu.ops.short_conv import gated_conv_taps
 from psana_ray_tpu.parallel import sparse_attention as sa
 from psana_ray_tpu.parallel.moe import dropless_moe
 
-# what frame_step's statistics vector holds, summed over the layers
+# what frame_step's statistics vector holds: the first four summed over the
+# batch and over the layers that have the thing counted
 STEP_STATS = (
     "expert_tokens_max_total",   # the busiest held expert's token slots
-    "expert_tokens_mean_total",  # token slots per expert, were the load even: S * k / E
-    "attn_tiles_live_total",     # 512 x 512 tiles at or below the diagonal with a selected pair
+    "expert_tokens_mean_total",  # token slots per expert, were the load even: B * S * k / E
+    "attn_tiles_live_total",     # 512 x 512 tiles at or below the diagonal with an attended pair
     "attn_tiles_causal_total",   # all such tiles
+    "decoder_tokens_total",      # tokens the step served: B * S
+    "decoder_sequences_total",   # sequences (frames) the step served: B
 )
+ATTENTION, CONV = "full_attention", "conv"  # layer_types, as config.json spells them
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,7 +72,12 @@ class DecoderConfig:
     vocab_size: int
     rms_eps: float
     rope_theta: float
-    mrope_section: Tuple[int, int, int]
+    # multimodal rotary over (t, h, w) positions; None: plain rotary on the sequence index
+    mrope_section: Optional[Tuple[int, int, int]] = None
+    # each layer's operator, ATTENTION or CONV (empty: attention in every layer)
+    layer_types: Tuple[str, ...] = ()
+    conv_taps: int = 3  # of the gated short convolution (conv_L_cache)
+    tie_embedding: bool = False  # the output head is the embedding table
     # learned sparse attention (None: plain causal attention)
     indexer_heads: Optional[int] = None
     indexer_head_dim: int = 0
@@ -72,12 +89,22 @@ class DecoderConfig:
     q_tile: int = 128  # of the selection kernel (a query tile's whole score row sits in VMEM)
     kv_tile: int = 512  # sa_config's kv_chunk_size
     attn_q_tile: int = 256  # of the attention kernel, a multiple of q_tile
+    # of the maskless causal kernel (no selection: q_tile and kv_tile do not bind it). On the v5e
+    # at 4 x 8,704 tokens, heads of 64: 1088 x 1088 25.5 ms a layer, 512 x 1088 27.0, 256 x 2176
+    # 27.3, 512 x 512 33.1, 256 x 512 40.5, 256 x 256 74.6 (my chip runs, PR 38)
+    causal_q_tile: int = 1088
+    causal_kv_tile: int = 1088
     # experts (num_experts 0: a dense gated MLP of intermediate_size)
     num_experts: int = 0
     experts_per_token: int = 0
     expert_width: int = 0
     experts_held: Tuple[int, int] = (0, 0)
     norm_topk_prob: bool = True
+    num_dense_layers: int = 0  # leading layers whose MLP is dense although there are experts
+    router_scoring: str = "softmax"  # or "sigmoid" (moe.SCORINGS)
+    expert_bias: bool = False  # experts CHOSEN by score + bias, weighted by the score alone
+    gate_eps: float = 0.0  # in the renormalising sum of the chosen scores
+    routed_scaling_factor: float = 1.0
     intermediate_size: int = 0
     patch: int = 8
 
@@ -85,19 +112,42 @@ class DecoderConfig:
     def patch_dim(self) -> int:
         return self.patch * self.patch
 
+    def layer_kind(self, i: int) -> Tuple[str, bool]:
+        """``(operator, has experts)`` of layer ``i``."""
+        op = self.layer_types[i] if self.layer_types else ATTENTION
+        return op, bool(self.num_experts) and i >= self.num_dense_layers
+
     @classmethod
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
-        benchmark's configuration file repeats them), plus ``patch`` and
-        ``experts_held``."""
+        benchmark's configuration file repeats them), plus ``patch``,
+        ``experts_held`` and ``tie_embedding``. Keye-VL-2.0's spelling
+        (``head_dim``, ``rms_norm_eps``, ``rope_scaling.mrope_section``,
+        ``sa_config``) and LFM2's (``norm_eps``, ``layer_types``,
+        ``num_dense_layers``, ``conv_L_cache``, ``use_expert_bias`` with
+        ``routed_scaling_factor``: sigmoid affinities, DeepSeek-V3's router;
+        no ``head_dim``: hidden / heads; no ``rope_scaling``: plain rotary)
+        are both read."""
         sa_cfg = m.get("sa_config")
         n_exp = int(m.get("num_experts", 0))
+        n_layers, heads = int(m["num_hidden_layers"]), int(m["num_attention_heads"])
+        layer_types = tuple(m.get("layer_types", ()))
+        if layer_types and (len(layer_types) != n_layers
+                            or set(layer_types) - {ATTENTION, CONV}):
+            raise ValueError(f"layer_types {layer_types} does not name {n_layers} layers' "
+                             f"operators, each {ATTENTION!r} or {CONV!r}")
+        mrope = (m.get("rope_scaling") or {}).get("mrope_section")
+        sigmoid = "use_expert_bias" in m
         return cls(
-            hidden_size=int(m["hidden_size"]), num_layers=int(m["num_hidden_layers"]),
-            num_heads=int(m["num_attention_heads"]), num_kv_heads=int(m["num_key_value_heads"]),
-            head_dim=int(m["head_dim"]), vocab_size=int(m["vocab_size"]),
-            rms_eps=float(m["rms_norm_eps"]), rope_theta=float(m["rope_theta"]),
-            mrope_section=tuple(int(v) for v in m["rope_scaling"]["mrope_section"]),
+            hidden_size=int(m["hidden_size"]), num_layers=n_layers,
+            num_heads=heads, num_kv_heads=int(m["num_key_value_heads"]),
+            head_dim=int(m.get("head_dim") or int(m["hidden_size"]) // heads),
+            vocab_size=int(m["vocab_size"]),
+            rms_eps=float(m["rms_norm_eps"] if "rms_norm_eps" in m else m["norm_eps"]),
+            rope_theta=float(m["rope_theta"]),
+            mrope_section=tuple(int(v) for v in mrope) if mrope else None,
+            layer_types=layer_types, conv_taps=int(m.get("conv_L_cache", 3)),
+            tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
             indexer_heads=int(sa_cfg["indexer_num_heads"]) if sa_cfg else None,
             indexer_head_dim=int(sa_cfg["indexer_head_dim"]) if sa_cfg else 0,
             topk=int(sa_cfg["topk"]) if sa_cfg else 0,
@@ -106,6 +156,11 @@ class DecoderConfig:
             expert_width=int(m.get("moe_intermediate_size", 0)),
             experts_held=tuple(int(v) for v in m.get("experts_held", (0, n_exp))),
             norm_topk_prob=bool(m.get("norm_topk_prob", True)),
+            num_dense_layers=int(m.get("num_dense_layers", 0)),
+            router_scoring="sigmoid" if sigmoid else "softmax",
+            expert_bias=bool(m.get("use_expert_bias", False)),
+            gate_eps=1e-6 if sigmoid else 0.0,
+            routed_scaling_factor=float(m.get("routed_scaling_factor", 1.0)),
             intermediate_size=int(m.get("intermediate_size", 0)),
             patch=int(m.get("patch", 8)),
         )
@@ -116,39 +171,50 @@ class DecoderConfig:
 # ---------------------------------------------------------------------------
 
 def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
-    """normal(0, 0.02) matrices and unit gains, as one tree:
-    ``{"patch", "embed", "layers": [..], "norm", "head"}``. Call under
-    ``jax.jit`` to make the weights on the device."""
+    """normal(0, 0.02) matrices (the router's selection bias too, in
+    float32) and unit gains, as one tree: ``{"patch", "embed", "layers":
+    [..], "norm", "head"}`` (no ``head`` where it is the embedding). Call
+    under ``jax.jit`` to make the weights on the device."""
     d, hd = cfg.hidden_size, cfg.head_dim
     keys = iter(jax.random.split(key, 16 * cfg.num_layers + 8))
 
-    def w(*shape):
+    def w(*shape, dtype=dtype):
         return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(dtype)
 
     def gain(n):
         return jnp.ones((n,), dtype)
 
     layers = []
-    for _ in range(cfg.num_layers):
-        p = {
-            "norm1": gain(d), "wq": w(d, cfg.num_heads * hd), "wk": w(d, cfg.num_kv_heads * hd),
-            "wv": w(d, cfg.num_kv_heads * hd), "q_norm": gain(hd), "k_norm": gain(hd),
-            "wo": w(cfg.num_heads * hd, d), "norm2": gain(d),
-        }
-        if cfg.indexer_heads:
-            di = cfg.indexer_head_dim
-            p.update(idx_wq=w(d, cfg.indexer_heads * di), idx_wk=w(d, di),
-                     idx_k_norm=gain(di), idx_ww=w(d, cfg.indexer_heads))
-        if cfg.num_experts:
+    for i in range(cfg.num_layers):
+        op, experts = cfg.layer_kind(i)
+        if op == CONV:
+            p = {"norm1": gain(d), "w_in": w(d, 3 * d), "conv_w": w(d, cfg.conv_taps),
+                 "w_out": w(d, d), "norm2": gain(d)}
+        else:
+            p = {
+                "norm1": gain(d), "wq": w(d, cfg.num_heads * hd), "wk": w(d, cfg.num_kv_heads * hd),
+                "wv": w(d, cfg.num_kv_heads * hd), "q_norm": gain(hd), "k_norm": gain(hd),
+                "wo": w(cfg.num_heads * hd, d), "norm2": gain(d),
+            }
+            if cfg.indexer_heads:
+                di = cfg.indexer_head_dim
+                p.update(idx_wq=w(d, cfg.indexer_heads * di), idx_wk=w(d, di),
+                         idx_k_norm=gain(di), idx_ww=w(d, cfg.indexer_heads))
+        if experts:
             held = cfg.experts_held[1]
             p.update(router=w(d, cfg.num_experts), w_gate=w(held, d, cfg.expert_width),
                      w_up=w(held, d, cfg.expert_width), w_down=w(held, cfg.expert_width, d))
+            if cfg.expert_bias:
+                p.update(router_bias=w(cfg.num_experts, dtype=jnp.float32))
         else:
             p.update(w_gate=w(d, cfg.intermediate_size), w_up=w(d, cfg.intermediate_size),
                      w_down=w(cfg.intermediate_size, d))
         layers.append(p)
-    return {"patch": w(cfg.patch_dim, d), "embed": w(cfg.vocab_size, d), "layers": layers,
-            "norm": gain(d), "head": w(d, cfg.vocab_size)}
+    params = {"patch": w(cfg.patch_dim, d), "embed": w(cfg.vocab_size, d), "layers": layers,
+              "norm": gain(d)}
+    if not cfg.tie_embedding:
+        params["head"] = w(d, cfg.vocab_size)
+    return params
 
 
 # ---------------------------------------------------------------------------
@@ -226,58 +292,101 @@ def _dense_mlp(p, b):
     return _mm(h, p["w_down"]).astype(b.dtype)
 
 
-def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig):
-    """One block: ``x [S, D]`` -> ``(x, stats [4] float32)``. Each part is
-    a call of its own under its scope (``proj``, ``indexer``,
-    ``sparse_attn``, ``moe``): a scope reaches the chip's profile only on
-    ops inlined from a call."""
-    s = x.shape[0]
+def gated_short_conv(p, x, batch: int, cfg: DecoderConfig):
+    """LFM2's operator on ``x [B*S, D]`` -> ``x + Op``: with ``[B | C | z]
+    = rms(x) W_in``, a causal depthwise convolution of ``conv_taps`` taps
+    over ``u = B * z`` (tap ``j`` meets ``u[t - (taps - 1) + j]``, zeros
+    before each of the ``batch`` sequences, no bias, no activation), gated
+    by ``C``, then ``W_out``. The gates and taps are one pass between the
+    two matrix products (``ops/short_conv.gated_conv_taps``)."""
+    dt = x.dtype
+    a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
+    y = gated_conv_taps(_mm(a, p["w_in"]).astype(dt), p["conv_w"], seq_len=x.shape[0] // batch)
+    return x + _mm(y, p["w_out"]).astype(dt)
+
+
+def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
+    """The attention operator on ``x [B*S, D]`` -> ``(x + Op, live,
+    causal)``, the last two the layer's statistics tiles. Each part is a
+    call of its own under its scope (``proj``, ``indexer``,
+    ``sparse_attn``): a scope reaches the chip's profile only on ops
+    inlined from a call."""
+    s = x.shape[0] // batch
     with jax.named_scope("proj"):
         a, q, k, v = jax.jit(_projections, static_argnums=3)(p, x, angles, cfg)
-    with jax.named_scope("indexer"):
-        if cfg.indexer_heads:
+    if cfg.indexer_heads:
+        if batch != 1:
+            raise ValueError(f"a learned key selection is per sequence: batch {batch} is not 1")
+        with jax.named_scope("indexer"):
             mask, flags = jax.jit(_indexer, static_argnums=3)(p, a, idx_angles, cfg)
-        else:
-            mask, flags = sa.causal_tiles(s, cfg.q_tile, cfg.kv_tile)
-        live, causal = sa.live_tiles(flags, mask.shape[2], mask.shape[3])
-    with jax.named_scope("sparse_attn"):
-        o = jax.jit(sa.masked_gqa_attention, static_argnames=("num_kv_heads", "block_q"))(
-            q, k, v, mask, num_kv_heads=cfg.num_kv_heads,
-            block_q=max(cfg.attn_q_tile, mask.shape[2]))
+            live, causal = sa.live_tiles(flags, mask.shape[2], mask.shape[3])
+        with jax.named_scope("sparse_attn"):
+            o = jax.jit(sa.masked_gqa_attention, static_argnames=("num_kv_heads", "block_q"))(
+                q, k, v, mask, num_kv_heads=cfg.num_kv_heads,
+                block_q=max(cfg.attn_q_tile, mask.shape[2]))
+    else:
+        live = causal = batch * sa.causal_tile_count(s)  # every earlier key is attended
+        with jax.named_scope("sparse_attn"):
+            o = jax.jit(sa.masked_gqa_attention,
+                        static_argnames=("num_kv_heads", "block_q", "block_k"))(
+                *(u.reshape(batch, s, -1) for u in (q, k, v)), num_kv_heads=cfg.num_kv_heads,
+                block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile).reshape(x.shape[0], -1)
     with jax.named_scope("proj"):
         x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(x, o, p["wo"])
-    with jax.named_scope("moe"):
+    return x, live, causal
+
+
+def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int = 1):
+    """One block: ``x [B*S, D]`` -> ``(x, stats [4] float32)``, the first
+    four of :data:`STEP_STATS`. ``kind`` is ``cfg.layer_kind(i)``: the
+    operator runs under ``conv`` or attention's scopes, the feed-forward
+    under ``moe`` (experts) or ``mlp`` (dense)."""
+    op, experts = kind
+    live, causal = 0, 0
+    if op == CONV:
+        with jax.named_scope("conv"):
+            x = jax.jit(gated_short_conv, static_argnums=(2, 3))(p, x, batch, cfg)
+    else:
+        x, live, causal = _attention(p, x, angles, idx_angles, batch, cfg)
+    with jax.named_scope("moe" if experts else "mlp"):
         def mlp(p, x):
             b = rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype)
-            if not cfg.num_experts:
+            if not experts:
                 return x + _dense_mlp(p, b), jnp.zeros((), jnp.int32)
             y, tokens = dropless_moe(
                 b, p["router"], p["w_gate"], p["w_up"], p["w_down"], k=cfg.experts_per_token,
                 num_experts=cfg.num_experts, experts_held=cfg.experts_held,
-                renormalise=cfg.norm_topk_prob)
+                renormalise=cfg.norm_topk_prob, scoring=cfg.router_scoring,
+                select_bias=p.get("router_bias"), gate_eps=cfg.gate_eps,
+                gate_scale=cfg.routed_scaling_factor)
             return x + y, jnp.max(tokens)
 
         x, busiest = jax.jit(mlp)(p, x)
-    even = s * cfg.experts_per_token / cfg.num_experts if cfg.num_experts else 0.0
+    even = x.shape[0] * cfg.experts_per_token / cfg.num_experts if experts else 0.0
     stats = jnp.stack([busiest.astype(jnp.float32), jnp.float32(even),
-                       live.astype(jnp.float32), jnp.float32(causal)])
+                       jnp.asarray(live, jnp.float32), jnp.float32(causal)])
     return x, stats
 
 
-def trunk(params, x, pos, cfg: DecoderConfig):
-    """``x [S, D]`` embedded tokens at ``pos [S, 3]`` (static) through
-    every layer -> ``(x [S, D], stats [4])``."""
+def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
+    """``x [B*S, D]``, the embedded tokens of ``batch`` sequences one after
+    the other, each at ``pos`` (static: ``[S, 3]`` under the multimodal
+    rotary, else ``[S]``), through every layer -> ``(x [B*S, D], stats
+    [6] in :data:`STEP_STATS`' order)``."""
+    s = x.shape[0] // batch
     pairs = cfg.head_dim // 2
     angles = rotary_angles(pos, cfg.rope_theta, pairs, cfg.mrope_section)
     idx_angles = None
     if cfg.indexer_heads:
         # the indexer's vectors turn with the sequence index alone
-        idx_angles = rotary_angles(np.arange(x.shape[0]), cfg.rope_theta, cfg.indexer_head_dim // 2)
-    stats = jnp.zeros((len(STEP_STATS),), jnp.float32)
-    for p in params["layers"]:
-        x, layer_stats = decoder_layer(p, x, angles, idx_angles, cfg)
+        idx_angles = rotary_angles(np.arange(s), cfg.rope_theta, cfg.indexer_head_dim // 2)
+    if batch != 1:
+        angles = jnp.tile(angles, (batch, 1))
+    stats = jnp.zeros((4,), jnp.float32)
+    for i, p in enumerate(params["layers"]):
+        x, layer_stats = decoder_layer(p, x, angles, idx_angles, cfg, cfg.layer_kind(i), batch)
         stats = stats + layer_stats
-    return x, stats
+    return x, jnp.concatenate([stats, jnp.asarray([batch * s, batch], jnp.float32)])
 
 
 def embed(params, patches, prompt_ids):
@@ -290,38 +399,52 @@ def embed(params, patches, prompt_ids):
     ])
 
 
+def head_params(params) -> dict:
+    """What :func:`logits_of` reads of the tree: the final gain, and the
+    output head or, where the two are tied, the embedding table."""
+    table = "head" if "head" in params else "embed"
+    return {"norm": params["norm"], table: params[table]}
+
+
 def logits_of(params, x, cfg: DecoderConfig):
     """Final norm and output head on rows ``x [N, D]`` -> ``[N, V]`` float32."""
-    return _mm(rms_norm(x, params["norm"], cfg.rms_eps).astype(x.dtype), params["head"])
+    a = rms_norm(x, params["norm"], cfg.rms_eps).astype(x.dtype)
+    if cfg.tie_embedding:
+        return jax.lax.dot_general(a, params["embed"], (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+    return _mm(a, params["head"])
 
 
 def frame_hidden(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, threshold: float):
-    """``frames [1, P, H, W]`` raw (batch ONE: a frame is a sequence),
-    calibrated, cut into patches, embedded and followed by the prompt,
-    through the trunk -> ``(x [S, D] at every token, stats [4] float32 in
-    :data:`STEP_STATS`' order)``."""
+    """``frames [B, P, H, W]`` raw (a frame is a sequence), calibrated,
+    cut into patches, embedded and each followed by the prompt, through
+    the trunk -> ``(x [B*S, D] at every token, frame after frame, stats
+    [6] float32 in :data:`STEP_STATS`' order)``."""
     from psana_ray_tpu.models.vit import patchify_panels
     from psana_ray_tpu.ops import fused_calibrate
 
-    if frames.shape[0] != 1:
-        raise ValueError(f"a frame is one sequence: batch {frames.shape[0]} is not 1")
-    _, panels, height, width = frames.shape
-    pos = frame_positions(panels, height // cfg.patch, width // cfg.patch, prompt_ids.shape[0])
+    batch, panels, height, width = frames.shape
+    n_prompt = prompt_ids.shape[0]
+    if cfg.mrope_section:
+        pos = frame_positions(panels, height // cfg.patch, width // cfg.patch, n_prompt)
+    else:  # the index within the frame's own sequence
+        pos = np.arange(panels * (height // cfg.patch) * (width // cfg.patch) + n_prompt)
     with jax.named_scope("calib"):
         x = fused_calibrate(frames, *calib, threshold=threshold, out_dtype=jnp.bfloat16)
     with jax.named_scope("embed"):
-        x = jax.jit(lambda p, x, ids: embed(p, patchify_panels(x, cfg.patch)[0], ids))(
+        x = jax.jit(lambda p, x, ids: jnp.concatenate(
+            [embed(p, frame, ids) for frame in patchify_panels(x, cfg.patch)]))(
             {"patch": params["patch"], "embed": params["embed"]}, x, prompt_ids)
-    return trunk(params, x, pos, cfg)
+    return trunk(params, x, pos, cfg, batch)
 
 
 def frame_step(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, threshold: float):
-    """The serving step: :func:`frame_hidden`, then the logits of the
-    next token -> ``(logits [1, V] float32, stats [4] float32)``."""
+    """The serving step: :func:`frame_hidden`, then the logits of each
+    frame's next token -> ``(logits [B, V] float32, stats [6] float32)``."""
     x, stats = frame_hidden(params, calib, frames, prompt_ids, cfg=cfg, threshold=threshold)
+    s = x.shape[0] // frames.shape[0]
     with jax.named_scope("head"):
-        logits = jax.jit(lambda p, x: logits_of(p, x, cfg))(
-            {"norm": params["norm"], "head": params["head"]}, x[-1:])
+        logits = jax.jit(lambda p, x: logits_of(p, x, cfg))(head_params(params), x[s - 1::s])
     return logits, stats
 
 

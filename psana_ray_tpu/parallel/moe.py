@@ -180,15 +180,30 @@ def total_aux_loss(intermediates) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def route_top_k(probs: jax.Array, k: int, renormalise: bool = True):
+def route_top_k(probs: jax.Array, k: int, renormalise: bool = True, *, select_bias=None,
+                gate_eps: float = 0.0, gate_scale: float = 1.0):
     """``probs [T, E]`` float32 -> ``(ids [T, k] int32, gates [T, k]
-    float32)``: each token's ``k`` most probable experts (equal
-    probabilities: the lower index first) and their gates, renormalised
-    to sum to one over the ``k`` (``norm_topk_prob``) or as they are."""
-    top_p, ids = jax.lax.top_k(probs, k)
+    float32)``: each token's ``k`` highest-scoring experts (equal scores:
+    the lower index first) and their gates, renormalised to sum to one
+    over the ``k`` (``norm_topk_prob``) or as they are. With
+    ``select_bias [E]`` the experts are CHOSEN by ``probs + select_bias``
+    and WEIGHTED by ``probs`` without it (DeepSeek-V3's bias-steered
+    selection); ``gate_eps`` joins the renormalising sum and
+    ``gate_scale`` multiplies the gates (``routed_scaling_factor``)."""
+    if select_bias is None:
+        top_p, ids = jax.lax.top_k(probs, k)
+    else:
+        _, ids = jax.lax.top_k(probs + select_bias.astype(probs.dtype), k)
+        top_p = jnp.take_along_axis(probs, ids, axis=-1)
     if renormalise:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / (total + gate_eps if gate_eps else total)
+    if gate_scale != 1.0:
+        top_p = top_p * gate_scale
     return ids.astype(jnp.int32), top_p
+
+
+SCORINGS = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1), "sigmoid": jax.nn.sigmoid}
 
 
 def _grouped_product(rows, weights, tokens, first, out_dtype, interpret):
@@ -198,26 +213,37 @@ def _grouped_product(rows, weights, tokens, first, out_dtype, interpret):
     the down product 6.4, against ``lax.ragged_dot``'s 11.3 and 11.0 (my
     chip runs, PR 36). The row tile divides the row count; the other two
     tiles are the whole contraction and output widths, which is what was
-    fastest of the tilings tried."""
+    fastest of the tilings tried, as long as a weight tile stays within 4
+    MiB: at ``2048 x 1792`` (LFM2's experts) the whole widths take 47.5 MB
+    of VMEM, over Mosaic's 44, and the output width goes in the largest
+    128-multiple that divides it and fits (896; 1024 of the down product's
+    2048)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     m, k = rows.shape
     n = weights.shape[-1]
     tm = next((t for t in (512, 256, 128, 64, 32, 16, 8) if m % t == 0 and t * k <= 512 * 1024), m)
+    tk, tn = min(k, 2048), min(n, 2048)
+    fits = 4 * 1024 * 1024 // (2 * tk)  # output columns of a bf16 weight tile within 4 MiB
+    if tn > fits:
+        tn = next((t for t in range(tn - tn % 128, 0, -128) if n % t == 0 and t <= fits), tn)
     return gmm(rows, weights, tokens, preferred_element_type=out_dtype,
-               tiling=(tm, min(k, 2048), min(n, 2048)), group_offset=jnp.int32(first),
-               interpret=interpret)
+               tiling=(tm, tk, tn), group_offset=jnp.int32(first), interpret=interpret)
 
 
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
-                 experts_held=None, renormalise: bool = True, interpret=None):
+                 experts_held=None, renormalise: bool = True, scoring: str = "softmax",
+                 select_bias=None, gate_eps: float = 0.0, gate_scale: float = 1.0,
+                 interpret=None):
     """Top-``k`` of ``num_experts`` gated-SiLU experts with NO dropped
     token: ``x [T, D]`` -> ``(y [T, D], tokens [count] int32)``.
 
     The layer is told which experts it holds, ``experts_held = (first,
     count)`` (default: all), and given THEIR weights only (``w_gate, w_up
     [count, D, F]``, ``w_down [count, F, D]``). It routes over all
-    ``num_experts`` (``router_w [D, E]``, probabilities in float32), and
+    ``num_experts`` (``router_w [D, E]``; the affinities, ``scoring``
+    ``"softmax"`` or ``"sigmoid"`` of the logits, in float32; ``select_bias``,
+    ``gate_eps`` and ``gate_scale`` as :func:`route_top_k` takes them), and
     returns its own experts' part of the result: the sum over the chosen
     experts that live here of ``gate * (silu(x W_gate) * (x W_up))
     W_down``; what the absent experts would add is left out, and the
@@ -240,7 +266,9 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
         interpret = jax.default_backend() != "tpu"
     with jax.named_scope("moe_route"):
         logits = jnp.dot(x, router_w.astype(x.dtype), preferred_element_type=jnp.float32)
-        ids, gates = route_top_k(jax.nn.softmax(logits, axis=-1), k, renormalise)
+        ids, gates = route_top_k(SCORINGS[scoring](logits), k, renormalise,
+                                 select_bias=select_bias, gate_eps=gate_eps,
+                                 gate_scale=gate_scale)
         held = (ids >= first) & (ids < first + count)
         flat = ids.reshape(-1)
         order = jnp.argsort(flat, stable=True)

@@ -28,7 +28,10 @@ What is here, and what it is:
   the optimisation this form is the yardstick for. The ``G`` key-value
   heads each serve ``H/G`` query heads, whose query tiles are stacked
   into one ``[H/G * bq, d]`` operand so that a key tile is loaded once
-  for all of them.
+  for all of them. WITHOUT a mask it is plain causal attention over a
+  batch of sequences: no mask operand, and a grid that holds the tiles at
+  or below the diagonal only (a table of ``(query tile, key tile)`` pairs
+  rides in as scalar prefetch), at any head width (heads of 64: LFM2).
 - :func:`live_tiles` — how many ``stat_tile`` x ``stat_tile`` tiles at
   or below the diagonal hold a selected pair (from the flags the selection
   kernel writes beside its mask), and how many there are: what a
@@ -212,14 +215,12 @@ def mask_to_dense(mask: jax.Array) -> jax.Array:
     return jnp.transpose(mask, (0, 2, 1, 3)).reshape(n_qb * bq, n_kb * bk) != 0
 
 
-def causal_tiles(s: int, block_q: int = 128, block_k: int = 512) -> Tuple[jax.Array, jax.Array]:
-    """The mask and tile flags of plain causal attention in
-    :func:`select_keys`' form: what a layer without an indexer hands the
-    attention kernel."""
-    bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
-    dense = jnp.tril(jnp.ones((s, s), jnp.int8))
-    mask = jnp.transpose(dense.reshape(s // bq, bq, s // bk, bk), (0, 2, 1, 3))
-    return mask, jnp.any(mask != 0, axis=(2, 3)).astype(jnp.int32)
+def causal_tile_count(s: int, stat_tile: int = 512) -> int:
+    """How many ``stat_tile``-square tiles lie at or below the diagonal of
+    one sequence of ``s`` tokens: what :func:`live_tiles` counts as
+    ``causal``, and as ``live`` too where every earlier key is attended."""
+    n = s // pick_tile(s, stat_tile)
+    return n * (n + 1) // 2
 
 
 def live_tiles(live: jax.Array, block_q: int, block_k: int,
@@ -283,14 +284,102 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
             o_ref[:, h * d:(h + 1) * d] = out[h * block_q:(h + 1) * block_q].astype(o_ref.dtype)
 
 
-def masked_gqa_attention(q, k, v, mask, *, num_kv_heads: int, block_q: Optional[int] = None,
-                         interpret: Optional[bool] = None) -> jax.Array:
+def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+                   *, block_q, block_k):
+    t = pl.program_id(2)
+    qi, kb = qi_ref[t], kb_ref[t]
+    rep, _, d = q_ref.shape
+    rows = rep * block_q  # the group's query heads, stacked: one product serves them all
+
+    @pl.when(kb == 0)
+    def _reset():
+        m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def update(with_diagonal):
+        v = v_ref[...]
+        s = jax.lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if with_diagonal:  # key 0 of the sequence is open to every row: m is finite from tile 0
+            row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+            col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+            s = jnp.where((col <= row)[None], s.reshape(rep, block_q, block_k),
+                          NEG_INF).reshape(rows, block_k)
+        m = m_ref[:]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(p.astype(v.dtype), v,
+                                                  preferred_element_type=jnp.float32)
+        m_ref[:] = m_new
+
+    below = (kb + 1) * block_k - 1 <= qi * block_q  # every pair of the tile is causal
+    pl.when(below)(lambda: update(False))
+    pl.when(jnp.logical_not(below))(lambda: update(True))
+
+    @pl.when(kb == ((qi + 1) * block_q - 1) // block_k)  # the row's last tile
+    def _finalize():
+        o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(rep, block_q, d).astype(o_ref.dtype)
+
+
+def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool):
+    """The maskless form of :func:`masked_gqa_attention`. Operands go head
+    major (``[B, G, H/G, S, d]`` and ``[B, G, S, d]``: a block's last
+    dimension is then the whole head width, which Mosaic takes at 64 where
+    a 64-lane block of ``[S, G*64]`` it does not), and the grid's last axis
+    runs over the ``(query tile, key tile)`` pairs at or below the
+    diagonal, a row's key tiles in order, so a tile above it costs not
+    even a grid step."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, s, hd = q.shape
+    d = k.shape[2] // g
+    rep = hd // (g * d)
+    bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
+    pairs = [(i, j) for i in range(s // bq) for j in range(((i + 1) * bq - 1) // bk + 1)]
+    qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
+    q5 = jnp.transpose(q.reshape(b, s, g, rep, d), (0, 2, 3, 1, 4))
+    k4, v4 = (jnp.transpose(x.reshape(b, s, g, d), (0, 2, 1, 3)) for x in (k, v))
+    kv_spec = pl.BlockSpec((None, None, bk, d), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0))
+    q_spec = pl.BlockSpec((None, None, rep, bq, d), lambda bi, gi, t, qi, kb: (bi, gi, 0, qi[t], 0))
+    o5 = pl.pallas_call(
+        functools.partial(_causal_kernel, block_q=bq, block_k=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, g, len(pairs)),
+            in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((rep * bq, 1), jnp.float32),
+                pltpu.VMEM((rep * bq, 1), jnp.float32),
+                pltpu.VMEM((rep * bq, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(q5.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="masked_gqa_attention",
+    )(qi, kb, q5, k4, v4)
+    return jnp.transpose(o5, (0, 3, 1, 2, 4)).reshape(b, s, hd)
+
+
+def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Optional[int] = None,
+                         block_k: int = 512, interpret: Optional[bool] = None) -> jax.Array:
     """``q [S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [S, G*d]``,
     ``mask`` from :func:`select_keys` -> ``o [S, H*d]``: softmax attention
     of every query head over the keys its query selected, query head ``h``
     reading key-value head ``h // (H/G)``. Masked-dense: every causal tile
     is computed. ``block_q`` (a multiple of the mask's query tile, which is
-    the default) is this kernel's own query tile."""
+    the default) is this kernel's own query tile.
+
+    ``mask=None``: plain causal attention of a BATCH of sequences, ``q [B,
+    S, H*d]`` and ``k, v [B, S, G*d]`` -> ``[B, S, H*d]``, each sequence
+    on its own, in tiles of ``block_q`` (default 256) by ``block_k``; only
+    tiles at or below the diagonal are visited (:func:`_causal_attention`)."""
+    if mask is None:
+        return _causal_attention(q, k, v, int(num_kv_heads), block_q or 256, block_k,
+                                 _interpret(interpret))
     from jax.experimental.pallas import tpu as pltpu
 
     n_qb, n_kb, mq, bk = mask.shape

@@ -190,7 +190,59 @@ def _keye_experts():
                 S((128, 768, 2048), BF16)], 3
 
 
+LFM2_B, LFM2_S = 4, 8704  # four frames of 8,448 patches (16 x 16 pixels) + 256 prompt tokens
+
+
+def _lfm2_attention():
+    """The maskless causal form at LFM2's heads: 32 query heads on 8
+    key-value heads of 64, four sequences, a grid of the tiles at or below
+    the diagonal only (scalar-prefetched tile tables)."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    def fn(q, k, v):
+        return sa.masked_gqa_attention(q, k, v, num_kv_heads=8, block_q=256, block_k=512,
+                                       interpret=False)
+
+    kv = S((LFM2_B, LFM2_S, 512), BF16)
+    return fn, [S((LFM2_B, LFM2_S, 2048), BF16), kv, kv], 1
+
+
+def _lfm2_conv():
+    """The gated short convolution on 34,816 rows of 2,048: two matrix
+    products around the one-pass kernel of the gates and three taps."""
+    from psana_ray_tpu.models import decoder
+
+    cfg = decoder.DecoderConfig(hidden_size=2048, num_layers=1, num_heads=32, num_kv_heads=8,
+                                head_dim=64, vocab_size=65536, rms_eps=1e-5, rope_theta=1e6,
+                                layer_types=("conv",))
+
+    def fn(p, x):
+        return decoder.gated_short_conv(p, x, LFM2_B, cfg)
+
+    p = {"norm1": S((2048,), BF16), "w_in": S((2048, 6144), BF16), "conv_w": S((2048, 3), BF16),
+         "w_out": S((2048, 2048), BF16)}
+    return fn, [p, S((LFM2_B * LFM2_S, 2048), BF16)], 1
+
+
+def _lfm2_experts():
+    """The dropless expert layer at 32 experts of 2048 x 1792, top 4 under
+    the sigmoid router: the grouped product's output tile is cut to 896
+    (whole, it overflows Mosaic's scoped VMEM)."""
+    from psana_ray_tpu.parallel.moe import dropless_moe
+
+    def fn(x, router, bias, w_gate, w_up, w_down):
+        return dropless_moe(x, router, w_gate, w_up, w_down, k=4, num_experts=32,
+                            scoring="sigmoid", select_bias=bias, gate_eps=1e-6, interpret=False)
+
+    up = S((32, 2048, 1792), BF16)
+    return fn, [S((LFM2_B * LFM2_S, 2048), BF16), S((2048, 32), BF16), S((32,), F32), up, up,
+                S((32, 1792, 2048), BF16)], 3
+
+
 CASES = {
+    "lfm2_causal_gqa_attention_4x8704x64": _lfm2_attention,
+    "lfm2_gated_short_conv_34816": _lfm2_conv,
+    "lfm2_dropless_experts_34816x4": _lfm2_experts,
     "keye_select_keys_34304": _keye_select,
     "keye_masked_gqa_attention_34304": _keye_attention,
     "keye_dropless_experts_34304x8": _keye_experts,

@@ -21,7 +21,12 @@ affinities under a selection bias), of which this holder may hold a
 share (``experts_held``). The trunk runs that schedule over ``B``
 sequences of ``S`` tokens held as ``[B*S, D]`` rows: what mixes tokens
 (attention, the convolution, the rotary) is told ``B`` and stays inside
-a sequence; the expert layer sorts all ``B*S`` rows at once.
+a sequence; the expert layer sorts all ``B*S`` rows at once and moves each
+row once each way: one in-bounds gather into expert order
+(``ops/row_gather.py``), and on the way back a token's ``k`` rows read
+where the sort put them and summed under their gates in one pass, in the
+order of the token's choices, so a sequence's rows do not depend on its
+place in the batch.
 
 :func:`frame_step` is the serving step of a FRAME READER: ``B`` detector
 frames, calibrated on the device, cut into patches, embedded by a linear

@@ -11,6 +11,7 @@ rule's cheap guards).
 """
 
 import os
+import re
 import subprocess
 import sys
 
@@ -178,7 +179,8 @@ def _keye_attention():
 
 def _keye_experts():
     """The dropless expert layer at 128 experts of 2048 x 768, top 8:
-    three megablox grouped products over 274,432 sorted rows."""
+    the row gather's kernel, then three megablox grouped products over
+    274,432 sorted rows."""
     from psana_ray_tpu.parallel.moe import dropless_moe
 
     def fn(x, router, w_gate, w_up, w_down):
@@ -187,7 +189,7 @@ def _keye_experts():
 
     up = S((128, 2048, 768), BF16)
     return fn, [S((KEYE_S, 2048), BF16), S((2048, 128), BF16), up, up,
-                S((128, 768, 2048), BF16)], 3
+                S((128, 768, 2048), BF16)], 4, (KEYE_S, 8)
 
 
 LFM2_B, LFM2_S = 4, 8704  # four frames of 8,448 patches (16 x 16 pixels) + 256 prompt tokens
@@ -227,7 +229,8 @@ def _lfm2_conv():
 def _lfm2_experts():
     """The dropless expert layer at 32 experts of 2048 x 1792, top 4 under
     the sigmoid router: the grouped product's output tile is cut to 896
-    (whole, it overflows Mosaic's scoped VMEM)."""
+    (whole, it overflows Mosaic's scoped VMEM); the rows reach expert
+    order through the row gather's kernel."""
     from psana_ray_tpu.parallel.moe import dropless_moe
 
     def fn(x, router, bias, w_gate, w_up, w_down):
@@ -236,7 +239,7 @@ def _lfm2_experts():
 
     up = S((32, 2048, 1792), BF16)
     return fn, [S((LFM2_B * LFM2_S, 2048), BF16), S((2048, 32), BF16), S((32,), F32), up, up,
-                S((32, 1792, 2048), BF16)], 3
+                S((32, 1792, 2048), BF16)], 4, (LFM2_B * LFM2_S, 4)
 
 
 CASES = {
@@ -256,15 +259,33 @@ CASES = {
 }
 
 
+def _rows_move_once_each_way(text, tokens, k):
+    """The dropless expert layer as compiled (PR 39): no second pass over
+    the gathered ``[T*k, 2048]`` rows that fills where an index is out of
+    range (``jnp.take``'s default mode), no ``[T, k, 2048]`` array (at k 4
+    a relayout into half-filled tiles), and the three grouped products
+    under the name their roofline share is read by."""
+    entry = text[text.index("ENTRY"):]
+    filled = [line for line in entry.splitlines()
+              if f"[{tokens * k},2048]" in line.split(" fusion(")[0] and "select_n" in line]
+    assert not filled, filled
+    assert f"[{tokens},{k},2048]" not in entry
+    assert len(re.findall(r"^\s*(?:ROOT )?%gmm[.\d]* = ", entry, re.M)) == 3
+    assert len(re.findall(r"^\s*(?:ROOT )?%row_gather[.\d]* = ", entry, re.M)) == 1
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
-    fn, arg_shapes, min_mosaic = CASES[case]()
+    fn, arg_shapes, min_mosaic, *expert_layer = CASES[case]()
     # code that asks default_backend() would take its CPU (interpret)
     # branch under a described topology; steer it here, not in the program
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), arg_shapes)
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
-    assert compiled.as_text().count("tpu_custom_call") >= min_mosaic
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= min_mosaic
+    if expert_layer:
+        _rows_move_once_each_way(text, *expert_layer[0])
     mem = compiled.memory_analysis()
     # one v5e chip: 16 GB of HBM for arguments, outputs and temporaries
     assert (

@@ -17,6 +17,8 @@ import pytest
 from benchmark import harness
 from benchmark.reference import keye_decoder as ref
 from psana_ray_tpu.models import decoder
+from psana_ray_tpu.ops import row_gather
+from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
 from psana_ray_tpu.parallel.moe import dropless_moe
 
@@ -209,6 +211,145 @@ def test_the_four_shares_of_an_expert_layer_add_up_to_the_uncut_reference(scorin
     # and the reference, given one share, gives that share
     one, _ = experts_of(_held(p, 32, 32), b, {**m, "experts_held": (32, 32)}, jnp.float32)
     np.testing.assert_allclose(parts[1], np.asarray(one), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# (e') each row moves once each way: the dispatch and the combine against the
+# formulation they replaced (PR 38's, written out)
+# ---------------------------------------------------------------------------
+
+BF16_ULP = 2.0 ** -7  # a bfloat16 keeps 8 significant bits: neighbours lie at most 2**-7 of the value apart
+
+
+def _layer_as_it_was(p, x, k, experts, held):
+    """PR 38's ``dropless_moe`` in plain steps: a stable argsort of the
+    token slots, ``take`` (its default mode), the three grouped products as
+    one ``dot`` an expert, ``take`` back by the inverse permutation, the
+    gated float32 sum over ``k``. -> the sorted rows, the experts' rows,
+    the order, the gates, ``y``, ``tokens``."""
+    first, count = held
+    t, d = x.shape
+    logits = jnp.dot(x, p["router"].astype(x.dtype), preferred_element_type=jnp.float32)
+    ids, gates = moe.route_top_k(jax.nn.softmax(logits, axis=-1), k)
+    gates = jnp.where((ids >= first) & (ids < first + count), gates, 0.0)
+    flat = np.asarray(ids).reshape(-1)
+    order = np.argsort(flat, kind="stable")
+    rows = jnp.take(x, jnp.asarray(order // k), axis=0)
+    out = np.zeros((t * k, d), np.float32)
+    for e in range(first, first + count):
+        mine = np.flatnonzero(flat[order] == e)
+        if mine.size:
+            r = rows[mine]
+            h = jax.nn.silu(jnp.dot(r, p["w_gate"][e - first], preferred_element_type=jnp.float32))
+            h = (h * jnp.dot(r, p["w_up"][e - first], preferred_element_type=jnp.float32)).astype(x.dtype)
+            out[mine] = jnp.dot(h, p["w_down"][e - first], preferred_element_type=jnp.float32).astype(x.dtype)
+    out = jnp.asarray(out, x.dtype)
+    back = jnp.argsort(jnp.asarray(order))
+    y = jnp.take(out, back, axis=0).reshape(t, k, d)
+    y = jnp.sum(y.astype(jnp.float32) * gates[..., None], axis=1).astype(x.dtype)
+    tokens = np.bincount(flat, minlength=experts)[first:first + count]
+    return rows, out, jnp.asarray(order, jnp.int32), gates, y, tokens
+
+
+def _loaded_layer(t, k, experts, load, share, d=2048, width=16):
+    """An expert layer over bfloat16 rows of 2,048 (the width the row
+    gather's kernel takes: it runs here, interpreted) whose router gives
+    the ``load``; ``share``: the middle half of the experts is held."""
+    rng = np.random.default_rng(t + k + experts)
+    held = (experts // 4, experts // 2) if share else (0, experts)
+    router = rng.standard_normal((d, experts)) * 0.02
+    x = rng.standard_normal((t, d))
+    if load == "hot":  # an expert EVERY token chooses: experts / k times the even share
+        x[:, 0], router[0, held[0]] = 4.0, 5.0
+    elif load == "cold":  # an expert NO token chooses
+        x[:, 0], router[0, held[0]] = 4.0, -5.0
+    p = {"router": jnp.asarray(router, jnp.float32)}
+    for name, shape in (("w_gate", (d, width)), ("w_up", (d, width)), ("w_down", (width, d))):
+        scale = shape[0] ** -0.5
+        p[name] = jnp.asarray(rng.standard_normal((held[1],) + shape) * scale, jnp.bfloat16)
+    return p, jnp.asarray(x, jnp.bfloat16), held
+
+
+def _within_ulps(got, want, ulps, slack=0.0):
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    return np.abs(got - want) <= ulps * BF16_ULP * np.maximum(np.abs(got), np.abs(want)) + slack
+
+
+@pytest.mark.parametrize("share", [False, True], ids=["all_held", "a_share_held"])
+@pytest.mark.parametrize("load", ["even", "hot", "cold"])
+@pytest.mark.parametrize("t,k,experts", [(64, 4, 8), (96, 8, 16), (40, 2, 4), (64, 2, 16)])
+def test_rows_go_out_and_come_back_as_they_did_before(t, k, experts, load, share):
+    p, x, held = _loaded_layer(t, k, experts, load, share)
+    rows, out, order, gates, y_was, tokens_was = _layer_as_it_was(p, x, k, experts, held)
+    mean = t * k / experts
+    if load == "hot":  # (64, 2, 16): 8 x the even share; where experts / k is 2, twice: all a token can give
+        assert tokens_was[0] == t == (experts // k) * mean
+    elif load == "cold":
+        assert tokens_was[0] == 0
+    # out: the same rows, bit for bit (a copy), through the kernel or through XLA
+    assert row_gather.tile_rows(t * k, x.shape[1], x.dtype) == t * k  # one tile, through the kernel
+    got_rows = row_gather.gather_rows(x, order // k)
+    assert got_rows.dtype == rows.dtype and bool(jnp.array_equal(got_rows, rows))
+    # back, from the SAME experts' rows. Rows of experts not held are never written
+    # by the grouped product: NaN stands for what they may hold
+    held_slot = jnp.asarray(np.asarray(gates) > 0) if share else None
+    dirty = jnp.where(jnp.take(gates.reshape(-1), order)[:, None] > 0, out, jnp.nan) if share else out
+    got = moe.gated_row_sum(dirty, order, gates, held_slot)
+    # the float32 sum in the order j = 0 .. k-1, rounded once: what the new pass
+    # states. WITHIN ONE bfloat16 step of it, and of the old reduce, not bit-equal:
+    # XLA may keep a product unrounded into the add (one rounding fewer) and the
+    # old reduce fixed no order, so a float32 sum can differ by the float32
+    # roundings of its k terms (all that is left where they cancel), and a few per
+    # 100,000 results then round to the neighbouring bfloat16
+    chosen = np.asarray(jnp.take(out, jnp.argsort(order), axis=0), np.float32).reshape(t, k, -1)
+    terms = chosen * np.asarray(gates)[..., None]
+    want = np.zeros((t, chosen.shape[-1]), np.float32)
+    for j in range(k):
+        want = want + terms[:, j]
+    want = np.asarray(jnp.asarray(want).astype(jnp.bfloat16), np.float32)
+    roundings = k * 2.0 ** -23 * np.abs(terms).max(axis=1)
+    assert not np.isnan(np.asarray(got, np.float32)).any()
+    assert _within_ulps(got, want, 1, roundings).all() and _within_ulps(got, y_was, 1, roundings).all()
+    assert (np.asarray(got, np.float32) == want).mean() > 0.999
+    # the layer whole: the same tokens a held expert, and y within the roundings
+    # of two bfloat16 intermediates (h and the experts' rows: the grouped product
+    # and a plain dot add in different orders, so one in some thousand of those
+    # rounds the other way)
+    y, tokens = moe.dropless_moe(x, p["router"], p["w_gate"], p["w_up"], p["w_down"], k=k,
+                                 num_experts=experts, experts_held=held)
+    np.testing.assert_array_equal(np.asarray(tokens), tokens_was)
+    assert y.dtype == x.dtype and not np.isnan(np.asarray(y, np.float32)).any()
+    scale = float(np.abs(np.asarray(y_was, np.float32)).max())
+    np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_was, np.float32),
+                               atol=2 * BF16_ULP * scale)
+
+
+def test_a_sequence_reads_the_same_bits_at_another_place_of_the_batch():
+    """Sequence 1 of three, then the batch moved one place on (it sits at
+    place 2): its rows of the expert layer are the same BITS. The sum over a
+    token's k rows depends on that token's rows only, in a fixed order."""
+    p, x, held = _loaded_layer(96, 4, 8, "even", False)
+    seqs = [x[i * 32:(i + 1) * 32] for i in range(3)]
+    args = (p["router"], p["w_gate"], p["w_up"], p["w_down"])
+    at_1, _ = moe.dropless_moe(jnp.concatenate(seqs), *args, k=4, num_experts=8)
+    at_2, _ = moe.dropless_moe(jnp.concatenate([seqs[2], seqs[0], seqs[1]]), *args, k=4, num_experts=8)
+    assert float(jnp.abs(at_1[32:64].astype(jnp.float32)).max()) > 0
+    assert bool(jnp.array_equal(at_1[32:64], at_2[64:96]))
+    assert bool(jnp.array_equal(at_1[:32], at_2[32:64]))
+
+
+def test_row_gather_kernel_over_several_tiles_is_a_copy():
+    """2,048 rows: two grid steps, so the second tile's copies are issued
+    under the first one's unpacking and land in the other buffer."""
+    rng = np.random.default_rng(5)
+    x = jnp.asarray(rng.standard_normal((200, 2048)), jnp.bfloat16)
+    idx = jnp.asarray(rng.integers(0, 200, 2048), jnp.int32)
+    assert row_gather.tile_rows(2048, 2048, x.dtype) == 1024
+    assert bool(jnp.array_equal(row_gather.gather_rows(x, idx), jnp.take(x, idx, axis=0)))
+    # what the kernel does not take goes to XLA's in-bounds gather
+    assert row_gather.tile_rows(2048, 2048, jnp.float32) == 0 == row_gather.tile_rows(2048, 768, x.dtype)
+    wide = jnp.asarray(rng.standard_normal((200, 96)), jnp.float32)
+    assert bool(jnp.array_equal(row_gather.gather_rows(wide, idx), jnp.take(wide, idx, axis=0)))
 
 
 # ---------------------------------------------------------------------------

@@ -24,8 +24,8 @@ from psana_ray_tpu.obs.stages import (
     HOP_DEQ,
     HOP_ENQ,
     HOP_PUSH,
-    PHASE_BATCH,
-    PHASE_DEQUEUE,
+    PHASE_COPY,
+    PHASE_DECODE,
     PHASE_QUEUE_WAIT,
 )
 from psana_ray_tpu.obs.tracing import TRACE_KEY, TRACER
@@ -327,12 +327,13 @@ def batches_from_queue(
 
     Every turn of the loop is three consecutive phases (``utils.trace.
     phase``): ``queue_wait`` (the pop: it blocks up to the poll interval
-    for a first record, and on the view path copies nothing), ``dequeue``
-    (EOS tally, stamps) and ``batch`` (the copy into the arena); time
-    suspended at a ``yield`` is the consumer's. ``metrics`` (the serving
-    loop's ``PipelineMetrics``, optional) gets one ``queue_wait``
-    observation per turn that popped something, covering the whole wait
-    since the previous such turn — empty polls report nothing of their own.
+    for a first record, and on the view path copies nothing), ``decode``
+    (EOS tally, stamps) and ``copy`` (the copy into the arena; its span
+    carries the bytes copied); time suspended at a ``yield`` is the
+    consumer's. ``metrics`` (the serving loop's ``PipelineMetrics``,
+    optional) gets one observation of each per turn that popped
+    something, ``queue_wait``'s covering the whole wait since the
+    previous such turn — empty polls report nothing of their own.
 
     ``between_turns`` (optional) is called on this loop's own thread at
     the end of every turn that emitted no batch — a starved poll, or
@@ -353,8 +354,8 @@ def batches_from_queue(
     wait_t0: Optional[float] = None  # start of the first of a run of empty polls
     # the loop's three phases, built once: it can turn a thousand times a second
     in_queue_wait = phase(PHASE_QUEUE_WAIT, metrics)
-    in_dequeue = phase(PHASE_DEQUEUE)
-    in_batch = phase(PHASE_BATCH)
+    in_decode = phase(PHASE_DECODE, metrics)
+    in_copy = phase(PHASE_COPY, metrics)
     # drain preference: server-push stream (TCP streaming mode — no pull
     # RTT, no empty-queue polls) > zero-copy view drain (shm ring slots)
     # > plain get_batch. Every TCP variant returns lease-backed records
@@ -443,8 +444,8 @@ def batches_from_queue(
             ready: List[Batch] = []
             frames: List[FrameRecord] = []
             stream_done = False
-            in_dequeue.frames = len(items)
-            with in_dequeue:
+            in_decode.frames = len(items)
+            with in_decode:
                 tally.flush_duplicates(queue)  # gets just freed slots
                 tracing = TRACER.enabled
                 for pos, item in enumerate(items):
@@ -489,8 +490,10 @@ def batches_from_queue(
                         # per-frame e2e exist on this side of it
                         hops[HOP_ENQ] = item.t_enq
                     frames.append(item)
-            in_batch.frames = len(frames)
-            with in_batch:
+            in_copy.frames = len(frames)
+            with in_copy:
+                if tracing:  # the span's bytes: summed for the spool alone
+                    in_copy.nbytes = sum(f.nbytes for f in frames)
                 for item in frames:
                     if batcher is None:
                         batcher = FrameBatcher(batch_size, n_buffers=n_buffers)

@@ -4,9 +4,12 @@ The reference's consumer does a blocking cross-node RPC per frame and
 sleeps 1 s when starved (``data_reader.py:35``, ``psana_consumer.py:40``) —
 device compute and host transfer never overlap. Here a background thread
 stages the next ``prefetch_depth`` batches onto the devices while the
-current batch computes, so at steady state the TPU never waits for host
-transfer (the classic double-buffering pattern; depth 2 suffices when
-transfer < compute).
+current batch computes (the classic double-buffering pattern). Depth 2
+suffices when a transfer, from the ``device_put`` call to the last byte
+on the device, is shorter than the lead the depth buys; that is measured,
+not promised (``h2d_ms.hit``, PERF.md §5 (2): in the hit-finding cell,
+batches of 831 MB, a transfer takes 1.2 batch periods and 8-24% of the
+launches find their bytes not yet there).
 
 Host-side memory discipline (ISSUE 2): the batch source underneath
 (``batches_from_queue``) drains zero-copy when the transport offers it
@@ -30,12 +33,15 @@ from psana_ray_tpu.infeed.batcher import Batch, batches_from_queue
 from psana_ray_tpu.obs.stages import (
     PHASE_DEVICE_PUT,
     PHASE_DEVICE_WAIT,
+    PHASE_H2D_TAIL,
     PHASE_INFEED_WAIT,
     PHASE_LAUNCH,
     PHASE_PREFETCH_FULL,
+    SPAN_H2D,
     observe_batch_done,
     observe_frame_stages,
 )
+from psana_ray_tpu.obs.tracing import TRACER
 from psana_ray_tpu.utils.metrics import PipelineMetrics
 from psana_ray_tpu.utils.trace import phase
 
@@ -45,6 +51,15 @@ class StopStream(Exception):
     consumer-side stop (training-step quota reached, result budget hit)
     as opposed to the producer-side typed EOS. ``run()`` catches it,
     closes the pipeline cleanly, and returns the count so far."""
+
+
+def _arrays_of(staged) -> list:
+    """The arrays of a staged batch, whatever ``to_device`` made of it."""
+    if not isinstance(staged, Batch):
+        return jax.tree_util.tree_leaves(staged)
+    arrays: list = []
+    staged.map_arrays(arrays.append)  # THE enumeration of a Batch's array fields
+    return arrays
 
 
 class DevicePrefetcher:
@@ -59,6 +74,15 @@ class DevicePrefetcher:
     room in the buffer); the rest of its time is the source's own
     (``batches_from_queue``'s phases). ``metrics`` (optional) gets one
     observation of each per batch.
+
+    ``device_put`` times the CALL, which returns while the bytes still
+    cross. While ``TRACER`` is on, every staged batch is also handed to a
+    watcher thread (started with the first such batch) that waits for its
+    arrays (``jax.block_until_ready``, the ``h2d_tail`` phase) and writes
+    ONE span ``h2d`` into the spool under the batch's id: ``device_put``
+    called -> every array on the device. The staged batch goes on to the
+    buffer without waiting for it. With the tracer off there is no such
+    thread and no further reference to the arrays.
 
     Always ``close()`` (or use as a context manager, or exhaust the
     iterator) — an abandoned prefetcher would otherwise pin
@@ -87,6 +111,10 @@ class DevicePrefetcher:
         # alone cannot interrupt
         self._stop = stop_event if stop_event is not None else threading.Event()
         self._done = False
+        # (batch_id, frames, staged, t0) of every batch staged while the
+        # tracer is on, for the watcher thread; None ends it
+        self._watched: Optional[_queue.SimpleQueue] = None
+        self._watcher: Optional[threading.Thread] = None
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
 
@@ -112,6 +140,8 @@ class DevicePrefetcher:
                     staged = self._to_device(batch)
                 if isinstance(staged, Batch):
                     staged.t_staged = ph.t1
+                if TRACER.enabled:
+                    self._watch(batch.batch_id, batch.num_valid, staged, ph.t0)
                 with phase(PHASE_PREFETCH_FULL, *mark):
                     ok = self._put(staged)
                 if not ok:
@@ -119,7 +149,33 @@ class DevicePrefetcher:
         except BaseException as e:  # surface in consumer thread
             self._err = e
         finally:
+            if self._watched is not None:
+                self._watched.put(None)
             self._put(None)  # stream end marker (internal)
+
+    def _watch(self, batch_id: int, frames: int, staged, t0: float) -> None:
+        """Hand a staged batch to the watcher thread (the prefetch
+        thread's side; the first call starts the watcher)."""
+        if self._watched is None:
+            self._watched = _queue.SimpleQueue()
+            self._watcher = threading.Thread(target=self._watch_h2d, daemon=True)
+            self._watcher.start()
+        self._watched.put((batch_id, frames, _arrays_of(staged), t0))
+
+    def _watch_h2d(self) -> None:
+        """The watcher thread: one staged batch at a time, in the order
+        staged, wait until every array is on the device. Where a transfer
+        outlasts the batch period the next batch's ``h2d_tail`` region
+        begins when this one's ended; its ``h2d`` span is exact all the
+        same as long as transfers end in the order they were issued."""
+        tail = phase(PHASE_H2D_TAIL)
+        while (item := self._watched.get()) is not None:
+            tail.batch_id, tail.frames, arrays, t0 = item
+            del item
+            with tail:
+                jax.block_until_ready(arrays)
+            del arrays  # this thread's reference goes with the wait
+            TRACER.phase_span(tail.batch_id, SPAN_H2D, t0, tail.t1, tail.frames)
 
     def set_prefetch_depth(self, n: int) -> int:
         """Resize the staging buffer LIVE (ISSUE 15 autotune knob): the
@@ -145,6 +201,8 @@ class DevicePrefetcher:
         except _queue.Empty:
             pass
         self._thread.join(timeout=timeout)
+        if self._watcher is not None:  # _run's ``finally`` sent its end marker
+            self._watcher.join(timeout=timeout)
         # wake any OTHER thread blocked in __next__ (fan-in pump threads
         # iterate from their own thread): the producer thread is gone, so
         # its end marker may have been drained above or never landed

@@ -60,8 +60,8 @@ TAG_DEVICE_PUT = 5
 TAG_DISPATCH = 6
 
 # The names from "queue_wait" on are loop phases beyond the hop-stage
-# names (obs.stages.PHASES; the phases ``dequeue``/``batch``/
-# ``device_put`` share the stages' tags): looked up by name, TAG_OF_STAGE.
+# names (obs.stages.PHASES; the phase ``device_put`` shares the stage's
+# tag): looked up by name, TAG_OF_STAGE.
 TAG_NAMES = (
     "untagged",
     "enqueue",
@@ -71,7 +71,10 @@ TAG_NAMES = (
     "device_put",
     "dispatch",
     "queue_wait",
+    "decode",
+    "copy",
     "prefetch_full",
+    "h2d_tail",
     "infeed_wait",
     "launch",
     "device_wait",

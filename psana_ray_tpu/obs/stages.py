@@ -29,27 +29,36 @@ Loop phases (what a serving THREAD is doing, marked once per loop turn or
 per batch by :func:`psana_ray_tpu.utils.trace.phase`; consecutive, never
 nested, covering the whole loop body)::
 
-    batches_from_queue   queue_wait -> dequeue -> batch
+    batches_from_queue   queue_wait -> decode -> copy
     DevicePrefetcher     device_put -> prefetch_full
+      (its watcher)      h2d_tail, while the tracer is on
     InfeedPipeline.run   infeed_wait -> launch -> device_wait -> (on_result)
     SfxPipeline.run      (the batcher's three) -> launch -> device_wait
                          -> fold -> append
 
 ``SfxPipeline.run`` drains a batch (``device_wait -> fold -> append``)
 after the next batch's ``launch`` or, when its result is ready sooner,
-between two turns of the batcher: after one turn's ``batch``, before the
+between two turns of the batcher: after one turn's ``copy``, before the
 next turn's ``queue_wait``, never inside a turn.
 
 A phase is a ``stage.<name>`` region on the profiler's timeline, a tag
 for the flame sampler, one span in the trace spool (named ``stage.<name>``
 there too, its id the batch's) and, where the loop owns a
-``PipelineMetrics``, one observation per batch in its stage histograms.
-Hop stages are per FRAME and phases per THREAD; three names serve both
-(``dequeue``, ``batch``, ``device_put``). In the histograms a name never
-means two things: ``device_put`` is the prefetcher's phase (one value a
-batch, the same for all its frames), and the batcher's ``dequeue`` and
-``batch`` phases stay out of the histograms, where those names are a
-frame's hop stages.
+``PipelineMetrics``, one observation per batch or turn in its stage
+histograms. Hop stages are per FRAME and phases per THREAD, and in the
+histograms, the spool and the profile a name means one thing: ``dequeue``
+and ``batch`` are a frame's hop stages (popped -> copied in -> batch
+emitted), ``decode`` and ``copy`` the batcher thread's two phases of a
+turn that popped something. One name serves both vocabularies,
+``device_put``: the prefetcher's phase, one value a batch and the same
+for all its frames.
+
+``device_put`` times the CALL: ``jax.device_put`` returns while the
+bytes still cross. While the tracer is on, a watcher thread beside the
+prefetcher waits for every staged batch's arrays (``h2d_tail``, from the
+call's return to the bytes' arrival) and writes ONE span ``h2d`` a batch
+into the spool under the batch's id: ``device_put`` called -> every
+array of the batch on the device.
 
 Because stages are CONSECUTIVE differences of one record's timeline, the
 per-stage means over a set of records sum EXACTLY to the mean of the
@@ -101,10 +110,11 @@ STAGES = (
 # Loop phases (see the module docstring). The first three run once per
 # turn of ``batches_from_queue``; the rest once per batch.
 PHASE_QUEUE_WAIT = "queue_wait"  # blocked in the transport's pop
-PHASE_DEQUEUE = STAGE_DEQUEUE  # EOS tally, decode, stamps
-PHASE_BATCH = STAGE_BATCH  # the copy into the batch arena
-PHASE_DEVICE_PUT = STAGE_DEVICE_PUT  # host -> device placement
+PHASE_DECODE = "decode"  # EOS tally, decode, stamps
+PHASE_COPY = "copy"  # the copy into the batch arena
+PHASE_DEVICE_PUT = STAGE_DEVICE_PUT  # host -> device placement: the call
 PHASE_PREFETCH_FULL = "prefetch_full"  # staged batch waits for room
+PHASE_H2D_TAIL = "h2d_tail"  # device_put returned -> the bytes are there
 PHASE_INFEED_WAIT = "infeed_wait"  # serving thread waits for a staged batch
 PHASE_LAUNCH = "launch"  # the step call returns (async dispatch)
 PHASE_DEVICE_WAIT = "device_wait"  # host blocks on the step's result
@@ -112,12 +122,18 @@ PHASE_FOLD = "fold"  # device rows -> per-event results
 PHASE_APPEND = "append"  # sink append + cursor
 PHASE_GC = "gc"  # a generation-2 collection, inside whatever phase was open
 
+# One span a staged batch in the spool, beside the phases' (not a phase:
+# it overlaps the prefetch thread's next turns): ``device_put`` called ->
+# every array of the batch on the device.
+SPAN_H2D = "h2d"
+
 PHASES = (
     PHASE_QUEUE_WAIT,
-    PHASE_DEQUEUE,
-    PHASE_BATCH,
+    PHASE_DECODE,
+    PHASE_COPY,
     PHASE_DEVICE_PUT,
     PHASE_PREFETCH_FULL,
+    PHASE_H2D_TAIL,
     PHASE_INFEED_WAIT,
     PHASE_LAUNCH,
     PHASE_DEVICE_WAIT,

@@ -57,6 +57,7 @@ def load_spool(path: str) -> dict:
     peers: List[dict] = []
     spans: List[dict] = []
     instants: List[dict] = []
+    tally: dict = {}  # the tracer's last count of rows kept and dropped
     with open(path, "r", encoding="utf-8") as f:
         for line in f:
             line = line.strip()
@@ -77,6 +78,8 @@ def load_spool(path: str) -> dict:
                 spans.append(rec)
             elif t == "i":
                 instants.append(rec)
+            elif t == "d":
+                tally = rec
     return {
         "path": path,
         "meta": meta,
@@ -84,6 +87,7 @@ def load_spool(path: str) -> dict:
         "peers": peers,
         "spans": spans,
         "instants": instants,
+        "tally": tally,
     }
 
 
@@ -168,6 +172,10 @@ def merge(paths: List[str], only_trace: Optional[int] = None) -> dict:
                 "spool": spool["path"],
                 "spans": len(spool["spans"]),
                 "instants": len(spool["instants"]),
+                # what the tracer's bounds cut off: a timeline with drops
+                # is missing its END (the newest rows go first)
+                "spans_dropped": spool["tally"].get("dropped", 0),
+                "phase_spans_dropped": spool["tally"].get("phase_dropped", 0),
                 "mono_to_wall_offset_s": offset,
                 "skew_vs_server_s": skew,
                 "peer_anchor_exchanges": len(spool["peers"]),

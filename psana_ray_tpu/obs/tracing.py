@@ -133,9 +133,11 @@ class TraceContext:
 # Spool record tags (one JSON object per line):
 #   m = meta (process identity, sample config)   a = clock anchor
 #   p = peer anchor (tcp opcode 'A' exchange)    s = span   i = instant
+#   d = tally, written with every flush: rows kept and dropped so far
 # A span line is {"t":"s","id","n","a","b"} plus, on a frame's span, "j"
 # (the id of the batch it joined) or, on a loop phase's span ("n" is
-# "stage.<phase>", "id" the batch's), "k" (frames in the batch or turn).
+# "stage.<phase>" or "h2d", "id" the batch's), "k" (frames in the batch
+# or turn) and, where the phase moved them, "y" (bytes).
 
 
 class Tracer:
@@ -169,6 +171,10 @@ class Tracer:
         self._spans = 0
         self._drops = 0
         self._max_spans = 0
+        # loop phases' spans (O(batches + turns)) under a bound of their
+        # own, so that the frames' rows (O(frames)) never crowd them out
+        self._phase_spans = 0
+        self._phase_drops = 0
         self._atexit_registered = False
         # full (generation-2) collections while tracing is on: a stop-
         # the-world pause inside whatever phase a serving thread had open
@@ -190,7 +196,10 @@ class Tracer:
         previous spool first. ``max_spans`` bounds the spool, and with it
         the spans held in memory between flushes — beyond it spans are
         dropped and counted (``spans_dropped``), never blocking the
-        pipeline."""
+        pipeline. The loop phases' spans (:meth:`phase_span`) count
+        against a second bound of the same size: a stream of traced
+        frames that fills the first leaves every phase's span in place
+        (the readers of the phases refuse a spool that dropped one)."""
         if sample_every <= 0:
             raise ValueError("sample_every must be >= 1 (frames per sample)")
         with self._lock:
@@ -203,6 +212,8 @@ class Tracer:
             self._count = 0
             self._spans = 0
             self._drops = 0
+            self._phase_spans = 0
+            self._phase_drops = 0
             self._max_spans = max_spans
             # unique-across-processes id space: pid in the top bits, a
             # wall-clock sub-second salt so quick restarts don't collide
@@ -264,18 +275,31 @@ class Tracer:
         )
 
     # -- span sinks (sampled frames and loop phases) ----------------------
-    def span(self, trace_id: int, name: str, t0: float, t1: float,
-             frames: int = 0) -> None:
-        """One completed span ``[t0, t1]`` in THIS process's monotonic
-        domain (the merge tool aligns domains via the spooled anchors).
-        ``frames`` marks a loop phase's span (``trace_id`` is then the
-        batch's id): how many frames the batch or loop turn held."""
+    def span(self, trace_id: int, name: str, t0: float, t1: float) -> None:
+        """One completed span of a sampled FRAME, ``[t0, t1]`` in THIS
+        process's monotonic domain (the merge tool aligns domains via the
+        spooled anchors)."""
         if self.enabled:
-            self._keep((trace_id, name, t0, t1, None, frames))
+            self._keep((trace_id, name, t0, t1, None, 0))
+
+    def phase_span(self, batch_id: int, name: str, t0: float, t1: float,
+                   frames: int = 0, nbytes: int = 0) -> None:
+        """One completed span of a serving thread's loop PHASE
+        (``utils.trace.phase``: ``stage.<name>``, or ``h2d``) under the
+        batch's id, with the ``frames`` the batch or loop turn held and
+        the ``nbytes`` it moved. Kept under the phases' own bound."""
+        if not self.enabled:
+            return
+        with self._lock:
+            if self._phase_spans >= self._max_spans:
+                self._phase_drops += 1
+                return
+            self._phase_spans += 1
+            self._buf.append((batch_id, name, t0, t1, None, frames, nbytes))
 
     def _keep(self, row: tuple) -> None:
-        """THE bounded sink of single rows: kept in memory, or dropped
-        and counted beyond ``max_spans``."""
+        """THE bounded sink of a frame's single rows: kept in memory, or
+        dropped and counted beyond ``max_spans``."""
         with self._lock:
             if self._spans >= self._max_spans:
                 self._drops += 1
@@ -325,17 +349,9 @@ class Tracer:
             with self._lock:
                 self._gc_seconds += t1 - t0
                 self._gc_count += 1
-            self.span(0, GC_SPAN, t0, t1)
+            self.phase_span(0, GC_SPAN, t0, t1)
 
     # -- clock alignment --------------------------------------------------
-    def write_anchor(self) -> None:
-        """Record a (wallclock, monotonic) pair — the merge tool estimates
-        this process's monotonic->wall offset from the median of these."""
-        if not self.enabled:
-            return
-        with self._lock:
-            self._write_locked(self._anchor_line())
-
     def record_peer_anchor(self, exchange: dict) -> None:
         """Record one ping/anchor exchange with the queue server (tcp
         opcode ``A``: local send/recv wall+mono around the server's
@@ -378,13 +394,19 @@ class Tracer:
             if len(row) == 3:
                 out.append(self._line(t="i", id=row[0], n=row[1], a=row[2]))
                 continue
-            tid, name, t0, t1, joined, frames = row
+            tid, name, t0, t1, joined, frames, *nbytes = row  # a phase's row ends in its bytes
             rec = {"t": "s", "id": tid, "n": name, "a": t0, "b": t1}
             if joined is not None:
                 rec["j"] = joined
             if frames:
                 rec["k"] = frames
+            if nbytes and nbytes[0]:
+                rec["y"] = nbytes[0]
             out.append(self._line(**rec))
+        out.append(self._line(
+            t="d", spans=self._spans, dropped=self._drops,
+            phase_spans=self._phase_spans, phase_dropped=self._phase_drops,
+        ))
         self._f.write("\n".join(out) + "\n")
         self._f.flush()
 
@@ -418,6 +440,8 @@ class Tracer:
                 "frames_seen_total": self._count,
                 "spans_total": self._spans,
                 "spans_dropped_total": self._drops,
+                "phase_spans_total": self._phase_spans,
+                "phase_spans_dropped_total": self._phase_drops,
                 "gc_collections_total": self._gc_count,
                 "gc_seconds_total": round(self._gc_seconds, 6),
             }
@@ -430,7 +454,8 @@ class Tracer:
         if not self.enabled:
             return ""
         with self._lock:
-            every, spans, drops = self._every, self._spans, self._drops
+            every, spans = self._every, self._spans + self._phase_spans
+            drops = self._drops + self._phase_drops
         suffix = f" trace[1/{every} spans={spans}"
         if drops:
             suffix += f" drops={drops}"

@@ -27,7 +27,7 @@ for the stream.
 
 The loop's thread is always inside one phase (``utils.trace.phase``; the
 vocabulary is ``obs.stages.PHASES``): the batcher's ``queue_wait`` /
-``dequeue`` / ``batch``, then ``launch`` (the jit call, with its implicit
+``decode`` / ``copy``, then ``launch`` (the jit call, with its implicit
 host-to-device copy of the frames), and for the batch before it
 ``device_wait`` (the ``device_get`` drain), ``fold`` (panel rows -> per-
 event peak sets) and ``append`` (``writer.append`` + cursor); an early

@@ -98,26 +98,28 @@ class phase:
     - on exit ONE observation of the duration in ``metrics.stages``
       (``metrics``: a ``PipelineMetrics`` or None) and, while the tracer
       is on, ONE ``stage.<name>`` span in its spool under ``batch_id``
-      with the ``frames`` the batch or loop turn held.
+      with the ``frames`` the batch or loop turn held and the ``nbytes``
+      it moved (kept under the phases' own bound, ``Tracer.phase_span``).
 
     Phases of one thread are consecutive and never nested, and together
     cover the loop body: the benchmark bills each idle gap of the device
     to the phase the host was in. ``t0``/``t1`` (monotonic seconds) stay
-    readable after exit; a loop may set ``batch_id``/``frames`` inside
-    the region once it knows them, widen ``t0`` to the start of a wait
+    readable after exit; a loop may set ``batch_id``/``frames``/``nbytes``
+    inside the region once it knows them, widen ``t0`` to the start of a wait
     that spanned several polls, or clear ``record`` for a turn that has
     nothing to report (an empty poll): the timeline region and the tag
     are kept, the histogram and the spool skipped. An object may be
     entered again and again (a loop that turns a thousand times a second
     builds its phases once); each entry starts with ``record`` set."""
 
-    __slots__ = ("name", "batch_id", "frames", "record", "t0", "t1",
+    __slots__ = ("name", "batch_id", "frames", "nbytes", "record", "t0", "t1",
                  "_metrics", "_tag", "_region", "_ann", "_prev")
 
     def __init__(self, name: str, metrics=None, batch_id: int = 0, frames: int = 0):
         self.name = name
         self.batch_id = batch_id
         self.frames = frames
+        self.nbytes = 0
         self.record = True
         self.t0 = self.t1 = 0.0
         self._metrics = metrics
@@ -144,5 +146,6 @@ class phase:
             if self._metrics is not None:
                 self._metrics.stages.observe(self.name, t1 - self.t0)
             if TRACER.enabled:
-                TRACER.span(self.batch_id, self._region, self.t0, t1, self.frames)
+                TRACER.phase_span(
+                    self.batch_id, self._region, self.t0, t1, self.frames, self.nbytes)
         return False

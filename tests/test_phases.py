@@ -45,7 +45,7 @@ SHAPE = (2, 32, 128)  # panels, height, width: the benchmark's rehearsal detecto
 BATCH = 4
 BATCH_PHASES_SFX = ("launch", "device_wait", "fold", "append")
 BATCH_PHASES_INFEED = ("device_put", "prefetch_full", "infeed_wait", "launch", "device_wait")
-TURN_PHASES = ("queue_wait", "dequeue", "batch")
+TURN_PHASES = ("queue_wait", "decode", "copy")
 
 
 @pytest.fixture(autouse=True)
@@ -115,7 +115,7 @@ class TestPhaseHelper:
     @pytest.mark.parametrize("name", PHASES)
     def test_tag_is_set_inside_and_restored_after(self, name):
         assert current_tag() == TAG_UNTAGGED
-        with phase("batch"):
+        with phase("copy"):
             outer = current_tag()
             with phase(name):  # (loops never nest; the unwinding still holds)
                 assert TAG_NAMES[current_tag()] == name
@@ -177,12 +177,12 @@ class TestPhaseHelper:
         from psana_ray_tpu.obs.profiling import FlameSampler
 
         sampler = FlameSampler(hz=97.0, process="unit", register=False)
-        for name, ticks in (("queue_wait", 3), ("batch", 2), ("append", 1)):
+        for name, ticks in (("queue_wait", 3), ("copy", 2), ("append", 1)):
             with phase(name):
                 for _ in range(ticks):
                     sampler._sample_once()
         totals = sampler.trie.stage_totals()
-        for name, ticks in (("queue_wait", 3), ("batch", 2), ("append", 1)):
+        for name, ticks in (("queue_wait", 3), ("copy", 2), ("append", 1)):
             assert totals[name]["on"] + totals[name]["off"] == ticks
         before = dict(totals)
         sampler._sample_once()  # outside any phase
@@ -258,7 +258,7 @@ class TestTracerInMemory:
 
         t = Tracer().configure(str(tmp_path), sample_every=1, process="t")
         t.extend([(5, "batch", 10.0, 12.0, 3, 0)])
-        t.span(3, "stage.launch", 12.0, 12.5, 4)
+        t.phase_span(3, "stage.launch", 12.0, 12.5, 4)
         t.instant(5, "produce", 9.0)
         path = t.spool_path
         t.close()
@@ -448,9 +448,11 @@ class TestSfxLoop:
         assert turns >= n_batches
         for name in TURN_PHASES:
             assert len(_phase_spans(rows, name)) == turns, name
-        assert stages.stat("queue_wait").count == turns
-        # the batcher's dequeue/batch PHASES stay out of the histograms,
-        # where those names are a frame's hop stages
+        # the batcher thread's phases: one observation a turn that popped
+        # something, tracer or no tracer
+        for name in TURN_PHASES:
+            assert stages.stat(name).count == turns, name
+        # ``dequeue`` and ``batch`` are a frame's hop stages and nothing else
         for name in ("dequeue", "batch"):
             stat = stages.stat(name)
             assert (stat.count if stat else 0) == (self.N if traced else 0), name
@@ -517,7 +519,7 @@ class TestSfxLoop:
 class TestSfxEarlyDrain:
     """``SfxPipeline.run`` drains a batch whose step has ended between
     two turns of the batcher (ISSUE 35): its ``device_wait`` / ``fold`` /
-    ``append`` lie after one turn's ``batch`` (or the ``launch``, over
+    ``append`` lie after one turn's ``copy`` (or the ``launch``, over
     empty polls, which leave no span) and before the next turn's
     ``queue_wait`` — never inside a turn."""
 
@@ -558,7 +560,7 @@ class TestSfxEarlyDrain:
             if name == "device_wait":
                 # a drain begins where a turn has ended or a launch has,
                 # and its three phases follow one another
-                assert order[i - 1] in ("batch", "launch"), order[max(0, i - 3): i + 4]
+                assert order[i - 1] in ("copy", "launch"), order[max(0, i - 3): i + 4]
                 assert order[i + 1: i + 3] == ["fold", "append"]
                 assert spans[i]["id"] == spans[i + 1]["id"] == spans[i + 2]["id"]
                 if i + 3 < len(order):
@@ -632,7 +634,7 @@ class TestInfeedLoop:
             return real_observe(self, *a, **kw)
 
         monkeypatch.setattr(StageTimes, "observe", observe)
-        monkeypatch.setattr(TRACER, "span", lambda *a, **kw: calls.__setitem__(
+        monkeypatch.setattr(TRACER, "phase_span", lambda *a, **kw: calls.__setitem__(
             "span", calls["span"] + calls["after"]))
         monkeypatch.setattr(TRACER, "extend", lambda *a, **kw: calls.__setitem__(
             "extend", calls["extend"] + calls["after"]))
@@ -648,6 +650,181 @@ class TestInfeedLoop:
         assert calls["observe"] == 1 + 1 + n
         assert calls["span"] == 1 and calls["extend"] == 0
         assert metrics.stages.stat("queue_dwell").count == n  # folded before the wait
+
+
+# ---------------------------------------------------------------------------
+# the hand-off (ISSUE 40): the transfer's true end, the batcher's two
+# phases under names of their own, a bound of their own for the phases
+# ---------------------------------------------------------------------------
+
+def _host_batches(ids):
+    z = np.zeros(BATCH)
+    return [
+        Batch(np.zeros((BATCH,) + SHAPE, np.uint16), np.ones(BATCH, np.uint8),
+              z.astype(np.int32), np.arange(BATCH, dtype=np.int64), z.astype(np.float32),
+              batch_id=i)
+        for i in ids
+    ]
+
+
+class _Late:
+    """What a ``to_device`` hands back: on the device only once ``arrived``
+    is set (``jax.block_until_ready`` calls this on a leaf that has it)."""
+
+    def __init__(self, batch_id, arrived):
+        self.batch_id, self._arrived = batch_id, arrived
+
+    def block_until_ready(self):
+        assert self._arrived.wait(timeout=30)
+        return self
+
+
+class TestTransferEnd:
+    IDS = (11, 12, 13)
+
+    def test_tracer_on_one_h2d_span_a_batch_and_the_hand_off_does_not_wait(self, tmp_path):
+        from psana_ray_tpu.infeed.pipeline import DevicePrefetcher
+
+        TRACER.configure(str(tmp_path), sample_every=1, process="t")
+        arrived = threading.Event()
+        pf = DevicePrefetcher(
+            iter(_host_batches(self.IDS)), prefetch_depth=1,
+            to_device=lambda b: _Late(b.batch_id, arrived),
+        )
+        # all three come through, in order, while no transfer has ended
+        assert [item.batch_id for item in pf] == list(self.IDS)
+        assert pf._watcher.is_alive()
+        time.sleep(0.02)
+        arrived.set()
+        pf.close()
+        assert not pf._watcher.is_alive()
+        rows = _spool(TRACER)
+        h2d = [r for r in rows if r["t"] == "s" and r["n"] == "h2d"]
+        put = {r["id"]: r for r in _phase_spans(rows, "device_put")}
+        assert [r["id"] for r in h2d] == list(self.IDS)
+        for r in h2d:
+            assert r["a"] == put[r["id"]]["a"] and r["b"] >= put[r["id"]]["b"]
+            assert r["k"] == BATCH
+        assert h2d[0]["b"] - put[11]["b"] >= 0.02  # the tail, not the call
+        tails = _phase_spans(rows, "h2d_tail")
+        assert [r["id"] for r in tails] == list(self.IDS)
+        assert [r["b"] for r in tails] == [r["b"] for r in h2d]
+
+    def test_a_real_device_put_is_waited_for(self, tmp_path):
+        from psana_ray_tpu.infeed.pipeline import DevicePrefetcher
+
+        TRACER.configure(str(tmp_path), sample_every=1, process="t")
+        with DevicePrefetcher(iter(_host_batches(self.IDS))) as pf:
+            staged = list(pf)
+        assert all(isinstance(b.frames, jax.Array) for b in staged)
+        rows = _spool(TRACER)
+        assert [r["id"] for r in rows if r["t"] == "s" and r["n"] == "h2d"] == list(self.IDS)
+
+    def test_tracer_off_no_thread_beyond_todays_and_no_reference(self):
+        from psana_ray_tpu.infeed.pipeline import DevicePrefetcher
+
+        before = threading.active_count()
+        release = threading.Event()
+
+        def src():
+            yield from _host_batches(self.IDS)
+            assert release.wait(timeout=30)  # hold the prefetch thread alive
+
+        pf = DevicePrefetcher(src(), prefetch_depth=4, to_device=lambda b: b)
+        try:
+            deadline = time.monotonic() + 10
+            while pf._buf.qsize() < len(self.IDS) and time.monotonic() < deadline:
+                time.sleep(0.001)
+            assert pf._buf.qsize() == len(self.IDS)  # all three were staged
+            assert threading.active_count() == before + 1  # the prefetch thread alone
+            assert pf._watcher is None and pf._watched is None
+        finally:
+            release.set()
+            pf.close()
+
+
+class TestBatcherPhasesHaveNamesOfTheirOwn:
+    N = 6
+
+    @pytest.mark.parametrize("traced", [False, True], ids=["tracer-off", "tracer-on"])
+    def test_decode_and_copy_once_a_turn_with_frames_none_on_an_empty_poll(
+        self, tmp_path, traced
+    ):
+        from psana_ray_tpu.transport import RingBuffer
+
+        if traced:
+            TRACER.configure(str(tmp_path), sample_every=1, process="t")
+        q = RingBuffer(maxsize=16)
+        pops = {"empty": 0, "held": 0}
+        real = q.get_batch
+
+        def counting(n, timeout=None):
+            items = real(n, timeout=timeout)
+            pops["held" if items else "empty"] += 1
+            return items
+
+        q.get_batch = counting
+
+        def feed():
+            for i in range(self.N):
+                q.put_wait(_frame(i), timeout=30)
+                time.sleep(0.01)  # several empty polls between two frames
+            q.put_wait(EndOfStream(total_events=self.N), timeout=30)
+
+        t = threading.Thread(target=feed, daemon=True)
+        t.start()
+        m = PipelineMetrics()
+        batches = list(batches_from_queue(q, BATCH, poll_interval_s=0.001, metrics=m))
+        t.join(timeout=30)
+        assert sum(b.num_valid for b in batches) == self.N
+        assert pops["empty"] > 0
+        for name in TURN_PHASES:
+            assert m.stages.stat(name).count == pops["held"], name
+        # the hop histograms are the frames' and are left alone: an untimed
+        # stream has none (the serving loop folds them, obs.stages)
+        assert m.stages.stat("dequeue") is None and m.stages.stat("batch") is None
+        if traced:
+            rows = _spool(TRACER)
+            copies = _phase_spans(rows, "copy")
+            assert len(copies) == len(_phase_spans(rows, "decode")) == pops["held"]
+            assert sum(r.get("k", 0) for r in copies) == self.N
+            assert sum(r.get("y", 0) for r in copies) == self.N * _frame(0).nbytes
+
+
+class TestPhaseSpansHaveABoundOfTheirOwn:
+    def test_a_full_frame_budget_drops_frames_and_keeps_every_phase(self, tmp_path):
+        t = Tracer().configure(str(tmp_path), sample_every=1, process="t", max_spans=5)
+        t.extend([(i, "batch", 0.0, 1.0, 3, 0) for i in range(8)])  # 5 fit
+        t.span(9, "queue_dwell", 0.0, 1.0)  # none fits
+        for i in range(5):
+            t.phase_span(i, "stage.copy", float(i), i + 0.5, 2, 4096)
+        snap = t.snapshot()
+        assert snap["spans_total"] == 5 and snap["spans_dropped_total"] == 4
+        assert snap["phase_spans_total"] == 5 and snap["phase_spans_dropped_total"] == 0
+        t.phase_span(5, "h2d", 5.0, 5.5, 2)  # the phases' own bound, and its count
+        assert t.snapshot()["phase_spans_dropped_total"] == 1
+        assert "drops=5" in t.status_suffix()
+        rows = _spool(t)
+        assert len(_phase_spans(rows, "copy")) == 5
+        assert all(r["k"] == 2 and r["y"] == 4096 for r in _phase_spans(rows, "copy"))
+        assert [r for r in rows if r["t"] == "d"][-1] == {
+            "t": "d", "spans": 5, "dropped": 4, "phase_spans": 5, "phase_dropped": 1}
+        from psana_ray_tpu.obs import trace_merge
+
+        (track,) = trace_merge.merge([str(tmp_path)])["otherData"]["tracks"]
+        assert (track["spans_dropped"], track["phase_spans_dropped"]) == (4, 1)
+
+    def test_the_benchmarks_reader_refuses_a_spool_that_dropped_a_phase(self, tmp_path, capsys):
+        from benchmark.readers import span_time_share
+
+        t = Tracer().configure(str(tmp_path), sample_every=1, process="t", max_spans=2)
+        for i in range(3):
+            t.phase_span(i, "stage.copy", float(i), i + 0.5)
+        path = t.spool_path
+        t.close()
+        ctx = types.SimpleNamespace(spool_path=path, window=(0.0, 10.0))
+        assert span_time_share.read(ctx, spans=["stage.copy"]) is None
+        assert "dropped 1 phase spans" in capsys.readouterr().err
 
 
 # ---------------------------------------------------------------------------

@@ -22,21 +22,36 @@ candidate per block (15,104 a panel row for epix10k2M at d = 2, not
 raster order, so the peaks, their scores and their ORDER are those of
 ``top_k`` over the whole raster-flat map (``tests/dense_peaks.py``).
 
-TPU notes: the test is unrolled shifted comparisons with static
-tie-breaks, all elementwise on per-block planes — XLA fuses the window
-and the block reduction into one kernel, batch on the lanes, which is
-also the operand layout its TopK runs fastest on; ``top_k`` gives a FIXED
-peak-count output (padded, with a validity count) so a streaming consumer
-never sees a shape change.
+TPU notes. Every access of the test is a STATIC slice (``lax.slice``, or a
+reshape of major axes and a plain slice), never a strided ``jnp`` index:
+``x[ry::b, rx::b]`` traces to a gather, and XLA then fetches each phase a
+row at a time (nine gathers of 15,600 rows a step on the epix10k2M cell,
+1.35 ms, behind 1.73 ms of ``depth_to_space``, relayout and pad passes:
+my chip runs, PR 41). The plain form (:func:`_local_maxima`) is unrolled
+shifted comparisons with static tie-breaks, elementwise on per-block
+slabs, batch on the lanes; XLA fuses the window and the block reduction
+into one kernel (0.55 ms there), but only behind copies that bring the
+map into that shape. Where the logits come PACKED from a space-to-depth
+head (``s2d`` > 1) and the TPU takes the shape, the test is
+``ops/peak_nms.packed_local_maxima``: one Pallas pass over the map in the
+layout the head wrote it in (0.34 ms). Both leave one candidate per block
+with the batch on the lanes, the operand layout XLA's TopK runs fastest on
+(1.48 ms; batch on the sublanes 2.99); ``top_k`` gives a FIXED peak-count
+output (padded, with a validity count) so a streaming consumer never sees
+a shape change.
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from psana_ray_tpu.ops import peak_nms
 
 
 def find_peaks(
@@ -44,6 +59,7 @@ def find_peaks(
     max_peaks: int = 128,
     threshold: float = 0.5,
     min_distance: int = 1,
+    s2d: int = 1,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Extract up to ``max_peaks`` peak centers from ``[N, H, W, 1]`` (or
     ``[N, H, W]``) segmentation logits.
@@ -56,20 +72,45 @@ def find_peaks(
     ``(min_distance+1)^2`` block — a block holds at most one peak (module
     docstring), so the result is that of ``top_k`` over every pixel.
 
+    ``s2d = r > 1`` takes the logits PACKED, ``[N, H/r, W/r, r*r]`` as a
+    space-to-depth head computes them (``PeakNetUNetTPU.apply(...,
+    packed=True)``: channel ``a*r + c`` of packed pixel ``(i, j)`` is pixel
+    ``(r*i + a, r*j + c)``), and reads them in that layout: the
+    full-resolution map is never formed. The result is, element for
+    element, that of ``find_peaks(depth_to_space(logits, r))``; ``r`` is a
+    static property of the model that made the logits, not a mode. On the
+    TPU, whole lane tiles of packed rows go through one kernel
+    (``ops/peak_nms``); everything else through the plain form below.
+
     Returns ``(yx, score, n)``: ``yx [N, max_peaks, 2]`` int32 row/col
     (padded entries are (-1,-1)), ``score [N, max_peaks]`` f32 probability
     (padded 0), ``n [N]`` int32 valid count. Fixed shapes — jit/pjit safe.
     """
-    if logits.ndim == 4:
+    if s2d == 1 and logits.ndim == 4:
         logits = logits[..., 0]
-    n_, h, w = logits.shape
-    b = min_distance + 1
-    hb, wb = -(-h // b), -(-w // b)
+    elif s2d > 1 and (logits.ndim != 4 or logits.shape[-1] != s2d * s2d):
+        raise ValueError(
+            f"packed logits are [N, H/{s2d}, W/{s2d}, {s2d * s2d}] (one class); "
+            f"got {logits.shape}"
+        )
+    n_, w = logits.shape[0], logits.shape[2] * s2d
     # two named scopes, metadata only: ``nms`` (the local-max test, down to
     # one candidate per block) and ``topk`` (the K best per row) are found
     # again by name in a device trace, whatever XLA numbers its fusions
     with jax.named_scope("nms"):
-        cand, where = _local_maxima(logits, threshold, min_distance, b)
+        if jax.default_backend() == "tpu" and peak_nms.takes(logits.shape, s2d, min_distance):
+            cand, where = peak_nms.packed_local_maxima(
+                jax.nn.sigmoid(logits.astype(jnp.float32)),
+                threshold=threshold, d=min_distance, r=s2d,
+            )
+        else:
+            cand, where = _local_maxima(logits, threshold, min_distance, min_distance + 1, s2d)
+    # whole super-blocks: where lcm(s2d, block) does not divide H or W, a
+    # few more blocks than ceil(H / block) x ceil(W / block), all empty. A
+    # row of ``cand`` is a row of blocks; its blocks stand in the order the
+    # test leaves them in (not left to right where s2d > 1), which nothing
+    # below depends on
+    hb, wb = cand.shape[1:]
     with jax.named_scope("topk"):
         k = min(max_peaks, hb * wb)
         top, cidx = jax.lax.top_k(cand.reshape(n_, hb * wb), k)
@@ -98,53 +139,91 @@ def find_peaks(
         return yx, jnp.where(valid, score, 0.0), valid.sum(axis=1).astype(jnp.int32)
 
 
-def _local_maxima(logits, threshold: float, d: int, b: int):
-    """``(score [N,Hb,Wb] f32, where [N,Hb,Wb] int32)`` of ``[N,H,W]`` logits:
-    each ``b x b`` block's surviving pixel — its probability and its raster
+def _local_maxima(logits, threshold: float, d: int, b: int, r: int = 1):
+    """``(score [N,Hb,Wb] f32, where [N,Hb,Wb] int32)`` of ``[N,H,W]`` logits
+    (``r = 1``) or of the same map packed ``[N, H/r, W/r, r*r]``: each
+    ``b x b`` block's surviving pixel — its probability and its raster
     index ``y*W + x`` (both 0 where the block has none). ``b = 1`` is the
     full-resolution map; ``b = d + 1`` loses nothing (module docstring).
+    The grid is whole SUPER-BLOCKS of ``L = lcm(r, b)`` pixels a side:
+    ``Hb = ceil(H / L) * L / b``; a block beyond the frame is empty. Row
+    ``(L/b)*m + p`` holds block row ``p`` of super-block row ``m``, and in
+    it block column ``q`` of every super-block, then the next ``q``.
 
     A pixel survives when its probability reaches ``threshold`` and no
     neighbour in its ``(2d+1)^2`` window beats it on (probability, earlier
     raster index): an earlier neighbour wins a tie, a later one does not —
     static per offset, exact where a float "prob - idx*eps" key would lose
-    the tie-break to f32 rounding near 1."""
-    n_, h, w = logits.shape
-    hb, wb = -(-h // b), -(-w // b)
-    # Batch last, so that a strided slice moves whole rows of N values.
-    # phase[ry, rx] holds the padded map's pixels (ry + b*i, rx + b*j): cut
-    # ONCE, after which every window neighbour of every in-block place is a
-    # contiguous slice of one phase and the test is elementwise on
-    # [Hb, Wb, N] — XLA fuses it whole, and the full-resolution score map is
-    # never written. Out-of-frame pixels are -inf: they beat nothing and,
-    # where H or W is padded up to whole blocks, never survive.
-    prob = jax.nn.sigmoid(logits.transpose(1, 2, 0).astype(jnp.float32))
-    hq, wq = hb + -(-2 * d // b), wb + -(-2 * d // b)
+    the tie-break to f32 rounding near 1.
+
+    Every access is a static slice (module docstring). Row ``y = r*i + a``
+    lies at packed row ``i``, sub-pixel ``a``; block row ``I = (L/b)*m + p``
+    and in-block place ``iy`` put a neighbour at ``y + dy = L*m + (b*p + iy
+    + dy)``: packed row ``(L/r)*m + (b*p + iy + dy) // r``, sub-pixel ``(b*p
+    + iy + dy) % r``, and the same along x. So the packed map falls into
+    ``(L/r)^2 * r^2`` PHASES (packed row and column mod ``L/r``, sub-pixel),
+    cut once, and every neighbour of every place of every block is a
+    contiguous slab of one phase, shifted by whole super-blocks."""
+    n_, hp, wp = logits.shape[:3]
+    h, w = hp * r, wp * r
+    sup = math.lcm(r, b)  # L: a super-block is whole blocks AND whole packed pixels
+    lr, nb = sup // r, sup // b
+    mh, mw = -(-h // sup), -(-w // sup)
+    # a border of ``edge`` packed pixels of -inf: out-of-frame neighbours
+    # beat nothing and, where H or W is padded up to whole super-blocks,
+    # never survive. ``reach``: whole super-blocks a neighbour may lie on.
+    edge = -(-d // r)
+    off = edge * r
+    reach = (sup - 1 + d + off) // r // lr
+    qh, qw = mh + reach, mw + reach
+    # batch last: a slab is [rows, columns, N], the test elementwise on it
+    prob = jax.nn.sigmoid(logits.astype(jnp.float32)).reshape(n_, hp, wp, r, r)
     pprob = jnp.pad(
-        prob, ((d, hq * b - h - d), (d, wq * b - w - d), (0, 0)), constant_values=-jnp.inf
-    )
-    phase = {(ry, rx): pprob[ry::b, rx::b] for ry in range(b) for rx in range(b)}
+        prob.transpose(1, 2, 3, 4, 0),
+        ((edge, qh * lr - hp - edge), (edge, qw * lr - wp - edge), (0, 0), (0, 0), (0, 0)),
+        constant_values=-jnp.inf,
+    ).reshape(qh, lr, qw, lr, r, r, n_)
 
-    def at(oy, ox):  # padded pixel (oy + b*i, ox + b*j) of every block (i, j)
-        return phase[oy % b, ox % b][oy // b : oy // b + hb, ox // b : ox // b + wb]
+    @functools.cache
+    def phase(qy, qx, a, c):  # lax.slice, never a strided jnp index: that is a gather
+        lo = (0, qy, 0, qx, a, c, 0)
+        hi = (qh, qy + 1, qw, qx + 1, a + 1, c + 1, n_)
+        return jax.lax.slice(pprob, lo, hi).reshape(qh, qw, n_)
 
-    corner = (jnp.arange(hb, dtype=jnp.int32)[:, None] * w + jnp.arange(wb, dtype=jnp.int32)) * b
-    score = jnp.zeros((hb, wb, n_), jnp.float32)
-    where = jnp.zeros((hb, wb, n_), jnp.int32)
-    for iy in range(b):
-        for ix in range(b):
-            c = at(d + iy, d + ix)
-            beaten = jnp.zeros(c.shape, dtype=bool)
-            for dy in range(-d, d + 1):
-                for dx in range(-d, d + 1):
-                    if (dy, dx) != (0, 0):
-                        sp = at(d + iy + dy, d + ix + dx)
-                        beaten |= (sp >= c) if (dy, dx) < (0, 0) else (sp > c)
-            s = jnp.where((c >= threshold) & ~beaten, c, 0.0)
-            # at most one place of a block survives: max IS that one's score
-            score = jnp.maximum(score, s)
-            where = jnp.where(s > 0.0, corner[:, :, None] + (iy * w + ix), where)
-    return score.transpose(2, 0, 1), where.transpose(2, 0, 1)
+    @functools.cache
+    def at(ty, tx):  # padded pixel (ty + L*m, tx + L*n) of every super-block (m, n)
+        (py, a), (px, c) = divmod(ty, r), divmod(tx, r)
+        sy, sx = py // lr, px // lr
+        return phase(py % lr, px % lr, a, c)[sy : sy + mh, sx : sx + mw]
+
+    corner = (
+        jnp.arange(mh, dtype=jnp.int32)[:, None] * w + jnp.arange(mw, dtype=jnp.int32)
+    )[:, :, None] * sup
+    scores, wheres = [], []
+    for p in range(nb):
+        for q in range(nb):
+            score = jnp.zeros((mh, mw, n_), jnp.float32)
+            where = jnp.zeros((mh, mw, n_), jnp.int32)
+            for iy in range(b * p, b * p + b):
+                for ix in range(b * q, b * q + b):
+                    cen = at(off + iy, off + ix)
+                    beaten = jnp.zeros(cen.shape, dtype=bool)
+                    for dy in range(-d, d + 1):
+                        for dx in range(-d, d + 1):
+                            if (dy, dx) != (0, 0):
+                                sp = at(off + iy + dy, off + ix + dx)
+                                beaten |= (sp >= cen) if (dy, dx) < (0, 0) else (sp > cen)
+                    s = jnp.where((cen >= threshold) & ~beaten, cen, 0.0)
+                    # at most one place of a block survives: max IS that one's score
+                    score = jnp.maximum(score, s)
+                    where = jnp.where(s > 0.0, corner + (iy * w + ix), where)
+            scores.append(score)
+            wheres.append(where)
+
+    def grid(planes):  # [L/b * L/b] of [Mh, Mw, N] -> [N, Mh * L/b, L/b * Mw]
+        return jnp.stack(planes, axis=1).transpose(3, 0, 1, 2).reshape(n_, mh * nb, nb * mw)
+
+    return grid(scores), grid(wheres)
 
 
 def peak_metrics(

@@ -83,7 +83,11 @@ class PeakNetUNetTPU(nn.Module):
     s2d: int = 2
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, packed: bool = False):
+        """``packed=True`` returns the head's output as it is computed,
+        ``[N, H/s2d, W/s2d, s2d²·num_classes]`` (what
+        ``find_peaks(..., s2d=s2d)`` reads): the same numbers, not
+        unshuffled."""
         n, h, w, _ = x.shape
         quantum = self.s2d * 2 ** (len(self.features) - 1)
         if h % quantum or w % quantum:
@@ -126,4 +130,4 @@ class PeakNetUNetTPU(nn.Module):
                 ),
                 name="logits",
             )(x)
-            return depth_to_space(y, self.s2d)
+            return y if packed else depth_to_space(y, self.s2d)

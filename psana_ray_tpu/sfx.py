@@ -51,6 +51,7 @@ and merge.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import time
 from typing import Callable, Optional, Tuple
@@ -261,8 +262,13 @@ class SfxPipeline:
                     x, ped, gain, mask,
                     threshold=cfg.calib_threshold, out_dtype=jnp.bfloat16,
                 )
+        # the head's logits stay packed as the model computes them, and
+        # ``find_peaks`` reads them so (``s2d`` is the checkpoint's own):
+        # no full-resolution map between the two
         with jax.named_scope("peaknet"):
-            logits = jax.jit(self._model.apply)(variables, panels_to_nhwc(x, mode="batch"))
+            logits = jax.jit(functools.partial(self._model.apply, packed=True))(
+                variables, panels_to_nhwc(x, mode="batch")
+            )
         with jax.named_scope("find_peaks"):
             return jax.jit(
                 lambda lg: find_peaks(
@@ -270,6 +276,7 @@ class SfxPipeline:
                     max_peaks=cfg.max_peaks,
                     threshold=cfg.peak_threshold,
                     min_distance=cfg.min_distance,
+                    s2d=self.s2d,
                 )
             )(logits)
 

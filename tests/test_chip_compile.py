@@ -10,6 +10,7 @@ off the chip, and the producer staying JAX-free (the one-process-per-chip
 rule's cheap guards).
 """
 
+import functools
 import os
 import re
 import subprocess
@@ -146,7 +147,10 @@ def _sfx_serve_step():
     calib = (np.zeros(panel, np.float32), np.ones(panel, np.float32), np.ones(panel, np.uint8))
     pipe = SfxPipeline(variables, writer=None, calib=calib)
     resident = jax.tree.map(lambda a: S(a.shape, a.dtype), (pipe._variables, pipe._calib))
-    return pipe._device_step, [*resident, S((SfxConfig.batch_size, *panel), jnp.uint16)], 1
+    step = [*resident, S((SfxConfig.batch_size, *panel), jnp.uint16)]
+    rows = SfxConfig.batch_size * PANELS
+    # the calibration kernel and the local-maximum kernel
+    return pipe._device_step, step, 2, functools.partial(_peaks_read_the_packed_map, rows=rows)
 
 
 KEYE_S = 34304  # 33,792 patches of an epix10k2M frame + 512 prompt tokens
@@ -189,7 +193,8 @@ def _keye_experts():
 
     up = S((128, 2048, 768), BF16)
     return fn, [S((KEYE_S, 2048), BF16), S((2048, 128), BF16), up, up,
-                S((128, 768, 2048), BF16)], 4, (KEYE_S, 8)
+                S((128, 768, 2048), BF16)], 4, functools.partial(
+                    _rows_move_once_each_way, tokens=KEYE_S, k=8)
 
 
 LFM2_B, LFM2_S = 4, 8704  # four frames of 8,448 patches (16 x 16 pixels) + 256 prompt tokens
@@ -239,7 +244,8 @@ def _lfm2_experts():
 
     up = S((32, 2048, 1792), BF16)
     return fn, [S((LFM2_B * LFM2_S, 2048), BF16), S((2048, 32), BF16), S((32,), F32), up, up,
-                S((32, 1792, 2048), BF16)], 4, (LFM2_B * LFM2_S, 4)
+                S((32, 1792, 2048), BF16)], 4, functools.partial(
+                    _rows_move_once_each_way, tokens=LFM2_B * LFM2_S, k=4)
 
 
 CASES = {
@@ -274,9 +280,49 @@ def _rows_move_once_each_way(text, tokens, k):
     assert len(re.findall(r"^\s*(?:ROOT )?%row_gather[.\d]* = ", entry, re.M)) == 1
 
 
+_SHAPE = re.compile(r"\b(f32|s32|bf16|u16|pred|u8|s8)\[([\d,]*)\]")
+_BYTES = {"f32": 4, "s32": 4, "bf16": 2, "u16": 2, "pred": 1, "u8": 1, "s8": 1}
+
+
+def _peaks_read_the_packed_map(text, rows):
+    """What ``find_peaks_ms`` rests on (PR 41), in the SFX step as compiled
+    for ``rows`` panel rows: the head's probabilities go from the fusion
+    that writes them into ONE kernel and come out as 15,104 candidates a
+    row. No gather cuts phases (a strided ``jnp`` index is one: nine
+    gather fusions before), no float32 map at full resolution exists
+    under ``peaknet`` or ``find_peaks``, at most one map-sized copy, pad or slice stands
+    under ``find_peaks`` (five before), and ``top_k`` reads one candidate
+    per block."""
+    entry = text[text.index("ENTRY"):]
+    assert not re.findall(r'op_name="[^"]*/nms/[^"]*gather', text)
+    def shapes(hlo):  # (dtype, dims) of every array named in a piece of HLO text
+        return [(t, [int(x) for x in d.split(",") if x]) for t, d in _SHAPE.findall(hlo)]
+
+    for line in text.splitlines():
+        if "/peaknet/" not in line and "/find_peaks/" not in line:
+            continue  # the calibration kernel reads its frames as float32
+        for dtype, dims in shapes(line):
+            full = dtype == "f32" and H in dims and W in dims and np.prod(dims) >= rows * H * W
+            assert not full, line[:200]
+    the_map = rows * H * W * 4
+    passes = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (.*?) (copy|pad|slice|fusion|transpose)\(", line)
+        if m and "/find_peaks/" in line:
+            size = sum(_BYTES[t] * int(np.prod(dims)) for t, dims in shapes(m.group(2)))
+            if size >= 0.7 * the_map:
+                passes.append(m.group(1))
+    assert len(passes) <= 1, passes
+    assert len(re.findall(r"^\s*(?:ROOT )?%peak_nms[.\d]* = ", entry, re.M)) == 1
+    top_k = [line for line in entry.splitlines() if 'custom_call_target="TopK"' in line]
+    assert len(top_k) == 1
+    operand = re.search(r"custom-call\((%[\w.\-]+)\)", top_k[0]).group(1)
+    assert re.search(rf"^\s*{re.escape(operand)} = f32\[{rows},15104\]", entry, re.M), operand
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
-    fn, arg_shapes, min_mosaic, *expert_layer = CASES[case]()
+    fn, arg_shapes, min_mosaic, *pins = CASES[case]()
     # code that asks default_backend() would take its CPU (interpret)
     # branch under a described topology; steer it here, not in the program
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -284,13 +330,32 @@ def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= min_mosaic
-    if expert_layer:
-        _rows_move_once_each_way(text, *expert_layer[0])
+    for pin in pins:
+        pin(text)
     mem = compiled.memory_analysis()
     # one v5e chip: 16 GB of HBM for arguments, outputs and temporaries
     assert (
         mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     ) < 16e9
+
+
+@pytest.mark.parametrize("d", [0, 1, 2, 3])
+@pytest.mark.parametrize("r", [1, 2, 4])
+def test_local_maxima_cuts_its_phases_without_a_gather(r, d):
+    """``x[ry::b, rx::b]`` traces to the ``gather`` primitive, and on the
+    TPU each phase then costs a row fetch a row (PR 41: 1.35 ms of nine
+    gathers a step); ``lax.slice`` is the static form. Runs on the CPU."""
+    from psana_ray_tpu.models.peaks import _local_maxima
+
+    def primitives(jaxpr):
+        for eqn in jaxpr.eqns:
+            yield eqn.primitive.name
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from primitives(sub)
+
+    logits = S((3, 48 // r, 64 // r, r * r), F32)
+    names = set(primitives(jax.make_jaxpr(lambda x: _local_maxima(x, 0.5, d, d + 1, r))(logits).jaxpr))
+    assert "slice" in names and "gather" not in names
 
 
 # -- the compile cache can be placed from outside ---------------------------

@@ -228,6 +228,116 @@ class TestBlockCandidates:
         assert list(top_k_widths(jax.make_jaxpr(step)(logits).jaxpr)) == [15104]
 
 
+def _scenes(h, w, rng):
+    """Six rows of one ``[6, h, w]`` logit batch, each a case the packed
+    form could get wrong: random; few distinct values (plateaus over every
+    packed-pixel and super-block border); probability exactly 1.0 in
+    plateaus; distinct peaks on every frame edge and corner plus plateaus
+    ACROSS rows/columns 5|6|7 and 11|12|13 (packed-pixel borders for r 2
+    and 4, block borders for d 1-3, the super-block border at 12); nothing
+    over the threshold; and more equal single pixels than any cap."""
+    z = np.full((6, h, w), -8.0, np.float32)
+    z[0] = rng.normal(size=(h, w)) * 3.0
+    z[1] = np.round(rng.normal(size=(h, w)) * 2.0)
+    z[2] = np.where(rng.normal(size=(h, w)) > 1.0, 40.0, -40.0)
+    edges = [(0, 0), (0, w - 1), (h - 1, 0), (h - 1, w - 1),
+             (0, w // 2), (h - 1, w // 2), (h // 2, 0), (h // 2, w - 1)]
+    for i, (cy, cx) in enumerate(edges):
+        z[3, cy, cx] = 8.0 - 0.25 * i
+    z[3, 5:8, 11:14] = 6.0
+    z[3, 11:14, 5:8] = 6.0
+    z[3, 12, 20:28] = 6.0
+    z[4] = -6.0 - np.abs(rng.normal(size=(h, w)))
+    z[5] = -40.0
+    z[5, 1::5, 2::7] = 40.0
+    return z
+
+
+class TestPackedLogits:
+    """``find_peaks(..., s2d=r)`` reads the head's packed ``[N, H/r, W/r,
+    r*r]`` logits by static slices and never forms the full-resolution
+    map; its result is the dense form's, element for element (ISSUE 41)."""
+
+    @pytest.mark.parametrize("hw", [(352, 384), (32, 128), (44, 52)])
+    @pytest.mark.parametrize("d", [0, 1, 2, 3])
+    @pytest.mark.parametrize("r", [1, 2, 4])
+    def test_packed_form_agrees_with_dense(self, r, d, hw):
+        """Sizes the super-block lcm(r, d+1) divides (32 x 128 at d 1, 3)
+        and does not (352 x 384 at d 2; 44 x 52: 11 x 13 packed pixels at
+        r 4, odd in blocks at every d > 0)."""
+        from psana_ray_tpu.models.unet_tpu import space_to_depth
+
+        z = _scenes(*hw, np.random.default_rng(100 * r + 10 * d + hw[0]))
+        cap = 128 if hw[0] > 100 else 8
+        packed = space_to_depth(jnp.asarray(z)[..., None], r)
+        got = jax.jit(lambda y: find_peaks(y, cap, 0.5, d, s2d=r))(packed)
+        want = _JIT_DENSE(jnp.asarray(z), cap, 0.5, d)
+        for name, g, w_ in zip(("yx", "score", "n"), got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w_), err_msg=name)
+        n = np.asarray(got[2])
+        assert n[4] == 0 and n[0] == cap and n[5] == cap  # empty row; rows over the cap
+        assert (np.asarray(got[1])[5] == 1.0).all()  # the cut falls among equals
+        assert n[3] >= 8  # every edge and corner peak is there
+
+    @pytest.mark.parametrize("r", [2, 4])
+    def test_packed_call_equals_the_call_on_the_unshuffled_map(self, r):
+        """``find_peaks(depth_to_space(y, r))`` and the packed call on ``y``
+        give the same bits: one algorithm, ``r`` a property of the model."""
+        from psana_ray_tpu.models.unet_tpu import depth_to_space
+
+        rng = np.random.default_rng(r)
+        y = jnp.asarray(np.round(rng.normal(size=(3, 24, 20, r * r)) * 4.0) / 2.0, jnp.float32)
+        got = jax.jit(lambda a: find_peaks(a, 16, 0.5, 2, s2d=r))(y)
+        want = jax.jit(lambda a: find_peaks(depth_to_space(a, r), 16, 0.5, 2))(y)
+        for g, w_ in zip(got, want):
+            assert np.asarray(g).tobytes() == np.asarray(w_).tobytes()
+        assert int(np.asarray(got[2]).min()) == 16
+
+    def test_packed_logits_must_carry_one_class(self):
+        with pytest.raises(ValueError, match="packed logits"):
+            find_peaks(jnp.zeros((1, 8, 8, 8)), s2d=2)
+        with pytest.raises(ValueError, match="packed logits"):
+            find_peaks(jnp.zeros((1, 8, 8)), s2d=2)
+
+
+class TestPackedKernel:
+    """``ops/peak_nms.packed_local_maxima``, the one-pass form the TPU runs
+    on packed probabilities, in Pallas interpret mode: the plain form's
+    candidates, and through ``find_peaks`` the dense form's peaks."""
+
+    @pytest.mark.parametrize("hw", [(48, 128), (44, 52)])
+    @pytest.mark.parametrize("d", [0, 1, 2, 3])
+    @pytest.mark.parametrize("r", [2, 4])
+    def test_kernel_candidates_and_peaks(self, r, d, hw, monkeypatch):
+        import psana_ray_tpu.models.peaks as peaks
+        from psana_ray_tpu.models.unet_tpu import space_to_depth
+        from psana_ray_tpu.ops.peak_nms import packed_local_maxima
+
+        def kernel(logits, threshold, d_, b, r_=1):
+            assert (b, r_) == (d + 1, r)
+            return packed_local_maxima(
+                jax.nn.sigmoid(logits.astype(jnp.float32)), threshold=threshold, d=d_, r=r_,
+                interpret=True)
+
+        z = _scenes(*hw, np.random.default_rng(7 * r + d))
+        packed = space_to_depth(jnp.asarray(z)[..., None], r)
+        plain = _local_maxima(packed, 0.5, d, d + 1, r)
+        for name, g, w_ in zip(("score", "where"), kernel(packed, 0.5, d, d + 1, r), plain):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w_), err_msg=name)
+        monkeypatch.setattr(peaks, "_local_maxima", kernel)
+        got = find_peaks(packed, 8, 0.5, d, s2d=r)
+        for name, g, w_ in zip(("yx", "score", "n"), got, _JIT_DENSE(jnp.asarray(z), 8, 0.5, d)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w_), err_msg=name)
+
+    def test_kernel_takes_whole_tiles_only(self):
+        from psana_ray_tpu.ops.peak_nms import takes
+
+        assert takes((256, 176, 192, 4), 2, 2) and takes((128, 88, 96, 16), 4, 2)
+        assert not takes((16, 176, 192, 4), 2, 2)  # a sixteenth of a lane tile
+        assert not takes((128, 22, 26, 4), 2, 2)  # 26 packed columns: not whole sublane tiles
+        assert not takes((128, 352, 384, 1), 1, 2) and not takes((128, 352, 384), 1, 2)  # nothing packed
+
+
 class TestCxiRoundtrip:
     def test_roundtrip(self, tmp_path):
         centers = [(5, 7), (20, 33)]

@@ -227,18 +227,31 @@ def _stream_to_cxi_datasets(serving_ckpt, path: str) -> dict:
 
 def test_cxi_rows_equal_the_dense_find_peaks(serving_ckpt, tmp_path, monkeypatch):
     """The ORDER of peaks is part of the file: ``find_peaks`` runs TopK over
-    one candidate per block, and what lands in the CXI datasets is byte for
-    byte what the dense form (tests/dense_peaks.py) patched in writes."""
+    one candidate per block and, in the step, reads the head's logits
+    PACKED (``s2d`` the checkpoint's own, the full-resolution map never
+    formed: PR 41); what lands in the CXI datasets is byte for byte what
+    the dense form (tests/dense_peaks.py) writes when patched in over the
+    same logits unshuffled."""
     from dense_peaks import dense_find_peaks
 
     import psana_ray_tpu.models.peaks as peaks
     from psana_ray_tpu.cxi import read_cxi_peaks
+    from psana_ray_tpu.models.unet_tpu import depth_to_space
     from psana_ray_tpu.sources.base import DETECTORS
+
+    handed = []
+
+    def dense_on_the_unshuffled_map(logits, s2d=1, **kw):
+        handed.append((logits.shape, s2d))
+        return dense_find_peaks(depth_to_space(logits, s2d), **kw)
 
     blocks = str(tmp_path / "blocks.cxi")
     got = _stream_to_cxi_datasets(serving_ckpt, blocks)
-    monkeypatch.setattr(peaks, "find_peaks", dense_find_peaks)  # looked up per trace
+    monkeypatch.setattr(peaks, "find_peaks", dense_on_the_unshuffled_map)  # looked up per trace
     want = _stream_to_cxi_datasets(serving_ckpt, str(tmp_path / "dense.cxi"))
+    spec = DETECTORS[DET]
+    # the step hands over what the head computes: [rows, H/2, W/2, 4], s2d 2
+    assert handed and set(handed) == {((4 * spec.panels, spec.height // 2, spec.width // 2, 4), 2)}
     assert got.keys() == want.keys() and len(got) >= 7
     for name in want:
         assert got[name] == want[name], name

@@ -4,21 +4,27 @@ Nothing else in ``models/`` has an RMS norm, a rotary embedding, a gated
 MLP or a block assembled from a configuration (``vit.py`` hard-codes
 LayerNorm, GELU and a position table). This module builds the text
 decoder of a language model from the keys of its published
-``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B):
+``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B, DeepSeek-V3's
+block as Kimi-K2 spells it):
 
     x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
-    x  -> x + ff(rms(x))                      two kinds, its ff one of two
+    x  -> x + ff(rms(x))                      three kinds, its ff one of two
 
 A layer's OPERATOR (``layer_types``) is grouped-query attention (a
 per-head RMS norm on q and k, then the rotary, multimodal or on the
 sequence index), plain causal or restricted, per query, to the ``topk``
 keys a learned indexer ranks highest (``parallel/sparse_attention.py``);
-or a gated short convolution (:func:`gated_short_conv`). Its FEED-FORWARD
-is a dense gated-SiLU MLP (the first ``num_dense_layers``, or all where
-there are no experts) or top-k of ``num_experts`` experts without dropped
-tokens (``parallel/moe.dropless_moe``; a softmax router, or sigmoid
-affinities under a selection bias), of which this holder may hold a
-share (``experts_held``). The trunk runs that schedule over ``B``
+or a gated short convolution (:func:`gated_short_conv`); or, where the
+configuration has a ``kv_lora_rank``, LATENT attention
+(:func:`latent_attention`: low-rank queries, keys and values decompressed
+per head from one normed latent, one rotary key for all heads, YaRN's
+frequencies). Its FEED-FORWARD is a dense gated-SiLU MLP (the first
+``num_dense_layers``, or all where there are no experts) or top-k of
+``num_experts`` experts without dropped tokens
+(``parallel/moe.dropless_moe``; a softmax router, or sigmoid affinities
+under a selection bias), of which this holder may hold a share
+(``experts_held``: only the held slots' rows move), beside
+``shared_experts`` that every token passes through. The trunk runs that schedule over ``B``
 sequences of ``S`` tokens held as ``[B*S, D]`` rows: what mixes tokens
 (attention, the convolution, the rotary) is told ``B`` and stays inside
 a sequence; the expert layer sorts all ``B*S`` rows at once and moves each
@@ -64,7 +70,56 @@ STEP_STATS = (
     "decoder_tokens_total",      # tokens the step served: B * S
     "decoder_sequences_total",   # sequences (frames) the step served: B
 )
+# two more, after those six, from a holder of a SHARE of the experts (where
+# every expert is held they would say the same thing twice, and the step is
+# the program it was)
+SHARE_STATS = (
+    "expert_rows_held_total",    # token slots whose expert lives here, over the expert layers
+    "expert_rows_routed_total",  # all token slots of those layers: B * S * k each
+)
 ATTENTION, CONV = "full_attention", "conv"  # layer_types, as config.json spells them
+LATENT = "latent_attention"  # what an ATTENTION layer is under a kv_lora_rank
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """``rope_scaling`` of type ``yarn``, as DeepSeek-V3's code reads it."""
+
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @staticmethod
+    def _mscale(factor: float, m: float) -> float:
+        return 0.1 * m * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+    @property
+    def rotary_scale(self) -> float:
+        """What the cosines and sines are multiplied by."""
+        return self._mscale(self.factor, self.mscale) / self._mscale(self.factor, self.mscale_all_dim)
+
+    @property
+    def softmax_scale(self) -> float:
+        """``mscale**2``: what the softmax scale ``d**-0.5`` is multiplied by."""
+        return self._mscale(self.factor, self.mscale_all_dim) ** 2
+
+    def inv_freq(self, theta: float, pairs: int) -> np.ndarray:
+        """``[pairs]`` float64: pair ``i`` keeps ``theta**(-i/pairs)`` where
+        the ramp between the two correction dimensions is 0 and takes
+        that over ``factor`` where it is 1, blended in between."""
+        def correction(turns):  # the pair that makes `turns` turns over the original positions
+            return (2 * pairs * np.log(self.original_positions / (turns * 2 * np.pi))
+                    / (2 * np.log(theta)))
+
+        low = max(int(np.floor(correction(self.beta_fast))), 0)
+        high = min(int(np.ceil(correction(self.beta_slow))), 2 * pairs - 1)
+        ramp = np.clip((np.arange(pairs) - low) / ((high + 0.001 if low == high else high) - low),
+                       0.0, 1.0)
+        plain = theta ** (-np.arange(pairs, dtype=np.float64) / pairs)
+        return plain / self.factor * ramp + plain * (1.0 - ramp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +138,16 @@ class DecoderConfig:
     layer_types: Tuple[str, ...] = ()
     conv_taps: int = 3  # of the gated short convolution (conv_L_cache)
     tie_embedding: bool = False  # the output head is the embedding table
+    rope_yarn: Optional[Yarn] = None  # YaRN-blended rotary frequencies and the softmax's mscale^2
+    # latent attention (kv_lora_rank 0: grouped-query attention): queries through a normed
+    # q_lora_rank, keys and values from ONE normed kv_lora_rank latent; a head's query and key
+    # are [qk_nope_head_dim | qk_rope_head_dim] (head_dim is their sum), the rotary part of the
+    # key one for all heads; values v_head_dim wide
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # learned sparse attention (None: plain causal attention)
     indexer_heads: Optional[int] = None
     indexer_head_dim: int = 0
@@ -110,6 +175,7 @@ class DecoderConfig:
     expert_bias: bool = False  # experts CHOSEN by score + bias, weighted by the score alone
     gate_eps: float = 0.0  # in the renormalising sum of the chosen scores
     routed_scaling_factor: float = 1.0
+    shared_experts: int = 0  # beside the routed ones, ungated: one MLP of shared_experts * expert_width
     intermediate_size: int = 0
     patch: int = 8
 
@@ -117,42 +183,80 @@ class DecoderConfig:
     def patch_dim(self) -> int:
         return self.patch * self.patch
 
+    @property
+    def rope_dim(self) -> int:
+        """The width the rotary turns: the whole head, or a latent head's rotary part."""
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+
+    @property
+    def holds_a_share(self) -> bool:
+        """Of the routed experts: the step then counts :data:`SHARE_STATS` too."""
+        return bool(self.num_experts) and self.experts_held[1] < self.num_experts
+
     def layer_kind(self, i: int) -> Tuple[str, bool]:
         """``(operator, has experts)`` of layer ``i``."""
         op = self.layer_types[i] if self.layer_types else ATTENTION
+        if op == ATTENTION and self.kv_lora_rank:
+            op = LATENT
         return op, bool(self.num_experts) and i >= self.num_dense_layers
 
     @classmethod
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Keye-VL-2.0's spelling
-        (``head_dim``, ``rms_norm_eps``, ``rope_scaling.mrope_section``,
-        ``sa_config``) and LFM2's (``norm_eps``, ``layer_types``,
-        ``num_dense_layers``, ``conv_L_cache``, ``use_expert_bias`` with
-        ``routed_scaling_factor``: sigmoid affinities, DeepSeek-V3's router;
-        no ``head_dim``: hidden / heads; no ``rope_scaling``: plain rotary)
-        are both read."""
+        ``experts_held`` and ``tie_embedding``. Three spellings are read:
+        Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
+        ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
+        (``norm_eps``, ``layer_types``, ``num_dense_layers``,
+        ``conv_L_cache``, ``use_expert_bias`` with ``routed_scaling_factor``:
+        sigmoid affinities, DeepSeek-V3's router; no ``head_dim``: hidden /
+        heads; no ``rope_scaling``: plain rotary); and DeepSeek-V3's own, as
+        Kimi-K2's file has it (``q_lora_rank``, ``kv_lora_rank``,
+        ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``: latent
+        attention without per-head norms; ``rope_scaling.type: yarn``;
+        ``n_routed_experts``, ``n_shared_experts``,
+        ``first_k_dense_replace``, ``scoring_func``, ``topk_method:
+        noaux_tc``: the selection bias). Where a file's ``n_routed_experts``
+        counts the experts HELD (a chip's share), ``router_experts`` gives
+        the width the router keeps."""
         sa_cfg = m.get("sa_config")
-        n_exp = int(m.get("num_experts", 0))
+        held_key = "num_experts" if "num_experts" in m else "n_routed_experts"
+        n_exp = int(m.get("router_experts", m.get(held_key, 0)))
         n_layers, heads = int(m["num_hidden_layers"]), int(m["num_attention_heads"])
         layer_types = tuple(m.get("layer_types", ()))
         if layer_types and (len(layer_types) != n_layers
                             or set(layer_types) - {ATTENTION, CONV}):
             raise ValueError(f"layer_types {layer_types} does not name {n_layers} layers' "
                              f"operators, each {ATTENTION!r} or {CONV!r}")
-        mrope = (m.get("rope_scaling") or {}).get("mrope_section")
-        sigmoid = "use_expert_bias" in m
+        rope = m.get("rope_scaling") or {}
+        mrope = rope.get("mrope_section")
+        yarn = None
+        if rope.get("type") == "yarn":
+            yarn = Yarn(float(rope["factor"]), int(rope["original_max_position_embeddings"]),
+                        float(rope.get("beta_fast", 32)), float(rope.get("beta_slow", 1)),
+                        float(rope.get("mscale", 1)), float(rope.get("mscale_all_dim", 0)))
+        latent = int(m.get("kv_lora_rank") or 0)
+        if latent and not m.get("q_lora_rank"):
+            raise ValueError("latent attention without a q_lora_rank (a full-rank query "
+                             "projection) is not built")
+        nope, rope_dim = int(m.get("qk_nope_head_dim", 0)), int(m.get("qk_rope_head_dim", 0))
+        deepseek = "scoring_func" in m  # DeepSeek-V3's spelling of the router
+        sigmoid = "use_expert_bias" in m or m.get("scoring_func") == "sigmoid"
         return cls(
             hidden_size=int(m["hidden_size"]), num_layers=n_layers,
             num_heads=heads, num_kv_heads=int(m["num_key_value_heads"]),
-            head_dim=int(m.get("head_dim") or int(m["hidden_size"]) // heads),
+            head_dim=(nope + rope_dim if latent
+                      else int(m.get("head_dim") or int(m["hidden_size"]) // heads)),
             vocab_size=int(m["vocab_size"]),
             rms_eps=float(m["rms_norm_eps"] if "rms_norm_eps" in m else m["norm_eps"]),
             rope_theta=float(m["rope_theta"]),
             mrope_section=tuple(int(v) for v in mrope) if mrope else None,
             layer_types=layer_types, conv_taps=int(m.get("conv_L_cache", 3)),
             tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
+            rope_yarn=yarn,
+            q_lora_rank=int(m.get("q_lora_rank") or 0) if latent else 0, kv_lora_rank=latent,
+            qk_nope_head_dim=nope if latent else 0, qk_rope_head_dim=rope_dim if latent else 0,
+            v_head_dim=int(m.get("v_head_dim", 0)) if latent else 0,
             indexer_heads=int(sa_cfg["indexer_num_heads"]) if sa_cfg else None,
             indexer_head_dim=int(sa_cfg["indexer_head_dim"]) if sa_cfg else 0,
             topk=int(sa_cfg["topk"]) if sa_cfg else 0,
@@ -161,11 +265,13 @@ class DecoderConfig:
             expert_width=int(m.get("moe_intermediate_size", 0)),
             experts_held=tuple(int(v) for v in m.get("experts_held", (0, n_exp))),
             norm_topk_prob=bool(m.get("norm_topk_prob", True)),
-            num_dense_layers=int(m.get("num_dense_layers", 0)),
+            num_dense_layers=int(m.get("num_dense_layers", m.get("first_k_dense_replace", 0))),
             router_scoring="sigmoid" if sigmoid else "softmax",
-            expert_bias=bool(m.get("use_expert_bias", False)),
-            gate_eps=1e-6 if sigmoid else 0.0,
+            expert_bias=bool(m.get("use_expert_bias", m.get("topk_method") == "noaux_tc")),
+            # the renormalising sum's epsilon: LFM2's code has 1e-6, DeepSeek-V3's 1e-20
+            gate_eps=(1e-20 if deepseek else 1e-6) if sigmoid else 0.0,
             routed_scaling_factor=float(m.get("routed_scaling_factor", 1.0)),
+            shared_experts=int(m.get("n_shared_experts") or 0) if n_exp else 0,
             intermediate_size=int(m.get("intermediate_size", 0)),
             patch=int(m.get("patch", 8)),
         )
@@ -195,6 +301,12 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
         if op == CONV:
             p = {"norm1": gain(d), "w_in": w(d, 3 * d), "conv_w": w(d, cfg.conv_taps),
                  "w_out": w(d, d), "norm2": gain(d)}
+        elif op == LATENT:
+            heads, rq, rkv = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
+            p = {"norm1": gain(d), "wq_a": w(d, rq), "q_a_norm": gain(rq), "wq_b": w(rq, heads * hd),
+                 "wkv_a": w(d, rkv + cfg.qk_rope_head_dim), "kv_a_norm": gain(rkv),
+                 "wkv_b": w(rkv, heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                 "wo": w(heads * cfg.v_head_dim, d), "norm2": gain(d)}
         else:
             p = {
                 "norm1": gain(d), "wq": w(d, cfg.num_heads * hd), "wk": w(d, cfg.num_kv_heads * hd),
@@ -211,6 +323,9 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                      w_up=w(held, d, cfg.expert_width), w_down=w(held, cfg.expert_width, d))
             if cfg.expert_bias:
                 p.update(router_bias=w(cfg.num_experts, dtype=jnp.float32))
+            if cfg.shared_experts:
+                wide = cfg.shared_experts * cfg.expert_width
+                p.update(shared_gate=w(d, wide), shared_up=w(d, wide), shared_down=w(wide, d))
         else:
             p.update(w_gate=w(d, cfg.intermediate_size), w_up=w(d, cfg.intermediate_size),
                      w_down=w(cfg.intermediate_size, d))
@@ -244,11 +359,15 @@ def frame_positions(panels: int, rows: int, cols: int, prompt_len: int) -> np.nd
     return np.concatenate([patches, np.stack([text] * 3, axis=1)]).astype(np.int32)
 
 
-def rotary_angles(pos, theta: float, pairs: int, sections=None):
-    """``[S, pairs]`` angles: pair ``i`` turns by ``pos * theta**(-i/pairs)``;
+def rotary_angles(pos, theta: float, pairs: int, sections=None, yarn: Optional[Yarn] = None):
+    """``[S, pairs]`` angles: pair ``i`` turns by ``pos * theta**(-i/pairs)``
+    (under ``yarn``, by its blended frequency: :meth:`Yarn.inv_freq`);
     with ``sections`` (multimodal rotary) ``pos`` is ``[S, 3]`` and pair
     ``i`` reads the position component of the section it falls in."""
-    inv_freq = theta ** (-np.arange(pairs, dtype=np.float64) / pairs)
+    if yarn is not None:
+        inv_freq = yarn.inv_freq(theta, pairs)
+    else:
+        inv_freq = theta ** (-np.arange(pairs, dtype=np.float64) / pairs)
     if sections is None:
         return jnp.asarray(pos, jnp.float32)[:, None] * jnp.asarray(inv_freq, jnp.float32)
     which = np.repeat(np.arange(len(sections)), sections)  # [pairs] -> component
@@ -310,6 +429,58 @@ def gated_short_conv(p, x, batch: int, cfg: DecoderConfig):
     return x + _mm(y, p["w_out"]).astype(dt)
 
 
+def _latent_projections(p, x, angles, cfg: DecoderConfig):
+    """``x [T, D]`` -> ``(q_nope [T, H*dn], q_rope [T, H*dr], k_nope [T,
+    H*dn], k_rope [T, dr], v [T, H*dv])``: the queries through their normed
+    low rank, the keys' and values' per-head parts decompressed from the
+    normed latent, the ONE rotary key (not normed) and each head's rotary
+    query turned; the softmax scale (with YaRN's ``mscale**2``) rides on
+    both parts of q."""
+    t, dt = x.shape[0], x.dtype
+    h, dn, dr, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    yarn = cfg.rope_yarn
+    scale = cfg.head_dim ** -0.5 * (yarn.softmax_scale if yarn else 1.0)
+    turned = yarn.rotary_scale if yarn else 1.0
+    a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
+    c_q = rms_norm(_mm(a, p["wq_a"]), p["q_a_norm"], cfg.rms_eps).astype(dt)
+    q = _mm(c_q, p["wq_b"]).reshape(t, h, dn + dr)
+    q_nope = q[..., :dn] * scale
+    q_rope = rotate(q[..., dn:], angles) * (scale * turned)
+    down = _mm(a, p["wkv_a"])  # [T, latent | the rotary key]
+    c_kv = rms_norm(down[:, :cfg.kv_lora_rank], p["kv_a_norm"], cfg.rms_eps).astype(dt)
+    k_rope = rotate(down[:, None, cfg.kv_lora_rank:], angles)[:, 0] * turned
+    kv = _mm(c_kv, p["wkv_b"]).reshape(t, h, dn + dv)
+    return (q_nope.reshape(t, -1).astype(dt), q_rope.reshape(t, -1).astype(dt),
+            kv[..., :dn].reshape(t, -1).astype(dt), k_rope.astype(dt),
+            kv[..., dn:].reshape(t, -1).astype(dt))
+
+
+def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig):
+    """DeepSeek-V3's operator on ``x [B*S, D]`` -> ``x + Op``, prefill in
+    the DECOMPRESSED form: every head has its own keys and values (``2 *
+    (head_dim + v_head_dim)`` FLOPs a causal pair and head; the absorbed
+    form, scores against the latent itself, is decode's), and a score is
+    ``q_nope . k_nope + q_rope . k_rope`` with the rotary key read from its
+    one ``[B, S, dr]`` array (``sparse_attention.masked_gqa_attention``'s
+    shared part), never written ``H`` times over. Under the scopes ``proj``
+    (both low-rank paths, their norms, the rotary, ``W_o``) and
+    ``latent_attn`` (the attention itself)."""
+    s = x.shape[0] // batch
+    with jax.named_scope("proj"):
+        q_nope, q_rope, k_nope, k_rope, v = jax.jit(_latent_projections, static_argnums=3)(
+            p, x, angles, cfg)
+    with jax.named_scope("latent_attn"):
+        attend = jax.jit(sa.masked_gqa_attention,
+                         static_argnames=("num_kv_heads", "block_q", "block_k"))
+        q_nope, q_rope, k_nope, k_rope, v = (
+            u.reshape(batch, s, -1) for u in (q_nope, q_rope, k_nope, k_rope, v))
+        o = attend(q_nope, k_nope, v, num_kv_heads=cfg.num_heads, block_q=cfg.causal_q_tile,
+                   block_k=cfg.causal_kv_tile, q_shared=q_rope, k_shared=k_rope)
+    with jax.named_scope("proj"):
+        return jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(
+            x, o.reshape(x.shape[0], -1), p["wo"])
+
+
 def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
     """The attention operator on ``x [B*S, D]`` -> ``(x + Op, live,
     causal)``, the last two the layer's statistics tiles. Each part is a
@@ -342,20 +513,35 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
 
 
 def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int = 1):
-    """One block: ``x [B*S, D]`` -> ``(x, stats [4] float32)``, the first
-    four of :data:`STEP_STATS`. ``kind`` is ``cfg.layer_kind(i)``: the
-    operator runs under ``conv`` or attention's scopes, the feed-forward
-    under ``moe`` (experts) or ``mlp`` (dense)."""
+    """One block: ``x [B*S, D]`` -> ``(x, stats float32)``, the first four
+    of :data:`STEP_STATS` and, from a holder of a share of the experts,
+    :data:`SHARE_STATS`. ``kind`` is ``cfg.layer_kind(i)``: the operator
+    runs under ``conv``, latent attention's or attention's scopes, the
+    feed-forward under ``moe`` (the routed experts), ``shared_expert``
+    (beside them, added once) or ``mlp`` (dense)."""
     op, experts = kind
     live, causal = 0, 0
     if op == CONV:
         with jax.named_scope("conv"):
             x = jax.jit(gated_short_conv, static_argnums=(2, 3))(p, x, batch, cfg)
+    elif op == LATENT:
+        x = latent_attention(p, x, angles, batch, cfg)
+        live = causal = batch * sa.causal_tile_count(x.shape[0] // batch)
     else:
         x, live, causal = _attention(p, x, angles, idx_angles, batch, cfg)
+    share = experts and cfg.holds_a_share
+    given = ()  # from the shared expert: the layer's normed rows b, and x + Shared(b)
+    if experts and cfg.shared_experts:
+        with jax.named_scope("shared_expert"):  # every token's, whatever it chose; the norm is here, once
+            def beside(p, x):
+                b = rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype)
+                return b, x + _dense_mlp(p, b)
+
+            given = jax.jit(beside)({"norm2": p["norm2"], "w_gate": p["shared_gate"],
+                                     "w_up": p["shared_up"], "w_down": p["shared_down"]}, x)
     with jax.named_scope("moe" if experts else "mlp"):
-        def mlp(p, x):
-            b = rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype)
+        def mlp(p, x, *given):
+            b, onto = given or (rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype), x)
             if not experts:
                 return x + _dense_mlp(p, b), jnp.zeros((), jnp.int32)
             y, tokens = dropless_moe(
@@ -364,34 +550,42 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
                 renormalise=cfg.norm_topk_prob, scoring=cfg.router_scoring,
                 select_bias=p.get("router_bias"), gate_eps=cfg.gate_eps,
                 gate_scale=cfg.routed_scaling_factor)
-            return x + y, jnp.max(tokens)
+            out = onto + y
+            return (out, jnp.max(tokens), jnp.sum(tokens)) if share else (out, jnp.max(tokens))
 
-        x, busiest = jax.jit(mlp)(p, x)
+        x, busiest, *held = jax.jit(mlp)(p, x, *given)
     even = x.shape[0] * cfg.experts_per_token / cfg.num_experts if experts else 0.0
-    stats = jnp.stack([busiest.astype(jnp.float32), jnp.float32(even),
-                       jnp.asarray(live, jnp.float32), jnp.float32(causal)])
-    return x, stats
+    stats = [busiest.astype(jnp.float32), jnp.float32(even),
+             jnp.asarray(live, jnp.float32), jnp.float32(causal)]
+    if cfg.holds_a_share:
+        routed = x.shape[0] * cfg.experts_per_token if experts else 0
+        stats += [held[0].astype(jnp.float32) if held else jnp.float32(0), jnp.float32(routed)]
+    return x, jnp.stack(stats)
 
 
 def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     """``x [B*S, D]``, the embedded tokens of ``batch`` sequences one after
     the other, each at ``pos`` (static: ``[S, 3]`` under the multimodal
     rotary, else ``[S]``), through every layer -> ``(x [B*S, D], stats
-    [6] in :data:`STEP_STATS`' order)``."""
+    in :data:`STEP_STATS`' order``, then :data:`SHARE_STATS` where the
+    holder has a share of the experts)."""
     s = x.shape[0] // batch
-    pairs = cfg.head_dim // 2
-    angles = rotary_angles(pos, cfg.rope_theta, pairs, cfg.mrope_section)
+    angles = rotary_angles(pos, cfg.rope_theta, cfg.rope_dim // 2, cfg.mrope_section,
+                           cfg.rope_yarn)
     idx_angles = None
     if cfg.indexer_heads:
         # the indexer's vectors turn with the sequence index alone
         idx_angles = rotary_angles(np.arange(s), cfg.rope_theta, cfg.indexer_head_dim // 2)
     if batch != 1:
         angles = jnp.tile(angles, (batch, 1))
-    stats = jnp.zeros((4,), jnp.float32)
+    stats = jnp.zeros((6 if cfg.holds_a_share else 4,), jnp.float32)
     for i, p in enumerate(params["layers"]):
         x, layer_stats = decoder_layer(p, x, angles, idx_angles, cfg, cfg.layer_kind(i), batch)
         stats = stats + layer_stats
-    return x, jnp.concatenate([stats, jnp.asarray([batch * s, batch], jnp.float32)])
+    served = jnp.asarray([batch * s, batch], jnp.float32)
+    if cfg.holds_a_share:
+        return x, jnp.concatenate([stats[:4], served, stats[4:]])
+    return x, jnp.concatenate([stats, served])
 
 
 def embed(params, patches, prompt_ids):
@@ -454,8 +648,9 @@ def frame_step(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, thresho
 
 
 def fold_step_stats(metrics, stats) -> None:
-    """Add one step's statistics vector (on the host or the device) to the
+    """Add one step's statistics vector (on the host or the device: six
+    values, or eight from a holder of a share of the experts) to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""
-    for name, value in zip(STEP_STATS, np.asarray(stats, np.float64)):
+    for name, value in zip(STEP_STATS + SHARE_STATS, np.asarray(stats, np.float64)):
         metrics.add_counter(name, float(value))

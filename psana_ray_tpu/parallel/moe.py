@@ -208,8 +208,9 @@ def route_top_k(probs: jax.Array, k: int, renormalise: bool = True, *, select_bi
 SCORINGS = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1), "sigmoid": jax.nn.sigmoid}
 
 
-def _grouped_product(rows, weights, tokens, first, out_dtype, interpret):
-    """``rows[group e] @ weights[e - first]`` for the held groups, by the
+def _grouped_product(rows, weights, tokens, out_dtype, interpret):
+    """``rows[group e] @ weights[e]`` (``tokens [E]``: the rows of each group,
+    the first group at row 0), by the
     megablox grouped matrix product (``pallas.ops.tpu.megablox.gmm``). On
     the v5e at ``[274432, 2048] x [128, 2048, 768]`` it took 6.3 ms and
     the down product 6.4, against ``lax.ragged_dot``'s 11.3 and 11.0 (my
@@ -219,18 +220,24 @@ def _grouped_product(rows, weights, tokens, first, out_dtype, interpret):
     MiB: at ``2048 x 1792`` (LFM2's experts) the whole widths take 47.5 MB
     of VMEM, over Mosaic's 44, and the output width goes in the largest
     128-multiple that divides it and fits (896; 1024 of the down product's
-    2048)."""
+    2048). A contraction over 2,048 wide goes in its largest 128-multiple
+    of at most 2,048 that divides it (1,792 of 7,168: no masked remainder
+    tile), and the row tile is sized against that tile, not the whole
+    width (256 rows of 7,168, where the whole width allowed 64: a quarter
+    of the v5e's ridge, each expert's weights read six times)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     m, k = rows.shape
     n = weights.shape[-1]
-    tm = next((t for t in (512, 256, 128, 64, 32, 16, 8) if m % t == 0 and t * k <= 512 * 1024), m)
     tk, tn = min(k, 2048), min(n, 2048)
+    if k % tk:
+        tk = next((t for t in range(tk - tk % 128, 0, -128) if k % t == 0), tk)
+    tm = next((t for t in (512, 256, 128, 64, 32, 16, 8) if m % t == 0 and t * tk <= 512 * 1024), m)
     fits = 4 * 1024 * 1024 // (2 * tk)  # output columns of a bf16 weight tile within 4 MiB
     if tn > fits:
         tn = next((t for t in range(tn - tn % 128, 0, -128) if n % t == 0 and t <= fits), tn)
     return gmm(rows, weights, tokens, preferred_element_type=out_dtype,
-               tiling=(tm, tk, tn), group_offset=jnp.int32(first), interpret=interpret)
+               tiling=(tm, tk, tn), interpret=interpret)
 
 
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
@@ -262,11 +269,11 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
     put them, ``k`` in-bounds gathers of ``[T, D]``, and one pass writes
     their gated sum, float32 inside, the ``k`` added in the order of the
     token's choices: a token's result depends on its own rows only,
-    wherever it sits among the others. The grouped product starts at the
-    first held expert's rows and leaves the rows of experts not held
-    unwritten: their gates are zero and the sum skips them. On one holder
-    this runs without an exchange; nothing here stands in for the other
-    holders. Off the TPU the kernels run in Pallas interpret mode."""
+    wherever it sits among the others. A holder of a SHARE of the experts
+    moves and multiplies the held slots' rows only (:func:`_held_rows_moe`).
+    On one holder this runs without an exchange; nothing here stands in
+    for the other holders. Off the TPU the kernels run in Pallas interpret
+    mode."""
     t, d = x.shape
     first, count = (0, num_experts) if experts_held is None else map(int, experts_held)
     if interpret is None:
@@ -276,32 +283,87 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
         ids, gates = route_top_k(SCORINGS[scoring](logits), k, renormalise,
                                  select_bias=select_bias, gate_eps=gate_eps,
                                  gate_scale=gate_scale)
-        held = (ids >= first) & (ids < first + count)
+        if count < num_experts:  # a share of the experts: the rows HELD move, no others
+            return _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first, count, interpret)
         flat = ids.reshape(-1)
         order = jnp.argsort(flat, stable=True)
         per_expert = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
-        gates = jnp.where(held, gates, 0.0)
     with jax.named_scope("moe_experts"):
         rows = gather_rows(x, order // k, interpret=interpret)  # [T*k, D], expert-major
-        product = functools.partial(_grouped_product, tokens=per_expert, first=first,
-                                    interpret=interpret)
+        product = functools.partial(_grouped_product, tokens=per_expert, interpret=interpret)
         h = jax.nn.silu(product(rows, w_gate, out_dtype=jnp.float32))
         h = (h * product(rows, w_up, out_dtype=jnp.float32)).astype(x.dtype)
         # out in x's type: the sorted rows are T*k*D, and float32 would be 2.2 GB at 34,304 x 8
         out = product(h, w_down, out_dtype=x.dtype)
-        y = gated_row_sum(out, order, gates, held if count < num_experts else None)
-    return y, per_expert[first:first + count]
+        y = gated_row_sum(out, order, gates)
+    return y, per_expert
+
+
+# rows a turn of the held rows' loop (two tiles of the row gather's kernel). On the v5e at 17,408
+# x 8 slots of 7,168, 12 of 384 experts held, 3,805 held rows: 2,048 a turn 15.3 ms a layer,
+# 1,024 15.3, 4,096 26.3, 8,192 29.2: XLA's scatter-add of float32 rows takes 4.05 ms for 2,048
+# rows and 16.6 for 4,096 (promised sorted and unique: 16.0 and 16.6); the parent's way, all
+# 139,264 slots' rows gathered and the grouped product offset to the held groups, 55.9 ms and
+# 6.2 GB (my chip runs, PR 42)
+HELD_CHUNK = 2048
+
+
+def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, interpret,
+                   chunk: int = HELD_CHUNK):
+    """:func:`dropless_moe` where the holder has ``count`` of the experts,
+    from the routing on (``ids, gates [T, k]``): time and memory follow the
+    token slots whose expert lives HERE, not ``T * k``. The slots are
+    sorted held experts first (expert-major among them, a token's order
+    within an expert its order in ``x``), and a ``lax.while_loop`` takes
+    them ``chunk`` rows a turn, as many turns as the held rows fill: a
+    turn gathers its rows, runs the three grouped products over the part
+    of each expert's group that falls into it, and adds each row, under
+    its gate, to its token's sum (float32, rounded once at the end). No
+    array of ``T * k`` rows exists; when every token chooses held experts
+    the loop runs ``T * k / chunk`` turns and still drops nothing."""
+    t, d = x.shape
+    k = ids.shape[1]
+    slots = t * k
+    chunk = min(chunk, slots)
+    with jax.named_scope("moe_route"):
+        here = (ids >= first) & (ids < first + count)
+        local = jnp.where(here, ids - first, count).reshape(-1)  # not held: sorts last
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, -slots % chunk))  # a turn's slice never runs off the end
+        per_expert = jnp.bincount(local, length=count + 1)[:count].astype(jnp.int32)
+        ends = jnp.cumsum(per_expert)
+        starts, n_held = ends - per_expert, ends[-1]
+        flat_gates = gates.reshape(-1)
+
+    def turn(state):
+        c, y = state
+        lo = c * chunk
+        slot = jax.lax.dynamic_slice(order, (lo,), (chunk,))
+        live = lo + jnp.arange(chunk, dtype=jnp.int32) < n_held
+        token = slot // k
+        rows = gather_rows(x, token, interpret=interpret)
+        sizes = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
+        product = functools.partial(_grouped_product, tokens=sizes, interpret=interpret)
+        h = jax.nn.silu(product(rows, w_gate, out_dtype=jnp.float32))
+        h = (h * product(rows, w_up, out_dtype=jnp.float32)).astype(x.dtype)
+        out = product(h, w_down, out_dtype=jnp.float32)
+        # past the held rows the products left `out` unwritten: 0 x garbage is not 0
+        term = jnp.where(live[:, None], out * flat_gates[slot][:, None], 0.0)
+        return c + 1, y.at[token].add(term)
+
+    with jax.named_scope("moe_experts"):
+        _, y = jax.lax.while_loop(lambda state: state[0] * chunk < n_held, turn,
+                                  (jnp.int32(0), jnp.zeros((t, d), jnp.float32)))
+    return y.astype(x.dtype), per_expert
 
 
 @jax.jit  # one trace and one lowering a process, not one an expert layer: k gathers are slow to trace
-def gated_row_sum(out, order, gates, held=None):
+def gated_row_sum(out, order, gates):
     """The expert layer's way back: ``out [T*k, D]`` (rows in the order
     ``order [T*k]`` gave the token slots), ``gates [T, k]`` float32 ->
     ``y [T, D]`` in ``out``'s type, ``y[t] = sum_j gates[t, j] * out[row
     of slot (t, j)]`` in float32, ``j = 0 .. k-1`` in that order, rounded
-    once. Where ``held [T, k]`` is given, a slot that is not held adds
-    nothing, whatever its row holds (the grouped product leaves such rows
-    unwritten, and 0 x garbage is not 0).
+    once.
 
     One sort inverts the permutation; ``k`` gathers of ``[T, D]`` with
     indices promised in bounds (no fill pass) feed ONE fused pass. What
@@ -316,7 +378,5 @@ def gated_row_sum(out, order, gates, held=None):
     for j in range(k):
         rows = out.at[back[:, j]].get(mode="promise_in_bounds", unique_indices=True)
         term = rows.astype(jnp.float32) * gates[:, j, None]
-        if held is not None:
-            term = jnp.where(held[:, j, None], term, 0.0)
         y = term if y is None else y + term
     return y.astype(out.dtype)

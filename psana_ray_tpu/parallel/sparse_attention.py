@@ -284,8 +284,11 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
             o_ref[:, h * d:(h + 1) * d] = out[h * block_q:(h + 1) * block_q].astype(o_ref.dtype)
 
 
-def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
-                   *, block_q, block_k):
+def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared):
+    if shared:  # the part of the score that all heads read from ONE key
+        qs_ref, ks_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
     t = pl.program_id(2)
     qi, kb = qi_ref[t], kb_ref[t]
     rep, _, d = q_ref.shape
@@ -301,6 +304,10 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc
         v = v_ref[...]
         s = jax.lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
+        if shared:
+            s = s + jax.lax.dot_general(
+                qs_ref[...].reshape(rows, qs_ref.shape[2]), ks_ref[...],
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         if with_diagonal:  # key 0 of the sequence is open to every row: m is finite from tile 0
             row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
@@ -321,51 +328,73 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc
 
     @pl.when(kb == ((qi + 1) * block_q - 1) // block_k)  # the row's last tile
     def _finalize():
-        o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(rep, block_q, d).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
-def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool):
+def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool,
+                      q_shared=None, k_shared=None):
     """The maskless form of :func:`masked_gqa_attention`. Operands go head
     major (``[B, G, H/G, S, d]`` and ``[B, G, S, d]``: a block's last
     dimension is then the whole head width, which Mosaic takes at 64 where
     a 64-lane block of ``[S, G*64]`` it does not), and the grid's last axis
     runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
-    even a grid step."""
+    even a grid step. The values' width is ``v``'s own (``v [B, S, G*dv]``
+    -> ``o [B, S, H*dv]``). With ``q_shared [B, S, H*ds]`` and ``k_shared
+    [B, S, ds]`` a score is ``q . k + q_shared . k_shared``: the shared
+    key's tile is read from its one array, once a grid step, for every
+    head (latent attention's one rotary key)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, hd = q.shape
-    d = k.shape[2] // g
+    d, dv = k.shape[2] // g, v.shape[2] // g
     rep = hd // (g * d)
+    shared = q_shared is not None
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
     pairs = [(i, j) for i in range(s // bq) for j in range(((i + 1) * bq - 1) // bk + 1)]
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
-    q5 = jnp.transpose(q.reshape(b, s, g, rep, d), (0, 2, 3, 1, 4))
-    k4, v4 = (jnp.transpose(x.reshape(b, s, g, d), (0, 2, 1, 3)) for x in (k, v))
-    kv_spec = pl.BlockSpec((None, None, bk, d), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0))
-    q_spec = pl.BlockSpec((None, None, rep, bq, d), lambda bi, gi, t, qi, kb: (bi, gi, 0, qi[t], 0))
+
+    def q_major(x, width):
+        return jnp.transpose(x.reshape(b, s, g, rep, width), (0, 2, 3, 1, 4))
+
+    def q_spec(width):
+        return pl.BlockSpec((None, None, rep, bq, width),
+                            lambda bi, gi, t, qi, kb: (bi, gi, 0, qi[t], 0))
+
+    def kv_spec(width):
+        return pl.BlockSpec((None, None, bk, width), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0))
+
+    q5 = q_major(q, d)
+    k4, v4 = (jnp.transpose(x.reshape(b, s, g, w), (0, 2, 1, 3)) for x, w in ((k, d), (v, dv)))
+    operands, in_specs = [q5, k4, v4], [q_spec(d), kv_spec(d), kv_spec(dv)]
+    if shared:
+        ds = k_shared.shape[2]
+        operands += [q_major(q_shared, ds), k_shared]
+        in_specs += [q_spec(ds),
+                     pl.BlockSpec((None, bk, ds), lambda bi, gi, t, qi, kb: (bi, kb[t], 0))]
     o5 = pl.pallas_call(
-        functools.partial(_causal_kernel, block_q=bq, block_k=bk),
+        functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g, len(pairs)),
-            in_specs=[q_spec, kv_spec, kv_spec], out_specs=q_spec,
+            in_specs=in_specs, out_specs=q_spec(dv),
             scratch_shapes=[
                 pltpu.VMEM((rep * bq, 1), jnp.float32),
                 pltpu.VMEM((rep * bq, 1), jnp.float32),
-                pltpu.VMEM((rep * bq, d), jnp.float32),
+                pltpu.VMEM((rep * bq, dv), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct(q5.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, g, rep, s, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="masked_gqa_attention",
-    )(qi, kb, q5, k4, v4)
-    return jnp.transpose(o5, (0, 3, 1, 2, 4)).reshape(b, s, hd)
+    )(qi, kb, *operands)
+    return jnp.transpose(o5, (0, 3, 1, 2, 4)).reshape(b, s, g * rep * dv)
 
 
 def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Optional[int] = None,
-                         block_k: int = 512, interpret: Optional[bool] = None) -> jax.Array:
+                         block_k: int = 512, interpret: Optional[bool] = None,
+                         q_shared=None, k_shared=None) -> jax.Array:
     """``q [S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [S, G*d]``,
     ``mask`` from :func:`select_keys` -> ``o [S, H*d]``: softmax attention
     of every query head over the keys its query selected, query head ``h``
@@ -376,10 +405,16 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     ``mask=None``: plain causal attention of a BATCH of sequences, ``q [B,
     S, H*d]`` and ``k, v [B, S, G*d]`` -> ``[B, S, H*d]``, each sequence
     on its own, in tiles of ``block_q`` (default 256) by ``block_k``; only
-    tiles at or below the diagonal are visited (:func:`_causal_attention`)."""
+    tiles at or below the diagonal are visited (:func:`_causal_attention`).
+    There the value heads may have a width of their own (``v [B, S,
+    G*dv]`` -> ``[B, S, H*dv]``), and a score may have a second part,
+    ``q_shared [B, S, H*ds] . k_shared [B, S, ds]``, whose key is ONE for
+    all heads (latent attention: the rotary key)."""
     if mask is None:
         return _causal_attention(q, k, v, int(num_kv_heads), block_q or 256, block_k,
-                                 _interpret(interpret))
+                                 _interpret(interpret), q_shared, k_shared)
+    if q_shared is not None or v.shape[1] != k.shape[1]:
+        raise ValueError("a shared key part and a value width of its own are the maskless form's")
     from jax.experimental.pallas import tpu as pltpu
 
     n_qb, n_kb, mq, bk = mask.shape

@@ -248,7 +248,59 @@ def _lfm2_experts():
                     _rows_move_once_each_way, tokens=LFM2_B * LFM2_S, k=4)
 
 
+KIMI_B, KIMI_S, KIMI_D = 2, 8704, 7168  # two frames of 8,448 patches + 256 prompt tokens
+
+
+def _kimi_attention():
+    """Latent attention's prefill at Kimi-K2's heads: 64 heads, a score of a
+    128-deep product per head plus a 64-deep product against the ONE rotary
+    key (read from its ``[B, S, 64]`` array: no ``[B, S, 64 * 192]`` key
+    exists), values 128 wide."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    def fn(q, k, v, q_rope, k_rope):
+        return sa.masked_gqa_attention(q, k, v, num_kv_heads=64, block_q=1088, block_k=1088,
+                                       q_shared=q_rope, k_shared=k_rope, interpret=False)
+
+    wide = S((KIMI_B, KIMI_S, 64 * 128), BF16)
+
+    def no_broadcast_key(text):
+        assert f"[{KIMI_B},{KIMI_S},{64 * 192}]" not in text
+        assert f"[{KIMI_B},64,{KIMI_S},192]" not in text
+
+    return fn, [wide, wide, wide, S((KIMI_B, KIMI_S, 64 * 64), BF16),
+                S((KIMI_B, KIMI_S, 64), BF16)], 1, no_broadcast_key
+
+
+def _kimi_experts():
+    """The expert layer on a holder of 12 of 384 experts of 7168 x 2048,
+    top 8 under the sigmoid router: a loop over the HELD rows in chunks, so
+    no array of all 139,264 token slots' rows exists. The loop's body calls
+    three Pallas kernels, the grouped products, and the layer no other:
+    ``gmm_roofline_share.kimi`` divides by the time of every Pallas call
+    under the scope ``moe`` (``readers/roofline_share_per_run.py``)."""
+    from psana_ray_tpu.parallel.moe import dropless_moe
+
+    def fn(x, router, bias, w_gate, w_up, w_down):
+        return dropless_moe(x, router, w_gate, w_up, w_down, k=8, num_experts=384,
+                            experts_held=(0, 12), scoring="sigmoid", select_bias=bias,
+                            gate_eps=1e-20, gate_scale=2.827, interpret=False)
+
+    def held_rows_only(text):
+        slots = KIMI_B * KIMI_S * 8
+        assert f"[{slots},{KIMI_D}]" not in text and f"[{slots},2048]" not in text
+        assert "while(" in text  # the loop over the held rows' chunks
+        kernels = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text, re.M)
+        assert len(kernels) == 3, kernels  # gate, up, down: the row gather is XLA's at this width
+
+    up = S((12, KIMI_D, 2048), BF16)
+    return fn, [S((KIMI_B * KIMI_S, KIMI_D), BF16), S((KIMI_D, 384), BF16), S((384,), F32), up, up,
+                S((12, 2048, KIMI_D), BF16)], 3, held_rows_only
+
+
 CASES = {
+    "kimi_latent_attention_2x8704x64x192": _kimi_attention,
+    "kimi_held_experts_17408x8_12_of_384": _kimi_experts,
     "lfm2_causal_gqa_attention_4x8704x64": _lfm2_attention,
     "lfm2_gated_short_conv_34816": _lfm2_conv,
     "lfm2_dropless_experts_34816x4": _lfm2_experts,
@@ -337,6 +389,46 @@ def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
     assert (
         mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     ) < 16e9
+
+
+# sha256 of the served step's lowered text (StableHLO), the kernels' serialized bodies cut out
+# (they carry file paths and line numbers): what `decoder.frame_step` traces to for the two
+# decoders the benchmark had before PR 42. Pinned on PR 41's tree first (PR 42's trunk lowered
+# to it), then again in PR 42, knowingly: with every share sent to `_held_rows_moe`, the
+# all-held path lost what it did for a share (the held mask and its `where` on the gates, the
+# grouped product's group offset of 0, the slice of the per-expert counts); the device times
+# did not move (PERF.md section 6). A PR that means to change
+# one of these programs re-pins it, knowingly: an equal text is an equal key in the compile
+# cache, and a decoder cell's warm `setup_s` (bound 0.1) pays seconds for anything new to trace
+PINNED_STEPS = {
+    "keye_vl2_prefill_epix10k2m": "7ba74ce99ef580a3c7965ffc0a69c7388c5b3088a8b3cee64ab9e1dd8a69af1f",
+    "lfm2_8b_a1b_prefill_epix10k2m": "7434bf59d9d3941cfe175dc4d0ec2e51f5f336d0b2a010924095d61446a8dd9d",
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_STEPS))
+def test_the_other_decoders_steps_lower_to_the_programs_they_were(name, one_chip, monkeypatch):
+    import hashlib
+    import json
+
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmark", "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    dcfg = decoder.DecoderConfig.from_mapping(cfg)
+    params = jax.eval_shape(lambda k: decoder.init_params(dcfg, k), jax.random.key(0))
+    calib = (S((PANELS, H, W), F32), S((PANELS, H, W), F32), S((PANELS, H, W), jnp.uint8))
+    frames = S((cfg["batch_size"], PANELS, H, W), jnp.uint16)
+    ids = S((cfg["prompt_tokens"],), jnp.int32)
+
+    def step(p, c, f, i):
+        return decoder.frame_step(p, c, f, i, cfg=dcfg, threshold=10.0)
+
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), (params, calib, frames, ids))
+    text = jax.jit(step).lower(*args).as_text()
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
+    assert hashlib.sha256(text.encode()).hexdigest() == PINNED_STEPS[name]
 
 
 @pytest.mark.parametrize("d", [0, 1, 2, 3])

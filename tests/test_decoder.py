@@ -290,11 +290,9 @@ def test_rows_go_out_and_come_back_as_they_did_before(t, k, experts, load, share
     assert row_gather.tile_rows(t * k, x.shape[1], x.dtype) == t * k  # one tile, through the kernel
     got_rows = row_gather.gather_rows(x, order // k)
     assert got_rows.dtype == rows.dtype and bool(jnp.array_equal(got_rows, rows))
-    # back, from the SAME experts' rows. Rows of experts not held are never written
-    # by the grouped product: NaN stands for what they may hold
-    held_slot = jnp.asarray(np.asarray(gates) > 0) if share else None
-    dirty = jnp.where(jnp.take(gates.reshape(-1), order)[:, None] > 0, out, jnp.nan) if share else out
-    got = moe.gated_row_sum(dirty, order, gates, held_slot)
+    # back, from the SAME experts' rows (a share's slots that are not held: a gate of 0 on a
+    # row of zeros here; the layer itself never makes such a row, `_held_rows_moe`)
+    got = moe.gated_row_sum(out, order, gates)
     # the float32 sum in the order j = 0 .. k-1, rounded once: what the new pass
     # states. WITHIN ONE bfloat16 step of it, and of the old reduce, not bit-equal:
     # XLA may keep a product unrounded into the add (one rounding fewer) and the

@@ -1,0 +1,611 @@
+"""Latent attention, YaRN, the shared expert and a holder's SHARE of the
+routed experts (``models/decoder.py`` reading Kimi-K2-Instruct's keys:
+DeepSeek-V3's block) against the benchmark's plain reference
+(``benchmark/reference/kimi_k2_decoder.py``) at small sizes on the CPU;
+the causal kernel at a key width that is not its value width and with a
+key part shared by all heads; the new cell's manifest entries and counts."""
+
+import dataclasses
+import importlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import kimi_k2_decoder as ref
+from psana_ray_tpu.models import decoder
+from psana_ray_tpu.parallel import moe
+from psana_ray_tpu.parallel import sparse_attention as sa
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
+CONFIG = os.path.join(REPO, "benchmark", "configs", "kimi_k2_prefill_epix10k2m.json")
+CELL = "kimi_k2_epix_saturated"
+YARN = {"type": "yarn", "factor": 32, "original_max_position_embeddings": 16, "beta_fast": 1,
+        "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}
+
+
+def mapping(**over):
+    """Kimi-K2's Hugging Face keys at a small size: 16 routed experts, all held."""
+    m = dict(
+        hidden_size=64, num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=4,
+        vocab_size=256, rms_norm_eps=1e-6, rope_theta=50000, q_lora_rank=24, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, first_k_dense_replace=1,
+        n_routed_experts=16, n_shared_experts=1, num_experts_per_tok=4, moe_intermediate_size=32,
+        intermediate_size=96, norm_topk_prob=True, scoring_func="sigmoid",
+        topk_method="noaux_tc", routed_scaling_factor=2.827, tie_word_embeddings=False,
+        rope_scaling=dict(YARN), patch=8,
+    )
+    m.update(over)
+    return m
+
+
+def small(m):
+    """Tiles that cut 64 tokens into several."""
+    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=16, causal_kv_tile=32)
+
+
+def loud(params, by=5.0):
+    """The same tree with its matrices scaled up: at a hidden size of 64,
+    normal(0, 0.02) makes every operator's output a hundredth of the
+    residual stream's, and a test would not see a fault in one."""
+    return jax.tree.map(lambda a: a * by if a.ndim >= 2 else a, params)
+
+
+def inputs(seed, batch=1):
+    rng = np.random.default_rng(seed)
+    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
+    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
+
+
+def embedded(params, patches, ids):
+    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
+
+
+def share_of(params, first, count):
+    """The tree a holder of experts ``first .. first + count`` has."""
+    held = ("w_gate", "w_up", "w_down")
+    return {**params, "layers": [
+        {k: (v[first:first + count] if k in held and v.ndim == 3 else v) for k, v in p.items()}
+        for p in params["layers"]]}
+
+
+# ---------------------------------------------------------------------------
+# the trunk against the reference, float32, all positions
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("held", ["all_16", "experts_4_to_7_of_16", "no_dense_layer"])
+def test_trunk_with_latent_attention_matches_reference_at_all_positions(held):
+    m = mapping()
+    if held == "no_dense_layer":
+        m.update(first_k_dense_replace=0)
+    cfg = small(m)
+    params = loud(decoder.init_params(cfg, jax.random.key(3), jnp.float32))
+    if held == "experts_4_to_7_of_16":  # a share: the file's key counts the experts held
+        m.update(n_routed_experts=4, router_experts=16, experts_held=[4, 4])
+        cfg, params = small(m), share_of(params, 4, 4)
+    patches, ids = inputs(3)
+    sizes = ref.sizes(m)
+    with jax.default_matmul_precision("highest"):
+        x, stats = jax.jit(lambda p: decoder.trunk(
+            p, embedded(p, patches, ids), np.arange(64), cfg))(params)
+        got = decoder.logits_of(decoder.head_params(params), x, cfg)
+        want_x = ref.hidden(params, patches[0], ids, sizes, block=16)
+        want = ref.logits_of(params, want_x, sizes)
+    assert got.shape == (64, 256) and "head" in params  # untied
+    for a, b in ((x, want_x), (got, want)):
+        scale = float(jnp.sqrt(jnp.mean(b ** 2)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4 * scale, rtol=0)
+    n_moe = 3 if held == "no_dense_layer" else 2
+    assert float(stats[1]) == n_moe * 64 * 4 / 16  # the even share of an expert, over the expert layers
+    assert float(stats[2]) == float(stats[3]) == 3  # one 64 x 64 tile a latent-attention layer
+    assert [float(v) for v in stats[4:6]] == [64.0, 1.0]
+    if held == "experts_4_to_7_of_16":  # a holder of a share counts what it held, and what was routed
+        assert len(stats) == 8 and float(stats[7]) == n_moe * 64 * 4
+        assert 0 < float(stats[6]) < float(stats[7])
+    else:
+        assert len(stats) == 6
+
+
+@pytest.mark.parametrize("fault", ["turn_key", "mscale", "yarn", "kv_norm", "shared", "select_bias",
+                                   "softmax"])
+def test_the_reference_with_a_fault_in_it_is_another_trunk(fault):
+    m = mapping()
+    cfg = small(m)
+    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
+    params["layers"] = [{**p, "kv_a_norm": p["kv_a_norm"] * 3.0} for p in params["layers"]]
+    patches, ids = inputs(5)
+    faults = {"softmax": {"scoring": "softmax"}}.get(fault, {fault: False})
+    with jax.default_matmul_precision("highest"):
+        x, _ = decoder.trunk(params, embedded(params, patches, ids), np.arange(64), cfg)
+        want = ref.hidden(params, patches[0], ids, ref.sizes(m, **faults), block=16)
+    scale = float(jnp.sqrt(jnp.mean(want ** 2)))
+    assert float(jnp.abs(x - want).max()) > 1e-2 * scale  # what a control puts in is seen
+
+
+# ---------------------------------------------------------------------------
+# the latent-attention layer alone, and its kernel
+# ---------------------------------------------------------------------------
+
+def test_latent_attention_layer_is_the_reference_operator_for_a_batch_of_two():
+    m = mapping()
+    cfg = small(m)
+    p = loud(decoder.init_params(cfg, jax.random.key(7), jnp.float32))["layers"][0]
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((2 * 64, 64)), jnp.float32)
+    angles = jnp.tile(decoder.rotary_angles(np.arange(64), cfg.rope_theta, 4, None, cfg.rope_yarn),
+                      (2, 1))
+    sizes = ref.sizes(m)
+    with jax.default_matmul_precision("highest"):
+        got = decoder.latent_attention(p, x, angles, 2, cfg) - x
+        want = jnp.concatenate([
+            ref.latent_attention(p, ref.rms(x[i * 64:(i + 1) * 64], p["norm1"], 1e-6), sizes,
+                                 jnp.float32, 16) for i in range(2)])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert "q_norm" not in p and "k_norm" not in p  # no per-head norm in this operator
+
+
+@pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16), (48, 32), (96, 96)])
+@pytest.mark.parametrize("rep", [1, 2])
+def test_causal_kernel_with_a_shared_key_part_and_values_of_another_width(bq, bk, rep):
+    rng = np.random.default_rng(bq + rep)
+    b, s, g, d, ds, dv = 2, 96, 2, 16, 8, 24
+    h = g * rep
+    q = jnp.asarray(rng.standard_normal((b, s, h * d)), jnp.float32) * 0.3
+    qs = jnp.asarray(rng.standard_normal((b, s, h * ds)), jnp.float32) * 0.3
+    k = jnp.asarray(rng.standard_normal((b, s, g * d)), jnp.float32)
+    ks = jnp.asarray(rng.standard_normal((b, s, ds)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((b, s, g * dv)), jnp.float32)
+    got = sa.masked_gqa_attention(q, k, v, num_kv_heads=g, block_q=bq, block_k=bk,
+                                  q_shared=qs, k_shared=ks)
+    kh, vh = (jnp.repeat(x.reshape(b, s, g, -1), rep, axis=2) for x in (k, v))
+    score = (jnp.einsum("bthd,bshd->bhts", q.reshape(b, s, h, d), kh, precision="highest")
+             + jnp.einsum("bthd,bsd->bhts", qs.reshape(b, s, h, ds), ks, precision="highest"))
+    score = jnp.where(jnp.tril(jnp.ones((s, s), bool)), score, -jnp.inf)
+    want = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(score, -1), vh, precision="highest")
+    assert got.shape == (b, s, h * dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want.reshape(b, s, h * dv)), atol=3e-6)
+    # the plain way to the same numbers: the shared key written once a head beside the head's own
+    wide_q = jnp.concatenate([q.reshape(b, s, h, d), qs.reshape(b, s, h, ds)], -1).reshape(b, s, -1)
+    wide_k = jnp.concatenate([k.reshape(b, s, g, d), jnp.broadcast_to(ks[:, :, None], (b, s, g, ds))],
+                             -1).reshape(b, s, -1)
+    plain = sa.masked_gqa_attention(wide_q, wide_k, v, num_kv_heads=g, block_q=bq, block_k=bk)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(plain), atol=3e-6)
+
+
+def test_a_mask_goes_with_neither_a_shared_key_nor_a_value_width_of_its_own():
+    z = jnp.zeros((16, 32), jnp.float32)
+    mask = jnp.ones((1, 1, 16, 16), jnp.int8)
+    with pytest.raises(ValueError, match="maskless"):
+        sa.masked_gqa_attention(z, z, z[:, :16], mask, num_kv_heads=2)
+    with pytest.raises(ValueError, match="maskless"):
+        sa.masked_gqa_attention(z, z, z, mask, num_kv_heads=2, q_shared=z, k_shared=z[:, :8])
+
+
+# ---------------------------------------------------------------------------
+# YaRN, against the formula written out
+# ---------------------------------------------------------------------------
+
+def test_yarn_angles_at_the_published_sizes_are_the_formula_written_out():
+    with open(CONFIG) as f:
+        cfg = decoder.DecoderConfig.from_mapping(json.load(f))
+    yarn = cfg.rope_yarn
+    assert yarn == decoder.Yarn(32.0, 4096, 1.0, 1.0, 1.0, 1.0) and cfg.rope_dim == 64
+    correction = 64 * np.log(4096 / (2 * np.pi)) / (2 * np.log(50000))
+    assert 19.1 < correction < 19.2  # pairs 0..19 keep their frequency, 20..31 are divided by 32
+    want = np.asarray([50000.0 ** (-i / 32) / (1.0 if i <= 19 else 32.0) for i in range(32)])
+    np.testing.assert_allclose(yarn.inv_freq(50000.0, 32), want, rtol=1e-12)
+    np.testing.assert_allclose(ref.yarn_inv_freq(ref.sizes(json.load(open(CONFIG)))), want, rtol=1e-12)
+    pos = np.asarray([0, 1, 4095, 8703])
+    got = decoder.rotary_angles(pos, 50000.0, 32, None, yarn)
+    np.testing.assert_allclose(np.asarray(got), pos[:, None] * want[None, :], rtol=1e-6)
+    assert yarn.rotary_scale == 1.0  # mscale / mscale_all_dim
+    assert yarn.softmax_scale == pytest.approx((0.1 * np.log(32) + 1) ** 2) == pytest.approx(1.8133, abs=1e-4)
+    # a ramp that spans several pairs blends; no scaling under a factor of 1
+    wide = decoder.Yarn(4.0, 64, 8.0, 1.0, 1.0, 0.0).inv_freq(10000.0, 16)
+    plain = 10000.0 ** (-np.arange(16) / 16)
+    assert wide[0] == plain[0] and wide[-1] == plain[-1] / 4 and np.all(np.diff(wide / plain) <= 0)
+    assert decoder.Yarn(1.0, 64).softmax_scale == 1.0
+    # and plain rotary is what it was
+    np.testing.assert_allclose(np.asarray(decoder.rotary_angles(pos, 1e6, 32)),
+                               pos[:, None] * (1e6 ** (-np.arange(32) / 32))[None, :], rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the shares of a divided layer, the shared expert counted once
+# ---------------------------------------------------------------------------
+
+def _expert_layer(seed, t=64, d=32, width=16, experts=16, k=4):
+    rng = np.random.default_rng(seed)
+
+    def w(*shape, by=0.2):
+        return jnp.asarray(rng.standard_normal(shape) * by, jnp.float32)
+
+    p = {"router": w(d, experts, by=0.5), "router_bias": w(experts, by=0.3),
+         "w_gate": w(experts, d, width), "w_up": w(experts, d, width), "w_down": w(experts, width, d),
+         "shared_gate": w(d, width), "shared_up": w(d, width), "shared_down": w(width, d)}
+    m = ref.sizes(mapping(n_routed_experts=experts, num_experts_per_tok=k))
+    return p, w(t, d, by=1.0), m
+
+
+def _routed(p, b, held, **kw):
+    first, count = held
+    return moe.dropless_moe(b, p["router"], p["w_gate"][first:first + count],
+                            p["w_up"][first:first + count], p["w_down"][first:first + count],
+                            k=4, num_experts=16, experts_held=held, scoring="sigmoid",
+                            select_bias=p["router_bias"], gate_eps=1e-20, gate_scale=2.827, **kw)
+
+
+def test_four_shares_of_four_experts_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+    p, b, m = _expert_layer(9)
+    with jax.default_matmul_precision("highest"):
+        parts, served = [], 0
+        for first in (0, 4, 8, 12):
+            y, tokens = _routed(p, b, (first, 4))
+            parts.append(np.asarray(y, np.float64))
+            served += int(np.asarray(tokens).sum())
+        shared = np.asarray(decoder._dense_mlp(
+            {"w_gate": p["shared_gate"], "w_up": p["shared_up"], "w_down": p["shared_down"]}, b))
+        routed, chosen = ref.experts(p, b, m, jnp.float32)
+        want = np.asarray(routed + ref.shared_expert(p, b, jnp.float32))
+    assert served == 64 * 4 and np.asarray(chosen).sum() == 64 * 4  # every slot, once
+    assert min(np.abs(part).max() for part in parts) > 0 and np.abs(shared).max() > 0
+    np.testing.assert_allclose(sum(parts) + shared, want, atol=2e-5)
+    # every share WITH the shared expert would count it four times: not the layer
+    assert np.abs(sum(part + shared for part in parts) - want).max() > 1e-2
+    # and the reference, given one share, gives that share
+    held = {k: (v[4:8] if k in ("w_gate", "w_up", "w_down") else v) for k, v in p.items()}
+    one, _ = ref.experts(held, b, {**m, "experts_held": (4, 4)}, jnp.float32)
+    np.testing.assert_allclose(parts[1], np.asarray(one), atol=2e-5)
+
+
+@pytest.mark.parametrize("chunk", [16, 64, 4096])
+def test_when_every_token_chooses_held_experts_no_row_is_dropped(chunk, monkeypatch):
+    """The adversarial routing: the selection bias lifts experts 4..7 over
+    all others for every token, so all T * k slots are held rows, several
+    turns of the loop at a chunk of 16 or 64."""
+    p, b, m = _expert_layer(11)
+    p["router_bias"] = jnp.zeros(16).at[4:8].set(10.0)
+    real = moe._held_rows_moe
+    monkeypatch.setattr(moe, "_held_rows_moe", lambda *a: real(*a, chunk=chunk))
+    with jax.default_matmul_precision("highest"):
+        y, tokens = _routed(p, b, (4, 4))
+        want, chosen = ref.experts(p, b, m, jnp.float32)  # the uncut layer: nothing else was chosen
+    assert np.asarray(tokens).tolist() == [64] * 4  # exact: every slot of every token, here
+    assert np.asarray(chosen)[:, 4:8].all() and np.asarray(chosen).sum() == 64 * 4
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
+    # and a holder nobody chose computes nothing, in no turn of the loop
+    y0, tokens0 = _routed(p, b, (12, 4))
+    assert int(np.asarray(tokens0).sum()) == 0 and float(jnp.abs(y0).max()) == 0.0
+
+
+def test_a_share_moves_its_held_rows_only_and_the_whole_layer_is_the_program_it_was(monkeypatch):
+    p, b, m = _expert_layer(13)
+    real = moe._held_rows_moe
+    monkeypatch.setattr(moe, "_held_rows_moe", lambda *a: real(*a, chunk=64))
+    share = jax.make_jaxpr(lambda b: _routed(p, b, (0, 4)))(b)
+    whole = jax.make_jaxpr(lambda b: _routed(p, b, (0, 16)))(b)
+    slots = 64 * 4
+
+    def shapes(jaxpr, out=None):
+        out = set() if out is None else out
+        for eqn in jaxpr.eqns:
+            out.update(tuple(v.aval.shape) for v in eqn.outvars if hasattr(v.aval, "shape"))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                shapes(sub, out)
+        return out
+
+    assert (slots, 32) in shapes(whole.jaxpr)  # all T * k rows gathered where every expert is held
+    assert (slots, 32) not in shapes(share.jaxpr)  # and none in a share: 64 rows a turn
+    assert "while" in str(share) and "while" not in str(whole)
+
+
+# ---------------------------------------------------------------------------
+# the configuration's third spelling
+# ---------------------------------------------------------------------------
+
+def test_kimi_configuration_reads_the_published_keys():
+    with open(CONFIG) as f:
+        cfg = json.load(f)
+    got = decoder.DecoderConfig.from_mapping(cfg)
+    assert (got.hidden_size, got.num_heads, got.head_dim, got.rope_dim) == (7168, 64, 192, 64)
+    assert (got.q_lora_rank, got.kv_lora_rank, got.qk_nope_head_dim, got.qk_rope_head_dim,
+            got.v_head_dim) == (1536, 512, 128, 64, 128)
+    assert (got.num_experts, got.experts_held, got.experts_per_token, got.expert_width,
+            got.shared_experts, got.num_dense_layers) == (384, (0, 12), 8, 2048, 1, 1)
+    assert (got.router_scoring, got.expert_bias, got.gate_eps, got.routed_scaling_factor,
+            got.norm_topk_prob, got.tie_embedding) == ("sigmoid", True, 1e-20, 2.827, True, False)
+    assert got.holds_a_share and got.vocab_size == 20480 == cfg["published"]["vocab_size"] // 8
+    kinds = [got.layer_kind(i) for i in range(got.num_layers)]
+    assert kinds == [(decoder.LATENT, False)] + [(decoder.LATENT, True)] * 6
+    shapes = jax.eval_shape(lambda k: decoder.init_params(got, k), jax.random.key(0))
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    assert 4.84e9 < n < 4.86e9  # the file's 4.85 G parameters, 9.70 GB in bf16
+    layer = shapes["layers"][1]
+    assert layer["router"].shape == (7168, 384) and layer["w_gate"].shape == (12, 7168, 2048)
+    assert layer["wkv_a"].shape == (7168, 576) and layer["wq_b"].shape == (1536, 64 * 192)
+    assert cfg["sequence_tokens"] == 16 * (352 // 16) * (384 // 16) + cfg["prompt_tokens"] == 8704
+    assert cfg["step_tokens"] == cfg["batch_size"] * cfg["sequence_tokens"] == 17408
+    assert cfg["n_routed_experts"] == cfg["experts_held"][1] == cfg["published"]["n_routed_experts"] // 32
+    with pytest.raises(ValueError, match="q_lora_rank"):
+        decoder.DecoderConfig.from_mapping({**cfg, "q_lora_rank": None})
+
+
+@pytest.mark.parametrize("name", ["keye_vl2_prefill_epix10k2m", "lfm2_8b_a1b_prefill_epix10k2m"])
+def test_the_other_two_readers_have_nothing_of_what_kimi_brought(name):
+    with open(os.path.join(REPO, "benchmark", "configs", name + ".json")) as f:
+        got = decoder.DecoderConfig.from_mapping(json.load(f))
+    assert (got.rope_yarn, got.q_lora_rank, got.kv_lora_rank, got.qk_nope_head_dim,
+            got.qk_rope_head_dim, got.v_head_dim, got.shared_experts) == (None, 0, 0, 0, 0, 0, 0)
+    assert not got.holds_a_share and got.rope_dim == got.head_dim
+    assert decoder.LATENT not in {got.layer_kind(i)[0] for i in range(got.num_layers)}
+    assert got.gate_eps == (1e-6 if "lfm2" in name else 0.0)
+
+
+def test_catalog_numbers_are_in_the_file_unchanged():
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if not os.path.exists(catalog):
+        pytest.skip("no catalog here")
+    with open(catalog) as f:
+        row, = [r for r in map(json.loads, f) if r["name"] == "Kimi-K2-Instruct"]
+    with open(CONFIG) as f:
+        cfg = json.load(f)
+    assert cfg["source"] == row["source_url"] and len(cfg["source"]) <= 200
+    differs = {k for k, v in row["config"].items() if cfg.get(k) != v}
+    assert differs == set(cfg["reduced"]) == {"num_hidden_layers", "n_routed_experts", "vocab_size"}
+    assert {k: row["config"][k] for k in differs} == {k: cfg["published"][k] for k in differs}
+    assert "32 chips that share each layer" in cfg["deployment"] and len(cfg["assumed"]) >= 8
+
+
+# ---------------------------------------------------------------------------
+# the counters of a share, in snapshot() and under /metrics
+# ---------------------------------------------------------------------------
+
+def test_counters_of_a_share_reach_the_snapshot_and_the_exposition():
+    from benchmark import harness
+    from psana_ray_tpu.infeed import InfeedPipeline
+    from psana_ray_tpu.obs.registry import MetricsRegistry
+    from psana_ray_tpu.records import EndOfStream, FrameRecord
+    from psana_ray_tpu.transport import RingBuffer
+
+    cfg = small(mapping(n_routed_experts=4, router_experts=16, experts_held=[0, 4]))
+    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
+    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
+                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
+    calib = harness.make_calibration(detector, 1)
+    ids = jnp.arange(PROMPT, dtype=jnp.int32)
+    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
+    rng = np.random.default_rng(2)
+    q = RingBuffer(maxsize=8)
+    for i in range(4):
+        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
+    q.put(EndOfStream(total_events=4))
+    pipe = InfeedPipeline(q, batch_size=2, poll_interval_s=0.001)
+    logits = []
+
+    def on_result(out, batch):
+        logits.append(np.asarray(out[0]))
+        decoder.fold_step_stats(pipe.metrics, out[1])
+
+    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 4
+    assert all(x.shape == (2, 256) and np.isfinite(x).all() for x in logits)
+    snap = pipe.metrics.snapshot()
+    steps, tokens = 2, 2 * (2 * 2 * 14 + PROMPT)
+    assert snap["decoder_tokens_total"] == steps * tokens
+    assert snap["expert_rows_routed_total"] == steps * 2 * tokens * 4  # two expert layers, 4 a token
+    assert 0 < snap["expert_rows_held_total"] < snap["expert_rows_routed_total"]
+    assert snap["expert_tokens_mean_total"] == steps * 2 * tokens * 4 / 16
+    text = MetricsRegistry()
+    text.register("reader", pipe.metrics)
+    text = text.render_prometheus()
+    for name in decoder.STEP_STATS + decoder.SHARE_STATS:
+        assert f'psana_ray_{name}{{source="reader"}}' in text, name
+
+
+# ---------------------------------------------------------------------------
+# the manifest's new files
+# ---------------------------------------------------------------------------
+
+def _manifest():
+    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+        return json.load(f)
+
+
+KIMI_METRICS = ["proj_ms.kimi", "latent_attn_ms.kimi", "shared_expert_ms.kimi", "moe_ms.kimi",
+                "mlp_ms.kimi", "latent_attention_roofline_share.kimi", "gmm_roofline_share.kimi",
+                "step_mfu.kimi", "expert_load_peak.kimi", "held_rows_share.kimi"]
+
+
+@pytest.mark.parametrize("name", KIMI_METRICS)
+def test_every_metric_file_of_the_kimi_cell_names_a_reader_and_keys_that_exist(name):
+    manifest = _manifest()
+    entry, = [e for e in manifest["per_layer"] if e["name"] == name]
+    assert entry["workloads"] == [CELL] and entry["moves"] == "fps.hit"
+    assert [e["name"] for e in manifest["per_layer"][-len(KIMI_METRICS):]] == KIMI_METRICS  # appended
+    with open(CONFIG) as f:
+        cfg = json.load(f)
+    with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
+        spec = json.load(f)
+    reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
+    assert callable(reader.read)
+    args = spec["args"]
+    if "function" in args:
+        module, fn = args["function"].rsplit(".", 1)
+        need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
+        given = set(args["shape_from"]) | ({"held_share"} if "share" in args else set())
+        assert given == set(need.__code__.co_varnames[:need.__code__.co_argcount])
+        assert all(path in cfg for path in args["shape_from"].values())
+    for key in ("pattern", "within"):
+        if args.get(key, "").startswith("@"):
+            assert args[key][1:] in cfg["trace_names"]
+    if name == "gmm_roofline_share.kimi":  # found by scope and primitive: XLA renames a kernel in a loop
+        assert (args["scope"], args["leaf"]) == ("moe", "pallas_call")
+    for key in ("numerator", "denominator"):
+        for counters in (args, args.get("share", {})):
+            if key in counters:
+                assert counters[key] in decoder.STEP_STATS + decoder.SHARE_STATS
+
+
+@pytest.mark.parametrize("name", [
+    "producer_blocked_share.hit", "ring_depth.hit", "device_put_ms", "device_wait_ms.hit",
+    "step_ms.hit", "device_idle_share.hit", "queue_dwell_ms.hit", "infeed_wait_ms", "launch_ms.hit",
+    "stopped_ms.hit", "h2d_ms.hit", "prefetch_starved_share.hit", "prefetch_busy_share.hit",
+    "prefetch_copy_share.hit", "prefetch_blocked_share.hit", "idle_launched_share.hit",
+    "idle_unfed_share.hit", "idle_in_h2d_share.hit", "fps.hit",
+])
+def test_the_kimi_cell_reports_the_host_path_under_the_names_the_other_decoders_have(name):
+    manifest = _manifest()
+    entry, = [e for e in manifest["per_layer"] + manifest["end_to_end"] if e["name"] == name]
+    assert entry["workloads"][-3:] == ["keye_epix_saturated", "lfm2_epix_saturated", CELL]
+    calib, = [e for e in manifest["per_layer"] if e["name"] == "calib_roofline_share.hit"]
+    assert calib["workloads"] == ["hit_epix_saturated"]  # PERF.md section 7 (b)
+    cell, = [w for w in manifest["workloads"] if w["name"] == CELL]
+    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "saturated", "kimi_k2_prefill_epix10k2m")
+    assert manifest["workloads"][-1] is cell and manifest["configs"][-1]["name"] == cell["config"]
+
+
+def test_kimi_roofline_counts_at_the_published_sizes():
+    from benchmark.roofline import kimi_k2 as need
+
+    with open(CONFIG) as f:
+        cfg = json.load(f)
+    attn = need.latent_attention(2, 8704, 64, 128, 64, 128)
+    assert attn["flops"] == 2 * (192 + 128) * 64 * 2 * (8704 * 8705 // 2)  # 3.10 T a layer
+    assert 3.09e12 < attn["flops"] < 3.11e12
+    assert attn["bytes"] == 2 * 17408 * (64 * (128 + 64 + 128 + 128 + 128) + 64)
+    metrics = os.path.join(REPO, "benchmark", "metrics")
+    with open(os.path.join(metrics, "step_mfu.kimi.json")) as f:
+        shape_from = json.load(f)["args"]["shape_from"]
+    step = need.step(**{k: cfg[path] for k, path in shape_from.items()})
+    assert 72.0e12 < step["flops"] < 72.5e12  # ISSUE 42's 72.2 T: 0.37 s at the peak
+    latent = 7 * (3.52e12 + 3.10e12)
+    assert 0.63 < latent / step["flops"] < 0.65  # latent attention and its projections
+    with open(os.path.join(metrics, "gmm_roofline_share.kimi.json")) as f:
+        shape_from = json.load(f)["args"]["shape_from"]
+    gmm = need.held_products(held_share=12 / 384, **{k: cfg[path] for k, path in shape_from.items()})
+    assert gmm["flops"] == 18 * 2 * 4352 * 7168 * 2048  # 0.38 T an expert layer
+    assert gmm["bytes"] == 18 * 2 * (12 * 7168 * 2048 + 4352 * (7168 + 2048))
+
+
+def _held_rows_loop_trace(tmp_path, monkeypatch, joined=()):
+    """A reader's context over a hand-written trace of two runs of a step
+    with ONE expert layer: a loop whose body calls three Pallas kernels (the
+    grouped products) and a scatter-add, and whatever ``joined`` the scope
+    (name -> last component of its name stack)."""
+    import types
+
+    from benchmark.readers import trace_scope_leaf_time
+
+    ops = [("%while.5 = (s32[]) while(...)", 5e5, 2e6),  # spans its body
+           ("%tpu_custom_call.1 = f32[8,8] custom-call(...)", 1e6, 3e5),
+           ("%tpu_custom_call.2 = f32[8,8] custom-call(...)", 2e6, 1e5),
+           ("%_lambda_.7 = f32[8,8] custom-call(...)", 2.1e6, 2e5),
+           ("%fusion.3 = f32[8,8] fusion(...)", 2.3e6, 2e5),
+           ("%fusion.9 = f32[8,8] fusion(...)", 3e6, 9e5),
+           ("%while.5 = (s32[]) while(...)", 5.5e6, 2e6),
+           ("%tpu_custom_call.1 = f32[8,8] custom-call(...)", 6e6, 2e5),
+           ("%tpu_custom_call.2 = f32[8,8] custom-call(...)", 7e6, 2e5),
+           ("%_lambda_.7 = f32[8,8] custom-call(...)", 7.2e6, 2e5),
+           ("%fusion.3 = f32[8,8] fusion(...)", 7.4e6, 2e5)]
+    stack = "jit(kimi_k2_step)/moe/jit(mlp)"  # a body's operations keep the LOOP's scopes only
+    scopes = {"while.5": stack + "/while", "tpu_custom_call.1": stack + "/pallas_call",
+              "tpu_custom_call.2": stack + "/pallas_call", "_lambda_.7": stack + "/pallas_call",
+              "fusion.3": stack + "/scatter-add", "fusion.9": "jit(kimi_k2_step)/proj/dot_general"}
+    for i, (name, leaf) in enumerate(joined):
+        ops += [(f"%{name} = f32[8,8] custom-call(...)", 2.6e6 + i * 1e4, 1e3),
+                (f"%{name} = f32[8,8] custom-call(...)", 7.7e6 + i * 1e4, 1e3)]
+        scopes[name] = f"{stack}/{leaf}"
+    device = {0: {"XLA Modules": [("jit_kimi_k2_step(1)", 0.0, 4e6), ("jit_kimi_k2_step(1)", 5e6, 4e6)],
+                  "XLA Ops": sorted(ops, key=lambda e: e[1])}}
+    trace = types.SimpleNamespace(device=device)
+    cfg = {"trace_names": {"step": "jit_kimi_k2_step"},
+           "t": 64, "k": 4, "d": 128, "f": 64, "n": 4, "l": 2, "dense": 1}
+    counters = {"expert_rows_held_total": 50.0, "expert_rows_routed_total": 200.0}
+    (tmp_path / "trace").mkdir()
+    (tmp_path / "trace" / "x.xplane.pb").write_bytes(b"")
+    monkeypatch.setattr(trace_scope_leaf_time, "load_scopes", lambda path: scopes)
+    ctx = types.SimpleNamespace(
+        trace=trace, trace_window=(0.0, 1e9), cfg=cfg, spool_path=str(tmp_path / "spans" / "spool"),
+        metrics=types.SimpleNamespace(snapshot=lambda: counters),
+        peaks={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e30})
+    return ctx, scopes
+
+
+def _gmm_share_args():
+    with open(os.path.join(REPO, "benchmark", "metrics", "gmm_roofline_share.kimi.json")) as f:
+        args = json.load(f)["args"]
+    assert (args["scope"], args["leaf"], args["within"]) == ("moe", "pallas_call", "@step")
+    return {**args, "shape_from": {"tokens": "t", "per_token": "k", "hidden": "d", "width": "f",
+                                   "held": "n", "layers": "l", "dense_layers": "dense"}}
+
+
+def test_a_loops_body_is_counted_once_and_its_kernels_are_found_by_scope_and_primitive(tmp_path, monkeypatch):
+    import types
+
+    from benchmark.readers import roofline_share_per_run, trace_scope_leaf_time, trace_scope_time
+
+    ctx, scopes = _held_rows_loop_trace(tmp_path, monkeypatch)
+    trace, step = ctx.trace, "^%?jit_kimi_k2_step"
+    # the whole scope: the loop's own event (2.0 ms, spanning its body) is left out
+    assert trace_scope_time.scope_ms(trace, scopes, "moe", step, 0.0, 1e9) == pytest.approx(2.8)
+    assert trace_scope_leaf_time.leaf_scope_ms(trace, scopes, "moe", step, 0.0, 1e9) == pytest.approx(0.8)
+    ran = set()
+    assert trace_scope_leaf_time.leaf_scope_ms(trace, scopes, "moe", step, 0.0, 1e9, "pallas_call",
+                                               ran) == pytest.approx(0.6)
+    assert ran == {"tpu_custom_call.1", "tpu_custom_call.2", "_lambda_.7"}
+    assert trace_scope_leaf_time.leaf_scope_ms(trace, scopes, "no_such_scope", step, 0.0, 1e9) is None
+    # the share: the least time of all the step's products over the products' time in a run
+    args = _gmm_share_args()
+    flops = 3 * 2 * (64 * 4 * 0.25) * 128 * 64
+    assert roofline_share_per_run.read(ctx, **args) == pytest.approx(flops / 1e12 / 6e-4 * 100.0)
+    ctx.metrics = types.SimpleNamespace(snapshot=lambda: {})  # the parent's program: no such counters
+    assert roofline_share_per_run.read(ctx, **args) is None
+    ctx.trace = None
+    assert roofline_share_per_run.read(ctx, **args) is None
+
+
+@pytest.mark.parametrize("joined,read", [
+    ((("row_scatter.4", "pallas_call"),), False),  # a fourth kernel in the loop: not gmm's time
+    ((("row_gather.2", "pallas_call"), ("row_scatter.4", "pallas_call")), False),
+    ((("fusion.12", "gather"),), True),  # what is no Pallas call may come and go
+], ids=["a_row_scatter_joins", "two_kernels_join", "an_xla_gather_joins"])
+def test_the_grouped_products_share_reads_nothing_once_another_kernel_joins_the_scope(
+        tmp_path, monkeypatch, capsys, joined, read):
+    """``gmm_roofline_share.kimi`` reads every Pallas call under ``moe``:
+    the roofline function counts three call sites an expert layer, and a
+    trace in which another number ran is refused aloud, not read low."""
+    from benchmark.readers import roofline_share_per_run
+
+    ctx, _ = _held_rows_loop_trace(tmp_path, monkeypatch, joined)
+    got = roofline_share_per_run.read(ctx, **_gmm_share_args())
+    said = capsys.readouterr().err
+    if read:
+        assert got is not None and not said
+    else:
+        assert got is None and "call sites" in said and joined[-1][0] in said
+
+
+def test_the_adapter_ends_the_run_where_the_package_has_no_latent_attention(monkeypatch):
+    from benchmark.programs import prefill_latent
+
+    @dataclasses.dataclass(frozen=True)
+    class Older:  # the parent's DecoderConfig, as far as the adapter looks
+        hidden_size: int = 0
+
+    monkeypatch.setattr(decoder, "DecoderConfig", Older)
+    with pytest.raises(SystemExit) as e:
+        prefill_latent.Program({"name": "kimi_k2_prefill_epix10k2m"}, 1, "", None)
+    assert e.value.code not in (0, None) and "latent attention" in str(e.value.code)
+
+
+def test_the_adapter_ends_the_run_where_the_file_counts_another_share_than_it_holds():
+    from benchmark.programs import prefill_latent
+
+    with open(CONFIG) as f:
+        cfg = json.load(f)
+    with pytest.raises(SystemExit) as e:  # before anything is built
+        prefill_latent.Program({**cfg, "experts_held": [0, 24]}, 1, "", None)
+    assert e.value.code not in (0, None) and "experts_held" in str(e.value.code)

@@ -388,7 +388,8 @@ def test_every_metric_file_of_the_lfm2_cell_names_a_reader_that_exists():
 def test_the_lfm2_cell_reports_the_host_path_under_the_names_the_hit_cell_has(name):
     manifest = _manifest()
     entry, = [e for e in manifest["per_layer"] + manifest["end_to_end"] if e["name"] == name]
-    assert entry["workloads"][-1] == "lfm2_epix_saturated"
+    assert entry["workloads"][:3] == ["hit_epix_saturated", "keye_epix_saturated",
+                                      "lfm2_epix_saturated"]  # later cells follow
     calib, = [e for e in manifest["per_layer"] if e["name"] == "calib_roofline_share.hit"]
     assert calib["workloads"] == ["hit_epix_saturated"]  # PERF.md section 7 (b)
 

@@ -1320,12 +1320,13 @@ def _bench_sfx(jax, jnp, pedestal, gain, mask, x_warm, x_fresh_list, extras, sha
         config=SfxConfig(batch_size=b),
     )
     x_fresh = x_fresh_list[0]
+    # the form the loop serves: the batch as b per-frame arrays
     samples = [
-        (x_fresh[k * b:(k + 1) * b],)
+        (tuple(x_fresh[k * b:(k + 1) * b]),)
         for k in range(min(3, len(x_fresh) // b))
     ]
     ms = device_time_ms(
-        jax, pipe._step, (x_warm[:b],), samples, "sfx-step", extras
+        jax, pipe._step, (tuple(x_warm[:b]),), samples, "sfx-step", extras
     )
     extras["device_sfx_pipeline_fps"] = round(b / (ms / 1e3), 1)
     log(

@@ -435,11 +435,12 @@ def phase_inspect(size: Size, out: str, export: str, npz: str, served: dict, pla
         hit = "/jax/compilation_cache/cache_hits" in events
     peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
     # the compiled text needs an ahead-of-time compile; after the call
-    # above it reuses that executable in-process
+    # above it reuses that executable in-process (the served form: the
+    # batch as one array a frame)
     t0 = time.monotonic()
+    frame = jax.ShapeDtypeStruct(batch.frames.shape[1:], batch.frames.dtype)
     compiled = pipe._jit_step.lower(
-        pipe._variables, pipe._calib,
-        jax.ShapeDtypeStruct(batch.frames.shape, batch.frames.dtype),
+        pipe._variables, pipe._calib, (frame,) * len(batch.frames),
     ).compile()
     aot_s = time.monotonic() - t0
     mosaic = compiled.as_text().count("tpu_custom_call")

@@ -170,6 +170,7 @@ class FrameBatcher:
         self._pool_i = 0
         self._cur: Optional[tuple] = None
         self._fill = 0
+        self._told = 0  # rows of the current arena a consumer was told of
         self._hops: Optional[List[dict]] = None  # stamps of the current batch
         self._t_enq = 0.0  # oldest enqueue stamp of the current batch
 
@@ -203,7 +204,7 @@ class FrameBatcher:
             )
         if self._cur is None:
             self._cur = self._acquire()
-            self._fill = 0
+            self._fill = self._told = 0
         frames, valid, rank, idx, energy = self._cur
         i = self._fill
         frames[i] = rec.panels
@@ -252,6 +253,17 @@ class FrameBatcher:
     def pending(self) -> int:
         return self._fill if self._cur is not None else 0
 
+    def landed(self) -> Optional[tuple]:
+        """``(frames, lo, hi)``: rows ``lo:hi`` of the CURRENT arena's
+        ``frames`` were copied in since this was last asked; None when
+        none were. Only rows below the fill are ever named (the tail's
+        padding is written at ``flush``), and each row once: what a
+        consumer was told of stays as it is until the arena is emitted."""
+        if self._cur is None or self._told == self._fill:
+            return None
+        lo, self._told = self._told, self._fill
+        return self._cur[0], lo, self._fill
+
     def _emit(self) -> Batch:
         frames, valid, rank, idx, energy = self._cur
         n = self._fill
@@ -262,7 +274,7 @@ class FrameBatcher:
             idx[n:] = 0
             energy[n:] = 0
         self._cur = None
-        self._fill = 0
+        self._fill = self._told = 0
         hops, self._hops = self._hops, None
         t_enq, self._t_enq = self._t_enq, 0.0
         if hops is not None:  # one emit stamp for every record in the batch
@@ -287,6 +299,7 @@ def batches_from_queue(
     control: Optional[DrainControl] = None,
     metrics=None,
     between_turns: Optional[Callable[[], Optional[bool]]] = None,
+    rows_landed: Optional[Callable[[np.ndarray, int, int], None]] = None,
 ) -> Iterator[Batch]:
     """Drain a transport queue into fixed-shape batches until EOS.
 
@@ -347,6 +360,23 @@ def batches_from_queue(
     spans several empty polls starts anew after it. A turn ends with
     every frame that arrives, and on a silent stream with every poll
     interval.
+
+    ``rows_landed(frames, lo, hi)`` (optional) is called at the same
+    place, on the same thread, right after ``between_turns`` (and not at
+    all when that ended the iteration): rows ``lo:hi`` of the CURRENT
+    arena's ``frames`` were copied in since the consumer was last told
+    (:meth:`FrameBatcher.landed`) — in a turn that did not fill the
+    arena, or behind a batch that an earlier turn emitted. ``frames`` is
+    the very array the next emitted :class:`Batch` will carry, so the
+    consumer can start on a frame's bytes in the turn they arrived
+    (``SfxPipeline.run`` puts them on the device there) and know the
+    batch they belong to by identity. Rows are named once, in order,
+    only below the fill; a full arena that one turn filled is never
+    named (the batch itself says it). Its time is the consumer's too.
+    Under ``n_buffers > 0`` a named row is overwritten when its arena
+    comes round again: whoever reads it later than that must not pool.
+    Without either hook a turn does what it did (``InfeedPipeline``,
+    the fan-in, the gateway pass none).
     """
     batcher: Optional[FrameBatcher] = None
     starved_since: Optional[float] = None
@@ -356,6 +386,22 @@ def batches_from_queue(
     in_queue_wait = phase(PHASE_QUEUE_WAIT, metrics)
     in_decode = phase(PHASE_DECODE, metrics)
     in_copy = phase(PHASE_COPY, metrics)
+
+    def consumers_turn() -> Optional[bool]:
+        """The end of a turn that emitted no batch: ``between_turns``,
+        then ``rows_landed``. None = neither found anything to do,
+        True = end the iteration."""
+        done = between_turns() if between_turns is not None else None
+        if done:
+            return True
+        if rows_landed is not None and batcher is not None:
+            rows = batcher.landed()
+            if rows is not None:
+                rows_landed(*rows)
+                return False
+        return done
+
+    hooked = between_turns is not None or rows_landed is not None
     # drain preference: server-push stream (TCP streaming mode — no pull
     # RTT, no empty-queue polls) > zero-copy view drain (shm ring slots)
     # > plain get_batch. Every TCP variant returns lease-backed records
@@ -424,8 +470,8 @@ def batches_from_queue(
                             f"no EOS (producer stalled or unreachable)"
                         )
                     return
-                if between_turns is not None:
-                    done = between_turns()
+                if hooked:
+                    done = consumers_turn()
                     if done:
                         return
                     if done is not None:  # it worked: the wait is counted anew
@@ -506,7 +552,7 @@ def batches_from_queue(
             yield from ready  # suspended-at-yield time is the consumer's
             if stream_done:
                 return
-            if not ready and between_turns is not None and between_turns():
+            if not ready and hooked and consumers_turn():
                 return
     finally:
         tally.flush_duplicates(queue, final=True)

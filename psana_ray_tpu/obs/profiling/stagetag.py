@@ -81,6 +81,7 @@ TAG_NAMES = (
     "fold",
     "append",
     "gc",
+    "put_ahead",
 )
 N_TAGS = len(TAG_NAMES)
 

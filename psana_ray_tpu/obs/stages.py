@@ -33,13 +33,17 @@ nested, covering the whole loop body)::
     DevicePrefetcher     device_put -> prefetch_full
       (its watcher)      h2d_tail, while the tracer is on
     InfeedPipeline.run   infeed_wait -> launch -> device_wait -> (on_result)
-    SfxPipeline.run      (the batcher's three) -> launch -> device_wait
-                         -> fold -> append
+    SfxPipeline.run      (the batcher's three) -> [put_ahead] -> launch
+                         -> device_wait -> fold -> append
 
 ``SfxPipeline.run`` drains a batch (``device_wait -> fold -> append``)
 after the next batch's ``launch`` or, when its result is ready sooner,
 between two turns of the batcher: after one turn's ``copy``, before the
-next turn's ``queue_wait``, never inside a turn.
+next turn's ``queue_wait``, never inside a turn. At the same place,
+after that drain, it puts the frames that landed without filling the
+arena on the device (``put_ahead``: the ``device_put`` call, one span a
+turn with the frames and bytes it started), so ``launch`` carries only
+the frames of the turn that filled the batch.
 
 A phase is a ``stage.<name>`` region on the profiler's timeline, a tag
 for the flame sampler, one span in the trace spool (named ``stage.<name>``
@@ -121,6 +125,7 @@ PHASE_DEVICE_WAIT = "device_wait"  # host blocks on the step's result
 PHASE_FOLD = "fold"  # device rows -> per-event results
 PHASE_APPEND = "append"  # sink append + cursor
 PHASE_GC = "gc"  # a generation-2 collection, inside whatever phase was open
+PHASE_PUT_AHEAD = "put_ahead"  # landed frames put on the device before their batch fills: the call
 
 # One span a staged batch in the spool, beside the phases' (not a phase:
 # it overlaps the prefetch thread's next turns): ``device_put`` called ->
@@ -140,6 +145,7 @@ PHASES = (
     PHASE_FOLD,
     PHASE_APPEND,
     PHASE_GC,
+    PHASE_PUT_AHEAD,  # last: the tags of the phases before it stay what they were
 )
 
 # The hops a frame has crossed by the time its batch is emitted: what the

@@ -25,13 +25,24 @@ has filled — between two turns of the batcher, as soon as its result is
 seen ready (:meth:`SfxPipeline.run`): a result never waits on the device
 for the stream.
 
+A frame's bytes cross to the device in the turn of the batcher in which
+they landed, not when the batch fills: frames that arrive without
+filling the arena are put on the device between two turns, one
+``[P, H, W]`` array a frame, so the launch carries only the frames of
+the turn that completed the batch (at a paced stream, one of sixteen)
+and the step starts that much sooner. An arena that one turn fills
+crosses whole at the launch, as it always did. Both ways the step is
+ONE compiled program over B per-frame arrays.
+
 The loop's thread is always inside one phase (``utils.trace.phase``; the
 vocabulary is ``obs.stages.PHASES``): the batcher's ``queue_wait`` /
-``decode`` / ``copy``, then ``launch`` (the jit call, with its implicit
-host-to-device copy of the frames), and for the batch before it
-``device_wait`` (the ``device_get`` drain), ``fold`` (panel rows -> per-
-event peak sets) and ``append`` (``writer.append`` + cursor); an early
-drain's three lie between two turns of the batcher. Each is a
+``decode`` / ``copy``, then ``put_ahead`` (the ``device_put`` call for
+frames that landed without filling the batch) or ``launch`` (the put of
+the frames not yet on the device + the jit call), and for the batch
+before it ``device_wait`` (the ``device_get`` drain), ``fold`` (panel
+rows -> per-event peak sets) and ``append`` (``writer.append`` +
+cursor); an early drain's three lie between two turns of the batcher,
+ahead of that turn's ``put_ahead``. Each is a
 ``stage.<name>`` region on the profiler's timeline, one observation per
 batch in ``metrics.stages``, and one span in the trace spool.
 
@@ -54,7 +65,7 @@ import dataclasses
 import functools
 import math
 import time
-from typing import Callable, Optional, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -63,6 +74,7 @@ from psana_ray_tpu.obs.stages import (
     PHASE_DEVICE_WAIT,
     PHASE_FOLD,
     PHASE_LAUNCH,
+    PHASE_PUT_AHEAD,
     observe_batch_done,
     observe_frame_stages,
 )
@@ -230,10 +242,18 @@ class SfxPipeline:
 
     # -- the one compiled program ----------------------------------------
     def _device_step(self, variables, calib, frames):
-        """``[B, P, H, W]`` raw-or-calibrated frames -> panel-row peak
-        tuples ``(yx [B*P, K, 2], score [B*P, K], n [B*P])``. Pure in its
-        arguments (``calib`` is the ``(pedestal, gain, mask)`` triple or
-        None), so it also runs per shard under ``shard_map``.
+        """Raw-or-calibrated frames -> panel-row peak tuples ``(yx [B*P,
+        K, 2], score [B*P, K], n [B*P])``. ``frames`` is what it is given:
+        a sequence of B per-frame ``[P, H, W]`` arrays (the form the loop
+        serves: each frame went to the device on its own, and the stack
+        is the step's first operation — on the TPU one in-place
+        ``dynamic-update-slice`` fusion a frame, 0.09 ms of a 74 ms step
+        at batch 16, which the convert ahead of the calibration kernel
+        wins back by reading the batch from where they wrote it), or one
+        ``[B, P, H, W]`` array, taken as it is. Pure in its arguments (``calib`` is the
+        ``(pedestal, gain, mask)`` triple or None), so it also runs per
+        shard under ``shard_map``. Calibration stays ONE call over the
+        whole batch either way.
 
         The three parts run under named scopes (``calib``, ``peaknet``,
         ``find_peaks``), by which a device trace finds their ops again
@@ -252,7 +272,7 @@ class SfxPipeline:
         from psana_ray_tpu.models.peaks import find_peaks
 
         cfg = self.cfg
-        x = frames
+        x = jnp.stack(frames) if isinstance(frames, (tuple, list)) else frames
         if calib is not None:
             from psana_ray_tpu.ops import fused_calibrate  # itself a jit
 
@@ -281,26 +301,43 @@ class SfxPipeline:
             )(logits)
 
     def _step(self, frames):
-        """The compiled step on this pipeline's own weights and constants."""
+        """The compiled step on this pipeline's own weights and constants
+        (``frames``: either form of :meth:`_device_step`; a tuple and an
+        array are two programs, and the loop serves the tuple alone)."""
         return self._jit_step(self._variables, self._calib, frames)
 
     # -- host side: panel rows -> per-event raw-coordinate peak sets ------
-    def dispatch(self, batch):
+    def dispatch(self, batch, staged: Sequence = ()):
         """Enqueue one batch's device step WITHOUT waiting for the result.
 
-        The jit call returns as soon as the transfer + computation are
-        enqueued; pairing it with :meth:`drain` one batch later overlaps
-        the device program for batch N with the host-side peak fold and
-        HDF5 append for batch N-1 (the serial loop leaves the chip idle
-        for the whole host phase). :meth:`run` drains a handle after the
-        next launch at the latest, and sooner once its outputs answer
-        ``is_ready()``; results are bit-identical to the serial path.
+        ``staged`` holds the device arrays of the batch's first
+        ``len(staged)`` frames, put there as they landed (:meth:`run`);
+        the rows not yet on the device — all of them for a batch nobody
+        staged, the padding of a tail — follow here in ONE
+        ``jax.device_put`` of their views, and the step is called with
+        the B per-frame arrays: always that form, so every caller
+        (:meth:`run`, :meth:`process_batch`, a warm-up through either)
+        shares one compiled program. Both calls return once the transfers
+        and the computation are enqueued; a staged frame's transfer began
+        turns ago. Pairing this with :meth:`drain` one batch later
+        overlaps the device program for batch N with the host-side peak
+        fold and HDF5 append for batch N-1 (the serial loop leaves the
+        chip idle for the whole host phase). :meth:`run` drains a handle
+        after the next launch at the latest, and sooner once its outputs
+        answer ``is_ready()``; results are bit-identical to the serial
+        path and to a batch that crossed whole.
 
-        The ``launch`` phase; a timed batch's per-frame stamps are folded
-        right after it, while the device works (``obs.stages.
-        observe_frame_stages``)."""
+        The ``launch`` phase; ``metrics.frames_staged_ahead`` counts the
+        frames that were on their way before it began. A timed batch's
+        per-frame stamps are folded right after it, while the device
+        works (``obs.stages.observe_frame_stages``)."""
+        import jax
+
+        if staged:
+            self.metrics.frames_staged_ahead.add(len(staged))
         with phase(PHASE_LAUNCH, self.metrics, batch.batch_id, batch.num_valid):
-            out = self._step(batch.frames)
+            rest = jax.device_put(list(batch.frames[len(staged):]))  # a view a frame
+            out = self._step((*staged, *rest))
         observe_frame_stages(self.metrics.stages, batch)
         return out, batch
 
@@ -402,7 +439,26 @@ class SfxPipeline:
         N's device step executes while batch N-1's peaks fold into raw
         coordinates and append to the HDF5 file on the host (see
         :meth:`dispatch`) — the serial loop pays host-write time as chip
-        idle time. WHEN a batch is drained follows what the loop sees:
+        idle time.
+
+        WHEN a frame's bytes go to the device follows what the loop
+        sees: frames that land without filling the arena are put there
+        at the end of that turn of the batcher (its ``rows_landed``
+        hook, after any early drain: a finished result goes to the file
+        first), one ``[P, H, W]`` array each in one asynchronous
+        ``jax.device_put`` (the ``put_ahead`` phase: the call returns,
+        nothing waits), and wait on the device for their batch; the
+        launch then carries the frames of the filling turn alone. Frames
+        that fill an arena in one turn cross at the launch. Only real
+        rows are staged (a tail's padding is written at the flush, and
+        crosses at the launch), and only for the batcher's CURRENT
+        arena, whose array the emitted batch carries: what was staged
+        belongs to that batch by identity, and a ``stop`` or a raise
+        drops it with the arena. The arenas are fresh ones (no
+        ``n_buffers``): an asynchronous put may read a row after the
+        batcher has moved on.
+
+        WHEN a batch is drained follows what the loop sees too:
 
         - right after the next batch is launched, blocking on the result
           (the one-deep schedule) — where frames outrun the device, the
@@ -455,6 +511,30 @@ class SfxPipeline:
             return max_events is not None and self.n_events - start >= max_events
 
         pending = None
+        # the current arena's first rows, already on the device
+        ahead_of: Optional[np.ndarray] = None
+        ahead: list = []
+        in_put_ahead = phase(PHASE_PUT_AHEAD, self.metrics)  # built once: it can run every turn
+
+        def _put_ahead(arena: np.ndarray, lo: int, hi: int) -> None:
+            """The batcher's ``rows_landed``: start rows ``lo:hi`` of the
+            arena on their way to the device, a ``[P, H, W]`` array each."""
+            nonlocal ahead_of, ahead
+            import jax
+
+            if ahead_of is not arena:  # the first rows of a new arena
+                ahead_of, ahead = arena, []
+            rows = arena[lo:hi]
+            in_put_ahead.frames, in_put_ahead.nbytes = hi - lo, rows.nbytes
+            with in_put_ahead:
+                ahead += jax.device_put(list(rows))
+
+        def _staged_for(batch) -> Sequence:
+            """What was put ahead of ``batch``, if it is that arena's."""
+            nonlocal ahead_of, ahead
+            staged = ahead if ahead_of is batch.frames else ()
+            ahead_of, ahead = None, []
+            return staged
 
         def _drain_if_ready() -> Optional[bool]:
             """The batcher's ``between_turns``: drain the pending batch if
@@ -473,9 +553,9 @@ class SfxPipeline:
             for batch in batches_from_queue(
                 queue, self.cfg.batch_size, poll_interval_s=poll_interval_s,
                 stop=stop, control=dials, metrics=self.metrics,
-                between_turns=_drain_if_ready,
+                between_turns=_drain_if_ready, rows_landed=_put_ahead,
             ):
-                nxt = self.dispatch(batch)
+                nxt = self.dispatch(batch, _staged_for(batch))
                 _watch_the_step(True)
                 # clear ``pending`` BEFORE draining it: if drain raises
                 # after its writer.append, the finally below must not

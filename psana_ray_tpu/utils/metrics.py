@@ -252,6 +252,11 @@ class PipelineMetrics:
         # between two turns of the batcher): over ``batches`` near 1 when
         # the device outruns the frames, 0 at saturation
         self.drained_ahead = Meter("drained_ahead")
+        # real frames whose bytes had been put on the device before their
+        # batch's launch began (SfxPipeline.run stages frames that land
+        # without filling the arena): over ``frames`` (B-1)/B where
+        # frames trickle in, 0 where every pop fills a batch
+        self.frames_staged_ahead = Meter("frames_staged_ahead")
         self.step_latency = LatencyStats()
         self.stages = StageTimes()
         # counters a step's own statistics feed, by name: a model whose
@@ -308,6 +313,7 @@ class PipelineMetrics:
             "batches_total": self.batches.count,
             "batches_per_second": round(self.batches.rate(), 3),
             "drained_ahead_total": self.drained_ahead.count,
+            "frames_staged_ahead_total": self.frames_staged_ahead.count,
             "step_latency": self.step_latency.snapshot(),
         }
         with self._counters_lock:

@@ -199,6 +199,7 @@ def everything(tmp_path):
     for _ in range(4):
         m.observe_batch(16, 0.06)
     m.drained_ahead.add(3)
+    m.frames_staged_ahead.add(60)  # 15 of each batch's 16
     for s in (0.0015, 0.0017, 0.0021):
         m.stages.observe("append", s)
     ctx.metrics = m
@@ -217,6 +218,7 @@ def everything(tmp_path):
     ("launch_lead_ms.paced", 14.0),
     ("ready_lag_ms.paced", 125.0),  # step ends 200 -> device_wait 300; 600 -> 750: median
     ("drained_ahead_share.paced", 75.0),
+    ("staged_ahead_share.paced", 93.75),  # PR 43: 60 of 64 frames were put before their launch
     ("append_ms", 1.7),
     ("append_ms.paced", 1.7),
 ])
@@ -226,6 +228,22 @@ def test_each_new_metric_file_reads_the_hand_made_run(tmp_path, metric, want):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         entry = next(e for e in json.load(f)["per_layer"] if e["name"] == metric)
     assert bench.read_metric(everything(tmp_path), entry) == pytest.approx(want)
+
+
+def test_a_program_without_the_staging_counter_leaves_its_share_out(tmp_path):
+    """PR 43's parent counts no ``frames_staged_ahead_total``: the share's
+    reader finds nothing there, returns nothing and does not raise, and
+    the counter's neighbour reads as it did."""
+    from benchmark import run as bench
+
+    ctx = everything(tmp_path)
+    snapshot = ctx.metrics.snapshot()
+    del snapshot["frames_staged_ahead_total"]
+    ctx.metrics = type("Parent", (), {"snapshot": lambda self: snapshot})()
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entries = {e["name"]: e for e in json.load(f)["per_layer"]}
+    assert bench.read_metric(ctx, entries["staged_ahead_share.paced"]) is None
+    assert bench.read_metric(ctx, entries["drained_ahead_share.paced"]) == pytest.approx(75.0)
 
 
 def test_the_parent_has_nothing_to_read_and_nothing_raises(tmp_path):

@@ -61,7 +61,23 @@ def one_chip(cache_setting):
         pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    return SingleDeviceSharding(topo.devices[0])
+    # The pins below name kernels as a fresh process names them
+    # (``%row_gather``, ``%peak_nms``: the ``pallas_call``'s own name, which
+    # locations carry only with full tracebacks, JAX's default). An
+    # earlier test of this xdist worker that ran a CLI's ``main`` in-process
+    # (``tests/test_sfx.py``) has been through ``configure_compile_cache``,
+    # which turns them off for good: the kernel is then named after the
+    # function around it (``%gather_rows``), as on the chip. Which files
+    # share a worker changes with every test added, so state it here;
+    # ``cache_setting`` puts back what it found. The traces go too: a
+    # kernel's wrapper asks ``default_backend()`` while it is TRACED and
+    # the trace is cached by shapes alone, so one made on the CPU would be
+    # lowered here in the kernel's place, and one made here would reach a
+    # later CPU test.
+    jax.config.update("jax_include_full_tracebacks_in_locations", True)
+    jax.clear_caches()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.clear_caches()
 
 
 def _calib(dtype):
@@ -127,12 +143,15 @@ def _unet_level1():
     return fn, [S((32, 88, 96, 64), BF16), S((3, 3, 64, 128), F32), vec, vec, k, vec, vec, k], 1
 
 
-def _sfx_serve_step():
+def _sfx_serve_step(per_frame=True):
     """The program ``python -m psana_ray_tpu.sfx`` compiles at its
-    defaults: u16 [8,16,352,384] -> fused calibration -> PeakNetUNetTPU
+    defaults: u16 frames -> fused calibration -> PeakNetUNetTPU
     (64,128,256,512; s2d=2; frozen) -> find_peaks(128, 0.5, 2), built by
     the pipeline's own constructor; its weights and calibration constants
-    are arguments of the step."""
+    are arguments of the step. The SERVED form takes the batch as 8
+    per-frame ``u16[16,352,384]`` operands (each frame went to the device
+    as it landed, PR 43); the whole-array form ``u16[8,16,352,384]`` is
+    what ``benchmark/programs/sfx_dp.py`` lowers per shard."""
     from flax.core import meta
 
     from psana_ray_tpu.models import PeakNetUNetTPU
@@ -147,10 +166,13 @@ def _sfx_serve_step():
     calib = (np.zeros(panel, np.float32), np.ones(panel, np.float32), np.ones(panel, np.uint8))
     pipe = SfxPipeline(variables, writer=None, calib=calib)
     resident = jax.tree.map(lambda a: S(a.shape, a.dtype), (pipe._variables, pipe._calib))
-    step = [*resident, S((SfxConfig.batch_size, *panel), jnp.uint16)]
-    rows = SfxConfig.batch_size * PANELS
+    b = SfxConfig.batch_size
+    frames = tuple(S(panel, jnp.uint16) for _ in range(b)) if per_frame else S((b, *panel), jnp.uint16)
+    pins = [functools.partial(_peaks_read_the_packed_map, rows=b * PANELS)]
+    if per_frame:
+        pins.append(functools.partial(_the_stack_is_one_pass_in_place, frames=b))
     # the calibration kernel and the local-maximum kernel
-    return pipe._device_step, step, 2, functools.partial(_peaks_read_the_packed_map, rows=rows)
+    return pipe._device_step, [*resident, frames], 2, *pins
 
 
 KEYE_S = 34304  # 33,792 patches of an epix10k2M frame + 512 prompt tokens
@@ -310,6 +332,7 @@ CASES = {
     "calib_epix10k2M_u16": lambda: _calib(jnp.uint16),
     "calib_epix10k2M_f32": lambda: _calib(F32),
     "sfx_serve_step_cli_defaults": _sfx_serve_step,
+    "sfx_serve_step_whole_array": lambda: _sfx_serve_step(per_frame=False),
     "resnet50_stage4_bottleneck": _resnet_stage4,
     "unet_level1_conv_block": _unet_level1,
     "flash_fwd_2x4x8448x128": _flash_fwd,
@@ -330,6 +353,29 @@ def _rows_move_once_each_way(text, tokens, k):
     assert f"[{tokens},{k},2048]" not in entry
     assert len(re.findall(r"^\s*(?:ROOT )?%gmm[.\d]* = ", entry, re.M)) == 3
     assert len(re.findall(r"^\s*(?:ROOT )?%row_gather[.\d]* = ", entry, re.M)) == 1
+
+
+def _the_stack_is_one_pass_in_place(text, frames):
+    """What the per-frame operands cost the served step (PR 43), as
+    compiled: XLA does NOT fuse the stack into the convert ahead of the
+    calibration kernel. It writes the ``u16[B,16,352,384]`` batch by one
+    in-place ``dynamic-update-slice`` fusion a frame (each moves one
+    frame's 4.33 MB in and out: one pass over the batch in all), and ONE
+    convert then reads the whole batch, as it reads the whole-array
+    form's operand; no ``concatenate`` or ``copy`` of the batch stands in
+    the entry computation, and the calibration kernel is still one call.
+    A convert written per frame, ahead of the stack, is hoisted behind it
+    again and compiles to this same text."""
+    entry = text[text.index("ENTRY"):]
+    batch = rf"u16\[{frames},{PANELS},{H},{W}\]"
+    stacked = re.findall(rf"^\s*(%[\w.\-]+) = {batch}\S* (\S+?)\(", entry, re.M)
+    assert len(stacked) == frames and {op for _, op in stacked} == {"fusion"}, stacked
+    assert all("dynamic-update-slice" in name for name, _ in stacked), stacked
+    rows = frames * PANELS
+    whole = rf"(?:u16|f32)\[(?:{frames},{PANELS}|{rows}),{H},{W}\]"
+    passes = re.findall(rf"^\s*(?:ROOT )?(%[\w.\-]+) = {whole}\S* (concatenate|copy|convert)\(", entry, re.M)
+    assert [op for _, op in passes] == ["convert"], passes
+    assert len(re.findall(r"^\s*(?:ROOT )?%fused_calibrate[.\d]* = ", entry, re.M)) == 1
 
 
 _SHAPE = re.compile(r"\b(f32|s32|bf16|u16|pred|u8|s8)\[([\d,]*)\]")

@@ -202,7 +202,94 @@ class TestBetweenTurns:
         q.put(EndOfStream(total_events=4))
         pipe = InfeedPipeline(q, batch_size=4, poll_interval_s=0.001)
         assert pipe.run(lambda b: b.frames) == 4
-        assert seen and "between_turns" not in seen
+        assert seen and "between_turns" not in seen and "rows_landed" not in seen
+
+    def test_without_a_hook_a_turn_is_what_it_was(self, monkeypatch):
+        # nobody asks the batcher what landed, and the phases observed and
+        # the batches yielded are those of a loop whose hooks do nothing
+        from psana_ray_tpu.utils.metrics import PipelineMetrics
+
+        script = [2, 2, 0, 1, 3, 3, 4, 0, 2]
+
+        def turns(**hooks):
+            m = PipelineMetrics()
+            got = list(batches_from_queue(_Pops(script), 4, poll_interval_s=0.001, metrics=m, **hooks))
+            phases = {name: m.stages.stat(name).count for name in m.stages.stages()}
+            return phases, [(b.num_valid, b.event_idx.tolist(), b.valid.tolist()) for b in got]
+
+        hooked = turns(between_turns=lambda: None, rows_landed=lambda *rows: None)
+        monkeypatch.setattr(FrameBatcher, "landed", lambda self: pytest.fail("asked what landed"))
+        bare = turns()
+        assert bare == hooked
+        pops = sum(1 for k in script if k) + 1  # the pops that brought something, and the end's
+        assert bare[0] == {"queue_wait": pops, "decode": pops, "copy": pops}
+        assert [n for n, _, _ in bare[1]] == [4, 4, 4, 4, 1]
+
+
+class TestRowsLanded:
+    """``rows_landed``: the rows of the current arena that were copied in
+    since the consumer was last told, at the end of every turn that
+    emitted no batch (ISSUE 43)."""
+
+    @pytest.mark.parametrize(
+        "script,want",
+        [
+            ([4, 4, 4], [[], [], []]),  # an arena filled in one turn is never named
+            ([1, 1, 1, 1], [[(0, 1), (1, 2), (2, 3)]]),  # a trickle: all but the one that fills
+            ([2, 2, 1, 3], [[(0, 2)], [(0, 1)]]),
+            ([3, 4, 1], [[(0, 3)], []]),  # rows behind an emitted batch wait for a turn without one
+            ([3, 4, 0, 0, 1], [[(0, 3)], [(0, 3)]]),  # which a starved poll is; told once
+            ([4, 1, 0, 1], [[], [(0, 1), (1, 2)]]),  # the tail: its real rows, never its padding
+        ],
+        ids=["full-pops", "trickle", "partial", "straddling", "straddling-then-starved", "eos-tail"],
+    )
+    def test_each_row_below_the_fill_is_named_once_in_order(self, script, want):
+        told, per_batch = [], []
+        it = batches_from_queue(_Pops(script), 4, poll_interval_s=0.001,
+                                rows_landed=lambda arena, lo, hi: told.append((arena, lo, hi)))
+        for batch in it:
+            # what was named since the previous batch is this batch's own arena
+            assert all(arena is batch.frames for arena, _, _ in told)
+            assert all(hi <= batch.num_valid for _, _, hi in told)
+            per_batch.append([(lo, hi) for _, lo, hi in told])
+            told.clear()
+        assert per_batch == want and not told
+
+    def test_it_follows_between_turns_on_the_loops_thread_outside_every_phase(self):
+        from psana_ray_tpu.obs.profiling.stagetag import TAG_UNTAGGED, current_tag
+
+        seen = []
+        list(batches_from_queue(
+            _Pops([0, 2, 0, 2]), 4, poll_interval_s=0.001,
+            between_turns=lambda: seen.append("between"),
+            rows_landed=lambda *rows: seen.append((threading.get_ident(), current_tag())),
+        ))
+        assert seen == ["between", "between", (threading.get_ident(), TAG_UNTAGGED), "between"]
+
+    def test_a_between_turns_that_ends_the_iteration_is_not_followed(self):
+        told = []
+        got = list(batches_from_queue(_Pops([2, 2]), 4, poll_interval_s=0.001,
+                                      between_turns=lambda: True, rows_landed=told.append))
+        assert got == [] and told == []
+
+    def test_a_wait_over_several_polls_starts_anew_after_rows_were_named(self):
+        from psana_ray_tpu.utils.metrics import PipelineMetrics
+
+        m = PipelineMetrics()
+        # 3 + 2 straddle a batch; the first starved poll names the row behind it
+        list(batches_from_queue(_Pops([3, 2, 0, 0, 3]), 4, poll_interval_s=0.001, metrics=m,
+                                rows_landed=lambda *rows: time.sleep(0.03)))
+        waits = m.stages.stat("queue_wait")._samples
+        assert len(waits) == 4 and max(waits) < 0.03, waits
+
+    def test_what_it_raises_surfaces_from_the_iterator(self):
+        def boom(*rows):
+            raise OSError("no device")
+
+        it = batches_from_queue(_Pops([4, 1]), 4, poll_interval_s=0.001, rows_landed=boom)
+        assert next(it).num_valid == 4
+        with pytest.raises(OSError, match="no device"):
+            next(it)
 
 
 class TestDevicePrefetch:

@@ -544,7 +544,13 @@ class TestSfxEarlyDrain:
         feeder.join(timeout=30)
         rows = _spool(TRACER)
         n_batches = self.N // BATCH
-        names = TURN_PHASES + BATCH_PHASES_SFX
+        names = TURN_PHASES + ("put_ahead",) + BATCH_PHASES_SFX
+        # frames that landed without filling a batch went to the device in
+        # a phase of their own, which reports the frames it started
+        ahead = _phase_spans(rows, "put_ahead")
+        staged = pipe.metrics.frames_staged_ahead.count
+        assert 0 < staged == sum(r["k"] for r in ahead) <= self.N - n_batches
+        assert pipe.metrics.stages.stat("put_ahead").count == len(ahead)
         for name in BATCH_PHASES_SFX:  # still once a batch, under the batch's id
             spans = _phase_spans(rows, name)
             assert len(spans) == n_batches, name
@@ -563,8 +569,8 @@ class TestSfxEarlyDrain:
                 assert order[i - 1] in ("copy", "launch"), order[max(0, i - 3): i + 4]
                 assert order[i + 1: i + 3] == ["fold", "append"]
                 assert spans[i]["id"] == spans[i + 1]["id"] == spans[i + 2]["id"]
-                if i + 3 < len(order):
-                    assert order[i + 3] == "queue_wait"
+                if i + 3 < len(order):  # then the turn's own frame, if it filled nothing
+                    assert order[i + 3] in ("queue_wait", "put_ahead")
                 launches = [r for r in spans if r["n"] == "stage.launch"]
                 own = next(r for r in launches if r["id"] == spans[i]["id"])
                 later = [r for r in launches if r["a"] > own["a"]]

@@ -774,11 +774,14 @@ class _Scripted:
 
         self.log = log = []
         self.handles = handles = []
+        self.staged = []  # frames put ahead of each dispatch, in dispatch order
+        self.served = served = []  # (the batch's own rows, the rows its step was given), likewise
         self.writer = writer if writer is not None else _RowsWriter()
         self.pipe = pipe = _small_pipe(small_variables, self.writer, **cfg)
         gates = []
 
         def stub_step(frames):
+            served.append(np.stack([np.asarray(f) for f in frames]))
             rows = len(frames) * SMALL[0]
             gates.append(gate := {"ready": ready_at_once})
             return tuple(
@@ -793,15 +796,22 @@ class _Scripted:
             if not stub and handles:
                 jax.block_until_ready(handles[-1][0])
 
-        if stub:
-            monkeypatch.setattr(pipe, "_step", stub_step)
+        real_step = pipe._step
+
+        def watched_step(frames):
+            served.append(np.stack([np.asarray(f) for f in frames]))
+            return real_step(frames)
+
+        monkeypatch.setattr(pipe, "_step", stub_step if stub else watched_step)
         real_dispatch, real_drain, real_batches = (
             pipe.dispatch, pipe.drain, batcher_mod.batches_from_queue,
         )
 
-        def dispatch(batch):
+        def dispatch(batch, staged=()):
             log.append(("dispatch", len(handles)))
-            handles.append(real_dispatch(batch))
+            self.staged.append(len(staged))
+            handles.append(real_dispatch(batch, staged))
+            served[-1] = (batch.frames.copy(), served[-1])
             return handles[-1]
 
         def drain(pending, **kw):
@@ -834,6 +844,12 @@ class _Scripted:
     def calls(self):
         """The ``dispatch`` / ``drain`` calls alone, in order."""
         return [e for e in self.log if e[0] in ("dispatch", "drain")]
+
+    def every_step_was_given_its_own_batch(self):
+        """Staged or not, the rows a step was given are its batch's, in
+        the batch's order (a tail's padding included)."""
+        return bool(self.served) and all(
+            np.array_equal(own, given) for own, given in self.served)
 
     def drains_inside_the_hook(self):
         inside, n = False, 0
@@ -1000,11 +1016,24 @@ def test_soak_over_a_real_ring_loses_and_doubles_nothing(small_variables, gap_s)
 
 
 @pytest.mark.parametrize(
-    "script",
-    [[B, "ready", 1, B - 1, 0, "ready", 0, B, "ready", 2], [B] * 3 + [2], [2] * 7],
-    ids=["sparse", "dense", "dense-half-pops"],
+    "script,staged",
+    [
+        ([B, "ready", 1, B - 1, 0, "ready", 0, B, "ready", 2], [0, 1, 0, 2]),
+        ([B] * 3 + [2], [0, 0, 0, 2]),
+        ([2] * 7, [2, 2, 2, 2]),
+        # one frame a turn: all but the frame that fills the batch went ahead;
+        # of the tail its three real rows, its padding at the launch
+        ([1] * (2 * B + 3), [B - 1, B - 1, 3]),
+        ([1, "ready", 0] * (2 * B + 1), [B - 1, B - 1, 1]),
+        ([B - 1, B, 0, B, 1], [B - 1, B - 1, 0]),  # rows behind a batch go at the next starved poll, or not at all
+    ],
+    ids=["sparse", "dense", "dense-half-pops", "trickle", "trickle-drained-ahead", "straddling"],
 )
-def test_cxi_file_is_byte_for_byte_the_serial_loops(small_variables, monkeypatch, tmp_path, script):
+def test_cxi_file_is_byte_for_byte_the_serial_loops(
+    small_variables, monkeypatch, tmp_path, script, staged
+):
+    """Whatever share of a batch went to the device ahead of its launch,
+    the file is ``process_batch``'s over the same frames, bit for bit."""
     from psana_ray_tpu.infeed.batcher import FrameBatcher
     from psana_ray_tpu.models.peaks import CxiWriter
 
@@ -1012,6 +1041,8 @@ def test_cxi_file_is_byte_for_byte_the_serial_loops(small_variables, monkeypatch
     with CxiWriter(str(tmp_path / "run.cxi"), max_peaks=64) as writer:
         s = _Scripted(small_variables, monkeypatch, script, stub=False, writer=writer)
         assert s.run() == n
+    assert s.staged == staged and s.every_step_was_given_its_own_batch()
+    assert s.pipe.metrics.frames_staged_ahead.count == sum(staged)
     with CxiWriter(str(tmp_path / "serial.cxi"), max_peaks=64) as writer:
         pipe = _small_pipe(small_variables, writer)
         batcher = FrameBatcher(B)
@@ -1129,3 +1160,111 @@ def test_drained_ahead_is_counted_and_exported(small_variables, monkeypatch, scr
     assert samples[("psana_ray_drained_ahead_total", 'source="sfx"')] == snap["drained_ahead_total"]
     assert samples[("psana_ray_batches_total", 'source="sfx"')] == 12.0
     assert "# TYPE psana_ray_drained_ahead_total counter" in text
+
+
+# ---------------------------------------------------------------------------
+# frames go to the device as they land (ISSUE 43): what was staged belongs
+# to the batcher's current arena, and to nothing else
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize(
+    "script,per_batch,puts",
+    [([1] * (6 * B), B - 1, 6 * (B - 1)), ([B] * 6, 0, 0), ([B // 2] * 12, B // 2, 6)],
+    ids=["trickle", "whole-batches", "half-a-batch-a-pop"],
+)
+def test_frames_staged_ahead_are_counted_and_exported(
+    small_variables, monkeypatch, script, per_batch, puts
+):
+    from test_obs import parse_prometheus
+
+    from psana_ray_tpu.obs import MetricsRegistry
+
+    s = _Scripted(small_variables, monkeypatch, script)
+    assert s.run() == 6 * B
+    assert s.staged == [per_batch] * 6 and s.every_step_was_given_its_own_batch()
+    snap = s.pipe.metrics.snapshot()
+    assert snap["frames_total"] == 6 * B
+    assert snap["frames_staged_ahead_total"] == 6 * per_batch
+    # the staging has a phase of its own, one observation a turn that staged
+    put_ahead = s.pipe.metrics.stages.stat("put_ahead")
+    assert (put_ahead.count if put_ahead else 0) == puts
+    reg = MetricsRegistry()
+    reg.register("sfx", s.pipe.metrics)
+    text = reg.render_prometheus()
+    samples = parse_prometheus(text)
+    assert samples[("psana_ray_frames_staged_ahead_total", 'source="sfx"')] == 6 * per_batch
+    assert "# TYPE psana_ray_frames_staged_ahead_total counter" in text
+
+
+def test_the_put_ahead_phase_is_a_span_with_its_frames_and_bytes(small_variables, monkeypatch, tmp_path):
+    import json
+
+    from psana_ray_tpu.obs.tracing import TRACER
+
+    TRACER.configure(str(tmp_path), sample_every=1, process="t")
+    try:
+        s = _Scripted(small_variables, monkeypatch, [1, 2, 1, B])
+        assert s.run() == 2 * B
+        path = TRACER.spool_path
+    finally:
+        TRACER.close()
+    rows = [json.loads(line) for line in open(path) if line.strip()]
+    spans = [r for r in rows if r["t"] == "s" and r["n"] == "stage.put_ahead"]
+    frame_bytes = int(np.prod(SMALL)) * 2
+    assert [(r["k"], r["y"]) for r in spans] == [(1, frame_bytes), (2, 2 * frame_bytes)]
+    # each lies between a turn's copy and the next turn's wait, before its batch's launch
+    launch = next(r for r in rows if r["t"] == "s" and r["n"] == "stage.launch")
+    assert all(r["b"] <= launch["a"] for r in spans)
+
+
+def test_a_stop_drops_what_was_staged_with_its_arena(small_variables, monkeypatch):
+    stop = threading.Event()
+    s = _Scripted(small_variables, monkeypatch, [B, 1, 1, stop.set, 0, B])
+    assert s.run(stop=stop) == B  # the two staged frames are abandoned with their arena
+    assert s.staged == [0] and s.writer.events == list(range(B))
+    # the same pipeline over what the producer sends again: nothing of the
+    # abandoned arena is served, under any batch's ids
+    s.queue = _ScriptedQueue([1] * (2 * B), lambda: None)
+    assert s.run() == 2 * B
+    assert s.staged == [0, B - 1, B - 1] and s.every_step_was_given_its_own_batch()
+    assert s.writer.events == list(range(B)) + list(range(2 * B))
+
+
+@pytest.mark.parametrize("rows_land", [False, True], ids=["before-the-rows", "after-the-rows"])
+def test_a_drain_that_raises_between_two_staged_frames_serves_nothing_twice(
+    small_variables, monkeypatch, rows_land
+):
+    # batch 1's early drain fails while two frames of batch 2 are on the device
+    s = _Scripted(small_variables, monkeypatch, [B, "ready", 0, B, 1, 1, "ready", 1, 1, B],
+                  writer=_FailingWriter(rows_land))
+    with pytest.raises(OSError, match="disk full"):
+        s.run()
+    assert [e[1] for e in s.calls if e[0] == "drain"] == [0, 1]
+    assert s.staged == [0, 0]  # batch 2 was never launched
+    assert s.writer.events == list(range(2 * B if rows_land else B))  # each row once
+    assert s.every_step_was_given_its_own_batch()
+    s.queue = _ScriptedQueue([1] * B + [B], lambda: None)
+    assert s.run() == 2 * B  # a fresh stream: fresh arenas, nothing carried over
+    assert s.staged == [0, 0, B - 1, 0] and s.every_step_was_given_its_own_batch()
+
+
+def test_a_trickled_stream_compiles_nothing_after_the_warm_up(small_variables):
+    """The benchmark warms the loop through ``process_batch`` alone; a
+    stream that stages 1, 2 or 3 frames a turn, or none, and ends in a
+    padded tail must then find its one program compiled."""
+    from psana_ray_tpu.infeed.batcher import Batch
+
+    pipe = _small_pipe(small_variables, _RowsWriter())
+    full = np.stack([_small_frame(i).panels for i in range(B)])
+    for first in (0, B):
+        pipe.process_batch(Batch(
+            frames=full, valid=np.ones(B, np.uint8), shard_rank=np.full(B, -1, np.int32),
+            event_idx=np.arange(first, first + B, dtype=np.int64),
+            photon_energy=np.zeros(B, np.float32),
+        ))
+    compiled = pipe._jit_step._cache_size()
+    assert compiled == 1
+    queue = _ScriptedQueue([1, 1, 1, 1, 2, 2, 3, 1, B, 1, 0, 3, 2], lambda: None)
+    assert pipe.run(queue, poll_interval_s=0.001) == queue.sent == 4 * B + 6
+    assert pipe.metrics.frames_staged_ahead.count == 3 + 2 + 3 + 0 + 1 + 2
+    assert pipe._jit_step._cache_size() == compiled  # no miss: the tuple of B frames, as warmed

@@ -5,26 +5,32 @@ MLP or a block assembled from a configuration (``vit.py`` hard-codes
 LayerNorm, GELU and a position table). This module builds the text
 decoder of a language model from the keys of its published
 ``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B, DeepSeek-V3's
-block as Kimi-K2 spells it):
+block as Kimi-K2 spells it, DeepSeek-V3.2's):
 
     x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
     x  -> x + ff(rms(x))                      three kinds, its ff one of two
 
 A layer's OPERATOR (``layer_types``) is grouped-query attention (a
 per-head RMS norm on q and k, then the rotary, multimodal or on the
-sequence index), plain causal or restricted, per query, to the ``topk``
-keys a learned indexer ranks highest (``parallel/sparse_attention.py``);
-or a gated short convolution (:func:`gated_short_conv`); or, where the
-configuration has a ``kv_lora_rank``, LATENT attention
+sequence index); or a gated short convolution (:func:`gated_short_conv`);
+or, where the configuration has a ``kv_lora_rank``, LATENT attention
 (:func:`latent_attention`: low-rank queries, keys and values decompressed
 per head from one normed latent, one rotary key for all heads, YaRN's
-frequencies). Its FEED-FORWARD is a dense gated-SiLU MLP (the first
-``num_dense_layers``, or all where there are no experts) or top-k of
-``num_experts`` experts without dropped tokens
-(``parallel/moe.dropless_moe``; a softmax router, or sigmoid affinities
-under a selection bias), of which this holder may hold a share
-(``experts_held``: only the held slots' rows move), beside
-``shared_experts`` that every token passes through. The trunk runs that schedule over ``B``
+frequencies). Either attention is plain causal or restricted, per query
+and in every head alike, to the ``topk`` keys a learned INDEXER ranks
+highest (``parallel/sparse_attention.py``; :func:`_indexer`): its queries
+are projected from the layer's normed input or, under latent attention,
+from the query's normed low rank; its one key goes through an RMS norm
+or a LayerNorm, and the rotary turns all of an index vector or its
+leading part (fields, with the first indexer's values as defaults). Its
+FEED-FORWARD is a dense gated-SiLU MLP (the first ``num_dense_layers``,
+or all where there are no experts) or top-k of ``num_experts`` experts
+without dropped tokens (``parallel/moe.dropless_moe``; a softmax router,
+or sigmoid affinities under a selection bias, the choice limited to the
+best ``router_groups_kept`` of ``router_groups`` groups where there are
+groups), of which this holder may hold a share (``experts_held``: only
+the held slots' rows move), beside ``shared_experts`` that every token
+passes through. The trunk runs that schedule over ``B``
 sequences of ``S`` tokens held as ``[B*S, D]`` rows: what mixes tokens
 (attention, the convolution, the rotary) is told ``B`` and stays inside
 a sequence; the expert layer sorts all ``B*S`` rows at once and moves each
@@ -76,6 +82,13 @@ STEP_STATS = (
 SHARE_STATS = (
     "expert_rows_held_total",    # token slots whose expert lives here, over the expert layers
     "expert_rows_routed_total",  # all token slots of those layers: B * S * k each
+)
+# and two after those eight, from a key selection over LATENT attention: what
+# the selection kept of what it could. |Sel(t)| = min(t + 1, topk) exactly
+# (`select_keys` cuts ties to it), so both are counted from the shapes
+PAIR_STATS = (
+    "attn_pairs_selected_total",  # (query, key) pairs attended, over the layers with a selection
+    "attn_pairs_causal_total",    # pairs at or below the diagonal in those layers
 )
 ATTENTION, CONV = "full_attention", "conv"  # layer_types, as config.json spells them
 LATENT = "latent_attention"  # what an ATTENTION layer is under a kv_lora_rank
@@ -148,10 +161,13 @@ class DecoderConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # learned sparse attention (None: plain causal attention)
+    # learned sparse attention (None: plain causal attention). The index queries are projected
+    # from the layer's normed input, or from the normed query rank where attention is latent
     indexer_heads: Optional[int] = None
     indexer_head_dim: int = 0
     topk: int = 0
+    indexer_rope_dim: int = 0  # leading components of an index vector the rotary turns (0: all)
+    indexer_key_norm: str = "rms"  # of the one index key: an RMS norm, or "layer" (gain and bias)
     # the computation's tiles; no effect on the mathematics. Measured on the
     # v5e at 34,304 tokens: selection 36 ms a layer at 128 queries against 67
     # at 256 (512 needs 70 MB of VMEM for a tile's score row), attention 124
@@ -175,6 +191,10 @@ class DecoderConfig:
     expert_bias: bool = False  # experts CHOSEN by score + bias, weighted by the score alone
     gate_eps: float = 0.0  # in the renormalising sum of the chosen scores
     routed_scaling_factor: float = 1.0
+    # group-limited routing (moe.route_top_k): the experts in router_groups runs, chosen among
+    # the router_groups_kept best groups' only (1: no limit)
+    router_groups: int = 1
+    router_groups_kept: int = 1
     shared_experts: int = 0  # beside the routed ones, ungated: one MLP of shared_experts * expert_width
     intermediate_size: int = 0
     patch: int = 8
@@ -192,6 +212,18 @@ class DecoderConfig:
     def holds_a_share(self) -> bool:
         """Of the routed experts: the step then counts :data:`SHARE_STATS` too."""
         return bool(self.num_experts) and self.experts_held[1] < self.num_experts
+
+    @property
+    def selects_over_latent(self) -> bool:
+        """A key selection over latent attention: the step then counts
+        :data:`SHARE_STATS` and :data:`PAIR_STATS` too."""
+        return bool(self.indexer_heads) and bool(self.kv_lora_rank)
+
+    @property
+    def layer_stats(self) -> int:
+        """How many statistics a layer counts: the first four of
+        :data:`STEP_STATS`, then the two groups above."""
+        return 8 if self.selects_over_latent else 6 if self.holds_a_share else 4
 
     def layer_kind(self, i: int) -> Tuple[str, bool]:
         """``(operator, has experts)`` of layer ``i``."""
@@ -216,10 +248,24 @@ class DecoderConfig:
         attention without per-head norms; ``rope_scaling.type: yarn``;
         ``n_routed_experts``, ``n_shared_experts``,
         ``first_k_dense_replace``, ``scoring_func``, ``topk_method:
-        noaux_tc``: the selection bias). Where a file's ``n_routed_experts``
-        counts the experts HELD (a chip's share), ``router_experts`` gives
-        the width the router keeps."""
+        noaux_tc``: the selection bias; ``n_group`` and ``topk_group``: the
+        group limit; and as DeepSeek-V3.2's file has it, ``index_n_heads``,
+        ``index_head_dim``, ``index_topk``: the key selection over latent
+        attention, whose index key goes through a LayerNorm and whose
+        rotary turns ``qk_rope_head_dim`` of an index vector). Where a
+        file's ``n_routed_experts`` counts the experts HELD (a chip's
+        share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
+        index = {}
+        if sa_cfg:
+            index = dict(indexer_heads=int(sa_cfg["indexer_num_heads"]),
+                         indexer_head_dim=int(sa_cfg["indexer_head_dim"]),
+                         topk=int(sa_cfg["topk"]), kv_tile=int(sa_cfg["kv_chunk_size"]))
+        elif m.get("index_n_heads"):
+            index = dict(indexer_heads=int(m["index_n_heads"]),
+                         indexer_head_dim=int(m["index_head_dim"]), topk=int(m["index_topk"]),
+                         indexer_rope_dim=int(m.get("qk_rope_head_dim", 0)),
+                         indexer_key_norm="layer")
         held_key = "num_experts" if "num_experts" in m else "n_routed_experts"
         n_exp = int(m.get("router_experts", m.get(held_key, 0)))
         n_layers, heads = int(m["num_hidden_layers"]), int(m["num_attention_heads"])
@@ -257,10 +303,7 @@ class DecoderConfig:
             q_lora_rank=int(m.get("q_lora_rank") or 0) if latent else 0, kv_lora_rank=latent,
             qk_nope_head_dim=nope if latent else 0, qk_rope_head_dim=rope_dim if latent else 0,
             v_head_dim=int(m.get("v_head_dim", 0)) if latent else 0,
-            indexer_heads=int(sa_cfg["indexer_num_heads"]) if sa_cfg else None,
-            indexer_head_dim=int(sa_cfg["indexer_head_dim"]) if sa_cfg else 0,
-            topk=int(sa_cfg["topk"]) if sa_cfg else 0,
-            kv_tile=int(sa_cfg["kv_chunk_size"]) if sa_cfg else 512,
+            **index,
             num_experts=n_exp, experts_per_token=int(m.get("num_experts_per_tok", 0)),
             expert_width=int(m.get("moe_intermediate_size", 0)),
             experts_held=tuple(int(v) for v in m.get("experts_held", (0, n_exp))),
@@ -271,6 +314,8 @@ class DecoderConfig:
             # the renormalising sum's epsilon: LFM2's code has 1e-6, DeepSeek-V3's 1e-20
             gate_eps=(1e-20 if deepseek else 1e-6) if sigmoid else 0.0,
             routed_scaling_factor=float(m.get("routed_scaling_factor", 1.0)),
+            router_groups=int(m.get("n_group") or 1),
+            router_groups_kept=int(m.get("topk_group") or 1),
             shared_experts=int(m.get("n_shared_experts") or 0) if n_exp else 0,
             intermediate_size=int(m.get("intermediate_size", 0)),
             patch=int(m.get("patch", 8)),
@@ -287,7 +332,10 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
     [..], "norm", "head"}`` (no ``head`` where it is the embedding). Call
     under ``jax.jit`` to make the weights on the device."""
     d, hd = cfg.hidden_size, cfg.head_dim
-    keys = iter(jax.random.split(key, 16 * cfg.num_layers + 8))
+    # a latent layer with an indexer draws 17 matrices: 20 keys a layer there, and where the
+    # count was 16 it stays 16 (the same seed, the same weights)
+    keys = iter(jax.random.split(
+        key, (20 if cfg.selects_over_latent else 16) * cfg.num_layers + 8))
 
     def w(*shape, dtype=dtype):
         return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(dtype)
@@ -307,6 +355,8 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                  "wkv_a": w(d, rkv + cfg.qk_rope_head_dim), "kv_a_norm": gain(rkv),
                  "wkv_b": w(rkv, heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
                  "wo": w(heads * cfg.v_head_dim, d), "norm2": gain(d)}
+            if cfg.indexer_heads:  # the index queries read the query's normed low rank
+                p.update(_index_params(cfg, w, gain, rq))
         else:
             p = {
                 "norm1": gain(d), "wq": w(d, cfg.num_heads * hd), "wk": w(d, cfg.num_kv_heads * hd),
@@ -314,9 +364,7 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 "wo": w(cfg.num_heads * hd, d), "norm2": gain(d),
             }
             if cfg.indexer_heads:
-                di = cfg.indexer_head_dim
-                p.update(idx_wq=w(d, cfg.indexer_heads * di), idx_wk=w(d, di),
-                         idx_k_norm=gain(di), idx_ww=w(d, cfg.indexer_heads))
+                p.update(_index_params(cfg, w, gain, d))
         if experts:
             held = cfg.experts_held[1]
             p.update(router=w(d, cfg.num_experts), w_gate=w(held, d, cfg.expert_width),
@@ -335,6 +383,17 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
     if not cfg.tie_embedding:
         params["head"] = w(d, cfg.vocab_size)
     return params
+
+
+def _index_params(cfg: DecoderConfig, w, gain, q_from: int) -> dict:
+    """The indexer's matrices, its queries projected from ``q_from`` wide
+    rows; a LayerNorm on the key has a bias beside its gain."""
+    d, di = cfg.hidden_size, cfg.indexer_head_dim
+    p = {"idx_wq": w(q_from, cfg.indexer_heads * di), "idx_wk": w(d, di),
+         "idx_k_norm": gain(di), "idx_ww": w(d, cfg.indexer_heads)}
+    if cfg.indexer_key_norm == "layer":
+        p["idx_k_bias"] = w(di)
+    return p
 
 
 # ---------------------------------------------------------------------------
@@ -399,11 +458,36 @@ def _projections(p, x, angles, cfg: DecoderConfig):
     return a, q.reshape(s, -1).astype(dt), k.reshape(s, -1).astype(dt), v.astype(dt)
 
 
-def _indexer(p, a, idx_angles, cfg: DecoderConfig):
+def layer_norm(u, g, b, eps: float):
+    """``(u - mean(u)) / sqrt(var(u) + eps) * g + b`` over the last axis, in float32."""
+    u = u.astype(jnp.float32)
+    u = u - jnp.mean(u, axis=-1, keepdims=True)
+    return (u * jax.lax.rsqrt(jnp.mean(u * u, axis=-1, keepdims=True) + eps)
+            * g.astype(jnp.float32) + b.astype(jnp.float32))
+
+
+def _turn_leading(x, angles, width: int):
+    """:func:`rotate` on the first ``width`` components of ``x``'s last
+    axis (0, or the whole width: on all of them)."""
+    if not width or width == x.shape[-1]:
+        return rotate(x, angles)
+    return jnp.concatenate([rotate(x[..., :width], angles), x[..., width:]], axis=-1)
+
+
+def _indexer(p, a, idx_angles, cfg: DecoderConfig, q_from=None):
+    """The selection of one sequence from the layer's normed input ``a``:
+    index queries from ``q_from`` (default: ``a``), ONE normed index key and
+    the heads' weights from ``a`` -> :func:`sparse_attention.select_keys`'
+    mask and flags."""
     s = a.shape[0]
     h, d = cfg.indexer_heads, cfg.indexer_head_dim
-    q = rotate(_mm(a, p["idx_wq"]).reshape(s, h, d), idx_angles)
-    k = rotate(rms_norm(_mm(a, p["idx_wk"]), p["idx_k_norm"], cfg.rms_eps)[:, None, :], idx_angles)
+    q = _turn_leading(_mm(a if q_from is None else q_from, p["idx_wq"]).reshape(s, h, d),
+                      idx_angles, cfg.indexer_rope_dim)
+    if cfg.indexer_key_norm == "layer":
+        k = layer_norm(_mm(a, p["idx_wk"]), p["idx_k_norm"], p["idx_k_bias"], cfg.rms_eps)
+    else:
+        k = rms_norm(_mm(a, p["idx_wk"]), p["idx_k_norm"], cfg.rms_eps)
+    k = _turn_leading(k[:, None, :], idx_angles, cfg.indexer_rope_dim)
     w = _mm(a, p["idx_ww"]) * h ** -0.5
     # a call of its own: the kernel is `select_keys` in a device trace
     return jax.jit(sa.select_keys, static_argnames=("topk", "block_q", "block_k"))(
@@ -431,7 +515,9 @@ def gated_short_conv(p, x, batch: int, cfg: DecoderConfig):
 
 def _latent_projections(p, x, angles, cfg: DecoderConfig):
     """``x [T, D]`` -> ``(q_nope [T, H*dn], q_rope [T, H*dr], k_nope [T,
-    H*dn], k_rope [T, dr], v [T, H*dv])``: the queries through their normed
+    H*dn], k_rope [T, dr], v [T, H*dv])``, and under a key selection the
+    normed input and the normed query rank ``(a [T, D], c_q [T, rq])``
+    after them: the queries through their normed
     low rank, the keys' and values' per-head parts decompressed from the
     normed latent, the ONE rotary key (not normed) and each head's rotary
     query turned; the softmax scale (with YaRN's ``mscale**2``) rides on
@@ -450,35 +536,56 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
     c_kv = rms_norm(down[:, :cfg.kv_lora_rank], p["kv_a_norm"], cfg.rms_eps).astype(dt)
     k_rope = rotate(down[:, None, cfg.kv_lora_rank:], angles)[:, 0] * turned
     kv = _mm(c_kv, p["wkv_b"]).reshape(t, h, dn + dv)
-    return (q_nope.reshape(t, -1).astype(dt), q_rope.reshape(t, -1).astype(dt),
-            kv[..., :dn].reshape(t, -1).astype(dt), k_rope.astype(dt),
-            kv[..., dn:].reshape(t, -1).astype(dt))
+    out = (q_nope.reshape(t, -1).astype(dt), q_rope.reshape(t, -1).astype(dt),
+           kv[..., :dn].reshape(t, -1).astype(dt), k_rope.astype(dt),
+           kv[..., dn:].reshape(t, -1).astype(dt))
+    return out + (a, c_q) if cfg.indexer_heads else out  # what an indexer reads
 
 
-def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig):
-    """DeepSeek-V3's operator on ``x [B*S, D]`` -> ``x + Op``, prefill in
+def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=None):
+    """DeepSeek-V3's operator on ``x [B*S, D]`` -> ``(x + Op, live,
+    causal)``, the last two the layer's statistics tiles, prefill in
     the DECOMPRESSED form: every head has its own keys and values (``2 *
     (head_dim + v_head_dim)`` FLOPs a causal pair and head; the absorbed
     form, scores against the latent itself, is decode's), and a score is
     ``q_nope . k_nope + q_rope . k_rope`` with the rotary key read from its
     one ``[B, S, dr]`` array (``sparse_attention.masked_gqa_attention``'s
-    shared part), never written ``H`` times over. Under the scopes ``proj``
-    (both low-rank paths, their norms, the rotary, ``W_o``) and
-    ``latent_attn`` (the attention itself)."""
+    shared part), never written ``H`` times over. Under a learned key
+    selection (DeepSeek-V3.2's: an indexer fed by the query's normed low
+    rank, the same ``Sel(t)`` in every head) the same kernel takes the
+    selection's mask: still the decompressed form over every causal tile,
+    which at a selection of 2,048 of 8,704 does less arithmetic than the
+    absorbed form over the selected pairs alone. Under the scopes ``proj``
+    (both low-rank paths, their norms, the rotary, ``W_o``), ``indexer``
+    (its three projections and ``select_keys``) and ``latent_attn`` (the
+    attention itself)."""
     s = x.shape[0] // batch
     with jax.named_scope("proj"):
-        q_nope, q_rope, k_nope, k_rope, v = jax.jit(_latent_projections, static_argnums=3)(
-            p, x, angles, cfg)
+        q_nope, q_rope, k_nope, k_rope, v, *read = jax.jit(
+            _latent_projections, static_argnums=3)(p, x, angles, cfg)
+    selection = ()  # the mask, where there is one: the kernel's fourth operand
+    if cfg.indexer_heads:
+        if batch != 1:
+            raise ValueError(f"a learned key selection is per sequence: batch {batch} is not 1")
+        with jax.named_scope("indexer"):
+            mask, flags = jax.jit(_indexer, static_argnums=3)(p, read[0], idx_angles, cfg,
+                                                              q_from=read[1])
+            live, causal = sa.live_tiles(flags, mask.shape[2], mask.shape[3])
+            selection = (mask,)
+    else:
+        live = causal = batch * sa.causal_tile_count(s)  # every earlier key is attended
     with jax.named_scope("latent_attn"):
         attend = jax.jit(sa.masked_gqa_attention,
                          static_argnames=("num_kv_heads", "block_q", "block_k"))
         q_nope, q_rope, k_nope, k_rope, v = (
             u.reshape(batch, s, -1) for u in (q_nope, q_rope, k_nope, k_rope, v))
-        o = attend(q_nope, k_nope, v, num_kv_heads=cfg.num_heads, block_q=cfg.causal_q_tile,
-                   block_k=cfg.causal_kv_tile, q_shared=q_rope, k_shared=k_rope)
+        o = attend(q_nope, k_nope, v, *selection, num_kv_heads=cfg.num_heads,
+                   block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile, q_shared=q_rope,
+                   k_shared=k_rope)
     with jax.named_scope("proj"):
-        return jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(
+        x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(
             x, o.reshape(x.shape[0], -1), p["wo"])
+    return x, live, causal
 
 
 def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
@@ -515,7 +622,8 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
 def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int = 1):
     """One block: ``x [B*S, D]`` -> ``(x, stats float32)``, the first four
     of :data:`STEP_STATS` and, from a holder of a share of the experts,
-    :data:`SHARE_STATS`. ``kind`` is ``cfg.layer_kind(i)``: the operator
+    :data:`SHARE_STATS` (under a selection over latent attention those
+    and :data:`PAIR_STATS`: ``cfg.layer_stats`` in all). ``kind`` is ``cfg.layer_kind(i)``: the operator
     runs under ``conv``, latent attention's or attention's scopes, the
     feed-forward under ``moe`` (the routed experts), ``shared_expert``
     (beside them, added once) or ``mlp`` (dense)."""
@@ -525,11 +633,10 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
         with jax.named_scope("conv"):
             x = jax.jit(gated_short_conv, static_argnums=(2, 3))(p, x, batch, cfg)
     elif op == LATENT:
-        x = latent_attention(p, x, angles, batch, cfg)
-        live = causal = batch * sa.causal_tile_count(x.shape[0] // batch)
+        x, live, causal = latent_attention(p, x, angles, batch, cfg, idx_angles)
     else:
         x, live, causal = _attention(p, x, angles, idx_angles, batch, cfg)
-    share = experts and cfg.holds_a_share
+    share = experts and cfg.layer_stats > 4
     given = ()  # from the shared expert: the layer's normed rows b, and x + Shared(b)
     if experts and cfg.shared_experts:
         with jax.named_scope("shared_expert"):  # every token's, whatever it chose; the norm is here, once
@@ -549,7 +656,8 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
                 num_experts=cfg.num_experts, experts_held=cfg.experts_held,
                 renormalise=cfg.norm_topk_prob, scoring=cfg.router_scoring,
                 select_bias=p.get("router_bias"), gate_eps=cfg.gate_eps,
-                gate_scale=cfg.routed_scaling_factor)
+                gate_scale=cfg.routed_scaling_factor, groups=cfg.router_groups,
+                groups_kept=cfg.router_groups_kept)
             out = onto + y
             return (out, jnp.max(tokens), jnp.sum(tokens)) if share else (out, jnp.max(tokens))
 
@@ -557,9 +665,14 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     even = x.shape[0] * cfg.experts_per_token / cfg.num_experts if experts else 0.0
     stats = [busiest.astype(jnp.float32), jnp.float32(even),
              jnp.asarray(live, jnp.float32), jnp.float32(causal)]
-    if cfg.holds_a_share:
+    if cfg.layer_stats > 4:
         routed = x.shape[0] * cfg.experts_per_token if experts else 0
         stats += [held[0].astype(jnp.float32) if held else jnp.float32(0), jnp.float32(routed)]
+    if cfg.selects_over_latent:
+        s = x.shape[0] // batch
+        kept = min(s, cfg.topk)  # sum over a sequence's queries of min(t + 1, topk)
+        stats += [jnp.float32(batch * (kept * (kept + 1) // 2 + (s - kept) * cfg.topk)),
+                  jnp.float32(batch * (s * (s + 1) // 2))]
     return x, jnp.stack(stats)
 
 
@@ -568,22 +681,26 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     the other, each at ``pos`` (static: ``[S, 3]`` under the multimodal
     rotary, else ``[S]``), through every layer -> ``(x [B*S, D], stats
     in :data:`STEP_STATS`' order``, then :data:`SHARE_STATS` where the
-    holder has a share of the experts)."""
+    holder has a share of the experts, and :data:`PAIR_STATS` under a
+    selection over latent attention)."""
     s = x.shape[0] // batch
     angles = rotary_angles(pos, cfg.rope_theta, cfg.rope_dim // 2, cfg.mrope_section,
                            cfg.rope_yarn)
     idx_angles = None
     if cfg.indexer_heads:
-        # the indexer's vectors turn with the sequence index alone
-        idx_angles = rotary_angles(np.arange(s), cfg.rope_theta, cfg.indexer_head_dim // 2)
+        # the indexer's vectors turn with the sequence index alone (under YaRN by its frequencies:
+        # where the rotary part is as wide as the key's, by the rotary key's own angles)
+        idx_angles = rotary_angles(
+            np.arange(s), cfg.rope_theta, (cfg.indexer_rope_dim or cfg.indexer_head_dim) // 2,
+            yarn=cfg.rope_yarn)
     if batch != 1:
         angles = jnp.tile(angles, (batch, 1))
-    stats = jnp.zeros((6 if cfg.holds_a_share else 4,), jnp.float32)
+    stats = jnp.zeros((cfg.layer_stats,), jnp.float32)
     for i, p in enumerate(params["layers"]):
         x, layer_stats = decoder_layer(p, x, angles, idx_angles, cfg, cfg.layer_kind(i), batch)
         stats = stats + layer_stats
     served = jnp.asarray([batch * s, batch], jnp.float32)
-    if cfg.holds_a_share:
+    if cfg.layer_stats > 4:
         return x, jnp.concatenate([stats[:4], served, stats[4:]])
     return x, jnp.concatenate([stats, served])
 
@@ -649,8 +766,9 @@ def frame_step(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, thresho
 
 def fold_step_stats(metrics, stats) -> None:
     """Add one step's statistics vector (on the host or the device: six
-    values, or eight from a holder of a share of the experts) to the
+    values, eight from a holder of a share of the experts, ten under a
+    selection over latent attention) to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""
-    for name, value in zip(STEP_STATS + SHARE_STATS, np.asarray(stats, np.float64)):
+    for name, value in zip(STEP_STATS + SHARE_STATS + PAIR_STATS, np.asarray(stats, np.float64)):
         metrics.add_counter(name, float(value))

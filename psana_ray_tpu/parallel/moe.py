@@ -183,7 +183,8 @@ def total_aux_loss(intermediates) -> jax.Array:
 
 
 def route_top_k(probs: jax.Array, k: int, renormalise: bool = True, *, select_bias=None,
-                gate_eps: float = 0.0, gate_scale: float = 1.0):
+                gate_eps: float = 0.0, gate_scale: float = 1.0, groups: int = 1,
+                groups_kept: int = 1):
     """``probs [T, E]`` float32 -> ``(ids [T, k] int32, gates [T, k]
     float32)``: each token's ``k`` highest-scoring experts (equal scores:
     the lower index first) and their gates, renormalised to sum to one
@@ -191,8 +192,22 @@ def route_top_k(probs: jax.Array, k: int, renormalise: bool = True, *, select_bi
     ``select_bias [E]`` the experts are CHOSEN by ``probs + select_bias``
     and WEIGHTED by ``probs`` without it (DeepSeek-V3's bias-steered
     selection); ``gate_eps`` joins the renormalising sum and
-    ``gate_scale`` multiplies the gates (``routed_scaling_factor``)."""
-    if select_bias is None:
+    ``gate_scale`` multiplies the gates (``routed_scaling_factor``).
+    With ``groups`` > 1 the choice is GROUP-LIMITED (``n_group``,
+    ``topk_group``): the experts are ``groups`` runs of ``E / groups``
+    consecutive ones, a group's score is the sum of its two largest
+    choosing scores, the ``groups_kept`` best groups stay (equal scores:
+    the lower group) and the ``k`` are chosen among their experts only."""
+    if groups > 1:
+        scores = probs if select_bias is None else probs + select_bias.astype(probs.dtype)
+        t, e = scores.shape
+        best_two = jax.lax.top_k(scores.reshape(t, groups, e // groups), 2)[0]
+        _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), groups_kept)  # [T, groups_kept]
+        stays = jnp.any(kept[:, :, None] == jnp.arange(groups, dtype=kept.dtype), axis=1)
+        _, ids = jax.lax.top_k(
+            jnp.where(jnp.repeat(stays, e // groups, axis=1), scores, -jnp.inf), k)
+        top_p = jnp.take_along_axis(probs, ids, axis=-1)
+    elif select_bias is None:
         top_p, ids = jax.lax.top_k(probs, k)
     else:
         _, ids = jax.lax.top_k(probs + select_bias.astype(probs.dtype), k)
@@ -243,7 +258,7 @@ def _grouped_product(rows, weights, tokens, out_dtype, interpret):
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
                  experts_held=None, renormalise: bool = True, scoring: str = "softmax",
                  select_bias=None, gate_eps: float = 0.0, gate_scale: float = 1.0,
-                 interpret=None):
+                 groups: int = 1, groups_kept: int = 1, interpret=None):
     """Top-``k`` of ``num_experts`` gated-SiLU experts with NO dropped
     token: ``x [T, D]`` -> ``(y [T, D], tokens [count] int32)``.
 
@@ -252,7 +267,8 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
     [count, D, F]``, ``w_down [count, F, D]``). It routes over all
     ``num_experts`` (``router_w [D, E]``; the affinities, ``scoring``
     ``"softmax"`` or ``"sigmoid"`` of the logits, in float32; ``select_bias``,
-    ``gate_eps`` and ``gate_scale`` as :func:`route_top_k` takes them), and
+    ``gate_eps``, ``gate_scale``, ``groups`` and ``groups_kept`` as
+    :func:`route_top_k` takes them), and
     returns its own experts' part of the result: the sum over the chosen
     experts that live here of ``gate * (silu(x W_gate) * (x W_up))
     W_down``; what the absent experts would add is left out, and the
@@ -282,7 +298,7 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
         logits = jnp.dot(x, router_w.astype(x.dtype), preferred_element_type=jnp.float32)
         ids, gates = route_top_k(SCORINGS[scoring](logits), k, renormalise,
                                  select_bias=select_bias, gate_eps=gate_eps,
-                                 gate_scale=gate_scale)
+                                 gate_scale=gate_scale, groups=groups, groups_kept=groups_kept)
         if count < num_experts:  # a share of the experts: the rows HELD move, no others
             return _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first, count, interpret)
         flat = ids.reshape(-1)

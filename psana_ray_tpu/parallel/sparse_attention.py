@@ -1,8 +1,10 @@
 """Learned sparse attention: index scores, a per-query top-k key selection,
 and grouped-query attention restricted to the selected keys.
 
-The mechanism (DeepSeek-V3.2-Exp's description of DSA, with the sizes of
-Keye-VL-2.0's ``sa_config``): a light INDEXER of ``H_I`` query heads
+The mechanism (DeepSeek-V3.2-Exp's description of DSA, at the sizes of
+Keye-VL-2.0's ``sa_config``, 16 index heads of 64 over grouped-query
+attention, and at DeepSeek-V3.2's own, 64 of 128 over latent attention):
+a light INDEXER of ``H_I`` query heads
 against ONE key head scores every earlier key for every query,
 
     I[t, s] = sum_j w[t, j] * relu(qI[t, j] . kI[s]) / sqrt(d_I)     s <= t
@@ -28,10 +30,14 @@ What is here, and what it is:
   the optimisation this form is the yardstick for. The ``G`` key-value
   heads each serve ``H/G`` query heads, whose query tiles are stacked
   into one ``[H/G * bq, d]`` operand so that a key tile is loaded once
-  for all of them. WITHOUT a mask it is plain causal attention over a
-  batch of sequences: no mask operand, and a grid that holds the tiles at
-  or below the diagonal only (a table of ``(query tile, key tile)`` pairs
-  rides in as scalar prefetch), at any head width (heads of 64: LFM2).
+  for all of them. Its BATCHED form (``[B, S, .]`` operands) has a grid
+  that holds the tiles at or below the diagonal only (a table of ``(query
+  tile, key tile)`` pairs rides in as scalar prefetch), at any head width
+  (heads of 64: LFM2), with values of a width of their own and a part of
+  the score read from ONE key for all heads (latent attention). Without a
+  mask it is plain causal attention over a batch of sequences; with one
+  (one sequence) it is the selection over LATENT attention, masked-dense
+  again: the mask's tile is one more operand of a grid step.
 - :func:`live_tiles` — how many ``stat_tile`` x ``stat_tile`` tiles at
   or below the diagonal hold a selected pair (from the flags the selection
   kernel writes beside its mask), and how many there are: what a
@@ -284,11 +290,13 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
             o_ref[:, h * d:(h + 1) * d] = out[h * block_q:(h + 1) * block_q].astype(o_ref.dtype)
 
 
-def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared):
+def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared, masked):
+    rest = list(rest)
     if shared:  # the part of the score that all heads read from ONE key
-        qs_ref, ks_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        qs_ref, ks_ref = rest.pop(0), rest.pop(0)
+    if masked:  # the selection's tile, one for all heads: it is causal by construction
+        mask_ref = rest.pop(0)
+    o_ref, m_ref, l_ref, acc_ref = rest
     t = pl.program_id(2)
     qi, kb = qi_ref[t], kb_ref[t]
     rep, _, d = q_ref.shape
@@ -308,7 +316,14 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
             s = s + jax.lax.dot_general(
                 qs_ref[...].reshape(rows, qs_ref.shape[2]), ks_ref[...],
                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        if with_diagonal:  # key 0 of the sequence is open to every row: m is finite from tile 0
+        if masked:
+            # a row with nothing selected yet has m_new == NEG_INF and p == 1 on its masked
+            # entries: the first selected key's alpha == 0 wipes that, and every row selects a
+            # key at or before its diagonal tile
+            sel = mask_ref[...].astype(jnp.float32).reshape(block_q, block_k)
+            s = jnp.where((sel > 0.0)[None], s.reshape(rep, block_q, block_k),
+                          NEG_INF).reshape(rows, block_k)
+        elif with_diagonal:  # key 0 of the sequence is open to every row: m is finite from tile 0
             row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where((col <= row)[None], s.reshape(rep, block_q, block_k),
@@ -322,9 +337,12 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
                                                   preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    below = (kb + 1) * block_k - 1 <= qi * block_q  # every pair of the tile is causal
-    pl.when(below)(lambda: update(False))
-    pl.when(jnp.logical_not(below))(lambda: update(True))
+    if masked:
+        update(False)
+    else:
+        below = (kb + 1) * block_k - 1 <= qi * block_q  # every pair of the tile is causal
+        pl.when(below)(lambda: update(False))
+        pl.when(jnp.logical_not(below))(lambda: update(True))
 
     @pl.when(kb == ((qi + 1) * block_q - 1) // block_k)  # the row's last tile
     def _finalize():
@@ -332,8 +350,8 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
 
 
 def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool,
-                      q_shared=None, k_shared=None):
-    """The maskless form of :func:`masked_gqa_attention`. Operands go head
+                      q_shared=None, k_shared=None, mask=None):
+    """The batched form of :func:`masked_gqa_attention`. Operands go head
     major (``[B, G, H/G, S, d]`` and ``[B, G, S, d]``: a block's last
     dimension is then the whole head width, which Mosaic takes at 64 where
     a 64-lane block of ``[S, G*64]`` it does not), and the grid's last axis
@@ -343,14 +361,26 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     -> ``o [B, S, H*dv]``). With ``q_shared [B, S, H*ds]`` and ``k_shared
     [B, S, ds]`` a score is ``q . k + q_shared . k_shared``: the shared
     key's tile is read from its one array, once a grid step, for every
-    head (latent attention's one rotary key)."""
+    head (latent attention's one rotary key). With ``mask`` (the selection
+    of ONE sequence, from :func:`select_keys`) every tile at or below the
+    diagonal is still visited, and a pair counts where the mask says so: the
+    key tile is the mask's own, the query tile the largest multiple of the
+    mask's that divides ``S`` and is at most ``block_q`` (8,704 = 17 x 512:
+    512 x 512 under masks of 128 x 512), and the mask's tile is read once
+    a grid step, so once a HEAD."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, hd = q.shape
     d, dv = k.shape[2] // g, v.shape[2] // g
     rep = hd // (g * d)
-    shared = q_shared is not None
+    shared, masked = q_shared is not None, mask is not None
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
+    if masked:
+        n_qb, n_kb, mq, bk = mask.shape
+        if b != 1 or n_qb * mq != s or n_kb * bk != s:
+            raise ValueError(f"a mask {mask.shape} selects the keys of one sequence of "
+                             f"{n_qb * mq}: not of {b} of {s}")
+        bq = next(t for t in range(max(min(block_q, s) // mq, 1) * mq, 0, -mq) if s % t == 0)
     pairs = [(i, j) for i in range(s // bq) for j in range(((i + 1) * bq - 1) // bk + 1)]
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
 
@@ -372,8 +402,12 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         operands += [q_major(q_shared, ds), k_shared]
         in_specs += [q_spec(ds),
                      pl.BlockSpec((None, bk, ds), lambda bi, gi, t, qi, kb: (bi, kb[t], 0))]
+    if masked:
+        operands.append(mask)
+        in_specs.append(pl.BlockSpec((bq // mq, None, mq, bk),
+                                     lambda bi, gi, t, qi, kb: (qi[t], kb[t], 0, 0)))
     o5 = pl.pallas_call(
-        functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared),
+        functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g, len(pairs)),
             in_specs=in_specs, out_specs=q_spec(dv),
@@ -402,19 +436,21 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     is computed. ``block_q`` (a multiple of the mask's query tile, which is
     the default) is this kernel's own query tile.
 
-    ``mask=None``: plain causal attention of a BATCH of sequences, ``q [B,
-    S, H*d]`` and ``k, v [B, S, G*d]`` -> ``[B, S, H*d]``, each sequence
-    on its own, in tiles of ``block_q`` (default 256) by ``block_k``; only
-    tiles at or below the diagonal are visited (:func:`_causal_attention`).
-    There the value heads may have a width of their own (``v [B, S,
-    G*dv]`` -> ``[B, S, H*dv]``), and a score may have a second part,
-    ``q_shared [B, S, H*ds] . k_shared [B, S, ds]``, whose key is ONE for
-    all heads (latent attention: the rotary key)."""
-    if mask is None:
+    The BATCHED form (``q [B, S, H*d]`` and ``k, v [B, S, G*d]`` -> ``[B,
+    S, H*d]``; :func:`_causal_attention`): each sequence on its own, in
+    tiles of ``block_q`` (default 256) by ``block_k``, only tiles at or
+    below the diagonal visited. Without a mask it is plain causal
+    attention. There the value heads may have a width of their own (``v
+    [B, S, G*dv]`` -> ``[B, S, H*dv]``), and a score may have a second
+    part, ``q_shared [B, S, H*ds] . k_shared [B, S, ds]``, whose key is ONE
+    for all heads (latent attention: the rotary key). With a mask (``B``
+    1: a selection is one sequence's) it is the selection over latent
+    attention, masked-dense as the form above, in the mask's key tile."""
+    if mask is None or q.ndim == 3:
         return _causal_attention(q, k, v, int(num_kv_heads), block_q or 256, block_k,
-                                 _interpret(interpret), q_shared, k_shared)
+                                 _interpret(interpret), q_shared, k_shared, mask)
     if q_shared is not None or v.shape[1] != k.shape[1]:
-        raise ValueError("a shared key part and a value width of its own are the maskless form's")
+        raise ValueError("a shared key part and a value width of its own are the batched form's")
     from jax.experimental.pallas import tpu as pltpu
 
     n_qb, n_kb, mq, bk = mask.shape

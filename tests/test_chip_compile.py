@@ -320,7 +320,54 @@ def _kimi_experts():
                 S((12, 2048, KIMI_D), BF16)], 3, held_rows_only
 
 
+DSV32_S = 8704  # one frame of 8,448 patches + 256 prompt tokens
+
+
+def _dsv32_select():
+    """The selection at DeepSeek-V3.2's indexer: 64 index heads of 128 over
+    one sequence of 8,704, each query's 2,048 best keys, masks of 128 x 512
+    (17 key tiles a row): a query tile's 64 index queries (2 MB), the whole
+    index key and the tile's score row stay in VMEM."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    def fn(q, k, w):
+        return sa.select_keys(q, k, w, topk=2048, block_q=128, block_k=512, interpret=False)
+
+    def mask_in_tiles(text):
+        assert f"s8[{DSV32_S // 128},{DSV32_S // 512},128,512]" in text
+
+    return fn, [S((64, DSV32_S, 128), BF16), S((DSV32_S, 128), BF16), S((DSV32_S, 64), F32)], 1, \
+        mask_in_tiles
+
+
+def _dsv32_attention():
+    """Latent attention under the selection's mask at DeepSeek-V3.2's heads:
+    128 heads of 128 + 64 against the ONE rotary key, values 128 wide, one
+    sequence of 8,704 in 512 x 512 tiles (the mask's key tile; the largest
+    multiple of its query tile under 1,088 that divides 8,704). ONE Pallas
+    call, and the mask is read in the layout ``select_keys`` wrote: no
+    ``[8704, 8704]`` copy of it exists."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    def fn(q, k, v, q_rope, k_rope, mask):
+        return sa.masked_gqa_attention(q, k, v, mask, num_kv_heads=128, block_q=1088, block_k=1088,
+                                       q_shared=q_rope, k_shared=k_rope, interpret=False)
+
+    wide = S((1, DSV32_S, 128 * 128), BF16)
+
+    def one_call_and_no_relaid_mask(text):
+        kernels = re.findall(r'custom_call_target="tpu_custom_call"', text)
+        assert len(kernels) == 1, kernels
+        assert f"s8[{DSV32_S},{DSV32_S}]" not in text and f"[1,{DSV32_S},{128 * 192}]" not in text
+
+    return fn, [wide, wide, wide, S((1, DSV32_S, 128 * 64), BF16), S((1, DSV32_S, 64), BF16),
+                S((DSV32_S // 128, DSV32_S // 512, 128, 512), jnp.int8)], 1, \
+        one_call_and_no_relaid_mask
+
+
 CASES = {
+    "dsv32_select_keys_8704x64x128": _dsv32_select,
+    "dsv32_masked_latent_attention_1x8704x128x192": _dsv32_attention,
     "kimi_latent_attention_2x8704x64x192": _kimi_attention,
     "kimi_held_experts_17408x8_12_of_384": _kimi_experts,
     "lfm2_causal_gqa_attention_4x8704x64": _lfm2_attention,
@@ -439,7 +486,7 @@ def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
 
 # sha256 of the served step's lowered text (StableHLO), the kernels' serialized bodies cut out
 # (they carry file paths and line numbers): what `decoder.frame_step` traces to for the two
-# decoders the benchmark had before PR 42. Pinned on PR 41's tree first (PR 42's trunk lowered
+# decoders the benchmark had before PR 42 (and, since PR 46, for PR 42's own). Pinned on PR 41's tree first (PR 42's trunk lowered
 # to it), then again in PR 42, knowingly: with every share sent to `_held_rows_moe`, the
 # all-held path lost what it did for a share (the held mask and its `where` on the gates, the
 # grouped product's group offset of 0, the slice of the per-expert counts); the device times
@@ -447,6 +494,8 @@ def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
 # one of these programs re-pins it, knowingly: an equal text is an equal key in the compile
 # cache, and a decoder cell's warm `setup_s` (bound 0.1) pays seconds for anything new to trace
 PINNED_STEPS = {
+    # pinned on PR 43's tree in PR 46, which put a selection's mask on latent attention's path
+    "kimi_k2_prefill_epix10k2m": "0aeb2c16eef4e9c0db8dbde6350c2b3bf9ec25b7811103c19653c4f7a8ec0b98",
     "keye_vl2_prefill_epix10k2m": "7ba74ce99ef580a3c7965ffc0a69c7388c5b3088a8b3cee64ab9e1dd8a69af1f",
     "lfm2_8b_a1b_prefill_epix10k2m": "7434bf59d9d3941cfe175dc4d0ec2e51f5f336d0b2a010924095d61446a8dd9d",
 }

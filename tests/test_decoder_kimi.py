@@ -140,12 +140,14 @@ def test_latent_attention_layer_is_the_reference_operator_for_a_batch_of_two():
                       (2, 1))
     sizes = ref.sizes(m)
     with jax.default_matmul_precision("highest"):
-        got = decoder.latent_attention(p, x, angles, 2, cfg) - x
+        out, live, causal = decoder.latent_attention(p, x, angles, 2, cfg)
+        got = out - x
         want = jnp.concatenate([
             ref.latent_attention(p, ref.rms(x[i * 64:(i + 1) * 64], p["norm1"], 1e-6), sizes,
                                  jnp.float32, 16) for i in range(2)])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
     assert "q_norm" not in p and "k_norm" not in p  # no per-head norm in this operator
+    assert live == causal == 2  # no selection: every tile of both sequences is attended
 
 
 @pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16), (48, 32), (96, 96)])
@@ -176,13 +178,8 @@ def test_causal_kernel_with_a_shared_key_part_and_values_of_another_width(bq, bk
     np.testing.assert_allclose(np.asarray(got), np.asarray(plain), atol=3e-6)
 
 
-def test_a_mask_goes_with_neither_a_shared_key_nor_a_value_width_of_its_own():
-    z = jnp.zeros((16, 32), jnp.float32)
-    mask = jnp.ones((1, 1, 16, 16), jnp.int8)
-    with pytest.raises(ValueError, match="maskless"):
-        sa.masked_gqa_attention(z, z, z[:, :16], mask, num_kv_heads=2)
-    with pytest.raises(ValueError, match="maskless"):
-        sa.masked_gqa_attention(z, z, z, mask, num_kv_heads=2, q_shared=z, k_shared=z[:, :8])
+# (a mask WITH a shared key part and a value width of its own is the selection over latent
+# attention: tests/test_decoder_dsv32.py)
 
 
 # ---------------------------------------------------------------------------
@@ -461,12 +458,13 @@ def test_every_metric_file_of_the_kimi_cell_names_a_reader_and_keys_that_exist(n
 def test_the_kimi_cell_reports_the_host_path_under_the_names_the_other_decoders_have(name):
     manifest = _manifest()
     entry, = [e for e in manifest["per_layer"] + manifest["end_to_end"] if e["name"] == name]
-    assert entry["workloads"][-3:] == ["keye_epix_saturated", "lfm2_epix_saturated", CELL]
+    at = entry["workloads"].index(CELL)  # later cells are appended after it
+    assert entry["workloads"][at - 2:at + 1] == ["keye_epix_saturated", "lfm2_epix_saturated", CELL]
     calib, = [e for e in manifest["per_layer"] if e["name"] == "calib_roofline_share.hit"]
     assert calib["workloads"] == ["hit_epix_saturated"]  # PERF.md section 7 (b)
     cell, = [w for w in manifest["workloads"] if w["name"] == CELL]
     assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "saturated", "kimi_k2_prefill_epix10k2m")
-    assert manifest["workloads"][-1] is cell and manifest["configs"][-1]["name"] == cell["config"]
+    assert cell["config"] in [c["name"] for c in manifest["configs"]]
 
 
 def test_kimi_roofline_counts_at_the_published_sizes():

@@ -173,11 +173,16 @@ class DecoderConfig:
     # at 256 (512 needs 70 MB of VMEM for a tile's score row), attention 124
     # ms at 256 against 146 at 128 and 128 at 512
     q_tile: int = 128  # of the selection kernel (a query tile's whole score row sits in VMEM)
-    kv_tile: int = 512  # sa_config's kv_chunk_size
+    kv_tile: int = 512  # sa_config's kv_chunk_size: the pieces the selection scores and counts in
     attn_q_tile: int = 256  # of the attention kernel, a multiple of q_tile
-    # of the maskless causal kernel (no selection: q_tile and kv_tile do not bind it). On the v5e
-    # at 4 x 8,704 tokens, heads of 64: 1088 x 1088 25.5 ms a layer, 512 x 1088 27.0, 256 x 2176
-    # 27.3, 512 x 512 33.1, 256 x 512 40.5, 256 x 256 74.6 (my chip runs, PR 38)
+    # of the batched causal kernel. Without a selection q_tile and kv_tile do not bind it. On the
+    # v5e at 4 x 8,704 tokens, heads of 64: 1088 x 1088 25.5 ms a layer, 512 x 1088 27.0, 256 x
+    # 2176 27.3, 512 x 512 33.1, 256 x 512 40.5, 256 x 256 74.6 (my chip runs, PR 38). Under a
+    # selection (latent attention) the key tile is the one `select_keys` WROTE its mask in, which
+    # `sparse_attention.mask_tile` picks from S for this kernel (2,176 at 8,704 tokens; kv_tile
+    # where S has no wider whole-lane divisor), and the query tile the largest multiple of q_tile
+    # under causal_q_tile that divides S: 512 x 2,176 at 128 heads of 128 + 64, 34.8 ms a layer
+    # where the 512 x 512 that kv_tile used to force took 43.4 (my chip runs, PR 47)
     causal_q_tile: int = 1088
     causal_kv_tile: int = 1088
     # experts (num_experts 0: a dense gated MLP of intermediate_size)
@@ -570,7 +575,7 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
         with jax.named_scope("indexer"):
             mask, flags = jax.jit(_indexer, static_argnums=3)(p, read[0], idx_angles, cfg,
                                                               q_from=read[1])
-            live, causal = sa.live_tiles(flags, mask.shape[2], mask.shape[3])
+            live, causal = sa.live_tiles(flags, s)
             selection = (mask,)
     else:
         live = causal = batch * sa.causal_tile_count(s)  # every earlier key is attended
@@ -602,7 +607,7 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
             raise ValueError(f"a learned key selection is per sequence: batch {batch} is not 1")
         with jax.named_scope("indexer"):
             mask, flags = jax.jit(_indexer, static_argnums=3)(p, a, idx_angles, cfg)
-            live, causal = sa.live_tiles(flags, mask.shape[2], mask.shape[3])
+            live, causal = sa.live_tiles(flags, s)
         with jax.named_scope("sparse_attn"):
             o = jax.jit(sa.masked_gqa_attention, static_argnames=("num_kv_heads", "block_q"))(
                 q, k, v, mask, num_kv_heads=cfg.num_kv_heads,

@@ -21,8 +21,11 @@ What is here, and what it is:
   32-step bisection over the scores' bit patterns (no sort, no
   ``lax.top_k``: a k of 2048 over 34,304 is a sort on the TPU), ties at
   that score are cut at the key index that ``Sel`` would cut them at, and
-  the selection leaves as an int8 mask ``[S/bq, S/bk, bq, bk]`` (tile
-  major, so that the attention kernel's mask tile is one block).
+  the selection leaves as an int8 mask ``[S/bq, S/mk, bq, mk]`` (tile
+  major, so that the attention kernel's mask tile is one block). The key
+  tile ``mk`` it is WRITTEN in is the attention's, chosen for that
+  kernel's speed (:func:`mask_tile`), not the ``bk`` the selection scores
+  and counts in.
 - :func:`masked_gqa_attention` — a flash kernel over ALL causal tiles
   that applies that mask: the MASKED-DENSE form. It does the work of
   dense causal attention (``S^2/2`` pairs a head) whatever the selection;
@@ -37,7 +40,8 @@ What is here, and what it is:
   the score read from ONE key for all heads (latent attention). Without a
   mask it is plain causal attention over a batch of sequences; with one
   (one sequence) it is the selection over LATENT attention, masked-dense
-  again: the mask's tile is one more operand of a grid step.
+  again: the mask's tile is one more operand of a grid step, and its key
+  tile is the kernel's.
 - :func:`live_tiles` — how many ``stat_tile`` x ``stat_tile`` tiles at
   or below the diagonal hold a selected pair (from the flags the selection
   kernel writes beside its mask), and how many there are: what a
@@ -49,6 +53,7 @@ Off the TPU the kernels run in Pallas interpret mode (tests, rehearsals).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -58,6 +63,8 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 INT_MIN = -(2**31)
 _VMEM_LIMIT = 100 * 1024 * 1024  # of the v5e's 128 MiB; the default scope is 16
+MASK_TILE = 2176  # the widest key tile a selection's mask is written in (:func:`mask_tile`): on the
+# v5e the kernel under it read 34.8 ms a layer at 512 x 2,176 and 39.4 at 256 x 4,352 (PR 47)
 
 
 def pick_tile(s: int, want: int) -> int:
@@ -70,6 +77,16 @@ def pick_tile(s: int, want: int) -> int:
         if s % b == 0:
             return b
     return s
+
+
+def mask_tile(s: int, block_k: int) -> int:
+    """The key tile a selection's mask is WRITTEN in, which is the key tile
+    the attention under it runs in: the widest whole number of 128-lane
+    blocks that divides ``s`` up to :data:`MASK_TILE` (8,704 = 68 x 128 ->
+    2,176 = 17 x 128; 34,304 = 268 x 128 -> 512, the selection's own
+    pieces), and the pieces' ``block_k`` where ``s`` is no whole number of
+    lane blocks (small test sizes)."""
+    return pick_tile(s, MASK_TILE) if s % 128 == 0 else block_k
 
 
 def _interpret(interpret: Optional[bool]) -> bool:
@@ -87,7 +104,7 @@ def sortable_key(x: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _select_kernel(q_ref, k_ref, w_ref, mask_ref, live_ref, keys_ref, *, topk, scale, block_q,
-                   block_k, n_kb):
+                   block_k, n_kb, mask_k):
     qi = pl.program_id(0)
     n_heads = q_ref.shape[0]
     row0 = qi * block_q
@@ -163,36 +180,48 @@ def _select_kernel(q_ref, k_ref, w_ref, mask_ref, live_ref, keys_ref, *, topk, s
 
     cut = jax.lax.cond(jnp.max(excess) > 0, find_cut, keep_all, 0)
 
+    # the mask leaves in key tiles of mask_k, a piece in slices of what the two widths share
+    # (512-wide pieces in 2,176-wide tiles: four 128-lane slices, the last of every fifth
+    # piece in the next tile); the flags stay the pieces' own
     live_ref[...] = jnp.zeros(live_ref.shape, jnp.int32)
+    mask_ref[...] = jnp.zeros(mask_ref.shape, jnp.int8)
+    lanes = math.gcd(block_k, mask_k)
     for kb in range(n_kb):
         @pl.when(kb < n_live)
         def _live(kb=kb):
             k = keys_ref[kb]
             sel = ((k > thr) | ((k == thr) & (cols0 + kb * block_k < cut))).astype(jnp.int32)
-            mask_ref[0, kb] = sel.astype(jnp.int8)
             live_ref[0, :, kb:kb + 1] = jnp.max(sel, axis=(0, 1), keepdims=True)
-
-        @pl.when(kb >= n_live)
-        def _dead(kb=kb):
-            mask_ref[0, kb] = jnp.zeros((block_q, block_k), jnp.int8)
+            sel = sel.astype(jnp.int8)
+            for c in range(0, block_k, lanes):
+                tile, at = divmod(kb * block_k + c, mask_k)
+                mask_ref[0, tile, :, at:at + lanes] = sel[:, c:c + lanes]
 
 
 def select_keys(q_idx, k_idx, w_idx, *, topk: int, block_q: int = 128, block_k: int = 512,
+                mask_k: Optional[int] = None,
                 interpret: Optional[bool] = None) -> Tuple[jax.Array, jax.Array]:
     """``q_idx [H_I, S, d_I]`` and ``k_idx [S, d_I]`` (after their rotary
     and norm), ``w_idx [S, H_I]`` float32 -> the selection as an int8 mask
-    ``[S/bq, S/bk, bq, bk]``: entry ``[a, b, i, j]`` is 1 iff key
-    ``b*bk + j`` is in ``Sel(a*bq + i)``; and which of its tiles hold a
-    selected pair, int32 ``[S/bq, S/bk]`` (reducing the mask for that
-    afterwards took 38 ms a layer on the v5e: my chip run, PR 36)."""
+    ``[S/bq, S/mk, bq, mk]``: entry ``[a, b, i, j]`` is 1 iff key
+    ``b*mk + j`` is in ``Sel(a*bq + i)``; and which ``bq x bk`` pieces of
+    it hold a selected pair, int32 ``[S/bq, S/bk]`` (reducing the mask for
+    that afterwards took 38 ms a layer on the v5e: my chip run, PR 36).
+    The kernel scores, counts and flags in pieces of ``bk`` keys; the
+    mask's own key tile ``mk`` (``mask_k``, by default :func:`mask_tile`'s:
+    2,176 at 8,704 keys, ``bk`` itself at 34,304) is the attention's to
+    run in, and only the last write knows it."""
     from jax.experimental.pallas import tpu as pltpu
 
     n_heads, s, d = q_idx.shape
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
+    mk = mask_tile(s, bk) if mask_k is None else int(mask_k)
+    if s % mk:
+        raise ValueError(f"the mask's key tile {mk} does not divide the {s} keys")
     n_qb, n_kb = s // bq, s // bk
     w = jnp.transpose(w_idx.astype(jnp.float32))[:, :, None]  # [H_I, S, 1]
     kernel = functools.partial(_select_kernel, topk=int(topk), scale=float(d) ** -0.5,
-                               block_q=bq, block_k=bk, n_kb=n_kb)
+                               block_q=bq, block_k=bk, n_kb=n_kb, mask_k=mk)
     lanes = -(-n_kb // 128) * 128
     mask, live = pl.pallas_call(
         kernel,
@@ -202,9 +231,9 @@ def select_keys(q_idx, k_idx, w_idx, *, topk: int, block_q: int = 128, block_k: 
             pl.BlockSpec((s, d), lambda i: (0, 0)),
             pl.BlockSpec((n_heads, bq, 1), lambda i: (0, i, 0)),
         ],
-        out_specs=[pl.BlockSpec((1, n_kb, bq, bk), lambda i: (i, 0, 0, 0)),
+        out_specs=[pl.BlockSpec((1, s // mk, bq, mk), lambda i: (i, 0, 0, 0)),
                    pl.BlockSpec((1, 1, lanes), lambda i: (i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_qb, n_kb, bq, bk), jnp.int8),
+        out_shape=[jax.ShapeDtypeStruct((n_qb, s // mk, bq, mk), jnp.int8),
                    jax.ShapeDtypeStruct((n_qb, 1, lanes), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((n_kb, bq, bk), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
@@ -229,18 +258,19 @@ def causal_tile_count(s: int, stat_tile: int = 512) -> int:
     return n * (n + 1) // 2
 
 
-def live_tiles(live: jax.Array, block_q: int, block_k: int,
-               stat_tile: int = 512) -> Tuple[jax.Array, int]:
+def live_tiles(live: jax.Array, s: int, stat_tile: int = 512) -> Tuple[jax.Array, int]:
     """``(live, causal)``: of the ``stat_tile``-square tiles at or below
-    the diagonal, how many hold a selected pair (int32 scalar, on the
-    device) and how many there are (static), from the ``[S/bq, S/bk]``
-    flags of the mask's own tiles."""
+    the diagonal of a sequence of ``s``, how many hold a selected pair
+    (int32 scalar, on the device) and how many there are (static), from
+    :func:`select_keys`' ``[S/bq, S/bk]`` flags of its own pieces (whatever
+    key tile the mask beside them was written in)."""
     n_qb, n_kb = live.shape
-    tile = pick_tile(n_qb * block_q, stat_tile)
+    block_q, block_k = s // n_qb, s // n_kb
+    tile = pick_tile(s, stat_tile)
     if tile % block_q or tile % block_k:
         raise ValueError(f"the statistics' tile {tile} is no multiple of the mask's "
                          f"{block_q} x {block_k}")
-    n = n_qb * block_q // tile
+    n = s // tile
     big = jnp.any(live.reshape(n, tile // block_q, n, tile // block_k) != 0, axis=(1, 3))
     return jnp.sum(big.astype(jnp.int32)), n * (n + 1) // 2
 
@@ -364,10 +394,16 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     head (latent attention's one rotary key). With ``mask`` (the selection
     of ONE sequence, from :func:`select_keys`) every tile at or below the
     diagonal is still visited, and a pair counts where the mask says so: the
-    key tile is the mask's own, the query tile the largest multiple of the
-    mask's that divides ``S`` and is at most ``block_q`` (8,704 = 17 x 512:
-    512 x 512 under masks of 128 x 512), and the mask's tile is read once
-    a grid step, so once a HEAD."""
+    key tile is the mask's own (:func:`mask_tile` chose it for THIS
+    kernel), the query tile the largest multiple of the mask's that divides
+    ``S`` and is at most ``block_q``: 512 x 2,176 under masks of 128 x
+    2,176 at 8,704 tokens, 44 grid steps a head. The mask's tile is read
+    once a grid step, so once a HEAD. One layer's kernel at 128 heads of
+    128 + 64 on the v5e (my chip runs, PR 47), ms: 512 x 2,176 34.8, 256 x
+    2,176 36.2, 256 x 4,352 39.4, 512 x 512 (the mask written in the
+    selection's 512-wide pieces, as it was until PR 47) 43.4, 2,176 x 512
+    54.9; WITHOUT a mask 512 x 512 43.1 and 1,088 x 1,088 32.7: the tile
+    is what a mask costs, its convert, compare and select 0.35."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, hd = q.shape

@@ -325,28 +325,35 @@ DSV32_S = 8704  # one frame of 8,448 patches + 256 prompt tokens
 
 def _dsv32_select():
     """The selection at DeepSeek-V3.2's indexer: 64 index heads of 128 over
-    one sequence of 8,704, each query's 2,048 best keys, masks of 128 x 512
-    (17 key tiles a row): a query tile's 64 index queries (2 MB), the whole
+    one sequence of 8,704, each query's 2,048 best keys, scored and counted
+    in pieces of 128 x 512 (17 a row, whose flags leave as they did) and
+    WRITTEN as a mask of 128 x 2,176 (four key tiles a row: 17 of the 68
+    lane blocks each, ``sparse_attention.mask_tile``'s choice for the
+    attention under it): a query tile's 64 index queries (2 MB), the whole
     index key and the tile's score row stay in VMEM."""
     from psana_ray_tpu.parallel import sparse_attention as sa
 
     def fn(q, k, w):
         return sa.select_keys(q, k, w, topk=2048, block_q=128, block_k=512, interpret=False)
 
-    def mask_in_tiles(text):
-        assert f"s8[{DSV32_S // 128},{DSV32_S // 512},128,512]" in text
+    def mask_in_the_attention_s_tiles(text):
+        assert f"s8[{DSV32_S // 128},{DSV32_S // 2176},128,2176]" in text
+        assert f"s8[{DSV32_S // 128},{DSV32_S // 512},128,512]" not in text
+        assert f"s32[{DSV32_S // 128},{DSV32_S // 512}]" in text  # the pieces' flags
 
     return fn, [S((64, DSV32_S, 128), BF16), S((DSV32_S, 128), BF16), S((DSV32_S, 64), F32)], 1, \
-        mask_in_tiles
+        mask_in_the_attention_s_tiles
 
 
 def _dsv32_attention():
     """Latent attention under the selection's mask at DeepSeek-V3.2's heads:
     128 heads of 128 + 64 against the ONE rotary key, values 128 wide, one
-    sequence of 8,704 in 512 x 512 tiles (the mask's key tile; the largest
-    multiple of its query tile under 1,088 that divides 8,704). ONE Pallas
-    call, and the mask is read in the layout ``select_keys`` wrote: no
-    ``[8704, 8704]`` copy of it exists."""
+    sequence of 8,704 in 512 x 2,176 tiles (the key tile the mask was
+    written in; the largest multiple of its query tile under 1,088 that
+    divides 8,704): 44 pairs of tiles at or below the diagonal a head, the
+    length of the table the grid reads (153 at 512 x 512, until PR 47).
+    ONE Pallas call, and the mask is read in the layout ``select_keys``
+    wrote: no ``[8704, 8704]`` copy of it exists."""
     from psana_ray_tpu.parallel import sparse_attention as sa
 
     def fn(q, k, v, q_rope, k_rope, mask):
@@ -354,15 +361,18 @@ def _dsv32_attention():
                                        q_shared=q_rope, k_shared=k_rope, interpret=False)
 
     wide = S((1, DSV32_S, 128 * 128), BF16)
+    mask_k = sa.mask_tile(DSV32_S, 512)
 
-    def one_call_and_no_relaid_mask(text):
+    def one_call_in_wide_tiles_and_no_relaid_mask(text):
         kernels = re.findall(r'custom_call_target="tpu_custom_call"', text)
         assert len(kernels) == 1, kernels
         assert f"s8[{DSV32_S},{DSV32_S}]" not in text and f"[1,{DSV32_S},{128 * 192}]" not in text
+        assert mask_k == 2176 and f"s8[{DSV32_S // 128},4,128,2176]" in text
+        assert "s32[44]" in text and "s32[153]" not in text  # the (query tile, key tile) table
 
     return fn, [wide, wide, wide, S((1, DSV32_S, 128 * 64), BF16), S((1, DSV32_S, 64), BF16),
-                S((DSV32_S // 128, DSV32_S // 512, 128, 512), jnp.int8)], 1, \
-        one_call_and_no_relaid_mask
+                S((DSV32_S // 128, DSV32_S // mask_k, 128, mask_k), jnp.int8)], 1, \
+        one_call_in_wide_tiles_and_no_relaid_mask
 
 
 CASES = {

@@ -181,6 +181,56 @@ def test_sel_is_the_reference_s_sets_at_64_index_heads_and_a_partial_rotary(case
         assert any(np.flatnonzero(got[t])[0] < t - 15 for t in range(16, s))
 
 
+@pytest.mark.parametrize("case", ["random_scores", "tied_scores", "within_topk_is_dense"])
+def test_the_wide_mask_is_the_same_sel_bit_for_bit_and_the_flags_stay_the_pieces_own(case):
+    """8,704's geometry in small, a block of 4 keys standing for 128 lanes:
+    272 keys = 68 blocks, scored, counted and flagged in pieces of 4 blocks
+    (16 keys: the 512), the mask WRITTEN in key tiles of 17 blocks (68
+    keys: the 2,176), so every fifth piece ends in the next tile. The
+    dense mask of that layout is the piece-wide layout's and the
+    reference's ``Sel`` bit for bit, ties included; the flags are the same
+    array, and ``live_tiles`` reads the same two numbers from it."""
+    rng = np.random.default_rng(47)
+    s, heads, d = 272, 4, 16
+    topk = {"random_scores": 24, "tied_scores": 24, "within_topk_is_dense": 512}[case]
+    q = jnp.asarray(rng.standard_normal((heads, s, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((s, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((s, heads)), jnp.float32)
+    if case == "tied_scores":  # every key one of five vectors: a query's scores tie in runs
+        k, w = k[jnp.arange(s) % 5], jnp.abs(w)
+    narrow, flags = sa.select_keys(q, k, w, topk=topk, block_q=8, block_k=16, mask_k=16)
+    wide, wide_flags = sa.select_keys(q, k, w, topk=topk, block_q=8, block_k=16, mask_k=68)
+    assert narrow.shape == (34, 17, 8, 16) and wide.shape == (34, 4, 8, 68)
+    assert flags.shape == wide_flags.shape == (34, 17)
+    got = np.asarray(sa.mask_to_dense(wide))
+    np.testing.assert_array_equal(got, np.asarray(sa.mask_to_dense(narrow)))
+    dots = jnp.einsum("htd,sd->ths", q, k, precision="highest")
+    scores = jnp.sum(w[:, :, None] * jax.nn.relu(dots), axis=1) / np.sqrt(d)
+    np.testing.assert_array_equal(got, np.asarray(ref_select(scores, jnp.arange(s), topk)))
+    np.testing.assert_array_equal(got.sum(axis=1), np.minimum(np.arange(s) + 1, topk))
+    np.testing.assert_array_equal(np.asarray(flags), np.asarray(wide_flags))
+    np.testing.assert_array_equal(np.asarray(flags) != 0, np.asarray(narrow).any(axis=(2, 3)))
+    live, causal = sa.live_tiles(wide_flags, s, stat_tile=16)
+    assert causal == 17 * 18 // 2
+    tiles = got.reshape(17, 16, 17, 16).any(axis=(1, 3))  # 16 x 16 tiles with a selected pair
+    assert int(live) == int(tiles.sum()) == int(sa.live_tiles(flags, s, stat_tile=16)[0])
+    if case == "tied_scores":
+        best = np.flatnonzero(got[s - 1])
+        assert len(set(best % 5)) == 1 and (best == best[0] + 5 * np.arange(24)).all()
+    if case == "within_topk_is_dense":
+        assert int(live) == causal
+
+
+@pytest.mark.parametrize("s,pieces,want", [
+    (8704, 512, 2176),    # 68 lane blocks: 17 of them, the widest under the limit
+    (34304, 512, 512),    # 268 = 4 x 67 lane blocks: keye's pieces are its mask's tile, as they were
+    (17408, 512, 2176), (4352, 512, 2176), (2048, 512, 2048), (8704, 128, 2176),
+    (96, 32, 32), (64, 32, 32), (272, 16, 16),  # no whole lane block: the pieces' own
+])
+def test_the_mask_s_key_tile_is_one_rule_of_the_shape(s, pieces, want):
+    assert sa.mask_tile(s, pieces) == want and s % want == 0
+
+
 def test_the_two_indexers_differ_by_fields_and_keye_s_are_the_defaults():
     with open(os.path.join(REPO, "benchmark", "configs", "keye_vl2_prefill_epix10k2m.json")) as f:
         keye = decoder.DecoderConfig.from_mapping(json.load(f))
@@ -205,12 +255,18 @@ def test_the_two_indexers_differ_by_fields_and_keye_s_are_the_defaults():
 # the causal kernel under a mask, with a shared key part and values of another width
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("mq,bk,bq", [(16, 32, 16), (16, 32, 48), (32, 16, 96), (96, 96, 96),
-                                      (16, 48, 1088)])
+@pytest.mark.parametrize("s,mq,bk,bq", [
+    (96, 16, 32, 16), (96, 16, 32, 48), (96, 32, 16, 96), (96, 96, 96, 96), (96, 16, 48, 1088),
+    # 8,704's geometry under a mask written 2,176 wide, a block of 4 keys for 128 lanes: 68
+    # blocks, key tiles of 17, query tiles of 4 (17 of them, 44 pairs of tiles a head) or of
+    # 2; one query tile over all four key tiles; two key tiles of 17 under query tiles of 1
+    (272, 8, 68, 16), (272, 8, 68, 8), (272, 4, 68, 1088), (136, 4, 68, 4),
+])
 @pytest.mark.parametrize("rep", [1, 2])
-def test_masked_causal_kernel_with_a_shared_key_part_and_values_of_another_width(mq, bk, bq, rep):
+def test_masked_causal_kernel_with_a_shared_key_part_and_values_of_another_width(s, mq, bk, bq,
+                                                                                 rep):
     rng = np.random.default_rng(mq + bk + rep)
-    s, g, d, ds, dv = 96, 2, 16, 8, 24
+    g, d, ds, dv = 2, 16, 8, 24
     h = g * rep
     q = jnp.asarray(rng.standard_normal((1, s, h * d)), jnp.float32) * 0.3
     qs = jnp.asarray(rng.standard_normal((1, s, h * ds)), jnp.float32) * 0.3
@@ -239,6 +295,33 @@ def test_masked_causal_kernel_with_a_shared_key_part_and_values_of_another_width
                                            q_shared=qs, k_shared=ks)),
         np.asarray(sa.masked_gqa_attention(q, k, v, num_kv_heads=g, block_q=32, block_k=32,
                                            q_shared=qs, k_shared=ks)), atol=3e-6)
+
+
+def test_latent_attention_runs_in_the_key_tile_the_rule_wrote_its_mask_in():
+    """The operator end to end where the rule widens the tile: 384 tokens
+    are three 128-lane blocks, the selection scores and flags them in
+    pieces of one, ``mask_tile`` writes ONE key tile of three, and the
+    attention under it runs in 32 x 384 (no configuration field says so)
+    and is the reference's, with the statistics read from the pieces' flags."""
+    m = mapping(index_topk=48)
+    cfg = dataclasses.replace(small(m), kv_tile=128)
+    s = 384
+    p = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))["layers"][0]
+    x = jnp.asarray(np.random.default_rng(5).standard_normal((s, 64)), jnp.float32)
+    angles = decoder.rotary_angles(np.arange(s), cfg.rope_theta, 4, None, cfg.rope_yarn)
+    with jax.default_matmul_precision("highest"):
+        a = decoder.rms_norm(x, p["norm1"], 1e-6)
+        c_q = decoder.rms_norm(a @ p["wq_a"], p["q_a_norm"], 1e-6)
+        mask, flags = decoder._indexer(p, a, angles, cfg, q_from=c_q)
+        got, live, causal = decoder.latent_attention(p, x, angles, 1, cfg, angles)
+        want, sel = ref.latent_attention(p, ref.rms(x, p["norm1"], 1e-6), ref.sizes(m),
+                                         jnp.float32, 32, with_sel=True)
+    assert mask.shape == (24, 1, 16, 384) and flags.shape == (24, 3)
+    np.testing.assert_array_equal(np.asarray(sa.mask_to_dense(mask)), np.asarray(sel))
+    want = x + want
+    scale = float(jnp.sqrt(jnp.mean(want ** 2)))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4 * scale, rtol=0)
+    assert (int(live), causal) == (1, 1)  # one 384 x 384 statistics tile
 
 
 def test_a_mask_is_one_sequence_s_and_the_plain_form_keeps_its_own_shapes():

@@ -519,14 +519,30 @@ def gated_short_conv(p, x, batch: int, cfg: DecoderConfig):
 
 
 def _latent_projections(p, x, angles, cfg: DecoderConfig):
-    """``x [T, D]`` -> ``(q_nope [T, H*dn], q_rope [T, H*dr], k_nope [T,
-    H*dn], k_rope [T, dr], v [T, H*dv])``, and under a key selection the
-    normed input and the normed query rank ``(a [T, D], c_q [T, rq])``
-    after them: the queries through their normed
-    low rank, the keys' and values' per-head parts decompressed from the
-    normed latent, the ONE rotary key (not normed) and each head's rotary
-    query turned; the softmax scale (with YaRN's ``mscale**2``) rides on
-    both parts of q."""
+    """``x [T, D]`` -> ``(q_nope [T, H*dn], q_rope [T, H*dr], k_nope, k_rope
+    [T, dr], v)``, and under a key selection the normed input and the
+    normed query rank ``(a [T, D], c_q [T, rq])`` after them: the queries
+    through their normed low rank, the keys' and values' per-head parts
+    decompressed from the normed latent, the ONE rotary key (not normed) and
+    each head's rotary query turned; the softmax scale (with YaRN's
+    ``mscale**2``) rides on both parts of q.
+
+    Every array leaves the matrix product that makes it in the layout the
+    attention kernel reads (PR 48), token-major: ``W_uq``'s COLUMNS are cut
+    into the heads' ``dn`` and ``dr`` parts (a 38 MB weight at 64 heads) and
+    two products write ``q_nope`` in bf16, scaled in the product's epilogue,
+    and the rotary part; where keys and values are equally wide (``dn ==
+    dv``: every published latent model) ``k_nope`` is the ONE product ``[T,
+    H*(dn + dv)]``, head ``h``'s keys at column block ``2h`` and its values
+    at ``2h + 1``, and ``v`` is ``None``
+    (``sparse_attention.masked_gqa_attention`` reads both from it); else
+    two arrays cut from it. Cutting the ACTIVATIONS ``[T, H, dn + dr]`` and
+    ``[T, H, dn + dv]``, as until PR 48, made XLA write the query product in
+    float32, column-major, and slice, relay and convert it: with the
+    head-major transposes around the kernel eleven array-sized passes a
+    layer that computed nothing. One layer alone on the v5e (projections,
+    attention, ``W_o``; my chip runs, PR 48): kimi's 66.97 -> 59.84 ms,
+    dsv32's with its indexer 84.32 -> 70.63."""
     t, dt = x.shape[0], x.dtype
     h, dn, dr, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     yarn = cfg.rope_yarn
@@ -534,16 +550,20 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
     turned = yarn.rotary_scale if yarn else 1.0
     a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
     c_q = rms_norm(_mm(a, p["wq_a"]), p["q_a_norm"], cfg.rms_eps).astype(dt)
-    q = _mm(c_q, p["wq_b"]).reshape(t, h, dn + dr)
-    q_nope = q[..., :dn] * scale
-    q_rope = rotate(q[..., dn:], angles) * (scale * turned)
+    wq_b = p["wq_b"].reshape(-1, h, dn + dr)
+    q_nope = _mm(c_q, wq_b[..., :dn].reshape(-1, h * dn)) * scale
+    q_rope = rotate(_mm(c_q, wq_b[..., dn:].reshape(-1, h * dr)).reshape(t, h, dr), angles)
+    q_rope = q_rope * (scale * turned)
     down = _mm(a, p["wkv_a"])  # [T, latent | the rotary key]
     c_kv = rms_norm(down[:, :cfg.kv_lora_rank], p["kv_a_norm"], cfg.rms_eps).astype(dt)
     k_rope = rotate(down[:, None, cfg.kv_lora_rank:], angles)[:, 0] * turned
-    kv = _mm(c_kv, p["wkv_b"]).reshape(t, h, dn + dv)
-    out = (q_nope.reshape(t, -1).astype(dt), q_rope.reshape(t, -1).astype(dt),
-           kv[..., :dn].reshape(t, -1).astype(dt), k_rope.astype(dt),
-           kv[..., dn:].reshape(t, -1).astype(dt))
+    kv = _mm(c_kv, p["wkv_b"]).astype(dt)  # a head's keys, then its values
+    if dn == dv:
+        k_nope, v = kv, None
+    else:
+        kv = kv.reshape(t, h, dn + dv)
+        k_nope, v = kv[..., :dn].reshape(t, -1), kv[..., dn:].reshape(t, -1)
+    out = (q_nope.astype(dt), q_rope.reshape(t, -1).astype(dt), k_nope, k_rope.astype(dt), v)
     return out + (a, c_q) if cfg.indexer_heads else out  # what an indexer reads
 
 
@@ -560,10 +580,16 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
     rank, the same ``Sel(t)`` in every head) the same kernel takes the
     selection's mask: still the decompressed form over every causal tile,
     which at a selection of 2,048 of 8,704 does less arithmetic than the
-    absorbed form over the selected pairs alone. Under the scopes ``proj``
-    (both low-rank paths, their norms, the rotary, ``W_o``), ``indexer``
-    (its three projections and ``select_keys``) and ``latent_attn`` (the
-    attention itself)."""
+    absorbed form over the selected pairs alone. Nothing array-sized runs
+    between the projections' products, the kernel and ``W_o`` but the
+    rotary's own fusion (PR 48): q, k, v and o are column blocks of
+    token-major arrays, the kernel's ``[B, S, H*dv]`` output is the array
+    ``W_o`` contracts, and only the ``dr``-wide rotary query is head-major
+    (a 64-lane block of ``[S, H*64]`` Mosaic does not take), written so by
+    the fusion that turns it. Under the scopes ``proj`` (both low-rank
+    paths, their norms, the rotary, ``W_o``), ``indexer`` (its three
+    projections and ``select_keys``) and ``latent_attn`` (the attention
+    itself)."""
     s = x.shape[0] // batch
     with jax.named_scope("proj"):
         q_nope, q_rope, k_nope, k_rope, v, *read = jax.jit(
@@ -582,11 +608,13 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
     with jax.named_scope("latent_attn"):
         attend = jax.jit(sa.masked_gqa_attention,
                          static_argnames=("num_kv_heads", "block_q", "block_k"))
-        q_nope, q_rope, k_nope, k_rope, v = (
-            u.reshape(batch, s, -1) for u in (q_nope, q_rope, k_nope, k_rope, v))
-        o = attend(q_nope, k_nope, v, *selection, num_kv_heads=cfg.num_heads,
-                   block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile, q_shared=q_rope,
-                   k_shared=k_rope)
+
+        def rows(u):  # of each sequence
+            return u if u is None else u.reshape(batch, s, -1)
+
+        o = attend(rows(q_nope), rows(k_nope), rows(v), *selection, num_kv_heads=cfg.num_heads,
+                   block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile, q_shared=rows(q_rope),
+                   k_shared=rows(k_rope))
     with jax.named_scope("proj"):
         x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(
             x, o.reshape(x.shape[0], -1), p["wo"])

@@ -36,8 +36,11 @@ What is here, and what it is:
   for all of them. Its BATCHED form (``[B, S, .]`` operands) has a grid
   that holds the tiles at or below the diagonal only (a table of ``(query
   tile, key tile)`` pairs rides in as scalar prefetch), at any head width
-  (heads of 64: LFM2), with values of a width of their own and a part of
-  the score read from ONE key for all heads (latent attention). Without a
+  (heads of 64, LFM2's, go head-major into it; heads of 128 alone in
+  their groups, latent attention's, are read and written as column blocks
+  of the token-major arrays their products wrote), with values of a width
+  of their own and a part of the score read from ONE key for all heads
+  (latent attention). Without a
   mask it is plain causal attention over a batch of sequences; with one
   (one sequence) it is the selection over LATENT attention, masked-dense
   again: the mask's tile is one more operand of a grid step, and its key
@@ -381,17 +384,40 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
 
 def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool,
                       q_shared=None, k_shared=None, mask=None):
-    """The batched form of :func:`masked_gqa_attention`. Operands go head
-    major (``[B, G, H/G, S, d]`` and ``[B, G, S, d]``: a block's last
-    dimension is then the whole head width, which Mosaic takes at 64 where
-    a 64-lane block of ``[S, G*64]`` it does not), and the grid's last axis
-    runs over the ``(query tile, key tile)`` pairs at or below the
+    """The batched form of :func:`masked_gqa_attention`. The grid's last
+    axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
-    even a grid step. The values' width is ``v``'s own (``v [B, S, G*dv]``
+    even a grid step. ONE kernel body, two ways of addressing its blocks,
+    chosen by the widths, each operand by its own. Where a head is whole
+    lane blocks and alone in its group (``width % 128 == 0``, ``H == G``:
+    latent attention's 128-wide q, k, v and o) a head's tile is column
+    block ``h`` of the TOKEN-MAJOR array ``[B, S, H*width]``, read and
+    written where the projection's product wrote it and where ``W_o``
+    contracts it: in the tiled HBM layout a run of whole 4 KB tiles at a
+    fixed stride. ``v`` may then be ``None``: ``k [B, S, G*2d]`` is ONE
+    array with head ``h``'s keys at column block ``2h`` and its values at
+    ``2h + 1`` (the latent's one decompression), two blocks of one
+    operand. Else (heads of 64: LFM2's; ``H/G`` query heads a group)
+    operands go HEAD-MAJOR (``[B, G, H/G, S, d]`` and ``[B, G, S, d]``: a
+    block's last dimension is then the whole head width, which Mosaic
+    takes at 64 where a 64-lane block of ``[S, G*64]`` it does not) by a
+    transpose each way. One layer's kernel alone on the v5e, head-major
+    and then in place (my chip runs, PR 48), ms: 64 heads, B 2, maskless
+    32.79 and 32.82 (32.86 with k and v of one array); 128 heads, masked
+    34.83 and 34.86 (34.89): the strided tiles cost nothing that shows,
+    and the transposes around the kernel, 8.2 ms a layer, are gone (the
+    call with them 41.04 -> 34.43 and 43.07 -> 35.98, what is left being
+    the rotary query's, which inside the layer its own fusion writes).
+    The values' width is ``v``'s own (``v [B, S, G*dv]``
     -> ``o [B, S, H*dv]``). With ``q_shared [B, S, H*ds]`` and ``k_shared
     [B, S, ds]`` a score is ``q . k + q_shared . k_shared``: the shared
     key's tile is read from its one array, once a grid step, for every
-    head (latent attention's one rotary key). With ``mask`` (the selection
+    head (latent attention's one rotary key); the shared QUERY part is
+    narrow (64) and always head-major, as ``[G, H/G, B*S, ds]`` with the
+    batch in the rows: that is a layout of the product ``[B*S, H*ds]`` it
+    comes from, so XLA has the fusion that turns it write it so, where
+    ``[B, G, ..]`` cost a reshape and a copy (compiled for a described v5e,
+    PR 48). With ``mask`` (the selection
     of ONE sequence, from :func:`select_keys`) every tile at or below the
     diagonal is still visited, and a pair counts where the mask says so: the
     key tile is the mask's own (:func:`mask_tile` chose it for THIS
@@ -407,7 +433,10 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, hd = q.shape
-    d, dv = k.shape[2] // g, v.shape[2] // g
+    if v is None:  # ONE array: head h's keys at column block 2h, its values at 2h + 1
+        d = dv = k.shape[2] // (2 * g)
+    else:
+        d, dv = k.shape[2] // g, v.shape[2] // g
     rep = hd // (g * d)
     shared, masked = q_shared is not None, mask is not None
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
@@ -420,23 +449,40 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     pairs = [(i, j) for i in range(s // bq) for j in range(((i + 1) * bq - 1) // bk + 1)]
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
 
-    def q_major(x, width):
-        return jnp.transpose(x.reshape(b, s, g, rep, width), (0, 2, 3, 1, 4))
+    def in_place(width):  # a head of whole lane blocks, alone in its group
+        return rep == 1 and width % 128 == 0
 
-    def q_spec(width):
-        return pl.BlockSpec((None, None, rep, bq, width),
+    def q_spec(width):  # the kernel's [rep, bq, width] tile of a query-side array
+        if in_place(width):  # of [B, 1, S, H*width]: column block gi of the product's own array
+            return pl.BlockSpec((None, rep, bq, width),
+                                lambda bi, gi, t, qi, kb: (bi, 0, qi[t], gi))
+        return pl.BlockSpec((None, None, rep, bq, width),  # of [B, G, H/G, S, width]
                             lambda bi, gi, t, qi, kb: (bi, gi, 0, qi[t], 0))
 
-    def kv_spec(width):
-        return pl.BlockSpec((None, None, bk, width), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0))
+    def q_tiles(x, width):
+        if in_place(width):
+            return x.reshape(b, 1, s, g * width), q_spec(width)
+        return jnp.transpose(x.reshape(b, s, g, rep, width), (0, 2, 3, 1, 4)), q_spec(width)
 
-    q5 = q_major(q, d)
-    k4, v4 = (jnp.transpose(x.reshape(b, s, g, w), (0, 2, 1, 3)) for x, w in ((k, d), (v, dv)))
-    operands, in_specs = [q5, k4, v4], [q_spec(d), kv_spec(d), kv_spec(dv)]
+    def kv_tiles(x, width, part=0, parts=1):  # head gi's part is column block gi*parts + part
+        if in_place(width):
+            return x, pl.BlockSpec((None, bk, width),
+                                   lambda bi, gi, t, qi, kb: (bi, kb[t], gi * parts + part))
+        return (jnp.transpose(x.reshape(b, s, g, width), (0, 2, 1, 3)),
+                pl.BlockSpec((None, None, bk, width), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0)))
+
+    parts = 1
+    if v is None and in_place(d):
+        v, parts = k, 2  # two blocks of the one operand
+    elif v is None:
+        k, v = (k.reshape(b, s, g, 2, d)[:, :, :, i].reshape(b, s, g * d) for i in (0, 1))
+    operands, in_specs = (list(u) for u in zip(
+        q_tiles(q, d), kv_tiles(k, d, 0, parts), kv_tiles(v, dv, parts - 1, parts)))
     if shared:
         ds = k_shared.shape[2]
-        operands += [q_major(q_shared, ds), k_shared]
-        in_specs += [q_spec(ds),
+        operands += [jnp.transpose(q_shared.reshape(b * s, g, rep, ds), (1, 2, 0, 3)), k_shared]
+        in_specs += [pl.BlockSpec((None, rep, bq, ds),
+                                  lambda bi, gi, t, qi, kb: (gi, 0, bi * (s // bq) + qi[t], 0)),
                      pl.BlockSpec((None, bk, ds), lambda bi, gi, t, qi, kb: (bi, kb[t], 0))]
     if masked:
         operands.append(mask)
@@ -452,13 +498,16 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
                 pltpu.VMEM((rep * bq, 1), jnp.float32),
                 pltpu.VMEM((rep * bq, dv), jnp.float32),
             ]),
-        out_shape=jax.ShapeDtypeStruct((b, g, rep, s, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (b, 1, s, g * dv) if in_place(dv) else (b, g, rep, s, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="masked_gqa_attention",
     )(qi, kb, *operands)
+    if in_place(dv):
+        return o5.reshape(b, s, g * dv)
     return jnp.transpose(o5, (0, 3, 1, 2, 4)).reshape(b, s, g * rep * dv)
 
 
@@ -476,8 +525,13 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     S, H*d]``; :func:`_causal_attention`): each sequence on its own, in
     tiles of ``block_q`` (default 256) by ``block_k``, only tiles at or
     below the diagonal visited. Without a mask it is plain causal
-    attention. There the value heads may have a width of their own (``v
-    [B, S, G*dv]`` -> ``[B, S, H*dv]``), and a score may have a second
+    attention. Operands and output are token-major HERE; what the kernel
+    reads in place and what it has transposed head-major first follows
+    from the widths (:func:`_causal_attention`: heads of 128 alone in
+    their groups in place, heads of 64 transposed). There the value heads
+    may have a width of their own (``v [B, S, G*dv]`` -> ``[B, S, H*dv]``),
+    ``v`` may be ``None`` where ``k [B, S, G*2d]`` holds each head's keys
+    and then its values (read in place: no slice), and a score may have a second
     part, ``q_shared [B, S, H*ds] . k_shared [B, S, ds]``, whose key is ONE
     for all heads (latent attention: the rotary key). With a mask (``B``
     1: a selection is one sequence's) it is the selection over latent

@@ -504,25 +504,36 @@ def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
 # one of these programs re-pins it, knowingly: an equal text is an equal key in the compile
 # cache, and a decoder cell's warm `setup_s` (bound 0.1) pays seconds for anything new to trace
 PINNED_STEPS = {
-    # pinned on PR 43's tree in PR 46, which put a selection's mask on latent attention's path
-    "kimi_k2_prefill_epix10k2m": "0aeb2c16eef4e9c0db8dbde6350c2b3bf9ec25b7811103c19653c4f7a8ec0b98",
+    # pinned on PR 43's tree in PR 46, which put a selection's mask on latent attention's path;
+    # re-pinned in PR 48, knowingly: its latent layers make two query products from W_uq's
+    # columns and hand the kernel token-major operands, keys and values in ONE array
+    "kimi_k2_prefill_epix10k2m": "405f211b55a3ad85a8e15994a0b903018547f99b27f1062156dcc78aceddc74b",
     "keye_vl2_prefill_epix10k2m": "7ba74ce99ef580a3c7965ffc0a69c7388c5b3088a8b3cee64ab9e1dd8a69af1f",
     "lfm2_8b_a1b_prefill_epix10k2m": "7434bf59d9d3941cfe175dc4d0ec2e51f5f336d0b2a010924095d61446a8dd9d",
 }
 
 
-@pytest.mark.parametrize("name", sorted(PINNED_STEPS))
-def test_the_other_decoders_steps_lower_to_the_programs_they_were(name, one_chip, monkeypatch):
-    import hashlib
+def _decoder_cell(name):
+    """A decoder cell's configuration as the benchmark runs it: the mapping, the
+    ``DecoderConfig`` and the parameters' shapes."""
     import json
 
     from psana_ray_tpu.models import decoder
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with open(os.path.join(REPO, "benchmark", "configs", name + ".json")) as f:
         cfg = json.load(f)
     dcfg = decoder.DecoderConfig.from_mapping(cfg)
-    params = jax.eval_shape(lambda k: decoder.init_params(dcfg, k), jax.random.key(0))
+    return cfg, dcfg, jax.eval_shape(lambda k: decoder.init_params(dcfg, k), jax.random.key(0))
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_STEPS))
+def test_the_other_decoders_steps_lower_to_the_programs_they_were(name, one_chip, monkeypatch):
+    import hashlib
+
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, params = _decoder_cell(name)
     calib = (S((PANELS, H, W), F32), S((PANELS, H, W), F32), S((PANELS, H, W), jnp.uint8))
     frames = S((cfg["batch_size"], PANELS, H, W), jnp.uint16)
     ids = S((cfg["prompt_tokens"],), jnp.int32)
@@ -534,6 +545,57 @@ def test_the_other_decoders_steps_lower_to_the_programs_they_were(name, one_chip
     text = jax.jit(step).lower(*args).as_text()
     text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
     assert hashlib.sha256(text.encode()).hexdigest() == PINNED_STEPS[name]
+
+
+@pytest.mark.parametrize("name", ["kimi_k2_prefill_epix10k2m", "deepseek_v32_prefill_epix10k2m"])
+def test_latent_attention_s_operands_reach_the_kernel_where_their_products_wrote_them(
+        name, one_chip, monkeypatch):
+    """ONE latent layer (``decoder.latent_attention``) at the cell's
+    published widths, batch and 8,704 tokens a sequence, as compiled: in
+    the entry computation no ``copy``, ``slice``, ``reshape`` or
+    copy/bitcast fusion writes an array of ``T * H * 64`` elements or more
+    between the projections' products, ``masked_gqa_attention`` and ``W_o``
+    (the indexer's own head-major index queries apart: its scope). On PR
+    47's tree this counted ten in kimi's layer, beside a pass that scaled
+    and converted the float32 query: the 128-wide query sliced out of a
+    float32 ``[T, H*192]`` product, relaid, and transposed head-major
+    (three); the rotary query reshaped and copied (two); keys and values
+    each relaid and transposed (four); the output transposed back (one);
+    and six in dsv32's (the query's slice and relayout, the keys-and-values
+    product relaid whole and then a copy each, the output's): 3.4 GB
+    written a layer that computed nothing. Since PR 48 the kernel reads q,
+    k, v and writes o as column blocks of the products' own token-major
+    arrays (k and v of ONE array), and the rotary's fusion writes the 64-wide
+    rotary query head-major itself."""
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, params = _decoder_cell(name)
+    batch, seq = cfg["batch_size"], 8704
+    tokens, heads = batch * seq, dcfg.num_heads
+
+    def layer(p, x):
+        angles = decoder.rotary_angles(np.arange(seq), dcfg.rope_theta, dcfg.rope_dim // 2,
+                                       yarn=dcfg.rope_yarn)
+        return decoder.latent_attention(p, x, jnp.tile(angles, (batch, 1)), batch, dcfg, angles)
+
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip),
+                        (params["layers"][1], S((tokens, dcfg.hidden_size), BF16)))
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    assert len(re.findall(r"^\s*(?:ROOT )?%masked_gqa_attention[.\d]* = ", entry, re.M)) == 1
+    assert f"[{tokens},{heads * dcfg.head_dim}]" not in entry  # no product of whole [nope | rope] heads
+    moved = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+        if not m or "/indexer/" in line:
+            continue
+        op_name, dims, opcode = m.groups()
+        moves = opcode in ("copy", "slice", "reshape") or (
+            opcode == "fusion" and ("copy" in op_name or "bitcast" in op_name))
+        if moves and np.prod([int(x) for x in dims.split(",") if x]) >= tokens * heads * 64:
+            moved.append(op_name)
+    assert not moved, moved
 
 
 @pytest.mark.parametrize("d", [0, 1, 2, 3])

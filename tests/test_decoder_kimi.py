@@ -130,8 +130,13 @@ def test_the_reference_with_a_fault_in_it_is_another_trunk(fault):
 # the latent-attention layer alone, and its kernel
 # ---------------------------------------------------------------------------
 
-def test_latent_attention_layer_is_the_reference_operator_for_a_batch_of_two():
-    m = mapping()
+@pytest.mark.parametrize("widths", [
+    {},  # heads of 16: head-major operands, the one keys-and-values array cut in two
+    {"qk_nope_head_dim": 128, "v_head_dim": 128, "num_attention_heads": 2},  # read where written
+    {"v_head_dim": 24},  # values of another width than the keys: two arrays from the projections
+], ids=["heads_of_16", "heads_of_128", "values_of_24"])
+def test_latent_attention_layer_is_the_reference_operator_for_a_batch_of_two(widths):
+    m = mapping(**widths)
     cfg = small(m)
     p = loud(decoder.init_params(cfg, jax.random.key(7), jnp.float32))["layers"][0]
     rng = np.random.default_rng(7)
@@ -150,6 +155,19 @@ def test_latent_attention_layer_is_the_reference_operator_for_a_batch_of_two():
     assert live == causal == 2  # no selection: every tile of both sequences is attended
 
 
+def _plain_attention(q, k, v, qs, ks, g, allowed):
+    """Softmax attention over the ``allowed [S, S]`` pairs, a score being ``q
+    . k + qs . ks`` with the ONE shared key; ``H/G`` query heads read a key head."""
+    b, s, _ = q.shape
+    h = qs.shape[2] // ks.shape[2]
+    kh, vh = (jnp.repeat(x.reshape(b, s, g, -1), h // g, axis=2) for x in (k, v))
+    score = (jnp.einsum("bthd,bshd->bhts", q.reshape(b, s, h, -1), kh, precision="highest")
+             + jnp.einsum("bthd,bsd->bhts", qs.reshape(b, s, h, -1), ks, precision="highest"))
+    score = jnp.where(allowed, score, -jnp.inf)
+    return jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(score, -1), vh,
+                      precision="highest").reshape(b, s, -1)
+
+
 @pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16), (48, 32), (96, 96)])
 @pytest.mark.parametrize("rep", [1, 2])
 def test_causal_kernel_with_a_shared_key_part_and_values_of_another_width(bq, bk, rep):
@@ -163,19 +181,91 @@ def test_causal_kernel_with_a_shared_key_part_and_values_of_another_width(bq, bk
     v = jnp.asarray(rng.standard_normal((b, s, g * dv)), jnp.float32)
     got = sa.masked_gqa_attention(q, k, v, num_kv_heads=g, block_q=bq, block_k=bk,
                                   q_shared=qs, k_shared=ks)
-    kh, vh = (jnp.repeat(x.reshape(b, s, g, -1), rep, axis=2) for x in (k, v))
-    score = (jnp.einsum("bthd,bshd->bhts", q.reshape(b, s, h, d), kh, precision="highest")
-             + jnp.einsum("bthd,bsd->bhts", qs.reshape(b, s, h, ds), ks, precision="highest"))
-    score = jnp.where(jnp.tril(jnp.ones((s, s), bool)), score, -jnp.inf)
-    want = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(score, -1), vh, precision="highest")
+    want = _plain_attention(q, k, v, qs, ks, g, np.tril(np.ones((s, s), bool)))
     assert got.shape == (b, s, h * dv)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want.reshape(b, s, h * dv)), atol=3e-6)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
     # the plain way to the same numbers: the shared key written once a head beside the head's own
     wide_q = jnp.concatenate([q.reshape(b, s, h, d), qs.reshape(b, s, h, ds)], -1).reshape(b, s, -1)
     wide_k = jnp.concatenate([k.reshape(b, s, g, d), jnp.broadcast_to(ks[:, :, None], (b, s, g, ds))],
                              -1).reshape(b, s, -1)
     plain = sa.masked_gqa_attention(wide_q, wide_k, v, num_kv_heads=g, block_q=bq, block_k=bk)
     np.testing.assert_allclose(np.asarray(got), np.asarray(plain), atol=3e-6)
+
+
+def _latent_operands(seed, b, s, g, d, ds):
+    """Latent attention's operands at ``rep`` 1 and values as wide as the
+    keys: ``q, k, v [B, S, G*d]``, the shared parts, and the ONE array that
+    holds head ``h``'s keys at column block ``2h`` and its values at ``2h + 1``."""
+    rng = np.random.default_rng(seed)
+    q, k, v = (jnp.asarray(rng.standard_normal((b, s, g * d)), jnp.float32) for _ in range(3))
+    qs = jnp.asarray(rng.standard_normal((b, s, g * ds)), jnp.float32) * 0.3
+    ks = jnp.asarray(rng.standard_normal((b, s, ds)), jnp.float32)
+    kv = jnp.concatenate([k.reshape(b, s, g, d), v.reshape(b, s, g, d)], -1).reshape(b, s, -1)
+    return q * 0.1, k, v, qs, ks, kv
+
+
+def _transposes(fn, *args):
+    return sum(e.primitive.name == "transpose" for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns)
+
+
+@pytest.mark.parametrize("kv", ["one_array", "two_arrays"])
+@pytest.mark.parametrize("case", ["maskless_batch_of_two", "masked_one_sequence"])
+def test_causal_kernel_reads_heads_of_whole_lane_blocks_where_their_products_wrote_them(case, kv):
+    """Heads of 128 with values of 128, alone in their groups (latent
+    attention's case): q, k, v and o are column blocks of the token-major
+    arrays, k and v of ONE array or of two; the numbers are the reference's
+    and those of the head-major addressing (taken where a head is no whole
+    number of lane blocks: the same operands with eight zero columns a head)."""
+    b, s, g, d, ds = (2 if case == "maskless_batch_of_two" else 1), 64, 3, 128, 8
+    q, k, v, qs, ks, one = _latent_operands(len(case), b, s, g, d, ds)
+    allowed, selection = np.tril(np.ones((s, s), bool)), ()
+    if case == "masked_one_sequence":  # a selection: half of the earlier keys, and the query's own
+        allowed &= np.random.default_rng(5).random((s, s)) < 0.5
+        allowed |= np.eye(s, dtype=bool)
+        selection = (jnp.asarray(allowed.reshape(s // 16, 16, s // 32, 32).transpose(0, 2, 1, 3),
+                                 jnp.int8),)
+    keys_values = (one, None) if kv == "one_array" else (k, v)
+
+    def attend(q, k, v, qs):
+        return sa.masked_gqa_attention(q, k, v, *selection, num_kv_heads=g, block_q=32,
+                                       block_k=32, q_shared=qs, k_shared=ks)
+
+    got = attend(q, *keys_values, qs)
+    assert got.shape == (b, s, g * d)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(_plain_attention(q, k, v, qs, ks, g, allowed)), atol=3e-6)
+    # one transpose, the narrow shared query's; the head-major addressing makes five (q, k, v,
+    # the shared query, o back)
+    assert _transposes(attend, q, *keys_values, qs) == 1
+
+    def padded(x):
+        return jnp.pad(x.reshape(b, s, g, d), ((0, 0),) * 3 + ((0, 8),)).reshape(b, s, -1)
+
+    assert _transposes(attend, padded(q), padded(k), padded(v), qs) == 5
+    major = attend(padded(q), padded(k), padded(v), qs).reshape(b, s, g, d + 8)
+    np.testing.assert_array_equal(np.asarray(major[..., d:]), 0.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(major[..., :d].reshape(b, s, -1)),
+                               atol=3e-6)
+
+
+def test_heads_of_64_keep_the_head_major_addressing():
+    """A 64-lane block of ``[S, G*64]`` Mosaic does not take: heads of 64
+    (LFM2's, ``rep`` 4 there; here alone in their groups, so that the width
+    alone decides) are transposed to head-major and back as they were, and
+    ONE array of keys and values is cut in two first."""
+    b, s, g, d, ds = 2, 64, 2, 64, 8
+    q, k, v, qs, ks, one = _latent_operands(64, b, s, g, d, ds)
+
+    def attend(q, k, v):
+        return sa.masked_gqa_attention(q, k, v, num_kv_heads=g, block_q=32, block_k=32,
+                                       q_shared=qs, k_shared=ks)
+
+    want = _plain_attention(q, k, v, qs, ks, g, np.tril(np.ones((s, s), bool)))
+    for keys_values in ((k, v), (one, None)):
+        assert _transposes(attend, q, *keys_values) == 5
+        np.testing.assert_allclose(np.asarray(attend(q, *keys_values)), np.asarray(want), atol=3e-6)
+    assert _transposes(lambda q, k, v: sa.masked_gqa_attention(  # and without a shared part: four
+        q, k, v, num_kv_heads=g, block_q=32, block_k=32), q, k, v) == 4
 
 
 # (a mask WITH a shared key part and a value width of its own is the selection over latent

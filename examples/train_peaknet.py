@@ -19,8 +19,7 @@ Run (small, CPU-friendly):
     python examples/train_peaknet.py --steps 4
 
 Convergence scale: on the synthetic oracle this recipe saturates peak
-recall/precision around ~300 steps at batch 2 (bench step sweep,
-PERF_NOTES.md r5) — the tiny defaults here demonstrate the plumbing,
+recall/precision around ~300 steps at batch 2 — the tiny defaults here demonstrate the plumbing,
 not a finished detector.
 """
 
@@ -48,14 +47,13 @@ def main():
         "--export-serving", default=None, metavar="DIR", dest="export_serving",
         help="after training, fold BatchNorm stats into FrozenAffine "
         "constants (models/fold.py) and save serving params here — the "
-        "parameter form peaknet_tpu_fused_infer consumes. Implies --norm "
-        "batch.",
+        "parameter form psana-ray-tpu-sfx serves. Implies --norm batch.",
     )
     ap.add_argument(
         "--features", default="16,32",
         help="comma-separated encoder widths (default keeps the example "
-        "CPU-fast; 64,128,256,512 is the real PeakNet-TPU capacity the "
-        "bench and psana-ray-tpu-sfx serve). The exported checkpoint "
+        "CPU-fast; 64,128,256,512 is the real PeakNet-TPU capacity "
+        "psana-ray-tpu-sfx serves). The exported checkpoint "
         "carries the widths — sfx infers them back, no flag to keep in "
         "sync.",
     )
@@ -70,12 +68,11 @@ def main():
         help="focal-loss positive-class weight. At this domain's ~1e-4 "
         "peak-pixel fraction the textbook 0.25 collapses training to "
         "all-background within a few steps (measured on epix10k2M: "
-        "recall 0.04 after 320 steps at 0.25 vs 1.00 at 0.95 — the "
-        "bench quality probe's calibrated recipe)",
+        "recall 0.04 after 320 steps at 0.25 vs 1.00 at 0.95)",
     )
     ap.add_argument(
         "--lr", type=float, default=3e-3,
-        help="learning rate (default: the bench probe's measured recipe; "
+        help="learning rate (default: the quality probe's recipe; "
         "precision is the slow-saturating metric — at 1e-3 a 320-step "
         "epix10k2M run stops around precision 0.4 where 3e-3 saturates)",
     )
@@ -219,7 +216,7 @@ def main():
         print(
             f"serving params (norm='frozen' form) exported to "
             f"{args.export_serving} — consumable by "
-            f"PeakNetUNetTPU(norm='frozen').apply and peaknet_tpu_fused_infer"
+            f"PeakNetUNetTPU(norm='frozen').apply (what psana-ray-tpu-sfx serves)"
         )
 
 

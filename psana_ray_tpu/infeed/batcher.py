@@ -137,8 +137,7 @@ class FrameBatcher:
     ``n_buffers > 0`` preallocates that many batch-buffer sets and reuses
     them round-robin instead of allocating ~batch-size x frame-size fresh
     per batch (at epix scale a fresh 138 MB allocation is re-page-faulted
-    every batch — measured 1.6 GB/s effective vs 8.8 GB/s copy bandwidth,
-    PERF_NOTES.md). CONTRACT: a pooled Batch's arrays are overwritten
+    every batch, which costs more than the copy into it). CONTRACT: a pooled Batch's arrays are overwritten
     ``n_buffers`` batches later, so ``n_buffers`` must EXCEED the maximum
     number of batches simultaneously alive anywhere downstream — queued
     in a prefetcher or merge queue, held by the consumer, still being

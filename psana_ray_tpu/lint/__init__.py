@@ -14,7 +14,7 @@ Entry points:
 - ``python -m psana_ray_tpu.lint [--json]`` — the CLI; exits non-zero
   on findings (CI gate);
 - :func:`run_lint` — the library call ``tests/test_lint.py`` (tier-1)
-  and the bench artifact use;
+  uses;
 - ``REGISTRY`` — name -> checker, populated by importing
   :mod:`psana_ray_tpu.lint.checkers`.
 
@@ -47,11 +47,11 @@ def run_lint(
     use_cache: bool = False,
 ) -> LintResult:
     """Run the registry (or a named subset) over ``paths`` (default: the
-    package + bench.py). Allowlist rot is reported only on full-registry,
+    package). Allowlist rot is reported only on full-registry,
     full-tree runs — a partial run legitimately leaves other checkers'
     entries unused. ``duration_s`` covers the WHOLE run — file reading
-    and parsing included — so the budget in tier-1 and the bench
-    artifact measure what an operator actually waits for.
+    and parsing included — so the budget in tier-1 measures what an
+    operator actually waits for.
     ``use_cache=True`` reuses parses across runs via the content-keyed
     (sha256) cache in ``.lint_cache/`` (the CLI default; library
     callers opt in)."""

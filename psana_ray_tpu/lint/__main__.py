@@ -2,16 +2,16 @@
 
 Exit status is the CI contract: 0 = clean, 1 = findings (including
 allowlist rot), 2 = usage error. Runs the full registry over the
-package + bench.py by default, a subset with ``--checker`` (repeatable),
+package by default, a subset with ``--checker`` (repeatable),
 explicit files/directories given as positional paths, or — the
 pre-commit path — only the files touched since a git ref with
 ``--changed REF`` (the wire-protocol pair rides along so the
 cross-file checkers keep both sides in scope; see
 ``core.PROTOCOL_COMPANIONS``).
 
-``--json`` emits the same shape the bench artifact embeds
-(``counts_by_checker`` includes zeros for every checker that ran, so
-"ran clean" and "did not run" stay distinguishable); ``--sarif`` emits
+``--json`` emits ``LintResult.to_json()`` (``counts_by_checker``
+includes zeros for every checker that ran, so "ran clean" and "did not
+run" stay distinguishable); ``--sarif`` emits
 SARIF 2.1.0 for CI PR annotation. Parses are cached across runs in
 ``.lint_cache/`` (``--no-cache`` for a cold run).
 """
@@ -46,7 +46,7 @@ def main(argv=None) -> int:
     )
     ap.add_argument(
         "paths", nargs="*",
-        help="files/directories to scan (default: the package + bench.py)",
+        help="files/directories to scan (default: the package)",
     )
     ap.add_argument("--json", action="store_true", help="machine-readable output")
     ap.add_argument(

@@ -113,22 +113,6 @@ ALLOWLIST = (
     # i.e. the __init__ declaration line), and every justification names
     # what bounds the race — the bar the checker's hint sets. ----------
     Allow(
-        "lockset-inference", "bench.py", "self._deadline = None",
-        why="watchdog soft-cancel deadline: remaining_s() reads it bare "
-        "because the watchdog surface must never block on a lock a "
-        "wedged section might hold; a torn read costs one poll tick of "
-        "deadline slack, never a missed hard exit (the poller re-reads "
-        "under the lock)",
-    ),
-    Allow(
-        "lockset-inference", "bench.py", "self._section = None",
-        why="watchdog section label: _hard_exit() reads it bare on the "
-        "os._exit path by design (last line of defense — taking the "
-        "section lock there could deadlock with the wedged holder); "
-        "worst case is a mislabeled watchdog_fired key, never a lost "
-        "bench artifact",
-    ),
-    Allow(
         "lockset-inference", "obs/tracing.py", "self.enabled = False",
         why="the tracing on/off gate maybe_trace()/span() read bare — "
         "the documented lock-free hot path (disabled = ONE attribute "

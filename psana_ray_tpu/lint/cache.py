@@ -18,7 +18,7 @@ Honest numbers (this box, 99 files): a cold full-tree parse is
 for the INCREMENTAL path: ``--changed`` scans a handful of files, and
 the warm common case (nothing changed since the last pre-commit run)
 keeps the whole parse phase flat as the tree grows. It will never make
-the checkers themselves faster — see PERF_NOTES.
+the checkers themselves faster.
 
 The cache lives in ``<repo>/.lint_cache/`` (gitignored). Corruption is
 handled by deletion: any unpickling error is a miss, never a crash.

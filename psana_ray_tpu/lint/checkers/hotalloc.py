@@ -7,9 +7,9 @@ satellite). The transport/infeed hot path moves every frame payload as
 so ``.tobytes()`` (frame-sized serialization copy), ``.to_bytes(``
 calls (contiguous assembly), raw ``.recv(`` (a fresh bytes object per
 chunk), and frame-scale ``bytes(...)`` materialization are banned in
-the hot files. PERF_NOTES' host-datapath section records what regrowing
-any of these costs (the pre-ISSUE-2 path paid >=3 frame-sized copies
-per frame).
+the hot files. Regrowing any of these costs a frame-sized copy per
+frame (the pre-ISSUE-2 path paid >=3); ``tests/test_wire_zero_copy.py``
+pins the path at one.
 
 Reviewed, size-bounded exceptions live in the central allowlist
 (control-plane reads of a few bytes, 1-byte tag peeks, legacy

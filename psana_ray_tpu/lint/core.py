@@ -136,13 +136,8 @@ class FileIndex:
 
 
 def default_target_files() -> List[pathlib.Path]:
-    """The tree the project invariants cover: the package + bench.py
-    (the same population the old ``tests/test_static.py`` screened)."""
-    files = sorted(PACKAGE_DIR.rglob("*.py"))
-    bench = REPO_ROOT / "bench.py"
-    if bench.exists():
-        files.append(bench)
-    return files
+    """The tree the project invariants cover: the package."""
+    return sorted(PACKAGE_DIR.rglob("*.py"))
 
 
 # files the CROSS-FILE checkers anchor at; an incremental run always
@@ -283,8 +278,8 @@ class LintResult:
 
     def counts_by_checker(self) -> Dict[str, int]:
         """Finding counts keyed by checker, INCLUDING zeros for every
-        checker that ran — the bench artifact records static-cleanliness
-        per invariant, and an absent key must mean "did not run", never
+        checker that ran — ``--json`` reports static-cleanliness per
+        invariant, and an absent key must mean "did not run", never
         "ran clean"."""
         counts = {name: 0 for name in self.checkers_run}
         for f in self.findings:

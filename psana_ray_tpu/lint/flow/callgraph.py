@@ -112,7 +112,7 @@ class CallGraph:
 
     Construction is one recursive pass over every file's AST with
     dict-indexed resolution, so the whole thing stays linear in tree
-    size (the lint budget covers it — see PERF_NOTES)."""
+    size (the lint budget in ``tests/test_lint.py`` covers it)."""
 
     def __init__(self, index):
         self.index = index

@@ -9,7 +9,7 @@ table in scope, nothing repo-specific hard-coded):
    transport is in scope the model->code direction runs too.
 2. bounded exploration — only when the real transport is in scope, on
    the quick profile so the registry entry stays well inside the lint
-   budgets (the full profile belongs to ``--model`` and bench.py).  A
+   budgets (the full profile belongs to ``--model``).  A
    counterexample on the live tree is a finding carrying the rendered
    trace; so is a truncated (non-exhausted) run, because a truncated
    "zero counterexamples" claim is not a claim.
@@ -37,7 +37,7 @@ _TRANSPORT_RELS = (
 
 
 def run_model_report(profile="full"):
-    """The ``--model`` / bench entry point: full-profile exploration of
+    """The ``--model`` entry point: full-profile exploration of
     every model plus the drift gate over the protocol companions.
 
     Returns ``(results, drift)``: a list of ExploreResult and a list of

@@ -15,8 +15,8 @@ Bounds are explicit and enforced three ways:
 - ``budget_s`` is a wall-clock cap checked between expansions.
 
 A run that exhausts the state space with no violation sets
-``exhausted=True`` — that is the claim bench.py pins: "all interleavings
-of this bounded configuration, zero counterexamples".
+``exhausted=True`` — that is the claim ``tests/test_model.py`` pins:
+"all interleavings of this bounded configuration, zero counterexamples".
 """
 
 from __future__ import annotations

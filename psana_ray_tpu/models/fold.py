@@ -1,7 +1,8 @@
 """Train→serve continuity: fold BatchNorm statistics into FrozenAffine.
 
-Every fused serving path (models/pallas_resnet.py, models/pallas_unet.py)
-consumes the ``norm='frozen'`` parameter form — per-channel affine
+The served models (``norm='frozen'`` under ``jax.jit``, and the fused
+ResNet path in models/pallas_resnet.py) consume the ``norm='frozen'``
+parameter form — per-channel affine
 constants that fuse into conv epilogues. This module supplies the
 supported route from a TRAINED checkpoint to that form, closing the gap
 the reference's mission statement implies (streaming *to inference*,

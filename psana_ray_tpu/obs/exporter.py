@@ -5,8 +5,8 @@ Every long-running CLI (producer, consumer, sfx, queue server) takes a
 daemon thread serving:
 
 - ``GET /metrics``  — Prometheus exposition text-format 0.0.4 (scrape me);
-- ``GET /healthz``  — the same registry as a JSON snapshot (humans, tests,
-  and the bench artifact use this shape);
+- ``GET /healthz``  — the same registry as a JSON snapshot (humans and
+  tests use this shape);
 - ``GET /federate`` — the snapshot wrapped host-tagged (host/pid/wall/
   mono), byte-compatible with the queue server's 'N' ``{"op":
   "metrics"}`` RPC answer — what the ISSUE 13 cluster collector pulls

@@ -16,16 +16,15 @@ about once a second on the sampler's housekeeping tick:
 
 Registered as the ``prof`` source on the MetricsRegistry, every value
 here is numeric, so it flows unmodified through ``flatten_numeric``
-into Prometheus, the PR 13 history rings, federation metrics, and the
-bench baseline gate. The non-numeric profile summary (hot frame NAMES)
+into Prometheus, the PR 13 history rings and federation metrics. The
+non-numeric profile summary (hot frame NAMES)
 deliberately lives outside this source — see
 ``registry.federation_payload``'s ``profile`` key — because the metric
 grammar drops strings.
 
 Deltas are computed against injected ``frames_fn`` / ``bytes_fn`` when
-the caller has a better frame counter than the wire totals (bench.py
-injects its own frame count so the model scores exactly the measured
-window).
+the caller has a better frame counter than the wire totals (a caller
+that counts its own frames scores exactly the window it measured).
 """
 
 from __future__ import annotations

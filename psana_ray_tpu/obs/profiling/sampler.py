@@ -1,7 +1,6 @@
 """Continuous flame sampler: bounded stack trie + 97 Hz daemon thread.
 
-The host datapath's ceiling is Python CPU (PERF_NOTES: ~340 fps
-passthrough vs 28.2k fps/chip device-side), but until this PR nothing
+The host datapath's ceiling is Python CPU, but until this module nothing
 measured WHERE that CPU goes. This module is the always-on half of the
 answer: a daemon thread wakes ~97 times a second (off-aligned from the
 100 Hz USER_HZ tick and from 1 Hz telemetry scrapes, so it never beats

@@ -3,8 +3,8 @@
 One :class:`MetricsRegistry` per process collects every metrics-bearing
 object (``PipelineMetrics`` bundles, ``Meter``/``LatencyStats``
 singletons, queue ``stats()`` callables, stall detectors) under a source
-name; :meth:`snapshot` returns the whole tree as a JSON-safe dict (tests,
-bench artifacts) and :meth:`render_prometheus` flattens the same tree
+name; :meth:`snapshot` returns the whole tree as a JSON-safe dict (tests)
+and :meth:`render_prometheus` flattens the same tree
 into Prometheus exposition text-format 0.0.4 for the HTTP exporter
 (:mod:`psana_ray_tpu.obs.exporter`).
 

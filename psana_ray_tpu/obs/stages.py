@@ -67,8 +67,8 @@ array of the batch on the device.
 Because stages are CONSECUTIVE differences of one record's timeline, the
 per-stage means over a set of records sum EXACTLY to the mean of the
 ``e2e`` pseudo-stage (src → step done) over the same records — that is
-what lets BENCH's 3400× device-vs-e2e gap decompose into named stages
-instead of a single opaque number. A missing boundary (e.g. records that
+what lets the gap between device time and end-to-end time decompose
+into named stages instead of a single opaque number. A missing boundary (e.g. records that
 crossed a process hop, where monotonic stamps don't travel) never breaks
 the telescoping: the next present boundary's stage absorbs the gap.
 """

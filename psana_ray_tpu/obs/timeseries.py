@@ -222,8 +222,8 @@ class TimeSeriesStore:
 class HistorySampler:
     """The per-process sampling loop: every ``interval_s`` take ONE
     registry snapshot and record it into the store. A daemon thread with
-    a bounded Event wait; ``sample_once`` is exposed so tests (and the
-    bench A/B) drive time explicitly."""
+    a bounded Event wait; ``sample_once`` is exposed so tests drive
+    time explicitly."""
 
     def __init__(
         self,

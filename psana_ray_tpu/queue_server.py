@@ -102,7 +102,7 @@ def main(argv=None):
         help="segment-log fsync policy: 'none' survives process death "
         "(page cache) but a machine crash may lose the tail; 'batch' "
         "fsyncs every --fsync_batch_n appends + on roll/commit; "
-        "'always' fsyncs per append (measured overhead in PERF_NOTES)",
+        "'always' fsyncs per append (one disk flush a frame)",
     )
     p.add_argument(
         "--fsync_batch_n", type=int, default=dur_defaults.fsync_batch_n,

@@ -4,9 +4,8 @@ transport and the device consumers.
 Three cooperating mechanisms, all driven by MEASUREMENT (the tf.data
 "measure-then-control" philosophy, PAPERS.md):
 
-- :class:`SloPolicy` — the measured latency/throughput frontier
-  (bench's ``device_latency_operating_point``: B1 0.89 ms ... B8
-  4.33 ms) as a control law: pick the batch size per dispatch from the
+- :class:`SloPolicy` — the latency/throughput frontier (device time
+  per batch size, refined online from every dispatch) as a control law: pick the batch size per dispatch from the
   current backlog so an idle system serves B1 latency and a loaded one
   serves B8 throughput, always keeping predicted queue-wait + device
   time inside the p99 SLO budget;

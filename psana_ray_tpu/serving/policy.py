@@ -1,10 +1,9 @@
 """SLO policy: the measured latency/throughput frontier as a control law.
 
-The bench measures the device's operating points — batch size vs device
-latency (``device_latency_operating_point``: B1 0.89 ms ... B8 4.33 ms
-on the fused calib path, BENCH_r05) — but until ISSUE 12 the consumer
-drained fixed-size batches regardless of load. :class:`SloPolicy` turns
-that table into the two decisions the gateway makes per dispatch:
+The device has operating points — batch size vs device latency (a small
+batch answers sooner, a large one moves more frames a second).
+:class:`SloPolicy` turns that table into the two decisions the gateway
+makes per dispatch:
 
 - **which batch size**: the largest operating point the current backlog
   can fill (idle -> B1, no batching tax; loaded -> B8, max throughput),
@@ -13,10 +12,10 @@ that table into the two decisions the gateway makes per dispatch:
   against the SLO budget (shrunk while the stall detector says the
   system is degraded — graceful degradation instead of collapse).
 
-The table is seeded from the bench numbers and REFINED online: every
+The table starts from seed values and is REFINED online: every
 dispatch's measured wall time feeds an EWMA per batch size, so the
 policy tracks the machine it is actually running on (tf.data's
-measure-then-control, PAPERS.md), not the one the bench ran on.
+measure-then-control, PAPERS.md), not the one the seeds came from.
 
 Threading: the EWMA table has a single writer (the gateway dispatch
 loop); readers see whole float values (GIL-atomic dict reads), so the
@@ -28,9 +27,10 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Sequence, Tuple
 
-# (batch, device_ms) measured on the fused-calib device path (bench
-# device-latency section, BENCH_r05). Intermediate points interpolated
-# on the measured B1/B8 anchors; the online EWMA refines all of them.
+# (batch, device_ms) seeds only: an early reading of the fused-calib
+# device path at B1 and B8 with the points between interpolated. No
+# line of PERF_LEDGER.jsonl holds them, and none needs to: the online
+# EWMA replaces each with this machine's own dispatch times.
 DEFAULT_OPERATING_POINTS: Tuple[Tuple[int, float], ...] = (
     (1, 0.89),
     (2, 1.43),

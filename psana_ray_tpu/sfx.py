@@ -104,24 +104,23 @@ class SfxConfig:
 
 
 # Per-mode default find_peaks thresholds, keyed by s2d — calibrated on
-# the synthetic oracle's precision/recall sweep (bench _bench_unet_quality
-# on v5e-1, 320-step probe; full curves in bench_full.json). With an
-# adequately trained checkpoint BOTH modes saturate the oracle across a
-# wide threshold range (s2d=4 at 320 steps: recall/precision 1.0/1.0 at
-# thr 0.3-0.5, degrading only gently above — 0.6 still scores 0.98/1.0),
-# so 0.5 is the shared default for both modes: inside the saturated
-# range, matching s2d=2's calibrated knee, with mild degradation rather
-# than a cliff on either side. Earlier rounds shipped
-# s2d=4 at 0.8 with a "triage-only" warning; a step sweep (PERF_NOTES
-# r5) showed that quarter-res precision ceiling was an UNDERTRAINING
+# the synthetic oracle's precision/recall sweep (a 320-step training
+# probe). With an adequately trained checkpoint BOTH modes saturate the
+# oracle across a wide threshold range (s2d=4 at 320 steps:
+# recall/precision 1.0/1.0 at thr 0.3-0.5, degrading only gently above —
+# 0.6 still scores 0.98/1.0), so 0.5 is the shared default for both
+# modes: inside the saturated range, matching s2d=2's calibrated knee,
+# with mild degradation rather than a cliff on either side. Earlier
+# rounds shipped s2d=4 at 0.8 with a "triage-only" warning; a step sweep
+# showed that quarter-res precision ceiling was an UNDERTRAINING
 # artifact of the then-16-step probe (16 steps -> prec ~0.2-0.5 and an
 # unstable knee; 192 -> 0.97; 320 -> 1.00), not a resolution limit.
 # Operating guidance: with a converged checkpoint s2d=4 is a
-# full-quality operating point at 3.6x the s2d=2 throughput on the
-# shipped batch-8 basis (521 vs 146 fps, README measured table);
-# an UNDERTRAINED s2d=4 checkpoint degrades toward
-# over-prediction, so raise --peak_threshold if CXI output from an
-# early checkpoint floods downstream indexing.
+# full-quality operating point that runs the trunk at a quarter of the
+# resolution (no ledger cell measures it yet: ROADMAP R1); an
+# UNDERTRAINED s2d=4 checkpoint degrades toward over-prediction, so
+# raise --peak_threshold if CXI output from an early checkpoint floods
+# downstream indexing.
 DEFAULT_THRESHOLDS = {2: 0.5, 4: 0.5}
 
 

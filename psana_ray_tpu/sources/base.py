@@ -46,7 +46,7 @@ DETECTORS = {
     "jungfrau4M": DetectorSpec("jungfrau4M", panels=8, height=512, width=1024),
     "cspad": DetectorSpec("cspad", panels=32, height=185, width=388),
     "epix100": DetectorSpec("epix100", panels=1, height=704, width=768),
-    # tiny lane-aligned geometries for off-TPU smoke runs (bench BENCH_SMOKE=1)
+    # tiny lane-aligned geometries for off-TPU smoke runs (tests, examples)
     "smoke_a": DetectorSpec("smoke_a", panels=2, height=16, width=128),
     "smoke_b": DetectorSpec("smoke_b", panels=1, height=32, width=128),
 }

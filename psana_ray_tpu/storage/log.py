@@ -24,8 +24,8 @@ Layout of the log directory::
 - ``batch``  — fsync the active segment every ``fsync_batch_n``
   appends, on segment roll, and on every commit. Bounds machine-crash
   loss to one batch.
-- ``always`` — fsync after every append. The measured-overhead row in
-  the bench exists so nobody picks this by accident.
+- ``always`` — fsync after every append: one disk flush a frame, so
+  nobody should pick this by accident.
 
 Retention: segments whose every record sits below the LIVE committed
 floor (group ``""`` — the queue's own consumption cursor) are kept

@@ -3,8 +3,8 @@
 One process-wide instance (:data:`DURABLE`) shared by every SegmentLog /
 DurableRingBuffer in the process, registered in the default
 MetricsRegistry on first durable use — the same self-registration
-pattern as the stream and evloop sources, so ``--metrics_port`` and the
-bench artifact pick it up with zero wiring.
+pattern as the stream and evloop sources, so ``--metrics_port``
+picks it up with zero wiring.
 """
 
 from __future__ import annotations

@@ -897,7 +897,7 @@ def compress_encoded_parts(item, parts, codec, pool):
         return parts, None
     cached = cached_wire_parts(item, codec)
     if cached is not None:
-        # relay pass-through backstop for DIRECT callers (bench, tests):
+        # relay pass-through backstop for DIRECT callers (tests):
         # this record arrived COMPRESSED with the same codec — re-send
         # the exact bytes, zero codec CPU. The cached lease rides the
         # record (released with it), so no staging lease changes hands.

@@ -1,7 +1,7 @@
 """Kernel pass-through for payload bytes: sendfile spans + capability probe.
 
-The brokered hot path's remaining Python-byte source (PERF_NOTES ISSUE
-16) is the durable spill read: ``SegmentLog.read`` copies the payload
+The brokered hot path's remaining Python-byte source is the durable
+spill read: ``SegmentLog.read`` copies the payload
 out of the mmap into interpreter-owned bytes just so the evloop can
 hand them back to ``socket.sendmsg``. But the bytes at rest in a
 segment ARE the wire payload (tag byte + record body, written verbatim
@@ -13,7 +13,8 @@ buffers. This module teaches it to speak FILE REGIONS instead:
   ``os.sendfile`` — payload bytes go mmap-page -> socket inside the
   kernel and never enter the interpreter; only the ~9-byte frame header
   stays Python. ``py_bytes_per_frame ~= 0`` on the spliced path, by
-  construction, and the PR 16 cost model measures it.
+  construction; the PR 16 cost model measures it and
+  ``tests/test_workers.py`` pins it.
 - **capability probe** — ``os.sendfile`` is Linux/macOS/FreeBSD; exotic
   sockets (AF_UNIX on some kernels, TLS wrappers) refuse it at call
   time with ENOTSOCK/EINVAL. :func:`sendfile_capable` answers the

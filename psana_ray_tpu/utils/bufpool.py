@@ -1,8 +1,7 @@
 """Size-classed receive-buffer pool with lease/release semantics.
 
-PERF_NOTES round 3 decomposed the host fan-in ceiling to raw memory
-traffic: >= 3 frame-sized copies per frame plus a fresh multi-MB
-allocation per hop. ``enable_large_alloc_reuse`` (utils/hostmem.py)
+The host fan-in ceiling is raw memory traffic: >= 3 frame-sized copies
+per frame plus a fresh multi-MB allocation per hop. ``enable_large_alloc_reuse`` (utils/hostmem.py)
 attacked the allocation half indirectly, by asking glibc to keep
 MB-scale blocks on the heap; this module attacks it EXPLICITLY — the
 transport hot path leases recycled buffers from a process-wide pool, so
@@ -14,8 +13,8 @@ steady-state receive costs zero allocations regardless of libc:
   and also runs on GC, so a leaked record can delay reuse but never
   corrupts it (a buffer is NEVER handed out while its lease is alive);
 - :class:`WireCounters` — process-wide copy accounting
-  (``wire.bytes_copied`` / ``wire.copies_total``) so the bench can
-  report copies/frame instead of inferring it.
+  (``wire.bytes_copied`` / ``wire.copies_total``) so a test can
+  pin copies/frame instead of inferring it.
 
 Contract for view-backed records (records.decode with a lease): the
 numpy view into the leased buffer is valid for the LIFETIME OF THE
@@ -250,9 +249,9 @@ class WireCounters:
     """Process-wide payload-copy accounting for the wire datapath.
 
     Every frame-sized memcpy on the host datapath (decode-with-copy,
-    encode-into-slot, batch-arena assembly) reports here, so the bench's
-    host-datapath section can state copies/frame as a measurement, and a
-    test can pin the consumer side to exactly one copy. Registered as
+    encode-into-slot, batch-arena assembly) reports here, so copies/frame is a
+    count and not an inference: ``tests/test_wire_zero_copy.py`` pins the
+    consumer side to exactly one copy. Registered as
     the ``wire`` obs source alongside the default pool.
     """
 

@@ -4,15 +4,13 @@ Every frame and batch buffer in the infeed path is megabytes — far above
 glibc's default 128 KB mmap threshold, so malloc serves each one with a
 fresh mmap and frees it with munmap. The hidden cost is not the syscall
 but the PAGE FAULTS: every reallocated buffer is re-faulted (and
-kernel-zeroed) page by page on first touch, which measured ~2x slower
-than the actual memcpy through it on the streaming path (PERF_NOTES.md
-round 3: batcher assembly at 1.6 GB/s effective vs 8.8 GB/s copy
-bandwidth).
+kernel-zeroed) page by page on first touch, which costs more than the
+actual memcpy through it on the streaming path.
 
 ``enable_large_alloc_reuse()`` raises the mmap threshold so MB-scale
 blocks come from the regular heap and get REUSED across frames/batches —
 one fault per page for the process lifetime instead of per allocation.
-Call it once at process start (producer CLIs, consumers, bench do);
+Call it once at process start (producer CLIs and consumers do);
 it is a no-op on non-glibc platforms.
 """
 

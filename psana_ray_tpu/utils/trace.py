@@ -9,7 +9,7 @@ TensorBoard or Perfetto (``tensorboard --logdir <dir>`` -> Profile tab).
 Three surfaces:
 
 - :func:`trace` — context manager capturing a device trace of the
-  enclosed block (producer/consumer loops, a bench section);
+  enclosed block (producer/consumer loops, a benchmark window);
 - :func:`annotate` — named region that shows up on the trace timeline;
 - :class:`phase` — THE way a serving loop marks what its thread is doing:
   one mark feeds the profiler timeline, the flame sampler's stage tags,

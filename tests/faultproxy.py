@@ -34,8 +34,7 @@ local port and forward to a destination.
     offering at the configured rate while the server drowns, which is
     exactly the adversary an admission-controlled gateway exists for
     (a closed-loop client would politely back off and hide the
-    overload). Reused by the bench ``serving`` section and the gateway
-    tests.
+    overload). Used by the gateway tests.
 
 :class:`FaultProxy`
     Byte-counting fault injector. Faults are armed per direction

@@ -430,7 +430,7 @@ class TestAutoCodecDecision:
     """``--wire_codec auto`` (ISSUE 15 satellite): one-shot decision at
     connect from the link-rate probe, re-evaluated on reconnect,
     breadcrumbed — forced both ways via the env threshold override (no
-    link shaping needed; the bench's A/B runs the real throttle)."""
+    link shaping needed)."""
 
     def test_fast_link_decides_off(self, monkeypatch):
         monkeypatch.setenv("PSANA_AUTO_CODEC_MB_S", "0.000001")

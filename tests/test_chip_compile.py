@@ -130,19 +130,6 @@ def _resnet_stage4():
     ], 1
 
 
-def _unet_level1():
-    """PeakNet-TPU encoder level 1 at s2d=2, two frames: 88x96, 64 -> 128
-    channels, with its stride-2 downsample."""
-    from psana_ray_tpu.models.pallas_unet import fused_conv_block
-
-    def fn(x, w1, s1, b1, w2, s2, b2, wd):
-        return fused_conv_block(x, w1, (s1, b1), w2, (s2, b2), wd=wd, interpret=False)
-
-    vec = S((128,), F32)
-    k = S((3, 3, 128, 128), F32)
-    return fn, [S((32, 88, 96, 64), BF16), S((3, 3, 64, 128), F32), vec, vec, k, vec, vec, k], 1
-
-
 def _sfx_serve_step(per_frame=True):
     """The program ``python -m psana_ray_tpu.sfx`` compiles at its
     defaults: u16 frames -> fused calibration -> PeakNetUNetTPU
@@ -391,7 +378,6 @@ CASES = {
     "sfx_serve_step_cli_defaults": _sfx_serve_step,
     "sfx_serve_step_whole_array": lambda: _sfx_serve_step(per_frame=False),
     "resnet50_stage4_bottleneck": _resnet_stage4,
-    "unet_level1_conv_block": _unet_level1,
     "flash_fwd_2x4x8448x128": _flash_fwd,
     "flash_bwd_2x4x8448x128": _flash_bwd,
 }
@@ -475,14 +461,19 @@ def _peaks_read_the_packed_map(text, rows):
     assert re.search(rf"^\s*{re.escape(operand)} = f32\[{rows},15104\]", entry, re.M), operand
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
-def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
-    fn, arg_shapes, min_mosaic, *pins = CASES[case]()
+def _compile_case(fn, arg_shapes, one_chip, monkeypatch):
+    """One of ``CASES``' programs compiled for the described chip."""
     # code that asks default_backend() would take its CPU (interpret)
     # branch under a described topology; steer it here, not in the program
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), arg_shapes)
-    compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
+    return jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
+    fn, arg_shapes, min_mosaic, *pins = CASES[case]()
+    compiled = _compile_case(fn, arg_shapes, one_chip, monkeypatch)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= min_mosaic
     for pin in pins:
@@ -492,6 +483,21 @@ def test_compiles_for_described_v5e(case, one_chip, monkeypatch):
     assert (
         mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
     ) < 16e9
+
+
+def test_the_served_peaknet_is_the_plain_flax_model(one_chip, monkeypatch):
+    """ROADMAP S1 (4), as compiled for the described v5e: the SFX step's
+    only Mosaic kernels are the calibration kernel and ``peak_nms``, one
+    call each, and none stands under the scope ``peaknet`` — the U-Net is
+    XLA's own convolutions. ``test_compiles_for_described_v5e`` counts
+    kernels from below only (``>= min_mosaic``)."""
+    fn, arg_shapes, *_ = _sfx_serve_step()
+    text = _compile_case(fn, arg_shapes, one_chip, monkeypatch).as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = sorted(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
+    assert names == ["fused_calibrate", "peak_nms"], names
+    assert not [line[:200] for line in calls if "/peaknet/" in line]
+    assert any("/peaknet/" in line and " convolution(" in line for line in text.splitlines())
 
 
 # sha256 of the served step's lowered text (StableHLO), the kernels' serialized bodies cut out

@@ -2,9 +2,9 @@
 routing-client semantics, consumer groups with generation-fenced
 rebalance, cross-server EOS aggregation, and server-death failover.
 
-Everything here is jax-free and loopback-only. Wall-clock throughput
-lives in bench.py's ``cluster-scaling`` section; the tier-1 acceptance
-pin below uses the deterministic message-count proxy (the PR 5/6
+Everything here is jax-free and loopback-only. Wall-clock throughput is
+no tier-1 matter (the slow-marked class below models it); the tier-1
+acceptance pin uses the deterministic message-count proxy (the PR 5/6
 flake-avoidance convention): with a balanced map over 4 servers no
 server hosts more than 3/8 of the stream, so aggregate capacity is
 >= 2x any single server's at equal service rates — and every frame is
@@ -785,7 +785,7 @@ def _balanced_queue_name(addrs, P=8, per_server_cap=3):
 
 
 class _RelayCore:
-    """Saturated-relay model shared with bench cluster-scaling: one
+    """Saturated-relay model: one
     token bucket per server caps its queue ops/s — the regime where the
     single Python relay core is the bottleneck (ROADMAP item 2), which
     a 2-core loopback box cannot otherwise reach."""
@@ -884,14 +884,14 @@ class TestClusterScalingWallClock:
                 break
         assert best >= 2.0, (
             f"4-server aggregate only {best:.2f}x the 1-server figure "
-            f"under the saturated-relay model (bench measured 2.6x)"
+            f"under the saturated-relay model"
         )
 
 
 class TestClusterScalingProxy:
     def test_four_servers_balanced_capacity_and_complete_delivery(self):
         """ISSUE 7 acceptance, deterministic proxy form (the wall-clock
-        2x row lives in bench cluster-scaling): with 4 servers and a
+        2x is the slow-marked class above): with 4 servers and a
         balanced 8-partition map, round-robin placement puts <= 3/8 of
         the stream on any one server — aggregate capacity >= 2x any
         single server at equal service rates — and the merged streams

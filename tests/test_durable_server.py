@@ -16,7 +16,9 @@ from faultproxy import FaultProxy
 from psana_ray_tpu.obs.flight import FLIGHT
 from psana_ray_tpu.records import EndOfStream, FrameRecord, is_eos
 from psana_ray_tpu.storage import DurableRingBuffer, SegmentLog
+from psana_ray_tpu.transport import RingBuffer
 from psana_ray_tpu.transport.tcp import TcpQueueClient, TcpQueueServer
+from psana_ray_tpu.utils.bufpool import WIRE
 
 
 def _rec(i, shape=(1, 16, 16)):
@@ -357,6 +359,43 @@ class TestSpillThroughRelay:
             cons.disconnect()
         finally:
             srv.shutdown()
+
+
+    @pytest.mark.parametrize("fsync,relay_copies", [(None, 0), ("none", 1), ("batch", 1)],
+                             ids=["log_off", "fsync_none", "fsync_batch"])
+    def test_relay_added_copies_a_frame(self, tmp_path, fsync, relay_copies):
+        """What durability costs the relay in payload copies, counted:
+        a memory-only server adds none (put by ``sendmsg``, the queued
+        record views its receive lease, the get sends that same buffer),
+        a log-backed one adds EXACTLY one — ``encode_into``'s memcpy into
+        the mmap'd segment, no intermediate bytes — whatever the fsync
+        policy. (The consumer's batch-arena copy is downstream of the
+        relay: ``tests/test_wire_zero_copy.py``.)"""
+        n = 48
+        if fsync is None:
+            srv = TcpQueueServer(RingBuffer(64), host="127.0.0.1").serve_background()
+        else:
+            srv = _durable_server(tmp_path, maxsize=64, fsync=fsync, fsync_batch_n=8)
+        try:
+            prod = TcpQueueClient("127.0.0.1", srv.port)
+            cons = TcpQueueClient("127.0.0.1", srv.port)
+            c0 = WIRE.stats()
+            for i in range(n):
+                assert prod.put_pipelined(_rec(i), deadline=time.monotonic() + 30)
+            assert prod.flush_puts(deadline=time.monotonic() + 30)
+            assert prod.put_wait(EndOfStream(total_events=n), timeout=30)
+            got = [r for r in _drain(cons) if not is_eos(r)]
+            c1 = WIRE.stats()
+            assert [r.event_idx for r in got] == list(range(n))
+            assert np.array_equal(got[-1].panels, _rec(n - 1).panels)
+            prod.disconnect()
+            cons.disconnect()
+        finally:
+            srv.shutdown()
+        assert c1["copies_total"] - c0["copies_total"] == relay_copies * n
+        assert c1["bytes_copied_total"] - c0["bytes_copied_total"] == (
+            relay_copies * n * _rec(0).panels.nbytes
+        )
 
 
 class TestFaultProxyDriven:

@@ -6,6 +6,9 @@ each detector's step must compile exactly once (fixed per-detector shapes
 streams must be processed before the loop ends.
 """
 
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -17,6 +20,8 @@ import pytest
 from psana_ray_tpu.infeed import DetectorStream, FanInPipeline
 from psana_ray_tpu.records import EndOfStream, FrameRecord
 from psana_ray_tpu.transport import RingBuffer, TransportClosed
+from psana_ray_tpu.transport.shm_ring import ShmRingBuffer, native_available
+from psana_ray_tpu.utils.bufpool import WIRE
 
 EPIX_SHAPE = (2, 16, 24)  # scaled-down epix10k2M (16, 352, 384)
 JF_SHAPE = (1, 32, 8)  # scaled-down jungfrau4M (8, 512, 1024)
@@ -46,6 +51,90 @@ def _start_producers(specs):
     for t in threads:
         t.start()
     return threads
+
+
+# A detector's ingest process, as deployed: its own interpreter, no JAX,
+# frame i filled with i so a consumer can tell a torn or doubled one.
+_SHM_PRODUCER = """
+import sys, time
+import numpy as np
+from psana_ray_tpu.records import EndOfStream, FrameRecord
+from psana_ray_tpu.transport.shm_ring import ShmRingBuffer
+name, n, shape = sys.argv[1], int(sys.argv[2]), tuple(int(x) for x in sys.argv[3].split(","))
+ring = ShmRingBuffer.attach(name, retries=40, interval_s=0.25)
+for i in range(n):
+    rec = FrameRecord(0, i, np.full(shape, i % 4096, np.uint16), 9.5)
+    while not ring.put(rec):
+        time.sleep(0.001)
+assert ring.put_wait(EndOfStream(total_events=n), timeout=120.0)
+ring.disconnect()
+"""
+
+
+@pytest.mark.skipif(not native_available(), reason="native toolchain unavailable")
+@pytest.mark.parametrize(
+    "shapes,counts,batches",
+    [
+        # frame-bound: few large frames, tails that pad (37 % 8, 19 % 4)
+        pytest.param(((4, 64, 96), (2, 128, 64)), (37, 19), (8, 4), id="frame_bound"),
+        # record-bound: many small frames, the rings wrap dozens of times
+        pytest.param(((1, 8, 16), (1, 16, 8)), (600, 300), (64, 32), id="record_bound"),
+    ],
+)
+def test_two_processes_two_shm_rings_no_loss_no_double_one_copy(shapes, counts, batches):
+    """Two detectors, each its own producer PROCESS and shm ring, merged
+    by one FanInPipeline on the host: every frame arrives exactly once,
+    intact, and the consumer side pays ONE payload copy a frame (slot
+    view -> batch arena; the producers' copies into their slots are
+    theirs, in their processes)."""
+    names = ("epix", "jf")
+    ring_names = {det: f"fanin_{det}_{os.getpid()}_{time.monotonic_ns()}" for det in names}
+    rings, procs = {}, []
+    try:
+        for det, shape in zip(names, shapes):
+            rings[det] = ShmRingBuffer.create(
+                ring_names[det], maxsize=8, slot_bytes=int(np.prod(shape)) * 2 + 4096
+            )
+        fan = FanInPipeline(
+            [
+                DetectorStream(det, rings[det], batch_size=b, poll_interval_s=0.002,
+                               place_on_device=False)
+                for det, b in zip(names, batches)
+            ]
+        )
+        c0 = WIRE.stats()
+        for det, shape, n in zip(names, shapes, counts):
+            procs.append(subprocess.Popen(
+                [sys.executable, "-c", _SHM_PRODUCER, ring_names[det], str(n),
+                 ",".join(map(str, shape))],
+                cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ))
+        seen = {det: [] for det in names}
+
+        def on_result(name, out, batch):
+            idx = batch.event_idx[: batch.num_valid]
+            # frame i is all-i: a torn or recycled slot would not be
+            assert (batch.frames[: batch.num_valid].reshape(len(idx), -1)
+                    == (idx % 4096)[:, None]).all()
+            seen[name].extend(int(i) for i in idx)
+
+        got = fan.run({det: (lambda b: None) for det in names}, on_result=on_result)
+        for p in procs:
+            assert p.wait(timeout=60) == 0
+        c1 = WIRE.stats()
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.kill()
+        for r in rings.values():
+            r.destroy()
+    assert got == dict(zip(names, counts))
+    for det, n in zip(names, counts):
+        assert sorted(seen[det]) == list(range(n)), f"{det}: lost or doubled frames"
+    assert c1["copies_total"] - c0["copies_total"] == sum(counts)
+    assert c1["bytes_copied_total"] - c0["bytes_copied_total"] == sum(
+        n * int(np.prod(s)) * 2 for n, s in zip(counts, shapes)
+    )
 
 
 class TestFanInPipeline:

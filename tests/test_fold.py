@@ -82,27 +82,6 @@ class TestFoldPeakNetTPU:
         got = frozen_model.apply(folded, x)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=1e-4, atol=1e-4)
 
-    def test_folded_params_feed_fused_infer(self, rng):
-        """The whole point: a trained-then-folded checkpoint must drive
-        peaknet_tpu_fused_infer (interpret mode on CPU = same math as the
-        TPU kernels)."""
-        from psana_ray_tpu.models.pallas_unet import peaknet_tpu_fused_infer
-
-        features = (8, 16, 16)
-        train_model = PeakNetUNetTPU(features=features, norm="batch")
-        x = jnp.asarray(rng.normal(size=(1, 32, 64, 1)).astype(np.float32))
-        variables = _train_mode_stats(train_model, x)
-        folded = fold_batchnorm(variables)
-
-        frozen_model = PeakNetUNetTPU(features=features, norm="frozen")
-        ref = np.asarray(frozen_model.apply(folded, x), np.float32)
-        got = np.asarray(
-            peaknet_tpu_fused_infer(folded, x, features=features, interpret=True),
-            np.float32,
-        )
-        rel = np.max(np.abs(ref - got)) / max(np.max(np.abs(ref)), 1e-3)
-        assert rel < 0.05  # bf16 kernel tolerance (same bar as test_pallas_unet)
-
 
 class TestBatchNormTraining:
     def test_train_step_updates_stats_and_params(self):

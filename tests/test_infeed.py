@@ -479,7 +479,7 @@ class TestUint16Stream:
 class TestPooledBatcher:
     """FrameBatcher(n_buffers=K): recycled batch-buffer arena (round-3
     fan-in profiling: fresh 100+ MB allocations were re-page-faulted every
-    batch — see utils/hostmem.py and PERF_NOTES)."""
+    batch — see utils/hostmem.py)."""
 
     def test_pool_reuses_buffers_round_robin(self):
         b = FrameBatcher(batch_size=2, n_buffers=2)

@@ -40,7 +40,7 @@ def test_shipped_tree_is_clean_under_full_registry():
     assert result.ok, "lint findings on the shipped tree:\n" + "\n".join(
         f.render() for f in result.findings
     )
-    assert result.files_scanned > 50  # the whole package + bench.py
+    assert result.files_scanned > 50  # the whole package
     assert set(result.checkers_run) == set(REGISTRY)
     assert result.duration_s < 25.0, (
         f"full registry took {result.duration_s:.2f}s — the budget keeps "
@@ -52,6 +52,20 @@ def test_shipped_tree_is_clean_under_full_registry():
         f"with the tree, never delete it; the <2 s incremental gate is "
         f"--changed, pinned below)"
     )
+
+
+def test_the_default_scan_is_the_package_and_nothing_beside_it():
+    """The invariants are the package's: a script beside it that the
+    default scan picked up would need allowlist entries of its own, and
+    a tool's defaults would bend round a file no entry point imports."""
+    from psana_ray_tpu.lint.core import PACKAGE_DIR, default_target_files
+
+    files = default_target_files()
+    assert files and all(f.is_relative_to(PACKAGE_DIR) for f in files)
+    assert {f.resolve() for f in files} == {f.resolve() for f in PACKAGE_DIR.rglob("*.py")}
+    scanned = [f.as_posix() for f in files]
+    outside = sorted({e.file for e in ALLOWLIST if not any(p.endswith("/" + e.file) for p in scanned)})
+    assert not outside, f"allowlist entries for files outside the package: {outside}"
 
 
 def test_every_allowlist_entry_has_a_justification():

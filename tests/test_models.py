@@ -173,7 +173,7 @@ _UPCONV_CHANNELS = [(1, 1), (8, 4), (16, 16)]
 
 # every leaf of PeakNetUNetTPU(features=(8, 16, 32), s2d=2, norm='frozen'),
 # written out: checkpoints, benchmark/reference/peaknet.py, sfx.infer_s2d
-# and infer_features, pallas_unet.peaknet_tpu_fused_infer go by these paths
+# and infer_features go by these paths
 _TPU_FROZEN_TREE = {
     "ConvBlock_0/Conv_0/kernel": (3, 3, 4, 8), "ConvBlock_0/Conv_1/kernel": (3, 3, 8, 8),
     "ConvBlock_0/FrozenAffine_0/bias": (8,), "ConvBlock_0/FrozenAffine_0/scale": (8,),
@@ -390,7 +390,7 @@ class TestUNetTPU:
 
 class TestHostInit:
     """host_init (jitted init on the CPU backend) and eval_shape_init
-    (the explicit no-trace numpy build bench.py's serving export uses)."""
+    (the explicit no-trace numpy build)."""
 
     def test_eval_shape_init_matches_real_init_structure(self):
         from psana_ray_tpu.models.init import eval_shape_init

@@ -264,8 +264,8 @@ class TestExpertParallel:
 
 
 def test_serving_capacity_factor_is_trace_time_only():
-    """The serving-side capacity trick (bench: train at cf=2.0, serve at
-    cf=1.25 for ~10% fps): expert capacity is a trace-time constant, so
+    """The serving-side capacity trick (train at cf=2.0, serve at
+    cf=1.25): expert capacity is a trace-time constant, so
     one trained tree must apply unchanged under ANY capacity factor, and
     with capacity >= tokens/expert-worst-case the outputs must agree
     exactly (no token ever dropped at either setting)."""

@@ -160,7 +160,7 @@ class TestResNetFusedInfer:
 def test_small_extent_falls_back_to_flax():
     """Inputs too small for the fused stage pipeline (deep stages would
     degenerate to 0 rows) must run the plain flax forward, not crash in a
-    kernel slice — the bench smoke geometry (16x128) hit exactly this."""
+    kernel slice — the smoke geometry (16x128) hit exactly this."""
     from psana_ray_tpu.models import ResNet50, host_init, panels_to_nhwc
     from psana_ray_tpu.models.pallas_resnet import resnet_fused_infer
 

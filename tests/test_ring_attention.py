@@ -419,8 +419,7 @@ def test_ulysses_flash_matches_reference_and_grads(seq_mesh, causal):
 
 
 class TestPickBlocks:
-    """Block-shape selection invariants (the 6x kernel lever — see
-    PERF_NOTES.md round-4 section): picked blocks must divide the
+    """Block-shape selection invariants: picked blocks must divide the
     sequence lengths and respect both VMEM footprint caps."""
 
     def test_vit_serving_shape(self):

@@ -8,11 +8,19 @@ tier-1 driver; these two tests pin the MIGRATED screens by name so the
 original invariants keep their own failure identity (a hot-path
 regression fails here exactly as it did pre-framework, not just inside
 an aggregate lint test).
+
+A third screen is over the records, not the code: the files the
+operator documents cite exist.
 """
 
 from __future__ import annotations
 
+import re
+
+import pytest
+
 from psana_ray_tpu.lint import run_lint
+from psana_ray_tpu.lint.core import PACKAGE_DIR, REPO_ROOT
 
 
 def _findings(checker: str):
@@ -32,3 +40,24 @@ def test_hot_path_has_no_per_frame_allocation_idioms():
     .tobytes()/.to_bytes(/raw .recv(/bytes(...) per-frame idioms."""
     found = _findings("hot-alloc")
     assert not found, "\n".join(f.render() for f in found)
+
+
+# the one path the operator documents cite that is NOT this repo's: the
+# reference project's own consumer, in MIGRATION.md's "psana-ray" column
+_REFERENCE_REPO_FILES = {"examples/psana_consumer.py"}
+
+
+@pytest.mark.parametrize("doc", ["README.md", "PARITY.md", "MIGRATION.md"])
+def test_every_file_an_operator_document_cites_exists(doc):
+    """The documents a new owner reads first send the reader to files
+    (``tests/test_x.py``, ``models/fold.py``, ``benchmark/run.py``): each
+    such path, as written or under the package, must exist — a deleted
+    module or script must take its citations with it."""
+    text = (REPO_ROOT / doc).read_text()
+    cited = set(re.findall(r"`([\w.\-]+/[\w./\-]+\.(?:py|json|cpp|md|toml))(?:::?[\w:.\-\[\]]+)?`", text))
+    assert cited, f"{doc} cites no file: the pattern has rotted"
+    gone = sorted(
+        p for p in cited - _REFERENCE_REPO_FILES
+        if not (REPO_ROOT / p).exists() and not (PACKAGE_DIR / p).exists()
+    )
+    assert not gone, f"{doc} cites files that do not exist: {gone}"

@@ -118,6 +118,54 @@ class TestStreamBasics:
             srv.shutdown()
 
 
+class TestCreditWindow:
+    @pytest.mark.parametrize("window", [1, 4])
+    def test_server_pushes_no_further_than_the_unacked_window(self, window):
+        """The stream's flow control, counted on the server's own
+        ``stream`` gauges: with a backlog of 20 it pushes ``window``
+        frames and stops; only a cumulative ack (the client coming back
+        for more) buys the next ``window``; a clean drain redelivers
+        nothing. ``window=1`` is stop-and-wait."""
+        q, srv = _mk()
+        n = 20
+
+        def pushed():
+            return STREAM.stats()["frames_pushed_total"] - base["frames_pushed_total"]
+
+        def settled(target):  # pushed since base, once it stops moving
+            deadline = time.monotonic() + 5.0
+            while pushed() < target and time.monotonic() < deadline:
+                time.sleep(0.01)
+            time.sleep(0.15)  # a server that meant to push past the window would have
+            return pushed()
+
+        try:
+            base = STREAM.stats()
+            for i in range(n):
+                q.put(_rec(i))
+            c = TcpQueueClient("127.0.0.1", srv.port)
+            c.stream_open(window=window)
+            assert settled(window) == window
+            assert q.size() == n - window
+            assert STREAM.stats()["inflight"] - base["inflight"] == window
+            got = c.get_batch_stream(window, timeout=2.0)  # consumed, not yet acked
+            assert len(got) == window and settled(window) == window
+            while len(got) < n:  # each call acks what the last one returned
+                got.extend(c.get_batch_stream(window, timeout=2.0))
+                assert pushed() <= len(got) + window
+            assert [r.event_idx for r in got] == list(range(n))
+            c.disconnect()  # final cumulative ack
+            assert settled(n) == n  # the loop counts a push after its send
+            deadline = time.monotonic() + 5.0
+            while STREAM.stats()["inflight"] != base["inflight"] and time.monotonic() < deadline:
+                time.sleep(0.01)
+            end = STREAM.stats()
+            assert end["inflight"] == base["inflight"]
+            assert end["redelivered_total"] == base["redelivered_total"] and q.size() == 0
+        finally:
+            srv.shutdown()
+
+
 class TestCrashRedeliveryStreaming:
     """ISSUE 5 acceptance: kill a streaming consumer mid-window and every
     un-ACKed frame redelivers to a second consumer — duplicates allowed,
@@ -675,7 +723,7 @@ class TestRttIndependence:
         (~7 ms/frame) is commensurate with the RTT, so the theoretical
         streaming win is (RTT + transfer)/transfer ≈ 2.5x, not 10x — the
         10x regime needs RTT >> transfer (the 16 KB test above, or real
-        NICs at multi-GB/s; PERF_NOTES has the arithmetic). What MUST
+        NICs at multi-GB/s). What MUST
         hold at frame scale: streaming removes the RTT tax (well above
         the no-pipelining baseline) and never regresses to it."""
         n = 24

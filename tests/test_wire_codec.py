@@ -548,8 +548,7 @@ class TestWireSavings:
         """The deterministic acceptance proxy (no wall clocks — this
         box's CPU share flutters): the SAME stream through the SAME
         byte-counting proxy must put >= 2x fewer bytes on the wire
-        compressed than raw; the >= 2x FPS number through the real
-        50 MB/s throttle is recorded by bench.py (measured 3.19x)."""
+        compressed than raw."""
         frames = [FrameRecord(0, i, detector_u16(), 9.5) for i in range(4)]
 
         def run(codec):

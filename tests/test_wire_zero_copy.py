@@ -5,6 +5,7 @@ one (the batch-arena memcpy), with steady-state recv allocations zero.
 """
 
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -219,7 +220,12 @@ class TestTcpCopyCount:
     (request/response pull and the ISSUE 5 server-push stream the
     batcher now prefers)."""
 
-    def _run_relay(self, n, prefer_stream, pool=None, codec=None, shape=(2, 16, 16)):
+    def _run_relay(
+        self, n, prefer_stream, pool=None, codec=None, shape=(2, 16, 16), stamp=None
+    ):
+        """``stamp(rec, port) -> rec`` is the producer's hook on each
+        frame (the tracer's sampling gate; an observer of the live server
+        on ``port``)."""
         q = RingBuffer(16)
         srv = TcpQueueServer(q, host="127.0.0.1", pool=pool).serve_background()
         prod = TcpQueueClient("127.0.0.1", srv.port, pool=pool, codec=codec)
@@ -228,7 +234,8 @@ class TestTcpCopyCount:
 
             def produce():
                 for i in range(n):
-                    assert prod.put_wait(_rec(i, shape=shape), timeout=30)
+                    rec = _rec(i, shape=shape)
+                    assert prod.put_wait(stamp(rec, srv.port) if stamp else rec, timeout=30)
                 assert prod.put_wait(EndOfStream(total_events=n), timeout=30)
 
             t = threading.Thread(target=produce, daemon=True)
@@ -269,6 +276,30 @@ class TestTcpCopyCount:
                 if release is not None:
                     release()
 
+    @staticmethod
+    def _assert_no_churn_no_leaks(pool, what):
+        """Zero pool-churn allocations on an instrumented private pool
+        (working-set growth up to the credit window is not churn), and
+        every lease back once the drain's final ack has landed."""
+        s = pool.stats()
+        assert s["churn_misses"] == 0, (
+            f"{what} churned {s['churn_misses']} allocations (pool: {s})"
+        )
+        # the last pushed window stays leased until the client's final
+        # cumulative ack (sent at disconnect) prunes it server-side —
+        # that retention IS the redelivery guarantee, so allow the
+        # asynchronous prune a moment before calling anything a leak
+        # (10 s: under a CPU-share-throttled full tier-1 run the prune
+        # + record GC episodically exceeded the old 2 s grace — a leak
+        # never clears however long we wait, so the wider window only
+        # trades flake for patience)
+        deadline = time.monotonic() + 10.0
+        while pool.stats()["leases"] and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert pool.stats()["leases"] == 0, (
+            f"{what} leaked leases after drain+ack: {pool.stats()}"
+        )
+
     def test_consumer_side_exactly_one_copy_per_frame(self):
         n = 24
         copies, nbytes = self._run_relay(n, prefer_stream=False)
@@ -288,27 +319,7 @@ class TestTcpCopyCount:
         copies, nbytes = self._run_relay(n, prefer_stream=True, pool=pool)
         assert copies == n, f"expected exactly 1 copy/frame, got {copies}/{n}"
         assert nbytes == n * _rec(0, shape=(2, 16, 16)).nbytes
-        s = pool.stats()
-        assert s["churn_misses"] == 0, (
-            f"streaming path churned {s['churn_misses']} allocations "
-            f"(pool: {s})"
-        )
-        # the last pushed window stays leased until the client's final
-        # cumulative ack (sent at disconnect) prunes it server-side —
-        # that retention IS the redelivery guarantee, so allow the
-        # asynchronous prune a moment before calling anything a leak
-        # (10 s: under a CPU-share-throttled full tier-1 run the prune
-        # + record GC episodically exceeded the old 2 s grace — a leak
-        # never clears however long we wait, so the wider window only
-        # trades flake for patience)
-        import time as _time
-
-        deadline = _time.monotonic() + 10.0
-        while pool.stats()["leases"] and _time.monotonic() < deadline:
-            _time.sleep(0.01)
-        assert pool.stats()["leases"] == 0, (
-            f"leaked leases after drain+ack: {pool.stats()}"
-        )
+        self._assert_no_churn_no_leaks(pool, "streaming path")
 
     def test_compressed_streaming_one_copy_zero_alloc_zero_leaks(self):
         """ISSUE 9 acceptance pin: the NEGOTIATED-CODEC streaming path
@@ -334,19 +345,81 @@ class TestTcpCopyCount:
         assert s1["frames_compressed_total"] > s0["frames_compressed_total"]
         assert copies == n, f"expected exactly 1 copy/frame, got {copies}/{n}"
         assert nbytes == n * _rec(0, shape=shape).nbytes
-        s = pool.stats()
-        assert s["churn_misses"] == 0, (
-            f"compressed streaming churned {s['churn_misses']} allocations "
-            f"(pool: {s})"
-        )
-        import time as _time
+        self._assert_no_churn_no_leaks(pool, "compressed streaming")
 
-        deadline = _time.monotonic() + 10.0
-        while pool.stats()["leases"] and _time.monotonic() < deadline:
-            _time.sleep(0.01)
-        assert pool.stats()["leases"] == 0, (
-            f"leaked leases after compressed drain+ack: {pool.stats()}"
-        )
+    @pytest.mark.parametrize("prefer_stream", [False, True], ids=["pull", "stream"])
+    @pytest.mark.parametrize("observer", ["tracer_1_in_16", "flame_sampler", "telemetry_plane"])
+    def test_observed_relay_still_one_copy_zero_alloc(self, observer, prefer_stream, tmp_path):
+        """What watches the relay reads counters and stacks, never
+        frames: with the tracer sampling 1 frame in 16, with the 97 Hz
+        flame sampler on this process's threads, and with the history
+        sampler and a federation collector sweeping the live server
+        mid-stream, both drain modes still make exactly one copy a frame
+        and churn no allocation. Each observer is made to act WHILE
+        frames are in flight (the producer's hook waits for it), so the
+        pin cannot pass by finishing first."""
+        import dataclasses
+
+        from psana_ray_tpu.obs.collector import ClusterCollector
+        from psana_ray_tpu.obs.profiling import FlameSampler
+        from psana_ray_tpu.obs.timeseries import HistorySampler
+        from psana_ray_tpu.obs.tracing import TRACER
+
+        pool = BufferPool()
+        n = 48
+        acted = []  # what the observer did while the stream ran
+
+        if observer == "tracer_1_in_16":
+            TRACER.configure(str(tmp_path), sample_every=16, process="relay")
+
+            def stamp(rec, port):
+                return dataclasses.replace(rec, trace=TRACER.maybe_trace())
+
+            def stop():
+                acted.append(TRACER.snapshot()["spans_total"])
+                TRACER.close()
+
+        elif observer == "flame_sampler":
+            flame = FlameSampler(hz=97.0, process="relay", register=False).start()
+
+            def stamp(rec, port):
+                if rec.event_idx % 16 == 8:  # a sample lands between two frames
+                    seen, deadline = flame.trie.samples_total, time.monotonic() + 5.0
+                    while flame.trie.samples_total == seen and time.monotonic() < deadline:
+                        time.sleep(0.002)
+                    acted.append(flame.trie.samples_total - seen)
+                return rec
+
+            def stop():
+                flame.stop(write_spool=False)
+
+        else:
+            history = HistorySampler(interval_s=3600.0)  # swept by hand, below
+            collector = []  # needs the live server's port: made mid-stream
+
+            def stamp(rec, port):
+                if rec.event_idx % 16 == 8:
+                    if not collector:
+                        collector.append(ClusterCollector(
+                            [f"127.0.0.1:{port}"], interval_s=3600.0, register=False
+                        ))
+                    history.sample_once()
+                    acted.append(collector[0].poll_once())
+                return rec
+
+            def stop():
+                for c in collector:
+                    c.stop()
+                    assert c.snapshot()["pulls_ok_total"] == len(acted), c.snapshot()
+
+        try:
+            copies, nbytes = self._run_relay(n, prefer_stream, pool=pool, stamp=stamp)
+        finally:
+            stop()
+        assert acted and all(acted), acted
+        assert copies == n, f"expected exactly 1 copy/frame, got {copies}/{n}"
+        assert nbytes == n * _rec(0, shape=(2, 16, 16)).nbytes
+        self._assert_no_churn_no_leaks(pool, f"relay under {observer}")
 
     def test_tcp_roundtrip_content_through_pool(self):
         # recycled buffers must never bleed between frames

@@ -50,6 +50,7 @@ from psana_ray_tpu.transport.workers import (
     queue_owner,
     resolve_port,
 )
+from psana_ray_tpu.utils.bufpool import WIRE
 
 HAVE_REUSEPORT = hasattr(socket, "SO_REUSEPORT")
 HAVE_FORK = hasattr(os, "fork")
@@ -398,6 +399,50 @@ class TestSplicedRelay:
             cons.disconnect()
         finally:
             srv.shutdown()
+
+
+    @pytest.mark.skipif(not sendfile_capable(), reason="no os.sendfile here")
+    @pytest.mark.parametrize("codec", [None, "shuffle-rle"], ids=["spliced", "materialized"])
+    def test_payload_bytes_through_python_on_the_drain(self, tmp_path, codec):
+        """The drain of a spilled backlog, counted by the wire counters
+        the cost model's ``py_bytes_per_frame`` reads: a plain connection
+        moves 0 payload bytes through the interpreter (mmap page ->
+        socket by ``os.sendfile``; the client's records view their
+        receive leases), a compressed one reads every spilled frame back
+        to re-encode it — so the counter would see a lost splice."""
+        n, ram_items = 24, 1
+        srv = _lazy_spill_server(tmp_path, ram_items=ram_items)
+        try:
+            prod = TcpQueueClient(
+                "127.0.0.1", srv.port, namespace="ns", queue_name="pb",
+                reconnect_tries=1,
+            )
+            for i in range(n):
+                assert prod.put(_rec(i))
+            prod.disconnect()
+            # the appends paid their log memcpy above; the window is the drain
+            w0, s0 = WIRE.stats(), SPLICE.snapshot()
+            cons = TcpQueueClient(
+                "127.0.0.1", srv.port, namespace="ns", queue_name="pb",
+                reconnect_tries=1, codec=codec,
+            )
+            got = _drain(cons, n)
+            w1, s1 = WIRE.stats(), SPLICE.snapshot()
+            assert [r.event_idx for r in got] == list(range(n))
+            assert all(
+                np.array_equal(r.panels, _rec(r.event_idx).panels) for r in got
+            )
+            cons.disconnect()
+        finally:
+            srv.shutdown()
+        py_bytes = w1["bytes_copied_total"] - w0["bytes_copied_total"]
+        spliced = s1["spliced_frames_total"] - s0["spliced_frames_total"]
+        if codec is None:
+            assert spliced >= n - ram_items and s1["fallback_total"] == s0["fallback_total"]
+            assert py_bytes == 0, f"{py_bytes} payload bytes crossed Python on a spliced drain"
+        else:
+            assert spliced == 0
+            assert py_bytes >= (n - ram_items) * _rec(0).panels.nbytes
 
 
 # ---------------------------------------------------------------------------

@@ -5,18 +5,22 @@ MLP or a block assembled from a configuration (``vit.py`` hard-codes
 LayerNorm, GELU and a position table). This module builds the text
 decoder of a language model from the keys of its published
 ``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B, DeepSeek-V3's
-block as Kimi-K2 spells it, DeepSeek-V3.2's):
+block as Kimi-K2 spells it, DeepSeek-V3.2's, Ling-3.0's hybrid):
 
     x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
-    x  -> x + ff(rms(x))                      three kinds, its ff one of two
+    x  -> x + ff(rms(x))                      four kinds, its ff one of two
 
 A layer's OPERATOR (``layer_types``) is grouped-query attention (a
 per-head RMS norm on q and k, then the rotary, multimodal or on the
 sequence index); or a gated short convolution (:func:`gated_short_conv`);
+or LINEAR attention (:func:`linear_attention`: the gated delta rule with a
+decay per channel, a float32 state a head carried along the sequence:
+``ops/delta_rule.py``);
 or, where the configuration has a ``kv_lora_rank``, LATENT attention
-(:func:`latent_attention`: low-rank queries, keys and values decompressed
-per head from one normed latent, one rotary key for all heads, YaRN's
-frequencies). Either attention is plain causal or restricted, per query
+(:func:`latent_attention`: queries of full rank or through a normed low
+rank, keys and values decompressed per head from one normed latent, one
+rotary key for all heads, plain or YaRN's frequencies, and where the
+configuration says so a sigmoid gate a head on the output). Either attention is plain causal or restricted, per query
 and in every head alike, to the ``topk`` keys a learned INDEXER ranks
 highest (``parallel/sparse_attention.py``; :func:`_indexer`): its queries
 are projected from the layer's normed input or, under latent attention,
@@ -62,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from psana_ray_tpu.ops.delta_rule import CHUNK, chunk_rows, gated_delta_rule
 from psana_ray_tpu.ops.short_conv import gated_conv_taps
 from psana_ray_tpu.parallel import sparse_attention as sa
 from psana_ray_tpu.parallel.moe import dropless_moe
@@ -90,7 +95,13 @@ PAIR_STATS = (
     "attn_pairs_selected_total",  # (query, key) pairs attended, over the layers with a selection
     "attn_pairs_causal_total",    # pairs at or below the diagonal in those layers
 )
-ATTENTION, CONV = "full_attention", "conv"  # layer_types, as config.json spells them
+# and two after those ten (the two above 0 where nothing selects), where layers are LINEAR
+LINEAR_STATS = (
+    "linear_attn_tokens_total",  # tokens through a linear-attention layer, over such layers
+    "linear_attn_chunks_total",  # chunks of the delta rule's kernel: head-sequences x chunks a layer
+)
+# layer_types, as config.json spells them
+ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
 LATENT = "latent_attention"  # what an ATTENTION layer is under a kv_lora_rank
 
 
@@ -147,13 +158,17 @@ class DecoderConfig:
     rope_theta: float
     # multimodal rotary over (t, h, w) positions; None: plain rotary on the sequence index
     mrope_section: Optional[Tuple[int, int, int]] = None
-    # each layer's operator, ATTENTION or CONV (empty: attention in every layer)
+    # each layer's operator, ATTENTION, CONV or LINEAR (empty: attention in every layer)
     layer_types: Tuple[str, ...] = ()
-    conv_taps: int = 3  # of the gated short convolution (conv_L_cache)
+    conv_taps: int = 3  # of a short convolution (conv_L_cache, short_conv_kernel_size)
+    # linear attention (the gated delta rule with a decay per channel): num_heads heads whose keys
+    # and values are linear_head_dim wide, the log-decay a token in (linear_decay_floor, 0)
+    linear_head_dim: int = 0
+    linear_decay_floor: float = 0.0
     tie_embedding: bool = False  # the output head is the embedding table
     rope_yarn: Optional[Yarn] = None  # YaRN-blended rotary frequencies and the softmax's mscale^2
     # latent attention (kv_lora_rank 0: grouped-query attention): queries through a normed
-    # q_lora_rank, keys and values from ONE normed kv_lora_rank latent; a head's query and key
+    # q_lora_rank (0: of full rank), keys and values from ONE normed kv_lora_rank latent; a head's query and key
     # are [qk_nope_head_dim | qk_rope_head_dim] (head_dim is their sum), the rotary part of the
     # key one for all heads; values v_head_dim wide
     q_lora_rank: int = 0
@@ -161,6 +176,8 @@ class DecoderConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # "head_wise": latent attention's output times sigmoid(a W_G), one scalar a head and token
+    attn_gate: str = ""
     # learned sparse attention (None: plain causal attention). The index queries are projected
     # from the layer's normed input, or from the normed query rank where attention is latent
     indexer_heads: Optional[int] = None
@@ -185,6 +202,9 @@ class DecoderConfig:
     # where the 512 x 512 that kv_tile used to force took 43.4 (my chip runs, PR 47)
     causal_q_tile: int = 1088
     causal_kv_tile: int = 1088
+    # rows a chunk of the delta rule's kernel (the largest whole-tile divisor of S under it): on
+    # the v5e at 4 x 8,704 tokens 128 rows 19.9 ms a layer, 64 rows 26.4 (my chip runs, PR 50)
+    linear_chunk: int = CHUNK
     # experts (num_experts 0: a dense gated MLP of intermediate_size)
     num_experts: int = 0
     experts_per_token: int = 0
@@ -225,9 +245,17 @@ class DecoderConfig:
         return bool(self.indexer_heads) and bool(self.kv_lora_rank)
 
     @property
+    def has_linear(self) -> bool:
+        """Linear-attention layers: the step then counts :data:`SHARE_STATS`,
+        :data:`PAIR_STATS` and :data:`LINEAR_STATS` too."""
+        return LINEAR in self.layer_types
+
+    @property
     def layer_stats(self) -> int:
         """How many statistics a layer counts: the first four of
-        :data:`STEP_STATS`, then the two groups above."""
+        :data:`STEP_STATS`, then the groups above."""
+        if self.has_linear:
+            return 10
         return 8 if self.selects_over_latent else 6 if self.holds_a_share else 4
 
     def layer_kind(self, i: int) -> Tuple[str, bool]:
@@ -257,7 +285,14 @@ class DecoderConfig:
         group limit; and as DeepSeek-V3.2's file has it, ``index_n_heads``,
         ``index_head_dim``, ``index_topk``: the key selection over latent
         attention, whose index key goes through a LayerNorm and whose
-        rotary turns ``qk_rope_head_dim`` of an index vector). Where a
+        rotary turns ``qk_rope_head_dim`` of an index vector); and
+        Ling-3.0's (``bailing_hybrid``: DeepSeek-V3's keys with
+        ``num_experts`` and ``num_shared_experts``; ``q_lora_rank`` null
+        beside a ``kv_lora_rank``: a full-rank query;
+        ``gated_attention_proj_granularity_type``: the output gate;
+        ``layer_types`` entries ``linear_attention`` with ``head_dim``,
+        ``short_conv_kernel_size`` and ``kda_lower_bound``: the delta
+        rule's layers). Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -276,9 +311,9 @@ class DecoderConfig:
         n_layers, heads = int(m["num_hidden_layers"]), int(m["num_attention_heads"])
         layer_types = tuple(m.get("layer_types", ()))
         if layer_types and (len(layer_types) != n_layers
-                            or set(layer_types) - {ATTENTION, CONV}):
+                            or set(layer_types) - {ATTENTION, CONV, LINEAR}):
             raise ValueError(f"layer_types {layer_types} does not name {n_layers} layers' "
-                             f"operators, each {ATTENTION!r} or {CONV!r}")
+                             f"operators, each {ATTENTION!r}, {CONV!r} or {LINEAR!r}")
         rope = m.get("rope_scaling") or {}
         mrope = rope.get("mrope_section")
         yarn = None
@@ -287,9 +322,10 @@ class DecoderConfig:
                         float(rope.get("beta_fast", 32)), float(rope.get("beta_slow", 1)),
                         float(rope.get("mscale", 1)), float(rope.get("mscale_all_dim", 0)))
         latent = int(m.get("kv_lora_rank") or 0)
-        if latent and not m.get("q_lora_rank"):
-            raise ValueError("latent attention without a q_lora_rank (a full-rank query "
-                             "projection) is not built")
+        gate = str(m.get("gated_attention_proj_granularity_type") or "") if latent else ""
+        if gate not in ("", "head_wise"):
+            raise ValueError(f"an output gate of granularity {gate!r} is not built")
+        linear = LINEAR in layer_types
         nope, rope_dim = int(m.get("qk_nope_head_dim", 0)), int(m.get("qk_rope_head_dim", 0))
         deepseek = "scoring_func" in m  # DeepSeek-V3's spelling of the router
         sigmoid = "use_expert_bias" in m or m.get("scoring_func") == "sigmoid"
@@ -302,12 +338,15 @@ class DecoderConfig:
             rms_eps=float(m["rms_norm_eps"] if "rms_norm_eps" in m else m["norm_eps"]),
             rope_theta=float(m["rope_theta"]),
             mrope_section=tuple(int(v) for v in mrope) if mrope else None,
-            layer_types=layer_types, conv_taps=int(m.get("conv_L_cache", 3)),
+            layer_types=layer_types,
+            conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size", 3))),
+            linear_head_dim=int(m["head_dim"]) if linear else 0,
+            linear_decay_floor=float(m["kda_lower_bound"]) if linear else 0.0,
             tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
             rope_yarn=yarn,
             q_lora_rank=int(m.get("q_lora_rank") or 0) if latent else 0, kv_lora_rank=latent,
             qk_nope_head_dim=nope if latent else 0, qk_rope_head_dim=rope_dim if latent else 0,
-            v_head_dim=int(m.get("v_head_dim", 0)) if latent else 0,
+            v_head_dim=int(m.get("v_head_dim", 0)) if latent else 0, attn_gate=gate,
             **index,
             num_experts=n_exp, experts_per_token=int(m.get("num_experts_per_tok", 0)),
             expert_width=int(m.get("moe_intermediate_size", 0)),
@@ -321,7 +360,8 @@ class DecoderConfig:
             routed_scaling_factor=float(m.get("routed_scaling_factor", 1.0)),
             router_groups=int(m.get("n_group") or 1),
             router_groups_kept=int(m.get("topk_group") or 1),
-            shared_experts=int(m.get("n_shared_experts") or 0) if n_exp else 0,
+            shared_experts=int(m.get("n_shared_experts")
+                               or m.get("num_shared_experts") or 0) if n_exp else 0,
             intermediate_size=int(m.get("intermediate_size", 0)),
             patch=int(m.get("patch", 8)),
         )
@@ -342,8 +382,11 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
     keys = iter(jax.random.split(
         key, (20 if cfg.selects_over_latent else 16) * cfg.num_layers + 8))
 
-    def w(*shape, dtype=dtype):
-        return (0.02 * jax.random.normal(next(keys), shape, jnp.float32)).astype(dtype)
+    def w(*shape, dtype=dtype, scale=0.02):
+        return (scale * jax.random.normal(next(keys), shape, jnp.float32)).astype(dtype)
+
+    def between(low, high, *shape):
+        return jax.random.uniform(next(keys), shape, jnp.float32, low, high)
 
     def gain(n):
         return jnp.ones((n,), dtype)
@@ -354,12 +397,27 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
         if op == CONV:
             p = {"norm1": gain(d), "w_in": w(d, 3 * d), "conv_w": w(d, cfg.conv_taps),
                  "w_out": w(d, d), "norm2": gain(d)}
+        elif op == LINEAR:
+            wide = cfg.num_heads * cfg.linear_head_dim
+            # taps of order 1, so that the SiLU is not in its linear part; the decay's A and b
+            # spread it over its range: a channel's mean log-decay from -0.01 to -4.4 a token
+            p = {"norm1": gain(d), "w_qkv": w(d, 3 * wide),
+                 "conv_w": w(3 * wide, cfg.conv_taps, scale=0.5), "w_f": w(d, wide),
+                 "decay_a": between(-0.7, 0.7, cfg.num_heads), "decay_b": between(-6.0, 2.0, wide),
+                 "w_beta": w(d, cfg.num_heads), "w_z": w(d, wide),
+                 "o_norm": gain(cfg.linear_head_dim), "wo": w(wide, d), "norm2": gain(d)}
         elif op == LATENT:
             heads, rq, rkv = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
-            p = {"norm1": gain(d), "wq_a": w(d, rq), "q_a_norm": gain(rq), "wq_b": w(rq, heads * hd),
-                 "wkv_a": w(d, rkv + cfg.qk_rope_head_dim), "kv_a_norm": gain(rkv),
-                 "wkv_b": w(rkv, heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-                 "wo": w(heads * cfg.v_head_dim, d), "norm2": gain(d)}
+            if rq:
+                p = {"norm1": gain(d), "wq_a": w(d, rq), "q_a_norm": gain(rq),
+                     "wq_b": w(rq, heads * hd)}
+            else:
+                p = {"norm1": gain(d), "wq": w(d, heads * hd)}
+            p.update({"wkv_a": w(d, rkv + cfg.qk_rope_head_dim), "kv_a_norm": gain(rkv),
+                      "wkv_b": w(rkv, heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+                      "wo": w(heads * cfg.v_head_dim, d), "norm2": gain(d)})
+            if cfg.attn_gate:
+                p["w_attn_gate"] = w(d, heads)
             if cfg.indexer_heads:  # the index queries read the query's normed low rank
                 p.update(_index_params(cfg, w, gain, rq))
         else:
@@ -521,8 +579,10 @@ def gated_short_conv(p, x, batch: int, cfg: DecoderConfig):
 def _latent_projections(p, x, angles, cfg: DecoderConfig):
     """``x [T, D]`` -> ``(q_nope [T, H*dn], q_rope [T, H*dr], k_nope, k_rope
     [T, dr], v)``, and under a key selection the normed input and the
-    normed query rank ``(a [T, D], c_q [T, rq])`` after them: the queries
-    through their normed low rank, the keys' and values' per-head parts
+    normed query rank ``(a [T, D], c_q [T, rq])`` after them, and last,
+    where the output is gated, ``sigmoid(a W_G) [T, H]`` float32: the queries
+    through their normed low rank (``q_lora_rank`` 0: from the normed input,
+    of full rank), the keys' and values' per-head parts
     decompressed from the normed latent, the ONE rotary key (not normed) and
     each head's rotary query turned; the softmax scale (with YaRN's
     ``mscale**2``) rides on both parts of q.
@@ -549,8 +609,11 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
     scale = cfg.head_dim ** -0.5 * (yarn.softmax_scale if yarn else 1.0)
     turned = yarn.rotary_scale if yarn else 1.0
     a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
-    c_q = rms_norm(_mm(a, p["wq_a"]), p["q_a_norm"], cfg.rms_eps).astype(dt)
-    wq_b = p["wq_b"].reshape(-1, h, dn + dr)
+    if cfg.q_lora_rank:
+        c_q = rms_norm(_mm(a, p["wq_a"]), p["q_a_norm"], cfg.rms_eps).astype(dt)
+        wq_b = p["wq_b"].reshape(-1, h, dn + dr)
+    else:
+        c_q, wq_b = a, p["wq"].reshape(-1, h, dn + dr)
     q_nope = _mm(c_q, wq_b[..., :dn].reshape(-1, h * dn)) * scale
     q_rope = rotate(_mm(c_q, wq_b[..., dn:].reshape(-1, h * dr)).reshape(t, h, dr), angles)
     q_rope = q_rope * (scale * turned)
@@ -564,7 +627,11 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
         kv = kv.reshape(t, h, dn + dv)
         k_nope, v = kv[..., :dn].reshape(t, -1), kv[..., dn:].reshape(t, -1)
     out = (q_nope.astype(dt), q_rope.reshape(t, -1).astype(dt), k_nope, k_rope.astype(dt), v)
-    return out + (a, c_q) if cfg.indexer_heads else out  # what an indexer reads
+    if cfg.indexer_heads:
+        out += (a, c_q)  # what an indexer reads
+    if cfg.attn_gate:
+        out += (jax.nn.sigmoid(_mm(a, p["w_attn_gate"])),)
+    return out
 
 
 def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=None):
@@ -616,9 +683,67 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
                    block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile, q_shared=rows(q_rope),
                    k_shared=rows(k_rope))
     with jax.named_scope("proj"):
-        x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(
-            x, o.reshape(x.shape[0], -1), p["wo"])
+        if cfg.attn_gate:  # each head's output under its own scalar, then W_o
+            def gated(x, o, gate, wo):
+                o = o.reshape(x.shape[0], cfg.num_heads, -1) * gate[:, :, None]
+                return x + _mm(o.astype(x.dtype).reshape(x.shape[0], -1), wo).astype(x.dtype)
+
+            x = jax.jit(gated)(x, o, read[-1], p["wo"])
+        else:
+            x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(
+                x, o.reshape(x.shape[0], -1), p["wo"])
     return x, live, causal
+
+
+def conv_silu(u, taps_w, seq_len: int):
+    """``silu(conv(u))`` on ``u [T, C]`` (whole sequences of ``seq_len``
+    rows): a causal depthwise convolution, tap ``j`` of ``taps_w [C, taps]``
+    on ``u[t - (taps - 1) + j]``, zeros before each sequence's first row, no
+    bias; float32 inside, ``u``'s type out."""
+    t, c = u.shape
+    taps = taps_w.shape[1]
+    w = taps_w.astype(jnp.float32)
+    padded = jnp.pad(u.reshape(t // seq_len, seq_len, c), ((0, 0), (taps - 1, 0), (0, 0)))
+    acc = sum(w[:, j] * padded[:, j:j + seq_len].astype(jnp.float32) for j in range(taps))
+    return jax.nn.silu(acc).astype(u.dtype).reshape(t, c)
+
+
+def _linear_projections(p, x, cfg: DecoderConfig):
+    """``x [T, D]`` -> ``([q | k | v] [T, 3*H*d]`` before their convolution,
+    ``f [T, H*d]`` float32 (the decay's pre-activation: a log-decay is summed
+    over a chunk's rows, so it is never rounded to bf16), ``z [T, H*d]``
+    (the output gate's), ``beta [T, H]`` float32)."""
+    dt = x.dtype
+    a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
+    return (_mm(a, p["w_qkv"]).astype(dt), _mm(a, p["w_f"]), _mm(a, p["w_z"]).astype(dt),
+            jax.nn.sigmoid(_mm(a, p["w_beta"])))
+
+
+def linear_attention(p, x, batch: int, cfg: DecoderConfig):
+    """Kimi Delta Attention, as Ling-3.0's linear layers have it, on ``x
+    [B*S, D]`` -> ``x + Op``: with ``a = rms(x)``, ``[q | k | v] = a W_qkv``
+    each through a causal depthwise convolution of ``conv_taps`` taps and a
+    SiLU (:func:`conv_silu`; zeros before each sequence); per head ``q`` and
+    ``k`` L2-normed, the log-decay ``linear_decay_floor * sigmoid(exp(A) (a
+    W_f + b))`` per head AND channel, the step size ``sigmoid(a W_beta)``
+    one a head; the delta rule's state starts at 0 with every sequence; the
+    output normed per head (``o_norm``) and gated by ``sigmoid(a W_z)``,
+    then ``W_o``. No rotary. Under the scopes ``proj`` (the norm, the four
+    products, ``W_o``), ``conv`` (the three convolutions and their SiLU: one
+    pass over ``[T, 3*H*d]``) and ``kda`` (the gate, the L2 norms, the
+    recurrence and the output's norm and gate: ONE kernel,
+    ``ops/delta_rule.gated_delta_rule``)."""
+    s = x.shape[0] // batch
+    with jax.named_scope("proj"):
+        qkv, f, z, beta = jax.jit(_linear_projections, static_argnums=2)(p, x, cfg)
+    with jax.named_scope("conv"):
+        qkv = jax.jit(conv_silu, static_argnums=2)(qkv, p["conv_w"], s)
+    with jax.named_scope("kda"):
+        o = gated_delta_rule(qkv, f, z, beta, p["decay_a"], p["decay_b"], p["o_norm"], seq_len=s,
+                             heads=cfg.num_heads, lower=cfg.linear_decay_floor, eps=cfg.rms_eps,
+                             chunk=cfg.linear_chunk)
+    with jax.named_scope("proj"):
+        return jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(x, o, p["wo"])
 
 
 def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
@@ -656,8 +781,9 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     """One block: ``x [B*S, D]`` -> ``(x, stats float32)``, the first four
     of :data:`STEP_STATS` and, from a holder of a share of the experts,
     :data:`SHARE_STATS` (under a selection over latent attention those
-    and :data:`PAIR_STATS`: ``cfg.layer_stats`` in all). ``kind`` is ``cfg.layer_kind(i)``: the operator
-    runs under ``conv``, latent attention's or attention's scopes, the
+    and :data:`PAIR_STATS`, with linear layers :data:`LINEAR_STATS` after
+    them: ``cfg.layer_stats`` in all). ``kind`` is ``cfg.layer_kind(i)``: the operator
+    runs under ``conv``, linear attention's, latent attention's or attention's scopes, the
     feed-forward under ``moe`` (the routed experts), ``shared_expert``
     (beside them, added once) or ``mlp`` (dense)."""
     op, experts = kind
@@ -665,6 +791,8 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     if op == CONV:
         with jax.named_scope("conv"):
             x = jax.jit(gated_short_conv, static_argnums=(2, 3))(p, x, batch, cfg)
+    elif op == LINEAR:
+        x = linear_attention(p, x, batch, cfg)
     elif op == LATENT:
         x, live, causal = latent_attention(p, x, angles, batch, cfg, idx_angles)
     else:
@@ -706,6 +834,14 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
         kept = min(s, cfg.topk)  # sum over a sequence's queries of min(t + 1, topk)
         stats += [jnp.float32(batch * (kept * (kept + 1) // 2 + (s - kept) * cfg.topk)),
                   jnp.float32(batch * (s * (s + 1) // 2))]
+    if cfg.has_linear:
+        s = x.shape[0] // batch
+        through = op == LINEAR
+        if not cfg.selects_over_latent:  # PAIR_STATS' places: nothing selects
+            stats += [jnp.float32(0), jnp.float32(0)]
+        stats += [jnp.float32(through * x.shape[0]),
+                  jnp.float32(through * batch * cfg.num_heads
+                              * (s // chunk_rows(s, cfg.linear_chunk)))]
     return x, jnp.stack(stats)
 
 
@@ -714,8 +850,9 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     the other, each at ``pos`` (static: ``[S, 3]`` under the multimodal
     rotary, else ``[S]``), through every layer -> ``(x [B*S, D], stats
     in :data:`STEP_STATS`' order``, then :data:`SHARE_STATS` where the
-    holder has a share of the experts, and :data:`PAIR_STATS` under a
-    selection over latent attention)."""
+    holder has a share of the experts, :data:`PAIR_STATS` under a
+    selection over latent attention, and :data:`LINEAR_STATS` after both
+    where layers are linear)."""
     s = x.shape[0] // batch
     angles = rotary_angles(pos, cfg.rope_theta, cfg.rope_dim // 2, cfg.mrope_section,
                            cfg.rope_yarn)
@@ -800,8 +937,9 @@ def frame_step(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, thresho
 def fold_step_stats(metrics, stats) -> None:
     """Add one step's statistics vector (on the host or the device: six
     values, eight from a holder of a share of the experts, ten under a
-    selection over latent attention) to the
+    selection over latent attention, twelve with linear layers) to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""
-    for name, value in zip(STEP_STATS + SHARE_STATS + PAIR_STATS, np.asarray(stats, np.float64)):
+    for name, value in zip(STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS,
+                           np.asarray(stats, np.float64)):
         metrics.add_counter(name, float(value))

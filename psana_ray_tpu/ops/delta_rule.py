@@ -1,0 +1,220 @@
+"""The gated delta rule with a decay PER CHANNEL, in chunks.
+
+Kimi Delta Attention's recurrence (arXiv:2510.26692) carries, per head, a
+``[d_k, d_v]`` float32 state along the sequence, ``S_0 = 0`` at every
+sequence's first token:
+
+    S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T        o_t = S_t^T q_t
+
+with ``alpha_t = exp(g_t)`` per head AND channel, ``g_t = lower *
+sigmoid(exp(A) (f_t + b))`` in ``(lower, 0)`` (the bounded gate:
+``kda_safe_gate``, ``kda_lower_bound``), ``q_t`` and ``k_t`` the L2-normed
+rows of the head (``q_t`` times ``d_k^-1/2``), and the output normed per
+head and gated: ``rms(o_t; gain) * sigmoid(z_t)``. Nothing else in the
+package carries a state along a sequence (``ops/short_conv.py`` carries
+rows of its input).
+
+Token by token that is 8,704 dependent rank-one updates a sequence. Here it
+is matrix products over chunks of ``C`` rows, an identity and not an
+approximation (Kimi Linear's WY form). With ``G`` the running sum of ``g``
+inside the chunk and ``S`` the state the chunk starts from:
+
+    A_kk[i, j] = sum_c k_i[c] k_j[c] e^(G_i[c] - G_j[c])    (j < i)     A_qk alike from q_i   (j <= i)
+    X = (I + Diag(beta) tril(A_kk, -1))^-1
+    [W | U] = X [beta k e^G | beta v]          vbar = U - W S          o = (q e^G) S + tril(A_qk) vbar
+    S <- Diag(e^(G_C)) S + (k e^(G_C - G))^T vbar
+
+Three things shape it on the chip:
+
+- ``e^(-G_j)`` leaves float32 after 17 rows at -5 a row, so ``A_kk`` and
+  ``A_qk`` are not one product of pre-scaled operands: a row block of
+  ``BLOCK`` (16) rows scales its rows by ``e^(G_i - r)`` and the keys by
+  ``e^(r - G_j)``, ``r`` the block's first row's ``G``: the first is at
+  most 1, the second at most ``e^75`` inside the block and at most 1
+  before it (after it the entry is masked, and the exponent is capped so
+  that nothing masked is infinite);
+- the inverse of the unit lower-triangular ``I + B`` comes by halves: with
+  the inverses of the two diagonal halves known, the block below the
+  diagonal is ``-X_22 B_21 X_11``; from 1 x 1 blocks up that is ``2 log2(C)
+  - 2`` products of whole ``C x C`` matrices (the block-diagonal ``X`` of
+  one level on both sides of that level's off-diagonal part of ``B``) in
+  place of a row-by-row substitution. Every factor is a true inverse of a
+  sub-block or a part of ``B``: bounded. The series ``(I + N)(I + N^2)(I +
+  N^4)...`` costs the same and is the same matrix on paper, but its powers
+  are not bounded: a run of identical keys under a slow decay (a detector's
+  blank patches) makes ``N`` a triangle of ``-beta`` whose 64th power has
+  entries of 10^37, and the step came out NaN on the chip (PR 50);
+- one head's chunk is a few ``64 x 128 x 128`` products: ``HEADS`` heads
+  a grid step, unrolled, so that their chains interleave; the chunks are
+  the sequential grid axis, the states sit in VMEM scratch (transposed,
+  ``[d_v, d_k]``: the decay then scales lanes).
+
+Matrix products take bf16 operands and sum in float32; the state, the
+running sums of ``g`` (a triangular product of ``g`` split in three bf16
+parts: exact) and every exponent are float32. The gate, both L2 norms,
+the recurrence and the output's norm and gate are this ONE kernel: it
+reads ``[q | k | v]`` (after their convolution), ``f``, ``z`` and ``beta``
+once and writes the gated output once. Off the TPU it runs in Pallas
+interpret mode (tests, rehearsals).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+# rows a chunk. On the v5e at 4 x 8,704 tokens, 32 heads of 128, HEADS 4: 128 rows 19.9 ms a layer,
+# 64 rows 26.4, 32 rows 38.6 (a grid step's own cost, paid per chunk); HEADS 8 19.3, HEADS 2 20.7 at
+# 128 rows (my chip runs, PR 50)
+CHUNK = 128
+BLOCK = 16  # rows that share a reference row: 15 rows at -5 a row is e^75, float32 ends at e^88
+HEADS = 4  # heads a grid step
+L2_EPS = 1e-6  # in the L2 norms' root, as the public KDA kernels have it
+_CAP = 80.0  # of a masked entry's exponent: 128 channels of e^80 still sum inside float32
+
+
+def _mm(a, b, dims=((1,), (0,))):
+    return jax.lax.dot_general(a.astype(jnp.bfloat16), b.astype(jnp.bfloat16), (dims, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _running_sum(g):
+    """``[C, d]`` float32 -> the sums over rows ``0..i``, exact: a lower-triangular
+    product of ones with ``g`` split in three bf16 parts."""
+    c, d = g.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    hi = g.astype(jnp.bfloat16)
+    rest = g - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    parts = _mm((row >= col).astype(jnp.bfloat16), jnp.concatenate([hi, mid, low], axis=1))
+    return parts[:, :d] + parts[:, d:2 * d] + parts[:, 2 * d:]
+
+
+def _unit_lower_inverse(below, row, col):
+    """``(I + below)^-1`` for ``below [C, C]`` strictly lower triangular, by
+    halves from 1 x 1 blocks up: a level's ``X`` holds the inverses of the
+    diagonal blocks of ``size`` rows, and the next level's is ``X - X E X``,
+    ``E`` the part of ``below`` that joins two such blocks into one."""
+    c = below.shape[0]
+    x = jnp.where(row == col, 1.0, 0.0)
+    shift = 0
+    while 1 << shift < c:
+        joins = ((row >> (shift + 1)) == (col >> (shift + 1))) & ((row >> shift) != (col >> shift))
+        e = jnp.where(joins, below, 0.0)
+        x = x - (_mm(_mm(x, e), x) if shift else e)  # the 1 x 1 blocks' inverses are 1
+        shift += 1
+    return x
+
+
+def _chunk(q, k, v, g, beta, state_t, block: int):
+    """One head's chunk: ``q, k`` (normed), ``v [C, d]``, ``g [C, d_k]`` float32,
+    ``beta [C, 1]``, the state transposed ``[d_v, d_k]`` -> ``(o [C, d_v], state_t)``."""
+    c = q.shape[0]
+    gam = _running_sum(g)
+    akk, aqk = [], []
+    for lo in range(0, c, block):
+        r = gam[lo:lo + 1]
+        keys = k * jnp.exp(jnp.minimum(r - gam, _CAP))
+        scale = jnp.exp(gam[lo:lo + block] - r)
+        a = _mm(jnp.concatenate([k[lo:lo + block] * scale, q[lo:lo + block] * scale]), keys,
+                ((1,), (1,)))
+        akk.append(a[:block])
+        aqk.append(a[block:])
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    below = jnp.where(row > col, beta * jnp.concatenate(akk), 0.0)
+    x = _unit_lower_inverse(below, row, col)
+    decay = jnp.exp(gam)
+    wu = _mm(x, jnp.concatenate([beta * k * decay, beta * v], axis=1))
+    d_k = k.shape[1]
+    from_state = _mm(jnp.concatenate([wu[:, :d_k], q * decay]), state_t, ((1,), (1,)))
+    vbar = wu[:, d_k:] - from_state[:c]
+    o = from_state[c:] + _mm(jnp.where(row >= col, jnp.concatenate(aqk), 0.0), vbar)
+    last = gam[c - 1:c]
+    state_t = state_t * jnp.exp(last) + _mm(vbar, k * jnp.exp(last - gam), ((0,), (0,)))
+    return o, state_t
+
+
+def _kernel(q_ref, k_ref, v_ref, f_ref, z_ref, beta_ref, ea_ref, b_ref, gain_ref, o_ref,
+            state_ref, *, heads, d, lower, eps, block):
+    @pl.when(pl.program_id(2) == 0)  # a sequence starts: S_0 = 0
+    def _start():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
+
+    gain = gain_ref[...].astype(jnp.float32)
+    for h in range(heads):
+        at = slice(h * d, (h + 1) * d)  # the head's columns of every operand
+        q, k = (u[:, at].astype(jnp.float32) for u in (q_ref, k_ref))
+        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + L2_EPS) * d ** -0.5
+        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + L2_EPS)
+        g = lower * jax.nn.sigmoid(ea_ref[:, at] * (f_ref[:, at] + b_ref[:, at]))
+        o, state_ref[h] = _chunk(q, k, v_ref[:, at].astype(jnp.float32), g,
+                                 beta_ref[:, h:h + 1], state_ref[h], block)
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * gain
+        o_ref[:, at] = (o * jax.nn.sigmoid(z_ref[:, at].astype(jnp.float32))).astype(o_ref.dtype)
+
+
+def chunk_rows(seq_len: int, chunk: int = CHUNK) -> int:
+    """The rows of a chunk for sequences of ``seq_len``: the largest multiple
+    of 8 that divides it and is at most ``chunk``."""
+    rows = next((c for c in range(min(chunk, seq_len) // 8 * 8, 0, -8) if seq_len % c == 0), 0)
+    if not rows:
+        raise ValueError(f"delta rule: no chunk of whole 8-row tiles divides {seq_len} rows")
+    return rows
+
+
+@functools.partial(jax.jit, static_argnames=("seq_len", "heads", "lower", "eps", "chunk",
+                                             "interpret"))
+def gated_delta_rule(qkv, f, z, beta, log_a, bias, gain, *, seq_len: int, heads: int,
+                     lower: float, eps: float, chunk: int = CHUNK,
+                     interpret: Optional[bool] = None) -> jax.Array:
+    """``qkv [T, 3*H*d]`` (``[q | k | v]`` head after head, ``T`` rows being
+    whole sequences of ``seq_len``), ``f [T, H*d]`` float32 (the decay's
+    pre-activation), ``z [T, H*d]`` (the output gate's), ``beta [T, H]``
+    float32 in (0, 1), ``log_a [H]``, ``bias [H*d]``, ``gain [d]`` -> the
+    normed, gated output ``[T, H*d]`` in ``qkv``'s type. ``lower`` is the
+    log-decay's bound a row (negative), ``eps`` the output norm's."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, hd = f.shape
+    d = hd // heads
+    rows = chunk_rows(seq_len, chunk)
+    block = BLOCK if rows % BLOCK == 0 else 8
+    if qkv.shape != (t, 3 * hd) or t % seq_len or hd % heads or (block - 1) * -lower > _CAP:
+        raise ValueError(f"delta rule: [q | k | v] {qkv.shape} and f {f.shape} are not sequences "
+                         f"of {seq_len} rows of {heads} heads, or {block - 1} rows at {lower} a "
+                         "row leave the exponent's cap")
+    group = next(n for n in range(min(HEADS, heads), 0, -1) if heads % n == 0)
+    n_groups, n_chunks = heads // group, seq_len // rows
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+
+    def cols(part):  # a group's columns of q, k or v (parts 0-2 of qkv), or of f, z and the output
+        return pl.BlockSpec((rows, group * d),
+                            lambda b, j, c: (b * n_chunks + c, part * n_groups + j))
+
+    per_channel = pl.BlockSpec((1, group * d), lambda b, j, c: (0, j))
+    expand = jnp.repeat(jnp.exp(log_a.astype(jnp.float32)), d)[None]
+    return pl.pallas_call(
+        functools.partial(_kernel, heads=group, d=d, lower=float(lower), eps=float(eps),
+                          block=block),
+        grid=(t // seq_len, n_groups, n_chunks),
+        in_specs=[cols(0), cols(1), cols(2), cols(0), cols(0),
+                  pl.BlockSpec((None, rows, group), lambda b, j, c: (j, b * n_chunks + c, 0)),
+                  per_channel, per_channel, pl.BlockSpec((1, d), lambda b, j, c: (0, 0))],
+        out_specs=cols(0),
+        out_shape=jax.ShapeDtypeStruct((t, hd), qkv.dtype),
+        scratch_shapes=[pltpu.VMEM((group, d, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="gated_delta_rule",
+    )(qkv, qkv, qkv, f.astype(jnp.float32), z,
+      jnp.transpose(beta.astype(jnp.float32).reshape(t, n_groups, group), (1, 0, 2)),
+      expand, bias.astype(jnp.float32)[None], gain.astype(jnp.float32)[None])

@@ -300,7 +300,8 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
                                  select_bias=select_bias, gate_eps=gate_eps,
                                  gate_scale=gate_scale, groups=groups, groups_kept=groups_kept)
         if count < num_experts:  # a share of the experts: the rows HELD move, no others
-            return _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first, count, interpret)
+            return _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first, count, interpret,
+                                  _rows_ahead(t * k, count, num_experts))
         flat = ids.reshape(-1)
         order = jnp.argsort(flat, stable=True)
         per_expert = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
@@ -322,10 +323,28 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
 # 139,264 slots' rows gathered and the grouped product offset to the held groups, 55.9 ms and
 # 6.2 GB (my chip runs, PR 42)
 HELD_CHUNK = 2048
+# a holder of at least this share of the experts takes its even share of the slots, and half as
+# much again, in ONE pass ahead of the loop. On the v5e at 34,816 x 8 slots of 2,560, 128 of 512
+# experts held, 74,085 held rows: the loop alone 53.6 ms a layer, 37 turns whose scatter-add is 24.1
+# of them (0.32 us a row; the three products 10.2), and what a step takes follows the rows a seed
+# happens to hold; at 1/32 of the experts the loop's two or three turns are cheaper than the
+# pass's k gathers of [T, D] back (my chip runs, PR 50)
+AHEAD_SHARE = 1 / 8
+
+
+def _rows_ahead(slots: int, count: int, num_experts: int) -> int:
+    """The rows :func:`_held_rows_moe` takes in one pass ahead of its loop:
+    none under :data:`AHEAD_SHARE`, else 1.5 even shares in whole 8-row tiles
+    (104,448 = 204 x 512 of 278,528 slots at 128 of 512 experts)."""
+    if count < AHEAD_SHARE * num_experts:
+        return 0
+    even = -(-slots * count // num_experts)  # rounded up, as the next two
+    ahead = -(-3 * even // 2)
+    return min(-(-ahead // 8) * 8, slots // 8 * 8)
 
 
 def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, interpret,
-                   chunk: int = HELD_CHUNK):
+                   ahead: int = 0, chunk: int = HELD_CHUNK):
     """:func:`dropless_moe` where the holder has ``count`` of the experts,
     from the routing on (``ids, gates [T, k]``): time and memory follow the
     token slots whose expert lives HERE, not ``T * k``. The slots are
@@ -336,7 +355,16 @@ def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, 
     of each expert's group that falls into it, and adds each row, under
     its gate, to its token's sum (float32, rounded once at the end). No
     array of ``T * k`` rows exists; when every token chooses held experts
-    the loop runs ``T * k / chunk`` turns and still drops nothing."""
+    the loop runs ``T * k / chunk`` turns and still drops nothing.
+
+    ``ahead`` > 0 (a holder of a LARGE share, :func:`_rows_ahead`): the
+    first ``ahead`` sorted rows go through ONE pass before the loop, the
+    all-held path's way at that size (one gather out, the three products
+    over ``[ahead, .]``, a token's rows read back where the sort put them
+    and summed under their gates in the order of its choices:
+    :func:`gated_row_sum`'s rule, a slot past the held rows or past
+    ``ahead`` counting nothing); the loop then takes what lies beyond
+    ``ahead``, on an even load nothing."""
     t, d = x.shape
     k = ids.shape[1]
     slots = t * k
@@ -345,15 +373,20 @@ def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, 
         here = (ids >= first) & (ids < first + count)
         local = jnp.where(here, ids - first, count).reshape(-1)  # not held: sorts last
         order = jnp.argsort(local, stable=True).astype(jnp.int32)
-        order = jnp.pad(order, (0, -slots % chunk))  # a turn's slice never runs off the end
+        back = jnp.argsort(order).reshape(t, k) if ahead else None  # slot (t, j) -> its sorted row
+        # a turn's slice never runs off the end
+        order = jnp.pad(order, (0, -(slots - ahead) % chunk))
         per_expert = jnp.bincount(local, length=count + 1)[:count].astype(jnp.int32)
         ends = jnp.cumsum(per_expert)
         starts, n_held = ends - per_expert, ends[-1]
         flat_gates = gates.reshape(-1)
 
+    def rows_before(c):  # turn c's first row (no `+ 0` traced where nothing goes ahead)
+        return c * chunk + ahead if ahead else c * chunk
+
     def turn(state):
         c, y = state
-        lo = c * chunk
+        lo = rows_before(c)
         slot = jax.lax.dynamic_slice(order, (lo,), (chunk,))
         live = lo + jnp.arange(chunk, dtype=jnp.int32) < n_held
         token = slot // k
@@ -368,9 +401,31 @@ def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, 
         return c + 1, y.at[token].add(term)
 
     with jax.named_scope("moe_experts"):
-        _, y = jax.lax.while_loop(lambda state: state[0] * chunk < n_held, turn,
-                                  (jnp.int32(0), jnp.zeros((t, d), jnp.float32)))
+        y = (_held_rows_ahead(x, order[:ahead], back, gates, w_gate, w_up, w_down, starts, ends,
+                              interpret) if ahead else jnp.zeros((t, d), jnp.float32))
+        _, y = jax.lax.while_loop(lambda state: rows_before(state[0]) < n_held, turn,
+                                  (jnp.int32(0), y))
     return y.astype(x.dtype), per_expert
+
+
+def _held_rows_ahead(x, slot, back, gates, w_gate, w_up, w_down, starts, ends, interpret):
+    """The first ``len(slot)`` sorted rows of :func:`_held_rows_moe` in one
+    pass -> each token's gated sum over them, ``[T, D]`` float32."""
+    t, k = gates.shape
+    ahead = slot.shape[0]
+    rows = gather_rows(x, slot // k, interpret=interpret)
+    sizes = jnp.clip(ends, 0, ahead) - jnp.clip(starts, 0, ahead)
+    product = functools.partial(_grouped_product, tokens=sizes, interpret=interpret)
+    h = jax.nn.silu(product(rows, w_gate, out_dtype=jnp.float32))
+    h = (h * product(rows, w_up, out_dtype=jnp.float32)).astype(x.dtype)
+    out = product(h, w_down, out_dtype=x.dtype)
+    counted = back < jnp.minimum(ends[-1], ahead)  # past them the products left `out` unwritten
+    y = jnp.zeros((t, x.shape[1]), jnp.float32)
+    for j in range(k):
+        at = jnp.where(counted[:, j], back[:, j], 0)
+        got = out.at[at].get(mode="promise_in_bounds")
+        y = y + jnp.where(counted[:, j, None], got.astype(jnp.float32) * gates[:, j, None], 0.0)
+    return y
 
 
 @jax.jit  # one trace and one lowering a process, not one an expert layer: k gathers are slow to trace

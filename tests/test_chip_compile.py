@@ -362,7 +362,47 @@ def _dsv32_attention():
         one_call_in_wide_tiles_and_no_relaid_mask
 
 
+LING3_B, LING3_S, LING3_H = 4, 8704, 32  # four frames of 8,448 patches + 256 prompt tokens
+
+
+def _ling3_delta_rule():
+    """The gated delta rule with a per-channel decay at Ling-3.0's linear
+    layers' sizes: 4 x 32 head-sequences of 8,704 tokens, heads of 128, in
+    chunks of 128 rows: ONE kernel (the gate, the L2 norms, the recurrence,
+    the output's norm and gate), its operands the arrays their products and
+    the convolution wrote, ``[q | k | v]`` read in place as three column
+    blocks of one array."""
+    from psana_ray_tpu.ops.delta_rule import gated_delta_rule
+
+    def fn(qkv, f, z, beta, log_a, bias, gain):
+        return gated_delta_rule(qkv, f, z, beta, log_a, bias, gain, seq_len=LING3_S,
+                                heads=LING3_H, lower=-5.0, eps=1e-6, interpret=False)
+
+    rows, wide = LING3_B * LING3_S, LING3_H * 128
+
+    def one_kernel_and_no_copy_of_its_operands(text):
+        kernels = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text, re.M)
+        assert [k.split(".")[0] for k in kernels] == ["gated_delta_rule"], kernels
+        entry = text[text.index("ENTRY"):]
+        assert not re.search(rf"= \w+\[{rows},{3 * wide}\][^ ]* (copy|slice|fusion)\(", entry)
+
+    return fn, [S((rows, 3 * wide), BF16), S((rows, wide), F32), S((rows, wide), BF16),
+                S((rows, LING3_H), F32), S((LING3_H,), F32), S((wide,), F32), S((128,), F32)], 1, \
+        one_kernel_and_no_copy_of_its_operands
+
+
+def _ling3_conv():
+    """The three 4-tap convolutions and their SiLU over ``[q | k | v]``
+    (34,816 x 12,288): XLA's own, in one pass (no Mosaic kernel)."""
+    from psana_ray_tpu.models.decoder import conv_silu
+
+    rows, wide = LING3_B * LING3_S, 3 * LING3_H * 128
+    return (lambda u, w: conv_silu(u, w, LING3_S)), [S((rows, wide), BF16), S((wide, 4), BF16)], 0
+
+
 CASES = {
+    "ling3_gated_delta_rule_4x8704x32x128": _ling3_delta_rule,
+    "ling3_conv_silu_34816x12288": _ling3_conv,
     "dsv32_select_keys_8704x64x128": _dsv32_select,
     "dsv32_masked_latent_attention_1x8704x128x192": _dsv32_attention,
     "kimi_latent_attention_2x8704x64x192": _kimi_attention,
@@ -510,6 +550,9 @@ def test_the_served_peaknet_is_the_plain_flax_model(one_chip, monkeypatch):
 # one of these programs re-pins it, knowingly: an equal text is an equal key in the compile
 # cache, and a decoder cell's warm `setup_s` (bound 0.1) pays seconds for anything new to trace
 PINNED_STEPS = {
+    # pinned on PR 49's tree in PR 50, which gave latent attention a full-rank query and an
+    # output gate and the schedule a linear operator: none of it may reach this program
+    "deepseek_v32_prefill_epix10k2m": "17138bc67cdbb7c71b519a3cb05aa9a5e1f5accb0a0ddbe3c0e60ffae0a6f0e2",
     # pinned on PR 43's tree in PR 46, which put a selection's mask on latent attention's path;
     # re-pinned in PR 48, knowingly: its latent layers make two query products from W_uq's
     # columns and hand the kernel token-major operands, keys and values in ONE array
@@ -551,6 +594,46 @@ def test_the_other_decoders_steps_lower_to_the_programs_they_were(name, one_chip
     text = jax.jit(step).lower(*args).as_text()
     text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
     assert hashlib.sha256(text.encode()).hexdigest() == PINNED_STEPS[name]
+
+
+def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_count_them(
+        one_chip, monkeypatch):
+    """The whole served step of ``ling3_flash_prefill_epix10k2m`` at the
+    published sizes, compiled for the described v5e (half a minute): it fits
+    the chip beside nothing else (weights 10.5 GB), and its Mosaic kernels
+    are the ones the cell's roofline metrics read by name or by count: six
+    ``gated_delta_rule`` (one a linear layer: ``kda_roofline_share.ling3``
+    reads each call), the grouped products under ``moe`` — eighteen in the
+    pass ahead of the held rows' loop (``kimi_k2.held_products``'
+    ``call_sites``: the ones that RUN on any load under 1.5 even shares) and
+    the loop's own eighteen, which run only on what overflows the pass —
+    one ``masked_gqa_attention``, the calibration kernel, and no other."""
+    import collections
+
+    from benchmark.roofline import kimi_k2
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, params = _decoder_cell("ling3_flash_prefill_epix10k2m")
+    calib = (S((PANELS, H, W), F32), S((PANELS, H, W), F32), S((PANELS, H, W), jnp.uint8))
+    frames = S((cfg["batch_size"], PANELS, H, W), jnp.uint16)
+    ids = S((cfg["prompt_tokens"],), jnp.int32)
+
+    def step(p, c, f, i):
+        return decoder.frame_step(p, c, f, i, cfg=dcfg, threshold=10.0)
+
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), (params, calib, frames, ids))
+    compiled = jax.jit(step).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes < 15e9
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
+    sites = kimi_k2.held_products(cfg["step_tokens"], 8, 2560, 768, 128, 7, 1, 0.25)["call_sites"]
+    assert names == {"gated_delta_rule": cfg["layer_types"].count("linear_attention"), "gmm": 2 * sites,
+                     "masked_gqa_attention": 1, "fused_calibrate": 1}, names
+    assert all("/moe/" in line for line in calls if re.match(r"\s*%gmm", line))
+    assert all("/kda/" in line for line in calls if re.match(r"\s*%gated_delta_rule", line))
 
 
 @pytest.mark.parametrize("name", ["kimi_k2_prefill_epix10k2m", "deepseek_v32_prefill_epix10k2m"])

@@ -417,8 +417,11 @@ def test_kimi_configuration_reads_the_published_keys():
     assert cfg["sequence_tokens"] == 16 * (352 // 16) * (384 // 16) + cfg["prompt_tokens"] == 8704
     assert cfg["step_tokens"] == cfg["batch_size"] * cfg["sequence_tokens"] == 17408
     assert cfg["n_routed_experts"] == cfg["experts_held"][1] == cfg["published"]["n_routed_experts"] // 32
-    with pytest.raises(ValueError, match="q_lora_rank"):
-        decoder.DecoderConfig.from_mapping({**cfg, "q_lora_rank": None})
+    # a null query rank was refused until PR 50; it is a FULL-RANK query (W_q in W_dq and W_uq's place)
+    full = decoder.DecoderConfig.from_mapping({**cfg, "q_lora_rank": None})
+    assert (full.q_lora_rank, full.kv_lora_rank, full.attn_gate) == (0, 512, "")
+    theirs = jax.eval_shape(lambda k: decoder.init_params(full, k), jax.random.key(0))["layers"][1]
+    assert theirs["wq"].shape == (7168, 64 * 192) and "wq_b" not in theirs
 
 
 @pytest.mark.parametrize("name", ["keye_vl2_prefill_epix10k2m", "lfm2_8b_a1b_prefill_epix10k2m"])

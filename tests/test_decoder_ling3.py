@@ -1,0 +1,722 @@
+"""Linear attention by the gated delta rule with a decay per channel
+(``ops/delta_rule.py``), latent attention with a full-rank query and a
+head-wise output gate, and the trunk that mixes them (``models/decoder.py``
+reading Ling-3.0's keys) against the benchmark's plain reference
+(``benchmark/reference/ling3_decoder.py``: the recurrence token by token) at
+small sizes on the CPU; the shares of the expert layer at 8 groups; the new
+cell's manifest entries, counters and counts; and, for every decoder cell at
+once, what each configuration's reader has and has not."""
+
+import dataclasses
+import importlib
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import ling3_decoder as ref
+from psana_ray_tpu.models import decoder
+from psana_ray_tpu.ops import delta_rule as dr
+from psana_ray_tpu.parallel import moe
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
+CONFIGS = os.path.join(REPO, "benchmark", "configs")
+CONFIG = os.path.join(CONFIGS, "ling3_flash_prefill_epix10k2m.json")
+CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
+CELL = "ling3_epix_saturated"
+KDA, MLA = decoder.LINEAR, decoder.ATTENTION
+# the controls' faults (benchmark/tests/ling3_controls.py), at this size's chunk
+FAULTS = {"bf16_state": {"state": "bfloat16"}, "no_decay": {"decay": "none"},
+          "decay_per_head": {"decay": "head"}, "beta_one": {"beta": False},
+          "state_not_carried": {"carry": 16}, "latest_taps_only": {"taps_used": (2, 3)},
+          "no_l2_norm": {"l2": False}, "no_output_norm": {"o_norm": False},
+          "no_output_gate": {"o_gate": False}, "no_head_gate": {"attn_gate": False},
+          "softmax_router": {"scoring": "softmax"}, "no_shared_expert": {"shared": False}}
+# the decoder cells the benchmark had before this one: configuration file -> its cell's suffix
+OTHERS = {"keye_vl2_prefill_epix10k2m": "keye", "lfm2_8b_a1b_prefill_epix10k2m": "lfm2",
+          "kimi_k2_prefill_epix10k2m": "kimi", "deepseek_v32_prefill_epix10k2m": "dsv32"}
+
+
+def mapping(**over):
+    """Ling-3.0's Hugging Face keys at a small size: two linear layers, the
+    second with experts, then a latent layer; 16 routed experts in 4 groups
+    of which 2 stay, all held."""
+    m = dict(
+        hidden_size=64, num_hidden_layers=3, layer_types=[KDA, KDA, MLA], num_attention_heads=4,
+        num_key_value_heads=4, head_dim=16, vocab_size=256, rms_norm_eps=1e-6, rope_theta=6000000,
+        q_lora_rank=None, kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        rope_scaling=None, short_conv_kernel_size=4, kda_lower_bound=-5,
+        gated_attention_proj_granularity_type="head_wise", first_k_dense_replace=1,
+        num_experts=16, num_shared_experts=1, num_experts_per_tok=4, moe_intermediate_size=32,
+        moe_shared_expert_intermediate_size=32, n_group=4, topk_group=2, intermediate_size=96,
+        norm_topk_prob=True, score_function="sigmoid", scoring_func="sigmoid",
+        moe_router_enable_expert_bias=True, topk_method="noaux_tc", routed_scaling_factor=2.5,
+        tie_word_embeddings=False, patch=8,
+    )
+    m.update(over)
+    return m
+
+
+def small(m, chunk=16):
+    """Tiles that cut 64 tokens into several: the delta rule in chunks of 16, attention in 32 x 32."""
+    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=32,
+                               causal_kv_tile=32, linear_chunk=chunk)
+
+
+def loud(params, by=5.0):
+    """The same tree with its 0.02-matrices scaled up, so that every part
+    of a layer moves its output by more than a rounding (the taps and the
+    decay's A and b are of order 1 as drawn)."""
+    def up(path, a):
+        name = path[-1].key if hasattr(path[-1], "key") else ""
+        return a * by if a.ndim >= 2 and name != "conv_w" else a
+
+    return jax.tree_util.tree_map_with_path(up, params)
+
+
+def inputs(seed, batch=1):
+    rng = np.random.default_rng(seed)
+    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
+    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
+
+
+def embedded(params, patches, ids):
+    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
+
+
+def share_of(params, first, count):
+    held = ("w_gate", "w_up", "w_down")
+    return {**params, "layers": [
+        {k: (v[first:first + count] if k in held and v.ndim == 3 else v) for k, v in p.items()}
+        for p in params["layers"]]}
+
+
+@pytest.fixture
+def float32_products(monkeypatch):
+    """The kernel's products in float32, so that what is left between it and
+    the recurrence is its FORM alone: the kernel's compiled programs hold the
+    products they were traced with, so its cache goes before and after."""
+    def mm(a, b, dims=((1,), (0,))):
+        return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32), (dims, ((), ())),
+                                   precision=jax.lax.Precision.HIGHEST)
+
+    dr.gated_delta_rule.clear_cache()
+    monkeypatch.setattr(dr, "_mm", mm)
+    yield
+    dr.gated_delta_rule.clear_cache()
+
+
+# ---------------------------------------------------------------------------
+# the kernel against the recurrence, token by token
+# ---------------------------------------------------------------------------
+
+def _kernel_case(case, seed=0, heads=4, d=16, seq=48, batch=2):
+    rng = np.random.default_rng(seed)
+    t = batch * seq
+    qkv = rng.standard_normal((t, 3 * heads * d))
+    f = 2.0 * rng.standard_normal((t, heads * d))
+    beta = rng.uniform(0.05, 0.95, (t, heads))
+    log_a = rng.uniform(-0.7, 0.7, heads)
+    bias = rng.uniform(-6.0, 2.0, heads * d)
+    if case == "decay_at_the_bound":  # every channel forgets at -5 a token: e^-75 over a block
+        bias = np.full(heads * d, 40.0)
+    elif case == "decay_near_none":  # alpha = 1 to six places: the plain delta rule
+        bias = np.full(heads * d, -40.0)
+    elif case == "identical_keys":  # a detector's blank patches: one key again and again, kept
+        qkv = np.tile(qkv[:1], (t, 1))
+        bias, beta = np.full(heads * d, -40.0), np.full((t, heads), 0.9)
+    elif case == "beta_near_0":
+        beta = np.full((t, heads), 1e-3)
+    elif case == "beta_near_1":
+        beta = np.full((t, heads), 1.0 - 1e-3)
+    arrays = [jnp.asarray(a, jnp.float32) for a in (qkv, f, rng.standard_normal((t, heads * d)),
+                                                    beta, log_a, bias, rng.uniform(0.5, 1.5, d))]
+    return arrays, dict(seq_len=seq, heads=heads, lower=-5.0, eps=1e-6)
+
+
+def _recurrence(arrays, seq_len, heads, lower, eps, **fault):
+    """The reference's own lines on the kernel's operands, sequence by sequence."""
+    qkv, f, z, beta, log_a, bias, gain = arrays
+    t, d = qkv.shape[0], f.shape[1] // heads
+    m = {"carry": 0, "state": "float32", **fault}
+    q, k, v = (u.reshape(t, heads, d) for u in jnp.split(qkv, 3, axis=1))
+    q, k = (u / jnp.sqrt(jnp.sum(u * u, axis=-1, keepdims=True) + ref.L2_EPS) for u in (q, k))
+    g = lower * jax.nn.sigmoid(jnp.exp(log_a)[None, :, None]
+                               * (f.reshape(t, heads, d) + bias.reshape(heads, d)))
+    out = [ref.delta_rule(q[lo:lo + seq_len] * d ** -0.5, k[lo:lo + seq_len], v[lo:lo + seq_len],
+                          g[lo:lo + seq_len], beta[lo:lo + seq_len], m, jnp.float32)
+           for lo in range(0, t, seq_len)]
+    o = ref.rms(jnp.concatenate(out), gain, eps) * jax.nn.sigmoid(z.reshape(t, heads, d))
+    return o.reshape(t, heads * d), g
+
+
+@pytest.mark.parametrize("chunk", [8, 16, 48])
+@pytest.mark.parametrize("case", ["spread_decay", "decay_at_the_bound", "decay_near_none",
+                                  "beta_near_0", "beta_near_1", "identical_keys"])
+def test_the_chunked_form_is_the_recurrence_and_not_an_approximation(case, chunk, float32_products):
+    """Two sequences of 48 in one array (a boundary inside it), in chunks of
+    8 (six a sequence), 16 (three) and 48 (one chunk of three blocks): with
+    float32 products the kernel IS the token-by-token recurrence to float32's
+    own rounding, so the state, the running sums and the exponents are float32
+    and no term is dropped, whatever the decay and the step size."""
+    arrays, sizes = _kernel_case(case)
+    with jax.default_matmul_precision("highest"):
+        got = dr.gated_delta_rule(*arrays, chunk=chunk, **sizes)
+        want, g = _recurrence(arrays, **sizes)
+    if case == "decay_at_the_bound":
+        assert float(g.max()) < -4.999
+    if case in ("decay_near_none", "identical_keys"):
+        assert float(g.min()) > -1e-5
+    assert dr.chunk_rows(48, chunk) == chunk
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=0)
+    # and a state that crossed the sequences' boundary, or stopped at a chunk's, is another result
+    leaked, _ = _recurrence(arrays, **{**sizes, "seq_len": 96})
+    seen = {"decay_at_the_bound": 1e-4, "identical_keys": 1e-3}.get(case, 1e-2)
+    assert float(jnp.abs(leaked[48:] - want[48:]).max()) > seen
+    if chunk < 48 and seen == 1e-2:
+        dropped, _ = _recurrence(arrays, carry=chunk, **sizes)
+        assert float(jnp.abs(dropped - want).max()) > 1e-2
+
+
+@pytest.mark.parametrize("case", ["spread_decay", "decay_at_the_bound", "beta_near_1",
+                                  "identical_keys"])
+def test_bf16_products_keep_the_kernel_within_their_rounding_of_the_recurrence(case):
+    arrays, sizes = _kernel_case(case, seed=1)
+    arrays[0] = arrays[0].astype(jnp.bfloat16).astype(jnp.float32)  # what a bf16 [q | k | v] holds
+    got = dr.gated_delta_rule(arrays[0].astype(jnp.bfloat16), *arrays[1:], chunk=16, **sizes)
+    with jax.default_matmul_precision("highest"):
+        want, _ = _recurrence(arrays, **sizes)
+    assert got.dtype == jnp.bfloat16 and np.isfinite(np.asarray(got, np.float32)).all()
+    err = np.sqrt(np.mean((np.asarray(got, np.float64) - np.asarray(want, np.float64)) ** 2)
+                  / np.mean(np.asarray(want, np.float64) ** 2))
+    assert err < 2e-2, err
+
+
+def test_a_block_of_sixteen_rows_at_the_bound_stays_inside_float32_and_a_lower_bound_is_refused():
+    arrays, sizes = _kernel_case("decay_at_the_bound")
+    assert (dr.BLOCK - 1) * 5.0 <= dr._CAP < 88.0 - np.log(128.0)  # e^75 kept, 128 e^80 summed
+    with pytest.raises(ValueError, match="leave the exponent's cap"):
+        dr.gated_delta_rule(*arrays, chunk=16, **{**sizes, "lower": -6.0})
+    with pytest.raises(ValueError, match="no chunk of whole 8-row tiles"):
+        dr.chunk_rows(12)
+    assert [dr.chunk_rows(s) for s in (8704, 24, 64, 136)] == [128, 24, 64, 8]
+    assert 8704 % dr.CHUNK == 0 and dr.CHUNK % dr.BLOCK == 0
+
+
+def test_conv_silu_is_four_shifted_sums_that_start_anew_with_every_sequence():
+    rng = np.random.default_rng(4)
+    u = jnp.asarray(rng.standard_normal((32, 24)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((24, 4)), jnp.float32)
+    got = decoder.conv_silu(u, w, 16)
+    m = {"taps": 4, "taps_used": (0, 1, 2, 3)}
+    want = jnp.concatenate([ref.conv_silu(u[:16], w, m), ref.conv_silu(u[16:], w, m)])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    as_one = ref.conv_silu(u, w, m)  # the second sequence's first three rows read the first's last
+    assert float(jnp.abs(as_one[16:19] - want[16:19]).max()) > 1e-2
+    np.testing.assert_allclose(np.asarray(as_one[19:]), np.asarray(want[19:]), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the trunk against the reference, float32, all positions, a batch of two
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("held", ["all_16", "experts_0_to_7_of_16"])
+def test_the_hybrid_trunk_matches_the_reference_at_all_positions_of_a_batch_of_two(
+        held, float32_products):
+    m = mapping()
+    cfg = small(m)
+    params = loud(decoder.init_params(cfg, jax.random.key(3), jnp.float32))
+    if held == "experts_0_to_7_of_16":  # a share: groups 0 and 1 of the four
+        m.update(num_experts=8, router_experts=16, experts_held=[0, 8])
+        cfg, params = small(m), share_of(params, 0, 8)
+    patches, ids = inputs(3, batch=2)
+    sizes = ref.sizes(m)
+    with jax.default_matmul_precision("highest"):
+        x, stats = jax.jit(lambda p: decoder.trunk(
+            p, embedded(p, patches, ids), np.arange(64), cfg, 2))(params)
+        got = decoder.logits_of(decoder.head_params(params), x, cfg)
+        want_x = jnp.concatenate([ref.hidden(params, frame, ids, sizes, block=16)
+                                  for frame in patches])
+        want = ref.logits_of(params, want_x, sizes)
+    for a, b in ((x, want_x), (got, want)):
+        scale = float(jnp.sqrt(jnp.mean(b ** 2)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4 * scale, rtol=0)
+    # twelve statistics with linear layers, whatever the share
+    names = decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS + decoder.LINEAR_STATS
+    assert len(stats) == 12 == len(names)
+    got_stats = dict(zip(names, (float(v) for v in stats)))
+    assert got_stats["expert_tokens_mean_total"] == 2 * 128 * 4 / 16
+    assert got_stats["attn_tiles_causal_total"] == got_stats["attn_tiles_live_total"] == 2  # one latent layer
+    assert (got_stats["decoder_tokens_total"], got_stats["decoder_sequences_total"]) == (128, 2)
+    assert got_stats["expert_rows_routed_total"] == 2 * 128 * 4
+    assert (got_stats["expert_rows_held_total"] == 2 * 128 * 4) == (held == "all_16")
+    assert got_stats["attn_pairs_selected_total"] == got_stats["attn_pairs_causal_total"] == 0
+    assert got_stats["linear_attn_tokens_total"] == 2 * 128  # two linear layers
+    assert got_stats["linear_attn_chunks_total"] == 2 * 2 * 4 * (64 // 16)
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTS))
+def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault, float32_products):
+    m = mapping()
+    cfg = small(m)
+    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
+    patches, ids = inputs(5)
+    with jax.default_matmul_precision("highest"):
+        x, _ = decoder.trunk(params, embedded(params, patches, ids), np.arange(64), cfg)
+        want = ref.hidden(params, patches[0], ids, ref.sizes(m, **FAULTS[fault]), block=16)
+        same = ref.hidden(params, patches[0], ids, ref.sizes(m), block=16)
+    scale = float(jnp.sqrt(jnp.mean(want ** 2)))
+    assert float(jnp.abs(x - same).max()) < 1e-3 * scale
+    # what a control puts in is seen; a bf16 state's rounding is small and still no float32 state
+    seen = 1e-3 if fault == "bf16_state" else 1e-2
+    assert float(jnp.abs(x - want).max()) > seen * scale
+
+
+def test_a_sequence_of_the_batch_does_not_read_its_neighbour_s_state_or_taps():
+    cfg = small(mapping())
+    params = loud(decoder.init_params(cfg, jax.random.key(7), jnp.float32))
+    patches, ids = inputs(7, batch=2)
+    run = jax.jit(lambda p, x: decoder.trunk(p, x, np.arange(64), cfg, 2)[0])
+    x = run(params, embedded(params, patches, ids))
+    moved = run(params, embedded(params, patches[::-1], ids))
+    np.testing.assert_array_equal(np.asarray(x[:64]), np.asarray(moved[64:]))
+    np.testing.assert_array_equal(np.asarray(x[64:]), np.asarray(moved[:64]))
+
+
+# ---------------------------------------------------------------------------
+# latent attention with a full-rank query and a head-wise gate
+# ---------------------------------------------------------------------------
+
+def test_a_null_query_rank_is_a_full_rank_query_and_the_gate_is_one_scalar_a_head():
+    cfg = small(mapping())
+    assert (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.head_dim, cfg.rope_dim) == (0, 16, 24, 8)
+    assert cfg.attn_gate == "head_wise" and cfg.rope_yarn is None
+    shapes = jax.eval_shape(lambda k: decoder.init_params(cfg, k), jax.random.key(0))
+    latent = shapes["layers"][2]
+    assert latent["wq"].shape == (64, 4 * 24) and latent["w_attn_gate"].shape == (64, 4)
+    assert not {"wq_a", "q_a_norm", "wq_b"} & set(latent)
+    with pytest.raises(ValueError, match="granularity"):
+        decoder.DecoderConfig.from_mapping(mapping(gated_attention_proj_granularity_type="element_wise"))
+    # kimi's spelling (a query rank, no gate) keeps its own parameters
+    ranked = decoder.DecoderConfig.from_mapping(mapping(
+        q_lora_rank=24, layer_types=[MLA] * 3, gated_attention_proj_granularity_type=None))
+    theirs = jax.eval_shape(lambda k: decoder.init_params(ranked, k), jax.random.key(0))["layers"][2]
+    assert {"wq_a", "q_a_norm", "wq_b"} <= set(theirs) and "w_attn_gate" not in theirs
+    assert "wq" not in theirs and not ranked.has_linear and ranked.layer_stats == 4
+
+
+def test_the_gated_full_rank_latent_layer_is_the_reference_s():
+    m = mapping(layer_types=[MLA, MLA, MLA], first_k_dense_replace=3, num_hidden_layers=3)
+    cfg = small(m)
+    params = loud(decoder.init_params(cfg, jax.random.key(11), jnp.float32))
+    patches, ids = inputs(11)
+    with jax.default_matmul_precision("highest"):
+        x, stats = decoder.trunk(params, embedded(params, patches, ids), np.arange(64), cfg)
+        want = ref.hidden(params, patches[0], ids, ref.sizes(m), block=16)
+        ungated = ref.hidden(params, patches[0], ids, ref.sizes(m, attn_gate=False), block=16)
+    scale = float(jnp.sqrt(jnp.mean(want ** 2)))
+    np.testing.assert_allclose(np.asarray(x), np.asarray(want), atol=2e-4 * scale, rtol=0)
+    assert float(jnp.abs(x - ungated).max()) > 1e-2 * scale
+    assert len(stats) == 6  # no share, no selection, no linear layer: the six every decoder counts
+
+
+# ---------------------------------------------------------------------------
+# the shares add up, at 8 groups of which 4 stay
+# ---------------------------------------------------------------------------
+
+def _expert_layer(seed, t=64, d=32, width=16, experts=32, k=4):
+    rng = np.random.default_rng(seed)
+
+    def w(*shape, by=0.2):
+        return jnp.asarray(rng.standard_normal(shape) * by, jnp.float32)
+
+    p = {"router": w(d, experts, by=0.5), "router_bias": w(experts, by=0.3),
+         "w_gate": w(experts, d, width), "w_up": w(experts, d, width), "w_down": w(experts, width, d),
+         "shared_gate": w(d, width), "shared_up": w(d, width), "shared_down": w(width, d)}
+    m = ref.sizes(mapping(num_experts=experts, num_experts_per_tok=k, n_group=8, topk_group=4))
+    return p, w(t, d, by=1.0), m
+
+
+def test_four_shares_of_two_groups_each_and_the_shared_expert_once_add_up_to_the_uncut_layer():
+    p, b, m = _expert_layer(9)
+    with jax.default_matmul_precision("highest"):
+        parts, served = [], []
+        for first in (0, 8, 16, 24):  # a share is two of the eight groups, as the cell's 128 of 512
+            y, tokens = moe.dropless_moe(
+                b, p["router"], p["w_gate"][first:first + 8], p["w_up"][first:first + 8],
+                p["w_down"][first:first + 8], k=4, num_experts=32, experts_held=(first, 8),
+                scoring="sigmoid", select_bias=p["router_bias"], gate_eps=1e-20, gate_scale=2.5,
+                groups=8, groups_kept=4)
+            parts.append(np.asarray(y, np.float64))
+            served.append(int(np.asarray(tokens).sum()))
+        shared = np.asarray(decoder._dense_mlp(
+            {"w_gate": p["shared_gate"], "w_up": p["shared_up"], "w_down": p["shared_down"]}, b))
+        routed, chosen = ref.experts(p, b, m, jnp.float32)
+        want = np.asarray(routed + ref.shared_expert(p, b, jnp.float32))
+        unlimited, _ = ref.experts(p, b, {**m, "group_limit": False}, jnp.float32)
+    assert sum(served) == 64 * 4 and np.asarray(chosen).sum() == 64 * 4  # every slot, once
+    assert min(np.abs(part).max() for part in parts) > 0 and np.abs(shared).max() > 0
+    np.testing.assert_allclose(sum(parts) + shared, want, atol=2e-5)
+    assert np.abs(np.asarray(unlimited) - np.asarray(routed)).max() > 1e-2  # the limit is in the sum
+    assert np.abs(sum(part + shared for part in parts) - want).max() > 1e-2  # counted four times: no
+    held = {k: (v[8:16] if k in ("w_gate", "w_up", "w_down") else v) for k, v in p.items()}
+    one, _ = ref.experts(held, b, {**m, "experts_held": (8, 8)}, jnp.float32)
+    np.testing.assert_allclose(parts[1], np.asarray(one), atol=2e-5)
+
+
+@pytest.mark.parametrize("case", ["within_the_pass", "overflowing_into_the_loop"])
+def test_a_large_share_takes_its_rows_in_one_pass_and_the_loop_takes_what_overflows(case):
+    """A holder of a quarter of the experts takes 1.5 even shares of the slots
+    ahead of the held rows' loop (``moe._rows_ahead``); where a selection bias
+    sends EVERY token's four choices to the held eight, 256 rows are held for
+    the pass's 96 and the loop adds the other 160: nothing is dropped, and
+    the layer is the reference's either way."""
+    p, b, m = _expert_layer(13)
+    bias = p["router_bias"]
+    if case == "overflowing_into_the_loop":
+        bias = bias.at[8:16].add(5.0)
+    assert moe._rows_ahead(64 * 4, 8, 32) == 96
+    with jax.default_matmul_precision("highest"):
+        y, tokens = moe.dropless_moe(
+            b, p["router"], p["w_gate"][8:16], p["w_up"][8:16], p["w_down"][8:16], k=4,
+            num_experts=32, experts_held=(8, 8), scoring="sigmoid", select_bias=bias,
+            gate_eps=1e-20, gate_scale=2.5, groups=8, groups_kept=4)
+        held = {k: (v[8:16] if k in ("w_gate", "w_up", "w_down") else v) for k, v in p.items()}
+        want, _ = ref.experts({**held, "router_bias": bias}, b, {**m, "experts_held": (8, 8)},
+                              jnp.float32)
+    rows = int(np.asarray(tokens).sum())
+    assert (rows == 256) if case == "overflowing_into_the_loop" else (0 < rows <= 96)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
+
+
+def test_the_pass_ahead_is_a_large_share_s_alone():
+    assert moe._rows_ahead(34816 * 8, 128, 512) == 104448 == 204 * 512  # the cell's: 1.5 x 69,632
+    assert moe._rows_ahead(17408 * 8, 12, 384) == 0 == moe._rows_ahead(8704 * 8, 8, 256)  # kimi's, dsv32's
+    assert moe._rows_ahead(64 * 4, 16, 32) == 192 and moe._rows_ahead(64 * 4, 31, 32) == 256
+
+
+# ---------------------------------------------------------------------------
+# the configuration's fifth spelling
+# ---------------------------------------------------------------------------
+
+def _catalog_row(name="Ling-3.0-flash"):
+    if not os.path.exists(CATALOG):
+        pytest.skip("no catalog here")
+    with open(CATALOG) as f:
+        row, = [r for r in map(json.loads, f) if r["name"] == name]
+    return row
+
+
+def _file(name=None):
+    with open(os.path.join(CONFIGS, name + ".json") if name else CONFIG) as f:
+        return json.load(f)
+
+
+def test_from_mapping_reads_the_published_keys_given_the_layers_kinds():
+    published = _catalog_row()["config"]
+    kinds = [MLA if (i + 1) % published["layer_group_size"] == 0 else KDA
+             for i in range(published["num_hidden_layers"])]
+    got = decoder.DecoderConfig.from_mapping({**published, "layer_types": kinds})
+    assert (got.hidden_size, got.num_layers, got.num_heads, got.head_dim, got.rope_dim) == (
+        2560, 42, 32, 192, 64)
+    assert (got.q_lora_rank, got.kv_lora_rank, got.qk_nope_head_dim, got.qk_rope_head_dim,
+            got.v_head_dim, got.attn_gate) == (0, 512, 128, 64, 128, "head_wise")
+    assert (got.linear_head_dim, got.linear_decay_floor, got.conv_taps, got.linear_chunk) == (
+        128, -5.0, 4, 128)
+    assert (got.num_experts, got.experts_held, got.experts_per_token, got.expert_width,
+            got.shared_experts, got.num_dense_layers, got.intermediate_size) == (
+        512, (0, 512), 8, 768, 1, 2, 6144)
+    assert (got.router_groups, got.router_groups_kept, got.router_scoring, got.expert_bias,
+            got.gate_eps, got.routed_scaling_factor) == (8, 4, "sigmoid", True, 1e-20, 2.5)
+    assert got.rope_yarn is None and got.rope_theta == 6e6 and not got.tie_embedding
+    assert got.has_linear and not got.holds_a_share and not got.selects_over_latent
+    assert got.layer_stats == 10 and kinds.count(MLA) == 7 and kinds.count(KDA) == 35
+    assert [got.layer_kind(i) for i in (0, 1, 2, 5, 11, 41)] == [
+        (KDA, False), (KDA, False), (KDA, True), (decoder.LATENT, True), (decoder.LATENT, True),
+        (decoder.LATENT, True)]
+    with pytest.raises(ValueError, match="layer_types"):
+        decoder.DecoderConfig.from_mapping({**published, "layer_types": kinds[:7]})
+
+
+def test_the_file_holds_the_catalog_s_numbers_unchanged_and_names_its_cuts():
+    row, cfg = _catalog_row(), _file()
+    assert cfg["source"] == row["source_url"] and len(cfg["source"]) <= 200
+    differs = {k for k, v in row["config"].items() if cfg.get(k, "absent") != v}
+    assert differs == set(cfg["reduced"]) == {"num_hidden_layers", "first_k_dense_replace",
+                                              "num_experts", "vocab_size"}
+    assert {k: row["config"][k] for k in differs} == {k: cfg["published"][k] for k in differs}
+    assert "4 chips of a v5e host" in cfg["deployment"] and "NO cited deployment" in cfg["deployment"]
+    said = " ".join(cfg["assumed"])
+    for reading in ("BOUNDED gate", "softplus", "L2-normed", "each head's query and key", "head_wise",
+                    "layer_group_size", "uniform in (-0.7, 0.7)", "uniform in (-6, 2)",
+                    "multi-token-prediction", "linear patch embedding", "rotate-half"):
+        assert reading in said, reading
+    assert "no kept layer has a limit" in cfg["published"]["swiglu_limits"]
+    assert "6 KDA : 1 latent" in cfg["what"] and "lfm2's scope name" in cfg["what"]
+    got = decoder.DecoderConfig.from_mapping(cfg)
+    assert (got.num_layers, got.num_dense_layers, got.num_experts, got.experts_held,
+            got.vocab_size) == (7, 1, 512, (0, 128), 39296)
+    assert got.layer_types == (KDA,) * 6 + (MLA,) and got.holds_a_share and got.has_linear
+    assert got.vocab_size % 128 == 0 and got.vocab_size * 4 == cfg["published"]["vocab_size"]
+    shapes = jax.eval_shape(lambda k: decoder.init_params(got, k), jax.random.key(0))
+    n = sum(int(np.prod(a.shape)) for a in jax.tree.leaves(shapes))
+    assert 5.22e9 < n < 5.24e9  # the file's 5.23 G parameters, 10.46 GB in bf16
+    linear, latent = shapes["layers"][1], shapes["layers"][6]
+    assert linear["w_qkv"].shape == (2560, 3 * 4096) and linear["conv_w"].shape == (3 * 4096, 4)
+    assert linear["decay_a"].shape == (32,) and linear["decay_b"].shape == (4096,)
+    assert linear["router"].shape == (2560, 512) and linear["w_gate"].shape == (128, 2560, 768)
+    assert latent["wq"].shape == (2560, 32 * 192) and latent["w_attn_gate"].shape == (2560, 32)
+    assert shapes["layers"][0]["w_gate"].shape == (2560, 6144)
+    assert cfg["sequence_tokens"] == 16 * (352 // 16) * (384 // 16) + cfg["prompt_tokens"] == 8704
+    assert cfg["step_tokens"] == cfg["batch_size"] * cfg["sequence_tokens"] == 34816
+    assert cfg["num_experts"] == cfg["experts_held"][1] == cfg["published"]["num_experts"] // 4
+    # the rehearsal's size keeps every mechanism: both operators, a gate, a group limit, a share
+    small_cfg = decoder.DecoderConfig.from_mapping({**cfg, **cfg["rehearse"]})
+    assert small_cfg.has_linear and small_cfg.holds_a_share and small_cfg.attn_gate == "head_wise"
+    assert (small_cfg.router_groups, small_cfg.router_groups_kept) == (4, 2)
+    assert decoder.LATENT in [small_cfg.layer_kind(i)[0] for i in range(small_cfg.num_layers)]
+
+
+@pytest.mark.parametrize("name", sorted(OTHERS))
+def test_the_other_four_readers_have_nothing_of_what_this_one_brought(name):
+    cfg = _file(name)
+    got = decoder.DecoderConfig.from_mapping(cfg)
+    assert (got.linear_head_dim, got.linear_decay_floor, got.attn_gate) == (0, 0.0, "")
+    assert not got.has_linear and got.layer_stats <= 8 and KDA not in got.layer_types
+    assert bool(got.q_lora_rank) == bool(got.kv_lora_rank)  # a latent query there goes through a rank
+    shapes = jax.eval_shape(lambda k: decoder.init_params(got, k), jax.random.key(0))
+    brought = {"w_qkv", "w_f", "w_z", "w_beta", "decay_a", "decay_b", "o_norm", "wq", "w_attn_gate"}
+    kept = {"wq"} if OTHERS[name] in ("lfm2", "keye") else set()  # grouped-query attention's own
+    for layer in shapes["layers"]:
+        assert not (brought - kept) & set(layer)
+    metrics = [e["name"] for e in _manifest()["per_layer"] if e["name"].endswith("." + OTHERS[name])]
+    assert metrics and not [n for n in metrics if n.startswith("kda_")]
+
+
+# ---------------------------------------------------------------------------
+# the two new counters, in snapshot() and under /metrics
+# ---------------------------------------------------------------------------
+
+def test_linear_counters_reach_the_snapshot_and_the_exposition():
+    from benchmark import harness
+    from psana_ray_tpu.infeed import InfeedPipeline
+    from psana_ray_tpu.obs.registry import MetricsRegistry
+    from psana_ray_tpu.records import EndOfStream, FrameRecord
+    from psana_ray_tpu.transport import RingBuffer
+
+    cfg = small(mapping(num_experts=8, router_experts=16, experts_held=[0, 8]), chunk=8)
+    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
+    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
+                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
+    calib = harness.make_calibration(detector, 1)
+    ids = jnp.arange(PROMPT, dtype=jnp.int32)
+    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
+    rng = np.random.default_rng(2)
+    q = RingBuffer(maxsize=8)
+    for i in range(4):
+        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
+    q.put(EndOfStream(total_events=4))
+    pipe = InfeedPipeline(q, batch_size=2, poll_interval_s=0.001)
+    logits = []
+
+    def on_result(out, batch):
+        logits.append(np.asarray(out[0]))
+        decoder.fold_step_stats(pipe.metrics, out[1])
+
+    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 4
+    assert all(x.shape == (2, 256) and np.isfinite(x).all() for x in logits)
+    snap = pipe.metrics.snapshot()
+    steps, s = 2, 2 * 2 * 14 + PROMPT  # 64 tokens a frame, two frames a step
+    assert snap["decoder_tokens_total"] == steps * 2 * s
+    assert snap["linear_attn_tokens_total"] == steps * 2 * (2 * s)  # two linear layers
+    assert snap["linear_attn_chunks_total"] == steps * 2 * (2 * 4 * (s // 8))
+    assert snap["linear_attn_tokens_total"] * 4 / snap["linear_attn_chunks_total"] == 8  # the chunk
+    assert snap["attn_pairs_causal_total"] == 0 == snap["attn_pairs_selected_total"]
+    assert 0 < snap["expert_rows_held_total"] < snap["expert_rows_routed_total"] == steps * 2 * 2 * s * 4
+    text = MetricsRegistry()
+    text.register("reader", pipe.metrics)
+    text = text.render_prometheus()
+    for name in (decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS
+                 + decoder.LINEAR_STATS):
+        assert f'psana_ray_{name}{{source="reader"}}' in text, name
+
+
+# ---------------------------------------------------------------------------
+# the manifest's new files
+# ---------------------------------------------------------------------------
+
+def _manifest():
+    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+        return json.load(f)
+
+
+LING3_METRICS = ["proj_ms.ling3", "conv_ms.ling3", "kda_ms.ling3", "latent_attn_ms.ling3",
+                 "shared_expert_ms.ling3", "moe_ms.ling3", "mlp_ms.ling3",
+                 "kda_roofline_share.ling3", "latent_attention_roofline_share.ling3",
+                 "gmm_roofline_share.ling3", "step_mfu.ling3", "expert_load_peak.ling3",
+                 "held_rows_share.ling3", "kda_chunk_rows.ling3"]
+COUNTERS = decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS + decoder.LINEAR_STATS
+
+
+def _spec_names_what_exists(name, cfg):
+    """A metric's data file names a reader that exists, a roofline function
+    that takes exactly the shapes it is given from keys ``cfg`` has, trace
+    names the file declares and counters the step counts -> its ``args``."""
+    with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
+        spec = json.load(f)
+    assert callable(importlib.import_module(f"benchmark.readers.{spec['reader']}").read), name
+    args = spec["args"]
+    if "function" in args:
+        module, fn = args["function"].rsplit(".", 1)
+        need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
+        given = set(args["shape_from"]) | ({"held_share"} if "share" in args else set())
+        assert given == set(need.__code__.co_varnames[:need.__code__.co_argcount]), name
+        assert all(path.split(".")[0] in cfg for path in args["shape_from"].values()), name
+    for key in ("pattern", "within"):
+        if args.get(key, "").startswith("@"):
+            assert args[key][1:] in cfg["trace_names"], name
+    for key in ("numerator", "denominator"):
+        for counters in (args, args.get("share", {})):
+            assert counters.get(key, COUNTERS[0]) in COUNTERS, name
+    return args
+
+
+@pytest.mark.parametrize("name", LING3_METRICS)
+def test_every_metric_file_of_the_ling3_cell_names_a_reader_and_keys_that_exist(name):
+    manifest = _manifest()
+    entry, = [e for e in manifest["per_layer"] if e["name"] == name]
+    assert entry["workloads"] == [CELL] and entry["moves"] == "fps.hit"
+    assert entry["layer"] == ("kernels" if "roofline" in name else "device program")
+    names = [e["name"] for e in manifest["per_layer"]]
+    assert names[-len(LING3_METRICS):] == LING3_METRICS  # appended as one run, in this order
+    cfg = _file()
+    args = _spec_names_what_exists(name, cfg)
+    if "scope" in args:  # a scope the step has
+        assert args["scope"] in ("proj", "conv", "kda", "latent_attn", "shared_expert", "moe", "mlp")
+
+
+def test_the_ling3_cell_is_the_manifest_s_last_and_reports_the_host_path_as_the_decoders_do():
+    manifest = _manifest()
+    assert len(manifest["workloads"]) == 8 and len(manifest["configs"]) == 7
+    assert {w["chips"] for w in manifest["workloads"]} == {1}
+    cell = manifest["workloads"][-1]
+    assert (cell["name"], cell["chips"], cell["traffic"], cell["config"]) == (
+        CELL, 1, "saturated", "ling3_flash_prefill_epix10k2m")
+    config = manifest["configs"][-1]
+    assert config["file"] == os.path.relpath(CONFIG, REPO) and len(cell["why"]) <= 200
+    assert config["reduced"] == _file()["reduced"] == [
+        "num_hidden_layers", "first_k_dense_replace", "num_experts", "vocab_size"]
+    shared = [e for e in manifest["per_layer"] + manifest["end_to_end"]
+              if "dsv32_epix_saturated" in e.get("workloads", ()) and not e["name"].endswith(".dsv32")]
+    assert len(shared) == 19 and "fps.hit" in [e["name"] for e in shared]
+    for e in shared:  # fps.hit and the 18 host-path metrics
+        assert e["workloads"][-2:] == ["dsv32_epix_saturated", CELL]
+    roofline = [e for e in manifest["per_layer"] if e["name"] == "calib_roofline_share.hit"]
+    assert roofline[0]["workloads"] == ["hit_epix_saturated"]
+    cfg = _file()
+    assert cfg["transport"]["slots"] == 16 and cfg["batch_size"] == 4
+    assert cfg["trace_names"]["kda_kernel"] == "gated_delta_rule"  # the pallas_call's own name
+
+
+def test_ling3_roofline_counts_at_the_published_sizes():
+    from benchmark.roofline import kimi_k2
+    from benchmark.roofline import ling3 as roofline
+
+    cfg = _file()
+
+    def need(name):
+        with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
+            args = json.load(f)["args"]
+        module, fn = args["function"].rsplit(".", 1)
+        return getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn), {
+            k: cfg[path] for k, path in args["shape_from"].items()}
+
+    fn, shapes = need("kda_roofline_share.ling3")
+    rule = fn(**shapes)
+    assert rule["flops"] == 7 * 128 * 128 * 32 * 34816  # 0.128 T a layer, whatever the chunk
+    assert rule["bytes"] == 34816 * 32 * (5 * 2 * 128 + 4)  # 1.43 GB: bound by bytes
+    assert rule["bytes"] / 819e9 > rule["flops"] / 197e12
+    assert "chunk" not in fn.__code__.co_varnames
+    fn, shapes = need("latent_attention_roofline_share.ling3")
+    attention = fn(**shapes)["flops"]
+    assert attention == kimi_k2.latent_attention(2, 8704, 64, 128, 64, 128)["flops"]  # kimi's grid
+    assert abs(attention / 1e12 - 3.10) < 0.01
+    fn, shapes = need("gmm_roofline_share.ling3")
+    held = fn(held_share=128 / 512, **shapes)
+    assert held["call_sites"] == 18 and held["flops"] == 18 * 2 * 69632 * 2560 * 768
+    fn, shapes = need("step_mfu.ling3")
+    step = fn(**shapes)["flops"]
+    assert abs(step / 1e12 - 43.7) < 0.1
+    rows = 34816
+    linear = 2 * rows * 2560 * (6 * 4096 + 32) + 2 * 4 * rows * 12288 + rule["flops"]
+    latent = 2 * rows * (2560 * 32 * 192 + 2560 * 576 + 512 * 32 * 256 + 4096 * 2560
+                         + 2560 * 32) + attention
+    sparse = 6 * rows * 2560 * 768 * (1 + 8 * 128 / 512) + 2 * rows * 2560 * 512
+    rest = 2 * 4 * 8448 * 256 * 2560 + 2 * 4 * 2560 * 39296 + 6 * rows * 2560 * 6144
+    assert step == pytest.approx(6 * linear + latent + 6 * sparse + rest, rel=1e-12)
+    assert abs(2 * 2560 * (6 * 4096 + 32) / 1e6 - 126) < 0.5  # a linear layer's products a token
+    assert roofline.step.__code__.co_argcount == len(shapes)
+
+
+@pytest.mark.parametrize("name", sorted(OTHERS) + ["ling3_flash_prefill_epix10k2m"])
+def test_a_decoder_cell_s_own_metrics_read_functions_counters_and_names_that_exist(name):
+    """What repeats across the decoder cells, as ONE rule over the manifest:
+    every per-layer metric a decoder cell has to itself names a reader that
+    exists, a roofline function that takes exactly the shapes it is given
+    from keys its configuration has, trace names the file declares, and
+    counters the step counts."""
+    manifest, cfg = _manifest(), _file(name)
+    cell, = [w["name"] for w in manifest["workloads"] if w["config"] == name]
+    own = [e for e in manifest["per_layer"] if e.get("workloads") == [cell]]
+    assert len(own) >= 9 and len({e["name"].rsplit(".", 1)[1] for e in own}) == 1
+    assert any("roofline" in e["name"] for e in own)
+    for entry in own:
+        _spec_names_what_exists(entry["name"], cfg)
+
+
+# ---------------------------------------------------------------------------
+# the adapter, and the cell's rehearsal
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("lacks", ["linear_head_dim", "attn_gate"])
+def test_the_adapter_ends_the_run_where_the_package_lacks_the_mechanism(monkeypatch, lacks):
+    from benchmark.programs import prefill_hybrid
+
+    older = dataclasses.make_dataclass(
+        "Older", [(f.name, f.type, dataclasses.field(default=None))
+                  for f in dataclasses.fields(decoder.DecoderConfig) if f.name != lacks], frozen=True)
+    monkeypatch.setattr(decoder, "DecoderConfig", older)
+    with pytest.raises(SystemExit) as e:
+        prefill_hybrid.Program({"name": "ling3_flash_prefill_epix10k2m"}, 1, "", None)
+    assert e.value.code not in (0, None) and lacks in str(e.value.code)
+
+
+def test_the_adapter_ends_the_run_where_the_file_counts_other_experts_than_it_holds():
+    from benchmark.programs import prefill_hybrid
+
+    with pytest.raises(SystemExit) as e:
+        prefill_hybrid.Program({**_file(), "num_experts": 512}, 1, "", None)
+    assert "is not the count of experts_held" in str(e.value.code)
+
+
+def test_the_cell_s_rehearsal_runs_the_served_path_and_is_correct():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run(
+        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
+         "--seed", "1", "--seconds", "2", "--trace", "1"],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-2000:]
+    line = json.loads(done.stdout.strip().splitlines()[-1])
+    assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["metrics"] == {}
+    assert line["cell"] == CELL and line["attempted"] > 0
+    for name in ("kda_chunk_rows.ling3", "held_rows_share.ling3", "expert_load_peak.ling3",
+                 "device_wait_ms.hit"):
+        assert name in line["would_report"], line["would_report"]
+    assert "compiles inside the window 0" in done.stderr

@@ -182,6 +182,10 @@ def total_aux_loss(intermediates) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
+# one trace and one lowering a process, as gated_row_sum: k passes, k gates and k compares a layer
+# cost a warm start 0.35 s in a six-layer step where they were traced a layer (my chip runs, PR 51)
+@functools.partial(jax.jit, static_argnames=("k", "renormalise", "gate_eps", "gate_scale", "groups",
+                                             "groups_kept"))
 def route_top_k(probs: jax.Array, k: int, renormalise: bool = True, *, select_bias=None,
                 gate_eps: float = 0.0, gate_scale: float = 1.0, groups: int = 1,
                 groups_kept: int = 1):
@@ -197,27 +201,97 @@ def route_top_k(probs: jax.Array, k: int, renormalise: bool = True, *, select_bi
     ``topk_group``): the experts are ``groups`` runs of ``E / groups``
     consecutive ones, a group's score is the sum of its two largest
     choosing scores, the ``groups_kept`` best groups stay (equal scores:
-    the lower group) and the ``k`` are chosen among their experts only."""
+    the lower group) and the ``k`` are chosen among their experts only.
+
+    Nothing here indexes by data: no sort, gather or scatter. The ``k``
+    are taken by ``k`` dense passes over ``[T, E]`` and their affinities
+    read by a compare and a row sum (what ``lax.top_k`` and
+    ``take_along_axis`` gave, bit for bit, ties included); the
+    renormalising sum is written out (:func:`_sum_by_halves`). On the v5e
+    the whole routing of a layer at 34,816 x 512, 8 of 4 groups in 8,
+    took 12.0 ms that way and takes 2.0 this way (my chip runs, PR 51)."""
+    scores = probs if select_bias is None else probs + select_bias.astype(probs.dtype)
     if groups > 1:
-        scores = probs if select_bias is None else probs + select_bias.astype(probs.dtype)
-        t, e = scores.shape
-        best_two = jax.lax.top_k(scores.reshape(t, groups, e // groups), 2)[0]
-        _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), groups_kept)  # [T, groups_kept]
-        stays = jnp.any(kept[:, :, None] == jnp.arange(groups, dtype=kept.dtype), axis=1)
-        _, ids = jax.lax.top_k(
-            jnp.where(jnp.repeat(stays, e // groups, axis=1), scores, -jnp.inf), k)
-        top_p = jnp.take_along_axis(probs, ids, axis=-1)
-    elif select_bias is None:
-        top_p, ids = jax.lax.top_k(probs, k)
-    else:
-        _, ids = jax.lax.top_k(probs + select_bias.astype(probs.dtype), k)
-        top_p = jnp.take_along_axis(probs, ids, axis=-1)
+        width = scores.shape[1] // groups
+        if groups_kept * width < k:  # a struck winner and a barred expert both read -inf
+            raise ValueError(f"{groups_kept} groups of {width} experts hold fewer than k={k}")
+        scores = jnp.where(_groups_kept(scores, groups, groups_kept), scores, -jnp.inf)
+    ids = _first_best(scores, k)
+    chosen = _at_columns(probs, ids)  # k columns [T]
+    top_p = jnp.stack(chosen, axis=1)
     if renormalise:
-        total = jnp.sum(top_p, axis=-1, keepdims=True)
+        total = _sum_by_halves(chosen)[:, None]
         top_p = top_p / (total + gate_eps if gate_eps else total)
     if gate_scale != 1.0:
         top_p = top_p * gate_scale
-    return ids.astype(jnp.int32), top_p
+    return ids, top_p
+
+
+def _sum_by_halves(terms):
+    """The sum of a list of arrays in ONE written order on every backend:
+    term ``i`` meets term ``i + n/2`` first, ``n`` the next power of two
+    (of eight: ``((t0 + t4) + (t2 + t6)) + ((t1 + t5) + (t3 + t7))``). It is
+    the order XLA's lane reduce gave ``jnp.sum(top_p, axis=-1)`` on the TPU
+    while ``top_p`` was a gather's output (278,528 rows of 278,528 at k 8,
+    139,264 at k 4: my chip runs, PR 51), kept so that the gates, and with
+    them a served step's outputs, did not move by a bit when the gather
+    went; on the CPU that ``jnp.sum`` adds in index order, 1-2 ulps off."""
+    terms = list(terms)
+    terms += [None] * ((1 << (len(terms) - 1).bit_length()) - len(terms))
+    while len(terms) > 1:
+        half = len(terms) // 2
+        terms = [a if b is None else a + b for a, b in zip(terms[:half], terms[half:])]
+    return terms[0]
+
+
+def _first_best(scores, k: int):
+    """``scores [T, E]`` -> ``[T, k]`` int32: each row's ``k`` largest in
+    descending order, equal scores the lower index first (``lax.top_k``'s
+    order), by ``k`` passes over ``[T, E]``: a pass takes the first index
+    of the row's maximum and strikes it."""
+    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    ids = []
+    for _ in range(k):
+        best = jnp.argmax(scores, axis=-1).astype(jnp.int32)
+        ids.append(best)
+        scores = jnp.where(col == best[:, None], -jnp.inf, scores)
+    return jnp.stack(ids, axis=1)
+
+
+def _at_columns(values, ids):
+    """``values[t, ids[t, j]]`` for each ``j``, ``k`` arrays ``[T]``, without
+    a gather: a sum over the row with one nonzero term, so exact."""
+    col = jax.lax.broadcasted_iota(jnp.int32, values.shape, 1)
+    return [jnp.sum(jnp.where(col == ids[:, j, None], values, 0), axis=-1)
+            for j in range(ids.shape[1])]
+
+
+def _groups_kept(scores, groups: int, kept: int):
+    """``[T, E]`` bool: the experts of each row's ``kept`` best groups of
+    ``groups`` runs of consecutive experts, a group's score the sum of its
+    two largest (equal scores: the lower group stays)."""
+    t, e = scores.shape
+    by_group = scores.reshape(t, groups, e // groups)
+    best = jnp.max(by_group, axis=-1)
+    first = jnp.argmax(by_group, axis=-1)
+    within = jax.lax.broadcasted_iota(jnp.int32, by_group.shape, 2)
+    second = jnp.max(jnp.where(within == first[..., None], -jnp.inf, by_group), axis=-1)
+    score = best + second  # [T, groups]
+    g = jnp.arange(groups)
+    ahead = (score[:, None, :] > score[:, :, None]) | (
+        (score[:, None, :] == score[:, :, None]) & (g[None, :] < g[:, None]))
+    stays = jnp.sum(ahead, axis=-1) < kept  # a group's rank among the row's groups
+    return jnp.repeat(stays, e // groups, axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("first", "count"))
+def _slots_per_expert(ids, first: int, count: int):
+    """``[count]`` int32: how many of the token slots ``ids [T, k]`` chose
+    each of the experts ``first .. first + count - 1``: column sums of a
+    compare, no scatter."""
+    experts = first + jnp.arange(count, dtype=ids.dtype)
+    chose = sum((ids[:, j, None] == experts).astype(jnp.int32) for j in range(ids.shape[1]))
+    return jnp.sum(chose, axis=0)
 
 
 SCORINGS = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1), "sigmoid": jax.nn.sigmoid}
@@ -275,6 +349,9 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
     parts of all holders add up to the whole layer. ``tokens`` is how
     many token slots each held expert served.
 
+    The routing (scope ``moe_route``: the router's product, the choice,
+    the gates, each held expert's count, the slots' sort) is dense passes
+    over ``[T, E]`` and one or two ``argsort``s of the ``T * k`` slots.
     Token slots are sorted by expert (stable: a token's order within an
     expert is its order in ``x``) and each row moves ONCE each way. Out:
     one in-bounds gather puts the rows in expert order
@@ -299,12 +376,12 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
         ids, gates = route_top_k(SCORINGS[scoring](logits), k, renormalise,
                                  select_bias=select_bias, gate_eps=gate_eps,
                                  gate_scale=gate_scale, groups=groups, groups_kept=groups_kept)
-        if count < num_experts:  # a share of the experts: the rows HELD move, no others
-            return _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first, count, interpret,
-                                  _rows_ahead(t * k, count, num_experts))
-        flat = ids.reshape(-1)
-        order = jnp.argsort(flat, stable=True)
-        per_expert = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+    if count < num_experts:  # a share of the experts: the rows HELD move, no others
+        return _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first, count, interpret,
+                              _rows_ahead(t * k, count, num_experts))
+    with jax.named_scope("moe_route"):
+        order = jnp.argsort(ids.reshape(-1), stable=True)
+        per_expert = _slots_per_expert(ids, 0, num_experts)
     with jax.named_scope("moe_experts"):
         rows = gather_rows(x, order // k, interpret=interpret)  # [T*k, D], expert-major
         product = functools.partial(_grouped_product, tokens=per_expert, interpret=interpret)
@@ -376,7 +453,7 @@ def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, 
         back = jnp.argsort(order).reshape(t, k) if ahead else None  # slot (t, j) -> its sorted row
         # a turn's slice never runs off the end
         order = jnp.pad(order, (0, -(slots - ahead) % chunk))
-        per_expert = jnp.bincount(local, length=count + 1)[:count].astype(jnp.int32)
+        per_expert = _slots_per_expert(ids, first, count)
         ends = jnp.cumsum(per_expert)
         starts, n_held = ends - per_expert, ends[-1]
         flat_gates = gates.reshape(-1)

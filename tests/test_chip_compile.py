@@ -550,15 +550,17 @@ def test_the_served_peaknet_is_the_plain_flax_model(one_chip, monkeypatch):
 # one of these programs re-pins it, knowingly: an equal text is an equal key in the compile
 # cache, and a decoder cell's warm `setup_s` (bound 0.1) pays seconds for anything new to trace
 PINNED_STEPS = {
-    # pinned on PR 49's tree in PR 50, which gave latent attention a full-rank query and an
-    # output gate and the schedule a linear operator: none of it may reach this program
-    "deepseek_v32_prefill_epix10k2m": "17138bc67cdbb7c71b519a3cb05aa9a5e1f5accb0a0ddbe3c0e60ffae0a6f0e2",
-    # pinned on PR 43's tree in PR 46, which put a selection's mask on latent attention's path;
-    # re-pinned in PR 48, knowingly: its latent layers make two query products from W_uq's
-    # columns and hand the kernel token-major operands, keys and values in ONE array
-    "kimi_k2_prefill_epix10k2m": "405f211b55a3ad85a8e15994a0b903018547f99b27f1062156dcc78aceddc74b",
-    "keye_vl2_prefill_epix10k2m": "7ba74ce99ef580a3c7965ffc0a69c7388c5b3088a8b3cee64ab9e1dd8a69af1f",
-    "lfm2_8b_a1b_prefill_epix10k2m": "7434bf59d9d3941cfe175dc4d0ec2e51f5f336d0b2a010924095d61446a8dd9d",
+    # all four re-pinned in PR 51, knowingly: every decoder's router takes its k experts by k dense
+    # passes over [T, E], reads their gates by a compare and a row sum and counts each held
+    # expert's slots by column sums of a compare (no top_k, take_along_axis or bincount: ids, raw
+    # affinities and counts bit for bit what they were; the renormalising sum over k written out
+    # in the order the TPU's lane reduce gave it), and a share-holder's products left moe_route.
+    # Before that: dsv32's pinned on PR 49's tree in PR 50; kimi's on PR 43's tree in PR 46 and
+    # again in PR 48 (token-major operands for the latent kernel)
+    "deepseek_v32_prefill_epix10k2m": "3ca0d8f5dd3b388f7599208326be1de70f4fe4855ff1186ab645fefe0ba3220f",
+    "kimi_k2_prefill_epix10k2m": "da84d0e21bf0ca69bc00745c6d74d6a290b8f986ee45602e3076dc0a6e764708",
+    "keye_vl2_prefill_epix10k2m": "e71a0690926768eb794d3c96c9ba607bcd9baab98ff6cda06f37b36147d5e090",
+    "lfm2_8b_a1b_prefill_epix10k2m": "8b0c0caa3bd0b7156f13ff2f2d29e892c90b50522d24d3595d5d719fbc4cb085",
 }
 
 
@@ -685,6 +687,58 @@ def test_latent_attention_s_operands_reach_the_kernel_where_their_products_wrote
         if moves and np.prod([int(x) for x in dims.split(",") if x]) >= tokens * heads * 64:
             moved.append(op_name)
     assert not moved, moved
+
+
+def _ling3_experts():
+    """The expert layer on a holder of 128 of 512 experts of 2560 x 768,
+    top 8 of the 4 best of 8 groups under the sigmoid router: 1.5 even
+    shares of the slots in one pass ahead of the held rows' loop."""
+    from psana_ray_tpu.parallel.moe import dropless_moe
+
+    def fn(x, router, bias, w_gate, w_up, w_down):
+        return dropless_moe(x, router, w_gate, w_up, w_down, k=8, num_experts=512,
+                            experts_held=(0, 128), scoring="sigmoid", select_bias=bias,
+                            gate_eps=1e-20, gate_scale=2.5, groups=8, groups_kept=4,
+                            interpret=False)
+
+    up = S((128, 2560, 768), BF16)
+    return fn, [S((LING3_B * LING3_S, 2560), BF16), S((2560, 512), BF16), S((512,), F32), up, up,
+                S((128, 768, 2560), BF16)]
+
+
+@pytest.mark.parametrize("layer", ["ling3", "lfm2"])
+def test_the_router_indexes_nothing_by_data(layer, one_chip, monkeypatch):
+    """ONE expert layer at ling3's and at lfm2's published sizes, as
+    compiled (PR 51): under the scope ``moe_route`` there is no ``scatter``
+    (``bincount``'s: 2.4 ms a layer at ling3's 278,528 slots), no ``gather``
+    (``take_along_axis``'s: 2.9 ms) and no sort but the slots' own
+    ``argsort``s over ``T * k`` (``lax.top_k`` was a full sort of ``[T, 512]``:
+    3.3 ms, and two more for the group limit); no array over slots AND
+    experts (``[T, k, E]``, ``[T * k, E]``) exists, inside a fusion or out; and on a holder of a share the
+    products and the way back stand under ``moe_experts`` alone, where until
+    PR 51 the whole layer stood under ``moe_route``."""
+    case, tokens, k, experts = {"ling3": (_ling3_experts, LING3_B * LING3_S, 8, 512),
+                                "lfm2": (_lfm2_experts, LFM2_B * LFM2_S, 4, 32)}[layer]
+    fn, arg_shapes, *_ = case()
+    text = _compile_case(fn, arg_shapes, one_chip, monkeypatch).as_text()
+    routed = [line for line in text.splitlines() if "/moe_route/" in line]
+    assert len(routed) > 20  # the scope reaches the compiled text
+    by_data = [line.strip()[:160] for line in routed
+               if re.search(r" (scatter|gather|custom-call)\(", line) or "TopK" in line]
+    assert not by_data, by_data
+    sorts = [line for line in routed if re.search(r" sort\(", line)]
+    assert 1 <= len(sorts) <= 2, sorts
+    for line in sorts:  # each an argsort of the T * k slots: keys and their places, one axis
+        dims = {d for _, d in _SHAPE.findall(line.split(" sort(")[0])}
+        assert dims == {str(tokens * k)}, line[:200]
+    spread = [sorted(dims) for dims in ((tokens, k, experts), (tokens * k, experts), (tokens, k * experts))]
+    sized = [line.strip()[:160] for line in text.splitlines()
+             for _, dims in _SHAPE.findall(line.split(", metadata=")[0])
+             if sorted(int(x) for x in dims.split(",") if x) in spread]
+    assert not sized, sized[:3]
+    assert not re.findall(r'op_name="[^"]*moe_route/[^"]*moe_experts', text)
+    kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all("/moe_experts/" in line and "/moe_route/" not in line for line in kernels)
 
 
 @pytest.mark.parametrize("d", [0, 1, 2, 3])

@@ -294,3 +294,155 @@ def test_serving_capacity_factor_is_trace_time_only():
     out_lo = serve.apply(variables, frames)
     assert out_lo.shape == out_nd1.shape
     assert np.isfinite(np.asarray(out_lo)).all()
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer's router: dense passes held to the indexed form they replaced
+# ---------------------------------------------------------------------------
+
+
+def _chip_order_sum(top_p):
+    """``jnp.sum(top_p, axis=-1, keepdims=True)`` in the order the TPU gave it
+    until PR 51 (column i meets column i + n/2 first, n the next power of
+    two), spelled out here on its own: the order ``route_top_k`` now writes."""
+    cols = [top_p[:, j] for j in range(top_p.shape[1])]
+    while len(cols) & (len(cols) - 1):
+        cols.append(None)
+    while len(cols) > 1:
+        half = len(cols) // 2
+        cols = [lo if hi is None else lo + hi for lo, hi in zip(cols[:half], cols[half:])]
+    return cols[0][:, None]
+
+
+def _indexed_route(probs, k, renormalise=True, *, select_bias=None, gate_eps=0.0, gate_scale=1.0,
+                   groups=1, groups_kept=1, total_of=lambda top_p: jnp.sum(top_p, axis=-1, keepdims=True)):
+    """``moe.route_top_k`` as it stood until PR 51, line for line: ``lax.top_k``
+    for the choice (three with a group limit), ``take_along_axis`` for the gates
+    (``total_of``: its ``jnp.sum`` over the k, or that sum in the chip's order)."""
+    if groups > 1:
+        scores = probs if select_bias is None else probs + select_bias.astype(probs.dtype)
+        t, e = scores.shape
+        best_two = jax.lax.top_k(scores.reshape(t, groups, e // groups), 2)[0]
+        _, kept = jax.lax.top_k(jnp.sum(best_two, axis=-1), groups_kept)
+        stays = jnp.any(kept[:, :, None] == jnp.arange(groups, dtype=kept.dtype), axis=1)
+        _, ids = jax.lax.top_k(
+            jnp.where(jnp.repeat(stays, e // groups, axis=1), scores, -jnp.inf), k)
+        top_p = jnp.take_along_axis(probs, ids, axis=-1)
+    elif select_bias is None:
+        top_p, ids = jax.lax.top_k(probs, k)
+    else:
+        _, ids = jax.lax.top_k(probs + select_bias.astype(probs.dtype), k)
+        top_p = jnp.take_along_axis(probs, ids, axis=-1)
+    if renormalise:
+        total = total_of(top_p)
+        top_p = top_p / (total + gate_eps if gate_eps else total)
+    if gate_scale != 1.0:
+        top_p = top_p * gate_scale
+    return ids.astype(jnp.int32), top_p
+
+
+def _indexed_counts(ids, first, count, num_experts):
+    """Each held expert's token slots as both paths of the layer counted
+    them until PR 51: ``bincount`` over all experts, or over the held ones
+    with every other slot sent to one bin past them."""
+    if count == num_experts:
+        return jnp.bincount(ids.reshape(-1), length=num_experts).astype(jnp.int32)
+    here = (ids >= first) & (ids < first + count)
+    local = jnp.where(here, ids - first, count).reshape(-1)
+    return jnp.bincount(local, length=count + 1)[:count].astype(jnp.int32)
+
+
+def _cell(e, k, scoring, bias, **kw):
+    return dict(e=e, k=k, scoring=scoring, bias=bias, **kw)
+
+
+# (E, k, groups, groups_kept, scoring, bias, the gate's constants) of the five decoder cells,
+# then what a router's ties, bias and gate options can do
+ROUTINGS = {
+    "ling3": _cell(512, 8, "sigmoid", "random", groups=8, groups_kept=4, gate_eps=1e-20, gate_scale=2.5),
+    "lfm2": _cell(32, 4, "sigmoid", "random", gate_eps=1e-6),
+    "kimi": _cell(384, 8, "sigmoid", "random", gate_eps=1e-20, gate_scale=2.827),
+    "dsv32": _cell(256, 8, "sigmoid", "random", groups=8, groups_kept=4, gate_eps=1e-20, gate_scale=2.5),
+    "keye": _cell(128, 8, "softmax", None),
+    "all_scores_equal": _cell(64, 8, "sigmoid", None, logits="zero"),
+    "all_scores_equal_under_a_group_limit": _cell(64, 8, "sigmoid", "zero", groups=8, groups_kept=4,
+                                                  logits="zero"),
+    "few_distinct_scores": _cell(128, 8, "sigmoid", "coarse", logits="coarse", gate_eps=1e-20),
+    "few_distinct_scores_under_a_group_limit": _cell(128, 8, "sigmoid", "coarse", groups=8, groups_kept=4,
+                                                     logits="coarse", gate_eps=1e-20),
+    "ties_across_a_group_boundary": _cell(32, 6, "sigmoid", None, groups=4, groups_kept=2,
+                                          logits="boundary"),
+    "groups_tie": _cell(32, 4, "sigmoid", None, groups=8, groups_kept=3, logits="coarse"),
+    "a_bias_that_reorders": _cell(64, 6, "softmax", "large"),
+    "a_bias_that_reorders_under_a_group_limit": _cell(64, 6, "sigmoid", "large", groups=4, groups_kept=2),
+    "renormalise_off": _cell(64, 6, "sigmoid", "random", renormalise=False),
+    "renormalise_off_scaled": _cell(64, 6, "softmax", None, renormalise=False, gate_scale=2.5),
+    "gate_eps": _cell(64, 6, "sigmoid", "random", gate_eps=1e-6),
+    "gate_scale": _cell(64, 6, "sigmoid", "random", gate_scale=2.827),
+    "k_is_one": _cell(16, 1, "softmax", None),
+    "every_expert_chosen": _cell(8, 8, "sigmoid", "coarse", logits="coarse"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(ROUTINGS))
+def test_the_router_s_dense_passes_are_the_indexed_form_bit_for_bit(name):
+    """``route_top_k`` and the per-expert counts (``lax.argmax`` passes, a
+    compare and a row sum, column sums of a compare) against ``lax.top_k`` +
+    ``take_along_axis`` + ``bincount``: ``ids``, ``gates`` and ``per_expert``
+    equal BIT FOR BIT, ties included (the lower index first, in descending
+    order of the choosing score), jitted as the layer runs them. The
+    renormalising sum over the k is the one float32 sum whose ORDER a backend
+    chose (the CPU by index, the TPU's lane reduce by halves): the gates are
+    held bit for bit to the indexed form summing in the chip's order, which
+    the router now writes out, and to the indexed form's own ``jnp.sum`` as
+    the CPU runs it within 4 ulps, bit for bit where nothing is renormalised."""
+    from psana_ray_tpu.parallel import moe
+
+    case = dict(ROUTINGS[name])
+    e, k, scoring, bias, logits = (case.pop(key, None) for key in ("e", "k", "scoring", "bias", "logits"))
+    rng = np.random.default_rng(sorted(ROUTINGS).index(name))
+    t = 160
+    raw = rng.normal(size=(t, e)).astype(np.float32)
+    if logits == "zero":
+        raw = np.zeros_like(raw)
+    elif logits == "coarse":  # three values: every row full of ties
+        raw = np.round(raw)
+        raw[::7] = 0.0
+    elif logits == "boundary":  # the last expert of a group equals the first of the next, in every row
+        width = e // case["groups"]
+        raw = np.round(raw * 2) / 2
+        raw[:, width] = raw[:, width - 1]
+        raw[:, 2 * width - 1] = raw[:, 2 * width]
+    select_bias = {None: None, "zero": np.zeros(e), "random": rng.normal(size=e) * 0.02,
+                   "coarse": np.round(rng.normal(size=e)) / 4, "large": rng.normal(size=e) * 3}[bias]
+    if select_bias is not None:
+        select_bias = jnp.asarray(select_bias, jnp.float32)
+    probs = moe.SCORINGS[scoring](jnp.asarray(raw))
+
+    want = jax.jit(lambda p: _indexed_route(p, k, select_bias=select_bias, total_of=_chip_order_sum,
+                                            **case))(probs)
+    got = jax.jit(lambda p: moe.route_top_k(p, k, select_bias=select_bias, **case))(probs)
+    for a, b in zip(want, got):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    ids, gates = (np.asarray(a) for a in got)
+    ids_cpu, gates_cpu = (np.asarray(a) for a in jax.jit(
+        lambda p: _indexed_route(p, k, select_bias=select_bias, **case))(probs))
+    np.testing.assert_array_equal(ids, ids_cpu)
+    ulps = np.abs(gates.view(np.int32).astype(np.int64) - gates_cpu.view(np.int32).astype(np.int64))
+    assert ulps.max() <= (4 if case.get("renormalise", True) else 0), ulps.max()
+    ids = got[0]
+    if logits == "zero":
+        np.testing.assert_array_equal(np.asarray(ids), np.tile(np.arange(k), (t, 1)))
+    for first, count in ((0, e), (0, max(e // 4, 1)), (e // 2, max(e // 8, 1))):
+        want_n = jax.jit(lambda i: _indexed_counts(i, first, count, e))(ids)
+        got_n = jax.jit(lambda i: moe._slots_per_expert(i, first, count))(ids)
+        assert want_n.dtype == got_n.dtype
+        np.testing.assert_array_equal(np.asarray(want_n), np.asarray(got_n))
+
+
+def test_a_group_limit_that_leaves_fewer_than_k_experts_is_refused():
+    from psana_ray_tpu.parallel import moe
+
+    with pytest.raises(ValueError, match="fewer than k"):
+        moe.route_top_k(jnp.zeros((4, 16)), 6, groups=4, groups_kept=1)

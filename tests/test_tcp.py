@@ -145,19 +145,21 @@ class TestBatchedOpcodes:
             recs = [
                 FrameRecord(0, i, np.zeros((1, 8, 8), np.float32), 1.0) for i in range(n)
             ]
-            assert client.put_batch(recs) == n
-            t0 = time.monotonic()
-            out = client.get_batch(n, timeout=2.0)
-            t_batch = time.monotonic() - t0
-            assert len(out) == n
-            assert client.put_batch(recs) == n
-            t0 = time.monotonic()
-            for _ in range(n):
-                assert client.get() is not EMPTY
-            t_single = time.monotonic() - t0
+            t_batch, t_single = [], []
+            for _ in range(3):  # one stall of the machine inside a 10 ms timing must not decide
+                assert client.put_batch(recs) == n
+                t0 = time.monotonic()
+                out = client.get_batch(n, timeout=2.0)
+                t_batch.append(time.monotonic() - t0)
+                assert len(out) == n
+                assert client.put_batch(recs) == n
+                t0 = time.monotonic()
+                for _ in range(n):
+                    assert client.get() is not EMPTY
+                t_single.append(time.monotonic() - t0)
             # loopback round trips are ~50us each; batch should win clearly,
             # but keep the margin loose for CI noise
-            assert t_batch < t_single
+            assert min(t_batch) < min(t_single)
             client.disconnect()
         finally:
             srv.shutdown()

@@ -69,7 +69,7 @@ import numpy as np
 from psana_ray_tpu.ops.delta_rule import CHUNK, chunk_rows, gated_delta_rule
 from psana_ray_tpu.ops.short_conv import gated_conv_taps
 from psana_ray_tpu.parallel import sparse_attention as sa
-from psana_ray_tpu.parallel.moe import dropless_moe
+from psana_ray_tpu.parallel.moe import dropless_moe, goes_ahead, rows_ahead
 
 # what frame_step's statistics vector holds: the first four summed over the
 # batch and over the layers that have the thing counted
@@ -99,6 +99,11 @@ PAIR_STATS = (
 LINEAR_STATS = (
     "linear_attn_tokens_total",  # tokens through a linear-attention layer, over such layers
     "linear_attn_chunks_total",  # chunks of the delta rule's kernel: head-sequences x chunks a layer
+)
+# and one after those twelve (the groups above 0 where the step has none), from a holder of so LARGE
+# a share that a pass goes ahead of its held rows' loop (`moe.rows_ahead`)
+AHEAD_STATS = (
+    "expert_rows_ahead_total",   # held rows that pass, and its way back, took: the loop took the rest
 )
 # layer_types, as config.json spells them
 ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
@@ -251,9 +256,18 @@ class DecoderConfig:
         return LINEAR in self.layer_types
 
     @property
+    def rows_go_ahead(self) -> bool:
+        """A share of the experts so large that ``dropless_moe`` takes a pass
+        ahead of its held rows' loop: the step then counts every group, and
+        :data:`AHEAD_STATS` after them."""
+        return self.holds_a_share and goes_ahead(self.experts_held[1], self.num_experts)
+
+    @property
     def layer_stats(self) -> int:
         """How many statistics a layer counts: the first four of
         :data:`STEP_STATS`, then the groups above."""
+        if self.rows_go_ahead:
+            return 4 + len(SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS)
         if self.has_linear:
             return 10
         return 8 if self.selects_over_latent else 6 if self.holds_a_share else 4
@@ -782,7 +796,8 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     of :data:`STEP_STATS` and, from a holder of a share of the experts,
     :data:`SHARE_STATS` (under a selection over latent attention those
     and :data:`PAIR_STATS`, with linear layers :data:`LINEAR_STATS` after
-    them: ``cfg.layer_stats`` in all). ``kind`` is ``cfg.layer_kind(i)``: the operator
+    them, and last :data:`AHEAD_STATS` where a pass goes ahead of the held
+    rows' loop: ``cfg.layer_stats`` in all). ``kind`` is ``cfg.layer_kind(i)``: the operator
     runs under ``conv``, linear attention's, latent attention's or attention's scopes, the
     feed-forward under ``moe`` (the routed experts), ``shared_expert``
     (beside them, added once) or ``mlp`` (dense)."""
@@ -842,6 +857,11 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
         stats += [jnp.float32(through * x.shape[0]),
                   jnp.float32(through * batch * cfg.num_heads
                               * (s // chunk_rows(s, cfg.linear_chunk)))]
+    if cfg.rows_go_ahead:
+        # the places of the groups the step has not
+        stats += [jnp.float32(0)] * (cfg.layer_stats - len(AHEAD_STATS) - len(stats))
+        ahead = rows_ahead(x.shape[0] * cfg.experts_per_token, cfg.experts_held[1], cfg.num_experts)
+        stats += [jnp.minimum(held[0], ahead).astype(jnp.float32) if held else jnp.float32(0)]
     return x, jnp.stack(stats)
 
 
@@ -851,8 +871,9 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     rotary, else ``[S]``), through every layer -> ``(x [B*S, D], stats
     in :data:`STEP_STATS`' order``, then :data:`SHARE_STATS` where the
     holder has a share of the experts, :data:`PAIR_STATS` under a
-    selection over latent attention, and :data:`LINEAR_STATS` after both
-    where layers are linear)."""
+    selection over latent attention, :data:`LINEAR_STATS` after both
+    where layers are linear, :data:`AHEAD_STATS` last where a pass goes
+    ahead of the held rows' loop)."""
     s = x.shape[0] // batch
     angles = rotary_angles(pos, cfg.rope_theta, cfg.rope_dim // 2, cfg.mrope_section,
                            cfg.rope_yarn)
@@ -937,9 +958,10 @@ def frame_step(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, thresho
 def fold_step_stats(metrics, stats) -> None:
     """Add one step's statistics vector (on the host or the device: six
     values, eight from a holder of a share of the experts, ten under a
-    selection over latent attention, twelve with linear layers) to the
+    selection over latent attention, twelve with linear layers, thirteen
+    where a pass goes ahead of the held rows' loop) to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""
-    for name, value in zip(STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS,
+    for name, value in zip(STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS,
                            np.asarray(stats, np.float64)):
         metrics.add_counter(name, float(value))

@@ -29,11 +29,29 @@ a warm start 0.7 s a program; without the overlap 2.46, with a wait a
 row 2.59), 4.19 for 274,432; a bfloat16 buffer unpacked by strided
 half-word reads 4.75 and 9.36 (my chip runs, PR 39). Off the TPU it runs
 in Pallas interpret mode.
+
+:func:`sum_counted_rows` is the way BACK of a holder of a share of the
+experts (``parallel/moe._held_rows_ahead``): ``y[t] = sum_j gates[t, j] *
+out[back[t, j]]`` over the slots that COUNT, a quarter of them at ling3's
+shape. XLA's shapes are static, so its ``k`` gathers of ``[T, D]`` move
+every slot's row and a ``where`` throws three of four away (12.9 ms + 2.5
+for the sum at 34,816 x 8 slots of 2,560). Here a token tile's counted
+slots are listed first (one sort of ``[T k / 1024, 1024]`` int32, 0.04
+ms), the scalar unit starts a copy for each of them in whole bursts of 16
+(so for up to 15 slots a grid step that do not count: their rows land at
+their own places and are selected to zero like any uncounted place), and
+a token's sum is made on whole vregs of its own rows (``[12, 128]`` words:
+two vregs a slot) and leaves by two strided stores into the ``[T, D]``
+float32 tiles: 2.7-2.9 ms, after 1.8 to lay ``out`` down as rows of words
+(:func:`_rows_as_words`). The first form summed eight tokens a vreg, a
+strided load for every slot and word sublane: 5.5 ms, 3.4 of them the
+348,160 strided loads, 9.8 ns each (my chip runs, PR 52).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -131,3 +149,195 @@ def gather_rows(x, idx, *, interpret: Optional[bool] = None) -> jax.Array:
         interpret=interpret,
         name="row_gather",
     )(idx, idx, x.reshape(n, 2 * a, _LANES))
+
+
+def _words_kernel(x_ref, o_ref, buf, sem, *, rows, words, steps):
+    from jax.experimental.pallas import tpu as pltpu
+
+    i = pl.program_id(0)
+    slot = lax.rem(i, 2)
+
+    def put(step, at):
+        return pltpu.make_async_copy(buf.at[at], o_ref.at[pl.ds(step * rows, rows)], sem.at[at])
+
+    @pl.when(i >= 2)
+    def _buffer_free():
+        put(i - 2, slot).wait()
+
+    def chunk(q, c):
+        first = pl.multiple_of(q * _CHUNK, _CHUNK)
+
+        def bits(of):  # 16 rows of a chunk of 128 columns, each value the HIGH half of a word
+            part = x_ref[pl.ds(first, _CHUNK), pl.ds(pl.multiple_of(of * _LANES, _LANES), _LANES)]
+            return lax.bitcast_convert_type(part.astype(jnp.float32), jnp.uint32)
+
+        def word(s, carry):  # word (s, l) of a row: its columns 256 s + l and 256 s + 128 + l
+            buf[slot, pl.ds(first, _CHUNK), s, :] = (bits(2 * s) >> 16) | bits(2 * s + 1)
+            return carry
+
+        lax.fori_loop(0, words, word, None, unroll=True)  # traced once: a warm start pays the trace
+        return c
+
+    lax.fori_loop(0, rows // _CHUNK, chunk, 0)
+    put(i, slot).start()
+
+    @pl.when(i == steps - 1)
+    def _all_written():
+        if steps > 1:
+            put(i - 1, 1 - slot).wait()
+        put(i, slot).wait()
+
+
+def _rows_as_words(x, view: int, interpret) -> jax.Array:
+    """``x [N, D]`` bfloat16, ``N`` in whole 16s and ``D`` in whole 256s -> ``[N, view / 2, 128]`` uint32, the words
+    :func:`gather_rows` reads (a row ONE block of ``view / 2`` word sublanes, those past ``D /
+    256`` left as they were), in one pass over ``x``. XLA's own relayout to the ``[N, view,
+    128]`` view takes two passes where the columns are padded first (a pad, a transposing copy:
+    3.77 ms for ``[104448, 2560]``) and three where the view comes first (5.56); this one 1.81
+    (my chip runs, PR 52)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = x.shape
+    rows = math.gcd(n, 512)
+    steps = n // rows
+    return pl.pallas_call(
+        functools.partial(_words_kernel, rows=rows, words=d // (2 * _LANES), steps=steps),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((rows, d), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct((n, view // 2, _LANES), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((2, rows, view // 2, _LANES), jnp.uint32), pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="rows_as_words",
+    )(x)
+
+
+_FLAG = 1 << 30  # on a listed slot that does not count: it sorts last
+
+
+def _sum_kernel(limit_ref, n_ref, list_ref, next_ref, back_ref, gates_ref, rows_ref, o_ref, buf, sem, *,
+                tokens, k, chunks, halves, steps):
+    """A step's ``tokens``: ``rows_ref [N, a, 128]`` 32-bit (HBM; a row, one block), the step's
+    ``tokens * k`` slots listed the counted first, ``n_ref[i]`` of them."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    i = pl.program_id(0)
+    slot = lax.rem(i, 2)
+    span = tokens * k
+
+    def bursts(n, one):
+        """``one(c)`` for the first ``n`` of the tile's listed slots, in whole bursts of 16 (the
+        body traced once: every unrolled copy is traced and lowered at each start of a process)."""
+        def burst(b, carry):
+            return lax.fori_loop(0, _CHUNK, lambda u, _: one(b * _CHUNK + u), carry, unroll=True)
+
+        lax.fori_loop(0, pl.cdiv(n, _CHUNK), burst, None)
+
+    def fetch_tile(listed, n, to):
+        """Starts the copies of a tile's counted slots (and of the uncounted that fill the last
+        burst): a slot's row lands at the slot's own place in the buffer."""
+        def fetch(c):
+            p = listed[c] & (_FLAG - 1)
+            pltpu.make_async_copy(rows_ref.at[lax.div(p, span)], buf.at[to, lax.rem(p, span)], sem.at[to]).start()
+
+        bursts(n, fetch)
+
+    @pl.when(i == 0)
+    def _first_tile():
+        fetch_tile(list_ref, n_ref[0], 0)
+
+    # the semaphore counts bytes: a wait a row, as many as were started
+    bursts(n_ref[i], lambda c: pltpu.make_async_copy(rows_ref.at[0], buf.at[slot, 0], sem.at[slot]).wait())
+
+    @pl.when(i + 1 < steps)
+    def _next_tile():  # in flight while this tile is summed
+        fetch_tile(next_ref, n_ref[jnp.minimum(i + 1, steps - 1)], 1 - slot)
+
+    limit = limit_ref[0]
+
+    def token(t, c):
+        def add(j, sums):
+            counts = back_ref[t * k + j] < limit
+            gate = lax.select(counts, gates_ref[t * k + j], jnp.float32(0))
+            w = buf[slot, t * k + j]  # [a, 128]: the slot's row, or where it got no copy anything
+            w = lax.select(jnp.broadcast_to(counts, w.shape), w, jnp.zeros_like(w))
+            if halves == 2:  # word (s, l): the row's columns 256 s + l and 256 s + 128 + l
+                parts = (lax.bitcast_convert_type(w << 16, jnp.float32),
+                         lax.bitcast_convert_type(w & jnp.uint32(0xFFFF0000), jnp.float32))
+            else:
+                parts = (w,)
+            return tuple(acc + part * gate for acc, part in zip(sums, parts))
+
+        sums = lax.fori_loop(0, k, add, (jnp.zeros(buf.shape[2:], jnp.float32),) * halves, unroll=True)
+        # the output block is the [tokens, D] float32 TILE BY TILE, [tokens / 8, D / 128, 8, 128] as
+        # rows of 128. Sublane s of a sum is the token's columns of chunk `halves * s + h`: row
+        # t % 8 of tile (t / 8, chunk), a stride of `halves` tiles apart
+        first = lax.div(t, 8) * (chunks * 8) + lax.rem(t, 8)
+        for h, acc in enumerate(sums):
+            held = (chunks - h + halves - 1) // halves
+            if held:  # a bfloat16 row of one chunk has no odd half
+                o_ref[pl.ds(first + 8 * h, held, stride=8 * halves), :] = acc[:held]
+        return c
+
+    lax.fori_loop(0, tokens, token, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def sum_counted_rows(out, back, limit, gates, *, interpret: Optional[bool] = None) -> jax.Array:
+    """``out [N, D]``, ``back [T, k]`` int32 (slot ``(t, j)``'s row of
+    ``out``), ``limit`` (a slot COUNTS where ``back < limit``; ``limit <=
+    N``), ``gates [T, k]`` float32 -> ``y [T, D]`` float32, ``y[t] = sum_j
+    gates[t, j] * out[back[t, j]]`` over the counted ``j``, ascending, in
+    float32: a slot that does not count adds exactly nothing, and its row is
+    not copied (but for the up to 15 a grid step of 1,024 slots that fill
+    the last burst of 16 copies: what a place holds is selected to zero
+    wherever its slot does not count)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = out.shape
+    t, k = back.shape
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    halves = 2 if out.dtype == jnp.bfloat16 else 1  # columns a 32-bit word holds
+    chunks = -(-d // _LANES)
+    # a row as ONE block for a copy: whole 8-row tiles of the [N, ., 128] view
+    view = -(-chunks // 8) * 8
+    if halves == 2:  # (whole words of whole 16 rows as they come: the pad is none)
+        rows = _rows_as_words(jnp.pad(out, ((0, -n % _CHUNK), (0, -d % (2 * _LANES)))), view, interpret)
+    else:  # 32-bit rows are their own words: XLA's view
+        rows = jnp.pad(out.astype(jnp.float32), ((0, 0), (0, view * _LANES - d))).reshape(n, view, _LANES)
+    # token slots a grid step: whole tiles of 1,024 (an SMEM block's), or one step of them all
+    # (tokens in whole 8-row tiles of the output, their slots in whole bursts of copies)
+    tokens = _ROWS // math.gcd(k, _ROWS) if t * k > _ROWS else -(-t // 16) * 16
+    span = tokens * k
+    steps = -(-t // tokens)
+    if n * span > _FLAG:
+        raise ValueError(f"{n} rows x {span} slots a step do not fit a listed slot's 30 bits")
+    back = jnp.pad(back.astype(jnp.int32), ((0, steps * tokens - t), (0, 0)), constant_values=n).reshape(-1)
+    gates = jnp.pad(gates.astype(jnp.float32), ((0, steps * tokens - t), (0, 0))).reshape(-1)
+    limit = jnp.minimum(jnp.asarray(limit, jnp.int32), n).reshape(1)
+    counted = (back < limit).reshape(steps, span)
+    # a step's slots, the counted first: row * span + the slot's place in the step
+    listed = jnp.minimum(back, n - 1).reshape(steps, span) * span + jnp.arange(span, dtype=jnp.int32)
+    listed = lax.sort(jnp.where(counted, listed, listed | _FLAG), dimension=1, is_stable=False).reshape(-1)
+    per_step = jnp.sum(counted, axis=1, dtype=jnp.int32)
+    whole = pl.BlockSpec(memory_space=pltpu.SMEM)
+    slots = pl.BlockSpec((span,), lambda i: (i,), memory_space=pltpu.SMEM)
+    following = pl.BlockSpec((span,), lambda i: (jnp.minimum(i + 1, steps - 1),), memory_space=pltpu.SMEM)
+    y = pl.pallas_call(
+        functools.partial(_sum_kernel, tokens=tokens, k=k, chunks=chunks, halves=halves, steps=steps),
+        grid=(steps,),
+        in_specs=[whole, whole, slots, following, slots, slots, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((tokens * chunks, _LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((steps * tokens * chunks, _LANES), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, span, view // halves, _LANES), rows.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
+                                             vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret,
+        name="sum_counted_rows",
+    )(limit, per_step, listed, listed, back, gates, rows)
+    # [T / 8, D / 128, 8, 128] row-major IS [T, D] in its (8, 128) tiles: no pass
+    return y.reshape(-1, chunks, 8, _LANES).transpose(0, 2, 1, 3).reshape(steps * tokens, chunks * _LANES)[:t, :d]

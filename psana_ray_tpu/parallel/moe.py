@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from psana_ray_tpu.ops.row_gather import gather_rows
+from psana_ray_tpu.ops.row_gather import gather_rows, sum_counted_rows
 
 Dtype = Any
 
@@ -363,7 +363,8 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
     their gated sum, float32 inside, the ``k`` added in the order of the
     token's choices: a token's result depends on its own rows only,
     wherever it sits among the others. A holder of a SHARE of the experts
-    moves and multiplies the held slots' rows only (:func:`_held_rows_moe`).
+    moves and multiplies the held slots' rows only (:func:`_held_rows_moe`;
+    its way back reads the COUNTED rows only, ``ops/row_gather.sum_counted_rows``).
     On one holder this runs without an exchange; nothing here stands in
     for the other holders. Off the TPU the kernels run in Pallas interpret
     mode."""
@@ -378,7 +379,7 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
                                  gate_scale=gate_scale, groups=groups, groups_kept=groups_kept)
     if count < num_experts:  # a share of the experts: the rows HELD move, no others
         return _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first, count, interpret,
-                              _rows_ahead(t * k, count, num_experts))
+                              rows_ahead(t * k, count, num_experts))
     with jax.named_scope("moe_route"):
         order = jnp.argsort(ids.reshape(-1), stable=True)
         per_expert = _slots_per_expert(ids, 0, num_experts)
@@ -404,16 +405,26 @@ HELD_CHUNK = 2048
 # much again, in ONE pass ahead of the loop. On the v5e at 34,816 x 8 slots of 2,560, 128 of 512
 # experts held, 74,085 held rows: the loop alone 53.6 ms a layer, 37 turns whose scatter-add is 24.1
 # of them (0.32 us a row; the three products 10.2), and what a step takes follows the rows a seed
-# happens to hold; at 1/32 of the experts the loop's two or three turns are cheaper than the
-# pass's k gathers of [T, D] back (my chip runs, PR 50)
+# happens to hold (my chip runs, PR 50). The break-even was set while the pass went back by k
+# gathers of [T, D] (15.4 ms at that shape, dearer than the two or three turns a holder of 1/32
+# takes); since PR 52 it goes back by the counted rows alone (4.9 ms there), so the share at which
+# the pass pays is lower than this: moving it moves kimi's and dsv32's steps, and is not done here
 AHEAD_SHARE = 1 / 8
 
 
-def _rows_ahead(slots: int, count: int, num_experts: int) -> int:
+def goes_ahead(count: int, num_experts: int) -> bool:
+    """Whether a holder of ``count`` of ``num_experts`` takes a pass ahead of
+    its held rows' loop: the one place the rule stands (the decoder's
+    statistics ask here too)."""
+    return count >= AHEAD_SHARE * num_experts
+
+
+def rows_ahead(slots: int, count: int, num_experts: int) -> int:
     """The rows :func:`_held_rows_moe` takes in one pass ahead of its loop:
-    none under :data:`AHEAD_SHARE`, else 1.5 even shares in whole 8-row tiles
-    (104,448 = 204 x 512 of 278,528 slots at 128 of 512 experts)."""
-    if count < AHEAD_SHARE * num_experts:
+    none where the holder's share does not :func:`goes_ahead`, else 1.5 even
+    shares in whole 8-row tiles (104,448 = 204 x 512 of 278,528 slots at 128
+    of 512 experts)."""
+    if not goes_ahead(count, num_experts):
         return 0
     even = -(-slots * count // num_experts)  # rounded up, as the next two
     ahead = -(-3 * even // 2)
@@ -434,14 +445,18 @@ def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, 
     array of ``T * k`` rows exists; when every token chooses held experts
     the loop runs ``T * k / chunk`` turns and still drops nothing.
 
-    ``ahead`` > 0 (a holder of a LARGE share, :func:`_rows_ahead`): the
+    ``ahead`` > 0 (a holder of a LARGE share, :func:`rows_ahead`): the
     first ``ahead`` sorted rows go through ONE pass before the loop, the
-    all-held path's way at that size (one gather out, the three products
-    over ``[ahead, .]``, a token's rows read back where the sort put them
-    and summed under their gates in the order of its choices:
-    :func:`gated_row_sum`'s rule, a slot past the held rows or past
-    ``ahead`` counting nothing); the loop then takes what lies beyond
-    ``ahead``, on an even load nothing."""
+    all-held path's way at that size out (one gather, the three products
+    over ``[ahead, .]``); back, a token's COUNTED rows alone are copied
+    from where the sort put them and summed under their gates in the
+    order of its choices (:func:`gated_row_sum`'s rule; a slot past the
+    held rows or past ``ahead`` counts nothing, and but for the few that
+    fill a burst of copies moves nothing:
+    ``ops/row_gather.sum_counted_rows``, 4.9 ms a layer at ling3's shape
+    where ``k`` gathers of ``[T, D]`` and their sum took 15.4: my chip
+    runs, PR 52); the loop then takes what lies beyond ``ahead``, on an
+    even load nothing."""
     t, d = x.shape
     k = ids.shape[1]
     slots = t * k
@@ -488,7 +503,7 @@ def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, 
 def _held_rows_ahead(x, slot, back, gates, w_gate, w_up, w_down, starts, ends, interpret):
     """The first ``len(slot)`` sorted rows of :func:`_held_rows_moe` in one
     pass -> each token's gated sum over them, ``[T, D]`` float32."""
-    t, k = gates.shape
+    k = gates.shape[1]
     ahead = slot.shape[0]
     rows = gather_rows(x, slot // k, interpret=interpret)
     sizes = jnp.clip(ends, 0, ahead) - jnp.clip(starts, 0, ahead)
@@ -496,13 +511,9 @@ def _held_rows_ahead(x, slot, back, gates, w_gate, w_up, w_down, starts, ends, i
     h = jax.nn.silu(product(rows, w_gate, out_dtype=jnp.float32))
     h = (h * product(rows, w_up, out_dtype=jnp.float32)).astype(x.dtype)
     out = product(h, w_down, out_dtype=x.dtype)
-    counted = back < jnp.minimum(ends[-1], ahead)  # past them the products left `out` unwritten
-    y = jnp.zeros((t, x.shape[1]), jnp.float32)
-    for j in range(k):
-        at = jnp.where(counted[:, j], back[:, j], 0)
-        got = out.at[at].get(mode="promise_in_bounds")
-        y = y + jnp.where(counted[:, j, None], got.astype(jnp.float32) * gates[:, j, None], 0.0)
-    return y
+    # past the held rows the products left `out` unwritten: such a slot, as one past `ahead`, adds
+    # nothing whatever its row holds (the sum selects it to zero)
+    return sum_counted_rows(out, back, jnp.minimum(ends[-1], ahead), gates, interpret=interpret)
 
 
 @jax.jit  # one trace and one lowering a process, not one an expert layer: k gathers are slow to trace

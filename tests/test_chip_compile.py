@@ -609,6 +609,13 @@ def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_c
     pass ahead of the held rows' loop (``kimi_k2.held_products``'
     ``call_sites``: the ones that RUN on any load under 1.5 even shares) and
     the loop's own eighteen, which run only on what overflows the pass —
+    and, since PR 52, the pass's way back, two kernels an expert layer
+    (``rows_as_words`` lays the down product's rows out a block each,
+    ``sum_counted_rows`` copies a token's counted rows and writes their gated
+    sum: with them 30 Pallas instructions run under ``moe``, not ``call_sites``'
+    18, so ``gmm_roofline_share.ling3``, which reads every ``pallas_call``
+    there, reads nothing, and ``gmm_ahead_roofline_share.ling3`` reads the
+    pass's eighteen by their name, ``%gmm``, which this test pins),
     one ``masked_gqa_attention``, the calibration kernel, and no other."""
     import collections
 
@@ -632,9 +639,13 @@ def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_c
              if 'custom_call_target="tpu_custom_call"' in line]
     names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
     sites = kimi_k2.held_products(cfg["step_tokens"], 8, 2560, 768, 128, 7, 1, 0.25)["call_sites"]
+    expert_layers = dcfg.num_layers - dcfg.num_dense_layers
     assert names == {"gated_delta_rule": cfg["layer_types"].count("linear_attention"), "gmm": 2 * sites,
+                     "rows_as_words": expert_layers, "sum_counted_rows": expert_layers,
                      "masked_gqa_attention": 1, "fused_calibrate": 1}, names
-    assert all("/moe/" in line for line in calls if re.match(r"\s*%gmm", line))
+    assert sites == 3 * expert_layers == 18
+    assert all("/moe/" in line for line in calls
+               if re.match(r"\s*%(gmm|rows_as_words|sum_counted_rows)", line))
     assert all("/kda/" in line for line in calls if re.match(r"\s*%gated_delta_rule", line))
 
 
@@ -706,6 +717,16 @@ def _ling3_experts():
                 S((128, 768, 2560), BF16)]
 
 
+_LAYER_TEXT = {}  # an expert layer's compiled text (25 s each), for the tests that read it
+
+
+def _expert_layer_text(case, one_chip, monkeypatch):
+    if case not in _LAYER_TEXT:
+        fn, arg_shapes, *_ = case()
+        _LAYER_TEXT[case] = _compile_case(fn, arg_shapes, one_chip, monkeypatch).as_text()
+    return _LAYER_TEXT[case]
+
+
 @pytest.mark.parametrize("layer", ["ling3", "lfm2"])
 def test_the_router_indexes_nothing_by_data(layer, one_chip, monkeypatch):
     """ONE expert layer at ling3's and at lfm2's published sizes, as
@@ -719,8 +740,7 @@ def test_the_router_indexes_nothing_by_data(layer, one_chip, monkeypatch):
     PR 51 the whole layer stood under ``moe_route``."""
     case, tokens, k, experts = {"ling3": (_ling3_experts, LING3_B * LING3_S, 8, 512),
                                 "lfm2": (_lfm2_experts, LFM2_B * LFM2_S, 4, 32)}[layer]
-    fn, arg_shapes, *_ = case()
-    text = _compile_case(fn, arg_shapes, one_chip, monkeypatch).as_text()
+    text = _expert_layer_text(case, one_chip, monkeypatch)
     routed = [line for line in text.splitlines() if "/moe_route/" in line]
     assert len(routed) > 20  # the scope reaches the compiled text
     by_data = [line.strip()[:160] for line in routed
@@ -739,6 +759,35 @@ def test_the_router_indexes_nothing_by_data(layer, one_chip, monkeypatch):
     assert not re.findall(r'op_name="[^"]*moe_route/[^"]*moe_experts', text)
     kernels = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     assert kernels and all("/moe_experts/" in line and "/moe_route/" not in line for line in kernels)
+
+
+def test_a_share_holder_s_way_back_moves_no_row_of_every_token(one_chip, monkeypatch):
+    """ONE expert layer at ling3's sizes, as compiled (PR 52): under
+    ``moe_experts`` XLA gathers rows of 2,560 ONCE outside the loop, the
+    ``[ahead, 2560]`` of the way out; none writes ``[T, 2560]`` (the way
+    back was eight of them, 1.6 ms each, three slots of four fetched to be
+    thrown away). The way back is two kernels, and what ``sum_counted_rows``
+    writes tile by tile reaches ``[T, 2560]`` float32 by a bitcast, no pass."""
+    import collections
+
+    from psana_ray_tpu.parallel import moe
+
+    text = _expert_layer_text(_ling3_experts, one_chip, monkeypatch)
+    tokens = LING3_B * LING3_S
+    ahead = moe.rows_ahead(tokens * 8, 128, 512)
+    rows_gathered = [int(_SHAPE.search(line).group(2).split(",")[0]) for line in text.splitlines()
+                     if " gather(" in line and "/moe_experts/" in line and "/while/" not in line
+                     and _SHAPE.search(line).group(2).endswith(",2560")]
+    assert rows_gathered == [ahead] and ahead == 104448, rows_gathered
+    kernels = collections.Counter(
+        re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line and "/while/" not in line)
+    assert kernels == {"gmm": 3, "rows_as_words": 1, "sum_counted_rows": 1}, kernels
+    entry = text[text.index("ENTRY"):]
+    written = [line.strip()[:120] for line in entry.splitlines()
+               if re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = f32\[{tokens},2560\]", line)
+               and "/sum_counted_rows/" in line.replace("jit(sum_counted_rows)", "/sum_counted_rows/")]
+    assert written and all(" bitcast(" in line for line in written), written
 
 
 @pytest.mark.parametrize("d", [0, 1, 2, 3])

@@ -104,8 +104,11 @@ def test_trunk_with_latent_attention_matches_reference_at_all_positions(held):
     assert float(stats[2]) == float(stats[3]) == 3  # one 64 x 64 tile a latent-attention layer
     assert [float(v) for v in stats[4:6]] == [64.0, 1.0]
     if held == "experts_4_to_7_of_16":  # a holder of a share counts what it held, and what was routed
-        assert len(stats) == 8 and float(stats[7]) == n_moe * 64 * 4
-        assert 0 < float(stats[6]) < float(stats[7])
+        assert float(stats[7]) == n_moe * 64 * 4 and 0 < float(stats[6]) < float(stats[7])
+        # and a holder of a QUARTER (this one, not the cell's 12 of 384, whose vector is eight long)
+        # takes a pass ahead of its loop: every group's places, then the rows that pass took
+        assert len(stats) == 13 and cfg.rows_go_ahead and not any(float(v) for v in stats[8:12])
+        assert float(stats[12]) == float(stats[6])  # under 1.5 even shares a layer: all of them
     else:
         assert len(stats) == 6
 

@@ -247,10 +247,14 @@ def test_the_hybrid_trunk_matches_the_reference_at_all_positions_of_a_batch_of_t
     for a, b in ((x, want_x), (got, want)):
         scale = float(jnp.sqrt(jnp.mean(b ** 2)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4 * scale, rtol=0)
-    # twelve statistics with linear layers, whatever the share
+    # twelve statistics with linear layers, whatever the share, and the pass's rows from a holder of half
     names = decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS + decoder.LINEAR_STATS
-    assert len(stats) == 12 == len(names)
+    assert len(names) == 12 and cfg.rows_go_ahead == (held != "all_16")
+    names += decoder.AHEAD_STATS if cfg.rows_go_ahead else ()
+    assert len(stats) == len(names)
     got_stats = dict(zip(names, (float(v) for v in stats)))
+    if cfg.rows_go_ahead:  # under 1.5 even shares a layer: the pass took every held row
+        assert got_stats["expert_rows_ahead_total"] == got_stats["expert_rows_held_total"] > 0
     assert got_stats["expert_tokens_mean_total"] == 2 * 128 * 4 / 16
     assert got_stats["attn_tiles_causal_total"] == got_stats["attn_tiles_live_total"] == 2  # one latent layer
     assert (got_stats["decoder_tokens_total"], got_stats["decoder_sequences_total"]) == (128, 2)
@@ -373,7 +377,7 @@ def test_four_shares_of_two_groups_each_and_the_shared_expert_once_add_up_to_the
 @pytest.mark.parametrize("case", ["within_the_pass", "overflowing_into_the_loop"])
 def test_a_large_share_takes_its_rows_in_one_pass_and_the_loop_takes_what_overflows(case):
     """A holder of a quarter of the experts takes 1.5 even shares of the slots
-    ahead of the held rows' loop (``moe._rows_ahead``); where a selection bias
+    ahead of the held rows' loop (``moe.rows_ahead``); where a selection bias
     sends EVERY token's four choices to the held eight, 256 rows are held for
     the pass's 96 and the loop adds the other 160: nothing is dropped, and
     the layer is the reference's either way."""
@@ -381,7 +385,7 @@ def test_a_large_share_takes_its_rows_in_one_pass_and_the_loop_takes_what_overfl
     bias = p["router_bias"]
     if case == "overflowing_into_the_loop":
         bias = bias.at[8:16].add(5.0)
-    assert moe._rows_ahead(64 * 4, 8, 32) == 96
+    assert moe.rows_ahead(64 * 4, 8, 32) == 96
     with jax.default_matmul_precision("highest"):
         y, tokens = moe.dropless_moe(
             b, p["router"], p["w_gate"][8:16], p["w_up"][8:16], p["w_down"][8:16], k=4,
@@ -396,9 +400,9 @@ def test_a_large_share_takes_its_rows_in_one_pass_and_the_loop_takes_what_overfl
 
 
 def test_the_pass_ahead_is_a_large_share_s_alone():
-    assert moe._rows_ahead(34816 * 8, 128, 512) == 104448 == 204 * 512  # the cell's: 1.5 x 69,632
-    assert moe._rows_ahead(17408 * 8, 12, 384) == 0 == moe._rows_ahead(8704 * 8, 8, 256)  # kimi's, dsv32's
-    assert moe._rows_ahead(64 * 4, 16, 32) == 192 and moe._rows_ahead(64 * 4, 31, 32) == 256
+    assert moe.rows_ahead(34816 * 8, 128, 512) == 104448 == 204 * 512  # the cell's: 1.5 x 69,632
+    assert moe.rows_ahead(17408 * 8, 12, 384) == 0 == moe.rows_ahead(8704 * 8, 8, 256)  # kimi's, dsv32's
+    assert moe.rows_ahead(64 * 4, 16, 32) == 192 and moe.rows_ahead(64 * 4, 31, 32) == 256
 
 
 # ---------------------------------------------------------------------------
@@ -539,11 +543,12 @@ def test_linear_counters_reach_the_snapshot_and_the_exposition():
     assert snap["linear_attn_tokens_total"] * 4 / snap["linear_attn_chunks_total"] == 8  # the chunk
     assert snap["attn_pairs_causal_total"] == 0 == snap["attn_pairs_selected_total"]
     assert 0 < snap["expert_rows_held_total"] < snap["expert_rows_routed_total"] == steps * 2 * 2 * s * 4
+    assert 0 < snap["expert_rows_ahead_total"] <= snap["expert_rows_held_total"]
     text = MetricsRegistry()
     text.register("reader", pipe.metrics)
     text = text.render_prometheus()
     for name in (decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS
-                 + decoder.LINEAR_STATS):
+                 + decoder.LINEAR_STATS + decoder.AHEAD_STATS):
         assert f'psana_ray_{name}{{source="reader"}}' in text, name
 
 
@@ -560,8 +565,10 @@ LING3_METRICS = ["proj_ms.ling3", "conv_ms.ling3", "kda_ms.ling3", "latent_attn_
                  "shared_expert_ms.ling3", "moe_ms.ling3", "mlp_ms.ling3",
                  "kda_roofline_share.ling3", "latent_attention_roofline_share.ling3",
                  "gmm_roofline_share.ling3", "step_mfu.ling3", "expert_load_peak.ling3",
-                 "held_rows_share.ling3", "kda_chunk_rows.ling3"]
-COUNTERS = decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS + decoder.LINEAR_STATS
+                 "held_rows_share.ling3", "kda_chunk_rows.ling3", "ahead_rows_share.ling3",
+                 "gmm_ahead_roofline_share.ling3"]
+COUNTERS = (decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS + decoder.LINEAR_STATS
+            + decoder.AHEAD_STATS)
 
 
 def _spec_names_what_exists(name, cfg):
@@ -582,7 +589,7 @@ def _spec_names_what_exists(name, cfg):
         if args.get(key, "").startswith("@"):
             assert args[key][1:] in cfg["trace_names"], name
     for key in ("numerator", "denominator"):
-        for counters in (args, args.get("share", {})):
+        for counters in (args, args.get("share", {}), args.get("share_where_alone", {})):
             assert counters.get(key, COUNTERS[0]) in COUNTERS, name
     return args
 
@@ -622,6 +629,71 @@ def test_the_ling3_cell_is_the_manifest_s_last_and_reports_the_host_path_as_the_
     cfg = _file()
     assert cfg["transport"]["slots"] == 16 and cfg["batch_size"] == 4
     assert cfg["trace_names"]["kda_kernel"] == "gated_delta_rule"  # the pallas_call's own name
+
+
+# what ran under `moe` in a traced step of ONE expert layer: name -> (the name stack's leaf, ms)
+PASS = {"gmm": ("pallas_call", 0.2), "gmm.1": ("pallas_call", 0.3), "gmm.2": ("pallas_call", 0.1)}
+WAY_BACK = {"rows_as_words": ("pallas_call", 0.4), "sum_counted_rows.1": ("pallas_call", 0.5)}
+LOOP = {"tpu_custom_call.3": ("pallas_call", 0.7), "fusion.8": ("scatter-add", 0.9)}
+NAMED_TRACES = {
+    # (what ran, counts the pass's rows, the rows' share read / None: nothing, said on stderr)
+    "the_change_s_step": ({**PASS, **WAY_BACK}, True, 0.2, ""),
+    "the_change_s_step_overflowing": ({**PASS, **WAY_BACK, **LOOP}, True, 0.2, ""),
+    "the_parent_s_step": (PASS, False, 0.25, ""),
+    "the_parent_s_step_overflowing": ({**PASS, **LOOP}, False, None, "tpu_custom_call.3"),
+    "a_product_more": ({**PASS, "gmm.3": ("pallas_call", 0.1), **WAY_BACK}, True, None, "call sites"),
+    "no_product_outside_a_loop": ({**WAY_BACK, **LOOP}, True, None, ""),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NAMED_TRACES))
+def test_the_pass_s_products_are_read_by_name_beside_the_kernels_of_its_way_back(
+        case, tmp_path, monkeypatch, capsys):
+    """``gmm_ahead_roofline_share.ling3`` (``readers/roofline_share_named_per_run``)
+    over a hand-written trace of two runs of a step: the instructions named
+    ``gmm`` under ``moe`` alone are summed, whatever else runs there; their
+    need follows the rows the PASS took where the program counts them, the
+    rows held where it does not and nothing else ran under the scope; three
+    named instructions must have run (``call_sites`` at one expert layer)."""
+    import types
+
+    from benchmark.readers import roofline_share_named_per_run, trace_scope_leaf_time
+
+    ran, counts_the_pass, share, said = NAMED_TRACES[case]
+    stack = "jit(ling3_step)/moe/jit(mlp)"
+    scopes = {name: f"{stack}/{leaf}" for name, (leaf, _) in ran.items()}
+    scopes["fusion.9"] = "jit(ling3_step)/proj/dot_general"
+    ops = [(f"%{name} = f32[8,8] custom-call(...)", run + 1e5 * (i + 1), ms * 1e6)
+           for run in (0.0, 5e7) for i, (name, (_, ms)) in enumerate(ran.items())]
+    ops += [("%fusion.9 = f32[8,8] fusion(...)", 4e7, 9e5)]
+    device = {0: {"XLA Modules": [("jit_ling3_step(1)", 0.0, 4.5e7), ("jit_ling3_step(1)", 5e7, 4.5e7)],
+                  "XLA Ops": sorted(ops, key=lambda e: e[1])}}
+    counters = {"expert_rows_held_total": 50.0, "expert_rows_routed_total": 200.0}
+    if counts_the_pass:
+        counters["expert_rows_ahead_total"] = 40.0
+    (tmp_path / "trace").mkdir()
+    (tmp_path / "trace" / "x.xplane.pb").write_bytes(b"")
+    monkeypatch.setattr(trace_scope_leaf_time, "load_scopes", lambda path: scopes)
+    ctx = types.SimpleNamespace(
+        trace=types.SimpleNamespace(device=device), trace_window=(0.0, 1e9),
+        cfg={"trace_names": {"step": "jit_ling3_step"},
+             "t": 64, "k": 4, "d": 128, "f": 64, "n": 4, "l": 2, "dense": 1},
+        spool_path=str(tmp_path / "spans" / "spool"),
+        metrics=types.SimpleNamespace(snapshot=lambda: counters),
+        peaks={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e30})
+    with open(os.path.join(REPO, "benchmark", "metrics", "gmm_ahead_roofline_share.ling3.json")) as f:
+        args = json.load(f)["args"]
+    args["shape_from"] = {"tokens": "t", "per_token": "k", "hidden": "d", "width": "f", "held": "n",
+                          "layers": "l", "dense_layers": "dense"}
+    got = roofline_share_named_per_run.read(ctx, **args)
+    err = capsys.readouterr().err
+    assert (said in err) if said else not err
+    if share is None:
+        assert got is None
+    else:  # three products over the rows' share of 64 x 4 slots, in the named three's 0.6 ms
+        assert got == pytest.approx(3 * 2 * (64 * 4 * share) * 128 * 64 / 1e12 / 6e-4 * 100.0)
+    ctx.trace = None
+    assert roofline_share_named_per_run.read(ctx, **args) is None
 
 
 def test_ling3_roofline_counts_at_the_published_sizes():

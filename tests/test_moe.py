@@ -446,3 +446,107 @@ def test_a_group_limit_that_leaves_fewer_than_k_experts_is_refused():
 
     with pytest.raises(ValueError, match="fewer than k"):
         moe.route_top_k(jnp.zeros((4, 16)), 6, groups=4, groups_kept=1)
+
+
+# ---------------------------------------------------------------------------
+# A share-holder's way back: the counted rows' kernel held to the k gathers it replaced
+# ---------------------------------------------------------------------------
+
+
+def _for_j_form(out, back, limit, gates):
+    """The pass's way back until PR 52 (``moe._held_rows_ahead``'s lines): ``k``
+    gathers of ``[T, D]``, each under a ``where`` that throws away what it
+    fetched for a slot that does not count."""
+    counted = back < limit
+    y = jnp.zeros((back.shape[0], out.shape[1]), jnp.float32)
+    for j in range(back.shape[1]):
+        at = jnp.where(counted[:, j], back[:, j], 0)
+        got = out.at[at].get(mode="promise_in_bounds")
+        y = y + jnp.where(counted[:, j, None], got.astype(jnp.float32) * gates[:, j, None], 0.0)
+    return y
+
+
+# tokens, k, rows of `out`, limit (None: every row), dtype: the shapes' corners
+WAYS_BACK = {
+    "a_quarter_counted": (48, 8, 104, 50, jnp.bfloat16),  # most tokens: none, one or two of eight
+    "every_row_counted": (48, 8, 384, None, jnp.bfloat16),  # all eight of every token
+    "nothing_held": (48, 8, 104, 0, jnp.bfloat16),
+    "rows_past_the_limit_unwritten": (40, 8, 104, 77, jnp.bfloat16),  # NaN there: never read
+    "tokens_not_a_tile": (21, 4, 40, 33, jnp.bfloat16),  # 21 tokens, 40 rows: both padded
+    "two_steps_of_the_grid": (512, 4, 1000, 900, jnp.bfloat16),  # 2,048 slots: 2 tiles of 1,024
+    "float32_rows": (48, 8, 104, 60, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("gates_are", ["powers_of_two", "with_zeros", "any"])
+@pytest.mark.parametrize("d", [2560, 2048, 64])
+@pytest.mark.parametrize("name", sorted(WAYS_BACK))
+def test_the_counted_rows_sum_is_the_k_gathers_it_replaced(name, d, gates_are):
+    """``ops/row_gather.sum_counted_rows`` (interpret mode here) against the
+    ``for j`` form: float32 sums in the order of a token's choices, a slot
+    past the limit adding nothing. Under gates that are powers of two every
+    product is exact, so the two are equal BIT FOR BIT whatever a backend
+    does with ``a * b + c`` and only the rows, the masks and the ORDER of the
+    sum decide; under any gates XLA's CPU backend contracts the interpreted
+    kernel's product and sum into one rounding where it keeps the gathers'
+    apart (on the TPU the two forms are equal to the bit: PERF.md section 5, PR 52),
+    so there the two may differ in the last place."""
+    from psana_ray_tpu.ops.row_gather import sum_counted_rows
+
+    t, k, n, limit, dtype = WAYS_BACK[name]
+    if d == 2560 and name == "two_steps_of_the_grid":
+        d = 512  # the same two steps, a fifth of the interpreter's time
+    rng = np.random.default_rng(len(name) * 1000 + d)
+    out = rng.standard_normal((n, d)).astype(np.float32)
+    limit = n if limit is None else limit
+    out[limit:] = np.nan  # what the grouped products leave past the held rows: anything
+    back = jnp.asarray(rng.permutation(max(t * k, n))[:t * k].reshape(t, k), jnp.int32)
+    if name == "a_quarter_counted":
+        back = back.at[3].set(jnp.arange(k) + limit)  # a token with no counted slot ...
+        back = back.at[5].set(jnp.arange(k))  # ... and one whose eight all count
+    gates = 2.0 ** -rng.integers(0, 7, (t, k)) if gates_are != "any" else rng.random((t, k)) + 0.1
+    if gates_are == "with_zeros":
+        gates = np.where(rng.random((t, k)) < 0.3, 0.0, gates)
+    out, gates = jnp.asarray(out, dtype), jnp.asarray(gates, jnp.float32)
+    got = np.asarray(sum_counted_rows(out, back, limit, gates))
+    want = np.asarray(jax.jit(_for_j_form)(out, back, jnp.int32(limit), gates))
+    assert got.shape == (t, d) and got.dtype == np.float32 and np.isfinite(got).all()
+    counted = np.asarray(back) < limit
+    assert (counted.sum(1) == 0).any() or limit == n
+    np.testing.assert_array_equal(got[counted.sum(1) == 0], 0.0)
+    if gates_are == "any":
+        np.testing.assert_allclose(got, want, rtol=0, atol=4 * np.spacing(np.abs(want).max()))
+    else:
+        np.testing.assert_array_equal(got, want)  # -0.0 == 0.0
+
+
+@pytest.mark.parametrize("case", ["within_the_pass", "more_held_than_the_pass_takes", "nothing_held"])
+def test_a_large_share_s_layer_is_the_whole_layer_restricted_to_the_held(case):
+    """8 of 32 experts held (a quarter: a pass of 96 rows goes ahead of the
+    loop) against ``dropless_moe`` on ALL experts with the others' down
+    weights zeroed: the pass's way back and, where a bias sends every
+    choice to the held eight, the loop's 160 rows add up to the same layer."""
+    from psana_ray_tpu.parallel import moe
+
+    t, d, f, e, k = 64, 64, 32, 32, 4
+    keys = jax.random.split(jax.random.key(52), 6)
+    x = jax.random.normal(keys[0], (t, d), jnp.float32)
+    router = 0.3 * jax.random.normal(keys[1], (d, e), jnp.float32)
+    w_gate, w_up = (0.2 * jax.random.normal(key, (e, d, f), jnp.float32) for key in keys[2:4])
+    w_down = 0.2 * jax.random.normal(keys[4], (e, f, d), jnp.float32)
+    bias = 0.1 * jax.random.normal(keys[5], (e,), jnp.float32)
+    bias = bias.at[8:16].add({"within_the_pass": 0.0, "more_held_than_the_pass_takes": 5.0,
+                              "nothing_held": -5.0}[case])
+    kw = dict(k=k, num_experts=e, scoring="sigmoid", select_bias=bias, gate_eps=1e-20, gate_scale=2.5)
+    assert moe.rows_ahead(t * k, 8, e) == 96
+    with jax.default_matmul_precision("highest"):
+        y, tokens = moe.dropless_moe(x, router, w_gate[8:16], w_up[8:16], w_down[8:16],
+                                     experts_held=(8, 8), **kw)
+        held_only = w_down.at[:8].set(0.0).at[16:].set(0.0)
+        want, all_tokens = moe.dropless_moe(x, router, w_gate, w_up, held_only, **kw)
+    rows = int(np.asarray(tokens).sum())
+    assert {"within_the_pass": 0 < rows <= 96, "more_held_than_the_pass_takes": rows == t * k,
+            "nothing_held": rows == 0}[case], rows
+    np.testing.assert_array_equal(np.asarray(tokens), np.asarray(all_tokens)[8:16])
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
+    assert (rows == 0) == (np.abs(np.asarray(y)).max() == 0)

@@ -564,9 +564,10 @@ class TestSfxEarlyDrain:
         early = 0
         for i, name in enumerate(order):
             if name == "device_wait":
-                # a drain begins where a turn has ended or a launch has,
-                # and its three phases follow one another
-                assert order[i - 1] in ("copy", "launch"), order[max(0, i - 3): i + 4]
+                # a drain begins where a turn has ended or a launch has (a turn that filled
+                # nothing ends with its frame's `put_ahead`: on a loaded machine the step
+                # ends only in the silence after one), and its three phases follow one another
+                assert order[i - 1] in ("copy", "launch", "put_ahead"), order[max(0, i - 3): i + 4]
                 assert order[i + 1: i + 3] == ["fold", "append"]
                 assert spans[i]["id"] == spans[i + 1]["id"] == spans[i + 2]["id"]
                 if i + 3 < len(order):  # then the turn's own frame, if it filled nothing

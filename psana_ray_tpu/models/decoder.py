@@ -5,14 +5,19 @@ MLP or a block assembled from a configuration (``vit.py`` hard-codes
 LayerNorm, GELU and a position table). This module builds the text
 decoder of a language model from the keys of its published
 ``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B, DeepSeek-V3's
-block as Kimi-K2 spells it, DeepSeek-V3.2's, Ling-3.0's hybrid):
+block as Kimi-K2 spells it, DeepSeek-V3.2's, Ling-3.0's hybrid,
+Laguna-S-2.1's windowed and full layers):
 
     x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
     x  -> x + ff(rms(x))                      four kinds, its ff one of two
 
 A layer's OPERATOR (``layer_types``) is grouped-query attention (a
-per-head RMS norm on q and k, then the rotary, multimodal or on the
-sequence index); or a gated short convolution (:func:`gated_short_conv`);
+per-head RMS norm on q and k where the model has one, then the rotary,
+multimodal or on the sequence index, over all of a head or its leading
+part; full causal, or SLIDING: over the band of the ``sliding_window``
+latest keys, with the layer's own count of query heads and its layer
+type's rotary, and where the configuration says so a sigmoid gate a head
+on the output); or a gated short convolution (:func:`gated_short_conv`);
 or LINEAR attention (:func:`linear_attention`: the gated delta rule with a
 decay per channel, a float32 state a head carried along the sequence:
 ``ops/delta_rule.py``);
@@ -107,6 +112,7 @@ AHEAD_STATS = (
 )
 # layer_types, as config.json spells them
 ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
+SLIDING = "sliding_attention"  # grouped-query attention over the band t - sliding_window < j <= t
 LATENT = "latent_attention"  # what an ATTENTION layer is under a kv_lora_rank
 
 
@@ -163,8 +169,19 @@ class DecoderConfig:
     rope_theta: float
     # multimodal rotary over (t, h, w) positions; None: plain rotary on the sequence index
     mrope_section: Optional[Tuple[int, int, int]] = None
-    # each layer's operator, ATTENTION, CONV or LINEAR (empty: attention in every layer)
+    # each layer's operator, ATTENTION, SLIDING, CONV or LINEAR (empty: attention in every layer)
     layer_types: Tuple[str, ...] = ()
+    # a SLIDING layer's band: a query attends to its own key and the sliding_window - 1 before it
+    sliding_window: int = 0
+    # each layer's query heads where layers differ (num_attention_heads_per_layer: wq, wo and the
+    # output gate are that many heads wide; empty: num_heads in all)
+    heads_per_layer: Tuple[int, ...] = ()
+    # the rotary is the LAYER TYPE's (rope_parameters): rope_theta and rope_yarn are the FULL
+    # layers', and turn the leading rope_partial_dim of a grouped-query head (0: all of it, the
+    # rest passes as it is); a SLIDING layer's is plain, over the whole head, at sliding_rope_theta
+    rope_partial_dim: int = 0
+    sliding_rope_theta: float = 0.0
+    qk_norm: bool = True  # an RMS norm with a gain on each grouped-query head's query and key
     conv_taps: int = 3  # of a short convolution (conv_L_cache, short_conv_kernel_size)
     # linear attention (the gated delta rule with a decay per channel): num_heads heads whose keys
     # and values are linear_head_dim wide, the log-decay a token in (linear_decay_floor, 0)
@@ -181,7 +198,7 @@ class DecoderConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # "head_wise": latent attention's output times sigmoid(a W_G), one scalar a head and token
+    # "head_wise": either attention's output times sigmoid(a W_G), one scalar a head and token
     attn_gate: str = ""
     # learned sparse attention (None: plain causal attention). The index queries are projected
     # from the layer's normed input, or from the normed query rank where attention is latent
@@ -236,7 +253,7 @@ class DecoderConfig:
     @property
     def rope_dim(self) -> int:
         """The width the rotary turns: the whole head, or a latent head's rotary part."""
-        return self.qk_rope_head_dim if self.kv_lora_rank else self.head_dim
+        return self.qk_rope_head_dim if self.kv_lora_rank else self.rope_partial_dim or self.head_dim
 
     @property
     def holds_a_share(self) -> bool:
@@ -256,6 +273,17 @@ class DecoderConfig:
         return LINEAR in self.layer_types
 
     @property
+    def has_window(self) -> bool:
+        """Windowed layers: the step then counts :data:`SHARE_STATS` and
+        :data:`PAIR_STATS` too (the band's pairs of what a causal layer has)."""
+        return SLIDING in self.layer_types
+
+    @property
+    def counts_pairs(self) -> bool:
+        """The step counts :data:`PAIR_STATS`: a selection's kept pairs, or a band's."""
+        return self.selects_over_latent or self.has_window
+
+    @property
     def rows_go_ahead(self) -> bool:
         """A share of the experts so large that ``dropless_moe`` takes a pass
         ahead of its held rows' loop: the step then counts every group, and
@@ -270,7 +298,13 @@ class DecoderConfig:
             return 4 + len(SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS)
         if self.has_linear:
             return 10
-        return 8 if self.selects_over_latent else 6 if self.holds_a_share else 4
+        if self.counts_pairs:
+            return 8
+        return 6 if self.holds_a_share else 4
+
+    def heads(self, i: int) -> int:
+        """Layer ``i``'s query heads."""
+        return self.heads_per_layer[i] if self.heads_per_layer else self.num_heads
 
     def layer_kind(self, i: int) -> Tuple[str, bool]:
         """``(operator, has experts)`` of layer ``i``."""
@@ -283,7 +317,7 @@ class DecoderConfig:
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Three spellings are read:
+        ``experts_held`` and ``tie_embedding``. Six spellings are read:
         Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
         ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
         (``norm_eps``, ``layer_types``, ``num_dense_layers``,
@@ -306,7 +340,17 @@ class DecoderConfig:
         ``gated_attention_proj_granularity_type``: the output gate;
         ``layer_types`` entries ``linear_attention`` with ``head_dim``,
         ``short_conv_kernel_size`` and ``kda_lower_bound``: the delta
-        rule's layers). Where a
+        rule's layers); and Laguna's (``laguna``: ``layer_types`` entries
+        ``sliding_attention`` under a ``sliding_window``;
+        ``num_attention_heads_per_layer``; ``rope_parameters``, a rotary by
+        layer type, the full layers' YaRN over ``partial_rotary_factor`` of
+        a head with ``attention_factor`` on its cosines and sines, the
+        sliding layers' plain over the whole head; ``gating: per-head``:
+        the output gate, on grouped-query attention; no per-head q / k
+        norm; ``mlp_only_layers``, ``shared_expert_intermediate_size`` and
+        ``moe_routed_scaling_factor``; the router's score, which its
+        config.json does not name, under the file's own ``router_scoring``).
+        Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -325,34 +369,74 @@ class DecoderConfig:
         n_layers, heads = int(m["num_hidden_layers"]), int(m["num_attention_heads"])
         layer_types = tuple(m.get("layer_types", ()))
         if layer_types and (len(layer_types) != n_layers
-                            or set(layer_types) - {ATTENTION, CONV, LINEAR}):
+                            or set(layer_types) - {ATTENTION, SLIDING, CONV, LINEAR}):
             raise ValueError(f"layer_types {layer_types} does not name {n_layers} layers' "
-                             f"operators, each {ATTENTION!r}, {CONV!r} or {LINEAR!r}")
-        rope = m.get("rope_scaling") or {}
+                             f"operators, each {ATTENTION!r}, {SLIDING!r}, {CONV!r} or {LINEAR!r}")
+        latent = int(m.get("kv_lora_rank") or 0)
+        head_dim = int(m.get("head_dim") or int(m["hidden_size"]) // heads)
+        by_type = m.get("rope_parameters")  # Laguna's: a rotary a layer type
+        window = {}
+        if by_type:
+            rope = dict(by_type[ATTENTION])
+            rope["type"] = rope.get("rope_type")
+            theta = float(rope["rope_theta"])
+            turned = round(head_dim * float(rope.get("partial_rotary_factor", 1)))
+            sliding = by_type.get(SLIDING) or {}
+            if (sliding.get("rope_type", "default") != "default"
+                    or float(sliding.get("partial_rotary_factor", 1)) != 1):
+                raise ValueError(f"a sliding layer's rotary {sliding} is not the plain one over "
+                                 f"the whole head: not built")
+            window = dict(sliding_window=int(m.get("sliding_window") or 0),
+                          rope_partial_dim=0 if turned == head_dim else turned,
+                          sliding_rope_theta=float(sliding.get("rope_theta", theta)),
+                          heads_per_layer=tuple(
+                              int(h) for h in m.get("num_attention_heads_per_layer", ())),
+                          qk_norm=False)
+            if len(window["heads_per_layer"]) not in (0, n_layers):
+                raise ValueError(f"num_attention_heads_per_layer names {len(window['heads_per_layer'])} "
+                                 f"layers' heads, not {n_layers}")
+            if m.get("moe_router_logit_softcapping"):
+                raise ValueError("a soft cap on the router's logits is not built")
+        else:
+            rope, theta = m.get("rope_scaling") or {}, float(m["rope_theta"])
+        if SLIDING in layer_types and not window.get("sliding_window"):
+            raise ValueError(f"{SLIDING!r} layers and no sliding_window under rope_parameters")
         mrope = rope.get("mrope_section")
         yarn = None
         if rope.get("type") == "yarn":
             yarn = Yarn(float(rope["factor"]), int(rope["original_max_position_embeddings"]),
                         float(rope.get("beta_fast", 32)), float(rope.get("beta_slow", 1)),
                         float(rope.get("mscale", 1)), float(rope.get("mscale_all_dim", 0)))
-        latent = int(m.get("kv_lora_rank") or 0)
-        gate = str(m.get("gated_attention_proj_granularity_type") or "") if latent else ""
+            stated = rope.get("attention_factor")  # what the cosines and sines are multiplied by
+            if stated is not None and abs(float(stated) - yarn.rotary_scale) > 1e-6:
+                raise ValueError(f"attention_factor {stated} is not YaRN's own "
+                                 f"{yarn.rotary_scale}: not built")
+        if latent:
+            gate = str(m.get("gated_attention_proj_granularity_type") or "")
+        else:  # Laguna's spelling, on grouped-query attention
+            gate = str(m.get("gating") or "")
+            gate = "head_wise" if gate == "per-head" else gate
         if gate not in ("", "head_wise"):
             raise ValueError(f"an output gate of granularity {gate!r} is not built")
+        dense_only = sorted(int(i) for i in m.get("mlp_only_layers", ()))
+        if dense_only != list(range(len(dense_only))):
+            raise ValueError(f"mlp_only_layers {dense_only} are not the leading layers: not built")
         linear = LINEAR in layer_types
         nope, rope_dim = int(m.get("qk_nope_head_dim", 0)), int(m.get("qk_rope_head_dim", 0))
-        deepseek = "scoring_func" in m  # DeepSeek-V3's spelling of the router
-        sigmoid = "use_expert_bias" in m or m.get("scoring_func") == "sigmoid"
+        # DeepSeek-V3's router, by its spelling or (Laguna's file) by the reading stated there
+        deepseek = "scoring_func" in m or "router_scoring" in m
+        sigmoid = ("use_expert_bias" in m or m.get("scoring_func") == "sigmoid"
+                   or m.get("router_scoring") == "sigmoid")
+        width = int(m.get("moe_intermediate_size", 0))
         return cls(
             hidden_size=int(m["hidden_size"]), num_layers=n_layers,
             num_heads=heads, num_kv_heads=int(m["num_key_value_heads"]),
-            head_dim=(nope + rope_dim if latent
-                      else int(m.get("head_dim") or int(m["hidden_size"]) // heads)),
+            head_dim=nope + rope_dim if latent else head_dim,
             vocab_size=int(m["vocab_size"]),
             rms_eps=float(m["rms_norm_eps"] if "rms_norm_eps" in m else m["norm_eps"]),
-            rope_theta=float(m["rope_theta"]),
+            rope_theta=theta,
             mrope_section=tuple(int(v) for v in mrope) if mrope else None,
-            layer_types=layer_types,
+            layer_types=layer_types, **window,
             conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size", 3))),
             linear_head_dim=int(m["head_dim"]) if linear else 0,
             linear_decay_floor=float(m["kda_lower_bound"]) if linear else 0.0,
@@ -363,19 +447,22 @@ class DecoderConfig:
             v_head_dim=int(m.get("v_head_dim", 0)) if latent else 0, attn_gate=gate,
             **index,
             num_experts=n_exp, experts_per_token=int(m.get("num_experts_per_tok", 0)),
-            expert_width=int(m.get("moe_intermediate_size", 0)),
+            expert_width=width,
             experts_held=tuple(int(v) for v in m.get("experts_held", (0, n_exp))),
             norm_topk_prob=bool(m.get("norm_topk_prob", True)),
-            num_dense_layers=int(m.get("num_dense_layers", m.get("first_k_dense_replace", 0))),
+            num_dense_layers=int(m.get("num_dense_layers",
+                                       m.get("first_k_dense_replace", len(dense_only)))),
             router_scoring="sigmoid" if sigmoid else "softmax",
             expert_bias=bool(m.get("use_expert_bias", m.get("topk_method") == "noaux_tc")),
             # the renormalising sum's epsilon: LFM2's code has 1e-6, DeepSeek-V3's 1e-20
             gate_eps=(1e-20 if deepseek else 1e-6) if sigmoid else 0.0,
-            routed_scaling_factor=float(m.get("routed_scaling_factor", 1.0)),
+            routed_scaling_factor=float(m.get("routed_scaling_factor",
+                                              m.get("moe_routed_scaling_factor", 1.0))),
             router_groups=int(m.get("n_group") or 1),
             router_groups_kept=int(m.get("topk_group") or 1),
-            shared_experts=int(m.get("n_shared_experts")
-                               or m.get("num_shared_experts") or 0) if n_exp else 0,
+            shared_experts=int(m.get("n_shared_experts") or m.get("num_shared_experts")
+                               or int(m.get("shared_expert_intermediate_size", 0)) // max(width, 1)
+                               ) if n_exp else 0,
             intermediate_size=int(m.get("intermediate_size", 0)),
             patch=int(m.get("patch", 8)),
         )
@@ -434,12 +521,16 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 p["w_attn_gate"] = w(d, heads)
             if cfg.indexer_heads:  # the index queries read the query's normed low rank
                 p.update(_index_params(cfg, w, gain, rq))
-        else:
+        else:  # grouped-query attention, full or windowed, this layer's own count of query heads
+            heads = cfg.heads(i)
             p = {
-                "norm1": gain(d), "wq": w(d, cfg.num_heads * hd), "wk": w(d, cfg.num_kv_heads * hd),
-                "wv": w(d, cfg.num_kv_heads * hd), "q_norm": gain(hd), "k_norm": gain(hd),
-                "wo": w(cfg.num_heads * hd, d), "norm2": gain(d),
+                "norm1": gain(d), "wq": w(d, heads * hd), "wk": w(d, cfg.num_kv_heads * hd),
+                "wv": w(d, cfg.num_kv_heads * hd), "wo": w(heads * hd, d), "norm2": gain(d),
             }
+            if cfg.qk_norm:
+                p.update(q_norm=gain(hd), k_norm=gain(hd))
+            if cfg.attn_gate:
+                p["w_attn_gate"] = w(d, heads)
             if cfg.indexer_heads:
                 p.update(_index_params(cfg, w, gain, d))
         if experts:
@@ -523,16 +614,34 @@ def _mm(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
-def _projections(p, x, angles, cfg: DecoderConfig):
+def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
+    """Grouped-query attention's ``(a, q, k, v)`` from ``x [T, D]``, each
+    ``[T, heads * head_dim]``, the query heads as many as THIS layer's
+    ``wq`` has, and where the output is gated ``sigmoid(a W_G) [T, heads]``
+    float32 after them. ``angles [T, pairs]`` turn the leading ``2 * pairs``
+    components of a head (the layer type's rotary: all of a head, or the
+    full layers' partial one) and the rest passes as it is; under YaRN (the
+    full layers' alone: a ``windowed`` layer's rotary is plain) the turned
+    part is multiplied by ``rotary_scale``, the cosines' and sines' factor."""
     s = x.shape[0]
     dt = x.dtype
     a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
-    q = rms_norm(_mm(a, p["wq"]).reshape(s, cfg.num_heads, cfg.head_dim), p["q_norm"], cfg.rms_eps)
-    k = rms_norm(_mm(a, p["wk"]).reshape(s, cfg.num_kv_heads, cfg.head_dim), p["k_norm"], cfg.rms_eps)
-    q = rotate(q, angles) * cfg.head_dim ** -0.5  # the softmax scale rides on q
-    k = rotate(k, angles)
+    q = _mm(a, p["wq"]).reshape(s, -1, cfg.head_dim)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
+    k = _mm(a, p["wk"]).reshape(s, cfg.num_kv_heads, cfg.head_dim)
+    if cfg.qk_norm:
+        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
+    yarn = None if windowed else cfg.rope_yarn
+    turn = dict(angles=angles, width=2 * angles.shape[-1], scale=yarn.rotary_scale if yarn else 1.0)
+    # the softmax scale (with YaRN's mscale^2) rides on q
+    q = _turn_leading(q, **turn) * (cfg.head_dim ** -0.5 * (yarn.softmax_scale if yarn else 1.0))
+    k = _turn_leading(k, **turn)
     v = _mm(a, p["wv"])
-    return a, q.reshape(s, -1).astype(dt), k.reshape(s, -1).astype(dt), v.astype(dt)
+    out = (a, q.reshape(s, -1).astype(dt), k.reshape(s, -1).astype(dt), v.astype(dt))
+    if cfg.attn_gate:
+        out += (jax.nn.sigmoid(_mm(a, p["w_attn_gate"])),)
+    return out
 
 
 def layer_norm(u, g, b, eps: float):
@@ -543,12 +652,15 @@ def layer_norm(u, g, b, eps: float):
             * g.astype(jnp.float32) + b.astype(jnp.float32))
 
 
-def _turn_leading(x, angles, width: int):
+def _turn_leading(x, angles, width: int, scale: float = 1.0):
     """:func:`rotate` on the first ``width`` components of ``x``'s last
-    axis (0, or the whole width: on all of them)."""
-    if not width or width == x.shape[-1]:
-        return rotate(x, angles)
-    return jnp.concatenate([rotate(x[..., :width], angles), x[..., width:]], axis=-1)
+    axis (0, or the whole width: on all of them), the turned part times
+    ``scale``; the rest passes as it is."""
+    whole = not width or width == x.shape[-1]
+    turned = rotate(x if whole else x[..., :width], angles)
+    if scale != 1.0:
+        turned = turned * scale
+    return turned if whole else jnp.concatenate([turned, x[..., width:]], axis=-1)
 
 
 def _indexer(p, a, idx_angles, cfg: DecoderConfig, q_from=None):
@@ -648,6 +760,13 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
     return out
 
 
+def gated(x, o, gate, wo):
+    """``x + (o [T, H*dv], each head times its own scalar of gate [T, H]) W_o``:
+    the head-wise output gate, fused into ``W_o``'s operand."""
+    o = o.reshape(x.shape[0], gate.shape[1], -1) * gate[:, :, None]
+    return x + _mm(o.astype(x.dtype).reshape(x.shape[0], -1), wo).astype(x.dtype)
+
+
 def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=None):
     """DeepSeek-V3's operator on ``x [B*S, D]`` -> ``(x + Op, live,
     causal)``, the last two the layer's statistics tiles, prefill in
@@ -698,10 +817,6 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
                    k_shared=rows(k_rope))
     with jax.named_scope("proj"):
         if cfg.attn_gate:  # each head's output under its own scalar, then W_o
-            def gated(x, o, gate, wo):
-                o = o.reshape(x.shape[0], cfg.num_heads, -1) * gate[:, :, None]
-                return x + _mm(o.astype(x.dtype).reshape(x.shape[0], -1), wo).astype(x.dtype)
-
             x = jax.jit(gated)(x, o, read[-1], p["wo"])
         else:
             x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(
@@ -760,15 +875,21 @@ def linear_attention(p, x, batch: int, cfg: DecoderConfig):
         return jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(x, o, p["wo"])
 
 
-def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
+def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window: int = 0):
     """The attention operator on ``x [B*S, D]`` -> ``(x + Op, live,
     causal)``, the last two the layer's statistics tiles. Each part is a
     call of its own under its scope (``proj``, ``indexer``,
     ``sparse_attn``): a scope reaches the chip's profile only on ops
-    inlined from a call."""
+    inlined from a call. Under a ``window`` (a SLIDING layer: keys ``t -
+    window < j <= t``) the same kernel visits the tiles that meet the band
+    alone, under a name and a scope of its own (``windowed_gqa_attention``,
+    ``window_attn``), and ``live`` counts the statistics tiles the band
+    meets. Where the output is gated (``attn_gate``) each head's output
+    meets its own sigmoid scalar in ``W_o``'s operand."""
     s = x.shape[0] // batch
     with jax.named_scope("proj"):
-        a, q, k, v = jax.jit(_projections, static_argnums=3)(p, x, angles, cfg)
+        a, q, k, v, *gate = jax.jit(_projections, static_argnums=(3, 4))(
+            p, x, angles, cfg, *((True,) if window else ()))
     if cfg.indexer_heads:
         if batch != 1:
             raise ValueError(f"a learned key selection is per sequence: batch {batch} is not 1")
@@ -781,13 +902,20 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig):
                 block_q=max(cfg.attn_q_tile, mask.shape[2]))
     else:
         live = causal = batch * sa.causal_tile_count(s)  # every earlier key is attended
-        with jax.named_scope("sparse_attn"):
-            o = jax.jit(sa.masked_gqa_attention,
-                        static_argnames=("num_kv_heads", "block_q", "block_k"))(
+        attend, band = sa.masked_gqa_attention, {}
+        if window:  # the same kernel under its own name (a trace tells the two kinds of layer apart)
+            attend, band = sa.windowed_gqa_attention, {"window": window}
+            live = batch * sa.band_tile_count(s, window)
+        with jax.named_scope("window_attn" if window else "sparse_attn"):
+            o = jax.jit(attend, static_argnames=("num_kv_heads", "block_q", "block_k", *band))(
                 *(u.reshape(batch, s, -1) for u in (q, k, v)), num_kv_heads=cfg.num_kv_heads,
-                block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile).reshape(x.shape[0], -1)
+                block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile,
+                **band).reshape(x.shape[0], -1)
     with jax.named_scope("proj"):
-        x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(x, o, p["wo"])
+        if gate:
+            x = jax.jit(gated)(x, o, gate[0], p["wo"])
+        else:
+            x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(x, o, p["wo"])
     return x, live, causal
 
 
@@ -811,7 +939,8 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     elif op == LATENT:
         x, live, causal = latent_attention(p, x, angles, batch, cfg, idx_angles)
     else:
-        x, live, causal = _attention(p, x, angles, idx_angles, batch, cfg)
+        x, live, causal = _attention(p, x, angles, idx_angles, batch, cfg,
+                                     cfg.sliding_window if op == SLIDING else 0)
     share = experts and cfg.layer_stats > 4
     given = ()  # from the shared expert: the layer's normed rows b, and x + Shared(b)
     if experts and cfg.shared_experts:
@@ -844,15 +973,19 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     if cfg.layer_stats > 4:
         routed = x.shape[0] * cfg.experts_per_token if experts else 0
         stats += [held[0].astype(jnp.float32) if held else jnp.float32(0), jnp.float32(routed)]
-    if cfg.selects_over_latent:
+    if cfg.counts_pairs:
+        # a selection keeps min(t + 1, topk) of a query's keys, a band min(t + 1, window): the sum
+        # over a sequence's queries, in the layers that select or slide
         s = x.shape[0] // batch
-        kept = min(s, cfg.topk)  # sum over a sequence's queries of min(t + 1, topk)
-        stats += [jnp.float32(batch * (kept * (kept + 1) // 2 + (s - kept) * cfg.topk)),
-                  jnp.float32(batch * (s * (s + 1) // 2))]
+        width = cfg.topk if cfg.selects_over_latent else cfg.sliding_window
+        kept = min(s, width)
+        through = batch * (cfg.selects_over_latent or op == SLIDING)
+        stats += [jnp.float32(through * (kept * (kept + 1) // 2 + (s - kept) * width)),
+                  jnp.float32(through * (s * (s + 1) // 2))]
     if cfg.has_linear:
         s = x.shape[0] // batch
         through = op == LINEAR
-        if not cfg.selects_over_latent:  # PAIR_STATS' places: nothing selects
+        if not cfg.counts_pairs:  # PAIR_STATS' places: nothing selects
             stats += [jnp.float32(0), jnp.float32(0)]
         stats += [jnp.float32(through * x.shape[0]),
                   jnp.float32(through * batch * cfg.num_heads
@@ -875,6 +1008,7 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     where layers are linear, :data:`AHEAD_STATS` last where a pass goes
     ahead of the held rows' loop)."""
     s = x.shape[0] // batch
+    # (under rope_parameters: the FULL layers' table, over the part of a head their rotary turns)
     angles = rotary_angles(pos, cfg.rope_theta, cfg.rope_dim // 2, cfg.mrope_section,
                            cfg.rope_yarn)
     idx_angles = None
@@ -884,11 +1018,16 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
         idx_angles = rotary_angles(
             np.arange(s), cfg.rope_theta, (cfg.indexer_rope_dim or cfg.indexer_head_dim) // 2,
             yarn=cfg.rope_yarn)
+    # the rotary is the layer type's: a second table where SLIDING layers have their own
+    by_op = {SLIDING: rotary_angles(pos, cfg.sliding_rope_theta or cfg.rope_theta,
+                                    cfg.head_dim // 2)} if cfg.has_window else {}
     if batch != 1:
         angles = jnp.tile(angles, (batch, 1))
+        by_op = {op: jnp.tile(table, (batch, 1)) for op, table in by_op.items()}
     stats = jnp.zeros((cfg.layer_stats,), jnp.float32)
     for i, p in enumerate(params["layers"]):
-        x, layer_stats = decoder_layer(p, x, angles, idx_angles, cfg, cfg.layer_kind(i), batch)
+        kind = cfg.layer_kind(i)
+        x, layer_stats = decoder_layer(p, x, by_op.get(kind[0], angles), idx_angles, cfg, kind, batch)
         stats = stats + layer_stats
     served = jnp.asarray([batch * s, batch], jnp.float32)
     if cfg.layer_stats > 4:
@@ -958,7 +1097,8 @@ def frame_step(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, thresho
 def fold_step_stats(metrics, stats) -> None:
     """Add one step's statistics vector (on the host or the device: six
     values, eight from a holder of a share of the experts, ten under a
-    selection over latent attention, twelve with linear layers, thirteen
+    selection over latent attention or with windowed layers (the band's
+    pairs in the selection's places), twelve with linear layers, thirteen
     where a pass goes ahead of the held rows' loop) to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""

@@ -323,6 +323,11 @@ def sum_counted_rows(out, back, limit, gates, *, interpret: Optional[bool] = Non
     listed = jnp.minimum(back, n - 1).reshape(steps, span) * span + jnp.arange(span, dtype=jnp.int32)
     listed = lax.sort(jnp.where(counted, listed, listed | _FLAG), dimension=1, is_stable=False).reshape(-1)
     per_step = jnp.sum(counted, axis=1, dtype=jnp.int32)
+    # the two buffers of a step's rows (a row's word sublanes in whole 8-sublane tiles) and the two
+    # of its output tile: at k 8 a step is 1,024 slots (16.8 + 2.6 MB at ling3's 2,560 columns), at
+    # k 10, the first k that is no power of two, 5,120 (83.9 + 12.6 MB at 3,072 columns: 98 MiB
+    # with Mosaic's own), and the scope grows with it (the v5e has 128 MiB)
+    need = 2 * 4 * _LANES * (span * (-(-(view // halves) // 8) * 8) + tokens * chunks)
     whole = pl.BlockSpec(memory_space=pltpu.SMEM)
     slots = pl.BlockSpec((span,), lambda i: (i,), memory_space=pltpu.SMEM)
     following = pl.BlockSpec((span,), lambda i: (jnp.minimum(i + 1, steps - 1),), memory_space=pltpu.SMEM)
@@ -334,8 +339,9 @@ def sum_counted_rows(out, back, limit, gates, *, interpret: Optional[bool] = Non
         out_shape=jax.ShapeDtypeStruct((steps * tokens * chunks, _LANES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((2, span, view // halves, _LANES), rows.dtype),
                         pltpu.SemaphoreType.DMA((2,))],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
-                                             vmem_limit_bytes=64 * 1024 * 1024),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=max(64, -(-need // 2 ** 20) + 8) * 1024 * 1024),
         interpret=interpret,
         name="sum_counted_rows",
     )(limit, per_step, listed, listed, back, gates, rows)

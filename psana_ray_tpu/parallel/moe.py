@@ -313,7 +313,12 @@ def _grouped_product(rows, weights, tokens, out_dtype, interpret):
     of at most 2,048 that divides it (1,792 of 7,168: no masked remainder
     tile), and the row tile is sized against that tile, not the whole
     width (256 rows of 7,168, where the whole width allowed 64: a quarter
-    of the v5e's ridge, each expert's weights read six times)."""
+    of the v5e's ridge, each expert's weights read six times). Last, the row
+    tile halves while the kernel's tiles (two buffers of each operand's and of
+    the output's, and the float32 accumulator) pass 21 MiB: the loop's down
+    product at ``[2048, 1024] x [64, 1024, 3072]`` into float32 takes 23.4 MB
+    at 512 rows where Mosaic allows 22 (compiled for a described v5e, PR 53);
+    no shape served before it comes near (ling3's, the nearest, 19.5 MiB)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     m, k = rows.shape
@@ -325,6 +330,9 @@ def _grouped_product(rows, weights, tokens, out_dtype, interpret):
     fits = 4 * 1024 * 1024 // (2 * tk)  # output columns of a bf16 weight tile within 4 MiB
     if tn > fits:
         tn = next((t for t in range(tn - tn % 128, 0, -128) if n % t == 0 and t <= fits), tn)
+    out_bytes = jnp.dtype(out_dtype).itemsize
+    while tm > 8 and 2 * (2 * tm * tk + 2 * tk * tn + out_bytes * tm * tn) + 4 * tm * tn > 21 * 2 ** 20:
+        tm //= 2
     return gmm(rows, weights, tokens, preferred_element_type=out_dtype,
                tiling=(tm, tk, tn), interpret=interpret)
 

@@ -44,7 +44,13 @@ What is here, and what it is:
   mask it is plain causal attention over a batch of sequences; with one
   (one sequence) it is the selection over LATENT attention, masked-dense
   again: the mask's tile is one more operand of a grid step, and its key
-  tile is the kernel's.
+  tile is the kernel's. Under a WINDOW (``window``: a query attends to
+  its own key and the ``window - 1`` before it; Laguna's sliding layers)
+  the grid holds only the tiles that MEET the band, the running softmax
+  starts at a query tile's first visited key tile, and each edge of the
+  band is compared only in the tiles it crosses: the same body under a
+  name of its own (``windowed_gqa_attention``). The tiles follow from the
+  heads a group and the window (:func:`causal_tiles`).
 - :func:`live_tiles` — how many ``stat_tile`` x ``stat_tile`` tiles at
   or below the diagonal hold a selected pair (from the flags the selection
   kernel writes beside its mask), and how many there are: what a
@@ -66,6 +72,16 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 INT_MIN = -(2**31)
 _VMEM_LIMIT = 100 * 1024 * 1024  # of the v5e's 128 MiB; the default scope is 16
+# of the batched causal kernel's stacked score tile [heads a group x query tile, key tile] float32
+# (:func:`causal_tiles`): a fifth of the limit, for the tile, its exponentials and their bf16 copy
+SCORE_TILE_BYTES = _VMEM_LIMIT // 5
+# a windowed call's widest (query tile, key tile), as shares of its window: the key tile the
+# window's own width, the query tile half of it. One windowed layer on the v5e, 2 x 8,704 tokens,
+# 9 heads of 128 a group, a window of 512, the head-major transposes with it (my chip runs, PR
+# 53), ms: 256 x 512 12.2, 256 x 256 14.3, 128 x 256 15.4, 512 x 256 16.8, 128 x 128 19.2, 256 x
+# 128 20.4, 512 x 512 21.1. A narrower key tile meets fewer pairs outside the band (768 keys a row
+# at 256 x 256 where 256 x 512 and 512 x 512 meet 1,024) and loses more to its grid steps
+BAND_TILES = (0.5, 1.0)
 MASK_TILE = 2176  # the widest key tile a selection's mask is written in (:func:`mask_tile`): on the
 # v5e the kernel under it read 34.8 ms a layer at 512 x 2,176 and 39.4 at 256 x 4,352 (PR 47)
 
@@ -261,6 +277,14 @@ def causal_tile_count(s: int, stat_tile: int = 512) -> int:
     return n * (n + 1) // 2
 
 
+def band_tile_count(s: int, window: int, stat_tile: int = 512) -> int:
+    """How many of :func:`causal_tile_count`'s tiles MEET the band ``t -
+    window < j <= t`` of one sequence of ``s`` tokens (33 of 153 at 8,704
+    tokens under a window of 512): what a windowed layer counts as ``live``."""
+    tile = pick_tile(s, stat_tile)
+    return len(_band_tiles(s, tile, tile, window))
+
+
 def live_tiles(live: jax.Array, s: int, stat_tile: int = 512) -> Tuple[jax.Array, int]:
     """``(live, causal)``: of the ``stat_tile``-square tiles at or below
     the diagonal of a sequence of ``s``, how many hold a selected pair
@@ -323,7 +347,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
             o_ref[:, h * d:(h + 1) * d] = out[h * block_q:(h + 1) * block_q].astype(o_ref.dtype)
 
 
-def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared, masked):
+def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared, masked,
+                   window=None):
     rest = list(rest)
     if shared:  # the part of the score that all heads read from ONE key
         qs_ref, ks_ref = rest.pop(0), rest.pop(0)
@@ -334,14 +359,17 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
     qi, kb = qi_ref[t], kb_ref[t]
     rep, _, d = q_ref.shape
     rows = rep * block_q  # the group's query heads, stacked: one product serves them all
+    # the query tile's first visited key tile: tile 0, or under a window the one that holds the
+    # key `window - 1` before the tile's first row (`_band_tiles`' first)
+    first = 0 if window is None else jnp.maximum(qi * block_q - (window - 1), 0) // block_k
 
-    @pl.when(kb == 0)
+    @pl.when(kb == first)
     def _reset():
         m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    def update(with_diagonal):
+    def update(with_diagonal, with_lower_edge=False):
         v = v_ref[...]
         s = jax.lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -356,10 +384,18 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
             sel = mask_ref[...].astype(jnp.float32).reshape(block_q, block_k)
             s = jnp.where((sel > 0.0)[None], s.reshape(rep, block_q, block_k),
                           NEG_INF).reshape(rows, block_k)
-        elif with_diagonal:  # key 0 of the sequence is open to every row: m is finite from tile 0
+        elif with_diagonal or with_lower_edge:
+            # without a window key 0 of the sequence is open to every row: m is finite from tile
+            # 0. Under one a row may see NO key of its first visited tile (the band's lower edge
+            # lies past the tile's last key for it): the masked form's case above, m_new ==
+            # NEG_INF and p == 1 there, wiped by alpha == 0 at its first real key, which it
+            # meets at the latest in its diagonal tile, the row's last
             row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            s = jnp.where((col <= row)[None], s.reshape(rep, block_q, block_k),
+            edges = ([col <= row] if with_diagonal else []) + (
+                [col > row - window] if with_lower_edge else [])
+            open_ = functools.reduce(jnp.logical_and, edges)
+            s = jnp.where(open_[None], s.reshape(rep, block_q, block_k),
                           NEG_INF).reshape(rows, block_k)
         m = m_ref[:]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
@@ -374,16 +410,37 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
         update(False)
     else:
         below = (kb + 1) * block_k - 1 <= qi * block_q  # every pair of the tile is causal
-        pl.when(below)(lambda: update(False))
-        pl.when(jnp.logical_not(below))(lambda: update(True))
+        if window is None:
+            pl.when(below)(lambda: update(False))
+            pl.when(jnp.logical_not(below))(lambda: update(True))
+        else:  # each edge of the band is compared only in the tiles it crosses
+            # the tile's first key is inside the band of its last row, and so of every row
+            inside = kb * block_k > (qi + 1) * block_q - 1 - window
+            for diagonal in (False, True):
+                for lower_edge in (False, True):
+                    pl.when((jnp.logical_not(below) if diagonal else below)
+                            & (jnp.logical_not(inside) if lower_edge else inside))(
+                        functools.partial(update, diagonal, lower_edge))
 
     @pl.when(kb == ((qi + 1) * block_q - 1) // block_k)  # the row's last tile
     def _finalize():
         o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(o_ref.shape).astype(o_ref.dtype)
 
 
+def _band_tiles(s: int, bq: int, bk: int, window: Optional[int] = None) -> list:
+    """The ``(query tile, key tile)`` pairs a sequence of ``s`` is run in,
+    a query tile's key tiles in order: those that hold a pair at or below
+    the diagonal, and under a ``window`` of those the ones that MEET the
+    band ``t - window < j <= t`` of some row ``t`` of the query tile (at
+    8,704 tokens in 512 x 512 tiles 153, and 33 under a window of 512)."""
+    def first(i):  # the tile of the key `window - 1` before the query tile's first row
+        return 0 if window is None else max(i * bq - (window - 1), 0) // bk
+
+    return [(i, j) for i in range(s // bq) for j in range(first(i), ((i + 1) * bq - 1) // bk + 1)]
+
+
 def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool,
-                      q_shared=None, k_shared=None, mask=None):
+                      q_shared=None, k_shared=None, mask=None, window: Optional[int] = None):
     """The batched form of :func:`masked_gqa_attention`. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
@@ -446,7 +503,9 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
             raise ValueError(f"a mask {mask.shape} selects the keys of one sequence of "
                              f"{n_qb * mq}: not of {b} of {s}")
         bq = next(t for t in range(max(min(block_q, s) // mq, 1) * mq, 0, -mq) if s % t == 0)
-    pairs = [(i, j) for i in range(s // bq) for j in range(((i + 1) * bq - 1) // bk + 1)]
+    if window is not None and (masked or window < 1):
+        raise ValueError("a window is a band of at least the query's own key, and the maskless form's")
+    pairs = _band_tiles(s, bq, bk, window)
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
 
     def in_place(width):  # a head of whole lane blocks, alone in its group
@@ -489,7 +548,8 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         in_specs.append(pl.BlockSpec((bq // mq, None, mq, bk),
                                      lambda bi, gi, t, qi, kb: (qi[t], kb[t], 0, 0)))
     o5 = pl.pallas_call(
-        functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked),
+        functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked,
+                          window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g, len(pairs)),
             in_specs=in_specs, out_specs=q_spec(dv),
@@ -504,16 +564,47 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-        name="masked_gqa_attention",
+        # the same body under a name of its own: a trace tells the windowed layers' calls apart
+        name="masked_gqa_attention" if window is None else "windowed_gqa_attention",
     )(qi, kb, *operands)
     if in_place(dv):
         return o5.reshape(b, s, g * dv)
     return jnp.transpose(o5, (0, 3, 1, 2, 4)).reshape(b, s, g * rep * dv)
 
 
+def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
+                 window: Optional[int] = None) -> Tuple[int, int]:
+    """The ``(query tile, key tile)`` the maskless batched kernel runs a
+    sequence of ``s`` in, from the tiles asked for (``pick_tile``'s, as
+    ever), the ``rep`` query heads a group whose query tiles it stacks, and
+    the window: a rule, where there were two constants measured at two
+    shapes. (1) Under a window neither tile is wider than its share of the
+    window (:data:`BAND_TILES`): a row is run against the ``window + bq +
+    bk`` keys or so that its tiles meet, of which only ``window`` are the
+    band's. (2) The stacked score tile ``[rep * bq, bk]`` float32 stays
+    within :data:`SCORE_TILE_BYTES`: the query tile is the largest that
+    divides ``s`` and does. It leaves the tiles of every step measured
+    before it as they were (4 heads of 64 a group at 1,088 x 1,088: 18.9 MB;
+    a head of 128 + 64 alone: 4.7) and gives 6 heads of 128 a group 512 x
+    1,088 (13.4 MB) and 9 under a window of 512 256 x 512 (4.7). One full
+    layer on the v5e, 2 x 8,704 tokens, 6 heads of 128 a group, the
+    head-major transposes with it (my chip runs, PR 53), ms: 512 x 1,088
+    21.2, 256 x 1,088 21.0, 512 x 512 24.6, 1,088 x 512 24.7, 1,088 x 1,088
+    (a score tile of 28.4 MB) 31.7; the windowed layer's are beside
+    :data:`BAND_TILES`."""
+    bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
+    if window is not None:
+        bq, bk = (min(tile, pick_tile(s, max(int(window * share), 1)))
+                  for tile, share in zip((bq, bk), BAND_TILES))
+    fits = SCORE_TILE_BYTES // (4 * rep * bk)
+    if bq > fits:
+        bq = min(bq, pick_tile(s, max(fits, 1)))
+    return bq, bk
+
+
 def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Optional[int] = None,
                          block_k: int = 512, interpret: Optional[bool] = None,
-                         q_shared=None, k_shared=None) -> jax.Array:
+                         q_shared=None, k_shared=None, window: Optional[int] = None) -> jax.Array:
     """``q [S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [S, G*d]``,
     ``mask`` from :func:`select_keys` -> ``o [S, H*d]``: softmax attention
     of every query head over the keys its query selected, query head ``h``
@@ -535,10 +626,20 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     part, ``q_shared [B, S, H*ds] . k_shared [B, S, ds]``, whose key is ONE
     for all heads (latent attention: the rotary key). With a mask (``B``
     1: a selection is one sequence's) it is the selection over latent
-    attention, masked-dense as the form above, in the mask's key tile."""
+    attention, masked-dense as the form above, in the mask's key tile.
+    With ``window`` (maskless only) a query attends to the keys ``t -
+    window < j <= t`` of its sequence and the kernel visits the tiles that
+    meet that band alone, under the name ``windowed_gqa_attention``. The
+    maskless form's tiles are :func:`causal_tiles`' of the two asked for."""
     if mask is None or q.ndim == 3:
-        return _causal_attention(q, k, v, int(num_kv_heads), block_q or 256, block_k,
-                                 _interpret(interpret), q_shared, k_shared, mask)
+        block_q = block_q or 256
+        if mask is None:
+            g = int(num_kv_heads)
+            d = k.shape[2] // (2 * g if v is None else g)
+            block_q, block_k = causal_tiles(q.shape[1], q.shape[2] // (g * d), block_q, block_k,
+                                            window)
+        return _causal_attention(q, k, v, int(num_kv_heads), block_q, block_k,
+                                 _interpret(interpret), q_shared, k_shared, mask, window)
     if q_shared is not None or v.shape[1] != k.shape[1]:
         raise ValueError("a shared key part and a value width of its own are the batched form's")
     from jax.experimental.pallas import tpu as pltpu
@@ -579,3 +680,19 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
         interpret=_interpret(interpret),
         name="masked_gqa_attention",
     )(q, k, v, mask)
+
+
+def windowed_gqa_attention(q, k, v, *, window: int, num_kv_heads: int, block_q: Optional[int] = None,
+                           block_k: int = 512, interpret: Optional[bool] = None) -> jax.Array:
+    """:func:`masked_gqa_attention`'s batched maskless form under a
+    ``window``: ``q [B, S, H*d]``, ``k, v [B, S, G*d]`` -> ``[B, S, H*d]``,
+    a query attending to the keys ``t - window < j <= t`` of its own
+    sequence. A function of its own because a device trace names a kernel
+    after the jitted function it was traced in wherever locations hold one
+    frame (``utils/jaxenv.configure_compile_cache``: every entry point):
+    jitted under this name the windowed layers' calls are
+    ``%windowed_gqa_attention`` there, and a full layer's stay
+    ``%masked_gqa_attention`` (seen on the v5e, PR 53: under the one jit all
+    nine of a step carried the one name)."""
+    return masked_gqa_attention(q, k, v, num_kv_heads=num_kv_heads, block_q=block_q,
+                                block_k=block_k, interpret=interpret, window=window)

@@ -649,6 +649,66 @@ def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_c
     assert all("/kda/" in line for line in calls if re.match(r"\s*%gated_delta_rule", line))
 
 
+def test_the_laguna_step_compiles_with_its_kernels_where_the_roofline_functions_count_them(
+        one_chip, monkeypatch):
+    """The whole served step of ``laguna_s21_prefill_epix10k2m`` at the
+    published sizes, compiled for the described v5e: it fits the chip beside
+    nothing else (weights 11.4 GB), and its Mosaic kernels are the ones the
+    cell's roofline metrics read by name: three ``masked_gqa_attention`` (the
+    full layers, 6 query heads of 128 a group, under ``sparse_attn``), six
+    ``windowed_gqa_attention`` (the same body over the band's tiles, 9 a
+    group, under ``window_attn``), the grouped products under ``moe`` —
+    twenty-four in the pass ahead of the held rows' loop (``call_sites``,
+    named ``gmm``) and the loop's own twenty-four (named after the jit the
+    loop stands in) — and the pass's way back at TEN slots a token
+    and 24 lane chunks a row (``rows_as_words``, ``sum_counted_rows``, whose
+    step of 5,120 slots takes 60 MB of VMEM for its two buffers), the
+    calibration kernel, and no other: 3,072-wide rows are no whole
+    2,048-column tiles, so the rows go out by XLA's gather."""
+    import collections
+
+    from benchmark.roofline import laguna
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # as every entry point compiles (`jaxenv.configure_compile_cache`): locations of one frame, under
+    # which an instruction is named after the jitted function it was traced in. The windowed layers'
+    # calls are jitted under `sparse_attention.windowed_gqa_attention` for that: on the v5e all nine
+    # kernels of a step carried the full layers' name while one jit served both (PR 53)
+    jax.config.update("jax_include_full_tracebacks_in_locations", False)
+    cfg, dcfg, params = _decoder_cell("laguna_s21_prefill_epix10k2m")
+    calib = (S((PANELS, H, W), F32), S((PANELS, H, W), F32), S((PANELS, H, W), jnp.uint8))
+    frames = S((cfg["batch_size"], PANELS, H, W), jnp.uint16)
+    ids = S((cfg["prompt_tokens"],), jnp.int32)
+
+    def step(p, c, f, i):
+        return decoder.frame_step(p, c, f, i, cfg=dcfg, threshold=10.0)
+
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), (params, calib, frames, ids))
+    compiled = jax.jit(step).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes < 15e9
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
+    sites = laguna.held_products(cfg["step_tokens"], 10, 3072, 1024, 64, 9, [0], 0.25)["call_sites"]
+    expert_layers = dcfg.num_layers - dcfg.num_dense_layers
+    # by the jitted function each was traced in: the pass's products `gmm` (what
+    # `gmm_ahead_roofline_share.laguna` reads by name), the loop's after the jit the loop stands in,
+    # both kernels of the way back after `sum_counted_rows`
+    assert names == {"masked_gqa_attention": cfg["layer_types"].count("full_attention"),
+                     "windowed_gqa_attention": cfg["layer_types"].count("sliding_attention"),
+                     "gmm": sites, "mlp": sites, "sum_counted_rows": 2 * expert_layers,
+                     "fused_calibrate": 1}, names
+    assert sites == 3 * expert_layers == 24 and names["masked_gqa_attention"] == 3
+    assert all("/moe/" in line for line in calls if re.match(r"\s*%(gmm|mlp|sum_counted_rows)", line))
+    assert all("/sparse_attn/" in line for line in calls if re.match(r"\s*%masked_gqa", line))
+    assert all("/window_attn/" in line for line in calls if re.match(r"\s*%windowed_gqa", line))
+    text = compiled.as_text()
+    for scope in ("proj", "shared_expert", "mlp", "moe", "sparse_attn", "window_attn"):
+        assert f"jit(step)/{scope}/" in text, scope
+
+
 @pytest.mark.parametrize("name", ["kimi_k2_prefill_epix10k2m", "deepseek_v32_prefill_epix10k2m"])
 def test_latent_attention_s_operands_reach_the_kernel_where_their_products_wrote_them(
         name, one_chip, monkeypatch):

@@ -297,44 +297,99 @@ def _slots_per_expert(ids, first: int, count: int):
 SCORINGS = {"softmax": lambda logits: jax.nn.softmax(logits, axis=-1), "sigmoid": jax.nn.sigmoid}
 
 
+WEIGHT_TILE_BYTES = 4 * 2 ** 20  # a bf16 weight tile [tk, tn] of the grouped product, at most
+GMM_VMEM_BYTES = 21 * 2 ** 20  # the kernel's tiles in all, by `_gmm_vmem` (Mosaic allows 22 MB)
+
+
+def grouped_tiles(m: int, groups: int, k: int, n: int, out_bytes: int):
+    """The tiles ``(tm, tk, tn)`` the megablox grouped product runs ``[m, k] x
+    [groups, k, n]`` in, from its operands' shapes alone (``out_bytes``: the
+    output's item size). The kernel's grid is (output tiles, VISITS,
+    contraction tiles): a visit is one (row tile, group) pair and multiplies
+    a whole ``tm x tk x tn`` block whatever part of the tile's rows are the
+    group's, a row tile two groups share is visited twice, and a last output
+    tile is computed whole where ``tn`` does not divide ``n``. Three things
+    follow, one rule (ms a product on the v5e, the product ALONE under the
+    profiler: my chip runs, PR 54; PERF.md section 5 has every candidate):
+
+    (1) ``tn`` divides ``n`` in whole 128-lane tiles, as wide as a bf16 weight
+    tile ``[tk, tn]`` within 4 MiB allows: no column is computed that is not
+    stored. Until PR 54 a width over 2,048 went in tiles of 2,048: ling3's
+    down product ``[104448, 768] x [128, 768, 2560]`` (68,762 rows held)
+    computed 4,096 columns for 2,560 and took 4.45 ms at (512, 768, 2048),
+    2.77 at (512, 768, 2560). LFM2's ``2048 x 1792`` goes in 896 (whole it
+    overflows Mosaic's VMEM), laguna's ``1024 x 3072`` in 1,536.
+
+    (2) ``tk = k`` where a weight tile of the whole contraction and 512
+    columns (or all ``n``) stays within those 4 MiB, that is up to ``k``
+    4,096: with ONE contraction tile the weight block's index is the same
+    over a group's consecutive visits, the pipeline does not fetch it again
+    and a visit moves its row tile only (ling3's gate / up ``[104448, 2560] x
+    [128, 2560, 768]``: 3.14 ms at (256, 1280, 768), 2.36 at (256, 2560,
+    768); cut in two at 128 rows 4.43: then every visit fetches its weights).
+    Laguna's ``3072 x 1024`` takes two output tiles of 512 for it and reads
+    its rows twice: 2.75 ms at (256, 1536, 1024), 2.25 at (128, 3072, 512). A
+    wider contraction (7,168) goes in its largest 128-multiple of at most
+    2,048 that divides it (1,792: no masked remainder tile), as before.
+
+    (3) ``tm`` follows the rows a group can have, ``m // groups``: what a
+    group's boundary wastes is ``tm`` over the group's rows, so under a whole
+    contraction the row tile is the largest of (512, 256, 128) of at most a
+    QUARTER of them (ling3's pass, 816: 128 rows, its three products 3.14 +
+    3.14 + 4.45 -> 2.33 + 2.33 + 2.39 at 60% of the peak for 44 and 31;
+    laguna's, 1,020: 128, 2.75 + 2.75 + 2.82 -> 2.25 + 2.25 + 2.26; at 256
+    rows both read within 4% of that), never under the MXU's own 128. Where
+    even 128 is more than a quarter (a group of under 512 rows: a turn of
+    2,048 over kimi's 12 or dsv32's 8 experts) or the contraction is cut
+    (every visit fetches its weights, and more visits fetch more) the tile is
+    as it was, the largest that divides ``m`` with ``tm * tk`` at most 512 Ki:
+    such a product is bound by reading its weights, and at 128 rows kimi's
+    down product read 1.38 ms a layer for 1.37, dsv32's 0.78 for 0.79, their
+    gate / up 2.15 for 1.52 and 1.15 for 0.87. Keye's ``[274432, 2048] x
+    [128, 2048, 768]`` stays (256, 2048, 768), 5.40 ms at 82% of the peak
+    (5.60 at 512 rows, 5.53 at 128), its down product (512, 768, 2048) 5.62;
+    LFM2's (256, 2048, 896) 5.88 at 89% (6.04 at 128). Last, the row tile
+    halves while the kernel's tiles pass 21 MiB (:func:`_gmm_vmem`): 512
+    rows beside ``[768, 2560]`` into float32, the down product of ling3's idle
+    loop, would take 25.2 MB."""
+    def dividing(x, most):  # the largest 128-multiple of at most `most` that divides x
+        return next((t for t in range(most - most % 128, 0, -128) if x % t == 0), None)
+
+    whole = WEIGHT_TILE_BYTES // (2 * k) >= min(n, 512)
+    tk = k if whole else dividing(k, 2048) or 2048
+    fits = WEIGHT_TILE_BYTES // (2 * tk)
+    tn = n if n <= fits else dividing(n, fits) or min(n, 2048)
+    quarter = m // groups // 4
+    most = quarter if whole and quarter >= 128 else 512
+    tm = next((t for t in (512, 256, 128, 64, 32, 16, 8)
+               if t <= most and m % t == 0 and t * tk <= 512 * 1024), m)
+    while tm > 8 and _gmm_vmem(tm, tk, tn, out_bytes) > GMM_VMEM_BYTES:
+        tm //= 2
+    return tm, tk, tn
+
+
+def _gmm_vmem(tm: int, tk: int, tn: int, out_bytes: int) -> int:
+    """Bytes of VMEM the grouped product's tiles take: two buffers of each
+    bf16 operand's tile and of the output's, and the float32 accumulator
+    (the loop's down product at ``[2048, 1024] x [64, 1024, 3072]`` into
+    float32 took 23.4 MB at (512, 1024, 2048) where Mosaic allows 22:
+    compiled for a described v5e, PR 53)."""
+    return 2 * (2 * tm * tk + 2 * tk * tn + out_bytes * tm * tn) + 4 * tm * tn
+
+
 def _grouped_product(rows, weights, tokens, out_dtype, interpret):
     """``rows[group e] @ weights[e]`` (``tokens [E]``: the rows of each group,
-    the first group at row 0), by the
-    megablox grouped matrix product (``pallas.ops.tpu.megablox.gmm``). On
-    the v5e at ``[274432, 2048] x [128, 2048, 768]`` it took 6.3 ms and
-    the down product 6.4, against ``lax.ragged_dot``'s 11.3 and 11.0 (my
-    chip runs, PR 36). The row tile divides the row count; the other two
-    tiles are the whole contraction and output widths, which is what was
-    fastest of the tilings tried, as long as a weight tile stays within 4
-    MiB: at ``2048 x 1792`` (LFM2's experts) the whole widths take 47.5 MB
-    of VMEM, over Mosaic's 44, and the output width goes in the largest
-    128-multiple that divides it and fits (896; 1024 of the down product's
-    2048). A contraction over 2,048 wide goes in its largest 128-multiple
-    of at most 2,048 that divides it (1,792 of 7,168: no masked remainder
-    tile), and the row tile is sized against that tile, not the whole
-    width (256 rows of 7,168, where the whole width allowed 64: a quarter
-    of the v5e's ridge, each expert's weights read six times). Last, the row
-    tile halves while the kernel's tiles (two buffers of each operand's and of
-    the output's, and the float32 accumulator) pass 21 MiB: the loop's down
-    product at ``[2048, 1024] x [64, 1024, 3072]`` into float32 takes 23.4 MB
-    at 512 rows where Mosaic allows 22 (compiled for a described v5e, PR 53);
-    no shape served before it comes near (ling3's, the nearest, 19.5 MiB)."""
+    the first group at row 0; rows past the last group's stay unwritten), by
+    the megablox grouped matrix product (``pallas.ops.tpu.megablox.gmm``) in
+    the tiles :func:`grouped_tiles` gives the operands' shapes. On the v5e at
+    ``[274432, 2048] x [128, 2048, 768]`` it took 6.3 ms and the down product
+    6.4, against ``lax.ragged_dot``'s 11.3 and 11.0 (my chip runs, PR 36)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    m, k = rows.shape
-    n = weights.shape[-1]
-    tk, tn = min(k, 2048), min(n, 2048)
-    if k % tk:
-        tk = next((t for t in range(tk - tk % 128, 0, -128) if k % t == 0), tk)
-    tm = next((t for t in (512, 256, 128, 64, 32, 16, 8) if m % t == 0 and t * tk <= 512 * 1024), m)
-    fits = 4 * 1024 * 1024 // (2 * tk)  # output columns of a bf16 weight tile within 4 MiB
-    if tn > fits:
-        tn = next((t for t in range(tn - tn % 128, 0, -128) if n % t == 0 and t <= fits), tn)
-    out_bytes = jnp.dtype(out_dtype).itemsize
-    while tm > 8 and 2 * (2 * tm * tk + 2 * tk * tn + out_bytes * tm * tn) + 4 * tm * tn > 21 * 2 ** 20:
-        tm //= 2
-    return gmm(rows, weights, tokens, preferred_element_type=out_dtype,
-               tiling=(tm, tk, tn), interpret=interpret)
+    (m, k), (groups, _, n) = rows.shape, weights.shape
+    tiling = grouped_tiles(m, groups, k, n, jnp.dtype(out_dtype).itemsize)
+    return gmm(rows, weights, tokens, preferred_element_type=out_dtype, tiling=tiling,
+               interpret=interpret)
 
 
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,

@@ -550,3 +550,107 @@ def test_a_large_share_s_layer_is_the_whole_layer_restricted_to_the_held(case):
     np.testing.assert_array_equal(np.asarray(tokens), np.asarray(all_tokens)[8:16])
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=2e-5)
     assert (rows == 0) == (np.abs(np.asarray(y)).max() == 0)
+
+
+# the grouped product's served shapes (PR 54): name -> (m, groups, k, n, the output's item size,
+# rows a group on an even load, the tiles the rule gives, the tiles until PR 54, the even-load
+# fill under those and under the rule's). Where the two tiles are equal the shape was LEFT ALONE:
+# its layer alone did not win 3% of its products' time (PERF.md section 5, PR 54). A "turn" is
+# one of the held rows' loop (2,048 rows); ling3's and laguna's loops are idle on an even load
+SERVED_PRODUCTS = {
+    "ling3_pass_gate_up": (104448, 128, 2560, 768, 4, 544, (128, 2560, 768), (256, 1280, 768), 0.708, 0.850),
+    "ling3_pass_down": (104448, 128, 768, 2560, 2, 544, (128, 768, 2560), (512, 768, 2048), 0.332, 0.850),
+    "ling3_turn_gate_up": (2048, 128, 2560, 768, 4, 544, (128, 2560, 768), (256, 1280, 768), 0.727, 0.842),
+    "ling3_turn_down": (2048, 128, 768, 2560, 4, 544, (256, 768, 2560), (512, 768, 2048), 0.357, 0.727),
+    "laguna_pass_gate_up": (65280, 64, 3072, 1024, 4, 680, (128, 3072, 512), (256, 1536, 1024), 0.733, 0.850),
+    "laguna_pass_down": (65280, 64, 1024, 3072, 2, 680, (128, 1024, 1536), (256, 1024, 2048), 0.550, 0.850),
+    "laguna_turn_gate_up": (2048, 64, 3072, 1024, 4, 680, (128, 3072, 512), (256, 1536, 1024), 0.727, 0.842),
+    "laguna_turn_down": (2048, 64, 1024, 3072, 4, 680, (512, 1024, 1536), (256, 1024, 2048), 0.545, 0.571),
+    "kimi_turn_gate_up": (2048, 12, 7168, 2048, 4, 362, (256, 1792, 1024), (256, 1792, 1024), 0.615, 0.615),
+    "kimi_turn_down": (2048, 12, 2048, 7168, 4, 362, (256, 2048, 1024), (256, 2048, 1024), 0.615, 0.615),
+    "dsv32_turn_gate_up": (2048, 8, 7168, 2048, 4, 272, (256, 1792, 1024), (256, 1792, 1024), 0.533, 0.533),
+    "dsv32_turn_down": (2048, 8, 2048, 7168, 4, 272, (256, 2048, 1024), (256, 2048, 1024), 0.533, 0.533),
+    "keye_gate_up": (274432, 128, 2048, 768, 4, 2144, (256, 2048, 768), (256, 2048, 768), 0.905, 0.905),
+    "keye_down": (274432, 128, 768, 2048, 2, 2144, (512, 768, 2048), (512, 768, 2048), 0.817, 0.817),
+    "lfm2_gate_up": (139264, 32, 2048, 1792, 4, 4352, (256, 2048, 896), (256, 2048, 896), 1.000, 1.000),
+    "lfm2_down": (139264, 32, 1792, 2048, 2, 4352, (256, 1792, 1024), (256, 1792, 1024), 1.000, 1.000),
+}
+
+
+def _even_fill(m, groups, n, rows_a_group, tiles):
+    """Needed rows x columns over what the kernel's grid passes over, every
+    group ``rows_a_group`` rows from row 0 on and cut at ``m``: a visit is one
+    (row tile, group) pair and multiplies a whole ``tm x tn`` block of every
+    output tile, the last one whole too where ``tn`` does not divide ``n``."""
+    tm, _, tn = tiles
+    ends = np.minimum(np.arange(1, groups + 1) * rows_a_group, m)
+    starts = np.minimum(np.arange(groups) * rows_a_group, m)
+    live = ends > starts
+    visits = int((-(-ends[live] // tm) - starts[live] // tm).sum())
+    return int((ends - starts).sum()) * n / (visits * tm * -(-n // tn) * tn)
+
+
+@pytest.mark.parametrize("name", sorted(SERVED_PRODUCTS))
+def test_the_grouped_product_s_tiles_at_every_served_shape(name):
+    """``moe.grouped_tiles`` at the shapes the benchmark's six decoders serve:
+    the output tile is whole 128-lane tiles that DIVIDE the width (ling3's
+    down product computed 4,096 columns for 2,560), the contraction tile
+    divides the contraction, the row tile the rows; the kernel's tiles stay
+    within the 21 MiB reckoning and a weight tile within 4 MiB; a shape whose
+    layer did not win keeps its tiles to the letter."""
+    from psana_ray_tpu.parallel import moe
+
+    m, groups, k, n, out_bytes, rows, want, before, fill_before, fill = SERVED_PRODUCTS[name]
+    tm, tk, tn = tiles = moe.grouped_tiles(m, groups, k, n, out_bytes)
+    assert tiles == want
+    assert tn % 128 == 0 and n % tn == 0 and tk % 128 == 0 and k % tk == 0 and m % tm == 0
+    assert tm >= 128
+    assert moe._gmm_vmem(tm, tk, tn, out_bytes) <= moe.GMM_VMEM_BYTES
+    assert 2 * tk * tn <= moe.WEIGHT_TILE_BYTES
+    assert _even_fill(m, groups, n, rows, before) == pytest.approx(fill_before, abs=1e-3)
+    assert _even_fill(m, groups, n, rows, tiles) == pytest.approx(fill, abs=1e-3) and fill >= fill_before
+
+
+# m, the groups' sizes, k, n, the output's type: each meets a branch of the rule or of the kernel
+GROUPED_PRODUCTS = {
+    "uneven_groups_that_end_inside_a_tile": (512, [200, 56, 130, 126], 256, 384, jnp.float32),
+    "an_empty_group_and_one_smaller_than_the_tile": (512, [0, 40, 300, 0, 172], 256, 384, jnp.bfloat16),
+    "rows_past_the_held": (512, [150, 0, 90, 60], 256, 384, jnp.float32),
+    "nothing_held": (256, [0, 0, 0], 128, 128, jnp.float32),
+    "a_contraction_cut_in_four": (256, [100, 0, 156], 7168, 384, jnp.float32),
+    "a_group_of_many_row_tiles": (4096, [2500, 1596], 128, 256, jnp.bfloat16),
+    "a_row_tile_of_a_quarter_of_a_group": (2048, [700, 648, 700], 128, 384, jnp.float32),
+    "an_output_cut_to_a_divisor": (256, [131, 125], 1024, 3072, jnp.bfloat16),
+    "widths_of_no_whole_lane_tile": (48, [7, 0, 30, 11], 64, 32, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GROUPED_PRODUCTS))
+def test_the_grouped_product_is_each_group_s_dense_product(name):
+    """``moe._grouped_product`` (interpret mode here) under
+    :func:`moe.grouped_tiles` against ``x[group] @ w[e]`` in float32 from the
+    bf16 operands: groups that end inside a row tile, an empty group, a group
+    smaller than the tile, a width of 3 x 128, the contraction whole and cut,
+    a row tile of today's and of a quarter of a group. Rows past the held ones
+    are no group's: the kernel leaves them unwritten and nothing is asked of
+    them here (``_held_rows_ahead``'s sum ignores them: the NaN test above)."""
+    from psana_ray_tpu.parallel import moe
+
+    m, sizes, k, n, out_dtype = GROUPED_PRODUCTS[name]
+    tm, tk, tn = moe.grouped_tiles(m, len(sizes), k, n, jnp.dtype(out_dtype).itemsize)
+    assert {"a_contraction_cut_in_four": tk == 1792, "an_output_cut_to_a_divisor": tn == 1536,
+            "a_group_of_many_row_tiles": tm == 512, "a_row_tile_of_a_quarter_of_a_group": tm == 128,
+            }.get(name, (tk, tn) == (k, n)), (tm, tk, tn)
+    rng = np.random.default_rng(len(name))
+    x = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((len(sizes), k, n)) * k ** -0.5, jnp.bfloat16)
+    got = np.asarray(moe._grouped_product(x, w, jnp.asarray(sizes, jnp.int32), out_dtype,
+                                          interpret=True).astype(jnp.float32))
+    assert got.shape == (m, n)
+    held, lo = sum(sizes), 0
+    for e, rows in enumerate(sizes):
+        want = np.asarray(x[lo:lo + rows], np.float32) @ np.asarray(w[e], np.float32)
+        tol = 2e-5 if out_dtype == jnp.float32 else 2 ** -7  # bf16 rounds the stored sum once
+        np.testing.assert_allclose(got[lo:lo + rows], want, rtol=tol, atol=tol)
+        lo += rows
+    assert lo == held and np.isfinite(got[:held]).all()

@@ -41,6 +41,7 @@ from psana_ray_tpu.obs.stages import (
     observe_batch_done,
     observe_frame_stages,
 )
+from psana_ray_tpu.obs.jitwatch import WATCH
 from psana_ray_tpu.obs.tracing import TRACER
 from psana_ray_tpu.utils.metrics import PipelineMetrics
 from psana_ray_tpu.utils.trace import phase
@@ -390,7 +391,12 @@ class InfeedPipeline:
 
         The serving thread's phases per batch: ``infeed_wait`` (blocked
         on the prefetcher), ``launch`` and ``device_wait``
-        (:func:`drive_step`), then ``on_result``, the caller's own."""
+        (:func:`drive_step`), then ``on_result``, the caller's own.
+
+        When the process's first result is out the loop logs ONE line at
+        INFO (``obs.jitwatch``: what the start traced, lowered, loaded
+        and compiled); a load or compile after it is a flight-recorder
+        event ``recompile``."""
         n = 0
         batches = iter(self)
         try:
@@ -405,6 +411,8 @@ class InfeedPipeline:
                 n += batch.num_valid
                 if on_result is not None:
                     on_result(out, batch)
+                if not WATCH.serving:  # the first result is out: the start's account, one line
+                    WATCH.first_result("InfeedPipeline.run")
         except StopStream:
             pass  # consumer-side early stop; close() below
         finally:

@@ -6,7 +6,10 @@
 queue server, consumer, ...), estimates each process's clock offset, and
 emits the Chrome trace-event format that Perfetto (https://ui.perfetto.dev)
 and TensorBoard load directly: one track per process, frame spans linked
-across tracks by trace id (flow arrows).
+across tracks by trace id (flow arrows); a process's ``jit.trace`` /
+``jit.lower`` / ``jit.cache_load`` / ``jit.compile`` spans (what its start
+traced, lowered, loaded and compiled: :mod:`psana_ray_tpu.obs.jitwatch`)
+stand on its track with the function's name under ``args.fun``.
 
 Clock alignment, two layers:
 
@@ -193,15 +196,19 @@ def merge(paths: List[str], only_trace: Optional[int] = None) -> dict:
         for s in spool["spans"]:
             tid = s.get("id", 0)
             ts = us(s["a"])
+            args = {"trace_id": f"{tid:#x}"}
+            if "f" in s:  # a jit.<kind> span (obs.jitwatch): which function
+                args["fun"] = s["f"]
             events.append(
                 {
                     "ph": "X", "name": s["n"], "cat": "frame",
                     "pid": pid, "tid": 0,
                     "ts": ts, "dur": max(0.0, us(s["b"]) - ts),
-                    "args": {"trace_id": f"{tid:#x}"},
+                    "args": args,
                 }
             )
-            flows.setdefault(tid, []).append({"ts": ts, "pid": pid})
+            if "f" not in s:  # the start's compile path is no frame's journey
+                flows.setdefault(tid, []).append({"ts": ts, "pid": pid})
         for i in spool["instants"]:
             tid = i.get("id", 0)
             events.append(

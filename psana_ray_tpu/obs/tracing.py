@@ -28,7 +28,10 @@ Three pieces:
   per process, frame spans linked by trace id. The serving loops'
   phases (:func:`psana_ray_tpu.utils.trace.phase`) land in the same
   spool as ``stage.<name>`` spans, one per batch, under the batch's id;
-  a frame's own spans name the batch it joined (``j``).
+  a frame's own spans name the batch it joined (``j``). What JAX
+  traced, lowered, loaded or compiled before the first batch (and
+  after: :mod:`psana_ray_tpu.obs.jitwatch`) lands there too, as
+  ``jit.<kind>`` spans that name their function (``f``).
 
 Everything here is pure stdlib (no numpy, no jax) so every process —
 including the queue server — can afford the import. Recording a span is
@@ -53,6 +56,8 @@ import sys
 import threading
 import time
 from typing import Any, Dict, Optional
+
+from psana_ray_tpu.obs import jitwatch
 
 __all__ = [
     "TraceContext",
@@ -137,7 +142,8 @@ class TraceContext:
 # A span line is {"t":"s","id","n","a","b"} plus, on a frame's span, "j"
 # (the id of the batch it joined) or, on a loop phase's span ("n" is
 # "stage.<phase>" or "h2d", "id" the batch's), "k" (frames in the batch
-# or turn) and, where the phase moved them, "y" (bytes).
+# or turn) and, where the phase moved them, "y" (bytes); a "jit.<kind>"
+# span (obs.jitwatch) carries "f", the jitted function's name.
 
 
 class Tracer:
@@ -148,8 +154,11 @@ class Tracer:
     unsampled frames (counter arithmetic only — pinned by test and the
     hot-alloc checker's span fixtures)."""
 
-    def __init__(self):
+    def __init__(self, jit_watch: Optional[jitwatch.JitWatch] = None):
         self.enabled = False
+        # whose compile-path rows this tracer spools while it is on (the
+        # process's, for the global tracer; none for one a test builds)
+        self._jit_watch = jit_watch
         # reentrant: an allocation under the lock can start a full
         # collection, whose hook (_on_gc) records its span from inside
         self._lock = threading.RLock()
@@ -199,7 +208,11 @@ class Tracer:
         pipeline. The loop phases' spans (:meth:`phase_span`) count
         against a second bound of the same size: a stream of traced
         frames that fills the first leaves every phase's span in place
-        (the readers of the phases refuse a spool that dropped one)."""
+        (the readers of the phases refuse a spool that dropped one). What
+        the process's ``jitwatch`` heard BEFORE the spool opened goes into
+        it here, on the same monotonic clock, and what it hears from now
+        on as it comes: a restart's trace / lower / load / compile stand
+        on the process's track ahead of its first batch."""
         if sample_every <= 0:
             raise ValueError("sample_every must be >= 1 (frames per sample)")
         with self._lock:
@@ -237,6 +250,9 @@ class Tracer:
             if not self._atexit_registered:
                 self._atexit_registered = True
                 atexit.register(self.close)
+            if self._jit_watch is not None:
+                for kind, fun, t0, t1, _ in self._jit_watch.attach(self.phase_span):
+                    self.phase_span(0, kind, t0, t1, label=fun)
         return self
 
     @property
@@ -283,11 +299,13 @@ class Tracer:
             self._keep((trace_id, name, t0, t1, None, 0))
 
     def phase_span(self, batch_id: int, name: str, t0: float, t1: float,
-                   frames: int = 0, nbytes: int = 0) -> None:
+                   frames: int = 0, nbytes: int = 0, label: str = "") -> None:
         """One completed span of a serving thread's loop PHASE
         (``utils.trace.phase``: ``stage.<name>``, or ``h2d``) under the
         batch's id, with the ``frames`` the batch or loop turn held and
-        the ``nbytes`` it moved. Kept under the phases' own bound."""
+        the ``nbytes`` it moved; or one ``jit.<kind>`` span of
+        ``obs.jitwatch`` with the function's name as its ``label``. Kept
+        under the phases' own bound."""
         if not self.enabled:
             return
         with self._lock:
@@ -295,7 +313,7 @@ class Tracer:
                 self._phase_drops += 1
                 return
             self._phase_spans += 1
-            self._buf.append((batch_id, name, t0, t1, None, frames, nbytes))
+            self._buf.append((batch_id, name, t0, t1, None, frames, nbytes, label))
 
     def _keep(self, row: tuple) -> None:
         """THE bounded sink of a frame's single rows: kept in memory, or
@@ -394,14 +412,16 @@ class Tracer:
             if len(row) == 3:
                 out.append(self._line(t="i", id=row[0], n=row[1], a=row[2]))
                 continue
-            tid, name, t0, t1, joined, frames, *nbytes = row  # a phase's row ends in its bytes
+            tid, name, t0, t1, joined, frames, *more = row  # a phase's row ends in bytes, label
             rec = {"t": "s", "id": tid, "n": name, "a": t0, "b": t1}
             if joined is not None:
                 rec["j"] = joined
             if frames:
                 rec["k"] = frames
-            if nbytes and nbytes[0]:
-                rec["y"] = nbytes[0]
+            if more and more[0]:
+                rec["y"] = more[0]
+            if more and more[1]:
+                rec["f"] = more[1]
             out.append(self._line(**rec))
         out.append(self._line(
             t="d", spans=self._spans, dropped=self._drops,
@@ -427,6 +447,8 @@ class Tracer:
         self._f = None
         self.enabled = False
         self._every = 0
+        if self._jit_watch is not None:
+            self._jit_watch.detach(self.phase_span)
         if self._on_gc in gc.callbacks:
             gc.callbacks.remove(self._on_gc)
 
@@ -465,8 +487,9 @@ class Tracer:
         return suffix
 
 
-#: The process-global tracer every CLI configures (tests build their own).
-TRACER = Tracer()
+#: The process-global tracer every CLI configures (tests build their own);
+#: it spools what the process's compile-path listener hears.
+TRACER = Tracer(jit_watch=jitwatch.WATCH)
 
 
 def exchange_anchors(queue, n: int = 3, tracer: Optional[Tracer] = None) -> int:

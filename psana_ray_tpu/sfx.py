@@ -69,6 +69,7 @@ from typing import Callable, Optional, Sequence, Tuple
 
 import numpy as np
 
+from psana_ray_tpu.obs.jitwatch import WATCH
 from psana_ray_tpu.obs.stages import (
     PHASE_APPEND,
     PHASE_DEVICE_WAIT,
@@ -483,7 +484,13 @@ class SfxPipeline:
         by one extra batch: up to ``2*batch_size - 1`` events past the
         bound, vs the serial loop's ``batch_size - 1``. A drain that
         raises, early or not, surfaces from here: its handle is not
-        drained again, and the cursor is saved over what was written."""
+        drained again, and the cursor is saved over what was written.
+
+        When the process's first result is out the loop logs ONE line at
+        INFO (``obs.jitwatch``): seconds since the process started, what
+        JAX traced, lowered, loaded from the compile cache and compiled
+        until then, and which functions; a load or compile after it is a
+        flight-recorder event ``recompile``."""
         from psana_ray_tpu.infeed.batcher import DrainControl, batches_from_queue
 
         start = self.n_events
@@ -507,6 +514,8 @@ class SfxPipeline:
         def _drain_one(pending) -> bool:
             """Drain + cursor bookkeeping; True = hit the max_events bound."""
             self.drain(pending, cursor=cursor, on_appended=on_appended)
+            if not WATCH.serving:  # the first result is out: the start's account, one line
+                WATCH.first_result("SfxPipeline.run")
             return max_events is not None and self.n_events - start >= max_events
 
         pending = None

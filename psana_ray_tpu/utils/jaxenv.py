@@ -1,9 +1,15 @@
 """Process-level JAX settings shared by every entry point that compiles.
 
-Two things a device run has to make visible instead of leaving to
+Three things a device run has to make visible instead of leaving to
 defaults: where compiled programs are cached (a CLI that sets nothing
-cold-compiles on every start), and which device the process actually
-compiled for (a run that fell to the CPU must say so in its own log).
+cold-compiles on every start), which device the process actually
+compiled for (a run that fell to the CPU must say so in its own log),
+and what the start itself cost: :func:`configure_compile_cache` also
+installs the listener of :mod:`psana_ray_tpu.obs.jitwatch`, which turns
+JAX's own timings of every trace, lowering, cache load and compile into
+``jit.*`` spans and ``jit_*_total`` counters from the first jitted call
+on (it is called on compile events only: a loop that compiles nothing
+never reaches it).
 """
 
 from __future__ import annotations
@@ -33,8 +39,14 @@ def configure_compile_cache() -> str:
     the directory JAX reads it itself and no directory is set in code;
     either way every child process inherits the same one, so repeated CLI
     starts on one machine — and every process one script launches — share
-    compiles."""
+    compiles. Also installs, once, the process's compile-path listener
+    (``obs.jitwatch.install()``): every entry point comes through here
+    before anything compiles, so the account of a start is whole."""
     import jax
+
+    from psana_ray_tpu.obs import jitwatch
+
+    jitwatch.install()
 
     # A Pallas kernel rides in the program as a serialized Mosaic module
     # whose op locations, by default, hold the Python call stack (10

@@ -27,6 +27,18 @@ def _fresh_registry():
     MetricsRegistry.reset_default()
 
 
+@pytest.fixture(autouse=True)
+def _no_compile_listener_left():
+    """A CLI's ``main`` (or ``configure_compile_cache``) run in-process
+    installs the process's compile-path listener (``obs/jitwatch.py``); it
+    must not listen into the next test of this worker, nor hand that
+    test's tracer what an earlier one compiled."""
+    yield
+    from psana_ray_tpu.obs import jitwatch
+
+    jitwatch.WATCH.uninstall()
+
+
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)

@@ -225,7 +225,7 @@ class DecoderConfig:
     causal_q_tile: int = 1088
     causal_kv_tile: int = 1088
     # rows a chunk of the delta rule's kernel (the largest whole-tile divisor of S under it): on
-    # the v5e at 4 x 8,704 tokens 128 rows 19.9 ms a layer, 64 rows 26.4 (my chip runs, PR 50)
+    # the v5e at 4 x 8,704 tokens 128 rows 7.5 ms a layer, 64 rows 10.1 (my chip runs, PR 56)
     linear_chunk: int = CHUNK
     # experts (num_experts 0: a dense gated MLP of intermediate_size)
     num_experts: int = 0

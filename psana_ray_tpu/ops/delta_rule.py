@@ -24,15 +24,17 @@ inside the chunk and ``S`` the state the chunk starts from:
     [W | U] = X [beta k e^G | beta v]          vbar = U - W S          o = (q e^G) S + tril(A_qk) vbar
     S <- Diag(e^(G_C)) S + (k e^(G_C - G))^T vbar
 
-Three things shape it on the chip:
+Four things shape it on the chip:
 
 - ``e^(-G_j)`` leaves float32 after 17 rows at -5 a row, so ``A_kk`` and
   ``A_qk`` are not one product of pre-scaled operands: a row block of
   ``BLOCK`` (16) rows scales its rows by ``e^(G_i - r)`` and the keys by
   ``e^(r - G_j)``, ``r`` the block's first row's ``G``: the first is at
-  most 1, the second at most ``e^75`` inside the block and at most 1
-  before it (after it the entry is masked, and the exponent is capped so
-  that nothing masked is infinite);
+  most 1, the second at most ``e^75`` inside the block (capped, so that
+  nothing past a row's diagonal, which is masked, is infinite) and at most
+  1 before it. A block reads the keys up to its own last row only, and the
+  ones before the block's before come from that block's, times ``e^(r -
+  r_before)``: one ``[1, d]`` factor of at most 1 (``_keys_of_blocks``);
 - the inverse of the unit lower-triangular ``I + B`` comes by halves: with
   the inverses of the two diagonal halves known, the block below the
   diagonal is ``-X_22 B_21 X_11``; from 1 x 1 blocks up that is ``2 log2(C)
@@ -44,10 +46,24 @@ Three things shape it on the chip:
   are not bounded: a run of identical keys under a slow decay (a detector's
   blank patches) makes ``N`` a triangle of ``-beta`` whose 64th power has
   entries of 10^37, and the step came out NaN on the chip (PR 50);
-- one head's chunk is a few ``64 x 128 x 128`` products: ``HEADS`` heads
-  a grid step, unrolled, so that their chains interleave; the chunks are
-  the sequential grid axis, the states sit in VMEM scratch (transposed,
-  ``[d_v, d_k]``: the decay then scales lanes).
+- one head's chunk is a CHAIN: the running sum, the blocks, twelve
+  products of the inverse each waiting for the one before, ``W`` and ``U``,
+  the state. ``HEADS`` heads a grid step, and the body goes part by part
+  and level by level through ALL of them (``_chunks``), so that the matrix
+  unit always has another head's product to take. Written head after head
+  the compiler kept that order and every product waited for its own
+  result: 18.9 ms a layer at the served shape for 7.5 (PR 56; the inverse
+  alone 10.6 ms of it for 2.5). The chunks are the sequential grid axis,
+  the states sit in VMEM scratch (transposed, ``[d_v, d_k]``: the decay
+  then scales lanes);
+- what the chains hid costs little once they run side by side. Counted in
+  results of ``[8, 128]`` registers the body was 3,362 a head-chunk, about
+  a fifth of them the inverse's masks (seven shifts-and-compares of ``[C,
+  C]`` a head) and a quarter the blocks' keys (all ``C`` rows rescaled for
+  every block). The masks built once a grid step (``_constants``) and the
+  keys rescaled from the block before leave 2,500; that bought 3% under
+  the old order and 1.1% under the new, and one cast an operand in place
+  of ``_mm``'s two bought nothing (PR 56, on the chip: PERF.md section 7).
 
 Matrix products take bf16 operands and sum in float32; the state, the
 running sums of ``g`` (a triangular product of ``g`` split in three bf16
@@ -67,9 +83,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-# rows a chunk. On the v5e at 4 x 8,704 tokens, 32 heads of 128, HEADS 4: 128 rows 19.9 ms a layer,
-# 64 rows 26.4, 32 rows 38.6 (a grid step's own cost, paid per chunk); HEADS 8 19.3, HEADS 2 20.7 at
-# 128 rows (my chip runs, PR 50)
+# rows a chunk. On the v5e at 4 x 8,704 tokens, 32 heads of 128, HEADS 4, a layer: 128 rows 7.53 ms,
+# 64 rows 10.13, 32 rows 15.47 (a grid step's own cost, paid per chunk); HEADS 8 6.64, HEADS 2 11.35
+# at 128 rows (my chip runs, PR 56: the heads of a grid step side by side; 8 heads are a body twice
+# as long to trace and compile). Head after head, PR 50's body: 19.9 / 26.4 / 38.6; HEADS 8 19.3,
+# HEADS 2 20.7 (my chip runs, PR 50): more heads bought a grid step's cost and no more
 CHUNK = 128
 BLOCK = 16  # rows that share a reference row: 15 rows at -5 a row is e^75, float32 ends at e^88
 HEADS = 4  # heads a grid step
@@ -82,63 +100,96 @@ def _mm(a, b, dims=((1,), (0,))):
                                preferred_element_type=jnp.float32)
 
 
-def _running_sum(g):
-    """``[C, d]`` float32 -> the sums over rows ``0..i``, exact: a lower-triangular
-    product of ones with ``g`` split in three bf16 parts."""
-    c, d = g.shape
+def _constants(c):
+    """What a chunk's body reads of its rows' and columns' NUMBERS, the same for
+    every head, chunk and layer, made once a grid step: the two triangles, the
+    identity, the running sum's triangle of ones, and for each level of the
+    inverse the entries that join two diagonal blocks of ``2^shift`` rows into
+    one (same block of ``2 * 2^shift``, other half: ``(row ^ col) >> shift ==
+    1``; of a strictly lower ``below`` that is the part under the diagonal)."""
     row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    apart, lower_eq = row ^ col, row >= col
+    joins = [(apart >> shift) == 1 for shift in range((c - 1).bit_length())]
+    return row > col, lower_eq, jnp.where(apart == 0, 1.0, 0.0), lower_eq.astype(jnp.bfloat16), joins
+
+
+def _running_sum(g, ones):
+    """``[C, d]`` float32 -> the sums over rows ``0..i``, exact: the product of
+    ``ones`` (lower triangular, bf16) with ``g`` split in three bf16 parts."""
+    d = g.shape[1]
     hi = g.astype(jnp.bfloat16)
     rest = g - hi.astype(jnp.float32)
     mid = rest.astype(jnp.bfloat16)
     low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    parts = _mm((row >= col).astype(jnp.bfloat16), jnp.concatenate([hi, mid, low], axis=1))
+    parts = _mm(ones, jnp.concatenate([hi, mid, low], axis=1))
     return parts[:, :d] + parts[:, d:2 * d] + parts[:, 2 * d:]
 
 
-def _unit_lower_inverse(below, row, col):
-    """``(I + below)^-1`` for ``below [C, C]`` strictly lower triangular, by
-    halves from 1 x 1 blocks up: a level's ``X`` holds the inverses of the
-    diagonal blocks of ``size`` rows, and the next level's is ``X - X E X``,
-    ``E`` the part of ``below`` that joins two such blocks into one."""
-    c = below.shape[0]
-    x = jnp.where(row == col, 1.0, 0.0)
-    shift = 0
-    while 1 << shift < c:
-        joins = ((row >> (shift + 1)) == (col >> (shift + 1))) & ((row >> shift) != (col >> shift))
-        e = jnp.where(joins, below, 0.0)
-        x = x - (_mm(_mm(x, e), x) if shift else e)  # the 1 x 1 blocks' inverses are 1
-        shift += 1
-    return x
+def _unit_lower_inverses(below, eye, joins):
+    """``(I + b)^-1`` for each ``b [C, C]`` of ``below``, strictly lower
+    triangular, by halves from 1 x 1 blocks up: a level's ``X`` holds the
+    inverses of the diagonal blocks of ``size`` rows, and the next level's is
+    ``X - X E X``, ``E`` the part of ``b`` that joins two such blocks into one.
+    Level by level for ALL the matrices, so that their chains run side by side."""
+    xs = [eye - jnp.where(joins[0], b, 0.0) for b in below]  # the 1 x 1 blocks' inverses are 1
+    for level in joins[1:]:
+        half = [_mm(x, jnp.where(level, b, 0.0)) for x, b in zip(xs, below)]
+        xs = [x - _mm(xe, x) for x, xe in zip(xs, half)]
+    return xs
 
 
-def _chunk(q, k, v, g, beta, state_t, block: int):
-    """One head's chunk: ``q, k`` (normed), ``v [C, d]``, ``g [C, d_k]`` float32,
-    ``beta [C, 1]``, the state transposed ``[d_v, d_k]`` -> ``(o [C, d_v], state_t)``."""
-    c = q.shape[0]
-    gam = _running_sum(g)
-    akk, aqk = [], []
-    for lo in range(0, c, block):
-        r = gam[lo:lo + 1]
-        keys = k * jnp.exp(jnp.minimum(r - gam, _CAP))
-        scale = jnp.exp(gam[lo:lo + block] - r)
-        a = _mm(jnp.concatenate([k[lo:lo + block] * scale, q[lo:lo + block] * scale]), keys,
-                ((1,), (1,)))
-        akk.append(a[:block])
-        aqk.append(a[block:])
-    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    below = jnp.where(row > col, beta * jnp.concatenate(akk), 0.0)
-    x = _unit_lower_inverse(below, row, col)
-    decay = jnp.exp(gam)
-    wu = _mm(x, jnp.concatenate([beta * k * decay, beta * v], axis=1))
-    d_k = k.shape[1]
-    from_state = _mm(jnp.concatenate([wu[:, :d_k], q * decay]), state_t, ((1,), (1,)))
-    vbar = wu[:, d_k:] - from_state[:c]
-    o = from_state[c:] + _mm(jnp.where(row >= col, jnp.concatenate(aqk), 0.0), vbar)
-    last = gam[c - 1:c]
-    state_t = state_t * jnp.exp(last) + _mm(vbar, k * jnp.exp(last - gam), ((0,), (0,)))
-    return o, state_t
+def _keys_of_blocks(k, gam, block):
+    """For each block of ``block`` rows, first row ``lo``, the keys it reads:
+    rows ``0 .. lo + block`` of ``k [C, d]``, row ``j`` times ``e^(r - G_j)``,
+    ``r = G_lo``. The block's own rows and the block's before are made so; the
+    rows before those are the block before's, times ``e^(r - r_before)``: one
+    ``[1, d]`` factor of at most 1, so nothing can overflow and what underflows
+    is the true value's own underflow. The cap guards the block's own rows
+    (``j > lo``: at most ``e^75``; past a row's diagonal they are masked)."""
+    out = []
+    for lo in range(0, k.shape[0], block):
+        r, near = gam[lo:lo + 1], max(lo - block, 0)
+        keys = k[near:lo + block] * jnp.exp(jnp.minimum(r - gam[near:lo + block], _CAP))
+        if near:
+            keys = jnp.concatenate([out[-1][:near] * jnp.exp(r - gam[near:near + 1]), keys])
+        out.append(keys)
+    return out
+
+
+def _chunks(q, k, v, g, beta, state_t, constants, block: int):
+    """One chunk of each of a grid step's heads (lists over the heads): ``q, k``
+    (normed), ``v [C, d]``, ``g [C, d_k]`` float32, ``beta [C, 1]``, the state
+    transposed ``[d_v, d_k]`` -> ``(o [C, d_v], state_t)`` per head. Part by
+    part for all the heads: one head's parts are a chain."""
+    strict, lower_eq, eye, ones, joins = constants
+    heads, (c, d_k) = range(len(q)), k[0].shape
+    gam = [_running_sum(u, ones) for u in g]
+    keys = [_keys_of_blocks(k[h], gam[h], block) for h in heads]
+    a = [[] for _ in heads]
+    for i, lo in enumerate(range(0, c, block)):
+        for h in heads:
+            scale = jnp.exp(gam[h][lo:lo + block] - gam[h][lo:lo + 1])
+            seen = keys[h][i]  # the rows after the block are masked: zeros
+            if lo + block < c:
+                seen = jnp.concatenate([seen, jnp.zeros((c - lo - block, d_k), jnp.float32)])
+            a[h].append(_mm(jnp.concatenate([k[h][lo:lo + block] * scale,
+                                             q[h][lo:lo + block] * scale]), seen, ((1,), (1,))))
+    xs = _unit_lower_inverses(
+        [jnp.where(strict, beta[h] * jnp.concatenate([u[:block] for u in a[h]]), 0.0)
+         for h in heads], eye, joins)
+    decay = [jnp.exp(u) for u in gam]
+    wu = [_mm(xs[h], jnp.concatenate([beta[h] * k[h] * decay[h], beta[h] * v[h]], axis=1))
+          for h in heads]
+    from_state = [_mm(jnp.concatenate([wu[h][:, :d_k], q[h] * decay[h]]), state_t[h], ((1,), (1,)))
+                  for h in heads]
+    vbar = [wu[h][:, d_k:] - from_state[h][:c] for h in heads]
+    o = [from_state[h][c:]
+         + _mm(jnp.where(lower_eq, jnp.concatenate([u[block:] for u in a[h]]), 0.0), vbar[h])
+         for h in heads]
+    last = [u[c - 1:c] for u in gam]
+    return [(o[h], state_t[h] * jnp.exp(last[h])
+             + _mm(vbar[h], k[h] * jnp.exp(last[h] - gam[h]), ((0,), (0,)))) for h in heads]
 
 
 def _kernel(q_ref, k_ref, v_ref, f_ref, z_ref, beta_ref, ea_ref, b_ref, gain_ref, o_ref,
@@ -148,14 +199,16 @@ def _kernel(q_ref, k_ref, v_ref, f_ref, z_ref, beta_ref, ea_ref, b_ref, gain_ref
         state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
 
     gain = gain_ref[...].astype(jnp.float32)
-    for h in range(heads):
-        at = slice(h * d, (h + 1) * d)  # the head's columns of every operand
-        q, k = (u[:, at].astype(jnp.float32) for u in (q_ref, k_ref))
-        q = q * jax.lax.rsqrt(jnp.sum(q * q, axis=-1, keepdims=True) + L2_EPS) * d ** -0.5
-        k = k * jax.lax.rsqrt(jnp.sum(k * k, axis=-1, keepdims=True) + L2_EPS)
-        g = lower * jax.nn.sigmoid(ea_ref[:, at] * (f_ref[:, at] + b_ref[:, at]))
-        o, state_ref[h] = _chunk(q, k, v_ref[:, at].astype(jnp.float32), g,
-                                 beta_ref[:, h:h + 1], state_ref[h], block)
+    cols = [slice(h * d, (h + 1) * d) for h in range(heads)]  # a head's columns of every operand
+    q, k = ([u[:, at].astype(jnp.float32) for at in cols] for u in (q_ref, k_ref))
+    q = [u * jax.lax.rsqrt(jnp.sum(u * u, axis=-1, keepdims=True) + L2_EPS) * d ** -0.5 for u in q]
+    k = [u * jax.lax.rsqrt(jnp.sum(u * u, axis=-1, keepdims=True) + L2_EPS) for u in k]
+    g = [lower * jax.nn.sigmoid(ea_ref[:, at] * (f_ref[:, at] + b_ref[:, at])) for at in cols]
+    done = _chunks(q, k, [v_ref[:, at].astype(jnp.float32) for at in cols], g,
+                   [beta_ref[:, h:h + 1] for h in range(heads)],
+                   [state_ref[h] for h in range(heads)], _constants(q_ref.shape[0]), block)
+    for h, (at, (o, state)) in enumerate(zip(cols, done)):
+        state_ref[h] = state
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * gain
         o_ref[:, at] = (o * jax.nn.sigmoid(z_ref[:, at].astype(jnp.float32))).astype(o_ref.dtype)
 

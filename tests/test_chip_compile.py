@@ -561,6 +561,14 @@ PINNED_STEPS = {
     "kimi_k2_prefill_epix10k2m": "da84d0e21bf0ca69bc00745c6d74d6a290b8f986ee45602e3076dc0a6e764708",
     "keye_vl2_prefill_epix10k2m": "e71a0690926768eb794d3c96c9ba607bcd9baab98ff6cda06f37b36147d5e090",
     "lfm2_8b_a1b_prefill_epix10k2m": "8b0c0caa3bd0b7156f13ff2f2d29e892c90b50522d24d3595d5d719fbc4cb085",
+    # pinned in PR 56, both hashed on PR 55's tree first and NEITHER moved by it: laguna's runs
+    # nothing of `ops/delta_rule.py`; ling3's does, and PR 56 rewrote that kernel's body (the heads
+    # of a grid step side by side), but a Mosaic kernel's body rides in its call's `backend_config`,
+    # which this test blanks (it carries file names and line numbers): the pin holds what is
+    # AROUND a kernel (its operands, their shapes and types, its grid's result) and no kernel's
+    # body. A kernel's own cache entry follows its body and its file's path
+    "ling3_flash_prefill_epix10k2m": "7ccf30c1fc0daaf22777b5332f2eab8dcb0c84756be65add4bcdd4ce4029f008",
+    "laguna_s21_prefill_epix10k2m": "2bc8bf337d0927163241159bd19544e69bcef76f5fa162bfa47307851a0a0209",
 }
 
 

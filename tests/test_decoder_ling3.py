@@ -156,16 +156,24 @@ def _recurrence(arrays, seq_len, heads, lower, eps, **fault):
     return o.reshape(t, heads * d), g
 
 
-@pytest.mark.parametrize("chunk", [8, 16, 48])
-@pytest.mark.parametrize("case", ["spread_decay", "decay_at_the_bound", "decay_near_none",
-                                  "beta_near_0", "beta_near_1", "identical_keys"])
-def test_the_chunked_form_is_the_recurrence_and_not_an_approximation(case, chunk, float32_products):
+# sequences of 48 rows in chunks of 8, 16 and 48, and (PR 56) of 256 in the SERVED chunk of 128:
+# eight row blocks whose keys come one from the other, seven levels of the inverse
+CHUNKED = [(case, chunk, 48) for chunk in (8, 16, 48)
+           for case in ("spread_decay", "decay_at_the_bound", "decay_near_none", "beta_near_0",
+                        "beta_near_1", "identical_keys")]
+CHUNKED += [(case, 128, 256) for case in ("spread_decay", "decay_at_the_bound", "identical_keys")]
+
+
+@pytest.mark.parametrize("case,chunk,seq", CHUNKED, ids=[f"{c}-{n}" for c, n, _ in CHUNKED])
+def test_the_chunked_form_is_the_recurrence_and_not_an_approximation(case, chunk, seq,
+                                                                     float32_products):
     """Two sequences of 48 in one array (a boundary inside it), in chunks of
-    8 (six a sequence), 16 (three) and 48 (one chunk of three blocks): with
-    float32 products the kernel IS the token-by-token recurrence to float32's
-    own rounding, so the state, the running sums and the exponents are float32
-    and no term is dropped, whatever the decay and the step size."""
-    arrays, sizes = _kernel_case(case)
+    8 (six a sequence), 16 (three) and 48 (one chunk of three blocks), and two
+    of 256 in chunks of 128 (one group of four heads, two chunks a sequence):
+    with float32 products the kernel IS the token-by-token recurrence to
+    float32's own rounding, so the state, the running sums and the exponents
+    are float32 and no term is dropped, whatever the decay and the step size."""
+    arrays, sizes = _kernel_case(case, seq=seq)
     with jax.default_matmul_precision("highest"):
         got = dr.gated_delta_rule(*arrays, chunk=chunk, **sizes)
         want, g = _recurrence(arrays, **sizes)
@@ -173,15 +181,48 @@ def test_the_chunked_form_is_the_recurrence_and_not_an_approximation(case, chunk
         assert float(g.max()) < -4.999
     if case in ("decay_near_none", "identical_keys"):
         assert float(g.min()) > -1e-5
-    assert dr.chunk_rows(48, chunk) == chunk
+    assert dr.chunk_rows(seq, chunk) == chunk
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4, rtol=0)
     # and a state that crossed the sequences' boundary, or stopped at a chunk's, is another result
-    leaked, _ = _recurrence(arrays, **{**sizes, "seq_len": 96})
+    leaked, _ = _recurrence(arrays, **{**sizes, "seq_len": 2 * seq})
     seen = {"decay_at_the_bound": 1e-4, "identical_keys": 1e-3}.get(case, 1e-2)
-    assert float(jnp.abs(leaked[48:] - want[48:]).max()) > seen
-    if chunk < 48 and seen == 1e-2:
+    assert float(jnp.abs(leaked[seq:] - want[seq:]).max()) > seen
+    if chunk < seq and seen == 1e-2:
         dropped, _ = _recurrence(arrays, carry=chunk, **sizes)
         assert float(jnp.abs(dropped - want).max()) > 1e-2
+
+
+def test_a_block_s_keys_rescaled_from_the_block_before_are_the_keys_scaled_anew():
+    """``_keys_of_blocks`` over a chunk of 128 rows in 8 blocks, a channel's
+    decay from -0.01 to -5 a row: block ``I`` reads rows ``0 .. lo + 16``
+    times ``e^(G_lo - G_j)``. Its own rows and the block's before are made
+    so; the older ones went through up to six factors of at most 1, and are
+    the direct form to float32's rounding. At -5 a row the direct form
+    underflows two blocks back, and there both are 0."""
+    rng = np.random.default_rng(56)
+    c, d, block = 128, 16, dr.BLOCK
+    k = rng.standard_normal((c, d))
+    k = jnp.asarray(k / np.linalg.norm(k, axis=1, keepdims=True), jnp.float32)
+    gam = jnp.asarray(np.arange(1, c + 1)[:, None] * -np.linspace(0.01, 5.0, d)[None], jnp.float32)
+    got = dr._keys_of_blocks(k, gam, block)
+    assert [u.shape for u in got] == [(lo + block, d) for lo in range(0, c, block)]
+    underflowed = rescaled = 0
+    for lo, keys in zip(range(0, c, block), got):
+        exact = np.asarray(k[:lo + block], np.float64) * np.exp(
+            np.asarray(gam[lo:lo + 1], np.float64) - np.asarray(gam[:lo + block], np.float64))
+        direct = np.asarray(k[:lo + block] * jnp.exp(jnp.minimum(gam[lo:lo + 1] - gam[:lo + block],
+                                                                 dr._CAP)))
+        keys = np.asarray(keys)
+        assert np.isfinite(keys).all() and np.abs(keys[:lo]).max(initial=0.0) <= 1.0
+        # the block's own rows and the block's before ARE the direct form
+        np.testing.assert_array_equal(keys[max(lo - block, 0):], direct[max(lo - block, 0):])
+        # either form is 4e-6 from the exact value here: an exponent is a difference of two G
+        np.testing.assert_allclose(keys, direct, rtol=2e-5, atol=1e-37)
+        np.testing.assert_allclose(keys, exact, rtol=2e-5, atol=1e-37)
+        assert not keys[direct == 0.0].any()
+        underflowed += int((direct[:, -1] == 0.0).sum())
+        rescaled += int((np.abs(keys[:max(lo - block, 0)]) > 1e-30).sum())
+    assert underflowed > 100 and rescaled > 1000  # both kinds of entry were there to compare
 
 
 @pytest.mark.parametrize("case", ["spread_decay", "decay_at_the_bound", "beta_near_1",

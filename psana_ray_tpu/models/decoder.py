@@ -6,10 +6,12 @@ LayerNorm, GELU and a position table). This module builds the text
 decoder of a language model from the keys of its published
 ``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B, DeepSeek-V3's
 block as Kimi-K2 spells it, DeepSeek-V3.2's, Ling-3.0's hybrid,
-Laguna-S-2.1's windowed and full layers):
+Laguna-S-2.1's windowed and full layers, Granite-4.0-H's state-space hybrid):
 
     x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
-    x  -> x + ff(rms(x))                      four kinds, its ff one of two
+    x  -> x + ff(rms(x))                      five kinds, its ff one of two
+
+(a branch times ``residual_multiplier`` before it is added, where a model has one).
 
 A layer's OPERATOR (``layer_types``) is grouped-query attention (a
 per-head RMS norm on q and k where the model has one, then the rotary,
@@ -20,7 +22,10 @@ type's rotary, and where the configuration says so a sigmoid gate a head
 on the output); or a gated short convolution (:func:`gated_short_conv`);
 or LINEAR attention (:func:`linear_attention`: the gated delta rule with a
 decay per channel, a float32 state a head carried along the sequence:
-``ops/delta_rule.py``);
+``ops/delta_rule.py``); or a STATE-SPACE layer (:func:`state_space`:
+Mamba-2's selective scan, one scalar decay a head and token, keys and
+queries shared by all heads, a float32 state a head carried along the
+sequence: ``ops/ssd.py``);
 or, where the configuration has a ``kv_lora_rank``, LATENT attention
 (:func:`latent_attention`: queries of full rank or through a normed low
 rank, keys and values decompressed per head from one normed latent, one
@@ -73,6 +78,7 @@ import numpy as np
 
 from psana_ray_tpu.ops.delta_rule import CHUNK, chunk_rows, gated_delta_rule
 from psana_ray_tpu.ops.short_conv import gated_conv_taps
+from psana_ray_tpu.ops.ssd import scan_rows, ssd_scan
 from psana_ray_tpu.parallel import sparse_attention as sa
 from psana_ray_tpu.parallel.moe import dropless_moe, goes_ahead, rows_ahead
 
@@ -100,10 +106,12 @@ PAIR_STATS = (
     "attn_pairs_selected_total",  # (query, key) pairs attended, over the layers with a selection
     "attn_pairs_causal_total",    # pairs at or below the diagonal in those layers
 )
-# and two after those ten (the two above 0 where nothing selects), where layers are LINEAR
+# and two after those ten (the two above 0 where nothing selects), where layers CARRY A STATE
+# along the sequence: LINEAR (the delta rule) or, since PR 57, MAMBA (the state-space scan). No new
+# name and no new length: a step with either kind returns these twelve
 LINEAR_STATS = (
-    "linear_attn_tokens_total",  # tokens through a linear-attention layer, over such layers
-    "linear_attn_chunks_total",  # chunks of the delta rule's kernel: head-sequences x chunks a layer
+    "linear_attn_tokens_total",  # tokens through a layer that carries a state, over such layers
+    "linear_attn_chunks_total",  # chunks of the layer's kernel: head-sequences x chunks a layer
 )
 # and one after those twelve (the groups above 0 where the step has none), from a holder of so LARGE
 # a share that a pass goes ahead of its held rows' loop (`moe.rows_ahead`)
@@ -113,6 +121,7 @@ AHEAD_STATS = (
 # layer_types, as config.json spells them
 ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
 SLIDING = "sliding_attention"  # grouped-query attention over the band t - sliding_window < j <= t
+MAMBA = "mamba"  # Mamba-2's state-space layer (granitemoehybrid's spelling; its "attention" is ATTENTION)
 LATENT = "latent_attention"  # what an ATTENTION layer is under a kv_lora_rank
 
 
@@ -187,6 +196,20 @@ class DecoderConfig:
     # and values are linear_head_dim wide, the log-decay a token in (linear_decay_floor, 0)
     linear_head_dim: int = 0
     linear_decay_floor: float = 0.0
+    # a MAMBA layer's scan: ssm_heads heads of ssm_head_dim channels over a state ssm_state wide
+    # (one group: B and C shared by all heads); conv_bias: its convolution adds a bias per channel
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    conv_bias: bool = False
+    # Granite's four multipliers, each defaulting to what every other model has: a branch's output
+    # times residual_multiplier before it is added, the embedded rows times embedding_multiplier,
+    # the softmax scale (None: head_dim ** -0.5), the logits over logits_scaling
+    residual_multiplier: float = 1.0
+    embedding_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
+    rotary: bool = True  # False (position_embedding_type nope): q and k are not turned at all
     tie_embedding: bool = False  # the output head is the embedding table
     rope_yarn: Optional[Yarn] = None  # YaRN-blended rotary frequencies and the softmax's mscale^2
     # latent attention (kv_lora_rank 0: grouped-query attention): queries through a normed
@@ -225,7 +248,8 @@ class DecoderConfig:
     causal_q_tile: int = 1088
     causal_kv_tile: int = 1088
     # rows a chunk of the delta rule's kernel (the largest whole-tile divisor of S under it): on
-    # the v5e at 4 x 8,704 tokens 128 rows 7.5 ms a layer, 64 rows 10.1 (my chip runs, PR 56)
+    # the v5e at 4 x 8,704 tokens 128 rows 7.5 ms a layer, 64 rows 10.1 (my chip runs, PR 56). (The
+    # state-space scan's chunk is its own, from S alone: `ops/ssd.scan_rows`)
     linear_chunk: int = CHUNK
     # experts (num_experts 0: a dense gated MLP of intermediate_size)
     num_experts: int = 0
@@ -267,10 +291,30 @@ class DecoderConfig:
         return bool(self.indexer_heads) and bool(self.kv_lora_rank)
 
     @property
+    def softmax_scale(self) -> float:
+        """Of grouped-query attention's scores, before YaRN's ``mscale**2``."""
+        return self.head_dim ** -0.5 if self.attention_multiplier is None else self.attention_multiplier
+
+    @property
+    def stream_dtype(self):
+        """The type of the stream between the layers and of the embedded rows
+        that start it: float32 where branches are added at a
+        ``residual_multiplier``, else ``None`` (the weights' own, bf16). A
+        branch then comes in at a fraction of its size, twice a layer: were
+        the stream bf16, ITS rounding at every addition (and the rounding of
+        the embedded rows times ``embedding_multiplier``) and not the
+        products' would be the step's error (Granite-4.0-H's 40 layers read
+        2.6-5.7 times what rounding every operand to bf16 costs; float32,
+        1.0-1.5). Every product's operands stay the weights' type; the stream
+        is 71 MB at 8,704 x 2,048, 1% of a layer's traffic."""
+        return jnp.float32 if self.residual_multiplier != 1.0 else None
+
+    @property
     def has_linear(self) -> bool:
-        """Linear-attention layers: the step then counts :data:`SHARE_STATS`,
-        :data:`PAIR_STATS` and :data:`LINEAR_STATS` too."""
-        return LINEAR in self.layer_types
+        """Layers that carry a state (linear attention, or a state-space
+        scan): the step then counts :data:`SHARE_STATS`, :data:`PAIR_STATS`
+        and :data:`LINEAR_STATS` too."""
+        return LINEAR in self.layer_types or MAMBA in self.layer_types
 
     @property
     def has_window(self) -> bool:
@@ -317,7 +361,7 @@ class DecoderConfig:
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Six spellings are read:
+        ``experts_held`` and ``tie_embedding``. Seven spellings are read:
         Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
         ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
         (``norm_eps``, ``layer_types``, ``num_dense_layers``,
@@ -349,8 +393,18 @@ class DecoderConfig:
         the output gate, on grouped-query attention; no per-head q / k
         norm; ``mlp_only_layers``, ``shared_expert_intermediate_size`` and
         ``moe_routed_scaling_factor``; the router's score, which its
-        config.json does not name, under the file's own ``router_scoring``).
-        Where a
+        config.json does not name, under the file's own ``router_scoring``);
+        and Granite-4.0-H's (``granitemoehybrid``), each key read for
+        itself whatever the file's ``model_type``: ``layer_types`` entries
+        ``mamba`` and ``attention``, the second read as ``full_attention``;
+        ``mamba_n_heads`` with ``mamba_d_head``, ``mamba_d_state``,
+        ``mamba_d_conv``, ``mamba_conv_bias``: the state-space layers, one
+        group; ``residual_multiplier``, ``embedding_multiplier``,
+        ``logits_scaling`` (1 where a file has none) and
+        ``attention_multiplier`` (the softmax scale, and no per-head q / k
+        norm); ``position_embedding_type: nope``: no rotary;
+        ``shared_intermediate_size``: the always-on MLP, which with
+        ``num_local_experts`` 0 is the whole feed-forward). Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -367,12 +421,31 @@ class DecoderConfig:
         held_key = "num_experts" if "num_experts" in m else "n_routed_experts"
         n_exp = int(m.get("router_experts", m.get(held_key, 0)))
         n_layers, heads = int(m["num_hidden_layers"]), int(m["num_attention_heads"])
-        layer_types = tuple(m.get("layer_types", ()))
+        # Granite-4.0-H's spelling of a full causal layer is "attention"
+        layer_types = tuple(ATTENTION if op == "attention" else op
+                            for op in m.get("layer_types", ()))
+        ssm = {}
+        if "mamba_n_heads" in m:  # Mamba-2's state-space layers
+            if int(m.get("mamba_n_groups", 1)) != 1:
+                raise ValueError("more than one group of B and C (mamba_n_groups) is not built")
+            ssm = dict(ssm_heads=int(m["mamba_n_heads"]), ssm_head_dim=int(m["mamba_d_head"]),
+                       ssm_state=int(m["mamba_d_state"]),
+                       conv_bias=bool(m.get("mamba_conv_bias", False)))
+        if "shared_intermediate_size" in m and int(m.get("num_local_experts") or 0):
+            raise ValueError("experts (num_local_experts) beside the always-on MLP of "
+                             "shared_intermediate_size are not built")
+        # no file has a key for the per-head norm of q and k: the files that state their softmax
+        # scale (Granite's) or a rotary a layer type (Laguna's) are of models without one
+        scale = m.get("attention_multiplier")
+        qk_norm = scale is None and "rope_parameters" not in m
         if layer_types and (len(layer_types) != n_layers
-                            or set(layer_types) - {ATTENTION, SLIDING, CONV, LINEAR}):
+                            or set(layer_types) - {ATTENTION, SLIDING, CONV, LINEAR}
+                            - ({MAMBA} if ssm else set())):
             raise ValueError(f"layer_types {layer_types} does not name {n_layers} layers' "
                              f"operators, each {ATTENTION!r}, {SLIDING!r}, {CONV!r} or {LINEAR!r}")
         latent = int(m.get("kv_lora_rank") or 0)
+        if latent and m.get("position_embedding_type") == "nope":
+            raise ValueError("latent attention without a rotary is not built")
         head_dim = int(m.get("head_dim") or int(m["hidden_size"]) // heads)
         by_type = m.get("rope_parameters")  # Laguna's: a rotary a layer type
         window = {}
@@ -390,8 +463,7 @@ class DecoderConfig:
                           rope_partial_dim=0 if turned == head_dim else turned,
                           sliding_rope_theta=float(sliding.get("rope_theta", theta)),
                           heads_per_layer=tuple(
-                              int(h) for h in m.get("num_attention_heads_per_layer", ())),
-                          qk_norm=False)
+                              int(h) for h in m.get("num_attention_heads_per_layer", ())))
             if len(window["heads_per_layer"]) not in (0, n_layers):
                 raise ValueError(f"num_attention_heads_per_layer names {len(window['heads_per_layer'])} "
                                  f"layers' heads, not {n_layers}")
@@ -436,8 +508,14 @@ class DecoderConfig:
             rms_eps=float(m["rms_norm_eps"] if "rms_norm_eps" in m else m["norm_eps"]),
             rope_theta=theta,
             mrope_section=tuple(int(v) for v in mrope) if mrope else None,
-            layer_types=layer_types, **window,
-            conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size", 3))),
+            layer_types=layer_types, **window, **ssm, qk_norm=qk_norm,
+            residual_multiplier=float(m.get("residual_multiplier", 1.0)),
+            embedding_multiplier=float(m.get("embedding_multiplier", 1.0)),
+            attention_multiplier=None if scale is None else float(scale),
+            logits_scaling=float(m.get("logits_scaling", 1.0)),
+            rotary=m.get("position_embedding_type") != "nope",
+            conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size",
+                                                      m.get("mamba_d_conv", 3)))),
             linear_head_dim=int(m["head_dim"]) if linear else 0,
             linear_decay_floor=float(m["kda_lower_bound"]) if linear else 0.0,
             tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
@@ -463,7 +541,7 @@ class DecoderConfig:
             shared_experts=int(m.get("n_shared_experts") or m.get("num_shared_experts")
                                or int(m.get("shared_expert_intermediate_size", 0)) // max(width, 1)
                                ) if n_exp else 0,
-            intermediate_size=int(m.get("intermediate_size", 0)),
+            intermediate_size=int(m.get("shared_intermediate_size", m.get("intermediate_size", 0))),
             patch=int(m.get("patch", 8)),
         )
 
@@ -507,6 +585,21 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                  "decay_a": between(-0.7, 0.7, cfg.num_heads), "decay_b": between(-6.0, 2.0, wide),
                  "w_beta": w(d, cfg.num_heads), "w_z": w(d, wide),
                  "o_norm": gain(cfg.linear_head_dim), "wo": w(wide, d), "norm2": gain(d)}
+        elif op == MAMBA:
+            wide = cfg.ssm_heads * cfg.ssm_head_dim
+            conv = wide + 2 * cfg.ssm_state  # [x | B | C]: what the convolution passes over
+            # Mamba-2's published initialiser: A = -U(1, 16), the step's bias the inverse softplus
+            # of a log-uniform step in [0.001, 0.1] (log-decays from -0.001 to -1.6 a token), D 1;
+            # taps of order 1 (ling3's) and a bias in +-0.5, so that the SiLU is not in its linear part
+            first = jnp.exp(between(np.log(0.001), np.log(0.1), cfg.ssm_heads))
+            p = {"norm1": gain(d), "w_in": w(d, wide + conv + cfg.ssm_heads),
+                 "conv_w": w(conv, cfg.conv_taps, scale=0.5),
+                 "dt_bias": first + jnp.log(-jnp.expm1(-first)),
+                 "a_log": jnp.log(between(1.0, 16.0, cfg.ssm_heads)),
+                 "d_skip": jnp.ones((cfg.ssm_heads,), jnp.float32), "ssm_norm": gain(wide),
+                 "w_out": w(wide, d), "norm2": gain(d)}
+            if cfg.conv_bias:
+                p["conv_b"] = between(-0.5, 0.5, conv).astype(dtype)
         elif op == LATENT:
             heads, rq, rkv = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
             if rq:
@@ -523,8 +616,15 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 p.update(_index_params(cfg, w, gain, rq))
         else:  # grouped-query attention, full or windowed, this layer's own count of query heads
             heads = cfg.heads(i)
+            # under a STATED softmax scale W_q and W_k are drawn so that the scores spread as they
+            # do under head_dim ** -0.5 (the factor is 1 there): at Granite's 1 / 64, normal(0,
+            # 0.02) leaves scores of a tenth and the softmax a mean over the causal keys, whatever
+            # scales or turns q and k (a trained model's scores are sharp: that is what the scale
+            # was trained with)
+            sharp = 0.02 * (hd ** -0.5 / cfg.softmax_scale) ** 0.5
             p = {
-                "norm1": gain(d), "wq": w(d, heads * hd), "wk": w(d, cfg.num_kv_heads * hd),
+                "norm1": gain(d), "wq": w(d, heads * hd, scale=sharp),
+                "wk": w(d, cfg.num_kv_heads * hd, scale=sharp),
                 "wv": w(d, cfg.num_kv_heads * hd), "wo": w(heads * hd, d), "norm2": gain(d),
             }
             if cfg.qk_norm:
@@ -618,13 +718,14 @@ def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
     """Grouped-query attention's ``(a, q, k, v)`` from ``x [T, D]``, each
     ``[T, heads * head_dim]``, the query heads as many as THIS layer's
     ``wq`` has, and where the output is gated ``sigmoid(a W_G) [T, heads]``
-    float32 after them. ``angles [T, pairs]`` turn the leading ``2 * pairs``
+    float32 after them. ``angles [T, pairs]`` (``None`` where the model has no
+    rotary: q and k then pass as their products left them) turn the leading ``2 * pairs``
     components of a head (the layer type's rotary: all of a head, or the
     full layers' partial one) and the rest passes as it is; under YaRN (the
     full layers' alone: a ``windowed`` layer's rotary is plain) the turned
     part is multiplied by ``rotary_scale``, the cosines' and sines' factor."""
     s = x.shape[0]
-    dt = x.dtype
+    dt = p["wq"].dtype  # the activations' type: x's own, but where the stream is kept in float32
     a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
     q = _mm(a, p["wq"]).reshape(s, -1, cfg.head_dim)
     if cfg.qk_norm:
@@ -633,10 +734,15 @@ def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
     if cfg.qk_norm:
         k = rms_norm(k, p["k_norm"], cfg.rms_eps)
     yarn = None if windowed else cfg.rope_yarn
-    turn = dict(angles=angles, width=2 * angles.shape[-1], scale=yarn.rotary_scale if yarn else 1.0)
     # the softmax scale (with YaRN's mscale^2) rides on q
-    q = _turn_leading(q, **turn) * (cfg.head_dim ** -0.5 * (yarn.softmax_scale if yarn else 1.0))
-    k = _turn_leading(k, **turn)
+    scale = cfg.softmax_scale * (yarn.softmax_scale if yarn else 1.0)
+    if angles is None:
+        q = q * scale
+    else:
+        turn = dict(angles=angles, width=2 * angles.shape[-1],
+                    scale=yarn.rotary_scale if yarn else 1.0)
+        q = _turn_leading(q, **turn) * scale
+        k = _turn_leading(k, **turn)
     v = _mm(a, p["wv"])
     out = (a, q.reshape(s, -1).astype(dt), k.reshape(s, -1).astype(dt), v.astype(dt))
     if cfg.attn_gate:
@@ -824,16 +930,18 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
     return x, live, causal
 
 
-def conv_silu(u, taps_w, seq_len: int):
-    """``silu(conv(u))`` on ``u [T, C]`` (whole sequences of ``seq_len``
+def conv_silu(u, taps_w, seq_len: int, bias=None):
+    """``silu(conv(u) + bias)`` on ``u [T, C]`` (whole sequences of ``seq_len``
     rows): a causal depthwise convolution, tap ``j`` of ``taps_w [C, taps]``
-    on ``u[t - (taps - 1) + j]``, zeros before each sequence's first row, no
-    bias; float32 inside, ``u``'s type out."""
+    on ``u[t - (taps - 1) + j]``, zeros before each sequence's first row,
+    ``bias [C]`` where the model has one; float32 inside, ``u``'s type out."""
     t, c = u.shape
     taps = taps_w.shape[1]
     w = taps_w.astype(jnp.float32)
     padded = jnp.pad(u.reshape(t // seq_len, seq_len, c), ((0, 0), (taps - 1, 0), (0, 0)))
     acc = sum(w[:, j] * padded[:, j:j + seq_len].astype(jnp.float32) for j in range(taps))
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
     return jax.nn.silu(acc).astype(u.dtype).reshape(t, c)
 
 
@@ -875,6 +983,65 @@ def linear_attention(p, x, batch: int, cfg: DecoderConfig):
         return jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(x, o, p["wo"])
 
 
+def _onto(x, o, wo, by: float):
+    """``x + by * (o W_o)``: a branch under its residual multiplier, which
+    rides in the product's epilogue; the sum is float32, and stays so where
+    the stream is (``DecoderConfig.stream_dtype``)."""
+    return (x + by * _mm(o, wo)).astype(x.dtype)
+
+
+def _ssm_projections(p, x, cfg: DecoderConfig):
+    """``x [T, D]`` -> ``(z [T, H*P]`` (the gate's), ``[x | B | C] [T, H*P +
+    2*N]`` before their convolution, ``dt [T, H]`` float32 (the step's
+    pre-activation: a log-decay is summed over a chunk's rows, so it is
+    never rounded to bf16)): ``W_in``'s COLUMNS are cut, not the product
+    (:func:`_latent_projections` says why), so each array leaves its own
+    product in the type and layout the next pass reads."""
+    w = p["w_in"]
+    dt = w.dtype  # the activations' type (the stream x itself: `cfg.stream_dtype`)
+    wide = cfg.ssm_heads * cfg.ssm_head_dim
+    conv = wide + 2 * cfg.ssm_state
+    a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
+    return (_mm(a, w[:, :wide]).astype(dt), _mm(a, w[:, wide:wide + conv]).astype(dt),
+            _mm(a, w[:, wide + conv:]))
+
+
+def state_space(p, x, batch: int, cfg: DecoderConfig):
+    """Mamba-2's layer, as Granite-4.0-H has it, on ``x [B*S, D]`` -> ``x +
+    residual_multiplier * Op``: with ``a = rms(x)``, ``[z | xBC | dt] = a
+    W_in``; ``xBC`` through a causal depthwise convolution of ``conv_taps``
+    taps with a bias and a SiLU (:func:`conv_silu`; zeros before each
+    sequence); ``[x | B | C] = xBC``, the selective scan over ``ssm_heads``
+    heads with ONE ``B`` and ``C`` for all (the state starts at 0 with every
+    sequence), the skip ``D x``, the gate ``silu(z)`` and THEN the RMS norm
+    over all of a token's channels; then ``W_out``. No rotary, no bias in
+    the products. Under the scopes ``proj`` (the norm, ``W_in``'s three
+    products, ``W_out``), ``conv`` (the convolution, its bias and SiLU: one
+    pass over ``[T, H*P + 2*N]``) and ``ssd`` (the step's softplus, the
+    decays, the scan, the skip, the gate and the norm: ONE kernel,
+    ``ops/ssd.ssd_scan``). Each part is jitted by NAME: the layers of a
+    model trace and lower once, however many they are."""
+    s = x.shape[0] // batch
+    with jax.named_scope("proj"):
+        z, xbc, dt = jax.jit(_ssm_projections, static_argnums=2)(p, x, cfg)
+    with jax.named_scope("conv"):
+        xbc = jax.jit(conv_silu, static_argnums=2)(xbc, p["conv_w"], s, p.get("conv_b"))
+    with jax.named_scope("ssd"):
+        o = ssd_scan(xbc, z, dt, p["dt_bias"], p["a_log"], p["d_skip"], p["ssm_norm"], seq_len=s,
+                     heads=cfg.ssm_heads, state=cfg.ssm_state, eps=cfg.rms_eps)
+    with jax.named_scope("proj"):
+        return jax.jit(_onto, static_argnums=3)(x, o, p["w_out"], cfg.residual_multiplier)
+
+
+def _mlp_onto(p, x, cfg: DecoderConfig):
+    """``x + residual_multiplier * MLP(rms(x))``, the dense feed-forward of
+    a model with a residual multiplier, jitted by name (as :func:`state_space`'s parts)."""
+    dt = p["w_gate"].dtype
+    b = rms_norm(x, p["norm2"], cfg.rms_eps).astype(dt)
+    h = (jax.nn.silu(_mm(b, p["w_gate"])) * _mm(b, p["w_up"])).astype(dt)
+    return _onto(x, h, p["w_down"], cfg.residual_multiplier)
+
+
 def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window: int = 0):
     """The attention operator on ``x [B*S, D]`` -> ``(x + Op, live,
     causal)``, the last two the layer's statistics tiles. Each part is a
@@ -914,6 +1081,8 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
     with jax.named_scope("proj"):
         if gate:
             x = jax.jit(gated)(x, o, gate[0], p["wo"])
+        elif cfg.residual_multiplier != 1.0:
+            x = jax.jit(_onto, static_argnums=3)(x, o, p["wo"], cfg.residual_multiplier)
         else:
             x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(x, o, p["wo"])
     return x, live, causal
@@ -926,7 +1095,8 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     and :data:`PAIR_STATS`, with linear layers :data:`LINEAR_STATS` after
     them, and last :data:`AHEAD_STATS` where a pass goes ahead of the held
     rows' loop: ``cfg.layer_stats`` in all). ``kind`` is ``cfg.layer_kind(i)``: the operator
-    runs under ``conv``, linear attention's, latent attention's or attention's scopes, the
+    runs under ``conv``, linear attention's, the state-space layer's, latent attention's or
+    attention's scopes, the
     feed-forward under ``moe`` (the routed experts), ``shared_expert``
     (beside them, added once) or ``mlp`` (dense)."""
     op, experts = kind
@@ -936,6 +1106,8 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
             x = jax.jit(gated_short_conv, static_argnums=(2, 3))(p, x, batch, cfg)
     elif op == LINEAR:
         x = linear_attention(p, x, batch, cfg)
+    elif op == MAMBA:
+        x = state_space(p, x, batch, cfg)
     elif op == LATENT:
         x, live, causal = latent_attention(p, x, angles, batch, cfg, idx_angles)
     else:
@@ -966,7 +1138,12 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
             out = onto + y
             return (out, jnp.max(tokens), jnp.sum(tokens)) if share else (out, jnp.max(tokens))
 
-        x, busiest, *held = jax.jit(mlp)(p, x, *given)
+        if not experts and cfg.residual_multiplier != 1.0:
+            dense = {k: p[k] for k in ("norm2", "w_gate", "w_up", "w_down")}
+            x = jax.jit(_mlp_onto, static_argnums=2)(dense, x, cfg)
+            busiest, held = jnp.zeros((), jnp.int32), ()
+        else:
+            x, busiest, *held = jax.jit(mlp)(p, x, *given)
     even = x.shape[0] * cfg.experts_per_token / cfg.num_experts if experts else 0.0
     stats = [busiest.astype(jnp.float32), jnp.float32(even),
              jnp.asarray(live, jnp.float32), jnp.float32(causal)]
@@ -984,12 +1161,14 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
                   jnp.float32(through * (s * (s + 1) // 2))]
     if cfg.has_linear:
         s = x.shape[0] // batch
-        through = op == LINEAR
         if not cfg.counts_pairs:  # PAIR_STATS' places: nothing selects
             stats += [jnp.float32(0), jnp.float32(0)]
-        stats += [jnp.float32(through * x.shape[0]),
-                  jnp.float32(through * batch * cfg.num_heads
-                              * (s // chunk_rows(s, cfg.linear_chunk)))]
+        chunks = 0  # the layer's kernel's head-sequences x its chunks: the delta rule's, or the scan's
+        if op == LINEAR:
+            chunks = batch * cfg.num_heads * (s // chunk_rows(s, cfg.linear_chunk))
+        elif op == MAMBA:
+            chunks = batch * cfg.ssm_heads * (s // scan_rows(s))
+        stats += [jnp.float32(x.shape[0] if chunks else 0), jnp.float32(chunks)]
     if cfg.rows_go_ahead:
         # the places of the groups the step has not
         stats += [jnp.float32(0)] * (cfg.layer_stats - len(AHEAD_STATS) - len(stats))
@@ -1010,7 +1189,7 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     s = x.shape[0] // batch
     # (under rope_parameters: the FULL layers' table, over the part of a head their rotary turns)
     angles = rotary_angles(pos, cfg.rope_theta, cfg.rope_dim // 2, cfg.mrope_section,
-                           cfg.rope_yarn)
+                           cfg.rope_yarn) if cfg.rotary else None  # None: nothing is turned
     idx_angles = None
     if cfg.indexer_heads:
         # the indexer's vectors turn with the sequence index alone (under YaRN by its frequencies:
@@ -1022,8 +1201,10 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     by_op = {SLIDING: rotary_angles(pos, cfg.sliding_rope_theta or cfg.rope_theta,
                                     cfg.head_dim // 2)} if cfg.has_window else {}
     if batch != 1:
-        angles = jnp.tile(angles, (batch, 1))
+        angles = angles if angles is None else jnp.tile(angles, (batch, 1))
         by_op = {op: jnp.tile(table, (batch, 1)) for op, table in by_op.items()}
+    if cfg.stream_dtype:
+        x = x.astype(cfg.stream_dtype)
     stats = jnp.zeros((cfg.layer_stats,), jnp.float32)
     for i, p in enumerate(params["layers"]):
         kind = cfg.layer_kind(i)
@@ -1035,14 +1216,18 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     return x, jnp.concatenate([stats, served])
 
 
-def embed(params, patches, prompt_ids):
+def embed(params, patches, prompt_ids, scale: float = 1.0, dtype=None):
     """``patches [N, patch_dim]`` through the linear patch embedding, then
-    the prompt's rows of the embedding table: ``[N + T, D]``."""
+    the prompt's rows of the embedding table: ``[N + T, D]`` in ``dtype``
+    (``cfg.stream_dtype``; None: the weights' type), both times ``scale`` (a
+    model's ``embedding_multiplier``) in float32 where it is not 1."""
     dt = params["patch"].dtype
-    return jnp.concatenate([
-        _mm(patches.astype(dt), params["patch"]).astype(dt),
-        jnp.take(params["embed"], prompt_ids, axis=0),
-    ])
+
+    def rows(r):
+        return (r if scale == 1.0 else r.astype(jnp.float32) * scale).astype(dtype or dt)
+
+    return jnp.concatenate([rows(_mm(patches.astype(dt), params["patch"])),
+                            rows(jnp.take(params["embed"], prompt_ids, axis=0))])
 
 
 def head_params(params) -> dict:
@@ -1053,12 +1238,15 @@ def head_params(params) -> dict:
 
 
 def logits_of(params, x, cfg: DecoderConfig):
-    """Final norm and output head on rows ``x [N, D]`` -> ``[N, V]`` float32."""
-    a = rms_norm(x, params["norm"], cfg.rms_eps).astype(x.dtype)
+    """Final norm and output head on rows ``x [N, D]`` -> ``[N, V]`` float32
+    (over ``logits_scaling`` where the model has one)."""
+    a = rms_norm(x, params["norm"], cfg.rms_eps).astype(params["norm"].dtype)
     if cfg.tie_embedding:
-        return jax.lax.dot_general(a, params["embed"], (((1,), (1,)), ((), ())),
-                                   preferred_element_type=jnp.float32)
-    return _mm(a, params["head"])
+        logits = jax.lax.dot_general(a, params["embed"], (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+    else:
+        logits = _mm(a, params["head"])
+    return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
 
 def frame_hidden(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, threshold: float):
@@ -1079,7 +1267,8 @@ def frame_hidden(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, thres
         x = fused_calibrate(frames, *calib, threshold=threshold, out_dtype=jnp.bfloat16)
     with jax.named_scope("embed"):
         x = jax.jit(lambda p, x, ids: jnp.concatenate(
-            [embed(p, frame, ids) for frame in patchify_panels(x, cfg.patch)]))(
+            [embed(p, frame, ids, cfg.embedding_multiplier, cfg.stream_dtype)
+             for frame in patchify_panels(x, cfg.patch)]))(
             {"patch": params["patch"], "embed": params["embed"]}, x, prompt_ids)
     return trunk(params, x, pos, cfg, batch)
 

@@ -400,7 +400,31 @@ def _ling3_conv():
     return (lambda u, w: conv_silu(u, w, LING3_S)), [S((rows, wide), BF16), S((wide, 4), BF16)], 0
 
 
+def _granite_ssd_scan():
+    """Mamba-2's selective scan at Granite-4.0-H-Micro's sizes: one sequence of
+    8,704 tokens, 64 heads of 64 over a state of 128, in chunks of 512 rows: ONE
+    kernel (the step's softplus, the decays, the scan, the skip, the gate, the
+    norm over all 4,096 channels), ``x``, ``B`` and ``C`` read in place as column
+    blocks of the array their convolution wrote."""
+    from psana_ray_tpu.ops.ssd import ssd_scan
+
+    def fn(xbc, z, dt, dt_bias, a_log, skip, gain):
+        return ssd_scan(xbc, z, dt, dt_bias, a_log, skip, gain, seq_len=8704, heads=64, state=128,
+                        eps=1e-5, interpret=False)
+
+    def one_kernel_and_no_copy_of_its_operands(text):
+        kernels = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text, re.M)
+        assert [k.split(".")[0] for k in kernels] == ["ssd_scan"], kernels
+        entry = text[text.index("ENTRY"):]
+        assert not re.search(r"= \w+\[8704,(4352|4096)\][^ ]* (copy|slice|fusion)\(", entry)
+
+    return fn, [S((8704, 4352), BF16), S((8704, 4096), BF16), S((8704, 64), F32), S((64,), F32),
+                S((64,), F32), S((64,), F32), S((4096,), BF16)], 1, \
+        one_kernel_and_no_copy_of_its_operands
+
+
 CASES = {
+    "granite_ssd_scan_8704x64x64x128": _granite_ssd_scan,
     "ling3_gated_delta_rule_4x8704x32x128": _ling3_delta_rule,
     "ling3_conv_silu_34816x12288": _ling3_conv,
     "dsv32_select_keys_8704x64x128": _dsv32_select,
@@ -569,6 +593,10 @@ PINNED_STEPS = {
     # body. A kernel's own cache entry follows its body and its file's path
     "ling3_flash_prefill_epix10k2m": "7ccf30c1fc0daaf22777b5332f2eab8dcb0c84756be65add4bcdd4ce4029f008",
     "laguna_s21_prefill_epix10k2m": "2bc8bf337d0927163241159bd19544e69bcef76f5fa162bfa47307851a0a0209",
+    # pinned in PR 57, which brought it: the six above were hashed on PR 56's tree first and none
+    # moved, though every one of them now traces `_projections`, `embed`, `logits_of` and `trunk`
+    # through the new fields' branches (taken in Python, before anything is traced)
+    "granite4_h_micro_prefill_epix10k2m": "3bf51190128b2aab64f95e6afe217682c5b35f672d3badb3a9179d9397645b37",
 }
 
 
@@ -655,6 +683,46 @@ def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_c
     assert all("/moe/" in line for line in calls
                if re.match(r"\s*%(gmm|rows_as_words|sum_counted_rows)", line))
     assert all("/kda/" in line for line in calls if re.match(r"\s*%gated_delta_rule", line))
+
+
+def test_the_granite_step_compiles_whole_with_its_kernels_under_the_scopes_a_trace_reads(
+        one_chip, monkeypatch):
+    """The whole served step of ``granite4_h_micro_prefill_epix10k2m`` at the
+    published sizes, ALL 40 layers and the whole vocabulary, compiled for the
+    described v5e (under half a minute: 36 of the layers are one function at
+    one shape, traced and lowered once): it fits the chip (weights 6.4 GB,
+    half a GB of temporaries), and its Mosaic kernels are 36 ``ssd_scan`` (one a
+    state-space layer, under the scope ``ssd``: ``ssd_roofline_share.granite``
+    reads each call by that name), 4 ``masked_gqa_attention`` (under
+    ``sparse_attn``), the calibration kernel, and no other."""
+    import collections
+
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, params = _decoder_cell("granite4_h_micro_prefill_epix10k2m")
+    calib = (S((PANELS, H, W), F32), S((PANELS, H, W), F32), S((PANELS, H, W), jnp.uint8))
+    frames = S((cfg["batch_size"], PANELS, H, W), jnp.uint16)
+    ids = S((cfg["prompt_tokens"],), jnp.int32)
+
+    def step(p, c, f, i):
+        return decoder.frame_step(p, c, f, i, cfg=dcfg, threshold=10.0)
+
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), (params, calib, frames, ids))
+    compiled = jax.jit(step).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert 6.3e9 < mem.argument_size_in_bytes < 6.5e9 and mem.temp_size_in_bytes < 1.5e9
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
+    assert names == {"ssd_scan": cfg["layer_types"].count("mamba") == 36 and 36,
+                     "masked_gqa_attention": cfg["layer_types"].count("attention") == 4 and 4,
+                     "fused_calibrate": 1}, names
+    assert all("/ssd/" in line for line in calls if re.match(r"\s*%ssd_scan", line))
+    assert all("/sparse_attn/" in line for line in calls if re.match(r"\s*%masked_gqa", line))
+    # the stream between the layers is float32 (`decoder.trunk`), every product's operands bf16
+    text = compiled.as_text()
+    assert re.search(r"f32\[8704,2048\]", text) and not re.search(r"f32\[8704,8192\]\{[^}]*\} dot\(", text)
 
 
 def test_the_laguna_step_compiles_with_its_kernels_where_the_roofline_functions_count_them(

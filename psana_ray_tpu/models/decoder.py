@@ -1052,7 +1052,9 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
     alone, under a name and a scope of its own (``windowed_gqa_attention``,
     ``window_attn``), and ``live`` counts the statistics tiles the band
     meets. Where the output is gated (``attn_gate``) each head's output
-    meets its own sigmoid scalar in ``W_o``'s operand."""
+    meets its own sigmoid scalar where the batched kernel writes it
+    (``out_gate``: :func:`gated`'s arithmetic to the bit, and ``W_o`` reads
+    what the kernel wrote; PR 58), under a selection in ``W_o``'s operand."""
     s = x.shape[0] // batch
     with jax.named_scope("proj"):
         a, q, k, v, *gate = jax.jit(_projections, static_argnums=(3, 4))(
@@ -1073,8 +1075,10 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
         if window:  # the same kernel under its own name (a trace tells the two kinds of layer apart)
             attend, band = sa.windowed_gqa_attention, {"window": window}
             live = batch * sa.band_tile_count(s, window)
+        if gate:  # applied where the kernel writes o: nothing is left for `gated` below
+            band["out_gate"] = gate.pop().reshape(batch, s, -1)
         with jax.named_scope("window_attn" if window else "sparse_attn"):
-            o = jax.jit(attend, static_argnames=("num_kv_heads", "block_q", "block_k", *band))(
+            o = jax.jit(attend, static_argnames=("num_kv_heads", "block_q", "block_k", "window"))(
                 *(u.reshape(batch, s, -1) for u in (q, k, v)), num_kv_heads=cfg.num_kv_heads,
                 block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile,
                 **band).reshape(x.shape[0], -1)

@@ -36,11 +36,13 @@ What is here, and what it is:
   for all of them. Its BATCHED form (``[B, S, .]`` operands) has a grid
   that holds the tiles at or below the diagonal only (a table of ``(query
   tile, key tile)`` pairs rides in as scalar prefetch), at any head width
-  (heads of 64, LFM2's, go head-major into it; heads of 128 alone in
-  their groups, latent attention's, are read and written as column blocks
-  of the token-major arrays their products wrote), with values of a width
-  of their own and a part of the score read from ONE key for all heads
-  (latent attention). Without a
+  (heads of 64, LFM2's, go head-major into it; heads of whole lane blocks
+  — latent attention's alone in their groups, Laguna's six and nine a
+  group — are read and written as column blocks of the token-major arrays
+  their products wrote), with values of a width of their own, a part of
+  the score read from ONE key for all heads (latent attention) and a
+  gate a (token, head) applied where the output is written (Laguna).
+  Without a
   mask it is plain causal attention over a batch of sequences; with one
   (one sequence) it is the selection over LATENT attention, masked-dense
   again: the mask's tile is one more operand of a grid step, and its key
@@ -77,10 +79,12 @@ _VMEM_LIMIT = 100 * 1024 * 1024  # of the v5e's 128 MiB; the default scope is 16
 SCORE_TILE_BYTES = _VMEM_LIMIT // 5
 # a windowed call's widest (query tile, key tile), as shares of its window: the key tile the
 # window's own width, the query tile half of it. One windowed layer on the v5e, 2 x 8,704 tokens,
-# 9 heads of 128 a group, a window of 512, the head-major transposes with it (my chip runs, PR
-# 53), ms: 256 x 512 12.2, 256 x 256 14.3, 128 x 256 15.4, 512 x 256 16.8, 128 x 128 19.2, 256 x
-# 128 20.4, 512 x 512 21.1. A narrower key tile meets fewer pairs outside the band (768 keys a row
-# at 256 x 256 where 256 x 512 and 512 x 512 meet 1,024) and loses more to its grid steps
+# 9 heads of 128 a group, a window of 512, on PR 53's tree (the call with the head-major
+# transposes it then had: my chip runs, PR 53), ms: 256 x 512 12.2, 256 x 256 14.3, 128 x 256
+# 15.4, 512 x 256 16.8, 128 x 128 19.2, 256 x 128 20.4, 512 x 512 21.1; since PR 58 the call at
+# 256 x 512 is 10.8 for a kernel of 8.02 (my chip runs, PR 58). A narrower key tile meets fewer
+# pairs outside the band (768 keys a row at 256 x 256 where 256 x 512 and 512 x 512 meet 1,024)
+# and loses more to its grid steps
 BAND_TILES = (0.5, 1.0)
 MASK_TILE = 2176  # the widest key tile a selection's mask is written in (:func:`mask_tile`): on the
 # v5e the kernel under it read 34.8 ms a layer at 512 x 2,176 and 39.4 at 256 x 4,352 (PR 47)
@@ -348,14 +352,16 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared, masked,
-                   window=None):
+                   gated=False, window=None):
     rest = list(rest)
     if shared:  # the part of the score that all heads read from ONE key
         qs_ref, ks_ref = rest.pop(0), rest.pop(0)
     if masked:  # the selection's tile, one for all heads: it is causal by construction
         mask_ref = rest.pop(0)
+    if gated:  # a float32 scalar a (token, head) on the output: the query tile's, [1, bq, H]
+        gate_ref = rest.pop(0)
     o_ref, m_ref, l_ref, acc_ref = rest
-    t = pl.program_id(2)
+    gi, t = pl.program_id(1), pl.program_id(2)
     qi, kb = qi_ref[t], kb_ref[t]
     rep, _, d = q_ref.shape
     rows = rep * block_q  # the group's query heads, stacked: one product serves them all
@@ -424,7 +430,24 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
 
     @pl.when(kb == ((qi + 1) * block_q - 1) // block_k)  # the row's last tile
     def _finalize():
-        o_ref[...] = (acc_ref[:] / l_ref[:]).reshape(o_ref.shape).astype(o_ref.dtype)
+        out = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+        if not gated and o_ref.shape[0] == rep:
+            o_ref[...] = out.reshape(o_ref.shape)
+            return
+        dv = v_ref.shape[-1]
+        if gated:  # head h's scalars are lane h of the tile: picked by a compare and a lane sum
+            gate = gate_ref[0]
+            lane = jax.lax.broadcasted_iota(jnp.int32, gate.shape, 1)
+        for r in range(rep):  # head by head: whole lane blocks, and no product
+            head = out[r * block_q:(r + 1) * block_q]
+            if gated:
+                of_head = jnp.sum(jnp.where(lane == gi * rep + r, gate, 0.0),
+                                  axis=-1, keepdims=True)
+                head = (head.astype(jnp.float32) * of_head).astype(o_ref.dtype)
+            if o_ref.shape[0] == rep:
+                o_ref[r] = head
+            else:  # the group's heads are adjacent lane blocks of ONE token-major tile
+                o_ref[0, :, r * dv:(r + 1) * dv] = head
 
 
 def _band_tiles(s: int, bq: int, bk: int, window: Optional[int] = None) -> list:
@@ -440,41 +463,66 @@ def _band_tiles(s: int, bq: int, bk: int, window: Optional[int] = None) -> list:
 
 
 def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool,
-                      q_shared=None, k_shared=None, mask=None, window: Optional[int] = None):
+                      q_shared=None, k_shared=None, mask=None, window: Optional[int] = None,
+                      out_gate=None):
     """The batched form of :func:`masked_gqa_attention`. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
-    even a grid step. ONE kernel body, two ways of addressing its blocks,
-    chosen by the widths, each operand by its own. Where a head is whole
-    lane blocks and alone in its group (``width % 128 == 0``, ``H == G``:
-    latent attention's 128-wide q, k, v and o) a head's tile is column
-    block ``h`` of the TOKEN-MAJOR array ``[B, S, H*width]``, read and
-    written where the projection's product wrote it and where ``W_o``
-    contracts it: in the tiled HBM layout a run of whole 4 KB tiles at a
-    fixed stride. ``v`` may then be ``None``: ``k [B, S, G*2d]`` is ONE
-    array with head ``h``'s keys at column block ``2h`` and its values at
-    ``2h + 1`` (the latent's one decompression), two blocks of one
-    operand. Else (heads of 64: LFM2's; ``H/G`` query heads a group)
-    operands go HEAD-MAJOR (``[B, G, H/G, S, d]`` and ``[B, G, S, d]``: a
-    block's last dimension is then the whole head width, which Mosaic
-    takes at 64 where a 64-lane block of ``[S, G*64]`` it does not) by a
-    transpose each way. One layer's kernel alone on the v5e, head-major
-    and then in place (my chip runs, PR 48), ms: 64 heads, B 2, maskless
-    32.79 and 32.82 (32.86 with k and v of one array); 128 heads, masked
-    34.83 and 34.86 (34.89): the strided tiles cost nothing that shows,
-    and the transposes around the kernel, 8.2 ms a layer, are gone (the
-    call with them 41.04 -> 34.43 and 43.07 -> 35.98, what is left being
-    the rotary query's, which inside the layer its own fusion writes).
+    even a grid step. ONE kernel body, several ways of addressing its
+    blocks, chosen by the widths, each operand by its own. Where a head
+    is whole lane blocks (``width % 128 == 0``), at ANY number of query
+    heads a group: a key or value head's tile is column block ``g`` of
+    the TOKEN-MAJOR array ``[B, S, G*width]``, read where the projection's
+    product wrote it, and a group's ``H/G`` output heads are ONE block
+    ``[bq, H/G * dv]`` of ``[B, S, H*dv]``, written head by head to its
+    lane blocks where ``W_o`` contracts it: in the tiled HBM layout runs
+    of whole 4 KB tiles at a fixed stride. ``v`` may then be ``None``: ``k
+    [B, S, G*2d]`` is ONE array with head ``h``'s keys at column block
+    ``2h`` and its values at ``2h + 1`` (the latent's one decompression),
+    two blocks of one operand. The QUERY of such heads is a column block
+    too where a head is alone in its group (latent attention's, straight
+    from its product); with ``H/G > 1`` heads a group it goes as ``[G,
+    H/G, B*S, d]``, the batch in the rows: a rotary writes its heads
+    ``[T, H, d]`` with the HEADS in the sublanes, of which this is a
+    layout (XLA has the rotary's own fusion write it so: no op), where the
+    token-major ``[B, S, H*d]`` is another tiling and cost a ``reshape`` of
+    the whole query (tried: a group's heads stacked from ONE token-major
+    block into a VMEM scratch once a query tile; Laguna's step, its gate
+    still XLA's, 523.6 ms against this form's 514.1 and the parent's
+    522.9: my chip runs, PR 58). Else
+    (heads of 64: LFM2's, granite's) operands go HEAD-MAJOR (``[B, G, H/G,
+    S, d]`` and ``[B, G, S, d]``: a block's last dimension is then the
+    whole head width, which Mosaic takes at 64 where a 64-lane block of
+    ``[S, G*64]`` it does not) by a transpose each way. One layer's kernel
+    alone on the v5e, head-major and then in place, ms (my chip runs, PR
+    48): 64 heads alone in their groups, B 2, maskless 32.79 and 32.82
+    (32.86 with k and v of one array); 128 heads, masked 34.83 and 34.86
+    (34.89), the call with its transposes 41.04 -> 34.43 and 43.07 ->
+    35.98; (PR 58, B 2 x 8,704, 8 key heads) 48 heads, six a group 18.25
+    and 18.28 (18.43 with the gate), the call 21.08 -> 19.90, the layer
+    with its projections 45.44 -> 38.94; 72 heads, nine a group, under a
+    window of 512 8.00 and 8.02 (8.26), 12.12 -> 10.78, 43.06 -> 33.65:
+    the strided tiles cost nothing that shows, and what stood around the
+    kernel is gone (at nine a group q's copy 0.96 ms, and THREE float32
+    passes over o and a materialised broadcast of its gate 6.5: XLA had
+    ``gated`` run with the tokens in the lanes).
     The values' width is ``v``'s own (``v [B, S, G*dv]``
     -> ``o [B, S, H*dv]``). With ``q_shared [B, S, H*ds]`` and ``k_shared
     [B, S, ds]`` a score is ``q . k + q_shared . k_shared``: the shared
     key's tile is read from its one array, once a grid step, for every
     head (latent attention's one rotary key); the shared QUERY part is
-    narrow (64) and always head-major, as ``[G, H/G, B*S, ds]`` with the
-    batch in the rows: that is a layout of the product ``[B*S, H*ds]`` it
-    comes from, so XLA has the fusion that turns it write it so, where
-    ``[B, G, ..]`` cost a reshape and a copy (compiled for a described v5e,
-    PR 48). With ``mask`` (the selection
+    narrow (64) and always head-major with the batch in the rows, ``[G,
+    H/G, B*S, ds]`` (PR 48: where ``[B, G, ..]`` cost a reshape and a
+    copy). With ``out_gate [B, S, H]`` (float32: a scalar a token and
+    head, Laguna's sigmoid gate) the output leaves as ``gated`` made it
+    — the output rounded to its type, times the scalar in float32,
+    rounded: equal to the bit — from the tile's last grid step: the
+    query tile's scalars ride in as ONE block ``[bq, H]`` of the array as
+    it is, and head ``h``'s are lane ``h`` of it, picked by a compare and
+    a lane sum. ``W_o`` then contracts what the kernel wrote; done by XLA
+    on a token-major output the same multiply cost a float32 copy of o
+    and a materialised ``[T, H, dv]`` broadcast of the gate, and the step
+    514.1 ms where it is 455.2 (my chip runs, PR 58). With ``mask`` (the selection
     of ONE sequence, from :func:`select_keys`) every tile at or below the
     diagonal is still visited, and a pair counts where the mask says so: the
     key tile is the mask's own (:func:`mask_tile` chose it for THIS
@@ -508,20 +556,26 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     pairs = _band_tiles(s, bq, bk, window)
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
 
-    def in_place(width):  # a head of whole lane blocks, alone in its group
-        return rep == 1 and width % 128 == 0
+    def in_place(width):  # a head of whole lane blocks
+        return width % 128 == 0
 
-    def q_spec(width):  # the kernel's [rep, bq, width] tile of a query-side array
-        if in_place(width):  # of [B, 1, S, H*width]: column block gi of the product's own array
-            return pl.BlockSpec((None, rep, bq, width),
-                                lambda bi, gi, t, qi, kb: (bi, 0, qi[t], gi))
-        return pl.BlockSpec((None, None, rep, bq, width),  # of [B, G, H/G, S, width]
+    def rows_spec(width):  # of [G, H/G, B*S, width]: the batch in the rows
+        return pl.BlockSpec((None, rep, bq, width),
+                            lambda bi, gi, t, qi, kb: (gi, 0, bi * (s // bq) + qi[t], 0))
+
+    def major_spec(width):  # of [B, G, H/G, S, width]
+        return pl.BlockSpec((None, None, rep, bq, width),
                             lambda bi, gi, t, qi, kb: (bi, gi, 0, qi[t], 0))
 
-    def q_tiles(x, width):
+    def place_spec(width):  # of [B, 1, S, H*width]: the group's heads, column block gi
+        return pl.BlockSpec((None, 1, bq, rep * width), lambda bi, gi, t, qi, kb: (bi, 0, qi[t], gi))
+
+    def q_tiles(x, width):  # -> the kernel's [rep, bq, width] tile
+        if in_place(width) and rep == 1:
+            return x.reshape(b, 1, s, g * width), place_spec(width)
         if in_place(width):
-            return x.reshape(b, 1, s, g * width), q_spec(width)
-        return jnp.transpose(x.reshape(b, s, g, rep, width), (0, 2, 3, 1, 4)), q_spec(width)
+            return jnp.transpose(x.reshape(b * s, g, rep, width), (1, 2, 0, 3)), rows_spec(width)
+        return jnp.transpose(x.reshape(b, s, g, rep, width), (0, 2, 3, 1, 4)), major_spec(width)
 
     def kv_tiles(x, width, part=0, parts=1):  # head gi's part is column block gi*parts + part
         if in_place(width):
@@ -540,26 +594,28 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     if shared:
         ds = k_shared.shape[2]
         operands += [jnp.transpose(q_shared.reshape(b * s, g, rep, ds), (1, 2, 0, 3)), k_shared]
-        in_specs += [pl.BlockSpec((None, rep, bq, ds),
-                                  lambda bi, gi, t, qi, kb: (gi, 0, bi * (s // bq) + qi[t], 0)),
+        in_specs += [rows_spec(ds),
                      pl.BlockSpec((None, bk, ds), lambda bi, gi, t, qi, kb: (bi, kb[t], 0))]
     if masked:
         operands.append(mask)
         in_specs.append(pl.BlockSpec((bq // mq, None, mq, bk),
                                      lambda bi, gi, t, qi, kb: (qi[t], kb[t], 0, 0)))
+    if out_gate is not None:  # [B, S, H] as it is: a query tile's scalars, every head's
+        operands.append(out_gate.astype(jnp.float32).reshape(b, 1, s, g * rep))
+        in_specs.append(pl.BlockSpec((None, 1, bq, g * rep), lambda bi, gi, t, qi, kb: (bi, 0, qi[t], 0)))
     o5 = pl.pallas_call(
         functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked,
-                          window=window),
+                          gated=out_gate is not None, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g, len(pairs)),
-            in_specs=in_specs, out_specs=q_spec(dv),
+            in_specs=in_specs, out_specs=place_spec(dv) if in_place(dv) else major_spec(dv),
             scratch_shapes=[
                 pltpu.VMEM((rep * bq, 1), jnp.float32),
                 pltpu.VMEM((rep * bq, 1), jnp.float32),
                 pltpu.VMEM((rep * bq, dv), jnp.float32),
             ]),
         out_shape=jax.ShapeDtypeStruct(
-            (b, 1, s, g * dv) if in_place(dv) else (b, g, rep, s, dv), q.dtype),
+            (b, 1, s, g * rep * dv) if in_place(dv) else (b, g, rep, s, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
@@ -568,7 +624,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         name="masked_gqa_attention" if window is None else "windowed_gqa_attention",
     )(qi, kb, *operands)
     if in_place(dv):
-        return o5.reshape(b, s, g * dv)
+        return o5.reshape(b, s, g * rep * dv)
     return jnp.transpose(o5, (0, 3, 1, 2, 4)).reshape(b, s, g * rep * dv)
 
 
@@ -587,11 +643,13 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
     before it as they were (4 heads of 64 a group at 1,088 x 1,088: 18.9 MB;
     a head of 128 + 64 alone: 4.7) and gives 6 heads of 128 a group 512 x
     1,088 (13.4 MB) and 9 under a window of 512 256 x 512 (4.7). One full
-    layer on the v5e, 2 x 8,704 tokens, 6 heads of 128 a group, the
-    head-major transposes with it (my chip runs, PR 53), ms: 512 x 1,088
-    21.2, 256 x 1,088 21.0, 512 x 512 24.6, 1,088 x 512 24.7, 1,088 x 1,088
-    (a score tile of 28.4 MB) 31.7; the windowed layer's are beside
-    :data:`BAND_TILES`."""
+    layer on the v5e, 2 x 8,704 tokens, 6 heads of 128 a group, on PR 53's
+    tree (the call with the head-major transposes it then had: my chip
+    runs, PR 53), ms: 512 x 1,088 21.2, 256 x 1,088 21.0, 512 x 512 24.6,
+    1,088 x 512 24.7, 1,088 x 1,088 (a score tile of 28.4 MB) 31.7; since
+    PR 58 the same call at 512 x 1,088 is 19.9 for a kernel of 18.28 (my
+    chip runs, PR 58: q's layout and k's reshape are what is left); the
+    windowed layer's are beside :data:`BAND_TILES`."""
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
     if window is not None:
         bq, bk = (min(tile, pick_tile(s, max(int(window * share), 1)))
@@ -604,7 +662,8 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
 
 def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Optional[int] = None,
                          block_k: int = 512, interpret: Optional[bool] = None,
-                         q_shared=None, k_shared=None, window: Optional[int] = None) -> jax.Array:
+                         q_shared=None, k_shared=None, window: Optional[int] = None,
+                         out_gate=None) -> jax.Array:
     """``q [S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [S, G*d]``,
     ``mask`` from :func:`select_keys` -> ``o [S, H*d]``: softmax attention
     of every query head over the keys its query selected, query head ``h``
@@ -618,8 +677,9 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     below the diagonal visited. Without a mask it is plain causal
     attention. Operands and output are token-major HERE; what the kernel
     reads in place and what it has transposed head-major first follows
-    from the widths (:func:`_causal_attention`: heads of 128 alone in
-    their groups in place, heads of 64 transposed). There the value heads
+    from the widths (:func:`_causal_attention`: heads of whole lane
+    blocks in place at any number a group, their query as its rotary
+    wrote it; heads of 64 transposed). There the value heads
     may have a width of their own (``v [B, S, G*dv]`` -> ``[B, S, H*dv]``),
     ``v`` may be ``None`` where ``k [B, S, G*2d]`` holds each head's keys
     and then its values (read in place: no slice), and a score may have a second
@@ -629,8 +689,11 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     attention, masked-dense as the form above, in the mask's key tile.
     With ``window`` (maskless only) a query attends to the keys ``t -
     window < j <= t`` of its sequence and the kernel visits the tiles that
-    meet that band alone, under the name ``windowed_gqa_attention``. The
-    maskless form's tiles are :func:`causal_tiles`' of the two asked for."""
+    meet that band alone, under the name ``windowed_gqa_attention``. With
+    ``out_gate [B, S, H]`` float32 each head's output leaves times its
+    token's scalar (``decoder.gated``'s arithmetic, in the kernel's last
+    step). The maskless form's tiles are :func:`causal_tiles`' of the two
+    asked for."""
     if mask is None or q.ndim == 3:
         block_q = block_q or 256
         if mask is None:
@@ -639,9 +702,10 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
             block_q, block_k = causal_tiles(q.shape[1], q.shape[2] // (g * d), block_q, block_k,
                                             window)
         return _causal_attention(q, k, v, int(num_kv_heads), block_q, block_k,
-                                 _interpret(interpret), q_shared, k_shared, mask, window)
-    if q_shared is not None or v.shape[1] != k.shape[1]:
-        raise ValueError("a shared key part and a value width of its own are the batched form's")
+                                 _interpret(interpret), q_shared, k_shared, mask, window, out_gate)
+    if q_shared is not None or out_gate is not None or v.shape[1] != k.shape[1]:
+        raise ValueError("a shared key part, a value width of its own and an output gate are "
+                         "the batched form's")
     from jax.experimental.pallas import tpu as pltpu
 
     n_qb, n_kb, mq, bk = mask.shape
@@ -683,7 +747,8 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
 
 
 def windowed_gqa_attention(q, k, v, *, window: int, num_kv_heads: int, block_q: Optional[int] = None,
-                           block_k: int = 512, interpret: Optional[bool] = None) -> jax.Array:
+                           block_k: int = 512, interpret: Optional[bool] = None,
+                           out_gate=None) -> jax.Array:
     """:func:`masked_gqa_attention`'s batched maskless form under a
     ``window``: ``q [B, S, H*d]``, ``k, v [B, S, G*d]`` -> ``[B, S, H*d]``,
     a query attending to the keys ``t - window < j <= t`` of its own
@@ -695,4 +760,5 @@ def windowed_gqa_attention(q, k, v, *, window: int, num_kv_heads: int, block_q: 
     ``%masked_gqa_attention`` (seen on the v5e, PR 53: under the one jit all
     nine of a step carried the one name)."""
     return masked_gqa_attention(q, k, v, num_kv_heads=num_kv_heads, block_q=block_q,
-                                block_k=block_k, interpret=interpret, window=window)
+                                block_k=block_k, interpret=interpret, window=window,
+                                out_gate=out_gate)

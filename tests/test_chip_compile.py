@@ -592,7 +592,12 @@ PINNED_STEPS = {
     # AROUND a kernel (its operands, their shapes and types, its grid's result) and no kernel's
     # body. A kernel's own cache entry follows its body and its file's path
     "ling3_flash_prefill_epix10k2m": "7ccf30c1fc0daaf22777b5332f2eab8dcb0c84756be65add4bcdd4ce4029f008",
-    "laguna_s21_prefill_epix10k2m": "2bc8bf337d0927163241159bd19544e69bcef76f5fa162bfa47307851a0a0209",
+    # laguna's re-pinned in PR 58, knowingly: its nine attention calls take k, v and the query
+    # tile's gate where their products wrote them, q as `[G, H/G, B*S, d]` (a layout of the
+    # rotary's fusion) and write o token-major `[B, 1, S, H*128]`, already gated, for `W_o` to
+    # read; the six others were hashed before and after and did not move (heads alone in their
+    # groups were in place already, heads of 64 stay head-major)
+    "laguna_s21_prefill_epix10k2m": "1cf0fbb0051f4454385c9ff7492ec618eb4c8d2f26493cc7fb8b95e136bdbc13",
     # pinned in PR 57, which brought it: the six above were hashed on PR 56's tree first and none
     # moved, though every one of them now traces `_projections`, `embed`, `logits_of` and `trunk`
     # through the new fields' branches (taken in Python, before anything is traced)
@@ -785,6 +790,24 @@ def test_the_laguna_step_compiles_with_its_kernels_where_the_roofline_functions_
         assert f"jit(step)/{scope}/" in text, scope
 
 
+def _array_sized_moves(entry, floor, opcodes, apart=None):
+    """``name type[dims]`` of every instruction of a compiled entry
+    computation that only MOVES an array of ``floor`` elements or more: one
+    of ``opcodes``, or a copy/bitcast fusion; lines that carry ``apart`` (a
+    scope of its own account) left out."""
+    moved = []
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", line)
+        if not m or (apart and apart in line):
+            continue
+        op_name, dtype, dims, opcode = m.groups()
+        moves = opcode in opcodes or (
+            opcode == "fusion" and ("copy" in op_name or "bitcast" in op_name))
+        if moves and np.prod([int(x) for x in dims.split(",") if x]) >= floor:
+            moved.append(f"{op_name} {dtype}[{dims}]")
+    return moved
+
+
 @pytest.mark.parametrize("name", ["kimi_k2_prefill_epix10k2m", "deepseek_v32_prefill_epix10k2m"])
 def test_latent_attention_s_operands_reach_the_kernel_where_their_products_wrote_them(
         name, one_chip, monkeypatch):
@@ -823,17 +846,59 @@ def test_latent_attention_s_operands_reach_the_kernel_where_their_products_wrote
     entry = text[text.index("ENTRY"):]
     assert len(re.findall(r"^\s*(?:ROOT )?%masked_gqa_attention[.\d]* = ", entry, re.M)) == 1
     assert f"[{tokens},{heads * dcfg.head_dim}]" not in entry  # no product of whole [nope | rope] heads
-    moved = []
-    for line in entry.splitlines():
-        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\(", line)
-        if not m or "/indexer/" in line:
-            continue
-        op_name, dims, opcode = m.groups()
-        moves = opcode in ("copy", "slice", "reshape") or (
-            opcode == "fusion" and ("copy" in op_name or "bitcast" in op_name))
-        if moves and np.prod([int(x) for x in dims.split(",") if x]) >= tokens * heads * 64:
-            moved.append(op_name)
+    moved = _array_sized_moves(entry, tokens * heads * 64, ("copy", "slice", "reshape"), "/indexer/")
     assert not moved, moved
+
+
+@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+def test_laguna_s_grouped_heads_reach_the_kernel_where_their_products_wrote_them(
+        kind, one_chip, monkeypatch):
+    """ONE attention layer of laguna's (``decoder._attention``: a windowed
+    one at 72 query heads, a full one at 48, 8 key heads of 128, two
+    sequences of 8,704) as compiled: one kernel, its output the token-major
+    ``[B, 1, S, H*128]`` that ``gated`` reads, and between ``W_q``'s product
+    and ``W_o`` no ``copy``, ``transpose``, ``reshape`` or copy/bitcast
+    fusion of ``T * H * 64`` elements or more — but the rotary's own: the
+    float32 halves ``[T, H, 64]`` it is computed from (ROADMAP S12 (1):
+    another mechanism, another PR). On PR 57's tree a windowed layer held,
+    beside those, q's head-major copy (bf16 ``[2,8,9,8704,128]``) and THREE
+    float32 passes over o on the way back (``[2,8704,8,9,128]`` twice,
+    ``[1152,8,2,8704]``: 0.64 GB each) with the gate broadcast to
+    ``[T, H, 128]`` beside them. Since PR 58 k, v and o are column blocks of
+    the token-major arrays at any number of heads a group, q is what the
+    rotary's fusion wrote, bitcast, and the gate is applied where the kernel
+    writes o: nothing of o's size stands between the kernel and ``W_o``."""
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, params = _decoder_cell("laguna_s21_prefill_epix10k2m")
+    batch, seq, i = cfg["batch_size"], cfg["sequence_tokens"], cfg["layer_types"].index(kind)
+    tokens, heads = batch * seq, dcfg.heads(i)
+    sliding = kind == "sliding_attention"
+    assert heads == (72 if sliding else 48) and dcfg.sliding_window == 512
+
+    def layer(p, x):
+        if sliding:
+            angles = decoder.rotary_angles(np.arange(seq), dcfg.sliding_rope_theta, dcfg.head_dim // 2)
+        else:
+            angles = decoder.rotary_angles(np.arange(seq), dcfg.rope_theta, dcfg.rope_dim // 2,
+                                           yarn=dcfg.rope_yarn)
+        return decoder._attention(p, x, jnp.tile(angles, (batch, 1)), None, batch, dcfg,
+                                  dcfg.sliding_window if sliding else 0)[0]
+
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip),
+                        (params["layers"][i], S((tokens, dcfg.hidden_size), BF16)))
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    kernel = "windowed_gqa_attention" if sliding else "masked_gqa_attention"
+    calls = re.findall(rf"^\s*(?:ROOT )?%{kernel}[.\d]* = (\w+\[[\d,]*\])", entry, re.M)
+    assert calls == [f"bf16[{batch},1,{seq},{heads * dcfg.head_dim}]"], calls
+    moved = _array_sized_moves(entry, tokens * heads * 64,
+                               ("copy", "transpose", "reshape", "convert", "broadcast"))
+    # the rotary's own: float32 [T, H, 64], a windowed layer's two halves of a head and a full
+    # layer's turned leading half (its partial rotary turns 64 of 128)
+    halves = f" f32[{tokens},{heads},{dcfg.head_dim // 2}]"
+    assert [m for m in moved if not m.endswith(halves)] == [], moved
 
 
 def _ling3_experts():

@@ -251,13 +251,18 @@ def test_causal_kernel_reads_heads_of_whole_lane_blocks_where_their_products_wro
                                atol=3e-6)
 
 
-def test_heads_of_64_keep_the_head_major_addressing():
+@pytest.mark.parametrize("rep", [1, 4])
+def test_heads_of_64_keep_the_head_major_addressing(rep):
     """A 64-lane block of ``[S, G*64]`` Mosaic does not take: heads of 64
-    (LFM2's, ``rep`` 4 there; here alone in their groups, so that the width
-    alone decides) are transposed to head-major and back as they were, and
+    (alone in their groups, and four a group: LFM2's and granite's) are
+    transposed to head-major and back as they were, whatever the heads a
+    group (the width alone decides: PR 58 took ``rep`` out of the rule), and
     ONE array of keys and values is cut in two first."""
     b, s, g, d, ds = 2, 64, 2, 64, 8
-    q, k, v, qs, ks, one = _latent_operands(64, b, s, g, d, ds)
+    _, k, v, _, ks, one = _latent_operands(64, b, s, g, d, ds)
+    rng = np.random.default_rng(rep)
+    q, qs = (jnp.asarray(rng.standard_normal((b, s, g * rep * width)), jnp.float32) * scale
+             for width, scale in ((d, 0.1), (ds, 0.3)))
 
     def attend(q, k, v):
         return sa.masked_gqa_attention(q, k, v, num_kv_heads=g, block_q=32, block_k=32,

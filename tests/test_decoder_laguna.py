@@ -9,6 +9,7 @@ is no power of two; the shares of the expert layer; the new cell's manifest
 entries, counters and counts."""
 
 import dataclasses
+import functools
 import importlib
 import json
 import os
@@ -146,6 +147,66 @@ def test_the_windowed_kernel_is_a_dense_softmax_under_the_band(case, batch):
     if batch == 2:
         alone = sa._causal_attention(q[1:], k[1:], v[1:], g, bq, bk, True, window=window)
         np.testing.assert_array_equal(np.asarray(got[1:]), np.asarray(alone))
+
+
+def _transposes(fn, *args):
+    return sum(e.primitive.name == "transpose" for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns)
+
+
+@pytest.mark.parametrize("kind", ["full", "windowed"])
+@pytest.mark.parametrize("rep", [3, 9])
+def test_grouped_heads_of_whole_lane_blocks_are_read_where_their_products_wrote_them(rep, kind):
+    """Heads of 128, ``rep`` of them on each of 2 key heads, a batch of two
+    (the cell's layers have 6 and 9 a group): k, v and o are column blocks of
+    the token-major arrays, a group's ``rep`` output heads ONE block that the
+    kernel writes head by head; q alone is handed over head-major with the
+    batch in the rows (``[G, rep, B*S, d]``: ONE transpose traced, which
+    compiled is a layout of the rotary's own fusion and no op:
+    ``tests/test_chip_compile.py``). The numbers are the dense reference's
+    and those of the head-major addressing (taken where a head is no whole
+    number of lane blocks: the same operands with eight zero columns a
+    head); with a gate a (token, head) the output is that times the gate,
+    to the bit, in both addressings."""
+    b, s, g, d, window = 2, 64, 2, 128, 20
+    q, k, v = qkv(rep + len(kind), b, s, g, rep, d)
+    q = q * 0.1
+    gate = jax.nn.sigmoid(qkv(rep, b, s, g, rep, 1)[0])  # [B, S, H]
+
+    def attend(q, k, v, **gated):
+        if kind == "windowed":  # the band crosses the 16-wide tiles: all four variants of the body
+            return sa.windowed_gqa_attention(q, k, v, window=window, num_kv_heads=g, block_q=16,
+                                             block_k=16, **gated)
+        return sa.masked_gqa_attention(q, k, v, num_kv_heads=g, block_q=16, block_k=16, **gated)
+
+    assert _transposes(attend, q, k, v) == 1
+    got = attend(q, k, v)
+    assert got.shape == (b, s, g * rep * d)
+    want = band_softmax(q, k, v, g, window if kind == "windowed" else s)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
+    assert _transposes(functools.partial(attend, out_gate=gate), q, k, v) == 1
+    np.testing.assert_array_equal(
+        np.asarray(attend(q, k, v, out_gate=gate)),
+        np.asarray((got.reshape(b, s, g * rep, d) * gate[..., None]).reshape(b, s, -1)))
+
+    def padded(x):
+        return jnp.pad(x.reshape(b, s, -1, d), ((0, 0),) * 3 + ((0, 8),)).reshape(b, s, -1)
+
+    assert _transposes(attend, padded(q), padded(k), padded(v)) == 4  # q, k, v in and o back
+    major = attend(padded(q), padded(k), padded(v)).reshape(b, s, g * rep, d + 8)
+    np.testing.assert_array_equal(np.asarray(major[..., d:]), 0.0)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(major[..., :d].reshape(b, s, -1)),
+                               atol=3e-6)
+    np.testing.assert_array_equal(
+        np.asarray(attend(padded(q), padded(k), padded(v), out_gate=gate)),
+        np.asarray((major * gate[..., None]).reshape(b, s, -1)))
+    # in bfloat16 it is `decoder.gated`'s arithmetic: the rounded output, times the float32
+    # scalar, rounded again
+    q16, k16, v16 = (u.astype(jnp.bfloat16) for u in (q, k, v))
+    rounded = attend(q16, k16, v16).reshape(b, s, g * rep, d)
+    np.testing.assert_array_equal(
+        np.asarray(attend(q16, k16, v16, out_gate=gate).astype(jnp.float32)),
+        np.asarray((rounded * gate[..., None]).astype(jnp.bfloat16).reshape(b, s, -1)
+                   .astype(jnp.float32)))
 
 
 def test_the_kernel_s_tiles_are_the_band_s_alone_and_the_statistics_count_them():

@@ -118,6 +118,13 @@ LINEAR_STATS = (
 AHEAD_STATS = (
     "expert_rows_ahead_total",   # held rows that pass, and its way back, took: the loop took the rest
 )
+# and two after those thirteen (the groups above 0 where the step has none), where ONE stack of
+# layers is run `passes` times over with the same weights (a looped model: `DecoderConfig.passes`)
+LOOP_STATS = (
+    "loop_passes_total",         # passes through the stack: `passes` a step
+    "exit_pass_sum",             # over the step's frames, sum_r r * p_r at the frame's last token: the
+                                 # pass the exit gate would have left at, in expectation (/ frames)
+)
 # layer_types, as config.json spells them
 ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
 SLIDING = "sliding_attention"  # grouped-query attention over the band t - sliding_window < j <= t
@@ -211,6 +218,15 @@ class DecoderConfig:
     logits_scaling: float = 1.0
     rotary: bool = True  # False (position_embedding_type nope): q and k are not turned at all
     tie_embedding: bool = False  # the output head is the embedding table
+    # a LOOPED model (Ouro's): the one stack of layers is run `passes` times over with the SAME
+    # weights, the final norm at the end of EVERY pass and its rows what the next pass starts from
+    # (the head then reads the last pass's normed rows as they are); under `exit_gate`
+    # lambda_r = sigmoid(h_r w_e + b_e) a token and pass gives the exit distribution
+    # (:func:`exit_distribution`; the step runs every pass whatever it says: early_exit_threshold 1).
+    # `sandwich`: a branch is normed AGAIN before it is added, x + rms(Op(rms(x))): four gains a layer
+    passes: int = 1
+    sandwich: bool = False
+    exit_gate: bool = False
     rope_yarn: Optional[Yarn] = None  # YaRN-blended rotary frequencies and the softmax's mscale^2
     # latent attention (kv_lora_rank 0: grouped-query attention): queries through a normed
     # q_lora_rank (0: of full rank), keys and values from ONE normed kv_lora_rank latent; a head's query and key
@@ -361,7 +377,7 @@ class DecoderConfig:
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Seven spellings are read:
+        ``experts_held`` and ``tie_embedding``. Eight spellings are read:
         Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
         ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
         (``norm_eps``, ``layer_types``, ``num_dense_layers``,
@@ -404,7 +420,12 @@ class DecoderConfig:
         ``attention_multiplier`` (the softmax scale, and no per-head q / k
         norm); ``position_embedding_type: nope``: no rotary;
         ``shared_intermediate_size``: the always-on MLP, which with
-        ``num_local_experts`` 0 is the whole feed-forward). Where a
+        ``num_local_experts`` 0 is the whole feed-forward); and Ouro's
+        (``ouro``: ``total_ut_steps``, the passes through the one stack,
+        which also says that a layer's branches are normed twice, that an
+        exit gate reads every pass's normed rows and that q and k have no
+        norm, none of which the file has a key for;
+        ``early_exit_threshold``, held to 1). Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -437,7 +458,14 @@ class DecoderConfig:
         # no file has a key for the per-head norm of q and k: the files that state their softmax
         # scale (Granite's) or a rotary a layer type (Laguna's) are of models without one
         scale = m.get("attention_multiplier")
-        qk_norm = scale is None and "rope_parameters" not in m
+        loop = {}
+        if "total_ut_steps" in m:  # Ouro's looped stack: sandwich norms, an exit gate, no q / k norm
+            if float(m.get("early_exit_threshold", 1)) != 1:
+                raise ValueError(
+                    f"early_exit_threshold {m['early_exit_threshold']} is not 1: an exit before the "
+                    f"last pass (a pass count the data sets) is not supported")
+            loop = dict(passes=int(m["total_ut_steps"]), sandwich=True, exit_gate=True)
+        qk_norm = scale is None and "rope_parameters" not in m and not loop
         if layer_types and (len(layer_types) != n_layers
                             or set(layer_types) - {ATTENTION, SLIDING, CONV, LINEAR}
                             - ({MAMBA} if ssm else set())):
@@ -519,6 +547,7 @@ class DecoderConfig:
             linear_head_dim=int(m["head_dim"]) if linear else 0,
             linear_decay_floor=float(m["kda_lower_bound"]) if linear else 0.0,
             tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
+            **loop,
             rope_yarn=yarn,
             q_lora_rank=int(m.get("q_lora_rank") or 0) if latent else 0, kv_lora_rank=latent,
             qk_nope_head_dim=nope if latent else 0, qk_rope_head_dim=rope_dim if latent else 0,
@@ -553,7 +582,8 @@ class DecoderConfig:
 def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
     """normal(0, 0.02) matrices (the router's selection bias too, in
     float32) and unit gains, as one tree: ``{"patch", "embed", "layers":
-    [..], "norm", "head"}`` (no ``head`` where it is the embedding). Call
+    [..], "norm", "head"}`` (no ``head`` where it is the embedding; an
+    ``exit_gate`` ``{"w" [D], "b"}`` where the model has one). Call
     under ``jax.jit`` to make the weights on the device."""
     d, hd = cfg.hidden_size, cfg.head_dim
     # a latent layer with an indexer draws 17 matrices: 20 keys a layer there, and where the
@@ -633,6 +663,8 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 p["w_attn_gate"] = w(d, heads)
             if cfg.indexer_heads:
                 p.update(_index_params(cfg, w, gain, d))
+        if cfg.sandwich:  # each branch's second norm
+            p.update(norm1_post=gain(d), norm2_post=gain(d))
         if experts:
             held = cfg.experts_held[1]
             p.update(router=w(d, cfg.num_experts), w_gate=w(held, d, cfg.expert_width),
@@ -650,6 +682,8 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
               "norm": gain(d)}
     if not cfg.tie_embedding:
         params["head"] = w(d, cfg.vocab_size)
+    if cfg.exit_gate:  # lambda = sigmoid(h w + b): near 1/2 under these draws, seen and not saturated
+        params["exit_gate"] = {"w": w(d), "b": jnp.zeros((), jnp.float32)}
     return params
 
 
@@ -1042,6 +1076,23 @@ def _mlp_onto(p, x, cfg: DecoderConfig):
     return _onto(x, h, p["w_down"], cfg.residual_multiplier)
 
 
+def _onto_normed(x, o, wo, g, eps: float):
+    """``x + rms(o W_o; g)``: a sandwich layer's attention branch, normed
+    again before it is added. The norm is the product's epilogue (float32,
+    no pass of its own over ``[T, D]``); jitted by name, as
+    :func:`state_space`'s parts: the layers of a model trace and lower once."""
+    return (x + rms_norm(_mm(o, wo), g, eps)).astype(x.dtype)
+
+
+def _mlp_onto_normed(p, x, eps: float):
+    """``x + rms(MLP(rms(x; norm2)); norm2_post)``: a sandwich layer's dense
+    feed-forward, jitted by name (as :func:`_onto_normed`)."""
+    dt = p["w_gate"].dtype
+    b = rms_norm(x, p["norm2"], eps).astype(dt)
+    h = (jax.nn.silu(_mm(b, p["w_gate"])) * _mm(b, p["w_up"])).astype(dt)
+    return _onto_normed(x, h, p["w_down"], p["norm2_post"], eps)
+
+
 def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window: int = 0):
     """The attention operator on ``x [B*S, D]`` -> ``(x + Op, live,
     causal)``, the last two the layer's statistics tiles. Each part is a
@@ -1085,6 +1136,8 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
     with jax.named_scope("proj"):
         if gate:
             x = jax.jit(gated)(x, o, gate[0], p["wo"])
+        elif cfg.sandwich:
+            x = jax.jit(_onto_normed, static_argnums=4)(x, o, p["wo"], p["norm1_post"], cfg.rms_eps)
         elif cfg.residual_multiplier != 1.0:
             x = jax.jit(_onto, static_argnums=3)(x, o, p["wo"], cfg.residual_multiplier)
         else:
@@ -1105,6 +1158,10 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     (beside them, added once) or ``mlp`` (dense)."""
     op, experts = kind
     live, causal = 0, 0
+    if cfg.sandwich and (op != ATTENTION or experts or cfg.attn_gate or cfg.indexer_heads
+                         or cfg.residual_multiplier != 1.0):
+        raise ValueError("a branch's second norm (sandwich) is built on plain causal attention "
+                         "and a dense MLP alone")
     if op == CONV:
         with jax.named_scope("conv"):
             x = jax.jit(gated_short_conv, static_argnums=(2, 3))(p, x, batch, cfg)
@@ -1142,7 +1199,11 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
             out = onto + y
             return (out, jnp.max(tokens), jnp.sum(tokens)) if share else (out, jnp.max(tokens))
 
-        if not experts and cfg.residual_multiplier != 1.0:
+        if cfg.sandwich:
+            dense = {k: p[k] for k in ("norm2", "w_gate", "w_up", "w_down", "norm2_post")}
+            x = jax.jit(_mlp_onto_normed, static_argnums=2)(dense, x, cfg.rms_eps)
+            busiest, held = jnp.zeros((), jnp.int32), ()
+        elif not experts and cfg.residual_multiplier != 1.0:
             dense = {k: p[k] for k in ("norm2", "w_gate", "w_up", "w_down")}
             x = jax.jit(_mlp_onto, static_argnums=2)(dense, x, cfg)
             busiest, held = jnp.zeros((), jnp.int32), ()
@@ -1181,7 +1242,7 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     return x, jnp.stack(stats)
 
 
-def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
+def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = False):
     """``x [B*S, D]``, the embedded tokens of ``batch`` sequences one after
     the other, each at ``pos`` (static: ``[S, 3]`` under the multimodal
     rotary, else ``[S]``), through every layer -> ``(x [B*S, D], stats
@@ -1189,7 +1250,11 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     holder has a share of the experts, :data:`PAIR_STATS` under a
     selection over latent attention, :data:`LINEAR_STATS` after both
     where layers are linear, :data:`AHEAD_STATS` last where a pass goes
-    ahead of the held rows' loop)."""
+    ahead of the held rows' loop). A looped model (``cfg.passes > 1``) goes
+    through the stack that many times (:func:`_passes`): ``x`` is then the
+    last pass's NORMED rows, :data:`LOOP_STATS` follow every other group,
+    and with ``exits`` the exit distribution ``p [R, B*S]`` at every token
+    (:func:`exit_distribution`) comes back third."""
     s = x.shape[0] // batch
     # (under rope_parameters: the FULL layers' table, over the part of a head their rotary turns)
     angles = rotary_angles(pos, cfg.rope_theta, cfg.rope_dim // 2, cfg.mrope_section,
@@ -1210,14 +1275,72 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1):
     if cfg.stream_dtype:
         x = x.astype(cfg.stream_dtype)
     stats = jnp.zeros((cfg.layer_stats,), jnp.float32)
-    for i, p in enumerate(params["layers"]):
-        kind = cfg.layer_kind(i)
-        x, layer_stats = decoder_layer(p, x, by_op.get(kind[0], angles), idx_angles, cfg, kind, batch)
-        stats = stats + layer_stats
     served = jnp.asarray([batch * s, batch], jnp.float32)
+
+    def stack(x, stats):  # every layer once
+        for i, p in enumerate(params["layers"]):
+            kind = cfg.layer_kind(i)
+            x, layer_stats = decoder_layer(p, x, by_op.get(kind[0], angles), idx_angles, cfg, kind, batch)
+            stats = stats + layer_stats
+        return x, stats
+
+    if cfg.passes > 1:
+        x, stats, logits = _passes(params, x, stack, stats, served, cfg, batch)
+        return (x, stats, exit_distribution(logits)) if exits else (x, stats)
+    x, stats = stack(x, stats)
     if cfg.layer_stats > 4:
         return x, jnp.concatenate([stats[:4], served, stats[4:]])
     return x, jnp.concatenate([stats, served])
+
+
+def _pass_end(x, g, gate, eps: float):
+    """The end of a pass on its rows ``x [T, D]``: ``h = rms(x; g)``, the
+    rows the next pass (or the head) reads, in the stream's type, and the
+    exit gate's logit ``h w_e + b_e [T]`` float32 from those rows as they
+    were rounded. One pass over ``[T, D]``: the
+    logit is a row sum of the norm's own output. Jitted by name."""
+    h = rms_norm(x, g, eps).astype(x.dtype)
+    return h, jnp.sum(h.astype(jnp.float32) * gate["w"].astype(jnp.float32), axis=-1) + gate["b"]
+
+
+def exit_distribution(logits):
+    """The exit gate's logits ``[R, ...]``, one a pass, -> ``p [R, ...]``
+    float32: with ``lambda_r = sigmoid(logit_r)``, ``p_r = lambda_r prod_{j<r}
+    (1 - lambda_j)`` for ``r < R`` and ``p_R = prod_{j<R} (1 - lambda_j)``:
+    the probability that the model answers from pass ``r``; sums to 1."""
+    lam = jax.nn.sigmoid(logits.astype(jnp.float32))
+    stayed = jnp.concatenate([jnp.ones_like(lam[:1]), jnp.cumprod(1.0 - lam[:-1], axis=0)])
+    return jnp.concatenate([lam[:-1] * stayed[:-1], stayed[-1:]])
+
+
+def _passes(params, x, stack, stats, served, cfg: DecoderConfig, batch: int):
+    """:func:`trunk` where the stack (``stack(x, stats)``: every layer once)
+    is run ``cfg.passes`` times with the
+    SAME weights -> ``(x, stats, logits)``: the last pass's normed rows, the
+    statistics vector (the layers' places summed over passes and layers,
+    ``tokens`` and ``served`` once a step, every group the step has not 0,
+    :data:`LOOP_STATS` last) and the exit gate's logits ``[R, B*S]``
+    float32. The passes are a LOOP IN THE PROGRAM
+    (``lax.scan`` over the pass: the compiled module is one stack long, one
+    ``while`` around it), its body the stack and
+    then, under the scope ``pass_end``, the final norm and the gate
+    (:func:`_pass_end`); the weights are the loop's invariants, held once;
+    the rotary's tables are built outside, once."""
+    def one_pass(carry, _):
+        x, stats = stack(*carry)
+        with jax.named_scope("pass_end"):
+            x, logit = jax.jit(_pass_end, static_argnums=3)(
+                x, params["norm"], params["exit_gate"], cfg.rms_eps)
+        return (x, stats), logit
+
+    (x, stats), logits = jax.lax.scan(one_pass, (x, stats), None, length=cfg.passes)
+    s = x.shape[0] // batch
+    at_last = exit_distribution(logits[:, s - 1::s])  # [R, B]: each frame's last token
+    left_at = jnp.sum(jnp.arange(1, cfg.passes + 1, dtype=jnp.float32)[:, None] * at_last)
+    groups = 4 + len(SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS)
+    return x, jnp.concatenate([
+        stats[:4], served, stats[4:], jnp.zeros((groups - cfg.layer_stats,), jnp.float32),
+        jnp.stack([jnp.float32(cfg.passes), left_at])]), logits
 
 
 def embed(params, patches, prompt_ids, scale: float = 1.0, dtype=None):
@@ -1243,8 +1366,12 @@ def head_params(params) -> dict:
 
 def logits_of(params, x, cfg: DecoderConfig):
     """Final norm and output head on rows ``x [N, D]`` -> ``[N, V]`` float32
-    (over ``logits_scaling`` where the model has one)."""
-    a = rms_norm(x, params["norm"], cfg.rms_eps).astype(params["norm"].dtype)
+    (over ``logits_scaling`` where the model has one); the head alone on
+    the rows of a looped model, which :func:`trunk` has normed already."""
+    if cfg.passes > 1:  # a looped model's rows come normed from the end of their last pass
+        a = x.astype(params["norm"].dtype)
+    else:
+        a = rms_norm(x, params["norm"], cfg.rms_eps).astype(params["norm"].dtype)
     if cfg.tie_embedding:
         logits = jax.lax.dot_general(a, params["embed"], (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
@@ -1253,11 +1380,13 @@ def logits_of(params, x, cfg: DecoderConfig):
     return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
 
-def frame_hidden(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, threshold: float):
+def frame_hidden(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, threshold: float,
+                 exits: bool = False):
     """``frames [B, P, H, W]`` raw (a frame is a sequence), calibrated,
     cut into patches, embedded and each followed by the prompt, through
     the trunk -> ``(x [B*S, D] at every token, frame after frame, stats
-    [6] float32 in :data:`STEP_STATS`' order)``."""
+    [6] float32 in :data:`STEP_STATS`' order)``, and with ``exits`` a looped
+    model's exit distribution ``[R, B*S]`` third (:func:`trunk`)."""
     from psana_ray_tpu.models.vit import patchify_panels
     from psana_ray_tpu.ops import fused_calibrate
 
@@ -1274,7 +1403,7 @@ def frame_hidden(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, thres
             [embed(p, frame, ids, cfg.embedding_multiplier, cfg.stream_dtype)
              for frame in patchify_panels(x, cfg.patch)]))(
             {"patch": params["patch"], "embed": params["embed"]}, x, prompt_ids)
-    return trunk(params, x, pos, cfg, batch)
+    return trunk(params, x, pos, cfg, batch, exits)
 
 
 def frame_step(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, threshold: float):
@@ -1292,9 +1421,10 @@ def fold_step_stats(metrics, stats) -> None:
     values, eight from a holder of a share of the experts, ten under a
     selection over latent attention or with windowed layers (the band's
     pairs in the selection's places), twelve with linear layers, thirteen
-    where a pass goes ahead of the held rows' loop) to the
+    where a pass goes ahead of the held rows' loop, fifteen from a looped
+    model) to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""
-    for name, value in zip(STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS,
-                           np.asarray(stats, np.float64)):
+    names = STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS + LOOP_STATS
+    for name, value in zip(names, np.asarray(stats, np.float64)):
         metrics.add_counter(name, float(value))

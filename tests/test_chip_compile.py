@@ -602,6 +602,10 @@ PINNED_STEPS = {
     # moved, though every one of them now traces `_projections`, `embed`, `logits_of` and `trunk`
     # through the new fields' branches (taken in Python, before anything is traced)
     "granite4_h_micro_prefill_epix10k2m": "3bf51190128b2aab64f95e6afe217682c5b35f672d3badb3a9179d9397645b37",
+    # pinned in PR 60, which brought it: the seven above were hashed on PR 58's tree first and none
+    # moved (the looped trunk, the sandwich and the gate are branches taken in Python, before
+    # anything is traced; at one pass `trunk` is the code it was, to the letter)
+    "ouro_2p6b_prefill_epix10k2m": "e0ddc2de1a2502d79eaca77547efeb6e0f1c59d8805ac52111283d4ebf6092b9",
 }
 
 
@@ -728,6 +732,47 @@ def test_the_granite_step_compiles_whole_with_its_kernels_under_the_scopes_a_tra
     # the stream between the layers is float32 (`decoder.trunk`), every product's operands bf16
     text = compiled.as_text()
     assert re.search(r"f32\[8704,2048\]", text) and not re.search(r"f32\[8704,8192\]\{[^}]*\} dot\(", text)
+
+
+def test_the_ouro_step_compiles_whole_as_one_loop_around_one_stack_of_layers(one_chip, monkeypatch):
+    """The whole served step of ``ouro_2p6b_prefill_epix10k2m`` at the published
+    sizes, ALL 48 layers four times over and the whole vocabulary, compiled for
+    the described v5e (a quarter of a minute: the 48 layers are one function at
+    one shape, traced and lowered once, and the four passes are a loop IN the
+    program): it fits the chip with the weights held ONCE (5.34 GB of
+    arguments, a third of a GB of temporaries), holds ONE ``while``, whose body
+    has the 48 ``masked_gqa_attention`` call sites (under ``sparse_attn``; the
+    calibration kernel stands outside) and no copy of a weight."""
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, params = _decoder_cell("ouro_2p6b_prefill_epix10k2m")
+    calib = (S((PANELS, H, W), F32), S((PANELS, H, W), F32), S((PANELS, H, W), jnp.uint8))
+    frames = S((cfg["batch_size"], PANELS, H, W), jnp.uint16)
+    ids = S((cfg["prompt_tokens"],), jnp.int32)
+
+    def step(p, c, f, i):
+        return decoder.frame_step(p, c, f, i, cfg=dcfg, threshold=10.0)
+
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), (params, calib, frames, ids))
+    compiled = jax.jit(step).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert 5.3e9 < mem.argument_size_in_bytes < 5.4e9 and mem.temp_size_in_bytes < 0.6e9
+    text = compiled.as_text()
+    loops = re.findall(r" while\(.*body=%?([\w.\-]+)", text)
+    assert len(loops) == 1, loops
+    start = text.index("%" + loops[0] + " (")
+    body = text[start:text.index("\n}\n", start)].splitlines()
+    calls = [line for line in body if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == dcfg.num_layers == 48 and text.count('custom_call_target="tpu_custom_call"') == 49
+    assert all(re.match(r"\s*%masked_gqa_attention", line) and "/sparse_attn/" in line for line in calls)
+    assert any("/pass_end/" in line for line in body)
+    # the weights are the loop's invariants: nothing in the body copies, transposes or converts one
+    weights = {tuple(a.shape) for a in jax.tree.leaves(params) if a.ndim == 2}
+    moved = [line for line in body
+             if (m := re.match(r"\s*%[\w.\-]+ = bf16\[([\d,]+)\]\S* (copy|transpose|convert)\(", line))
+             and tuple(int(n) for n in m.group(1).split(",")) in weights | {w[::-1] for w in weights}]
+    assert not moved, moved[:3]
 
 
 def test_the_laguna_step_compiles_with_its_kernels_where_the_roofline_functions_count_them(

@@ -849,17 +849,26 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
     where the output is gated, ``sigmoid(a W_G) [T, H]`` float32: the queries
     through their normed low rank (``q_lora_rank`` 0: from the normed input,
     of full rank), the keys' and values' per-head parts
-    decompressed from the normed latent, the ONE rotary key (not normed) and
-    each head's rotary query turned; the softmax scale (with YaRN's
-    ``mscale**2``) rides on both parts of q.
+    decompressed from the normed latent and the ONE rotary key (not normed),
+    turned. ``q_rope`` leaves FLOAT32 and UNTURNED, as ``W_uq``'s rotary
+    columns' product wrote it (PR 61): the attention kernel turns its query
+    tile by the step's tables (:func:`turn_tables`), scales it and rounds it
+    once, as ``rotate`` here did. The softmax scale (with YaRN's
+    ``mscale**2``) rides on ``q_nope``; ``q_rope``'s is the kernel's
+    (:func:`_latent_scales`).
 
     Every array leaves the matrix product that makes it in the layout the
     attention kernel reads (PR 48), token-major: ``W_uq``'s COLUMNS are cut
     into the heads' ``dn`` and ``dr`` parts (a 38 MB weight at 64 heads) and
     two products write ``q_nope`` in bf16, scaled in the product's epilogue,
-    and the rotary part; where keys and values are equally wide (``dn ==
-    dv``: every published latent model) ``k_nope`` is the ONE product ``[T,
-    H*(dn + dv)]``, head ``h``'s keys at column block ``2h`` and its values
+    and the rotary part, head-major ``[H, 1, T, dr]`` float32 (the kernel's
+    operand: the transpose is that product's layout, no op; ONE product of
+    three dimensions, ``[T, rq] x [rq, H, dr]``: written two-dimensional
+    and reshaped, XLA compiled six of kimi's seven layers, in the whole
+    step alone, to a column-major product and a copy of it, 1.4 ms a layer:
+    PR 61); where keys and values are equally wide (``dn == dv``: every
+    published latent model) ``k_nope`` is the ONE product ``[T, H*(dn +
+    dv)]``, head ``h``'s keys at column block ``2h`` and its values
     at ``2h + 1``, and ``v`` is ``None``
     (``sparse_attention.masked_gqa_attention`` reads both from it); else
     two arrays cut from it. Cutting the ACTIVATIONS ``[T, H, dn + dr]`` and
@@ -868,12 +877,12 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
     head-major transposes around the kernel eleven array-sized passes a
     layer that computed nothing. One layer alone on the v5e (projections,
     attention, ``W_o``; my chip runs, PR 48): kimi's 66.97 -> 59.84 ms,
-    dsv32's with its indexer 84.32 -> 70.63."""
+    dsv32's with its indexer 84.32 -> 70.63; with the rotary query's turn
+    in the kernel (the ops' sum; my chip runs, PR 61) 60.04 -> 55.40 and
+    70.70 -> 66.45."""
     t, dt = x.shape[0], x.dtype
     h, dn, dr, dv = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    yarn = cfg.rope_yarn
-    scale = cfg.head_dim ** -0.5 * (yarn.softmax_scale if yarn else 1.0)
-    turned = yarn.rotary_scale if yarn else 1.0
+    scale, turned = _latent_scales(cfg)
     a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
     if cfg.q_lora_rank:
         c_q = rms_norm(_mm(a, p["wq_a"]), p["q_a_norm"], cfg.rms_eps).astype(dt)
@@ -881,8 +890,8 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
     else:
         c_q, wq_b = a, p["wq"].reshape(-1, h, dn + dr)
     q_nope = _mm(c_q, wq_b[..., :dn].reshape(-1, h * dn)) * scale
-    q_rope = rotate(_mm(c_q, wq_b[..., dn:].reshape(-1, h * dr)).reshape(t, h, dr), angles)
-    q_rope = q_rope * (scale * turned)
+    q_rope = jnp.einsum("tr,rhd->thd", c_q, wq_b[..., dn:],  # float32: the kernel turns it
+                        preferred_element_type=jnp.float32).reshape(t, -1)
     down = _mm(a, p["wkv_a"])  # [T, latent | the rotary key]
     c_kv = rms_norm(down[:, :cfg.kv_lora_rank], p["kv_a_norm"], cfg.rms_eps).astype(dt)
     k_rope = rotate(down[:, None, cfg.kv_lora_rank:], angles)[:, 0] * turned
@@ -892,12 +901,28 @@ def _latent_projections(p, x, angles, cfg: DecoderConfig):
     else:
         kv = kv.reshape(t, h, dn + dv)
         k_nope, v = kv[..., :dn].reshape(t, -1), kv[..., dn:].reshape(t, -1)
-    out = (q_nope.astype(dt), q_rope.reshape(t, -1).astype(dt), k_nope, k_rope.astype(dt), v)
+    out = (q_nope.astype(dt), q_rope, k_nope, k_rope.astype(dt), v)
     if cfg.indexer_heads:
         out += (a, c_q)  # what an indexer reads
     if cfg.attn_gate:
         out += (jax.nn.sigmoid(_mm(a, p["w_attn_gate"])),)
     return out
+
+
+def _latent_scales(cfg: DecoderConfig) -> Tuple[float, float]:
+    """Latent attention's two scalars: the softmax scale on q (with YaRN's
+    ``mscale**2``) and YaRN's factor on a turned part (its cosines' and sines')."""
+    yarn = cfg.rope_yarn
+    return (cfg.head_dim ** -0.5 * (yarn.softmax_scale if yarn else 1.0),
+            yarn.rotary_scale if yarn else 1.0)
+
+
+def turn_tables(angles):
+    """``angles [T, pairs]`` -> ``([cos | cos], [sin | sin])``, each ``[T,
+    2*pairs]`` float32: what turns a head by the rotate-half convention as
+    ``x * [cos | cos] + [-x2 | x1] * [sin | sin]`` (:func:`rotate`, no half
+    sliced). The causal kernel turns its shared query tile by them."""
+    return tuple(jnp.concatenate([f(angles)] * 2, axis=-1) for f in (jnp.cos, jnp.sin))
 
 
 def gated(x, o, gate, wo):
@@ -921,19 +946,25 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
     selection's mask: still the decompressed form over every causal tile,
     which at a selection of 2,048 of 8,704 does less arithmetic than the
     absorbed form over the selected pairs alone. Nothing array-sized runs
-    between the projections' products, the kernel and ``W_o`` but the
-    rotary's own fusion (PR 48): q, k, v and o are column blocks of
-    token-major arrays, the kernel's ``[B, S, H*dv]`` output is the array
-    ``W_o`` contracts, and only the ``dr``-wide rotary query is head-major
-    (a 64-lane block of ``[S, H*64]`` Mosaic does not take), written so by
-    the fusion that turns it. Under the scopes ``proj`` (both low-rank
-    paths, their norms, the rotary, ``W_o``), ``indexer`` (its three
-    projections and ``select_keys``) and ``latent_attn`` (the attention
-    itself)."""
+    between the projections' products, the kernel and ``W_o`` (PR 48): q,
+    k, v and o are column blocks of token-major arrays, the kernel's ``[B,
+    S, H*dv]`` output is the array ``W_o`` contracts, and only the
+    ``dr``-wide rotary query is head-major (a 64-lane block of ``[S, H*64]``
+    Mosaic does not take), written so by its own product: float32 and
+    unturned, and the kernel turns a query tile of it where it reads it
+    (PR 61; until then XLA turned it in two float32 passes over lanes a half
+    and a quarter full and wrote a third, bf16, head-major: 3.7 GB of
+    traffic a layer for 143 MB of query). The ONE rotary key stays XLA's
+    to turn: every key step reads it. Under the scopes ``proj`` (both
+    low-rank paths, their norms, the key's rotary, ``W_o``), ``indexer``
+    (its three projections and ``select_keys``) and ``latent_attn`` (the
+    attention itself)."""
     s = x.shape[0] // batch
     with jax.named_scope("proj"):
         q_nope, q_rope, k_nope, k_rope, v, *read = jax.jit(
             _latent_projections, static_argnums=3)(p, x, angles, cfg)
+        tables = jax.jit(turn_tables)(angles)  # every layer's: the step's ONE pair
+    scale, turned = _latent_scales(cfg)  # the kernel's, on the rotary query after its turn
     selection = ()  # the mask, where there is one: the kernel's fourth operand
     if cfg.indexer_heads:
         if batch != 1:
@@ -947,14 +978,14 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
         live = causal = batch * sa.causal_tile_count(s)  # every earlier key is attended
     with jax.named_scope("latent_attn"):
         attend = jax.jit(sa.masked_gqa_attention,
-                         static_argnames=("num_kv_heads", "block_q", "block_k"))
+                         static_argnames=("num_kv_heads", "block_q", "block_k", "shared_scale"))
 
         def rows(u):  # of each sequence
             return u if u is None else u.reshape(batch, s, -1)
 
         o = attend(rows(q_nope), rows(k_nope), rows(v), *selection, num_kv_heads=cfg.num_heads,
                    block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile, q_shared=rows(q_rope),
-                   k_shared=rows(k_rope))
+                   k_shared=rows(k_rope), shared_turn=tables, shared_scale=scale * turned)
     with jax.named_scope("proj"):
         if cfg.attn_gate:  # each head's output under its own scalar, then W_o
             x = jax.jit(gated)(x, o, read[-1], p["wo"])

@@ -40,7 +40,9 @@ What is here, and what it is:
   — latent attention's alone in their groups, Laguna's six and nine a
   group — are read and written as column blocks of the token-major arrays
   their products wrote), with values of a width of their own, a part of
-  the score read from ONE key for all heads (latent attention) and a
+  the score read from ONE key for all heads (latent attention; that
+  part of the QUERY comes float32 and unturned where its angle tables
+  come with it, and the kernel turns a query tile of it once) and a
   gate a (token, head) applied where the output is written (Laguna).
   Without a
   mask it is plain causal attention over a batch of sequences; with one
@@ -351,11 +353,29 @@ def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
             o_ref[:, h * d:(h + 1) * d] = out[h * block_q:(h + 1) * block_q].astype(o_ref.dtype)
 
 
+def _turned_tile(x, cos, sin, scale: float, dtype):
+    """A query tile's rotary part ``x [rep, bq, ds]`` float32 as its product
+    wrote it -> ``[rep * bq, ds]`` turned by the tile's angles (``cos, sin
+    [bq, ds]``: ``[cos | cos]`` and ``[sin | sin]``), times ``scale``,
+    rounded once to ``dtype``: ``decoder.rotate``'s arithmetic, ``x * [cos |
+    cos] + [-x2 | x1] * [sin | sin]``, the halves cut at a static lane. One
+    layer's kernel at kimi's shape on the v5e, ms (my chip runs, PR 61): the
+    query turned before it 32.87; this form 33.45, as with the sign in the
+    sine table and no negation; the rotate-half as a product against the
+    signed permutation at the highest precision (six bf16 passes) 34.01."""
+    rep, bq, ds = x.shape
+    half = jnp.concatenate([-x[..., ds // 2:], x[..., :ds // 2]], axis=-1)
+    return ((x * cos[None] + half * sin[None]) * scale).astype(dtype).reshape(rep * bq, ds)
+
+
 def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared, masked,
-                   gated=False, window=None):
+                   gated=False, window=None, turn=None):
     rest = list(rest)
     if shared:  # the part of the score that all heads read from ONE key
         qs_ref, ks_ref = rest.pop(0), rest.pop(0)
+    if turn is not None:  # the shared query part is float32 and UNTURNED: its tile's two tables,
+        # and last of the scratch the turned tile, written at the tile's first key step
+        cos_ref, sin_ref, turned_ref = rest.pop(0), rest.pop(0), rest.pop()
     if masked:  # the selection's tile, one for all heads: it is causal by construction
         mask_ref = rest.pop(0)
     if gated:  # a float32 scalar a (token, head) on the output: the query tile's, [1, bq, H]
@@ -374,15 +394,19 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
         m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+        if turn is not None:  # once a query tile: every key step reads the scratch
+            turned_ref[...] = _turned_tile(qs_ref[...], cos_ref[...], sin_ref[...], turn,
+                                           turned_ref.dtype)
 
     def update(with_diagonal, with_lower_edge=False):
         v = v_ref[...]
         s = jax.lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...], (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if shared:
-            s = s + jax.lax.dot_general(
-                qs_ref[...].reshape(rows, qs_ref.shape[2]), ks_ref[...],
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            qs = (qs_ref[...].reshape(rows, qs_ref.shape[2]) if turn is None
+                  else turned_ref[...])
+            s = s + jax.lax.dot_general(qs, ks_ref[...], (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
         if masked:
             # a row with nothing selected yet has m_new == NEG_INF and p == 1 on its masked
             # entries: the first selected key's alpha == 0 wipes that, and every row selects a
@@ -464,7 +488,7 @@ def _band_tiles(s: int, bq: int, bk: int, window: Optional[int] = None) -> list:
 
 def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool,
                       q_shared=None, k_shared=None, mask=None, window: Optional[int] = None,
-                      out_gate=None):
+                      out_gate=None, shared_turn=None, shared_scale: float = 1.0):
     """The batched form of :func:`masked_gqa_attention`. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
@@ -513,8 +537,20 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     head (latent attention's one rotary key); the shared QUERY part is
     narrow (64) and always head-major with the batch in the rows, ``[G,
     H/G, B*S, ds]`` (PR 48: where ``[B, G, ..]`` cost a reshape and a
-    copy). With ``out_gate [B, S, H]`` (float32: a scalar a token and
-    head, Laguna's sigmoid gate) the output leaves as ``gated`` made it
+    copy). With ``shared_turn`` (``[cos | cos]`` and ``[sin | sin]``, each
+    ``[B*S, ds]`` float32: ``decoder.turn_tables``) ``q_shared`` is the
+    rotary part as its product wrote it, FLOAT32 and UNTURNED, and the
+    kernel turns it: at a query tile's first key step it reads the ``[H/G,
+    bq, ds]`` float32 tile and the tile's rows of the two tables, turns
+    (:func:`_turned_tile`), multiplies by ``shared_scale``, rounds ONCE to
+    the activations' type into a VMEM scratch ``[H/G * bq, ds]``, and
+    every key step of the tile reads the scratch where it read the operand
+    (PR 61: the transpose above is then the product's own layout, and the
+    two lane-padded float32 passes and the bf16 head-major copy XLA made
+    of the turn are gone). A branch taken in Python by the operands given:
+    a call without the tables traces the body it traced. With ``out_gate
+    [B, S, H]`` (float32: a scalar a token and head, Laguna's sigmoid
+    gate) the output leaves as ``gated`` made it
     — the output rounded to its type, times the scalar in float32,
     rounded: equal to the bit — from the tile's last grid step: the
     query tile's scalars ride in as ONE block ``[bq, H]`` of the array as
@@ -553,6 +589,8 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         bq = next(t for t in range(max(min(block_q, s) // mq, 1) * mq, 0, -mq) if s % t == 0)
     if window is not None and (masked or window < 1):
         raise ValueError("a window is a band of at least the query's own key, and the maskless form's")
+    if shared_turn is not None and not shared:
+        raise ValueError("the tables turn the shared query part: there is none")
     pairs = _band_tiles(s, bq, bk, window)
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
 
@@ -591,11 +629,18 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         k, v = (k.reshape(b, s, g, 2, d)[:, :, :, i].reshape(b, s, g * d) for i in (0, 1))
     operands, in_specs = (list(u) for u in zip(
         q_tiles(q, d), kv_tiles(k, d, 0, parts), kv_tiles(v, dv, parts - 1, parts)))
+    scratch = [pltpu.VMEM((rep * bq, 1), jnp.float32), pltpu.VMEM((rep * bq, 1), jnp.float32),
+               pltpu.VMEM((rep * bq, dv), jnp.float32)]
     if shared:
         ds = k_shared.shape[2]
         operands += [jnp.transpose(q_shared.reshape(b * s, g, rep, ds), (1, 2, 0, 3)), k_shared]
         in_specs += [rows_spec(ds),
                      pl.BlockSpec((None, bk, ds), lambda bi, gi, t, qi, kb: (bi, kb[t], 0))]
+    if shared_turn is not None:  # the query tile's rows of [cos | cos] and [sin | sin] [B*S, ds]
+        operands += [table.reshape(b * s, ds) for table in shared_turn]
+        in_specs += [pl.BlockSpec((bq, ds),
+                                  lambda bi, gi, t, qi, kb: (bi * (s // bq) + qi[t], 0))] * 2
+        scratch.append(pltpu.VMEM((rep * bq, ds), q.dtype))  # the tile as the key steps read it
     if masked:
         operands.append(mask)
         in_specs.append(pl.BlockSpec((bq // mq, None, mq, bk),
@@ -605,15 +650,12 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         in_specs.append(pl.BlockSpec((None, 1, bq, g * rep), lambda bi, gi, t, qi, kb: (bi, 0, qi[t], 0)))
     o5 = pl.pallas_call(
         functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked,
-                          gated=out_gate is not None, window=window),
+                          gated=out_gate is not None, window=window,
+                          turn=None if shared_turn is None else float(shared_scale)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g, len(pairs)),
             in_specs=in_specs, out_specs=place_spec(dv) if in_place(dv) else major_spec(dv),
-            scratch_shapes=[
-                pltpu.VMEM((rep * bq, 1), jnp.float32),
-                pltpu.VMEM((rep * bq, 1), jnp.float32),
-                pltpu.VMEM((rep * bq, dv), jnp.float32),
-            ]),
+            scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct(
             (b, 1, s, g * rep * dv) if in_place(dv) else (b, g, rep, s, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -663,7 +705,7 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
 def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Optional[int] = None,
                          block_k: int = 512, interpret: Optional[bool] = None,
                          q_shared=None, k_shared=None, window: Optional[int] = None,
-                         out_gate=None) -> jax.Array:
+                         out_gate=None, shared_turn=None, shared_scale: float = 1.0) -> jax.Array:
     """``q [S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [S, G*d]``,
     ``mask`` from :func:`select_keys` -> ``o [S, H*d]``: softmax attention
     of every query head over the keys its query selected, query head ``h``
@@ -684,7 +726,11 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     ``v`` may be ``None`` where ``k [B, S, G*2d]`` holds each head's keys
     and then its values (read in place: no slice), and a score may have a second
     part, ``q_shared [B, S, H*ds] . k_shared [B, S, ds]``, whose key is ONE
-    for all heads (latent attention: the rotary key). With a mask (``B``
+    for all heads (latent attention: the rotary key); with ``shared_turn``
+    (two float32 tables ``[B*S, ds]``, ``[cos | cos]`` and ``[sin | sin]``
+    of every token's angles) ``q_shared`` is float32 and not yet turned,
+    and the kernel turns each query tile of it, times ``shared_scale``,
+    rounded once to ``q``'s type. With a mask (``B``
     1: a selection is one sequence's) it is the selection over latent
     attention, masked-dense as the form above, in the mask's key tile.
     With ``window`` (maskless only) a query attends to the keys ``t -
@@ -702,7 +748,8 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
             block_q, block_k = causal_tiles(q.shape[1], q.shape[2] // (g * d), block_q, block_k,
                                             window)
         return _causal_attention(q, k, v, int(num_kv_heads), block_q, block_k,
-                                 _interpret(interpret), q_shared, k_shared, mask, window, out_gate)
+                                 _interpret(interpret), q_shared, k_shared, mask, window, out_gate,
+                                 shared_turn, shared_scale)
     if q_shared is not None or out_gate is not None or v.shape[1] != k.shape[1]:
         raise ValueError("a shared key part, a value width of its own and an output gate are "
                          "the batched form's")

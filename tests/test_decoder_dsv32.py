@@ -22,6 +22,7 @@ from benchmark.reference.keye_decoder import select as ref_select
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
+from xla_turn import TURNS, assert_the_kernel_s_turn_is_xla_s, turned_by_xla
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -267,8 +268,9 @@ def test_the_two_indexers_differ_by_fields_and_keye_s_are_the_defaults():
     (272, 8, 68, 16), (272, 8, 68, 8), (272, 4, 68, 1088), (136, 4, 68, 4),
 ])
 @pytest.mark.parametrize("rep", [1, 2])
+@TURNS
 def test_masked_causal_kernel_with_a_shared_key_part_and_values_of_another_width(s, mq, bk, bq,
-                                                                                 rep):
+                                                                                 rep, turn):
     rng = np.random.default_rng(mq + bk + rep)
     g, d, ds, dv = 2, 16, 8, 24
     h = g * rep
@@ -277,6 +279,9 @@ def test_masked_causal_kernel_with_a_shared_key_part_and_values_of_another_width
     k = jnp.asarray(rng.standard_normal((1, s, g * d)), jnp.float32)
     ks = jnp.asarray(rng.standard_normal((1, s, ds)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, s, g * dv)), jnp.float32)
+    raw = qs
+    if turn is not None:  # (the rest of the test on the query XLA turned)
+        qs, tables = turned_by_xla(raw, turn, h)
     # a selection: each query's 12 best of random scores, itself among them or not
     dense = np.asarray(ref_select(jnp.asarray(rng.standard_normal((s, s)), jnp.float32),
                                   jnp.arange(s), 12))
@@ -284,6 +289,11 @@ def test_masked_causal_kernel_with_a_shared_key_part_and_values_of_another_width
     np.testing.assert_array_equal(np.asarray(sa.mask_to_dense(mask)), dense)
     got = sa.masked_gqa_attention(q, k, v, mask, num_kv_heads=g, block_q=bq, block_k=1088,
                                   q_shared=qs, k_shared=ks)
+    if turn is not None:  # the same call on the float32 product and the tables
+        by_xla, got = got, sa.masked_gqa_attention(
+            q, k, v, mask, num_kv_heads=g, block_q=bq, block_k=1088, q_shared=raw, k_shared=ks,
+            shared_turn=tables, shared_scale=turn)
+        assert_the_kernel_s_turn_is_xla_s(got, by_xla, raw, qs, tables, turn, h, 3e-6)
     kh, vh = (jnp.repeat(x.reshape(1, s, g, -1), rep, axis=2) for x in (k, v))
     score = (jnp.einsum("bthd,bshd->bhts", q.reshape(1, s, h, d), kh, precision="highest")
              + jnp.einsum("bthd,bsd->bhts", qs.reshape(1, s, h, ds), ks, precision="highest"))

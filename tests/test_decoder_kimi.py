@@ -19,6 +19,7 @@ from benchmark.reference import kimi_k2_decoder as ref
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
+from xla_turn import TURNS, assert_the_kernel_s_turn_is_xla_s, turned_by_xla
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -171,9 +172,10 @@ def _plain_attention(q, k, v, qs, ks, g, allowed):
                       precision="highest").reshape(b, s, -1)
 
 
+@TURNS
 @pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16), (48, 32), (96, 96)])
 @pytest.mark.parametrize("rep", [1, 2])
-def test_causal_kernel_with_a_shared_key_part_and_values_of_another_width(bq, bk, rep):
+def test_causal_kernel_with_a_shared_key_part_and_values_of_another_width(bq, bk, rep, turn):
     rng = np.random.default_rng(bq + rep)
     b, s, g, d, ds, dv = 2, 96, 2, 16, 8, 24
     h = g * rep
@@ -182,8 +184,16 @@ def test_causal_kernel_with_a_shared_key_part_and_values_of_another_width(bq, bk
     k = jnp.asarray(rng.standard_normal((b, s, g * d)), jnp.float32)
     ks = jnp.asarray(rng.standard_normal((b, s, ds)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((b, s, g * dv)), jnp.float32)
+    raw = qs
+    if turn is not None:
+        qs, tables = turned_by_xla(raw, turn, h)
     got = sa.masked_gqa_attention(q, k, v, num_kv_heads=g, block_q=bq, block_k=bk,
                                   q_shared=qs, k_shared=ks)
+    if turn is not None:
+        by_xla, got = got, sa.masked_gqa_attention(
+            q, k, v, num_kv_heads=g, block_q=bq, block_k=bk, q_shared=raw, k_shared=ks,
+            shared_turn=tables, shared_scale=turn)
+        assert_the_kernel_s_turn_is_xla_s(got, by_xla, raw, qs, tables, turn, h, 3e-6)
     want = _plain_attention(q, k, v, qs, ks, g, np.tril(np.ones((s, s), bool)))
     assert got.shape == (b, s, h * dv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
@@ -249,6 +259,63 @@ def test_causal_kernel_reads_heads_of_whole_lane_blocks_where_their_products_wro
     np.testing.assert_array_equal(np.asarray(major[..., d:]), 0.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(major[..., :d].reshape(b, s, -1)),
                                atol=3e-6)
+
+
+@pytest.mark.parametrize("rep", [1, 2])
+@pytest.mark.parametrize("case", ["maskless_batch_of_two", "masked_one_sequence"])
+def test_the_kernel_turns_its_bf16_tile_to_the_bit_as_the_projections_did(case, rep):
+    """Kimi's widths (heads of 128 + 64, values of 128) in bf16, the cells'
+    type: the kernel given the float32 product and the tables against the
+    kernel given the query :func:`decoder.rotate` turned and rounded, in
+    tiles of 128 over sequences of 256, heads alone in their groups and two
+    a group, maskless over a batch and under a selection's mask: the turned
+    tiles are equal to the bit, and so is every output."""
+    b, s, g, d, ds = (2 if case == "maskless_batch_of_two" else 1), 256, 2, 128, 64
+    h, dt = g * rep, jnp.bfloat16
+    _, k, v, _, ks, _ = _latent_operands(61, b, s, g, d, ds)
+    rng = np.random.default_rng(rep)
+    q, raw = (jnp.asarray(rng.standard_normal((b, s, h * width)), jnp.float32) * scale
+              for width, scale in ((d, 0.1), (ds, 0.3)))
+    turned, tables = turned_by_xla(raw, 0.1147, h)
+    selection = ()
+    if case == "masked_one_sequence":  # half of the earlier keys, and the query's own
+        allowed = np.tril(np.random.default_rng(5).random((s, s)) < 0.5) | np.eye(s, dtype=bool)
+        selection = (jnp.asarray(allowed.reshape(s // 32, 32, s // 128, 128).transpose(0, 2, 1, 3),
+                                 jnp.int8),)
+
+    def attend(qs, **turn):
+        return sa.masked_gqa_attention(q.astype(dt), k.astype(dt), v.astype(dt), *selection,
+                                       num_kv_heads=g, block_q=128, block_k=128, q_shared=qs,
+                                       k_shared=ks.astype(dt), **turn)
+
+    in_kernel = attend(raw, shared_turn=tables, shared_scale=0.1147)
+    assert in_kernel.dtype == dt and in_kernel.shape == (b, s, h * d)
+    assert assert_the_kernel_s_turn_is_xla_s(in_kernel, attend(turned.astype(dt)), raw,
+                                             turned.astype(dt), tables, 0.1147, h, 2e-2)
+    with pytest.raises(ValueError, match="there is none"):  # tables with no shared part to turn
+        sa.masked_gqa_attention(q, k, v, num_kv_heads=g, shared_turn=tables)
+
+
+def test_a_call_without_the_tables_traces_the_kernel_it_traced():
+    """LFM2's call (no shared part, heads of 64, four a group) and kimi's
+    as it was until PR 61 (the shared query turned by XLA, bf16): their
+    jaxprs, the kernel's body in them, hashed on PR 60's tree and on PR
+    61's — equal. The turn is a branch taken in Python by the operands
+    given; :data:`tests.test_chip_compile.PINNED_STEPS` blanks a kernel's
+    body, this does not."""
+    import hashlib
+
+    S = jax.ShapeDtypeStruct
+    wide, narrow = S((4, 8704, 32 * 64), jnp.bfloat16), S((4, 8704, 8 * 64), jnp.bfloat16)
+    lfm2 = jax.make_jaxpr(lambda q, k, v: sa.masked_gqa_attention(
+        q, k, v, num_kv_heads=8, block_q=1088, block_k=1088, interpret=False))(wide, narrow, narrow)
+    heads = S((2, 8704, 64 * 128), jnp.bfloat16)
+    kimi = jax.make_jaxpr(lambda q, k, v, qs, ks: sa.masked_gqa_attention(
+        q, k, v, num_kv_heads=64, block_q=1088, block_k=1088, q_shared=qs, k_shared=ks,
+        interpret=False))(heads, heads, heads, S((2, 8704, 64 * 64), jnp.bfloat16),
+                          S((2, 8704, 64), jnp.bfloat16))
+    assert [hashlib.sha256(str(j).encode()).hexdigest()[:16] for j in (lfm2, kimi)] == [
+        "f3a740c1d0e1fb7b", "7a235d7ede244698"]
 
 
 @pytest.mark.parametrize("rep", [1, 4])

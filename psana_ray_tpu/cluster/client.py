@@ -711,48 +711,6 @@ class ClusterClient:
                 self._stream_window = window
         return self
 
-    # -- live knob surface (ISSUE 15 autotune) -----------------------------
-    @property
-    def put_window(self) -> int:
-        with self._lock:
-            return self._put_window
-
-    def set_put_window(self, n: int) -> None:
-        """Fan the windowed-PUT depth out to every live partition
-        connection; future partition dials inherit it. A partition
-        mid-failover is skipped (its replacement dials with the new
-        value)."""
-        n = max(1, int(n))
-        with self._lock:
-            self._put_window = n
-            clients = list(self._clients.values())
-        for c in clients:
-            try:
-                c.set_put_window(n)
-            except (TransportClosed, OSError):
-                continue  # failover path re-dials with the stored value
-
-    @property
-    def stream_window(self) -> int:
-        with self._lock:
-            return self._stream_window
-
-    def set_stream_window(self, n: int) -> None:
-        """Fan the stream credit window out to every SUBSCRIBED
-        partition connection (a live 'M' resize each); partitions not
-        yet streaming pick the stored value up at subscribe."""
-        n = max(1, int(n))
-        with self._lock:
-            self._stream_window = n
-            clients = list(self._clients.values())
-        for c in clients:
-            try:
-                c.set_stream_window(n)
-            except RuntimeError:
-                continue  # not subscribed yet: subscribes with the new value
-            except (TransportClosed, OSError):
-                continue
-
     def _ensure_joined(self) -> None:
         # guarded-by-caller: _lock
         if self._session is not None and not self._joined:

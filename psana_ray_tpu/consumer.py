@@ -120,12 +120,6 @@ class DataReader:
                 raise DataReaderError(str(e)) from e
         return self
 
-    @property
-    def queue(self) -> Any:
-        """The underlying transport handle once connected (None before)
-        — what the autotune knob factories wrap (ISSUE 15)."""
-        return self._queue
-
     def close(self):
         q = self._queue
         self._queue = None
@@ -310,7 +304,6 @@ def main(argv=None):
         "seconds — the consumer-side mirror of the producer's end-of-run "
         "summary; 0 = off",
     )
-    from psana_ray_tpu.autotune import add_autotune_args
     from psana_ray_tpu.obs import (
         add_history_args,
         add_metrics_args,
@@ -330,7 +323,6 @@ def main(argv=None):
     add_cluster_args(p, consumer=True)
     add_wire_args(p)
     add_tenant_args(p)
-    add_autotune_args(p)
     p.add_argument(
         "--cursor_path", default=None,
         help="persist a StreamCursor (contiguous per-shard watermark of "
@@ -447,7 +439,6 @@ def main(argv=None):
     from psana_ray_tpu.obs.stages import STAGE_DEQUEUE
 
     monitor = None
-    autotune = None
     try:
         replay_from = None
         if a.replay is not None:
@@ -476,40 +467,6 @@ def main(argv=None):
                 except Exception as e:  # noqa: BLE001 — depth is optional
                     log.debug("queue monitor unavailable: %s", e)
             configure_from_args(a, "consumer", queue=monitor)
-            # autotune (ISSUE 15): consumer-side knobs — the stream
-            # credit window (when --stream subscribed), the wire codec
-            # on pull-mode connections, and the recv-pool retention
-            # floor — judged by the measured consume rate. An explicit
-            # --stream_window / --wire_codec pins its knob.
-            if a.autotune != "off":
-                from psana_ray_tpu.autotune import (
-                    Objective,
-                    configure_autotune_from_args,
-                )
-                from psana_ray_tpu.autotune.knobs import (
-                    bufpool_retention_knob,
-                    stream_window_knob,
-                    wire_codec_knob,
-                )
-                from psana_ray_tpu.utils.bufpool import BufferPool
-
-                knobs = [bufpool_retention_knob(BufferPool.default())]
-                pinned = {}
-                if a.stream:
-                    knobs.append(stream_window_knob(reader.queue))
-                    if a.stream_window != p.get_default("stream_window"):
-                        pinned["stream_window"] = "--stream_window set explicitly"
-                else:
-                    # a streamed connection's codec is decided at
-                    # (re)connect; only pull-mode renegotiates live
-                    knobs.append(wire_codec_knob(reader.queue))
-                    # an explicit name AND an explicit "none" are both
-                    # operator decisions ("auto" delegates)
-                    if a.wire_codec and a.wire_codec != "auto":
-                        pinned["wire_codec_on"] = "--wire_codec set explicitly"
-                autotune = configure_autotune_from_args(
-                    a, knobs, Objective("consumer.frames_total"), pinned=pinned
-                )
             try:
                 for rec in reader.iter_records(stop=_should_stop):
                     t_rec = time.monotonic()
@@ -565,8 +522,6 @@ def main(argv=None):
         return 1
     finally:
         heartbeat_done.set()
-        if autotune is not None:
-            autotune.stop()
         if history is not None:
             history.stop()
         if heartbeat is not None:

@@ -42,18 +42,15 @@ _BATCH_IDS = itertools.count(1)
 
 
 class DrainControl:
-    """Live dials for :func:`batches_from_queue` (ISSUE 15 autotune):
-    ``chunk`` is the max items per drain round trip (None = the
-    batcher's batch size, the pre-autotune behavior) and ``poll_s`` the
+    """The live dial of :func:`batches_from_queue`: ``poll_s`` is the
     starvation poll interval (None = the call's ``poll_interval_s``).
-    The drain loop re-reads both every iteration — plain attribute
-    reads, GIL-atomic — so the autotune controller adjusts them from
-    its own thread with no lock on the hot path."""
+    The drain loop re-reads it every turn — a plain attribute read — so
+    the consumer that owns the loop's thread (``SfxPipeline.run``, from
+    its hooks) shortens the wait while a device step runs."""
 
-    __slots__ = ("chunk", "poll_s")
+    __slots__ = ("poll_s",)
 
-    def __init__(self, chunk: Optional[int] = None, poll_s: Optional[float] = None):
-        self.chunk = chunk
+    def __init__(self, poll_s: Optional[float] = None):
         self.poll_s = poll_s
 
 
@@ -332,10 +329,8 @@ def batches_from_queue(
     global shard is covered, and duplicate markers (copies meant for
     sibling consumers) are re-enqueued.
 
-    ``control`` (a :class:`DrainControl`) makes the pop chunk size and
-    the poll interval LIVE dials the autotune controller adjusts while
-    this loop runs (ISSUE 15); the batch SHAPE stays fixed regardless —
-    pjit compiles per shape, so only the drain granularity moves.
+    ``control`` (a :class:`DrainControl`) makes the poll interval a LIVE
+    dial the consumer adjusts from its hooks while this loop runs.
 
     Every turn of the loop is three consecutive phases (``utils.trace.
     phase``): ``queue_wait`` (the pop: it blocks up to the poll interval
@@ -377,6 +372,10 @@ def batches_from_queue(
     Without either hook a turn does what it did (``InfeedPipeline``,
     the fan-in, the gateway pass none).
     """
+    if not poll_interval_s > 0:  # 0 would spin on the pop, a negative wait means nothing
+        raise ValueError(f"poll_interval_s must be positive, got {poll_interval_s!r}")
+    if control is None:
+        control = DrainControl()
     batcher: Optional[FrameBatcher] = None
     starved_since: Optional[float] = None
     tally = EosTally()
@@ -413,19 +412,13 @@ def batches_from_queue(
         while True:
             if stop is not None and stop.is_set():
                 return
-            # live dials (autotune): re-read per iteration, default to
-            # the call's own parameters when no controller is attached
-            chunk = batch_size
-            poll_s = poll_interval_s
-            if control is not None:
-                if control.chunk:
-                    chunk = max(1, int(control.chunk))
-                if control.poll_s:
-                    poll_s = float(control.poll_s)
+            # the live dial: re-read every turn, the call's own
+            # parameter where the consumer set none
+            poll_s = control.poll_s or poll_interval_s
             closed = False
             with in_queue_wait as ph:
                 try:
-                    items = pop(chunk, timeout=poll_s)
+                    items = pop(batch_size, timeout=poll_s)
                 except TransportWedged:
                     # a peer crashed mid-claim and frames are stuck behind
                     # the wedge: this is data loss, NOT a clean end of

@@ -178,21 +178,6 @@ class DevicePrefetcher:
             del arrays  # this thread's reference goes with the wait
             TRACER.phase_span(tail.batch_id, SPAN_H2D, t0, tail.t1, tail.frames)
 
-    def set_prefetch_depth(self, n: int) -> int:
-        """Resize the staging buffer LIVE (ISSUE 15 autotune knob): the
-        queue's bound moves under its own mutex and any put blocked on
-        the old bound is woken. Shrinking never drops batches — already-
-        staged items stay; the bound applies to new puts. Returns the
-        depth now in effect. Callers that preallocate batch arenas must
-        respect the ``FrameBatcher.n_buffers`` aliasing contract —
-        :meth:`InfeedPipeline.set_prefetch_depth` enforces it."""
-        n = max(1, int(n))
-        with self._buf.mutex:
-            self._buf.maxsize = n
-            self._buf.not_full.notify_all()
-        self.prefetch_depth = n
-        return n
-
     def close(self, timeout: float = 5.0):
         """Stop the prefetch thread and release buffered batches."""
         self._stop.set()
@@ -315,7 +300,6 @@ class InfeedPipeline:
             )
         self.queue = queue
         self.batch_size = batch_size
-        self._batcher_buffers = batcher_buffers
         self.metrics = metrics if metrics is not None else PipelineMetrics(queue=queue)
         self._obs_name = f"infeed.{name}" if name else None
         if self._obs_name:
@@ -343,22 +327,6 @@ class InfeedPipeline:
 
     def __iter__(self) -> Iterator[Batch]:
         return iter(self._prefetcher)
-
-    @property
-    def prefetch_depth(self) -> int:
-        return self._prefetcher.prefetch_depth
-
-    def set_prefetch_depth(self, n: int) -> int:
-        """Live prefetch-depth dial (ISSUE 15 autotune), clipped to the
-        batch-arena aliasing bound when arenas are pooled: a pooled
-        Batch is overwritten ``batcher_buffers`` batches later, so the
-        depth may never grow past ``batcher_buffers - 4`` (the
-        ``FrameBatcher.n_buffers`` contract this constructor validates
-        the static way). Returns the depth now in effect."""
-        n = max(1, int(n))
-        if self._batcher_buffers > 0:
-            n = min(n, max(1, self._batcher_buffers - 4))
-        return self._prefetcher.set_prefetch_depth(n)
 
     def close(self):
         self._prefetcher.close()

@@ -65,12 +65,6 @@ ROOT_HOME = {
     "ServingGateway.dispatch_once": "serving/gateway.py",
     "ServingGateway.run": "serving/gateway.py",
     "ServingGateway.serve_queue": "serving/gateway.py",
-    # the autotune controller's actuation path (ISSUE 15): every knob
-    # setter runs on the controller tick — a setter that sleeps or
-    # waits unboundedly stalls tuning AND (for client-side knobs under
-    # the client lock) the data path sharing that lock
-    "HillClimber.tick": "autotune/controller.py",
-    "KnobRegistry.apply": "autotune/knobs.py",
     # the continuous profiler's sampling loop (ISSUE 16): it runs ~97
     # times a second in EVERY pipeline process — a sleep or unbounded
     # wait here freezes the profile AND holds the GIL budget hostage

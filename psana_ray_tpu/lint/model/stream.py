@@ -41,7 +41,7 @@ class StreamModel(Model):
     WIRE_OPS = frozenset({"_OP_STREAM", "_OP_STREAM_ACK", "_OP_BYE"})
     WIRE_STATUSES = frozenset({"_ST_OK"})
     MODE = "stream"
-    MODE_LEGAL_OPS = frozenset({"_OP_STREAM", "_OP_STREAM_ACK", "_OP_BYE"})
+    MODE_LEGAL_OPS = frozenset({"_OP_STREAM_ACK", "_OP_BYE"})
 
     def __init__(self, requeue_at_head=True, enforce_window=True,
                  requeue_lost=True):

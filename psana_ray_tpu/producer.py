@@ -217,10 +217,9 @@ class ProducerRuntime:
     # -- rendezvous (parity: producer.py:35-71) ---------------------------
     def bootstrap(self):
         if self._queue is not None:
-            # idempotent: the CLI may bootstrap early (autotune knobs
-            # wrap the data client) and run()/the tracer path bootstrap
-            # again — re-opening would orphan the connection the knobs
-            # actuate while the pumps send on a fresh one
+            # idempotent: the CLI's tracer path bootstraps early (the
+            # clock-anchor exchange rides the data client) and run()
+            # bootstraps again — re-opening would orphan that connection
             return self._queue
         t = self.config.transport
         self._queue = open_queue(t, role="producer", registry=self.registry)
@@ -412,7 +411,6 @@ def parse_arguments(argv=None):
     p.add_argument("--num_consumers", type=int, default=1)
     p.add_argument("--max_steps", type=int, default=None)
     p.add_argument("--log_level", default="INFO")
-    from psana_ray_tpu.autotune import add_autotune_args
     from psana_ray_tpu.obs import (
         add_history_args,
         add_metrics_args,
@@ -427,7 +425,6 @@ def parse_arguments(argv=None):
     add_profile_args(p)
     add_cluster_args(p)
     add_wire_args(p, producer=True)
-    add_autotune_args(p)
     p.add_argument("--num_shards", type=int, default=1, help="local ingest workers")
     p.add_argument("--num_events", type=int, default=1024, help="synthetic events")
     p.add_argument(
@@ -571,28 +568,6 @@ def main(argv=None):
     from psana_ray_tpu.obs.tracing import configure_from_args, exchange_anchors
 
     tracer = configure_from_args(args, "producer", queue=monitor)
-    # autotune (ISSUE 15): close the loop on the producer-side knobs —
-    # the windowed-PUT depth and the wire codec on/off — judged by the
-    # measured produce rate. An explicitly-set --wire_codec pins that
-    # knob (the operator's value is a decision, not a default).
-    autotune = None
-    if args.autotune != "off":
-        from psana_ray_tpu.autotune import Objective, configure_autotune_from_args
-        from psana_ray_tpu.autotune.knobs import put_window_knob, wire_codec_knob
-
-        q = runtime.bootstrap()
-        pinned = {}
-        wc = config.transport.wire_codec
-        # an explicit codec name AND an explicit "none" are both
-        # operator decisions ("auto" delegates, "" is the default)
-        if wc and wc != "auto":
-            pinned["wire_codec_on"] = "--wire_codec set explicitly"
-        autotune = configure_autotune_from_args(
-            args,
-            [put_window_knob(q), wire_codec_knob(q)],
-            Objective("producer.frames_total"),
-            pinned=pinned,
-        )
     try:
         if tracer is not None and monitor is None:
             # clock alignment against the queue server (tcp opcode 'A'):
@@ -604,8 +579,6 @@ def main(argv=None):
             exchange_anchors(runtime._queue)
         runtime.run(block=True)
     finally:
-        if autotune is not None:
-            autotune.stop()
         if history is not None:
             history.stop()
         if metrics_server is not None:

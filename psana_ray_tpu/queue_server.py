@@ -174,7 +174,6 @@ def main(argv=None):
             "empty or this many seconds pass, THEN closes (0 = abrupt)"
         ),
     )
-    from psana_ray_tpu.autotune import add_autotune_args
     from psana_ray_tpu.obs import (
         add_history_args,
         add_metrics_args,
@@ -186,7 +185,6 @@ def main(argv=None):
     add_trace_args(p)
     add_history_args(p)
     add_profile_args(p)
-    add_autotune_args(p)
     p.add_argument(
         "--stall_poll_s", type=float, default=1.0,
         help="queue-health poll interval for the stall detector "
@@ -239,11 +237,11 @@ def main(argv=None):
             p.error("--workers is incompatible with --replicate_peers "
                     "(replica links bind queues directly to one serving "
                     "process; run replicated servers single-worker)")
-        return _run_workers(a, dur_defaults)
-    return _serve(a, dur_defaults)
+        return _run_workers(a)
+    return _serve(a)
 
 
-def _run_workers(a, dur_defaults) -> int:
+def _run_workers(a) -> int:
     """The parent of a ``--workers N`` fleet: resolve the shared port,
     fork N workers (each builds its full server in :func:`_serve`),
     respawn the dead, forward shutdown. The parent itself serves
@@ -264,7 +262,7 @@ def _run_workers(a, dur_defaults) -> int:
 
     def _worker_entry(worker_id):
         ctx = WorkerContext(worker_id, a.workers, sock_dir)
-        _serve(a, dur_defaults, worker_ctx=ctx, port=port)
+        _serve(a, worker_ctx=ctx, port=port)
 
     sup = WorkerSupervisor(a.workers, _worker_entry).start()
     if a.port_file:
@@ -293,9 +291,9 @@ def _run_workers(a, dur_defaults) -> int:
     return 0
 
 
-def _serve(a, dur_defaults, worker_ctx=None, port=None) -> int:
+def _serve(a, worker_ctx=None, port=None) -> int:
     """One full queue-server process: backing, TCP server, obs plane,
-    autotune, signal-driven drain. With ``worker_ctx`` this is one
+    signal-driven drain. With ``worker_ctx`` this is one
     worker of a ``--workers`` fleet: it reuseport-binds the shared
     port, owns only its rendezvous partitions, and tags its telemetry
     with the worker id."""
@@ -308,13 +306,9 @@ def _serve(a, dur_defaults, worker_ctx=None, port=None) -> int:
     queue_factory = None
     group_store_path = None
     replication = None
-    # late-bound autotune registry hook: named queues open AFTER the
-    # daemon starts, and each durable one registers its own dials
-    tune_box = {"daemon": None}
     if a.durable_dir:
         import os
 
-        from psana_ray_tpu.autotune.knobs import fsync_batch_knob, ram_items_knob
         from psana_ray_tpu.storage import DurableRingBuffer, SegmentLog
 
         os.makedirs(a.durable_dir, exist_ok=True)
@@ -354,27 +348,6 @@ def _serve(a, dur_defaults, worker_ctx=None, port=None) -> int:
                     ns, name, depth, qdir, log.committed(""),
                     ", TORN TAIL repaired" if log.torn_tail_repaired else "",
                 )
-            daemon = tune_box["daemon"]
-            if daemon is not None and (ns, name) != ("default", "default"):
-                # per-named-queue dials (ISSUE 17): each durable log
-                # tunes fsync batching and spill threshold to ITS
-                # producer, suffixed so names never collide
-                reg = daemon.controller.registry
-                try:
-                    reg.register(
-                        fsync_batch_knob(log, name=f"fsync_batch_n:{ns}/{name}"),
-                        "--fsync_batch_n set explicitly"
-                        if a.fsync_batch_n != dur_defaults.fsync_batch_n
-                        else None,
-                    )
-                    reg.register(
-                        ram_items_knob(q, name=f"ram_items:{ns}/{name}"),
-                        "--ram_items set explicitly"
-                        if a.ram_items != dur_defaults.ram_items
-                        else None,
-                    )
-                except ValueError:
-                    pass  # same name re-opened in-process: dials exist
             return q
 
         queue_factory = _durable_backing
@@ -515,45 +488,6 @@ def _serve(a, dur_defaults, worker_ctx=None, port=None) -> int:
         MetricsRegistry.default().register("stalls", stall)
         stall.start()
 
-    # autotune (ISSUE 15): server-side knobs — fsync batching and the
-    # RAM spill threshold on the default durable queue (plus one dial
-    # pair PER NAMED durable queue as they open), the relay recv-pool
-    # retention floor, and the recommendation-only data-plane width —
-    # judged by the measured relay rate (gets/s on the default queue).
-    # Explicitly-set flags pin their knobs: the operator's value is a
-    # decision, not a default (a flag passed AT its default value reads
-    # as unset — documented).
-    autotune = None
-    if a.autotune != "off":
-        from psana_ray_tpu.autotune import Objective, configure_autotune_from_args
-        from psana_ray_tpu.autotune.knobs import (
-            bufpool_retention_knob,
-            fsync_batch_knob,
-            ram_items_knob,
-            workers_knob,
-        )
-        from psana_ray_tpu.utils.bufpool import BufferPool
-
-        knobs = [
-            bufpool_retention_knob(BufferPool.default()),
-            # declines on a single-core box; recommendation-only
-            workers_knob(current=a.workers),
-        ]
-        pinned = {}
-        if a.workers > 1:
-            pinned["workers"] = "--workers set explicitly"
-        if a.durable_dir and getattr(backing, "log", None) is not None:
-            knobs.append(fsync_batch_knob(backing.log))
-            knobs.append(ram_items_knob(backing))
-            if a.fsync_batch_n != dur_defaults.fsync_batch_n:
-                pinned["fsync_batch_n"] = "--fsync_batch_n set explicitly"
-            if a.ram_items != dur_defaults.ram_items:
-                pinned["ram_items"] = "--ram_items set explicitly"
-        autotune = configure_autotune_from_args(
-            a, knobs, Objective("queue_server.default.gets"), pinned=pinned
-        )
-        tune_box["daemon"] = autotune
-
     done = threading.Event()
     force = threading.Event()
 
@@ -584,8 +518,6 @@ def _serve(a, dur_defaults, worker_ctx=None, port=None) -> int:
             logger.warning(
                 "drain window ended with %d item(s) still queued", server.depth()
             )
-    if autotune is not None:
-        autotune.stop()
     if stall is not None:
         stall.stop()
     if history is not None:

@@ -430,7 +430,6 @@ class SfxPipeline:
         cursor_save_every: int = 32,
         stop=None,
         max_events: Optional[int] = None,
-        drain_control=None,
     ) -> int:
         """Drain ``queue`` to EOS (or ``stop``/``max_events``) through the
         pipeline; returns events written this run.
@@ -472,11 +471,10 @@ class SfxPipeline:
           back at the transport after one readback, ``fold`` and
           ``append``; ``metrics.drained_ahead`` counts such batches.
           While a dispatched batch is undrained the pop's wait ends
-          every millisecond (the live ``poll_s`` dial; a caller's
-          ``drain_control`` keeps its own), so the step's end is seen
-          within one: at frame arrivals alone it is seen up to a frame
-          period late, and how late follows where the step time happens
-          to fall between two frames.
+          every millisecond (the live ``poll_s`` dial), so the step's
+          end is seen within one: at frame arrivals alone it is seen up
+          to a frame period late, and how late follows where the step
+          time happens to fall between two frames.
 
         The in-flight batch is always drained before returning (it was
         dispatched, and the producer will not re-send it), so ``stop``
@@ -494,13 +492,11 @@ class SfxPipeline:
         from psana_ray_tpu.infeed.batcher import DrainControl, batches_from_queue
 
         start = self.n_events
-        # the pop's live dials: the caller's (autotune), or the loop's own
-        dials = drain_control if drain_control is not None else DrainControl()
+        dials = DrainControl()  # the pop's live dial
         ask_every_s = min(poll_interval_s, 0.001)  # after a running step
 
         def _watch_the_step(running: bool) -> None:
-            if drain_control is None:
-                dials.poll_s = ask_every_s if running else None
+            dials.poll_s = ask_every_s if running else None
 
         def _save_cursor(wrote: int) -> None:
             if (self.n_events // cursor_save_every) != (
@@ -659,7 +655,6 @@ def main(argv=None):
         help="allow truncating an existing --output on a FRESH run "
         "(resumed runs — cursor already has positions — always append)",
     )
-    from psana_ray_tpu.autotune import add_autotune_args
     from psana_ray_tpu.obs import (
         add_history_args,
         add_metrics_args,
@@ -669,7 +664,6 @@ def main(argv=None):
     from psana_ray_tpu.transport.addressing import add_cluster_args
 
     add_cluster_args(ap, consumer=True)
-    add_autotune_args(ap)
 
     add_metrics_args(ap)
     add_trace_args(ap)
@@ -822,8 +816,6 @@ def main(argv=None):
     from psana_ray_tpu.obs import configure_tracing_from_args
 
     configure_tracing_from_args(a, "sfx", queue=monitor)
-    autotune = None
-    drain_control = None
     try:
         with CxiWriter(a.output, max_peaks=a.max_peaks, mode=writer_mode) as writer:
             # features already cross-checked above (one source of truth:
@@ -834,33 +826,6 @@ def main(argv=None):
             MetricsRegistry.default().register("sfx", pipe.metrics)
             if monitor is not None:
                 pipe.metrics.attach_queue(monitor)
-            # autotune (ISSUE 15): the drain chunk/poll dials plus the
-            # recv-pool retention floor, judged by the measured event
-            # rate. The drain-chunk knob sits in the `serving` group —
-            # it would defer to a bound gateway's SloPolicy.
-            if a.autotune != "off":
-                from psana_ray_tpu.autotune import (
-                    Objective,
-                    configure_autotune_from_args,
-                )
-                from psana_ray_tpu.autotune.knobs import (
-                    bufpool_retention_knob,
-                    drain_chunk_knob,
-                    drain_poll_knob,
-                )
-                from psana_ray_tpu.infeed.batcher import DrainControl
-                from psana_ray_tpu.utils.bufpool import BufferPool
-
-                drain_control = DrainControl(chunk=a.batch, poll_s=0.01)
-                autotune = configure_autotune_from_args(
-                    a,
-                    [
-                        drain_chunk_knob(drain_control),
-                        drain_poll_knob(drain_control),
-                        bufpool_retention_knob(BufferPool.default()),
-                    ],
-                    Objective("sfx.frames_total"),
-                )
             import time
 
             t0 = time.monotonic()
@@ -871,7 +836,6 @@ def main(argv=None):
                 cursor_save_every=a.cursor_save_every,
                 stop=stop_ev,  # SIGINT -> clean stop between batches
                 max_events=a.max_events,
-                drain_control=drain_control,
             )
             dt = time.monotonic() - t0
             log.info(
@@ -886,8 +850,6 @@ def main(argv=None):
         log.error("%s", e)
         return 1
     finally:
-        if autotune is not None:
-            autotune.stop()
         if history is not None:
             history.stop()
         if metrics_server is not None:

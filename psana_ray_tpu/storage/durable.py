@@ -109,7 +109,8 @@ class DurableRingBuffer(RingBuffer):
     ):
         super().__init__(maxsize=maxsize, name=name)
         self.log = log
-        self.ram_items = int(ram_items) if ram_items else int(maxsize)
+        # None / 0 = the queue's maxsize; a negative count is held at 1
+        self.ram_items = max(1, int(ram_items)) if ram_items else int(maxsize)
         self.commit_on_get = commit_on_get
         # lazy_spill: deliver spilled entries as SpilledRecord handles
         # instead of eagerly decoding (the evloop server's kernel
@@ -212,15 +213,6 @@ class DurableRingBuffer(RingBuffer):
         else:
             self._outstanding[id(item)] = entry
         return item
-
-    def set_ram_items(self, n: int) -> None:
-        """Live spill-threshold dial (ISSUE 15 autotune): RAM-resident
-        records admitted before new puts spill to log-only entries.
-        Applies to FUTURE puts — shrinking never evicts already-resident
-        entries (they drain through delivery), so the transition is
-        monotone and alloc-free."""
-        with self._lock:
-            self.ram_items = max(1, int(n))
 
     # -- replicated ack floor support (ISSUE 11) ---------------------------
     def put(self, item: Any) -> bool:

@@ -119,8 +119,8 @@ class SegmentLog:
         self.name = name
         self.segment_bytes = int(segment_bytes)
         self.retain_segments = max(1, int(retain_segments))
-        self.fsync = fsync
-        self.fsync_batch_n = max(1, int(fsync_batch_n))
+        self._fsync = fsync
+        self._fsync_batch_n = max(1, int(fsync_batch_n))
         self._lock = threading.RLock()
         self._segments: List[Segment] = []  # oldest..active  # guarded-by: _lock
         self._free: List[Segment] = []  # recycled, awaiting reuse  # guarded-by: _lock
@@ -249,15 +249,17 @@ class SegmentLog:
                 seg.close()
                 os.unlink(seg.path)
 
-    def set_fsync_batch_n(self, n: int) -> None:
-        """Live fsync-batching dial (ISSUE 15 autotune): appends per
-        fsync under the ``batch`` policy. The pending-appends counter is
-        untouched, so a shrink takes effect at the very next append and
-        a grow simply stretches the current batch — durability
-        semantics (what a machine crash can lose) scale with the value,
-        exactly as the ``--fsync_batch_n`` flag documents."""
-        with self._lock:
-            self.fsync_batch_n = max(1, int(n))
+    @property
+    def fsync(self) -> str:
+        """The flush policy: given at construction, fixed for the life
+        of the log (what a machine crash can lose is what the
+        configuration states, not a value that moves at run time)."""
+        return self._fsync
+
+    @property
+    def fsync_batch_n(self) -> int:
+        """Appends per fsync under the ``batch`` policy; fixed likewise."""
+        return self._fsync_batch_n
 
     # -- append ------------------------------------------------------------
     def append(self, item) -> int:

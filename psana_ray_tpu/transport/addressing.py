@@ -105,8 +105,8 @@ def add_wire_args(parser, producer: bool = False) -> None:
         "connection from a brief link-rate probe at connect — "
         "compression on through slow links (tunnels), off on fast LANs "
         "where the codec only burns CPU — re-decided on every "
-        "reconnect (codec_auto_decision flight breadcrumb either way; "
-        "works with --autotune off). A name advertises exactly that "
+        "reconnect (codec_auto_decision flight breadcrumb either way). "
+        "A name advertises exactly that "
         "codec (pure-numpy shuffle-rle always; lz4/bitshuffle when "
         "installed). The server picks; old servers degrade the "
         "connection to uncompressed. Default: off (wire bytes "

@@ -692,13 +692,9 @@ class _EvConn:
                 f"bad opcode {op:#04x} on replica connection"
             )
         if self.stream is not None:
-            # a streamed connection carries only acks, window resizes
-            # ('M' again — ISSUE 15 autotune), and BYE upstream
+            # a streamed connection carries only acks and BYE upstream
             if op == _OP_STREAM_ACK[0]:
                 self._expect(8, self._on_stream_ack)
-                return
-            if op == _OP_STREAM[0]:
-                self._expect(4, self._stream_resize)
                 return
             if op == _OP_BYE[0]:
                 self._finish_stream(clean=True)
@@ -1073,26 +1069,6 @@ class _EvConn:
         FLIGHT.record("stream_open", port=self.srv.port, window=window)
         self.loop.add_stream(self)
         self._await_op()  # from here: only 'K'/'F' upstream
-
-    def _stream_resize(self) -> None:
-        """'M' on an already-streamed connection (ISSUE 15 autotune):
-        resize the credit window in place — seq/acked/unacked state is
-        untouched, so the budget shifts immediately and the next 'K'
-        replenishes against the new window. No response, exactly like
-        the subscribe."""
-        (window,) = struct.unpack_from("<I", self._hdr)
-        window = max(1, min(int(window), 4096))
-        st = self.stream
-        old, st.window = st.window, window
-        if window != old:
-            STREAM.resized(old, window)
-            FLIGHT.record(
-                "stream_resize", port=self.srv.port, old=old, window=window
-            )
-            if window > old:
-                # new credit: the pump may have pushes waiting on budget
-                self.loop.queue_touched(self.queue)
-        self._await_op()
 
     def _on_stream_ack(self) -> None:
         (seq,) = struct.unpack_from("<Q", self._hdr)

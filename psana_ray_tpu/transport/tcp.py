@@ -162,9 +162,8 @@ link can stay full of in-flight work:
   frames are held server-side and RE-ENQUEUED (head placement) when the
   connection dies — at-least-once crash-redelivery, exactly as
   in-flight GETs. A streamed connection carries ONLY pushes downstream
-  and 'K'/'F' upstream — plus 'M' again as a live credit-window RESIZE
-  (ISSUE 15 autotune: the budget shifts in place, no response, seq
-  state untouched); any other opcode on it is a protocol error.
+  and 'K'/'F' upstream; any other opcode on it (a second 'M' included)
+  is a protocol error.
 - windowed PUT ('W'): up to W sequence-numbered puts in flight before
   the client blocks reading statuses. The server enqueues each (waiting
   for space — backpressure arrives as delayed acks) and answers
@@ -347,12 +346,6 @@ class StreamTelemetry:
     def closed(self, window: int):
         with self._lock:
             self.credit_window -= window
-
-    def resized(self, old: int, new: int):
-        """Live credit-window resize (ISSUE 15 autotune): adjust the
-        aggregate gauge without counting a new subscription."""
-        with self._lock:
-            self.credit_window += new - old
 
     def pushed(self, n: int):
         with self._lock:
@@ -1193,88 +1186,12 @@ class TcpQueueClient:
         # guarded-by-caller: _lock
         return _wire_encode(item, self._codec, self._pool)
 
-    # -- live knob surface (ISSUE 15 autotune) -----------------------------
-    @property
-    def put_window(self) -> int:
-        with self._lock:
-            return self._put_window
-
-    def set_put_window(self, n: int) -> None:
-        """Resize the windowed-PUT pipeline depth live (autotune knob).
-        Purely client-side state: a shrink simply waits for more acks
-        before the next send; a grow admits more in-flight puts."""
-        with self._lock:
-            self._put_window = max(1, int(n))
-
-    @property
-    def stream_window(self) -> int:
-        with self._lock:
-            st = self._stream
-            return st.window if st is not None else 0
-
-    def set_stream_window(self, n: int) -> bool:
-        """Resize the stream credit window live (autotune knob): one 'M'
-        with the new credit count on the streamed connection — the
-        server adjusts its budget in place (no response, exactly like
-        the subscribe), and the next cumulative 'K' replenishes against
-        the new window. Requires an open subscription."""
-        n = max(1, min(int(n), 4096))
-        with self._lock:
-            if self._replay_args is not None:
-                # replay is pull-mode: no stream to resize (and the
-                # server kills 'M' on a replay connection)
-                raise RuntimeError(
-                    "set_stream_window on a replay connection — replay "
-                    "is pull-mode"
-                )
-            if self._stream is None:
-                raise RuntimeError(
-                    "set_stream_window needs an open stream subscription "
-                    "(call stream_open first)"
-                )
-            st = self._stream
-            if n == st.window:
-                return True
-            st.window = n  # before the send: a reconnect resubscribes with it
-            try:
-                self._sock.sendall(_OP_STREAM + struct.pack("<I", n))
-            except (ConnectionError, socket.timeout, OSError) as e:
-                self._reconnect(e)  # resubscribes at the NEW window
-            return True
-
     @property
     def codec_name(self) -> Optional[str]:
         """The negotiated wire codec's name, or None when raw."""
         with self._lock:
             codec = self._codec
         return getattr(codec, "name", None) if codec is not None else None
-
-    def renegotiate_codec(self, names=None) -> bool:
-        """Flip wire compression live (autotune knob): renegotiate this
-        connection's codec via a fresh 'Z' exchange — ``names`` is a
-        codec list to advertise, None/empty renegotiates down to raw.
-        Refused on streamed connections (a mid-push 'Z' would desync
-        the push framing; the reconnect-time auto decision owns those)
-        and a no-op after an old-peer refusal latched. Bounded: any
-        outstanding windowed-put acks drain under the probe deadline
-        first (their responses precede the 'Z' answer in the byte
-        stream). Returns True when a codec is now negotiated."""
-        if self._stream is not None:
-            raise RuntimeError(
-                "renegotiate_codec on a streamed connection — the codec "
-                "there is re-decided at (re)connect, not mid-push"
-            )
-        if names:
-            names = [str(n) for n in names]
-            for n in names:
-                get_codec(n)  # fail fast on unknown names
-        deadline = time.monotonic() + self.PROBE_DEADLINE_S
-        with self._lock:
-            if self._codec_refused:
-                return False
-            self._codec_names = names or None
-            self._retrying(self._negotiate_raw, deadline)
-            return self._codec is not None
 
     def _reconnect(self, cause: BaseException, deadline: Optional[float] = None):
         """Re-dial with exponential backoff and replay the named binding.

@@ -116,7 +116,7 @@ class BufferPool:
     _default_lock = threading.Lock()
 
     def __init__(self, min_per_class: int = 4, debug: Optional[bool] = None):
-        self.min_per_class = min_per_class
+        self.min_per_class = max(0, int(min_per_class))
         if debug is None:
             debug = os.environ.get("PSANA_RAY_BUFPOOL_DEBUG", "") not in ("", "0")
         self.debug = debug
@@ -214,15 +214,6 @@ class BufferPool:
             if len(free) < keep:
                 free.append(buf)
                 self._bytes_pooled += cls_bytes
-
-    def set_min_per_class(self, n: int) -> None:
-        """Live retention-floor dial (ISSUE 15 autotune): the minimum
-        free buffers each size class keeps regardless of the adaptive
-        peak. A shrink trims lazily on the next release (the existing
-        decay path); a grow retains more on future releases — no
-        allocation happens here."""
-        with self._lock:
-            self.min_per_class = max(0, int(n))
 
     def leaks(self) -> List[str]:
         """Acquisition stacks of outstanding leases (debug mode only)."""

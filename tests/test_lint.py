@@ -19,6 +19,7 @@ import json
 import pathlib
 import subprocess
 import sys
+import time
 
 import pytest
 
@@ -36,22 +37,23 @@ _STEM = {name: name.replace("-", "_") for name in REGISTRY}
 # ---------------------------------------------------------------------------
 
 def test_shipped_tree_is_clean_under_full_registry():
+    cpu0 = time.process_time()
     result = run_lint()
+    cpu_s = time.process_time() - cpu0
     assert result.ok, "lint findings on the shipped tree:\n" + "\n".join(
         f.render() for f in result.findings
     )
     assert result.files_scanned > 50  # the whole package
     assert set(result.checkers_run) == set(REGISTRY)
-    assert result.duration_s < 25.0, (
-        f"full registry took {result.duration_s:.2f}s — the budget keeps "
-        f"lint viable as a pre-commit/tier-1 gate (15 s through ISSUE 9; "
-        f"ISSUE 10's flow layer — CFGs with exception edges, the resolved "
-        f"call graph, three whole-program analyses — measures 8.5-10 s "
-        f"idle on this CPU-share-throttled box, so 25 s keeps the same "
-        f"~1.6x loaded-box headroom the old budget carried. Scale it "
-        f"with the tree, never delete it; the <2 s incremental gate is "
-        f"--changed, pinned below)"
-    )
+    # the budget keeps lint viable as a pre-commit/tier-1 gate. It is CPU
+    # time of this process (one thread does all of it), not the wall
+    # clock: a machine that runs six test workers stretches the second,
+    # not the first. 9.8 s on this box (15 s budget through ISSUE 9;
+    # ISSUE 10's flow layer — CFGs with exception edges, the resolved
+    # call graph, three whole-program analyses — took it here). Scale it
+    # with the tree, never delete it; the incremental gate is --changed,
+    # pinned below.
+    assert cpu_s < 25.0, f"full registry took {cpu_s:.2f}s of CPU"
 
 
 def test_the_default_scan_is_the_package_and_nothing_beside_it():
@@ -670,39 +672,6 @@ def test_blocking_checker_covers_the_gateway_dispatch():
     assert "get_batch_stream" in SEED_EDGES["serve_queue"]
 
 
-def test_blocking_checker_covers_the_autotune_actuation_path():
-    """ISSUE 15 satellite: the autotune controller's actuation path —
-    the controller tick and the knob-registry apply every setter runs
-    under — is inside the blocking-hot-path audited graph. A sleep
-    pacing a setter or the tick must flag (fixture pair), and the REAL
-    autotune package must scan clean (setters are lock-guarded
-    assignments or deadline-bounded client exchanges; pacing lives in
-    the daemon's stoppable Event wait)."""
-    bad = FIXTURES / "autotune_actuate_bad.py"
-    good = FIXTURES / "autotune_actuate_good.py"
-    flagged = run_lint(paths=[bad], checkers=["blocking-hot-path"], use_allowlist=False)
-    hits = [
-        f for f in flagged.findings
-        if "time.sleep" in f.message
-        and ("KnobRegistry.apply" in f.message or "HillClimber.tick" in f.message)
-    ]
-    assert len(hits) >= 2, flagged.findings
-    clean = run_lint(paths=[good], checkers=["blocking-hot-path"], use_allowlist=False)
-    assert not clean.findings, clean.findings
-    # ...and the shipped controller + knob factories are in the audited
-    # set with no findings
-    autotune_dir = REPO_ROOT / "psana_ray_tpu" / "autotune"
-    real = run_lint(
-        paths=sorted(autotune_dir.glob("*.py")),
-        checkers=["blocking-hot-path"],
-    )
-    assert not real.findings, real.findings
-    from psana_ray_tpu.lint.checkers.blocking import ROOTS
-
-    assert "HillClimber.tick" in ROOTS
-    assert "KnobRegistry.apply" in ROOTS
-
-
 def test_blocking_checker_covers_the_flame_sampler():
     """ISSUE 16 satellite: the continuous profiler's sampling loop —
     it fires ~97 times a second in EVERY pipeline process — is inside
@@ -754,15 +723,6 @@ def test_sample_path_marker_covers_the_flame_sampler():
         checkers=["telemetry-discipline"],
     )
     assert not real.findings, real.findings
-
-
-def test_telemetry_discipline_covers_the_autotune_source():
-    """ISSUE 15 satellite: the ``autotune`` obs source (the knob
-    registry's snapshot) is a lock-owning snapshot class — the
-    telemetry-discipline checker must cover it and find it clean."""
-    knobs = REPO_ROOT / "psana_ray_tpu" / "autotune" / "knobs.py"
-    result = run_lint(paths=[knobs], checkers=["telemetry-discipline"])
-    assert not result.findings, result.findings
 
 
 def test_event_loop_checker_roots_resolve_and_real_loop_is_clean():
@@ -844,14 +804,11 @@ def test_flow_layer_protocol_pair_scans_clean_and_reconstructs():
     for op, rec in d["ops"].items():
         assert not rec["handler_missing"], op
         assert rec["senders"], f"{op} has no client sender"
-    # the streamed mode allows exactly ack + bye + the 'M' window
-    # RESIZE (ISSUE 15 autotune: same header as the subscribe, applied
-    # to the open stream) on both sides
+    # the streamed mode allows exactly ack + bye on both sides (a
+    # second subscribe is a protocol violation like any other opcode)
     stream = d["modes"]["stream"]
     assert stream["opened_by"] == "_OP_STREAM"
-    assert stream["server_allowed"] == {
-        "_OP_STREAM_ACK", "_OP_BYE", "_OP_STREAM",
-    }
+    assert stream["server_allowed"] == {"_OP_STREAM_ACK", "_OP_BYE"}
     assert stream["client_attr"] == "_stream"
     # replay is pull-mode: stream subscribe is illegal server-side
     replay = d["modes"]["replay"]
@@ -927,15 +884,18 @@ def test_changed_mode_is_fast_and_clean():
 
     touched = REPO_ROOT / "psana_ray_tpu" / "utils" / "metrics.py"
     companions = [REPO_ROOT / rel for rel in INCREMENTAL_COMPANIONS]
+    cpu0 = time.process_time()
     result = run_lint(paths=[touched, *companions], use_cache=True)
+    cpu_s = time.process_time() - cpu0
     assert not result.findings, result.findings
-    # measures 1.1-1.5 s idle on this box; pinned with the same ~2.5x
-    # loaded-box headroom the full-tree budget carries (a tier-1 run
-    # sharing the core was observed to push this to ~3 s)
-    assert result.duration_s < 4.0, (
-        f"changed-files run took {result.duration_s:.2f}s — seconds-not-"
-        f"tens-of-seconds is what makes --changed viable as a pre-commit "
-        f"hook"
+    # what makes it seconds and not tens of seconds is what it reads: the
+    # touched file and the companions, not the tree. 1.7-2.0 s of this
+    # process's CPU on this box (not the wall clock, which a tier-1 run
+    # sharing the core was seen to push to ~3 s).
+    assert result.files_scanned == 1 + len(companions)
+    assert cpu_s < 4.0, (
+        f"changed-files run took {cpu_s:.2f}s of CPU — seconds-not-tens-"
+        f"of-seconds is what makes --changed viable as a pre-commit hook"
     )
 
 

@@ -67,15 +67,17 @@ def test_full_profile_exhausts_every_model_with_zero_counterexamples():
             f"truncated run proves nothing"
         )
         assert r.states > 50  # a trivial state space would prove nothing
-    # the budget claim: the whole fleet exhausts in seconds, not minutes
-    assert sum(r.duration_s for r in results) < 10.0
+    # the budget claim: the whole fleet exhausts in seconds, not minutes —
+    # held on the states explored (1,917 today; a box explores a few
+    # hundred thousand a second), which a loaded machine does not move
+    assert sum(r.states for r in results) < 100_000
 
 
 def test_quick_profile_exhausts_too():
     # the registry entry runs this profile inside the lint budget
     for r in run_models("quick"):
         assert r.violation is None and r.exhausted
-        assert r.duration_s < 1.0
+        assert r.states < 10_000  # 240 the largest today; states, not seconds
 
 
 # ---------------------------------------------------------------------------
@@ -161,7 +163,8 @@ def test_unmodeled_wire_op_is_a_finding(dialogue):
 def test_mode_legal_set_drift_is_a_finding(dialogue):
     models = all_models()
     victim = next(m for m in models if m.name == "stream")
-    victim.MODE_LEGAL_OPS = frozenset({"_OP_STREAM_ACK", "_OP_BYE"})
+    # a model that still allows a second subscribe on an open stream
+    victim.MODE_LEGAL_OPS = frozenset({"_OP_STREAM", "_OP_STREAM_ACK", "_OP_BYE"})
     drift = list(check_drift(dialogue, models, full=True))
     assert any("legal-op drift" in m for m, _h in drift)
 
@@ -217,9 +220,12 @@ def test_workers_is_a_protocol_companion(dialogue):
         evloop._OPS[op] for op in evloop._WORKER_LOCAL_OPS
     }
     assert local_handlers <= handlers
-    # the 'M' stream-adoption state must stay in the stream mode legal
-    # set the models pin
-    assert "_OP_STREAM" in dialogue["modes"]["stream"]["server_allowed"]
+    # an adopted connection's replayed 'M' is a subscribe like any other:
+    # dispatched on a connection that is not streamed yet, where it
+    # OPENS the stream mode (inside the mode it is not legal)
+    assert "_op_stream" in handlers
+    assert dialogue["modes"]["stream"]["opened_by"] == "_OP_STREAM"
+    assert "_OP_STREAM" not in dialogue["modes"]["stream"]["server_allowed"]
 
 
 # ---------------------------------------------------------------------------

@@ -398,3 +398,200 @@ class TestBatchedProducerPath:
         assert sender.flush()
         t.join()
         assert [r.event_idx for r in drained] == list(range(8))  # FIFO kept
+
+
+# ---------------------------------------------------------------------------
+# what a CLI offers, it uses; what a layer is given, it checks there
+# ---------------------------------------------------------------------------
+
+def _parser_of(cli, monkeypatch):
+    """The ``ArgumentParser`` a CLI module builds, caught at its
+    ``parse_args``: nothing past the parser's construction runs."""
+    import argparse
+    import importlib
+
+    class _Built(Exception):
+        pass
+
+    def caught(parser, args=None, namespace=None):
+        raise _Built(parser)
+
+    monkeypatch.setattr(argparse.ArgumentParser, "parse_args", caught)
+    mod = importlib.import_module(f"psana_ray_tpu.{cli}")
+    with pytest.raises(_Built) as built:
+        (parse_arguments if cli == "producer" else mod.main)([])
+    return mod, built.value.args[0]
+
+
+def _namespace_reads(path):
+    """Every ``a.<name>`` / ``args.<name>`` read and every ``getattr(x,
+    "<name>", ...)`` of a module, by ``ast``."""
+    import ast
+
+    names = set()
+    for node in ast.walk(ast.parse(path.read_text())):
+        if (
+            isinstance(node, ast.Attribute) and isinstance(node.ctx, ast.Load)
+            and isinstance(node.value, ast.Name) and node.value.id in ("a", "args")
+        ):
+            names.add(node.attr)
+        elif (
+            isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
+            and node.func.id == "getattr" and len(node.args) >= 2
+            and isinstance(node.args[1], ast.Constant)
+        ):
+            names.add(node.args[1].value)
+    return names
+
+
+def _defined_in(path):
+    import ast
+
+    return [n.name for n in ast.parse(path.read_text()).body if isinstance(n, ast.FunctionDef)]
+
+
+@pytest.mark.parametrize("cli", ["producer", "consumer", "queue_server", "sfx"])
+def test_every_flag_a_cli_defines_is_read(cli, monkeypatch):
+    """Every ``dest`` of the parser is read: by the module that built
+    the parser, or — a flag of a shared ``add_*_args`` — by the module
+    that defines it (its ``configure_*_from_args`` takes the namespace).
+    A flag nobody reads is an option the operator sets to no effect."""
+    import pathlib
+
+    mod, parser = _parser_of(cli, monkeypatch)
+    dests = {action.dest for action in parser._actions} - {"help"}
+    assert len(dests) > 20, "the parser was not the CLI's own"
+    package = pathlib.Path(mod.__file__).parent
+    shared = {
+        path for path in package.rglob("*.py")
+        if path != pathlib.Path(mod.__file__)
+        and any(fn.startswith("add_") and fn.endswith("_args") for fn in _defined_in(path))
+    }
+    read = _namespace_reads(pathlib.Path(mod.__file__))
+    read_there = set().union(*(_namespace_reads(path) for path in shared))
+    dead = sorted(d for d in dests if d not in read and d not in read_there)
+    assert not dead, f"{cli} defines flags nothing reads: {dead}"
+
+
+def _dial_put_window(tmp_path):
+    from psana_ray_tpu.transport.tcp import TcpQueueClient, TcpQueueServer
+
+    srv = TcpQueueServer(RingBuffer(16), host="127.0.0.1").serve_background()
+    try:
+        for given in (0, -7):  # held at 1: stop-and-wait, and it still delivers
+            c = TcpQueueClient("127.0.0.1", srv.port, put_window=given)
+            assert c._put_window == 1
+            for i in range(3):
+                assert c.put_pipelined(FrameRecord(0, i, np.zeros((1, 2, 2), np.float32), 1.0))
+                assert len(c._put_unacked) <= 1
+            assert c.flush_puts()
+            assert [c.get_wait(timeout=2.0).event_idx for _ in range(3)] == [0, 1, 2]
+            c.disconnect()
+    finally:
+        srv.shutdown()
+
+
+def _dial_stream_window(tmp_path):
+    from psana_ray_tpu.transport.tcp import STREAM, TcpQueueClient, TcpQueueServer
+
+    srv = TcpQueueServer(RingBuffer(16), host="127.0.0.1").serve_background()
+    try:
+        for given, taken in ((0, 1), (-5, 1), (10**6, 4096)):  # the server's own bounds
+            base = STREAM.stats()["credit_window"]
+            c = TcpQueueClient("127.0.0.1", srv.port)
+            c.stream_open(window=given)
+            deadline = time.monotonic() + 5.0
+            while STREAM.stats()["credit_window"] == base and time.monotonic() < deadline:
+                time.sleep(0.01)
+            assert STREAM.stats()["credit_window"] - base == taken
+            c.disconnect()
+            while STREAM.stats()["credit_window"] != base and time.monotonic() < deadline:
+                time.sleep(0.01)
+    finally:
+        srv.shutdown()
+
+
+def _dial_prefetch_depth(tmp_path):
+    from psana_ray_tpu.infeed import InfeedPipeline
+    from psana_ray_tpu.infeed.pipeline import DevicePrefetcher
+
+    for given in (0, -1):
+        with pytest.raises(ValueError, match="prefetch_depth"):
+            DevicePrefetcher(iter(()), prefetch_depth=given)
+        with pytest.raises(ValueError, match="prefetch_depth"):
+            InfeedPipeline(RingBuffer(4), batch_size=2, prefetch_depth=given, place_on_device=False)
+    # over range: past what the pooled arenas can keep alive
+    with pytest.raises(ValueError, match="prefetch_depth"):
+        InfeedPipeline(RingBuffer(4), batch_size=2, prefetch_depth=5, batcher_buffers=8)
+
+
+def _dial_retain_segments(tmp_path):
+    from psana_ray_tpu.storage import SegmentLog
+
+    for given in (0, -3):  # a log keeps its active segment whatever it is told
+        log = SegmentLog(str(tmp_path / f"r{given}"), segment_bytes=1 << 16, retain_segments=given, fsync="none")
+        assert log.retain_segments == 1
+        log.close()
+
+
+def _dial_fsync_batch_n(tmp_path):
+    from psana_ray_tpu.storage import SegmentLog
+
+    for given in (0, -4):  # never rarer than asked: held at every append
+        log = SegmentLog(str(tmp_path / f"f{given}"), segment_bytes=1 << 16, fsync="batch", fsync_batch_n=given)
+        assert log.fsync_batch_n == 1
+        log.close()
+
+
+def _dial_ram_items(tmp_path):
+    from psana_ray_tpu.storage import DurableRingBuffer, SegmentLog
+
+    for given, taken in ((None, 8), (0, 8), (-2, 1), (3, 3)):
+        log = SegmentLog(str(tmp_path / f"q{given}"), segment_bytes=1 << 16, fsync="none")
+        q = DurableRingBuffer(log, maxsize=8, ram_items=given, name="t")
+        assert q.ram_items == taken
+        for i in range(5):
+            assert q.put(FrameRecord(0, i, np.zeros((1, 2, 2), np.uint16), 1.0))
+        assert q.stats()["spilled"] == max(0, 5 - taken)
+        assert [q.get().event_idx for _ in range(5)] == list(range(5))  # spilled or not, in order
+        log.close()
+
+
+def _dial_min_per_class(tmp_path):
+    from psana_ray_tpu.utils.bufpool import BufferPool
+
+    for given, taken in ((-3, 0), (0, 0), (2, 2)):
+        pool = BufferPool(min_per_class=given)
+        assert pool.min_per_class == taken
+        pool.lease(1024).release()  # the floor only ever adds to the adaptive peak
+        assert pool.stats()["leases"] == 0
+
+
+def _dial_poll_interval_s(tmp_path):
+    from psana_ray_tpu.infeed.batcher import batches_from_queue
+
+    for given in (0, 0.0, -0.01):  # 0 would spin on the pop
+        with pytest.raises(ValueError, match="poll_interval_s"):
+            # (max_wait_s: a loop that took the value would end, not spin)
+            next(batches_from_queue(RingBuffer(4), 2, poll_interval_s=given, max_wait_s=0.05), None)
+
+
+_DIALS = {
+    "put_window": _dial_put_window,
+    "stream_window": _dial_stream_window,
+    "prefetch_depth": _dial_prefetch_depth,
+    "retain_segments": _dial_retain_segments,
+    "fsync_batch_n": _dial_fsync_batch_n,
+    "ram_items": _dial_ram_items,
+    "min_per_class": _dial_min_per_class,
+    "poll_interval_s": _dial_poll_interval_s,
+}
+
+
+@pytest.mark.parametrize("dial", list(_DIALS))
+def test_a_dial_out_of_range_is_clamped_or_refused_where_it_is_given(dial, tmp_path):
+    """No layer keeps a setter for a plane above it, so a value's only
+    fence is where it is given — the constructor, or the server that
+    takes it off the wire: zero, negative and over-range values meet
+    the clamp or the refusal documented there."""
+    _DIALS[dial](tmp_path)

@@ -953,16 +953,6 @@ def test_while_a_step_runs_the_pop_is_asked_to_end_every_millisecond(small_varia
     assert s.pipe.metrics.drained_ahead.count == 2
 
 
-def test_a_callers_dials_are_left_alone(small_variables, monkeypatch):
-    from psana_ray_tpu.infeed.batcher import DrainControl
-
-    dials = DrainControl(poll_s=0.05)
-    s = _Scripted(small_variables, monkeypatch, [B, 0, "ready", 0, 0, B])
-    assert s.run(poll_interval_s=0.25, drain_control=dials) == 2 * B
-    assert set(s.queue.timeouts) == {0.05} and dials.poll_s == 0.05
-    assert s.pipe.metrics.drained_ahead.count == 1  # drained ahead all the same
-
-
 @pytest.mark.parametrize("gap_s", [0.0, 0.002], ids=["as-fast-as-the-ring-accepts", "paced"])
 def test_soak_over_a_real_ring_loses_and_doubles_nothing(small_variables, gap_s):
     """Several hundred batches through a 16-slot ``shm://`` ring and the

@@ -253,3 +253,30 @@ class TestDurableRingBuffer:
         assert len(cur.next_batch(100)) == 5
         assert q.size() == 5  # live depth untouched
         assert [r.event_idx for r in q.get_batch(8, timeout=0)] == list(range(5))
+
+
+@pytest.mark.parametrize("policy", ["none", "batch", "always"])
+def test_the_flush_policy_is_the_one_given_for_the_life_of_the_log(tmp_path, monkeypatch, policy):
+    """What a machine crash can lose is what the configuration states:
+    N appends make exactly the syncs the policy says (counted, not
+    timed), and the log has no dial that moves it afterwards."""
+    from psana_ray_tpu.storage.segment import Segment
+
+    syncs = []
+    real_sync = Segment.sync
+    monkeypatch.setattr(Segment, "sync", lambda seg: (syncs.append(seg), real_sync(seg))[1])
+    n, every = 24, 8
+    said = {"none": 0, "batch": n // every, "always": n}[policy]
+    log = _log(tmp_path, fsync=policy, fsync_batch_n=every)
+    for i in range(n):
+        log.append(_rec(i))
+    assert len(syncs) == said
+    assert (log.fsync, log.fsync_batch_n) == (policy, every)
+    assert not [name for name in dir(log) if name.startswith("set_")]
+    for dial, value in (("fsync", "none"), ("fsync_batch_n", 1 << 20)):
+        with pytest.raises(AttributeError):
+            setattr(log, dial, value)
+    for i in range(n, 2 * n):  # the same cadence after the attempts
+        log.append(_rec(i))
+    assert len(syncs) == 2 * said
+    log.close()

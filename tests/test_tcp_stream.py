@@ -166,6 +166,68 @@ class TestCreditWindow:
             srv.shutdown()
 
 
+    @pytest.mark.parametrize("window", [1, 4])
+    def test_a_second_subscribe_on_an_open_stream_ends_that_connection_alone(self, window):
+        """'M' has ONE meaning on the wire: it opens a stream. On a
+        connection that is already streamed it is what any other opcode
+        is there, a protocol violation: the server ends THAT connection
+        and requeues what it had pushed and not seen acked. The server's
+        other stream keeps its window and receives those frames; every
+        frame arrives once."""
+        import struct
+
+        from psana_ray_tpu.transport.tcp import _OP_STREAM
+
+        q, srv = _mk()
+        n = 12
+
+        def since(key):
+            return STREAM.stats()[key] - base[key]
+
+        def until(cond):
+            deadline = time.monotonic() + 5.0
+            while not cond() and time.monotonic() < deadline:
+                time.sleep(0.01)
+            return cond()
+
+        try:
+            base = STREAM.stats()
+            other = TcpQueueClient("127.0.0.1", srv.port)
+            other.stream_open(window=window)
+            bad = TcpQueueClient("127.0.0.1", srv.port, reconnect_tries=0)
+            bad.stream_open(window=window)
+            assert until(lambda: since("credit_window") == 2 * window)
+            for i in range(n):
+                q.put(_rec(i))
+            assert until(lambda: since("inflight") == 2 * window)  # both windows full
+            held = bad.get_batch_stream(window, timeout=2.0)  # consumed, never acked
+            assert len(held) == window
+            bad._sock.sendall(_OP_STREAM + struct.pack("<I", 64))  # not a resize
+            bad._sock.settimeout(5.0)
+            try:
+                assert bad._sock.recv(1) == b""  # closed, and nothing said first
+            except ConnectionError:
+                pass
+            # the violator's window is gone with it, its frames are back
+            # at the head, and the other stream was not touched: still
+            # ``window`` credits, all of them out
+            assert until(lambda: since("credit_window") == window)
+            assert since("redelivered_total") == window
+            assert since("inflight") == window and q.size() == n - window
+            got = []
+            while len(got) < n:
+                more = other.get_batch_stream(n, timeout=2.0)
+                assert more, f"the other stream went dry at {len(got)} of {n}"
+                got.extend(more)
+                assert since("inflight") <= window  # its window, never the 64 asked for
+            assert sorted(r.event_idx for r in got) == list(range(n))  # none lost, none doubled
+            assert {r.event_idx for r in held} <= {r.event_idx for r in got}
+            other.disconnect()
+            assert until(lambda: since("inflight") == 0) and q.size() == 0
+        finally:
+            srv.shutdown()
+
+
 class TestCrashRedeliveryStreaming:
     """ISSUE 5 acceptance: kill a streaming consumer mid-window and every
     un-ACKed frame redelivers to a second consumer — duplicates allowed,

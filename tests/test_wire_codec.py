@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from faultproxy import ThrottleProxy
+from psana_ray_tpu.obs.flight import FLIGHT
 from psana_ray_tpu.records import (
     EndOfStream,
     FrameRecord,
@@ -699,3 +700,76 @@ class TestStreamedCompressed:
             sib.disconnect()
         finally:
             srv.shutdown()
+
+
+def _rec(i, shape=(2, 16, 16)):
+    return FrameRecord(0, i, np.full(shape, i % 251, np.uint16), 9.5)
+
+
+def _flight_since(n0, kind):
+    """Events of ``kind`` recorded after lifetime-count ``n0`` (marks
+    are ``FLIGHT.count_of(kind)``) — robust to ring eviction, unlike
+    slicing ``events()`` by the lifetime event_count."""
+    evs = [e for e in FLIGHT.events() if e["kind"] == kind]
+    new = FLIGHT.count_of(kind) - n0
+    return evs[-new:] if new > 0 else []
+
+
+class TestAutoCodecDecision:
+    """``--wire_codec auto``: one-shot decision at
+    connect from the link-rate probe, re-evaluated on reconnect,
+    breadcrumbed — forced both ways via the env threshold override (no
+    link shaping needed)."""
+
+    def test_fast_link_decides_off(self, monkeypatch):
+        monkeypatch.setenv("PSANA_AUTO_CODEC_MB_S", "0.000001")
+        srv = TcpQueueServer(RingBuffer(8), host="127.0.0.1").serve_background()
+        mark = FLIGHT.count_of("codec_auto_decision")
+        c = TcpQueueClient("127.0.0.1", srv.port, codec="auto")
+        try:
+            assert c.codec_name is None
+            evs = _flight_since(mark, "codec_auto_decision")
+            assert evs and evs[-1]["codec_on"] is False
+            assert evs[-1]["link_mb_s"] is not None
+            rec = _rec(2)
+            assert c.put(rec)
+            out = c.get()
+            assert out.equals(rec)
+            out.release()
+        finally:
+            c.disconnect()
+            srv.shutdown()
+
+    def test_slow_link_decides_on_and_reconnect_redecides(self, monkeypatch):
+        monkeypatch.setenv("PSANA_AUTO_CODEC_MB_S", "1e9")
+        srv = TcpQueueServer(RingBuffer(8), host="127.0.0.1").serve_background()
+        mark = FLIGHT.count_of("codec_auto_decision")
+        c = TcpQueueClient("127.0.0.1", srv.port, codec="auto")
+        try:
+            assert c.codec_name == "shuffle-rle"
+            evs = _flight_since(mark, "codec_auto_decision")
+            assert evs and evs[-1]["codec_on"] is True
+            # the link "changes" (threshold flips): a reconnect must
+            # RE-DECIDE, landing uncompressed this time
+            monkeypatch.setenv("PSANA_AUTO_CODEC_MB_S", "0.000001")
+            mark = FLIGHT.count_of("codec_auto_decision")
+            c._sock.close()  # sever: next op reconnects
+            rec = _rec(3)
+            assert c.put(rec)
+            evs = _flight_since(mark, "codec_auto_decision")
+            assert evs and evs[-1]["codec_on"] is False
+            assert c.codec_name is None
+            out = c.get()
+            assert out.equals(rec)
+            out.release()
+        finally:
+            c.disconnect()
+            srv.shutdown()
+
+    def test_producer_cli_accepts_auto(self):
+        """The CLI value works standalone: --wire_codec auto parses and
+        rides the config."""
+        from psana_ray_tpu.producer import parse_arguments
+
+        cfg, a = parse_arguments(["--wire_codec", "auto"])
+        assert cfg.transport.wire_codec == "auto"

@@ -748,6 +748,25 @@ def _mm(a, b):
     return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
 
+def _kernel_turns(cfg: DecoderConfig, angles) -> bool:
+    """Whether a grouped-query layer's rotary is the attention KERNEL's: it
+    has one, its heads are whole lane blocks (a half-swap is then a rotation
+    of whole vregs), and it runs the batched maskless or windowed kernel (no
+    selection's mask). Laguna's two kinds of layer and the looped reader's;
+    LFM2's heads of 64, keye's selection and granite (no rotary) are turned,
+    or not, by :func:`_projections`."""
+    return angles is not None and cfg.head_dim % 128 == 0 and not cfg.indexer_heads
+
+
+def _rotary_scales(cfg: DecoderConfig, windowed: bool) -> Tuple[float, float]:
+    """Grouped-query attention's two scalars: the softmax scale on q (with
+    YaRN's ``mscale**2``) and YaRN's factor on a turned part (the full layers'
+    alone: a ``windowed`` layer's rotary is plain)."""
+    yarn = None if windowed else cfg.rope_yarn
+    return (cfg.softmax_scale * (yarn.softmax_scale if yarn else 1.0),
+            yarn.rotary_scale if yarn else 1.0)
+
+
 def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
     """Grouped-query attention's ``(a, q, k, v)`` from ``x [T, D]``, each
     ``[T, heads * head_dim]``, the query heads as many as THIS layer's
@@ -757,7 +776,13 @@ def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
     components of a head (the layer type's rotary: all of a head, or the
     full layers' partial one) and the rest passes as it is; under YaRN (the
     full layers' alone: a ``windowed`` layer's rotary is plain) the turned
-    part is multiplied by ``rotary_scale``, the cosines' and sines' factor."""
+    part is multiplied by ``rotary_scale``, the cosines' and sines' factor.
+    Where the kernel turns (:func:`_kernel_turns`) q and k leave FLOAT32,
+    unturned and unscaled, token-major: exactly what ``W_q``'s and ``W_k``'s
+    products wrote (PR 63), and the kernel turns, scales and rounds them once,
+    as here (until then XLA sliced the two 64-lane halves of every head out of
+    the float32 product into copies of their own, turned them in lane-padded
+    passes and wrote the bf16 heads in a layout of its choosing)."""
     s = x.shape[0]
     dt = p["wq"].dtype  # the activations' type: x's own, but where the stream is kept in float32
     a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
@@ -767,18 +792,17 @@ def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
     k = _mm(a, p["wk"]).reshape(s, cfg.num_kv_heads, cfg.head_dim)
     if cfg.qk_norm:
         k = rms_norm(k, p["k_norm"], cfg.rms_eps)
-    yarn = None if windowed else cfg.rope_yarn
-    # the softmax scale (with YaRN's mscale^2) rides on q
-    scale = cfg.softmax_scale * (yarn.softmax_scale if yarn else 1.0)
+    scale, turned = _rotary_scales(cfg, windowed)  # the softmax scale rides on q
+    kept = _kernel_turns(cfg, angles)  # float32: the kernel rounds what it has turned
     if angles is None:
         q = q * scale
-    else:
-        turn = dict(angles=angles, width=2 * angles.shape[-1],
-                    scale=yarn.rotary_scale if yarn else 1.0)
+    elif not kept:
+        turn = dict(angles=angles, width=2 * angles.shape[-1], scale=turned)
         q = _turn_leading(q, **turn) * scale
         k = _turn_leading(k, **turn)
     v = _mm(a, p["wv"])
-    out = (a, q.reshape(s, -1).astype(dt), k.reshape(s, -1).astype(dt), v.astype(dt))
+    q, k = (u.reshape(s, -1) if kept else u.reshape(s, -1).astype(dt) for u in (q, k))
+    out = (a, q, k, v.astype(dt))
     if cfg.attn_gate:
         out += (jax.nn.sigmoid(_mm(a, p["w_attn_gate"])),)
     return out
@@ -917,12 +941,23 @@ def _latent_scales(cfg: DecoderConfig) -> Tuple[float, float]:
             yarn.rotary_scale if yarn else 1.0)
 
 
-def turn_tables(angles):
+def turn_tables(angles, head_dim: int = 0):
     """``angles [T, pairs]`` -> ``([cos | cos], [sin | sin])``, each ``[T,
     2*pairs]`` float32: what turns a head by the rotate-half convention as
     ``x * [cos | cos] + [-x2 | x1] * [sin | sin]`` (:func:`rotate`, no half
-    sliced). The causal kernel turns its shared query tile by them."""
-    return tuple(jnp.concatenate([f(angles)] * 2, axis=-1) for f in (jnp.cos, jnp.sin))
+    sliced). The causal kernel turns its shared query tile by them. With
+    ``head_dim`` the tables of a WHOLE head ``[T, head_dim]``, the sine
+    SIGNED: ``[cos | cos | 1]`` and ``[-sin | sin | 0]``, by which a head
+    turns as ``x * cos + rolled * sin`` with no half negated, ``rolled`` its
+    lanes rotated by half the turned width, and the lanes past ``2*pairs``
+    (a partial rotary's) pass as they are
+    (``sparse_attention._turned_head``: the grouped-query kernel's turn)."""
+    if not head_dim:
+        return tuple(jnp.concatenate([f(angles)] * 2, axis=-1) for f in (jnp.cos, jnp.sin))
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    passing = (angles.shape[0], head_dim - 2 * angles.shape[1])
+    return (jnp.concatenate([cos, cos, jnp.ones(passing, jnp.float32)], axis=-1),
+            jnp.concatenate([-sin, sin, jnp.zeros(passing, jnp.float32)], axis=-1))
 
 
 def gated(x, o, gate, wo):
@@ -1133,7 +1168,13 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
     window < j <= t``) the same kernel visits the tiles that meet the band
     alone, under a name and a scope of its own (``windowed_gqa_attention``,
     ``window_attn``), and ``live`` counts the statistics tiles the band
-    meets. Where the output is gated (``attn_gate``) each head's output
+    meets. Where the rotary is the kernel's (:func:`_kernel_turns`: heads of
+    whole lane blocks, no selection) q and k reach it float32 and unturned,
+    as ``W_q``'s and ``W_k``'s products wrote them, with the layer type's two
+    tables (:func:`turn_tables`, made under ``proj``: the step's ONE pair a
+    type), the turned width and the two scales, and the kernel turns, scales
+    and rounds a query tile once and a key tile where it meets it (PR 63).
+    Where the output is gated (``attn_gate``) each head's output
     meets its own sigmoid scalar where the batched kernel writes it
     (``out_gate``: :func:`gated`'s arithmetic to the bit, and ``W_o`` reads
     what the kernel wrote; PR 58), under a selection in ``W_o``'s operand."""
@@ -1159,8 +1200,14 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
             live = batch * sa.band_tile_count(s, window)
         if gate:  # applied where the kernel writes o: nothing is left for `gated` below
             band["out_gate"] = gate.pop().reshape(batch, s, -1)
+        if _kernel_turns(cfg, angles):  # q and k came float32 and unturned: the kernel's to turn
+            with jax.named_scope("proj"):  # every layer's of a type: the step's ONE pair
+                band["turn"] = jax.jit(turn_tables, static_argnums=1)(angles, cfg.head_dim)
+            band["q_scale"], band["turn_scale"] = _rotary_scales(cfg, bool(window))
+            band["turn_width"] = 2 * angles.shape[-1]
         with jax.named_scope("window_attn" if window else "sparse_attn"):
-            o = jax.jit(attend, static_argnames=("num_kv_heads", "block_q", "block_k", "window"))(
+            o = jax.jit(attend, static_argnames=("num_kv_heads", "block_q", "block_k", "window",
+                                                 "turn_width", "turn_scale", "q_scale"))(
                 *(u.reshape(batch, s, -1) for u in (q, k, v)), num_kv_heads=cfg.num_kv_heads,
                 block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile,
                 **band).reshape(x.shape[0], -1)

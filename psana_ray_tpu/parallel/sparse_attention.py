@@ -368,9 +368,39 @@ def _turned_tile(x, cos, sin, scale: float, dtype):
     return ((x * cos[None] + half * sin[None]) * scale).astype(dtype).reshape(rep * bq, ds)
 
 
+def _turned_head(x, cos, sin, width: int, scale: float):
+    """One head's rows ``x [rows, d]`` float32 as its product wrote them, ``d``
+    whole lane blocks, turned by the rows' tables (``cos, sin [rows, d]``:
+    ``decoder.turn_tables(angles, d)``, ``[cos | cos | 1]`` and the SIGNED
+    sine ``[-sin | sin | 0]``) over its leading ``width`` components, float32:
+    ``decoder._turn_leading``'s arithmetic in whole vregs, ``x * cos +
+    rolled * sin`` with ``rolled`` the head's lanes rotated so that each
+    turned lane meets its pair's other half (``a - b*s`` and ``a + b*(-s)``
+    are the same float32) — at ``width == d`` ONE rotation by ``d / 2``;
+    under a partial rotary two, by ``width / 2`` each way, chosen by lane (a
+    lane that passes meets a one and a zero) — and the turned lanes times
+    ``scale`` (YaRN's factor on the cosines and sines) where it is not 1."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    d, half = x.shape[-1], width // 2
+    rolled = pltpu.roll(x, d - half, 1)  # lane i meets lane i + half
+    if width != d:
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        rolled = jnp.where(lane < half, rolled, pltpu.roll(x, half, 1))
+    out = x * cos + rolled * sin
+    if scale != 1.0:
+        out = out * (scale if width == d else jnp.where(lane < width, scale, 1.0))
+    return out
+
+
 def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared, masked,
-                   gated=False, window=None, turn=None):
+                   gated=False, window=None, turn=None, rotary=None):
     rest = list(rest)
+    if rotary is not None:  # q and k are float32 and UNTURNED: the query tile's and the key tile's
+        # rows of the two tables, and last of the scratch the turned query tile, stacked
+        width, turned_by, q_scale = rotary
+        (cos_q, sin_q, cos_k, sin_k), rest = rest[:4], rest[4:]
+        stacked_ref = rest.pop()
     if shared:  # the part of the score that all heads read from ONE key
         qs_ref, ks_ref = rest.pop(0), rest.pop(0)
     if turn is not None:  # the shared query part is float32 and UNTURNED: its tile's two tables,
@@ -384,6 +414,9 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
     gi, t = pl.program_id(1), pl.program_id(2)
     qi, kb = qi_ref[t], kb_ref[t]
     rep, _, d = q_ref.shape
+    if rotary is not None:  # q is ONE token-major block [1, bq, rep * d]
+        d = k_ref.shape[-1]
+        rep = q_ref.shape[-1] // d
     rows = rep * block_q  # the group's query heads, stacked: one product serves them all
     # the query tile's first visited key tile: tile 0, or under a window the one that holds the
     # key `window - 1` before the tile's first row (`_band_tiles`' first)
@@ -397,11 +430,20 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
         if turn is not None:  # once a query tile: every key step reads the scratch
             turned_ref[...] = _turned_tile(qs_ref[...], cos_ref[...], sin_ref[...], turn,
                                            turned_ref.dtype)
+        if rotary is not None:  # the turn and the stacking are one pass, head by head
+            cos, sin = cos_q[...], sin_q[...]
+            for r in range(rep):
+                head = _turned_head(q_ref[0, :, r * d:(r + 1) * d], cos, sin, width, turned_by)
+                stacked_ref[r * block_q:(r + 1) * block_q] = (head * q_scale).astype(stacked_ref.dtype)
 
     def update(with_diagonal, with_lower_edge=False):
         v = v_ref[...]
-        s = jax.lax.dot_general(q_ref[...].reshape(rows, d), k_ref[...], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+        if rotary is None:
+            q, k = q_ref[...].reshape(rows, d), k_ref[...]
+        else:  # the key tile is turned at every visit (a sixth to a thirty-sixth of a score tile)
+            q, k = stacked_ref[...], _turned_head(
+                k_ref[...], cos_k[...], sin_k[...], width, turned_by).astype(stacked_ref.dtype)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
         if shared:
             qs = (qs_ref[...].reshape(rows, qs_ref.shape[2]) if turn is None
                   else turned_ref[...])
@@ -488,7 +530,8 @@ def _band_tiles(s: int, bq: int, bk: int, window: Optional[int] = None) -> list:
 
 def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool,
                       q_shared=None, k_shared=None, mask=None, window: Optional[int] = None,
-                      out_gate=None, shared_turn=None, shared_scale: float = 1.0):
+                      out_gate=None, shared_turn=None, shared_scale: float = 1.0, turn=None,
+                      turn_width: int = 0, turn_scale: float = 1.0, q_scale: float = 1.0):
     """The batched form of :func:`masked_gqa_attention`. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
@@ -505,15 +548,40 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     ``2h`` and its values at ``2h + 1`` (the latent's one decompression),
     two blocks of one operand. The QUERY of such heads is a column block
     too where a head is alone in its group (latent attention's, straight
-    from its product); with ``H/G > 1`` heads a group it goes as ``[G,
-    H/G, B*S, d]``, the batch in the rows: a rotary writes its heads
-    ``[T, H, d]`` with the HEADS in the sublanes, of which this is a
-    layout (XLA has the rotary's own fusion write it so: no op), where the
-    token-major ``[B, S, H*d]`` is another tiling and cost a ``reshape`` of
-    the whole query (tried: a group's heads stacked from ONE token-major
-    block into a VMEM scratch once a query tile; Laguna's step, its gate
-    still XLA's, 523.6 ms against this form's 514.1 and the parent's
-    522.9: my chip runs, PR 58). Else
+    from its product), and at ANY number a group where the kernel turns
+    it (below); else, ``H/G > 1`` heads a group that come TURNED go as
+    ``[G, H/G, B*S, d]``, the batch in the rows (a layout of the rotary's
+    own fusion wherever XLA turns: no op). With ``turn`` (``[cos | cos |
+    1]`` and the signed sine ``[-sin | sin | 0]``, each ``[B*S, d]``
+    float32: ``decoder.turn_tables(angles, d)``) the rotary is the
+    KERNEL's (PR 63; maskless or windowed, no shared part): ``q [B, S,
+    H*d]`` and ``k [B, S, G*d]`` come FLOAT32, unturned and unscaled,
+    exactly what ``W_q``'s and ``W_k``'s products wrote. At a query
+    tile's first key step the kernel reads the group's heads as ONE
+    token-major block ``[bq, H/G * d]`` and that tile's rows of the two
+    tables, turns head by head (:func:`_turned_head`: ``x * cos + rolled *
+    sin`` in whole vregs, the leading ``turn_width`` lanes of a head, times
+    ``turn_scale``), multiplies by ``q_scale`` (the softmax scale),
+    rounds ONCE to ``v``'s type and writes each head's rows into the
+    stacked ``[H/G * bq, d]`` VMEM scratch that every key step reads: the
+    turn and the stacking are one pass. A key tile is turned by ITS rows
+    of the tables at every visit (``bk * d`` elements beside a score tile
+    of ``H/G * bq * bk``), rounded once. Equal to the bit to
+    ``decoder._turn_leading`` before the call. What went: the two 64-lane
+    halves of every head that XLA sliced out of the float32 product into
+    copies of their own (fifteen ``f32[17408, 72|48, 64]`` a step in
+    Laguna's, 768 ``f32[4608, 16, 64]`` in the looped reader's), the
+    lane-padded passes that turned them and the bf16 head-major copy. One
+    layer alone on the v5e with its projections and ``W_o``, ms, before ->
+    with the keys turned once into a per-sequence VMEM scratch ``[S, d]``
+    / at every visit (my chip runs, PR 63): 72 heads under a window of 512
+    33.70 -> 21.13 / 20.33; 48 heads 38.99 -> 27.98 / 28.05; the looped
+    reader's 16 on 16 key heads at 2 x 2,304 2.599 -> 1.678 / 1.677; the
+    kernel's own call 11.08 -> 8.91 / 8.13, 20.10 -> 19.26 / 19.32, 0.626
+    -> 0.724 / 0.727: a kept key saves nothing that shows and its guarded
+    store costs the windowed layers 0.8 ms each, so every visit turns.
+    (Tried in PR 58: a group's TURNED heads stacked from one token-major
+    block, the gate still XLA's: Laguna's step 523.6 ms against 514.1.) Else
     (heads of 64: LFM2's, granite's) operands go HEAD-MAJOR (``[B, G, H/G,
     S, d]`` and ``[B, G, S, d]``: a block's last dimension is then the
     whole head width, which Mosaic takes at 64 where a 64-lane block of
@@ -591,6 +659,10 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         raise ValueError("a window is a band of at least the query's own key, and the maskless form's")
     if shared_turn is not None and not shared:
         raise ValueError("the tables turn the shared query part: there is none")
+    if turn is not None and (masked or shared or d % 128 or q.dtype != jnp.float32
+                             or k.dtype != jnp.float32 or v is None):
+        raise ValueError("the kernel turns float32 heads of whole lane blocks, maskless and with "
+                         "no shared part")
     pairs = _band_tiles(s, bq, bk, window)
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
 
@@ -609,8 +681,8 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         return pl.BlockSpec((None, 1, bq, rep * width), lambda bi, gi, t, qi, kb: (bi, 0, qi[t], gi))
 
     def q_tiles(x, width):  # -> the kernel's [rep, bq, width] tile
-        if in_place(width) and rep == 1:
-            return x.reshape(b, 1, s, g * width), place_spec(width)
+        if in_place(width) and (rep == 1 or turn is not None):  # (turned: stacked in the kernel)
+            return x.reshape(b, 1, s, g * rep * width), place_spec(width)
         if in_place(width):
             return jnp.transpose(x.reshape(b * s, g, rep, width), (1, 2, 0, 3)), rows_spec(width)
         return jnp.transpose(x.reshape(b, s, g, rep, width), (0, 2, 3, 1, 4)), major_spec(width)
@@ -631,6 +703,14 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         q_tiles(q, d), kv_tiles(k, d, 0, parts), kv_tiles(v, dv, parts - 1, parts)))
     scratch = [pltpu.VMEM((rep * bq, 1), jnp.float32), pltpu.VMEM((rep * bq, 1), jnp.float32),
                pltpu.VMEM((rep * bq, dv), jnp.float32)]
+    rotary = None
+    if turn is not None:  # the query tile's and the key tile's rows of the two tables [B*S, d], and
+        # last of the scratch the turned query tile, stacked, as the key steps read it
+        operands += [table.reshape(b * s, d) for table in turn] * 2
+        in_specs += [pl.BlockSpec((bq, d), lambda bi, gi, t, qi, kb: (bi * (s // bq) + qi[t], 0))] * 2
+        in_specs += [pl.BlockSpec((bk, d), lambda bi, gi, t, qi, kb: (bi * (s // bk) + kb[t], 0))] * 2
+        scratch.append(pltpu.VMEM((rep * bq, d), v.dtype))
+        rotary = (int(turn_width) or d, float(turn_scale), float(q_scale))
     if shared:
         ds = k_shared.shape[2]
         operands += [jnp.transpose(q_shared.reshape(b * s, g, rep, ds), (1, 2, 0, 3)), k_shared]
@@ -651,13 +731,15 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     o5 = pl.pallas_call(
         functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked,
                           gated=out_gate is not None, window=window,
-                          turn=None if shared_turn is None else float(shared_scale)),
+                          turn=None if shared_turn is None else float(shared_scale),
+                          rotary=rotary),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g, len(pairs)),
             in_specs=in_specs, out_specs=place_spec(dv) if in_place(dv) else major_spec(dv),
             scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct(
-            (b, 1, s, g * rep * dv) if in_place(dv) else (b, g, rep, s, dv), q.dtype),
+            (b, 1, s, g * rep * dv) if in_place(dv) else (b, g, rep, s, dv),
+            q.dtype if turn is None else v.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
@@ -671,7 +753,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
 
 
 def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
-                 window: Optional[int] = None) -> Tuple[int, int]:
+                 window: Optional[int] = None, turned: int = 0) -> Tuple[int, int]:
     """The ``(query tile, key tile)`` the maskless batched kernel runs a
     sequence of ``s`` in, from the tiles asked for (``pick_tile``'s, as
     ever), the ``rep`` query heads a group whose query tiles it stacks, and
@@ -681,7 +763,12 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
     bk`` keys or so that its tiles meet, of which only ``window`` are the
     band's. (2) The stacked score tile ``[rep * bq, bk]`` float32 stays
     within :data:`SCORE_TILE_BYTES`: the query tile is the largest that
-    divides ``s`` and does. It leaves the tiles of every step measured
+    divides ``s`` and does; where the kernel turns heads of ``turned`` lanes
+    (``_causal_attention``'s ``turn``) a query row's share of that budget
+    also counts its float32 block in the pipeline's two buffers, its rows
+    of the stacked bf16 scratch and of the two tables (at 6 heads of 128 a
+    group 26,112 + 9,728 bytes a row: 512 rows still fit). It leaves the
+    tiles of every step measured
     before it as they were (4 heads of 64 a group at 1,088 x 1,088: 18.9 MB;
     a head of 128 + 64 alone: 4.7) and gives 6 heads of 128 a group 512 x
     1,088 (13.4 MB) and 9 under a window of 512 256 x 512 (4.7). One full
@@ -696,7 +783,9 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
     if window is not None:
         bq, bk = (min(tile, pick_tile(s, max(int(window * share), 1)))
                   for tile, share in zip((bq, bk), BAND_TILES))
-    fits = SCORE_TILE_BYTES // (4 * rep * bk)
+    # a query row's bytes: its scores; where the kernel turns heads of `turned`, its float32 block
+    # (twice: the pipeline's two buffers), the stacked bf16 scratch and its rows of the two tables
+    fits = SCORE_TILE_BYTES // (4 * rep * bk + (10 * rep + 16) * turned)
     if bq > fits:
         bq = min(bq, pick_tile(s, max(fits, 1)))
     return bq, bk
@@ -705,7 +794,9 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
 def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Optional[int] = None,
                          block_k: int = 512, interpret: Optional[bool] = None,
                          q_shared=None, k_shared=None, window: Optional[int] = None,
-                         out_gate=None, shared_turn=None, shared_scale: float = 1.0) -> jax.Array:
+                         out_gate=None, shared_turn=None, shared_scale: float = 1.0, turn=None,
+                         turn_width: int = 0, turn_scale: float = 1.0,
+                         q_scale: float = 1.0) -> jax.Array:
     """``q [S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [S, G*d]``,
     ``mask`` from :func:`select_keys` -> ``o [S, H*d]``: softmax attention
     of every query head over the keys its query selected, query head ``h``
@@ -720,8 +811,13 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     attention. Operands and output are token-major HERE; what the kernel
     reads in place and what it has transposed head-major first follows
     from the widths (:func:`_causal_attention`: heads of whole lane
-    blocks in place at any number a group, their query as its rotary
-    wrote it; heads of 64 transposed). There the value heads
+    blocks in place at any number a group; heads of 64 transposed). With
+    ``turn`` (two float32 tables ``[B*S, d]``, ``decoder.turn_tables(angles,
+    d)``; maskless or windowed) ``q`` and ``k`` are FLOAT32 and unturned,
+    as their products wrote them, and the kernel turns the leading
+    ``turn_width`` lanes (0: all) of every head by them, times
+    ``turn_scale``, q also times ``q_scale``, each rounded once to ``v``'s
+    type. There the value heads
     may have a width of their own (``v [B, S, G*dv]`` -> ``[B, S, H*dv]``),
     ``v`` may be ``None`` where ``k [B, S, G*2d]`` holds each head's keys
     and then its values (read in place: no slice), and a score may have a second
@@ -746,10 +842,10 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
             g = int(num_kv_heads)
             d = k.shape[2] // (2 * g if v is None else g)
             block_q, block_k = causal_tiles(q.shape[1], q.shape[2] // (g * d), block_q, block_k,
-                                            window)
+                                            window, d if turn is not None else 0)
         return _causal_attention(q, k, v, int(num_kv_heads), block_q, block_k,
                                  _interpret(interpret), q_shared, k_shared, mask, window, out_gate,
-                                 shared_turn, shared_scale)
+                                 shared_turn, shared_scale, turn, turn_width, turn_scale, q_scale)
     if q_shared is not None or out_gate is not None or v.shape[1] != k.shape[1]:
         raise ValueError("a shared key part, a value width of its own and an output gate are "
                          "the batched form's")
@@ -795,7 +891,8 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
 
 def windowed_gqa_attention(q, k, v, *, window: int, num_kv_heads: int, block_q: Optional[int] = None,
                            block_k: int = 512, interpret: Optional[bool] = None,
-                           out_gate=None) -> jax.Array:
+                           out_gate=None, turn=None, turn_width: int = 0, turn_scale: float = 1.0,
+                           q_scale: float = 1.0) -> jax.Array:
     """:func:`masked_gqa_attention`'s batched maskless form under a
     ``window``: ``q [B, S, H*d]``, ``k, v [B, S, G*d]`` -> ``[B, S, H*d]``,
     a query attending to the keys ``t - window < j <= t`` of its own
@@ -808,4 +905,5 @@ def windowed_gqa_attention(q, k, v, *, window: int, num_kv_heads: int, block_q: 
     nine of a step carried the one name)."""
     return masked_gqa_attention(q, k, v, num_kv_heads=num_kv_heads, block_q=block_q,
                                 block_k=block_k, interpret=interpret, window=window,
-                                out_gate=out_gate)
+                                out_gate=out_gate, turn=turn, turn_width=turn_width,
+                                turn_scale=turn_scale, q_scale=q_scale)

@@ -614,7 +614,14 @@ PINNED_STEPS = {
     # rotary's fusion) and write o token-major `[B, 1, S, H*128]`, already gated, for `W_o` to
     # read; the six others were hashed before and after and did not move (heads alone in their
     # groups were in place already, heads of 64 stay head-major)
-    "laguna_s21_prefill_epix10k2m": "1cf0fbb0051f4454385c9ff7492ec618eb4c8d2f26493cc7fb8b95e136bdbc13",
+    # (again in PR 63, knowingly, with the looped reader's: where a layer has a rotary, heads of whole
+    # lane blocks and no selection, `_projections` hands q and k on float32 and unturned as W_q's and
+    # W_k's products wrote them, `_attention` makes the layer type's two tables `[T, 128]`, and the
+    # batched kernel takes them as four more operands (the query tile's rows and the key tile's) with
+    # a fourth scratch, q as ONE token-major block `[B, 1, S, H*128]`; the six others were hashed
+    # before and after and did not move: the rule is a branch taken in Python, `angles is None`,
+    # heads of 64 and a selection on its other side, and the latent cells' path is not touched)
+    "laguna_s21_prefill_epix10k2m": "57a6ffe394d3008531be953c6eac31ac2f15114b82ee6162753cb8e1b5b62283",
     # pinned in PR 57, which brought it: the six above were hashed on PR 56's tree first and none
     # moved, though every one of them now traces `_projections`, `embed`, `logits_of` and `trunk`
     # through the new fields' branches (taken in Python, before anything is traced)
@@ -622,7 +629,8 @@ PINNED_STEPS = {
     # pinned in PR 60, which brought it: the seven above were hashed on PR 58's tree first and none
     # moved (the looped trunk, the sandwich and the gate are branches taken in Python, before
     # anything is traced; at one pass `trunk` is the code it was, to the letter)
-    "ouro_2p6b_prefill_epix10k2m": "e0ddc2de1a2502d79eaca77547efeb6e0f1c59d8805ac52111283d4ebf6092b9",
+    # (re-pinned in PR 63 with laguna's, above: its 48 call sites take q and k float32 and unturned)
+    "ouro_2p6b_prefill_epix10k2m": "b888bb6e4984349512f6b63d1da4b969d42000eac112d1dc1b8e5c3303d092ce",
 }
 
 
@@ -934,32 +942,43 @@ def test_every_latent_layer_of_kimi_s_step_hands_the_kernel_what_its_product_wro
     assert sum("jit(turn_tables)" in line for _, line in made.values()) == 2  # [cos|cos], [sin|sin]
 
 
-@pytest.mark.parametrize("kind", ["sliding_attention", "full_attention"])
+@pytest.mark.parametrize("name,kind", [
+    ("laguna_s21_prefill_epix10k2m", "sliding_attention"),
+    ("laguna_s21_prefill_epix10k2m", "full_attention"),
+    ("ouro_2p6b_prefill_epix10k2m", "full_attention")], ids=["laguna-sliding", "laguna-full", "ouro"])
 def test_laguna_s_grouped_heads_reach_the_kernel_where_their_products_wrote_them(
-        kind, one_chip, monkeypatch):
-    """ONE attention layer of laguna's (``decoder._attention``: a windowed
-    one at 72 query heads, a full one at 48, 8 key heads of 128, two
-    sequences of 8,704) as compiled: one kernel, its output the token-major
-    ``[B, 1, S, H*128]`` that ``gated`` reads, and between ``W_q``'s product
-    and ``W_o`` no ``copy``, ``transpose``, ``reshape`` or copy/bitcast
-    fusion of ``T * H * 64`` elements or more — but the rotary's own: the
-    float32 halves ``[T, H, 64]`` it is computed from (ROADMAP S12 (1):
-    another mechanism, another PR). On PR 57's tree a windowed layer held,
-    beside those, q's head-major copy (bf16 ``[2,8,9,8704,128]``) and THREE
-    float32 passes over o on the way back (``[2,8704,8,9,128]`` twice,
-    ``[1152,8,2,8704]``: 0.64 GB each) with the gate broadcast to
-    ``[T, H, 128]`` beside them. Since PR 58 k, v and o are column blocks of
-    the token-major arrays at any number of heads a group, q is what the
-    rotary's fusion wrote, bitcast, and the gate is applied where the kernel
-    writes o: nothing of o's size stands between the kernel and ``W_o``."""
+        name, kind, one_chip, monkeypatch):
+    """ONE attention layer (``decoder._attention``) as compiled: laguna's
+    windowed one at 72 query heads and its full one at 48 (8 key heads of
+    128, two sequences of 8,704, the output gated), and ONE layer
+    application of the looped reader's (16 heads on 16 key heads, two
+    sequences of 2,304, ``[4608, 2048]``): one kernel, its output the
+    token-major ``[B, 1, S, H*128]`` that ``W_o`` reads, and between
+    ``W_q``'s and ``W_k``'s products and ``W_o`` NOTHING of ``T * H * 64``
+    elements or more that only moves: no ``copy``, ``transpose``,
+    ``reshape``, ``convert``, ``broadcast`` or copy/bitcast fusion. On PR
+    57's tree a windowed layer held q's head-major copy (bf16
+    ``[2,8,9,8704,128]``) and THREE float32 passes over o on the way back
+    with the gate broadcast to ``[T, H, 128]`` beside them; PR 58 left the
+    rotary's own: the two 64-lane halves of every head sliced out of the
+    float32 product into ``f32[T, H, 64]`` copies (fifteen a step in
+    laguna's, 21-26 ms; 768 in the looped reader's, 49.7 ms) and turned in
+    lane-padded passes. Since PR 63 the kernel's q and k ARE the products'
+    results, float32 and unturned, through bitcasts alone, and the kernel
+    turns them by the step's two tables (``jit(turn_tables)``, ``[T,
+    128]`` float32 each): no float32 half of a head exists. (A layer ALONE
+    copies its input and its result, the entry computation's parameter and
+    root, into the layout its neighbours would have given them: those two
+    of the looped reader's are not between the products and ``W_o``.)"""
     from psana_ray_tpu.models import decoder
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg, dcfg, params = _decoder_cell("laguna_s21_prefill_epix10k2m")
+    cfg, dcfg, params = _decoder_cell(name)
     batch, seq, i = cfg["batch_size"], cfg["sequence_tokens"], cfg["layer_types"].index(kind)
     tokens, heads = batch * seq, dcfg.heads(i)
     sliding = kind == "sliding_attention"
-    assert heads == (72 if sliding else 48) and dcfg.sliding_window == 512
+    assert (heads, tokens) == {"laguna-sliding": (72, 17408), "laguna-full": (48, 17408),
+                               "ouro-full": (16, 4608)}[name.split("_")[0] + "-" + kind.split("_")[0]]
 
     def layer(p, x):
         if sliding:
@@ -974,15 +993,33 @@ def test_laguna_s_grouped_heads_reach_the_kernel_where_their_products_wrote_them
                         (params["layers"][i], S((tokens, dcfg.hidden_size), BF16)))
     text = jax.jit(layer).lower(*args).compile().as_text()
     entry = text[text.index("ENTRY"):]
+    made = {m.group(1): (m.group(2), line) for line in entry.splitlines()
+            for m in [re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \S+ ([\w\-]+)\(", line)] if m}
     kernel = "windowed_gqa_attention" if sliding else "masked_gqa_attention"
-    calls = re.findall(rf"^\s*(?:ROOT )?%{kernel}[.\d]* = (\w+\[[\d,]*\])", entry, re.M)
-    assert calls == [f"bf16[{batch},1,{seq},{heads * dcfg.head_dim}]"], calls
+    calls = [line for made_by, (_, line) in made.items() if made_by.startswith("%" + kernel)]
+    assert len(calls) == 1 and f" = bf16[{batch},1,{seq},{heads * dcfg.head_dim}]" in calls[0]
     moved = _array_sized_moves(entry, tokens * heads * 64,
                                ("copy", "transpose", "reshape", "convert", "broadcast"))
-    # the rotary's own: float32 [T, H, 64], a windowed layer's two halves of a head and a full
-    # layer's turned leading half (its partial rotary turns 64 of 128)
-    halves = f" f32[{tokens},{heads},{dcfg.head_dim // 2}]"
-    assert [m for m in moved if not m.endswith(halves)] == [], moved
+
+    def at_the_edge(move):  # the entry's root, or a copy of one of its parameters
+        line = made[move.split()[0]][1]
+        source = re.search(r"copy\((%[\w.\-]+)\)", line)
+        return line.lstrip().startswith("ROOT") or bool(source) and "parameter(" in made[source.group(1)][1]
+
+    assert [m for m in moved if not at_the_edge(m)] == [], moved
+    assert len(moved) == (2 if name.startswith("ouro") else 0), moved
+    # the kernel's q and k: each, through bitcasts alone, a product's own float32 result
+    operands = re.findall(r"%[\w.\-]+", calls[0].split("custom-call(")[1].split(")")[0])
+    for operand, columns in ((operands[2], heads), (operands[3], dcfg.num_kv_heads)):
+        assert f" = f32[" in made[operand][1], made[operand][1][:200]
+        while made[operand][0] == "bitcast":
+            operand = re.search(r"bitcast\((%[\w.\-]+)\)", made[operand][1]).group(1)
+        kind_of, line = made[operand]
+        assert kind_of == "fusion" and "/dot_general" in line and (
+            f"f32[{tokens},{columns * dcfg.head_dim}]" in line), line[:300]
+    assert f"f32[{tokens},{heads},{dcfg.head_dim // 2}]" not in entry and "multiply_subtract_fusion" not in entry
+    assert sum("jit(turn_tables)" in line and f"f32[{tokens},{dcfg.head_dim}]" in line.split(" fusion(")[0]
+               for _, line in made.values()) == 2  # [cos | cos | 1], [-sin | sin | 0]
 
 
 def _ling3_experts():

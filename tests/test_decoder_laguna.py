@@ -209,6 +209,132 @@ def test_grouped_heads_of_whole_lane_blocks_are_read_where_their_products_wrote_
                    .astype(jnp.float32)))
 
 
+# the layer types' rotaries at heads of 128: (turned width, YaRN's factor on the turned part)
+ROTARIES = {"full_rotary": (128, 1.0), "partial_rotary_64_of_128_yarn": (64, 1.4852030263919618)}
+# heads a group -> (key heads, S, query tile, key tile): the looped reader's shape (16 heads on 16 key
+# heads at S 2,304 in 768 x 768: as many key heads as heads, three square tiles a sequence), a full
+# layer's (six a group in 512 x 1,088: the key tile twice the query tile and more) and a windowed
+# layer's (nine a group in 256 x 512, the window the key tile). In every one a key tile past the
+# first is FIRST met by a query tile that is not the sequence's first, and met again by later ones
+GROUPS = {1: (4, 96, 32, 32), 6: (2, 96, 16, 32), 9: (2, 128, 16, 32)}
+
+
+@pytest.mark.parametrize("rep", sorted(GROUPS))
+@pytest.mark.parametrize("band", ["maskless", "window"])
+@pytest.mark.parametrize("rotary", sorted(ROTARIES))
+def test_the_kernel_turns_float32_heads_to_the_bit_as_the_projections_did(rotary, band, rep):
+    """The kernel given q and k FLOAT32 and unturned, as ``W_q``'s and
+    ``W_k``'s products wrote them, with the step's two tables
+    (``decoder.turn_tables(angles, 128)``) against the kernel given what
+    ``_projections`` made of them until PR 63 (``_turn_leading``, the
+    softmax scale on q, ONE rounding to bf16), over a batch of two, with the
+    gate of a (token, head) where a group has more than one head (laguna's
+    layers; the looped reader has none). In two steps, because XLA's CPU
+    backend contracts ``x*c + r*s`` inside the interpreted kernel's one
+    compiled body and not op by op (the last float32 bit of one turned
+    component in 30,000, which flips a bf16 rounding now and then: the TPU
+    has no such instruction): (1) the tables' arithmetic on the whole
+    arrays, op by op, IS ``_turn_leading``'s, to the last float32 bit, by the
+    real tables; (2) where a contraction changes nothing — components of
+    eight bits and tables rounded to eight — the kernel that turns equals
+    the kernel given that arithmetic's result, rounded once, bit for bit; by
+    the real tables it is within two roundings on a handful of rows. Two
+    planted faults come out as OTHER results: the keys left unturned, and
+    the sine's sign flipped."""
+    width, factor = ROTARIES[rotary]
+    g, s, bq, bk = GROUPS[rep]
+    b, d, h, half = 2, 128, g * rep, width // 2
+    window = bk if band == "window" else None
+    q, k, v = (jnp.round(u * 32) / 32 for u in qkv(rep + width, b, s, g, rep, d))
+    v = v.astype(jnp.bfloat16)
+    gate = {"out_gate": jax.nn.sigmoid(qkv(rep, b, s, g, rep, 1)[0])} if rep > 1 else {}
+    angles = jnp.tile(decoder.rotary_angles(np.arange(s), 10000.0, half), (b, 1))
+    scale, lane = 0.1147, jnp.arange(d)
+
+    def by_tables(x, heads, tables, by=1.0):  # `_turned_head`'s arithmetic, op by op
+        x = x.reshape(b * s, heads, d)
+        cos, sin = (t[:, None, :] for t in tables)
+        rolled = jnp.where(lane < half, jnp.roll(x, d - half, -1), jnp.roll(x, half, -1))
+        return ((x * cos + rolled * sin) * jnp.where(lane < width, factor, 1.0) * by
+                ).reshape(b, s, -1)
+
+    def attend(q, k, **turn):
+        return sa._causal_attention(q, k, v, g, bq, bk, True, window=window, **gate, **turn)
+
+    tables = decoder.turn_tables(angles, d)
+    assert all(t.shape == (b * s, d) and t.dtype == jnp.float32 for t in tables)
+    for x, heads in ((q, h), (k, g)):  # (1)
+        np.testing.assert_array_equal(
+            np.asarray(by_tables(x, heads, tables)),
+            np.asarray(decoder._turn_leading(x.reshape(b * s, heads, d), angles, width, factor)
+                       .reshape(b, s, -1)))
+    coarse = tuple(jnp.round(t * 256) / 256 for t in tables)  # (2)
+    kernel = dict(turn=coarse, turn_width=width, turn_scale=factor, q_scale=scale)
+    got = attend(q, k, **kernel)
+    assert got.dtype == jnp.bfloat16 and got.shape == (b, s, h * d)
+    want = attend(by_tables(q, h, coarse, scale).astype(jnp.bfloat16),
+                  by_tables(k, g, coarse).astype(jnp.bfloat16))
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    real = np.asarray(attend(q, k, **{**kernel, "turn": tables}), np.float32)
+    by_xla = np.asarray(attend(by_tables(q, h, tables, scale).astype(jnp.bfloat16),
+                               by_tables(k, g, tables).astype(jnp.bfloat16)), np.float32)
+    np.testing.assert_allclose(real, by_xla, atol=2e-2)
+    assert np.mean(real != by_xla) < 5e-3
+    # a sequence's rows do not depend on its neighbour (the keys' scratch is a sequence's own)
+    alone = sa._causal_attention(q[1:], k[1:], v[1:], g, bq, bk, True, window=window,
+                                 **{n: u[1:] for n, u in gate.items()},
+                                 **{**kernel, "turn": tuple(t[s:] for t in coarse)})
+    np.testing.assert_array_equal(np.asarray(got[1:], np.float32), np.asarray(alone, np.float32))
+    # planted faults: each is another result, far past a rounding
+    unturned = attend(by_tables(q, h, coarse, scale).astype(jnp.bfloat16), k.astype(jnp.bfloat16))
+    flipped = attend(q, k, **{**kernel, "turn": (coarse[0], -coarse[1])})
+    for fault in (unturned, flipped):
+        assert float(jnp.abs(fault.astype(jnp.float32) - got.astype(jnp.float32)).max()) > 0.05
+    with pytest.raises(ValueError, match="float32 heads of whole lane blocks"):
+        attend(q.astype(jnp.bfloat16), k, **kernel)
+
+
+@pytest.mark.parametrize("kind", [FULL, SLIDING])
+def test_a_layer_of_whole_lane_heads_hands_the_kernel_what_its_products_wrote(kind, monkeypatch):
+    """``decoder._attention`` at heads of 128 (the rule's side the small
+    trunks of this file, heads of 16, never take): ``_projections`` hands q
+    and k on float32, unturned and unscaled, the kernel gets the layer
+    type's tables, width and scales, and the layer is the layer that
+    ``_projections`` turned (the rule switched off), within the
+    contraction's rounding (the test above); at heads of 16 q and k leave
+    turned and rounded, as ever."""
+    cfg = small(mapping(head_dim=128))
+    i = [FULL, SLIDING, SLIDING, FULL].index(kind)
+    p = loud(decoder.init_params(cfg, jax.random.key(2)))["layers"][i]
+    batch, s = 2, 64
+    x = jnp.asarray(np.random.default_rng(3).standard_normal((batch * s, 64)), jnp.bfloat16)
+    if kind == SLIDING:
+        angles = decoder.rotary_angles(np.arange(s), cfg.sliding_rope_theta, cfg.head_dim // 2)
+    else:
+        angles = decoder.rotary_angles(np.arange(s), cfg.rope_theta, cfg.rope_dim // 2,
+                                       yarn=cfg.rope_yarn)
+    angles = jnp.tile(angles, (batch, 1))
+    assert angles.shape[1] == (64 if kind == SLIDING else 32) and decoder._kernel_turns(cfg, angles)
+    windowed = (True,) if kind == SLIDING else ()
+    a, q, k, v, gate = decoder._projections(p, x, angles, cfg, *windowed)
+    assert q.dtype == k.dtype == jnp.float32 and v.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(q), np.asarray(decoder._mm(a, p["wq"])))
+    np.testing.assert_array_equal(np.asarray(k), np.asarray(decoder._mm(a, p["wk"])))
+    window = cfg.sliding_window if kind == SLIDING else 0
+    got = decoder._attention(p, x, angles, None, batch, cfg, window)[0]
+    monkeypatch.setattr(decoder, "_kernel_turns", lambda cfg, angles: False)
+    jax.clear_caches()  # `_attention` calls `_projections` jitted: traced under the rule above
+    a, q, k, v, gate = decoder._projections(p, x, angles, cfg, *windowed)
+    assert q.dtype == k.dtype == jnp.bfloat16
+    want = decoder._attention(p, x, angles, None, batch, cfg, window)[0]
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    np.testing.assert_allclose(got, want, atol=2e-2)
+    assert np.mean(got != want) < 5e-3 and float(np.abs(want - np.asarray(x, np.float32)).max()) > 0.5
+    jax.clear_caches()
+    narrow = small(mapping())
+    assert not decoder._kernel_turns(narrow, angles) and not decoder._kernel_turns(cfg, None)
+
+
 def test_the_kernel_s_tiles_are_the_band_s_alone_and_the_statistics_count_them():
     # 8,704 tokens in 512 x 512 tiles: 153 at or below the diagonal, 33 that meet a band of 512
     assert len(sa._band_tiles(8704, 512, 512)) == 153 == sa.causal_tile_count(8704)
@@ -232,6 +358,12 @@ def test_the_tiles_follow_from_the_group_s_rows_and_the_window():
     assert sa.causal_tiles(8704, 9, 1088, 1088, 512) == (256, 512) and sa.BAND_TILES == (0.5, 1.0)
     assert sa.causal_tiles(64, 3, 32, 32, 16) == (8, 16) and sa.causal_tiles(64, 3, 8, 8, 16) == (8, 8)
     assert sa.causal_tiles(8704, 9, 1088, 1088, 10 ** 6) == (512, 1088)  # the score tile's bytes bind
+    # where the kernel turns heads of 128 the float32 query block, the stacked scratch and the
+    # tables' rows are counted in the same bytes: the served tiles stand (laguna's two, ouro's)
+    assert sa.causal_tiles(8704, 6, 1088, 1088, None, 128) == (512, 1088)
+    assert sa.causal_tiles(8704, 9, 1088, 1088, 512, 128) == (256, 512)
+    assert sa.causal_tiles(2304, 1, 768, 768, None, 128) == (768, 768)
+    assert sa.causal_tiles(8704, 9, 1088, 1088, 10 ** 6, 128) == (256, 1088)  # and there they bind
 
 
 # ---------------------------------------------------------------------------

@@ -6,12 +6,18 @@ LayerNorm, GELU and a position table). This module builds the text
 decoder of a language model from the keys of its published
 ``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B, DeepSeek-V3's
 block as Kimi-K2 spells it, DeepSeek-V3.2's, Ling-3.0's hybrid,
-Laguna-S-2.1's windowed and full layers, Granite-4.0-H's state-space hybrid):
+Laguna-S-2.1's windowed and full layers, Granite-4.0-H's state-space hybrid,
+Ouro's looped stack, Nemotron-H's layers of one block each):
 
     x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
     x  -> x + ff(rms(x))                      five kinds, its ff one of two
 
-(a branch times ``residual_multiplier`` before it is added, where a model has one).
+(a branch times ``residual_multiplier`` before it is added, where a model has
+one). Where the configuration says that a layer is ONE block
+(``single_block``: Nemotron-H's ``hybrid_override_pattern``) a layer is
+EITHER line, an operator alone or a feed-forward alone, with the one norm
+that line has: ``layer_kind`` names what a layer has and ``decoder_layer``
+runs what the kind names.
 
 A layer's OPERATOR (``layer_types``) is grouped-query attention (a
 per-head RMS norm on q and k where the model has one, then the rotary,
@@ -24,7 +30,8 @@ or LINEAR attention (:func:`linear_attention`: the gated delta rule with a
 decay per channel, a float32 state a head carried along the sequence:
 ``ops/delta_rule.py``); or a STATE-SPACE layer (:func:`state_space`:
 Mamba-2's selective scan, one scalar decay a head and token, keys and
-queries shared by all heads, a float32 state a head carried along the
+queries shared by all heads or by the heads of each of ``ssm_groups`` groups,
+the gated norm by those groups, a float32 state a head carried along the
 sequence: ``ops/ssd.py``);
 or, where the configuration has a ``kv_lora_rank``, LATENT attention
 (:func:`latent_attention`: queries of full rank or through a normed low
@@ -37,8 +44,10 @@ are projected from the layer's normed input or, under latent attention,
 from the query's normed low rank; its one key goes through an RMS norm
 or a LayerNorm, and the rotary turns all of an index vector or its
 leading part (fields, with the first indexer's values as defaults). Its
-FEED-FORWARD is a dense gated-SiLU MLP (the first ``num_dense_layers``,
-or all where there are no experts) or top-k of ``num_experts`` experts
+FEED-FORWARD is a dense MLP (the first ``num_dense_layers``, or all where
+there are no experts; gated SiLU, or under ``mlp_act`` ``relu2`` the UNGATED
+``relu(x W_up)^2 W_down``, as the experts and the shared expert then are:
+``moe.hidden_rows``) or top-k of ``num_experts`` experts
 without dropped tokens (``parallel/moe.dropless_moe``; a softmax router,
 or sigmoid affinities under a selection bias, the choice limited to the
 best ``router_groups_kept`` of ``router_groups`` groups where there are
@@ -80,7 +89,7 @@ from psana_ray_tpu.ops.delta_rule import CHUNK, chunk_rows, gated_delta_rule
 from psana_ray_tpu.ops.short_conv import gated_conv_taps
 from psana_ray_tpu.ops.ssd import scan_rows, ssd_scan
 from psana_ray_tpu.parallel import sparse_attention as sa
-from psana_ray_tpu.parallel.moe import dropless_moe, goes_ahead, rows_ahead
+from psana_ray_tpu.parallel.moe import dropless_moe, goes_ahead, hidden_rows, rows_ahead
 
 # what frame_step's statistics vector holds: the first four summed over the
 # batch and over the layers that have the thing counted
@@ -130,6 +139,10 @@ ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
 SLIDING = "sliding_attention"  # grouped-query attention over the band t - sliding_window < j <= t
 MAMBA = "mamba"  # Mamba-2's state-space layer (granitemoehybrid's spelling; its "attention" is ATTENTION)
 LATENT = "latent_attention"  # what an ATTENTION layer is under a kv_lora_rank
+# a layer that is ONE block and has no operator names its feed-forward (`DecoderConfig.single_block`)
+EXPERTS, DENSE = "moe", "mlp"
+# a `hybrid_override_pattern`'s letters (nemotron_h's spelling of a schedule of single blocks)
+PATTERN = {"M": MAMBA, "*": ATTENTION, "E": EXPERTS, "-": DENSE}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,12 +216,20 @@ class DecoderConfig:
     # and values are linear_head_dim wide, the log-decay a token in (linear_decay_floor, 0)
     linear_head_dim: int = 0
     linear_decay_floor: float = 0.0
-    # a MAMBA layer's scan: ssm_heads heads of ssm_head_dim channels over a state ssm_state wide
-    # (one group: B and C shared by all heads); conv_bias: its convolution adds a bias per channel
+    # a MAMBA layer's scan: ssm_heads heads of ssm_head_dim channels over a state ssm_state wide,
+    # B and C shared by the heads of each of ssm_groups groups of consecutive heads, which are also
+    # the groups the gated norm goes by; conv_bias: its convolution adds a bias per channel
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0
+    ssm_groups: int = 1
     conv_bias: bool = False
+    # every layer is ONE block, x + Mixer(rms(x)): an operator ALONE (no feed-forward after it) or,
+    # where layer_types names EXPERTS or DENSE, a feed-forward alone (no operator before it)
+    single_block: bool = False
+    # an expert's, the shared expert's and a dense MLP's hidden activation: "silu" is the gated
+    # silu(x W_gate) * (x W_up); "relu2" the UNGATED relu(x W_up)^2, which has no W_gate
+    mlp_act: str = "silu"
     # Granite's four multipliers, each defaulting to what every other model has: a branch's output
     # times residual_multiplier before it is added, the embedded rows times embedding_multiplier,
     # the softmax scale (None: head_dim ** -0.5), the logits over logits_scaling
@@ -366,18 +387,26 @@ class DecoderConfig:
         """Layer ``i``'s query heads."""
         return self.heads_per_layer[i] if self.heads_per_layer else self.num_heads
 
-    def layer_kind(self, i: int) -> Tuple[str, bool]:
-        """``(operator, has experts)`` of layer ``i``."""
+    def layer_kind(self, i: int) -> Tuple[Optional[str], Optional[bool]]:
+        """``(operator, has experts)`` of layer ``i``: its operator, and
+        whether its feed-forward is the experts (else the dense MLP). Where
+        every layer is ONE block (``single_block``) one of the two is ``None``:
+        ``(operator, None)`` is an operator with no feed-forward after it,
+        ``(None, True)`` the experts alone, ``(None, False)`` a dense MLP alone."""
         op = self.layer_types[i] if self.layer_types else ATTENTION
+        if op in (EXPERTS, DENSE):
+            return None, op == EXPERTS
         if op == ATTENTION and self.kv_lora_rank:
             op = LATENT
+        if self.single_block:
+            return op, None
         return op, bool(self.num_experts) and i >= self.num_dense_layers
 
     @classmethod
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Eight spellings are read:
+        ``experts_held`` and ``tie_embedding``. Nine spellings are read:
         Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
         ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
         (``norm_eps``, ``layer_types``, ``num_dense_layers``,
@@ -425,7 +454,20 @@ class DecoderConfig:
         which also says that a layer's branches are normed twice, that an
         exit gate reads every pass's normed rows and that q and k have no
         norm, none of which the file has a key for;
-        ``early_exit_threshold``, held to 1). Where a
+        ``early_exit_threshold``, held to 1); and Nemotron-H's
+        (``nemotron_h``: ``hybrid_override_pattern``, a letter a layer, each
+        layer ONE block: ``M`` a state-space layer, ``*`` grouped-query
+        attention, ``E`` the experts, ``-`` a dense MLP, its first
+        ``num_hidden_layers`` letters read; ``mamba_num_heads`` with
+        ``mamba_head_dim``, ``ssm_state_size``, ``conv_kernel``,
+        ``use_conv_bias`` and ``n_groups``, the scan's groups of B and C
+        (beside ``n_group``, the ROUTER's groups: one letter apart);
+        ``layer_norm_epsilon``; ``mlp_hidden_act: relu2``: experts, shared
+        expert and dense MLP UNGATED, ``relu(x W_up)^2 W_down``;
+        ``moe_shared_expert_intermediate_size``, the shared expert's whole
+        width; and, as its module has them and its file has no key for,
+        DeepSeek-V3's router (sigmoid affinities, a selection bias, 1e-20), no
+        rotary and no per-head norm in an attention layer). Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -445,13 +487,28 @@ class DecoderConfig:
         # Granite-4.0-H's spelling of a full causal layer is "attention"
         layer_types = tuple(ATTENTION if op == "attention" else op
                             for op in m.get("layer_types", ()))
+        pattern = m.get("hybrid_override_pattern")  # Nemotron-H's: a letter a layer, ONE block each
+        if pattern is not None:
+            if len(pattern) < n_layers or set(pattern) - set(PATTERN):
+                raise ValueError(f"hybrid_override_pattern {pattern!r} does not spell {n_layers} "
+                                 f"layers' blocks, each one of {''.join(PATTERN)}")
+            layer_types = tuple(PATTERN[letter] for letter in pattern[:n_layers])
         ssm = {}
-        if "mamba_n_heads" in m:  # Mamba-2's state-space layers
-            if int(m.get("mamba_n_groups", 1)) != 1:
-                raise ValueError("more than one group of B and C (mamba_n_groups) is not built")
+        if "mamba_n_heads" in m:  # Mamba-2's state-space layers, as Granite-4.0-H spells them
             ssm = dict(ssm_heads=int(m["mamba_n_heads"]), ssm_head_dim=int(m["mamba_d_head"]),
-                       ssm_state=int(m["mamba_d_state"]),
+                       ssm_state=int(m["mamba_d_state"]), ssm_groups=int(m.get("mamba_n_groups", 1)),
                        conv_bias=bool(m.get("mamba_conv_bias", False)))
+        elif "mamba_num_heads" in m:  # and as Nemotron-H does (n_groups: the scan's, not n_group)
+            ssm = dict(ssm_heads=int(m["mamba_num_heads"]), ssm_head_dim=int(m["mamba_head_dim"]),
+                       ssm_state=int(m["ssm_state_size"]), ssm_groups=int(m.get("n_groups", 1)),
+                       conv_bias=bool(m.get("use_conv_bias", False)))
+        if ssm and ssm["ssm_heads"] % ssm["ssm_groups"]:
+            raise ValueError(f"{ssm['ssm_groups']} groups of B and C do not divide "
+                             f"{ssm['ssm_heads']} heads")
+        single = pattern is not None  # a schedule of single blocks: what its module does, its file has no key for
+        act = m.get("mlp_hidden_act", "silu")
+        if act not in ("silu", "relu2"):
+            raise ValueError(f"mlp_hidden_act {act!r} is not built (silu, gated; relu2, ungated)")
         if "shared_intermediate_size" in m and int(m.get("num_local_experts") or 0):
             raise ValueError("experts (num_local_experts) beside the always-on MLP of "
                              "shared_intermediate_size are not built")
@@ -465,10 +522,11 @@ class DecoderConfig:
                     f"early_exit_threshold {m['early_exit_threshold']} is not 1: an exit before the "
                     f"last pass (a pass count the data sets) is not supported")
             loop = dict(passes=int(m["total_ut_steps"]), sandwich=True, exit_gate=True)
-        qk_norm = scale is None and "rope_parameters" not in m and not loop
+        qk_norm = scale is None and "rope_parameters" not in m and not loop and not single
         if layer_types and (len(layer_types) != n_layers
                             or set(layer_types) - {ATTENTION, SLIDING, CONV, LINEAR}
-                            - ({MAMBA} if ssm else set())):
+                            - ({MAMBA} if ssm else set())
+                            - ({EXPERTS, DENSE} if single else set())):
             raise ValueError(f"layer_types {layer_types} does not name {n_layers} layers' "
                              f"operators, each {ATTENTION!r}, {SLIDING!r}, {CONV!r} or {LINEAR!r}")
         latent = int(m.get("kv_lora_rank") or 0)
@@ -524,26 +582,29 @@ class DecoderConfig:
         linear = LINEAR in layer_types
         nope, rope_dim = int(m.get("qk_nope_head_dim", 0)), int(m.get("qk_rope_head_dim", 0))
         # DeepSeek-V3's router, by its spelling or (Laguna's file) by the reading stated there
-        deepseek = "scoring_func" in m or "router_scoring" in m
+        # (or, Nemotron-H's file having no key for its router, by its schedule's spelling)
+        deepseek = "scoring_func" in m or "router_scoring" in m or single
         sigmoid = ("use_expert_bias" in m or m.get("scoring_func") == "sigmoid"
-                   or m.get("router_scoring") == "sigmoid")
+                   or m.get("router_scoring") == "sigmoid" or single)
         width = int(m.get("moe_intermediate_size", 0))
         return cls(
             hidden_size=int(m["hidden_size"]), num_layers=n_layers,
             num_heads=heads, num_kv_heads=int(m["num_key_value_heads"]),
             head_dim=nope + rope_dim if latent else head_dim,
             vocab_size=int(m["vocab_size"]),
-            rms_eps=float(m["rms_norm_eps"] if "rms_norm_eps" in m else m["norm_eps"]),
+            rms_eps=float(m["rms_norm_eps"] if "rms_norm_eps" in m
+                          else m["layer_norm_epsilon"] if "layer_norm_epsilon" in m else m["norm_eps"]),
             rope_theta=theta,
             mrope_section=tuple(int(v) for v in mrope) if mrope else None,
             layer_types=layer_types, **window, **ssm, qk_norm=qk_norm,
+            single_block=single, mlp_act=act,
             residual_multiplier=float(m.get("residual_multiplier", 1.0)),
             embedding_multiplier=float(m.get("embedding_multiplier", 1.0)),
             attention_multiplier=None if scale is None else float(scale),
             logits_scaling=float(m.get("logits_scaling", 1.0)),
-            rotary=m.get("position_embedding_type") != "nope",
+            rotary=m.get("position_embedding_type") != "nope" and not single,
             conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size",
-                                                      m.get("mamba_d_conv", 3)))),
+                                                      m.get("mamba_d_conv", m.get("conv_kernel", 3))))),
             linear_head_dim=int(m["head_dim"]) if linear else 0,
             linear_decay_floor=float(m["kda_lower_bound"]) if linear else 0.0,
             tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
@@ -560,14 +621,17 @@ class DecoderConfig:
             num_dense_layers=int(m.get("num_dense_layers",
                                        m.get("first_k_dense_replace", len(dense_only)))),
             router_scoring="sigmoid" if sigmoid else "softmax",
-            expert_bias=bool(m.get("use_expert_bias", m.get("topk_method") == "noaux_tc")),
+            expert_bias=bool(m.get("use_expert_bias", m.get("topk_method") == "noaux_tc" or single)),
             # the renormalising sum's epsilon: LFM2's code has 1e-6, DeepSeek-V3's 1e-20
             gate_eps=(1e-20 if deepseek else 1e-6) if sigmoid else 0.0,
             routed_scaling_factor=float(m.get("routed_scaling_factor",
                                               m.get("moe_routed_scaling_factor", 1.0))),
             router_groups=int(m.get("n_group") or 1),
             router_groups_kept=int(m.get("topk_group") or 1),
-            shared_experts=int(m.get("n_shared_experts") or m.get("num_shared_experts")
+            # (a file that states the shared expert's whole width is read by that: Nemotron-H's
+            # one shared expert is 3,712 wide beside routed ones of 1,856)
+            shared_experts=int(int(m.get("moe_shared_expert_intermediate_size", 0)) // max(width, 1)
+                               or m.get("n_shared_experts") or m.get("num_shared_experts")
                                or int(m.get("shared_expert_intermediate_size", 0)) // max(width, 1)
                                ) if n_exp else 0,
             intermediate_size=int(m.get("shared_intermediate_size", m.get("intermediate_size", 0))),
@@ -603,9 +667,11 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
     layers = []
     for i in range(cfg.num_layers):
         op, experts = cfg.layer_kind(i)
-        if op == CONV:
+        if op is None:  # a feed-forward alone: the block's one norm is norm2, below
+            p = {}
+        elif op == CONV:
             p = {"norm1": gain(d), "w_in": w(d, 3 * d), "conv_w": w(d, cfg.conv_taps),
-                 "w_out": w(d, d), "norm2": gain(d)}
+                 "w_out": w(d, d)}
         elif op == LINEAR:
             wide = cfg.num_heads * cfg.linear_head_dim
             # taps of order 1, so that the SiLU is not in its linear part; the decay's A and b
@@ -614,10 +680,11 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                  "conv_w": w(3 * wide, cfg.conv_taps, scale=0.5), "w_f": w(d, wide),
                  "decay_a": between(-0.7, 0.7, cfg.num_heads), "decay_b": between(-6.0, 2.0, wide),
                  "w_beta": w(d, cfg.num_heads), "w_z": w(d, wide),
-                 "o_norm": gain(cfg.linear_head_dim), "wo": w(wide, d), "norm2": gain(d)}
+                 "o_norm": gain(cfg.linear_head_dim), "wo": w(wide, d)}
         elif op == MAMBA:
             wide = cfg.ssm_heads * cfg.ssm_head_dim
-            conv = wide + 2 * cfg.ssm_state  # [x | B | C]: what the convolution passes over
+            # [x | B | C]: what the convolution passes over, a B and a C a group of heads
+            conv = wide + 2 * cfg.ssm_groups * cfg.ssm_state
             # Mamba-2's published initialiser: A = -U(1, 16), the step's bias the inverse softplus
             # of a log-uniform step in [0.001, 0.1] (log-decays from -0.001 to -1.6 a token), D 1;
             # taps of order 1 (ling3's) and a bias in +-0.5, so that the SiLU is not in its linear part
@@ -627,7 +694,7 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                  "dt_bias": first + jnp.log(-jnp.expm1(-first)),
                  "a_log": jnp.log(between(1.0, 16.0, cfg.ssm_heads)),
                  "d_skip": jnp.ones((cfg.ssm_heads,), jnp.float32), "ssm_norm": gain(wide),
-                 "w_out": w(wide, d), "norm2": gain(d)}
+                 "w_out": w(wide, d)}
             if cfg.conv_bias:
                 p["conv_b"] = between(-0.5, 0.5, conv).astype(dtype)
         elif op == LATENT:
@@ -639,7 +706,7 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 p = {"norm1": gain(d), "wq": w(d, heads * hd)}
             p.update({"wkv_a": w(d, rkv + cfg.qk_rope_head_dim), "kv_a_norm": gain(rkv),
                       "wkv_b": w(rkv, heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-                      "wo": w(heads * cfg.v_head_dim, d), "norm2": gain(d)})
+                      "wo": w(heads * cfg.v_head_dim, d)})
             if cfg.attn_gate:
                 p["w_attn_gate"] = w(d, heads)
             if cfg.indexer_heads:  # the index queries read the query's normed low rank
@@ -655,7 +722,7 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
             p = {
                 "norm1": gain(d), "wq": w(d, heads * hd, scale=sharp),
                 "wk": w(d, cfg.num_kv_heads * hd, scale=sharp),
-                "wv": w(d, cfg.num_kv_heads * hd), "wo": w(heads * hd, d), "norm2": gain(d),
+                "wv": w(d, cfg.num_kv_heads * hd), "wo": w(heads * hd, d),
             }
             if cfg.qk_norm:
                 p.update(q_norm=gain(hd), k_norm=gain(hd))
@@ -665,18 +732,26 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 p.update(_index_params(cfg, w, gain, d))
         if cfg.sandwich:  # each branch's second norm
             p.update(norm1_post=gain(d), norm2_post=gain(d))
+        gated = cfg.mlp_act == "silu"  # an ungated MLP (relu2) has no W_gate: only what a block has is drawn
+        if experts is not None:
+            p["norm2"] = gain(d)  # the feed-forward's norm (an operator ALONE has none)
         if experts:
             held = cfg.experts_held[1]
-            p.update(router=w(d, cfg.num_experts), w_gate=w(held, d, cfg.expert_width),
-                     w_up=w(held, d, cfg.expert_width), w_down=w(held, cfg.expert_width, d))
+            p.update(router=w(d, cfg.num_experts))
+            if gated:
+                p.update(w_gate=w(held, d, cfg.expert_width))
+            p.update(w_up=w(held, d, cfg.expert_width), w_down=w(held, cfg.expert_width, d))
             if cfg.expert_bias:
                 p.update(router_bias=w(cfg.num_experts, dtype=jnp.float32))
             if cfg.shared_experts:
                 wide = cfg.shared_experts * cfg.expert_width
-                p.update(shared_gate=w(d, wide), shared_up=w(d, wide), shared_down=w(wide, d))
-        else:
-            p.update(w_gate=w(d, cfg.intermediate_size), w_up=w(d, cfg.intermediate_size),
-                     w_down=w(cfg.intermediate_size, d))
+                if gated:
+                    p.update(shared_gate=w(d, wide))
+                p.update(shared_up=w(d, wide), shared_down=w(wide, d))
+        elif experts is not None:
+            if gated:
+                p.update(w_gate=w(d, cfg.intermediate_size))
+            p.update(w_up=w(d, cfg.intermediate_size), w_down=w(cfg.intermediate_size, d))
         layers.append(p)
     params = {"patch": w(cfg.patch_dim, d), "embed": w(cfg.vocab_size, d), "layers": layers,
               "norm": gain(d)}
@@ -849,7 +924,9 @@ def _indexer(p, a, idx_angles, cfg: DecoderConfig, q_from=None):
 
 
 def _dense_mlp(p, b):
-    h = (jax.nn.silu(_mm(b, p["w_gate"])) * _mm(b, p["w_up"])).astype(b.dtype)
+    """``MLP(b)``: gated SiLU where ``p`` has a ``w_gate``, the ungated
+    ``relu(b W_up)^2 W_down`` where it has none (``moe.hidden_rows``)."""
+    h = hidden_rows(lambda w: _mm(b, w), p.get("w_gate"), p["w_up"]).astype(b.dtype)
     return _mm(h, p["w_down"]).astype(b.dtype)
 
 
@@ -1092,7 +1169,7 @@ def _onto(x, o, wo, by: float):
 
 def _ssm_projections(p, x, cfg: DecoderConfig):
     """``x [T, D]`` -> ``(z [T, H*P]`` (the gate's), ``[x | B | C] [T, H*P +
-    2*N]`` before their convolution, ``dt [T, H]`` float32 (the step's
+    2*G*N]`` before their convolution (``G`` groups of B and C), ``dt [T, H]`` float32 (the step's
     pre-activation: a log-decay is summed over a chunk's rows, so it is
     never rounded to bf16)): ``W_in``'s COLUMNS are cut, not the product
     (:func:`_latent_projections` says why), so each array leaves its own
@@ -1100,7 +1177,7 @@ def _ssm_projections(p, x, cfg: DecoderConfig):
     w = p["w_in"]
     dt = w.dtype  # the activations' type (the stream x itself: `cfg.stream_dtype`)
     wide = cfg.ssm_heads * cfg.ssm_head_dim
-    conv = wide + 2 * cfg.ssm_state
+    conv = w.shape[1] - wide - cfg.ssm_heads  # H*P + 2*G*N: a B and a C a group, from the shapes
     a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
     return (_mm(a, w[:, :wide]).astype(dt), _mm(a, w[:, wide:wide + conv]).astype(dt),
             _mm(a, w[:, wide + conv:]))
@@ -1112,9 +1189,10 @@ def state_space(p, x, batch: int, cfg: DecoderConfig):
     W_in``; ``xBC`` through a causal depthwise convolution of ``conv_taps``
     taps with a bias and a SiLU (:func:`conv_silu`; zeros before each
     sequence); ``[x | B | C] = xBC``, the selective scan over ``ssm_heads``
-    heads with ONE ``B`` and ``C`` for all (the state starts at 0 with every
+    heads with ONE ``B`` and ``C`` for all, or one a group of heads where the
+    shapes hold ``ssm_groups`` of them (the state starts at 0 with every
     sequence), the skip ``D x``, the gate ``silu(z)`` and THEN the RMS norm
-    over all of a token's channels; then ``W_out``. No rotary, no bias in
+    over all of a token's channels, or over each group's; then ``W_out``. No rotary, no bias in
     the products. Under the scopes ``proj`` (the norm, ``W_in``'s three
     products, ``W_out``), ``conv`` (the convolution, its bias and SiLU: one
     pass over ``[T, H*P + 2*N]``) and ``ssd`` (the step's softplus, the
@@ -1136,9 +1214,9 @@ def state_space(p, x, batch: int, cfg: DecoderConfig):
 def _mlp_onto(p, x, cfg: DecoderConfig):
     """``x + residual_multiplier * MLP(rms(x))``, the dense feed-forward of
     a model with a residual multiplier, jitted by name (as :func:`state_space`'s parts)."""
-    dt = p["w_gate"].dtype
+    dt = p["w_up"].dtype
     b = rms_norm(x, p["norm2"], cfg.rms_eps).astype(dt)
-    h = (jax.nn.silu(_mm(b, p["w_gate"])) * _mm(b, p["w_up"])).astype(dt)
+    h = hidden_rows(lambda w: _mm(b, w), p.get("w_gate"), p["w_up"]).astype(dt)
     return _onto(x, h, p["w_down"], cfg.residual_multiplier)
 
 
@@ -1153,9 +1231,9 @@ def _onto_normed(x, o, wo, g, eps: float):
 def _mlp_onto_normed(p, x, eps: float):
     """``x + rms(MLP(rms(x; norm2)); norm2_post)``: a sandwich layer's dense
     feed-forward, jitted by name (as :func:`_onto_normed`)."""
-    dt = p["w_gate"].dtype
+    dt = p["w_up"].dtype
     b = rms_norm(x, p["norm2"], eps).astype(dt)
-    h = (jax.nn.silu(_mm(b, p["w_gate"])) * _mm(b, p["w_up"])).astype(dt)
+    h = hidden_rows(lambda w: _mm(b, w), p.get("w_gate"), p["w_up"]).astype(dt)
     return _onto_normed(x, h, p["w_down"], p["norm2_post"], eps)
 
 
@@ -1233,7 +1311,10 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     runs under ``conv``, linear attention's, the state-space layer's, latent attention's or
     attention's scopes, the
     feed-forward under ``moe`` (the routed experts), ``shared_expert``
-    (beside them, added once) or ``mlp`` (dense)."""
+    (beside them, added once) or ``mlp`` (dense). What the kind does not name
+    does not run: a layer that is ONE block (``cfg.single_block``) has no
+    operator (``op`` None) or no feed-forward (``experts`` None), one norm,
+    and counts zeros where a layer of the other kind counts."""
     op, experts = kind
     live, causal = 0, 0
     if cfg.sandwich and (op != ATTENTION or experts or cfg.attn_gate or cfg.indexer_heads
@@ -1249,7 +1330,7 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
         x = state_space(p, x, batch, cfg)
     elif op == LATENT:
         x, live, causal = latent_attention(p, x, angles, batch, cfg, idx_angles)
-    else:
+    elif op is not None:  # (None: a feed-forward alone)
         x, live, causal = _attention(p, x, angles, idx_angles, batch, cfg,
                                      cfg.sliding_window if op == SLIDING else 0)
     share = experts and cfg.layer_stats > 4
@@ -1260,15 +1341,16 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
                 b = rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype)
                 return b, x + _dense_mlp(p, b)
 
-            given = jax.jit(beside)({"norm2": p["norm2"], "w_gate": p["shared_gate"],
-                                     "w_up": p["shared_up"], "w_down": p["shared_down"]}, x)
+            given = jax.jit(beside)({"norm2": p["norm2"], "w_up": p["shared_up"],
+                                     "w_down": p["shared_down"],
+                                     **({"w_gate": p["shared_gate"]} if "shared_gate" in p else {})}, x)
     with jax.named_scope("moe" if experts else "mlp"):
         def mlp(p, x, *given):
             b, onto = given or (rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype), x)
             if not experts:
                 return x + _dense_mlp(p, b), jnp.zeros((), jnp.int32)
             y, tokens = dropless_moe(
-                b, p["router"], p["w_gate"], p["w_up"], p["w_down"], k=cfg.experts_per_token,
+                b, p["router"], p.get("w_gate"), p["w_up"], p["w_down"], k=cfg.experts_per_token,
                 num_experts=cfg.num_experts, experts_held=cfg.experts_held,
                 renormalise=cfg.norm_topk_prob, scoring=cfg.router_scoring,
                 select_bias=p.get("router_bias"), gate_eps=cfg.gate_eps,
@@ -1277,12 +1359,14 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
             out = onto + y
             return (out, jnp.max(tokens), jnp.sum(tokens)) if share else (out, jnp.max(tokens))
 
-        if cfg.sandwich:
-            dense = {k: p[k] for k in ("norm2", "w_gate", "w_up", "w_down", "norm2_post")}
+        if experts is None:  # an operator ALONE: no feed-forward, and nothing of one is counted
+            busiest, held = jnp.zeros((), jnp.int32), ()
+        elif cfg.sandwich:
+            dense = {k: p[k] for k in ("norm2", "w_gate", "w_up", "w_down", "norm2_post") if k in p}
             x = jax.jit(_mlp_onto_normed, static_argnums=2)(dense, x, cfg.rms_eps)
             busiest, held = jnp.zeros((), jnp.int32), ()
         elif not experts and cfg.residual_multiplier != 1.0:
-            dense = {k: p[k] for k in ("norm2", "w_gate", "w_up", "w_down")}
+            dense = {k: p[k] for k in ("norm2", "w_gate", "w_up", "w_down") if k in p}
             x = jax.jit(_mlp_onto, static_argnums=2)(dense, x, cfg)
             busiest, held = jnp.zeros((), jnp.int32), ()
         else:

@@ -176,6 +176,8 @@ def _words_kernel(x_ref, o_ref, buf, sem, *, rows, words, steps):
             return carry
 
         lax.fori_loop(0, words, word, None, unroll=True)  # traced once: a warm start pays the trace
+        if x_ref.shape[1] % (2 * _LANES):  # an odd last chunk of 128 columns: the low halves of one word more
+            buf[slot, pl.ds(first, _CHUNK), words, :] = bits(2 * words) >> 16
         return c
 
     lax.fori_loop(0, rows // _CHUNK, chunk, 0)
@@ -189,7 +191,9 @@ def _words_kernel(x_ref, o_ref, buf, sem, *, rows, words, steps):
 
 
 def _rows_as_words(x, view: int, interpret) -> jax.Array:
-    """``x [N, D]`` bfloat16, ``N`` in whole 16s and ``D`` in whole 256s -> ``[N, view / 2, 128]`` uint32, the words
+    """``x [N, D]`` bfloat16, ``N`` in whole 16s and ``D`` in whole 128s (an odd last chunk of 128
+    columns is the low halves of a last word, whose high halves are 0: no column is padded first, as
+    until PR 64, a pass over ``[N, D]``) -> ``[N, view / 2, 128]`` uint32, the words
     :func:`gather_rows` reads (a row ONE block of ``view / 2`` word sublanes, those past ``D /
     256`` left as they were), in one pass over ``x``. XLA's own relayout to the ``[N, view,
     128]`` view takes two passes where the columns are padded first (a pad, a transposing copy:
@@ -305,7 +309,7 @@ def sum_counted_rows(out, back, limit, gates, *, interpret: Optional[bool] = Non
     # a row as ONE block for a copy: whole 8-row tiles of the [N, ., 128] view
     view = -(-chunks // 8) * 8
     if halves == 2:  # (whole words of whole 16 rows as they come: the pad is none)
-        rows = _rows_as_words(jnp.pad(out, ((0, -n % _CHUNK), (0, -d % (2 * _LANES)))), view, interpret)
+        rows = _rows_as_words(jnp.pad(out, ((0, -n % _CHUNK), (0, -d % _LANES))), view, interpret)
     else:  # 32-bit rows are their own words: XLA's view
         rows = jnp.pad(out.astype(jnp.float32), ((0, 0), (0, view * _LANES - d))).reshape(n, view, _LANES)
     # token slots a grid step: whole tiles of 1,024 (an SMEM block's), or one step of them all

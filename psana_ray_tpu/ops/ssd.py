@@ -6,10 +6,14 @@ sequence, ``H_0 = 0`` at every sequence's first token; with the step
 
     H_t = exp(Delta_t[h] A[h]) H_{t-1} + Delta_t[h] x_t[h] (x) B_t        y_t[h] = H_t C_t + D[h] x_t[h]
 
-``B_t`` and ``C_t`` (``N`` wide) are shared by ALL heads (one group), the
-decay is ONE scalar a head and token, and the output is gated and then
-normed over all ``H * P`` channels of a token: ``rms(y * silu(z); gain)``
-(the gate BEFORE the norm, one group). ``ops/delta_rule.py`` is the nearest
+``B_t`` and ``C_t`` (``N`` wide) are shared by the heads of a GROUP (``G``
+groups of ``H / G`` consecutive heads: head ``h`` reads ``B_t[h // (H/G)]``;
+one group where all heads share them), the decay is ONE scalar a head and
+token, and the output is gated and then normed BY GROUP, over the ``H * P /
+G`` channels of a token's group: ``rms(y * silu(z); gain)`` (the gate BEFORE
+the norm; over all ``H * P`` channels under one group). ``G`` is read from
+the shapes: ``[x | B | C]`` is ``H * P + 2 * G * N`` wide, the groups' ``B``
+side by side and then their ``C``. ``ops/delta_rule.py`` is the nearest
 thing in the package and shares only the skeleton (chunks on a sequential
 grid axis, a float32 state in VMEM scratch zeroed at a sequence's first
 chunk, ``chunk_rows``): no inverse, no per-channel decay, no L2 norm here.
@@ -46,7 +50,16 @@ own underflow. What shapes it on the chip:
   columns (float32, in scratch) and add up the rows' sums of squares; the
   last group norms the rows and writes the block once. One kernel reads
   ``x``, ``B``, ``C``, ``z`` and ``dt`` once and writes the normed output
-  once; ``C B^T`` is made at a chunk's first group and kept.
+  once; ``C B^T`` is made at a chunk's first group and kept;
+- under ``G`` groups of ``B`` and ``C`` a grid step's heads lie inside ONE of
+  them (at most ``HEADS``, and a divisor of ``H / G``), its ``B`` and ``C``
+  blocks are that group's (the block index a function of the grid's
+  head-group), and all of the above holds per group: the output block is the
+  group's ``H * P / G`` columns, ``C B^T`` is made at the group's first step
+  and the norm closes at its last. At 64 heads in 8 groups a grid step's
+  heads ARE one group: ``C B^T`` is the step's own and the norm closes inside
+  the step (no scratch but the states: nothing is carried across the chunk's
+  steps and no write is deferred).
 
 Matrix products take bf16 operands and sum in float32; the state, the
 running sums (a triangular product of ``Delta A`` split in three bf16
@@ -100,9 +113,16 @@ def scan_rows(seq_len: int) -> int:
 
 
 def _kernel(x_ref, b_ref, c_ref, z_ref, dtr_ref, dtc_ref, bias_r_ref, nega_r_ref, bias_c_ref,
-            nega_c_ref, skip_ref, gain_ref, o_ref, state_ref, y_ref, ss_ref, cb_ref, *,
-            heads, p, pack, eps):
+            nega_c_ref, skip_ref, gain_ref, o_ref, state_ref, *carried_refs,
+            heads, p, pack, eps, steps=None):
+    """``steps``: the grid steps (of ``heads`` heads) that one group of ``B``
+    and ``C`` spans, ``None`` where all heads share one (then every step of a
+    chunk). Over more than one step the gated columns (``y_ref``), the rows'
+    sums of squares (``ss_ref``) and ``C B^T`` (``cb_ref``) are carried in
+    ``carried_refs``; a step that is a whole group of its own carries nothing."""
     c, g = pl.program_id(1), pl.program_id(2)
+    own = steps == 1  # this step's heads are one whole group of B and C
+    slot = g if steps is None else g % steps  # the step's place among its group's
     q = x_ref.shape[0]
     wide = p * pack  # a pack's lanes
     packs = heads // pack
@@ -114,10 +134,13 @@ def _kernel(x_ref, b_ref, c_ref, z_ref, dtr_ref, dtc_ref, bias_r_ref, nega_r_ref
     row = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
 
-    @pl.when(g == 0)  # a chunk starts: C B^T once for all its heads, its lower triangle
-    def _chunk():
-        ss_ref[...] = jnp.zeros(ss_ref.shape, jnp.float32)
-        cb_ref[...] = jnp.where(row >= col, _mm(c_ref[...], b_ref[...], ((1,), (1,))), 0.0)
+    if not own:
+        y_ref, ss_ref, cb_ref = carried_refs
+
+        @pl.when(slot == 0)  # a group starts: C B^T once for all its heads, its lower triangle
+        def _chunk():
+            ss_ref[...] = jnp.zeros(ss_ref.shape, jnp.float32)
+            cb_ref[...] = jnp.where(row >= col, _mm(c_ref[...], b_ref[...], ((1,), (1,))), 0.0)
 
     # the steps and the running sums of Delta A, as columns [Q, heads] and as rows [heads, Q]
     step = jax.nn.softplus(dtr_ref[...] + bias_r_ref[...])
@@ -141,7 +164,8 @@ def _kernel(x_ref, b_ref, c_ref, z_ref, dtr_ref, dtc_ref, bias_r_ref, nega_r_ref
         return out
 
     # part by part through all the heads: one head's parts are a chain
-    cb = cb_ref[...]
+    cb = (jnp.where(row >= col, _mm(c_ref[...], b_ref[...], ((1,), (1,))), 0.0) if own
+          else cb_ref[...])
     masks = [jnp.exp(jnp.minimum(s[:, h:h + 1] - s_t[h:h + 1], 0.0)) * cb for h in range(heads)]
     at = [slice(k * wide, (k + 1) * wide) for k in range(packs)]
     x = [x_ref[:, cols].astype(jnp.float32) for cols in at]
@@ -154,13 +178,24 @@ def _kernel(x_ref, b_ref, c_ref, z_ref, dtr_ref, dtc_ref, bias_r_ref, nega_r_ref
         out = y[k] + spread(carried, k) * from_state[k] + skip_ref[:, cols] * x[k]
         gate = z_ref[:, cols].astype(jnp.float32)
         out = out * gate * jax.nn.sigmoid(gate)
-        y_ref[g, :, cols] = out
-        ss_ref[...] += jnp.sum(out * out, axis=-1, keepdims=True)
+        if own:
+            y[k] = out
+        else:
+            y_ref[slot, :, cols] = out
+            ss_ref[...] += jnp.sum(out * out, axis=-1, keepdims=True)
     for k in range(packs):
         state_ref[g, k] = (states[k] * spread(left, k, row_head)
                            + _mm(x[k] * spread(kept, k), b_ref[...], ((0,), (0,))))
 
-    @pl.when(g == pl.num_programs(2) - 1)  # the chunk's last heads: every channel of a row is there
+    if own:  # every channel of the rows' group is here: the norm closes inside the step
+        scale = jax.lax.rsqrt(sum(jnp.sum(out * out, axis=-1, keepdims=True) for out in y)
+                              / o_ref.shape[1] + eps)
+        for k, cols in enumerate(at):
+            o_ref[:, cols] = (y[k] * scale * gain_ref[:, cols]).astype(o_ref.dtype)
+        return
+
+    # the group's last heads: every channel of a row's group is there
+    @pl.when(slot == (pl.num_programs(2) if steps is None else steps) - 1)
     def _norm():
         scale = jax.lax.rsqrt(ss_ref[...] / o_ref.shape[1] + eps)
         for j in range(y_ref.shape[0]):
@@ -173,12 +208,14 @@ def _kernel(x_ref, b_ref, c_ref, z_ref, dtr_ref, dtc_ref, bias_r_ref, nega_r_ref
 def ssd_scan(xbc, z, dt, dt_bias, a_log, skip, gain, *, seq_len: int, heads: int, state: int,
              eps: float, rows: Optional[int] = None,
              interpret: Optional[bool] = None) -> jax.Array:
-    """``xbc [T, H*P + 2*N]`` (``[x | B | C]`` after their convolution, ``T``
-    rows being whole sequences of ``seq_len``; ``x`` head after head, read in
-    place as column blocks), ``z [T, H*P]`` (the gate's), ``dt [T, H]``
+    """``xbc [T, H*P + 2*G*N]`` (``[x | B | C]`` after their convolution, ``T``
+    rows being whole sequences of ``seq_len``; ``x`` head after head, then the
+    ``G`` groups' ``B``, then their ``C``, each read in place as column blocks;
+    ``G`` follows from the width), ``z [T, H*P]`` (the gate's), ``dt [T, H]``
     float32 (the step's pre-activation: a log-decay is summed over a chunk),
     ``dt_bias, a_log, skip [H]``, ``gain [H*P]`` -> ``rms((y + skip x) *
-    silu(z); gain) [T, H*P]`` in ``xbc``'s type. ``state`` is ``N``, ``eps``
+    silu(z); gain) [T, H*P]`` in ``xbc``'s type, the norm over each group's
+    ``H*P / G`` channels. ``state`` is ``N``, ``eps``
     the norm's; ``rows`` a chunk's rows where a test or a timing run sets them
     (None, as the model calls it: :func:`scan_rows`)."""
     from jax.experimental.pallas import tpu as pltpu
@@ -186,11 +223,19 @@ def ssd_scan(xbc, z, dt, dt_bias, a_log, skip, gain, *, seq_len: int, heads: int
     t, wide = z.shape
     p = wide // heads
     rows = rows or scan_rows(seq_len)
-    if (xbc.shape != (t, wide + 2 * state) or dt.shape != (t, heads) or t % seq_len
-            or wide % heads or wide % state):
+    bc_groups = max((xbc.shape[1] - wide) // (2 * state), 1)  # of B and C, from the shapes
+    if (xbc.shape != (t, wide + 2 * bc_groups * state) or dt.shape != (t, heads) or t % seq_len
+            or wide % heads or wide % state or heads % bc_groups):
         raise ValueError(f"ssd: [x | B | C] {xbc.shape}, z {z.shape} and dt {dt.shape} are not "
-                         f"sequences of {seq_len} rows of {heads} heads over a state of {state}")
-    group = next(n for n in range(min(HEADS, heads), 0, -1) if heads % n == 0)
+                         f"sequences of {seq_len} rows of {heads} heads over a state of {state} "
+                         f"in whole groups of B and C")
+    # a grid step's heads lie inside one group of B and C
+    group = next(n for n in range(min(HEADS, heads // bc_groups), 0, -1)
+                 if heads // bc_groups % n == 0)
+    # the grid steps a group of B and C spans (None: one group, every step of a chunk), and the
+    # steps whose columns one output block holds
+    steps = None if bc_groups == 1 else heads // bc_groups // group
+    span = heads // group if steps is None else steps
     pack = next(n for n in range(min(max(LANES // p, 1), group), 0, -1) if group % n == 0)
     n_groups, n_chunks = heads // group, seq_len // rows
     if interpret is None:
@@ -211,25 +256,29 @@ def ssd_scan(xbc, z, dt, dt_bias, a_log, skip, gain, *, seq_len: int, heads: int
         return b * n_chunks + c
 
     cols = pl.BlockSpec((rows, group * p), lambda b, c, g: (chunk_of(b, c, g), g))
-    shared = [pl.BlockSpec((rows, state),
-                           lambda b, c, g, k=k: (chunk_of(b, c, g), wide // state + k))
-              for k in (0, 1)]  # B, then C: the column blocks after x's
+    def of_group(g):  # the group of B and C a grid step's heads lie in (one group: a constant)
+        return 0 if steps is None else g // steps
+
+    shared = [pl.BlockSpec((rows, state), lambda b, c, g, k=k: (
+        chunk_of(b, c, g), wide // state + k * bc_groups + of_group(g)))
+              for k in (0, 1)]  # the groups' B, then their C: the column blocks after x's
+    carried = [] if steps == 1 else [pltpu.VMEM((span, rows, group * p), f32),
+                                     pltpu.VMEM((rows, 1), f32), pltpu.VMEM((rows, rows), f32)]
     row_entry = pl.BlockSpec((None, 1, group), lambda b, c, g: (g, 0, 0))
     col_entry = pl.BlockSpec((None, group, 1), lambda b, c, g: (g, 0, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, heads=group, p=p, pack=pack, eps=float(eps)),
+        functools.partial(_kernel, heads=group, p=p, pack=pack, eps=float(eps), steps=steps),
         grid=(t // seq_len, n_chunks, n_groups),
         in_specs=[cols, *shared, cols,
                   pl.BlockSpec((None, rows, group), lambda b, c, g: (g, chunk_of(b, c, g), 0)),
                   pl.BlockSpec((None, group, rows), lambda b, c, g: (g, 0, chunk_of(b, c, g))),
                   row_entry, row_entry, col_entry, col_entry,
                   pl.BlockSpec((1, group * p), lambda b, c, g: (0, g)),
-                  pl.BlockSpec((1, wide), lambda b, c, g: (0, 0))],
-        out_specs=pl.BlockSpec((rows, wide), lambda b, c, g: (chunk_of(b, c, g), 0)),
+                  pl.BlockSpec((1, span * group * p), lambda b, c, g: (0, of_group(g)))],
+        out_specs=pl.BlockSpec((rows, span * group * p),
+                               lambda b, c, g: (chunk_of(b, c, g), of_group(g))),
         out_shape=jax.ShapeDtypeStruct((t, wide), xbc.dtype),
-        scratch_shapes=[pltpu.VMEM((n_groups, group // pack, pack * p, state), f32),
-                        pltpu.VMEM((n_groups, rows, group * p), f32),
-                        pltpu.VMEM((rows, 1), f32), pltpu.VMEM((rows, rows), f32)],
+        scratch_shapes=[pltpu.VMEM((n_groups, group // pack, pack * p, state), f32), *carried],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),

@@ -318,7 +318,13 @@ def grouped_tiles(m: int, groups: int, k: int, n: int, out_bytes: int):
     down product ``[104448, 768] x [128, 768, 2560]`` (68,762 rows held)
     computed 4,096 columns for 2,560 and took 4.45 ms at (512, 768, 2048),
     2.77 at (512, 768, 2560). LFM2's ``2048 x 1792`` goes in 896 (whole it
-    overflows Mosaic's VMEM), laguna's ``1024 x 3072`` in 1,536.
+    overflows Mosaic's VMEM), laguna's ``1024 x 3072`` in 1,536. A width no
+    128-multiple divides (1,856 = 14.5 lane tiles, the ungated experts' of PR
+    64) goes in the tile within those 4 MiB that computes the fewest columns
+    past it, the widest of those: 640, three tiles, 1,920 columns for 1,856
+    (whole, as until then, its weight tile ``[2688, 1856]`` is 10 MB and the
+    kernel's 41.7 MB of VMEM where Mosaic allows 37: compiled for a described
+    v5e, PR 64).
 
     (2) ``tk = k`` where a weight tile of the whole contraction and 512
     columns (or all ``n``) stays within those 4 MiB, that is up to ``k``
@@ -355,10 +361,14 @@ def grouped_tiles(m: int, groups: int, k: int, n: int, out_bytes: int):
     def dividing(x, most):  # the largest 128-multiple of at most `most` that divides x
         return next((t for t in range(most - most % 128, 0, -128) if x % t == 0), None)
 
+    def covering(x, most):  # where none divides x: the one that computes the fewest columns past x
+        return min(range(128, most - most % 128 + 1, 128), key=lambda t: (-(-x // t) * t, -t),
+                   default=None)
+
     whole = WEIGHT_TILE_BYTES // (2 * k) >= min(n, 512)
     tk = k if whole else dividing(k, 2048) or 2048
     fits = WEIGHT_TILE_BYTES // (2 * tk)
-    tn = n if n <= fits else dividing(n, fits) or min(n, 2048)
+    tn = n if n <= fits else dividing(n, fits) or covering(n, fits) or min(n, 2048)
     quarter = m // groups // 4
     most = quarter if whole and quarter >= 128 else 512
     tm = next((t for t in (512, 256, 128, 64, 32, 16, 8)
@@ -388,16 +398,45 @@ def _grouped_product(rows, weights, tokens, out_dtype, interpret):
 
     (m, k), (groups, _, n) = rows.shape, weights.shape
     tiling = grouped_tiles(m, groups, k, n, jnp.dtype(out_dtype).itemsize)
+    if n % 128 and not k % 128:
+        # the device lays a weight whose columns are no whole lane tiles out with its CONTRACTION
+        # minor (`{1,2,0}`: no lane padded), the kernel's operand is row-major, and XLA copied the
+        # weight at every use (2.02 ms for `[64, 2688, 1856]`, under no scope: my chip run, PR 64).
+        # In that layout its transpose IS row-major, a bitcast: the kernel contracts both minor axes
+        return gmm(rows, jnp.swapaxes(weights, 1, 2), tokens, preferred_element_type=out_dtype,
+                   tiling=tiling, transpose_rhs=True, interpret=interpret)
     return gmm(rows, weights, tokens, preferred_element_type=out_dtype, tiling=tiling,
                interpret=interpret)
+
+
+def hidden_rows(product, w_gate, w_up):
+    """An expert's (or a dense MLP's) hidden rows, float32, from
+    ``product(w)``, its input rows times a weight in float32: the gated SiLU
+    ``silu(product(w_gate)) * product(w_up)`` where a gate's weights are
+    given, ``relu(product(w_up))^2`` where none are (``w_gate`` None: an
+    UNGATED expert, two products a layer and not three). The one place the
+    form stands: the all-held path, the held rows' loop, the pass ahead of it
+    and the decoder's dense and shared MLPs read it here; the caller rounds."""
+    if w_gate is None:
+        return jnp.square(jax.nn.relu(product(w_up)))
+    h = jax.nn.silu(product(w_gate))
+    return h * product(w_up)
+
+
+def _expert_hidden(product, rows, w_gate, w_up, dtype):
+    """:func:`hidden_rows` of the experts' input ``rows`` by the grouped
+    ``product``, rounded once to ``dtype``."""
+    return hidden_rows(lambda w: product(rows, w, out_dtype=jnp.float32), w_gate, w_up).astype(dtype)
 
 
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
                  experts_held=None, renormalise: bool = True, scoring: str = "softmax",
                  select_bias=None, gate_eps: float = 0.0, gate_scale: float = 1.0,
                  groups: int = 1, groups_kept: int = 1, interpret=None):
-    """Top-``k`` of ``num_experts`` gated-SiLU experts with NO dropped
-    token: ``x [T, D]`` -> ``(y [T, D], tokens [count] int32)``.
+    """Top-``k`` of ``num_experts`` experts with NO dropped token: ``x [T,
+    D]`` -> ``(y [T, D], tokens [count] int32)``. An expert is a gated-SiLU
+    MLP, or, where ``w_gate`` is ``None``, the UNGATED ``relu(x W_up)^2
+    W_down`` (:func:`hidden_rows`).
 
     The layer is told which experts it holds, ``experts_held = (first,
     count)`` (default: all), and given THEIR weights only (``w_gate, w_up
@@ -449,8 +488,7 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
     with jax.named_scope("moe_experts"):
         rows = gather_rows(x, order // k, interpret=interpret)  # [T*k, D], expert-major
         product = functools.partial(_grouped_product, tokens=per_expert, interpret=interpret)
-        h = jax.nn.silu(product(rows, w_gate, out_dtype=jnp.float32))
-        h = (h * product(rows, w_up, out_dtype=jnp.float32)).astype(x.dtype)
+        h = _expert_hidden(product, rows, w_gate, w_up, x.dtype)
         # out in x's type: the sorted rows are T*k*D, and float32 would be 2.2 GB at 34,304 x 8
         out = product(h, w_down, out_dtype=x.dtype)
         y = gated_row_sum(out, order, gates)
@@ -548,8 +586,7 @@ def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, 
         rows = gather_rows(x, token, interpret=interpret)
         sizes = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
         product = functools.partial(_grouped_product, tokens=sizes, interpret=interpret)
-        h = jax.nn.silu(product(rows, w_gate, out_dtype=jnp.float32))
-        h = (h * product(rows, w_up, out_dtype=jnp.float32)).astype(x.dtype)
+        h = _expert_hidden(product, rows, w_gate, w_up, x.dtype)
         out = product(h, w_down, out_dtype=jnp.float32)
         # past the held rows the products left `out` unwritten: 0 x garbage is not 0
         term = jnp.where(live[:, None], out * flat_gates[slot][:, None], 0.0)
@@ -571,8 +608,7 @@ def _held_rows_ahead(x, slot, back, gates, w_gate, w_up, w_down, starts, ends, i
     rows = gather_rows(x, slot // k, interpret=interpret)
     sizes = jnp.clip(ends, 0, ahead) - jnp.clip(starts, 0, ahead)
     product = functools.partial(_grouped_product, tokens=sizes, interpret=interpret)
-    h = jax.nn.silu(product(rows, w_gate, out_dtype=jnp.float32))
-    h = (h * product(rows, w_up, out_dtype=jnp.float32)).astype(x.dtype)
+    h = _expert_hidden(product, rows, w_gate, w_up, x.dtype)
     out = product(h, w_down, out_dtype=x.dtype)
     # past the held rows the products left `out` unwritten: such a slot, as one past `ahead`, adds
     # nothing whatever its row holds (the sum selects it to zero)

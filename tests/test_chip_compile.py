@@ -431,7 +431,50 @@ def _granite_ssd_scan():
         one_kernel_and_no_copy_of_its_operands
 
 
+def _nemotron3_ssd_scan():
+    """The same scan in EIGHT groups of B and C (Nemotron-H's: 64 heads of 64
+    over a state of 128, eight heads a group, ``[x | B | C]`` 6,144 wide), four
+    sequences of 8,704: ONE kernel whose grid step is a whole group (``C B^T``
+    the step's own, the norm over the group's 512 channels closed inside it),
+    ``x`` and each group's ``B`` and ``C`` read in place as column blocks."""
+    from psana_ray_tpu.ops.ssd import ssd_scan
+
+    def fn(xbc, z, dt, dt_bias, a_log, skip, gain):
+        return ssd_scan(xbc, z, dt, dt_bias, a_log, skip, gain, seq_len=8704, heads=64, state=128,
+                        eps=1e-5, interpret=False)
+
+    def one_kernel_and_no_copy_of_its_operands(text):
+        kernels = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text, re.M)
+        assert [k.split(".")[0] for k in kernels] == ["ssd_scan"], kernels
+        entry = text[text.index("ENTRY"):]
+        assert not re.search(r"= \w+\[34816,(6144|4096)\][^ ]* (copy|slice|fusion)\(", entry)
+
+    rows = 4 * 8704
+    return fn, [S((rows, 6144), BF16), S((rows, 4096), BF16), S((rows, 64), F32), S((64,), F32),
+                S((64,), F32), S((64,), F32), S((4096,), BF16)], 1, \
+        one_kernel_and_no_copy_of_its_operands
+
+
+def _nemotron3_attention():
+    """The maskless causal form at the widest group any cell has: 32 query
+    heads of 128 on 2 key-value heads, SIXTEEN a group, four sequences of
+    8,704, unturned (no rotary): ``causal_tiles`` gives the stacked score tile
+    ``[16 * bq, 1088]`` float32 its 20 MiB at a query tile of 256 rows (301 fit), which
+    the compiler takes within VMEM."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    def fn(q, k, v):
+        return sa.masked_gqa_attention(q, k, v, num_kv_heads=2, block_q=1088, block_k=1088,
+                                       interpret=False)
+
+    assert sa.causal_tiles(8704, 16, 1088, 1088) == (256, 1088)  # 301 rows fit; 256 divides 8,704
+    kv = S((4, 8704, 256), BF16)
+    return fn, [S((4, 8704, 4096), BF16), kv, kv], 1
+
+
 CASES = {
+    "nemotron3_ssd_scan_4x8704x64x64x128_in_8_groups": _nemotron3_ssd_scan,
+    "nemotron3_causal_gqa_attention_4x8704x32_on_2x128": _nemotron3_attention,
     "granite_ssd_scan_8704x64x64x128": _granite_ssd_scan,
     "ling3_gated_delta_rule_4x8704x32x128": _ling3_delta_rule,
     "ling3_conv_silu_34816x12288": _ling3_conv,
@@ -631,6 +674,13 @@ PINNED_STEPS = {
     # anything is traced; at one pass `trunk` is the code it was, to the letter)
     # (re-pinned in PR 63 with laguna's, above: its 48 call sites take q and k float32 and unturned)
     "ouro_2p6b_prefill_epix10k2m": "b888bb6e4984349512f6b63d1da4b969d42000eac112d1dc1b8e5c3303d092ce",
+    # pinned in PR 64, which brought it: the eight above were hashed on PR 63's tree first and none
+    # moved, though every one of them now goes through `layer_kind`'s and `decoder_layer`'s branches
+    # for a layer of ONE block, `moe.hidden_rows` (a gated layer's products in the order its three
+    # copies wrote them), `ssd_scan`'s groups read from the shapes (granite's kernel at one group is
+    # the kernel it was: `tests/test_decoder_nemotron3.py -k traced_equation` holds its body) and
+    # `grouped_tiles`' and `rows_as_words`' rules for a width of 14.5 or 10.5 lane tiles
+    "nemotron3_nano_prefill_epix10k2m": "80c45983f26be08aa9ea3bb7aa21f15d6ce71f27273743c3b51e1c0c4cd5959a",
 }
 
 
@@ -745,6 +795,58 @@ def test_the_granite_step_compiles_whole_with_its_kernels_under_the_scopes_a_tra
     # the stream between the layers is float32 (`decoder.trunk`), every product's operands bf16
     text = compiled.as_text()
     assert re.search(r"f32\[8704,2048\]", text) and not re.search(r"f32\[8704,8192\]\{[^}]*\} dot\(", text)
+
+
+def test_the_nemotron3_step_compiles_whole_with_nothing_array_sized_between_a_block_s_parts(
+        one_chip, monkeypatch):
+    """The whole served step of ``nemotron3_nano_prefill_epix10k2m`` at the
+    published sizes (fourteen layers of ONE block each, four frames), compiled
+    for the described v5e (three quarters of a minute): it fits the chip
+    (weights 9.2 GB, 3.1 GB of temporaries), and its Mosaic kernels are six
+    ``ssd_scan`` (under ``ssd``), two ``masked_gqa_attention`` at sixteen heads
+    a group (under ``sparse_attn``), under ``moe`` TWO grouped products an
+    expert layer in the pass ahead of the held rows' loop and two in the loop
+    (``nemotron3.held_products``' ``call_sites``: an ungated expert has no
+    gate's product) with the pass's way back (``rows_as_words``,
+    ``sum_counted_rows``), the calibration kernel, and no other. Nothing
+    array-sized stands between ``W_in``'s products, the convolution, the scan
+    and ``W_out``, nor between the rows' gather, the two grouped products and
+    the way back: no copy, transpose, slice or pad of ``[34816, 6144 | 4096]``
+    or of ``[156672, .]`` (until PR 64 ``sum_counted_rows`` padded a width of
+    10.5 lane-tile pairs, 2,688, in a pass of its own). What IS left, and
+    named in PERF.md: q relaid head-major for its sixteen heads a group, one
+    copy of ``[34816, 2, 16, 128]`` an attention layer."""
+    import collections
+
+    from benchmark.roofline import nemotron3
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, lowered = _lowered_step("nemotron3_nano_prefill_epix10k2m", one_chip)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert 9.1e9 < mem.argument_size_in_bytes < 9.3e9 and mem.temp_size_in_bytes < 4e9
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
+    pattern = cfg["hybrid_override_pattern"][:cfg["num_hidden_layers"]]
+    sites = nemotron3.held_products(cfg["step_tokens"], 6, 2688, 1856, 64, 14, pattern, 0.5)["call_sites"]
+    assert names == {"ssd_scan": pattern.count("M"), "masked_gqa_attention": pattern.count("*"),
+                     "gmm": 2 * sites, "rows_as_words": pattern.count("E"),
+                     "sum_counted_rows": pattern.count("E"), "fused_calibrate": 1}, names
+    assert sites == 2 * pattern.count("E") == 12
+    assert all("/ssd/" in line for line in calls if re.match(r"\s*%ssd_scan", line))
+    assert all("/sparse_attn/" in line for line in calls if re.match(r"\s*%masked_gqa", line))
+    assert all("/moe/" in line for line in calls
+               if re.match(r"\s*%(gmm|rows_as_words|sum_counted_rows)", line))
+    entry = text[text.index("ENTRY"):]
+    moved = re.findall(r"= \w+\[(?:34816,(?:6144|4096)|4,8704,(?:6144|4096)|156672,\d+)\][^ ]* "
+                       r"(?:copy|transpose|slice|pad|concatenate)\(.*", entry)
+    assert not moved, [line[:160] for line in moved[:3]]
+    assert len(re.findall(r"= bf16\[34816,2,16,128\][^ ]* copy\(", entry)) == pattern.count("*")
+    # no held expert's weights are copied: the device lays `w_up [64, 2688, 1856]` out with the
+    # contraction minor (`{1,2,0}`: 1,856 is no whole lane tiles) and the grouped product reads it
+    # TRANSPOSED, a bitcast (until then a copy of 638 MB at every use, 2.02 ms under no scope)
+    assert not re.findall(r"= bf16\[64,(?:2688,1856|1856,2688)\][^ ]* (?:copy|transpose|fusion)\(", text)
 
 
 def test_the_ouro_step_compiles_whole_as_one_loop_around_one_stack_of_layers(one_chip, monkeypatch):

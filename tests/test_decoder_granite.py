@@ -415,8 +415,10 @@ def test_from_mapping_reads_the_published_keys():
     published = _file()
     with pytest.raises(ValueError, match="layer_types"):
         decoder.DecoderConfig.from_mapping({**published, "layer_types": published["layer_types"][:9]})
-    with pytest.raises(ValueError, match="not built"):
-        decoder.DecoderConfig.from_mapping({**published, "mamba_n_groups": 8})
+    # more than one group of B and C builds since PR 64 (tests/test_decoder_nemotron3.py)
+    assert decoder.DecoderConfig.from_mapping({**published, "mamba_n_groups": 8}).ssm_groups == 8
+    with pytest.raises(ValueError, match="groups of B and C"):
+        decoder.DecoderConfig.from_mapping({**published, "mamba_n_groups": 7})
     with pytest.raises(ValueError, match="not built"):
         decoder.DecoderConfig.from_mapping({**published, "num_local_experts": 32})
     with pytest.raises(ValueError, match="layer_types"):  # another model's file cannot say `mamba`

@@ -479,7 +479,7 @@ WAYS_BACK = {
 
 
 @pytest.mark.parametrize("gates_are", ["powers_of_two", "with_zeros", "any"])
-@pytest.mark.parametrize("d", [2560, 2048, 64])
+@pytest.mark.parametrize("d", [2560, 2048, 384, 64])  # 384: an odd count of 128-column chunks
 @pytest.mark.parametrize("name", sorted(WAYS_BACK))
 def test_the_counted_rows_sum_is_the_k_gathers_it_replaced(name, d, gates_are):
     """``ops/row_gather.sum_counted_rows`` (interpret mode here) against the
@@ -622,6 +622,10 @@ GROUPED_PRODUCTS = {
     "a_row_tile_of_a_quarter_of_a_group": (2048, [700, 648, 700], 128, 384, jnp.float32),
     "an_output_cut_to_a_divisor": (256, [131, 125], 1024, 3072, jnp.bfloat16),
     "widths_of_no_whole_lane_tile": (48, [7, 0, 30, 11], 64, 32, jnp.float32),
+    # columns of no whole lane tile beside a contraction of whole ones: the weight is read
+    # TRANSPOSED, as the device lays it out (PR 64: the ungated experts' [2688, 1856])
+    "a_weight_read_transposed": (256, [100, 0, 90, 66], 256, 96, jnp.float32),
+    "a_weight_read_transposed_in_tiles_that_cover_its_width": (512, [200, 312], 4096, 800, jnp.bfloat16),
 }
 
 
@@ -640,6 +644,7 @@ def test_the_grouped_product_is_each_group_s_dense_product(name):
     tm, tk, tn = moe.grouped_tiles(m, len(sizes), k, n, jnp.dtype(out_dtype).itemsize)
     assert {"a_contraction_cut_in_four": tk == 1792, "an_output_cut_to_a_divisor": tn == 1536,
             "a_group_of_many_row_tiles": tm == 512, "a_row_tile_of_a_quarter_of_a_group": tm == 128,
+            "a_weight_read_transposed_in_tiles_that_cover_its_width": (tk, tn) == (4096, 128),  # 7 x 128 = 896
             }.get(name, (tk, tn) == (k, n)), (tm, tk, tn)
     rng = np.random.default_rng(len(name))
     x = jnp.asarray(rng.standard_normal((m, k)), jnp.bfloat16)

@@ -301,10 +301,12 @@ WEIGHT_TILE_BYTES = 4 * 2 ** 20  # a bf16 weight tile [tk, tn] of the grouped pr
 GMM_VMEM_BYTES = 21 * 2 ** 20  # the kernel's tiles in all, by `_gmm_vmem` (Mosaic allows 22 MB)
 
 
-def grouped_tiles(m: int, groups: int, k: int, n: int, out_bytes: int):
-    """The tiles ``(tm, tk, tn)`` the megablox grouped product runs ``[m, k] x
-    [groups, k, n]`` in, from its operands' shapes alone (``out_bytes``: the
-    output's item size). The kernel's grid is (output tiles, VISITS,
+def grouped_tiles(m: int, groups: int, k: int, n: int, out_bytes: int, gated: bool = False):
+    """The tiles ``(tm, tk, tn)`` a grouped product (megablox's, and :func:`gmm`,
+    the one that ends in the activation) runs ``[m, k] x [groups, k, n]`` in,
+    from its operands' shapes alone (``out_bytes``: the output's item size;
+    ``gated``: a float32 tile of the gate's product rides beside the output's,
+    :func:`_gmm_vmem`). The kernel's grid is (output tiles, VISITS,
     contraction tiles): a visit is one (row tile, group) pair and multiplies
     a whole ``tm x tk x tn`` block whatever part of the tile's rows are the
     group's, a row tile two groups share is visited twice, and a last output
@@ -373,40 +375,137 @@ def grouped_tiles(m: int, groups: int, k: int, n: int, out_bytes: int):
     most = quarter if whole and quarter >= 128 else 512
     tm = next((t for t in (512, 256, 128, 64, 32, 16, 8)
                if t <= most and m % t == 0 and t * tk <= 512 * 1024), m)
-    while tm > 8 and _gmm_vmem(tm, tk, tn, out_bytes) > GMM_VMEM_BYTES:
+    while tm > 8 and _gmm_vmem(tm, tk, tn, out_bytes, gated) > GMM_VMEM_BYTES:
         tm //= 2
     return tm, tk, tn
 
 
-def _gmm_vmem(tm: int, tk: int, tn: int, out_bytes: int) -> int:
+def _gmm_vmem(tm: int, tk: int, tn: int, out_bytes: int, gated: bool = False) -> int:
     """Bytes of VMEM the grouped product's tiles take: two buffers of each
-    bf16 operand's tile and of the output's, and the float32 accumulator
-    (the loop's down product at ``[2048, 1024] x [64, 1024, 3072]`` into
-    float32 took 23.4 MB at (512, 1024, 2048) where Mosaic allows 22:
-    compiled for a described v5e, PR 53)."""
-    return 2 * (2 * tm * tk + 2 * tk * tn + out_bytes * tm * tn) + 4 * tm * tn
+    bf16 operand's tile and of the output's, the float32 accumulator, and
+    where the product ends in a GATED activation (:func:`gmm`) two buffers of
+    the gate's float32 tile (the loop's down product at ``[2048, 1024] x [64,
+    1024, 3072]`` into float32 took 23.4 MB at (512, 1024, 2048) where Mosaic
+    allows 22: compiled for a described v5e, PR 53)."""
+    return 2 * (2 * tm * tk + 2 * tk * tn + (out_bytes + 4 * gated) * tm * tn) + 4 * tm * tn
+
+
+def _tiled(rows, weights, out_dtype, gated=False):
+    """``(weights as the kernel reads them, whether that is transposed, the
+    tiles)`` of a grouped product ``rows x weights`` into ``out_dtype``."""
+    (m, k), (groups, _, n) = rows.shape, weights.shape
+    tiling = grouped_tiles(m, groups, k, n, jnp.dtype(out_dtype).itemsize, gated)
+    if n % 128 and not k % 128:
+        # the device lays a weight whose columns are no whole lane tiles out with its CONTRACTION
+        # minor (`{1,2,0}`: no lane padded), the kernel's operand is row-major, and XLA copied the
+        # weight at every use (2.02 ms for `[64, 2688, 1856]`, under no scope: my chip run, PR 64).
+        # In that layout its transpose IS row-major, a bitcast: the kernel contracts both minor axes
+        return jnp.swapaxes(weights, 1, 2), True, tiling
+    return weights, False, tiling
 
 
 def _grouped_product(rows, weights, tokens, out_dtype, interpret):
     """``rows[group e] @ weights[e]`` (``tokens [E]``: the rows of each group,
     the first group at row 0; rows past the last group's stay unwritten), by
     the megablox grouped matrix product (``pallas.ops.tpu.megablox.gmm``) in
-    the tiles :func:`grouped_tiles` gives the operands' shapes. On the v5e at
-    ``[274432, 2048] x [128, 2048, 768]`` it took 6.3 ms and the down product
-    6.4, against ``lax.ragged_dot``'s 11.3 and 11.0 (my chip runs, PR 36)."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    the tiles :func:`grouped_tiles` gives the operands' shapes: an expert
+    layer's gate and down products (its up product ends in the activation:
+    :func:`gmm`). On the v5e at ``[274432, 2048] x [128, 2048, 768]`` it took
+    6.3 ms and the down product 6.4, against ``lax.ragged_dot``'s 11.3 and
+    11.0 (my chip runs, PR 36)."""
+    from jax.experimental.pallas.ops.tpu import megablox
 
-    (m, k), (groups, _, n) = rows.shape, weights.shape
-    tiling = grouped_tiles(m, groups, k, n, jnp.dtype(out_dtype).itemsize)
-    if n % 128 and not k % 128:
-        # the device lays a weight whose columns are no whole lane tiles out with its CONTRACTION
-        # minor (`{1,2,0}`: no lane padded), the kernel's operand is row-major, and XLA copied the
-        # weight at every use (2.02 ms for `[64, 2688, 1856]`, under no scope: my chip run, PR 64).
-        # In that layout its transpose IS row-major, a bitcast: the kernel contracts both minor axes
-        return gmm(rows, jnp.swapaxes(weights, 1, 2), tokens, preferred_element_type=out_dtype,
-                   tiling=tiling, transpose_rhs=True, interpret=interpret)
-    return gmm(rows, weights, tokens, preferred_element_type=out_dtype, tiling=tiling,
-               interpret=interpret)
+    weights, transposed, tiling = _tiled(rows, weights, out_dtype)
+    return megablox.gmm(rows, weights, tokens, preferred_element_type=out_dtype, tiling=tiling,
+                        transpose_rhs=transposed, interpret=interpret)
+
+
+# named as megablox's: a device trace names a kernel after the jitted function it was traced in, and
+# an expert layer's products are read there by that name (`trace_names.grouped_product`)
+@functools.partial(jax.jit, static_argnames=("out_dtype", "tiling", "transpose_rhs", "interpret"))
+def gmm(rows, weights, tokens, gate=None, *, out_dtype, tiling, transpose_rhs=False,
+        interpret=False):
+    """An expert's hidden rows from its UP product's accumulator: megablox's
+    grouped product ``rows[group e] @ weights[e]`` (its grid of (output
+    tiles, visits, contraction tiles), its group metadata and its store mask;
+    ``weights [E, n, k]`` under ``transpose_rhs``) whose last contraction step
+    stores :func:`hidden_rows`' form and not the sum: ``silu(gate) * acc``
+    where ``gate [m, n]`` float32, the gate's product, is given (one more
+    operand, in the output's own block), ``relu(acc)^2`` where it is not;
+    float32 throughout, rounded ONCE, to ``out_dtype``. A Pallas call takes no
+    epilogue from XLA: as a fusion of its own between the products the form
+    read two float32 ``[139264, 1792]`` and wrote a bf16 one, 3.5 ms a layer
+    of lfm2's under products the MXU bounds (my chip runs, PR 39)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import _get_store_mask, make_group_metadata
+
+    (m, k), n = rows.shape, weights.shape[1 if transpose_rhs else 2]
+    tm, tk, tn = tiling
+    tiles_k, k_rem = -(-k // tk), k % tk
+    operand = jnp.bfloat16 if rows.dtype == weights.dtype == jnp.bfloat16 else jnp.float32
+    gates = [] if gate is None else [gate]
+    metadata, visits = make_group_metadata(
+        group_sizes=tokens, m=m, tm=tm, start_group=0, num_nonzero_groups=weights.shape[0],
+        visit_empty_groups=False)
+
+    def kernel(metadata, lhs, rhs, *rest):
+        *gate_tile, out, acc = rest
+        visit, k_i = pl.program_id(1), pl.program_id(2)
+
+        @pl.when(k_i == 0)
+        def _():
+            acc[...] = jnp.zeros_like(acc)
+
+        def within_k(x, axis):  # the last contraction tile's part past `k` holds anything: zero
+            col = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+            return jnp.where(col < k_rem, x.astype(jnp.float32), 0).astype(x.dtype)
+
+        def step(last):
+            a, b = lhs[...], rhs[...]
+            if last and k_rem:
+                a, b = within_k(a, 1), within_k(b, int(transpose_rhs))
+            acc[...] += jax.lax.dot_general(
+                a.astype(operand), b.astype(operand), (((1,), (int(transpose_rhs),)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if last:  # the form's products are this tile of the gate's and the accumulator
+                h = hidden_rows(lambda tile: tile[...], gate_tile[0] if gate_tile else None, acc)
+                here = _get_store_mask(grid_id=visit, group_metadata=metadata, tm=tm, tn=tn)
+                # a row tile two groups share is visited twice: the other group's rows stay
+                out[...] = jax.lax.select(here, h, out[...].astype(jnp.float32)).astype(out.dtype)
+
+        jax.lax.cond(k_i == tiles_k - 1, functools.partial(step, True), functools.partial(step, False))
+
+    def row_tile(n_i, visit, k_i, metadata):
+        return metadata[2][visit], k_i
+
+    def weight_tile(n_i, visit, k_i, metadata):
+        return (metadata[1][visit], n_i, k_i) if transpose_rhs else (metadata[1][visit], k_i, n_i)
+
+    def out_tile(n_i, visit, k_i, metadata):
+        return metadata[2][visit], n_i
+
+    out_spec = pl.BlockSpec((tm, tn), out_tile)
+    call = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            in_specs=[pl.BlockSpec((tm, tk), row_tile),
+                      pl.BlockSpec((None, tn, tk) if transpose_rhs else (None, tk, tn), weight_tile),
+                      *[out_spec for _ in gates]],
+            out_specs=out_spec,
+            grid=(-(-n // tn), visits, tiles_k),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=m * n * len(gates),
+            bytes_accessed=(rows.size * rows.itemsize * -(-n // tn)
+                            + k * n * weights.itemsize * metadata[1].size
+                            + m * n * (jnp.dtype(out_dtype).itemsize + 4 * len(gates)))))
+    return call(metadata, rows, weights, *gates)
 
 
 def hidden_rows(product, w_gate, w_up):
@@ -415,18 +514,26 @@ def hidden_rows(product, w_gate, w_up):
     ``silu(product(w_gate)) * product(w_up)`` where a gate's weights are
     given, ``relu(product(w_up))^2`` where none are (``w_gate`` None: an
     UNGATED expert, two products a layer and not three). The one place the
-    form stands: the all-held path, the held rows' loop, the pass ahead of it
-    and the decoder's dense and shared MLPs read it here; the caller rounds."""
+    form stands: the decoder's dense and shared MLPs read it here over XLA's
+    own products (which fuse it as their epilogue) and round; the routed
+    experts' grouped up product (:func:`gmm`) reads it tile by tile over its
+    accumulator, in the kernel, and rounds there."""
     if w_gate is None:
         return jnp.square(jax.nn.relu(product(w_up)))
     h = jax.nn.silu(product(w_gate))
     return h * product(w_up)
 
 
-def _expert_hidden(product, rows, w_gate, w_up, dtype):
-    """:func:`hidden_rows` of the experts' input ``rows`` by the grouped
-    ``product``, rounded once to ``dtype``."""
-    return hidden_rows(lambda w: product(rows, w, out_dtype=jnp.float32), w_gate, w_up).astype(dtype)
+def _expert_hidden(rows, w_gate, w_up, tokens, dtype, interpret):
+    """:func:`hidden_rows` of the experts' input ``rows`` (``tokens [E]``: the
+    rows of each expert's group), rounded once to ``dtype``: the gate's
+    grouped product float32, then the up product that ends in the activation
+    (:func:`gmm`) - no float32 array is written between an expert layer's
+    products but the gate's, and an ungated layer writes none."""
+    gate = None if w_gate is None else _grouped_product(rows, w_gate, tokens, jnp.float32, interpret)
+    weights, transposed, tiling = _tiled(rows, w_up, dtype, gated=gate is not None)
+    return gmm(rows, weights, tokens, gate, out_dtype=dtype, tiling=tiling,
+               transpose_rhs=transposed, interpret=interpret)
 
 
 def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
@@ -457,9 +564,12 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
     Token slots are sorted by expert (stable: a token's order within an
     expert is its order in ``x``) and each row moves ONCE each way. Out:
     one in-bounds gather puts the rows in expert order
-    (``ops/row_gather.gather_rows``). The three products are grouped
-    matrix products over the sorted rows (each expert's weights meet its
-    own rows only, so the work is ``T * k`` rows whatever the load). Back
+    (``ops/row_gather.gather_rows``). The three products (two where the
+    experts have no gate) are grouped matrix products over the sorted rows
+    (each expert's weights meet its own rows only, so the work is ``T * k``
+    rows whatever the load), and the activation is the UP product's last
+    step (:func:`gmm`: float32 over the accumulator's tile, rounded once; no
+    pass of XLA's stands between an expert layer's products). Back
     (:func:`gated_row_sum`): a token's ``k`` rows are read where the sort
     put them, ``k`` in-bounds gathers of ``[T, D]``, and one pass writes
     their gated sum, float32 inside, the ``k`` added in the order of the
@@ -487,10 +597,9 @@ def dropless_moe(x, router_w, w_gate, w_up, w_down, *, k: int, num_experts: int,
         per_expert = _slots_per_expert(ids, 0, num_experts)
     with jax.named_scope("moe_experts"):
         rows = gather_rows(x, order // k, interpret=interpret)  # [T*k, D], expert-major
-        product = functools.partial(_grouped_product, tokens=per_expert, interpret=interpret)
-        h = _expert_hidden(product, rows, w_gate, w_up, x.dtype)
+        h = _expert_hidden(rows, w_gate, w_up, per_expert, x.dtype, interpret)
         # out in x's type: the sorted rows are T*k*D, and float32 would be 2.2 GB at 34,304 x 8
-        out = product(h, w_down, out_dtype=x.dtype)
+        out = _grouped_product(h, w_down, per_expert, x.dtype, interpret)
         y = gated_row_sum(out, order, gates)
     return y, per_expert
 
@@ -585,9 +694,8 @@ def _held_rows_moe(x, ids, gates, w_gate, w_up, w_down, first: int, count: int, 
         token = slot // k
         rows = gather_rows(x, token, interpret=interpret)
         sizes = jnp.clip(ends, lo, lo + chunk) - jnp.clip(starts, lo, lo + chunk)
-        product = functools.partial(_grouped_product, tokens=sizes, interpret=interpret)
-        h = _expert_hidden(product, rows, w_gate, w_up, x.dtype)
-        out = product(h, w_down, out_dtype=jnp.float32)
+        h = _expert_hidden(rows, w_gate, w_up, sizes, x.dtype, interpret)
+        out = _grouped_product(h, w_down, sizes, jnp.float32, interpret)
         # past the held rows the products left `out` unwritten: 0 x garbage is not 0
         term = jnp.where(live[:, None], out * flat_gates[slot][:, None], 0.0)
         return c + 1, y.at[token].add(term)
@@ -607,9 +715,8 @@ def _held_rows_ahead(x, slot, back, gates, w_gate, w_up, w_down, starts, ends, i
     ahead = slot.shape[0]
     rows = gather_rows(x, slot // k, interpret=interpret)
     sizes = jnp.clip(ends, 0, ahead) - jnp.clip(starts, 0, ahead)
-    product = functools.partial(_grouped_product, tokens=sizes, interpret=interpret)
-    h = _expert_hidden(product, rows, w_gate, w_up, x.dtype)
-    out = product(h, w_down, out_dtype=x.dtype)
+    h = _expert_hidden(rows, w_gate, w_up, sizes, x.dtype, interpret)
+    out = _grouped_product(h, w_down, sizes, x.dtype, interpret)
     # past the held rows the products left `out` unwritten: such a slot, as one past `ahead`, adds
     # nothing whatever its row holds (the sum selects it to zero)
     return sum_counted_rows(out, back, jnp.minimum(ends[-1], ahead), gates, interpret=interpret)

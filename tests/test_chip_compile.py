@@ -640,10 +640,19 @@ PINNED_STEPS = {
     # latent kernel takes them as two more operands with a fourth scratch; keye's, lfm2's, laguna's,
     # granite's and the looped reader's were hashed before and after and did not move: a call
     # without the tables traces the kernel it traced, `tests/test_decoder_kimi.py` holds its body)
-    "deepseek_v32_prefill_epix10k2m": "8f8ce617400dd6d0a2e314126487ecdaf361f9ccb94cd62484ed0a9befc0f858",
-    "kimi_k2_prefill_epix10k2m": "b897819c07e4cbcd2ff76a918f10efaedf96e9439a55376a6ebbe4cd87838875",
-    "keye_vl2_prefill_epix10k2m": "e71a0690926768eb794d3c96c9ba607bcd9baab98ff6cda06f37b36147d5e090",
-    "lfm2_8b_a1b_prefill_epix10k2m": "8b0c0caa3bd0b7156f13ff2f2d29e892c90b50522d24d3595d5d719fbc4cb085",
+    # (the seven steps with routed experts — these four, ling3's, laguna's and nemotron3's — re-pinned
+    # in PR 65, knowingly: an expert layer's up product is `moe.gmm`, a grouped product of the repo's
+    # own (megablox's grid, metadata and store mask) whose last contraction step stores
+    # `silu(gate) * acc` (`relu(acc)^2` where the experts have no gate) rounded once: it takes the
+    # gate's float32 product as one more operand and writes the hidden rows in the activations' type,
+    # and the float32 up product, the `logistic`, the two multiplies and the `convert` that stood
+    # between the products in the all-held path, the held rows' loop and the pass ahead are gone
+    # from the step's own text; granite's and the looped reader's, which run no grouped product,
+    # were hashed before and after and did not move)
+    "deepseek_v32_prefill_epix10k2m": "93e63035e1c6ae4b40c6061ad89aad7399b816ef9e9a8405510c80c7c76f9b79",
+    "kimi_k2_prefill_epix10k2m": "925a848d15a6ad661f9d535f21b2b40cc2d30a588532cc372ceea425e6f4d185",
+    "keye_vl2_prefill_epix10k2m": "3019bf0c0433c3f79247bb5532f0e210e2eeec021b9b4624e0204c6a14183ab3",
+    "lfm2_8b_a1b_prefill_epix10k2m": "2b73e2e07e5723518527f753ddf201bc63b7e4f51da5e8f5fcd63c6617add56e",
     # pinned in PR 56, both hashed on PR 55's tree first and NEITHER moved by it: laguna's runs
     # nothing of `ops/delta_rule.py`; ling3's does, and PR 56 rewrote that kernel's body (the heads
     # of a grid step side by side), but a Mosaic kernel's body rides in its call's `backend_config`,
@@ -651,7 +660,7 @@ PINNED_STEPS = {
     # AROUND a kernel (its operands, their shapes and types, its grid's result) and no kernel's
     # body. A kernel's own cache entry follows its body and its file's path
     # (ling3's again in PR 61: its one latent layer, above)
-    "ling3_flash_prefill_epix10k2m": "6ce2269fcd11ceeea4fb9e4b5848d3ffcb2156d0988c821782903b310a1d28af",
+    "ling3_flash_prefill_epix10k2m": "12ed14e520ad1cc01cc1ef764beeae615b3c98cb8489a8db1227c80991a3041d",
     # laguna's re-pinned in PR 58, knowingly: its nine attention calls take k, v and the query
     # tile's gate where their products wrote them, q as `[G, H/G, B*S, d]` (a layout of the
     # rotary's fusion) and write o token-major `[B, 1, S, H*128]`, already gated, for `W_o` to
@@ -664,7 +673,7 @@ PINNED_STEPS = {
     # a fourth scratch, q as ONE token-major block `[B, 1, S, H*128]`; the six others were hashed
     # before and after and did not move: the rule is a branch taken in Python, `angles is None`,
     # heads of 64 and a selection on its other side, and the latent cells' path is not touched)
-    "laguna_s21_prefill_epix10k2m": "57a6ffe394d3008531be953c6eac31ac2f15114b82ee6162753cb8e1b5b62283",
+    "laguna_s21_prefill_epix10k2m": "88b0dac72b0024cb76aceaaea91953ed6e69d4d91604e394b2524a50277a99c1",
     # pinned in PR 57, which brought it: the six above were hashed on PR 56's tree first and none
     # moved, though every one of them now traces `_projections`, `embed`, `logits_of` and `trunk`
     # through the new fields' branches (taken in Python, before anything is traced)
@@ -680,7 +689,7 @@ PINNED_STEPS = {
     # copies wrote them), `ssd_scan`'s groups read from the shapes (granite's kernel at one group is
     # the kernel it was: `tests/test_decoder_nemotron3.py -k traced_equation` holds its body) and
     # `grouped_tiles`' and `rows_as_words`' rules for a width of 14.5 or 10.5 lane tiles
-    "nemotron3_nano_prefill_epix10k2m": "80c45983f26be08aa9ea3bb7aa21f15d6ce71f27273743c3b51e1c0c4cd5959a",
+    "nemotron3_nano_prefill_epix10k2m": "8f6156774fbd568ba84d535bb105baea3801835c157ec059dabcc4cb7eec0707",
 }
 
 
@@ -1212,6 +1221,57 @@ def test_a_share_holder_s_way_back_moves_no_row_of_every_token(one_chip, monkeyp
                if re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = f32\[{tokens},2560\]", line)
                and "/sum_counted_rows/" in line.replace("jit(sum_counted_rows)", "/sum_counted_rows/")]
     assert written and all(" bitcast(" in line for line in written), written
+
+
+def _nemotron3_experts():
+    """The UNGATED expert layer on a holder of 64 of 128 experts of 2688 x
+    1856 (14.5 lane tiles: the up weights read transposed), top 6 under the
+    sigmoid router: every held row in the pass ahead on an even load."""
+    from psana_ray_tpu.parallel.moe import dropless_moe
+
+    def fn(x, router, bias, w_up, w_down):
+        return dropless_moe(x, router, None, w_up, w_down, k=6, num_experts=128, experts_held=(0, 64),
+                            scoring="sigmoid", select_bias=bias, gate_eps=1e-20, gate_scale=2.5,
+                            interpret=False)
+
+    return fn, [S((LING3_B * LING3_S, 2688), BF16), S((2688, 128), BF16), S((128,), F32),
+                S((64, 2688, 1856), BF16), S((64, 1856, 2688), BF16)]
+
+
+@pytest.mark.parametrize("layer", ["lfm2", "ling3", "nemotron3"])
+def test_nothing_but_the_kernels_stands_between_an_expert_layer_s_up_and_down_products(
+        layer, one_chip, monkeypatch):
+    """ONE expert layer at lfm2's (all held), ling3's (the pass ahead of the
+    loop) and nemotron3's (ungated) published sizes, as compiled (PR 65): the
+    activation is the up product's last step (``moe.gmm``), so the only
+    float32 array of ``[rows, F]`` an expert layer has is the GATE's product,
+    written by one grouped product and read by the next, the loop's turn of
+    2,048 rows alike — no fusion, copy or convert writes or reads one (in
+    the whole text: a fusion's own computation names its parameters' types),
+    and the ungated layer has none. Until PR 65 XLA ran ``silu(gate) * up`` and
+    the rounding as a fusion of its own over two such arrays (lfm2: 2 x 998
+    MB read, 250 MB written, 3.5 ms a layer under products the MXU bounds),
+    ``relu(up)^2`` over one."""
+    from psana_ray_tpu.parallel import moe
+
+    case, rows, width, gated = {
+        "lfm2": (_lfm2_experts, LFM2_B * LFM2_S * 4, 1792, True),
+        "ling3": (_ling3_experts, moe.rows_ahead(LING3_B * LING3_S * 8, 128, 512), 768, True),
+        "nemotron3": (_nemotron3_experts, moe.rows_ahead(LING3_B * LING3_S * 6, 64, 128), 1856, False)}[layer]
+    text = _expert_layer_text(case, one_chip, monkeypatch)
+    def named(kind):  # an array's type stands on its writer's line and, as a kernel's operand layout, its reader's
+        lines = [line.split(", metadata=")[0].strip() for line in text.splitlines() if kind in line]
+        assert all('custom_call_target="tpu_custom_call"' in line for line in lines), lines[:3]
+        return [bool(re.match(rf"(?:ROOT )?%[\w.\-]+ = {re.escape(kind)}", line)) for line in lines]
+
+    for size in [rows] if layer == "lfm2" else [rows, moe.HELD_CHUNK]:
+        # the gate's product: one kernel writes it, the next reads it, and nothing else names it
+        assert sorted(named(f"f32[{size},{width}]")) == ([False, True] if gated else [])
+        # the hidden rows leave the up product rounded, once, for the down product alone
+        assert sorted(named(f"bf16[{size},{width}]")) == [False, True]
+    products = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line
+                and "/while/" not in line and re.match(r"\s*(?:ROOT )?%gmm", line)]
+    assert len(products) == (3 if gated else 2)  # outside the loop, by the name the roofline shares read
 
 
 @pytest.mark.parametrize("d", [0, 1, 2, 3])

@@ -11,6 +11,7 @@ import dataclasses
 import hashlib
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -337,7 +338,11 @@ def test_the_gated_experts_three_paths_trace_what_they_traced():
     gate's weights GIVEN: their jaxprs, hashed on PR 63's tree and on PR 64's —
     equal (the expert's hidden activation is ONE function now,
     ``moe.hidden_rows``, and a gated layer's products and their order are
-    what its three copies wrote)."""
+    what its three copies wrote) — and hashed anew in PR 65, knowingly: in all
+    three the up product is ``moe.gmm``, which takes the gate's float32 product
+    as an operand and ends in the activation, and the float32 up product, the
+    ``logistic``, the two multiplies and the rounding between the products are
+    gone from the layer's own equations."""
     S = jax.ShapeDtypeStruct
 
     def traced(held, gated=True, e=32, t=256, d=256, f=128, k=4):
@@ -351,10 +356,14 @@ def test_the_gated_experts_three_paths_trace_what_they_traced():
 
     holders = ((0, 32), (0, 2), (0, 8))
     assert [hashlib.sha256(traced(h).encode()).hexdigest()[:16] for h in holders] == [
-        "7d064b69e6de4d95", "e7a3aa38a4f1fd38", "17273060588a9dc2"]
+        "008f4b8085ef6677", "9b5224621a0d8b83", "8a432e3ee4d91474"]
+
+    def products(text):  # megablox's is a `custom_vjp_call` around its jit, both named gmm
+        return text.count("name=gmm") - len(re.findall(r"custom_vjp_call\[\s*name=gmm", text))
+
     # an ungated layer calls the grouped product twice where a gated one calls it three times
     for h in ((0, 32), (0, 2)):
-        assert 2 * traced(h).count("name=gmm") == 3 * traced(h, gated=False).count("name=gmm") > 0
+        assert (products(traced(h)), products(traced(h, gated=False))) == (3, 2)
 
 
 @pytest.mark.parametrize("gated", [True, False])
@@ -382,7 +391,7 @@ def test_a_width_no_lane_tile_divides_goes_in_the_tile_that_computes_the_fewest_
 
 
 def test_the_cell_s_grouped_products_tiles():
-    assert moe.grouped_tiles(156672, 64, 2688, 1856, 4) == (128, 2688, 640)   # the pass's up product
+    assert moe.grouped_tiles(156672, 64, 2688, 1856, 2) == (128, 2688, 640)   # the pass's up product (no gate)
     assert moe.grouped_tiles(156672, 64, 1856, 2688, 2) == (256, 1856, 896)   # and its down product
     assert moe.rows_ahead(34816 * 6, 64, 128) == 156672  # 1.5 even shares of 104,448
 

@@ -659,3 +659,88 @@ def test_the_grouped_product_is_each_group_s_dense_product(name):
         np.testing.assert_allclose(got[lo:lo + rows], want, rtol=tol, atol=tol)
         lo += rows
     assert lo == held and np.isfinite(got[:held]).all()
+
+
+@pytest.mark.parametrize("name", sorted(n for n in SERVED_PRODUCTS if n.endswith("_gate_up")) + ["nemotron3_pass_up"])
+def test_the_up_product_that_ends_in_the_activation_keeps_the_tiles_of_the_product_it_was(name):
+    """PR 65: an expert's up product writes its hidden rows in the activations'
+    type (2 bytes) and, where the expert is gated, reads a float32 tile of the
+    gate's product beside its output's: 12 bytes of VMEM an output element in
+    two buffers where the float32 product took 8. No served shape's row tile
+    halves for it (lfm2's (256, 2048, 896) takes 13.1 MB of the 21 allowed),
+    so gate and up run in the same tiles, the ones PR 54 measured."""
+    from psana_ray_tpu.parallel import moe
+
+    m, groups, k, n, _, _, want, *_ = SERVED_PRODUCTS.get(
+        name, (156672, 64, 2688, 1856, 4, 1632, (128, 2688, 640)))  # PR 64's ungated experts: no gate
+    gated = name != "nemotron3_pass_up"
+    assert moe.grouped_tiles(m, groups, k, n, 2, gated) == want == moe.grouped_tiles(m, groups, k, n, 4)
+    assert moe._gmm_vmem(*want, 2, gated) <= moe.GMM_VMEM_BYTES
+    assert moe._gmm_vmem(*want, 2, True) - moe._gmm_vmem(*want, 2) == 2 * 4 * want[0] * want[2]
+    if name == "lfm2_gate_up":
+        assert round(moe._gmm_vmem(*want, 2, True) / 1e6, 1) == 13.1
+
+
+# m, the groups' sizes, k, n, gated, the operands' type, the hidden rows' type: each meets a branch of
+# the kernel's last step or of what stands around it
+HIDDEN_PRODUCTS = {
+    "gated_groups_that_end_inside_a_tile": (512, [200, 56, 130, 126], 256, 384, True, jnp.bfloat16, jnp.bfloat16),
+    "ungated_groups_that_end_inside_a_tile": (512, [200, 56, 130, 126], 256, 384, False, jnp.bfloat16, jnp.bfloat16),
+    # tm 128: the second tile holds the end of group 0, all of group 1 and the start of group 2
+    "a_row_tile_three_groups_share": (2048, [700, 20, 648, 680], 128, 384, True, jnp.bfloat16, jnp.bfloat16),
+    "an_empty_group_first_and_between": (512, [0, 40, 300, 0, 172], 256, 384, True, jnp.bfloat16, jnp.bfloat16),
+    "rows_past_the_last_group": (512, [150, 0, 90, 60], 256, 384, True, jnp.bfloat16, jnp.bfloat16),
+    "ungated_rows_past_the_last_group": (512, [150, 0, 90, 60], 256, 384, False, jnp.bfloat16, jnp.bfloat16),
+    "nothing_held": (256, [0, 0, 0], 128, 128, True, jnp.bfloat16, jnp.bfloat16),
+    # columns of no whole lane tile beside a contraction of whole ones: the weight read TRANSPOSED
+    "gated_a_weight_read_transposed": (256, [100, 0, 90, 66], 256, 96, True, jnp.bfloat16, jnp.bfloat16),
+    "ungated_a_weight_read_transposed": (256, [100, 0, 90, 66], 256, 96, False, jnp.bfloat16, jnp.bfloat16),
+    # ... in tiles of 128 that cover 800 columns with 896: the last output tile is cut
+    "ungated_transposed_in_tiles_that_cover_its_width": (512, [200, 312], 4096, 800, False, jnp.bfloat16, jnp.bfloat16),
+    "gated_a_width_of_no_whole_lane_tile": (48, [7, 0, 30, 11], 64, 32, True, jnp.bfloat16, jnp.bfloat16),
+    "a_contraction_cut_in_four": (256, [100, 0, 156], 7168, 384, True, jnp.bfloat16, jnp.bfloat16),
+    # 4,100 columns in tiles of 2,048: the third holds four of them and whatever lies past the array
+    "a_contraction_whose_last_tile_is_cut": (64, [30, 34], 4100, 512, True, jnp.bfloat16, jnp.bfloat16),
+    "float32_operands": (512, [200, 56, 130, 126], 256, 384, True, jnp.float32, jnp.bfloat16),
+    "ungated_float32_operands": (512, [200, 56, 130, 126], 256, 384, False, jnp.float32, jnp.bfloat16),
+    "float32_operands_and_hidden_rows": (512, [150, 0, 90, 60], 256, 384, True, jnp.float32, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HIDDEN_PRODUCTS))
+def test_the_up_product_s_last_step_is_hidden_rows_over_each_group_s_dense_products(name):
+    """``moe._expert_hidden`` (PR 65; interpret mode here): the gate's grouped
+    product float32, then ``moe.gmm``, the up product whose last contraction
+    step stores ``silu(gate) * acc`` (``relu(acc)^2`` where the experts have no
+    gate) rounded once — against :func:`moe.hidden_rows`, the one statement of
+    the form, over ``x[group] @ w[e]`` in plain ``jnp``. Operands of small
+    integers times a power of two: every product is exact in float32 whatever
+    the order of its sum, so kernel and reference round the SAME float32
+    number and the hidden rows are equal to the bit. Rows past the last group
+    are no group's: left unwritten, and nothing is asked of them."""
+    from psana_ray_tpu.parallel import moe
+
+    m, sizes, k, n, gated, operand, hidden = HIDDEN_PRODUCTS[name]
+    rng = np.random.default_rng(len(name))
+    x = jnp.asarray(rng.integers(-3, 4, (m, k)), operand)
+    w_gate, w_up = (jnp.asarray(rng.integers(-2, 3, (len(sizes), k, n)) * 2.0 ** -5, operand) for _ in "gu")
+    tm, tk, tn = moe._tiled(x, w_up, hidden, gated)[2]
+    assert {"a_row_tile_three_groups_share": tm == 128, "a_contraction_cut_in_four": tk == 1792,
+            "a_contraction_whose_last_tile_is_cut": (tk, k % tk) == (2048, 4),
+            "ungated_transposed_in_tiles_that_cover_its_width": (tn, n % tn) == (128, 32)}.get(name, True), (tm, tk, tn)
+    assert moe._tiled(x, w_up, hidden, gated)[1] == ("transposed" in name)
+    got = moe._expert_hidden(x, w_gate if gated else None, w_up, jnp.asarray(sizes, jnp.int32), hidden,
+                             interpret=True)
+    assert got.shape == (m, n) and got.dtype == hidden
+    lo = 0
+    for e, rows in enumerate(sizes):
+        group = x[lo:lo + rows].astype(jnp.float32)
+        with jax.default_matmul_precision("highest"):
+            want = moe.hidden_rows(lambda w: group @ w[e].astype(jnp.float32),
+                                   w_gate if gated else None, w_up).astype(hidden)
+        np.testing.assert_array_equal(np.asarray(got[lo:lo + rows].astype(jnp.float32)),
+                                      np.asarray(want.astype(jnp.float32)))
+        lo += rows
+    assert lo == sum(sizes) and np.isfinite(np.asarray(got[:lo].astype(jnp.float32))).all()
+    if lo:  # something was computed: the form is not the plain product
+        assert np.abs(np.asarray(got[:lo].astype(jnp.float32))).max() > 0

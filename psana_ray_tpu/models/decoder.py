@@ -134,6 +134,16 @@ LOOP_STATS = (
     "exit_pass_sum",             # over the step's frames, sum_r r * p_r at the frame's last token: the
                                  # pass the exit gate would have left at, in expectation (/ frames)
 )
+# and two after those fifteen (the groups above 0 where the step has none), ONLY from a step whose
+# causal calls take a BLOCK of heads a grid step (`sparse_attention.heads_a_step`: heads alone in their
+# groups, latent attention's): constants of the shapes, their quotient the heads a grid step
+BLOCK_STATS = (
+    "attn_head_tiles_total",     # (head, query tile, key tile) visits of the step's causal calls
+                                 # (a group's stacked heads, which share their keys, count as one)
+    "attn_grid_steps_total",     # the grid steps those calls took: a block of heads' visits each
+)
+# the places the LAYERS count at most: the first four of STEP_STATS and the four groups a layer has
+LAYER_GROUPS = 4 + len(SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS)
 # layer_types, as config.json spells them
 ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
 SLIDING = "sliding_attention"  # grouped-query attention over the band t - sliding_window < j <= t
@@ -1404,6 +1414,30 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     return x, jnp.stack(stats)
 
 
+def causal_call_steps(cfg: DecoderConfig, i: int, batch: int, s: int) -> Tuple[int, int]:
+    """Layer ``i``'s share of :data:`BLOCK_STATS`: the head tiles its call of
+    the batched causal kernel visits and the grid steps it takes
+    (``sparse_attention.causal_steps``, from what the call is given: latent
+    attention's heads alone in their groups under the selection's mask or
+    none, a grouped-query layer's with or without a window and the kernel's
+    own rotary), constants of the shapes; ``(0, 0)`` where it makes no such
+    call. The two differ only where a grid step takes a block of heads."""
+    op = cfg.layer_kind(i)[0]
+    tiles = {"block_q": cfg.causal_q_tile, "block_k": cfg.causal_kv_tile}
+    if op == LATENT:
+        if cfg.indexer_heads:  # the tiles `_indexer` has the selection's mask written in
+            tiles["mask_tiles"] = (sa.pick_tile(s, cfg.q_tile),
+                                   sa.mask_tile(s, sa.pick_tile(s, cfg.kv_tile)))
+        return sa.causal_steps(batch, s, cfg.num_heads, 1, cfg.qk_nope_head_dim, cfg.v_head_dim,
+                               cfg.qk_rope_head_dim, **tiles)
+    if op in (ATTENTION, SLIDING) and not cfg.indexer_heads:
+        return sa.causal_steps(batch, s, cfg.num_kv_heads, cfg.heads(i) // cfg.num_kv_heads,
+                               cfg.head_dim, cfg.head_dim,
+                               window=cfg.sliding_window if op == SLIDING else None,
+                               turned=_kernel_turns(cfg, cfg.rotary or None), **tiles)
+    return 0, 0
+
+
 def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = False):
     """``x [B*S, D]``, the embedded tokens of ``batch`` sequences one after
     the other, each at ``pos`` (static: ``[S, 3]`` under the multimodal
@@ -1436,6 +1470,13 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
         by_op = {op: jnp.tile(table, (batch, 1)) for op, table in by_op.items()}
     if cfg.stream_dtype:
         x = x.astype(cfg.stream_dtype)
+    # a step in which some causal call takes a BLOCK of heads a grid step counts BLOCK_STATS, last of
+    # all: constants of the shapes, summed here over the layers (and a looped model's passes) and
+    # laid down as ONE constant (a scalar a layer is a transfer a layer while the step is traced)
+    calls = [causal_call_steps(cfg, i, batch, s) for i in range(len(params["layers"]))]
+    blocks = ()
+    if any(tiles != steps for tiles, steps in calls):
+        blocks = tuple(cfg.passes * sum(column) for column in zip(*calls))
     stats = jnp.zeros((cfg.layer_stats,), jnp.float32)
     served = jnp.asarray([batch * s, batch], jnp.float32)
 
@@ -1447,9 +1488,12 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
         return x, stats
 
     if cfg.passes > 1:
-        x, stats, logits = _passes(params, x, stack, stats, served, cfg, batch)
+        x, stats, logits = _passes(params, x, stack, stats, served, cfg, batch, blocks)
         return (x, stats, exit_distribution(logits)) if exits else (x, stats)
     x, stats = stack(x, stats)
+    if blocks:  # every group the step has not at 0, LOOP_STATS' places among them
+        rest = (0,) * (LAYER_GROUPS - cfg.layer_stats + len(LOOP_STATS)) + blocks
+        return x, jnp.concatenate([stats[:4], served, stats[4:], np.asarray(rest, np.float32)])
     if cfg.layer_stats > 4:
         return x, jnp.concatenate([stats[:4], served, stats[4:]])
     return x, jnp.concatenate([stats, served])
@@ -1475,13 +1519,14 @@ def exit_distribution(logits):
     return jnp.concatenate([lam[:-1] * stayed[:-1], stayed[-1:]])
 
 
-def _passes(params, x, stack, stats, served, cfg: DecoderConfig, batch: int):
+def _passes(params, x, stack, stats, served, cfg: DecoderConfig, batch: int, blocks=()):
     """:func:`trunk` where the stack (``stack(x, stats)``: every layer once)
     is run ``cfg.passes`` times with the
     SAME weights -> ``(x, stats, logits)``: the last pass's normed rows, the
     statistics vector (the layers' places summed over passes and layers,
     ``tokens`` and ``served`` once a step, every group the step has not 0,
-    :data:`LOOP_STATS` last) and the exit gate's logits ``[R, B*S]``
+    :data:`LOOP_STATS` last but for ``blocks``, the step's :data:`BLOCK_STATS` where its calls
+    take a block of heads) and the exit gate's logits ``[R, B*S]``
     float32. The passes are a LOOP IN THE PROGRAM
     (``lax.scan`` over the pass: the compiled module is one stack long, one
     ``while`` around it), its body the stack and
@@ -1499,10 +1544,10 @@ def _passes(params, x, stack, stats, served, cfg: DecoderConfig, batch: int):
     s = x.shape[0] // batch
     at_last = exit_distribution(logits[:, s - 1::s])  # [R, B]: each frame's last token
     left_at = jnp.sum(jnp.arange(1, cfg.passes + 1, dtype=jnp.float32)[:, None] * at_last)
-    groups = 4 + len(SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS)
     return x, jnp.concatenate([
-        stats[:4], served, stats[4:], jnp.zeros((groups - cfg.layer_stats,), jnp.float32),
-        jnp.stack([jnp.float32(cfg.passes), left_at])]), logits
+        stats[:4], served, stats[4:], jnp.zeros((LAYER_GROUPS - cfg.layer_stats,), jnp.float32),
+        jnp.stack([jnp.float32(cfg.passes), left_at]),
+        *([np.asarray(blocks, np.float32)] if blocks else [])]), logits
 
 
 def embed(params, patches, prompt_ids, scale: float = 1.0, dtype=None):
@@ -1584,9 +1629,11 @@ def fold_step_stats(metrics, stats) -> None:
     selection over latent attention or with windowed layers (the band's
     pairs in the selection's places), twelve with linear layers, thirteen
     where a pass goes ahead of the held rows' loop, fifteen from a looped
-    model) to the
+    model, seventeen where a causal call takes a block of heads a grid step)
+    to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""
-    names = STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS + LOOP_STATS
+    names = (STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS + LOOP_STATS
+             + BLOCK_STATS)
     for name, value in zip(names, np.asarray(stats, np.float64)):
         metrics.add_counter(name, float(value))

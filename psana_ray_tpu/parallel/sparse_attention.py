@@ -88,6 +88,10 @@ SCORE_TILE_BYTES = _VMEM_LIMIT // 5
 # pairs outside the band (768 keys a row at 256 x 256 where 256 x 512 and 512 x 512 meet 1,024)
 # and loses more to its grid steps
 BAND_TILES = (0.5, 1.0)
+BLOCK_HEADS = (8, 4, 2)  # the heads a grid step may take where each is alone in its group
+# of the score tiles a kernel body's unrolled heads write, over its branches (:func:`heads_a_step`):
+# 35.7 and 18.9 MB read faster than one head a step, 37.9 a third SLOWER (PR 66)
+UNROLLED_SCORE_BYTES = 36_000_000
 MASK_TILE = 2176  # the widest key tile a selection's mask is written in (:func:`mask_tile`): on the
 # v5e the kernel under it read 34.8 ms a layer at 512 x 2,176 and 39.4 at 256 x 4,352 (PR 47)
 
@@ -393,9 +397,12 @@ def _turned_head(x, cos, sin, width: int, scale: float):
     return out
 
 
-def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k, shared, masked,
-                   gated=False, window=None, turn=None, rotary=None):
+def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared, masked,
+                   gated=False, window=None, turn=None, rotary=None, heads=1, joint=False):
     rest = list(rest)
+    # a BLOCK of `heads` heads, each alone in its group, is a wider block of the same arrays: with
+    # `joint` keys and values are ONE block, head h's keys then its values
+    v_ref, parts = (k_ref, 2) if joint else (rest.pop(0), 1)
     if rotary is not None:  # q and k are float32 and UNTURNED: the query tile's and the key tile's
         # rows of the two tables, and last of the scratch the turned query tile, stacked
         width, turned_by, q_scale = rotary
@@ -414,13 +421,25 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
     gi, t = pl.program_id(1), pl.program_id(2)
     qi, kb = qi_ref[t], kb_ref[t]
     rep, _, d = q_ref.shape
-    if rotary is not None:  # q is ONE token-major block [1, bq, rep * d]
-        d = k_ref.shape[-1]
-        rep = q_ref.shape[-1] // d
+    if rotary is not None or heads > 1:  # q is ONE token-major block [1, bq, heads * rep * d]
+        d = k_ref.shape[-1] // (heads * parts)
+        rep = q_ref.shape[-1] // (heads * d)
+    dv = v_ref.shape[-1] // (heads * parts)
     rows = rep * block_q  # the group's query heads, stacked: one product serves them all
     # the query tile's first visited key tile: tile 0, or under a window the one that holds the
     # key `window - 1` before the tile's first row (`_band_tiles`' first)
     first = 0 if window is None else jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+    # (alone in its step a head reads the whole refs, as it was traced before there were blocks:
+    # `tests/test_decoder_kimi.py -k traces_the_kernel` holds that body's jaxpr)
+    def of(h):  # head h of the block's: its rows of the stacked scratch (all of them where it is alone)
+        return slice(None) if heads == 1 else slice(h * rows, (h + 1) * rows)
+
+    def lanes(ref, h, width, part=0):  # head h's part: a lane block of a token-major tile
+        if heads == 1:
+            return ref[...]
+        at = (h * parts + part) * width
+        return ref[..., at:at + width]
 
     @pl.when(kb == first)
     def _reset():
@@ -428,55 +447,75 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
         l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
         if turn is not None:  # once a query tile: every key step reads the scratch
-            turned_ref[...] = _turned_tile(qs_ref[...], cos_ref[...], sin_ref[...], turn,
-                                           turned_ref.dtype)
+            qs = qs_ref[...]  # [rep, bq, ds], or a block's [hb, 1, bq, ds]: its heads lead
+            turned_ref[...] = _turned_tile(qs if heads == 1 else qs.reshape(heads, block_q, -1),
+                                           cos_ref[...], sin_ref[...], turn, turned_ref.dtype)
         if rotary is not None:  # the turn and the stacking are one pass, head by head
             cos, sin = cos_q[...], sin_q[...]
-            for r in range(rep):
+            for r in range(heads * rep):
                 head = _turned_head(q_ref[0, :, r * d:(r + 1) * d], cos, sin, width, turned_by)
                 stacked_ref[r * block_q:(r + 1) * block_q] = (head * q_scale).astype(stacked_ref.dtype)
 
     def update(with_diagonal, with_lower_edge=False):
-        v = v_ref[...]
-        if rotary is None:
-            q, k = q_ref[...].reshape(rows, d), k_ref[...]
-        else:  # the key tile is turned at every visit (a sixth to a thirty-sixth of a score tile)
-            q, k = stacked_ref[...], _turned_head(
-                k_ref[...], cos_k[...], sin_k[...], width, turned_by).astype(stacked_ref.dtype)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        if shared:
-            qs = (qs_ref[...].reshape(rows, qs_ref.shape[2]) if turn is None
-                  else turned_ref[...])
-            s = s + jax.lax.dot_general(qs, ks_ref[...], (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-        if masked:
-            # a row with nothing selected yet has m_new == NEG_INF and p == 1 on its masked
-            # entries: the first selected key's alpha == 0 wipes that, and every row selects a
-            # key at or before its diagonal tile
-            sel = mask_ref[...].astype(jnp.float32).reshape(block_q, block_k)
-            s = jnp.where((sel > 0.0)[None], s.reshape(rep, block_q, block_k),
-                          NEG_INF).reshape(rows, block_k)
-        elif with_diagonal or with_lower_edge:
-            # without a window key 0 of the sequence is open to every row: m is finite from tile
-            # 0. Under one a row may see NO key of its first visited tile (the band's lower edge
-            # lies past the tile's last key for it): the masked form's case above, m_new ==
-            # NEG_INF and p == 1 there, wiped by alpha == 0 at its first real key, which it
-            # meets at the latest in its diagonal tile, the row's last
-            row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-            edges = ([col <= row] if with_diagonal else []) + (
-                [col > row - window] if with_lower_edge else [])
-            open_ = functools.reduce(jnp.logical_and, edges)
-            s = jnp.where(open_[None], s.reshape(rep, block_q, block_k),
-                          NEG_INF).reshape(rows, block_k)
-        m = m_ref[:]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jnp.dot(p.astype(v.dtype), v,
-                                                  preferred_element_type=jnp.float32)
-        m_ref[:] = m_new
+        open_ = []  # where a pair counts: the selection's tile or the band's edges, ONE for every head
+
+        def score(h):  # head h's value tile, and its score tile with the closed pairs at NEG_INF
+            v = lanes(v_ref, h, dv, parts - 1)
+            if rotary is None:
+                q = q_ref[...].reshape(rows, d) if heads == 1 else q_ref[0, :, h * d:(h + 1) * d]
+                k = lanes(k_ref, h, d)
+            else:  # the key tile is turned at every visit (a sixth to a thirty-sixth of a score tile)
+                q = stacked_ref[...] if heads == 1 else stacked_ref[of(h)]
+                k = _turned_head(lanes(k_ref, h, d), cos_k[...], sin_k[...], width,
+                                 turned_by).astype(stacked_ref.dtype)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            if shared:
+                if turn is not None:
+                    qs = turned_ref[...] if heads == 1 else turned_ref[of(h)]
+                else:
+                    qs = (qs_ref[...] if heads == 1 else qs_ref[h]).reshape(rows, qs_ref.shape[-1])
+                s = s + jax.lax.dot_general(qs, ks_ref[...], (((1,), (1,)), ((), ())),
+                                            preferred_element_type=jnp.float32)
+            if masked and not open_:
+                # a row with nothing selected yet has m_new == NEG_INF and p == 1 on its masked
+                # entries: the first selected key's alpha == 0 wipes that, and every row selects a
+                # key at or before its diagonal tile
+                sel = mask_ref[...].astype(jnp.float32).reshape(block_q, block_k)
+                open_.append((sel > 0.0)[None])
+            elif (with_diagonal or with_lower_edge) and not open_:
+                # without a window key 0 of the sequence is open to every row: m is finite from tile
+                # 0. Under one a row may see NO key of its first visited tile (the band's lower edge
+                # lies past the tile's last key for it): the masked form's case above, m_new ==
+                # NEG_INF and p == 1 there, wiped by alpha == 0 at its first real key, which it
+                # meets at the latest in its diagonal tile, the row's last
+                row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+                col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+                edges = ([col <= row] if with_diagonal else []) + (
+                    [col > row - window] if with_lower_edge else [])
+                open_.append(functools.reduce(jnp.logical_and, edges)[None])
+            if open_:
+                s = jnp.where(open_[0], s.reshape(rep, block_q, block_k),
+                              NEG_INF).reshape(rows, block_k)
+            return s, v
+
+        def fold(h, s, v):  # head h's running softmax takes the tile in
+            m = m_ref[of(h)]
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            l_ref[of(h)] = l_ref[of(h)] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[of(h)] = acc_ref[of(h)] * alpha + jnp.dot(p.astype(v.dtype), v,
+                                                              preferred_element_type=jnp.float32)
+            m_ref[of(h)] = m_new
+
+        # head h + 1's score product stands BEFORE head h's softmax, and head h's `p . v` after it:
+        # Mosaic keeps unrolled heads in the order they are written, so one head's products run
+        # under another's exponentials (two score tiles live; alone in its step a head is the
+        # chain it was)
+        ahead = score(0)
+        for h in range(heads):
+            tile, ahead = ahead, score(h + 1) if h + 1 < heads else None
+            fold(h, *tile)
 
     if masked:
         update(False)
@@ -497,20 +536,19 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, v_ref, *rest, block_q, block_k,
     @pl.when(kb == ((qi + 1) * block_q - 1) // block_k)  # the row's last tile
     def _finalize():
         out = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
-        if not gated and o_ref.shape[0] == rep:
+        if not gated and heads == 1 and o_ref.shape[0] == rep:
             o_ref[...] = out.reshape(o_ref.shape)
             return
-        dv = v_ref.shape[-1]
         if gated:  # head h's scalars are lane h of the tile: picked by a compare and a lane sum
             gate = gate_ref[0]
             lane = jax.lax.broadcasted_iota(jnp.int32, gate.shape, 1)
-        for r in range(rep):  # head by head: whole lane blocks, and no product
+        for r in range(heads * rep):  # head by head: whole lane blocks, and no product
             head = out[r * block_q:(r + 1) * block_q]
             if gated:
-                of_head = jnp.sum(jnp.where(lane == gi * rep + r, gate, 0.0),
+                of_head = jnp.sum(jnp.where(lane == gi * heads * rep + r, gate, 0.0),
                                   axis=-1, keepdims=True)
                 head = (head.astype(jnp.float32) * of_head).astype(o_ref.dtype)
-            if o_ref.shape[0] == rep:
+            if o_ref.shape[0] == rep and heads == 1:
                 o_ref[r] = head
             else:  # the group's heads are adjacent lane blocks of ONE token-major tile
                 o_ref[0, :, r * dv:(r + 1) * dv] = head
@@ -531,7 +569,8 @@ def _band_tiles(s: int, bq: int, bk: int, window: Optional[int] = None) -> list:
 def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bool,
                       q_shared=None, k_shared=None, mask=None, window: Optional[int] = None,
                       out_gate=None, shared_turn=None, shared_scale: float = 1.0, turn=None,
-                      turn_width: int = 0, turn_scale: float = 1.0, q_scale: float = 1.0):
+                      turn_width: int = 0, turn_scale: float = 1.0, q_scale: float = 1.0,
+                      heads: Optional[int] = None):
     """The batched form of :func:`masked_gqa_attention`. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
@@ -638,7 +677,37 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     2,176 36.2, 256 x 4,352 39.4, 512 x 512 (the mask written in the
     selection's 512-wide pieces, as it was until PR 47) 43.4, 2,176 x 512
     54.9; WITHOUT a mask 512 x 512 43.1 and 1,088 x 1,088 32.7: the tile
-    is what a mask costs, its convert, compare and select 0.35."""
+    is what a mask costs, its convert, compare and select 0.35. Where a
+    head is ALONE in its group (latent attention's) a
+    grid step takes a BLOCK of ``hb`` heads (:func:`heads_a_step`, the rule;
+    ``heads`` asks for a number of its own): the grid is ``(B, G / hb,
+    pairs)`` and a block is a WIDER block of the same arrays — q and o
+    ``[bq, hb * d]`` at column block ``gi``, k and v of one array ONE block
+    ``[bk, hb * 2d]`` holding ``[k_h | v_h]`` of the block's heads, the shared
+    query ``hb`` leading heads, ``m``, ``l``, ``acc`` and the turned scratch
+    ``hb`` times as tall; the shared key's tile, the tables' and the mask's
+    (its convert and compare too) are read once for ``hb`` heads. The body's
+    heads are unrolled and WRITTEN so that head ``h + 1``'s score product
+    stands before head ``h``'s softmax and head ``h``'s ``p . v`` after it:
+    alone in its step a head is a chain — product, then max, ``exp``, sum
+    and rescale over a 4.5-4.7 MB score tile, then product — and the MXU
+    idles under the vector work; Mosaic keeps the written order, so one
+    head's products run under another's exponentials. Every head's own
+    arithmetic is what it was: the outputs are equal to the bit. One
+    layer's kernel alone on the v5e, ms, 1 head a step -> ``hb`` heads
+    written head after head / skewed by one (built) / part by part through
+    all heads (my chip runs, PR 66; twenty calls a reading; the parents'
+    own readings of the single-head form: 34.83 PR 47, 32.87-33.45 PR 61):
+    128 heads under a mask, 512 x 2,176: 37.82 -> at 2: 36.60 / 36.13 /
+    36.13; at 4: 34.77 / 33.36 / 34.54; at 8: 33.73 / **32.00** / 33.86
+    (14.5 s to compile where one head takes 1.1); 64 heads at B 2, maskless
+    1,088 x 1,088: 36.59 -> at 2: 34.01 / **31.54** / 31.54; at 4: 49.71 /
+    47.76 / 48.26; at 8: 44.74 / 42.44 / 42.56 — SLOWER than no block; 32
+    heads at B 4 the same to 0.1; the looped reader's 16 heads at 2 x 2,304,
+    q and k turned by the kernel, 768 x 768: 0.727 -> at 2: 0.588 / 0.578 /
+    0.581; at 4: 0.566 / 0.541 / 0.520; at 8: 0.580 / 0.486-0.501 /
+    0.549 — measured, and NOT taken by the rule (:func:`heads_a_step` says
+    why: the step's 48 call sites pay for the longer body at every start)."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, hd = q.shape
@@ -654,7 +723,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         if b != 1 or n_qb * mq != s or n_kb * bk != s:
             raise ValueError(f"a mask {mask.shape} selects the keys of one sequence of "
                              f"{n_qb * mq}: not of {b} of {s}")
-        bq = next(t for t in range(max(min(block_q, s) // mq, 1) * mq, 0, -mq) if s % t == 0)
+        bq = _masked_query_tile(s, block_q, mq)
     if window is not None and (masked or window < 1):
         raise ValueError("a window is a band of at least the query's own key, and the maskless form's")
     if shared_turn is not None and not shared:
@@ -665,20 +734,25 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
                          "no shared part")
     pairs = _band_tiles(s, bq, bk, window)
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
+    ds = k_shared.shape[2] if shared else 0
+    hb = heads_a_step(g, rep, bq, bk, d, dv, ds, masked=masked, window=window,
+                      turned=turn is not None, want=heads)
+    joint = hb > 1 and v is None  # ONE block [k_h | v_h] of the block's heads
 
     def in_place(width):  # a head of whole lane blocks
         return width % 128 == 0
 
-    def rows_spec(width):  # of [G, H/G, B*S, width]: the batch in the rows
-        return pl.BlockSpec((None, rep, bq, width),
+    def rows_spec(width):  # of [G, H/G, B*S, width]: the batch in the rows; a block's hb heads lead
+        return pl.BlockSpec((None if hb == 1 else hb, rep, bq, width),
                             lambda bi, gi, t, qi, kb: (gi, 0, bi * (s // bq) + qi[t], 0))
 
     def major_spec(width):  # of [B, G, H/G, S, width]
         return pl.BlockSpec((None, None, rep, bq, width),
                             lambda bi, gi, t, qi, kb: (bi, gi, 0, qi[t], 0))
 
-    def place_spec(width):  # of [B, 1, S, H*width]: the group's heads, column block gi
-        return pl.BlockSpec((None, 1, bq, rep * width), lambda bi, gi, t, qi, kb: (bi, 0, qi[t], gi))
+    def place_spec(width):  # of [B, 1, S, H*width]: the step's heads, column block gi
+        return pl.BlockSpec((None, 1, bq, hb * rep * width),
+                            lambda bi, gi, t, qi, kb: (bi, 0, qi[t], gi))
 
     def q_tiles(x, width):  # -> the kernel's [rep, bq, width] tile
         if in_place(width) and (rep == 1 or turn is not None):  # (turned: stacked in the kernel)
@@ -688,9 +762,10 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         return jnp.transpose(x.reshape(b, s, g, rep, width), (0, 2, 3, 1, 4)), major_spec(width)
 
     def kv_tiles(x, width, part=0, parts=1):  # head gi's part is column block gi*parts + part
-        if in_place(width):
-            return x, pl.BlockSpec((None, bk, width),
-                                   lambda bi, gi, t, qi, kb: (bi, kb[t], gi * parts + part))
+        if in_place(width):  # (a block of hb heads: every part of theirs, column block gi)
+            return x, pl.BlockSpec((None, bk, hb * width * (parts if joint else 1)),
+                                   lambda bi, gi, t, qi, kb: (
+                                       bi, kb[t], gi if joint else gi * parts + part))
         return (jnp.transpose(x.reshape(b, s, g, width), (0, 2, 1, 3)),
                 pl.BlockSpec((None, None, bk, width), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0)))
 
@@ -700,19 +775,18 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     elif v is None:
         k, v = (k.reshape(b, s, g, 2, d)[:, :, :, i].reshape(b, s, g * d) for i in (0, 1))
     operands, in_specs = (list(u) for u in zip(
-        q_tiles(q, d), kv_tiles(k, d, 0, parts), kv_tiles(v, dv, parts - 1, parts)))
-    scratch = [pltpu.VMEM((rep * bq, 1), jnp.float32), pltpu.VMEM((rep * bq, 1), jnp.float32),
-               pltpu.VMEM((rep * bq, dv), jnp.float32)]
+        q_tiles(q, d), kv_tiles(k, d, 0, parts), *([] if joint else [kv_tiles(v, dv, parts - 1, parts)])))
+    scratch = [pltpu.VMEM((hb * rep * bq, 1), jnp.float32), pltpu.VMEM((hb * rep * bq, 1), jnp.float32),
+               pltpu.VMEM((hb * rep * bq, dv), jnp.float32)]
     rotary = None
     if turn is not None:  # the query tile's and the key tile's rows of the two tables [B*S, d], and
         # last of the scratch the turned query tile, stacked, as the key steps read it
         operands += [table.reshape(b * s, d) for table in turn] * 2
         in_specs += [pl.BlockSpec((bq, d), lambda bi, gi, t, qi, kb: (bi * (s // bq) + qi[t], 0))] * 2
         in_specs += [pl.BlockSpec((bk, d), lambda bi, gi, t, qi, kb: (bi * (s // bk) + kb[t], 0))] * 2
-        scratch.append(pltpu.VMEM((rep * bq, d), v.dtype))
+        scratch.append(pltpu.VMEM((hb * rep * bq, d), v.dtype))
         rotary = (int(turn_width) or d, float(turn_scale), float(q_scale))
     if shared:
-        ds = k_shared.shape[2]
         operands += [jnp.transpose(q_shared.reshape(b * s, g, rep, ds), (1, 2, 0, 3)), k_shared]
         in_specs += [rows_spec(ds),
                      pl.BlockSpec((None, bk, ds), lambda bi, gi, t, qi, kb: (bi, kb[t], 0))]
@@ -720,7 +794,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         operands += [table.reshape(b * s, ds) for table in shared_turn]
         in_specs += [pl.BlockSpec((bq, ds),
                                   lambda bi, gi, t, qi, kb: (bi * (s // bq) + qi[t], 0))] * 2
-        scratch.append(pltpu.VMEM((rep * bq, ds), q.dtype))  # the tile as the key steps read it
+        scratch.append(pltpu.VMEM((hb * rep * bq, ds), q.dtype))  # the tile as the key steps read it
     if masked:
         operands.append(mask)
         in_specs.append(pl.BlockSpec((bq // mq, None, mq, bk),
@@ -732,9 +806,9 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked,
                           gated=out_gate is not None, window=window,
                           turn=None if shared_turn is None else float(shared_scale),
-                          rotary=rotary),
+                          rotary=rotary, heads=hb, joint=joint),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(b, g, len(pairs)),
+            num_scalar_prefetch=2, grid=(b, g // hb, len(pairs)),
             in_specs=in_specs, out_specs=place_spec(dv) if in_place(dv) else major_spec(dv),
             scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct(
@@ -752,6 +826,86 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     return jnp.transpose(o5, (0, 3, 1, 2, 4)).reshape(b, s, g * rep * dv)
 
 
+def block_vmem_bytes(hb: int, bq: int, bk: int, d: int, dv: int, ds: int = 0, *,
+                     masked: bool = False) -> int:
+    """What a grid step of ``hb`` heads keeps in VMEM, counted as
+    :func:`causal_tiles` counts a score tile: the TWO live score tiles of
+    the written order, one's exponentials and their bf16 copy; the ``hb``
+    accumulators with their maxima and sums (a lane block each); the
+    pipeline's two buffers of every operand block (q, the heads' keys and
+    values, the output; the shared query part float32 in lanes half full,
+    its key, its two tables and the turned scratch; a mask's tile)."""
+    count = 14 * bq * bk + hb * bq * (4 * dv + 2 * 4 * 128)
+    count += 2 * hb * 2 * (bq * d + bk * (d + dv) + bq * dv)
+    if ds:
+        count += 2 * (hb * bq * 128 * 4 + bk * 128 * 2 + 2 * bq * 128 * 4) + hb * bq * 128 * 2
+    return count + (2 * bq * bk if masked else 0)
+
+
+def heads_a_step(g: int, rep: int, bq: int, bk: int, d: int, dv: int, ds: int = 0, *,
+                 masked: bool = False, window: Optional[int] = None, turned: bool = False,
+                 want: Optional[int] = None) -> int:
+    """How many heads ONE grid step of the batched kernel takes: the rule,
+    taken in Python from the shapes. 1 wherever heads share their keys
+    (``rep > 1``: a group's stacked heads already share a grid step), a
+    head is no whole lane blocks, or the kernel turns q and k itself
+    (``turned``: below); else the LARGEST of 8 / 4 / 2 (never 16: slower,
+    and 32 s to compile, PR 47) that divides ``g``, whose count
+    (:func:`block_vmem_bytes`) fits :data:`_VMEM_LIMIT`, and under which the
+    score tiles of the body's unrolled heads, over every branch the body
+    holds (one under a mask, two without: below the diagonal and on it;
+    four under a window), stay within :data:`UNROLLED_SCORE_BYTES` — past
+    that a block reads SLOWER than no block (the readings:
+    :func:`_causal_attention`; 128 heads under a mask in 512 x 2,176 tiles
+    are 35.7 MB at 8 heads, the best of its row; 64 maskless in 1,088 x
+    1,088 18.9 MB at 2, the best, and 37.9 at 4, a third slower than one
+    head a step). The looped reader's call (16 heads whose q and k the
+    kernel turns, 768 x 768) read faster ALONE at every block (0.727 ->
+    0.541 ms at 4) and its step 650.6 -> 624.7 ms, and stays at one head a
+    step all the same: its 48 call sites pay a block's longer body 48 times
+    while the step is traced, ``startup_trace_s`` 0.68 -> 1.56 and warm
+    ``setup_s`` 13.2-13.6 -> 14.3-15.7 of a bound of 1.34 s (my chip runs,
+    PR 66: a start is what its users pay at every restart). ``want`` (a
+    test's or a timing run's own) takes the place of the rule's choice
+    wherever heads are alone in their groups."""
+    if rep > 1 or d % 128 or dv % 128:
+        return 1
+    if want is not None:
+        return next(hb for hb in range(int(want), 0, -1) if g % hb == 0)
+    if turned:
+        return 1
+    bodies = 1 if masked else 2 if window is None else 4
+    for hb in BLOCK_HEADS:
+        if (g % hb == 0 and hb * bodies * 4 * bq * bk <= UNROLLED_SCORE_BYTES
+                and block_vmem_bytes(hb, bq, bk, d, dv, ds, masked=masked) <= _VMEM_LIMIT):
+            return hb
+    return 1
+
+
+def _masked_query_tile(s: int, block_q: int, mq: int) -> int:
+    """The largest multiple of the mask's query tile ``mq`` that divides ``s``
+    and is at most ``block_q``."""
+    return next(t for t in range(max(min(block_q, s) // mq, 1) * mq, 0, -mq) if s % t == 0)
+
+
+def causal_steps(b: int, s: int, g: int, rep: int, d: int, dv: int, ds: int = 0, *,
+                 block_q: int = 256, block_k: int = 512, mask_tiles: Optional[Tuple[int, int]] = None,
+                 window: Optional[int] = None, turned: bool = False) -> Tuple[int, int]:
+    """``(head tiles, grid steps)`` of ONE call of the batched kernel, from
+    what :func:`masked_gqa_attention` is given (``mask_tiles``: the mask's
+    ``(query tile, key tile)``): the ``(head, query tile, key tile)`` visits,
+    and the grid steps that make them; their quotient is the heads a grid
+    step beyond a group's own (:func:`heads_a_step`)."""
+    if mask_tiles is None:
+        bq, bk = causal_tiles(s, rep, block_q, block_k, window, d if turned else 0)
+    else:
+        bq, bk = _masked_query_tile(s, block_q, mask_tiles[0]), mask_tiles[1]
+    hb = heads_a_step(g, rep, bq, bk, d, dv, ds, masked=mask_tiles is not None, window=window,
+                      turned=turned)
+    pairs = len(_band_tiles(s, bq, bk, window))
+    return b * g * pairs, b * (g // hb) * pairs
+
+
 def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
                  window: Optional[int] = None, turned: int = 0) -> Tuple[int, int]:
     """The ``(query tile, key tile)`` the maskless batched kernel runs a
@@ -762,7 +916,11 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
     window (:data:`BAND_TILES`): a row is run against the ``window + bq +
     bk`` keys or so that its tiles meet, of which only ``window`` are the
     band's. (2) The stacked score tile ``[rep * bq, bk]`` float32 stays
-    within :data:`SCORE_TILE_BYTES`: the query tile is the largest that
+    within :data:`SCORE_TILE_BYTES` — a HEAD's budget (a group's stacked
+    heads are one tile): where a grid step takes a block of heads alone in
+    their groups (:func:`heads_a_step`) the step keeps the block's live
+    tiles, two of them, and that rule counts them
+    (:func:`block_vmem_bytes`), not this one: the query tile is the largest that
     divides ``s`` and does; where the kernel turns heads of ``turned`` lanes
     (``_causal_attention``'s ``turn``) a query row's share of that budget
     also counts its float32 block in the pipeline's two buffers, its rows

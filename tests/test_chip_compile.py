@@ -370,6 +370,37 @@ def _dsv32_attention():
         one_call_in_wide_tiles_and_no_relaid_mask
 
 
+def _latent_block(b, heads, takes, masked=False):
+    """The latent layer's call AS IT IS SERVED since PR 66: keys and values of
+    ONE array (``v`` None), the rotary query float32 with its tables, and a
+    BLOCK of ``takes`` heads a grid step (``sparse_attention.heads_a_step``:
+    eight under dsv32's mask in 512 x 2,176 tiles, two in kimi's and ling3's
+    maskless 1,088 x 1,088) — q, the heads' ``[k | v]`` and the output wider
+    blocks of the same arrays, ``m``, ``l``, ``acc`` and the turned scratch
+    ``takes`` times as tall, the body's heads unrolled: a VMEM refusal or an
+    unaligned slice shows here, on a CPU."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    s = DSV32_S
+    mask_k = sa.mask_tile(s, 512)
+
+    def fn(q, kv, q_rope, k_rope, cos, sin, *mask):
+        return sa.masked_gqa_attention(q, kv, None, *mask, num_kv_heads=heads, block_q=1088,
+                                       block_k=1088, q_shared=q_rope, k_shared=k_rope,
+                                       shared_turn=(cos, sin), shared_scale=0.1147, interpret=False)
+
+    def one_call_of_blocks(text):
+        assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 1
+        tiles, steps = sa.causal_steps(b, s, heads, 1, 128, 128, 64, block_q=1088, block_k=1088,
+                                       mask_tiles=(128, mask_k) if masked else None)
+        assert tiles == takes * steps and steps == b * (heads // takes) * (44 if masked else 36)
+
+    table = S((b * s, 64), F32)
+    mask = [S((s // 128, s // mask_k, 128, mask_k), jnp.int8)] if masked else []
+    return fn, [S((b, s, heads * 128), BF16), S((b, s, heads * 256), BF16), S((b, s, heads * 64), F32),
+                S((b, s, 64), BF16), table, table] + mask, 1, one_call_of_blocks
+
+
 LING3_B, LING3_S, LING3_H = 4, 8704, 32  # four frames of 8,448 patches + 256 prompt tokens
 
 
@@ -481,6 +512,9 @@ CASES = {
     "dsv32_select_keys_8704x64x128": _dsv32_select,
     "dsv32_masked_latent_attention_1x8704x128x192": _dsv32_attention,
     "kimi_latent_attention_2x8704x64x192": _kimi_attention,
+    "dsv32_latent_attention_a_block_of_8_heads_a_step": lambda: _latent_block(1, 128, 8, masked=True),
+    "kimi_latent_attention_a_block_of_2_heads_a_step": lambda: _latent_block(KIMI_B, 64, 2),
+    "ling3_latent_attention_a_block_of_2_heads_a_step": lambda: _latent_block(LING3_B, LING3_H, 2),
     "kimi_held_experts_17408x8_12_of_384": _kimi_experts,
     "lfm2_causal_gqa_attention_4x8704x64": _lfm2_attention,
     "lfm2_gated_short_conv_34816": _lfm2_conv,
@@ -649,8 +683,18 @@ PINNED_STEPS = {
     # between the products in the all-held path, the held rows' loop and the pass ahead are gone
     # from the step's own text; granite's and the looped reader's, which run no grouped product,
     # were hashed before and after and did not move)
-    "deepseek_v32_prefill_epix10k2m": "93e63035e1c6ae4b40c6061ad89aad7399b816ef9e9a8405510c80c7c76f9b79",
-    "kimi_k2_prefill_epix10k2m": "925a848d15a6ad661f9d535f21b2b40cc2d30a588532cc372ceea425e6f4d185",
+    # (the three steps with a latent layer — dsv32's, kimi's and ling3's — re-pinned in PR 66,
+    # knowingly: a grid step of `_causal_kernel` takes a BLOCK of heads there
+    # (`sparse_attention.heads_a_step`: eight under dsv32's mask, two in kimi's and ling3's latent
+    # calls), so the call's grid is `(B, G / hb, pairs)`, keys and values of one array ride in as
+    # ONE operand where they were two blocks of it, and the step's statistics vector ends in
+    # `BLOCK_STATS` (seventeen values, the last places ONE constant of the shapes); keye's, lfm2's,
+    # laguna's, granite's, nemotron3's and the looped reader's were hashed before and after and
+    # did not move: heads that share their keys, heads of 64, `_attn_kernel` and a kernel that
+    # turns q and k itself take one group a step as they did, and at one head a step the kernel's
+    # body is the jaxpr it was (`tests/test_decoder_kimi.py -k traces_the_kernel`))
+    "deepseek_v32_prefill_epix10k2m": "4459560881f4932af4843903dfaa127c21ecbadaa451ce1890d074e6fb249eac",
+    "kimi_k2_prefill_epix10k2m": "aabd919baf99e48f437abf546ab498c3b2c100d1f38b24cf58eff093ba9c462a",
     "keye_vl2_prefill_epix10k2m": "3019bf0c0433c3f79247bb5532f0e210e2eeec021b9b4624e0204c6a14183ab3",
     "lfm2_8b_a1b_prefill_epix10k2m": "2b73e2e07e5723518527f753ddf201bc63b7e4f51da5e8f5fcd63c6617add56e",
     # pinned in PR 56, both hashed on PR 55's tree first and NEITHER moved by it: laguna's runs
@@ -660,7 +704,7 @@ PINNED_STEPS = {
     # AROUND a kernel (its operands, their shapes and types, its grid's result) and no kernel's
     # body. A kernel's own cache entry follows its body and its file's path
     # (ling3's again in PR 61: its one latent layer, above)
-    "ling3_flash_prefill_epix10k2m": "12ed14e520ad1cc01cc1ef764beeae615b3c98cb8489a8db1227c80991a3041d",
+    "ling3_flash_prefill_epix10k2m": "95ba488baaa02edabc33bf7ae5963c80efd175a61162413b2420d0226483c5f0",
     # laguna's re-pinned in PR 58, knowingly: its nine attention calls take k, v and the query
     # tile's gate where their products wrote them, q as `[G, H/G, B*S, d]` (a layout of the
     # rotary's fusion) and write o token-major `[B, 1, S, H*128]`, already gated, for `W_o` to

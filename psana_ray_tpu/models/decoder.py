@@ -7,27 +7,32 @@ decoder of a language model from the keys of its published
 ``config.json`` (Keye-VL-2.0's text decoder, LFM2-8B-A1B, DeepSeek-V3's
 block as Kimi-K2 spells it, DeepSeek-V3.2's, Ling-3.0's hybrid,
 Laguna-S-2.1's windowed and full layers, Granite-4.0-H's state-space hybrid,
-Ouro's looped stack, Nemotron-H's layers of one block each):
+Ouro's looped stack, Nemotron-H's layers of one block each, Olmo-Hybrid's
+Gated DeltaNet layers under the reordered norm):
 
     x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
     x  -> x + ff(rms(x))                      five kinds, its ff one of two
 
 (a branch times ``residual_multiplier`` before it is added, where a model has
-one). Where the configuration says that a layer is ONE block
+one; a branch normed AGAIN before it is added under ``sandwich``, and with
+``pre_norm`` off normed ONLY then, ``x + rms(op(x))``: OLMo 2's reordered
+norm, one rule for which norms a branch has). Where the configuration says that a layer is ONE block
 (``single_block``: Nemotron-H's ``hybrid_override_pattern``) a layer is
 EITHER line, an operator alone or a feed-forward alone, with the one norm
 that line has: ``layer_kind`` names what a layer has and ``decoder_layer``
 runs what the kind names.
 
-A layer's OPERATOR (``layer_types``) is grouped-query attention (a
-per-head RMS norm on q and k where the model has one, then the rotary,
+A layer's OPERATOR (``layer_types``) is grouped-query attention (an RMS
+norm on q and k where the model has one, a head or over the whole
+projection, then the rotary,
 multimodal or on the sequence index, over all of a head or its leading
 part; full causal, or SLIDING: over the band of the ``sliding_window``
 latest keys, with the layer's own count of query heads and its layer
 type's rotary, and where the configuration says so a sigmoid gate a head
 on the output); or a gated short convolution (:func:`gated_short_conv`);
 or LINEAR attention (:func:`linear_attention`: the gated delta rule with a
-decay per channel, a float32 state a head carried along the sequence:
+decay per channel, or with ONE decay a head over keys and values of their own
+widths, a float32 state a head carried along the sequence:
 ``ops/delta_rule.py``); or a STATE-SPACE layer (:func:`state_space`:
 Mamba-2's selective scan, one scalar decay a head and token, keys and
 queries shared by all heads or by the heads of each of ``ssm_groups`` groups,
@@ -85,7 +90,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from psana_ray_tpu.ops.delta_rule import CHUNK, chunk_rows, gated_delta_rule
+from psana_ray_tpu.ops.delta_rule import (CHUNK, HEAD_CHUNK, chunk_rows, gated_delta_net,
+                                          gated_delta_rule, lanes_a_head)
 from psana_ray_tpu.ops.short_conv import gated_conv_taps
 from psana_ray_tpu.ops.ssd import scan_rows, ssd_scan
 from psana_ray_tpu.parallel import sparse_attention as sa
@@ -220,12 +226,22 @@ class DecoderConfig:
     # rest passes as it is); a SLIDING layer's is plain, over the whole head, at sliding_rope_theta
     rope_partial_dim: int = 0
     sliding_rope_theta: float = 0.0
-    qk_norm: bool = True  # an RMS norm with a gain on each grouped-query head's query and key
+    qk_norm: bool = True  # an RMS norm with a gain on each grouped-query head's query and key,
+    # or ("projection") on ALL of a token's query columns and all its key columns, before the heads are cut
+    qk_norm_span: str = "head"
     conv_taps: int = 3  # of a short convolution (conv_L_cache, short_conv_kernel_size)
     # linear attention (the gated delta rule with a decay per channel): num_heads heads whose keys
     # and values are linear_head_dim wide, the log-decay a token in (linear_decay_floor, 0)
     linear_head_dim: int = 0
     linear_decay_floor: float = 0.0
+    # the same operator's OTHER form (Gated DeltaNet's): values linear_value_dim wide (0: as wide as
+    # the keys, a square state), ONE decay a head and token, -exp(A) softplus(a + b), unbounded below
+    # ("head"; "channel": the bounded gate above), the step size sigmoid(.) times linear_beta_scale
+    # (2: a state's eigenvalues may be negative). `linear_decay` ALONE says which form a layer is: what
+    # is drawn for it, which kernel runs it, and the output's gate (silu per head, sigmoid per channel)
+    linear_value_dim: int = 0
+    linear_decay: str = "channel"
+    linear_beta_scale: float = 1.0
     # a MAMBA layer's scan: ssm_heads heads of ssm_head_dim channels over a state ssm_state wide,
     # B and C shared by the heads of each of ssm_groups groups of consecutive heads, which are also
     # the groups the gated norm goes by; conv_bias: its convolution adds a bias per channel
@@ -258,6 +274,10 @@ class DecoderConfig:
     passes: int = 1
     sandwich: bool = False
     exit_gate: bool = False
+    # which norms a BRANCH has, the rule for every block: one BEFORE it (`pre_norm`: x + Op(rms(x)))
+    # and/or one AFTER it (`sandwich`'s second: x + rms(Op(.))). Both: the sandwich; the second alone
+    # (pre_norm False): OLMo 2's REORDERED norm, x + rms(Op(x)), where nothing norms a branch's input
+    pre_norm: bool = True
     rope_yarn: Optional[Yarn] = None  # YaRN-blended rotary frequencies and the softmax's mscale^2
     # latent attention (kv_lora_rank 0: grouped-query attention): queries through a normed
     # q_lora_rank (0: of full rank), keys and values from ONE normed kv_lora_rank latent; a head's query and key
@@ -416,7 +436,7 @@ class DecoderConfig:
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Nine spellings are read:
+        ``experts_held`` and ``tie_embedding``. Ten spellings are read:
         Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
         ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
         (``norm_eps``, ``layer_types``, ``num_dense_layers``,
@@ -477,7 +497,23 @@ class DecoderConfig:
         ``moe_shared_expert_intermediate_size``, the shared expert's whole
         width; and, as its module has them and its file has no key for,
         DeepSeek-V3's router (sigmoid affinities, a selection bias, 1e-20), no
-        rotary and no per-head norm in an attention layer). Where a
+        rotary and no per-head norm in an attention layer); and
+        Olmo-Hybrid's (``olmo_hybrid``: ``layer_types`` entries
+        ``linear_attention`` beside ``linear_key_head_dim``,
+        ``linear_value_head_dim``, ``linear_num_key_heads`` =
+        ``linear_num_value_heads``, ``linear_conv_kernel_dim`` and
+        ``linear_allow_neg_eigval``: Gated DeltaNet's layers, ONE decay a head
+        over a rectangular state, the step size in (0, 2) where eigenvalues
+        may be negative, a SiLU gate after the output's norm;
+        ``rope_parameters`` with ONE ``rope_theta``, null: no rotary; and, as
+        its family has them and its file has no key for, OLMo 2's reordered
+        norm — a branch normed AFTER it is computed and nothing before it — on
+        both kinds of layer, and an RMS norm over the WHOLE query and key
+        projections before the heads are cut: the ``linear_*`` keys ARE read
+        as that family's mark, as ``total_ut_steps`` is the looped stack's,
+        so a file with them whose three head counts differ or whose
+        ``rope_theta`` is not null — another model that spells its linear
+        layers so — is refused and never given this block). Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -532,7 +568,23 @@ class DecoderConfig:
                     f"early_exit_threshold {m['early_exit_threshold']} is not 1: an exit before the "
                     f"last pass (a pass count the data sets) is not supported")
             loop = dict(passes=int(m["total_ut_steps"]), sandwich=True, exit_gate=True)
-        qk_norm = scale is None and "rope_parameters" not in m and not loop and not single
+        # Gated DeltaNet's layers, by the keys that size them (Olmo-Hybrid's spelling)
+        delta_net = "linear_key_head_dim" in m
+        if delta_net and (int(m["linear_num_key_heads"]) != int(m["linear_num_value_heads"])
+                          or int(m["linear_num_key_heads"]) != heads):
+            raise ValueError(f"linear layers of {m['linear_num_key_heads']} key heads, "
+                             f"{m['linear_num_value_heads']} value heads beside {heads} attention "
+                             "heads are not built: one count for all three is")
+        # what those layers are, and what that family does beside them: a branch normed AFTER it and
+        # nothing before it, q and k normed over their whole projections. None of it is a key of the
+        # file: the linear_* keys ARE read as the family's mark, so a file that has them and is not
+        # of that family's shape (one head count, no rotary: below) is refused, never built as this
+        net = dict(linear_value_dim=int(m["linear_value_head_dim"]), linear_decay="head",
+                   linear_beta_scale=2.0 if m.get("linear_allow_neg_eigval") else 1.0,
+                   linear_chunk=HEAD_CHUNK, sandwich=True, pre_norm=False,
+                   qk_norm_span="projection") if delta_net else {}
+        qk_norm = delta_net or (scale is None and "rope_parameters" not in m and not loop
+                                and not single)
         if layer_types and (len(layer_types) != n_layers
                             or set(layer_types) - {ATTENTION, SLIDING, CONV, LINEAR}
                             - ({MAMBA} if ssm else set())
@@ -544,6 +596,13 @@ class DecoderConfig:
             raise ValueError("latent attention without a rotary is not built")
         head_dim = int(m.get("head_dim") or int(m["hidden_size"]) // heads)
         by_type = m.get("rope_parameters")  # Laguna's: a rotary a layer type
+        flat = None
+        if by_type is not None and ATTENTION not in by_type:  # Olmo-Hybrid's: ONE rotary's own
+            flat, by_type = by_type, None
+        if delta_net and (flat is None or flat.get("rope_theta") is not None):
+            raise ValueError("linear_* keys beside a rotary (rope_parameters.rope_theta is not null) "
+                             "are not built: those keys are read as the mark of a position-free "
+                             "block under the reordered norm, and no key of the file says otherwise")
         window = {}
         if by_type:
             rope = dict(by_type[ATTENTION])
@@ -565,6 +624,8 @@ class DecoderConfig:
                                  f"layers' heads, not {n_layers}")
             if m.get("moe_router_logit_softcapping"):
                 raise ValueError("a soft cap on the router's logits is not built")
+        elif flat is not None:  # a null theta: q and k are not turned at all
+            rope, theta = {}, float(flat.get("rope_theta") or 0.0)
         else:
             rope, theta = m.get("rope_scaling") or {}, float(m["rope_theta"])
         if SLIDING in layer_types and not window.get("sliding_window"):
@@ -612,11 +673,13 @@ class DecoderConfig:
             embedding_multiplier=float(m.get("embedding_multiplier", 1.0)),
             attention_multiplier=None if scale is None else float(scale),
             logits_scaling=float(m.get("logits_scaling", 1.0)),
-            rotary=m.get("position_embedding_type") != "nope" and not single,
-            conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size",
-                                                      m.get("mamba_d_conv", m.get("conv_kernel", 3))))),
-            linear_head_dim=int(m["head_dim"]) if linear else 0,
-            linear_decay_floor=float(m["kda_lower_bound"]) if linear else 0.0,
+            rotary=(m.get("position_embedding_type") != "nope" and not single
+                    and (flat is None or flat.get("rope_theta") is not None)),
+            conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size", m.get(
+                "mamba_d_conv", m.get("conv_kernel", m.get("linear_conv_kernel_dim", 3)))))),
+            linear_head_dim=int(m["linear_key_head_dim" if delta_net else "head_dim"]) if linear else 0,
+            linear_decay_floor=float(m["kda_lower_bound"]) if linear and not delta_net else 0.0,
+            **net,
             tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
             **loop,
             rope_yarn=yarn,
@@ -682,6 +745,23 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
         elif op == CONV:
             p = {"norm1": gain(d), "w_in": w(d, 3 * d), "conv_w": w(d, cfg.conv_taps),
                  "w_out": w(d, d)}
+        elif op == LINEAR and cfg.linear_decay == "head":
+            wide, values = cfg.num_heads * cfg.linear_head_dim, cfg.num_heads * cfg.linear_value_dim
+            # W_q, W_k, W_v and each one's taps, the published shapes; taps of order 1 (as
+            # above); Mamba-2's published initialiser for the gate (A = U(1, 16); the bias the inverse
+            # softplus of a log-uniform step in [0.001, 0.1]: log-decays of -0.001 to -1.6 a token where
+            # the pre-activation is 0), and W_a at 1/4 over sqrt(D): the pre-activation of a stream of
+            # RMS r is normal(0, r / 4), so alpha spreads over (e^-3, 1) and past it in the tails, and
+            # a decay stuck at either end would show
+            first = jnp.exp(between(np.log(0.001), np.log(0.1), cfg.num_heads))
+            p = {"norm1": gain(d), "w_q": w(d, wide), "w_k": w(d, wide), "w_v": w(d, values),
+                 **{name: w(width, cfg.conv_taps, scale=0.5)
+                    for name, width in (("conv_q", wide), ("conv_k", wide), ("conv_v", values))},
+                 "w_f": w(d, cfg.num_heads, scale=0.25 * d ** -0.5),
+                 "dt_bias": first + jnp.log(-jnp.expm1(-first)),
+                 "a_log": jnp.log(between(1.0, 16.0, cfg.num_heads)),
+                 "w_beta": w(d, cfg.num_heads), "w_z": w(d, values),
+                 "o_norm": gain(cfg.linear_value_dim), "wo": w(values, d)}
         elif op == LINEAR:
             wide = cfg.num_heads * cfg.linear_head_dim
             # taps of order 1, so that the SiLU is not in its linear part; the decay's A and b
@@ -734,16 +814,20 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 "wk": w(d, cfg.num_kv_heads * hd, scale=sharp),
                 "wv": w(d, cfg.num_kv_heads * hd), "wo": w(heads * hd, d),
             }
-            if cfg.qk_norm:
-                p.update(q_norm=gain(hd), k_norm=gain(hd))
+            if cfg.qk_norm:  # a gain a head's component, or one a column of the whole projection
+                whole = cfg.qk_norm_span == "projection"
+                p.update(q_norm=gain(heads * hd if whole else hd),
+                         k_norm=gain(cfg.num_kv_heads * hd if whole else hd))
             if cfg.attn_gate:
                 p["w_attn_gate"] = w(d, heads)
             if cfg.indexer_heads:
                 p.update(_index_params(cfg, w, gain, d))
+        if not cfg.pre_norm:  # nothing norms a branch's input: only what a block has is drawn
+            p.pop("norm1", None)
         if cfg.sandwich:  # each branch's second norm
             p.update(norm1_post=gain(d), norm2_post=gain(d))
         gated = cfg.mlp_act == "silu"  # an ungated MLP (relu2) has no W_gate: only what a block has is drawn
-        if experts is not None:
+        if experts is not None and cfg.pre_norm:
             p["norm2"] = gain(d)  # the feed-forward's norm (an operator ALONE has none)
         if experts:
             held = cfg.experts_held[1]
@@ -870,13 +954,18 @@ def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
     passes and wrote the bf16 heads in a layout of its choosing)."""
     s = x.shape[0]
     dt = p["wq"].dtype  # the activations' type: x's own, but where the stream is kept in float32
-    a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
-    q = _mm(a, p["wq"]).reshape(s, -1, cfg.head_dim)
-    if cfg.qk_norm:
-        q = rms_norm(q, p["q_norm"], cfg.rms_eps)
-    k = _mm(a, p["wk"]).reshape(s, cfg.num_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
-        k = rms_norm(k, p["k_norm"], cfg.rms_eps)
+    a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt) if cfg.pre_norm else x.astype(dt)
+    # the q / k norm: none, a gain a head's component, or over the WHOLE projection before the cut
+    whole = cfg.qk_norm and cfg.qk_norm_span == "projection"
+    a_head = cfg.qk_norm and not whole
+
+    def normed(u, g, heads):
+        u = rms_norm(u, p[g], cfg.rms_eps) if whole else u
+        u = u.reshape(s, heads, cfg.head_dim)
+        return rms_norm(u, p[g], cfg.rms_eps) if a_head else u
+
+    q = normed(_mm(a, p["wq"]), "q_norm", -1)
+    k = normed(_mm(a, p["wk"]), "k_norm", cfg.num_kv_heads)
     scale, turned = _rotary_scales(cfg, windowed)  # the softmax scale rides on q
     kept = _kernel_turns(cfg, angles)  # float32: the kernel rounds what it has turned
     if angles is None:
@@ -1143,30 +1232,82 @@ def _linear_projections(p, x, cfg: DecoderConfig):
             jax.nn.sigmoid(_mm(a, p["w_beta"])))
 
 
+def _delta_net_projections(p, x, cfg: DecoderConfig):
+    """The same where ONE decay a head is projected (``w_f [D, H]``): ``x [T,
+    D]`` -> ``(q, k [T, H*dl]``, ``v [T, H*d_v]`` before their convolutions,
+    ``f [T, H]`` float32, ``z [T, H*d_v]``, ``beta [T, H]`` float32,
+    ``sigmoid(.)`` times ``linear_beta_scale``), from the normed input where
+    the block has a norm before its branch, else from the stream as it is.
+    The weights are held at the published shapes, ``W_q, W_k [D, H*d_k]``: a
+    head's ``d_k`` (96) columns are laid at whole lane tiles (``dl`` 128:
+    ``delta_rule.lanes_a_head`` on the WEIGHT, 11 M elements, where the
+    product's ``[T, H*d_k]`` result would cost three array-sized passes of
+    XLA's: a slice, a pad and a relayout, compiled and read), so ``q`` and
+    ``k`` leave their products as the kernel reads them: a zero column
+    changes no convolution, no L2 norm, no product and no row of a state."""
+    dt, heads = p["w_q"].dtype, cfg.num_heads
+    a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt) if cfg.pre_norm else x.astype(dt)
+    q, k = (_mm(a, lanes_a_head(p[w], heads)).astype(dt) for w in ("w_q", "w_k"))
+    return (q, k, _mm(a, p["w_v"]).astype(dt), _mm(a, p["w_f"]), _mm(a, p["w_z"]).astype(dt),
+            cfg.linear_beta_scale * jax.nn.sigmoid(_mm(a, p["w_beta"])))
+
+
+def _lanes_conv_silu(u, taps_w, seq_len: int, heads: int):
+    """:func:`conv_silu` on ``u [T, H*dl]`` with the published taps ``[H*d_k,
+    taps]`` laid out a head at whole lane tiles as ``u``'s columns are."""
+    return conv_silu(u, lanes_a_head(taps_w.T, heads).T, seq_len)
+
+
 def linear_attention(p, x, batch: int, cfg: DecoderConfig):
-    """Kimi Delta Attention, as Ling-3.0's linear layers have it, on ``x
-    [B*S, D]`` -> ``x + Op``: with ``a = rms(x)``, ``[q | k | v] = a W_qkv``
-    each through a causal depthwise convolution of ``conv_taps`` taps and a
-    SiLU (:func:`conv_silu`; zeros before each sequence); per head ``q`` and
-    ``k`` L2-normed, the log-decay ``linear_decay_floor * sigmoid(exp(A) (a
-    W_f + b))`` per head AND channel, the step size ``sigmoid(a W_beta)``
-    one a head; the delta rule's state starts at 0 with every sequence; the
-    output normed per head (``o_norm``) and gated by ``sigmoid(a W_z)``,
-    then ``W_o``. No rotary. Under the scopes ``proj`` (the norm, the four
-    products, ``W_o``), ``conv`` (the three convolutions and their SiLU: one
-    pass over ``[T, 3*H*d]``) and ``kda`` (the gate, the L2 norms, the
-    recurrence and the output's norm and gate: ONE kernel,
-    ``ops/delta_rule.gated_delta_rule``)."""
+    """Linear attention by the gated delta rule on ``x [B*S, D]`` -> ``x +
+    Op`` (the branch normed before it is added where the block has a norm
+    after it), in one of TWO forms told apart by ``cfg.linear_decay`` alone
+    (what :func:`init_params` draws by too): ``"channel"``, a decay per head
+    AND channel from ``w_f [D, H*d]`` (Kimi Delta Attention, as Ling-3.0's
+    linear layers have it), or ``"head"``, ONE a head from ``w_f [D, H]``
+    (Gated DeltaNet, as Olmo-Hybrid's). With ``a`` the layer's input
+    (normed where the block norms it), ``q, k, v = a W_q, a W_k, a W_v`` (one
+    ``W_qkv`` per channel; three matrices per head) each through
+    a causal depthwise convolution of ``conv_taps`` taps and a SiLU
+    (:func:`conv_silu`; zeros before each sequence); per head ``q`` and ``k``
+    L2-normed, the step size ``linear_beta_scale * sigmoid(a W_beta)`` one a
+    head; the delta rule's state, ``[d_k, d_v]`` float32 a head, starts at 0
+    with every sequence. Per channel: the log-decay ``linear_decay_floor *
+    sigmoid(exp(A) (a W_f + b))``, the output normed per head (``o_norm``)
+    and gated by ``sigmoid(a W_z)``. Per head: the log-decay ``-exp(A_log)
+    softplus(a W_f + dt_bias)``, unbounded below, keys ``linear_head_dim``
+    and values ``linear_value_dim`` wide, the output normed per head and THEN
+    gated by ``silu(a W_z)``. Then ``W_o``. No rotary. Under
+    the scopes ``proj`` (the norm, the four products, ``W_o`` with the
+    branch's norm as its epilogue), ``conv`` (the three convolutions and their
+    SiLU: one pass over ``[q | k | v]``) and the kernel under a scope of its
+    own, ``kda`` or ``gdn`` (the gate, the L2 norms, the recurrence and the
+    output's norm and gate: ONE kernel, ``ops/delta_rule.gated_delta_rule``
+    or ``gated_delta_net``)."""
     s = x.shape[0] // batch
+    if cfg.linear_decay == "head":  # ONE decay a head
+        with jax.named_scope("proj"):
+            q, k, v, f, z, beta = jax.jit(_delta_net_projections, static_argnums=2)(p, x, cfg)
+        with jax.named_scope("conv"):
+            q, k = (jax.jit(_lanes_conv_silu, static_argnums=(2, 3))(u, p[w], s, cfg.num_heads)
+                    for u, w in ((q, "conv_q"), (k, "conv_k")))
+            v = jax.jit(conv_silu, static_argnums=2)(v, p["conv_v"], s)
+        with jax.named_scope("gdn"):
+            o = gated_delta_net(q, k, v, f, z, beta, p["a_log"], p["dt_bias"], p["o_norm"],
+                                seq_len=s, heads=cfg.num_heads, key_dim=cfg.linear_head_dim,
+                                eps=cfg.rms_eps, chunk=cfg.linear_chunk)
+    else:
+        with jax.named_scope("proj"):
+            qkv, f, z, beta = jax.jit(_linear_projections, static_argnums=2)(p, x, cfg)
+        with jax.named_scope("conv"):
+            qkv = jax.jit(conv_silu, static_argnums=2)(qkv, p["conv_w"], s)
+        with jax.named_scope("kda"):
+            o = gated_delta_rule(qkv, f, z, beta, p["decay_a"], p["decay_b"], p["o_norm"], seq_len=s,
+                                 heads=cfg.num_heads, lower=cfg.linear_decay_floor, eps=cfg.rms_eps,
+                                 chunk=cfg.linear_chunk)
     with jax.named_scope("proj"):
-        qkv, f, z, beta = jax.jit(_linear_projections, static_argnums=2)(p, x, cfg)
-    with jax.named_scope("conv"):
-        qkv = jax.jit(conv_silu, static_argnums=2)(qkv, p["conv_w"], s)
-    with jax.named_scope("kda"):
-        o = gated_delta_rule(qkv, f, z, beta, p["decay_a"], p["decay_b"], p["o_norm"], seq_len=s,
-                             heads=cfg.num_heads, lower=cfg.linear_decay_floor, eps=cfg.rms_eps,
-                             chunk=cfg.linear_chunk)
-    with jax.named_scope("proj"):
+        if cfg.sandwich:
+            return jax.jit(_onto_normed, static_argnums=4)(x, o, p["wo"], p["norm1_post"], cfg.rms_eps)
         return jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(x, o, p["wo"])
 
 
@@ -1240,9 +1381,10 @@ def _onto_normed(x, o, wo, g, eps: float):
 
 def _mlp_onto_normed(p, x, eps: float):
     """``x + rms(MLP(rms(x; norm2)); norm2_post)``: a sandwich layer's dense
-    feed-forward, jitted by name (as :func:`_onto_normed`)."""
+    feed-forward (``MLP(x)`` itself where the block has no ``norm2``: the
+    reordered norm), jitted by name (as :func:`_onto_normed`)."""
     dt = p["w_up"].dtype
-    b = rms_norm(x, p["norm2"], eps).astype(dt)
+    b = (rms_norm(x, p["norm2"], eps) if "norm2" in p else x).astype(dt)
     h = hidden_rows(lambda w: _mm(b, w), p.get("w_gate"), p["w_up"]).astype(dt)
     return _onto_normed(x, h, p["w_down"], p["norm2_post"], eps)
 
@@ -1327,10 +1469,12 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     and counts zeros where a layer of the other kind counts."""
     op, experts = kind
     live, causal = 0, 0
-    if cfg.sandwich and (op != ATTENTION or experts or cfg.attn_gate or cfg.indexer_heads
-                         or cfg.residual_multiplier != 1.0):
-        raise ValueError("a branch's second norm (sandwich) is built on plain causal attention "
-                         "and a dense MLP alone")
+    if cfg.sandwich and (op not in (ATTENTION, LINEAR) or experts or cfg.attn_gate
+                         or cfg.indexer_heads or cfg.residual_multiplier != 1.0):
+        raise ValueError("a norm AFTER a branch (sandwich; with none before it, the reordered norm) "
+                         "is built on plain causal attention, linear attention and a dense MLP alone")
+    if not (cfg.pre_norm or cfg.sandwich):
+        raise ValueError("a block whose branches have no norm, before or after, is not built")
     if op == CONV:
         with jax.named_scope("conv"):
             x = jax.jit(gated_short_conv, static_argnums=(2, 3))(p, x, batch, cfg)

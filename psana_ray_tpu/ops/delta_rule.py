@@ -1,4 +1,4 @@
-"""The gated delta rule with a decay PER CHANNEL, in chunks.
+"""The gated delta rule in chunks: a decay PER CHANNEL, and ONE decay a head.
 
 Kimi Delta Attention's recurrence (arXiv:2510.26692) carries, per head, a
 ``[d_k, d_v]`` float32 state along the sequence, ``S_0 = 0`` at every
@@ -72,6 +72,25 @@ the recurrence and the output's norm and gate are this ONE kernel: it
 reads ``[q | k | v]`` (after their convolution), ``f``, ``z`` and ``beta``
 once and writes the gated output once. Off the TPU it runs in Pallas
 interpret mode (tests, rehearsals).
+
+The SECOND form (:func:`gated_delta_net`: Gated DeltaNet, arXiv:2412.06464)
+has ONE decay a head and token, Mamba's gate ``g_t = -exp(A_log) softplus(a_t
++ dt_bias)``, unbounded below, keys ``d_k`` and values ``d_v`` wide (a
+RECTANGULAR ``[d_k, d_v]`` state), a step size that may pass 1 and a SiLU
+gate AFTER the output's norm. A scalar decay leaves the products: ``A_kk[i,
+j] = (k_i . k_j) e^(G_i - G_j)``, one ``[C, C]`` matrix of decays a head whose
+every exponent under the mask is <= 0 (:func:`_head_products`), so there are
+no row blocks, no rescaled keys and no cap. What the two forms share: the
+constants made once a grid step, the exact running sums, the inverse by
+halves and everything after the scores (:func:`_chunks`: ``W | U``, the
+state's read and update, the heads of a grid step part by part side by
+side). Heads that fill no whole lane tile (96 wide: 30 of them are 22.5
+tiles, and no group of heads that divides 30 cuts them at tiles) reach the
+kernel a head at 128 columns, zeros after the 96 (:func:`lanes_a_head`). On
+the v5e at 8,704 tokens, 30 heads of 96 -> 192, a layer ALONE (my chip runs,
+PR 67; rows a chunk x heads a grid step): 128 x 6 1.83 ms (built), 128 x 10
+1.75, 128 x 2 2.88, 64 x 10 1.77, 64 x 6 2.18, 64 x 2 4.59, 32 x 6 3.09, 256
+x 2 4.10 (the first call of 10 heads 3.1 s for 6 heads' 1.7).
 """
 
 from __future__ import annotations
@@ -93,6 +112,12 @@ BLOCK = 16  # rows that share a reference row: 15 rows at -5 a row is e^75, floa
 HEADS = 4  # heads a grid step
 L2_EPS = 1e-6  # in the L2 norms' root, as the public KDA kernels have it
 _CAP = 80.0  # of a masked entry's exponent: 128 channels of e^80 still sum inside float32
+# the form with ONE decay a head (`gated_delta_net`): rows a chunk and heads a grid step. On the v5e
+# at 8,704 tokens, 30 heads of 96 -> 192, a layer: 128 rows x 6 heads 1.83 ms, x 10 heads 1.75 (a
+# body and a first call nearly twice as long), x 2 heads 2.88; 64 rows 2.18 / 1.77 / 4.59; 32 rows x
+# 6 heads 3.09; 256 rows x 2 heads 4.10 (my chip runs, PR 67)
+HEAD_CHUNK = 128
+HEAD_GROUP = 6
 
 
 def _mm(a, b, dims=((1,), (0,))):
@@ -118,12 +143,16 @@ def _running_sum(g, ones):
     """``[C, d]`` float32 -> the sums over rows ``0..i``, exact: the product of
     ``ones`` (lower triangular, bf16) with ``g`` split in three bf16 parts."""
     d = g.shape[1]
+    parts = _mm(ones, jnp.concatenate(_three_parts(g), axis=1))
+    return parts[:, :d] + parts[:, d:2 * d] + parts[:, 2 * d:]
+
+
+def _three_parts(g):
+    """``g`` float32 as three bf16 arrays whose sum it is, exactly."""
     hi = g.astype(jnp.bfloat16)
     rest = g - hi.astype(jnp.float32)
     mid = rest.astype(jnp.bfloat16)
-    low = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
-    parts = _mm(ones, jnp.concatenate([hi, mid, low], axis=1))
-    return parts[:, :d] + parts[:, d:2 * d] + parts[:, 2 * d:]
+    return [hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)]
 
 
 def _unit_lower_inverses(below, eye, joins):
@@ -157,12 +186,13 @@ def _keys_of_blocks(k, gam, block):
     return out
 
 
-def _chunks(q, k, v, g, beta, state_t, constants, block: int):
-    """One chunk of each of a grid step's heads (lists over the heads): ``q, k``
-    (normed), ``v [C, d]``, ``g [C, d_k]`` float32, ``beta [C, 1]``, the state
-    transposed ``[d_v, d_k]`` -> ``(o [C, d_v], state_t)`` per head. Part by
-    part for all the heads: one head's parts are a chain."""
-    strict, lower_eq, eye, ones, joins = constants
+def _channel_products(q, k, g, constants, block: int):
+    """The decay PER CHANNEL: ``g [C, d_k]`` a head -> ``(G, A)`` per head, ``G``
+    the running sums ``[C, d_k]`` and ``A`` a list over the row blocks of
+    ``[A_kk | A_qk]`` stacked ``[2 * block, C]`` (unmasked: zeros after the
+    block's last row, anything past a row's diagonal). The decays ride INSIDE
+    the products, on operands rescaled block by block."""
+    ones = constants[3]
     heads, (c, d_k) = range(len(q)), k[0].shape
     gam = [_running_sum(u, ones) for u in g]
     keys = [_keys_of_blocks(k[h], gam[h], block) for h in heads]
@@ -175,6 +205,45 @@ def _chunks(q, k, v, g, beta, state_t, constants, block: int):
                 seen = jnp.concatenate([seen, jnp.zeros((c - lo - block, d_k), jnp.float32)])
             a[h].append(_mm(jnp.concatenate([k[h][lo:lo + block] * scale,
                                              q[h][lo:lo + block] * scale]), seen, ((1,), (1,))))
+    return gam, a
+
+
+def _running_sum_rows(g, ones):
+    """:func:`_running_sum` with the rows along the LANES: ``[heads, C]`` ->
+    the sums over columns ``0..j``, exact (three products of a few rows: a
+    stack of them would cut sublane tiles)."""
+    return sum(_mm(part, ones, ((1,), (1,))) for part in _three_parts(g))
+
+
+def _head_products(q, k, g_cols, g_rows, constants):
+    """ONE decay a head: ``g_cols [C, heads]`` and the same numbers as
+    ``g_rows [heads, C]`` -> ``(G, A)`` as :func:`_channel_products` gives
+    them, ``G [C, 1]`` a head and ``A`` ONE block of all ``C`` rows. A scalar
+    decay leaves the products: ``A_kk[i, j] = (k_i . k_j) e^(G_i - G_j)``, one
+    ``[C, C]`` matrix of decays a head whose every exponent at or under the
+    diagonal is <= 0 (the rest is masked BEFORE the exponential), so there
+    are no row blocks, no rescaled keys and no cap, and the gate may be as
+    negative as it likes: what underflows is the true value's own underflow."""
+    lower_eq, ones = constants[1], constants[3]
+    gam, along = _running_sum(g_cols, ones), _running_sum_rows(g_rows, ones)
+    gam = [gam[:, h:h + 1] for h in range(len(q))]
+    a = []
+    for h, col in enumerate(gam):
+        decay = jnp.exp(jnp.where(lower_eq, col - along[h:h + 1], -jnp.inf))
+        a.append([_mm(jnp.concatenate([k[h], q[h]]), k[h], ((1,), (1,)))
+                  * jnp.concatenate([decay, decay])])
+    return gam, a
+
+
+def _chunks(q, k, v, gam, a, beta, state_t, constants, block: int):
+    """One chunk of each of a grid step's heads (lists over the heads): ``q, k``
+    (normed) ``[C, d_k]``, ``v [C, d_v]``, the running sums ``gam [C, d_k]`` or
+    ``[C, 1]`` and the scores ``a`` (:func:`_channel_products`,
+    :func:`_head_products`), ``beta [C, 1]``, the state transposed ``[d_v, d_k]``
+    -> ``(o [C, d_v], state_t)`` per head. Part by part for all the heads: one
+    head's parts are a chain."""
+    strict, lower_eq, eye, ones, joins = constants
+    heads, (c, d_k) = range(len(q)), k[0].shape
     xs = _unit_lower_inverses(
         [jnp.where(strict, beta[h] * jnp.concatenate([u[:block] for u in a[h]]), 0.0)
          for h in heads], eye, joins)
@@ -188,7 +257,11 @@ def _chunks(q, k, v, g, beta, state_t, constants, block: int):
          + _mm(jnp.where(lower_eq, jnp.concatenate([u[block:] for u in a[h]]), 0.0), vbar[h])
          for h in heads]
     last = [u[c - 1:c] for u in gam]
-    return [(o[h], state_t[h] * jnp.exp(last[h])
+    along = last
+    if gam[0].shape[1] == 1:  # ONE decay a head: along the lanes BEFORE the exponential, down the
+        along = [jnp.broadcast_to(u, (1, d_k)) for u in last]  # state's rows after it (Mosaic
+        # broadcasts one way at a time)
+    return [(o[h], state_t[h] * jnp.exp(along[h])
              + _mm(vbar[h], k[h] * jnp.exp(last[h] - gam[h]), ((0,), (0,)))) for h in heads]
 
 
@@ -204,9 +277,11 @@ def _kernel(q_ref, k_ref, v_ref, f_ref, z_ref, beta_ref, ea_ref, b_ref, gain_ref
     q = [u * jax.lax.rsqrt(jnp.sum(u * u, axis=-1, keepdims=True) + L2_EPS) * d ** -0.5 for u in q]
     k = [u * jax.lax.rsqrt(jnp.sum(u * u, axis=-1, keepdims=True) + L2_EPS) for u in k]
     g = [lower * jax.nn.sigmoid(ea_ref[:, at] * (f_ref[:, at] + b_ref[:, at])) for at in cols]
-    done = _chunks(q, k, [v_ref[:, at].astype(jnp.float32) for at in cols], g,
-                   [beta_ref[:, h:h + 1] for h in range(heads)],
-                   [state_ref[h] for h in range(heads)], _constants(q_ref.shape[0]), block)
+    v = [v_ref[:, at].astype(jnp.float32) for at in cols]
+    beta, state = [beta_ref[:, h:h + 1] for h in range(heads)], [state_ref[h] for h in range(heads)]
+    constants = _constants(q_ref.shape[0])
+    done = _chunks(q, k, v, *_channel_products(q, k, g, constants, block), beta, state, constants,
+                   block)
     for h, (at, (o, state)) in enumerate(zip(cols, done)):
         state_ref[h] = state
         o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * gain
@@ -271,3 +346,124 @@ def gated_delta_rule(qkv, f, z, beta, log_a, bias, gain, *, seq_len: int, heads:
     )(qkv, qkv, qkv, f.astype(jnp.float32), z,
       jnp.transpose(beta.astype(jnp.float32).reshape(t, n_groups, group), (1, 0, 2)),
       expand, bias.astype(jnp.float32)[None], gain.astype(jnp.float32)[None])
+
+
+# ---------------------------------------------------------------------------
+# ONE decay a head (Gated DeltaNet, arXiv:2412.06464), keys and values of their own widths
+# ---------------------------------------------------------------------------
+
+def _head_kernel(q_ref, k_ref, v_ref, z_ref, ac_ref, ar_ref, beta_ref, nega_c_ref, bias_c_ref,
+                 nega_r_ref, bias_r_ref, gain_ref, o_ref, state_ref, *, heads, d_k, eps):
+    @pl.when(pl.program_id(2) == 0)  # a sequence starts: S_0 = 0
+    def _start():
+        state_ref[...] = jnp.zeros(state_ref.shape, jnp.float32)
+
+    lanes, d_v = q_ref.shape[1] // heads, v_ref.shape[1] // heads
+    gain = gain_ref[...].astype(jnp.float32)
+    keys = [slice(h * lanes, (h + 1) * lanes) for h in range(heads)]  # a head's columns of q and k,
+    vals = [slice(h * d_v, (h + 1) * d_v) for h in range(heads)]  # and of v, z and the output
+    q, k = ([u[:, at].astype(jnp.float32) for at in keys] for u in (q_ref, k_ref))
+    q = [u * jax.lax.rsqrt(jnp.sum(u * u, axis=-1, keepdims=True) + L2_EPS) * d_k ** -0.5 for u in q]
+    k = [u * jax.lax.rsqrt(jnp.sum(u * u, axis=-1, keepdims=True) + L2_EPS) for u in k]
+    # Mamba's gate, unbounded below, as columns [C, heads] and as rows [heads, C]
+    g_cols = nega_c_ref[...] * jax.nn.softplus(ac_ref[...] + bias_c_ref[...])
+    g_rows = nega_r_ref[...] * jax.nn.softplus(ar_ref[...] + bias_r_ref[...])
+    v = [v_ref[:, at].astype(jnp.float32) for at in vals]
+    beta, state = [beta_ref[:, h:h + 1] for h in range(heads)], [state_ref[h] for h in range(heads)]
+    constants = _constants(q_ref.shape[0])
+    done = _chunks(q, k, v, *_head_products(q, k, g_cols, g_rows, constants), beta, state,
+                   constants, q_ref.shape[0])
+    for h, (at, (o, state)) in enumerate(zip(vals, done)):
+        state_ref[h] = state
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps) * gain
+        z = z_ref[:, at].astype(jnp.float32)
+        o_ref[:, at] = (o * jax.nn.silu(z)).astype(o_ref.dtype)
+
+
+def lanes_a_head(u, heads: int, lanes: int = 128):
+    """``u [T, H*d]`` -> ``[T, H*dl]``, ``dl`` the next multiple of ``lanes``:
+    each head's columns at whole lane tiles, zeros after them (``u`` itself
+    where ``d`` is whole tiles already). A zero column changes no L2 norm, no
+    product with a key and no row of a state: how heads that fill no whole
+    lane tile (96 wide: 30 of them are 22.5 tiles, and no group of heads that
+    divides 30 starts every block at a tile) reach :func:`gated_delta_net`."""
+    t, d = u.shape[0], u.shape[1] // heads
+    if d % lanes == 0:
+        return u
+    return jnp.pad(u.reshape(t, heads, d), ((0, 0), (0, 0), (0, -d % lanes))).reshape(t, -1)
+
+
+def head_group(heads: int, lanes: int, d_v: int, want: int = 0, tiles: bool = True) -> int:
+    """The heads a grid step of :func:`gated_delta_net` takes: the largest
+    count up to ``want`` (0: :data:`HEAD_GROUP`) that divides ``heads`` and,
+    on the chip (``tiles``), whose key and value columns are whole lane tiles
+    (values 192 wide: even counts); all the heads where none does."""
+    return next((n for n in range(min(want or HEAD_GROUP, heads), 0, -1)
+                 if heads % n == 0 and not (tiles and (n * lanes % 128 or n * d_v % 128))), heads)
+
+
+@functools.partial(jax.jit, static_argnames=("seq_len", "heads", "key_dim", "eps", "chunk", "group",
+                                             "interpret"))
+def gated_delta_net(q, k, v, a, z, beta, a_log, dt_bias, gain, *, seq_len: int, heads: int,
+                    key_dim: int, eps: float, chunk: int = HEAD_CHUNK, group: int = 0,
+                    interpret: Optional[bool] = None) -> jax.Array:
+    """The gated delta rule with ONE decay a head over a RECTANGULAR state:
+    ``q, k [T, H*dl]`` (after their convolution; a head's ``key_dim``
+    components in its first columns and zeros after them: :func:`lanes_a_head`),
+    ``v, z [T, H*d_v]``, ``a [T, H]`` float32 (the decay's pre-activation),
+    ``beta [T, H]`` float32 (the step size, whatever its range: ``2 sigmoid``
+    where eigenvalues may be negative), ``a_log, dt_bias [H]``, ``gain [d_v]``
+    -> the normed, gated output ``[T, H*d_v]`` in ``v``'s type, ``T`` rows
+    being whole sequences of ``seq_len``: per head ``g_t = -exp(a_log) softplus(a_t
+    + dt_bias)``, ``S_t = (I - beta_t k_t k_t^T) e^(g_t) S_{t-1} + beta_t k_t
+    v_t^T`` on a ``[key_dim, d_v]`` float32 state from 0, ``o_t = S_t^T q_t``
+    (q, k L2-normed, q times ``key_dim^-1/2``), ``rms(o_t; gain)`` times
+    ``silu(z_t)``. ``group``: the heads a grid step (0: :func:`head_group`'s)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, f32 = a.shape[0], jnp.float32
+    lanes, d_v = q.shape[1] // heads, v.shape[1] // heads
+    rows = chunk_rows(seq_len, chunk)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    group = head_group(heads, lanes, d_v, group, tiles=not interpret)
+    if (q.shape != (t, heads * lanes) or k.shape != q.shape or v.shape != (t, heads * d_v)
+            or z.shape != v.shape or a.shape != (t, heads) or beta.shape != a.shape or t % seq_len
+            or lanes < key_dim or heads % group):
+        raise ValueError(f"delta rule: q, k {q.shape}, v, z {v.shape}, a {a.shape} are not sequences "
+                         f"of {seq_len} rows of {heads} heads with keys {key_dim} wide in groups "
+                         f"of {group}")
+    n_groups, n_chunks = heads // group, seq_len // rows
+
+    def cols(width):  # a group's columns of q and k, or of v, z and the output
+        return pl.BlockSpec((rows, group * width), lambda b, j, c: (b * n_chunks + c, j))
+
+    def by_group(u):  # [T, H] -> [groups, T, group]: a grid step's heads as columns
+        return jnp.transpose(u.astype(f32).reshape(t, n_groups, group), (1, 0, 2))
+
+    def per_head(u):  # [H] -> a group's entries as a row and as a column
+        u = u.astype(f32).reshape(n_groups, 1, group)
+        return u, jnp.transpose(u, (0, 2, 1))
+
+    (nega_c, nega_r), (bias_c, bias_r) = per_head(-jnp.exp(a_log.astype(f32))), per_head(dt_bias)
+    as_cols = pl.BlockSpec((None, rows, group), lambda b, j, c: (j, b * n_chunks + c, 0))
+    col_entry = pl.BlockSpec((None, 1, group), lambda b, j, c: (j, 0, 0))
+    row_entry = pl.BlockSpec((None, group, 1), lambda b, j, c: (j, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_head_kernel, heads=group, d_k=key_dim, eps=float(eps)),
+        grid=(t // seq_len, n_groups, n_chunks),
+        in_specs=[cols(lanes), cols(lanes), cols(d_v), cols(d_v), as_cols,
+                  # the same pre-activations with a chunk's rows along the lanes, a chunk a block
+                  pl.BlockSpec((None, None, group, rows), lambda b, j, c: (j, b * n_chunks + c, 0, 0)),
+                  as_cols, col_entry, col_entry, row_entry, row_entry,
+                  pl.BlockSpec((1, d_v), lambda b, j, c: (0, 0))],
+        out_specs=cols(d_v),
+        out_shape=jax.ShapeDtypeStruct((t, heads * d_v), v.dtype),
+        scratch_shapes=[pltpu.VMEM((group, d_v, lanes), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="gated_delta_net",
+    )(q, k, v, z, by_group(a),
+      jnp.transpose(a.astype(f32).reshape(t // rows, rows, n_groups, group), (2, 0, 3, 1)),
+      by_group(beta), nega_c, bias_c, nega_r, bias_r, gain.astype(f32)[None])

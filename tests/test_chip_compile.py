@@ -734,6 +734,13 @@ PINNED_STEPS = {
     # the kernel it was: `tests/test_decoder_nemotron3.py -k traced_equation` holds its body) and
     # `grouped_tiles`' and `rows_as_words`' rules for a width of 14.5 or 10.5 lane tiles
     "nemotron3_nano_prefill_epix10k2m": "8f6156774fbd568ba84d535bb105baea3801835c157ec059dabcc4cb7eec0707",
+    # pinned in PR 67, which brought it: the nine above were hashed on PR 66's tree first and none
+    # moved, though every one of them now goes through `_projections`' rule for the q / k norm (none,
+    # a head, the whole projection) and for a block without a norm before its branch, `init_params`'
+    # and `decoder_layer`'s branches for the two forms of linear attention and the two norms a branch
+    # may have (all taken in Python, before anything is traced); ling3's kernel, whose body this
+    # test blanks, is held to the traced equation by `tests/test_decoder_olmo_hybrid.py -k ling3`
+    "olmo_hybrid_7b_prefill_epix10k2m": "89569092c2ac410f14de5f25dab796bf01a1e9f6c78be1e4c69057ce3f2a99bb",
 }
 
 
@@ -900,6 +907,102 @@ def test_the_nemotron3_step_compiles_whole_with_nothing_array_sized_between_a_bl
     # contraction minor (`{1,2,0}`: 1,856 is no whole lane tiles) and the grouped product reads it
     # TRANSPOSED, a bitcast (until then a copy of 638 MB at every use, 2.02 ms under no scope)
     assert not re.findall(r"= bf16\[64,(?:2688,1856|1856,2688)\][^ ]* (?:copy|transpose|fusion)\(", text)
+
+
+def test_the_olmo_hybrid_step_compiles_whole_with_its_kernels_where_the_roofline_functions_count_them(
+        one_chip, monkeypatch):
+    """The whole served step of ``olmo_hybrid_7b_prefill_epix10k2m`` at the
+    published sizes (sixteen layers, one frame), compiled for the described
+    v5e (a quarter of a minute: twelve of the layers are one function at one
+    shape): it fits the chip (weights 8.2 GB, under a GB of temporaries), and
+    its Mosaic kernels are the ones the roofline functions count: twelve
+    ``gated_delta_net`` (one a linear layer, under the scope ``gdn``:
+    ``olmo_hybrid.delta_rule`` counts a call), four ``masked_gqa_attention``
+    (under ``sparse_attn``: ``olmo_hybrid.causal_attention``) at TWO heads a
+    grid step (30 heads alone in their groups, unturned: 15 x 36 grid steps a
+    call), the calibration kernel, and no other. q and k leave their
+    products a head at whole lane tiles (``[8704, 3840]`` for 30 heads of 96:
+    the pad is on the 11 M-element WEIGHT) and nothing array-sized stands
+    between those products, the convolutions, the kernel and ``W_o``: no
+    copy, slice, pad or transpose of a bf16 ``[8704, 2880 | 3840 | 5760]``."""
+    import collections
+
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, lowered = _lowered_step("olmo_hybrid_7b_prefill_epix10k2m", one_chip)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert 8.1e9 < mem.argument_size_in_bytes < 8.3e9 and mem.temp_size_in_bytes < 1.5e9
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
+    assert names == {"gated_delta_net": cfg["layer_types"].count("linear_attention") == 12 and 12,
+                     "masked_gqa_attention": cfg["layer_types"].count("full_attention") == 4 and 4,
+                     "fused_calibrate": 1}, names
+    assert all("/gdn/" in line for line in calls if re.match(r"\s*%gated_delta_net", line))
+    assert all("/sparse_attn/" in line for line in calls if re.match(r"\s*%masked_gqa", line))
+    assert sa.heads_a_step(30, 1, 1088, 1088, 128, 128) == 2
+    from psana_ray_tpu.models import decoder
+
+    assert decoder.causal_call_steps(dcfg, 3, 1, 8704) == (30 * 36, 15 * 36)  # what the step counts
+    entry = text[text.index("ENTRY"):]
+    moved = re.findall(r"= bf16\[8704,(?:2880|3840|5760)\][^ ]* (?:copy|transpose|slice|pad|concatenate)\(.*",
+                       entry)
+    assert not moved, [line[:160] for line in moved[:3]]
+    # what IS left, and named in PERF.md section 7 (5): a full layer's q and k products leave their
+    # fusion float32 and COLUMN-major (XLA's choice for the whole-projection norm's row sums in the
+    # product's epilogue) and are copied row-major before the norm scales them: two a full layer
+    assert len(re.findall(r"= f32\[8704,3840\][^ ]* copy\(", entry)) == 2 * 4
+    assert len(re.findall(r"= bf16\[3840,30,128\][^ ]* pad\(", entry)) == 2 * 12  # W_q's and W_k's
+
+
+def test_the_delta_net_kernel_the_chip_compiles_carries_its_state_float32():
+    """The file states a float32 state a head, and on the chip no limit of the
+    cell's ``correct`` tells a state CARRIED in bf16 from it (0.36-2.23
+    yardsticks of 4: the state is a bf16 MXU operand either way). So the
+    kernel Mosaic is handed, traced at the published sizes as the step calls
+    it (not interpreted), is read: its one scratch is ``float32 [6, 192,
+    128]`` (six heads a grid step, the state transposed, a head's 96 keys at
+    128 lanes), what is stored there is float32, and nothing of a state's
+    shape is ever widened from bf16 (rounded on its way to the next chunk)."""
+    import functools
+
+    from psana_ray_tpu.ops import delta_rule as dr
+
+    _, dcfg, _ = _decoder_cell("olmo_hybrid_7b_prefill_epix10k2m")
+    t, h, d_v, bf16 = 8704, dcfg.num_heads, dcfg.linear_value_dim, jnp.bfloat16
+    wide = jax.eval_shape(lambda u: dr.lanes_a_head(u, h), S((1, h * dcfg.linear_head_dim), bf16)).shape[1]
+    operands = (S((t, wide), bf16), S((t, wide), bf16), S((t, h * d_v), bf16), S((t, h), F32),
+                S((t, h * d_v), bf16), S((t, h), F32), S((h,), F32), S((h,), F32), S((d_v,), bf16))
+    traced = jax.make_jaxpr(functools.partial(
+        dr.gated_delta_net, seq_len=t, heads=h, key_dim=dcfg.linear_head_dim, eps=dcfg.rms_eps,
+        chunk=dcfg.linear_chunk, interpret=False))(*operands)
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for inner in eqn.params.values():
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                    yield from pallas_calls(getattr(inner, "jaxpr", inner))
+
+    (call,) = pallas_calls(traced.jaxpr)
+    assert call.params["name"] == "gated_delta_net" and not call.params["interpret"]
+    (scratch,) = call.params["grid_mapping"].scratch_avals
+    group, lanes = dr.head_group(h, wide // h, d_v), wide // h
+    assert (group, lanes) == (dr.HEAD_GROUP, 128) == (6, 128)
+    assert scratch.dtype == F32 and scratch.shape == (group, d_v, lanes)
+    body = call.params["jaxpr"]
+    state_ref = body.invars[-1]
+    assert state_ref.aval.shape == scratch.shape and state_ref.aval.dtype == F32
+    stored = [eqn.invars[1].aval for eqn in body.eqns
+              if eqn.primitive.name == "swap" and eqn.invars[0] is state_ref]
+    assert len(stored) == group and all(a.dtype == F32 and a.shape == (d_v, lanes) for a in stored)
+    rounded = [eqn for eqn in body.eqns if eqn.primitive.name == "convert_element_type"
+               and eqn.invars[0].aval.dtype == bf16 and eqn.invars[0].aval.shape == (d_v, lanes)]
+    assert not rounded
 
 
 def test_the_ouro_step_compiles_whole_as_one_loop_around_one_stack_of_layers(one_chip, monkeypatch):

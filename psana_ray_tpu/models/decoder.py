@@ -299,8 +299,10 @@ class DecoderConfig:
     indexer_key_norm: str = "rms"  # of the one index key: an RMS norm, or "layer" (gain and bias)
     # the computation's tiles; no effect on the mathematics. Measured on the
     # v5e at 34,304 tokens: selection 36 ms a layer at 128 queries against 67
-    # at 256 (512 needs 70 MB of VMEM for a tile's score row), attention 124
-    # ms at 256 against 146 at 128 and 128 at 512
+    # at 256 (512 needs 70 MB of VMEM for a tile's score row); attention under
+    # the mask's sixteen key tiles of 2,176 (`sparse_attention.mask_tile`) 84.9
+    # ms at 256 against 85.7 at 128; 512 rows of eight stacked heads do not fit
+    # a score tile (my chip runs, PR 68; in 512-wide key tiles 124 / 146 / 128)
     q_tile: int = 128  # of the selection kernel (a query tile's whole score row sits in VMEM)
     kv_tile: int = 512  # sa_config's kv_chunk_size: the pieces the selection scores and counts in
     attn_q_tile: int = 256  # of the attention kernel, a multiple of q_tile
@@ -308,8 +310,8 @@ class DecoderConfig:
     # v5e at 4 x 8,704 tokens, heads of 64: 1088 x 1088 25.5 ms a layer, 512 x 1088 27.0, 256 x
     # 2176 27.3, 512 x 512 33.1, 256 x 512 40.5, 256 x 256 74.6 (my chip runs, PR 38). Under a
     # selection (latent attention) the key tile is the one `select_keys` WROTE its mask in, which
-    # `sparse_attention.mask_tile` picks from S for this kernel (2,176 at 8,704 tokens; kv_tile
-    # where S has no wider whole-lane divisor), and the query tile the largest multiple of q_tile
+    # `sparse_attention.mask_tile` picks from S for this kernel (2,176 at 8,704 tokens and, over
+    # keys padded to sixteen of them, at 34,304), and the query tile the largest multiple of q_tile
     # under causal_q_tile that divides S: 512 x 2,176 at 128 heads of 128 + 64, 34.8 ms a layer
     # where the 512 x 512 that kv_tile used to force took 43.4 (my chip runs, PR 47)
     causal_q_tile: int = 1088
@@ -1420,8 +1422,8 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
             live, causal = sa.live_tiles(flags, s)
         with jax.named_scope("sparse_attn"):
             o = jax.jit(sa.masked_gqa_attention, static_argnames=("num_kv_heads", "block_q"))(
-                q, k, v, mask, num_kv_heads=cfg.num_kv_heads,
-                block_q=max(cfg.attn_q_tile, mask.shape[2]))
+                *(u[None] for u in (q, k, v)), mask, num_kv_heads=cfg.num_kv_heads,
+                block_q=max(cfg.attn_q_tile, mask.shape[2]))[0]
     else:
         live = causal = batch * sa.causal_tile_count(s)  # every earlier key is attended
         attend, band = sa.masked_gqa_attention, {}

@@ -21,8 +21,9 @@ What is here, and what it is:
   32-step bisection over the scores' bit patterns (no sort, no
   ``lax.top_k``: a k of 2048 over 34,304 is a sort on the TPU), ties at
   that score are cut at the key index that ``Sel`` would cut them at, and
-  the selection leaves as an int8 mask ``[S/bq, S/mk, bq, mk]`` (tile
-  major, so that the attention kernel's mask tile is one block). The key
+  the selection leaves as an int8 mask ``[S/bq, ceil(S/mk), bq, mk]`` (tile
+  major, so that the attention kernel's mask tile is one block; a last
+  tile past the sequence ends in zeros). The key
   tile ``mk`` it is WRITTEN in is the attention's, chosen for that
   kernel's speed (:func:`mask_tile`), not the ``bk`` the selection scores
   and counts in.
@@ -33,12 +34,14 @@ What is here, and what it is:
   the optimisation this form is the yardstick for. The ``G`` key-value
   heads each serve ``H/G`` query heads, whose query tiles are stacked
   into one ``[H/G * bq, d]`` operand so that a key tile is loaded once
-  for all of them. Its BATCHED form (``[B, S, .]`` operands) has a grid
-  that holds the tiles at or below the diagonal only (a table of ``(query
+  for all of them. ONE body (``_causal_kernel``; until PR 68 a selection
+  over grouped-query heads ran a second one, on ``[S, .]`` operands over a
+  rectangular grid) on ``[B, S, .]`` operands, whose grid
+  holds the tiles at or below the diagonal only (a table of ``(query
   tile, key tile)`` pairs rides in as scalar prefetch), at any head width
   (heads of 64, LFM2's, go head-major into it; heads of whole lane blocks
   — latent attention's alone in their groups, Laguna's six and nine a
-  group — are read and written as column blocks of the token-major arrays
+  group, Keye's eight — are read and written as column blocks of the token-major arrays
   their products wrote), with values of a width of their own, a part of
   the score read from ONE key for all heads (latent attention; that
   part of the QUERY comes float32 and unturned where its angle tables
@@ -46,9 +49,13 @@ What is here, and what it is:
   gate a (token, head) applied where the output is written (Laguna).
   Without a
   mask it is plain causal attention over a batch of sequences; with one
-  (one sequence) it is the selection over LATENT attention, masked-dense
+  (one sequence) it is the selection, over latent or grouped-query
+  attention, masked-dense
   again: the mask's tile is one more operand of a grid step, and its key
-  tile is the kernel's. Under a WINDOW (``window``: a query attends to
+  tile is the kernel's — where that tile does not divide ``S``
+  (:func:`mask_tile`: 34,304 keys in sixteen tiles of 2,176) the keys
+  and values are padded with zeros to the whole tiles, which the mask
+  closes. Under a WINDOW (``window``: a query attends to
   its own key and the ``window - 1`` before it; Laguna's sliding layers)
   the grid holds only the tiles that MEET the band, the running softmax
   starts at a query tile's first visited key tile, and each edge of the
@@ -66,7 +73,6 @@ Off the TPU the kernels run in Pallas interpret mode (tests, rehearsals).
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional, Tuple
 
 import jax
@@ -112,10 +118,28 @@ def mask_tile(s: int, block_k: int) -> int:
     """The key tile a selection's mask is WRITTEN in, which is the key tile
     the attention under it runs in: the widest whole number of 128-lane
     blocks that divides ``s`` up to :data:`MASK_TILE` (8,704 = 68 x 128 ->
-    2,176 = 17 x 128; 34,304 = 268 x 128 -> 512, the selection's own
-    pieces), and the pieces' ``block_k`` where ``s`` is no whole number of
-    lane blocks (small test sizes)."""
-    return pick_tile(s, MASK_TILE) if s % 128 == 0 else block_k
+    2,176 = 17 x 128); where that is under half of :data:`MASK_TILE` and
+    not ``s`` itself (34,304 = 4 x 67 lane blocks: 512), a tile that does
+    NOT divide ``s`` — the narrowest whole number of lane blocks that
+    covers ``s`` in as many tiles as :data:`MASK_TILE` would (34,304 -> 16
+    tiles of 2,176 = 34,816 keys, +1.5%): the mask's last tile ends in
+    zeros and the attention pads its keys to the whole tiles
+    (:func:`_causal_attention`; the selection is causal, so a key laid
+    after the sequence is selected by no query). The pieces' ``block_k``
+    where ``s`` is no whole number of lane blocks (small test sizes). One
+    layer's kernel alone on the v5e at 34,304 tokens, 8 heads a group of 4,
+    ms (my chip runs, PR 68): 256 x 512 over 34,304 keys 111.75, over
+    34,816: 256 x 1,088 93.31, 256 x 2,176 **84.93**, 128 x 2,176 85.66,
+    512 x 1,088 94.09; :func:`select_keys` writing it in slices of what
+    piece and tile share 35.27 / 39.76 (1,088: 64 lanes) / 35.33, and a
+    piece in one store (as it is written now) 35.37 at 512, 35.40 at 2,176."""
+    if s % 128:
+        return block_k
+    tile = pick_tile(s, MASK_TILE)
+    if tile == s or 2 * tile >= MASK_TILE:
+        return tile
+    tiles = -(-s // MASK_TILE)
+    return -(-s // (128 * tiles)) * 128
 
 
 def _interpret(interpret: Optional[bool]) -> bool:
@@ -209,12 +233,13 @@ def _select_kernel(q_ref, k_ref, w_ref, mask_ref, live_ref, keys_ref, *, topk, s
 
     cut = jax.lax.cond(jnp.max(excess) > 0, find_cut, keep_all, 0)
 
-    # the mask leaves in key tiles of mask_k, a piece in slices of what the two widths share
-    # (512-wide pieces in 2,176-wide tiles: four 128-lane slices, the last of every fifth
-    # piece in the next tile); the flags stay the pieces' own
+    # the mask leaves in key tiles of mask_k, a piece in ONE store where it lies inside a tile and
+    # in two where it crosses into the next (512-wide pieces in 2,176-wide tiles: every fourth or
+    # fifth, cut at a whole lane block); the flags stay the pieces' own. (Until PR 68 a piece left
+    # in slices of gcd(block_k, mask_k), four stores each: the same bytes in as many ms, but 268
+    # unrolled stores at 34,304 keys cost a start 1.1 s of tracing where 82 cost nothing that shows)
     live_ref[...] = jnp.zeros(live_ref.shape, jnp.int32)
     mask_ref[...] = jnp.zeros(mask_ref.shape, jnp.int8)
-    lanes = math.gcd(block_k, mask_k)
     for kb in range(n_kb):
         @pl.when(kb < n_live)
         def _live(kb=kb):
@@ -222,9 +247,12 @@ def _select_kernel(q_ref, k_ref, w_ref, mask_ref, live_ref, keys_ref, *, topk, s
             sel = ((k > thr) | ((k == thr) & (cols0 + kb * block_k < cut))).astype(jnp.int32)
             live_ref[0, :, kb:kb + 1] = jnp.max(sel, axis=(0, 1), keepdims=True)
             sel = sel.astype(jnp.int8)
-            for c in range(0, block_k, lanes):
+            c = 0
+            while c < block_k:
                 tile, at = divmod(kb * block_k + c, mask_k)
-                mask_ref[0, tile, :, at:at + lanes] = sel[:, c:c + lanes]
+                run = min(block_k - c, mask_k - at)
+                mask_ref[0, tile, :, at:at + run] = sel[:, c:c + run]
+                c += run
 
 
 def select_keys(q_idx, k_idx, w_idx, *, topk: int, block_q: int = 128, block_k: int = 512,
@@ -232,22 +260,22 @@ def select_keys(q_idx, k_idx, w_idx, *, topk: int, block_q: int = 128, block_k: 
                 interpret: Optional[bool] = None) -> Tuple[jax.Array, jax.Array]:
     """``q_idx [H_I, S, d_I]`` and ``k_idx [S, d_I]`` (after their rotary
     and norm), ``w_idx [S, H_I]`` float32 -> the selection as an int8 mask
-    ``[S/bq, S/mk, bq, mk]``: entry ``[a, b, i, j]`` is 1 iff key
-    ``b*mk + j`` is in ``Sel(a*bq + i)``; and which ``bq x bk`` pieces of
-    it hold a selected pair, int32 ``[S/bq, S/bk]`` (reducing the mask for
-    that afterwards took 38 ms a layer on the v5e: my chip run, PR 36).
-    The kernel scores, counts and flags in pieces of ``bk`` keys; the
-    mask's own key tile ``mk`` (``mask_k``, by default :func:`mask_tile`'s:
-    2,176 at 8,704 keys, ``bk`` itself at 34,304) is the attention's to
-    run in, and only the last write knows it."""
+    ``[S/bq, ceil(S/mk), bq, mk]``: entry ``[a, b, i, j]`` is 1 iff key
+    ``b*mk + j`` is in ``Sel(a*bq + i)`` (where ``mk`` does not divide
+    ``S`` the last tile's tail, keys past the sequence, is zeros); and
+    which ``bq x bk`` pieces of it hold a selected pair, int32 ``[S/bq,
+    S/bk]`` (reducing the mask for that afterwards took 38 ms a layer on
+    the v5e: my chip run, PR 36). The kernel scores, counts and flags over
+    the ``S`` real keys in pieces of ``bk``; the mask's own key tile ``mk``
+    (``mask_k``, by default :func:`mask_tile`'s: 2,176 at 8,704 keys and,
+    sixteen tiles over 34,816, at 34,304) is the attention's to run in,
+    and only the last write knows it."""
     from jax.experimental.pallas import tpu as pltpu
 
     n_heads, s, d = q_idx.shape
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
     mk = mask_tile(s, bk) if mask_k is None else int(mask_k)
-    if s % mk:
-        raise ValueError(f"the mask's key tile {mk} does not divide the {s} keys")
-    n_qb, n_kb = s // bq, s // bk
+    n_qb, n_kb, n_mk = s // bq, s // bk, -(-s // mk)  # (the last key tile's tail: zeros)
     w = jnp.transpose(w_idx.astype(jnp.float32))[:, :, None]  # [H_I, S, 1]
     kernel = functools.partial(_select_kernel, topk=int(topk), scale=float(d) ** -0.5,
                                block_q=bq, block_k=bk, n_kb=n_kb, mask_k=mk)
@@ -260,9 +288,9 @@ def select_keys(q_idx, k_idx, w_idx, *, topk: int, block_q: int = 128, block_k: 
             pl.BlockSpec((s, d), lambda i: (0, 0)),
             pl.BlockSpec((n_heads, bq, 1), lambda i: (0, i, 0)),
         ],
-        out_specs=[pl.BlockSpec((1, s // mk, bq, mk), lambda i: (i, 0, 0, 0)),
+        out_specs=[pl.BlockSpec((1, n_mk, bq, mk), lambda i: (i, 0, 0, 0)),
                    pl.BlockSpec((1, 1, lanes), lambda i: (i, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_qb, s // mk, bq, mk), jnp.int8),
+        out_shape=[jax.ShapeDtypeStruct((n_qb, n_mk, bq, mk), jnp.int8),
                    jax.ShapeDtypeStruct((n_qb, 1, lanes), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((n_kb, bq, bk), jnp.int32)],
         compiler_params=pltpu.CompilerParams(
@@ -316,47 +344,6 @@ def live_tiles(live: jax.Array, s: int, stat_tile: int = 512) -> Tuple[jax.Array
 # masked grouped-query flash attention
 # ---------------------------------------------------------------------------
 
-def _attn_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, acc_ref,
-                 *, rep, d, block_q, block_k, n_kb):
-    qi = pl.program_id(1)
-    kb = pl.program_id(2)
-
-    @pl.when(kb == 0)
-    def _reset():
-        m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
-
-    @pl.when((qi + 1) * block_q > kb * block_k)  # the tile touches the causal part
-    def _tile():
-        # 0 where the query selected the key, NEG_INF where not (causal by
-        # construction); one tile for all the group's query heads
-        sel = mask_ref[:, 0].astype(jnp.float32).reshape(block_q, block_k)
-        bias = (sel - 1.0) * -NEG_INF
-        k, v = k_ref[...], v_ref[...]
-        for h in range(rep):  # the group's query heads share the key tile
-            rows = slice(h * block_q, (h + 1) * block_q)
-            s = jax.lax.dot_general(q_ref[:, h * d:(h + 1) * d], k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) + bias
-            m = m_ref[rows]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            # a row with nothing selected yet has m_new == NEG_INF and p == 1
-            # on its masked entries: the first selected key's alpha == 0 wipes
-            # that, and every row selects a key at or before its diagonal tile
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l_ref[rows] = l_ref[rows] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[rows] = acc_ref[rows] * alpha + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-            m_ref[rows] = m_new
-
-    @pl.when(kb == n_kb - 1)
-    def _finalize():
-        out = acc_ref[:] / l_ref[:]
-        for h in range(rep):
-            o_ref[:, h * d:(h + 1) * d] = out[h * block_q:(h + 1) * block_q].astype(o_ref.dtype)
-
-
 def _turned_tile(x, cos, sin, scale: float, dtype):
     """A query tile's rotary part ``x [rep, bq, ds]`` float32 as its product
     wrote it -> ``[rep * bq, ds]`` turned by the tile's angles (``cos, sin
@@ -398,7 +385,8 @@ def _turned_head(x, cos, sin, width: int, scale: float):
 
 
 def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared, masked,
-                   gated=False, window=None, turn=None, rotary=None, heads=1, joint=False):
+                   gated=False, window=None, turn=None, rotary=None, heads=1, joint=False,
+                   stack=False):
     rest = list(rest)
     # a BLOCK of `heads` heads, each alone in its group, is a wider block of the same arrays: with
     # `joint` keys and values are ONE block, head h's keys then its values
@@ -407,6 +395,7 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
         # rows of the two tables, and last of the scratch the turned query tile, stacked
         width, turned_by, q_scale = rotary
         (cos_q, sin_q, cos_k, sin_k), rest = rest[:4], rest[4:]
+    if rotary is not None or stack:  # last of the scratch: the query tile's heads, stacked
         stacked_ref = rest.pop()
     if shared:  # the part of the score that all heads read from ONE key
         qs_ref, ks_ref = rest.pop(0), rest.pop(0)
@@ -421,7 +410,7 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
     gi, t = pl.program_id(1), pl.program_id(2)
     qi, kb = qi_ref[t], kb_ref[t]
     rep, _, d = q_ref.shape
-    if rotary is not None or heads > 1:  # q is ONE token-major block [1, bq, heads * rep * d]
+    if rotary is not None or stack or heads > 1:  # q is ONE token-major block [1, bq, heads * rep * d]
         d = k_ref.shape[-1] // (heads * parts)
         rep = q_ref.shape[-1] // (heads * d)
     dv = v_ref.shape[-1] // (heads * parts)
@@ -455,13 +444,18 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
             for r in range(heads * rep):
                 head = _turned_head(q_ref[0, :, r * d:(r + 1) * d], cos, sin, width, turned_by)
                 stacked_ref[r * block_q:(r + 1) * block_q] = (head * q_scale).astype(stacked_ref.dtype)
+        elif stack:  # the stacking without the turn: a head's lane block becomes its rows
+            for r in range(rep):
+                stacked_ref[r * block_q:(r + 1) * block_q] = q_ref[0, :, r * d:(r + 1) * d]
 
     def update(with_diagonal, with_lower_edge=False):
         open_ = []  # where a pair counts: the selection's tile or the band's edges, ONE for every head
 
         def score(h):  # head h's value tile, and its score tile with the closed pairs at NEG_INF
             v = lanes(v_ref, h, dv, parts - 1)
-            if rotary is None:
+            if stack:
+                q, k = stacked_ref[...], k_ref[...]
+            elif rotary is None:
                 q = q_ref[...].reshape(rows, d) if heads == 1 else q_ref[0, :, h * d:(h + 1) * d]
                 k = lanes(k_ref, h, d)
             else:  # the key tile is turned at every visit (a sixth to a thirty-sixth of a score tile)
@@ -571,7 +565,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
                       out_gate=None, shared_turn=None, shared_scale: float = 1.0, turn=None,
                       turn_width: int = 0, turn_scale: float = 1.0, q_scale: float = 1.0,
                       heads: Optional[int] = None):
-    """The batched form of :func:`masked_gqa_attention`. The grid's last
+    """What :func:`masked_gqa_attention` runs. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
     even a grid step. ONE kernel body, several ways of addressing its
@@ -670,9 +664,20 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     diagonal is still visited, and a pair counts where the mask says so: the
     key tile is the mask's own (:func:`mask_tile` chose it for THIS
     kernel), the query tile the largest multiple of the mask's that divides
-    ``S`` and is at most ``block_q``: 512 x 2,176 under masks of 128 x
-    2,176 at 8,704 tokens, 44 grid steps a head. The mask's tile is read
-    once a grid step, so once a HEAD. One layer's kernel at 128 heads of
+    ``S`` and is at most ``block_q`` and whose stacked score tile fits
+    (:func:`_masked_query_tile`): 512 x 2,176 under masks of 128 x
+    2,176 at 8,704 tokens, 44 grid steps a head. Where the mask's tile does
+    not divide ``S`` (its last tile ends in zeros: :func:`mask_tile`) k and
+    v are padded with zero rows to the whole tiles — keys no query
+    selected — and the table of pairs is the real query tiles' over them.
+    Under a mask ``H/G > 1`` heads of whole lane blocks (Keye's eight a
+    group, 256 x 2,176 over 34,816 keys: 4,512 grid steps a layer) are read
+    as ONE token-major block ``[bq, H/G * d]`` and stacked head by head
+    into the ``[H/G * bq, d]`` VMEM scratch at a query tile's first key
+    step, the rotary path's stacking without its turn: 1.6 ms a layer
+    faster than XLA's head-major copy at every tile, which it spares
+    (:func:`mask_tile` has the readings). The mask's tile is read
+    once a grid step, so once a HEAD or group. One layer's kernel at 128 heads of
     128 + 64 on the v5e (my chip runs, PR 47), ms: 512 x 2,176 34.8, 256 x
     2,176 36.2, 256 x 4,352 39.4, 512 x 512 (the mask written in the
     selection's 512-wide pieces, as it was until PR 47) 43.4, 2,176 x 512
@@ -720,10 +725,14 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
     if masked:
         n_qb, n_kb, mq, bk = mask.shape
-        if b != 1 or n_qb * mq != s or n_kb * bk != s:
+        if b != 1 or n_qb * mq != s or not 0 <= n_kb * bk - s < bk:
             raise ValueError(f"a mask {mask.shape} selects the keys of one sequence of "
                              f"{n_qb * mq}: not of {b} of {s}")
-        bq = _masked_query_tile(s, block_q, mq)
+        bq = _masked_query_tile(s, block_q, mq, rep * bk)
+        if n_kb * bk > s:  # keys laid after the sequence, closed by the mask: whole key tiles
+            rows = ((0, 0), (0, n_kb * bk - s), (0, 0))
+            k, v, k_shared = (u if u is None else jnp.pad(u, rows) for u in (k, v, k_shared))
+    sk = k.shape[1]  # the keys' rows: the sequence's, or under a mask whole tiles of it
     if window is not None and (masked or window < 1):
         raise ValueError("a window is a band of at least the query's own key, and the maskless form's")
     if shared_turn is not None and not shared:
@@ -742,6 +751,10 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     def in_place(width):  # a head of whole lane blocks
         return width % 128 == 0
 
+    # a group's heads of whole lane blocks under a mask: read as ONE token-major block, stacked
+    # into the scratch at a query tile's first key step (the rotary's stacking without its turn)
+    stack = masked and rep > 1 and in_place(d) and not shared
+
     def rows_spec(width):  # of [G, H/G, B*S, width]: the batch in the rows; a block's hb heads lead
         return pl.BlockSpec((None if hb == 1 else hb, rep, bq, width),
                             lambda bi, gi, t, qi, kb: (gi, 0, bi * (s // bq) + qi[t], 0))
@@ -755,7 +768,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
                             lambda bi, gi, t, qi, kb: (bi, 0, qi[t], gi))
 
     def q_tiles(x, width):  # -> the kernel's [rep, bq, width] tile
-        if in_place(width) and (rep == 1 or turn is not None):  # (turned: stacked in the kernel)
+        if in_place(width) and (rep == 1 or turn is not None or stack):  # (stacked in the kernel)
             return x.reshape(b, 1, s, g * rep * width), place_spec(width)
         if in_place(width):
             return jnp.transpose(x.reshape(b * s, g, rep, width), (1, 2, 0, 3)), rows_spec(width)
@@ -766,14 +779,14 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
             return x, pl.BlockSpec((None, bk, hb * width * (parts if joint else 1)),
                                    lambda bi, gi, t, qi, kb: (
                                        bi, kb[t], gi if joint else gi * parts + part))
-        return (jnp.transpose(x.reshape(b, s, g, width), (0, 2, 1, 3)),
+        return (jnp.transpose(x.reshape(b, sk, g, width), (0, 2, 1, 3)),
                 pl.BlockSpec((None, None, bk, width), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0)))
 
     parts = 1
     if v is None and in_place(d):
         v, parts = k, 2  # two blocks of the one operand
     elif v is None:
-        k, v = (k.reshape(b, s, g, 2, d)[:, :, :, i].reshape(b, s, g * d) for i in (0, 1))
+        k, v = (k.reshape(b, sk, g, 2, d)[:, :, :, i].reshape(b, sk, g * d) for i in (0, 1))
     operands, in_specs = (list(u) for u in zip(
         q_tiles(q, d), kv_tiles(k, d, 0, parts), *([] if joint else [kv_tiles(v, dv, parts - 1, parts)])))
     scratch = [pltpu.VMEM((hb * rep * bq, 1), jnp.float32), pltpu.VMEM((hb * rep * bq, 1), jnp.float32),
@@ -786,6 +799,8 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         in_specs += [pl.BlockSpec((bk, d), lambda bi, gi, t, qi, kb: (bi * (s // bk) + kb[t], 0))] * 2
         scratch.append(pltpu.VMEM((hb * rep * bq, d), v.dtype))
         rotary = (int(turn_width) or d, float(turn_scale), float(q_scale))
+    elif stack:
+        scratch.append(pltpu.VMEM((rep * bq, d), q.dtype))
     if shared:
         operands += [jnp.transpose(q_shared.reshape(b * s, g, rep, ds), (1, 2, 0, 3)), k_shared]
         in_specs += [rows_spec(ds),
@@ -806,7 +821,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked,
                           gated=out_gate is not None, window=window,
                           turn=None if shared_turn is None else float(shared_scale),
-                          rotary=rotary, heads=hb, joint=joint),
+                          rotary=rotary, heads=hb, joint=joint, stack=stack),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g // hb, len(pairs)),
             in_specs=in_specs, out_specs=place_spec(dv) if in_place(dv) else major_spec(dv),
@@ -882,10 +897,14 @@ def heads_a_step(g: int, rep: int, bq: int, bk: int, d: int, dv: int, ds: int = 
     return 1
 
 
-def _masked_query_tile(s: int, block_q: int, mq: int) -> int:
+def _masked_query_tile(s: int, block_q: int, mq: int, row_keys: int) -> int:
     """The largest multiple of the mask's query tile ``mq`` that divides ``s``
-    and is at most ``block_q``."""
-    return next(t for t in range(max(min(block_q, s) // mq, 1) * mq, 0, -mq) if s % t == 0)
+    and is at most ``block_q``, and whose stacked score tile — ``row_keys``
+    float32 scores a query row: the group's heads times the key tile, as
+    :func:`causal_tiles` counts them — stays within :data:`SCORE_TILE_BYTES`
+    (the mask's own tile at the least)."""
+    most = min(block_q, s, SCORE_TILE_BYTES // (4 * row_keys))
+    return next(t for t in range(max(most // mq, 1) * mq, 0, -mq) if s % t == 0)
 
 
 def causal_steps(b: int, s: int, g: int, rep: int, d: int, dv: int, ds: int = 0, *,
@@ -899,7 +918,8 @@ def causal_steps(b: int, s: int, g: int, rep: int, d: int, dv: int, ds: int = 0,
     if mask_tiles is None:
         bq, bk = causal_tiles(s, rep, block_q, block_k, window, d if turned else 0)
     else:
-        bq, bk = _masked_query_tile(s, block_q, mask_tiles[0]), mask_tiles[1]
+        bk = mask_tiles[1]
+        bq = _masked_query_tile(s, block_q, mask_tiles[0], rep * bk)
     hb = heads_a_step(g, rep, bq, bk, d, dv, ds, masked=mask_tiles is not None, window=window,
                       turned=turned)
     pairs = len(_band_tiles(s, bq, bk, window))
@@ -955,15 +975,15 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
                          out_gate=None, shared_turn=None, shared_scale: float = 1.0, turn=None,
                          turn_width: int = 0, turn_scale: float = 1.0,
                          q_scale: float = 1.0) -> jax.Array:
-    """``q [S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [S, G*d]``,
-    ``mask`` from :func:`select_keys` -> ``o [S, H*d]``: softmax attention
-    of every query head over the keys its query selected, query head ``h``
-    reading key-value head ``h // (H/G)``. Masked-dense: every causal tile
-    is computed. ``block_q`` (a multiple of the mask's query tile, which is
-    the default) is this kernel's own query tile.
+    """``q [B, S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [B, S,
+    G*d]``, ``mask`` from :func:`select_keys` (``B`` 1: a selection is one
+    sequence's) or none -> ``o [B, S, H*d]``: causal softmax attention,
+    under a mask of every query head over the keys its query selected,
+    query head ``h`` reading key-value head ``h // (H/G)``. Masked-dense:
+    every causal tile is computed. ``block_q`` is the kernel's query tile
+    (under a mask a multiple of the mask's, which is the least).
 
-    The BATCHED form (``q [B, S, H*d]`` and ``k, v [B, S, G*d]`` -> ``[B,
-    S, H*d]``; :func:`_causal_attention`): each sequence on its own, in
+    ONE form (:func:`_causal_attention`): each sequence on its own, in
     tiles of ``block_q`` (default 256) by ``block_k``, only tiles at or
     below the diagonal visited. Without a mask it is plain causal
     attention. Operands and output are token-major HERE; what the kernel
@@ -984,9 +1004,12 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     (two float32 tables ``[B*S, ds]``, ``[cos | cos]`` and ``[sin | sin]``
     of every token's angles) ``q_shared`` is float32 and not yet turned,
     and the kernel turns each query tile of it, times ``shared_scale``,
-    rounded once to ``q``'s type. With a mask (``B``
-    1: a selection is one sequence's) it is the selection over latent
-    attention, masked-dense as the form above, in the mask's key tile.
+    rounded once to ``q``'s type. With a mask it is the selection, over
+    latent attention (a head alone in its group, a block of heads a grid
+    step) or over grouped-query heads (Keye's eight a group: the group's
+    query tile read as ONE token-major block and stacked in the kernel), in
+    the mask's key tile, k and v padded to its whole tiles where it does
+    not divide ``S``.
     With ``window`` (maskless only) a query attends to the keys ``t -
     window < j <= t`` of its sequence and the kernel visits the tiles that
     meet that band alone, under the name ``windowed_gqa_attention``. With
@@ -994,57 +1017,17 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     token's scalar (``decoder.gated``'s arithmetic, in the kernel's last
     step). The maskless form's tiles are :func:`causal_tiles`' of the two
     asked for."""
-    if mask is None or q.ndim == 3:
-        block_q = block_q or 256
-        if mask is None:
-            g = int(num_kv_heads)
-            d = k.shape[2] // (2 * g if v is None else g)
-            block_q, block_k = causal_tiles(q.shape[1], q.shape[2] // (g * d), block_q, block_k,
-                                            window, d if turn is not None else 0)
-        return _causal_attention(q, k, v, int(num_kv_heads), block_q, block_k,
-                                 _interpret(interpret), q_shared, k_shared, mask, window, out_gate,
-                                 shared_turn, shared_scale, turn, turn_width, turn_scale, q_scale)
-    if q_shared is not None or out_gate is not None or v.shape[1] != k.shape[1]:
-        raise ValueError("a shared key part, a value width of its own and an output gate are "
-                         "the batched form's")
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_qb, n_kb, mq, bk = mask.shape
-    s = q.shape[0]
-    g = int(num_kv_heads)
-    d = k.shape[1] // g
-    rep = q.shape[1] // (g * d)
-    bq = mq if block_q is None else pick_tile(s, block_q)
-    if bq % mq:
-        raise ValueError(f"the attention's query tile {bq} is no multiple of the mask's {mq}")
-    r = bq // mq
-
-    def last_live(i, j):  # a tile above the diagonal re-reads the last one below: no new copy
-        return jnp.minimum(j, ((i + 1) * bq - 1) // bk)
-
-    kernel = functools.partial(_attn_kernel, rep=rep, d=d, block_q=bq, block_k=bk, n_kb=n_kb)
-    return pl.pallas_call(
-        kernel,
-        grid=(g, s // bq, n_kb),
-        in_specs=[
-            pl.BlockSpec((bq, rep * d), lambda gi, i, j: (i, gi)),
-            pl.BlockSpec((bk, d), lambda gi, i, j: (last_live(i, j), gi)),
-            pl.BlockSpec((bk, d), lambda gi, i, j: (last_live(i, j), gi)),
-            pl.BlockSpec((r, 1, mq, bk), lambda gi, i, j: (i, last_live(i, j), 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bq, rep * d), lambda gi, i, j: (i, gi)),
-        out_shape=jax.ShapeDtypeStruct((s, rep * g * d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((rep * bq, 1), jnp.float32),
-            pltpu.VMEM((rep * bq, 1), jnp.float32),
-            pltpu.VMEM((rep * bq, d), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_interpret(interpret),
-        name="masked_gqa_attention",
-    )(q, k, v, mask)
+    if q.ndim != 3:
+        raise ValueError(f"operands are [B, S, .] (a selection's: [1, S, .]): q is {q.shape}")
+    block_q = block_q or 256
+    if mask is None:
+        g = int(num_kv_heads)
+        d = k.shape[2] // (2 * g if v is None else g)
+        block_q, block_k = causal_tiles(q.shape[1], q.shape[2] // (g * d), block_q, block_k,
+                                        window, d if turn is not None else 0)
+    return _causal_attention(q, k, v, int(num_kv_heads), block_q, block_k,
+                             _interpret(interpret), q_shared, k_shared, mask, window, out_gate,
+                             shared_turn, shared_scale, turn, turn_width, turn_scale, q_scale)
 
 
 def windowed_gqa_attention(q, k, v, *, window: int, num_kv_heads: int, block_q: Optional[int] = None,

@@ -178,16 +178,33 @@ def _keye_select():
 
 
 def _keye_attention():
-    """Masked grouped-query attention, 32 query heads on 4 key-value
-    heads of 128, query tile 256 over the selection's 128-row mask tiles."""
+    """Grouped-query attention under the selection's mask, 32 query heads
+    on 4 key-value heads of 128, as the step makes the call since PR 68:
+    the batched causal body over ONE sequence, the mask written in sixteen
+    key tiles of 2,176 over keys padded to 34,816 (``mask_tile``: no wide
+    tile divides 34,304), a query tile of 256 (eight stacked heads of 2,176
+    float32 scores a row: 17.8 MB), q read token-major and stacked in the
+    kernel. ONE Mosaic call; of the array-sized operands only k and v are
+    touched on the way in (two pads of 35 MB), q and o not at all."""
     from psana_ray_tpu.parallel import sparse_attention as sa
 
     def fn(q, k, v, mask):
         return sa.masked_gqa_attention(q, k, v, mask, num_kv_heads=4, block_q=256,
                                        interpret=False)
 
-    kv = S((KEYE_S, 512), BF16)
-    return fn, [S((KEYE_S, 4096), BF16), kv, kv, S((268, 67, 128, 512), jnp.int8)], 1
+    mask_k = sa.mask_tile(KEYE_S, 512)
+    assert mask_k == 2176 and sa.causal_steps(
+        1, KEYE_S, 4, 8, 128, 128, block_q=256, mask_tiles=(128, mask_k)) == (4512, 4512)
+
+    def pin(text):
+        entry = text[text.index("ENTRY"):]
+        assert len(re.findall(r"^\s*(?:ROOT )?%masked_gqa_attention[.\d]* = ", entry, re.M)) == 1
+        moved = _array_sized_moves(entry, KEYE_S * 4096, ("copy", "transpose", "reshape", "convert"))
+        assert not moved, moved
+        assert f"bf16[1,{16 * mask_k},512]" in entry  # k and v, padded to the mask's whole tiles
+
+    kv = S((1, KEYE_S, 512), BF16)
+    return fn, [S((1, KEYE_S, 4096), BF16), kv, kv, S((268, 16, 128, mask_k), jnp.int8)], 1, pin
 
 
 def _keye_experts():
@@ -690,12 +707,19 @@ PINNED_STEPS = {
     # ONE operand where they were two blocks of it, and the step's statistics vector ends in
     # `BLOCK_STATS` (seventeen values, the last places ONE constant of the shapes); keye's, lfm2's,
     # laguna's, granite's, nemotron3's and the looped reader's were hashed before and after and
-    # did not move: heads that share their keys, heads of 64, `_attn_kernel` and a kernel that
+    # did not move: heads that share their keys, heads of 64, keye's `_attn_kernel` (gone since PR 68) and a kernel that
     # turns q and k itself take one group a step as they did, and at one head a step the kernel's
     # body is the jaxpr it was (`tests/test_decoder_kimi.py -k traces_the_kernel`))
     "deepseek_v32_prefill_epix10k2m": "4459560881f4932af4843903dfaa127c21ecbadaa451ce1890d074e6fb249eac",
     "kimi_k2_prefill_epix10k2m": "aabd919baf99e48f437abf546ab498c3b2c100d1f38b24cf58eff093ba9c462a",
-    "keye_vl2_prefill_epix10k2m": "3019bf0c0433c3f79247bb5532f0e210e2eeec021b9b4624e0204c6a14183ab3",
+    # (keye's ALONE re-pinned in PR 68, knowingly: its four selection-attention calls leave
+    # `_attn_kernel` (deleted: ROADMAP D12) for the batched causal body under the mask — `[1, S, .]`
+    # operands, the mask `[268, 16, 128, 2176]` in a key tile that does not divide 34,304
+    # (`mask_tile`), k and v padded to 34,816 rows, a table of 1,128 causal pairs as scalar prefetch,
+    # q as ONE token-major block a group with a fourth scratch; the nine others were hashed before
+    # and after and did not move: at 8,704 tokens every rule gives what it gave, and the maskless
+    # cells take none of the changed branches)
+    "keye_vl2_prefill_epix10k2m": "e6f91a07d1ffad75878b5207c0512c178cb21d4d90873f532920c7bad818bd10",
     "lfm2_8b_a1b_prefill_epix10k2m": "2b73e2e07e5723518527f753ddf201bc63b7e4f51da5e8f5fcd63c6617add56e",
     # pinned in PR 56, both hashed on PR 55's tree first and NEITHER moved by it: laguna's runs
     # nothing of `ops/delta_rule.py`; ling3's does, and PR 56 rewrote that kernel's body (the heads
@@ -1278,6 +1302,55 @@ def test_laguna_s_grouped_heads_reach_the_kernel_where_their_products_wrote_them
     assert f"f32[{tokens},{heads},{dcfg.head_dim // 2}]" not in entry and "multiply_subtract_fusion" not in entry
     assert sum("jit(turn_tables)" in line and f"f32[{tokens},{dcfg.head_dim}]" in line.split(" fusion(")[0]
                for _, line in made.values()) == 2  # [cos | cos | 1], [-sin | sin | 0]
+
+
+def test_keye_s_selection_attention_operands_reach_the_kernel_with_two_pads_and_no_new_copy(
+        one_chip, monkeypatch):
+    """ONE attention layer of keye's (``decoder._attention``: 32 heads on 4
+    key heads of 128, the indexer's selection, one sequence of 34,304) as
+    compiled since PR 68 (``-k reach_the_kernel``'s count for this layer):
+    ONE ``masked_gqa_attention`` call on ``[1, S, .]`` operands under the
+    mask ``[268, 16, 128, 2176]``, its output the token-major ``[1, 1, S,
+    4096]`` that ``W_o``'s product reads as it is. Between ``W_q``'s product
+    and ``W_o`` what only moves ``S * 512`` elements or more is the PARENT's
+    two relayouts — XLA turns q and k with the tokens in the lanes
+    (``{0,2,1}``) and copies each row-major for the kernel, 281 + 35 MB a
+    layer (ROADMAP S13: ``_kernel_turns`` refuses a selection) — and this
+    PR's two pads of k and v to the mask's sixteen whole key tiles (35.6 MB
+    each). No head-major copy of q (the group's token-major block is stacked
+    in the kernel), nothing of o."""
+    from psana_ray_tpu.models import decoder
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, params = _decoder_cell("keye_vl2_prefill_epix10k2m")
+    seq, heads, patch = KEYE_S, dcfg.num_heads, cfg["patch"]
+    pos = decoder.frame_positions(PANELS, H // patch, W // patch, cfg["prompt_tokens"])
+    assert len(pos) == seq and cfg["batch_size"] == 1
+
+    def layer(p, x):
+        angles = decoder.rotary_angles(pos, dcfg.rope_theta, dcfg.rope_dim // 2, dcfg.mrope_section)
+        idx = decoder.rotary_angles(np.arange(seq), dcfg.rope_theta, dcfg.indexer_head_dim // 2)
+        return decoder._attention(p, x, angles, idx, 1, dcfg)[0]
+
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip),
+                        (params["layers"][1], S((seq, dcfg.hidden_size), BF16)))
+    text = jax.jit(layer).lower(*args).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    made = {m.group(1): (m.group(2), line) for line in entry.splitlines()
+            for m in [re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = \S+ ([\w\-]+)\(", line)] if m}
+    calls = [line for made_by, (_, line) in made.items() if made_by.startswith("%masked_gqa_attention")]
+    assert len(calls) == 1 and f" = bf16[1,1,{seq},{heads * dcfg.head_dim}]" in calls[0]
+    assert "s8[268,16,128,2176]" in calls[0]
+    kv = dcfg.num_kv_heads * dcfg.head_dim
+    moved = _array_sized_moves(entry, seq * kv, ("copy", "transpose", "reshape", "convert", "pad"),
+                               "/indexer/")  # (its head-major index queries: its scope's account)
+    index_halves = f"f32[{seq},{dcfg.indexer_heads},{dcfg.indexer_head_dim // 2}]"  # and their rotary
+    moved = sorted(m.split(" ", 1)[1] for m in moved if index_halves not in m)
+    assert moved == sorted([f"bf16[1,1,{seq},{heads * dcfg.head_dim}]", f"bf16[1,{seq},{kv}]",
+                            f"bf16[1,{16 * 2176},{kv}]", f"bf16[1,{16 * 2176},{kv}]"]), moved
+    # W_o's product reads what the kernel wrote
+    root = next(line for _, line in made.values() if line.lstrip().startswith("ROOT"))
+    assert "/dot_general" in root and calls[0].split(" = ")[0].strip() in root, root[:300]
 
 
 def _ling3_experts():

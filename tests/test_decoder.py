@@ -4,6 +4,7 @@ and dropless experts, against the benchmark's plain reference
 the benchmark's reader for the counters it feeds."""
 
 import dataclasses
+import functools
 import importlib
 import json
 import os
@@ -119,6 +120,92 @@ def test_selection_is_sel(case):
         t = s - 1
         best = np.flatnonzero(got[t])
         assert len(set(best % 3)) == 1 and (best == best[0] + 3 * np.arange(8)).all()
+
+
+# ---------------------------------------------------------------------------
+# (b') the attention under Sel: grouped heads of whole lane blocks, a key tile that need not divide
+# ---------------------------------------------------------------------------
+
+def _masked_grouped_call(seed, s=640, g=2, rep=4, d=128, topk=48):
+    rng = np.random.default_rng(seed)
+    h = g * rep
+    q = jnp.asarray(rng.standard_normal((1, s, h * d)), jnp.float32) * 0.1
+    k, v = (jnp.asarray(rng.standard_normal((1, s, g * d)), jnp.float32) for _ in range(2))
+    index = (jnp.asarray(rng.standard_normal((4, s, 32)), jnp.float32),
+             jnp.asarray(rng.standard_normal((s, 32)), jnp.float32),
+             jnp.asarray(rng.standard_normal((s, 4)), jnp.float32))
+    return q, k, v, functools.partial(sa.select_keys, *index, topk=topk, block_q=64, block_k=128)
+
+
+@pytest.mark.parametrize("block_q", [64, 128, 320])
+def test_a_selection_s_attention_runs_in_a_key_tile_that_does_not_divide_the_keys(block_q):
+    """Keye's call in small (PR 68): 640 keys, four heads of 128 a group,
+    the mask written in tiles of 256 — three of them, 768 keys, the last
+    128 columns keys that do not exist. The batched causal body under that
+    mask (k and v padded with zeros to the whole tiles, a group's query
+    tile read as ONE token-major block and stacked in the kernel) is the
+    dense softmax over the selected pairs, and the same call at a tile that
+    divides (128) to float32 rounding: the running softmax meets a row's
+    keys in other tiles, nothing else differs."""
+    q, k, v, select = _masked_grouped_call(68)
+    s, g, rep, d = 640, 2, 4, 128
+    mask, flags = select(mask_k=256)
+    assert mask.shape == (10, 3, 64, 256) and flags.shape == (10, 5)
+    sel = np.asarray(sa.mask_to_dense(mask))
+    assert not sel[:, s:].any()
+    sel = sel[:, :s]
+    np.testing.assert_array_equal(sel.sum(axis=1), np.minimum(np.arange(s) + 1, 48))
+    got = sa.masked_gqa_attention(q, k, v, mask, num_kv_heads=g, block_q=block_q)
+    kh, vh = (jnp.repeat(x.reshape(1, s, g, d), rep, axis=2) for x in (k, v))
+    score = jnp.einsum("bthd,bshd->bhts", q.reshape(1, s, g * rep, d), kh, precision="highest")
+    want = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(jnp.where(sel, score, -jnp.inf), -1), vh,
+                      precision="highest").reshape(1, s, -1)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    dividing = sa.masked_gqa_attention(q, k, v, select(mask_k=128)[0], num_kv_heads=g, block_q=block_q)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(dividing), atol=2e-6)
+
+
+def test_a_group_s_query_tile_is_one_token_major_block_stacked_in_the_kernel():
+    """What the call traces to where a group's heads are whole lane blocks
+    under a mask: no transpose of q (XLA's head-major copy: 0.28 GB and 1.6
+    ms a layer at keye's sizes), two pads (k and v, to the mask's whole key
+    tiles), and a kernel whose scratch holds the stacked query tile beside
+    the running maximum, sum and accumulator. Heads of 16 (no lane block)
+    keep their head-major operands and a tile that divides pads nothing."""
+    q, k, v, select = _masked_grouped_call(7)
+
+    def ops(fn, *args):
+        jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+        call = next(e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+        names = [str(e.params.get("name", e.primitive.name)) for e in jaxpr.eqns]  # (`jnp.pad` is a jit)
+        scratch = call.params["jaxpr"].invars[-call.params["grid_mapping"].num_scratch_operands:]
+        return names.count("transpose"), names.count("_pad"), [tuple(v.aval.shape) for v in scratch]
+
+    attend = functools.partial(sa.masked_gqa_attention, num_kv_heads=2, block_q=128)
+    assert ops(attend, q, k, v, select(mask_k=256)[0]) == (
+        0, 2, [(512, 1), (512, 1), (512, 128), (512, 128)])
+    assert ops(attend, q, k, v, select(mask_k=128)[0])[:2] == (0, 0)
+    narrow = (q[..., :128], k[..., :32], v[..., :32])  # eight heads of 16 on two
+    transposes, pads, scratch = ops(attend, *narrow, select(mask_k=256)[0])
+    assert transposes == 4 and pads == 2 and len(scratch) == 3
+
+
+def test_keye_s_call_takes_the_pair_table_s_grid_steps():
+    """``causal_steps`` at keye's shapes: 34,304 queries in tiles of 256 (eight
+    stacked heads of 2,176 float32 scores a row: 17.8 MB of the 20 a score
+    tile may take; 512 rows would be 35.6) against sixteen key tiles of
+    2,176 — 1,128 pairs at or below the diagonal a group, 4,512 grid steps a
+    layer where the rectangular grid took 4 x 134 x 67 = 35,912."""
+    s = 34304
+    tiles = (sa.pick_tile(s, 128), sa.mask_tile(s, sa.pick_tile(s, 512)))
+    assert tiles == (128, 2176)
+    pairs = len(sa._band_tiles(s, 256, 2176))
+    assert pairs == 1128 == sum(-(-(i + 1) * 256 // 2176) for i in range(s // 256))
+    assert sa.causal_steps(1, s, 4, 8, 128, 128, block_q=256, mask_tiles=tiles) == (4 * pairs,) * 2
+    assert sa.causal_steps(1, s, 4, 8, 128, 128, block_q=512, mask_tiles=tiles) == (4 * pairs,) * 2
+    assert sa._masked_query_tile(s, 512, 128, 8 * 2176) == 256  # what fits, not what was asked
+    assert sa._masked_query_tile(s, 512, 128, 8 * 1088) == 512
+    assert sa._masked_query_tile(8704, 1088, 128, 2176) == 512  # dsv32's, a head alone: as it was
 
 
 # ---------------------------------------------------------------------------

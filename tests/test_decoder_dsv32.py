@@ -228,12 +228,64 @@ def test_the_wide_mask_is_the_same_sel_bit_for_bit_and_the_flags_stay_the_pieces
 
 @pytest.mark.parametrize("s,pieces,want", [
     (8704, 512, 2176),    # 68 lane blocks: 17 of them, the widest under the limit
-    (34304, 512, 512),    # 268 = 4 x 67 lane blocks: keye's pieces are its mask's tile, as they were
+    # 268 = 4 x 67 lane blocks: the widest that divides is the pieces' 512, under half the limit, so
+    # the tile does NOT divide: sixteen of 2,176 over keys padded to 34,816 (PR 68)
+    (34304, 512, 2176),
     (17408, 512, 2176), (4352, 512, 2176), (2048, 512, 2048), (8704, 128, 2176),
     (96, 32, 32), (64, 32, 32), (272, 16, 16),  # no whole lane block: the pieces' own
 ])
 def test_the_mask_s_key_tile_is_one_rule_of_the_shape(s, pieces, want):
-    assert sa.mask_tile(s, pieces) == want and s % want == 0
+    assert sa.mask_tile(s, pieces) == want
+    if s == 34304:
+        assert -(-s // want) * want == 34816 and sa.pick_tile(s, sa.MASK_TILE) == pieces
+    else:
+        assert s % want == 0
+
+
+@pytest.mark.parametrize("s,want", [(2432, 1280), (2560, 1280), (6528, 2176), (4480, 1536),
+                                    (1280, 1280), (2176, 2176)])
+def test_a_tile_that_does_not_divide_pads_by_less_than_a_lane_block_a_tile(s, want):
+    """Where no wide tile divides, the rule takes the narrowest whole number
+    of lane blocks that covers ``s`` in as many tiles as the limit would
+    (2,432 = 19 lane blocks: two tiles of 10, not one of 17 and a second
+    nearly empty); a sequence under the limit is one tile as it was."""
+    got = sa.mask_tile(s, 512)
+    tiles = -(-s // got)
+    assert got == want and got % 128 == 0 and tiles * got - s < 128 * tiles
+    assert tiles == -(-s // sa.MASK_TILE) or s % got == 0
+
+
+@pytest.mark.parametrize("case", ["random_scores", "tied_scores", "within_topk_is_dense"])
+def test_a_mask_written_in_a_tile_that_does_not_divide_ends_in_zeros(case):
+    """34,304's case in small: 256 keys scored, counted and flagged in
+    sixteen pieces of 16, the mask WRITTEN in key tiles of 68 that do not
+    divide them — four tiles, 272 keys, the last tile's last sixteen
+    columns keys that do not exist (and every fifth piece ends in the next
+    tile). Cropped to ``[S, S]`` the dense mask is the piece-wide layout's
+    and the reference's ``Sel`` bit for bit, the tail is zeros, and the
+    flags (the pieces' own, over the real keys) are the same array."""
+    rng = np.random.default_rng(68)
+    s, heads, d = 256, 4, 16
+    topk = {"random_scores": 24, "tied_scores": 24, "within_topk_is_dense": 512}[case]
+    q = jnp.asarray(rng.standard_normal((heads, s, d)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((s, d)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((s, heads)), jnp.float32)
+    if case == "tied_scores":
+        k, w = k[jnp.arange(s) % 5], jnp.abs(w)
+    narrow, flags = sa.select_keys(q, k, w, topk=topk, block_q=8, block_k=16, mask_k=16)
+    wide, wide_flags = sa.select_keys(q, k, w, topk=topk, block_q=8, block_k=16, mask_k=68)
+    assert narrow.shape == (32, 16, 8, 16) and wide.shape == (32, 4, 8, 68)
+    assert flags.shape == wide_flags.shape == (32, 16)
+    got = np.asarray(sa.mask_to_dense(wide))
+    assert got.shape == (s, 272) and not got[:, s:].any()
+    np.testing.assert_array_equal(got[:, :s], np.asarray(sa.mask_to_dense(narrow)))
+    dots = jnp.einsum("htd,sd->ths", q, k, precision="highest")
+    scores = jnp.sum(w[:, :, None] * jax.nn.relu(dots), axis=1) / np.sqrt(d)
+    np.testing.assert_array_equal(got[:, :s], np.asarray(ref_select(scores, jnp.arange(s), topk)))
+    np.testing.assert_array_equal(got.sum(axis=1), np.minimum(np.arange(s) + 1, topk))
+    np.testing.assert_array_equal(np.asarray(flags), np.asarray(wide_flags))
+    assert [int(x) for x in sa.live_tiles(wide_flags, s, stat_tile=16)] == [
+        int(x) for x in sa.live_tiles(flags, s, stat_tile=16)]
 
 
 def test_the_two_indexers_differ_by_fields_and_keye_s_are_the_defaults():
@@ -346,8 +398,11 @@ def test_a_mask_is_one_sequence_s_and_the_plain_form_keeps_its_own_shapes():
     with pytest.raises(ValueError, match="one sequence"):  # a mask of another length
         sa.masked_gqa_attention(z[:1], z[:1], z[:1], jnp.ones((1, 1, 16, 16), jnp.int8),
                                 num_kv_heads=2)
-    with pytest.raises(ValueError, match="batched form"):  # [S, H*d] operands: the grouped-query form
-        sa.masked_gqa_attention(z[0], z[0], z[0, :, :16], mask, num_kv_heads=2)
+    with pytest.raises(ValueError, match=r"\[B, S, \.\]"):  # [S, H*d] operands: no form takes them
+        sa.masked_gqa_attention(z[0], z[0], z[0], mask, num_kv_heads=2)
+    with pytest.raises(ValueError, match="one sequence"):  # a key tile too many past the sequence
+        sa.masked_gqa_attention(z[:1], z[:1], z[:1], jnp.ones((2, 3, 16, 16), jnp.int8),
+                                num_kv_heads=2)
 
 
 # ---------------------------------------------------------------------------

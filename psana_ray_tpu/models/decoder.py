@@ -8,7 +8,8 @@ decoder of a language model from the keys of its published
 block as Kimi-K2 spells it, DeepSeek-V3.2's, Ling-3.0's hybrid,
 Laguna-S-2.1's windowed and full layers, Granite-4.0-H's state-space hybrid,
 Ouro's looped stack, Nemotron-H's layers of one block each, Olmo-Hybrid's
-Gated DeltaNet layers under the reordered norm):
+Gated DeltaNet layers under the reordered norm, MiniCPM-SALA's block-sparse
+and fixed-decay linear layers under MiniCPM's three multipliers):
 
     x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
     x  -> x + ff(rms(x))                      five kinds, its ff one of two
@@ -33,7 +34,9 @@ on the output); or a gated short convolution (:func:`gated_short_conv`);
 or LINEAR attention (:func:`linear_attention`: the gated delta rule with a
 decay per channel, or with ONE decay a head over keys and values of their own
 widths, a float32 state a head carried along the sequence:
-``ops/delta_rule.py``); or a STATE-SPACE layer (:func:`state_space`:
+``ops/delta_rule.py``; or, with no delta rule at all, :func:`fixed_decay_attention`:
+a decay that is a CONSTANT of the head, q and k normed a head and turned by
+a rotary inside the kernel: ``ops/lightning.py``); or a STATE-SPACE layer (:func:`state_space`:
 Mamba-2's selective scan, one scalar decay a head and token, keys and
 queries shared by all heads or by the heads of each of ``ssm_groups`` groups,
 the gated norm by those groups, a float32 state a head carried along the
@@ -48,7 +51,12 @@ highest (``parallel/sparse_attention.py``; :func:`_indexer`): its queries
 are projected from the layer's normed input or, under latent attention,
 from the query's normed low rank; its one key goes through an RMS norm
 or a LayerNorm, and the rotary turns all of an index vector or its
-leading part (fields, with the first indexer's values as defaults). Its
+leading part (fields, with the first indexer's values as defaults); or,
+WITHOUT an indexer, grouped-query attention restricted a KEY HEAD to the
+blocks of keys its own queries score highest against the mean-pooled keys
+(``block_select``: ``sparse_attention.select_blocks``, in sequences longer
+than its ``dense_len``), under an elementwise sigmoid gate where the
+configuration says so. Its
 FEED-FORWARD is a dense MLP (the first ``num_dense_layers``, or all where
 there are no experts; gated SiLU, or under ``mlp_act`` ``relu2`` the UNGATED
 ``relu(x W_up)^2 W_down``, as the experts and the shared expert then are:
@@ -90,6 +98,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from psana_ray_tpu.ops import lightning
 from psana_ray_tpu.ops.delta_rule import (CHUNK, HEAD_CHUNK, chunk_rows, gated_delta_net,
                                           gated_delta_rule, lanes_a_head)
 from psana_ray_tpu.ops.short_conv import gated_conv_taps
@@ -159,6 +168,9 @@ LATENT = "latent_attention"  # what an ATTENTION layer is under a kv_lora_rank
 EXPERTS, DENSE = "moe", "mlp"
 # a `hybrid_override_pattern`'s letters (nemotron_h's spelling of a schedule of single blocks)
 PATTERN = {"M": MAMBA, "*": ATTENTION, "E": EXPERTS, "-": DENSE}
+# a `mixer_types`' words (minicpm_sala's spelling of a schedule): grouped-query attention under the
+# file's block selection, and linear attention with a fixed decay a head
+MIXERS = {"minicpm4": ATTENTION, "lightning-attn": LINEAR}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -242,6 +254,11 @@ class DecoderConfig:
     linear_value_dim: int = 0
     linear_decay: str = "channel"
     linear_beta_scale: float = 1.0
+    # and its THIRD ("fixed"): no delta rule at all, S_t = lambda_h S_{t-1} + k_t v_t^T with a decay that
+    # is a CONSTANT of the head (Lightning Attention-2's slopes: `ops/lightning.decay_slopes`), q and k
+    # normed a head and, under `linear_rotary`, turned by the plain rotary at rope_theta over the whole
+    # head; the output normed a head and THEN gated by a sigmoid (`ops/lightning.py`)
+    linear_rotary: bool = False
     # a MAMBA layer's scan: ssm_heads heads of ssm_head_dim channels over a state ssm_state wide,
     # B and C shared by the heads of each of ssm_groups groups of consecutive heads, which are also
     # the groups the gated norm goes by; conv_bias: its convolution adds a bias per channel
@@ -288,7 +305,8 @@ class DecoderConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
-    # "head_wise": either attention's output times sigmoid(a W_G), one scalar a head and token
+    # "head_wise": either attention's output times sigmoid(a W_G), one scalar a head and token;
+    # "elementwise": grouped-query attention's times sigmoid(a W_G) [T, H * head_dim], a scalar a column
     attn_gate: str = ""
     # learned sparse attention (None: plain causal attention). The index queries are projected
     # from the layer's normed input, or from the normed query rank where attention is latent
@@ -297,6 +315,10 @@ class DecoderConfig:
     topk: int = 0
     indexer_rope_dim: int = 0  # leading components of an index vector the rotary turns (0: all)
     indexer_key_norm: str = "rms"  # of the one index key: an RMS norm, or "layer" (gain and bias)
+    # a selection WITHOUT an indexer (None: none): grouped-query attention restricted, a KEY HEAD, to the
+    # blocks of keys its own queries score highest against the mean-pooled keys, in sequences longer
+    # than its dense_len (`sparse_attention.select_blocks`; nothing is drawn for it)
+    block_select: Optional[sa.BlockSelection] = None
     # the computation's tiles; no effect on the mathematics. Measured on the
     # v5e at 34,304 tokens: selection 36 ms a layer at 128 queries against 67
     # at 256 (512 needs 70 MB of VMEM for a tile's score row); attention under
@@ -393,8 +415,9 @@ class DecoderConfig:
 
     @property
     def counts_pairs(self) -> bool:
-        """The step counts :data:`PAIR_STATS`: a selection's kept pairs, or a band's."""
-        return self.selects_over_latent or self.has_window
+        """The step counts :data:`PAIR_STATS`: a selection's kept pairs (of
+        keys over latent attention, of blocks a key head), or a band's."""
+        return self.selects_over_latent or self.has_window or self.block_select is not None
 
     @property
     def rows_go_ahead(self) -> bool:
@@ -438,7 +461,7 @@ class DecoderConfig:
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Ten spellings are read:
+        ``experts_held`` and ``tie_embedding``. Eleven spellings are read:
         Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
         ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
         (``norm_eps``, ``layer_types``, ``num_dense_layers``,
@@ -515,7 +538,22 @@ class DecoderConfig:
         as that family's mark, as ``total_ut_steps`` is the looped stack's,
         so a file with them whose three head counts differ or whose
         ``rope_theta`` is not null — another model that spells its linear
-        layers so — is refused and never given this block). Where a
+        layers so — is refused and never given this block); and
+        MiniCPM-SALA's (``minicpm_sala``: ``mixer_types``, a word a layer,
+        ``minicpm4`` grouped-query attention under a selection of BLOCKS a
+        key head with no indexer (its sizes the file's ``sparse_config`` or,
+        where it has none, MiniCPM4's published ones), ``lightning-attn``
+        linear attention with a FIXED decay a head (``lightning_nh`` =
+        ``lightning_nkv`` = the attention's heads, ``lightning_head_dim``,
+        ``lightning_use_rope``, with ``qk_norm``, ``use_output_norm`` and
+        ``use_output_gate`` all true: a norm a head on q and k, on the
+        LINEAR layers alone, the output normed a head and then gated);
+        ``attn_use_rope``; ``attn_use_output_gate``: an elementwise sigmoid
+        gate; MiniCPM's three multipliers, ``scale_emb`` on the embedded
+        rows, ``scale_depth`` over the root of the PUBLISHED depth on every
+        branch (``published.num_hidden_layers`` where the file is a cut) and
+        ``hidden_size / dim_model_base`` under the logits; a word it does not
+        know, or a count that is not the layers', is refused). Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -541,6 +579,28 @@ class DecoderConfig:
                 raise ValueError(f"hybrid_override_pattern {pattern!r} does not spell {n_layers} "
                                  f"layers' blocks, each one of {''.join(PATTERN)}")
             layer_types = tuple(PATTERN[letter] for letter in pattern[:n_layers])
+        mixers = m.get("mixer_types")  # MiniCPM-SALA's: a word a layer
+        mixed = {}  # what that spelling sets beside the schedule
+        if mixers is not None:
+            if len(mixers) != n_layers or set(mixers) - set(MIXERS):
+                raise ValueError(f"mixer_types {list(mixers)} does not spell {n_layers} layers' "
+                                 f"operators, each one of {sorted(MIXERS)}")
+            layer_types = tuple(MIXERS[word] for word in mixers)
+            if (int(m["lightning_nh"]) != heads or int(m["lightning_nkv"]) != heads
+                    or m.get("lightning_scale", "1/sqrt(d)") != "1/sqrt(d)"
+                    or not (m.get("qk_norm") and m.get("use_output_norm") and m.get("use_output_gate"))):
+                raise ValueError("linear layers whose heads are not the attention's, whose scale is "
+                                 "not 1/sqrt(d) or that lack the q / k norm, the output's norm or "
+                                 "its gate are not built")
+            depth = int((m.get("published") or {}).get("num_hidden_layers", n_layers))
+            mixed = dict(residual_multiplier=float(m.get("scale_depth", 1.0)) / depth ** 0.5,
+                         embedding_multiplier=float(m.get("scale_emb", 1.0)),
+                         logits_scaling=int(m["hidden_size"]) / float(m.get("dim_model_base",
+                                                                            m["hidden_size"])),
+                         block_select=sa.BlockSelection(**{k: int(v) for k, v in (
+                             m.get("sparse_config") or {}).items()}),
+                         linear_decay="fixed", linear_rotary=bool(m.get("lightning_use_rope")),
+                         linear_chunk=lightning.CHUNK)
         ssm = {}
         if "mamba_n_heads" in m:  # Mamba-2's state-space layers, as Granite-4.0-H spells them
             ssm = dict(ssm_heads=int(m["mamba_n_heads"]), ssm_head_dim=int(m["mamba_d_head"]),
@@ -586,7 +646,7 @@ class DecoderConfig:
                    linear_chunk=HEAD_CHUNK, sandwich=True, pre_norm=False,
                    qk_norm_span="projection") if delta_net else {}
         qk_norm = delta_net or (scale is None and "rope_parameters" not in m and not loop
-                                and not single)
+                                and not single and mixers is None)  # (mixers: the LINEAR layers' alone)
         if layer_types and (len(layer_types) != n_layers
                             or set(layer_types) - {ATTENTION, SLIDING, CONV, LINEAR}
                             - ({MAMBA} if ssm else set())
@@ -647,7 +707,9 @@ class DecoderConfig:
         else:  # Laguna's spelling, on grouped-query attention
             gate = str(m.get("gating") or "")
             gate = "head_wise" if gate == "per-head" else gate
-        if gate not in ("", "head_wise"):
+        if mixers is not None and m.get("attn_use_output_gate"):
+            gate = "elementwise"
+        if gate not in ("", "head_wise", "elementwise"):
             raise ValueError(f"an output gate of granularity {gate!r} is not built")
         dense_only = sorted(int(i) for i in m.get("mlp_only_layers", ()))
         if dense_only != list(range(len(dense_only))):
@@ -671,16 +733,19 @@ class DecoderConfig:
             mrope_section=tuple(int(v) for v in mrope) if mrope else None,
             layer_types=layer_types, **window, **ssm, qk_norm=qk_norm,
             single_block=single, mlp_act=act,
-            residual_multiplier=float(m.get("residual_multiplier", 1.0)),
-            embedding_multiplier=float(m.get("embedding_multiplier", 1.0)),
+            **{"residual_multiplier": float(m.get("residual_multiplier", 1.0)),
+               "embedding_multiplier": float(m.get("embedding_multiplier", 1.0)),
+               "logits_scaling": float(m.get("logits_scaling", 1.0)), **mixed},
             attention_multiplier=None if scale is None else float(scale),
-            logits_scaling=float(m.get("logits_scaling", 1.0)),
             rotary=(m.get("position_embedding_type") != "nope" and not single
-                    and (flat is None or flat.get("rope_theta") is not None)),
+                    and (flat is None or flat.get("rope_theta") is not None)
+                    and bool(m.get("attn_use_rope", True))),
             conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size", m.get(
                 "mamba_d_conv", m.get("conv_kernel", m.get("linear_conv_kernel_dim", 3)))))),
-            linear_head_dim=int(m["linear_key_head_dim" if delta_net else "head_dim"]) if linear else 0,
-            linear_decay_floor=float(m["kda_lower_bound"]) if linear and not delta_net else 0.0,
+            linear_head_dim=int(m["linear_key_head_dim" if delta_net else "lightning_head_dim"
+                                  if mixers is not None else "head_dim"]) if linear else 0,
+            linear_decay_floor=(float(m["kda_lower_bound"])
+                                if linear and not delta_net and mixers is None else 0.0),
             **net,
             tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
             **loop,
@@ -764,6 +829,13 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                  "a_log": jnp.log(between(1.0, 16.0, cfg.num_heads)),
                  "w_beta": w(d, cfg.num_heads), "w_z": w(d, values),
                  "o_norm": gain(cfg.linear_value_dim), "wo": w(values, d)}
+        elif op == LINEAR and cfg.linear_decay == "fixed":
+            wide, dl = cfg.num_heads * cfg.linear_head_dim, cfg.linear_head_dim
+            # no decay, no step size, no taps: five matrices and three gains of a head's width (q and
+            # k leave their norms at RMS 1 a column: a score's standard deviation is 1 under d ** -0.5)
+            p = {"norm1": gain(d), "w_q": w(d, wide), "w_k": w(d, wide), "w_v": w(d, wide),
+                 "q_norm": gain(dl), "k_norm": gain(dl), "w_z": w(d, wide), "o_norm": gain(dl),
+                 "wo": w(wide, d)}
         elif op == LINEAR:
             wide = cfg.num_heads * cfg.linear_head_dim
             # taps of order 1, so that the SiLU is not in its linear part; the decay's A and b
@@ -820,8 +892,8 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 whole = cfg.qk_norm_span == "projection"
                 p.update(q_norm=gain(heads * hd if whole else hd),
                          k_norm=gain(cfg.num_kv_heads * hd if whole else hd))
-            if cfg.attn_gate:
-                p["w_attn_gate"] = w(d, heads)
+            if cfg.attn_gate:  # a scalar a head, or one a column of the output
+                p["w_attn_gate"] = w(d, heads * hd if cfg.attn_gate == "elementwise" else heads)
             if cfg.indexer_heads:
                 p.update(_index_params(cfg, w, gain, d))
         if not cfg.pre_norm:  # nothing norms a branch's input: only what a block has is drawn
@@ -926,7 +998,8 @@ def _kernel_turns(cfg: DecoderConfig, angles) -> bool:
     selection's mask). Laguna's two kinds of layer and the looped reader's;
     LFM2's heads of 64, keye's selection and granite (no rotary) are turned,
     or not, by :func:`_projections`."""
-    return angles is not None and cfg.head_dim % 128 == 0 and not cfg.indexer_heads
+    return (angles is not None and cfg.head_dim % 128 == 0 and not cfg.indexer_heads
+            and cfg.block_select is None)
 
 
 def _rotary_scales(cfg: DecoderConfig, windowed: bool) -> Tuple[float, float]:
@@ -979,7 +1052,9 @@ def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
     v = _mm(a, p["wv"])
     q, k = (u.reshape(s, -1) if kept else u.reshape(s, -1).astype(dt) for u in (q, k))
     out = (a, q, k, v.astype(dt))
-    if cfg.attn_gate:
+    if cfg.attn_gate == "elementwise":  # the gate's PRE-activation [T, H * head_dim], as wide as o:
+        out += (_mm(a, p["w_attn_gate"]).astype(dt),)  # its sigmoid rides in W_o's operand
+    elif cfg.attn_gate:
         out += (jax.nn.sigmoid(_mm(a, p["w_attn_gate"])),)
     return out
 
@@ -1260,6 +1335,41 @@ def _lanes_conv_silu(u, taps_w, seq_len: int, heads: int):
     return conv_silu(u, lanes_a_head(taps_w.T, heads).T, seq_len)
 
 
+def _fixed_decay_projections(p, x, cfg: DecoderConfig):
+    """``x [T, D]`` -> ``(q, k [T, H*d]`` FLOAT32, as ``W_q``'s and ``W_k``'s
+    products wrote them: the kernel norms, turns and rounds them;
+    ``v [T, H*d]``, ``z [T, H*d]`` (the output gate's pre-activation))."""
+    dt = p["w_q"].dtype
+    a = rms_norm(x, p["norm1"], cfg.rms_eps).astype(dt)
+    return _mm(a, p["w_q"]), _mm(a, p["w_k"]), _mm(a, p["w_v"]).astype(dt), _mm(a, p["w_z"]).astype(dt)
+
+
+def fixed_decay_attention(p, x, angles, batch: int, cfg: DecoderConfig):
+    """Linear attention with a decay that is a CONSTANT of the head
+    (Lightning Attention-2, as MiniCPM-SALA's ``lightning-attn`` layers have
+    it) on ``x [B*S, D]`` -> ``x + residual_multiplier * Op``: with ``a =
+    rms(x)``, ``q, k, v = a W_q, a W_k, a W_v``; per head ``q`` and ``k``
+    RMS-normed over the head's columns (a gain each) and, where the layer has
+    a rotary (``angles [B*S, pairs]``), turned over the whole head; ``S_t =
+    lambda_h S_{t-1} + k_t v_t^T`` (float32, 0 at every sequence's start),
+    ``o_t = d^-1/2 S_t^T q_t``; the output normed per head and THEN gated by
+    ``sigmoid(a W_z)``; then ``W_o``. Under the scopes ``proj`` (the norm, the
+    four products, the rotary's two tables, ``W_o``) and ``lightning`` (the
+    two norms, the turn, the recurrence, the output's norm and gate: ONE
+    kernel, ``ops/lightning.lightning_attention``)."""
+    s, d = x.shape[0] // batch, cfg.linear_head_dim
+    with jax.named_scope("proj"):
+        q, k, v, z = jax.jit(_fixed_decay_projections, static_argnums=2)(p, x, cfg)
+        turn = None if angles is None else jax.jit(turn_tables, static_argnums=1)(angles, d)
+    with jax.named_scope("lightning"):
+        o = lightning.lightning_attention(
+            q, k, v, z, jnp.asarray(lightning.decay_slopes(cfg.num_heads)), p["q_norm"], p["k_norm"],
+            p["o_norm"], turn, seq_len=s, heads=cfg.num_heads, eps=cfg.rms_eps, scale=d ** -0.5,
+            chunk=cfg.linear_chunk)
+    with jax.named_scope("proj"):
+        return jax.jit(_onto, static_argnums=3)(x, o, p["wo"], cfg.residual_multiplier)
+
+
 def linear_attention(p, x, batch: int, cfg: DecoderConfig):
     """Linear attention by the gated delta rule on ``x [B*S, D]`` -> ``x +
     Op`` (the branch normed before it is added where the block has a norm
@@ -1318,6 +1428,15 @@ def _onto(x, o, wo, by: float):
     rides in the product's epilogue; the sum is float32, and stays so where
     the stream is (``DecoderConfig.stream_dtype``)."""
     return (x + by * _mm(o, wo)).astype(x.dtype)
+
+
+def _gated_onto(x, o, z, wo, by: float):
+    """``x + by * ((o * sigmoid(z)) W_o)``: an attention branch under an
+    ELEMENTWISE output gate (``z [T, H * head_dim]``, its pre-activation), the
+    gate in ``W_o``'s operand (float32, rounded once) and the residual
+    multiplier in its epilogue."""
+    y = (o.astype(jnp.float32) * jax.nn.sigmoid(z.astype(jnp.float32))).astype(wo.dtype)
+    return (x + by * _mm(y, wo)).astype(x.dtype)
 
 
 def _ssm_projections(p, x, cfg: DecoderConfig):
@@ -1414,6 +1533,8 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
     with jax.named_scope("proj"):
         a, q, k, v, *gate = jax.jit(_projections, static_argnums=(3, 4))(
             p, x, angles, cfg, *((True,) if window else ()))
+    # a selection of blocks is a LONG sequence's: one of at most dense_len tokens attends densely
+    blocks = cfg.block_select if cfg.block_select and cfg.block_select.selects(s) else None
     if cfg.indexer_heads:
         if batch != 1:
             raise ValueError(f"a learned key selection is per sequence: batch {batch} is not 1")
@@ -1424,14 +1545,28 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
             o = jax.jit(sa.masked_gqa_attention, static_argnames=("num_kv_heads", "block_q"))(
                 *(u[None] for u in (q, k, v)), mask, num_kv_heads=cfg.num_kv_heads,
                 block_q=max(cfg.attn_q_tile, mask.shape[2]))[0]
+    elif blocks is not None:  # no indexer: the attention's own q and k select, a key head at a time
+        if batch != 1:
+            raise ValueError(f"a selection of blocks is per sequence: batch {batch} is not 1")
+        with jax.named_scope("block_select"):  # pooling, scores, search: a call of its own
+            flags, pieces = jax.jit(sa.select_blocks, static_argnames=(
+                "num_kv_heads", "selection", "block_q"))(
+                q, k, num_kv_heads=cfg.num_kv_heads, selection=blocks, block_q=cfg.q_tile)
+            counted = [sa.live_tiles(pieces[g], s) for g in range(cfg.num_kv_heads)]
+            live, causal = sum(l for l, _ in counted), sum(c for _, c in counted)  # over the key heads
+        with jax.named_scope("sparse_attn"):
+            o = jax.jit(sa.masked_gqa_attention, static_argnames=(
+                "num_kv_heads", "block_q", "mask_blocks"))(
+                *(u[None] for u in (q, k, v)), flags, num_kv_heads=cfg.num_kv_heads,
+                block_q=cfg.attn_q_tile, mask_blocks=blocks)[0]
     else:
         live = causal = batch * sa.causal_tile_count(s)  # every earlier key is attended
         attend, band = sa.masked_gqa_attention, {}
         if window:  # the same kernel under its own name (a trace tells the two kinds of layer apart)
             attend, band = sa.windowed_gqa_attention, {"window": window}
             live = batch * sa.band_tile_count(s, window)
-        if gate:  # applied where the kernel writes o: nothing is left for `gated` below
-            band["out_gate"] = gate.pop().reshape(batch, s, -1)
+        if gate and cfg.attn_gate == "head_wise":  # applied where the kernel writes o: nothing is
+            band["out_gate"] = gate.pop().reshape(batch, s, -1)  # left for `gated` below
         if _kernel_turns(cfg, angles):  # q and k came float32 and unturned: the kernel's to turn
             with jax.named_scope("proj"):  # every layer's of a type: the step's ONE pair
                 band["turn"] = jax.jit(turn_tables, static_argnums=1)(angles, cfg.head_dim)
@@ -1444,7 +1579,9 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
                 block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile,
                 **band).reshape(x.shape[0], -1)
     with jax.named_scope("proj"):
-        if gate:
+        if gate and cfg.attn_gate == "elementwise":
+            x = jax.jit(_gated_onto, static_argnums=4)(x, o, gate[0], p["wo"], cfg.residual_multiplier)
+        elif gate:
             x = jax.jit(gated)(x, o, gate[0], p["wo"])
         elif cfg.sandwich:
             x = jax.jit(_onto_normed, static_argnums=4)(x, o, p["wo"], p["norm1_post"], cfg.rms_eps)
@@ -1480,6 +1617,8 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     if op == CONV:
         with jax.named_scope("conv"):
             x = jax.jit(gated_short_conv, static_argnums=(2, 3))(p, x, batch, cfg)
+    elif op == LINEAR and cfg.linear_decay == "fixed":  # (its rotary's angles, where it has one)
+        x = fixed_decay_attention(p, x, angles if cfg.linear_rotary else None, batch, cfg)
     elif op == LINEAR:
         x = linear_attention(p, x, batch, cfg)
     elif op == MAMBA:
@@ -1537,17 +1676,24 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
         # a selection keeps min(t + 1, topk) of a query's keys, a band min(t + 1, window): the sum
         # over a sequence's queries, in the layers that select or slide
         s = x.shape[0] // batch
-        width = cfg.topk if cfg.selects_over_latent else cfg.sliding_window
-        kept = min(s, width)
-        through = batch * (cfg.selects_over_latent or op == SLIDING)
-        stats += [jnp.float32(through * (kept * (kept + 1) // 2 + (s - kept) * width)),
-                  jnp.float32(through * (s * (s + 1) // 2))]
+        if cfg.block_select:  # a key head's blocks, the diagonal one cut at the query: over the key
+            # heads of a layer that selects (all of a short sequence's pairs: it attends densely)
+            through = batch * cfg.num_kv_heads * (op == ATTENTION)
+            selected = cfg.block_select.pairs(s) if cfg.block_select.selects(s) else s * (s + 1) // 2
+        else:
+            width = cfg.topk if cfg.selects_over_latent else cfg.sliding_window
+            kept = min(s, width)
+            through = batch * (cfg.selects_over_latent or op == SLIDING)
+            selected = kept * (kept + 1) // 2 + (s - kept) * width
+        stats += [jnp.float32(through * selected), jnp.float32(through * (s * (s + 1) // 2))]
     if cfg.has_linear:
         s = x.shape[0] // batch
         if not cfg.counts_pairs:  # PAIR_STATS' places: nothing selects
             stats += [jnp.float32(0), jnp.float32(0)]
         chunks = 0  # the layer's kernel's head-sequences x its chunks: the delta rule's, or the scan's
-        if op == LINEAR:
+        if op == LINEAR and cfg.linear_decay == "fixed":
+            chunks = batch * cfg.num_heads * (s // lightning.step_rows(s, cfg.linear_chunk)[1])
+        elif op == LINEAR:
             chunks = batch * cfg.num_heads * (s // chunk_rows(s, cfg.linear_chunk))
         elif op == MAMBA:
             chunks = batch * cfg.ssm_heads * (s // scan_rows(s))
@@ -1611,6 +1757,8 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
     # the rotary is the layer type's: a second table where SLIDING layers have their own
     by_op = {SLIDING: rotary_angles(pos, cfg.sliding_rope_theta or cfg.rope_theta,
                                     cfg.head_dim // 2)} if cfg.has_window else {}
+    if cfg.linear_rotary:  # the LINEAR layers' own: plain, over the whole of their heads
+        by_op[LINEAR] = rotary_angles(pos, cfg.rope_theta, cfg.linear_head_dim // 2)
     if batch != 1:
         angles = angles if angles is None else jnp.tile(angles, (batch, 1))
         by_op = {op: jnp.tile(table, (batch, 1)) for op, table in by_op.items()}

@@ -72,11 +72,13 @@ Off the TPU the kernels run in Pallas interpret mode (tests, rehearsals).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
@@ -100,6 +102,64 @@ BLOCK_HEADS = (8, 4, 2)  # the heads a grid step may take where each is alone in
 UNROLLED_SCORE_BYTES = 36_000_000
 MASK_TILE = 2176  # the widest key tile a selection's mask is written in (:func:`mask_tile`): on the
 # v5e the kernel under it read 34.8 ms a layer at 512 x 2,176 and 39.4 at 256 x 4,352 (PR 47)
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockSelection:
+    """The sizes of a selection of BLOCKS of keys a key head, scored by the
+    attention's own queries against mean-pooled keys (InfLLM-V2's
+    ``sparse_config``, arXiv:2509.24663): a pooled key is the mean of
+    ``kernel_size`` keys, one every ``kernel_stride``; a query keeps ``topk``
+    blocks of ``block_size`` keys, the first ``init_blocks`` and the latest
+    ``window_size // block_size`` among them whatever their scores; a sequence
+    of at most ``dense_len`` tokens attends densely (:func:`select_blocks`)."""
+
+    kernel_size: int = 32
+    kernel_stride: int = 16
+    block_size: int = 64
+    topk: int = 64
+    init_blocks: int = 1
+    window_size: int = 2048
+    dense_len: int = 8192
+
+    def __post_init__(self):
+        bs, st = self.block_size, self.kernel_stride
+        if (self.kernel_size != 2 * st or bs % st or bs & (bs - 1) or bs > MASK_TILE
+                or self.init_blocks + self.window_size // bs > self.topk):
+            raise ValueError(f"{self}: built for pooled keys of two strides, blocks of a power of "
+                             "two of whole strides, and forced blocks within topk")
+
+    @property
+    def window_blocks(self) -> int:
+        return self.window_size // self.block_size
+
+    def selects(self, s: int) -> bool:
+        """Whether a sequence of ``s`` tokens selects (else it attends densely)."""
+        return s > self.dense_len
+
+    def tiles(self, s: int) -> Tuple[int, int, int]:
+        """``(key tile, blocks a tile, flag lanes)`` the attention under the
+        selection runs a sequence of ``s`` in: the key tile is the widest
+        power of two of blocks (at most a lane tile's 128, so that a tile's
+        flags are ONE aligned run of a lane tile) that stays within
+        :data:`MASK_TILE` and within the sequence (32 blocks of 64: 2,048, and
+        34,304 keys are padded to 17 such tiles, as :func:`mask_tile` pads them
+        to 16 of 2,176); the flags of every (padded) block, whole lane tiles."""
+        a_tile = 1
+        while (a_tile < 128 and 2 * a_tile * self.block_size <= MASK_TILE
+               and 2 * a_tile * self.block_size <= max(s, self.block_size)):
+            a_tile *= 2
+        tiles = -(-s // (a_tile * self.block_size))
+        return a_tile * self.block_size, a_tile, -(-tiles * a_tile // 128) * 128
+
+    def pairs(self, s: int) -> int:
+        """The (query, key) pairs ONE key head's selection keeps of a sequence
+        of ``s``: a query at ``t`` keeps ``min(t // block_size + 1, topk)``
+        blocks, its own block among them (the latest blocks are forced) and of
+        that one the keys up to ``t``: a constant of the shapes."""
+        t = np.arange(s)
+        return int(np.sum((np.minimum(t // self.block_size + 1, self.topk) - 1) * self.block_size
+                          + t % self.block_size + 1))
 
 
 def pick_tile(s: int, want: int) -> int:
@@ -341,6 +401,168 @@ def live_tiles(live: jax.Array, s: int, stat_tile: int = 512) -> Tuple[jax.Array
 
 
 # ---------------------------------------------------------------------------
+# a selection of BLOCKS a key head, scored by the attention's own operands
+# ---------------------------------------------------------------------------
+
+BIG = 1e30  # a forced block's score: above every sum of a group's probabilities
+
+
+def pooled_keys(k, g: int, sel: BlockSelection, lanes: int):
+    """``k [S, G*d]`` -> the keys mean-pooled, ``K[j] = mean(k[st*j : st*j +
+    2*st])`` for ``j < S/st - 1``, in the type of ``k``, laid out for
+    :func:`select_blocks`' kernel: ``[G, R*lanes, d]`` with ``R = block_size /
+    kernel_stride`` and pooled key ``R*n + c`` at row ``c*lanes + n`` (block
+    ``n``'s ``c``-th pooled key in lane ``n`` of segment ``c``; zeros where
+    there is none)."""
+    s, d = k.shape[0], k.shape[1] // g
+    st, ratio = sel.kernel_stride, sel.block_size // sel.kernel_stride
+    runs = jnp.sum(k.astype(jnp.float32).reshape(s // st, st, g, d), axis=1)
+    pooled = ((runs[:-1] + runs[1:]) / sel.kernel_size).astype(k.dtype)  # [S/st - 1, G, d]
+    pooled = jnp.pad(pooled, ((0, ratio * lanes - pooled.shape[0]), (0, 0), (0, 0)))
+    return jnp.transpose(pooled.reshape(lanes, ratio, g, d), (2, 1, 0, 3)).reshape(g, ratio * lanes, d)
+
+
+def _select_blocks_kernel(q_ref, pool_ref, flags_ref, live_ref, *scores_ref, sel, rep, d, block_q,
+                          lanes, n_pool, piece):
+    from jax.experimental.pallas import tpu as pltpu
+
+    qi = pl.program_id(1)
+    f32 = jnp.float32
+    st, bs, ratio = sel.kernel_stride, sel.block_size, sel.block_size // sel.kernel_stride
+    t = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    n = jax.lax.broadcasted_iota(jnp.int32, (block_q, lanes), 1)
+    # pooled key R*n + c is VISIBLE to a query where it ends at or before it
+    seen = jnp.concatenate([(st * (ratio * n + c) + sel.kernel_size - 1 <= t)
+                            & (ratio * n + c < n_pool) for c in range(ratio)], axis=1)
+    pool = pool_ref[...]
+    total = jnp.zeros((block_q, ratio * lanes), f32)  # the group's heads' probabilities, summed
+    for h in range(rep):
+        q = q_ref[h] if len(q_ref.shape) == 3 else q_ref[:, h * d:(h + 1) * d]
+        s = jax.lax.dot_general(q, pool, (((1,), (1,)), ((), ())), preferred_element_type=f32)
+        s = jnp.where(seen, s, NEG_INF)
+        p = jnp.where(seen, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+        total = total + p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    # a block's score: the largest of the R + 1 pooled keys that overlap it, R*n - 1 .. R*n + R - 1
+    parts = [total[:, c * lanes:(c + 1) * lanes] for c in range(ratio)]
+    before = jnp.where(n == 0, 0.0, pltpu.roll(parts[-1], 1, 1))  # lane n meets pooled key R*n - 1
+    score = functools.reduce(jnp.maximum, parts + [before])
+    if scores_ref:
+        scores_ref[0][...] = score
+    last = t // bs  # the query's own block
+    forced = (n < sel.init_blocks) | (n > last - sel.window_blocks)
+    key = jnp.where(n <= last, sortable_key(jnp.where(forced, BIG, score)), INT_MIN)
+    want = jnp.minimum(last + 1, sel.topk)
+
+    def count(hit):
+        return jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+
+    # the want-th largest key, bit by bit from the top (a score is at least 0: so is its key)
+    def bit_step(i, carry):
+        lo, n_lo = carry
+        cand = lo + jnp.left_shift(jnp.int32(1), 30 - i)
+        hits = count(key >= cand)
+        ok = hits >= want
+        return jnp.where(ok, cand, lo), jnp.where(ok, hits, n_lo)
+
+    thr, _ = jax.lax.fori_loop(0, 31, bit_step, (jnp.zeros((block_q, 1), jnp.int32), count(key >= 0)))
+    # blocks that share the score thr: the LOWER ones stay, up to the largest bound c with
+    # count(key > thr) + count(key == thr, n < c) <= want (scores that underflow to 0 are equal)
+    above = count(key > thr)
+    bits = lanes.bit_length()
+
+    def cut_step(i, c):
+        cand = c + jnp.left_shift(jnp.int32(1), bits - 1 - i)
+        return jnp.where(above + count((key == thr) & (n < cand)) <= want, cand, c)
+
+    cut = jax.lax.fori_loop(0, bits, cut_step, jnp.zeros((block_q, 1), jnp.int32))
+    kept = (key > thr) | ((key == thr) & (n < cut))
+    flags_ref[...] = kept.astype(jnp.int8)
+    # which pieces of `piece` blocks hold a kept block: a product against the pieces' membership
+    of_piece = (jax.lax.broadcasted_iota(jnp.int32, (lanes, 128), 0) // piece
+                == jax.lax.broadcasted_iota(jnp.int32, (lanes, 128), 1))
+    held = jnp.dot(kept.astype(jnp.bfloat16), of_piece.astype(jnp.bfloat16),
+                   preferred_element_type=f32)
+    live_ref[...] = (jnp.max(held, axis=0, keepdims=True) > 0).astype(jnp.int32)[None]
+
+
+def select_blocks(q, k, *, num_kv_heads: int, selection: BlockSelection, block_q: int = 128,
+                  with_scores: bool = False, interpret: Optional[bool] = None):
+    """``q [S, H*d]`` (already scaled by ``d**-0.5``) and ``k [S, G*d]``, ONE
+    sequence's, the attention's own operands -> a selection of BLOCKS a KEY
+    HEAD ``g`` (query heads ``g*H/G ..``), InfLLM-V2's, with ``K`` the keys
+    mean-pooled (:func:`pooled_keys`):
+
+        p[t, h, j] = softmax_j(q[t, h] . K[g, j])      over the pooled keys that END at or before t
+        r[t, g, j] = sum_{h in g} p[t, h, j]           (all zero where none does)
+        b[t, g, n] = max_{R n - 1 <= j < R n + R} r[t, g, j]      for blocks n <= t // block_size
+        b = +inf for n < init_blocks and for the latest window_size / block_size blocks
+        Sel(t, g) = the min(t // block_size + 1, topk) largest b, equal scores to the LOWER n
+
+    as int8 flags ``[G, S, W]`` (entry ``[g, t, n]``: block ``n`` in ``Sel(t,
+    g)``; ``W`` whole lane tiles: ``selection.tiles(S)``), and which pieces of
+    ``pick_tile(S, 512)`` keys hold a kept block of a query tile, int32 ``[G,
+    S/bq, S/piece]`` (what :func:`live_tiles` reads, a group at a time); with
+    ``with_scores`` (a test's) ``b [G, S, W]`` float32 third, as scored, the
+    forced and unseen blocks not yet marked. ONE kernel a (key head, query
+    tile): a head's scores against ALL pooled keys are a ``[bq, R*W]`` tile
+    that never leaves VMEM (the sixteen heads' ``[16, S, S/16]`` would be 4.7
+    GB at 34,304), the softmax is the score's own (not InfLLM-V2's second,
+    coarser pooling of its denominator), and the ``topk``-th largest score is
+    found by :func:`select_keys`' bit-by-bit search, over a row of ``W``
+    candidates. The pooled keys lie block-major in ``R`` lane segments, so a
+    block's maximum over its ``R + 1`` overlapping pooled keys is a maximum of
+    whole lane segments and one rotation."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, g = q.shape[0], int(num_kv_heads)
+    d = k.shape[1] // g
+    rep = q.shape[1] // (g * d)
+    sel = selection
+    if s % sel.block_size or q.shape != (s, g * rep * d):
+        raise ValueError(f"a selection of blocks of {sel.block_size} keys over q {q.shape} and k "
+                         f"{k.shape}: not whole blocks of one sequence")
+    _, _, lanes = sel.tiles(s)
+    bq = pick_tile(s, block_q)
+    ratio = sel.block_size // sel.kernel_stride
+    n_pool = s // sel.kernel_stride - 1
+    keys = pick_tile(s, 512)  # the statistics' piece, in keys
+    if keys % sel.block_size:
+        raise ValueError(f"the statistics' piece of {keys} keys is no whole blocks of {sel.block_size}")
+    pool = pooled_keys(k, g, sel, lanes)
+    if d % 128:  # heads narrower than a lane tile (tests) go head-major
+        q_in = jnp.transpose(q.reshape(s, g, rep, d), (1, 2, 0, 3))
+        q_spec = pl.BlockSpec((None, rep, bq, d), lambda gi, i: (gi, 0, i, 0))
+    else:  # a group's heads are ONE token-major block, read where W_q's product wrote them
+        q_in, q_spec = q, pl.BlockSpec((bq, rep * d), lambda gi, i: (i, gi))
+    out_specs = [pl.BlockSpec((None, bq, lanes), lambda gi, i: (gi, i, 0)),
+                 pl.BlockSpec((None, 1, 1, 128), lambda gi, i: (gi, i, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((g, s, lanes), jnp.int8),
+                 jax.ShapeDtypeStruct((g, s // bq, 1, 128), jnp.int32)]
+    if with_scores:
+        out_specs.append(pl.BlockSpec((None, bq, lanes), lambda gi, i: (gi, i, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((g, s, lanes), jnp.float32))
+    flags, live, *scores = pl.pallas_call(
+        functools.partial(_select_blocks_kernel, sel=sel, rep=rep, d=d, block_q=bq, lanes=lanes,
+                          n_pool=n_pool, piece=keys // sel.block_size),
+        grid=(g, s // bq),
+        in_specs=[q_spec, pl.BlockSpec((None, ratio * lanes, d), lambda gi, i: (gi, 0, 0))],
+        out_specs=out_specs, out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"), vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(interpret),
+        name="select_blocks",
+    )(q_in, pool)
+    return (flags, live[:, :, 0, :s // keys], *scores)
+
+
+def blocks_to_dense(flags: jax.Array, s: int, block_size: int) -> jax.Array:
+    """A key head's block flags ``[S, W]`` as a plain ``[S, S]`` boolean over
+    the keys, the diagonal block cut at the query."""
+    open_ = jnp.repeat(flags[:, :s // block_size] != 0, block_size, axis=1)
+    return open_ & (jnp.arange(s)[None, :] <= jnp.arange(s)[:, None])
+
+
+# ---------------------------------------------------------------------------
 # masked grouped-query flash attention
 # ---------------------------------------------------------------------------
 
@@ -386,7 +608,7 @@ def _turned_head(x, cos, sin, width: int, scale: float):
 
 def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared, masked,
                    gated=False, window=None, turn=None, rotary=None, heads=1, joint=False,
-                   stack=False):
+                   stack=False, blocks=None):
     rest = list(rest)
     # a BLOCK of `heads` heads, each alone in its group, is a wider block of the same arrays: with
     # `joint` keys and values are ONE block, head h's keys then its values
@@ -470,7 +692,20 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
                     qs = (qs_ref[...] if heads == 1 else qs_ref[h]).reshape(rows, qs_ref.shape[-1])
                 s = s + jax.lax.dot_general(qs, ks_ref[...], (((1,), (1,)), ((), ())),
                                             preferred_element_type=jnp.float32)
-            if masked and not open_:
+            if masked and blocks is not None and not open_:
+                # a selection of BLOCKS (`select_blocks`' flags, the key head's own): the tile's
+                # flags are an aligned run of ONE lane tile, spread over the tile's keys by a
+                # product against the blocks' membership, and the diagonal block is cut at the
+                # query. Block 0 is forced: every row has an open key from its first tile on
+                flags = mask_ref[...].astype(jnp.float32).astype(jnp.bfloat16)  # [bq, 128]
+                first_flag = (kb % (128 // blocks[1])) * blocks[1]  # (blocks: keys a block, blocks a tile)
+                spread = (jax.lax.broadcasted_iota(jnp.int32, (128, block_k), 0) - first_flag
+                          == jax.lax.broadcasted_iota(jnp.int32, (128, block_k), 1) // blocks[0])
+                sel = jnp.dot(flags, spread.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+                row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+                col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+                open_.append(((sel > 0.0) & (col <= row))[None])
+            elif masked and not open_:
                 # a row with nothing selected yet has m_new == NEG_INF and p == 1 on its masked
                 # entries: the first selected key's alpha == 0 wipes that, and every row selects a
                 # key at or before its diagonal tile
@@ -564,7 +799,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
                       q_shared=None, k_shared=None, mask=None, window: Optional[int] = None,
                       out_gate=None, shared_turn=None, shared_scale: float = 1.0, turn=None,
                       turn_width: int = 0, turn_scale: float = 1.0, q_scale: float = 1.0,
-                      heads: Optional[int] = None):
+                      heads: Optional[int] = None, mask_blocks: Optional[BlockSelection] = None):
     """What :func:`masked_gqa_attention` runs. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
@@ -723,12 +958,21 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     rep = hd // (g * d)
     shared, masked = q_shared is not None, mask is not None
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
-    if masked:
+    blocks = None
+    if masked and mask_blocks is not None:  # a selection of BLOCKS, one a key head: flags [G, S, W]
+        bk, a_tile, lanes = mask_blocks.tiles(s)
+        blocks, n_kb, mq = (mask_blocks.block_size, a_tile), -(-s // bk), pick_tile(s, 128)
+        if b != 1 or mask.shape != (g, s, lanes):
+            raise ValueError(f"flags {mask.shape} select blocks a key head of one sequence: not "
+                             f"[{g}, {s}, {lanes}] of {b} of {s}")
+        bq = _masked_query_tile(s, block_q, mq, rep * bk)
+    elif masked:
         n_qb, n_kb, mq, bk = mask.shape
         if b != 1 or n_qb * mq != s or not 0 <= n_kb * bk - s < bk:
             raise ValueError(f"a mask {mask.shape} selects the keys of one sequence of "
                              f"{n_qb * mq}: not of {b} of {s}")
         bq = _masked_query_tile(s, block_q, mq, rep * bk)
+    if masked:
         if n_kb * bk > s:  # keys laid after the sequence, closed by the mask: whole key tiles
             rows = ((0, 0), (0, n_kb * bk - s), (0, 0))
             k, v, k_shared = (u if u is None else jnp.pad(u, rows) for u in (k, v, k_shared))
@@ -810,7 +1054,13 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         in_specs += [pl.BlockSpec((bq, ds),
                                   lambda bi, gi, t, qi, kb: (bi * (s // bq) + qi[t], 0))] * 2
         scratch.append(pltpu.VMEM((hb * rep * bq, ds), q.dtype))  # the tile as the key steps read it
-    if masked:
+    if blocks:  # the key head's flags of the query tile: the lane tile that holds the key tile's
+        if hb != 1:
+            raise ValueError("a selection of blocks is a key head's: one group a grid step")
+        operands.append(mask)
+        in_specs.append(pl.BlockSpec((None, bq, 128), lambda bi, gi, t, qi, kb: (
+            gi, qi[t], kb[t] // (128 // blocks[1]))))
+    elif masked:
         operands.append(mask)
         in_specs.append(pl.BlockSpec((bq // mq, None, mq, bk),
                                      lambda bi, gi, t, qi, kb: (qi[t], kb[t], 0, 0)))
@@ -821,7 +1071,8 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         functools.partial(_causal_kernel, block_q=bq, block_k=bk, shared=shared, masked=masked,
                           gated=out_gate is not None, window=window,
                           turn=None if shared_turn is None else float(shared_scale),
-                          rotary=rotary, heads=hb, joint=joint, stack=stack),
+                          rotary=rotary, heads=hb, joint=joint, stack=stack,
+                          **({"blocks": blocks} if blocks else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g // hb, len(pairs)),
             in_specs=in_specs, out_specs=place_spec(dv) if in_place(dv) else major_spec(dv),
@@ -974,10 +1225,15 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
                          q_shared=None, k_shared=None, window: Optional[int] = None,
                          out_gate=None, shared_turn=None, shared_scale: float = 1.0, turn=None,
                          turn_width: int = 0, turn_scale: float = 1.0,
-                         q_scale: float = 1.0) -> jax.Array:
+                         q_scale: float = 1.0,
+                         mask_blocks: Optional[BlockSelection] = None) -> jax.Array:
     """``q [B, S, H*d]`` (already scaled by ``d**-0.5``), ``k, v [B, S,
     G*d]``, ``mask`` from :func:`select_keys` (``B`` 1: a selection is one
-    sequence's) or none -> ``o [B, S, H*d]``: causal softmax attention,
+    sequence's; with ``mask_blocks``, the sizes of a selection of BLOCKS,
+    :func:`select_blocks`' flags ``[G, S, W]``, one selection a KEY HEAD:
+    every causal tile is still computed, in the selection's own key tile,
+    ``mask_blocks.tiles(S)``, the keys padded to whole tiles) or none -> ``o
+    [B, S, H*d]``: causal softmax attention,
     under a mask of every query head over the keys its query selected,
     query head ``h`` reading key-value head ``h // (H/G)``. Masked-dense:
     every causal tile is computed. ``block_q`` is the kernel's query tile
@@ -1027,7 +1283,8 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
                                         window, d if turn is not None else 0)
     return _causal_attention(q, k, v, int(num_kv_heads), block_q, block_k,
                              _interpret(interpret), q_shared, k_shared, mask, window, out_gate,
-                             shared_turn, shared_scale, turn, turn_width, turn_scale, q_scale)
+                             shared_turn, shared_scale, turn, turn_width, turn_scale, q_scale,
+                             mask_blocks=mask_blocks)
 
 
 def windowed_gqa_attention(q, k, v, *, window: int, num_kv_heads: int, block_q: Optional[int] = None,

@@ -1029,6 +1029,128 @@ def test_the_delta_net_kernel_the_chip_compiles_carries_its_state_float32():
     assert not rounded
 
 
+def test_the_minicpm_sala_step_compiles_whole_with_its_kernels_under_the_scopes_a_trace_reads(
+        one_chip, monkeypatch):
+    """The whole served step of ``minicpm_sala_prefill_epix10k2m`` at the
+    published sizes (four layers, one frame of 34,304 tokens), compiled for the
+    described v5e (under ten seconds): it fits the chip (weights 3.4 GB, 4 GB
+    of temporaries: a float32 stream and the MLP's 16,384-wide rows), and its
+    Mosaic kernels are the ones the roofline functions count, each under the
+    scope a trace reads: ONE ``select_blocks`` (under ``block_select``:
+    ``minicpm_sala.select_blocks``), ONE ``masked_gqa_attention`` under its flags
+    (``sparse_attn``: ``minicpm_sala.sparse_attention``), three
+    ``lightning_attention`` (``lightning``: ``minicpm_sala.lightning_attention``),
+    the calibration kernel, and no other. The sparse layer's calls meet shapes
+    no other cell compiles: sixteen heads a group UNDER a mask (a query tile of
+    128 x 2,048 keys, the keys padded to seventeen tiles), two selections a
+    layer from ``[2, 34304, 640]`` flags."""
+    import collections
+
+    from psana_ray_tpu.models import decoder
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, dcfg, lowered = _lowered_step("minicpm_sala_prefill_epix10k2m", one_chip)
+    compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    assert 3.4e9 < mem.argument_size_in_bytes < 3.5e9 and mem.temp_size_in_bytes < 4.5e9
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
+    assert names == {"select_blocks": 1, "masked_gqa_attention": 1, "lightning_attention": 3,
+                     "fused_calibrate": 1}, names
+    for kernel, scope in (("select_blocks", "block_select"), ("masked_gqa", "sparse_attn"),
+                          ("lightning_attention", "lightning")):
+        assert all(f"/{scope}/" in line for line in calls if re.match(rf"\s*%{kernel}", line))
+    assert re.search(r"s8\[2,34304,640\]", text)  # the flags, a key head each
+    sel = dcfg.block_select
+    assert sel.tiles(34304) == (2048, 32, 640)
+    assert sa._masked_query_tile(34304, dcfg.attn_q_tile, 128, 16 * 2048) == 128
+    assert decoder.causal_call_steps(dcfg, 1, 1, 34304) == (0, 0)  # a linear layer makes no causal call
+
+
+def test_the_lightning_kernel_the_chip_compiles_carries_its_state_float32(one_chip, monkeypatch):
+    """The file states a float32 state a head; a state CARRIED in bf16 would
+    pass the chip's limits (it is a bf16 MXU operand either way: olmo_hybrid's
+    finding). So the kernel Mosaic is handed, traced at the published sizes as
+    the step calls it (not interpreted), is read: its first scratch is
+    ``float32 [4, 128, 128]`` (four heads a grid step), what is stored there is
+    float32 of a state's shape, and the kernel alone compiles for the described
+    v5e at S 34,304, 32 heads of 128 x 128, in chunks of 256 rows."""
+    import functools
+
+    from psana_ray_tpu.ops import lightning
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, h, d = 34304, 32, 128
+    operands = (S((t, h * d), F32), S((t, h * d), F32), S((t, h * d), BF16), S((t, h * d), BF16),
+                S((h,), F32), S((d,), BF16), S((d,), BF16), S((d,), BF16), (S((t, d), F32), S((t, d), F32)))
+    fn = functools.partial(lightning.lightning_attention, seq_len=t, heads=h, eps=1e-6,
+                           scale=d ** -0.5, interpret=False)
+    traced = jax.make_jaxpr(fn)(*operands)
+
+    def pallas_calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for inner in eqn.params.values():
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                    yield from pallas_calls(getattr(inner, "jaxpr", inner))
+
+    (call,) = pallas_calls(traced.jaxpr)
+    assert call.params["name"] == "lightning_attention" and not call.params["interpret"]
+    state, masks, falls, left = call.params["grid_mapping"].scratch_avals
+    assert state.dtype == F32 and state.shape == (lightning.HEADS, d, d) == (4, 128, 128)
+    assert masks.shape == (4, 256, 256) and lightning.step_rows(t) == (512, 256)
+    body = call.params["jaxpr"]
+
+    def swaps(jaxpr):  # every store into a ref of the state's shape, the chunk loop's body included
+        for eqn in jaxpr.eqns:
+            ref = eqn.invars[0].aval if eqn.invars else None
+            if eqn.primitive.name == "swap" and getattr(ref, "shape", None) == state.shape:
+                assert ref.dtype == F32
+                yield eqn.invars[1].aval
+            for inner in eqn.params.values():
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                    yield from swaps(getattr(inner, "jaxpr", inner))
+
+    stored = list(swaps(body))
+    assert all(a.dtype == F32 for a in stored)  # a head's state a chunk leaves, four a chunk
+    assert [a.shape for a in stored if a.shape == (d, d)] == [(d, d)] * lightning.HEADS
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), operands)
+    assert jax.jit(fn).lower(*args).compile().as_text().count("tpu_custom_call") >= 1
+
+
+def test_the_block_selection_and_the_call_under_its_flags_compile_at_sixteen_heads_a_group(
+        one_chip, monkeypatch):
+    """The sparse layer's two calls ALONE at the published sizes (S 34,304, 32
+    query heads on 2 key heads of 128): the selection kernel (a query tile's
+    ``[128, 2560]`` score row a head, four lane segments of 640 blocks) and the
+    masked causal kernel under its flags, ``causal_tiles``, ``mask_tile`` and
+    ``heads_a_step`` at a shape keye's eight and nemotron3's maskless sixteen
+    have not compiled."""
+    import functools
+
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, h, g, d = 34304, 32, 2, 128
+    sel = sa.BlockSelection()
+    q, k = S((t, h * d), BF16, sharding=one_chip), S((t, g * d), BF16, sharding=one_chip)
+    select = jax.jit(functools.partial(sa.select_blocks, num_kv_heads=g, selection=sel, interpret=False))
+    text = select.lower(q, k).compile().as_text()
+    assert text.count("tpu_custom_call") == 1 and "s8[2,34304,640]" in text
+    flags = S((g, t, 640), jnp.int8, sharding=one_chip)
+    q3, k3 = (S((1, *a.shape), BF16, sharding=one_chip) for a in (q, k))
+    attend = jax.jit(lambda q, k, v, m: sa.masked_gqa_attention(
+        q, k, v, m, num_kv_heads=g, block_q=256, mask_blocks=sel, interpret=False))
+    compiled = attend.lower(q3, k3, k3, flags).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert sa.heads_a_step(g, h // g, 128, 2048, d, d, masked=True) == 1
+
+
 def test_the_ouro_step_compiles_whole_as_one_loop_around_one_stack_of_layers(one_chip, monkeypatch):
     """The whole served step of ``ouro_2p6b_prefill_epix10k2m`` at the published
     sizes, ALL 48 layers four times over and the whole vocabulary, compiled for

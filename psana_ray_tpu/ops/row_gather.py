@@ -30,6 +30,49 @@ row 2.59), 4.19 for 274,432; a bfloat16 buffer unpacked by strided
 half-word reads 4.75 and 9.36 (my chip runs, PR 39). Off the TPU it runs
 in Pallas interpret mode.
 
+A row is whole ``[8, 128]`` word tiles only at ``D % 2048 == 0``. At any
+other whole number of 128-column chunks (ling3's 2,560, nemotron3's 2,688
+with its odd 21st chunk, laguna's 3,072) XLA's reshape gives no view with a
+row a block, so the kernel reads ``x`` through :func:`_rows_as_words`' view
+(``[N, 12, 128]`` words at all three widths, a pass over ALL of ``x``),
+copies a row's REAL word sublanes (10, 11, 12) and unpacks the real
+chunks. :func:`tile_rows` is the one place that says who moves a call's
+rows, from its shapes and dtype alone. ALONE on the chip (my chip runs, PR
+70, ``_chip_archive/pr70/alone*.py``: the step's own indices, ``slot // k``
+of the slots sorted held experts first; twenty calls a reading, each read
+twice to 0.01 ms; every variant equal to XLA's gather to the bit), ms::
+
+    x, rows out              XLA's gather   view + kernel   the view in a jit of its own
+    [34816, 2560], 104,448   4.31 (41 ns)   2.55 (24 ns)    1.38 (0.60 as the step runs it)
+    [34816, 2688], 156,672   6.65 (42 ns)   3.53 (23 ns)    1.39
+    [17408, 3072],  65,280   0.65 (10 ns)   1.71 (26 ns)    0.73
+    [34816, 2048], 139,264   4.64 (33 ns)   2.74 (20 ns)    XLA's reshape, 0.43
+
+XLA's gather has TWO paces, and what sets them is the size of ``x``: 8-10
+ns a row where ``x`` is 111.0 MiB or less (``[17408, .]`` at 2,048 to
+3,072, ``[18944, 3072]``, ``[22528, 2560]``, ``[8704, 2560]``; a chip's 128
+MiB of vector memory less the 16 MiB XLA keeps for its scopes is 112), 40-48
+at 112.5 MiB and more (``[19200, 3072]``, ``[23040, 2560]``, every ``[34816,
+.]``), whatever ``m``. So laguna's rows stay XLA's (0.61 ms a layer in its
+step, where the kernel would take 1.7) and ling3's and nemotron3's go
+through the kernel (in their steps 4.24 -> 0.60 + 1.90 and 6.61 -> 0.62 + 2.87
+ms a layer, the view and the kernel). Fewer rows out
+of ling3's ``x`` (the same ``x``, ``m`` sorted rows; XLA / view + kernel):
+69,632 2.85 / 1.91; 52,224 2.14 / 1.60; 34,816 (``m = n``) 1.43 / 1.29;
+26,112 0.89 / 1.15; 17,408 0.73 / 0.97; 2,048 (a turn of the held rows'
+loop) 0.22 / 0.70; kimi's turn, 2,048 rows of ``[17408, 7168]``, 0.25 /
+0.90 (its view alone 1.59): the break-even stands between ``m = 0.75 n``
+and ``m = n``. What did NOT pay, each equal to the bit and slower or level:
+the buffer read through a 4-D index ``buf[slot, rows, s, :]`` (3.86 for 2.66:
+Mosaic lays a row's twelve word sublanes in SIXTEEN, so a FLAT view of a
+``[rows, 12, 128]`` buffer reads other words — wrong on the chip, right in
+interpret mode — and the buffer is ``[rows, 16, 128]`` with a row copied
+into its first sublanes); the words unpacked by integer operations on row
+pairs (``(even & 0xFFFF) | (odd << 16)`` bitcast to a packed tile: 2.89 for
+2.66, and 2.93 for 2.74 at 2,048: two strided loads of eight sublanes cost
+more than the converts); the view made the same way (level). Copying the
+real sublanes only (10 of 12) was worth 0.11 ms at 2,560.
+
 :func:`sum_counted_rows` is the way BACK of a holder of a share of the
 experts (``parallel/moe._held_rows_ahead``): ``y[t] = sum_j gates[t, j] *
 out[back[t, j]]`` over the slots that COUNT, a quarter of them at ling3's
@@ -69,11 +112,13 @@ def _kernel(idx_ref, next_ref, x_ref, o_ref, buf, sem, *, rows, steps):
 
     i = pl.program_id(0)
     slot = lax.rem(i, 2)
-    words = x_ref.bitcast(jnp.uint32)  # [N, a, 128]: a row's sublane pairs, one word each
-    a = words.shape[1]
+    # [N, a, 128] words: a row's sublane pairs, one word each (the words view comes as such)
+    words = x_ref if x_ref.dtype == jnp.uint32 else x_ref.bitcast(jnp.uint32)
+    chunks = o_ref.shape[1] // _LANES  # the REAL chunks of 128 columns: the view may hold more
+    a = -(-chunks // 2)  # and their word sublanes: the first `a` of a row's place in the buffer
 
     def fetch(ids, to, r):
-        pltpu.make_async_copy(words.at[ids[r]], buf.at[to, r], sem.at[to]).start()
+        pltpu.make_async_copy(words.at[ids[r], pl.ds(0, a)], buf.at[to, r, pl.ds(0, a)], sem.at[to]).start()
 
     @pl.when(i == 0)
     def _first_tile():  # once a call: a plain loop, a row a turn
@@ -84,8 +129,9 @@ def _kernel(idx_ref, next_ref, x_ref, o_ref, buf, sem, *, rows, steps):
         lax.fori_loop(0, rows, one, 0)
 
     # one wait for the tile: the semaphore counts bytes, and these are the bytes of `rows` rows
-    pltpu.make_async_copy(words.at[pl.ds(0, rows)], buf.at[slot], sem.at[slot]).wait()
-    flat = buf.at[slot].reshape(rows * a, _LANES)
+    pltpu.make_async_copy(words.at[pl.ds(0, rows), pl.ds(0, a)], buf.at[slot, :, pl.ds(0, a)], sem.at[slot]).wait()
+    place = buf.shape[2]  # `a` in whole 8-sublane tiles: only so is a flat view of the buffer rows of places
+    flat = buf.at[slot].reshape(rows * place, _LANES)
     more = i + 1 < steps
 
     def chunk(q, c):
@@ -97,44 +143,75 @@ def _kernel(idx_ref, next_ref, x_ref, o_ref, buf, sem, *, rows, steps):
                 fetch(next_ref, 1 - slot, first + u)
 
         for s in range(a):
-            w = flat[pl.ds(first * a + s, _CHUNK, stride=a), :]
+            w = flat[pl.ds(first * place + s, _CHUNK, stride=place), :]
             low = lax.bitcast_convert_type(w << 16, jnp.float32)
-            high = lax.bitcast_convert_type(w & jnp.uint32(0xFFFF0000), jnp.float32)
             o_ref[pl.ds(first, _CHUNK), 2 * s * _LANES:(2 * s + 1) * _LANES] = low.astype(o_ref.dtype)
-            o_ref[pl.ds(first, _CHUNK), (2 * s + 1) * _LANES:(2 * s + 2) * _LANES] = high.astype(o_ref.dtype)
+            if 2 * s + 1 < chunks:  # (an odd last chunk is the low halves of a last word)
+                high = lax.bitcast_convert_type(w & jnp.uint32(0xFFFF0000), jnp.float32)
+                o_ref[pl.ds(first, _CHUNK), (2 * s + 1) * _LANES:(2 * s + 2) * _LANES] = high.astype(o_ref.dtype)
         return c
 
     lax.fori_loop(0, rows // _CHUNK, chunk, 0)
 
 
-def tile_rows(m: int, d: int, dtype) -> int:
-    """Rows a grid step of the kernel for ``[m, d]`` rows of ``dtype``; 0
-    where the kernel does not take them (:func:`gather_rows` then leaves
-    the gather to XLA): bfloat16 rows of whole ``[8, 128]`` word tiles, in
-    tiles of 1,024 or, under that, one tile of whole chunks."""
-    if jnp.dtype(dtype) != jnp.bfloat16 or d % (16 * _LANES):
+# what XLA's own gather keeps in vector memory: over an `x` of up to 111.0 MiB it ran at 8-10 ns a
+# row, over one of 112.5 MiB and more at 40-48 (the module's docstring; my chip runs, PR 70)
+XLA_KEEPS_BYTES = 112 * 2 ** 20
+
+
+def tile_rows(n: int, m: int, d: int, dtype) -> int:
+    """Rows a grid step of the kernel for a call that moves ``m`` rows out of
+    ``[n, d]`` of ``dtype``; 0 where :func:`gather_rows` leaves the gather to
+    XLA. The ONE place the rule stands, shapes and a dtype in: the kernel
+    takes bfloat16 rows of whole 128-column chunks, in tiles of 1,024 (a
+    ragged last one) or, under that, one tile of whole bursts of copies.
+    Where a row is no whole ``[8, 128]`` word tiles (``d % 2048``: 2,560,
+    2,688, 3,072, 7,168) it reads ``x`` through a view that is a pass over
+    ALL of ``x``, and takes the call only where that pays: ``m >= n`` (a
+    pass ahead of the held rows' loop moves 3 to 4.5 rows a row of ``x``;
+    measured, ling3's ``x``: the kernel with its view wins from ``m = n``,
+    1.29 ms for 1.43, and loses at ``0.75 n``, 1.15 for 0.89; a turn of
+    the loop, 2,048 rows of 8,704 to 34,816, is XLA's at 0.22-0.25 ms
+    where the view alone is 0.6-1.6), and ``x`` past what XLA's own gather
+    keeps in vector memory (laguna's ``[17408, 3072]``, 102 MiB, is XLA's at
+    10 ns a row; ling3's and nemotron3's ``[34816, .]`` at 41-42 are the
+    kernel's at 23-24). Widths of whole word tiles keep the rule they had
+    (lfm2's and keye's ``x`` is 136 MiB and over)."""
+    if jnp.dtype(dtype) != jnp.bfloat16 or d % _LANES:
         return 0
-    if m % _ROWS == 0:
+    if d % (16 * _LANES) and (m < n or n * d * 2 <= XLA_KEEPS_BYTES):
+        return 0
+    if m >= _ROWS:
         return _ROWS
-    return m if m < _ROWS and m % _CHUNK == 0 else 0
+    return m if m % _CHUNK == 0 else 0
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_rows(x, idx, *, interpret: Optional[bool] = None) -> jax.Array:
     """``x [N, D]``, ``idx [M]`` int32, every index PROMISED in ``[0, N)``
     -> ``x[idx] [M, D]``, the same bits."""
+    rows = tile_rows(x.shape[0], idx.shape[0], x.shape[1], x.dtype)
+    if not rows:
+        return x.at[idx].get(mode="promise_in_bounds")
+    return _kernel_rows(x, idx, rows, interpret)
+
+
+def _kernel_rows(x, idx, rows: int, interpret: Optional[bool] = None) -> jax.Array:
+    """:func:`gather_rows` through the kernel, ``rows`` a grid step (1,024, or
+    all ``M`` of them in whole 16s): ``x`` bfloat16, ``D`` in whole 128s."""
     from jax.experimental.pallas import tpu as pltpu
 
     n, d = x.shape
     m = idx.shape[0]
-    rows = tile_rows(m, d, x.dtype)
-    if not rows:
-        return x.at[idx].get(mode="promise_in_bounds")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    steps = m // rows
-    a = d // (2 * _LANES)
+    steps = -(-m // rows)
+    chunks = d // _LANES
     idx = idx.astype(jnp.int32)
+    if steps * rows > m:  # whole SMEM tiles: the pad reads row 0, and the last output block is a partial one
+        idx = jnp.pad(idx, (0, steps * rows - m))
+    # a row as ONE block: XLA's reshape where it is whole [8, 128] word tiles, else the words laid down
+    words = _words_view(x, interpret) if chunks % 16 else x.reshape(n, chunks, _LANES)
     ids = pl.BlockSpec((rows,), lambda i: (i,), memory_space=pltpu.SMEM)
     ahead = pl.BlockSpec((rows,), lambda i: (jnp.minimum(i + 1, steps - 1),), memory_space=pltpu.SMEM)
     return pl.pallas_call(
@@ -143,12 +220,14 @@ def gather_rows(x, idx, *, interpret: Optional[bool] = None) -> jax.Array:
         in_specs=[ids, ahead, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
-        scratch_shapes=[pltpu.VMEM((2, rows, a, _LANES), jnp.uint32), pltpu.SemaphoreType.DMA((2,))],
+        # a row's place: its word sublanes in whole 8-sublane tiles (`_kernel` reads the buffer flat)
+        scratch_shapes=[pltpu.VMEM((2, rows, -(-chunks // 16) * 8, _LANES), jnp.uint32),
+                        pltpu.SemaphoreType.DMA((2,))],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",),
                                              vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret,
         name="row_gather",
-    )(idx, idx, x.reshape(n, 2 * a, _LANES))
+    )(idx, idx, words)
 
 
 def _words_kernel(x_ref, o_ref, buf, sem, *, rows, words, steps):
@@ -216,6 +295,15 @@ def _rows_as_words(x, view: int, interpret) -> jax.Array:
         interpret=interpret,
         name="rows_as_words",
     )(x)
+
+
+def _words_view(x, interpret) -> jax.Array:
+    """:func:`_rows_as_words` of any bfloat16 ``x [N, D]`` -> ``[N', view / 2, 128]``: a row ONE block
+    for a copy, its chunks' room in whole 8-row tiles of the ``[N, ., 128]`` view (whole words of
+    whole 16 rows as they come: the pad is none)."""
+    n, d = x.shape
+    view = -(-d // (8 * _LANES)) * 8
+    return _rows_as_words(jnp.pad(x, ((0, -n % _CHUNK), (0, -d % _LANES))), view, interpret)
 
 
 _FLAG = 1 << 30  # on a listed slot that does not count: it sorts last
@@ -308,8 +396,8 @@ def sum_counted_rows(out, back, limit, gates, *, interpret: Optional[bool] = Non
     chunks = -(-d // _LANES)
     # a row as ONE block for a copy: whole 8-row tiles of the [N, ., 128] view
     view = -(-chunks // 8) * 8
-    if halves == 2:  # (whole words of whole 16 rows as they come: the pad is none)
-        rows = _rows_as_words(jnp.pad(out, ((0, -n % _CHUNK), (0, -d % _LANES))), view, interpret)
+    if halves == 2:
+        rows = _words_view(out, interpret)
     else:  # 32-bit rows are their own words: XLA's view
         rows = jnp.pad(out.astype(jnp.float32), ((0, 0), (0, view * _LANES - d))).reshape(n, view, _LANES)
     # token slots a grid step: whole tiles of 1,024 (an SMEM block's), or one step of them all

@@ -520,7 +520,24 @@ def _nemotron3_attention():
     return fn, [S((4, 8704, 4096), BF16), kv, kv], 1
 
 
+def _row_gather(n, d, m):
+    """The row gather's kernel driven directly at ``x [n, d]``, ``m`` rows out:
+    a row read through ``rows_as_words``' view, its real word sublanes copied
+    into a place of whole 8-sublane tiles, ``m`` whole tiles of 1,024 or ragged
+    (laguna's 65,280 = 63.75: the rule leaves that call to XLA, whose gather
+    keeps an ``x`` of 102 MiB in vector memory; the ragged tile compiles)."""
+    from psana_ray_tpu.ops import row_gather
+
+    def fn(x, idx):
+        return row_gather._kernel_rows(x, idx, 1024, False)
+
+    return fn, [S((n, d), BF16), S((m,), jnp.int32)], 2
+
+
 CASES = {
+    "row_gather_104448_rows_of_34816x2560": lambda: _row_gather(34816, 2560, 104448),
+    "row_gather_156672_rows_of_34816x2688_an_odd_last_chunk": lambda: _row_gather(34816, 2688, 156672),
+    "row_gather_65280_rows_of_17408x3072_a_ragged_last_tile": lambda: _row_gather(17408, 3072, 65280),
     "nemotron3_ssd_scan_4x8704x64x64x128_in_8_groups": _nemotron3_ssd_scan,
     "nemotron3_causal_gqa_attention_4x8704x32_on_2x128": _nemotron3_attention,
     "granite_ssd_scan_8704x64x64x128": _granite_ssd_scan,
@@ -728,7 +745,17 @@ PINNED_STEPS = {
     # AROUND a kernel (its operands, their shapes and types, its grid's result) and no kernel's
     # body. A kernel's own cache entry follows its body and its file's path
     # (ling3's again in PR 61: its one latent layer, above)
-    "ling3_flash_prefill_epix10k2m": "95ba488baaa02edabc33bf7ae5963c80efd175a61162413b2420d0226483c5f0",
+    # (ling3's and nemotron3's re-pinned in PR 70, knowingly: once an expert layer, in the pass ahead of
+    # the held rows' loop, a `rows_as_words` call (`x [34816, 2560 | 2688]` -> `u32[34816, 12, 128]`)
+    # and a `row_gather` call (the slots' tokens, twice, and that view -> `[104448 | 156672, .]`)
+    # stand where a `gather` of `x` stood (a `pad` of nothing before the view, as on the way back): `row_gather.tile_rows` takes bfloat16 rows of any whole
+    # number of 128-column chunks where the call moves at least as many rows as `x` holds and `x` is
+    # past what XLA's own gather keeps in vector memory; the loop's turn (2,048 rows) keeps its
+    # `gather`. The eight others were hashed before and after and did not move: laguna's `x
+    # [17408, 3072]` is 102 MiB and stays XLA's by that rule, kimi's and dsv32's turns move fewer
+    # rows than `x` holds, lfm2's and keye's 2,048 columns take the kernel operand for operand as
+    # they did, granite's, ouro's and olmo_hybrid's gather no rows)
+    "ling3_flash_prefill_epix10k2m": "fd072057a39e23670358e0155bc1c4bf31c891026c12373957332062ee035db6",
     # laguna's re-pinned in PR 58, knowingly: its nine attention calls take k, v and the query
     # tile's gate where their products wrote them, q as `[G, H/G, B*S, d]` (a layout of the
     # rotary's fusion) and write o token-major `[B, 1, S, H*128]`, already gated, for `W_o` to
@@ -757,7 +784,9 @@ PINNED_STEPS = {
     # copies wrote them), `ssd_scan`'s groups read from the shapes (granite's kernel at one group is
     # the kernel it was: `tests/test_decoder_nemotron3.py -k traced_equation` holds its body) and
     # `grouped_tiles`' and `rows_as_words`' rules for a width of 14.5 or 10.5 lane tiles
-    "nemotron3_nano_prefill_epix10k2m": "8f6156774fbd568ba84d535bb105baea3801835c157ec059dabcc4cb7eec0707",
+    # (re-pinned in PR 70 with ling3's, above: six `rows_as_words` and six `row_gather` calls where
+    # six `gather`s of `x [34816, 2688]` stood)
+    "nemotron3_nano_prefill_epix10k2m": "9c74fe514a0a8d30f7a0485c551013344875141c77598cd7fd89a0c3ea893e01",
     # pinned in PR 67, which brought it: the nine above were hashed on PR 66's tree first and none
     # moved, though every one of them now goes through `_projections`' rule for the q / k norm (none,
     # a head, the whole projection) and for a block without a norm before its branch, `init_params`'
@@ -826,7 +855,12 @@ def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_c
     sum: with them 30 Pallas instructions run under ``moe``, not ``call_sites``'
     18, so ``gmm_roofline_share.ling3``, which reads every ``pallas_call``
     there, reads nothing, and ``gmm_ahead_roofline_share.ling3`` reads the
-    pass's eighteen by their name, ``%gmm``, which this test pins),
+    pass's eighteen by their name, ``%gmm``, which this test pins) and,
+    since PR 70, the pass's way OUT, two more an expert layer (a second
+    ``rows_as_words``, the view of ``x [34816, 2560]`` beside the way back's
+    of ``out``, and ``row_gather``, ONE an expert layer: the pass moves 3
+    rows a row of ``x``, a turn of the loop 2,048 of 34,816, and
+    ``row_gather.tile_rows`` leaves that one to XLA's gather: 42 under ``moe``),
     one ``masked_gqa_attention``, the calibration kernel, and no other."""
     import collections
 
@@ -843,11 +877,11 @@ def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_c
     sites = kimi_k2.held_products(cfg["step_tokens"], 8, 2560, 768, 128, 7, 1, 0.25)["call_sites"]
     expert_layers = dcfg.num_layers - dcfg.num_dense_layers
     assert names == {"gated_delta_rule": cfg["layer_types"].count("linear_attention"), "gmm": 2 * sites,
-                     "rows_as_words": expert_layers, "sum_counted_rows": expert_layers,
-                     "masked_gqa_attention": 1, "fused_calibrate": 1}, names
+                     "rows_as_words": 2 * expert_layers, "row_gather": expert_layers,
+                     "sum_counted_rows": expert_layers, "masked_gqa_attention": 1, "fused_calibrate": 1}, names
     assert sites == 3 * expert_layers == 18
     assert all("/moe/" in line for line in calls
-               if re.match(r"\s*%(gmm|rows_as_words|sum_counted_rows)", line))
+               if re.match(r"\s*%(gmm|rows_as_words|row_gather|sum_counted_rows)", line))
     assert all("/kda/" in line for line in calls if re.match(r"\s*%gated_delta_rule", line))
 
 
@@ -892,7 +926,10 @@ def test_the_nemotron3_step_compiles_whole_with_nothing_array_sized_between_a_bl
     expert layer in the pass ahead of the held rows' loop and two in the loop
     (``nemotron3.held_products``' ``call_sites``: an ungated expert has no
     gate's product) with the pass's way back (``rows_as_words``,
-    ``sum_counted_rows``), the calibration kernel, and no other. Nothing
+    ``sum_counted_rows``) and, since PR 70, its way out (a second
+    ``rows_as_words``, the view of ``x [34816, 2688]`` whose odd last chunk is
+    the low halves of a word, and ``row_gather``, one an expert layer, for the
+    156,672 rows XLA's gather moved), the calibration kernel, and no other. Nothing
     array-sized stands between ``W_in``'s products, the convolution, the scan
     and ``W_out``, nor between the rows' gather, the two grouped products and
     the way back: no copy, transpose, slice or pad of ``[34816, 6144 | 4096]``
@@ -915,13 +952,14 @@ def test_the_nemotron3_step_compiles_whole_with_nothing_array_sized_between_a_bl
     pattern = cfg["hybrid_override_pattern"][:cfg["num_hidden_layers"]]
     sites = nemotron3.held_products(cfg["step_tokens"], 6, 2688, 1856, 64, 14, pattern, 0.5)["call_sites"]
     assert names == {"ssd_scan": pattern.count("M"), "masked_gqa_attention": pattern.count("*"),
-                     "gmm": 2 * sites, "rows_as_words": pattern.count("E"),
-                     "sum_counted_rows": pattern.count("E"), "fused_calibrate": 1}, names
+                     "gmm": 2 * sites, "rows_as_words": 2 * pattern.count("E"),
+                     "row_gather": pattern.count("E"), "sum_counted_rows": pattern.count("E"),
+                     "fused_calibrate": 1}, names
     assert sites == 2 * pattern.count("E") == 12
     assert all("/ssd/" in line for line in calls if re.match(r"\s*%ssd_scan", line))
     assert all("/sparse_attn/" in line for line in calls if re.match(r"\s*%masked_gqa", line))
     assert all("/moe/" in line for line in calls
-               if re.match(r"\s*%(gmm|rows_as_words|sum_counted_rows)", line))
+               if re.match(r"\s*%(gmm|rows_as_words|row_gather|sum_counted_rows)", line))
     entry = text[text.index("ENTRY"):]
     moved = re.findall(r"= \w+\[(?:34816,(?:6144|4096)|4,8704,(?:6144|4096)|156672,\d+)\][^ ]* "
                        r"(?:copy|transpose|slice|pad|concatenate)\(.*", entry)
@@ -1198,8 +1236,12 @@ def test_the_laguna_step_compiles_with_its_kernels_where_the_roofline_functions_
     loop stands in) — and the pass's way back at TEN slots a token
     and 24 lane chunks a row (``rows_as_words``, ``sum_counted_rows``, whose
     step of 5,120 slots takes 60 MB of VMEM for its two buffers), the
-    calibration kernel, and no other: 3,072-wide rows are no whole
-    2,048-column tiles, so the rows go out by XLA's gather."""
+    calibration kernel, and no other: the rows go OUT by XLA's gather, and
+    since PR 70 by the row gather's own rule, not for their width (3,072 is
+    24 whole chunks, which the kernel takes through ``rows_as_words``' view):
+    ``x [17408, 3072]`` is 102 MiB, which XLA's gather keeps in vector memory
+    and moves at 10 ns a row, 0.61 ms a layer where the kernel with its view
+    reads 1.71 (``row_gather.tile_rows``; my chip runs, PR 70)."""
     import collections
 
     from benchmark.roofline import laguna
@@ -1538,11 +1580,13 @@ def test_the_router_indexes_nothing_by_data(layer, one_chip, monkeypatch):
 
 def test_a_share_holder_s_way_back_moves_no_row_of_every_token(one_chip, monkeypatch):
     """ONE expert layer at ling3's sizes, as compiled (PR 52): under
-    ``moe_experts`` XLA gathers rows of 2,560 ONCE outside the loop, the
-    ``[ahead, 2560]`` of the way out; none writes ``[T, 2560]`` (the way
-    back was eight of them, 1.6 ms each, three slots of four fetched to be
-    thrown away). The way back is two kernels, and what ``sum_counted_rows``
-    writes tile by tile reaches ``[T, 2560]`` float32 by a bitcast, no pass."""
+    ``moe_experts`` XLA gathers NO rows of 2,560 outside the loop: none
+    writes ``[T, 2560]`` (the way back was eight of them, 1.6 ms each, three
+    slots of four fetched to be thrown away), and since PR 70 the ``[ahead,
+    2560]`` of the way out leave by the row gather's kernel over a words view
+    of ``x`` (the pass moves 3 rows a row of ``x``: ``row_gather.tile_rows``).
+    The way back is two kernels, and what ``sum_counted_rows`` writes tile
+    by tile reaches ``[T, 2560]`` float32 by a bitcast, no pass."""
     import collections
 
     from psana_ray_tpu.parallel import moe
@@ -1553,11 +1597,14 @@ def test_a_share_holder_s_way_back_moves_no_row_of_every_token(one_chip, monkeyp
     rows_gathered = [int(_SHAPE.search(line).group(2).split(",")[0]) for line in text.splitlines()
                      if " gather(" in line and "/moe_experts/" in line and "/while/" not in line
                      and _SHAPE.search(line).group(2).endswith(",2560")]
-    assert rows_gathered == [ahead] and ahead == 104448, rows_gathered
+    assert rows_gathered == [] and ahead == 104448, rows_gathered
+    moved = [line.strip()[:200] for line in text.splitlines()
+             if re.match(rf"\s*(?:ROOT )?%row_gather[.\d]* = bf16\[{ahead},2560\]", line)]
+    assert len(moved) == 1 and re.search(r", %rows_as_words[.\d]*\), custom_call_target", moved[0]), moved
     kernels = collections.Counter(
         re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in text.splitlines()
         if 'custom_call_target="tpu_custom_call"' in line and "/while/" not in line)
-    assert kernels == {"gmm": 3, "rows_as_words": 1, "sum_counted_rows": 1}, kernels
+    assert kernels == {"gmm": 3, "rows_as_words": 2, "row_gather": 1, "sum_counted_rows": 1}, kernels
     entry = text[text.index("ENTRY"):]
     written = [line.strip()[:120] for line in entry.splitlines()
                if re.match(rf"\s*(?:ROOT )?%[\w.\-]+ = f32\[{tokens},2560\]", line)

@@ -374,7 +374,7 @@ def test_rows_go_out_and_come_back_as_they_did_before(t, k, experts, load, share
     elif load == "cold":
         assert tokens_was[0] == 0
     # out: the same rows, bit for bit (a copy), through the kernel or through XLA
-    assert row_gather.tile_rows(t * k, x.shape[1], x.dtype) == t * k  # one tile, through the kernel
+    assert row_gather.tile_rows(t, t * k, x.shape[1], x.dtype) == t * k  # one tile, through the kernel
     got_rows = row_gather.gather_rows(x, order // k)
     assert got_rows.dtype == rows.dtype and bool(jnp.array_equal(got_rows, rows))
     # back, from the SAME experts' rows (a share's slots that are not held: a gate of 0 on a
@@ -423,18 +423,66 @@ def test_a_sequence_reads_the_same_bits_at_another_place_of_the_batch():
     assert bool(jnp.array_equal(at_1[:32], at_2[32:64]))
 
 
-def test_row_gather_kernel_over_several_tiles_is_a_copy():
-    """2,048 rows: two grid steps, so the second tile's copies are issued
-    under the first one's unpacking and land in the other buffer."""
+@pytest.mark.parametrize("m", [2048, 1024 + 256], ids=["whole_tiles", "ragged"])
+@pytest.mark.parametrize("d", [2048, 2560, 2688, 3072, 768])
+def test_row_gather_kernel_over_several_tiles_is_a_copy(d, m):
+    """Two grid steps, so the second tile's copies are issued under the first
+    one's unpacking and land in the other buffer; ``m`` in whole tiles of 1,024
+    and ragged (the index padded to whole tiles, the last output block a partial
+    one). Widths: whole ``[8, 128]`` word tiles (2,048: ``x`` read through XLA's
+    reshape) and any other whole number of 128-column chunks, read through
+    ``_rows_as_words``' view (2,560 and 3,072: 24 chunks' room; 2,688: 21
+    chunks, an odd last one, the low halves of a last word; 768: six chunks in
+    a view of eight). The kernel is driven directly: at these sizes the rule
+    leaves every width but 2,048 to XLA (an ``x`` it keeps in vector memory)."""
     rng = np.random.default_rng(5)
-    x = jnp.asarray(rng.standard_normal((200, 2048)), jnp.bfloat16)
-    idx = jnp.asarray(rng.integers(0, 200, 2048), jnp.int32)
-    assert row_gather.tile_rows(2048, 2048, x.dtype) == 1024
+    x = jnp.asarray(rng.standard_normal((200, d)), jnp.bfloat16)
+    idx = jnp.asarray(rng.integers(0, 200, m), jnp.int32)
+    want = jnp.take(x, idx, axis=0)
+    got = jax.jit(row_gather._kernel_rows, static_argnums=(2, 3))(x, idx, 1024, True)
+    assert got.shape == (m, d) and got.dtype == x.dtype and bool(jnp.array_equal(got, want))
+    assert row_gather.tile_rows(200, m, d, x.dtype) == (1024 if d == 2048 else 0)
+    assert bool(jnp.array_equal(row_gather.gather_rows(x, idx), want))  # whoever moves them
+
+
+@pytest.mark.parametrize("n,m,d,dtype", [
+    (200, 2048, 2048, jnp.float32),  # 32-bit rows
+    (200, 2048, 96, jnp.float32),
+    (200, 2048, 2000, jnp.bfloat16),  # no whole number of 128-column chunks
+    (200, 2048, 768, jnp.bfloat16),  # a view width, and an x XLA's own gather keeps in vector memory
+    (200, 1000, 2048, jnp.bfloat16),  # under a tile, and no whole bursts of 16 copies
+], ids=["float32", "float32_narrow", "no_whole_chunks", "a_small_x_at_a_view_width", "no_whole_bursts"])
+def test_what_the_row_gather_kernel_does_not_take_goes_to_xla_s_in_bounds_gather(n, m, d, dtype):
+    rng = np.random.default_rng(6)
+    x = jnp.asarray(rng.standard_normal((n, d)), dtype)
+    idx = jnp.asarray(rng.integers(0, n, m), jnp.int32)
+    assert row_gather.tile_rows(n, m, d, dtype) == 0
+    assert "pallas_call" not in str(jax.make_jaxpr(row_gather.gather_rows)(x, idx))
     assert bool(jnp.array_equal(row_gather.gather_rows(x, idx), jnp.take(x, idx, axis=0)))
-    # what the kernel does not take goes to XLA's in-bounds gather
-    assert row_gather.tile_rows(2048, 2048, jnp.float32) == 0 == row_gather.tile_rows(2048, 768, x.dtype)
-    wide = jnp.asarray(rng.standard_normal((200, 96)), jnp.float32)
-    assert bool(jnp.array_equal(row_gather.gather_rows(wide, idx), jnp.take(wide, idx, axis=0)))
+
+
+def test_the_row_gather_s_rule_reads_shapes_and_a_dtype_alone():
+    """ONE function says who moves a call's rows: bfloat16 rows of whole
+    128-column chunks go through the kernel; where a row is no whole ``[8,
+    128]`` word tiles the kernel reads ``x`` through a view that is a pass
+    over ALL of it, so it takes the call only where that pass is the smaller
+    part, ``m >= n`` (a pass ahead of the held rows' loop: 3 to 4.5 rows out a
+    row of ``x``; a turn of the loop, 2,048 rows of 8,704 to 34,816, stays
+    XLA's), and where ``x`` is past what XLA's own gather keeps in vector
+    memory, 112 MiB (laguna's 102 MiB ``x`` goes out at 10 ns a row by XLA,
+    ling3's and nemotron3's 170 and 178.5 MiB at 41-42: PR 70's chip runs)."""
+    bf16 = jnp.bfloat16
+    assert row_gather.tile_rows(34816, 139264, 2048, bf16) == 1024  # lfm2's, keye's: as before
+    assert row_gather.tile_rows(34816, 104448, 2560, bf16) == 1024  # ling3's pass ahead
+    assert row_gather.tile_rows(34816, 156672, 2688, bf16) == 1024  # nemotron3's
+    assert row_gather.tile_rows(17408, 65280, 3072, bf16) == 0  # laguna's: an x of 102 MiB
+    assert row_gather.tile_rows(18944, 65280, 3072, bf16) == 0 < row_gather.tile_rows(19200, 65280, 3072, bf16)
+    for n, d in [(17408, 7168), (8704, 7168), (34816, 2560)]:
+        assert row_gather.tile_rows(n, 2048, d, bf16) == 0  # a turn of the loop: kimi's, dsv32's, ling3's
+    assert row_gather.tile_rows(34816, 34816, 2560, bf16) == 1024  # the threshold: m = n
+    assert row_gather.tile_rows(34816, 34815, 2560, bf16) == 0
+    assert row_gather.tile_rows(4096, 2048, 2048, bf16) == 1024  # whole word tiles need no view: any m, any x
+    assert row_gather.tile_rows(64, 256, 2048, bf16) == 256 and row_gather.tile_rows(64, 250, 2048, bf16) == 0
 
 
 # ---------------------------------------------------------------------------

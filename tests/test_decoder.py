@@ -5,7 +5,6 @@ the benchmark's reader for the counters it feeds."""
 
 import dataclasses
 import functools
-import importlib
 import json
 import os
 import types
@@ -22,6 +21,7 @@ from psana_ray_tpu.ops import row_gather
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
 from psana_ray_tpu.parallel.moe import dropless_moe
+from test_manifest_entries import need
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PANELS, ROWS, COLS, PROMPT = 2, 2, 14, 8  # 56 patches + 8 prompt ids = 64 tokens
@@ -719,7 +719,7 @@ def test_the_package_does_not_import_the_decoder():
 
 
 # ---------------------------------------------------------------------------
-# the benchmark's reader of two counters, and the manifest's new files
+# the benchmark's reader of two counters (the manifest's entries: tests/test_manifest_entries.py)
 # ---------------------------------------------------------------------------
 
 def _ctx(snapshot):
@@ -742,45 +742,13 @@ def test_program_counter_ratio(snapshot, args, want):
     assert got == want
 
 
-def test_every_metric_file_of_the_new_cell_names_a_reader_that_exists():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        manifest = json.load(f)
-    mine = [e for e in manifest["per_layer"] if e.get("workloads") == ["keye_epix_saturated"]]
-    assert len(mine) == 10
-    for entry in mine:
-        with open(os.path.join(REPO, "benchmark", "metrics", entry["name"] + ".json")) as f:
-            spec = json.load(f)
-        reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
-        assert callable(reader.read), entry["name"]
-        if spec["reader"] == "roofline_share":
-            module, fn = spec["args"]["function"].rsplit(".", 1)
-            need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
-            assert set(spec["args"]["shape_from"]) <= set(need.__code__.co_varnames)
-
-
-@pytest.mark.parametrize("name", [
-    "producer_blocked_share.hit", "ring_depth.hit", "queue_dwell_ms.hit", "device_put_ms",
-    "infeed_wait_ms", "launch_ms.hit", "device_wait_ms.hit", "step_ms.hit",
-    "device_idle_share.hit", "stopped_ms.hit", "fps.hit",
-])
-def test_the_new_cell_reports_the_host_path_under_the_names_the_hit_cell_has(name):
-    """The layers the cell shares with the hit cell (ring, batcher,
-    prefetcher, serving loop, device) are read by the same files under
-    the same names: one entry, both cells in its ``workloads``."""
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        manifest = json.load(f)
-    entry, = [e for e in manifest["per_layer"] + manifest["end_to_end"] if e["name"] == name]
-    assert entry["workloads"][:2] == ["hit_epix_saturated", "keye_epix_saturated"]  # later cells follow
-    assert entry.get("moves", "fps.hit") == "fps.hit"
-    assert not os.path.exists(os.path.join(
-        REPO, "benchmark", "metrics", name.replace(".hit", "") + ".keye.json"))
-
-
 def test_roofline_counts_at_the_published_sizes():
-    from benchmark.roofline import decoder as need
+    from benchmark.roofline import decoder as counts
 
     s = 34304
-    assert need.causal_pairs(s) == 588_399_360
-    assert need.selected_pairs(s, 2048) == 2048 * 2049 // 2 + (s - 2048) * 2048
-    assert need.selected_attention(s, 32, 4, 128, 2048)["flops"] == need.selected_pairs(s, 2048) * 16384
-    assert need.grouped_product(s, 8, 2048, 768, 128)["flops"] == 2 * s * 8 * 2048 * 768
+    assert counts.causal_pairs(s) == 588_399_360
+    assert counts.selected_pairs(s, 2048) == 2048 * 2049 // 2 + (s - 2048) * 2048
+    assert counts.selected_attention(s, 32, 4, 128, 2048)["flops"] == counts.selected_pairs(s, 2048) * 16384
+    asked, [shapes] = need("keye_epix_saturated", "decoder.selected_attention")  # the cell's file
+    assert asked(**shapes) == counts.selected_attention(s, 32, 4, 128, 2048)
+    assert counts.grouped_product(s, 8, 2048, 768, 128)["flops"] == 2 * s * 8 * 2048 * 768

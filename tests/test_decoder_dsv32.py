@@ -3,10 +3,9 @@ router (``models/decoder.py`` reading DeepSeek-V3.2's keys) against the
 benchmark's plain reference (``benchmark/reference/deepseek_v32_decoder.py``)
 at small sizes on the CPU; the causal kernel under a mask with a shared key
 part and a value width of its own; the shares of a group-limited expert
-layer; the new cell's manifest entries, counters and counts."""
+layer; the new cell's counters and counts."""
 
 import dataclasses
-import importlib
 import json
 import os
 import subprocess
@@ -22,6 +21,7 @@ from benchmark.reference.keye_decoder import select as ref_select
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
+from test_manifest_entries import BENCH, need, ratio_of
 from xla_turn import TURNS, assert_the_kernel_s_turn_is_xla_s, turned_by_xla
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -641,69 +641,17 @@ def test_pair_counters_reach_the_snapshot_and_the_exposition():
 
 
 # ---------------------------------------------------------------------------
-# the manifest's new files
+# the cell and its counts (its manifest entries: tests/test_manifest_entries.py)
 # ---------------------------------------------------------------------------
 
-def _manifest():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
-DSV32_METRICS = ["proj_ms.dsv32", "indexer_ms.dsv32", "latent_attn_ms.dsv32",
-                 "shared_expert_ms.dsv32", "moe_ms.dsv32", "mlp_ms.dsv32",
-                 "select_keys_roofline_share.dsv32", "sparse_latent_attention_roofline_share.dsv32",
-                 "gmm_roofline_share.dsv32", "step_mfu.dsv32", "selected_pairs_share.dsv32",
-                 "expert_load_peak.dsv32", "held_rows_share.dsv32"]
-
-
-@pytest.mark.parametrize("name", DSV32_METRICS)
-def test_every_metric_file_of_the_dsv32_cell_names_a_reader_and_keys_that_exist(name):
-    manifest = _manifest()
-    entry, = [e for e in manifest["per_layer"] if e["name"] == name]
-    assert entry["workloads"] == [CELL] and entry["moves"] == "fps.hit"
-    assert entry["layer"] == ("kernels" if "roofline" in name else "device program")
-    names = [e["name"] for e in manifest["per_layer"]]
-    first = names.index(DSV32_METRICS[0])  # appended as one run, in this order
-    assert names[first:first + len(DSV32_METRICS)] == DSV32_METRICS
-    with open(CONFIG) as f:
-        cfg = json.load(f)
-    with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
-        spec = json.load(f)
-    reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
-    assert callable(reader.read)
-    args = spec["args"]
-    if "function" in args:
-        module, fn = args["function"].rsplit(".", 1)
-        need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
-        given = set(args["shape_from"]) | ({"held_share"} if "share" in args else set())
-        assert given == set(need.__code__.co_varnames[:need.__code__.co_argcount])
-        assert all(path in cfg for path in args["shape_from"].values())
-    for key in ("pattern", "within"):
-        if args.get(key, "").startswith("@"):
-            assert args[key][1:] in cfg["trace_names"]
-    for key in ("numerator", "denominator"):
-        for counters in (args, args.get("share", {})):
-            if key in counters:
-                assert counters[key] in decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS
-
-
 def test_the_dsv32_cell_follows_kimi_s_and_reports_the_host_path_as_the_decoders_do():
-    manifest = _manifest()
-    cell, = [w for w in manifest["workloads"] if w["name"] == CELL]
+    cell = BENCH.cell(CELL)
     assert (cell["chips"], cell["traffic"], cell["config"]) == (
         1, "saturated", "deepseek_v32_prefill_epix10k2m")
-    config, = [c for c in manifest["configs"] if c["name"] == cell["config"]]
+    config = BENCH.config(cell["config"])
     assert config["file"] == os.path.relpath(CONFIG, REPO) and len(cell["why"]) <= 200
     assert config["reduced"] == ["num_hidden_layers", "first_k_dense_replace", "n_routed_experts",
                                  "vocab_size"]
-    shared = [e["name"] for e in manifest["per_layer"] + manifest["end_to_end"]
-              if "kimi_k2_epix_saturated" in e.get("workloads", ())
-              and "keye_epix_saturated" in e["workloads"]]
-    assert len(shared) == 19 and "fps.hit" in shared  # fps.hit and the 18 host-path metrics
-    for e in manifest["per_layer"] + manifest["end_to_end"]:
-        if e["name"] in shared:
-            at = e["workloads"].index(CELL)  # appended after kimi's; later cells after it
-            assert e["workloads"][at - 1] == "kimi_k2_epix_saturated"
     with open(CONFIG) as f:
         assert json.load(f)["transport"]["slots"] == 4
 
@@ -713,29 +661,19 @@ def test_dsv32_roofline_counts_at_the_published_sizes():
     from benchmark.roofline import deepseek_v32 as roofline
     from benchmark.roofline import kimi_k2
 
-    with open(CONFIG) as f:
-        cfg = json.load(f)
-
-    def need(name):
-        with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
-            args = json.load(f)["args"]
-        module, fn = args["function"].rsplit(".", 1)
-        return getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn), {
-            k: cfg[path] for k, path in args["shape_from"].items()}
-
     assert counts.selected_pairs(8704, 2048) == 15_729_664 and counts.causal_pairs(8704) == 37_884_160
-    fn, shapes = need("sparse_latent_attention_roofline_share.dsv32")
+    fn, [shapes] = need(CELL, "deepseek_v32.sparse_latent_attention")
     attention = fn(**shapes)
     assert attention["flops"] == 15_729_664 * 128 * 2 * (192 + 128)  # 1.289 T a layer
     assert abs(attention["flops"] / 1e12 - 1.289) < 1e-3
     assert attention["flops"] / kimi_k2.latent_attention(1, 8704, 128, 128, 64, 128)["flops"] == \
         pytest.approx(0.4152, abs=1e-4)
-    fn, shapes = need("select_keys_roofline_share.dsv32")
+    fn, [shapes] = need(CELL, "decoder.select_keys")
     assert fn(**shapes)["flops"] == 37_884_160 * 64 * 128 * 2  # 0.62 T a layer
-    fn, shapes = need("gmm_roofline_share.dsv32")
+    fn, [shapes] = need(CELL, "kimi_k2.held_products")
     held = fn(held_share=8 / 256, **shapes)
     assert held["call_sites"] == 15 and held["flops"] == 15 * 2 * 2176 * 7168 * 2048
-    fn, shapes = need("step_mfu.dsv32")
+    fn, [shapes] = need(CELL, "deepseek_v32.step")
     step = fn(**shapes)["flops"]
     assert abs(step / 1e12 - 44.3) < 0.1
     # the step's count is its parts': kimi's count at one frame, the selected pairs in the
@@ -776,7 +714,9 @@ def test_the_cell_s_rehearsal_runs_the_served_path_and_is_correct():
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["metrics"] == {}
     assert line["cell"] == CELL and line["attempted"] > 0
-    for name in ("selected_pairs_share.dsv32", "held_rows_share.dsv32", "expert_load_peak.dsv32",
-                 "device_wait_ms.hit"):
-        assert name in line["would_report"], line["would_report"]
+    for counted in (("attn_pairs_selected_total", "attn_pairs_causal_total"),
+                    ("expert_rows_held_total", "expert_rows_routed_total"),
+                    ("expert_tokens_max_total", "expert_tokens_mean_total")):
+        assert ratio_of(CELL, *counted) in line["would_report"], line["would_report"]
+    assert "device_wait_ms.hit" in line["would_report"]
     assert "compiles inside the window 0" in done.stderr

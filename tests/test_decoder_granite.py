@@ -3,7 +3,7 @@ grouped-query attention, the four multipliers and the trunk that mixes them
 (``models/decoder.py`` reading Granite-4.0-H's keys) against the benchmark's
 plain reference (``benchmark/reference/granite_decoder.py``: the recurrence
 token by token) at small sizes on the CPU; the new cell's configuration
-file, manifest entries, counters and counts."""
+file, counters and counts."""
 
 import dataclasses
 import json
@@ -19,6 +19,7 @@ import pytest
 from benchmark.reference import granite_decoder as ref
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import ssd
+from test_manifest_entries import BENCH, need
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -386,11 +387,6 @@ def _file(name="granite4_h_micro_prefill_epix10k2m"):
         return json.load(f)
 
 
-def _manifest():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
 def _catalog_row():
     if not os.path.exists(CATALOG):
         pytest.skip("no catalog beside the model-configs guide here")
@@ -495,45 +491,16 @@ def test_the_other_six_readers_have_nothing_of_what_this_one_brought(name):
 
 
 def test_the_granite_cell_follows_laguna_s_and_reports_the_host_path_as_the_decoders_do():
-    manifest = _manifest()
-    assert len(manifest["workloads"]) >= 10 and len(manifest["configs"]) >= 9
-    assert {w["chips"] for w in manifest["workloads"]} == {1}
-    cell = manifest["workloads"][9]  # the tenth cell of the ninth configuration; later ones after it
-    assert (cell["name"], cell["chips"], cell["traffic"], cell["config"]) == (
-        CELL, 1, "saturated", "granite4_h_micro_prefill_epix10k2m")
-    config = manifest["configs"][8]
+    cell = BENCH.cell(CELL)
+    assert (cell["chips"], cell["traffic"], cell["config"]) == (
+        1, "saturated", "granite4_h_micro_prefill_epix10k2m")
+    config = BENCH.config(cell["config"])
     assert config["file"] == os.path.relpath(CONFIG, REPO) and len(cell["why"]) <= 200
     assert config["reduced"] == _file()["reduced"] == [] and len(config["why"]) <= 200
-    assert config["source"] == _file()["source"]
-    shared = [e for e in manifest["per_layer"] + manifest["end_to_end"]
-              if "laguna_epix_saturated" in e.get("workloads", ()) and not e["name"].endswith(".laguna")]
-    assert len(shared) == 19 and "fps.hit" in [e["name"] for e in shared]
-    for e in shared:  # fps.hit and the 18 host-path and device metrics every decoder cell reports
-        assert e["workloads"][-1] == CELL or e["workloads"].index(CELL) == e["workloads"].index(
-            "laguna_epix_saturated") + 1
-    # per_layer stands at its limit of 128: of the nine metrics the issue names, ONE has an entry
-    own = [e for e in manifest["per_layer"] if e["name"].endswith(".granite")]
-    assert len(manifest["per_layer"]) <= 128
-    assert [(e["name"], e["workloads"], e["moves"], e["layer"]) for e in own] == [
-        ("ssd_roofline_share.granite", [CELL], "fps.hit", "kernels")]
     cfg = _file()
     assert cfg["trace_names"]["ssd_kernel"] == "ssd_scan"  # the pallas_call's own name
     assert cfg["trace_names"]["attention_kernel"] == "masked_gqa_attention"
     assert cfg["trace_names"]["step"] == "jit_granite_step"
-
-
-def test_the_cell_s_metric_file_names_a_reader_a_function_and_keys_that_exist():
-    import importlib
-
-    with open(os.path.join(REPO, "benchmark", "metrics", "ssd_roofline_share.granite.json")) as f:
-        spec = json.load(f)
-    assert os.path.exists(os.path.join(REPO, "benchmark", "readers", spec["reader"] + ".py"))
-    args, cfg = spec["args"], _file()
-    assert args["pattern"] == "@ssd_kernel" and args["pattern"][1:] in cfg["trace_names"]
-    module, fn = args["function"].rsplit(".", 1)
-    need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)(
-        **{k: cfg[path] for k, path in args["shape_from"].items()})
-    assert need["flops"] > 0 and need["bytes"] > 0
 
 
 def test_granite_roofline_counts_at_the_published_sizes():
@@ -543,6 +510,8 @@ def test_granite_roofline_counts_at_the_published_sizes():
     assert scan["flops"] == 5 * 64 * 128 * 64 * 8704 and round(scan["flops"] / 1e9, 1) == 22.8
     assert round(scan["bytes"] / 1e6, 1) == 220.6
     assert scan["bytes"] / 819e9 > scan["flops"] / 197e12  # bound by bytes: 0.27 ms against 0.12
+    fn, [shapes] = need(CELL, "granite.ssd_scan")  # as the cell's file asks for it
+    assert fn(**shapes) == scan
     cfg = _file()
     step = granite.step(1, 8704, 2048, cfg["layer_types"], 8192, 32, 8, 64, 64, 128, 4, 100352, 256,
                         16)["flops"]

@@ -3,10 +3,9 @@ routed experts (``models/decoder.py`` reading Kimi-K2-Instruct's keys:
 DeepSeek-V3's block) against the benchmark's plain reference
 (``benchmark/reference/kimi_k2_decoder.py``) at small sizes on the CPU;
 the causal kernel at a key width that is not its value width and with a
-key part shared by all heads; the new cell's manifest entries and counts."""
+key part shared by all heads; the new cell's counters, counts and readers."""
 
 import dataclasses
-import importlib
 import json
 import os
 
@@ -19,6 +18,7 @@ from benchmark.reference import kimi_k2_decoder as ref
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
+from test_manifest_entries import BENCH, asked, need
 from xla_turn import TURNS, assert_the_kernel_s_turn_is_xla_s, turned_by_xla
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -493,6 +493,7 @@ def test_kimi_configuration_reads_the_published_keys():
     assert cfg["sequence_tokens"] == 16 * (352 // 16) * (384 // 16) + cfg["prompt_tokens"] == 8704
     assert cfg["step_tokens"] == cfg["batch_size"] * cfg["sequence_tokens"] == 17408
     assert cfg["n_routed_experts"] == cfg["experts_held"][1] == cfg["published"]["n_routed_experts"] // 32
+    assert (BENCH.cell(CELL)["traffic"], BENCH.file(CELL)) == ("saturated", cfg)  # the cell runs THIS file
     # a null query rank was refused until PR 50; it is a FULL-RANK query (W_q in W_dq and W_uq's place)
     full = decoder.DecoderConfig.from_mapping({**cfg, "q_lora_rank": None})
     assert (full.q_lora_rank, full.kv_lora_rank, full.attn_gate) == (0, 512, "")
@@ -572,89 +573,23 @@ def test_counters_of_a_share_reach_the_snapshot_and_the_exposition():
 
 
 # ---------------------------------------------------------------------------
-# the manifest's new files
+# the cell's counts and its readers (its manifest entries: tests/test_manifest_entries.py)
 # ---------------------------------------------------------------------------
 
-def _manifest():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
-KIMI_METRICS = ["proj_ms.kimi", "latent_attn_ms.kimi", "shared_expert_ms.kimi", "moe_ms.kimi",
-                "mlp_ms.kimi", "latent_attention_roofline_share.kimi", "gmm_roofline_share.kimi",
-                "step_mfu.kimi", "expert_load_peak.kimi", "held_rows_share.kimi"]
-
-
-@pytest.mark.parametrize("name", KIMI_METRICS)
-def test_every_metric_file_of_the_kimi_cell_names_a_reader_and_keys_that_exist(name):
-    manifest = _manifest()
-    entry, = [e for e in manifest["per_layer"] if e["name"] == name]
-    assert entry["workloads"] == [CELL] and entry["moves"] == "fps.hit"
-    names = [e["name"] for e in manifest["per_layer"]]
-    first = names.index(KIMI_METRICS[0])  # appended as one run, in this order; later PRs append after it
-    assert names[first:first + len(KIMI_METRICS)] == KIMI_METRICS
-    with open(CONFIG) as f:
-        cfg = json.load(f)
-    with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
-        spec = json.load(f)
-    reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
-    assert callable(reader.read)
-    args = spec["args"]
-    if "function" in args:
-        module, fn = args["function"].rsplit(".", 1)
-        need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
-        given = set(args["shape_from"]) | ({"held_share"} if "share" in args else set())
-        assert given == set(need.__code__.co_varnames[:need.__code__.co_argcount])
-        assert all(path in cfg for path in args["shape_from"].values())
-    for key in ("pattern", "within"):
-        if args.get(key, "").startswith("@"):
-            assert args[key][1:] in cfg["trace_names"]
-    if name == "gmm_roofline_share.kimi":  # found by scope and primitive: XLA renames a kernel in a loop
-        assert (args["scope"], args["leaf"]) == ("moe", "pallas_call")
-    for key in ("numerator", "denominator"):
-        for counters in (args, args.get("share", {})):
-            if key in counters:
-                assert counters[key] in decoder.STEP_STATS + decoder.SHARE_STATS
-
-
-@pytest.mark.parametrize("name", [
-    "producer_blocked_share.hit", "ring_depth.hit", "device_put_ms", "device_wait_ms.hit",
-    "step_ms.hit", "device_idle_share.hit", "queue_dwell_ms.hit", "infeed_wait_ms", "launch_ms.hit",
-    "stopped_ms.hit", "h2d_ms.hit", "prefetch_starved_share.hit", "prefetch_busy_share.hit",
-    "prefetch_copy_share.hit", "prefetch_blocked_share.hit", "idle_launched_share.hit",
-    "idle_unfed_share.hit", "idle_in_h2d_share.hit", "fps.hit",
-])
-def test_the_kimi_cell_reports_the_host_path_under_the_names_the_other_decoders_have(name):
-    manifest = _manifest()
-    entry, = [e for e in manifest["per_layer"] + manifest["end_to_end"] if e["name"] == name]
-    at = entry["workloads"].index(CELL)  # later cells are appended after it
-    assert entry["workloads"][at - 2:at + 1] == ["keye_epix_saturated", "lfm2_epix_saturated", CELL]
-    calib, = [e for e in manifest["per_layer"] if e["name"] == "calib_roofline_share.hit"]
-    assert calib["workloads"] == ["hit_epix_saturated"]  # PERF.md section 7 (b)
-    cell, = [w for w in manifest["workloads"] if w["name"] == CELL]
-    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "saturated", "kimi_k2_prefill_epix10k2m")
-    assert cell["config"] in [c["name"] for c in manifest["configs"]]
-
-
 def test_kimi_roofline_counts_at_the_published_sizes():
-    from benchmark.roofline import kimi_k2 as need
+    from benchmark.roofline import kimi_k2 as roofline
 
-    with open(CONFIG) as f:
-        cfg = json.load(f)
-    attn = need.latent_attention(2, 8704, 64, 128, 64, 128)
+    attn = roofline.latent_attention(2, 8704, 64, 128, 64, 128)
     assert attn["flops"] == 2 * (192 + 128) * 64 * 2 * (8704 * 8705 // 2)  # 3.10 T a layer
     assert 3.09e12 < attn["flops"] < 3.11e12
     assert attn["bytes"] == 2 * 17408 * (64 * (128 + 64 + 128 + 128 + 128) + 64)
-    metrics = os.path.join(REPO, "benchmark", "metrics")
-    with open(os.path.join(metrics, "step_mfu.kimi.json")) as f:
-        shape_from = json.load(f)["args"]["shape_from"]
-    step = need.step(**{k: cfg[path] for k, path in shape_from.items()})
+    fn, [shapes] = need(CELL, "kimi_k2.step")  # as the cell's file asks for it
+    step = fn(**shapes)
     assert 72.0e12 < step["flops"] < 72.5e12  # ISSUE 42's 72.2 T: 0.37 s at the peak
     latent = 7 * (3.52e12 + 3.10e12)
     assert 0.63 < latent / step["flops"] < 0.65  # latent attention and its projections
-    with open(os.path.join(metrics, "gmm_roofline_share.kimi.json")) as f:
-        shape_from = json.load(f)["args"]["shape_from"]
-    gmm = need.held_products(held_share=12 / 384, **{k: cfg[path] for k, path in shape_from.items()})
+    fn, [shapes] = need(CELL, "kimi_k2.held_products")
+    gmm = fn(held_share=12 / 384, **shapes)
     assert gmm["flops"] == 18 * 2 * 4352 * 7168 * 2048  # 0.38 T an expert layer
     assert gmm["bytes"] == 18 * 2 * (12 * 7168 * 2048 + 4352 * (7168 + 2048))
 
@@ -704,8 +639,7 @@ def _held_rows_loop_trace(tmp_path, monkeypatch, joined=()):
 
 
 def _gmm_share_args():
-    with open(os.path.join(REPO, "benchmark", "metrics", "gmm_roofline_share.kimi.json")) as f:
-        args = json.load(f)["args"]
+    args, = asked(CELL, function="kimi_k2.held_products").values()  # the grouped products' share
     assert (args["scope"], args["leaf"], args["within"]) == ("moe", "pallas_call", "@step")
     return {**args, "shape_from": {"tokens": "t", "per_token": "k", "hidden": "d", "width": "f",
                                    "held": "n", "layers": "l", "dense_layers": "dense"}}

@@ -5,12 +5,11 @@ Laguna-S-2.1's keys; ``parallel/sparse_attention.py``'s batched causal
 kernel visiting a band's tiles alone) against the benchmark's plain
 reference (``benchmark/reference/laguna_decoder.py``: a dense band mask) at
 small sizes on the CPU; the expert layer at ten a token, the first k that
-is no power of two; the shares of the expert layer; the new cell's manifest
-entries, counters and counts."""
+is no power of two; the shares of the expert layer; the new cell's
+counters and counts."""
 
 import dataclasses
 import functools
-import importlib
 import json
 import os
 import subprocess
@@ -26,6 +25,7 @@ from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import row_gather
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
+from test_manifest_entries import BENCH, need, ratio_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -737,76 +737,18 @@ def test_the_other_five_readers_have_nothing_of_what_this_one_brought(name):
     for i, layer in enumerate(shapes["layers"]):
         if got.layer_kind(i)[0] == FULL:  # grouped-query attention keeps its per-head norms, ungated
             assert "q_norm" in layer and "k_norm" in layer and "w_attn_gate" not in layer
-    metrics = [e["name"] for e in _manifest()["per_layer"] if e["name"].endswith("." + OTHERS[name])]
-    assert metrics and not [n for n in metrics if n.startswith("window")]
 
 
 # ---------------------------------------------------------------------------
-# the manifest's new files
+# the cell and its counts (its manifest entries: tests/test_manifest_entries.py)
 # ---------------------------------------------------------------------------
-
-def _manifest():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
-LAGUNA_METRICS = ["proj_ms.laguna", "sparse_attn_ms.laguna", "window_attn_ms.laguna",
-                  "shared_expert_ms.laguna", "moe_ms.laguna", "mlp_ms.laguna",
-                  "masked_gqa_attention_roofline_share.laguna",
-                  "windowed_attention_roofline_share.laguna", "gmm_ahead_roofline_share.laguna",
-                  "step_mfu.laguna", "window_pairs_share.laguna", "attn_live_tile_share.laguna",
-                  "expert_load_peak.laguna", "held_rows_share.laguna", "ahead_rows_share.laguna"]
-COUNTERS = (decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS + decoder.LINEAR_STATS
-            + decoder.AHEAD_STATS)
-
-
-@pytest.mark.parametrize("name", LAGUNA_METRICS)
-def test_every_metric_file_of_the_laguna_cell_names_a_reader_and_keys_that_exist(name):
-    manifest = _manifest()
-    entry, = [e for e in manifest["per_layer"] if e["name"] == name]
-    assert entry["workloads"] == [CELL] and entry["moves"] == "fps.hit"
-    assert entry["layer"] == ("kernels" if "roofline" in name else "device program")
-    names = [e["name"] for e in manifest["per_layer"]]
-    at = names.index(LAGUNA_METRICS[0])  # appended as one run, in this order; later cells' after it
-    assert names[at:at + len(LAGUNA_METRICS)] == LAGUNA_METRICS
-    cfg = _file()
-    with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
-        spec = json.load(f)
-    assert callable(importlib.import_module(f"benchmark.readers.{spec['reader']}").read)
-    args = spec["args"]
-    if "function" in args:
-        module, fn = args["function"].rsplit(".", 1)
-        need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
-        given = set(args["shape_from"]) | ({"held_share"} if "share" in args else set())
-        assert given == set(need.__code__.co_varnames[:need.__code__.co_argcount])
-        assert all(path in cfg for path in args["shape_from"].values())
-    for key in ("pattern", "within"):
-        if args.get(key, "").startswith("@"):
-            assert args[key][1:] in cfg["trace_names"]
-    for key in ("numerator", "denominator"):
-        for counters in (args, args.get("share", {}), args.get("share_where_alone", {})):
-            assert counters.get(key, COUNTERS[0]) in COUNTERS
-    if "scope" in args:  # a scope the step has
-        assert args["scope"] in ("proj", "sparse_attn", "window_attn", "shared_expert", "moe", "mlp")
-
 
 def test_the_laguna_cell_follows_ling3_s_and_reports_the_host_path_as_the_decoders_do():
-    manifest = _manifest()
-    assert len(manifest["workloads"]) >= 9 and len(manifest["configs"]) >= 8
-    assert {w["chips"] for w in manifest["workloads"]} == {1}
-    cell = manifest["workloads"][8]  # the ninth cell of the eighth configuration; later ones after it
-    assert (cell["name"], cell["chips"], cell["traffic"], cell["config"]) == (
-        CELL, 1, "saturated", "laguna_s21_prefill_epix10k2m")
-    config = manifest["configs"][7]
-    assert config["file"] == os.path.relpath(CONFIG, REPO) and len(cell["why"]) <= 200
-    assert config["reduced"] == _file()["reduced"] and len(config["why"]) <= 200
-    assert config["source"] == _file()["source"]
-    shared = [e for e in manifest["per_layer"] + manifest["end_to_end"]
-              if "ling3_epix_saturated" in e.get("workloads", ()) and not e["name"].endswith(".ling3")]
-    assert len(shared) == 19 and "fps.hit" in [e["name"] for e in shared]
-    for e in shared:  # fps.hit and the 18 host-path metrics
-        at = e["workloads"].index(CELL)  # appended after ling3's; later cells after it
-        assert e["workloads"][at - 1] == "ling3_epix_saturated"
+    cell = BENCH.cell(CELL)
+    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "saturated", "laguna_s21_prefill_epix10k2m")
+    config = BENCH.config(cell["config"])
+    assert config["file"] == os.path.relpath(CONFIG, REPO)
+    assert max(len(cell["why"]), len(config["why"])) <= 200
     cfg = _file()
     assert cfg["transport"]["slots"] == 16 and cfg["batch_size"] == 2
     assert cfg["trace_names"]["window_kernel"] == "windowed_gqa_attention"  # the pallas_call's own name
@@ -815,27 +757,21 @@ def test_the_laguna_cell_follows_ling3_s_and_reports_the_host_path_as_the_decode
 
 
 def test_laguna_roofline_counts_at_the_published_sizes():
-    cfg = _file()
+    def count(function, **more):  # as the cell's file asks for it
+        fn, [shapes] = need(CELL, function)
+        return fn(**shapes, **more)
 
-    def need(name):
-        with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
-            args = json.load(f)["args"]
-        module, fn = args["function"].rsplit(".", 1)
-        function = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
-        shapes = {k: cfg[path] for k, path in args["shape_from"].items()}
-        return function(**shapes, **({"held_share": 0.25} if "share" in args else {}))
-
-    full = need("masked_gqa_attention_roofline_share.laguna")
+    full = count("laguna.full_attention")
     assert full["flops"] == 2 * 37884160 * 48 * 512 and round(full["flops"] / 1e12, 3) == 1.862
-    band = need("windowed_attention_roofline_share.laguna")
+    band = count("laguna.windowed_attention")
     assert band["flops"] == 2 * 4325632 * 72 * 512 and round(band["flops"] / 1e12, 3) == 0.319
     assert band["bytes"] == 2 * 17408 * 128 * (2 * 72 + 2 * 8)  # q, o, k, v once
     # the yardstick does not know the kernel's tiles: a tile as wide as the window meets twice the band
     visited = 33 * 512 * 512
     assert 1.9 < visited / 4325632 < 2.1
-    held = need("gmm_ahead_roofline_share.laguna")
+    held = count("laguna.held_products", held_share=0.25)
     assert held["call_sites"] == 24 and held["flops"] == 24 * 2 * 43520 * 3072 * 1024
-    step = need("step_mfu.laguna")
+    step = count("laguna.step")
     assert round(step["flops"] / 1e12, 1) == 38.7
     rows, d = 17408, 3072
     by_hand = (2 * 8448 * 2 * 256 * d + 2 * 2 * d * 25088
@@ -880,8 +816,12 @@ def test_the_cell_s_rehearsal_runs_the_served_path_is_correct_and_reports_its_co
     assert done.returncode == 0, done.stderr[-2000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["cell"] == CELL
-    for name in ("attn_live_tile_share.laguna", "window_pairs_share.laguna", "held_rows_share.laguna",
-                 "ahead_rows_share.laguna", "expert_load_peak.laguna", "ring_depth.hit",
+    counted = (("attn_tiles_live_total", "attn_tiles_causal_total"),
+               ("attn_pairs_selected_total", "attn_pairs_causal_total"),
+               ("expert_rows_held_total", "expert_rows_routed_total"),
+               ("expert_rows_ahead_total", "expert_rows_held_total"),
+               ("expert_tokens_max_total", "expert_tokens_mean_total"))
+    for name in (*(ratio_of(CELL, *counters) for counters in counted), "ring_depth.hit",
                  # the start's own account (PR 55), in every cell as setup_s is
                  "startup_trace_s", "startup_lower_s", "startup_cache_load_s", "startup_compile_s",
                  "startup_cache_misses", "startup_rest_s"):

@@ -2,10 +2,9 @@
 (``models/decoder.py`` reading LFM2-8B-A1B's keys), the maskless causal
 kernel and the sigmoid router with its selection bias, against the
 benchmark's plain reference (``benchmark/reference/lfm2_decoder.py``) at
-small sizes on the CPU; the new cell's manifest entries and counts."""
+small sizes on the CPU; the new cell's counters and counts."""
 
 import dataclasses
-import importlib
 import json
 import os
 
@@ -18,6 +17,7 @@ from benchmark.reference import lfm2_decoder as ref
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
+from test_manifest_entries import need
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -347,67 +347,20 @@ def test_counters_of_a_batched_stream_reach_the_snapshot_and_the_exposition():
 
 
 # ---------------------------------------------------------------------------
-# the manifest's new files
+# the cell's counts (its manifest entries: tests/test_manifest_entries.py)
 # ---------------------------------------------------------------------------
-
-def _manifest():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
-def test_every_metric_file_of_the_lfm2_cell_names_a_reader_that_exists():
-    mine = [e for e in _manifest()["per_layer"] if e.get("workloads") == ["lfm2_epix_saturated"]]
-    assert sorted(e["name"] for e in mine) == sorted([
-        "conv_ms.lfm2", "sparse_attn_ms.lfm2", "proj_ms.lfm2", "moe_ms.lfm2", "mlp_ms.lfm2",
-        "gmm_roofline_share.lfm2", "masked_gqa_attention_roofline_share.lfm2",
-        "gated_conv_taps_roofline_share.lfm2",
-        "expert_load_peak.lfm2", "step_mfu.lfm2"])
-    with open(CONFIG) as f:
-        cfg = json.load(f)
-    for entry in mine:
-        assert entry["moves"] == "fps.hit"
-        with open(os.path.join(REPO, "benchmark", "metrics", entry["name"] + ".json")) as f:
-            spec = json.load(f)
-        reader = importlib.import_module(f"benchmark.readers.{spec['reader']}")
-        assert callable(reader.read), entry["name"]
-        if "function" in spec["args"]:
-            module, fn = spec["args"]["function"].rsplit(".", 1)
-            need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
-            assert set(spec["args"]["shape_from"]) == set(
-                need.__code__.co_varnames[:need.__code__.co_argcount])
-            assert all(path in cfg for path in spec["args"]["shape_from"].values())
-        if spec["args"].get("pattern", "").startswith("@"):
-            assert spec["args"]["pattern"][1:] in cfg["trace_names"]
-
-
-@pytest.mark.parametrize("name", [
-    "producer_blocked_share.hit", "ring_depth.hit", "queue_dwell_ms.hit", "device_put_ms",
-    "infeed_wait_ms", "launch_ms.hit", "device_wait_ms.hit", "step_ms.hit",
-    "device_idle_share.hit", "stopped_ms.hit", "fps.hit",
-])
-def test_the_lfm2_cell_reports_the_host_path_under_the_names_the_hit_cell_has(name):
-    manifest = _manifest()
-    entry, = [e for e in manifest["per_layer"] + manifest["end_to_end"] if e["name"] == name]
-    assert entry["workloads"][:3] == ["hit_epix_saturated", "keye_epix_saturated",
-                                      "lfm2_epix_saturated"]  # later cells follow
-    calib, = [e for e in manifest["per_layer"] if e["name"] == "calib_roofline_share.hit"]
-    assert calib["workloads"] == ["hit_epix_saturated"]  # PERF.md section 7 (b)
-
 
 def test_lfm2_roofline_counts_at_the_published_sizes():
     from benchmark.roofline import decoder as shared
-    from benchmark.roofline import lfm2 as need
+    from benchmark.roofline import lfm2 as roofline
 
-    with open(CONFIG) as f:
-        cfg = json.load(f)
-    attn = need.causal_attention(4, 8704, 2048, 32, 8)
+    attn = roofline.causal_attention(4, 8704, 2048, 32, 8)
     assert attn["flops"] == 4 * 64 * 32 * 4 * (8704 * 8705 // 2)  # 1.24 T a layer
     assert attn["bytes"] == 2 * 4 * 8704 * 64 * (2 * 32 + 2 * 8)
     assert shared.grouped_product(34816, 4, 2048, 1792, 32)["flops"] == 2 * 34816 * 4 * 2048 * 1792
-    assert need.gated_conv_taps(34816, 2048, 3)["bytes"] == 34816 * 2048 * 2 * 4  # 570 MB: 0.70 ms
-    with open(os.path.join(REPO, "benchmark", "metrics", "step_mfu.lfm2.json")) as f:
-        shape_from = json.load(f)["args"]["shape_from"]
-    step = need.step(**{k: cfg[path] for k, path in shape_from.items()})
+    assert roofline.gated_conv_taps(34816, 2048, 3)["bytes"] == 34816 * 2048 * 2 * 4  # 570 MB: 0.70 ms
+    fn, [shapes] = need("lfm2_epix_saturated", "lfm2.step")  # as the cell's file asks for it
+    step = fn(**shapes)
     assert 53.0e12 < step["flops"] < 53.6e12  # ISSUE 38's 53.2 T, 270 ms at the peak
     experts = 10 * 3 * 2 * 34816 * 4 * 2048 * 1792
     assert 0.55 < experts / step["flops"] < 0.60  # the ten expert layers' products: 30.7 T

@@ -4,11 +4,10 @@ head-wise output gate, and the trunk that mixes them (``models/decoder.py``
 reading Ling-3.0's keys) against the benchmark's plain reference
 (``benchmark/reference/ling3_decoder.py``: the recurrence token by token) at
 small sizes on the CPU; the shares of the expert layer at 8 groups; the new
-cell's manifest entries, counters and counts; and, for every decoder cell at
+cell's counters and counts; and, for every decoder cell at
 once, what each configuration's reader has and has not."""
 
 import dataclasses
-import importlib
 import json
 import os
 import subprocess
@@ -23,6 +22,7 @@ from benchmark.reference import ling3_decoder as ref
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import delta_rule as dr
 from psana_ray_tpu.parallel import moe
+from test_manifest_entries import BENCH, asked, need, ratio_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -540,8 +540,6 @@ def test_the_other_four_readers_have_nothing_of_what_this_one_brought(name):
     kept = {"wq"} if OTHERS[name] in ("lfm2", "keye") else set()  # grouped-query attention's own
     for layer in shapes["layers"]:
         assert not (brought - kept) & set(layer)
-    metrics = [e["name"] for e in _manifest()["per_layer"] if e["name"].endswith("." + OTHERS[name])]
-    assert metrics and not [n for n in metrics if n.startswith("kda_")]
 
 
 # ---------------------------------------------------------------------------
@@ -594,80 +592,16 @@ def test_linear_counters_reach_the_snapshot_and_the_exposition():
 
 
 # ---------------------------------------------------------------------------
-# the manifest's new files
+# the cell, its readers and its counts (its manifest entries: tests/test_manifest_entries.py)
 # ---------------------------------------------------------------------------
 
-def _manifest():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
-LING3_METRICS = ["proj_ms.ling3", "conv_ms.ling3", "kda_ms.ling3", "latent_attn_ms.ling3",
-                 "shared_expert_ms.ling3", "moe_ms.ling3", "mlp_ms.ling3",
-                 "kda_roofline_share.ling3", "latent_attention_roofline_share.ling3",
-                 "gmm_roofline_share.ling3", "step_mfu.ling3", "expert_load_peak.ling3",
-                 "held_rows_share.ling3", "kda_chunk_rows.ling3", "ahead_rows_share.ling3",
-                 "gmm_ahead_roofline_share.ling3"]
-COUNTERS = (decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS + decoder.LINEAR_STATS
-            + decoder.AHEAD_STATS)
-
-
-def _spec_names_what_exists(name, cfg):
-    """A metric's data file names a reader that exists, a roofline function
-    that takes exactly the shapes it is given from keys ``cfg`` has, trace
-    names the file declares and counters the step counts -> its ``args``."""
-    with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
-        spec = json.load(f)
-    assert callable(importlib.import_module(f"benchmark.readers.{spec['reader']}").read), name
-    args = spec["args"]
-    if "function" in args:
-        module, fn = args["function"].rsplit(".", 1)
-        need = getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn)
-        given = set(args["shape_from"]) | ({"held_share"} if "share" in args else set())
-        assert given == set(need.__code__.co_varnames[:need.__code__.co_argcount]), name
-        assert all(path.split(".")[0] in cfg for path in args["shape_from"].values()), name
-    for key in ("pattern", "within"):
-        if args.get(key, "").startswith("@"):
-            assert args[key][1:] in cfg["trace_names"], name
-    for key in ("numerator", "denominator"):
-        for counters in (args, args.get("share", {}), args.get("share_where_alone", {})):
-            assert counters.get(key, COUNTERS[0]) in COUNTERS, name
-    return args
-
-
-@pytest.mark.parametrize("name", LING3_METRICS)
-def test_every_metric_file_of_the_ling3_cell_names_a_reader_and_keys_that_exist(name):
-    manifest = _manifest()
-    entry, = [e for e in manifest["per_layer"] if e["name"] == name]
-    assert entry["workloads"] == [CELL] and entry["moves"] == "fps.hit"
-    assert entry["layer"] == ("kernels" if "roofline" in name else "device program")
-    names = [e["name"] for e in manifest["per_layer"]]
-    at = names.index(LING3_METRICS[0])  # appended as one run, in this order; later cells' after it
-    assert names[at:at + len(LING3_METRICS)] == LING3_METRICS
-    cfg = _file()
-    args = _spec_names_what_exists(name, cfg)
-    if "scope" in args:  # a scope the step has
-        assert args["scope"] in ("proj", "conv", "kda", "latent_attn", "shared_expert", "moe", "mlp")
-
-
 def test_the_ling3_cell_follows_dsv32_s_and_reports_the_host_path_as_the_decoders_do():
-    manifest = _manifest()
-    assert {w["chips"] for w in manifest["workloads"]} == {1}
-    cell = manifest["workloads"][7]  # the eighth cell of the seventh configuration; later ones after it
-    assert (cell["name"], cell["chips"], cell["traffic"], cell["config"]) == (
-        CELL, 1, "saturated", "ling3_flash_prefill_epix10k2m")
-    config = manifest["configs"][6]
+    cell = BENCH.cell(CELL)
+    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "saturated", "ling3_flash_prefill_epix10k2m")
+    config = BENCH.config(cell["config"])
     assert config["file"] == os.path.relpath(CONFIG, REPO) and len(cell["why"]) <= 200
     assert config["reduced"] == _file()["reduced"] == [
         "num_hidden_layers", "first_k_dense_replace", "num_experts", "vocab_size"]
-    shared = [e for e in manifest["per_layer"] + manifest["end_to_end"]
-              if "dsv32_epix_saturated" in e.get("workloads", ()) and not e["name"].endswith(".dsv32")]
-    assert len(shared) == 19 and "fps.hit" in [e["name"] for e in shared]
-    for e in shared:  # fps.hit and the 18 host-path metrics
-        at = e["workloads"].index(CELL)  # appended after dsv32's; later cells after it
-        assert e["workloads"][at - 1] == "dsv32_epix_saturated"
-    roofline = [e for e in manifest["per_layer"] if e["name"] == "calib_roofline_share.hit"]
-    assert roofline[0]["workloads"] == ["hit_epix_saturated"]
     cfg = _file()
     assert cfg["transport"]["slots"] == 16 and cfg["batch_size"] == 4
     assert cfg["trace_names"]["kda_kernel"] == "gated_delta_rule"  # the pallas_call's own name
@@ -723,10 +657,10 @@ def test_the_pass_s_products_are_read_by_name_beside_the_kernels_of_its_way_back
         spool_path=str(tmp_path / "spans" / "spool"),
         metrics=types.SimpleNamespace(snapshot=lambda: counters),
         peaks={"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e30})
-    with open(os.path.join(REPO, "benchmark", "metrics", "gmm_ahead_roofline_share.ling3.json")) as f:
-        args = json.load(f)["args"]
-    args["shape_from"] = {"tokens": "t", "per_token": "k", "hidden": "d", "width": "f", "held": "n",
-                          "layers": "l", "dense_layers": "dense"}
+    [read_with, *_] = asked(CELL, reader="roofline_share_named_per_run").values()  # the cell's file
+    args = {**read_with,
+            "shape_from": {"tokens": "t", "per_token": "k", "hidden": "d", "width": "f", "held": "n",
+                           "layers": "l", "dense_layers": "dense"}}
     got = roofline_share_named_per_run.read(ctx, **args)
     err = capsys.readouterr().err
     assert (said in err) if said else not err
@@ -742,29 +676,21 @@ def test_ling3_roofline_counts_at_the_published_sizes():
     from benchmark.roofline import kimi_k2
     from benchmark.roofline import ling3 as roofline
 
-    cfg = _file()
-
-    def need(name):
-        with open(os.path.join(REPO, "benchmark", "metrics", name + ".json")) as f:
-            args = json.load(f)["args"]
-        module, fn = args["function"].rsplit(".", 1)
-        return getattr(importlib.import_module(f"benchmark.roofline.{module}"), fn), {
-            k: cfg[path] for k, path in args["shape_from"].items()}
-
-    fn, shapes = need("kda_roofline_share.ling3")
+    fn, [shapes] = need(CELL, "ling3.delta_rule")
     rule = fn(**shapes)
     assert rule["flops"] == 7 * 128 * 128 * 32 * 34816  # 0.128 T a layer, whatever the chunk
     assert rule["bytes"] == 34816 * 32 * (5 * 2 * 128 + 4)  # 1.43 GB: bound by bytes
     assert rule["bytes"] / 819e9 > rule["flops"] / 197e12
     assert "chunk" not in fn.__code__.co_varnames
-    fn, shapes = need("latent_attention_roofline_share.ling3")
+    fn, [shapes] = need(CELL, "kimi_k2.latent_attention")
     attention = fn(**shapes)["flops"]
     assert attention == kimi_k2.latent_attention(2, 8704, 64, 128, 64, 128)["flops"]  # kimi's grid
     assert abs(attention / 1e12 - 3.10) < 0.01
-    fn, shapes = need("gmm_roofline_share.ling3")
-    held = fn(held_share=128 / 512, **shapes)
-    assert held["call_sites"] == 18 and held["flops"] == 18 * 2 * 69632 * 2560 * 768
-    fn, shapes = need("step_mfu.ling3")
+    fn, asked_for = need(CELL, "kimi_k2.held_products")
+    for shapes in asked_for:  # of each entry that names it
+        held = fn(held_share=128 / 512, **shapes)
+        assert held["call_sites"] == 18 and held["flops"] == 18 * 2 * 69632 * 2560 * 768
+    fn, [shapes] = need(CELL, "ling3.step")
     step = fn(**shapes)["flops"]
     assert abs(step / 1e12 - 43.7) < 0.1
     rows = 34816
@@ -776,22 +702,6 @@ def test_ling3_roofline_counts_at_the_published_sizes():
     assert step == pytest.approx(6 * linear + latent + 6 * sparse + rest, rel=1e-12)
     assert abs(2 * 2560 * (6 * 4096 + 32) / 1e6 - 126) < 0.5  # a linear layer's products a token
     assert roofline.step.__code__.co_argcount == len(shapes)
-
-
-@pytest.mark.parametrize("name", sorted(OTHERS) + ["ling3_flash_prefill_epix10k2m"])
-def test_a_decoder_cell_s_own_metrics_read_functions_counters_and_names_that_exist(name):
-    """What repeats across the decoder cells, as ONE rule over the manifest:
-    every per-layer metric a decoder cell has to itself names a reader that
-    exists, a roofline function that takes exactly the shapes it is given
-    from keys its configuration has, trace names the file declares, and
-    counters the step counts."""
-    manifest, cfg = _manifest(), _file(name)
-    cell, = [w["name"] for w in manifest["workloads"] if w["config"] == name]
-    own = [e for e in manifest["per_layer"] if e.get("workloads") == [cell]]
-    assert len(own) >= 9 and len({e["name"].rsplit(".", 1)[1] for e in own}) == 1
-    assert any("roofline" in e["name"] for e in own)
-    for entry in own:
-        _spec_names_what_exists(entry["name"], cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -830,7 +740,9 @@ def test_the_cell_s_rehearsal_runs_the_served_path_and_is_correct():
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["metrics"] == {}
     assert line["cell"] == CELL and line["attempted"] > 0
-    for name in ("kda_chunk_rows.ling3", "held_rows_share.ling3", "expert_load_peak.ling3",
-                 "device_wait_ms.hit"):
-        assert name in line["would_report"], line["would_report"]
+    for counted in (("linear_attn_tokens_total", "linear_attn_chunks_total"),
+                    ("expert_rows_held_total", "expert_rows_routed_total"),
+                    ("expert_tokens_max_total", "expert_tokens_mean_total")):
+        assert ratio_of(CELL, *counted) in line["would_report"], line["would_report"]
+    assert "device_wait_ms.hit" in line["would_report"]
     assert "compiles inside the window 0" in done.stderr

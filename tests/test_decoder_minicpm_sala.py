@@ -23,6 +23,7 @@ from benchmark.roofline import minicpm_sala as roofline
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import lightning
 from psana_ray_tpu.parallel import sparse_attention as sa
+from test_manifest_entries import BENCH
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -473,16 +474,11 @@ def test_the_configuration_keeps_every_published_width_and_lists_what_it_assumes
     assert cfg["sequence_tokens"] == 34304 > cfg["sparse_config"]["dense_len"]
 
 
-def test_the_manifest_has_the_cell_and_lists_it_where_every_hit_cell_is_listed():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+def test_the_manifest_s_cell_runs_this_configuration_under_saturated_traffic_on_one_chip():
+    cell = BENCH.cell(CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == ("minicpm_sala_prefill_epix10k2m", "saturated", 1)
-    assert manifest["workloads"][-1] is cell and manifest["configs"][-1]["name"] == cell["config"]
-    fps = next(e for e in manifest["end_to_end"] if e["name"] == "fps.hit")
-    assert fps["workloads"][-1] == CELL
-    listed = [e for e in manifest["per_layer"] if CELL in e.get("workloads", ())]
-    assert len(listed) == 18 and all(e["moves"] == "fps.hit" and e["workloads"][-1] == CELL for e in listed)
+    with open(CONFIG) as f:
+        assert BENCH.file(CELL) == json.load(f)
 
 
 def test_the_adapter_names_fields_the_decoder_has():

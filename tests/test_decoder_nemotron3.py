@@ -24,6 +24,7 @@ from benchmark.reference import nemotron3_decoder as ref
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import ssd
 from psana_ray_tpu.parallel import moe
+from test_manifest_entries import BENCH
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -528,11 +529,6 @@ def _file(name=NAME):
         return json.load(f)
 
 
-def _manifest():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
 def test_from_mapping_reads_the_catalog_row_s_keys():
     got = decoder.DecoderConfig.from_mapping(_catalog_row()["config"])
     kinds = [got.layer_kind(i) for i in range(got.num_layers)]
@@ -586,8 +582,6 @@ def test_the_file_holds_the_catalog_s_numbers_unchanged_and_names_its_cuts():
     assert cfg["deployment"].startswith("2 chips share each layer")
     assert (cfg["n_routed_experts"], cfg["router_experts"], cfg["experts_held"]) == (64, 128, [0, 64])
     assert cfg["batch_size"] * cfg["sequence_tokens"] == cfg["step_tokens"] == 34816
-    entry = next(c for c in _manifest()["configs"] if c["name"] == NAME)
-    assert entry["reduced"] == cfg["reduced"] and entry["source"] == cfg["source"]
     assert not [k for k in cfg["reduced"] if k.endswith(("_dim", "_rank", "hidden_size"))
                 or "intermediate" in k]  # no width is cut
     got = decoder.DecoderConfig.from_mapping(cfg)
@@ -614,20 +608,9 @@ def test_the_other_eight_readers_have_nothing_of_what_this_one_brought(name):
         assert ("shared_up" in layer) == ("shared_gate" in layer)
 
 
-def test_the_cell_is_the_manifest_s_twelfth_and_reports_the_host_path_as_the_decoders_do():
-    manifest = _manifest()
-    cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
+def test_the_cell_runs_this_configuration_under_saturated_traffic_on_one_chip():
+    cell = BENCH.cell(CELL)
     assert cell == {**cell, "config": NAME, "traffic": "saturated", "chips": 1}
-    # (the twelfth; later cells are appended after it: PR 67's is the thirteenth)
-    assert manifest["workloads"][11]["name"] == CELL and len(manifest["workloads"]) >= 12
-    assert len(manifest["per_layer"]) == 128  # full: no entry of this cell's own
-    fps = next(e for e in manifest["end_to_end"] if e["name"] == "fps.hit")
-    assert fps["workloads"][9] == CELL and len(fps["workloads"]) >= 10
-    listing = [e["name"] for e in manifest["per_layer"] if CELL in e.get("workloads", ())]
-    assert len(listing) == 18 and all(
-        e["workloads"][8:10] == ["ouro_epix_saturated", CELL]
-        for e in manifest["per_layer"] if e["name"] in listing)
-    assert not [e["name"] for e in manifest["per_layer"] if "nemotron" in e["name"]]
     cfg = _file()
     assert cfg["transport"] == {"scheme": "shm", "slots": 16} and cfg["program"] == "prefill_blocks"
     assert cfg["reference"]["module"] == "nemotron3_decoder"

@@ -25,6 +25,7 @@ from benchmark.roofline import olmo_hybrid as roofline
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import delta_rule as dr
 from psana_ray_tpu.parallel import sparse_attention as sa
+from test_manifest_entries import BENCH
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -382,10 +383,7 @@ def test_the_file_holds_the_catalog_s_numbers_unchanged_and_names_its_cuts():
     with open(CATALOG) as f:
         row = next(r for r in map(json.loads, f) if r["name"] == "Olmo-Hybrid-7B")
     cfg, reduced = _file(), ["num_hidden_layers"]
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        entry = next(c for c in json.load(f)["configs"] if c["name"] == cfg["name"])
-    assert entry["source"] == cfg["source"] == row["source_url"] and entry["reduced"] == reduced
-    assert cfg["reduced"] == reduced
+    assert cfg["source"] == row["source_url"] and cfg["reduced"] == reduced
     for key, value in row["config"].items():
         if key == "layer_types":
             assert cfg[key] == value[:16] and value[:4] * 8 == value
@@ -482,17 +480,8 @@ def test_linear_and_block_counters_reach_the_snapshot_and_the_exposition():
 
 
 def test_the_cell_follows_granite_s_and_reports_the_host_path_as_the_decoders_do():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        manifest = json.load(f)
-    cells = {w["name"]: w for w in manifest["workloads"]}
-    # (the thirteenth; later cells are appended after it: PR 69's is the fourteenth)
-    assert list(cells)[12] == CELL and cells[CELL]["chips"] == 1 and cells[CELL]["traffic"] == "saturated"
-    assert len(manifest["workloads"]) >= 13 and len(manifest["per_layer"]) == 128
-    granite = {m["name"] for kind in ("end_to_end", "per_layer") for m in manifest[kind]
-               if "workloads" in m and "granite_epix_saturated" in m["workloads"]}
-    mine = {m["name"] for kind in ("end_to_end", "per_layer") for m in manifest[kind]
-            if "workloads" in m and CELL in m["workloads"]}
-    assert mine == granite - {"ssd_roofline_share.granite"} and "fps.hit" in mine and len(mine) == 19
+    cell = BENCH.cell(CELL)
+    assert (cell["chips"], cell["traffic"]) == (1, "saturated") and BENCH.file(CELL) == _file()
     cfg = _file()
     assert cfg["program"] == "prefill_reordered" and cfg["transport"]["slots"] == 4
     assert (cfg["patch"], cfg["prompt_tokens"], cfg["sequence_tokens"], cfg["batch_size"]) == (16, 256, 8704, 1)

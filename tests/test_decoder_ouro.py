@@ -2,8 +2,8 @@
 sandwich-normed layers run ``total_ut_steps`` times with the SAME weights,
 the final norm and an exit gate after every pass) against the benchmark's
 plain reference (``benchmark/reference/ouro_decoder.py``: the passes written
-out) at small sizes on the CPU; the new cell's configuration file, manifest
-entries, adapter, counters and counts."""
+out) at small sizes on the CPU; the new cell's configuration file,
+adapter, counters and counts."""
 
 import dataclasses
 import json
@@ -18,6 +18,7 @@ import pytest
 
 from benchmark.reference import ouro_decoder as ref
 from psana_ray_tpu.models import decoder
+from test_manifest_entries import BENCH
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
@@ -101,11 +102,6 @@ def _calls(jaxpr, name):
 
 def _file(name=NAME):
     with open(os.path.join(CONFIGS, name + ".json"), encoding="utf-8") as f:
-        return json.load(f)
-
-
-def _manifest():
-    with open(os.path.join(REPO, "BENCHMARK.json"), encoding="utf-8") as f:
         return json.load(f)
 
 
@@ -351,25 +347,12 @@ def test_the_other_seven_readers_have_nothing_of_what_this_one_brought(name):
 
 
 def test_the_ouro_cell_follows_granite_s_and_reports_the_host_path_as_the_decoders_do():
-    manifest = _manifest()
-    assert len(manifest["workloads"]) >= 11 and len(manifest["configs"]) >= 10
-    cell = manifest["workloads"][10]  # the eleventh cell of the tenth configuration; later ones after it
-    assert (cell["name"], cell["chips"], cell["traffic"], cell["config"]) == (
-        CELL, 1, "saturated", NAME)
-    config = manifest["configs"][9]
+    cell = BENCH.cell(CELL)
+    assert (cell["chips"], cell["traffic"], cell["config"]) == (1, "saturated", NAME)
+    config = BENCH.config(NAME)
     assert config["file"] == f"benchmark/configs/{NAME}.json" and len(cell["why"]) <= 200
     assert config["reduced"] == _file()["reduced"] == [] and len(config["why"]) <= 200
-    assert config["source"] == _file()["source"] and "catalog row Ouro-2.6B" in config["why"]
-    shared = [e for e in manifest["per_layer"] + manifest["end_to_end"]
-              if "granite_epix_saturated" in e.get("workloads", ()) and not e["name"].endswith(".granite")]
-    assert len(shared) == 19 and "fps.hit" in [e["name"] for e in shared]
-    for e in shared:  # fps.hit and the 18 host-path and device metrics every decoder cell reports
-        assert e["workloads"].index(CELL) == e["workloads"].index("granite_epix_saturated") + 1
-    # per_layer stands at its limit of 128: this cell brings no entry of its own
-    assert len(manifest["per_layer"]) == 128
-    assert not [e for e in manifest["per_layer"] if e["name"].endswith(".ouro")]
-    assert CELL not in next(e for e in manifest["per_layer"]
-                            if e["name"] == "ssd_roofline_share.granite")["workloads"]
+    assert "catalog row Ouro-2.6B" in config["why"]
 
 
 # ---------------------------------------------------------------------------

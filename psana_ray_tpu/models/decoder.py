@@ -101,7 +101,7 @@ import numpy as np
 from psana_ray_tpu.ops import lightning
 from psana_ray_tpu.ops.delta_rule import (CHUNK, HEAD_CHUNK, chunk_rows, gated_delta_net,
                                           gated_delta_rule, lanes_a_head)
-from psana_ray_tpu.ops.short_conv import gated_conv_taps
+from psana_ray_tpu.ops.short_conv import conv_silu_taps, gated_conv_taps
 from psana_ray_tpu.ops.ssd import scan_rows, ssd_scan
 from psana_ray_tpu.parallel import sparse_attention as sa
 from psana_ray_tpu.parallel.moe import dropless_moe, goes_ahead, hidden_rows, rows_ahead
@@ -1287,15 +1287,9 @@ def conv_silu(u, taps_w, seq_len: int, bias=None):
     """``silu(conv(u) + bias)`` on ``u [T, C]`` (whole sequences of ``seq_len``
     rows): a causal depthwise convolution, tap ``j`` of ``taps_w [C, taps]``
     on ``u[t - (taps - 1) + j]``, zeros before each sequence's first row,
-    ``bias [C]`` where the model has one; float32 inside, ``u``'s type out."""
-    t, c = u.shape
-    taps = taps_w.shape[1]
-    w = taps_w.astype(jnp.float32)
-    padded = jnp.pad(u.reshape(t // seq_len, seq_len, c), ((0, 0), (taps - 1, 0), (0, 0)))
-    acc = sum(w[:, j] * padded[:, j:j + seq_len].astype(jnp.float32) for j in range(taps))
-    if bias is not None:
-        acc = acc + bias.astype(jnp.float32)
-    return jax.nn.silu(acc).astype(u.dtype).reshape(t, c)
+    ``bias [C]`` where the model has one; float32 inside, ``u``'s type out:
+    ONE kernel (``ops/short_conv.conv_silu_taps``)."""
+    return conv_silu_taps(u, taps_w, bias, seq_len=seq_len)
 
 
 def _linear_projections(p, x, cfg: DecoderConfig):
@@ -1392,7 +1386,8 @@ def linear_attention(p, x, batch: int, cfg: DecoderConfig):
     gated by ``silu(a W_z)``. Then ``W_o``. No rotary. Under
     the scopes ``proj`` (the norm, the four products, ``W_o`` with the
     branch's norm as its epilogue), ``conv`` (the three convolutions and their
-    SiLU: one pass over ``[q | k | v]``) and the kernel under a scope of its
+    SiLU: one kernel's pass over ``[q | k | v]``, or over q, k and v each:
+    ``ops/short_conv.conv_silu_taps``) and the rule's kernel under a scope of its
     own, ``kda`` or ``gdn`` (the gate, the L2 norms, the recurrence and the
     output's norm and gate: ONE kernel, ``ops/delta_rule.gated_delta_rule``
     or ``gated_delta_net``)."""
@@ -1467,7 +1462,7 @@ def state_space(p, x, batch: int, cfg: DecoderConfig):
     over all of a token's channels, or over each group's; then ``W_out``. No rotary, no bias in
     the products. Under the scopes ``proj`` (the norm, ``W_in``'s three
     products, ``W_out``), ``conv`` (the convolution, its bias and SiLU: one
-    pass over ``[T, H*P + 2*N]``) and ``ssd`` (the step's softplus, the
+    kernel's pass over ``[T, H*P + 2*G*N]``, ``ops/short_conv.conv_silu_taps``) and ``ssd`` (the step's softplus, the
     decays, the scan, the skip, the gate and the norm: ONE kernel,
     ``ops/ssd.ssd_scan``). Each part is jitted by NAME: the layers of a
     model trace and lower once, however many they are."""

@@ -421,6 +421,19 @@ def _latent_block(b, heads, takes, masked=False):
 LING3_B, LING3_S, LING3_H = 4, 8704, 32  # four frames of 8,448 patches + 256 prompt tokens
 
 
+def _one_kernel_and_no_copy_of(kernel, dims):
+    """A pin on a compiled program's text: its only Mosaic kernel is ``kernel``,
+    and no copy, slice, pad or fusion of an array of ``dims`` (a regex of its
+    dimensions) stands in the entry computation beside it."""
+    def one_kernel_and_no_copy_of_its_operands(text):
+        kernels = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text, re.M)
+        assert [k.split(".")[0] for k in kernels] == [kernel], kernels
+        entry = text[text.index("ENTRY"):]
+        assert not re.search(rf"= \w+\[{dims}\][^ ]* (copy|slice|pad|fusion)\(", entry)
+
+    return one_kernel_and_no_copy_of_its_operands
+
+
 def _ling3_delta_rule():
     """The gated delta rule with a per-channel decay at Ling-3.0's linear
     layers' sizes: 4 x 32 head-sequences of 8,704 tokens, heads of 128, in
@@ -436,24 +449,23 @@ def _ling3_delta_rule():
 
     rows, wide = LING3_B * LING3_S, LING3_H * 128
 
-    def one_kernel_and_no_copy_of_its_operands(text):
-        kernels = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text, re.M)
-        assert [k.split(".")[0] for k in kernels] == ["gated_delta_rule"], kernels
-        entry = text[text.index("ENTRY"):]
-        assert not re.search(rf"= \w+\[{rows},{3 * wide}\][^ ]* (copy|slice|fusion)\(", entry)
-
     return fn, [S((rows, 3 * wide), BF16), S((rows, wide), F32), S((rows, wide), BF16),
                 S((rows, LING3_H), F32), S((LING3_H,), F32), S((wide,), F32), S((128,), F32)], 1, \
-        one_kernel_and_no_copy_of_its_operands
+        _one_kernel_and_no_copy_of("gated_delta_rule", f"{rows},{3 * wide}")
 
 
-def _ling3_conv():
-    """The three 4-tap convolutions and their SiLU over ``[q | k | v]``
-    (34,816 x 12,288): XLA's own, in one pass (no Mosaic kernel)."""
+def _conv_silu(rows, wide, bias=False):
+    """The 4-tap convolution and its SiLU ahead of a delta rule or a scan, at a
+    cell's own shape (sequences of 8,704 rows; ``bias`` where the model has
+    one): ONE Mosaic kernel (``ops/short_conv.conv_silu_taps``; XLA's loop
+    fusion until PR 73) that reads the product's array where it lies and
+    writes the next kernel's operand: no copy, slice, pad or fusion of
+    ``[rows, wide]`` beside it."""
     from psana_ray_tpu.models.decoder import conv_silu
 
-    rows, wide = LING3_B * LING3_S, 3 * LING3_H * 128
-    return (lambda u, w: conv_silu(u, w, LING3_S)), [S((rows, wide), BF16), S((wide, 4), BF16)], 0
+    return (lambda u, w, *b: conv_silu(u, w, LING3_S, *b)), [
+        S((rows, wide), BF16), S((wide, 4), BF16), *[S((wide,), BF16)] * bias], 1, \
+        _one_kernel_and_no_copy_of("conv_silu_taps", f"{rows},{wide}")
 
 
 def _granite_ssd_scan():
@@ -468,15 +480,9 @@ def _granite_ssd_scan():
         return ssd_scan(xbc, z, dt, dt_bias, a_log, skip, gain, seq_len=8704, heads=64, state=128,
                         eps=1e-5, interpret=False)
 
-    def one_kernel_and_no_copy_of_its_operands(text):
-        kernels = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text, re.M)
-        assert [k.split(".")[0] for k in kernels] == ["ssd_scan"], kernels
-        entry = text[text.index("ENTRY"):]
-        assert not re.search(r"= \w+\[8704,(4352|4096)\][^ ]* (copy|slice|fusion)\(", entry)
-
     return fn, [S((8704, 4352), BF16), S((8704, 4096), BF16), S((8704, 64), F32), S((64,), F32),
                 S((64,), F32), S((64,), F32), S((4096,), BF16)], 1, \
-        one_kernel_and_no_copy_of_its_operands
+        _one_kernel_and_no_copy_of("ssd_scan", "8704,(4352|4096)")
 
 
 def _nemotron3_ssd_scan():
@@ -491,16 +497,10 @@ def _nemotron3_ssd_scan():
         return ssd_scan(xbc, z, dt, dt_bias, a_log, skip, gain, seq_len=8704, heads=64, state=128,
                         eps=1e-5, interpret=False)
 
-    def one_kernel_and_no_copy_of_its_operands(text):
-        kernels = re.findall(r'^\s*(?:ROOT )?%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text, re.M)
-        assert [k.split(".")[0] for k in kernels] == ["ssd_scan"], kernels
-        entry = text[text.index("ENTRY"):]
-        assert not re.search(r"= \w+\[34816,(6144|4096)\][^ ]* (copy|slice|fusion)\(", entry)
-
     rows = 4 * 8704
     return fn, [S((rows, 6144), BF16), S((rows, 4096), BF16), S((rows, 64), F32), S((64,), F32),
                 S((64,), F32), S((64,), F32), S((4096,), BF16)], 1, \
-        one_kernel_and_no_copy_of_its_operands
+        _one_kernel_and_no_copy_of("ssd_scan", "34816,(6144|4096)")
 
 
 def _nemotron3_attention():
@@ -542,7 +542,11 @@ CASES = {
     "nemotron3_causal_gqa_attention_4x8704x32_on_2x128": _nemotron3_attention,
     "granite_ssd_scan_8704x64x64x128": _granite_ssd_scan,
     "ling3_gated_delta_rule_4x8704x32x128": _ling3_delta_rule,
-    "ling3_conv_silu_34816x12288": _ling3_conv,
+    "ling3_conv_silu_34816x12288": lambda: _conv_silu(LING3_B * LING3_S, 3 * LING3_H * 128),
+    "nemotron3_conv_silu_34816x6144_with_a_bias": lambda: _conv_silu(34816, 6144, bias=True),
+    "granite_conv_silu_8704x4352_with_a_bias": lambda: _conv_silu(8704, 4352, bias=True),
+    "olmo_hybrid_conv_silu_8704x3840_q_and_k_a_head_at_whole_lane_tiles": lambda: _conv_silu(8704, 3840),
+    "olmo_hybrid_conv_silu_8704x5760_v": lambda: _conv_silu(8704, 5760),
     "dsv32_select_keys_8704x64x128": _dsv32_select,
     "dsv32_masked_latent_attention_1x8704x128x192": _dsv32_attention,
     "kimi_latent_attention_2x8704x64x192": _kimi_attention,
@@ -755,7 +759,20 @@ PINNED_STEPS = {
     # [17408, 3072]` is 102 MiB and stays XLA's by that rule, kimi's and dsv32's turns move fewer
     # rows than `x` holds, lfm2's and keye's 2,048 columns take the kernel operand for operand as
     # they did, granite's, ouro's and olmo_hybrid's gather no rows)
-    "ling3_flash_prefill_epix10k2m": "fd072057a39e23670358e0155bc1c4bf31c891026c12373957332062ee035db6",
+    # (the FOUR steps that run `decoder.conv_silu` — ling3's, granite's, nemotron3's and olmo_hybrid's —
+    # re-pinned in PR 73, knowingly: ahead of every delta rule and every scan a `conv_silu_taps` call
+    # (`ops/short_conv.py`: the array, the taps transposed to float32 `[taps, C]` and, in granite's
+    # and nemotron3's, the bias as float32 `[1, C]` -> the array's shape and type) stands where the
+    # `pad`, the four `slice`s and `convert`s, the multiplies, the adds and the `logistic` of XLA's
+    # loop fusion stood: six calls in ling3's step over `[34816, 12288]`, 36 in granite's over
+    # `[8704, 4352]`, six in nemotron3's over `[34816, 6144]`, 36 in olmo_hybrid's (q's and k's over
+    # `[8704, 3840]` with the taps laid a head at whole lane tiles as before, v's over `[8704, 5760]`);
+    # the kernel's output equals the fusion's to the bit at all five shapes on the chip (PERF.md
+    # section 5). The six others — keye's, lfm2's, kimi's, dsv32's, laguna's and the looped reader's —
+    # were hashed before and after and did not move: none calls `conv_silu`, and lfm2's
+    # `gated_conv_taps` call is operand for operand what it was (its body, which now reaches the
+    # rows before a row through the function `conv_silu_taps` shares, rides in `backend_config`))
+    "ling3_flash_prefill_epix10k2m": "f9b85f1ad1bb9f9a6a99f9637b189a7e8cd137e7bc2380ebe3f580e060ab16d0",
     # laguna's re-pinned in PR 58, knowingly: its nine attention calls take k, v and the query
     # tile's gate where their products wrote them, q as `[G, H/G, B*S, d]` (a layout of the
     # rotary's fusion) and write o token-major `[B, 1, S, H*128]`, already gated, for `W_o` to
@@ -772,7 +789,7 @@ PINNED_STEPS = {
     # pinned in PR 57, which brought it: the six above were hashed on PR 56's tree first and none
     # moved, though every one of them now traces `_projections`, `embed`, `logits_of` and `trunk`
     # through the new fields' branches (taken in Python, before anything is traced)
-    "granite4_h_micro_prefill_epix10k2m": "3bf51190128b2aab64f95e6afe217682c5b35f672d3badb3a9179d9397645b37",
+    "granite4_h_micro_prefill_epix10k2m": "c4e620ad545884561b27a758dbb6a5655e7185cb6fd50a6f8553a5adf012b04e",
     # pinned in PR 60, which brought it: the seven above were hashed on PR 58's tree first and none
     # moved (the looped trunk, the sandwich and the gate are branches taken in Python, before
     # anything is traced; at one pass `trunk` is the code it was, to the letter)
@@ -786,14 +803,14 @@ PINNED_STEPS = {
     # `grouped_tiles`' and `rows_as_words`' rules for a width of 14.5 or 10.5 lane tiles
     # (re-pinned in PR 70 with ling3's, above: six `rows_as_words` and six `row_gather` calls where
     # six `gather`s of `x [34816, 2688]` stood)
-    "nemotron3_nano_prefill_epix10k2m": "9c74fe514a0a8d30f7a0485c551013344875141c77598cd7fd89a0c3ea893e01",
+    "nemotron3_nano_prefill_epix10k2m": "7c37b616f962576e35c0e82d1683fef3b1edbb559ce1c9fbadca072e73e86ef5",
     # pinned in PR 67, which brought it: the nine above were hashed on PR 66's tree first and none
     # moved, though every one of them now goes through `_projections`' rule for the q / k norm (none,
     # a head, the whole projection) and for a block without a norm before its branch, `init_params`'
     # and `decoder_layer`'s branches for the two forms of linear attention and the two norms a branch
     # may have (all taken in Python, before anything is traced); ling3's kernel, whose body this
     # test blanks, is held to the traced equation by `tests/test_decoder_olmo_hybrid.py -k ling3`
-    "olmo_hybrid_7b_prefill_epix10k2m": "89569092c2ac410f14de5f25dab796bf01a1e9f6c78be1e4c69057ce3f2a99bb",
+    "olmo_hybrid_7b_prefill_epix10k2m": "8cf4e88c84e23a402a343c6010b09e1fee7e07cbc84fc431a1967bd3dcb885da",
 }
 
 
@@ -861,7 +878,9 @@ def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_c
     of ``out``, and ``row_gather``, ONE an expert layer: the pass moves 3
     rows a row of ``x``, a turn of the loop 2,048 of 34,816, and
     ``row_gather.tile_rows`` leaves that one to XLA's gather: 42 under ``moe``),
-    one ``masked_gqa_attention``, the calibration kernel, and no other."""
+    one ``masked_gqa_attention``, the calibration kernel, since PR 73 six
+    ``conv_silu_taps`` (one a linear layer over ``[q | k | v]``, under the scope
+    ``conv``: ``conv_ms.ling3`` reads it there), and no other."""
     import collections
 
     from benchmark.roofline import kimi_k2
@@ -876,13 +895,15 @@ def test_the_ling3_step_compiles_with_its_kernels_where_the_roofline_functions_c
     names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
     sites = kimi_k2.held_products(cfg["step_tokens"], 8, 2560, 768, 128, 7, 1, 0.25)["call_sites"]
     expert_layers = dcfg.num_layers - dcfg.num_dense_layers
-    assert names == {"gated_delta_rule": cfg["layer_types"].count("linear_attention"), "gmm": 2 * sites,
+    linear = cfg["layer_types"].count("linear_attention")
+    assert names == {"gated_delta_rule": linear, "conv_silu_taps": linear, "gmm": 2 * sites,
                      "rows_as_words": 2 * expert_layers, "row_gather": expert_layers,
                      "sum_counted_rows": expert_layers, "masked_gqa_attention": 1, "fused_calibrate": 1}, names
     assert sites == 3 * expert_layers == 18
     assert all("/moe/" in line for line in calls
                if re.match(r"\s*%(gmm|rows_as_words|row_gather|sum_counted_rows)", line))
     assert all("/kda/" in line for line in calls if re.match(r"\s*%gated_delta_rule", line))
+    assert all("/conv/" in line for line in calls if re.match(r"\s*%conv_silu_taps", line))
 
 
 def test_the_granite_step_compiles_whole_with_its_kernels_under_the_scopes_a_trace_reads(
@@ -893,8 +914,9 @@ def test_the_granite_step_compiles_whole_with_its_kernels_under_the_scopes_a_tra
     one shape, traced and lowered once): it fits the chip (weights 6.4 GB,
     half a GB of temporaries), and its Mosaic kernels are 36 ``ssd_scan`` (one a
     state-space layer, under the scope ``ssd``: ``ssd_roofline_share.granite``
-    reads each call by that name), 4 ``masked_gqa_attention`` (under
-    ``sparse_attn``), the calibration kernel, and no other."""
+    reads each call by that name), 36 ``conv_silu_taps`` ahead of them (under
+    ``conv``, since PR 73), 4 ``masked_gqa_attention`` (under ``sparse_attn``),
+    the calibration kernel, and no other."""
     import collections
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -905,10 +927,11 @@ def test_the_granite_step_compiles_whole_with_its_kernels_under_the_scopes_a_tra
     calls = [line for line in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
-    assert names == {"ssd_scan": cfg["layer_types"].count("mamba") == 36 and 36,
+    assert names == {"ssd_scan": cfg["layer_types"].count("mamba") == 36 and 36, "conv_silu_taps": 36,
                      "masked_gqa_attention": cfg["layer_types"].count("attention") == 4 and 4,
                      "fused_calibrate": 1}, names
     assert all("/ssd/" in line for line in calls if re.match(r"\s*%ssd_scan", line))
+    assert all("/conv/" in line for line in calls if re.match(r"\s*%conv_silu_taps", line))
     assert all("/sparse_attn/" in line for line in calls if re.match(r"\s*%masked_gqa", line))
     # the stream between the layers is float32 (`decoder.trunk`), every product's operands bf16
     text = compiled.as_text()
@@ -921,7 +944,8 @@ def test_the_nemotron3_step_compiles_whole_with_nothing_array_sized_between_a_bl
     published sizes (fourteen layers of ONE block each, four frames), compiled
     for the described v5e (three quarters of a minute): it fits the chip
     (weights 9.2 GB, 3.1 GB of temporaries), and its Mosaic kernels are six
-    ``ssd_scan`` (under ``ssd``), two ``masked_gqa_attention`` at sixteen heads
+    ``ssd_scan`` (under ``ssd``), six ``conv_silu_taps`` ahead of them (under
+    ``conv``, since PR 73), two ``masked_gqa_attention`` at sixteen heads
     a group (under ``sparse_attn``), under ``moe`` TWO grouped products an
     expert layer in the pass ahead of the held rows' loop and two in the loop
     (``nemotron3.held_products``' ``call_sites``: an ungated expert has no
@@ -951,12 +975,13 @@ def test_the_nemotron3_step_compiles_whole_with_nothing_array_sized_between_a_bl
     names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
     pattern = cfg["hybrid_override_pattern"][:cfg["num_hidden_layers"]]
     sites = nemotron3.held_products(cfg["step_tokens"], 6, 2688, 1856, 64, 14, pattern, 0.5)["call_sites"]
-    assert names == {"ssd_scan": pattern.count("M"), "masked_gqa_attention": pattern.count("*"),
-                     "gmm": 2 * sites, "rows_as_words": 2 * pattern.count("E"),
+    assert names == {"ssd_scan": pattern.count("M"), "conv_silu_taps": pattern.count("M"),
+                     "masked_gqa_attention": pattern.count("*"), "gmm": 2 * sites, "rows_as_words": 2 * pattern.count("E"),
                      "row_gather": pattern.count("E"), "sum_counted_rows": pattern.count("E"),
                      "fused_calibrate": 1}, names
     assert sites == 2 * pattern.count("E") == 12
     assert all("/ssd/" in line for line in calls if re.match(r"\s*%ssd_scan", line))
+    assert all("/conv/" in line for line in calls if re.match(r"\s*%conv_silu_taps", line))
     assert all("/sparse_attn/" in line for line in calls if re.match(r"\s*%masked_gqa", line))
     assert all("/moe/" in line for line in calls
                if re.match(r"\s*%(gmm|rows_as_words|row_gather|sum_counted_rows)", line))
@@ -979,7 +1004,8 @@ def test_the_olmo_hybrid_step_compiles_whole_with_its_kernels_where_the_roofline
     shape): it fits the chip (weights 8.2 GB, under a GB of temporaries), and
     its Mosaic kernels are the ones the roofline functions count: twelve
     ``gated_delta_net`` (one a linear layer, under the scope ``gdn``:
-    ``olmo_hybrid.delta_rule`` counts a call), four ``masked_gqa_attention``
+    ``olmo_hybrid.delta_rule`` counts a call), 36 ``conv_silu_taps`` ahead of
+    them (q's, k's and v's a layer, under ``conv``, since PR 73), four ``masked_gqa_attention``
     (under ``sparse_attn``: ``olmo_hybrid.causal_attention``) at TWO heads a
     grid step (30 heads alone in their groups, unturned: 15 x 36 grid steps a
     call), the calibration kernel, and no other. q and k leave their
@@ -1000,9 +1026,10 @@ def test_the_olmo_hybrid_step_compiles_whole_with_its_kernels_where_the_roofline
     calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
     names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
     assert names == {"gated_delta_net": cfg["layer_types"].count("linear_attention") == 12 and 12,
-                     "masked_gqa_attention": cfg["layer_types"].count("full_attention") == 4 and 4,
+                     "conv_silu_taps": 3 * 12, "masked_gqa_attention": cfg["layer_types"].count("full_attention") == 4 and 4,
                      "fused_calibrate": 1}, names
     assert all("/gdn/" in line for line in calls if re.match(r"\s*%gated_delta_net", line))
+    assert all("/conv/" in line for line in calls if re.match(r"\s*%conv_silu_taps", line))
     assert all("/sparse_attn/" in line for line in calls if re.match(r"\s*%masked_gqa", line))
     assert sa.heads_a_step(30, 1, 1088, 1088, 128, 128) == 2
     from psana_ray_tpu.models import decoder

@@ -250,6 +250,80 @@ def test_a_block_of_sixteen_rows_at_the_bound_stays_inside_float32_and_a_lower_b
     assert 8704 % dr.CHUNK == 0 and dr.CHUNK % dr.BLOCK == 0
 
 
+# ---------------------------------------------------------------------------
+# ops/short_conv.conv_silu_taps: the kernel `decoder.conv_silu` is (PR 73), interpreted here
+
+
+def _reference_conv_silu(u, w, bias, seq_len):
+    """``benchmark/reference``'s convolution and SiLU (granite's form: a bias
+    or none), a sequence at a time, float32 out."""
+    from benchmark.reference import granite_decoder
+
+    m = {"taps": w.shape[1], "conv_bias": bias is not None}
+    return jnp.concatenate([granite_decoder.conv_silu(u[at:at + seq_len], w, bias, m)
+                            for at in range(0, u.shape[0], seq_len)])
+
+
+CONV_SILU_CASES = {  # rows, seq_len, channels, taps, a bias, the array's type, block_rows, block_cols
+    "4_taps": (32, 32, 128, 4, False, jnp.bfloat16, 512, 1024),
+    "4_taps_and_a_bias": (32, 32, 128, 4, True, jnp.bfloat16, 512, 1024),
+    "2_taps": (32, 32, 128, 2, False, jnp.bfloat16, 512, 1024),
+    "2_taps_and_a_bias": (32, 32, 128, 2, True, jnp.bfloat16, 512, 1024),
+    "float32_in_and_out": (32, 16, 24, 4, True, jnp.float32, 512, 1024),
+    "two_sequences_of_three_strips_the_second_starts_anew": (96, 48, 128, 4, False, jnp.bfloat16, 512, 1024),
+    "tiles_of_one_strip_the_carry_rides_between_them": (128, 64, 128, 4, True, jnp.bfloat16, 16, 1024),
+    "a_width_of_one_and_a_half_channel_blocks": (32, 16, 384, 4, True, jnp.bfloat16, 512, 256),
+    "a_row_tile_of_24_that_does_not_divide_512_taken_whole": (48, 24, 128, 4, False, jnp.bfloat16, 512, 1024),
+    "a_row_tile_of_8_eight_taps_all_of_the_carry": (24, 8, 128, 8, True, jnp.bfloat16, 512, 1024),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CONV_SILU_CASES))
+def test_conv_silu_taps_equals_the_reference_to_the_bit(case):
+    from psana_ray_tpu.ops.short_conv import conv_silu_taps
+
+    t, seq_len, c, taps, has_bias, dtype, block_rows, block_cols = CONV_SILU_CASES[case]
+    rng = np.random.default_rng(len(case))
+    u = jnp.asarray(rng.standard_normal((t, c)), dtype)
+    w = jnp.asarray(rng.standard_normal((c, taps)), dtype)
+    bias = jnp.asarray(rng.standard_normal(c), dtype) if has_bias else None
+    got = conv_silu_taps(u, w, bias, seq_len=seq_len, block_rows=block_rows, block_cols=block_cols)
+    want = _reference_conv_silu(u, w, bias, seq_len).astype(dtype)
+    assert got.dtype == dtype and got.shape == (t, c)
+    # to the bit where the result is rounded (the cells' case); float32 out to float32's last
+    # place: XLA's CPU backend contracts a fused body's multiply-adds as the eager reference's are not
+    tol = 1e-6 if dtype == jnp.float32 else 0.0
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32), rtol=10 * tol, atol=tol)
+    if t > seq_len:  # read as ONE sequence, a later sequence's first rows meet the rows before them
+        as_one = np.asarray(_reference_conv_silu(u, w, bias, t), np.float32)
+        starts = np.arange(seq_len, t, seq_len)[:, None] + np.arange(taps - 1)
+        assert np.abs(as_one[starts] - np.asarray(got, np.float32)[starts]).max() > 1e-2
+
+
+def test_lanes_conv_silu_lays_the_taps_a_head_at_whole_lane_tiles_as_the_columns_are():
+    """Olmo-Hybrid's q and k: 96 columns a head laid at 128, the published
+    taps ``[H * 96, 4]`` laid out the same way; a zero column stays zero."""
+    rng = np.random.default_rng(7)
+    heads, d_k, t = 2, 96, 32
+    u = jnp.asarray(rng.standard_normal((t, heads * d_k)), jnp.bfloat16)
+    w = jnp.asarray(rng.standard_normal((heads * d_k, 4)), jnp.bfloat16)
+    got = decoder._lanes_conv_silu(dr.lanes_a_head(u, heads), w, 16, heads)
+    want = _reference_conv_silu(u, w, None, 16).astype(jnp.bfloat16)
+    assert got.shape == (t, heads * 128)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(dr.lanes_a_head(want, heads), np.float32))
+
+
+@pytest.mark.parametrize("t,seq_len,taps", [(24, 12, 4), (32, 24, 4), (32, 16, 9)],
+                         ids=["rows_that_are_no_whole_sublane_tiles", "rows_that_are_no_whole_sequences",
+                              "taps_over_the_carry"])
+def test_conv_silu_taps_refuses_what_its_carry_cannot_hold(t, seq_len, taps):
+    from psana_ray_tpu.ops.short_conv import conv_silu_taps
+
+    with pytest.raises(ValueError, match="sequences"):
+        conv_silu_taps(jnp.zeros((t, 128), jnp.bfloat16), jnp.zeros((128, taps), jnp.bfloat16), seq_len=seq_len)
+
+
 def test_conv_silu_is_four_shifted_sums_that_start_anew_with_every_sequence():
     rng = np.random.default_rng(4)
     u = jnp.asarray(rng.standard_normal((32, 24)), jnp.float32)

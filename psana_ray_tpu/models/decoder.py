@@ -9,12 +9,16 @@ block as Kimi-K2 spells it, DeepSeek-V3.2's, Ling-3.0's hybrid,
 Laguna-S-2.1's windowed and full layers, Granite-4.0-H's state-space hybrid,
 Ouro's looped stack, Nemotron-H's layers of one block each, Olmo-Hybrid's
 Gated DeltaNet layers under the reordered norm, MiniCPM-SALA's block-sparse
-and fixed-decay linear layers under MiniCPM's three multipliers):
+and fixed-decay linear layers under MiniCPM's three multipliers,
+Phi-4-mini-flash's decoder-hybrid-decoder: Mamba-1 scans, differential
+attention, and layers that read what an earlier layer made):
 
-    x  -> x + op(rms(x))                      pre-norm; a layer's op is one of
-    x  -> x + ff(rms(x))                      five kinds, its ff one of two
+    x  -> x + op(norm(x))                     pre-norm; a layer's op is one of
+    x  -> x + ff(norm(x))                     its kinds, its ff one of two
 
-(a branch times ``residual_multiplier`` before it is added, where a model has
+(``norm`` an RMS norm with a gain or, where the configuration says so, a
+LayerNorm with a gain and a bias: :func:`block_norm`;
+a branch times ``residual_multiplier`` before it is added, where a model has
 one; a branch normed AGAIN before it is added under ``sandwich``, and with
 ``pre_norm`` off normed ONLY then, ``x + rms(op(x))``: OLMo 2's reordered
 norm, one rule for which norms a branch has). Where the configuration says that a layer is ONE block
@@ -36,11 +40,21 @@ decay per channel, or with ONE decay a head over keys and values of their own
 widths, a float32 state a head carried along the sequence:
 ``ops/delta_rule.py``; or, with no delta rule at all, :func:`fixed_decay_attention`:
 a decay that is a CONSTANT of the head, q and k normed a head and turned by
-a rotary inside the kernel: ``ops/lightning.py``); or a STATE-SPACE layer (:func:`state_space`:
-Mamba-2's selective scan, one scalar decay a head and token, keys and
+a rotary inside the kernel: ``ops/lightning.py``); or a STATE-SPACE layer of one of TWO kinds:
+Mamba-2's (:func:`state_space`: one scalar decay a head and token, keys and
 queries shared by all heads or by the heads of each of ``ssm_groups`` groups,
 the gated norm by those groups, a float32 state a head carried along the
-sequence: ``ops/ssd.py``);
+sequence, a chunk a matrix product: ``ops/ssd.py``) or Mamba-1's
+(:func:`selective_state_space`: a decay a CHANNEL and STATE, the step a
+channel through a low rank, a first-order recurrence an element with no
+matrix form: ``ops/selective_scan.py``); or a layer that READS WHAT AN
+EARLIER LAYER MADE (the stack hands it on beside ``x``:
+``DecoderConfig.read_from``): a gated memory unit (:func:`gated_memory`: a
+gate of its own times an earlier scan's output at the same token) or cross
+attention (queries of its own against an earlier layer's keys and values).
+Grouped-query attention may be DIFFERENTIAL (:func:`diff_attention`: heads in
+pairs, two softmaxes a pair whose outputs are subtracted under a learned
+lambda and normed);
 or, where the configuration has a ``kv_lora_rank``, LATENT attention
 (:func:`latent_attention`: queries of full rank or through a normed low
 rank, keys and values decompressed per head from one normed latent, one
@@ -81,7 +95,9 @@ frames, calibrated on the device, cut into patches, embedded by a linear
 patch embedding (standing in for a vision tower), each followed by the
 text prompt, read through the trunk; the logits of each frame's next token
 come back with a small statistics vector (:data:`STEP_STATS`) that
-:func:`fold_step_stats` adds to a pipeline's counters. Weights are an
+:func:`fold_step_stats` adds to a pipeline's counters. Where a schedule ENDS
+in layers that mix no tokens (``DecoderConfig.cut_layer``) the step runs
+them on the rows it serves alone (:func:`trunk`'s ``rows``). Weights are an
 ARGUMENT of the step: one step keys alike in the compile cache from every
 entry point.
 
@@ -101,6 +117,7 @@ import numpy as np
 from psana_ray_tpu.ops import lightning
 from psana_ray_tpu.ops.delta_rule import (CHUNK, HEAD_CHUNK, chunk_rows, gated_delta_net,
                                           gated_delta_rule, lanes_a_head)
+from psana_ray_tpu.ops.selective_scan import LANES as SCAN_LANES, scan_tiles, selective_scan
 from psana_ray_tpu.ops.short_conv import conv_silu_taps, gated_conv_taps
 from psana_ray_tpu.ops.ssd import scan_rows, ssd_scan
 from psana_ray_tpu.parallel import sparse_attention as sa
@@ -157,12 +174,28 @@ BLOCK_STATS = (
                                  # (a group's stacked heads, which share their keys, count as one)
     "attn_grid_steps_total",     # the grid steps those calls took: a block of heads' visits each
 )
+# and two after those seventeen (the groups above 0 where the step has none), ONLY from a step whose
+# LATER layers ran on fewer rows than its earlier ones (`trunk`'s `rows`, where the schedule ends in
+# layers that mix no tokens: `DecoderConfig.cut_layer`): their quotient names the regime a step was
+# timed in (every other step runs every layer on every row, and has no such counters)
+ROWS_STATS = (
+    "trunk_rows_run_total",      # over the layers, the rows the layer ran
+    "trunk_rows_full_total",     # layers x B * S: every layer on every row
+)
 # the places the LAYERS count at most: the first four of STEP_STATS and the four groups a layer has
 LAYER_GROUPS = 4 + len(SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS)
 # layer_types, as config.json spells them
 ATTENTION, CONV, LINEAR = "full_attention", "conv", "linear_attention"
 SLIDING = "sliding_attention"  # grouped-query attention over the band t - sliding_window < j <= t
-MAMBA = "mamba"  # Mamba-2's state-space layer (granitemoehybrid's spelling; its "attention" is ATTENTION)
+# the two kinds of STATE-SPACE layer: Mamba-2's (granitemoehybrid's spelling; its "attention" is
+# ATTENTION), ONE scalar decay a head and token, a chunk a matrix product (`ops/ssd.py`), and
+# Mamba-1's, a decay a CHANNEL and STATE, a recurrence an element (`ops/selective_scan.py`)
+MAMBA = "mamba"
+MAMBA1 = "mamba1"
+# two kinds of layer that read what an EARLIER layer made (`DecoderConfig.cut_layer`): a gated memory
+# unit reads the nearest earlier MAMBA1 layer's scan output at its own token; CROSS attention has
+# queries of its own and reads the keys and values of the nearest earlier ATTENTION layer
+GMU, CROSS = "gated_memory", "cross_attention"
 LATENT = "latent_attention"  # what an ATTENTION layer is under a kv_lora_rank
 # a layer that is ONE block and has no operator names its feed-forward (`DecoderConfig.single_block`)
 EXPERTS, DENSE = "moe", "mlp"
@@ -267,6 +300,18 @@ class DecoderConfig:
     ssm_state: int = 0
     ssm_groups: int = 1
     conv_bias: bool = False
+    # a MAMBA1 layer's scan: scan_channels channels (d_inner) over the same ssm_state, the step a
+    # channel through a low rank of scan_dt_rank; its convolution's taps and bias as above
+    scan_channels: int = 0
+    scan_dt_rank: int = 0
+    # the blocks' and the final norm: "rms" (a gain) or "layer" (LayerNorm: a gain and a bias)
+    norm: str = "rms"
+    attn_bias: bool = False  # a bias on an attention layer's products (W_qkv or W_q, and W_o)
+    # DIFFERENTIAL attention (arXiv:2410.05258) in every ATTENTION, SLIDING and CROSS layer: heads in
+    # PAIRS, a pair's two half-heads each a softmax of its own over the pair's values (both halves'
+    # side by side: twice head_dim wide), the second subtracted times a learned lambda, the
+    # difference RMS-normed over the pair's columns with a gain and scaled by 1 - lambda_init
+    diff_attention: bool = False
     # every layer is ONE block, x + Mixer(rms(x)): an operator ALONE (no feed-forward after it) or,
     # where layer_types names EXPERTS or DENSE, a feed-forward alone (no operator before it)
     single_block: bool = False
@@ -405,7 +450,41 @@ class DecoderConfig:
         """Layers that carry a state (linear attention, or a state-space
         scan): the step then counts :data:`SHARE_STATS`, :data:`PAIR_STATS`
         and :data:`LINEAR_STATS` too."""
-        return LINEAR in self.layer_types or MAMBA in self.layer_types
+        return bool({LINEAR, MAMBA, MAMBA1} & set(self.layer_types))
+
+    @property
+    def cut_layer(self) -> Optional[int]:
+        """The layer FROM which a step that serves some rows alone runs those
+        rows alone (``trunk``'s ``rows``), ``None`` where the schedule has no
+        such layer: the full ATTENTION layer whose keys and values the
+        trailing layers read, where EVERY layer after it is a gated memory
+        unit or cross attention — layers that mix no tokens: a row of theirs
+        needs that row of the stream, that row of the scan output handed on,
+        and the kept keys and values of ALL rows. The layer itself makes its
+        keys and values on every row and runs its query side, its output and
+        its feed-forward on the rows wanted (YOCO's prefill, arXiv:2405.05254:
+        "early exit before the cross-decoder")."""
+        tail = next((i for i in range(len(self.layer_types) - 1, -1, -1)
+                     if self.layer_types[i] not in (GMU, CROSS)), -1)
+        if tail < 0 or tail == len(self.layer_types) - 1 or self.layer_types[tail] != ATTENTION:
+            return None
+        return tail
+
+    def read_from(self, i: int) -> Optional[int]:
+        """The EARLIER layer whose product layer ``i`` reads: a gated memory
+        unit the nearest MAMBA1 layer before it (its scan output), cross
+        attention the nearest ATTENTION layer (its keys and values); ``None``
+        for every other kind."""
+        made_by = {GMU: MAMBA1, CROSS: ATTENTION}.get(self.layer_types[i] if self.layer_types else "")
+        if made_by is None:
+            return None
+        return next((j for j in range(i - 1, -1, -1) if self.layer_types[j] == made_by), None)
+
+    @property
+    def hands_on(self) -> Tuple[int, ...]:
+        """The layers whose product a later layer reads (:meth:`read_from`)."""
+        return tuple(sorted({j for j in map(self.read_from, range(len(self.layer_types)))
+                             if j is not None}))
 
     @property
     def has_window(self) -> bool:
@@ -461,7 +540,7 @@ class DecoderConfig:
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Eleven spellings are read:
+        ``experts_held`` and ``tie_embedding``. Twelve spellings are read:
         Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
         ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
         (``norm_eps``, ``layer_types``, ``num_dense_layers``,
@@ -553,7 +632,23 @@ class DecoderConfig:
         rows, ``scale_depth`` over the root of the PUBLISHED depth on every
         branch (``published.num_hidden_layers`` where the file is a cut) and
         ``hidden_size / dim_model_base`` under the logits; a word it does not
-        know, or a count that is not the layers', is refused). Where a
+        know, or a count that is not the layers', is refused); and
+        Phi-4-mini-flash's (``phi4flash``: ``mb_per_layer``, the mark of
+        SambaY's decoder-hybrid-decoder, whose schedule is the published RULE
+        over ``num_hidden_layers``: a Mamba-1 layer at every even index up to
+        ``L/2``, differential attention under ``sliding_window`` at every odd
+        one below it, ONE full differential layer at ``L/2 + 1`` whose keys
+        and values are kept, then gated memory units (even) and cross
+        attention (odd) in turn; refused where ``num_hidden_layers % 4`` is
+        not 0, as the published code asserts, or ``mb_per_layer`` is not 2;
+        ``layer_norm_eps``: LayerNorm with a bias before every branch and
+        before the head; no ``rope_theta``: no rotary; the scan's four sizes
+        the configuration class's defaults — a state of 16, 4 taps, channels
+        twice the hidden size, a rank of ``ceil(hidden / 16)`` — or the
+        mapping's ``mamba_d_state``, ``mamba_d_conv``, ``mamba_expand``,
+        ``mamba_dt_rank``; and, as its module has them and its file has no
+        key for, differential attention, a bias on the attention's products
+        and on the convolution, none in the MLP or the head). Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -601,6 +696,32 @@ class DecoderConfig:
                              m.get("sparse_config") or {}).items()}),
                          linear_decay="fixed", linear_rotary=bool(m.get("lightning_use_rope")),
                          linear_chunk=lightning.CHUNK)
+        flash = {}  # SambaY's decoder-hybrid-decoder (phi4flash), by the key that spaces its scans
+        if "mb_per_layer" in m:
+            if int(m["mb_per_layer"]) != 2 or n_layers % 4 or n_layers < 8:
+                raise ValueError(f"mb_per_layer {m['mb_per_layer']} over {n_layers} layers is not "
+                                 "built: a scan every 2 layers over a multiple of 4 layers is (the "
+                                 "published code asserts num_hidden_layers % 4 == 0)")
+            if m.get("mlp_bias") or m.get("lm_head_bias") or not m.get("sliding_window"):
+                raise ValueError("a bias in the MLP or the head, or no sliding_window, is not built")
+            half = n_layers // 2
+
+            def published(i):  # the published rule over the layer's index
+                if i % 2 == 0:
+                    return MAMBA1 if i <= half else GMU
+                return SLIDING if i < half else ATTENTION if i == half + 1 else CROSS
+
+            layer_types = tuple(published(i) for i in range(n_layers))
+            width = int(m["hidden_size"])
+            # the scan's four sizes are the configuration CLASS's defaults (the file has no key for
+            # them): a state of 16, 4 taps, channels twice the hidden size, a rank of ceil(D / 16)
+            flash = dict(scan_channels=int(m.get("mamba_expand", 2)) * width,
+                         scan_dt_rank=int(m.get("mamba_dt_rank") or -(-width // 16)),
+                         ssm_state=int(m.get("mamba_d_state", 16)), conv_bias=True,
+                         norm="layer", attn_bias=True, diff_attention=True)
+            if 2 * flash["ssm_state"] > SCAN_LANES or int(m["num_key_value_heads"]) % 2 or (
+                    heads // 2) % (int(m["num_key_value_heads"]) // 2):
+                raise ValueError("a scan's state over 64, or heads that do not pair, are not built")
         ssm = {}
         if "mamba_n_heads" in m:  # Mamba-2's state-space layers, as Granite-4.0-H spells them
             ssm = dict(ssm_heads=int(m["mamba_n_heads"]), ssm_head_dim=int(m["mamba_d_head"]),
@@ -646,10 +767,12 @@ class DecoderConfig:
                    linear_chunk=HEAD_CHUNK, sandwich=True, pre_norm=False,
                    qk_norm_span="projection") if delta_net else {}
         qk_norm = delta_net or (scale is None and "rope_parameters" not in m and not loop
-                                and not single and mixers is None)  # (mixers: the LINEAR layers' alone)
+                                and not single and mixers is None  # (mixers: the LINEAR layers' alone)
+                                and not flash)
         if layer_types and (len(layer_types) != n_layers
                             or set(layer_types) - {ATTENTION, SLIDING, CONV, LINEAR}
                             - ({MAMBA} if ssm else set())
+                            - ({MAMBA1, GMU, CROSS} if flash else set())
                             - ({EXPERTS, DENSE} if single else set())):
             raise ValueError(f"layer_types {layer_types} does not name {n_layers} layers' "
                              f"operators, each {ATTENTION!r}, {SLIDING!r}, {CONV!r} or {LINEAR!r}")
@@ -688,6 +811,9 @@ class DecoderConfig:
                 raise ValueError("a soft cap on the router's logits is not built")
         elif flat is not None:  # a null theta: q and k are not turned at all
             rope, theta = {}, float(flat.get("rope_theta") or 0.0)
+        elif flash:  # no rotary and no other position signal anywhere: the file has no rope_theta
+            rope, theta = {}, 0.0
+            window = dict(sliding_window=int(m["sliding_window"]))
         else:
             rope, theta = m.get("rope_scaling") or {}, float(m["rope_theta"])
         if SLIDING in layer_types and not window.get("sliding_window"):
@@ -728,20 +854,22 @@ class DecoderConfig:
             head_dim=nope + rope_dim if latent else head_dim,
             vocab_size=int(m["vocab_size"]),
             rms_eps=float(m["rms_norm_eps"] if "rms_norm_eps" in m
-                          else m["layer_norm_epsilon"] if "layer_norm_epsilon" in m else m["norm_eps"]),
+                          else m["layer_norm_epsilon"] if "layer_norm_epsilon" in m
+                          else m["layer_norm_eps"] if "layer_norm_eps" in m else m["norm_eps"]),
             rope_theta=theta,
             mrope_section=tuple(int(v) for v in mrope) if mrope else None,
-            layer_types=layer_types, **window, **ssm, qk_norm=qk_norm,
+            layer_types=layer_types, **window, **{**ssm, **flash}, qk_norm=qk_norm,
             single_block=single, mlp_act=act,
             **{"residual_multiplier": float(m.get("residual_multiplier", 1.0)),
                "embedding_multiplier": float(m.get("embedding_multiplier", 1.0)),
                "logits_scaling": float(m.get("logits_scaling", 1.0)), **mixed},
             attention_multiplier=None if scale is None else float(scale),
-            rotary=(m.get("position_embedding_type") != "nope" and not single
+            rotary=(m.get("position_embedding_type") != "nope" and not single and not flash
                     and (flat is None or flat.get("rope_theta") is not None)
                     and bool(m.get("attn_use_rope", True))),
             conv_taps=int(m.get("conv_L_cache", m.get("short_conv_kernel_size", m.get(
-                "mamba_d_conv", m.get("conv_kernel", m.get("linear_conv_kernel_dim", 3)))))),
+                "mamba_d_conv", m.get("conv_kernel", m.get("linear_conv_kernel_dim",
+                                                           4 if flash else 3)))))),
             linear_head_dim=int(m["linear_key_head_dim" if delta_net else "lightning_head_dim"
                                   if mixers is not None else "head_dim"]) if linear else 0,
             linear_decay_floor=(float(m["kda_lower_bound"])
@@ -861,6 +989,31 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                  "w_out": w(wide, d)}
             if cfg.conv_bias:
                 p["conv_b"] = between(-0.5, 0.5, conv).astype(dtype)
+        elif op == MAMBA1:
+            wide, rank, n = cfg.scan_channels, cfg.scan_dt_rank, cfg.ssm_state
+            # Mamba-1's published initialiser: A = -(1 .. N) in every channel (an OPERAND of the
+            # kernel all the same: a trained A is whatever training left), W_dt uniform within
+            # rank ** -0.5, the step's bias the inverse softplus of a log-uniform step in [0.001,
+            # 0.1], D 1; taps and their bias as the other state-space kind's, above
+            first = jnp.exp(between(np.log(0.001), np.log(0.1), wide))
+            p = {"norm1": gain(d), "w_in": w(d, 2 * wide), "conv_w": w(wide, cfg.conv_taps, scale=0.5),
+                 "conv_b": between(-0.5, 0.5, wide).astype(dtype), "w_x": w(wide, rank + 2 * n),
+                 "w_dt": between(-rank ** -0.5, rank ** -0.5, rank, wide).astype(dtype),
+                 "dt_bias": first + jnp.log(-jnp.expm1(-first)),
+                 "a_log": jnp.log(jnp.broadcast_to(jnp.arange(1, n + 1, dtype=jnp.float32), (wide, n))),
+                 "d_skip": jnp.ones((wide,), jnp.float32), "w_out": w(wide, d)}
+        elif op == GMU:  # silu(a W_1) times the scan output handed on, then W_2
+            p = {"norm1": gain(d), "w_1": w(d, cfg.scan_channels), "w_2": w(cfg.scan_channels, d)}
+        elif cfg.diff_attention and op in (ATTENTION, SLIDING, CROSS):
+            # W_qkv as published, [q | k | v] with a bias (cross attention: W_q alone); four lambda
+            # vectors normal(0, 0.1), the sub-norm's gain over a pair's 2 * head_dim columns
+            wide = cfg.num_heads * hd + (0 if op == CROSS else 2 * cfg.num_kv_heads * hd)
+            p = {"norm1": gain(d), "w_q" if op == CROSS else "w_qkv": w(d, wide),
+                 **{name: w(hd, dtype=jnp.float32, scale=0.1)
+                    for name in ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")},
+                 "sub_norm": gain(2 * hd), "wo": w(cfg.num_heads * hd, d)}
+            if cfg.attn_bias:
+                p.update({"b_q" if op == CROSS else "b_qkv": w(wide), "b_o": w(d)})
         elif op == LATENT:
             heads, rq, rkv = cfg.num_heads, cfg.q_lora_rank, cfg.kv_lora_rank
             if rq:
@@ -903,6 +1056,8 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
         gated = cfg.mlp_act == "silu"  # an ungated MLP (relu2) has no W_gate: only what a block has is drawn
         if experts is not None and cfg.pre_norm:
             p["norm2"] = gain(d)  # the feed-forward's norm (an operator ALONE has none)
+        if cfg.norm == "layer":  # a LayerNorm's bias beside every gain of a block's
+            p.update({f"{name}_b": w(d) for name in ("norm1", "norm2") if name in p})
         if experts:
             held = cfg.experts_held[1]
             p.update(router=w(d, cfg.num_experts))
@@ -925,6 +1080,8 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
               "norm": gain(d)}
     if not cfg.tie_embedding:
         params["head"] = w(d, cfg.vocab_size)
+    if cfg.norm == "layer":
+        params["norm_b"] = w(d)
     if cfg.exit_gate:  # lambda = sigmoid(h w + b): near 1/2 under these draws, seen and not saturated
         params["exit_gate"] = {"w": w(d), "b": jnp.zeros((), jnp.float32)}
     return params
@@ -1060,11 +1217,23 @@ def _projections(p, x, angles, cfg: DecoderConfig, windowed: bool = False):
 
 
 def layer_norm(u, g, b, eps: float):
-    """``(u - mean(u)) / sqrt(var(u) + eps) * g + b`` over the last axis, in float32."""
+    """``(u - mean(u)) / sqrt(var(u) + eps) * g + b`` over the last axis, in
+    float32: the one index key's norm under a key selection over latent
+    attention, and the blocks' and the final norm of a model whose
+    ``DecoderConfig.norm`` is ``"layer"`` (:func:`block_norm`)."""
     u = u.astype(jnp.float32)
     u = u - jnp.mean(u, axis=-1, keepdims=True)
     return (u * jax.lax.rsqrt(jnp.mean(u * u, axis=-1, keepdims=True) + eps)
             * g.astype(jnp.float32) + b.astype(jnp.float32))
+
+
+def block_norm(x, p, name: str, cfg: DecoderConfig):
+    """The norm ``name`` of a block (or the final one) on ``x``, float32: an
+    RMS norm with the gain ``p[name]`` or, under ``cfg.norm == "layer"``, a
+    LayerNorm with that gain and the bias ``p[name + "_b"]``."""
+    if cfg.norm == "layer":
+        return layer_norm(x, p[name], p[f"{name}_b"], cfg.rms_eps)
+    return rms_norm(x, p[name], cfg.rms_eps)
 
 
 def _turn_leading(x, angles, width: int, scale: float = 1.0):
@@ -1478,6 +1647,216 @@ def state_space(p, x, batch: int, cfg: DecoderConfig):
         return jax.jit(_onto, static_argnums=3)(x, o, p["w_out"], cfg.residual_multiplier)
 
 
+def _scan_projections(p, x, cfg: DecoderConfig):
+    """``x [T, D]`` -> ``(xs, z) [T, C]`` each: ``W_in``'s two halves, its
+    COLUMNS cut and not the product (:func:`_ssm_projections` says why)."""
+    w, wide = p["w_in"], cfg.scan_channels
+    dt = w.dtype
+    a = block_norm(x, p, "norm1", cfg).astype(dt)
+    return _mm(a, w[:, :wide]).astype(dt), _mm(a, w[:, wide:]).astype(dt)
+
+
+def _scan_operands(p, u, cfg: DecoderConfig):
+    """``u [T, C]`` (after its convolution) -> ``(delta [T, C]`` float32,
+    ``[B | C | 0] [T, 128])``: ``[delta | B | C] = u W_x`` as two products
+    of ``W_x``'s columns (the low rank; ``B`` and ``C``, laid on the WEIGHT
+    at the lane tile the kernel reads), and the step's pre-activation
+    ``delta W_dt``, float32: a log-decay's factor is never rounded to bf16."""
+    w, rank = p["w_x"], cfg.scan_dt_rank
+    dt = w.dtype
+    low = _mm(u, w[:, :rank]).astype(dt)
+    onto_tile = jnp.pad(w[:, rank:], ((0, 0), (0, SCAN_LANES - 2 * cfg.ssm_state)))
+    return _mm(low, p["w_dt"]), _mm(u, onto_tile).astype(dt)
+
+
+def selective_state_space(p, x, batch: int, cfg: DecoderConfig, keep: bool = False):
+    """Mamba-1's layer on ``x [B*S, D]`` -> ``x + Op`` (with ``keep``: ``(x +
+    Op, y)``, the scan's output with its skip and BEFORE its gate, ``[B*S,
+    C]``: what a later gated memory unit reads): with ``a`` the block's normed
+    input, ``[xs | z] = a W_in``; ``u = silu(conv(xs) + b_c)`` (:func:`conv_silu`;
+    zeros before each sequence); ``[delta | B | C] = u W_x``, ``Delta =
+    softplus(delta W_dt + b_dt)``, ``A = -exp(A_log) [C, N]``; the selective
+    scan with a decay a channel AND state (the state starts at 0 with every
+    sequence), the skip ``D u`` and the gate ``silu(z)``; then ``W_out``. No
+    bias in the products. Under the scopes ``proj`` (the norm, ``W_in``'s two
+    products, ``W_x``'s two and ``W_dt``'s, ``W_out``), ``conv`` and
+    ``selective_scan`` (the softplus, the recurrence, the skip and the gate:
+    ONE kernel, ``ops/selective_scan.py``)."""
+    s = x.shape[0] // batch
+    with jax.named_scope("proj"):
+        xs, z = jax.jit(_scan_projections, static_argnums=2)(p, x, cfg)
+    with jax.named_scope("conv"):
+        u = jax.jit(conv_silu, static_argnums=2)(xs, p["conv_w"], s, p["conv_b"])
+    with jax.named_scope("proj"):
+        delta, bc = jax.jit(_scan_operands, static_argnums=2)(p, u, cfg)
+    with jax.named_scope("selective_scan"):
+        o = selective_scan(u, delta, bc, z, -jnp.exp(p["a_log"].astype(jnp.float32)), p["d_skip"],
+                           p["dt_bias"], seq_len=s, keep=keep)
+    o, y = o if keep else (o, None)
+    with jax.named_scope("proj"):
+        x = jax.jit(_onto, static_argnums=3)(x, o, p["w_out"], 1.0)
+    return (x, y) if keep else x
+
+
+def gated_memory(p, x, memory, cfg: DecoderConfig):
+    """A gated memory unit on ``x [T, D]`` -> ``x + (silu(a W_1) * m) W_2``,
+    ``m [T, C]`` the scan output an earlier layer handed on, at the same
+    tokens. One jitted part, under the scope ``gmu``."""
+    def unit(p, x, memory):
+        dt = p["w_1"].dtype
+        a = block_norm(x, p, "norm1", cfg).astype(dt)
+        g = (jax.nn.silu(_mm(a, p["w_1"])) * memory.astype(jnp.float32)).astype(dt)
+        return x + _mm(g, p["w_2"]).astype(x.dtype)
+
+    with jax.named_scope("gmu"):
+        return jax.jit(unit)(p, x, memory)
+
+
+def lambda_init(index: int) -> float:
+    """Differential attention's ``lambda_init`` of the layer at (0-based) ``index``."""
+    return 0.8 - 0.6 * float(np.exp(-0.3 * index))
+
+
+def _half_products(a, w, bias, heads: int, d: int, scale: float = 1.0):
+    """``a W + b`` of the first and of the second half-heads, ``[T, heads * d]``
+    each in ``a``'s type (times ``scale``): ``W [D, heads * 2 * d]`` holds a
+    pair's two half-heads side by side, and its columns (and the bias's) are
+    cut on the WEIGHT, never on the product."""
+    out = []
+    for c in (0, 1):
+        y = _mm(a, w.reshape(-1, heads, 2, d)[:, :, c].reshape(-1, heads * d))
+        if bias is not None:
+            y = y + bias.reshape(heads, 2, d)[:, c].reshape(-1).astype(jnp.float32)
+        out.append((y * scale if scale != 1.0 else y).astype(a.dtype))
+    return out
+
+
+def _diff_queries(p, a, cfg: DecoderConfig):
+    """The normed rows ``a [T, D]`` -> ``(q1, q2) [T, P * d]``: the ``P`` query
+    pairs' first and second half-heads, times the softmax scale, from
+    ``W_qkv``'s leading columns or (cross attention) from ``W_q``."""
+    w, bias = (p["w_q"], p.get("b_q")) if "w_q" in p else (p["w_qkv"], p.get("b_qkv"))
+    wide = cfg.num_heads * cfg.head_dim
+    return _half_products(a, w[:, :wide], None if bias is None else bias[:wide],
+                          cfg.num_heads // 2, cfg.head_dim, cfg.softmax_scale)
+
+
+def _diff_keys_values(p, a, cfg: DecoderConfig):
+    """The normed rows ``a [T, D]`` -> ``(k1, k2 [T, G * d], v [T, G * 2d])``: the
+    ``G`` key pairs' first and second half-heads and the pairs' values, both
+    halves side by side as published, from ``W_qkv``'s columns after the queries'."""
+    w, bias, d = p["w_qkv"], p.get("b_qkv"), cfg.head_dim
+    lo = cfg.num_heads * d
+    hi = lo + cfg.num_kv_heads * d
+    k1, k2 = _half_products(a, w[:, lo:hi], None if bias is None else bias[lo:hi],
+                            cfg.num_kv_heads // 2, d)
+    v = _mm(a, w[:, hi:])
+    return k1, k2, (v if bias is None else v + bias[hi:].astype(jnp.float32)).astype(a.dtype)
+
+
+def _differ(o1, o2, p, cfg: DecoderConfig, index: int):
+    """The two softmaxes' outputs ``o1, o2 [T, P * 2d]`` -> ``rms(o1 - lambda o2;
+    gain) (1 - lambda_init) [T, P * 2d]``: ``lambda = exp(lq1 . lk1) - exp(lq2 .
+    lk2) + lambda_init``, the RMS norm over each pair's ``2d`` columns."""
+    f32 = jnp.float32
+    first = lambda_init(index)
+    lam = (jnp.exp(jnp.sum(p["lambda_q1"].astype(f32) * p["lambda_k1"].astype(f32)))
+           - jnp.exp(jnp.sum(p["lambda_q2"].astype(f32) * p["lambda_k2"].astype(f32))) + first)
+    o = (o1.astype(f32) - lam * o2.astype(f32)).reshape(o1.shape[0], -1, 2 * cfg.head_dim)
+    return (rms_norm(o, p["sub_norm"], cfg.rms_eps) * (1.0 - first)).astype(o1.dtype).reshape(o1.shape)
+
+
+def _row_attention(q, k, v, at, g: int):
+    """B single-row queries a sequence against ALL its keys, plain products:
+    ``q [B, R, H*d]`` (scaled) at the positions ``at [R]`` of sequences whose
+    keys are ``k [B, S, G*d]`` and values ``v [B, S, G*dv]`` -> ``[B, R, H*dv]``,
+    query head ``h`` reading key head ``h // (H/G)``, a query the keys at or
+    before its position."""
+    b, r, _ = q.shape
+    s = k.shape[1]
+    k, v = k.reshape(b, s, g, -1), v.reshape(b, s, g, -1)
+    q = q.reshape(b, r, g, -1, k.shape[-1])
+    scores = jnp.einsum("brgpd,bsgd->bgprs", q, k, preferred_element_type=jnp.float32)
+    open_ = jnp.arange(s)[None, :] <= jnp.asarray(at)[:, None]
+    prob = jax.nn.softmax(jnp.where(open_, scores, sa.NEG_INF), axis=-1).astype(v.dtype)
+    o = jnp.einsum("bgprs,bsge->brgpe", prob, v, preferred_element_type=jnp.float32)
+    return o.reshape(b, r, -1).astype(v.dtype)
+
+
+def diff_attention(p, x, batch: int, cfg: DecoderConfig, index: int, window: int = 0,
+                   kept=None, rows=None, cross: bool = False):
+    """Differential attention on ``x`` -> ``(x + Op, (k1, k2, v))``: with ``a``
+    the block's normed input, ``[q | k | v] = a W_qkv + b`` (``cross``: ``q = a
+    W_q + b`` and the keys and values are ``kept``, an EARLIER layer's); pair
+    ``j``'s half-head ``c`` is a causal softmax of ``q^c_j . k^c_{j // (P/G)}
+    / sqrt(d)`` over the pair's values ``[v^1 | v^2]`` (``2d`` wide): TWO calls
+    of the batched causal kernel a layer (``c`` = 1, 2) at ``d`` and ``2d``,
+    under a ``window`` the band's; then :func:`_differ` and ``W_o + b_o``.
+    No rotary. With ``rows`` (positions in a sequence, static) the QUERY side
+    runs on those rows alone: where ``x`` is still every row (``kept`` None:
+    the layer that makes the keys and values) it makes them on all rows and
+    cuts ``a`` and ``x`` to the rows; where ``x`` is the rows already
+    (``cross``) nothing is cut. B x R single-row queries against S keys are
+    plain products (:func:`_row_attention`): decode-shaped, with no cache to
+    manage, since the keys die with the step. Under the scopes ``proj``,
+    ``window_attn`` / ``sparse_attn`` / ``cross_attn`` (the two calls) and
+    ``diff`` (the difference, its norm and scale)."""
+    g = cfg.num_kv_heads // 2
+    with jax.named_scope("proj"):
+        x, q1, q2, *made = jax.jit(_diff_inputs, static_argnums=(2, 3, 4, 5))(
+            {name: u for name, u in p.items() if name in _DIFF_INPUTS}, x, cfg, batch,
+            None if rows is None or cross else tuple(rows), cross)
+    k1, k2, v = kept if cross else made
+    s_q, s_k = x.shape[0] // batch, v.shape[0] // batch
+
+    def seqs(u, s):
+        return u.reshape(batch, s, -1)
+
+    with jax.named_scope("cross_attn" if cross else "window_attn" if window else "sparse_attn"):
+        if rows is not None:
+            o1, o2 = (jax.jit(_row_attention, static_argnums=(3, 4))(
+                seqs(q, s_q), seqs(k, s_k), seqs(v, s_k), tuple(rows), g).reshape(x.shape[0], -1)
+                for q, k in ((q1, k1), (q2, k2)))
+        else:
+            attend, band = sa.masked_gqa_attention, {}
+            if window:  # the same kernel under its own name
+                attend, band = sa.windowed_gqa_attention, {"window": window}
+            attend = jax.jit(attend, static_argnames=("num_kv_heads", "block_q", "block_k", "window"))
+            o1, o2 = (attend(seqs(q, s_q), seqs(k, s_k), seqs(v, s_k), num_kv_heads=g,
+                             block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile,
+                             **band).reshape(x.shape[0], -1) for q, k in ((q1, k1), (q2, k2)))
+    with jax.named_scope("diff"):
+        o = jax.jit(_differ, static_argnums=(3, 4))(
+            o1, o2, {name: u for name, u in p.items() if name in _DIFFER_INPUTS}, cfg, index)
+    with jax.named_scope("proj"):
+        x = jax.jit(_onto_biased)(x, o, p["wo"], p.get("b_o"))
+    return x, (k1, k2, v)
+
+
+_DIFF_INPUTS = ("norm1", "norm1_b", "w_qkv", "b_qkv", "w_q", "b_q")  # what _diff_inputs reads of a layer
+_DIFFER_INPUTS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2", "sub_norm")  # and _differ
+
+
+def _diff_inputs(p, x, cfg: DecoderConfig, batch: int, rows, cross: bool):
+    """``(x, q1, q2)`` and, unless ``cross``, ``(k1, k2, v)`` after them, from
+    the block's normed input: keys and values on every row, and with ``rows``
+    (static positions in each of the ``batch`` sequences) the queries, and
+    ``x`` itself, on those rows alone. Jitted by name: the layers of a model
+    trace once a kind."""
+    a = block_norm(x, p, "norm1", cfg).astype(p["w_q" if cross else "w_qkv"].dtype)
+    made = () if cross else _diff_keys_values(p, a, cfg)
+    if rows is not None:
+        a, x = (u.reshape(batch, -1, u.shape[-1])[:, np.asarray(rows)].reshape(-1, u.shape[-1])
+                for u in (a, x))
+    return (x, *_diff_queries(p, a, cfg), *made)
+
+
+def _onto_biased(x, o, wo, bo):
+    """``x + (o W_o + b_o)`` (``b_o`` None: no bias)."""
+    out = _mm(o, wo)
+    return x + (out if bo is None else out + bo.astype(jnp.float32)).astype(x.dtype)
+
+
 def _mlp_onto(p, x, cfg: DecoderConfig):
     """``x + residual_multiplier * MLP(rms(x))``, the dense feed-forward of
     a model with a residual multiplier, jitted by name (as :func:`state_space`'s parts)."""
@@ -1587,7 +1966,14 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
     return x, live, causal
 
 
-def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int = 1):
+def _mlp_normed(p, x, cfg: DecoderConfig):
+    """``x + MLP(norm(x))`` under the block's own norm (:func:`block_norm`),
+    jitted by name (as :func:`state_space`'s parts)."""
+    return x + _dense_mlp(p, block_norm(x, p, "norm2", cfg).astype(p["w_up"].dtype))
+
+
+def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int = 1, *,
+                  index: int = 0, handed=None, rows=None):
     """One block: ``x [B*S, D]`` -> ``(x, stats float32)``, the first four
     of :data:`STEP_STATS` and, from a holder of a share of the experts,
     :data:`SHARE_STATS` (under a selection over latent attention those
@@ -1600,8 +1986,17 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     (beside them, added once) or ``mlp`` (dense). What the kind does not name
     does not run: a layer that is ONE block (``cfg.single_block``) has no
     operator (``op`` None) or no feed-forward (``experts`` None), one norm,
-    and counts zeros where a layer of the other kind counts."""
+    and counts zeros where a layer of the other kind counts. ``index`` is the
+    layer's place (differential attention's ``lambda_init`` is a function of
+    it). Where layers read what an EARLIER layer made (``cfg.hands_on``) the
+    stack hands it on beside ``x``: ``handed`` (a mapping: ``"kv"``, the kept
+    keys and values; ``"m"``, the kept scan output) comes in, and ``(x, stats,
+    handed)`` goes out, with what THIS layer made where a later one reads it.
+    With ``rows`` (static positions in a sequence: :func:`trunk`) a layer's
+    query side runs on those rows alone (:func:`diff_attention`)."""
     op, experts = kind
+    handing = handed is not None
+    handed = dict(handed or {})
     live, causal = 0, 0
     if cfg.sandwich and (op not in (ATTENTION, LINEAR) or experts or cfg.attn_gate
                          or cfg.indexer_heads or cfg.residual_multiplier != 1.0):
@@ -1618,6 +2013,22 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
         x = linear_attention(p, x, batch, cfg)
     elif op == MAMBA:
         x = state_space(p, x, batch, cfg)
+    elif op == MAMBA1:
+        if index in cfg.hands_on:  # its scan output (before the gate) is read later
+            x, handed["m"] = selective_state_space(p, x, batch, cfg, keep=True)
+        else:
+            x = selective_state_space(p, x, batch, cfg)
+    elif op == GMU:
+        x = gated_memory(p, x, handed["m"], cfg)
+    elif cfg.diff_attention and op in (ATTENTION, SLIDING, CROSS):
+        x, made = diff_attention(p, x, batch, cfg, index, cfg.sliding_window if op == SLIDING else 0,
+                                 kept=handed.get("kv"), rows=rows, cross=op == CROSS)
+        if index in cfg.hands_on:
+            handed["kv"] = made
+        if rows is None:  # the statistics tiles of the kernel's calls (single rows run none)
+            s = x.shape[0] // batch
+            causal = batch * sa.causal_tile_count(s)
+            live = batch * sa.band_tile_count(s, cfg.sliding_window) if op == SLIDING else causal
     elif op == LATENT:
         x, live, causal = latent_attention(p, x, angles, batch, cfg, idx_angles)
     elif op is not None:  # (None: a feed-forward alone)
@@ -1659,6 +2070,10 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
             dense = {k: p[k] for k in ("norm2", "w_gate", "w_up", "w_down") if k in p}
             x = jax.jit(_mlp_onto, static_argnums=2)(dense, x, cfg)
             busiest, held = jnp.zeros((), jnp.int32), ()
+        elif not experts and cfg.norm == "layer":
+            dense = {k: p[k] for k in ("norm2", "norm2_b", "w_gate", "w_up", "w_down") if k in p}
+            x = jax.jit(_mlp_normed, static_argnums=2)(dense, x, cfg)
+            busiest, held = jnp.zeros((), jnp.int32), ()
         else:
             x, busiest, *held = jax.jit(mlp)(p, x, *given)
     even = x.shape[0] * cfg.experts_per_token / cfg.num_experts if experts else 0.0
@@ -1692,13 +2107,16 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
             chunks = batch * cfg.num_heads * (s // chunk_rows(s, cfg.linear_chunk))
         elif op == MAMBA:
             chunks = batch * cfg.ssm_heads * (s // scan_rows(s))
+        elif op == MAMBA1:  # no heads: the kernel's grid steps, channel tiles x chunks a sequence
+            tile = scan_tiles(s, cfg.scan_channels)
+            chunks = batch * (cfg.scan_channels // tile[1]) * (s // tile[0])
         stats += [jnp.float32(x.shape[0] if chunks else 0), jnp.float32(chunks)]
     if cfg.rows_go_ahead:
         # the places of the groups the step has not
         stats += [jnp.float32(0)] * (cfg.layer_stats - len(AHEAD_STATS) - len(stats))
         ahead = rows_ahead(x.shape[0] * cfg.experts_per_token, cfg.experts_held[1], cfg.num_experts)
         stats += [jnp.minimum(held[0], ahead).astype(jnp.float32) if held else jnp.float32(0)]
-    return x, jnp.stack(stats)
+    return (x, jnp.stack(stats), handed) if handing else (x, jnp.stack(stats))
 
 
 def causal_call_steps(cfg: DecoderConfig, i: int, batch: int, s: int) -> Tuple[int, int]:
@@ -1717,6 +2135,13 @@ def causal_call_steps(cfg: DecoderConfig, i: int, batch: int, s: int) -> Tuple[i
                                    sa.mask_tile(s, sa.pick_tile(s, cfg.kv_tile)))
         return sa.causal_steps(batch, s, cfg.num_heads, 1, cfg.qk_nope_head_dim, cfg.v_head_dim,
                                cfg.qk_rope_head_dim, **tiles)
+    if cfg.diff_attention:  # two calls a layer, pairs of half-heads over values twice as wide
+        if op not in (ATTENTION, SLIDING, CROSS):
+            return 0, 0
+        one = sa.causal_steps(batch, s, cfg.num_kv_heads // 2, cfg.num_heads // cfg.num_kv_heads,
+                              cfg.head_dim, 2 * cfg.head_dim,
+                              window=cfg.sliding_window if op == SLIDING else None, **tiles)
+        return 2 * one[0], 2 * one[1]
     if op in (ATTENTION, SLIDING) and not cfg.indexer_heads:
         return sa.causal_steps(batch, s, cfg.num_kv_heads, cfg.heads(i) // cfg.num_kv_heads,
                                cfg.head_dim, cfg.head_dim,
@@ -1725,7 +2150,7 @@ def causal_call_steps(cfg: DecoderConfig, i: int, batch: int, s: int) -> Tuple[i
     return 0, 0
 
 
-def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = False):
+def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = False, rows=None):
     """``x [B*S, D]``, the embedded tokens of ``batch`` sequences one after
     the other, each at ``pos`` (static: ``[S, 3]`` under the multimodal
     rotary, else ``[S]``), through every layer -> ``(x [B*S, D], stats
@@ -1737,7 +2162,13 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
     through the stack that many times (:func:`_passes`): ``x`` is then the
     last pass's NORMED rows, :data:`LOOP_STATS` follow every other group,
     and with ``exits`` the exit distribution ``p [R, B*S]`` at every token
-    (:func:`exit_distribution`) comes back third."""
+    (:func:`exit_distribution`) comes back third. ``rows`` (static positions
+    in a sequence; ``None``: all) are the rows WANTED: ``x`` comes back at those
+    rows alone, ``[B * len(rows), D]``, and where the schedule ends in layers
+    that mix no tokens (``cfg.cut_layer``) those layers, and the query side of
+    the layer whose keys and values they read, RUN on those rows alone: the
+    same layers' functions at another row count, and :data:`ROWS_STATS` after
+    every other group."""
     s = x.shape[0] // batch
     # (under rope_parameters: the FULL layers' table, over the part of a head their rotary turns)
     angles = rotary_angles(pos, cfg.rope_theta, cfg.rope_dim // 2, cfg.mrope_section,
@@ -1751,7 +2182,7 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
             yarn=cfg.rope_yarn)
     # the rotary is the layer type's: a second table where SLIDING layers have their own
     by_op = {SLIDING: rotary_angles(pos, cfg.sliding_rope_theta or cfg.rope_theta,
-                                    cfg.head_dim // 2)} if cfg.has_window else {}
+                                    cfg.head_dim // 2)} if cfg.has_window and cfg.rotary else {}
     if cfg.linear_rotary:  # the LINEAR layers' own: plain, over the whole of their heads
         by_op[LINEAR] = rotary_angles(pos, cfg.rope_theta, cfg.linear_head_dim // 2)
     if batch != 1:
@@ -1769,17 +2200,38 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
     stats = jnp.zeros((cfg.layer_stats,), jnp.float32)
     served = jnp.asarray([batch * s, batch], jnp.float32)
 
+    cut = cfg.cut_layer if rows is not None else None  # the layer from which the wanted rows run alone
+    at = None if rows is None else np.asarray(rows)
+
+    def wanted(u):  # the wanted rows of each sequence
+        return u.reshape(batch, s, -1)[:, at].reshape(batch * len(at), -1)
+
     def stack(x, stats):  # every layer once
+        handed = {} if cfg.hands_on else None  # what a layer made that a later one reads, beside x
         for i, p in enumerate(params["layers"]):
             kind = cfg.layer_kind(i)
-            x, layer_stats = decoder_layer(p, x, by_op.get(kind[0], angles), idx_angles, cfg, kind, batch)
+            if handed is None:
+                x, layer_stats = decoder_layer(p, x, by_op.get(kind[0], angles), idx_angles, cfg,
+                                               kind, batch)
+            else:
+                x, layer_stats, handed = decoder_layer(
+                    p, x, by_op.get(kind[0], angles), idx_angles, cfg, kind, batch, index=i,
+                    handed=handed, rows=rows if cut is not None and i >= cut else None)
+                if i == cut and "m" in handed:  # the later layers read their own rows of it
+                    handed["m"] = wanted(handed["m"])
             stats = stats + layer_stats
-        return x, stats
+        return (x if rows is None or cut is not None else wanted(x)), stats
 
     if cfg.passes > 1:
         x, stats, logits = _passes(params, x, stack, stats, served, cfg, batch, blocks)
         return (x, stats, exit_distribution(logits)) if exits else (x, stats)
     x, stats = stack(x, stats)
+    if cut is not None:  # every group the step has not at 0, then the rows the layers ran
+        layers = len(params["layers"])
+        ran = cut * batch * s + (layers - cut) * batch * len(at)
+        rest = (0,) * (LAYER_GROUPS - cfg.layer_stats + len(LOOP_STATS + BLOCK_STATS))
+        return x, jnp.concatenate([stats[:4], served, stats[4:], np.asarray(
+            rest + (ran, layers * batch * s), np.float32)])
     if blocks:  # every group the step has not at 0, LOOP_STATS' places among them
         rest = (0,) * (LAYER_GROUPS - cfg.layer_stats + len(LOOP_STATS)) + blocks
         return x, jnp.concatenate([stats[:4], served, stats[4:], np.asarray(rest, np.float32)])
@@ -1854,10 +2306,11 @@ def embed(params, patches, prompt_ids, scale: float = 1.0, dtype=None):
 
 
 def head_params(params) -> dict:
-    """What :func:`logits_of` reads of the tree: the final gain, and the
-    output head or, where the two are tied, the embedding table."""
+    """What :func:`logits_of` reads of the tree: the final gain (a LayerNorm's
+    bias beside it), and the output head or, where the two are tied, the
+    embedding table."""
     table = "head" if "head" in params else "embed"
-    return {"norm": params["norm"], table: params[table]}
+    return {name: params[name] for name in ("norm", "norm_b", table) if name in params}
 
 
 def logits_of(params, x, cfg: DecoderConfig):
@@ -1867,7 +2320,7 @@ def logits_of(params, x, cfg: DecoderConfig):
     if cfg.passes > 1:  # a looped model's rows come normed from the end of their last pass
         a = x.astype(params["norm"].dtype)
     else:
-        a = rms_norm(x, params["norm"], cfg.rms_eps).astype(params["norm"].dtype)
+        a = block_norm(x, params, "norm", cfg).astype(params["norm"].dtype)
     if cfg.tie_embedding:
         logits = jax.lax.dot_general(a, params["embed"], (((1,), (1,)), ((), ())),
                                      preferred_element_type=jnp.float32)
@@ -1877,12 +2330,14 @@ def logits_of(params, x, cfg: DecoderConfig):
 
 
 def frame_hidden(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, threshold: float,
-                 exits: bool = False):
+                 exits: bool = False, last_rows: bool = False):
     """``frames [B, P, H, W]`` raw (a frame is a sequence), calibrated,
     cut into patches, embedded and each followed by the prompt, through
     the trunk -> ``(x [B*S, D] at every token, frame after frame, stats
     [6] float32 in :data:`STEP_STATS`' order)``, and with ``exits`` a looped
-    model's exit distribution ``[R, B*S]`` third (:func:`trunk`)."""
+    model's exit distribution ``[R, B*S]`` third (:func:`trunk`). With
+    ``last_rows`` each frame's LAST row alone is wanted: ``x [B, D]``
+    (:func:`trunk`'s ``rows``)."""
     from psana_ray_tpu.models.vit import patchify_panels
     from psana_ray_tpu.ops import fused_calibrate
 
@@ -1899,16 +2354,19 @@ def frame_hidden(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, thres
             [embed(p, frame, ids, cfg.embedding_multiplier, cfg.stream_dtype)
              for frame in patchify_panels(x, cfg.patch)]))(
             {"patch": params["patch"], "embed": params["embed"]}, x, prompt_ids)
-    return trunk(params, x, pos, cfg, batch, exits)
+    return trunk(params, x, pos, cfg, batch, exits, rows=(len(pos) - 1,) if last_rows else None)
 
 
 def frame_step(params, calib, frames, prompt_ids, *, cfg: DecoderConfig, threshold: float):
     """The serving step: :func:`frame_hidden`, then the logits of each
     frame's next token -> ``(logits [B, V] float32, stats [6] float32)``."""
-    x, stats = frame_hidden(params, calib, frames, prompt_ids, cfg=cfg, threshold=threshold)
+    cut = cfg.cut_layer is not None  # the trunk's later layers run on the served rows alone
+    x, stats = frame_hidden(params, calib, frames, prompt_ids, cfg=cfg, threshold=threshold,
+                            last_rows=cut)
     s = x.shape[0] // frames.shape[0]
     with jax.named_scope("head"):
-        logits = jax.jit(lambda p, x: logits_of(p, x, cfg))(head_params(params), x[s - 1::s])
+        logits = jax.jit(lambda p, x: logits_of(p, x, cfg))(head_params(params),
+                                                            x if cut else x[s - 1::s])
     return logits, stats
 
 
@@ -1918,11 +2376,12 @@ def fold_step_stats(metrics, stats) -> None:
     selection over latent attention or with windowed layers (the band's
     pairs in the selection's places), twelve with linear layers, thirteen
     where a pass goes ahead of the held rows' loop, fifteen from a looped
-    model, seventeen where a causal call takes a block of heads a grid step)
+    model, seventeen where a causal call takes a block of heads a grid step,
+    nineteen where the trunk's later layers ran on the served rows alone)
     to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""
     names = (STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS + LOOP_STATS
-             + BLOCK_STATS)
+             + BLOCK_STATS + ROWS_STATS)
     for name, value in zip(names, np.asarray(stats, np.float64)):
         metrics.add_counter(name, float(value))

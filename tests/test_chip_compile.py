@@ -1188,6 +1188,80 @@ def test_the_lightning_kernel_the_chip_compiles_carries_its_state_float32(one_ch
     assert jax.jit(fn).lower(*args).compile().as_text().count("tpu_custom_call") >= 1
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones among them."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for inner in eqn.params.values():
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(getattr(inner, "jaxpr", inner), "eqns"):
+                yield from _pallas_calls(getattr(inner, "jaxpr", inner))
+
+
+def test_the_selective_scan_the_chip_compiles_holds_one_float32_state_and_no_token_channel_state_array(
+        one_chip, monkeypatch):
+    """Mamba-1's scan at the published sizes (2 x 8,704 tokens, 5,120 channels
+    over a state of 16), traced as the step calls it (not interpreted): ONE
+    kernel whose scratch holds ONE float32 array of a state's size, ``[10,
+    16, 512]`` (a channel tile a slot); nothing the call makes outside or
+    inside it is as large as ``[T, 5,120, 16]`` (5.7 GB in float32: what an
+    associative scan of XLA's would write) or loops over the tokens in HBM;
+    and Mosaic takes it for the described v5e, with the second output a
+    later layer's memory unit reads."""
+    import functools
+
+    from psana_ray_tpu.ops import selective_scan as ss
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, s, c, n = 17408, 8704, 5120, 16
+    operands = (S((t, c), BF16), S((t, c), F32), S((t, ss.LANES), BF16), S((t, c), BF16),
+                S((c, n), F32), S((c,), F32), S((c,), F32))
+    fn = functools.partial(ss.selective_scan, seq_len=s, keep=True, interpret=False)
+    traced = jax.make_jaxpr(fn)(*operands)
+    (call,) = _pallas_calls(traced.jaxpr)
+    assert call.params["name"] == "selective_scan" and not call.params["interpret"]
+    assert ss.scan_tiles(s, c) == (256, 512)
+    scratch = call.params["grid_mapping"].scratch_avals
+    states = [a for a in scratch if int(np.prod(a.shape)) == c * n]
+    assert [(a.shape, a.dtype) for a in states] == [((10, n, 512), F32)]
+    assert all(a.dtype == F32 for a in scratch)
+    made = [v.aval for eqn in traced.jaxpr.eqns for v in eqn.outvars]
+    assert max(int(np.prod(a.shape)) for a in made + list(scratch)) <= t * c < t * c * n
+    assert not any(eqn.primitive.name in ("scan", "while") for eqn in traced.jaxpr.eqns)
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), operands)
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * t * c  # no array beside its operands
+
+
+def test_one_differential_windowed_layer_compiles_at_the_published_sizes_as_two_band_calls(
+        one_chip, monkeypatch):
+    """Phi-4-mini-flash's windowed layer at 2 x 8,704 tokens: 20 head pairs
+    over 10 key pairs of 2 x 64, two calls of the batched kernel at ``d`` 64,
+    ``dv`` 128, two query half-heads a key half-head, in 256 x 512 tiles under
+    the window of 512 — shapes no other cell compiles — and nothing else of
+    Mosaic's in the layer."""
+    import json
+
+    from psana_ray_tpu.models import decoder
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with open(os.path.join(REPO, "benchmark", "configs", "phi4_mini_flash_prefill_epix10k2m.json")) as f:
+        cfg = decoder.DecoderConfig.from_mapping(json.load(f))
+    assert cfg.layer_types[1] == decoder.SLIDING and cfg.sliding_window == 512
+    assert sa.causal_tiles(8704, 2, cfg.causal_q_tile, cfg.causal_kv_tile, 512) == (256, 512)
+    shapes = jax.eval_shape(lambda k: decoder.init_params(cfg, k), jax.random.key(0))["layers"][1]
+    layer = {k: v for k, v in shapes.items() if not k.startswith(("w_gate", "w_up", "w_down", "norm2"))}
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip),
+                        (layer, S((17408, 2560), BF16)))
+    text = jax.jit(lambda p, x: decoder.diff_attention(p, x, 2, cfg, 1, window=512)[0]).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2 and text.count("windowed_gqa_attention") >= 2
+
+
 def test_the_block_selection_and_the_call_under_its_flags_compile_at_sixteen_heads_a_group(
         one_chip, monkeypatch):
     """The sparse layer's two calls ALONE at the published sizes (S 34,304, 32

@@ -36,7 +36,8 @@ FOLD_DEBT = ["proj_ms", "mlp_ms", "sparse_attn_ms", "conv_ms", "latent_attn_ms",
              "attn_live_tile_share", "gmm_roofline_share", "latent_attention_roofline_share"]
 # decoder cells without a share of the peak: keye has no roofline module yet, the rest wait for room
 NO_STEP_MFU = {"keye_epix_saturated", "granite_epix_saturated", "ouro_epix_saturated",
-               "nemotron3_epix_saturated", "olmo_hybrid_epix_saturated", "minicpm_sala_epix_saturated"}
+               "nemotron3_epix_saturated", "olmo_hybrid_epix_saturated", "minicpm_sala_epix_saturated",
+               "phi4flash_epix_saturated"}
 # rule 8's table, the ONE place tests/ says which cell has which mechanism: a scope one kind of
 # layer opens -> whether a configuration has that kind (ops, feeds: its layer_kind()s, unzipped)
 OPENED_BY = {
@@ -44,7 +45,12 @@ OPENED_BY = {
     "gdn": lambda c, ops, feeds: decoder.LINEAR in ops and c.linear_decay == "head",
     "lightning": lambda c, ops, feeds: decoder.LINEAR in ops and c.linear_decay == "fixed",
     "ssd": lambda c, ops, feeds: decoder.MAMBA in ops,
-    "conv": lambda c, ops, feeds: bool({decoder.CONV, decoder.MAMBA} & ops) or (
+    "selective_scan": lambda c, ops, feeds: decoder.MAMBA1 in ops,
+    "diff": lambda c, ops, feeds: c.diff_attention and bool(
+        {decoder.ATTENTION, decoder.SLIDING, decoder.CROSS} & ops),
+    "cross_attn": lambda c, ops, feeds: decoder.CROSS in ops,
+    "gmu": lambda c, ops, feeds: decoder.GMU in ops,
+    "conv": lambda c, ops, feeds: bool({decoder.CONV, decoder.MAMBA, decoder.MAMBA1} & ops) or (
         decoder.LINEAR in ops and c.linear_decay != "fixed"),
     "window_attn": lambda c, ops, feeds: decoder.SLIDING in ops,
     "latent_attn": lambda c, ops, feeds: decoder.LATENT in ops,

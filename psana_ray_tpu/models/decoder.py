@@ -182,6 +182,13 @@ ROWS_STATS = (
     "trunk_rows_run_total",      # over the layers, the rows the layer ran
     "trunk_rows_full_total",     # layers x B * S: every layer on every row
 )
+# and one after those nineteen (the groups above 0 where the step has none, BLOCK_STATS' two at what
+# its calls take), ONLY from a step in which some causal call cuts a STACKED group's rows into parts
+# (`sparse_attention.parts_a_step`: heads that share their keys): a constant of the shapes
+PART_STATS = (
+    "attn_part_tiles_total",     # (part, query tile, key tile) score products the step's causal calls
+                                 # write: over `attn_grid_steps_total`, the parts a grid step
+)
 # the places the LAYERS count at most: the first four of STEP_STATS and the four groups a layer has
 LAYER_GROUPS = 4 + len(SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS)
 # layer_types, as config.json spells them
@@ -2119,14 +2126,17 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     return (x, jnp.stack(stats), handed) if handing else (x, jnp.stack(stats))
 
 
-def causal_call_steps(cfg: DecoderConfig, i: int, batch: int, s: int) -> Tuple[int, int]:
-    """Layer ``i``'s share of :data:`BLOCK_STATS`: the head tiles its call of
-    the batched causal kernel visits and the grid steps it takes
+def causal_call_steps(cfg: DecoderConfig, i: int, batch: int, s: int) -> Tuple[int, int, int]:
+    """Layer ``i``'s share of :data:`BLOCK_STATS` and :data:`PART_STATS`: the
+    head tiles its call of the batched causal kernel visits, the grid steps
+    it takes and the part tiles those steps write
     (``sparse_attention.causal_steps``, from what the call is given: latent
     attention's heads alone in their groups under the selection's mask or
-    none, a grouped-query layer's with or without a window and the kernel's
-    own rotary), constants of the shapes; ``(0, 0)`` where it makes no such
-    call. The two differ only where a grid step takes a block of heads."""
+    none, a grouped-query layer's under a selection of keys or of blocks,
+    with or without a window and the kernel's own rotary), constants of the
+    shapes; zeros where it makes no such call. The first two differ only
+    where a grid step takes a block of heads, the last two only where a
+    stacked group's rows are cut into parts."""
     op = cfg.layer_kind(i)[0]
     tiles = {"block_q": cfg.causal_q_tile, "block_k": cfg.causal_kv_tile}
     if op == LATENT:
@@ -2137,17 +2147,23 @@ def causal_call_steps(cfg: DecoderConfig, i: int, batch: int, s: int) -> Tuple[i
                                cfg.qk_rope_head_dim, **tiles)
     if cfg.diff_attention:  # two calls a layer, pairs of half-heads over values twice as wide
         if op not in (ATTENTION, SLIDING, CROSS):
-            return 0, 0
+            return 0, 0, 0
         one = sa.causal_steps(batch, s, cfg.num_kv_heads // 2, cfg.num_heads // cfg.num_kv_heads,
                               cfg.head_dim, 2 * cfg.head_dim,
                               window=cfg.sliding_window if op == SLIDING else None, **tiles)
-        return 2 * one[0], 2 * one[1]
-    if op in (ATTENTION, SLIDING) and not cfg.indexer_heads:
-        return sa.causal_steps(batch, s, cfg.num_kv_heads, cfg.heads(i) // cfg.num_kv_heads,
-                               cfg.head_dim, cfg.head_dim,
-                               window=cfg.sliding_window if op == SLIDING else None,
-                               turned=_kernel_turns(cfg, cfg.rotary or None), **tiles)
-    return 0, 0
+        return tuple(2 * count for count in one)
+    if op not in (ATTENTION, SLIDING):
+        return 0, 0, 0
+    group = (batch, s, cfg.num_kv_heads, cfg.heads(i) // cfg.num_kv_heads, cfg.head_dim, cfg.head_dim)
+    if cfg.indexer_heads:  # a selection of keys over grouped-query heads, as `_attention` calls it
+        q_tile = sa.pick_tile(s, cfg.q_tile)
+        return sa.causal_steps(*group, block_q=max(cfg.attn_q_tile, q_tile), mask_tiles=(
+            q_tile, sa.mask_tile(s, sa.pick_tile(s, cfg.kv_tile))))
+    if cfg.block_select and cfg.block_select.selects(s):  # of blocks: the flags' own key tile
+        return sa.causal_steps(*group, block_q=cfg.attn_q_tile, mask_tiles=(
+            sa.pick_tile(s, 128), cfg.block_select.tiles(s)[0]))
+    return sa.causal_steps(*group, window=cfg.sliding_window if op == SLIDING else None,
+                           turned=_kernel_turns(cfg, cfg.rotary or None), **tiles)
 
 
 def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = False, rows=None):
@@ -2193,14 +2209,15 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
     # a step in which some causal call takes a BLOCK of heads a grid step counts BLOCK_STATS, last of
     # all: constants of the shapes, summed here over the layers (and a looped model's passes) and
     # laid down as ONE constant (a scalar a layer is a transfer a layer while the step is traced)
-    calls = [causal_call_steps(cfg, i, batch, s) for i in range(len(params["layers"]))]
-    blocks = ()
-    if any(tiles != steps for tiles, steps in calls):
-        blocks = tuple(cfg.passes * sum(column) for column in zip(*calls))
+    cut = cfg.cut_layer if rows is not None else None  # the layer from which the wanted rows run alone
+    calls = [causal_call_steps(cfg, i, batch, s) for i in range(len(params["layers"]))
+             if cut is None or i < cut]  # (on the wanted rows alone a layer makes no call of the kernel)
+    tiles, steps, parts = (cfg.passes * sum(column) for column in zip(*calls))
+    cuts = (parts,) if parts != steps else ()  # PART_STATS, the vector's last
+    blocks = (tiles, steps) if cuts or tiles != steps else ()
+    tail = blocks + ((0, 0) + cuts if cuts else ())  # (ROWS_STATS' two places stand between them)
     stats = jnp.zeros((cfg.layer_stats,), jnp.float32)
     served = jnp.asarray([batch * s, batch], jnp.float32)
-
-    cut = cfg.cut_layer if rows is not None else None  # the layer from which the wanted rows run alone
     at = None if rows is None else np.asarray(rows)
 
     def wanted(u):  # the wanted rows of each sequence
@@ -2223,17 +2240,17 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
         return (x if rows is None or cut is not None else wanted(x)), stats
 
     if cfg.passes > 1:
-        x, stats, logits = _passes(params, x, stack, stats, served, cfg, batch, blocks)
+        x, stats, logits = _passes(params, x, stack, stats, served, cfg, batch, tail)
         return (x, stats, exit_distribution(logits)) if exits else (x, stats)
     x, stats = stack(x, stats)
     if cut is not None:  # every group the step has not at 0, then the rows the layers ran
         layers = len(params["layers"])
         ran = cut * batch * s + (layers - cut) * batch * len(at)
-        rest = (0,) * (LAYER_GROUPS - cfg.layer_stats + len(LOOP_STATS + BLOCK_STATS))
+        rest = (0,) * (LAYER_GROUPS - cfg.layer_stats + len(LOOP_STATS)) + (blocks or (0, 0))
         return x, jnp.concatenate([stats[:4], served, stats[4:], np.asarray(
-            rest + (ran, layers * batch * s), np.float32)])
-    if blocks:  # every group the step has not at 0, LOOP_STATS' places among them
-        rest = (0,) * (LAYER_GROUPS - cfg.layer_stats + len(LOOP_STATS)) + blocks
+            rest + (ran, layers * batch * s) + cuts, np.float32)])
+    if tail:  # every group the step has not at 0, LOOP_STATS' places among them
+        rest = (0,) * (LAYER_GROUPS - cfg.layer_stats + len(LOOP_STATS)) + tail
         return x, jnp.concatenate([stats[:4], served, stats[4:], np.asarray(rest, np.float32)])
     if cfg.layer_stats > 4:
         return x, jnp.concatenate([stats[:4], served, stats[4:]])
@@ -2267,7 +2284,7 @@ def _passes(params, x, stack, stats, served, cfg: DecoderConfig, batch: int, blo
     statistics vector (the layers' places summed over passes and layers,
     ``tokens`` and ``served`` once a step, every group the step has not 0,
     :data:`LOOP_STATS` last but for ``blocks``, the step's :data:`BLOCK_STATS` where its calls
-    take a block of heads) and the exit gate's logits ``[R, B*S]``
+    take a block of heads, and the places after them where they cut a group's rows) and the exit gate's logits ``[R, B*S]``
     float32. The passes are a LOOP IN THE PROGRAM
     (``lax.scan`` over the pass: the compiled module is one stack long, one
     ``while`` around it), its body the stack and
@@ -2377,11 +2394,12 @@ def fold_step_stats(metrics, stats) -> None:
     pairs in the selection's places), twelve with linear layers, thirteen
     where a pass goes ahead of the held rows' loop, fifteen from a looped
     model, seventeen where a causal call takes a block of heads a grid step,
-    nineteen where the trunk's later layers ran on the served rows alone)
+    nineteen where the trunk's later layers ran on the served rows alone,
+    twenty where a causal call cuts a stacked group's rows into parts)
     to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
     in ``snapshot()`` and so under ``/metrics``)."""
     names = (STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS + LOOP_STATS
-             + BLOCK_STATS + ROWS_STATS)
+             + BLOCK_STATS + ROWS_STATS + PART_STATS)
     for name, value in zip(names, np.asarray(stats, np.float64)):
         metrics.add_counter(name, float(value))

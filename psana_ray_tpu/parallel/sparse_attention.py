@@ -98,8 +98,19 @@ SCORE_TILE_BYTES = _VMEM_LIMIT // 5
 BAND_TILES = (0.5, 1.0)
 BLOCK_HEADS = (8, 4, 2)  # the heads a grid step may take where each is alone in its group
 # of the score tiles a kernel body's unrolled heads write, over its branches (:func:`heads_a_step`):
-# 35.7 and 18.9 MB read faster than one head a step, 37.9 a third SLOWER (PR 66)
+# 35.7 and 18.9 MB read faster than one head a step, 37.9 a third SLOWER (PR 66). The falloff is a
+# BLOCK OF HEADS', not the written order's: a stacked group cut into parts (:func:`parts_a_step`)
+# writes the score bytes its one product wrote, and read faster at lfm2's 37.9 MB (four parts, -20%),
+# at nemotron3's 35.6 in sixteen parts (32 unrolled part bodies, -18%) and at minicpm_sala's sixteen
+# (PR 75, the kernels alone): neither the unrolled bytes nor the number of unrolled bodies makes a body slow. What a
+# block adds and parts do not is every head's OWN key and value block, accumulator and turned
+# scratch in VMEM and in the pipeline's copies; no bundle dump was taken, so that is where to look
 UNROLLED_SCORE_BYTES = 36_000_000
+# of ONE part's score tile where a stacked group's rows are cut into parts (:func:`parts_a_step`): parts
+# of 1.05-1.1 MB read fastest or within 2% of it, parts of 0.52 MB slower than no cut (PR 75); and
+# the part bodies a kernel may hold unrolled over its branches: each costs a start 0.1 s
+PART_SCORE_BYTES = 1_000_000
+PART_BODIES = 8
 MASK_TILE = 2176  # the widest key tile a selection's mask is written in (:func:`mask_tile`): on the
 # v5e the kernel under it read 34.8 ms a layer at 512 x 2,176 and 39.4 at 256 x 4,352 (PR 47)
 
@@ -608,7 +619,7 @@ def _turned_head(x, cos, sin, width: int, scale: float):
 
 def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared, masked,
                    gated=False, window=None, turn=None, rotary=None, heads=1, joint=False,
-                   stack=False, blocks=None):
+                   stack=False, blocks=None, cut=1):
     rest = list(rest)
     # a BLOCK of `heads` heads, each alone in its group, is a wider block of the same arrays: with
     # `joint` keys and values are ONE block, head h's keys then its values
@@ -643,8 +654,10 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
 
     # (alone in its step a head reads the whole refs, as it was traced before there were blocks:
     # `tests/test_decoder_kimi.py -k traces_the_kernel` holds that body's jaxpr)
+    # (`cut > 1`: a STACKED group's rows in `cut` parts of whole heads, the same slices `cut` times as
+    # short. `heads > 1` are heads alone in their groups, `cut > 1` heads that share their keys)
     def of(h):  # head h of the block's: its rows of the stacked scratch (all of them where it is alone)
-        return slice(None) if heads == 1 else slice(h * rows, (h + 1) * rows)
+        return slice(None) if heads * cut == 1 else slice(h * rows // cut, (h + 1) * rows // cut)
 
     def lanes(ref, h, width, part=0):  # head h's part: a lane block of a token-major tile
         if heads == 1:
@@ -672,8 +685,19 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
 
     def update(with_diagonal, with_lower_edge=False):
         open_ = []  # where a pair counts: the selection's tile or the band's edges, ONE for every head
+        tiles = []  # a stacked group's key tile (turned, where the kernel turns) and value tile, ONE for its parts
 
-        def score(h):  # head h's value tile, and its score tile with the closed pairs at NEG_INF
+        def part(h):  # part h of a stacked group's rows, and the group's key and value tiles
+            if not tiles:
+                k = k_ref[...]
+                if rotary is not None:
+                    k = _turned_head(k, cos_k[...], sin_k[...], width, turned_by).astype(stacked_ref.dtype)
+                tiles.append((k, v_ref[...]))
+            if stack or rotary is not None:
+                return (stacked_ref[of(h)], *tiles[0])
+            return (q_ref[h * rep // cut:(h + 1) * rep // cut].reshape(rows // cut, d), *tiles[0])
+
+        def head(h):  # head h's rows (a group's, stacked), and its key and value tiles
             v = lanes(v_ref, h, dv, parts - 1)
             if stack:
                 q, k = stacked_ref[...], k_ref[...]
@@ -684,6 +708,10 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
                 q = stacked_ref[...] if heads == 1 else stacked_ref[of(h)]
                 k = _turned_head(lanes(k_ref, h, d), cos_k[...], sin_k[...], width,
                                  turned_by).astype(stacked_ref.dtype)
+            return q, k, v
+
+        def score(h):  # head h's value tile, and its score tile with the closed pairs at NEG_INF
+            q, k, v = part(h) if cut > 1 else head(h)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             if shared:
                 if turn is not None:
@@ -723,8 +751,8 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
                     [col > row - window] if with_lower_edge else [])
                 open_.append(functools.reduce(jnp.logical_and, edges)[None])
             if open_:
-                s = jnp.where(open_[0], s.reshape(rep, block_q, block_k),
-                              NEG_INF).reshape(rows, block_k)
+                s = jnp.where(open_[0], s.reshape(rep // cut, block_q, block_k),
+                              NEG_INF).reshape(rows // cut, block_k)
             return s, v
 
         def fold(h, s, v):  # head h's running softmax takes the tile in
@@ -742,8 +770,8 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
         # under another's exponentials (two score tiles live; alone in its step a head is the
         # chain it was)
         ahead = score(0)
-        for h in range(heads):
-            tile, ahead = ahead, score(h + 1) if h + 1 < heads else None
+        for h in range(heads * cut):
+            tile, ahead = ahead, score(h + 1) if h + 1 < heads * cut else None
             fold(h, *tile)
 
     if masked:
@@ -799,7 +827,8 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
                       q_shared=None, k_shared=None, mask=None, window: Optional[int] = None,
                       out_gate=None, shared_turn=None, shared_scale: float = 1.0, turn=None,
                       turn_width: int = 0, turn_scale: float = 1.0, q_scale: float = 1.0,
-                      heads: Optional[int] = None, mask_blocks: Optional[BlockSelection] = None):
+                      heads: Optional[int] = None, mask_blocks: Optional[BlockSelection] = None,
+                      cut: Optional[int] = None):
     """What :func:`masked_gqa_attention` runs. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
@@ -947,7 +976,43 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     q and k turned by the kernel, 768 x 768: 0.727 -> at 2: 0.588 / 0.578 /
     0.581; at 4: 0.566 / 0.541 / 0.520; at 8: 0.580 / 0.486-0.501 /
     0.549 — measured, and NOT taken by the rule (:func:`heads_a_step` says
-    why: the step's 48 call sites pay for the longer body at every start)."""
+    why: the step's 48 call sites pay for the longer body at every start).
+    Where heads SHARE their keys (``H/G > 1``) a grid step holds the group's
+    heads stacked and, since PR 75, cuts the stacked rows into ``parts`` runs
+    of WHOLE heads (:func:`parts_a_step`, the rule; ``cut`` asks for a number
+    of its own) written in the same order: part ``p + 1``'s score product
+    before part ``p``'s softmax. Every part reads the SAME key and value
+    tile, and the key tile's turn, the mask's convert and compare, the
+    flags' spread and the band's compares are computed once a visit; a
+    row's arithmetic is what it was, and on the chip every reading below
+    was EQUAL TO THE BIT to its parent's output. One layer's kernel alone on
+    the v5e, ms, the parent's one stacked product -> ``parts`` written part
+    after part / skewed by one (my chip runs, PR 75, seed 7500000001,
+    twenty calls a reading, the parent's read again last to 0.01; **bold**
+    the rule's): laguna full, 2 x 8,704, 8 groups of 6 turned by the
+    kernel, gated, 512 x 1,088: 19.33 -> at 2: 18.28 / 16.60; at 3: 17.80 /
+    **15.54**; at 6: 18.83 / 15.86; laguna windowed, 9 a group, 256 x 512
+    under 512: **8.13** -> at 3: 8.23 / 7.51 (twelve part bodies over its
+    four branches: past what a start pays for, :func:`parts_a_step`); at 9:
+    9.43 / 8.53 (parts of 0.52 MB: slower than none); keye, 34,304 under the mask, 4 groups of 8
+    stacked in the kernel, 256 x 2,176: 84.93 -> at 2: 77.24 / 75.38; at 4:
+    73.99 / 68.32; at 8: 73.57 / **65.61** (in 128 x 2,176 tiles 85.65 ->
+    78.56 / 75.70, 75.85 / 68.09, 82.20 / 64.61: the query tile stays);
+    lfm2, 4 x 8,704, 8 groups of 4 heads of 64 head-major, 1,088 x 1,088:
+    25.35 -> at 2: 24.63 / 21.19; at 4: 24.19 / **20.30** (in 544 x 1,088
+    tiles 25.80 -> 25.29 / 20.91, 25.29 / 20.49: the tile stays; granite's
+    one sequence 6.10 -> 5.93 / 5.06, 5.81 / **4.84**); nemotron3, 4 x
+    8,704, 2 groups of 16, 256 x 1,088: 25.25 -> at 2: 24.15 / 20.69; at 4:
+    23.89 / **20.32**; at 8: 24.98 / 20.68; at 16: 26.39 / 20.69;
+    minicpm_sala, 34,304 under a block selection's flags, 2 groups of 16,
+    128 x 2,048: 79.09 -> at 2: 73.67 / 77.13; at 4: 72.03 / 69.40; at 8:
+    72.65 / **66.51**; at 16: 80.18 / 62.91; phi4flash's differential
+    calls, 2 x 8,704, 10 groups of 2 half-heads of 64 over values of 128:
+    1,088 x 1,088 7.61 -> 7.50 / **6.12**; 256 x 512 under the window 3.02
+    -> 3.11 / 2.86 (**1 part**: 0.52 MB a part, under the floor the nine
+    set). Part after part gains a third of what the skew gains or nothing:
+    the gain is the ORDER, one part's exponentials under the next part's
+    product, not the smaller tile."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, hd = q.shape
@@ -991,6 +1056,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     hb = heads_a_step(g, rep, bq, bk, d, dv, ds, masked=masked, window=window,
                       turned=turn is not None, want=heads)
     joint = hb > 1 and v is None  # ONE block [k_h | v_h] of the block's heads
+    cut = parts_a_step(rep, bq, bk, ds, masked=masked, window=window, want=cut)
 
     def in_place(width):  # a head of whole lane blocks
         return width % 128 == 0
@@ -1026,7 +1092,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         return (jnp.transpose(x.reshape(b, sk, g, width), (0, 2, 1, 3)),
                 pl.BlockSpec((None, None, bk, width), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0)))
 
-    parts = 1
+    parts = 1  # (of the ONE operand that holds keys and values; a stacked group's are `cut`)
     if v is None and in_place(d):
         v, parts = k, 2  # two blocks of the one operand
     elif v is None:
@@ -1072,7 +1138,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
                           gated=out_gate is not None, window=window,
                           turn=None if shared_turn is None else float(shared_scale),
                           rotary=rotary, heads=hb, joint=joint, stack=stack,
-                          **({"blocks": blocks} if blocks else {})),
+                          **({"blocks": blocks} if blocks else {}), **({"cut": cut} if cut > 1 else {})),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(b, g // hb, len(pairs)),
             in_specs=in_specs, out_specs=place_spec(dv) if in_place(dv) else major_spec(dv),
@@ -1108,12 +1174,20 @@ def block_vmem_bytes(hb: int, bq: int, bk: int, d: int, dv: int, ds: int = 0, *,
     return count + (2 * bq * bk if masked else 0)
 
 
+def _branches(masked: bool, window: Optional[int]) -> int:
+    """The branches :func:`_causal_kernel`'s body holds, each with the unrolled
+    heads or parts of its own: one under a mask, two without (below the
+    diagonal and on it), four under a window (each edge of the band)."""
+    return 1 if masked else 2 if window is None else 4
+
+
 def heads_a_step(g: int, rep: int, bq: int, bk: int, d: int, dv: int, ds: int = 0, *,
                  masked: bool = False, window: Optional[int] = None, turned: bool = False,
                  want: Optional[int] = None) -> int:
     """How many heads ONE grid step of the batched kernel takes: the rule,
     taken in Python from the shapes. 1 wherever heads share their keys
-    (``rep > 1``: a group's stacked heads already share a grid step), a
+    (``rep > 1``: a group's stacked heads already share a grid step, and
+    :func:`parts_a_step` cuts THEIR rows into parts written in this order), a
     head is no whole lane blocks, or the kernel turns q and k itself
     (``turned``: below); else the LARGEST of 8 / 4 / 2 (never 16: slower,
     and 32 s to compile, PR 47) that divides ``g``, whose count
@@ -1140,12 +1214,59 @@ def heads_a_step(g: int, rep: int, bq: int, bk: int, d: int, dv: int, ds: int = 
         return next(hb for hb in range(int(want), 0, -1) if g % hb == 0)
     if turned:
         return 1
-    bodies = 1 if masked else 2 if window is None else 4
+    bodies = _branches(masked, window)
     for hb in BLOCK_HEADS:
         if (g % hb == 0 and hb * bodies * 4 * bq * bk <= UNROLLED_SCORE_BYTES
                 and block_vmem_bytes(hb, bq, bk, d, dv, ds, masked=masked) <= _VMEM_LIMIT):
             return hb
     return 1
+
+
+def parts_a_step(rep: int, bq: int, bk: int, ds: int = 0, *, masked: bool = False,
+                 window: Optional[int] = None, want: Optional[int] = None) -> int:
+    """How many PARTS of whole heads a grid step cuts a STACKED group's rows
+    into (``rep > 1`` query heads that share their keys: ``rep * bq`` rows,
+    ONE key and value tile): the rule, taken in Python from the shapes. Part
+    ``p + 1``'s score product is written before part ``p``'s softmax
+    (:func:`_causal_kernel`), so one part's exponentials run under another's
+    products; what the parts share — the key tile's turn, a mask's convert
+    and compare, the flags' spread, the band's compares — is computed once a
+    visit. 1 where a head is alone in its group (:func:`heads_a_step`'s
+    blocks are that case's) or the score has a shared part (latent
+    attention's, never stacked); else the MOST parts that divide ``rep``
+    whose score tile ``[rep / parts * bq, bk]`` float32 is at least
+    :data:`PART_SCORE_BYTES`, and under which the body's unrolled parts, over
+    every branch it holds (one under a mask, two without, four under a
+    window: :func:`heads_a_step` counts the same), are at most
+    :data:`PART_BODIES`. The readings (:func:`_causal_attention` has the
+    table): every served shape read fastest skewed, by 16-23% at the rule's
+    parts; under a mask the most parts read fastest (keye's eight of 256
+    rows: the mask's vector work hides the matrix unit's refills), maskless
+    1,024 rows read 2% under 256 or 512 (laguna's full call 15.54 ms at 3
+    parts, 15.86 at 6; nemotron3 20.32 at 4, 20.69 at 16), and parts of half
+    a megabyte LOSE (laguna's windowed call 8.13 -> 8.53 at nine parts of 256
+    x 512): the floor. The bound on the bodies is a START's: a part and
+    branch more costs about 0.1 s of trace and lowering at every start, a
+    kernel and not a call site (laguna's step at 6 parts in its full calls
+    and 3 in its windowed ones, 24 part bodies where 6 stood: ``step_ms.hit``
+    333.3 -> 319.2 and warm ``setup_s`` 19.0-19.3 -> 20.4-21.3,
+    ``startup_trace_s`` 3.18 -> 3.99, ``startup_lower_s`` 3.37 -> 4.29: my chip
+    runs, PR 75), while a compile alone shows nothing (laguna's full call 6.5
+    s at one part, 6.4-9.2 at 2 / 3 / 6). So laguna's windowed calls (7.51
+    ms at three parts for 8.13: 12 bodies) and minicpm_sala's sixteen parts
+    (62.91 for 66.51 at eight) stay behind it. No bound on VMEM or on the
+    unrolled score tiles: the parts' live set is a part's scores, the next
+    part's and one part's exponentials, less than the stacked tile's with its
+    own, and the body writes the score bytes it wrote
+    (:data:`UNROLLED_SCORE_BYTES`' comment). ``want`` (a test's or a timing
+    run's own) takes the place of the rule's choice: the most parts at or
+    under it that divide ``rep``."""
+    if rep == 1 or ds:
+        return 1
+    if want is not None:
+        return next(cut for cut in range(min(int(want), rep), 0, -1) if rep % cut == 0)
+    return next((cut for cut in range(min(rep, PART_BODIES // _branches(masked, window)), 1, -1)
+                 if rep % cut == 0 and rep // cut * bq * bk * 4 >= PART_SCORE_BYTES), 1)
 
 
 def _masked_query_tile(s: int, block_q: int, mq: int, row_keys: int) -> int:
@@ -1160,12 +1281,15 @@ def _masked_query_tile(s: int, block_q: int, mq: int, row_keys: int) -> int:
 
 def causal_steps(b: int, s: int, g: int, rep: int, d: int, dv: int, ds: int = 0, *,
                  block_q: int = 256, block_k: int = 512, mask_tiles: Optional[Tuple[int, int]] = None,
-                 window: Optional[int] = None, turned: bool = False) -> Tuple[int, int]:
-    """``(head tiles, grid steps)`` of ONE call of the batched kernel, from
-    what :func:`masked_gqa_attention` is given (``mask_tiles``: the mask's
-    ``(query tile, key tile)``): the ``(head, query tile, key tile)`` visits,
-    and the grid steps that make them; their quotient is the heads a grid
-    step beyond a group's own (:func:`heads_a_step`)."""
+                 window: Optional[int] = None, turned: bool = False) -> Tuple[int, int, int]:
+    """``(head tiles, grid steps, part tiles)`` of ONE call of the batched
+    kernel, from what :func:`masked_gqa_attention` is given (``mask_tiles``:
+    the mask's ``(query tile, key tile)``): the ``(head, query tile, key
+    tile)`` visits, the grid steps that make them — their quotient is the
+    heads a grid step beyond a group's own (:func:`heads_a_step`) — and the
+    ``(part, query tile, key tile)`` score products those steps write: over
+    the grid steps, the parts a stacked group's rows are cut into
+    (:func:`parts_a_step`; the grid steps' own number where nothing is cut)."""
     if mask_tiles is None:
         bq, bk = causal_tiles(s, rep, block_q, block_k, window, d if turned else 0)
     else:
@@ -1173,8 +1297,9 @@ def causal_steps(b: int, s: int, g: int, rep: int, d: int, dv: int, ds: int = 0,
         bq = _masked_query_tile(s, block_q, mask_tiles[0], rep * bk)
     hb = heads_a_step(g, rep, bq, bk, d, dv, ds, masked=mask_tiles is not None, window=window,
                       turned=turned)
+    cut = parts_a_step(rep, bq, bk, ds, masked=mask_tiles is not None, window=window)
     pairs = len(_band_tiles(s, bq, bk, window))
-    return b * g * pairs, b * (g // hb) * pairs
+    return b * g * pairs, b * (g // hb) * pairs, b * (g // hb) * pairs * cut
 
 
 def causal_tiles(s: int, rep: int, block_q: int, block_k: int,

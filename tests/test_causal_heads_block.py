@@ -98,7 +98,7 @@ def test_heads_that_a_block_does_not_divide_fall_to_the_next_smaller(g, want, ta
     assert sa.heads_a_step(g, 1, 32, 32, D, D, DS) == {6: 2, 5: 1, 4: 4}[g]
 
 
-# the nine served shapes: (groups, heads a group, query tile, key tile, head, values, shared part,
+# the served shapes: (groups, heads a group, query tile, key tile, head, values, shared part,
 # masked, window, turned by the kernel) -> the heads a grid step
 SERVED = {
     "dsv32": ((128, 1, 512, 2176, 128, 128, 64, True, None, False), 8),
@@ -110,6 +110,30 @@ SERVED = {
     "laguna_full": ((8, 6, 512, 1088, 128, 128, 0, False, None, True), 1),
     "laguna_windowed": ((8, 9, 256, 512, 128, 128, 0, False, 512, True), 1),
     "nemotron3": ((2, 16, 256, 1088, 128, 128, 0, False, None, False), 1),
+    "keye": ((4, 8, 256, 2176, 128, 128, 0, True, None, False), 1),
+    "minicpm_sala": ((2, 16, 128, 2048, 128, 128, 0, True, None, False), 1),
+    "phi4flash_full": ((10, 2, 1088, 1088, 64, 128, 0, False, None, False), 1),
+    "phi4flash_windowed": ((10, 2, 256, 512, 64, 128, 0, False, 512, False), 1),
+}
+# and the PARTS a grid step cuts each one's stacked group into, with the reading that chose it: the
+# kernel alone on the v5e, ms, the parent's body -> the rule's parts (and the other parts read), each
+# skewed by one; part after part read slower at every shape (my chip runs, PR 75, seed 7500000001,
+# twenty calls a reading; `_causal_attention`'s docstring has the whole table)
+PARTS = {
+    "dsv32": (1, "a head alone in its group: a block of heads (PR 66)"),
+    "kimi": (1, "a head alone in its group"),
+    "ling3_latent": (1, "a head alone in its group"),
+    "ouro": (1, "a head alone in its group"),
+    "lfm2": (4, "25.35 -> 20.30 (2: 21.19); in 544 x 1,088 tiles 25.80 -> 20.49: the tile stays"),
+    "granite": (4, "lfm2's shape at one sequence: 6.10 -> 4.84 (2: 5.06)"),
+    "laguna_full": (3, "19.33 -> 15.54 (2: 16.60, 6: 15.86)"),
+    "laguna_windowed": (1, "three parts read 8.13 -> 7.51, twelve bodies over its four branches, +0.8 s of a "
+                           "start; nine parts of 0.52 MB 8.53, SLOWER than none: the floor"),
+    "nemotron3": (4, "25.25 -> 20.32 (2: 20.69, 8: 20.68, 16: 20.69)"),
+    "keye": (8, "84.93 -> 65.61 (2: 75.38, 4: 68.32); in 128 x 2,176 tiles 85.65 -> 64.61: the tile stays"),
+    "minicpm_sala": (8, "79.09 -> 66.51 (2: 77.13, 4: 69.40; 16: 62.91, past the bodies a start pays for)"),
+    "phi4flash_full": (2, "7.61 -> 6.12"),
+    "phi4flash_windowed": (1, "two parts of 0.52 MB read 3.02 -> 2.86: under the floor laguna's nine set"),
 }
 
 
@@ -126,17 +150,112 @@ def test_the_rule_gives_every_served_shape_its_heads_a_step(name):
     assert hb * bodies * 4 * bq * bk <= sa.UNROLLED_SCORE_BYTES < 2 * hb * bodies * 4 * bq * bk
 
 
+@pytest.mark.parametrize("name", sorted(SERVED))
+def test_the_rule_gives_every_served_shape_its_parts_a_step(name):
+    (g, rep, bq, bk, d, dv, ds, masked, window, turned), _ = SERVED[name]
+    parts, reading = PARTS[name]
+    assert sa.parts_a_step(rep, bq, bk, ds, masked=masked, window=window) == parts and reading
+    if rep == 1:
+        assert parts == 1
+        return
+    bodies = 1 if masked else 2 if window is None else 4  # the body's branches
+    assert rep % parts == 0 and parts * bodies <= sa.PART_BODIES or parts == 1
+    # the MOST parts that fill the floor within the bodies a start pays for
+    finer = [cut for cut in range(parts + 1, rep + 1) if rep % cut == 0]
+    assert all(cut * bodies > sa.PART_BODIES or rep // cut * bq * bk * 4 < sa.PART_SCORE_BYTES for cut in finer)
+    if parts > 1:
+        assert rep // parts * bq * bk * 4 >= sa.PART_SCORE_BYTES
+
+
 def test_the_tiles_the_rule_is_asked_at_are_the_served_calls_own():
     """``causal_steps`` derives a call's tiles as the call does: dsv32's 512 x
     2,176 under masks of 128 x 2,176 (44 pairs a head), kimi's 1,088 x 1,088
     (36), the looped reader's 768 x 768 (6, one head a step): head tiles over
     grid steps is the heads a step."""
     assert sa.causal_steps(1, 8704, 128, 1, 128, 128, 64, block_q=1088, block_k=1088,
-                           mask_tiles=(128, 2176)) == (128 * 44, 16 * 44)
-    assert sa.causal_steps(2, 8704, 64, 1, 128, 128, 64, block_q=1088, block_k=1088) == (2 * 64 * 36, 2 * 32 * 36)
+                           mask_tiles=(128, 2176)) == (128 * 44, 16 * 44, 16 * 44)
+    assert sa.causal_steps(2, 8704, 64, 1, 128, 128, 64, block_q=1088, block_k=1088) == (
+        2 * 64 * 36, 2 * 32 * 36, 2 * 32 * 36)
     assert sa.causal_steps(2, 2304, 16, 1, 128, 128, block_q=1088, block_k=1088,
-                           turned=True) == (2 * 16 * 6,) * 2
-    assert sa.causal_steps(4, 8704, 8, 4, 64, 64, block_q=1088, block_k=1088) == (4 * 8 * 36,) * 2
+                           turned=True) == (2 * 16 * 6,) * 3
+    # a stacked group is ONE head tile a visit and, since PR 75, four parts (lfm2's 1,088 x 1,088)
+    assert sa.causal_steps(4, 8704, 8, 4, 64, 64, block_q=1088, block_k=1088) == (
+        4 * 8 * 36, 4 * 8 * 36, 4 * 4 * 8 * 36)
+
+
+# ---------------------------------------------------------------------------
+# a STACKED group's rows in PARTS a grid step (PR 75): heads that share their keys
+# ---------------------------------------------------------------------------
+
+def _grid(rng, shape, dtype=jnp.bfloat16):
+    """Components on a grid of 1/16 within +-2 (and a rotary's tables in halves, below): a score's
+    every product and float32 sum is exact, so XLA's CPU backend, which adds a product of ANOTHER
+    height in another order, gives every height the same scores (the TPU's matrix unit does not
+    know the height: `_chip_archive/pr75/alone.py` compared every served shape's parts there)."""
+    return jnp.asarray(np.clip(np.round(rng.standard_normal(shape) * 8) / 16, -2, 2), dtype)
+
+
+# every addressing a stacked group has: (groups, heads a group, head, values) and what rides beside
+STACKED = {
+    "heads_of_64_head_major": dict(g=2, rep=4, d=64),  # lfm2's, granite's
+    "six_turned_by_the_kernel_and_gated": dict(g=2, rep=6, rotary=True, gate=True),  # laguna's full
+    # laguna's windowed: the band's lower edge crosses key tile 0 for the query tiles 1 and 2
+    "nine_turned_under_a_window_whose_lower_edge_crosses_a_tile": dict(
+        g=1, rep=9, rotary=True, gate=True, window=24, bq=16),
+    "sixteen_in_place_unturned": dict(g=1, rep=16, bq=16),  # nemotron3's
+    "sixteen_under_a_mask_stacked_in_the_kernel": dict(g=1, rep=16, masked=True, b=1),  # keye's addressing
+    "sixteen_under_a_block_selection_s_flags": dict(g=2, rep=16, flags=True, b=1),  # minicpm_sala's
+    "two_half_heads_over_values_twice_as_wide": dict(g=2, rep=2, d=64, dv=128),  # phi4flash's full
+    "two_half_heads_under_a_window": dict(g=2, rep=2, d=64, dv=128, window=24, bq=16),  # and windowed
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _stacked(name, cut):
+    case = {"b": 2, "d": D, "bq": 32, "window": None, **STACKED[name]}
+    b, g, rep, d, bq, bk = case["b"], case["g"], case["rep"], case["d"], case["bq"], 32
+    dv = case.get("dv", d)
+    rng = np.random.default_rng(len(name))
+    s, extra = 96 if case.get("flags") else 64, {"window": case["window"]}
+    wide = jnp.float32 if case.get("rotary") else jnp.bfloat16  # the kernel turns what the products wrote
+    q, k, v = _grid(rng, (b, s, g * rep * d), wide), _grid(rng, (b, s, g * d), wide), _grid(rng, (b, s, g * dv))
+    if case.get("rotary"):
+        tables = decoder.turn_tables(jnp.asarray(rng.uniform(0, 6, (b * s, d // 2)), jnp.float32), d)
+        extra.update(turn=tuple(jnp.round(2 * table) / 2 for table in tables), q_scale=0.125)
+    if case.get("gate"):
+        extra["out_gate"] = jnp.asarray(rng.uniform(0, 1, (b, s, g * rep)), jnp.float32)
+    if case.get("masked"):
+        extra["mask"] = _mask({"rng": rng}, s, bq, bk)
+    with pytest.MonkeyPatch.context() as patch:
+        if case.get("flags"):  # `select_blocks`' own flags, in key tiles of 32 (four blocks of 8) over 96 keys
+            from test_decoder_minicpm_sala import SMALL_SELECTION
+
+            patch.setattr(sa, "MASK_TILE", 32)
+            sel = sa.BlockSelection(**SMALL_SELECTION)
+            extra.update(mask_blocks=sel, mask=sa.select_blocks(
+                q[0], k[0], num_kv_heads=g, selection=sel, block_q=32)[0])
+        return np.asarray(sa._causal_attention(q, k, v, g, bq, bk, True, cut=cut, **extra).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("name,cut", [(name, cut) for name, case in sorted(STACKED.items()) for cut in sorted(
+    {next(c for c in (2, 3) if case["rep"] % c == 0), case["rep"]})])
+def test_a_stacked_group_s_rows_in_parts_equal_one_stacked_product_to_the_bit(name, cut):
+    """``cut`` parts of whole heads a grid step, part ``p + 1``'s score product
+    written before part ``p``'s softmax, against ONE product over the stacked
+    rows (``cut=1``: the body as it was), at every addressing a stacked group
+    has: every row's arithmetic is what it was."""
+    whole, parts = _stacked(name, 1), _stacked(name, cut)
+    assert np.abs(whole).mean() > 0.01 and np.isfinite(whole).all()
+    assert (whole == parts).all()
+
+
+def test_parts_that_do_not_divide_a_group_fall_to_the_next_smaller_and_heads_alone_take_none():
+    assert sa.parts_a_step(6, 32, 32, want=4) == 3 and sa.parts_a_step(9, 32, 32, want=2) == 1
+    assert sa.parts_a_step(4, 32, 32, want=16) == 4
+    assert sa.parts_a_step(1, 512, 2176, masked=True) == sa.parts_a_step(1, 512, 2176, want=2) == 1  # blocks, not parts
+    assert sa.parts_a_step(8, 1088, 1088) == 4 and sa.parts_a_step(8, 1088, 1088, masked=True) == 8  # the branches
+    assert sa.parts_a_step(8, 1088, 1088, window=512) == 2 and sa.parts_a_step(9, 1088, 1088, window=512) == 1
+    assert sa.parts_a_step(2, 1088, 1088, DS) == sa.parts_a_step(2, 32, 32, DS, want=2) == 1  # latent attention's
 
 
 def _tiny_step(nope):
@@ -184,9 +303,58 @@ def test_a_step_of_one_head_a_grid_step_keeps_the_vector_it_had():
 
     cfg, s, stats = _tiny_step(16)
     assert stats.shape == (len(decoder.STEP_STATS),)
-    assert all(tiles == steps for tiles, steps in (
+    assert all(tiles == steps == parts for tiles, steps, parts in (
         decoder.causal_call_steps(cfg, i, 2, s) for i in range(cfg.num_layers)))
     metrics = PipelineMetrics()
     decoder.fold_step_stats(metrics, stats)
     assert not set(decoder.BLOCK_STATS) & set(metrics.counters)
     assert dataclasses.replace(cfg, causal_q_tile=32).layer_stats == cfg.layer_stats == 4
+
+
+def _stacked_step(monkeypatch, floor=None, run=True):
+    """Laguna's small trunk (two full layers of two heads a key head, two windowed of three, 64
+    tokens in 32 x 32 tiles, a batch of two), under the rule as it is or with its floor taken away:
+    its statistics vector, run or (the shape alone) traced."""
+    from test_decoder_laguna import embedded, inputs, mapping, small
+
+    if floor is not None:
+        monkeypatch.setattr(sa, "PART_SCORE_BYTES", floor)
+    jax.clear_caches()  # the rule is read while a call is traced
+    cfg = small(mapping())
+    params = decoder.init_params(cfg, jax.random.key(3), jnp.float32)
+    patches, ids = inputs(3, batch=2)
+    step = jax.jit(lambda p: decoder.trunk(p, embedded(p, patches, ids), np.arange(64), cfg, 2))
+    _, stats = step(params) if run else jax.eval_shape(step, params)
+    jax.clear_caches()
+    return cfg, stats
+
+
+def test_the_part_tiles_reach_the_pipeline_s_counters_and_a_step_that_cuts_nothing_keeps_its_vector(monkeypatch):
+    """At the test's size no part fills the rule's floor: the step's vector is
+    the ten values it was. With the floor taken away the full layers' two
+    heads a key head go in two parts: twenty values, ``BLOCK_STATS`` at what
+    the calls take, ``ROWS_STATS``' places 0, ``PART_STATS`` last."""
+    from psana_ray_tpu.utils.metrics import PipelineMetrics
+
+    cfg, stats = _stacked_step(monkeypatch, run=False)
+    assert stats.shape == (10,) and all(
+        tiles == steps == parts for tiles, steps, parts in (
+            decoder.causal_call_steps(cfg, i, 2, 64) for i in range(cfg.num_layers)))
+    _, stats = _stacked_step(monkeypatch, floor=1)
+    names = (decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS + decoder.LINEAR_STATS
+             + decoder.AHEAD_STATS + decoder.LOOP_STATS + decoder.BLOCK_STATS + decoder.ROWS_STATS
+             + decoder.PART_STATS)
+    assert stats.shape == (len(names),) == (20,)
+    metrics = PipelineMetrics()
+    for _ in range(3):
+        decoder.fold_step_stats(metrics, stats)
+    snap = metrics.snapshot()
+    # a key head's visits a layer: 3 pairs of 32 x 32 tiles, 14 of the window's 8 x 16 (`causal_tiles`)
+    full, band = (2 * 2 * len(sa._band_tiles(64, *sa.causal_tiles(64, rep, 32, 32, window), window))
+                  for rep, window in ((2, None), (3, 16)))
+    assert (full, band) == (2 * 2 * 3, 2 * 2 * 14)
+    assert snap["attn_head_tiles_total"] == snap["attn_grid_steps_total"] == 3 * 2 * (full + band)
+    # (two parts a step of a full layer; three heads under the window's four branches have no half)
+    assert snap["attn_part_tiles_total"] == 3 * 2 * (2 * full + band)
+    assert snap["trunk_rows_run_total"] == snap["trunk_rows_full_total"] == snap["loop_passes_total"] == 0
+    assert snap["decoder_tokens_total"] == 3 * 2 * 64

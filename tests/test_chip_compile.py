@@ -194,7 +194,7 @@ def _keye_attention():
 
     mask_k = sa.mask_tile(KEYE_S, 512)
     assert mask_k == 2176 and sa.causal_steps(
-        1, KEYE_S, 4, 8, 128, 128, block_q=256, mask_tiles=(128, mask_k)) == (4512, 4512)
+        1, KEYE_S, 4, 8, 128, 128, block_q=256, mask_tiles=(128, mask_k)) == (4512, 4512, 8 * 4512)  # eight parts a step (PR 75)
 
     def pin(text):
         entry = text[text.index("ENTRY"):]
@@ -408,9 +408,9 @@ def _latent_block(b, heads, takes, masked=False):
 
     def one_call_of_blocks(text):
         assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) == 1
-        tiles, steps = sa.causal_steps(b, s, heads, 1, 128, 128, 64, block_q=1088, block_k=1088,
-                                       mask_tiles=(128, mask_k) if masked else None)
-        assert tiles == takes * steps and steps == b * (heads // takes) * (44 if masked else 36)
+        tiles, steps, parts = sa.causal_steps(b, s, heads, 1, 128, 128, 64, block_q=1088, block_k=1088,
+                                              mask_tiles=(128, mask_k) if masked else None)
+        assert tiles == takes * steps and parts == steps == b * (heads // takes) * (44 if masked else 36)
 
     table = S((b * s, 64), F32)
     mask = [S((s // 128, s // mask_k, 128, mask_k), jnp.int8)] if masked else []
@@ -731,6 +731,15 @@ PINNED_STEPS = {
     # did not move: heads that share their keys, heads of 64, keye's `_attn_kernel` (gone since PR 68) and a kernel that
     # turns q and k itself take one group a step as they did, and at one head a step the kernel's
     # body is the jaxpr it was (`tests/test_decoder_kimi.py -k traces_the_kernel`))
+    # (the five steps whose causal calls hold a STACKED group — keye's, lfm2's, laguna's, granite's and
+    # nemotron3's — re-pinned in PR 75, knowingly: a grid step cuts the group's rows into parts
+    # (`sparse_attention.parts_a_step`: eight under keye's mask, four at lfm2's and granite's heads of
+    # 64 and in nemotron3's sixteen a group, three in laguna's full calls), part p + 1's
+    # score product written before part p's softmax; the kernel's body rides in the blanked
+    # `backend_config`, so what moved in the step's own text is the statistics vector, which ends in
+    # `PART_STATS` (twenty values: `attn_part_tiles_total` last, `BLOCK_STATS` at what the calls take,
+    # ONE constant of the shapes). dsv32's, kimi's, ling3's, the looped reader's and olmo_hybrid's were
+    # hashed before and after and did NOT move: `rep == 1` in every causal call of theirs)
     "deepseek_v32_prefill_epix10k2m": "4459560881f4932af4843903dfaa127c21ecbadaa451ce1890d074e6fb249eac",
     "kimi_k2_prefill_epix10k2m": "aabd919baf99e48f437abf546ab498c3b2c100d1f38b24cf58eff093ba9c462a",
     # (keye's ALONE re-pinned in PR 68, knowingly: its four selection-attention calls leave
@@ -740,8 +749,8 @@ PINNED_STEPS = {
     # q as ONE token-major block a group with a fourth scratch; the nine others were hashed before
     # and after and did not move: at 8,704 tokens every rule gives what it gave, and the maskless
     # cells take none of the changed branches)
-    "keye_vl2_prefill_epix10k2m": "e6f91a07d1ffad75878b5207c0512c178cb21d4d90873f532920c7bad818bd10",
-    "lfm2_8b_a1b_prefill_epix10k2m": "2b73e2e07e5723518527f753ddf201bc63b7e4f51da5e8f5fcd63c6617add56e",
+    "keye_vl2_prefill_epix10k2m": "874b8b6514802f5e70b9062a37c41ac16b82f4c8b72feb759a57b56574f6084a",
+    "lfm2_8b_a1b_prefill_epix10k2m": "b2f6c0b7cc4c217d608bdd9f3f4d7640728b046b9cc6bb7cd07e77c9307728c3",
     # pinned in PR 56, both hashed on PR 55's tree first and NEITHER moved by it: laguna's runs
     # nothing of `ops/delta_rule.py`; ling3's does, and PR 56 rewrote that kernel's body (the heads
     # of a grid step side by side), but a Mosaic kernel's body rides in its call's `backend_config`,
@@ -785,11 +794,11 @@ PINNED_STEPS = {
     # a fourth scratch, q as ONE token-major block `[B, 1, S, H*128]`; the six others were hashed
     # before and after and did not move: the rule is a branch taken in Python, `angles is None`,
     # heads of 64 and a selection on its other side, and the latent cells' path is not touched)
-    "laguna_s21_prefill_epix10k2m": "88b0dac72b0024cb76aceaaea91953ed6e69d4d91604e394b2524a50277a99c1",
+    "laguna_s21_prefill_epix10k2m": "bf2e9f3b3e8d3f54776ab762bae36e60139cc66bbf43473adae59659828f158b",
     # pinned in PR 57, which brought it: the six above were hashed on PR 56's tree first and none
     # moved, though every one of them now traces `_projections`, `embed`, `logits_of` and `trunk`
     # through the new fields' branches (taken in Python, before anything is traced)
-    "granite4_h_micro_prefill_epix10k2m": "c4e620ad545884561b27a758dbb6a5655e7185cb6fd50a6f8553a5adf012b04e",
+    "granite4_h_micro_prefill_epix10k2m": "c505aaa9ec91d92190243b4a5e593f2cd6dc452cfe299023e9d682c9bab1415c",
     # pinned in PR 60, which brought it: the seven above were hashed on PR 58's tree first and none
     # moved (the looped trunk, the sandwich and the gate are branches taken in Python, before
     # anything is traced; at one pass `trunk` is the code it was, to the letter)
@@ -803,7 +812,7 @@ PINNED_STEPS = {
     # `grouped_tiles`' and `rows_as_words`' rules for a width of 14.5 or 10.5 lane tiles
     # (re-pinned in PR 70 with ling3's, above: six `rows_as_words` and six `row_gather` calls where
     # six `gather`s of `x [34816, 2688]` stood)
-    "nemotron3_nano_prefill_epix10k2m": "7c37b616f962576e35c0e82d1683fef3b1edbb559ce1c9fbadca072e73e86ef5",
+    "nemotron3_nano_prefill_epix10k2m": "f3a91345e7df789180c0ba3a100203f7f3f440ee80c99a5152047949ed26bd37",
     # pinned in PR 67, which brought it: the nine above were hashed on PR 66's tree first and none
     # moved, though every one of them now goes through `_projections`' rule for the q / k norm (none,
     # a head, the whole projection) and for a block without a norm before its branch, `init_params`'
@@ -1034,7 +1043,7 @@ def test_the_olmo_hybrid_step_compiles_whole_with_its_kernels_where_the_roofline
     assert sa.heads_a_step(30, 1, 1088, 1088, 128, 128) == 2
     from psana_ray_tpu.models import decoder
 
-    assert decoder.causal_call_steps(dcfg, 3, 1, 8704) == (30 * 36, 15 * 36)  # what the step counts
+    assert decoder.causal_call_steps(dcfg, 3, 1, 8704) == (30 * 36, 15 * 36, 15 * 36)  # what the step counts
     entry = text[text.index("ENTRY"):]
     moved = re.findall(r"= bf16\[8704,(?:2880|3840|5760)\][^ ]* (?:copy|transpose|slice|pad|concatenate)\(.*",
                        entry)
@@ -1131,7 +1140,7 @@ def test_the_minicpm_sala_step_compiles_whole_with_its_kernels_under_the_scopes_
     sel = dcfg.block_select
     assert sel.tiles(34304) == (2048, 32, 640)
     assert sa._masked_query_tile(34304, dcfg.attn_q_tile, 128, 16 * 2048) == 128
-    assert decoder.causal_call_steps(dcfg, 1, 1, 34304) == (0, 0)  # a linear layer makes no causal call
+    assert decoder.causal_call_steps(dcfg, 1, 1, 34304) == (0, 0, 0)  # a linear layer makes no causal call
 
 
 def test_the_lightning_kernel_the_chip_compiles_carries_its_state_float32(one_chip, monkeypatch):
@@ -1567,6 +1576,58 @@ def test_laguna_s_grouped_heads_reach_the_kernel_where_their_products_wrote_them
     assert f"f32[{tokens},{heads},{dcfg.head_dim // 2}]" not in entry and "multiply_subtract_fusion" not in entry
     assert sum("jit(turn_tables)" in line and f"f32[{tokens},{dcfg.head_dim}]" in line.split(" fusion(")[0]
                for _, line in made.values()) == 2  # [cos | cos | 1], [-sin | sin | 0]
+
+
+@pytest.mark.parametrize("kind,heads,window,parts,products", [
+    ("full", 48, None, 3, 2 * 3 * 2), ("windowed", 72, 512, 1, 4 * 1 * 2)])
+def test_laguna_s_stacked_calls_compile_with_their_rows_in_parts(kind, heads, window, parts, products,
+                                                                   one_chip, monkeypatch):
+    """Laguna's two calls ALONE at the published sizes (2 x 8,704 tokens, 8
+    key heads of 128, q and k float32 for the kernel to turn, the gate a
+    head), as the step makes them since PR 75: a grid step's stacked group
+    cut into ``parts`` runs of whole heads (``parts_a_step``: three parts of
+    TWO heads at the full layers' 512 x 1,088; the windowed ones' nine heads
+    at 256 x 512 stay ONE product, their four branches leave room for two
+    parts and nine has no half), the body's products two a part and branch
+    (two branches, four under the window), the scratch what it was (``m``,
+    ``l``, ``acc`` and the turned query tile, stacked: a part is a slice of
+    each), and Mosaic takes the written order within ``_VMEM_LIMIT``. Compile
+    seconds for the described v5e here, the parent's one stacked product ->
+    three parts, lowering included (PR 75): full 8.8 -> 6.3; on the chip,
+    first call, 6.5 -> 7.0 (the windowed call at three parts 4.2 -> 5.8)."""
+    from psana_ray_tpu.parallel import sparse_attention as sa
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    b, s, g, d = 2, 8704, 8, 128
+    bq, bk = sa.causal_tiles(s, heads // g, 1088, 1088, window, d)
+    assert (bq, bk) == ((256, 512) if window else (512, 1088))
+    assert sa.parts_a_step(heads // g, bq, bk, window=window) == parts
+
+    def fn(q, k, v, cos, sin, gate):
+        attend = sa.windowed_gqa_attention if window else sa.masked_gqa_attention
+        return attend(q, k, v, num_kv_heads=g, block_q=1088, block_k=1088, interpret=False,
+                      out_gate=gate, turn=(cos, sin), turn_width=d if window else d // 2,
+                      q_scale=d ** -0.5, **({"window": window} if window else {}))
+
+    table = S((b * s, d), F32)
+    operands = (S((b, s, heads * d), F32), S((b, s, g * d), F32), S((b, s, g * d), BF16), table, table,
+                S((b, s, heads), F32))
+    (call,) = _pallas_calls(jax.make_jaxpr(fn)(*operands).jaxpr)
+
+    def count(jaxpr, name):  # through the branches' conds
+        return sum((eqn.primitive.name == name) + sum(
+            count(getattr(inner, "jaxpr", inner), name) for value in eqn.params.values()
+            for inner in (value if isinstance(value, (tuple, list)) else (value,))
+            if hasattr(getattr(inner, "jaxpr", inner), "eqns")) for eqn in jaxpr.eqns)
+
+    assert count(call.params["jaxpr"], "dot_general") == products
+    rows = heads // g * bq
+    assert [a.shape for a in call.params["grid_mapping"].scratch_avals] == [
+        (rows, 1), (rows, 1), (rows, d), (rows, d)]
+    args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip), operands)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert ("windowed_gqa_attention" if window else "masked_gqa_attention") in text
 
 
 def test_keye_s_selection_attention_operands_reach_the_kernel_with_two_pads_and_no_new_copy(

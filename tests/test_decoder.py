@@ -201,8 +201,9 @@ def test_keye_s_call_takes_the_pair_table_s_grid_steps():
     assert tiles == (128, 2176)
     pairs = len(sa._band_tiles(s, 256, 2176))
     assert pairs == 1128 == sum(-(-(i + 1) * 256 // 2176) for i in range(s // 256))
-    assert sa.causal_steps(1, s, 4, 8, 128, 128, block_q=256, mask_tiles=tiles) == (4 * pairs,) * 2
-    assert sa.causal_steps(1, s, 4, 8, 128, 128, block_q=512, mask_tiles=tiles) == (4 * pairs,) * 2
+    # (and since PR 75 a grid step's eight stacked heads are eight parts: `parts_a_step`)
+    assert sa.causal_steps(1, s, 4, 8, 128, 128, block_q=256, mask_tiles=tiles) == (4 * pairs, 4 * pairs, 32 * pairs)
+    assert sa.causal_steps(1, s, 4, 8, 128, 128, block_q=512, mask_tiles=tiles) == (4 * pairs, 4 * pairs, 32 * pairs)
     assert sa._masked_query_tile(s, 512, 128, 8 * 2176) == 256  # what fits, not what was asked
     assert sa._masked_query_tile(s, 512, 128, 8 * 1088) == 512
     assert sa._masked_query_tile(8704, 1088, 128, 2176) == 512  # dsv32's, a head alone: as it was

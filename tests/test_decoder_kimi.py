@@ -307,8 +307,11 @@ def test_a_call_without_the_tables_traces_the_kernel_it_traced():
 
     S = jax.ShapeDtypeStruct
     wide, narrow = S((4, 8704, 32 * 64), jnp.bfloat16), S((4, 8704, 8 * 64), jnp.bfloat16)
-    lfm2 = jax.make_jaxpr(lambda q, k, v: sa.masked_gqa_attention(
-        q, k, v, num_kv_heads=8, block_q=1088, block_k=1088, interpret=False))(wide, narrow, narrow)
+    # (as ONE stacked product, the form hashed then: since PR 75 the rule cuts this shape's four stacked
+    # heads into four parts, a body four times as long, and `cut=1` asks for the old one)
+    assert sa.causal_tiles(8704, 4, 1088, 1088) == (1088, 1088)
+    lfm2 = jax.make_jaxpr(lambda q, k, v: sa._causal_attention(
+        q, k, v, 8, 1088, 1088, False, cut=1))(wide, narrow, narrow)
     heads = S((2, 8704, 64 * 128), jnp.bfloat16)
     # (at ONE head a grid step, the form hashed then: since PR 66 the rule gives heads alone in their
     # groups a block of two at this shape, a body twice as long, and `heads=1` asks for the old one)

@@ -517,8 +517,8 @@ def test_the_rule_gives_the_full_layers_a_block_of_two_heads_at_the_published_si
     cfg = decoder.DecoderConfig.from_mapping(_file())
     assert sa.causal_tiles(8704, 1, cfg.causal_q_tile, cfg.causal_kv_tile) == (1088, 1088)
     assert sa.heads_a_step(30, 1, 1088, 1088, 128, 128) == 2
-    tiles, steps = decoder.causal_call_steps(cfg, 3, 1, 8704)
-    assert (tiles, steps) == (30 * 36, 15 * 36) and decoder.causal_call_steps(cfg, 0, 1, 8704) == (0, 0)
+    tiles, steps, parts = decoder.causal_call_steps(cfg, 3, 1, 8704)
+    assert (tiles, steps, parts) == (30 * 36, 15 * 36, 15 * 36) and decoder.causal_call_steps(cfg, 0, 1, 8704) == (0, 0, 0)
 
 
 # ---------------------------------------------------------------------------

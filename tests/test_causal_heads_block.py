@@ -6,13 +6,13 @@ TO THE BIT (interpret mode, small shapes); the rule that picks ``hb`` is a
 table of the served shapes; its two counters reach the pipeline's."""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from decoder_kit import Kit
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import sparse_attention as sa
 
@@ -57,9 +57,12 @@ CASES = {
 }
 
 
-@functools.lru_cache(maxsize=None)
+KIT = Kit()  # no model here: its cache alone, keyed by a case's name
+
+
 def _served(name, heads):
-    return np.asarray(_attend(CASES[name], heads).astype(jnp.float32))
+    return KIT.made("served", (name, heads), lambda: np.asarray(
+        _attend(CASES[name], heads).astype(jnp.float32)), under=None)
 
 
 def _attend(case, heads, s=64, bq=32, bk=32):
@@ -210,8 +213,11 @@ STACKED = {
 }
 
 
-@functools.lru_cache(maxsize=None)
 def _stacked(name, cut):
+    return KIT.made("stacked", (name, cut), lambda: _stacked_call(name, cut), under=None)
+
+
+def _stacked_call(name, cut):
     case = {"b": 2, "d": D, "bq": 32, "window": None, **STACKED[name]}
     b, g, rep, d, bq, bk = case["b"], case["g"], case["rep"], case["d"], case["bq"], 32
     dv = case.get("dv", d)

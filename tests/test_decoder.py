@@ -3,7 +3,6 @@ and dropless experts, against the benchmark's plain reference
 (``benchmark/reference/keye_decoder.py``) at small sizes on the CPU, and
 the benchmark's reader for the counters it feeds."""
 
-import dataclasses
 import functools
 import json
 import os
@@ -15,7 +14,9 @@ import numpy as np
 import pytest
 
 from benchmark import harness
+import decoder_kit
 from benchmark.reference import keye_decoder as ref
+from decoder_kit import PROMPT, Kit, streamed
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import row_gather
 from psana_ray_tpu.parallel import moe
@@ -24,7 +25,7 @@ from psana_ray_tpu.parallel.moe import dropless_moe
 from test_manifest_entries import need
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PANELS, ROWS, COLS, PROMPT = 2, 2, 14, 8  # 56 patches + 8 prompt ids = 64 tokens
+PANELS, ROWS, COLS = 2, 2, 14  # 56 patches + 8 prompt ids = 64 tokens
 
 
 def mapping(**over):
@@ -42,15 +43,12 @@ def mapping(**over):
     return m
 
 
-def small(m):
-    """The configuration of ``m`` with tiles that cut 64 tokens into several."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), q_tile=16, attn_q_tile=32)
+small = Kit(mapping, tiles=dict(q_tile=16, attn_q_tile=32)).small  # tiles that cut 64 tokens into several
 
 
-def inputs(seed):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((PANELS * ROWS * COLS, 64)), jnp.float32)
-    ids = jnp.asarray(rng.integers(0, 256, PROMPT))
+def frame_of(seed):
+    """The seed's one frame of patches, its prompt ids and the tokens' places."""
+    (patches,), ids = decoder_kit.inputs(seed, patches=PANELS * ROWS * COLS)
     return patches, ids, decoder.frame_positions(PANELS, ROWS, COLS, PROMPT)
 
 
@@ -72,7 +70,7 @@ def test_trunk_matches_reference_at_all_positions(variant):
         m.update(num_experts=0, num_experts_per_tok=0)
     cfg = small(m)
     params = decoder.init_params(cfg, jax.random.key(3), jnp.float32)
-    patches, ids, pos = inputs(3)
+    patches, ids, pos = frame_of(3)
     with jax.default_matmul_precision("highest"):
         got, stats = jax.jit(lambda p: all_logits(cfg, p, patches, ids, pos))(params)
         want = ref.forward(params, patches, ids, pos, m, block=16)
@@ -504,13 +502,13 @@ def test_bf16_passes_the_rows_verdict_and_float8_fails_it_at_every_seed():
     m = mapping()
     cfg = small(m)
     init = jax.jit(lambda key: decoder.init_params(cfg, key, jnp.bfloat16))
-    _, ids, pos = inputs(0)
+    _, ids, pos = frame_of(0)
     served = jax.jit(lambda p, f: decoder.trunk(p, decoder.embed(p, f, ids), pos, cfg)[0])
     plain = {c: jax.jit(lambda p, f, c=c: _reference_rows(p, f, ids, pos, m, c))
              for c in (jnp.float32, jnp.bfloat16, jnp.float8_e4m3fn)}
     for seed in range(8):
         params = init(harness.make_key(seed))
-        frame = inputs(seed)[0]
+        frame = frame_of(seed)[0]
         with jax.default_matmul_precision("highest"):
             want, stated, below = (np.asarray(plain[c](params, frame)) for c in plain)
         got = np.asarray(served(params, frame.astype(jnp.bfloat16)), np.float32)
@@ -606,7 +604,7 @@ def _verdicts(m, seeds=range(8)):
     reference with float8-rounded operands."""
     cfg = small(m)
     init = jax.jit(lambda key: decoder.init_params(cfg, key, jnp.bfloat16))
-    _, ids, pos = inputs(0)
+    _, ids, pos = frame_of(0)
 
     def served(p, f):
         x, _ = decoder.trunk(p, decoder.embed(p, f, ids), pos, cfg)
@@ -618,7 +616,7 @@ def _verdicts(m, seeds=range(8)):
     out = []
     for seed in seeds:
         params = init(harness.make_key(seed))
-        frame = inputs(seed)[0]
+        frame = frame_of(seed)[0]
         with jax.default_matmul_precision("highest"):
             want, stated, below = (np.asarray(plain[c](params, frame)) for c in plain)
         got = np.asarray(served(params, frame.astype(jnp.bfloat16)))
@@ -672,34 +670,8 @@ def test_the_reference_places_the_tokens_where_the_package_does(panels, rows, co
 # ---------------------------------------------------------------------------
 
 def test_counters_of_a_two_frame_stream_through_infeed_pipeline():
-    from psana_ray_tpu.infeed import InfeedPipeline
-    from psana_ray_tpu.records import EndOfStream, FrameRecord
-    from psana_ray_tpu.transport import RingBuffer
-
-    m = mapping()
-    cfg = small(m)
-    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
-    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
-                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
-    calib = harness.make_calibration(detector, 1)
-    ids = jnp.arange(PROMPT, dtype=jnp.int32)
-    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
-    rng = np.random.default_rng(2)
-    q = RingBuffer(maxsize=4)
-    for i in range(2):
-        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
-    q.put(EndOfStream(total_events=2))
-    pipe = InfeedPipeline(q, batch_size=1, poll_interval_s=0.001)
-    logits = []
-
-    def on_result(out, batch):
-        logits.append(np.asarray(out[0]))
-        assert len(out) == 2  # logits and statistics: nothing for a check rides on a served frame
-        decoder.fold_step_stats(pipe.metrics, out[1])
-
-    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 2
-    assert all(x.shape == (1, 256) and np.isfinite(x).all() for x in logits)
-    snap = pipe.metrics.snapshot()
+    outs, snap, _ = streamed(small(mapping()), frames=2, batch=1)
+    assert all(len(out) == 2 for out in outs)  # logits and statistics: nothing for a check rides on a served frame
     frames, layers, tokens = 2, 2, 64
     assert snap["expert_tokens_mean_total"] == frames * layers * tokens * 2 / 8
     assert snap["attn_tiles_causal_total"] == frames * layers * 1

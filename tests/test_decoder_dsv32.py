@@ -8,16 +8,16 @@ layer; the new cell's counters and counts."""
 import dataclasses
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_kit
 from benchmark.reference import deepseek_v32_decoder as ref
 from benchmark.reference.keye_decoder import select as ref_select
+from decoder_kit import PROMPT, Kit, inputs, rehearse, share_of, streamed
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
@@ -25,7 +25,6 @@ from test_manifest_entries import BENCH, need, ratio_of
 from xla_turn import TURNS, assert_the_kernel_s_turn_is_xla_s, turned_by_xla
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIG = os.path.join(REPO, "benchmark", "configs", "deepseek_v32_prefill_epix10k2m.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CELL = "dsv32_epix_saturated"
@@ -55,36 +54,18 @@ def mapping(**over):
     return m
 
 
-def small(m):
-    """Tiles that cut 64 tokens into several: masks of 16 x 32, attention in 32 x 32."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=32,
-                               causal_kv_tile=32, q_tile=16, kv_tile=32)
-
-
-def loud(params, by=5.0):
+def loud(params):
     """The same tree with its matrices scaled up (``tests/test_decoder_kimi.py``
     says why), the index key's LayerNorm given a gain and a bias that count."""
-    params = jax.tree.map(lambda a: a * by if a.ndim >= 2 else a, params)
+    params = decoder_kit.loud(params)
     params["layers"] = [{**p, "idx_k_norm": p["idx_k_norm"] * 1.5, "idx_k_bias": p["idx_k_bias"] * 20}
                         for p in params["layers"]]
     return params
 
 
-def inputs(seed):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((1, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
-def embedded(params, patches, ids):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
-
-
-def share_of(params, first, count):
-    held = ("w_gate", "w_up", "w_down")
-    return {**params, "layers": [
-        {k: (v[first:first + count] if k in held and v.ndim == 3 else v) for k, v in p.items()}
-        for p in params["layers"]]}
+# tiles that cut 64 tokens into several: masks of 16 x 32, attention in 32 x 32
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=32, causal_kv_tile=32, q_tile=16, kv_tile=32), loud=loud)
+small = KIT.small
 
 
 def selected_pairs(s, topk):
@@ -106,11 +87,8 @@ def test_trunk_with_a_selection_over_latent_attention_matches_reference_at_all_p
     patches, ids = inputs(3)
     sizes = ref.sizes(m)
     with jax.default_matmul_precision("highest"):
-        x, stats = jax.jit(lambda p: decoder.trunk(
-            p, embedded(p, patches, ids), np.arange(64), cfg))(params)
-        got = decoder.logits_of(decoder.head_params(params), x, cfg)
-        want_x = ref.hidden(params, patches[0], ids, sizes, block=16)
-        want = ref.logits_of(params, want_x, sizes)
+        x, got, stats = KIT.trunk_of(params, patches, ids, cfg)
+        want_x, want = KIT.reference_of(params, patches, ids, sizes)
     for a, b in ((x, want_x), (got, want)):
         scale = float(jnp.sqrt(jnp.mean(b ** 2)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4 * scale, rtol=0)
@@ -130,14 +108,8 @@ def test_trunk_with_a_selection_over_latent_attention_matches_reference_at_all_p
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault):
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
-    patches, ids = inputs(5)
-    with jax.default_matmul_precision("highest"):
-        x, _ = decoder.trunk(params, embedded(params, patches, ids), np.arange(64), cfg)
-        want = ref.hidden(params, patches[0], ids, ref.sizes(m, **FAULTS[fault]), block=16)
-        same = ref.hidden(params, patches[0], ids, ref.sizes(m), block=16)
+    x, same = KIT.trunk(5, jit=False)[0], KIT.reference(5)[0]  # made once for the nine cases
+    want = KIT.reference(5, **FAULTS[fault])[0]
     scale = float(jnp.sqrt(jnp.mean(want ** 2)))
     assert float(jnp.abs(x - same).max()) < 1e-3 * scale
     assert float(jnp.abs(x - want).max()) > 1e-2 * scale  # what a control puts in is seen
@@ -598,34 +570,8 @@ def test_the_file_holds_the_catalog_s_numbers_unchanged_and_names_its_cuts():
 # ---------------------------------------------------------------------------
 
 def test_pair_counters_reach_the_snapshot_and_the_exposition():
-    from benchmark import harness
-    from psana_ray_tpu.infeed import InfeedPipeline
-    from psana_ray_tpu.obs.registry import MetricsRegistry
-    from psana_ray_tpu.records import EndOfStream, FrameRecord
-    from psana_ray_tpu.transport import RingBuffer
-
     cfg = small(mapping(n_routed_experts=4, router_experts=16, experts_held=[0, 4]))
-    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
-    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
-                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
-    calib = harness.make_calibration(detector, 1)
-    ids = jnp.arange(PROMPT, dtype=jnp.int32)
-    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
-    rng = np.random.default_rng(2)
-    q = RingBuffer(maxsize=8)
-    for i in range(3):
-        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
-    q.put(EndOfStream(total_events=3))
-    pipe = InfeedPipeline(q, batch_size=1, poll_interval_s=0.001)
-    logits = []
-
-    def on_result(out, batch):
-        logits.append(np.asarray(out[0]))
-        decoder.fold_step_stats(pipe.metrics, out[1])
-
-    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 3
-    assert all(x.shape == (1, 256) and np.isfinite(x).all() for x in logits)
-    snap = pipe.metrics.snapshot()
+    _, snap, text = streamed(cfg, frames=3, batch=1)
     steps, s = 3, 2 * 2 * 14 + PROMPT
     assert snap["decoder_tokens_total"] == steps * s
     assert snap["attn_pairs_selected_total"] == steps * 3 * selected_pairs(s, 16)
@@ -633,9 +579,6 @@ def test_pair_counters_reach_the_snapshot_and_the_exposition():
     assert snap["attn_tiles_causal_total"] == steps * 3
     assert 0 < snap["attn_tiles_live_total"] <= snap["attn_tiles_causal_total"]
     assert 0 < snap["expert_rows_held_total"] < snap["expert_rows_routed_total"] == steps * 2 * s * 4
-    text = MetricsRegistry()
-    text.register("reader", pipe.metrics)
-    text = text.render_prometheus()
     for name in decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS:
         assert f'psana_ray_{name}{{source="reader"}}' in text, name
 
@@ -704,14 +647,7 @@ def test_the_adapter_ends_the_run_where_the_package_lacks_the_mechanism(monkeypa
 
 
 def test_the_cell_s_rehearsal_runs_the_served_path_and_is_correct():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
-         "--seed", "1", "--seconds", "2", "--trace", "1"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line, done = rehearse(CELL, seed=1, seconds=2, xla_flags=False, timeout=600)
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["metrics"] == {}
     assert line["cell"] == CELL and line["attempted"] > 0
     for counted in (("attn_pairs_selected_total", "attn_pairs_causal_total"),

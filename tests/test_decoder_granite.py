@@ -6,23 +6,23 @@ token by token) at small sizes on the CPU; the new cell's configuration
 file, counters and counts."""
 
 import dataclasses
+import functools
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_kit
 from benchmark.reference import granite_decoder as ref
+from decoder_kit import F32_PRODUCTS, Kit, checked, embedded, inputs, rehearse
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import ssd
 from test_manifest_entries import BENCH, need
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIGS = os.path.join(REPO, "benchmark", "configs")
 CONFIG = os.path.join(CONFIGS, "granite4_h_micro_prefill_epix10k2m.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -58,71 +58,19 @@ def mapping(**over):
     return m
 
 
-def small(m):
-    """Tiles that cut 64 tokens into several: attention in 32 x 32."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=32,
-                               causal_kv_tile=32)
+# loud: the taps, the bias and the scan's own parameters are of order 1 as drawn
+loud = functools.partial(decoder_kit.loud, keep=("conv_w",))
+# the scan's chunks at most 16 rows: the trunk's 64 tokens cross three chunk edges (`scan_rows` follows `ROWS`)
+PATCHES_OF = {"float32_products": lambda: decoder_kit.float32_products(ssd, ssd.ssd_scan),
+              "chunks_of_16": lambda: decoder_kit.chunks_of_16(ssd, ssd.ssd_scan)}
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=32, causal_kv_tile=32), loud=loud, patches=PATCHES_OF)
+small, trunk_of, reference_of = KIT.small, KIT.trunk_of, KIT.reference_of
 
 
 @pytest.fixture
-def chunks_of_16(monkeypatch):
-    """The scan's chunks at most 16 rows, so that the trunk's 64 tokens cross
-    three chunk edges (``scan_rows`` follows ``ROWS``; the scan's traces are
-    dropped on both sides: a trace holds the rows it was made with)."""
-    monkeypatch.setattr(ssd, "ROWS", 16)
-    ssd.ssd_scan.clear_cache()
-    yield
-    ssd.ssd_scan.clear_cache()
-
-
-def loud(params, by=5.0):
-    """The same tree with its 0.02-matrices scaled up, so that every part of a
-    layer moves its output by more than a rounding (the taps, the bias and the
-    scan's own parameters are of order 1 as drawn)."""
-    def up(path, a):
-        name = path[-1].key if hasattr(path[-1], "key") else ""
-        return a * by if a.ndim >= 2 and name != "conv_w" else a
-
-    return jax.tree_util.tree_map_with_path(up, params)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
-def embedded(params, patches, ids, cfg):
-    return jnp.concatenate([decoder.embed(params, frame, ids, cfg.embedding_multiplier, cfg.stream_dtype)
-                            for frame in patches])
-
-
-def trunk_of(params, patches, ids, cfg, pos=None):
-    """The program's trunk and logits at every position of the batch."""
-    batch = patches.shape[0]
-    x, stats = jax.jit(lambda p: decoder.trunk(
-        p, embedded(p, patches, ids, cfg), np.arange(64) if pos is None else pos, cfg, batch))(params)
-    return x, decoder.logits_of(decoder.head_params(params), x, cfg), stats
-
-
-def reference_of(params, patches, ids, sizes):
-    x = jnp.concatenate([ref.hidden(params, frame, ids, sizes, block=16) for frame in patches])
-    return x, ref.logits_of(params, x, sizes)
-
-
-@pytest.fixture
-def float32_products(monkeypatch):
-    """The kernel's products in float32, so that what is left between it and
-    the recurrence is its FORM alone: the kernel's compiled programs hold the
-    products they were traced with, so its cache goes before and after."""
-    def mm(a, b, dims=((1,), (0,))):
-        return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32), (dims, ((), ())),
-                                   precision=jax.lax.Precision.HIGHEST)
-
-    ssd.ssd_scan.clear_cache()
-    monkeypatch.setattr(ssd, "_mm", mm)
-    yield
-    ssd.ssd_scan.clear_cache()
+def float32_products():
+    with PATCHES_OF["float32_products"]():
+        yield
 
 
 # ---------------------------------------------------------------------------
@@ -253,15 +201,10 @@ def test_conv_silu_with_a_bias_is_the_reference_s_and_starts_anew_with_every_seq
 # the trunk against the reference, float32, all positions, a batch of two
 # ---------------------------------------------------------------------------
 
-def test_the_state_space_trunk_matches_the_reference_at_all_positions_of_a_batch_of_two(
-        float32_products, chunks_of_16):
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(3), jnp.float32))
-    patches, ids = inputs(3, batch=2)
-    with jax.default_matmul_precision("highest"):
-        x, got, stats = trunk_of(params, patches, ids, cfg)
-        want_x, want = reference_of(params, patches, ids, ref.sizes(m))
+def test_the_state_space_trunk_matches_the_reference_at_all_positions_of_a_batch_of_two():
+    cfg = small(mapping())
+    x, got, stats = KIT.trunk(3, batch=2, under=(*F32_PRODUCTS, "chunks_of_16"))
+    want_x, want = KIT.reference(3, batch=2)
     assert x.dtype == jnp.float32  # the stream of a model with a residual multiplier
     for a, b in ((x, want_x), (got, want)):
         scale = float(jnp.sqrt(jnp.mean(b ** 2)))
@@ -278,19 +221,13 @@ def test_the_state_space_trunk_matches_the_reference_at_all_positions_of_a_batch
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault, float32_products):
+def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault):
     """Each of the controls' faults moves the reference's own output (hidden
     rows, or for the head's divisor the logits) by far more than the program
     lies from it: the gate-before-norm order, the skip, the step's bias, the
     convolution's bias, a state cut or narrowed, each multiplier, a rotary."""
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
-    patches, ids = inputs(5)
-    with jax.default_matmul_precision("highest"):
-        x, logits, _ = trunk_of(params, patches, ids, cfg)
-        want = reference_of(params, patches, ids, ref.sizes(m))
-        other = reference_of(params, patches, ids, ref.sizes(m, **FAULTS[fault]))
+    x, logits, _ = KIT.trunk(5, under=F32_PRODUCTS)  # made once for the eleven cases, as the clean reference
+    want, other = KIT.reference(5), KIT.reference(5, **FAULTS[fault])
     which = 1 if fault == "no_logits_scaling" else 0
     got = (x, logits)[which]
     scale = float(jnp.sqrt(jnp.mean(want[which] ** 2)))
@@ -333,7 +270,7 @@ def test_without_a_rotary_nothing_reads_a_token_s_position(float32_products):
     for bit, wherever the sequence is said to sit; and no table of angles is built."""
     cfg = small(mapping())
     assert not cfg.rotary and not cfg.qk_norm
-    params = loud(decoder.init_params(cfg, jax.random.key(9), jnp.float32))
+    params = KIT.params(9)
     assert "q_norm" not in params["layers"][2] and "wq" in params["layers"][2]
     patches, ids = inputs(9)
     x, _, _ = trunk_of(params, patches, ids, cfg)
@@ -365,7 +302,7 @@ def test_a_sequence_of_the_batch_does_not_read_its_neighbour_s_state_or_taps():
     """Sequence 1 of a batch of two, alone and after another neighbour: the
     same rows (the state AND the convolution stop at a sequence's edge)."""
     cfg = small(mapping())
-    params = loud(decoder.init_params(cfg, jax.random.key(11), jnp.float32))
+    params = KIT.params(11)
     patches, ids = inputs(11, batch=2)
     both, _, _ = trunk_of(params, patches, ids, cfg)
     alone, _, _ = trunk_of(params, patches[1:], ids, cfg)
@@ -564,20 +501,13 @@ def test_every_part_of_rows_decides_in_this_adapter_a_sequence_s_first_rows_too(
 
 
 def test_the_cell_s_rehearsal_runs_the_served_path_is_correct_and_reports_its_counters():
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
-         "--seed", "2", "--seconds", "3", "--trace", "1"],
-        capture_output=True, text=True, timeout=900, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line, done = rehearse(CELL, seed=2)
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["cell"] == CELL
     for name in ("ring_depth.hit", "device_wait_ms.hit", "startup_trace_s", "startup_lower_s",
                  "startup_cache_load_s", "startup_compile_s", "startup_cache_misses",
                  "startup_rest_s"):
         assert name in line["would_report"], name
     # the check ran both sequences of the rehearsal's batch, and a sequence moved is itself
-    said = next(ln for ln in done.stdout.splitlines() if ln.startswith("[bench] correct check"))
-    verdict = json.loads(said[said.index("{"):])
+    verdict = checked(done)
     assert verdict["isolated.0"]["ok"] and verdict["isolated.1"]["ok"]
     assert verdict["patch_rows.1"]["rows_over_share_limit"] == 0.1

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import kimi_k2_decoder as ref
+from decoder_kit import PROMPT, Kit, inputs, share_of, streamed
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
@@ -22,7 +23,6 @@ from test_manifest_entries import BENCH, asked, need
 from xla_turn import TURNS, assert_the_kernel_s_turn_is_xla_s, turned_by_xla
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIG = os.path.join(REPO, "benchmark", "configs", "kimi_k2_prefill_epix10k2m.json")
 CELL = "kimi_k2_epix_saturated"
 YARN = {"type": "yarn", "factor": 32, "original_max_position_embeddings": 16, "beta_fast": 1,
@@ -44,34 +44,15 @@ def mapping(**over):
     return m
 
 
-def small(m):
-    """Tiles that cut 64 tokens into several."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=16, causal_kv_tile=32)
+def _kv_norm_times_3(params):
+    return {**params, "layers": [{**p, "kv_a_norm": p["kv_a_norm"] * 3.0} for p in params["layers"]]}
 
 
-def loud(params, by=5.0):
-    """The same tree with its matrices scaled up: at a hidden size of 64,
-    normal(0, 0.02) makes every operator's output a hundredth of the
-    residual stream's, and a test would not see a fault in one."""
-    return jax.tree.map(lambda a: a * by if a.ndim >= 2 else a, params)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
-def embedded(params, patches, ids):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
-
-
-def share_of(params, first, count):
-    """The tree a holder of experts ``first .. first + count`` has."""
-    held = ("w_gate", "w_up", "w_down")
-    return {**params, "layers": [
-        {k: (v[first:first + count] if k in held and v.ndim == 3 else v) for k, v in p.items()}
-        for p in params["layers"]]}
+# loud weights: at a hidden size of 64, normal(0, 0.02) makes every operator's output a hundredth of
+# the residual stream's, and a test would not see a fault in one; tiles that cut 64 tokens into several
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=16, causal_kv_tile=32))
+LOUD_KV_NORM = Kit(mapping, ref, tiles=KIT.tiles, loud=lambda params: _kv_norm_times_3(KIT.loud(params)))
+small = KIT.small
 
 
 # ---------------------------------------------------------------------------
@@ -84,18 +65,15 @@ def test_trunk_with_latent_attention_matches_reference_at_all_positions(held):
     if held == "no_dense_layer":
         m.update(first_k_dense_replace=0)
     cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(3), jnp.float32))
+    params = KIT.loud(decoder.init_params(cfg, jax.random.key(3), jnp.float32))
     if held == "experts_4_to_7_of_16":  # a share: the file's key counts the experts held
         m.update(n_routed_experts=4, router_experts=16, experts_held=[4, 4])
         cfg, params = small(m), share_of(params, 4, 4)
     patches, ids = inputs(3)
     sizes = ref.sizes(m)
     with jax.default_matmul_precision("highest"):
-        x, stats = jax.jit(lambda p: decoder.trunk(
-            p, embedded(p, patches, ids), np.arange(64), cfg))(params)
-        got = decoder.logits_of(decoder.head_params(params), x, cfg)
-        want_x = ref.hidden(params, patches[0], ids, sizes, block=16)
-        want = ref.logits_of(params, want_x, sizes)
+        x, got, stats = KIT.trunk_of(params, patches, ids, cfg)
+        want_x, want = KIT.reference_of(params, patches, ids, sizes)
     assert got.shape == (64, 256) and "head" in params  # untied
     for a, b in ((x, want_x), (got, want)):
         scale = float(jnp.sqrt(jnp.mean(b ** 2)))
@@ -117,15 +95,9 @@ def test_trunk_with_latent_attention_matches_reference_at_all_positions(held):
 @pytest.mark.parametrize("fault", ["turn_key", "mscale", "yarn", "kv_norm", "shared", "select_bias",
                                    "softmax"])
 def test_the_reference_with_a_fault_in_it_is_another_trunk(fault):
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
-    params["layers"] = [{**p, "kv_a_norm": p["kv_a_norm"] * 3.0} for p in params["layers"]]
-    patches, ids = inputs(5)
     faults = {"softmax": {"scoring": "softmax"}}.get(fault, {fault: False})
-    with jax.default_matmul_precision("highest"):
-        x, _ = decoder.trunk(params, embedded(params, patches, ids), np.arange(64), cfg)
-        want = ref.hidden(params, patches[0], ids, ref.sizes(m, **faults), block=16)
+    x = LOUD_KV_NORM.trunk(5, jit=False)[0]  # made once for the seven cases
+    want = LOUD_KV_NORM.reference(5, **faults)[0]
     scale = float(jnp.sqrt(jnp.mean(want ** 2)))
     assert float(jnp.abs(x - want).max()) > 1e-2 * scale  # what a control puts in is seen
 
@@ -142,7 +114,7 @@ def test_the_reference_with_a_fault_in_it_is_another_trunk(fault):
 def test_latent_attention_layer_is_the_reference_operator_for_a_batch_of_two(widths):
     m = mapping(**widths)
     cfg = small(m)
-    p = loud(decoder.init_params(cfg, jax.random.key(7), jnp.float32))["layers"][0]
+    p = KIT.params(7, over=widths)["layers"][0]
     rng = np.random.default_rng(7)
     x = jnp.asarray(rng.standard_normal((2 * 64, 64)), jnp.float32)
     angles = jnp.tile(decoder.rotary_angles(np.arange(64), cfg.rope_theta, 4, None, cfg.rope_yarn),
@@ -535,42 +507,13 @@ def test_catalog_numbers_are_in_the_file_unchanged():
 # ---------------------------------------------------------------------------
 
 def test_counters_of_a_share_reach_the_snapshot_and_the_exposition():
-    from benchmark import harness
-    from psana_ray_tpu.infeed import InfeedPipeline
-    from psana_ray_tpu.obs.registry import MetricsRegistry
-    from psana_ray_tpu.records import EndOfStream, FrameRecord
-    from psana_ray_tpu.transport import RingBuffer
-
     cfg = small(mapping(n_routed_experts=4, router_experts=16, experts_held=[0, 4]))
-    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
-    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
-                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
-    calib = harness.make_calibration(detector, 1)
-    ids = jnp.arange(PROMPT, dtype=jnp.int32)
-    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
-    rng = np.random.default_rng(2)
-    q = RingBuffer(maxsize=8)
-    for i in range(4):
-        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
-    q.put(EndOfStream(total_events=4))
-    pipe = InfeedPipeline(q, batch_size=2, poll_interval_s=0.001)
-    logits = []
-
-    def on_result(out, batch):
-        logits.append(np.asarray(out[0]))
-        decoder.fold_step_stats(pipe.metrics, out[1])
-
-    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 4
-    assert all(x.shape == (2, 256) and np.isfinite(x).all() for x in logits)
-    snap = pipe.metrics.snapshot()
+    _, snap, text = streamed(cfg)
     steps, tokens = 2, 2 * (2 * 2 * 14 + PROMPT)
     assert snap["decoder_tokens_total"] == steps * tokens
     assert snap["expert_rows_routed_total"] == steps * 2 * tokens * 4  # two expert layers, 4 a token
     assert 0 < snap["expert_rows_held_total"] < snap["expert_rows_routed_total"]
     assert snap["expert_tokens_mean_total"] == steps * 2 * tokens * 4 / 16
-    text = MetricsRegistry()
-    text.register("reader", pipe.metrics)
-    text = text.render_prometheus()
     for name in decoder.STEP_STATS + decoder.SHARE_STATS:
         assert f'psana_ray_{name}{{source="reader"}}' in text, name
 

@@ -12,8 +12,6 @@ import dataclasses
 import functools
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +19,7 @@ import numpy as np
 import pytest
 
 from benchmark.reference import laguna_decoder as ref
+from decoder_kit import Kit, embedded, inputs, loud, rehearse, share_of
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import row_gather
 from psana_ray_tpu.parallel import moe
@@ -28,7 +27,6 @@ from psana_ray_tpu.parallel import sparse_attention as sa
 from test_manifest_entries import BENCH, need, ratio_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIGS = os.path.join(REPO, "benchmark", "configs")
 CONFIG = os.path.join(CONFIGS, "laguna_s21_prefill_epix10k2m.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -70,33 +68,8 @@ def mapping(**over):
     return m
 
 
-def small(m, tile=32):
-    """Tiles that cut 64 tokens into several: attention in ``tile`` x ``tile``."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=tile,
-                               causal_kv_tile=tile)
-
-
-def loud(params, by=5.0):
-    """The same tree with its 0.02-matrices scaled up, so that every part
-    of a layer moves its output by more than a rounding."""
-    return jax.tree.map(lambda a: a * by if a.ndim >= 2 else a, params)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
-def embedded(params, patches, ids):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
-
-
-def share_of(params, first, count):
-    held = ("w_gate", "w_up", "w_down")
-    return {**params, "layers": [
-        {k: (v[first:first + count] if k in held and v.ndim == 3 else v) for k, v in p.items()}
-        for p in params["layers"]]}
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=32, causal_kv_tile=32))  # 64 tokens in 32 x 32 tiles
+small = KIT.small
 
 
 # ---------------------------------------------------------------------------
@@ -162,7 +135,7 @@ def test_grouped_heads_of_whole_lane_blocks_are_read_where_their_products_wrote_
     kernel writes head by head; q alone is handed over head-major with the
     batch in the rows (``[G, rep, B*S, d]``: ONE transpose traced, which
     compiled is a layout of the rotary's own fusion and no op:
-    ``tests/test_chip_compile.py``). The numbers are the dense reference's
+    ``tests/test_chip_compile_layers.py``). The numbers are the dense reference's
     and those of the head-major addressing (taken where a head is no whole
     number of lane blocks: the same operands with eight zero columns a
     head); with a gate a (token, head) the output is that times the gate,
@@ -381,12 +354,8 @@ def test_the_windowed_trunk_matches_the_reference_at_all_positions_of_a_batch_of
     patches, ids = inputs(3, batch=2)
     sizes = ref.sizes(m)
     with jax.default_matmul_precision("highest"):
-        x, stats = jax.jit(lambda p: decoder.trunk(
-            p, embedded(p, patches, ids), np.arange(64), cfg, 2))(params)
-        got = decoder.logits_of(decoder.head_params(params), x, cfg)
-        want_x = jnp.concatenate([ref.hidden(params, frame, ids, sizes, block=16)
-                                  for frame in patches])
-        want = ref.logits_of(params, want_x, sizes)
+        x, got, stats = KIT.trunk_of(params, patches, ids, cfg)
+        want_x, want = KIT.reference_of(params, patches, ids, sizes)
     for a, b in ((x, want_x), (got, want)):
         scale = float(jnp.sqrt(jnp.mean(b ** 2)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4 * scale, rtol=0)
@@ -429,22 +398,16 @@ def test_the_step_counts_the_tiles_the_band_meets_at_the_cell_s_shapes():
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault):
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
-    patches, ids = inputs(5)
-    with jax.default_matmul_precision("highest"):
-        x, _ = decoder.trunk(params, embedded(params, patches, ids), np.arange(64), cfg)
-        want = ref.hidden(params, patches[0], ids, ref.sizes(m, **FAULTS[fault]), block=16)
-        same = ref.hidden(params, patches[0], ids, ref.sizes(m), block=16)
+    x, same = KIT.trunk(5, jit=False)[0], KIT.reference(5)[0]  # made once for the nine cases
+    want = KIT.reference(5, **FAULTS[fault])[0]
     scale = float(jnp.sqrt(jnp.mean(want ** 2)))
     assert float(jnp.abs(x - same).max()) < 1e-3 * scale
     assert float(jnp.abs(x - want).max()) > 1e-2 * scale  # what a control puts in is seen
 
 
 def test_a_sequence_of_the_batch_does_not_read_its_neighbour_s_keys():
-    cfg = small(mapping(), tile=16)
-    params = loud(decoder.init_params(cfg, jax.random.key(7), jnp.float32))
+    cfg = small(mapping(), causal_q_tile=16, causal_kv_tile=16)
+    params = KIT.params(7)
     patches, ids = inputs(7, batch=2)
     run = jax.jit(lambda p, x: decoder.trunk(p, x, np.arange(64), cfg, 2)[0])
     x = run(params, embedded(params, patches, ids))
@@ -808,13 +771,7 @@ def test_the_adapter_ends_the_run_where_the_file_counts_other_experts_than_it_ho
 
 
 def test_the_cell_s_rehearsal_runs_the_served_path_is_correct_and_reports_its_counters():
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
-         "--seed", "2", "--seconds", "3", "--trace", "1"],
-        capture_output=True, text=True, timeout=900, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line, _ = rehearse(CELL, seed=2)
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["cell"] == CELL
     counted = (("attn_tiles_live_total", "attn_tiles_causal_total"),
                ("attn_pairs_selected_total", "attn_pairs_causal_total"),

@@ -4,7 +4,6 @@ kernel and the sigmoid router with its selection bias, against the
 benchmark's plain reference (``benchmark/reference/lfm2_decoder.py``) at
 small sizes on the CPU; the new cell's counters and counts."""
 
-import dataclasses
 import json
 import os
 
@@ -14,13 +13,13 @@ import numpy as np
 import pytest
 
 from benchmark.reference import lfm2_decoder as ref
+from decoder_kit import PROMPT, Kit, embedded, inputs, streamed
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.parallel import moe
 from psana_ray_tpu.parallel import sparse_attention as sa
 from test_manifest_entries import need
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIG = os.path.join(REPO, "benchmark", "configs", "lfm2_8b_a1b_prefill_epix10k2m.json")
 
 
@@ -38,17 +37,6 @@ def mapping(**over):
     return m
 
 
-def small(m):
-    """Tiles that cut 64 tokens into several."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=16, causal_kv_tile=32)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
 def loud(params):
     """The same tree with the convolution's weights scaled up: at a hidden
     size of 64, normal(0, 0.02) makes its output a thousandth of the
@@ -60,8 +48,8 @@ def loud(params):
     return {**params, "layers": [scale(p) if "conv_w" in p else p for p in params["layers"]]}
 
 
-def embedded(params, patches, ids):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=16, causal_kv_tile=32), loud=loud)  # 64 tokens in several tiles
+small = KIT.small
 
 
 # ---------------------------------------------------------------------------
@@ -81,11 +69,8 @@ def test_trunk_of_three_kinds_of_layer_matches_reference_at_all_positions(schedu
     patches, ids = inputs(3)
     sizes = ref.sizes(m)
     with jax.default_matmul_precision("highest"):
-        x, stats = jax.jit(lambda p: decoder.trunk(
-            p, embedded(p, patches, ids), np.arange(64), cfg))(params)
-        got = decoder.logits_of(decoder.head_params(params), x, cfg)
-        want_x = ref.hidden(params, patches[0], ids, sizes, block=16)
-        want = ref.logits_of(params, want_x, sizes)
+        x, got, stats = KIT.trunk_of(params, patches, ids, cfg)
+        want_x, want = KIT.reference_of(params, patches, ids, sizes)
     assert got.shape == (64, 256) and "head" not in params  # the head is the embedding
     for a, b in ((x, want_x), (got, want)):
         scale = float(jnp.sqrt(jnp.mean(b ** 2)))
@@ -306,42 +291,13 @@ def test_catalog_numbers_are_in_the_file_unchanged():
 # ---------------------------------------------------------------------------
 
 def test_counters_of_a_batched_stream_reach_the_snapshot_and_the_exposition():
-    from benchmark import harness
-    from psana_ray_tpu.infeed import InfeedPipeline
-    from psana_ray_tpu.obs.registry import MetricsRegistry
-    from psana_ray_tpu.records import EndOfStream, FrameRecord
-    from psana_ray_tpu.transport import RingBuffer
-
     cfg = small(mapping())
-    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
-    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
-                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
-    calib = harness.make_calibration(detector, 1)
-    ids = jnp.arange(PROMPT, dtype=jnp.int32)
-    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
-    rng = np.random.default_rng(2)
-    q = RingBuffer(maxsize=8)
-    for i in range(4):
-        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
-    q.put(EndOfStream(total_events=4))
-    pipe = InfeedPipeline(q, batch_size=2, poll_interval_s=0.001)
-    logits = []
-
-    def on_result(out, batch):
-        logits.append(np.asarray(out[0]))
-        decoder.fold_step_stats(pipe.metrics, out[1])
-
-    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 4
-    assert all(x.shape == (2, 256) and np.isfinite(x).all() for x in logits)
-    snap = pipe.metrics.snapshot()
+    _, snap, text = streamed(cfg)
     steps, tokens = 2, 2 * (2 * 2 * 14 + PROMPT)
     assert snap["decoder_tokens_total"] == steps * tokens
     assert snap["decoder_sequences_total"] == steps * 2
     assert snap["expert_tokens_mean_total"] == steps * 3 * tokens * 2 / 8  # three expert layers
     assert snap["attn_tiles_live_total"] == snap["attn_tiles_causal_total"] == steps * 2 * 1
-    registry = MetricsRegistry()
-    registry.register("reader", pipe.metrics)
-    text = registry.render_prometheus()
     for name in decoder.STEP_STATS:
         assert f'psana_ray_{name}{{source="reader"}}' in text, name
 

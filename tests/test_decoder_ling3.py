@@ -8,24 +8,24 @@ cell's counters and counts; and, for every decoder cell at
 once, what each configuration's reader has and has not."""
 
 import dataclasses
+import functools
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_kit
 from benchmark.reference import ling3_decoder as ref
+from decoder_kit import F32_PRODUCTS, PROMPT, Kit, embedded, inputs, rehearse, share_of, streamed
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import delta_rule as dr
 from psana_ray_tpu.parallel import moe
 from test_manifest_entries import BENCH, asked, need, ratio_of
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIGS = os.path.join(REPO, "benchmark", "configs")
 CONFIG = os.path.join(CONFIGS, "ling3_flash_prefill_epix10k2m.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -63,53 +63,19 @@ def mapping(**over):
     return m
 
 
-def small(m, chunk=16):
-    """Tiles that cut 64 tokens into several: the delta rule in chunks of 16, attention in 32 x 32."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=32,
-                               causal_kv_tile=32, linear_chunk=chunk)
-
-
-def loud(params, by=5.0):
-    """The same tree with its 0.02-matrices scaled up, so that every part
-    of a layer moves its output by more than a rounding (the taps and the
-    decay's A and b are of order 1 as drawn)."""
-    def up(path, a):
-        name = path[-1].key if hasattr(path[-1], "key") else ""
-        return a * by if a.ndim >= 2 and name != "conv_w" else a
-
-    return jax.tree_util.tree_map_with_path(up, params)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
-def embedded(params, patches, ids):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
-
-
-def share_of(params, first, count):
-    held = ("w_gate", "w_up", "w_down")
-    return {**params, "layers": [
-        {k: (v[first:first + count] if k in held and v.ndim == 3 else v) for k, v in p.items()}
-        for p in params["layers"]]}
+# loud: the taps and the decay's A and b are of order 1 as drawn
+loud = functools.partial(decoder_kit.loud, keep=("conv_w",))
+PATCHES_OF = {"float32_products": lambda: decoder_kit.float32_products(dr, dr.gated_delta_rule)}
+# 64 tokens in several tiles: the delta rule in chunks of 16, attention in 32 x 32
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=32, causal_kv_tile=32, linear_chunk=16), loud=loud,
+          patches=PATCHES_OF)
+small = KIT.small
 
 
 @pytest.fixture
-def float32_products(monkeypatch):
-    """The kernel's products in float32, so that what is left between it and
-    the recurrence is its FORM alone: the kernel's compiled programs hold the
-    products they were traced with, so its cache goes before and after."""
-    def mm(a, b, dims=((1,), (0,))):
-        return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32), (dims, ((), ())),
-                                   precision=jax.lax.Precision.HIGHEST)
-
-    dr.gated_delta_rule.clear_cache()
-    monkeypatch.setattr(dr, "_mm", mm)
-    yield
-    dr.gated_delta_rule.clear_cache()
+def float32_products():
+    with PATCHES_OF["float32_products"]():
+        yield
 
 
 # ---------------------------------------------------------------------------
@@ -353,12 +319,8 @@ def test_the_hybrid_trunk_matches_the_reference_at_all_positions_of_a_batch_of_t
     patches, ids = inputs(3, batch=2)
     sizes = ref.sizes(m)
     with jax.default_matmul_precision("highest"):
-        x, stats = jax.jit(lambda p: decoder.trunk(
-            p, embedded(p, patches, ids), np.arange(64), cfg, 2))(params)
-        got = decoder.logits_of(decoder.head_params(params), x, cfg)
-        want_x = jnp.concatenate([ref.hidden(params, frame, ids, sizes, block=16)
-                                  for frame in patches])
-        want = ref.logits_of(params, want_x, sizes)
+        x, got, stats = KIT.trunk_of(params, patches, ids, cfg)
+        want_x, want = KIT.reference_of(params, patches, ids, sizes)
     for a, b in ((x, want_x), (got, want)):
         scale = float(jnp.sqrt(jnp.mean(b ** 2)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4 * scale, rtol=0)
@@ -381,15 +343,9 @@ def test_the_hybrid_trunk_matches_the_reference_at_all_positions_of_a_batch_of_t
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault, float32_products):
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
-    patches, ids = inputs(5)
-    with jax.default_matmul_precision("highest"):
-        x, _ = decoder.trunk(params, embedded(params, patches, ids), np.arange(64), cfg)
-        want = ref.hidden(params, patches[0], ids, ref.sizes(m, **FAULTS[fault]), block=16)
-        same = ref.hidden(params, patches[0], ids, ref.sizes(m), block=16)
+def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault):
+    x, same = KIT.trunk(5, under=F32_PRODUCTS, jit=False)[0], KIT.reference(5)[0]  # made once for the twelve cases
+    want = KIT.reference(5, **FAULTS[fault])[0]
     scale = float(jnp.sqrt(jnp.mean(want ** 2)))
     assert float(jnp.abs(x - same).max()) < 1e-3 * scale
     # what a control puts in is seen; a bf16 state's rounding is small and still no float32 state
@@ -399,7 +355,7 @@ def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault, floa
 
 def test_a_sequence_of_the_batch_does_not_read_its_neighbour_s_state_or_taps():
     cfg = small(mapping())
-    params = loud(decoder.init_params(cfg, jax.random.key(7), jnp.float32))
+    params = KIT.params(7)
     patches, ids = inputs(7, batch=2)
     run = jax.jit(lambda p, x: decoder.trunk(p, x, np.arange(64), cfg, 2)[0])
     x = run(params, embedded(params, patches, ids))
@@ -431,14 +387,9 @@ def test_a_null_query_rank_is_a_full_rank_query_and_the_gate_is_one_scalar_a_hea
 
 
 def test_the_gated_full_rank_latent_layer_is_the_reference_s():
-    m = mapping(layer_types=[MLA, MLA, MLA], first_k_dense_replace=3, num_hidden_layers=3)
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(11), jnp.float32))
-    patches, ids = inputs(11)
-    with jax.default_matmul_precision("highest"):
-        x, stats = decoder.trunk(params, embedded(params, patches, ids), np.arange(64), cfg)
-        want = ref.hidden(params, patches[0], ids, ref.sizes(m), block=16)
-        ungated = ref.hidden(params, patches[0], ids, ref.sizes(m, attn_gate=False), block=16)
+    latent = dict(layer_types=[MLA, MLA, MLA], first_k_dense_replace=3, num_hidden_layers=3)
+    x, _, stats = KIT.trunk(11, over=latent, jit=False)
+    want, ungated = KIT.reference(11, over=latent)[0], KIT.reference(11, over=latent, attn_gate=False)[0]
     scale = float(jnp.sqrt(jnp.mean(want ** 2)))
     np.testing.assert_allclose(np.asarray(x), np.asarray(want), atol=2e-4 * scale, rtol=0)
     assert float(jnp.abs(x - ungated).max()) > 1e-2 * scale
@@ -621,34 +572,8 @@ def test_the_other_four_readers_have_nothing_of_what_this_one_brought(name):
 # ---------------------------------------------------------------------------
 
 def test_linear_counters_reach_the_snapshot_and_the_exposition():
-    from benchmark import harness
-    from psana_ray_tpu.infeed import InfeedPipeline
-    from psana_ray_tpu.obs.registry import MetricsRegistry
-    from psana_ray_tpu.records import EndOfStream, FrameRecord
-    from psana_ray_tpu.transport import RingBuffer
-
-    cfg = small(mapping(num_experts=8, router_experts=16, experts_held=[0, 8]), chunk=8)
-    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
-    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
-                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
-    calib = harness.make_calibration(detector, 1)
-    ids = jnp.arange(PROMPT, dtype=jnp.int32)
-    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
-    rng = np.random.default_rng(2)
-    q = RingBuffer(maxsize=8)
-    for i in range(4):
-        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
-    q.put(EndOfStream(total_events=4))
-    pipe = InfeedPipeline(q, batch_size=2, poll_interval_s=0.001)
-    logits = []
-
-    def on_result(out, batch):
-        logits.append(np.asarray(out[0]))
-        decoder.fold_step_stats(pipe.metrics, out[1])
-
-    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 4
-    assert all(x.shape == (2, 256) and np.isfinite(x).all() for x in logits)
-    snap = pipe.metrics.snapshot()
+    cfg = small(mapping(num_experts=8, router_experts=16, experts_held=[0, 8]), linear_chunk=8)
+    _, snap, text = streamed(cfg)
     steps, s = 2, 2 * 2 * 14 + PROMPT  # 64 tokens a frame, two frames a step
     assert snap["decoder_tokens_total"] == steps * 2 * s
     assert snap["linear_attn_tokens_total"] == steps * 2 * (2 * s)  # two linear layers
@@ -657,9 +582,6 @@ def test_linear_counters_reach_the_snapshot_and_the_exposition():
     assert snap["attn_pairs_causal_total"] == 0 == snap["attn_pairs_selected_total"]
     assert 0 < snap["expert_rows_held_total"] < snap["expert_rows_routed_total"] == steps * 2 * 2 * s * 4
     assert 0 < snap["expert_rows_ahead_total"] <= snap["expert_rows_held_total"]
-    text = MetricsRegistry()
-    text.register("reader", pipe.metrics)
-    text = text.render_prometheus()
     for name in (decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS
                  + decoder.LINEAR_STATS + decoder.AHEAD_STATS):
         assert f'psana_ray_{name}{{source="reader"}}' in text, name
@@ -804,14 +726,7 @@ def test_the_adapter_ends_the_run_where_the_file_counts_other_experts_than_it_ho
 
 
 def test_the_cell_s_rehearsal_runs_the_served_path_and_is_correct():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
-         "--seed", "1", "--seconds", "2", "--trace", "1"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line, done = rehearse(CELL, seed=1, seconds=2, xla_flags=False, timeout=600)
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["metrics"] == {}
     assert line["cell"] == CELL and line["attempted"] > 0
     for counted in (("linear_attn_tokens_total", "linear_attn_chunks_total"),

@@ -20,13 +20,13 @@ import pytest
 
 from benchmark.reference import minicpm_sala_decoder as ref
 from benchmark.roofline import minicpm_sala as roofline
+from decoder_kit import HIGHEST, Kit, inputs, loud
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import lightning
 from psana_ray_tpu.parallel import sparse_attention as sa
 from test_manifest_entries import BENCH
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIG = os.path.join(REPO, "benchmark", "configs", "minicpm_sala_prefill_epix10k2m.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CELL = "minicpm_sala_epix_saturated"
@@ -67,22 +67,10 @@ def mapping(**over):
     return m
 
 
-def small(m, chunk=16):
-    """Tiles that cut 64 tokens into several."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), q_tile=32, attn_q_tile=32,
-                               causal_q_tile=32, causal_kv_tile=32, linear_chunk=chunk)
-
-
-def loud(params, by=5.0):
-    """The same tree with its 0.02-matrices scaled up, so that every part of
-    a layer moves its output by more than a rounding."""
-    return jax.tree.map(lambda a: a * by if a.ndim >= 2 else a, params)
-
-
-def inputs(seed):
-    rng = np.random.default_rng(seed)
-    return (jnp.asarray(rng.standard_normal((PATCHES, 64)), jnp.float32),
-            jnp.asarray(rng.integers(0, 256, PROMPT)))
+# tiles that cut 64 tokens into several
+KIT = Kit(mapping, ref, tiles=dict(q_tile=32, attn_q_tile=32, causal_q_tile=32, causal_kv_tile=32,
+                                   linear_chunk=16))
+small = KIT.small
 
 
 def relative_rms(got, want):
@@ -340,7 +328,7 @@ def _both(m, seed, **fault):
     """The program's rows and the reference's (float32 and bf16 operands)."""
     cfg = small(m)
     params = jax.jit(lambda k: decoder.init_params(cfg, k))(jax.random.key(seed))
-    patches, ids = inputs(seed)
+    (patches,), ids = inputs(seed)
     sizes = ref.sizes(m, **fault)
     with jax.default_matmul_precision("highest"):
         want, stated = (ref.hidden(params, patches, ids, sizes, c, 32) for c in (jnp.float32, jnp.bfloat16))
@@ -399,14 +387,17 @@ def _layer_apart(fault):
     stream, and how far the rounding does (the kind of layer the fault is
     in: the sparse one, or the last linear one)."""
     m = mapping()
-    cfg = small(m)
-    params = loud(jax.jit(lambda k: decoder.init_params(cfg, k))(jax.random.key(2)))
     place = 0 if any(key in fault for key in SPARSE_S) else 3
-    kind, p = ref.kinds(ref.sizes(m))[place], params["layers"][place]
-    x = 3.0 * jnp.asarray(np.random.default_rng(0).standard_normal((64, 64)), jnp.float32)
+
+    def sound():  # the layer at `place`, its input and the reference sound and with bf16 operands
+        params = loud(jax.jit(lambda k: decoder.init_params(small(m), k))(jax.random.key(2)))
+        kind, p = ref.kinds(ref.sizes(m))[place], params["layers"][place]
+        x = 3.0 * jnp.asarray(np.random.default_rng(0).standard_normal((64, 64)), jnp.float32)
+        return (kind, p, x, *(ref.layer(p, x, kind, ref.sizes(m), c, 32) - x for c in (jnp.float32, jnp.bfloat16)))
+
+    kind, p, x, want, stated = KIT.made("layer", place, sound, (HIGHEST,))  # once a place for the 18 cases
     with jax.default_matmul_precision("highest"):
-        want, stated, other = (ref.layer(p, x, kind, ref.sizes(m, **f), c, 32) - x
-                               for f, c in (({}, jnp.float32), ({}, jnp.bfloat16), (fault, jnp.float32)))
+        other = ref.layer(p, x, kind, ref.sizes(m, **fault), jnp.float32, 32) - x
 
     def rms(u):
         return float(np.sqrt(np.mean(np.asarray(u, np.float64) ** 2)))

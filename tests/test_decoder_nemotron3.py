@@ -8,26 +8,26 @@ token by token, every held expert a dense pass) at small sizes on the CPU; the
 new cell's configuration file, manifest entries, adapter, counters and counts."""
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
 import re
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_kit
 from benchmark.reference import nemotron3_decoder as ref
+from decoder_kit import F32_PRODUCTS, PROMPT, Kit, checked, embedded, inputs, rehearse, streamed
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import ssd
 from psana_ray_tpu.parallel import moe
 from test_manifest_entries import BENCH
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIGS = os.path.join(REPO, "benchmark", "configs")
 NAME = "nemotron3_nano_prefill_epix10k2m"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -70,65 +70,18 @@ def mapping(**over):
     return m
 
 
-def small(m):
-    """Tiles that cut 64 tokens into several: attention in 32 x 32."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=32,
-                               causal_kv_tile=32)
+loud = functools.partial(decoder_kit.loud, keep=("conv_w",))
+# the scan's chunks at most 16 rows: the trunk's 64 tokens cross three chunk edges
+PATCHES_OF = {"float32_products": lambda: decoder_kit.float32_products(ssd, ssd.ssd_scan),
+              "chunks_of_16": lambda: decoder_kit.chunks_of_16(ssd, ssd.ssd_scan)}
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=32, causal_kv_tile=32), loud=loud, patches=PATCHES_OF)
+small, trunk_of = KIT.small, KIT.trunk_of
 
 
 @pytest.fixture
-def chunks_of_16(monkeypatch):
-    """The scan's chunks at most 16 rows: the trunk's 64 tokens cross three chunk edges."""
-    monkeypatch.setattr(ssd, "ROWS", 16)
-    ssd.ssd_scan.clear_cache()
-    yield
-    ssd.ssd_scan.clear_cache()
-
-
-@pytest.fixture
-def float32_products(monkeypatch):
-    """The scan kernel's products in float32: what is left between it and the
-    recurrence is its FORM alone."""
-    def mm(a, b, dims=((1,), (0,))):
-        return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32), (dims, ((), ())),
-                                   precision=jax.lax.Precision.HIGHEST)
-
-    ssd.ssd_scan.clear_cache()
-    monkeypatch.setattr(ssd, "_mm", mm)
-    yield
-    ssd.ssd_scan.clear_cache()
-
-
-def loud(params, by=5.0):
-    """The same tree with its 0.02-matrices scaled up, so that every part of a
-    block moves its output by more than a rounding."""
-    def up(path, a):
-        name = path[-1].key if hasattr(path[-1], "key") else ""
-        return a * by if a.ndim >= 2 and name != "conv_w" else a
-
-    return jax.tree_util.tree_map_with_path(up, params)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
-def embedded(params, patches, ids, cfg):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
-
-
-def trunk_of(params, patches, ids, cfg):
-    batch = patches.shape[0]
-    x, stats = jax.jit(lambda p: decoder.trunk(
-        p, embedded(p, patches, ids, cfg), np.arange(64), cfg, batch))(params)
-    return x, decoder.logits_of(decoder.head_params(params), x, cfg), stats
-
-
-def reference_of(params, patches, ids, sizes):
-    x = jnp.concatenate([ref.hidden(params, frame, ids, sizes, block=16) for frame in patches])
-    return x, ref.logits_of(params, x, sizes)
+def float32_products():
+    with PATCHES_OF["float32_products"]():
+        yield
 
 
 def _close(got, want, atol=2e-4):
@@ -402,37 +355,27 @@ def test_the_cell_s_grouped_products_tiles():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("letter", ["M", "*", "E", "-"])
-def test_a_block_alone_is_the_reference_s_at_all_positions_of_a_batch_of_two(
-        letter, float32_products, chunks_of_16):
+def test_a_block_alone_is_the_reference_s_at_all_positions_of_a_batch_of_two(letter):
     """A model of ONE layer of each kind: ``x + Mixer(rms(x))`` and nothing
     else (an ``M`` or ``*`` block has no feed-forward and no second norm, an
     ``E`` or ``-`` block no operator and no first), two sequences."""
-    m = mapping(num_hidden_layers=1, hybrid_override_pattern=letter)
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(2), jnp.float32))
-    layer, = params["layers"]
+    one = dict(num_hidden_layers=1, hybrid_override_pattern=letter)
+    layer, = KIT.params(2, over=one)["layers"]
     assert ("norm1" in layer, "norm2" in layer) == ((True, False) if letter in "M*" else (False, True))
     assert "w_gate" not in layer and "shared_gate" not in layer  # ungated: two matrices an MLP
     assert ("wq" in layer, "w_in" in layer, "router" in layer) == (
         letter == "*", letter == "M", letter == "E")
-    patches, ids = inputs(2, batch=2)
-    with jax.default_matmul_precision("highest"):
-        x, got, stats = trunk_of(params, patches, ids, cfg)
-        want_x, want = reference_of(params, patches, ids, ref.sizes(m))
+    x, got, stats = KIT.trunk(2, batch=2, over=one, under=(*F32_PRODUCTS, "chunks_of_16"))
+    want_x, want = KIT.reference(2, batch=2, over=one)
     _close(x, want_x)
     _close(got, want)
     assert len(stats) == (12 if letter == "M" else 6)  # no share held: the lengths such steps had
 
 
-def test_the_trunk_of_single_blocks_matches_the_reference_and_counts_by_block(
-        float32_products, chunks_of_16):
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(3), jnp.float32))
-    patches, ids = inputs(3, batch=2)
-    with jax.default_matmul_precision("highest"):
-        x, got, stats = trunk_of(params, patches, ids, cfg)
-        want_x, want = reference_of(params, patches, ids, ref.sizes(m))
+def test_the_trunk_of_single_blocks_matches_the_reference_and_counts_by_block():
+    cfg = small(mapping())
+    x, got, stats = KIT.trunk(3, batch=2, under=(*F32_PRODUCTS, "chunks_of_16"))
+    want_x, want = KIT.reference(3, batch=2)
     assert x.dtype == jnp.float32
     _close(x, want_x)
     _close(got, want)
@@ -449,17 +392,14 @@ def test_the_trunk_of_single_blocks_matches_the_reference_and_counts_by_block(
     assert s["attn_pairs_causal_total"] == s["attn_pairs_selected_total"] == 0
 
 
-def test_a_share_holder_s_trunk_is_the_reference_given_the_same_share(float32_products):
+def test_a_share_holder_s_trunk_is_the_reference_given_the_same_share():
     """8 of 16 experts held (the cell's half): the reference is given the held
     experts' weights and the router's whole width, as the adapter gives them."""
-    m = mapping(n_routed_experts=8, router_experts=16, experts_held=[0, 8])
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
+    share = dict(n_routed_experts=8, router_experts=16, experts_held=[0, 8])
+    params = KIT.params(5, over=share)
     assert params["layers"][1]["w_up"].shape == (8, 64, 32) and params["layers"][1]["router"].shape == (64, 16)
-    patches, ids = inputs(5, batch=2)
-    with jax.default_matmul_precision("highest"):
-        x, got, stats = trunk_of(params, patches, ids, cfg)
-        want_x, want = reference_of(params, patches, ids, ref.sizes(m))
+    x, got, stats = KIT.trunk(5, batch=2, over=share, under=F32_PRODUCTS)
+    want_x, want = KIT.reference(5, batch=2, over=share)
     _close(x, want_x)
     _close(got, want)
     held, routed, ahead = float(stats[6]), float(stats[7]), float(stats[12])
@@ -467,19 +407,13 @@ def test_a_share_holder_s_trunk_is_the_reference_given_the_same_share(float32_pr
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault, float32_products):
+def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault):
     """Each of the controls' faults moves the reference's own output by far
     more than the program lies from it. What no limit on the chip catches
     under random weights (PERF.md section 4) is held HERE."""
-    pattern = FAULT_IN.get(fault, "E") + "*"
-    m = mapping(num_hidden_layers=2, hybrid_override_pattern=pattern)
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
-    patches, ids = inputs(5)
-    with jax.default_matmul_precision("highest"):
-        x, _, _ = trunk_of(params, patches, ids, cfg)
-        want = reference_of(params, patches, ids, ref.sizes(m))[0]
-        other = reference_of(params, patches, ids, ref.sizes(m, **FAULTS[fault]))[0]
+    two = dict(num_hidden_layers=2, hybrid_override_pattern=FAULT_IN.get(fault, "E") + "*")
+    x = KIT.trunk(5, over=two, under=F32_PRODUCTS)[0]  # made once a pattern (three of them), as the clean reference
+    want, other = KIT.reference(5, over=two)[0], KIT.reference(5, over=two, **FAULTS[fault])[0]
     scale = float(jnp.sqrt(jnp.mean(want ** 2)))
     near = float(jnp.sqrt(jnp.mean((x - want) ** 2))) / scale
     far = float(jnp.sqrt(jnp.mean((other - want) ** 2))) / scale
@@ -673,33 +607,9 @@ def test_the_cell_s_share_of_rows_is_the_batched_adapter_s_own_with_nothing_laid
 
 
 def test_single_block_counters_reach_the_snapshot_and_the_exposition():
-    from benchmark import harness
-    from psana_ray_tpu.infeed import InfeedPipeline
-    from psana_ray_tpu.obs.registry import MetricsRegistry
-    from psana_ray_tpu.records import EndOfStream, FrameRecord
-    from psana_ray_tpu.transport import RingBuffer
-
     cfg = small(mapping(num_hidden_layers=4, hybrid_override_pattern="ME*E", n_routed_experts=8,
                         router_experts=16, experts_held=[0, 8]))
-    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
-    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
-                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
-    calib = harness.make_calibration(detector, 1)
-    ids = jnp.arange(PROMPT, dtype=jnp.int32)
-    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
-    rng = np.random.default_rng(2)
-    q = RingBuffer(maxsize=8)
-    for i in range(4):
-        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
-    q.put(EndOfStream(total_events=4))
-    pipe = InfeedPipeline(q, batch_size=2, poll_interval_s=0.001)
-
-    def on_result(out, batch):
-        assert out[0].shape == (2, 256) and np.isfinite(np.asarray(out[0])).all()
-        decoder.fold_step_stats(pipe.metrics, out[1])
-
-    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 4
-    snap = pipe.metrics.snapshot()
+    _, snap, text = streamed(cfg)
     steps, s = 2, 2 * 2 * 14 + PROMPT  # 64 tokens a frame, two frames a step
     assert snap["decoder_tokens_total"] == steps * 2 * s
     assert snap["linear_attn_tokens_total"] == steps * 2 * s  # ONE block with a scan
@@ -707,26 +617,16 @@ def test_single_block_counters_reach_the_snapshot_and_the_exposition():
     assert snap["attn_tiles_causal_total"] == steps * 2  # one attention block, one tile a sequence
     assert 0 < snap["expert_rows_held_total"] < snap["expert_rows_routed_total"] == steps * 2 * 2 * s * 4
     assert 0 < snap["expert_rows_ahead_total"] <= snap["expert_rows_held_total"]
-    text = MetricsRegistry()
-    text.register("reader", pipe.metrics)
-    text = text.render_prometheus()
     for name in (decoder.STEP_STATS + decoder.SHARE_STATS + decoder.PAIR_STATS
                  + decoder.LINEAR_STATS + decoder.AHEAD_STATS):
         assert f'psana_ray_{name}{{source="reader"}}' in text, name
 
 
 def test_the_cell_s_rehearsal_runs_the_served_path_and_is_correct():
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
-         "--seed", "1", "--seconds", "3", "--trace", "1"],
-        capture_output=True, text=True, timeout=900, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line, done = rehearse(CELL, seed=1)
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["cell"] == CELL
     for name in ("ring_depth.hit", "device_wait_ms.hit", "h2d_ms.hit", "startup_trace_s"):
         assert name in line["would_report"], name
-    said = next(ln for ln in done.stdout.splitlines() if ln.startswith("[bench] correct check"))
-    verdict = json.loads(said[said.index("{"):])
+    verdict = checked(done)
     assert verdict["isolated.0"]["ok"] and verdict["isolated.1"]["ok"]
     assert verdict["patch_rows.1"]["ok"] and verdict["first_rows.1"]["rows"] == 24  # for the record

@@ -9,26 +9,26 @@ has one; the new cell's manifest entries, counters, counts and adapter; and
 that Ling-3.0's kernel is the equation it was."""
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_kit
 from benchmark.reference import olmo_hybrid_decoder as ref
 from benchmark.roofline import olmo_hybrid as roofline
+from decoder_kit import F32_PRODUCTS, PROMPT, Kit, embedded, inputs, rehearse, streamed
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import delta_rule as dr
 from psana_ray_tpu.parallel import sparse_attention as sa
 from test_manifest_entries import BENCH
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIG = os.path.join(REPO, "benchmark", "configs", "olmo_hybrid_7b_prefill_epix10k2m.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 CELL = "olmo_hybrid_epix_saturated"
@@ -62,45 +62,19 @@ def mapping(**over):
     return m
 
 
-def small(m, chunk=16):
-    """Tiles that cut 64 tokens into several: the delta rule in chunks of 16, attention in 32 x 32."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=32,
-                               causal_kv_tile=32, linear_chunk=chunk)
-
-
-def loud(params, by=5.0):
-    """The same tree with its 0.02-matrices scaled up, so that every part of
-    a layer moves its output by more than a rounding (the taps are of order 1
-    as drawn; the gains and the gate's own vectors stay)."""
-    def up(path, a):
-        name = path[-1].key if hasattr(path[-1], "key") else ""
-        return a * by if a.ndim >= 2 and not name.startswith("conv_") else a
-
-    return jax.tree_util.tree_map_with_path(up, params)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
-def embedded(params, patches, ids):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
+# loud: the taps are of order 1 as drawn; the gains and the gate's own vectors stay
+loud = functools.partial(decoder_kit.loud, keep=("conv_",))
+PATCHES_OF = {"float32_products": lambda: decoder_kit.float32_products(dr, dr.gated_delta_net)}
+# 64 tokens in several tiles: the delta rule in chunks of 16, attention in 32 x 32
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=32, causal_kv_tile=32, linear_chunk=16), loud=loud,
+          patches=PATCHES_OF)
+small = KIT.small
 
 
 @pytest.fixture
-def float32_products(monkeypatch):
-    """The kernel's products in float32, so that what is left between it and
-    the recurrence is its FORM alone (as ``tests/test_decoder_ling3.py``)."""
-    def mm(a, b, dims=((1,), (0,))):
-        return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32), (dims, ((), ())),
-                                   precision=jax.lax.Precision.HIGHEST)
-
-    dr.gated_delta_net.clear_cache()
-    monkeypatch.setattr(dr, "_mm", mm)
-    yield
-    dr.gated_delta_net.clear_cache()
+def float32_products():
+    with PATCHES_OF["float32_products"]():
+        yield
 
 
 # ---------------------------------------------------------------------------
@@ -243,34 +217,10 @@ def test_ling3_s_kernel_is_the_equation_it_was():
 # the trunk against the reference, float32, all positions, a batch of two
 # ---------------------------------------------------------------------------
 
-_DONE = {}  # what several tests below read alike, computed once (every one of them under `float32_products`)
-
-
-def _trunk_and_reference(m, seed, batch, **fault):
-    """The served trunk on seeded weights and inputs, and the reference with
-    ``fault`` in it on the same."""
-    def once(key, make):
-        if key not in _DONE:
-            _DONE[key] = make()
-        return _DONE[key]
-
-    cfg = small(m)
-    params = once(("params", seed), lambda: loud(decoder.init_params(cfg, jax.random.key(seed), jnp.float32)))
-    patches, ids = inputs(seed, batch=batch)
-    with jax.default_matmul_precision("highest"):
-        x, stats = once(("trunk", seed, batch), lambda: jax.jit(lambda p: decoder.trunk(
-            p, embedded(p, patches, ids), np.arange(64), cfg, batch))(params))
-        want = once(("reference", seed, batch, tuple(sorted(fault.items()))), lambda: jnp.concatenate(
-            [ref.hidden(params, frame, ids, ref.sizes(m, **fault), block=16) for frame in patches]))
-    return cfg, params, x, stats, want
-
-
-def test_the_hybrid_trunk_matches_the_reference_at_all_positions_of_a_batch_of_two(float32_products):
-    m = mapping()
-    cfg, params, x, stats, want_x = _trunk_and_reference(m, 3, 2)
-    with jax.default_matmul_precision("highest"):
-        got = decoder.logits_of(decoder.head_params(params), x, cfg)
-        want = ref.logits_of(params, want_x, ref.sizes(m))
+def test_the_hybrid_trunk_matches_the_reference_at_all_positions_of_a_batch_of_two():
+    cfg = small(mapping())
+    x, got, stats = KIT.trunk(3, batch=2, under=F32_PRODUCTS)
+    want_x, want = KIT.reference(3, batch=2)
     for a, b in ((x, want_x), (got, want)):
         scale = float(jnp.sqrt(jnp.mean(b ** 2)))
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4 * scale, rtol=0)
@@ -298,8 +248,8 @@ def test_a_sequence_of_the_batch_does_not_read_its_neighbour_s_state_or_taps():
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault, float32_products):
-    _, _, x, _, want = _trunk_and_reference(mapping(), 5, 1, **FAULTS[fault])
+def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault):
+    x, want = KIT.trunk(5, under=F32_PRODUCTS)[0], KIT.reference(5, **FAULTS[fault])[0]
     scale = float(jnp.sqrt(jnp.mean(want ** 2)))
     # what a control puts in is seen; a bf16 state's rounding is small and still no float32 state
     seen = 1e-3 if fault == "bf16_state" else 1e-2
@@ -307,12 +257,11 @@ def test_the_reference_with_a_control_s_fault_in_it_is_another_trunk(fault, floa
 
 
 @pytest.mark.parametrize("reading", sorted(OTHER_READINGS))
-def test_an_assumption_s_other_reading_is_another_model(reading, float32_products):
+def test_an_assumption_s_other_reading_is_another_model(reading):
     """Each ``assumed`` entry of the file that names another reading: the
     reference computes both, the program is the first, and the two differ."""
-    m = mapping()
-    _, _, x, _, same = _trunk_and_reference(m, 5, 1)
-    _, _, _, _, other = _trunk_and_reference(m, 5, 1, **OTHER_READINGS[reading])
+    x, same = KIT.trunk(5, under=F32_PRODUCTS)[0], KIT.reference(5)[0]
+    other = KIT.reference(5, **OTHER_READINGS[reading])[0]
     scale = float(jnp.sqrt(jnp.mean(same ** 2)))
     assert float(jnp.abs(x - same).max()) < 1e-3 * scale
     assert float(jnp.abs(other - same).max()) > 3e-2 * scale
@@ -436,35 +385,9 @@ def test_a_norm_after_a_branch_is_refused_on_what_it_is_not_built_on():
 # ---------------------------------------------------------------------------
 
 def test_linear_and_block_counters_reach_the_snapshot_and_the_exposition():
-    from benchmark import harness
-    from psana_ray_tpu.infeed import InfeedPipeline
-    from psana_ray_tpu.obs.registry import MetricsRegistry
-    from psana_ray_tpu.records import EndOfStream, FrameRecord
-    from psana_ray_tpu.transport import RingBuffer
-
     # heads of 128, alone in their groups and unturned: two a grid step, and seventeen statistics
-    cfg = small(mapping(head_dim=128), chunk=8)
-    params = decoder.init_params(cfg, jax.random.key(1), jnp.bfloat16)
-    detector = {"panels": 2, "height": 16, "width": 112, "pedestal_adu": 100.0,
-                "photon_adu": 35.0, "bad_pixel_fraction": 0.003}
-    calib = harness.make_calibration(detector, 1)
-    ids = jnp.arange(PROMPT, dtype=jnp.int32)
-    step = jax.jit(lambda f: decoder.frame_step(params, calib, f, ids, cfg=cfg, threshold=10.0))
-    rng = np.random.default_rng(2)
-    q = RingBuffer(maxsize=8)
-    for i in range(4):
-        q.put(FrameRecord(0, i, rng.integers(90, 140, (2, 16, 112)).astype(np.uint16), 9.0))
-    q.put(EndOfStream(total_events=4))
-    pipe = InfeedPipeline(q, batch_size=2, poll_interval_s=0.001)
-    logits = []
-
-    def on_result(out, batch):
-        logits.append(np.asarray(out[0]))
-        decoder.fold_step_stats(pipe.metrics, out[1])
-
-    assert pipe.run(lambda batch: step(batch.frames), on_result=on_result) == 4
-    assert all(x.shape == (2, 256) and np.isfinite(x).all() for x in logits)
-    snap = pipe.metrics.snapshot()
+    cfg = small(mapping(head_dim=128), linear_chunk=8)
+    _, snap, text = streamed(cfg)
     steps, s = 2, 2 * 2 * 14 + PROMPT  # 64 tokens a frame, two frames a step
     assert snap["decoder_tokens_total"] == steps * 2 * s
     assert snap["linear_attn_tokens_total"] == steps * 3 * (2 * s)  # three linear layers
@@ -472,9 +395,6 @@ def test_linear_and_block_counters_reach_the_snapshot_and_the_exposition():
     assert snap["linear_attn_tokens_total"] * 6 / snap["linear_attn_chunks_total"] == 8  # the chunk
     assert snap["attn_head_tiles_total"] == 2 * snap["attn_grid_steps_total"] == steps * 2 * 6 * 3
     assert snap["loop_passes_total"] == snap["expert_rows_ahead_total"] == 0  # the groups it has not
-    text = MetricsRegistry()
-    text.register("reader", pipe.metrics)
-    text = text.render_prometheus()
     for name in decoder.LINEAR_STATS + decoder.BLOCK_STATS:
         assert f'psana_ray_{name}{{source="reader"}}' in text, name
 
@@ -541,14 +461,7 @@ def test_the_adapter_ends_the_run_where_the_package_lacks_the_mechanism(monkeypa
 
 
 def test_the_cell_s_rehearsal_runs_the_served_path_and_is_correct():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    env.pop("XLA_FLAGS", None)
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
-         "--seed", "1", "--seconds", "2", "--trace", "1"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line, done = rehearse(CELL, seed=1, seconds=2, xla_flags=False, timeout=600)
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["metrics"] == {}
     assert line["cell"] == CELL and line["attempted"] > 0
     for name in ("device_put_ms", "device_wait_ms.hit", "device_idle_share.hit", "h2d_ms.hit"):

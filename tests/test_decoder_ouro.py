@@ -6,22 +6,22 @@ out) at small sizes on the CPU; the new cell's configuration file,
 adapter, counters and counts."""
 
 import dataclasses
+import functools
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_kit
 from benchmark.reference import ouro_decoder as ref
+from decoder_kit import Kit, apart, checked, embedded, inputs, rehearse
 from psana_ray_tpu.models import decoder
 from test_manifest_entries import BENCH
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 56, 8  # 64 tokens a sequence
 CONFIGS = os.path.join(REPO, "benchmark", "configs")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 NAME, CELL = "ouro_2p6b_prefill_epix10k2m", "ouro_epix_saturated"
@@ -48,45 +48,25 @@ def mapping(**over):
     return m
 
 
-def small(m):
-    """Tiles that cut 64 tokens into several: attention in 32 x 32."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=32,
-                               causal_kv_tile=32)
-
-
 def loud(params, by=5.0):
     """The same tree with its 0.02-matrices (and the gate's vector) scaled up,
     so that every part moves the output by more than a rounding."""
-    def up(path, a):
-        gate = any(getattr(k, "key", "") == "exit_gate" for k in path)
-        return a * by if a.ndim >= 2 or (gate and a.ndim == 1) else a
-
-    return jax.tree_util.tree_map_with_path(up, params)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
-
-
-def embedded(params, patches, ids):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
-
-
-def trunk_of(params, patches, ids, cfg):
-    """The program's last-pass rows, logits, statistics and exit distribution
-    at every position of the batch."""
-    batch = patches.shape[0]
-    x, stats, p = jax.jit(lambda q: decoder.trunk(
-        q, embedded(q, patches, ids), np.arange(64), cfg, batch, exits=True))(params)
-    return x, decoder.logits_of(decoder.head_params(params), x, cfg), stats, p
+    params = decoder_kit.loud(params, by)
+    return {**params, "exit_gate": {**params["exit_gate"], "w": params["exit_gate"]["w"] * by}}
 
 
 def reference_of(params, patches, ids, sizes):
     x, p = zip(*(ref.hidden(params, frame, ids, sizes, block=16) for frame in patches))
     x = jnp.concatenate(x)
     return x, ref.logits_of(params, x, sizes), jnp.concatenate(p, axis=1)
+
+
+# the program's last-pass rows, logits, statistics and exit distribution at every position of the
+# batch; 64 tokens in 32 x 32 tiles
+trunk_of = functools.partial(Kit.trunk_of, exits=True)
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=32, causal_kv_tile=32), loud=loud, trunk_of=trunk_of,
+          reference_of=reference_of)
+small = KIT.small
 
 
 def _near(a, b, tol):
@@ -110,13 +90,9 @@ def _file(name=NAME):
 # ---------------------------------------------------------------------------
 
 def test_the_looped_trunk_matches_the_reference_at_all_positions_of_a_batch_of_two():
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(3), jnp.float32))
-    patches, ids = inputs(3, batch=2)
-    with jax.default_matmul_precision("highest"):
-        x, logits, stats, p = trunk_of(params, patches, ids, cfg)
-        want_x, want_logits, want_p = reference_of(params, patches, ids, ref.sizes(m))
+    cfg = small(mapping())
+    x, logits, stats, p = KIT.trunk(3, batch=2)
+    want_x, want_logits, want_p = KIT.reference(3, batch=2)
     _near(x, want_x, 2e-4)
     _near(logits, want_logits, 2e-4)
     _near(p, want_p, 2e-4)
@@ -142,11 +118,10 @@ def test_the_passes_are_the_same_layers_applied_again_with_the_final_norm_betwee
     the same weights four times, ``rms_f`` at the end of every pass."""
     cfg = small(mapping())
     once = dataclasses.replace(cfg, passes=1)
-    params = loud(decoder.init_params(cfg, jax.random.key(4), jnp.float32))
+    params = KIT.params(4)
     patches, ids = inputs(4, batch=2)
+    looped = KIT.trunk(4, batch=2)[0]
     with jax.default_matmul_precision("highest"):
-        looped = trunk_of(params, patches, ids, cfg)[0]
-
         def by_hand(q):
             x = embedded(q, patches, ids)
             for _ in range(4):
@@ -178,7 +153,7 @@ def test_one_pass_is_a_plain_sandwich_decoder_and_holds_no_loop():
     m = mapping(total_ut_steps=1)
     cfg = small(m)
     assert (cfg.passes, cfg.sandwich, cfg.exit_gate) == (1, True, True)
-    params = loud(decoder.init_params(cfg, jax.random.key(6), jnp.float32))
+    params = KIT.params(6, over=dict(total_ut_steps=1))
     patches, ids = inputs(6)
     jaxpr = jax.make_jaxpr(lambda q: decoder.trunk(q, embedded(q, patches, ids), np.arange(64),
                                                    cfg, 1))(params).jaxpr
@@ -200,20 +175,11 @@ def test_the_reference_with_a_control_s_fault_in_it_is_another_model(fault):
     """Each of the controls' faults moves the reference's own output (the last
     pass's rows, or for the gate's reading the exit distribution alone) by far
     more than the program lies from it."""
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(5), jnp.float32))
-    patches, ids = inputs(5)
-    with jax.default_matmul_precision("highest"):
-        x, _, _, p = trunk_of(params, patches, ids, cfg)
-        want_x, _, want_p = reference_of(params, patches, ids, ref.sizes(m))
-        other_x, _, other_p = reference_of(params, patches, ids, ref.sizes(m, **FAULTS[fault]))
+    x, _, _, p = KIT.trunk(5)  # made once for the five cases, as the clean reference
+    want_x, _, want_p = KIT.reference(5)
+    other_x, _, other_p = KIT.reference(5, **FAULTS[fault])
     if fault == "three_passes":  # a pass short: no fourth row
         other_p = jnp.concatenate([other_p, jnp.zeros_like(other_p[:1])])
-
-    def apart(a, b):
-        return float(jnp.sqrt(jnp.mean((a - b) ** 2)) / jnp.sqrt(jnp.mean(b ** 2)))
-
     assert apart(x, want_x) < 1e-5 and apart(p, want_p) < 1e-5
     if fault == "gate_before_norm":  # the rows are what they were: only the exits see it
         assert apart(other_x, want_x) == 0 and apart(other_p, want_p) > 0.05
@@ -223,9 +189,9 @@ def test_the_reference_with_a_control_s_fault_in_it_is_another_model(fault):
 
 def test_the_exit_distribution_sums_to_one_and_a_sure_gate_leaves_at_the_first_pass():
     cfg = small(mapping())
-    params = loud(decoder.init_params(cfg, jax.random.key(7), jnp.float32))
+    params = KIT.params(7)
     patches, ids = inputs(7, batch=2)
-    p = np.asarray(trunk_of(params, patches, ids, cfg)[3])
+    p = np.asarray(KIT.trunk(7, batch=2, under=())[3])
     np.testing.assert_allclose(p.sum(axis=0), 1.0, atol=1e-6)
     assert 0.02 < p.min() and p.max() < 0.98  # seen, not saturated
     sure = {**params, "exit_gate": {"w": params["exit_gate"]["w"], "b": jnp.float32(40.0)}}
@@ -432,19 +398,12 @@ def test_the_adapter_draws_a_layer_at_a_time_and_the_tree_is_init_params_own():
 
 
 def test_the_cell_s_rehearsal_runs_the_served_path_and_is_correct():
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
-         "--seed", "3000000007", "--seconds", "3", "--trace", "1"],
-        capture_output=True, text=True, timeout=900, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line, done = rehearse(CELL, seed=3000000007)
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["cell"] == CELL
     for name in ("ring_depth.hit", "device_wait_ms.hit", "startup_trace_s", "startup_compile_s"):
         assert name in line["would_report"], name
     # the check ran both sequences of the rehearsal's batch: rows, a sequence moved, the exits
-    said = next(ln for ln in done.stdout.splitlines() if ln.startswith("[bench] correct check"))
-    verdict = json.loads(said[said.index("{"):])
+    verdict = checked(done)
     for i in (0, 1):
         assert verdict[f"isolated.{i}"]["ok"] and verdict[f"exits.{i}"]["ok"]
         assert verdict[f"first_rows.{i}"]["rows_over_share_limit"] == 0.1

@@ -7,23 +7,22 @@ every layer on every row) at small sizes on the CPU; the new cell's
 configuration file, adapter and counts."""
 
 import dataclasses
-import functools
 import json
 import os
-import subprocess
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_kit
 from benchmark.reference import phi4flash_decoder as ref
+from decoder_kit import HIGHEST, PROMPT, Kit, apart, checked, rehearse
 from psana_ray_tpu.models import decoder
 from psana_ray_tpu.ops import selective_scan as ss
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PATCHES, PROMPT = 40, 8  # 48 tokens a sequence: no power of two
+PATCHES = 40  # with the prompt's 8, 48 tokens a sequence: no power of two
 S = PATCHES + PROMPT
 CONFIG = os.path.join(REPO, "benchmark", "configs", "phi4_mini_flash_prefill_epix10k2m.json")
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
@@ -56,59 +55,26 @@ def mapping(**over):
     return m
 
 
-def small(m):
-    """Tiles that cut 48 tokens into several: attention in 16 x 16."""
-    return dataclasses.replace(decoder.DecoderConfig.from_mapping(m), causal_q_tile=16,
-                               causal_kv_tile=16)
-
-
-@pytest.fixture
-def chunks_of_16(monkeypatch):
-    """The scan's chunks at most 16 rows, so that the trunk's 48 tokens cross
-    two chunk edges (the scan's traces hold the tiles they were made with)."""
-    monkeypatch.setattr(ss, "ROWS", 16)
-    ss.selective_scan.clear_cache()
-    yield
-    ss.selective_scan.clear_cache()
-
-
 def loud(params, by=5.0):
     """The same tree with its 0.02-matrices scaled up and A made RANDOM (the
     initialiser's is the same ramp in every channel: a kernel that leaned on
     that would pass), so that every part of a layer moves its output."""
-    def up(path, a):
-        name = path[-1].key if hasattr(path[-1], "key") else ""
-        if name == "a_log":
-            return a + 0.5 * jax.random.normal(jax.random.key(a.shape[0]), a.shape)
-        return a * by if a.ndim >= 2 and name not in ("conv_w", "w_dt") else a
+    def random_a(path, a):
+        if getattr(path[-1], "key", "") != "a_log":
+            return a
+        return a + 0.5 * jax.random.normal(jax.random.key(a.shape[0]), a.shape)
 
-    return jax.tree_util.tree_map_with_path(up, params)
-
-
-def inputs(seed, batch=1):
-    rng = np.random.default_rng(seed)
-    patches = jnp.asarray(rng.standard_normal((batch, PATCHES, 64)), jnp.float32)
-    return patches, jnp.asarray(rng.integers(0, 256, PROMPT))
+    return jax.tree_util.tree_map_with_path(
+        random_a, decoder_kit.loud(params, by, keep=("conv_w", "w_dt", "a_log")))
 
 
-def embedded(params, patches, ids):
-    return jnp.concatenate([decoder.embed(params, frame, ids) for frame in patches])
-
-
-def trunk_of(params, patches, ids, cfg, rows=None):
-    """The program's trunk and logits at every position of the batch (or at ``rows``)."""
-    x, stats = jax.jit(lambda p: decoder.trunk(
-        p, embedded(p, patches, ids), np.arange(S), cfg, patches.shape[0], rows=rows))(params)
-    return x, decoder.logits_of(decoder.head_params(params), x, cfg), stats
-
-
-def reference_of(params, patches, ids, sizes):
-    x = jnp.concatenate([ref.hidden(params, frame, ids, sizes, block=16) for frame in patches])
-    return x, ref.logits_of(params, x, sizes)
-
-
-def apart(a, b):
-    return float(jnp.sqrt(jnp.mean((a - b) ** 2)) / jnp.sqrt(jnp.mean(b ** 2)))
+# the scan's chunks at most 16 rows: the trunk's 48 tokens cross two chunk edges
+PATCHES_OF = {"chunks_of_16": lambda: decoder_kit.chunks_of_16(ss, ss.selective_scan)}
+# tiles that cut 48 tokens into several: attention in 16 x 16. `trunk_of(..., rows=)`: the cut trunk
+KIT = Kit(mapping, ref, tiles=dict(causal_q_tile=16, causal_kv_tile=16), loud=loud, patches=PATCHES_OF,
+          frame=PATCHES)
+small, inputs, trunk_of, reference_of = KIT.small, KIT.inputs, KIT.trunk_of, KIT.reference_of
+CHUNKS_OF_16 = (HIGHEST, "chunks_of_16")
 
 
 # ---------------------------------------------------------------------------
@@ -207,15 +173,11 @@ def test_whole_sequences_whole_tiles_and_a_state_that_fits_a_lane_tile_are_asked
 # the trunk against the reference; the cut against the trunk
 # ---------------------------------------------------------------------------
 
-def test_the_trunk_matches_the_reference_through_every_kind_of_layer_at_a_batch_of_two(chunks_of_16):
-    m = mapping()
-    cfg = small(m)
+def test_the_trunk_matches_the_reference_through_every_kind_of_layer_at_a_batch_of_two():
+    cfg = small(mapping())
     assert cfg.layer_types == (M1, W, M1, W, M1, A, G, C)
-    params = loud(decoder.init_params(cfg, jax.random.key(3), jnp.float32))
-    patches, ids = inputs(3, batch=2)
-    with jax.default_matmul_precision("highest"):
-        x, got, stats = trunk_of(params, patches, ids, cfg)
-        want_x, want = reference_of(params, patches, ids, ref.sizes(m))
+    x, got, stats = KIT.trunk(3, batch=2, under=CHUNKS_OF_16)
+    want_x, want = KIT.reference(3, batch=2)
     for a, b in ((x, want_x), (got, want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4 * float(jnp.sqrt(jnp.mean(b ** 2))), rtol=0)
@@ -232,17 +194,14 @@ def test_the_trunk_matches_the_reference_through_every_kind_of_layer_at_a_batch_
 
 
 @pytest.mark.parametrize("rows", [(S - 1,), (0, 17, S - 1)], ids=["last", "three"])
-def test_the_step_s_later_layers_on_the_served_rows_alone_are_the_same_layers(rows, chunks_of_16):
+def test_the_step_s_later_layers_on_the_served_rows_alone_are_the_same_layers(rows):
     """``frame_step``'s trunk (the cut) and ``frame_hidden``'s (all rows) are
     the same layers' functions at two row counts: equal at the served rows to
     1e-5 in float32 products, and the rows the layers ran are counted."""
     cfg = small(mapping())
     assert cfg.cut_layer == 5 and cfg.hands_on == (4, 5)
-    params = loud(decoder.init_params(cfg, jax.random.key(4), jnp.float32))
-    patches, ids = inputs(4, batch=2)
-    with jax.default_matmul_precision("highest"):
-        x, logits, _ = trunk_of(params, patches, ids, cfg)
-        cut_x, cut_logits, stats = trunk_of(params, patches, ids, cfg, rows=rows)
+    x, logits, _ = KIT.trunk(4, batch=2, under=CHUNKS_OF_16)  # all rows: made once for the two cases
+    cut_x, cut_logits, stats = KIT.trunk(4, batch=2, under=CHUNKS_OF_16, rows=rows)
     at = np.asarray(rows)
     want_x = x.reshape(2, S, -1)[:, at].reshape(2 * len(at), -1)
     want = logits.reshape(2, S, -1)[:, at].reshape(2 * len(at), -1)
@@ -292,21 +251,6 @@ def test_frame_step_serves_what_frame_hidden_computes_at_each_frame_s_last_row()
     assert len(stats) == 19 and float(stats[-1]) == 8 * 2 * s  # the cut's counters, last
 
 
-@functools.lru_cache(maxsize=None)
-def _trunks(seed):
-    """The program's all-rows trunk and its cut one, and the reference's, on
-    one batch of two, made once for the cases below."""
-    m = mapping()
-    cfg = small(m)
-    params = loud(decoder.init_params(cfg, jax.random.key(seed), jnp.float32))
-    patches, ids = inputs(seed, batch=2)
-    with jax.default_matmul_precision("highest"):
-        x = trunk_of(params, patches, ids, cfg)[0]
-        good = trunk_of(params, patches, ids, cfg, rows=(S - 1,))[0]
-        want = reference_of(params, patches[:1], ids, ref.sizes(m))[0]
-    return m, cfg, params, patches, ids, x, good, want
-
-
 @pytest.mark.parametrize("fault", ["keys_cut_to_the_served_rows", "the_other_frame_s_row",
                                    "memory_at_row_0"])
 def test_a_fault_of_the_cut_s_own_moves_the_served_rows(fault):
@@ -315,7 +259,8 @@ def test_a_fault_of_the_cut_s_own_moves_the_served_rows(fault):
     on the chip."""
     from benchmark.tests.phi4flash_controls import plant
 
-    _, cfg, params, patches, ids, x, good, _ = _trunks(7)
+    cfg, params, (patches, ids) = small(mapping()), KIT.params(7), inputs(7, batch=2)
+    x, good = KIT.trunk(7, batch=2)[0], KIT.trunk(7, batch=2, rows=(S - 1,))[0]  # once for the three cases
     restore = plant(decoder, fault)
     jax.clear_caches()
     try:
@@ -343,7 +288,10 @@ def test_the_reference_with_a_fault_or_the_other_reading_in_it_is_another_layer(
     trunk lies from the reference's — the two that the chip cannot see under
     the initialiser's A, one channel's decays in every channel and the ramp
     assumed, among them: the weights here have a random A."""
-    m, _, params, patches, ids, x, _, want = _trunks(5)
+    m, params, (patches, ids) = mapping(), KIT.params(5), inputs(5, batch=2)
+    x = KIT.trunk(5, batch=2)[0]  # once for the 22 cases, as the first frame's reference
+    want = KIT.made("reference of frame 0", 5, lambda: reference_of(params, patches[:1], ids, ref.sizes(m))[0],
+                    (HIGHEST,))
     near = apart(x[:S], want)
     faulty = ref.sizes(m, **FAULTS[fault])
     at = AT_LAYER.get(fault, 5)  # the attention's faults: in the full layer
@@ -514,17 +462,10 @@ def test_every_part_of_rows_decides_in_this_adapter_by_its_own_share(part, monke
 
 
 def test_the_cell_s_rehearsal_runs_the_served_path_is_correct_and_reports_the_cut_s_counters():
-    done = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "run.py"), "--rehearse", "--workload", CELL,
-         "--seed", "1", "--seconds", "3", "--trace", "1"],
-        capture_output=True, text=True, timeout=900, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert done.returncode == 0, done.stderr[-2000:]
-    line = json.loads(done.stdout.strip().splitlines()[-1])
+    line, done = rehearse(CELL, seed=1)
     assert line["rehearsal"] and line["correct"] and line["failed"] == 0 and line["cell"] == CELL
     for name in ("ring_depth.hit", "device_wait_ms.hit", "startup_trace_s"):
         assert name in line["would_report"], name
-    said = next(ln for ln in done.stdout.splitlines() if ln.startswith("[bench] correct check"))
-    verdict = json.loads(said[said.index("{"):])
+    verdict = checked(done)
     assert verdict["isolated.0"]["ok"] and verdict["isolated.1"]["ok"] and verdict["served"]["ok"]
     assert verdict["served"]["sequences"] == 2 and verdict["first_rows.1"]["rows_over_share_limit"] == 0.1

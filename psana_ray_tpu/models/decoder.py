@@ -1897,10 +1897,10 @@ def _attention(p, x, angles, idx_angles, batch: int, cfg: DecoderConfig, window:
     call of its own under its scope (``proj``, ``indexer``,
     ``sparse_attn``): a scope reaches the chip's profile only on ops
     inlined from a call. Under a ``window`` (a SLIDING layer: keys ``t -
-    window < j <= t``) the same kernel visits the tiles that meet the band
-    alone, under a name and a scope of its own (``windowed_gqa_attention``,
-    ``window_attn``), and ``live`` counts the statistics tiles the band
-    meets. Where the rotary is the kernel's (:func:`_kernel_turns`: heads of
+    window < j <= t``) the same kernel takes ONE step a query tile against
+    the key window that follows the band (PR 77), under a name and a scope
+    of its own (``windowed_gqa_attention``, ``window_attn``), and ``live``
+    counts the statistics tiles the band meets. Where the rotary is the kernel's (:func:`_kernel_turns`: heads of
     whole lane blocks, no selection) q and k reach it float32 and unturned,
     as ``W_q``'s and ``W_k``'s products wrote them, with the layer type's two
     tables (:func:`turn_tables`, made under ``proj``: the step's ONE pair a

@@ -56,12 +56,15 @@ What is here, and what it is:
   (:func:`mask_tile`: 34,304 keys in sixteen tiles of 2,176) the keys
   and values are padded with zeros to the whole tiles, which the mask
   closes. Under a WINDOW (``window``: a query attends to
-  its own key and the ``window - 1`` before it; Laguna's sliding layers)
-  the grid holds only the tiles that MEET the band, the running softmax
-  starts at a query tile's first visited key tile, and each edge of the
-  band is compared only in the tiles it crosses: the same body under a
-  name of its own (``windowed_gqa_attention``). The tiles follow from the
-  heads a group and the window (:func:`causal_tiles`).
+  its own key and the ``window - 1`` before it; Laguna's sliding layers,
+  Phi-4-mini-flash's differential ones) the keys follow the band
+  DIAGONALLY (PR 77): ONE grid step a query tile, against ONE window of
+  ``window + bq`` key rows that starts ``window`` rows before the tile's
+  first (:func:`band_keys`; Element-addressed blocks: the rows are no
+  whole blocks of their own size), so every key of a row is in the step
+  and the softmax is ONE pass with no running state: the same body under
+  a name of its own (``windowed_gqa_attention``). The query tile follows
+  from the heads a group and the window (:func:`causal_tiles`).
 - :func:`live_tiles` — how many ``stat_tile`` x ``stat_tile`` tiles at
   or below the diagonal hold a selected pair (from the flags the selection
   kernel writes beside its mask), and how many there are: what a
@@ -87,15 +90,17 @@ _VMEM_LIMIT = 100 * 1024 * 1024  # of the v5e's 128 MiB; the default scope is 16
 # of the batched causal kernel's stacked score tile [heads a group x query tile, key tile] float32
 # (:func:`causal_tiles`): a fifth of the limit, for the tile, its exponentials and their bf16 copy
 SCORE_TILE_BYTES = _VMEM_LIMIT // 5
-# a windowed call's widest (query tile, key tile), as shares of its window: the key tile the
-# window's own width, the query tile half of it. One windowed layer on the v5e, 2 x 8,704 tokens,
-# 9 heads of 128 a group, a window of 512, on PR 53's tree (the call with the head-major
-# transposes it then had: my chip runs, PR 53), ms: 256 x 512 12.2, 256 x 256 14.3, 128 x 256
-# 15.4, 512 x 256 16.8, 128 x 128 19.2, 256 x 128 20.4, 512 x 512 21.1; since PR 58 the call at
-# 256 x 512 is 10.8 for a kernel of 8.02 (my chip runs, PR 58). A narrower key tile meets fewer
-# pairs outside the band (768 keys a row at 256 x 256 where 256 x 512 and 512 x 512 meet 1,024)
-# and loses more to its grid steps
-BAND_TILES = (0.5, 1.0)
+# a windowed call's widest query tile, as a share of its window (the keys it is run against are no
+# share of anything: the window and the tile, `band_keys`). The kernel ALONE on the v5e, 2 x 8,704
+# tokens under a window of 512, twenty calls a reading, ms (my chip runs, PR 77, seed 7700000002;
+# **bold** what the rules give; the parent's body over the band's 256 x 512 TILES read again last):
+# laguna's call (9 heads of 128 a group turned by the kernel, gated), parent 8.12 (three parts
+# 7.50) -> a query tile of 128 against 640 keys 3.57 at one part, 3.62 at three; 256 against 768
+# 3.92, **3.71** at three, 3.65 at nine; 512 against 1,024 4.62 at three; phi4flash's (10 groups of 2
+# half-heads of 64 over values of 128, head-major), parent 3.01 -> 128: 1.85; 256: **1.67** (two
+# parts 1.62); 512: 1.76. A tile of 128 meets fewer keys a row outside the band (640 for 768)
+# and reads laguna's call 4% faster and phi4flash's 11% SLOWER than 256: the share stays
+BAND_QUERY_TILE = 0.5
 BLOCK_HEADS = (8, 4, 2)  # the heads a grid step may take where each is alone in its group
 # of the score tiles a kernel body's unrolled heads write, over its branches (:func:`heads_a_step`):
 # 35.7 and 18.9 MB read faster than one head a step, 37.9 a third SLOWER (PR 66). The falloff is a
@@ -648,9 +653,6 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
         rep = q_ref.shape[-1] // (heads * d)
     dv = v_ref.shape[-1] // (heads * parts)
     rows = rep * block_q  # the group's query heads, stacked: one product serves them all
-    # the query tile's first visited key tile: tile 0, or under a window the one that holds the
-    # key `window - 1` before the tile's first row (`_band_tiles`' first)
-    first = 0 if window is None else jnp.maximum(qi * block_q - (window - 1), 0) // block_k
 
     # (alone in its step a head reads the whole refs, as it was traced before there were blocks:
     # `tests/test_decoder_kimi.py -k traces_the_kernel` holds that body's jaxpr)
@@ -665,11 +667,11 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
         at = (h * parts + part) * width
         return ref[..., at:at + width]
 
-    @pl.when(kb == first)
-    def _reset():
-        m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
-        acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
+    def _reset():  # a query tile's first key step (under a window its only one: no state to reset)
+        if window is None:
+            m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+            acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
         if turn is not None:  # once a query tile: every key step reads the scratch
             qs = qs_ref[...]  # [rep, bq, ds], or a block's [hb, 1, bq, ds]: its heads lead
             turned_ref[...] = _turned_tile(qs if heads == 1 else qs.reshape(heads, block_q, -1),
@@ -740,16 +742,15 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
                 sel = mask_ref[...].astype(jnp.float32).reshape(block_q, block_k)
                 open_.append((sel > 0.0)[None])
             elif (with_diagonal or with_lower_edge) and not open_:
-                # without a window key 0 of the sequence is open to every row: m is finite from tile
-                # 0. Under one a row may see NO key of its first visited tile (the band's lower edge
-                # lies past the tile's last key for it): the masked form's case above, m_new ==
-                # NEG_INF and p == 1 there, wiped by alpha == 0 at its first real key, which it
-                # meets at the latest in its diagonal tile, the row's last
+                # key 0 of the sequence is open to every row: m is finite from tile 0 (under a
+                # window `kb` counts the key window's first row in query tiles, and every row
+                # meets its own key in the step)
                 row = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-                col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-                edges = ([col <= row] if with_diagonal else []) + (
+                col = kb * (block_k if window is None else block_q) + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                both = ([col <= row] if with_diagonal else []) + (
                     [col > row - window] if with_lower_edge else [])
-                open_.append(functools.reduce(jnp.logical_and, edges)[None])
+                open_.append(functools.reduce(jnp.logical_and, both)[None])
             if open_:
                 s = jnp.where(open_[0], s.reshape(rep // cut, block_q, block_k),
                               NEG_INF).reshape(rows // cut, block_k)
@@ -765,6 +766,11 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
                                                               preferred_element_type=jnp.float32)
             m_ref[of(h)] = m_new
 
+        def once(h, s, v):  # under a window every key of head h's rows is in the step: ONE pass
+            p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+            l_ref[of(h)] = jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[of(h)] = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
         # head h + 1's score product stands BEFORE head h's softmax, and head h's `p . v` after it:
         # Mosaic keeps unrolled heads in the order they are written, so one head's products run
         # under another's exponentials (two score tiles live; alone in its step a head is the
@@ -772,25 +778,8 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
         ahead = score(0)
         for h in range(heads * cut):
             tile, ahead = ahead, score(h + 1) if h + 1 < heads * cut else None
-            fold(h, *tile)
+            (fold if window is None else once)(h, *tile)
 
-    if masked:
-        update(False)
-    else:
-        below = (kb + 1) * block_k - 1 <= qi * block_q  # every pair of the tile is causal
-        if window is None:
-            pl.when(below)(lambda: update(False))
-            pl.when(jnp.logical_not(below))(lambda: update(True))
-        else:  # each edge of the band is compared only in the tiles it crosses
-            # the tile's first key is inside the band of its last row, and so of every row
-            inside = kb * block_k > (qi + 1) * block_q - 1 - window
-            for diagonal in (False, True):
-                for lower_edge in (False, True):
-                    pl.when((jnp.logical_not(below) if diagonal else below)
-                            & (jnp.logical_not(inside) if lower_edge else inside))(
-                        functools.partial(update, diagonal, lower_edge))
-
-    @pl.when(kb == ((qi + 1) * block_q - 1) // block_k)  # the row's last tile
     def _finalize():
         out = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
         if not gated and heads == 1 and o_ref.shape[0] == rep:
@@ -810,13 +799,42 @@ def _causal_kernel(qi_ref, kb_ref, q_ref, k_ref, *rest, block_q, block_k, shared
             else:  # the group's heads are adjacent lane blocks of ONE token-major tile
                 o_ref[0, :, r * dv:(r + 1) * dv] = head
 
+    if window is not None:  # one grid step a query tile, its first and its last: the step's keys are
+        # ONE window of `block_k` rows from row kb * block_q on, and both edges of the band are compared
+        # over the whole of it, in ONE branch (the compares on the column runs the edges cross alone, the
+        # runs between left open and the tile put together again, read SLOWER: `_causal_attention`)
+        _reset()
+        update(True, True)
+        _finalize()
+        return
+    pl.when(kb == 0)(_reset)
+    if masked:
+        update(False)
+    else:
+        below = (kb + 1) * block_k - 1 <= qi * block_q  # every pair of the tile is causal
+        pl.when(below)(lambda: update(False))
+        pl.when(jnp.logical_not(below))(lambda: update(True))
+    pl.when(kb == ((qi + 1) * block_q - 1) // block_k)(_finalize)  # the row's last tile
+
+
+def band_keys(s: int, bq: int, window: int) -> int:
+    """The rows of the ONE key window a query tile of ``bq`` rows is run against
+    under a ``window``: whole query tiles, the diagonal's and the ``ceil((window
+    - 1) / bq)`` before it that hold the key ``window - 1`` before the tile's
+    first row (``window + bq`` where ``bq`` divides the window: 768 at 256 x 512),
+    at most the sequence."""
+    return min((-(-(window - 1) // bq) + 1) * bq, s)
+
 
 def _band_tiles(s: int, bq: int, bk: int, window: Optional[int] = None) -> list:
     """The ``(query tile, key tile)`` pairs a sequence of ``s`` is run in,
     a query tile's key tiles in order: those that hold a pair at or below
-    the diagonal, and under a ``window`` of those the ones that MEET the
-    band ``t - window < j <= t`` of some row ``t`` of the query tile (at
-    8,704 tokens in 512 x 512 tiles 153, and 33 under a window of 512)."""
+    the diagonal. Under a ``window`` of those the ones that MEET the band
+    ``t - window < j <= t`` of some row ``t`` of the query tile (at 8,704
+    tokens in 512 x 512 tiles 153, and 33 under a window of 512): the
+    GEOMETRY the statistics count in (:func:`band_tile_count`), and until
+    PR 77 the grid of a windowed call, which since takes one step a query
+    tile against one key window (:func:`band_keys`)."""
     def first(i):  # the tile of the key `window - 1` before the query tile's first row
         return 0 if window is None else max(i * bq - (window - 1), 0) // bk
 
@@ -832,7 +850,8 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     """What :func:`masked_gqa_attention` runs. The grid's last
     axis runs over the ``(query tile, key tile)`` pairs at or below the
     diagonal, a row's key tiles in order, so a tile above it costs not
-    even a grid step. ONE kernel body, several ways of addressing its
+    even a grid step; under a ``window`` over the query tiles ALONE, each
+    against one key window (below). ONE kernel body, several ways of addressing its
     blocks, chosen by the widths, each operand by its own. Where a head
     is whole lane blocks (``width % 128 == 0``), at ANY number of query
     heads a group: a key or value head's tile is column block ``g`` of
@@ -1012,7 +1031,53 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     -> 3.11 / 2.86 (**1 part**: 0.52 MB a part, under the floor the nine
     set). Part after part gains a third of what the skew gains or nothing:
     the gain is the ORDER, one part's exponentials under the next part's
-    product, not the smaller tile."""
+    product, not the smaller tile.
+    Under a ``window`` (PR 77) the keys follow the band DIAGONALLY: the
+    grid's last axis is the query tiles, and a step's key and value blocks
+    (where the kernel turns, the key rows of the two tables; a shared key)
+    are ONE window of :func:`band_keys` rows — whole query tiles: the
+    diagonal's and the ``ceil((window - 1) / bq)`` before it, 768 rows at
+    256 x 512 where two key tiles of 512 met 1,024 — from row ``max(qi + 1
+    - tiles, 0) * bq`` on, which the scalar-prefetched table holds in query
+    tiles (a sequence's first tiles start at key 0). The rows of such a
+    window are no whole blocks of its own size, so these blocks are
+    ELEMENT-addressed (``pl.Element`` on every dimension that is not
+    squeezed, the offsets proven multiples of the query tile by
+    ``pl.multiple_of``): Mosaic takes that at token-major column blocks of
+    128 lanes and at head-major heads of 64. ``block_k`` is not a windowed
+    call's to take. With every key of a row in the step there is NO running
+    state: the maximum, the exponentials, their sum and ``p . v`` are taken
+    ONCE (``once``: no ``m``, no ``alpha``, no rescale of the accumulator,
+    no first and last step to tell apart), scores float32 and ``p`` rounded
+    to ``v``'s type as ever — the same mathematics, one rounding chain
+    shorter, so outputs are not the parent's to the bit (13% of laguna's
+    bf16 outputs differ, by an ulp; against the plain float32 band max
+    0.0190 for the parent's 0.0190, mean 9.52e-5 for 9.49e-5; phi4flash's
+    max 0.0146 for 0.0146, mean 3.93e-4 for 3.90e-4). Both edges of the
+    band are compared over the WHOLE window in ONE branch; the stacked
+    rows go in parts as above (laguna's nine heads in three of ``[768,
+    768]``). The kernel ALONE on the v5e, 2 x 8,704 tokens under a window
+    of 512, ms, twenty calls a reading (my chip runs, PR 77; the table of
+    tiles and parts is beside :data:`BAND_QUERY_TILE`, seed 7700000002):
+    laguna's call 8.12 -> **3.71**, phi4flash's 3.01 -> **1.67**. Read
+    first (seed 7700000001) with the compares on the column runs the edges
+    cross ALONE — a window that starts ``window`` keys before the tile's
+    first row meets the lower edge in its first ``bq`` columns and the
+    diagonal in its last, constants of the shapes; the open run between
+    left as it is and the three put together again, a sequence's first
+    tiles in a branch of their own: laguna's 4.29 at one part and 4.13 at
+    three where the whole window's compare read 3.92 and 3.70, phi4flash's
+    1.60 where it read 1.66. The select is one of a score element's six
+    or seven vector passes and was spared on a third of them; the put-
+    together cost more than that, and a second branch doubles the part
+    bodies a start pays for: ONE branch. With the GROUPS inside a query
+    tile's grid steps (the tables' rows and the gate's block then do not
+    move between eight steps and are not fetched again: 1.0 of the 3.5 MB
+    a 6.8 us step moves) laguna's call read 3.704 -> 3.706 and phi4flash's
+    1.665 -> 1.662 (seed 7700000003): the vector work binds, not the bytes,
+    and the grid keeps the other forms' order. First calls (trace, lowering and
+    Mosaic's compile, on the chip): laguna's 5.8-6.3 s -> 2.4-2.7,
+    phi4flash's 0.9-1.0 -> 0.36."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, s, hd = q.shape
@@ -1050,8 +1115,13 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
                              or k.dtype != jnp.float32 or v is None):
         raise ValueError("the kernel turns float32 heads of whole lane blocks, maskless and with "
                          "no shared part")
-    pairs = _band_tiles(s, bq, bk, window)
+    if window is None:
+        pairs = _band_tiles(s, bq, bk)
+    else:  # ONE key window a query tile, whole query tiles of rows: `kb` counts its first in those
+        bk = band_keys(s, bq, window)
+        pairs = [(i, max(i + 1 - bk // bq, 0)) for i in range(s // bq)]
     qi, kb = (jnp.asarray(col, jnp.int32) for col in zip(*pairs))
+    ku = bk if window is None else bq  # the rows `kb` counts in
     ds = k_shared.shape[2] if shared else 0
     hb = heads_a_step(g, rep, bq, bk, d, dv, ds, masked=masked, window=window,
                       turned=turn is not None, want=heads)
@@ -1077,6 +1147,17 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         return pl.BlockSpec((None, 1, bq, hb * rep * width),
                             lambda bi, gi, t, qi, kb: (bi, 0, qi[t], gi))
 
+    def keys_spec(shape, index, row):  # a key-side block, `bk` rows on axis `row` from row kb * ku
+        if window is None:
+            return pl.BlockSpec(shape, index)
+
+        def first(*at):  # under a window ELEMENT by element: the rows are no whole blocks of `bk`
+            units = [ku if axis == row else n for axis, n in enumerate(shape)]  # what an index counts
+            return tuple(i if n is None else i * n if isinstance(i, int) else pl.multiple_of(i * n, n)
+                         for i, n in zip(index(*at), units))
+
+        return pl.BlockSpec(tuple(n if n is None else pl.Element(n) for n in shape), first)
+
     def q_tiles(x, width):  # -> the kernel's [rep, bq, width] tile
         if in_place(width) and (rep == 1 or turn is not None or stack):  # (stacked in the kernel)
             return x.reshape(b, 1, s, g * rep * width), place_spec(width)
@@ -1086,11 +1167,11 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
 
     def kv_tiles(x, width, part=0, parts=1):  # head gi's part is column block gi*parts + part
         if in_place(width):  # (a block of hb heads: every part of theirs, column block gi)
-            return x, pl.BlockSpec((None, bk, hb * width * (parts if joint else 1)),
-                                   lambda bi, gi, t, qi, kb: (
-                                       bi, kb[t], gi if joint else gi * parts + part))
+            return x, keys_spec((None, bk, hb * width * (parts if joint else 1)),
+                                lambda bi, gi, t, qi, kb: (
+                                    bi, kb[t], gi if joint else gi * parts + part), 1)
         return (jnp.transpose(x.reshape(b, sk, g, width), (0, 2, 1, 3)),
-                pl.BlockSpec((None, None, bk, width), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0)))
+                keys_spec((None, None, bk, width), lambda bi, gi, t, qi, kb: (bi, gi, kb[t], 0), 2))
 
     parts = 1  # (of the ONE operand that holds keys and values; a stacked group's are `cut`)
     if v is None and in_place(d):
@@ -1106,7 +1187,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
         # last of the scratch the turned query tile, stacked, as the key steps read it
         operands += [table.reshape(b * s, d) for table in turn] * 2
         in_specs += [pl.BlockSpec((bq, d), lambda bi, gi, t, qi, kb: (bi * (s // bq) + qi[t], 0))] * 2
-        in_specs += [pl.BlockSpec((bk, d), lambda bi, gi, t, qi, kb: (bi * (s // bk) + kb[t], 0))] * 2
+        in_specs += [keys_spec((bk, d), lambda bi, gi, t, qi, kb: (bi * (s // ku) + kb[t], 0), 0)] * 2
         scratch.append(pltpu.VMEM((hb * rep * bq, d), v.dtype))
         rotary = (int(turn_width) or d, float(turn_scale), float(q_scale))
     elif stack:
@@ -1114,7 +1195,7 @@ def _causal_attention(q, k, v, g: int, block_q: int, block_k: int, interpret: bo
     if shared:
         operands += [jnp.transpose(q_shared.reshape(b * s, g, rep, ds), (1, 2, 0, 3)), k_shared]
         in_specs += [rows_spec(ds),
-                     pl.BlockSpec((None, bk, ds), lambda bi, gi, t, qi, kb: (bi, kb[t], 0))]
+                     keys_spec((None, bk, ds), lambda bi, gi, t, qi, kb: (bi, kb[t], 0), 1)]
     if shared_turn is not None:  # the query tile's rows of [cos | cos] and [sin | sin] [B*S, ds]
         operands += [table.reshape(b * s, ds) for table in shared_turn]
         in_specs += [pl.BlockSpec((bq, ds),
@@ -1166,7 +1247,9 @@ def block_vmem_bytes(hb: int, bq: int, bk: int, d: int, dv: int, ds: int = 0, *,
     accumulators with their maxima and sums (a lane block each); the
     pipeline's two buffers of every operand block (q, the heads' keys and
     values, the output; the shared query part float32 in lanes half full,
-    its key, its two tables and the turned scratch; a mask's tile)."""
+    its key, its two tables and the turned scratch; a mask's tile). Under a
+    window ``bk`` is the ONE key window's rows (:func:`band_keys`): the step's
+    whole row of scores and every key-side block at that height."""
     count = 14 * bq * bk + hb * bq * (4 * dv + 2 * 4 * 128)
     count += 2 * hb * 2 * (bq * d + bk * (d + dv) + bq * dv)
     if ds:
@@ -1177,8 +1260,9 @@ def block_vmem_bytes(hb: int, bq: int, bk: int, d: int, dv: int, ds: int = 0, *,
 def _branches(masked: bool, window: Optional[int]) -> int:
     """The branches :func:`_causal_kernel`'s body holds, each with the unrolled
     heads or parts of its own: one under a mask, two without (below the
-    diagonal and on it), four under a window (each edge of the band)."""
-    return 1 if masked else 2 if window is None else 4
+    diagonal and on it), ONE under a window (the whole key window's compare;
+    four, one an edge of the band and tile, until PR 77)."""
+    return 2 if window is None and not masked else 1
 
 
 def heads_a_step(g: int, rep: int, bq: int, bk: int, d: int, dv: int, ds: int = 0, *,
@@ -1193,8 +1277,8 @@ def heads_a_step(g: int, rep: int, bq: int, bk: int, d: int, dv: int, ds: int = 
     and 32 s to compile, PR 47) that divides ``g``, whose count
     (:func:`block_vmem_bytes`) fits :data:`_VMEM_LIMIT`, and under which the
     score tiles of the body's unrolled heads, over every branch the body
-    holds (one under a mask, two without: below the diagonal and on it;
-    four under a window), stay within :data:`UNROLLED_SCORE_BYTES` — past
+    holds (one under a mask or a window, two without: below the diagonal
+    and on it), stay within :data:`UNROLLED_SCORE_BYTES` — past
     that a block reads SLOWER than no block (the readings:
     :func:`_causal_attention`; 128 heads under a mask in 512 x 2,176 tiles
     are 35.7 MB at 8 heads, the best of its row; 64 maskless in 1,088 x
@@ -1236,8 +1320,8 @@ def parts_a_step(rep: int, bq: int, bk: int, ds: int = 0, *, masked: bool = Fals
     attention's, never stacked); else the MOST parts that divide ``rep``
     whose score tile ``[rep / parts * bq, bk]`` float32 is at least
     :data:`PART_SCORE_BYTES`, and under which the body's unrolled parts, over
-    every branch it holds (one under a mask, two without, four under a
-    window: :func:`heads_a_step` counts the same), are at most
+    every branch it holds (one under a mask or a window, two without:
+    :func:`heads_a_step` counts the same), are at most
     :data:`PART_BODIES`. The readings (:func:`_causal_attention` has the
     table): every served shape read fastest skewed, by 16-23% at the rule's
     parts; under a mask the most parts read fastest (keye's eight of 256
@@ -1245,16 +1329,20 @@ def parts_a_step(rep: int, bq: int, bk: int, ds: int = 0, *, masked: bool = Fals
     1,024 rows read 2% under 256 or 512 (laguna's full call 15.54 ms at 3
     parts, 15.86 at 6; nemotron3 20.32 at 4, 20.69 at 16), and parts of half
     a megabyte LOSE (laguna's windowed call 8.13 -> 8.53 at nine parts of 256
-    x 512): the floor. The bound on the bodies is a START's: a part and
+    x 512, on the band's tiles it ran in until PR 77): the floor. The bound on the bodies is a START's: a part and
     branch more costs about 0.1 s of trace and lowering at every start, a
     kernel and not a call site (laguna's step at 6 parts in its full calls
     and 3 in its windowed ones, 24 part bodies where 6 stood: ``step_ms.hit``
     333.3 -> 319.2 and warm ``setup_s`` 19.0-19.3 -> 20.4-21.3,
     ``startup_trace_s`` 3.18 -> 3.99, ``startup_lower_s`` 3.37 -> 4.29: my chip
     runs, PR 75), while a compile alone shows nothing (laguna's full call 6.5
-    s at one part, 6.4-9.2 at 2 / 3 / 6). So laguna's windowed calls (7.51
-    ms at three parts for 8.13: 12 bodies) and minicpm_sala's sixteen parts
-    (62.91 for 66.51 at eight) stay behind it. No bound on VMEM or on the
+    s at one part, 6.4-9.2 at 2 / 3 / 6). So minicpm_sala's sixteen parts
+    (62.91 for 66.51 at eight) stay behind it, as laguna's windowed calls
+    did while their body held four branches (7.51 ms at three parts for 8.13:
+    12 bodies); in ONE branch against one key window (PR 77) the rule gives
+    them three parts of ``[768, 768]`` (3.71 ms for 3.92 at one; nine of
+    0.79 MB read 3.65 and stay under the floor, as phi4flash's two, 1.62 for
+    1.67). No bound on VMEM or on the
     unrolled score tiles: the parts' live set is a part's scores, the next
     part's and one part's exponentials, less than the stacked tile's with its
     own, and the body writes the score bytes it wrote
@@ -1289,7 +1377,10 @@ def causal_steps(b: int, s: int, g: int, rep: int, d: int, dv: int, ds: int = 0,
     heads a grid step beyond a group's own (:func:`heads_a_step`) — and the
     ``(part, query tile, key tile)`` score products those steps write: over
     the grid steps, the parts a stacked group's rows are cut into
-    (:func:`parts_a_step`; the grid steps' own number where nothing is cut)."""
+    (:func:`parts_a_step`; the grid steps' own number where nothing is cut).
+    Under a ``window`` a call takes ONE grid step a query tile (its key tile
+    is the key window, :func:`band_keys`: laguna's windowed call 2 x 8 x 34 =
+    544 where the band's 256 x 512 tiles took 1,056 until PR 77)."""
     if mask_tiles is None:
         bq, bk = causal_tiles(s, rep, block_q, block_k, window, d if turned else 0)
     else:
@@ -1298,7 +1389,7 @@ def causal_steps(b: int, s: int, g: int, rep: int, d: int, dv: int, ds: int = 0,
     hb = heads_a_step(g, rep, bq, bk, d, dv, ds, masked=mask_tiles is not None, window=window,
                       turned=turned)
     cut = parts_a_step(rep, bq, bk, ds, masked=mask_tiles is not None, window=window)
-    pairs = len(_band_tiles(s, bq, bk, window))
+    pairs = s // bq if window is not None else len(_band_tiles(s, bq, bk))  # (one step a query tile)
     return b * g * pairs, b * (g // hb) * pairs, b * (g // hb) * pairs * cut
 
 
@@ -1308,10 +1399,11 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
     sequence of ``s`` in, from the tiles asked for (``pick_tile``'s, as
     ever), the ``rep`` query heads a group whose query tiles it stacks, and
     the window: a rule, where there were two constants measured at two
-    shapes. (1) Under a window neither tile is wider than its share of the
-    window (:data:`BAND_TILES`): a row is run against the ``window + bq +
-    bk`` keys or so that its tiles meet, of which only ``window`` are the
-    band's. (2) The stacked score tile ``[rep * bq, bk]`` float32 stays
+    shapes. (1) Under a window the query tile is no wider than its share of
+    the window (:data:`BAND_QUERY_TILE`) and the "key tile" is the ONE key
+    window a query tile is run against (:func:`band_keys`: the window and the
+    tile, ``block_k`` is not asked): a row is run against ``window + bq``
+    keys, of which ``window`` are the band's. (2) The stacked score tile ``[rep * bq, bk]`` float32 stays
     within :data:`SCORE_TILE_BYTES` — a HEAD's budget (a group's stacked
     heads are one tile): where a grid step takes a block of heads alone in
     their groups (:func:`heads_a_step`) the step keeps the block's live
@@ -1325,23 +1417,25 @@ def causal_tiles(s: int, rep: int, block_q: int, block_k: int,
     tiles of every step measured
     before it as they were (4 heads of 64 a group at 1,088 x 1,088: 18.9 MB;
     a head of 128 + 64 alone: 4.7) and gives 6 heads of 128 a group 512 x
-    1,088 (13.4 MB) and 9 under a window of 512 256 x 512 (4.7). One full
+    1,088 (13.4 MB) and 9 under a window of 512 256 rows against 768 keys
+    (7.1). One full
     layer on the v5e, 2 x 8,704 tokens, 6 heads of 128 a group, on PR 53's
     tree (the call with the head-major transposes it then had: my chip
     runs, PR 53), ms: 512 x 1,088 21.2, 256 x 1,088 21.0, 512 x 512 24.6,
     1,088 x 512 24.7, 1,088 x 1,088 (a score tile of 28.4 MB) 31.7; since
     PR 58 the same call at 512 x 1,088 is 19.9 for a kernel of 18.28 (my
     chip runs, PR 58: q's layout and k's reshape are what is left); the
-    windowed layer's are beside :data:`BAND_TILES`."""
+    windowed layer's are beside :data:`BAND_QUERY_TILE`."""
     bq, bk = pick_tile(s, block_q), pick_tile(s, block_k)
     if window is not None:
-        bq, bk = (min(tile, pick_tile(s, max(int(window * share), 1)))
-                  for tile, share in zip((bq, bk), BAND_TILES))
+        bq = min(bq, pick_tile(s, max(int(window * BAND_QUERY_TILE), 1)))
+        bk = band_keys(s, bq, window)
     # a query row's bytes: its scores; where the kernel turns heads of `turned`, its float32 block
     # (twice: the pipeline's two buffers), the stacked bf16 scratch and its rows of the two tables
     fits = SCORE_TILE_BYTES // (4 * rep * bk + (10 * rep + 16) * turned)
     if bq > fits:
         bq = min(bq, pick_tile(s, max(fits, 1)))
+        bk = bk if window is None else band_keys(s, bq, window)
     return bq, bk
 
 
@@ -1392,8 +1486,10 @@ def masked_gqa_attention(q, k, v, mask=None, *, num_kv_heads: int, block_q: Opti
     the mask's key tile, k and v padded to its whole tiles where it does
     not divide ``S``.
     With ``window`` (maskless only) a query attends to the keys ``t -
-    window < j <= t`` of its sequence and the kernel visits the tiles that
-    meet that band alone, under the name ``windowed_gqa_attention``. With
+    window < j <= t`` of its sequence and the kernel takes ONE grid step a
+    query tile, against one window of ``window + block_q`` keys that follows
+    the diagonal (``block_k`` is not its to take), in one softmax pass,
+    under the name ``windowed_gqa_attention``. With
     ``out_gate [B, S, H]`` float32 each head's output leaves times its
     token's scalar (``decoder.gated``'s arithmetic, in the kernel's last
     step). The maskless form's tiles are :func:`causal_tiles`' of the two

@@ -111,17 +111,18 @@ SERVED = {
     "lfm2": ((8, 4, 1088, 1088, 64, 64, 0, False, None, False), 1),
     "granite": ((8, 4, 1088, 1088, 64, 64, 0, False, None, False), 1),
     "laguna_full": ((8, 6, 512, 1088, 128, 128, 0, False, None, True), 1),
-    "laguna_windowed": ((8, 9, 256, 512, 128, 128, 0, False, 512, True), 1),
+    "laguna_windowed": ((8, 9, 256, 768, 128, 128, 0, False, 512, True), 1),  # (the key window: 512 + 256)
     "nemotron3": ((2, 16, 256, 1088, 128, 128, 0, False, None, False), 1),
     "keye": ((4, 8, 256, 2176, 128, 128, 0, True, None, False), 1),
     "minicpm_sala": ((2, 16, 128, 2048, 128, 128, 0, True, None, False), 1),
     "phi4flash_full": ((10, 2, 1088, 1088, 64, 128, 0, False, None, False), 1),
-    "phi4flash_windowed": ((10, 2, 256, 512, 64, 128, 0, False, 512, False), 1),
+    "phi4flash_windowed": ((10, 2, 256, 768, 64, 128, 0, False, 512, False), 1),
 }
 # and the PARTS a grid step cuts each one's stacked group into, with the reading that chose it: the
 # kernel alone on the v5e, ms, the parent's body -> the rule's parts (and the other parts read), each
 # skewed by one; part after part read slower at every shape (my chip runs, PR 75, seed 7500000001,
-# twenty calls a reading; `_causal_attention`'s docstring has the whole table)
+# twenty calls a reading; the two windowed rows my chip runs, PR 77, seed 7700000002;
+# `_causal_attention`'s docstring has the whole table)
 PARTS = {
     "dsv32": (1, "a head alone in its group: a block of heads (PR 66)"),
     "kimi": (1, "a head alone in its group"),
@@ -130,13 +131,13 @@ PARTS = {
     "lfm2": (4, "25.35 -> 20.30 (2: 21.19); in 544 x 1,088 tiles 25.80 -> 20.49: the tile stays"),
     "granite": (4, "lfm2's shape at one sequence: 6.10 -> 4.84 (2: 5.06)"),
     "laguna_full": (3, "19.33 -> 15.54 (2: 16.60, 6: 15.86)"),
-    "laguna_windowed": (1, "three parts read 8.13 -> 7.51, twelve bodies over its four branches, +0.8 s of a "
-                           "start; nine parts of 0.52 MB 8.53, SLOWER than none: the floor"),
+    "laguna_windowed": (3, "PR 77, one key window of 768 rows a query tile, ONE branch: 8.12 -> 3.71 (1: 3.92, 9 parts "
+                           "of 0.79 MB 3.65: under the floor; the band's tiles at three parts 7.50)"),
     "nemotron3": (4, "25.25 -> 20.32 (2: 20.69, 8: 20.68, 16: 20.69)"),
     "keye": (8, "84.93 -> 65.61 (2: 75.38, 4: 68.32); in 128 x 2,176 tiles 85.65 -> 64.61: the tile stays"),
     "minicpm_sala": (8, "79.09 -> 66.51 (2: 77.13, 4: 69.40; 16: 62.91, past the bodies a start pays for)"),
     "phi4flash_full": (2, "7.61 -> 6.12"),
-    "phi4flash_windowed": (1, "two parts of 0.52 MB read 3.02 -> 2.86: under the floor laguna's nine set"),
+    "phi4flash_windowed": (1, "PR 77, the key window: 3.02 -> 1.67 (two parts of 0.79 MB 1.62: under the floor)"),
 }
 
 
@@ -161,7 +162,7 @@ def test_the_rule_gives_every_served_shape_its_parts_a_step(name):
     if rep == 1:
         assert parts == 1
         return
-    bodies = 1 if masked else 2 if window is None else 4  # the body's branches
+    bodies = 2 if window is None and not masked else 1  # the body's branches (under a window ONE: PR 77)
     assert rep % parts == 0 and parts * bodies <= sa.PART_BODIES or parts == 1
     # the MOST parts that fill the floor within the bodies a start pays for
     finer = [cut for cut in range(parts + 1, rep + 1) if rep % cut == 0]
@@ -260,7 +261,7 @@ def test_parts_that_do_not_divide_a_group_fall_to_the_next_smaller_and_heads_alo
     assert sa.parts_a_step(4, 32, 32, want=16) == 4
     assert sa.parts_a_step(1, 512, 2176, masked=True) == sa.parts_a_step(1, 512, 2176, want=2) == 1  # blocks, not parts
     assert sa.parts_a_step(8, 1088, 1088) == 4 and sa.parts_a_step(8, 1088, 1088, masked=True) == 8  # the branches
-    assert sa.parts_a_step(8, 1088, 1088, window=512) == 2 and sa.parts_a_step(9, 1088, 1088, window=512) == 1
+    assert sa.parts_a_step(8, 1088, 1088, window=512) == 8 and sa.parts_a_step(9, 1088, 1088, window=512) == 3
     assert sa.parts_a_step(2, 1088, 1088, DS) == sa.parts_a_step(2, 32, 32, DS, want=2) == 1  # latent attention's
 
 
@@ -355,12 +356,13 @@ def test_the_part_tiles_reach_the_pipeline_s_counters_and_a_step_that_cuts_nothi
     for _ in range(3):
         decoder.fold_step_stats(metrics, stats)
     snap = metrics.snapshot()
-    # a key head's visits a layer: 3 pairs of 32 x 32 tiles, 14 of the window's 8 x 16 (`causal_tiles`)
-    full, band = (2 * 2 * len(sa._band_tiles(64, *sa.causal_tiles(64, rep, 32, 32, window), window))
-                  for rep, window in ((2, None), (3, 16)))
-    assert (full, band) == (2 * 2 * 3, 2 * 2 * 14)
+    # a key head's visits a layer: 3 pairs of 32 x 32 tiles; under the window ONE step a query tile
+    # of 8 (`causal_tiles`: 8 rows against a key window of 24)
+    assert sa.causal_tiles(64, 3, 32, 32, 16) == (8, 24)
+    full, band = 2 * 2 * len(sa._band_tiles(64, *sa.causal_tiles(64, 2, 32, 32))), 2 * 2 * (64 // 8)
+    assert (full, band) == (2 * 2 * 3, 2 * 2 * 8)
     assert snap["attn_head_tiles_total"] == snap["attn_grid_steps_total"] == 3 * 2 * (full + band)
-    # (two parts a step of a full layer; three heads under the window's four branches have no half)
-    assert snap["attn_part_tiles_total"] == 3 * 2 * (2 * full + band)
+    # (two parts a step of a full layer, three of a windowed one: its body is ONE branch)
+    assert snap["attn_part_tiles_total"] == 3 * 2 * (2 * full + 3 * band)
     assert snap["trunk_rows_run_total"] == snap["trunk_rows_full_total"] == snap["loop_passes_total"] == 0
     assert snap["decoder_tokens_total"] == 3 * 2 * 64

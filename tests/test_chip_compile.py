@@ -128,7 +128,15 @@ PINNED_STEPS = {
     # a fourth scratch, q as ONE token-major block `[B, 1, S, H*128]`; the six others were hashed
     # before and after and did not move: the rule is a branch taken in Python, `angles is None`,
     # heads of 64 and a selection on its other side, and the latent cells' path is not touched)
-    "laguna_s21_prefill_epix10k2m": "bf2e9f3b3e8d3f54776ab762bae36e60139cc66bbf43473adae59659828f158b",
+    # (laguna's ALONE re-pinned in PR 77, knowingly: its six windowed calls take ONE grid step a query
+    # tile — a table of 34 (query tile, key window's first tile) pairs as scalar prefetch where 66
+    # (query tile, key tile) pairs stood, the grid `(2, 8, 34)` — and the step's statistics vector
+    # ends in other constants (`attn_grid_steps_total` 6 x 544 where 6 x 1,056 stood of the windowed
+    # layers, `attn_part_tiles_total` three a step of theirs); the key window's Element-addressed
+    # blocks and the one-pass body ride in the blanked `backend_config`. The nine others were hashed
+    # before and after and did NOT move: none makes a windowed call, and the maskless and masked
+    # forms trace the body they traced)
+    "laguna_s21_prefill_epix10k2m": "97201b147e7661f7a4769482abb8fb66c6e313cbe1a02fe95dbc28db7c3cde04",
     # pinned in PR 57, which brought it: the six above were hashed on PR 56's tree first and none
     # moved, though every one of them now traces `_projections`, `embed`, `logits_of` and `trunk`
     # through the new fields' branches (taken in Python, before anything is traced)
@@ -439,8 +447,9 @@ def test_the_laguna_step_compiles_with_its_kernels_where_the_roofline_functions_
     nothing else (weights 11.4 GB), and its Mosaic kernels are the ones the
     cell's roofline metrics read by name: three ``masked_gqa_attention`` (the
     full layers, 6 query heads of 128 a group, under ``sparse_attn``), six
-    ``windowed_gqa_attention`` (the same body over the band's tiles, 9 a
-    group, under ``window_attn``), the grouped products under ``moe`` —
+    ``windowed_gqa_attention`` (the same body in ONE step a query tile against
+    a key window that follows the diagonal, 9 a group, under ``window_attn``),
+    the grouped products under ``moe`` —
     twenty-four in the pass ahead of the held rows' loop (``call_sites``,
     named ``gmm``) and the loop's own twenty-four (named after the jit the
     loop stands in) — and the pass's way back at TEN slots a token

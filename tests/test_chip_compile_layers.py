@@ -158,9 +158,11 @@ def test_one_differential_windowed_layer_compiles_at_the_published_sizes_as_two_
         one_chip, monkeypatch):
     """Phi-4-mini-flash's windowed layer at 2 x 8,704 tokens: 20 head pairs
     over 10 key pairs of 2 x 64, two calls of the batched kernel at ``d`` 64,
-    ``dv`` 128, two query half-heads a key half-head, in 256 x 512 tiles under
-    the window of 512 — shapes no other cell compiles — and nothing else of
-    Mosaic's in the layer."""
+    ``dv`` 128, two query half-heads a key half-head, a query tile of 256 a
+    grid step against ONE key window of 768 rows that follows the diagonal
+    (PR 77; head-major keys of 64, Element-addressed) under the window of 512
+    — shapes no other cell compiles — and nothing else of Mosaic's in the
+    layer."""
     import json
 
     from psana_ray_tpu.models import decoder
@@ -170,7 +172,7 @@ def test_one_differential_windowed_layer_compiles_at_the_published_sizes_as_two_
     with open(os.path.join(REPO, "benchmark", "configs", "phi4_mini_flash_prefill_epix10k2m.json")) as f:
         cfg = decoder.DecoderConfig.from_mapping(json.load(f))
     assert cfg.layer_types[1] == decoder.SLIDING and cfg.sliding_window == 512
-    assert sa.causal_tiles(8704, 2, cfg.causal_q_tile, cfg.causal_kv_tile, 512) == (256, 512)
+    assert sa.causal_tiles(8704, 2, cfg.causal_q_tile, cfg.causal_kv_tile, 512) == (256, 768)
     shapes = jax.eval_shape(lambda k: decoder.init_params(cfg, k), jax.random.key(0))["layers"][1]
     layer = {k: v for k, v in shapes.items() if not k.startswith(("w_gate", "w_up", "w_down", "norm2"))}
     args = jax.tree.map(lambda a: S(a.shape, a.dtype, sharding=one_chip),
@@ -346,19 +348,21 @@ def test_laguna_s_grouped_heads_reach_the_kernel_where_their_products_wrote_them
 
 
 @pytest.mark.parametrize("kind,heads,window,parts,products", [
-    ("full", 48, None, 3, 2 * 3 * 2), ("windowed", 72, 512, 1, 4 * 1 * 2)])
+    ("full", 48, None, 3, 2 * 3 * 2), ("windowed", 72, 512, 3, 1 * 3 * 2)])
 def test_laguna_s_stacked_calls_compile_with_their_rows_in_parts(kind, heads, window, parts, products,
                                                                    one_chip, monkeypatch):
     """Laguna's two calls ALONE at the published sizes (2 x 8,704 tokens, 8
     key heads of 128, q and k float32 for the kernel to turn, the gate a
     head), as the step makes them since PR 75: a grid step's stacked group
     cut into ``parts`` runs of whole heads (``parts_a_step``: three parts of
-    TWO heads at the full layers' 512 x 1,088; the windowed ones' nine heads
-    at 256 x 512 stay ONE product, their four branches leave room for two
-    parts and nine has no half), the body's products two a part and branch
-    (two branches, four under the window), the scratch what it was (``m``,
-    ``l``, ``acc`` and the turned query tile, stacked: a part is a slice of
-    each), and Mosaic takes the written order within ``_VMEM_LIMIT``. Compile
+    TWO heads at the full layers' 512 x 1,088; since PR 77 three parts of
+    THREE at the windowed ones' 256 rows against ONE key window of 768, whose
+    body is ONE branch where the band's tiles held four), the body's
+    products two a part and branch (two branches: below the diagonal and on
+    it; under the window one, the whole key window's compare), the
+    scratch what it was (``m``, ``l``, ``acc`` and the turned query tile,
+    stacked: a part is a slice of each; one softmax pass writes no ``m``),
+    and Mosaic takes the written order within ``_VMEM_LIMIT``. Compile
     seconds for the described v5e here, the parent's one stacked product ->
     three parts, lowering included (PR 75): full 8.8 -> 6.3; on the chip,
     first call, 6.5 -> 7.0 (the windowed call at three parts 4.2 -> 5.8)."""
@@ -367,7 +371,7 @@ def test_laguna_s_stacked_calls_compile_with_their_rows_in_parts(kind, heads, wi
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     b, s, g, d = 2, 8704, 8, 128
     bq, bk = sa.causal_tiles(s, heads // g, 1088, 1088, window, d)
-    assert (bq, bk) == ((256, 512) if window else (512, 1088))
+    assert (bq, bk) == ((256, 768) if window else (512, 1088))
     assert sa.parts_a_step(heads // g, bq, bk, window=window) == parts
 
     def fn(q, k, v, cos, sin, gate):

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from benchmark.reference import laguna_decoder as ref
 from decoder_kit import Kit, embedded, inputs, loud, rehearse, share_of
@@ -87,7 +88,7 @@ def band_softmax(q, k, v, g, window):
     t = jnp.arange(s)
     open_ = (t[None, :] <= t[:, None]) & (t[None, :] > t[:, None] - window)
     prob = jax.nn.softmax(jnp.where(open_, score, -jnp.inf), axis=-1)
-    return jnp.einsum("bgrst,btgd->bsgrd", prob, v.reshape(b, s, g, d)).reshape(b, s, hd)
+    return jnp.einsum("bgrst,btgd->bsgrd", prob, v.reshape(b, s, g, -1)).reshape(b, s, -1)
 
 
 def qkv(seed, b, s, g, rep, d):
@@ -96,23 +97,39 @@ def qkv(seed, b, s, g, rep, d):
             for heads in (g * rep, g, g)]
 
 
-# (S, window, query tile, key tile): the window under a tile, a tile, over a tile, over S, of one
-# key; S no multiple of the window; a query tile wider than the key tile and the reverse
-BANDS = {"window_under_the_tile": (64, 5, 16, 16), "window_is_the_tile": (64, 16, 16, 16),
-         "window_over_the_tile": (64, 24, 16, 16), "window_over_the_sequence": (48, 100, 16, 16),
-         "the_query_s_own_key_alone": (48, 1, 16, 16), "sequence_no_multiple_of_the_window": (80, 24, 16, 16),
-         "wide_query_tile": (64, 20, 32, 8), "wide_key_tile": (64, 20, 8, 32),
-         "one_tile": (48, 7, 48, 48)}
+# (S, window, query tile, the asked key tile — not a windowed call's to take —, the key window's
+# rows): the window under a tile, a tile, over a tile, over S, S itself, of one key; S no multiple
+# of the window; a window that is two query tiles, two and a half (its lower edge crosses TWO
+# tiles), two and one key (the lower edge at the first tile's diagonal); a query tile that holds
+# the window, and the whole sequence. Wherever the sequence is longer than the key window its
+# first query tiles start at key 0 and close what lies past their diagonal
+BANDS = {"window_under_the_tile": (64, 5, 16, 16, 32), "window_is_the_tile": (64, 16, 16, 16, 32),
+         "window_over_the_tile": (64, 24, 16, 16, 48), "window_over_the_sequence": (48, 100, 16, 16, 48),
+         "window_is_the_sequence": (48, 48, 16, 16, 48),
+         "the_query_s_own_key_alone": (48, 1, 16, 16, 16),
+         "sequence_no_multiple_of_the_window": (80, 24, 16, 16, 48),
+         "window_of_two_query_tiles": (64, 16, 8, 32, 24), "window_of_two_and_a_half": (64, 20, 8, 32, 32),
+         "window_of_two_and_a_key": (64, 17, 8, 32, 24),
+         "wide_query_tile": (64, 20, 32, 8, 64), "one_tile": (48, 7, 48, 48, 48)}
+
+
+def _pallas_call(fn, *args):
+    (call,) = (e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns if e.primitive.name == "pallas_call")
+    return call.params
 
 
 @pytest.mark.parametrize("case", sorted(BANDS))
 @pytest.mark.parametrize("batch", [1, 2])
 def test_the_windowed_kernel_is_a_dense_softmax_under_the_band(case, batch):
-    s, window, bq, bk = BANDS[case]
+    s, window, bq, bk, keys = BANDS[case]
     g, rep, d = 2, 3, 16
     q, k, v = qkv(len(case), batch, s, g, rep, d)
     got = sa._causal_attention(q, k, v, g, bq, bk, True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(band_softmax(q, k, v, g, window)), atol=2e-5)
+    # ONE grid step a query tile, against ONE window of keys that follows the diagonal
+    call = _pallas_call(lambda q, k, v: sa._causal_attention(q, k, v, g, bq, bk, True, window=window), q, k, v)
+    assert call["grid_mapping"].grid == (batch, g, s // bq) and sa.band_keys(s, bq, window) == keys
+    assert [m.block_aval.shape for m in call["grid_mapping"].block_mappings[1:3]] == [(keys, d)] * 2
     # and it is another result than full causal attention wherever the window binds
     full = sa._causal_attention(q, k, v, g, bq, bk, True)
     assert (float(jnp.abs(got - full).max()) > 1e-3) == (window < s)
@@ -120,6 +137,104 @@ def test_the_windowed_kernel_is_a_dense_softmax_under_the_band(case, batch):
     if batch == 2:
         alone = sa._causal_attention(q[1:], k[1:], v[1:], g, bq, bk, True, window=window)
         np.testing.assert_array_equal(np.asarray(got[1:]), np.asarray(alone))
+
+
+# the two forms the cells serve, small: (key heads, heads a group, head, values' width, S, query tile,
+# window): laguna's nine heads of 128 a group, token-major, turned and gated by the kernel (three
+# parts of three heads wherever the floor lets them: here `cut` asks); phi4flash's two half-heads
+# of 64 over values of 128, head-major, no rotary and no gate
+FORMS = {"laguna_nine_turned_and_gated": (2, 9, 128, 128, 96, 16, 32),
+         "laguna_window_no_whole_tiles": (2, 9, 128, 128, 96, 16, 40),
+         "phi4flash_half_heads_of_64": (3, 2, 64, 128, 64, 8, 16),
+         "phi4flash_sequence_under_the_window": (3, 2, 64, 128, 32, 8, 48)}
+
+
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_a_served_form_s_windowed_call_is_the_band_in_one_pass_and_its_parts_are_its_rows(form):
+    """The windowed call in the cells' two forms against the plain float32
+    band (``t - window < j <= t``): the outputs are held to the reference, not
+    to the parent's bits (one softmax pass: no ``alpha``, one rounding chain
+    shorter); a stacked group's rows in PARTS are the rows of the one product,
+    to the bit; and ``causal_steps``' three counts are the grid the call is
+    lowered with and the score products its body writes."""
+    g, rep, d, dv, s, bq, window = FORMS[form]
+    b, turned = 2, form.startswith("laguna")
+    rng = np.random.default_rng(len(form))
+    q, k = (jnp.asarray(np.round(rng.standard_normal((b, s, heads * d)) * 16) / 16, jnp.float32)
+            for heads in (g * rep, g))
+    v = jnp.asarray(rng.standard_normal((b, s, g * dv)), jnp.bfloat16)
+    extra, scale = {}, d ** -0.5
+    if turned:  # q and k float32 and unturned, the two tables, a gate a (token, head)
+        angles = jnp.tile(decoder.rotary_angles(np.arange(s), 10000.0, d // 2), (b, 1))
+        tables = tuple(jnp.round(t * 256) / 256 for t in decoder.turn_tables(angles, d))
+        gate = jax.nn.sigmoid(jnp.asarray(rng.standard_normal((b, s, g * rep)), jnp.float32))
+        extra = dict(turn=tables, turn_width=d, q_scale=scale, out_gate=gate)
+
+        def turn(x, heads, by=1.0):  # `_turned_head`'s arithmetic, rounded once as the kernel rounds
+            x = x.reshape(b * s, heads, d)
+            cos, sin = (t[:, None, :] for t in tables)
+            return ((x * cos + jnp.roll(x, d // 2, -1) * sin) * by).astype(jnp.bfloat16).reshape(b, s, -1)
+
+        want = band_softmax(turn(q, g * rep, scale), turn(k, g), v, g, window)
+        want = (want.astype(jnp.bfloat16).astype(jnp.float32).reshape(b, s, g * rep, dv)
+                * gate[..., None]).reshape(b, s, -1)
+    else:
+        q, k = (q * scale).astype(jnp.bfloat16), k.astype(jnp.bfloat16)
+        want = band_softmax(q, k, v, g, window)
+
+    def attend(cut):
+        return lambda q, k, v: sa._causal_attention(q, k, v, g, bq, bq, True, window=window, cut=cut, **extra)
+
+    got = attend(1)(q, k, v)
+    assert got.dtype == jnp.bfloat16 and got.shape == (b, s, g * rep * dv)
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want), atol=2e-2, rtol=2e-2)
+    assert float(jnp.abs(got.astype(jnp.float32) - want).mean()) < 2e-3
+    parts = 3 if rep == 9 else 2
+    np.testing.assert_array_equal(np.asarray(attend(parts)(q, k, v), np.float32), np.asarray(got, np.float32))
+    # the lowered call: ONE grid step a query tile, a body of ONE branch, two products a part
+    for cut in (1, parts):
+        call = _pallas_call(attend(cut), q, k, v)
+        assert call["grid_mapping"].grid == (b, g, s // bq)
+        text = str(call["jaxpr"])
+        assert text.count("dot_general") == 2 * cut and "cond[" not in text and "exp " in text
+        assert text.count("exp ") == cut  # one pass: a part's exponentials, and no alpha
+
+
+@pytest.mark.parametrize("cell", ["laguna", "phi4flash"])
+def test_causal_steps_counts_the_grid_a_cell_s_windowed_call_is_lowered_with(cell):
+    """At the published sizes (2 x 8,704 tokens, traced and not run):
+    ``causal_steps`` from what the call is given, against the ``pallas_call``
+    the call lowers to — laguna's nine turned heads a group: 2 x 8 x 34 = 544
+    grid steps where the band's tiles took 1,056, three parts a step;
+    phi4flash's two half-heads: 2 x 10 x 34, one part (0.79 MB a part of
+    two: under the floor)."""
+    b, s, window = 2, 8704, 512
+    g, rep, d, dv = (8, 9, 128, 128) if cell == "laguna" else (10, 2, 64, 128)
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    S = jax.ShapeDtypeStruct
+    operands = [S((b, s, g * rep * d), f32 if cell == "laguna" else bf16),
+                S((b, s, g * d), f32 if cell == "laguna" else bf16), S((b, s, g * dv), bf16)]
+    if cell == "laguna":  # the two tables and the gate a (token, head)
+        operands += [S((b * s, d), f32), S((b * s, d), f32), S((b, s, g * rep), f32)]
+
+    def fn(q, k, v, *turn_and_gate):
+        extra = {}
+        if turn_and_gate:
+            extra = dict(turn=turn_and_gate[:2], turn_width=d, q_scale=d ** -0.5, out_gate=turn_and_gate[2])
+        return sa.windowed_gqa_attention(q, k, v, window=window, num_kv_heads=g, block_q=1088, block_k=1088,
+                                         interpret=False, **extra)
+
+    call = _pallas_call(fn, *operands)
+    tiles, steps, parts = sa.causal_steps(b, s, g, rep, d, dv, block_q=1088, block_k=1088, window=window,
+                                          turned=cell == "laguna")
+    grid = call["grid_mapping"].grid
+    assert grid == (b, g, s // 256) and int(np.prod(grid)) == steps == tiles == {"laguna": 544, "phi4flash": 680}[cell]
+    assert parts == steps * {"laguna": 3, "phi4flash": 1}[cell]
+    # the key window: 768 rows where two tiles of 512 met 1,024, Element-addressed (no whole blocks)
+    keys = call["grid_mapping"].block_mappings[1]
+    assert keys.block_aval.shape == (768, d) and pl.Element(768) in keys.block_shape
+    assert str(call["jaxpr"]).count("dot_general") == 2 * parts // steps  # ONE branch, two products a part
+    assert call["name"] == "windowed_gqa_attention"
 
 
 def _transposes(fn, *args):
@@ -146,7 +261,7 @@ def test_grouped_heads_of_whole_lane_blocks_are_read_where_their_products_wrote_
     gate = jax.nn.sigmoid(qkv(rep, b, s, g, rep, 1)[0])  # [B, S, H]
 
     def attend(q, k, v, **gated):
-        if kind == "windowed":  # the band crosses the 16-wide tiles: all four variants of the body
+        if kind == "windowed":  # 16 rows against a key window of 48, the sequence's first tiles at key 0
             return sa.windowed_gqa_attention(q, k, v, window=window, num_kv_heads=g, block_q=16,
                                              block_k=16, **gated)
         return sa.masked_gqa_attention(q, k, v, num_kv_heads=g, block_q=16, block_k=16, **gated)
@@ -327,16 +442,20 @@ def test_the_tiles_follow_from_the_group_s_rows_and_the_window():
     # six heads of 128 a group: the stacked score tile within its bytes
     bq, bk = sa.causal_tiles(8704, 6, 1088, 1088)
     assert 6 * bq * bk * 4 <= sa.SCORE_TILE_BYTES < 6 * 1088 * 1088 * 4 and 8704 % bq == 0
-    # nine under a window of 512: no tile wider than its share of the window
-    assert sa.causal_tiles(8704, 9, 1088, 1088, 512) == (256, 512) and sa.BAND_TILES == (0.5, 1.0)
-    assert sa.causal_tiles(64, 3, 32, 32, 16) == (8, 16) and sa.causal_tiles(64, 3, 8, 8, 16) == (8, 8)
-    assert sa.causal_tiles(8704, 9, 1088, 1088, 10 ** 6) == (512, 1088)  # the score tile's bytes bind
+    # nine under a window of 512: a query tile of its share of the window, and as the "key tile" the
+    # rows of the ONE key window a query tile is run against (`band_keys`: the window and the tile)
+    assert sa.causal_tiles(8704, 9, 1088, 1088, 512) == (256, 768) and sa.BAND_QUERY_TILE == 0.5
+    assert not hasattr(sa, "BAND_TILES")  # (the key extent is no share of the window: it IS window + bq)
+    assert sa.causal_tiles(64, 3, 32, 32, 16) == (8, 24) == sa.causal_tiles(64, 3, 8, 8, 16)
+    assert sa.causal_tiles(8704, 2, 1088, 1088, 512) == (256, 768)  # phi4flash's two half-heads a group
+    assert [sa.band_keys(8704, bq, 512) for bq in (128, 256, 512)] == [640, 768, 1024]
+    assert sa.band_keys(8704, 128, 300) == 512 and sa.band_keys(64, 16, 100) == 64  # ceil(299 / 128) + 1 tiles
     # where the kernel turns heads of 128 the float32 query block, the stacked scratch and the
     # tables' rows are counted in the same bytes: the served tiles stand (laguna's two, ouro's)
     assert sa.causal_tiles(8704, 6, 1088, 1088, None, 128) == (512, 1088)
-    assert sa.causal_tiles(8704, 9, 1088, 1088, 512, 128) == (256, 512)
+    assert sa.causal_tiles(8704, 9, 1088, 1088, 512, 128) == (256, 768)
     assert sa.causal_tiles(2304, 1, 768, 768, None, 128) == (768, 768)
-    assert sa.causal_tiles(8704, 9, 1088, 1088, 10 ** 6, 128) == (256, 1088)  # and there they bind
+    assert sa.causal_tiles(8704, 9, 1088, 1088, None, 128) == (256, 1088)  # and there they bind
 
 
 # ---------------------------------------------------------------------------

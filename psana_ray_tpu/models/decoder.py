@@ -115,6 +115,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from psana_ray_tpu.ops import lightning
+from psana_ray_tpu.ops import hyper_connection as hc
 from psana_ray_tpu.ops.delta_rule import (CHUNK, HEAD_CHUNK, chunk_rows, gated_delta_net,
                                           gated_delta_rule, lanes_a_head)
 from psana_ray_tpu.ops.selective_scan import LANES as SCAN_LANES, scan_tiles, selective_scan
@@ -189,6 +190,15 @@ PART_STATS = (
     "attn_part_tiles_total",     # (part, query tile, key tile) score products the step's causal calls
                                  # write: over `attn_grid_steps_total`, the parts a grid step
 )
+# and two after those twenty (the groups above 0 where the step has none), ONLY from a step whose stream
+# between the layers is SEVERAL rows a token (`DecoderConfig.hc_mult`: hyper-connections). The second is
+# a MAXIMUM, over the step's tokens and branches and, folded, over the steps (`MAX_STATS`)
+HYPER_STATS = (
+    "hyper_mixes_total",         # (branch, token) pairs mixed in and out: 2 * layers * B * S
+    "hyper_sum_defect_max",      # the largest distance of a row or column sum of any H_res from 1: what
+                                 # says that the doubly stochastic constraint HELD on the served tokens
+)
+MAX_STATS = ("hyper_sum_defect_max",)  # folded by maximum, not summed
 # the places the LAYERS count at most: the first four of STEP_STATS and the four groups a layer has
 LAYER_GROUPS = 4 + len(SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS)
 # layer_types, as config.json spells them
@@ -347,6 +357,15 @@ class DecoderConfig:
     # and/or one AFTER it (`sandwich`'s second: x + rms(Op(.))). Both: the sandwich; the second alone
     # (pre_norm False): OLMo 2's REORDERED norm, x + rms(Op(x)), where nothing norms a branch's input
     pre_norm: bool = True
+    # HYPER-CONNECTIONS, manifold-constrained (0: the plain residual, one row a token): the stream is
+    # hc_mult rows of hidden_size a token, [T, hc_mult * D]; a branch reads a MIX of them and its output
+    # goes onto ANOTHER mix (`ops/hyper_connection.py`: the mixing numbers a token and branch from the
+    # stream's own wide norm, the stream-to-stream matrix projected onto the doubly stochastic ones by
+    # hc_iters Sinkhorn steps under sums guarded by hc_eps, its logits clamped to hc_clamp first)
+    hc_mult: int = 0
+    hc_iters: int = 0
+    hc_eps: float = 0.0
+    hc_clamp: Tuple[float, float] = (-30.0, 30.0)
     rope_yarn: Optional[Yarn] = None  # YaRN-blended rotary frequencies and the softmax's mscale^2
     # latent attention (kv_lora_rank 0: grouped-query attention): queries through a normed
     # q_lora_rank (0: of full rank), keys and values from ONE normed kv_lora_rank latent; a head's query and key
@@ -449,7 +468,12 @@ class DecoderConfig:
         products' would be the step's error (Granite-4.0-H's 40 layers read
         2.6-5.7 times what rounding every operand to bf16 costs; float32,
         1.0-1.5). Every product's operands stay the weights' type; the stream
-        is 71 MB at 8,704 x 2,048, 1% of a layer's traffic."""
+        is 71 MB at 8,704 x 2,048, 1% of a layer's traffic. Under
+        hyper-connections (``hc_mult``) the streams stay the weights' type: a
+        doubly stochastic mix keeps their size and a branch comes in at order
+        1 (``H_post`` in (0, 2)), so a rounding a branch is a plain bf16
+        model's (PERF.md section 4 has the chip's reading), and a float32
+        stream would be 1 GB, twice over, at 17,408 x 14,336."""
         return jnp.float32 if self.residual_multiplier != 1.0 else None
 
     @property
@@ -547,7 +571,7 @@ class DecoderConfig:
     def from_mapping(cls, m: Mapping) -> "DecoderConfig":
         """From the keys of a Hugging Face ``config.json`` (as the
         benchmark's configuration file repeats them), plus ``patch``,
-        ``experts_held`` and ``tie_embedding``. Twelve spellings are read:
+        ``experts_held`` and ``tie_embedding``. Thirteen spellings are read:
         Keye-VL-2.0's (``head_dim``, ``rms_norm_eps``,
         ``rope_scaling.mrope_section``, ``sa_config``); LFM2's
         (``norm_eps``, ``layer_types``, ``num_dense_layers``,
@@ -655,7 +679,12 @@ class DecoderConfig:
         mapping's ``mamba_d_state``, ``mamba_d_conv``, ``mamba_expand``,
         ``mamba_dt_rank``; and, as its module has them and its file has no
         key for, differential attention, a bias on the attention's products
-        and on the convolution, none in the MLP or the head). Where a
+        and on the convolution, none in the MLP or the head); and
+        Xing4.0's (``xing4_0``: DeepSeek-V3's keys beside ``hc_mult``,
+        ``hc_sinkhorn_iters``, ``hc_eps``, ``mhc_h_res_clamp_min`` / ``_max``:
+        the residual path is ``hc_mult`` streams under manifold-constrained
+        hyper-connections; refused without the latent keys, beside an indexer
+        or an output gate, or at a count of streams outside 2 to 8). Where a
         file's ``n_routed_experts`` counts the experts HELD (a chip's
         share), ``router_experts`` gives the width the router keeps."""
         sa_cfg = m.get("sa_config")
@@ -786,6 +815,20 @@ class DecoderConfig:
         latent = int(m.get("kv_lora_rank") or 0)
         if latent and m.get("position_embedding_type") == "nope":
             raise ValueError("latent attention without a rotary is not built")
+        hyper = {}
+        if "hc_mult" in m:  # xing4_0's: DeepSeek-V3's block under manifold-constrained hyper-connections
+            if not latent or index or m.get("gated_attention_proj_granularity_type"):
+                raise ValueError("hc_mult without the latent-attention keys (kv_lora_rank and the head's "
+                                 "parts), or beside an indexer or an output gate, is not built: the "
+                                 "streams' mixes stand around DeepSeek-V3's two branches alone")
+            if not 2 <= int(m["hc_mult"]) <= hc.GROUP:
+                # ONE stream is no plain residual either: its branch would read sigmoid(h) x and be
+                # added at 2 sigmoid(h), a model no file describes
+                raise ValueError(f"hc_mult {m['hc_mult']} is not built: 2 to {hc.GROUP} streams are")
+            hyper = dict(hc_mult=int(m["hc_mult"]), hc_iters=int(m["hc_sinkhorn_iters"]),
+                         hc_eps=float(m["hc_eps"]),
+                         hc_clamp=(float(m.get("mhc_h_res_clamp_min", -30.0)),
+                                   float(m.get("mhc_h_res_clamp_max", 30.0))))
         head_dim = int(m.get("head_dim") or int(m["hidden_size"]) // heads)
         by_type = m.get("rope_parameters")  # Laguna's: a rotary a layer type
         flat = None
@@ -883,7 +926,7 @@ class DecoderConfig:
                                 if linear and not delta_net and mixers is None else 0.0),
             **net,
             tie_embedding=bool(m.get("tie_embedding", m.get("tie_word_embeddings", False))),
-            **loop,
+            **loop, **hyper,
             rope_yarn=yarn,
             q_lora_rank=int(m.get("q_lora_rank") or 0) if latent else 0, kv_lora_rank=latent,
             qk_nope_head_dim=nope if latent else 0, qk_rope_head_dim=rope_dim if latent else 0,
@@ -928,7 +971,7 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
     # a latent layer with an indexer draws 17 matrices: 20 keys a layer there, and where the
     # count was 16 it stays 16 (the same seed, the same weights)
     keys = iter(jax.random.split(
-        key, (20 if cfg.selects_over_latent else 16) * cfg.num_layers + 8))
+        key, (24 if cfg.hc_mult else 20 if cfg.selects_over_latent else 16) * cfg.num_layers + 8))
 
     def w(*shape, dtype=dtype, scale=0.02):
         return (scale * jax.random.normal(next(keys), shape, jnp.float32)).astype(dtype)
@@ -1056,6 +1099,16 @@ def init_params(cfg: DecoderConfig, key, dtype=jnp.bfloat16) -> dict:
                 p["w_attn_gate"] = w(d, heads * hd if cfg.attn_gate == "elementwise" else heads)
             if cfg.indexer_heads:
                 p.update(_index_params(cfg, w, gain, d))
+        if cfg.hc_mult:
+            # a branch's phi [n D, n (n + 2)] = [pre | post | res], its three scalars and its bias. The
+            # publication starts alpha small (every H its bias); here alpha is of order 1/2 and the
+            # biases normal(0, 1), so that the products x~ phi (a deviation of 0.02 sqrt(n D)) MOVE the
+            # mixing numbers and a fault in them shows (a trained model's are whatever training left)
+            n = cfg.hc_mult
+            for which in ("hc1", "hc2"):  # the operator's, the feed-forward's
+                p.update({which + "_phi": w(n * d, n * (n + 2)),
+                          which + "_alpha": between(0.25, 0.75, 3),
+                          which + "_b": w(n * (n + 2), dtype=jnp.float32, scale=1.0)})
         if not cfg.pre_norm:  # nothing norms a branch's input: only what a block has is drawn
             p.pop("norm1", None)
         if cfg.sandwich:  # each branch's second norm
@@ -1396,9 +1449,12 @@ def gated(x, o, gate, wo):
     return x + _mm(o.astype(x.dtype).reshape(x.shape[0], -1), wo).astype(x.dtype)
 
 
-def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=None):
+def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=None,
+                     onto: bool = True):
     """DeepSeek-V3's operator on ``x [B*S, D]`` -> ``(x + Op, live,
-    causal)``, the last two the layer's statistics tiles, prefill in
+    causal)`` (``onto`` False: ``Op`` alone, the BRANCH's output, where what a
+    branch reads is not what it is added onto: ``cfg.hc_mult``), the last
+    two the layer's statistics tiles, prefill in
     the DECOMPRESSED form: every head has its own keys and values (``2 *
     (head_dim + v_head_dim)`` FLOPs a causal pair and head; the absorbed
     form, scores against the latent itself, is decode's), and a score is
@@ -1451,7 +1507,9 @@ def latent_attention(p, x, angles, batch: int, cfg: DecoderConfig, idx_angles=No
                    block_q=cfg.causal_q_tile, block_k=cfg.causal_kv_tile, q_shared=rows(q_rope),
                    k_shared=rows(k_rope), shared_turn=tables, shared_scale=scale * turned)
     with jax.named_scope("proj"):
-        if cfg.attn_gate:  # each head's output under its own scalar, then W_o
+        if not onto:  # (from_mapping: no output gate under hyper-connections)
+            x = jax.jit(lambda o, wo: _mm(o, wo).astype(o.dtype))(o.reshape(x.shape[0], -1), p["wo"])
+        elif cfg.attn_gate:  # each head's output under its own scalar, then W_o
             x = jax.jit(gated)(x, o, read[-1], p["wo"])
         else:
             x = jax.jit(lambda x, o, wo: x + _mm(o, wo).astype(x.dtype))(
@@ -2005,6 +2063,22 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
     handing = handed is not None
     handed = dict(handed or {})
     live, causal = 0, 0
+    # under hyper-connections x is the STREAMS [T, n*D]: a branch reads a mix of them (`mixed_in`: what
+    # it reads, and the mixing numbers for the way back) and hands back its OUTPUT alone, which
+    # `mixed_out` lays onto another mix
+    plain = not cfg.hc_mult
+
+    def mixed_in(x, which):
+        with jax.named_scope("hyper_in"):
+            return hc.hyper_in(x, p[which + "_phi"], p[which + "_alpha"], p[which + "_b"],
+                               streams=cfg.hc_mult, iters=cfg.hc_iters, eps=cfg.hc_eps,
+                               norm_eps=cfg.rms_eps, clamp=cfg.hc_clamp)
+
+    def mixed_out(x, y, mix):
+        with jax.named_scope("hyper_out"):
+            handed["defect"] = jnp.maximum(handed.get("defect", 0.0), hc.sum_defect(mix, cfg.hc_mult))
+            return hc.hyper_out(x, y, mix, streams=cfg.hc_mult)
+
     if cfg.sandwich and (op not in (ATTENTION, LINEAR) or experts or cfg.attn_gate
                          or cfg.indexer_heads or cfg.residual_multiplier != 1.0):
         raise ValueError("a norm AFTER a branch (sandwich; with none before it, the reordered norm) "
@@ -2036,27 +2110,38 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
             s = x.shape[0] // batch
             causal = batch * sa.causal_tile_count(s)
             live = batch * sa.band_tile_count(s, cfg.sliding_window) if op == SLIDING else causal
+    elif op == LATENT and not plain:
+        u, mix = mixed_in(x, "hc1")
+        y, live, causal = latent_attention(p, u, angles, batch, cfg, onto=False)
+        x = mixed_out(x, y, mix)
     elif op == LATENT:
         x, live, causal = latent_attention(p, x, angles, batch, cfg, idx_angles)
     elif op is not None:  # (None: a feed-forward alone)
         x, live, causal = _attention(p, x, angles, idx_angles, batch, cfg,
                                      cfg.sliding_window if op == SLIDING else 0)
     share = experts and cfg.layer_stats > 4
+    streams = x  # (under hyper-connections: what the feed-forward's output goes onto a mix of)
+    if not plain:
+        if op != LATENT or experts is None or cfg.sandwich or cfg.norm == "layer":
+            raise ValueError("hyper-connections are built around latent attention and a gated MLP or "
+                             "the experts, each under its own RMS norm, alone")
+        x, mix = mixed_in(x, "hc2")
     given = ()  # from the shared expert: the layer's normed rows b, and x + Shared(b)
     if experts and cfg.shared_experts:
         with jax.named_scope("shared_expert"):  # every token's, whatever it chose; the norm is here, once
             def beside(p, x):
                 b = rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype)
-                return b, x + _dense_mlp(p, b)
+                return b, x + _dense_mlp(p, b) if plain else _dense_mlp(p, b)
 
             given = jax.jit(beside)({"norm2": p["norm2"], "w_up": p["shared_up"],
                                      "w_down": p["shared_down"],
                                      **({"w_gate": p["shared_gate"]} if "shared_gate" in p else {})}, x)
     with jax.named_scope("moe" if experts else "mlp"):
         def mlp(p, x, *given):
-            b, onto = given or (rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype), x)
+            b, onto = given or (rms_norm(x, p["norm2"], cfg.rms_eps).astype(x.dtype),
+                                x if plain else 0)  # (the BRANCH's output alone where streams mix)
             if not experts:
-                return x + _dense_mlp(p, b), jnp.zeros((), jnp.int32)
+                return (x + _dense_mlp(p, b) if plain else _dense_mlp(p, b)), jnp.zeros((), jnp.int32)
             y, tokens = dropless_moe(
                 b, p["router"], p.get("w_gate"), p["w_up"], p["w_down"], k=cfg.experts_per_token,
                 num_experts=cfg.num_experts, experts_held=cfg.experts_held,
@@ -2083,6 +2168,8 @@ def decoder_layer(p, x, angles, idx_angles, cfg: DecoderConfig, kind, batch: int
             busiest, held = jnp.zeros((), jnp.int32), ()
         else:
             x, busiest, *held = jax.jit(mlp)(p, x, *given)
+    if not plain:
+        x = mixed_out(streams, x, mix)
     even = x.shape[0] * cfg.experts_per_token / cfg.num_experts if experts else 0.0
     stats = [busiest.astype(jnp.float32), jnp.float32(even),
              jnp.asarray(live, jnp.float32), jnp.float32(causal)]
@@ -2206,6 +2293,10 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
         by_op = {op: jnp.tile(table, (batch, 1)) for op, table in by_op.items()}
     if cfg.stream_dtype:
         x = x.astype(cfg.stream_dtype)
+    if cfg.hc_mult:  # the embedded row enters as hc_mult equal streams, side by side
+        if cfg.passes > 1 or rows is not None:
+            raise ValueError("hyper-connections in a looped stack, or on some rows alone, are not built")
+        x = jnp.concatenate([x] * cfg.hc_mult, axis=1)
     # a step in which some causal call takes a BLOCK of heads a grid step counts BLOCK_STATS, last of
     # all: constants of the shapes, summed here over the layers (and a looped model's passes) and
     # laid down as ONE constant (a scalar a layer is a transfer a layer while the step is traced)
@@ -2223,8 +2314,12 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
     def wanted(u):  # the wanted rows of each sequence
         return u.reshape(batch, s, -1)[:, at].reshape(batch * len(at), -1)
 
+    found = {}  # under hyper-connections: the step's largest defect of an H_res
+
     def stack(x, stats):  # every layer once
-        handed = {} if cfg.hands_on else None  # what a layer made that a later one reads, beside x
+        # what a layer made that a later one reads, beside x (under hyper-connections: the largest
+        # defect of an H_res so far, which every layer raises)
+        handed = {} if cfg.hands_on or cfg.hc_mult else None
         for i, p in enumerate(params["layers"]):
             kind = cfg.layer_kind(i)
             if handed is None:
@@ -2237,6 +2332,12 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
                 if i == cut and "m" in handed:  # the later layers read their own rows of it
                     handed["m"] = wanted(handed["m"])
             stats = stats + layer_stats
+        if cfg.hc_mult:  # the streams are SUMMED ahead of the final norm, in float32
+            found["defect"] = handed["defect"]
+            with jax.named_scope("hyper_out"):  # (lane-aligned slices: no [T, n, D] relayout)
+                d = cfg.hidden_size
+                x = sum(x[:, j * d:(j + 1) * d].astype(jnp.float32)
+                        for j in range(cfg.hc_mult)).astype(x.dtype)
         return (x if rows is None or cut is not None else wanted(x)), stats
 
     if cfg.passes > 1:
@@ -2251,10 +2352,17 @@ def trunk(params, x, pos, cfg: DecoderConfig, batch: int = 1, exits: bool = Fals
             rest + (ran, layers * batch * s) + cuts, np.float32)])
     if tail:  # every group the step has not at 0, LOOP_STATS' places among them
         rest = (0,) * (LAYER_GROUPS - cfg.layer_stats + len(LOOP_STATS)) + tail
-        return x, jnp.concatenate([stats[:4], served, stats[4:], np.asarray(rest, np.float32)])
-    if cfg.layer_stats > 4:
-        return x, jnp.concatenate([stats[:4], served, stats[4:]])
-    return x, jnp.concatenate([stats, served])
+        stats = jnp.concatenate([stats[:4], served, stats[4:], np.asarray(rest, np.float32)])
+    elif cfg.layer_stats > 4:
+        stats = jnp.concatenate([stats[:4], served, stats[4:]])
+    else:
+        stats = jnp.concatenate([stats, served])
+    if cfg.hc_mult:  # every group the step has not at 0, then HYPER_STATS, the vector's last
+        before = len(STEP_STATS) + LAYER_GROUPS - 4 + len(LOOP_STATS + BLOCK_STATS + ROWS_STATS + PART_STATS)
+        mixes = 2.0 * len(params["layers"]) * batch * s
+        stats = jnp.concatenate([stats, np.zeros((before - stats.shape[0],), np.float32),
+                                 jnp.stack([jnp.float32(mixes), found["defect"]])])
+    return x, stats
 
 
 def _pass_end(x, g, gate, eps: float):
@@ -2395,11 +2503,16 @@ def fold_step_stats(metrics, stats) -> None:
     where a pass goes ahead of the held rows' loop, fifteen from a looped
     model, seventeen where a causal call takes a block of heads a grid step,
     nineteen where the trunk's later layers ran on the served rows alone,
-    twenty where a causal call cuts a stacked group's rows into parts)
+    twenty where a causal call cuts a stacked group's rows into parts,
+    twenty-two where the stream between the layers is several rows a token)
     to the
     pipeline's counters of the same names (``PipelineMetrics.counters``:
-    in ``snapshot()`` and so under ``/metrics``)."""
+    in ``snapshot()`` and so under ``/metrics``); a name in :data:`MAX_STATS`
+    is RAISED to the step's value, not added to."""
     names = (STEP_STATS + SHARE_STATS + PAIR_STATS + LINEAR_STATS + AHEAD_STATS + LOOP_STATS
-             + BLOCK_STATS + ROWS_STATS + PART_STATS)
+             + BLOCK_STATS + ROWS_STATS + PART_STATS + HYPER_STATS)
     for name, value in zip(names, np.asarray(stats, np.float64)):
-        metrics.add_counter(name, float(value))
+        if name in MAX_STATS:
+            metrics.raise_counter(name, float(value))
+        else:
+            metrics.add_counter(name, float(value))

@@ -293,6 +293,12 @@ class PipelineMetrics:
         with self._counters_lock:
             self.counters[name] = self.counters.get(name, 0.0) + amount
 
+    def raise_counter(self, name: str, value: float):
+        """Raise the named counter to ``value`` where that is larger (created
+        at ``value`` on first use): a running maximum beside the sums."""
+        with self._counters_lock:
+            self.counters[name] = max(self.counters.get(name, value), value)
+
     def _queue_stats(self) -> Optional[dict]:
         q = self._queue
         if q is None:

@@ -162,6 +162,12 @@ PINNED_STEPS = {
     # may have (all taken in Python, before anything is traced); ling3's kernel, whose body this
     # test blanks, is held to the traced equation by `tests/test_decoder_olmo_hybrid.py -k ling3`
     "olmo_hybrid_7b_prefill_epix10k2m": "8cf4e88c84e23a402a343c6010b09e1fee7e07cbc84fc431a1967bd3dcb885da",
+    # pinned in PR 78, which added it (the eleventh): DeepSeek-V3's block with each branch between a
+    # `hyper_in` and a `hyper_out` (`ops/hyper_connection.py`) and the stream `[T, 4 * D]` between the
+    # layers. The twelve other decoders' steps were hashed on the parent's tree and on PR 78's (these ten
+    # and minicpm_sala's and phi4flash's, which have no pin): none moved — `latent_attention`, `beside` and
+    # `mlp` hand back the branch's output alone where `cfg.hc_mult` says so, read in Python
+    "xing4_29b_a4b_prefill_epix10k2m": "e69da6258d104d81442a4ff9963fe9c6b12b7ca96bc4daf942b9ba3ff715c271",
 }
 
 
@@ -530,3 +536,58 @@ def test_every_latent_layer_of_kimi_s_step_hands_the_kernel_what_its_product_wro
     assert sum("jit(turn_tables)" in line for _, line in made.values()) == 2  # [cos|cos], [sin|sin]
 
 
+
+
+def _xing4_step():
+    """``xing4_29b_a4b_prefill_epix10k2m``'s served step at the published sizes
+    (``tests/chip.py``'s ``compiled``: once a worker)."""
+    import jax.numpy as jnp
+
+    from chip import F32, H, PANELS, S, W
+    from psana_ray_tpu.models import decoder
+
+    cfg, dcfg, params = decoder_cell("xing4_29b_a4b_prefill_epix10k2m")
+    calib = (S((PANELS, H, W), F32), S((PANELS, H, W), F32), S((PANELS, H, W), jnp.uint8))
+    frames = S((cfg["batch_size"], PANELS, H, W), jnp.uint16)
+
+    def step(p, c, f, i):
+        return decoder.frame_step(p, c, f, i, cfg=dcfg, threshold=10.0)
+
+    return step, (params, calib, frames, S((cfg["prompt_tokens"],), jnp.int32)), cfg, dcfg
+
+
+def test_the_xing4_step_compiles_whole_with_its_mixes_in_two_kernels_a_branch(one_chip, monkeypatch):
+    """The whole served step of ``xing4_29b_a4b_prefill_epix10k2m`` (layers 0-7
+    of 40, every expert held, all 131,072 ids) compiled for the described v5e
+    (45 s): it fits the chip under the 14.5 GB the configuration's rule for its
+    DEPTH names (weights 11.36 GB; over that, the file takes layers 0-6); each
+    of the sixteen branches' mixes is ONE ``hyper_in`` and ONE ``hyper_out``
+    under the scopes a trace reads them by; the stream between the layers is
+    bf16 ``[17408, 14336]`` and NO float32 array of that size exists anywhere
+    (left to XLA the wide norm alone made one); the mixing numbers a kernel
+    hands the other are float32 ``[17408, 128]``; kimi's latent kernel runs
+    once a layer and the grouped product three times an expert layer, where the
+    roofline functions count them."""
+    import collections
+
+    from chip import compiled
+
+    text, (arguments, outputs, temporaries), (cfg, dcfg) = compiled(_xing4_step, one_chip, monkeypatch)
+    assert arguments + outputs + temporaries < 14.5e9 and 11.3e9 < arguments < 11.4e9
+    calls = [line for line in text.splitlines() if 'custom_call_target="tpu_custom_call"' in line]
+    names = collections.Counter(re.match(r"\s*(?:ROOT )?%([a-z_]+)", line).group(1) for line in calls)
+    branches, expert_layers = 2 * dcfg.num_layers, dcfg.num_layers - dcfg.num_dense_layers
+    assert names == {"hyper_in": branches, "hyper_out": branches, "masked_gqa_attention": dcfg.num_layers,
+                     "gmm": 3 * expert_layers, "rows_as_words": expert_layers,
+                     "row_gather": expert_layers, "fused_calibrate": 1}, names
+    assert all("/hyper_in/" in line for line in calls if re.match(r"\s*%hyper_in", line))
+    assert all("/hyper_out/" in line for line in calls if re.match(r"\s*%hyper_out", line))
+    tokens, wide = cfg["step_tokens"], dcfg.hc_mult * dcfg.hidden_size
+    assert (tokens, wide) == (17408, 14336)
+    entry = text[text.index("ENTRY"):]  # what the step WRITES (a fusion's own body may widen a value it reads)
+    made = [m.group(1) for line in entry.splitlines()
+            for m in [re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (\w+\[[\d,]*\])", line)] if m]
+    assert f"bf16[{tokens},{wide}]" in made and f"f32[{tokens},{wide}]" not in made
+    assert f"f32[{tokens},{dcfg.hc_mult},{dcfg.hidden_size}]" not in made
+    handed = [line for line in calls if re.match(r"\s*%hyper_out", line)]
+    assert all(f"f32[{tokens},128]" in line for line in handed)  # the mixing numbers: float32

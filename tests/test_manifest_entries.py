@@ -37,7 +37,7 @@ FOLD_DEBT = ["proj_ms", "mlp_ms", "sparse_attn_ms", "conv_ms", "latent_attn_ms",
 # decoder cells without a share of the peak: keye has no roofline module yet, the rest wait for room
 NO_STEP_MFU = {"keye_epix_saturated", "granite_epix_saturated", "ouro_epix_saturated",
                "nemotron3_epix_saturated", "olmo_hybrid_epix_saturated", "minicpm_sala_epix_saturated",
-               "phi4flash_epix_saturated"}
+               "phi4flash_epix_saturated", "xing4_epix_saturated"}
 # rule 8's table, the ONE place tests/ says which cell has which mechanism: a scope one kind of
 # layer opens -> whether a configuration has that kind (ops, feeds: its layer_kind()s, unzipped)
 OPENED_BY = {
@@ -61,6 +61,8 @@ OPENED_BY = {
     "mlp": lambda c, ops, feeds: False in feeds,
     "shared_expert": lambda c, ops, feeds: True in feeds and c.shared_experts > 0,
     "pass_end": lambda c, ops, feeds: c.passes > 1,
+    "hyper_in": lambda c, ops, feeds: c.hc_mult > 0,
+    "hyper_out": lambda c, ops, feeds: c.hc_mult > 0,
 }
 
 
